@@ -44,7 +44,6 @@ def main():
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from deepspeed_tpu.parallel.topology import MeshTopology
@@ -96,7 +95,7 @@ def main():
                         acc = acc + 0.0 * jnp.sum(fn(y)).astype(jnp.float32)
                     return (xw[0] + acc)[None]
 
-                return shard_map(body, mesh=mesh, in_specs=P("dp"),
+                return jax.shard_map(body, mesh=mesh, in_specs=P("dp"),
                                  out_specs=P("dp"))(x)
 
             x = jnp.ones((n, elems), jnp.float32)

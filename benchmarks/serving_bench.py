@@ -40,11 +40,11 @@ tokens/sec plus compile counts and the paged engine's ``stats()``:
    quantized lanes are NOT exact-parity lanes).  With ``--tp N`` a
    ``kv8`` lane also runs on the tp engine (the tp × kv8 combo: per-chip
    pool bytes divide by BOTH factors).  CPU-sim tok/s measures XLA-CPU
-   op mixes, not HBM bandwidth — the on-chip bandwidth argument is
-   PROFILE.md's (+32-34% w8a8 decode; int8 KV halves decode's dominant
-   traffic term).
+   op mixes, not HBM bandwidth — the on-chip bandwidth argument
+   (+32-34% w8a8 decode in a rounds 1–4 builder run; int8 KV halves
+   decode's dominant traffic term) has no ledger number yet.
 
-Methodology (PROFILE.md "continuous-batching serving" entry): the default
+Methodology: the default
 trace draws ARBITRARY prompt lengths in [32, 512] and completion budgets in
 [16, 64] — real mixed traffic, where the sequential path jit-compiles one
 program per exact request shape while the serving loop compiles O(1).

@@ -102,7 +102,8 @@ def add_tuning_arguments(parser):
     return lr_schedules.add_tuning_arguments(parser)
 
 
-def init_inference(model=None, config=None, params=None, **kwargs):
+def init_inference(model=None, config=None, params=None, *,
+                   device_group=None, **kwargs):
     """Inference engine entry (reference __init__.py:233).
 
     ``model`` may be a :class:`ModelSpec`, a HuggingFace torch model (its
@@ -110,6 +111,8 @@ def init_inference(model=None, config=None, params=None, **kwargs):
     the ``replace_transformer_layer`` analog), or a path to an HF checkpoint
     directory.  ``params``: trained parameter pytree; without it the engine
     serves the converted HF weights, or freshly-initialized ones.
+    ``device_group``: see :class:`~deepspeed_tpu.inference.engine
+    .InferenceEngine` (``None`` = span every device).
     """
     from .inference.engine import InferenceEngine
     from .inference.config import DeepSpeedInferenceConfig
@@ -124,7 +127,8 @@ def init_inference(model=None, config=None, params=None, **kwargs):
         model, converted = load_hf_weights(model)
         if params is None:
             params = converted
-    return InferenceEngine(model, config, params=params)
+    return InferenceEngine(model, config, params=params,
+                           device_group=device_group)
 
 
 def init_router(model=None, config=None, params=None, *, replicas=2,
@@ -136,9 +140,11 @@ def init_router(model=None, config=None, params=None, *, replicas=2,
                 max_rehomes=3, prefill_workers=None,
                 giant_context_tokens=0, **serving_kwargs):
     """Multi-replica serving entry (ROADMAP item 1): ``replicas`` ×
-    ``init_serving`` engines — all sharing ONE weight pytree (the first
-    replica's initialized/loaded params are reused, so every replica is
-    token-identical by construction) — behind a
+    ``init_serving`` engines — all serving ONE set of weights (the first
+    replica's initialized/loaded params are handed to the others, so every
+    replica is token-identical by construction; each replica places them
+    on its own ``tp x sp`` device group, sharing buffers only when groups
+    coincide) — behind a
     :class:`~deepspeed_tpu.serving.ReplicaRouter`.
 
     The router fronts the fleet with an incremental async API:
@@ -202,9 +208,14 @@ def init_router(model=None, config=None, params=None, *, replicas=2,
             "prefill_workers already assigns each replica's role")
     roles = plan_roles(int(replicas), prefill_workers)
     reps = []
-    for role in roles:
-        per = serving_kwargs if not prefill_workers else \
-            {**serving_kwargs, "role": role}
+    for i, role in enumerate(roles):
+        # one tp x sp device group per replica (wrapping around when the
+        # host has fewer groups than replicas); dp_tp engines span all
+        per = dict(serving_kwargs)
+        if per.get("engine_mode", "replicas") == "replicas":
+            per.setdefault("device_group", i)
+        if prefill_workers:
+            per["role"] = role
         srv = init_serving(model, config, params, **per)
         if params is None:
             params = srv.engine.params
@@ -235,7 +246,8 @@ def init_serving(model=None, config=None, params=None, *, slots=8,
                  ngram_max=3, ngram_min=1,
                  sampling=True, spec_verifier="rejection",
                  logit_masks=False,
-                 shard_kv=None, topology=None, debug_checks=False,
+                 shard_kv=None, topology=None, device_group=None,
+                 debug_checks=False,
                  trace_capacity=16384, slo_targets=None, peak_flops=None,
                  **kwargs):
     """Continuous-batching serving entry: an ``init_inference`` engine
@@ -273,7 +285,11 @@ def init_serving(model=None, config=None, params=None, *, slots=8,
     engine shards the paged KV pool over the KV-head dim so each chip
     stores ``HKV/N`` heads (N× the servable blocks/context).  ``shard_kv``
     (default auto) controls the pool sharding — see
-    :class:`~deepspeed_tpu.inference.serving.ServingEngine`.
+    :class:`~deepspeed_tpu.inference.serving.ServingEngine`.  One engine
+    occupies exactly one ``tp x sp`` group of chips — group
+    ``device_group`` (default 0; :func:`init_router` gives replica ``i``
+    group ``i``, wrapping around when there are fewer groups than
+    replicas) — except in ``engine_mode="dp_tp"``, which spans them all.
 
     **Quantized serving**: ``quantize="kv8"`` stores the paged KV pool
     (and the speculative draft pool) as int8 with a per-block scale table
@@ -409,7 +425,13 @@ def init_serving(model=None, config=None, params=None, *, slots=8,
             config = config.model_copy(deep=True)
             config.quant.enabled = True
             config.quant.type = "w8a8"
-    engine = init_inference(model, config, params, **kwargs)
+    if engine_mode == "replicas":
+        device_group = device_group or 0
+    elif device_group is not None:
+        raise ValueError("engine_mode='dp_tp' spans every device — "
+                         "device_group does not apply")
+    engine = init_inference(model, config, params,
+                            device_group=device_group, **kwargs)
     return ServingEngine(engine, slots=slots, max_seq_len=max_seq_len,
                          prompt_buckets=prompt_buckets,
                          prefill_batch=prefill_batch, block_size=block_size,

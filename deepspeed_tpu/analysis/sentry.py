@@ -73,11 +73,8 @@ def abstract_signature(args: tuple, kwargs: dict) -> Tuple[str, ...]:
     import jax
 
     leaves = jax.tree_util.tree_flatten_with_path((args, kwargs))[0]
-    try:
-        keystr = jax.tree_util.keystr
-    except AttributeError:              # very old jax: positional paths
-        keystr = str
-    return tuple(_describe_leaf(keystr(p), x) for p, x in leaves)
+    return tuple(_describe_leaf(jax.tree_util.keystr(p), x)
+                 for p, x in leaves)
 
 
 def signature_diff(prev: Sequence[str], cur: Sequence[str]) -> List[str]:

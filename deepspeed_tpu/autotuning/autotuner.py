@@ -135,7 +135,7 @@ class Autotuner:
     # -------------------------------------------------- staged search (v2)
     def _model_knob_space(self, stage_name: str) -> List[dict]:
         """Candidate ``_model`` overrides for one staged knob group.  These
-        are the knobs that actually set TPU throughput (PROFILE.md's
+        are the knobs that actually set TPU throughput (the rounds 1–4
         measured winners: remat policy, layer-loop unrolling, gas, flash
         block sizes) — the reference's fast mode never touches them."""
         probe = self.model_factory()

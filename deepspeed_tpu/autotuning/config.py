@@ -30,7 +30,7 @@ class AutotuningConfig(DeepSpeedConfigModel):
     #: staged mode: which knob groups to tune, in order.  "batch" = zero
     #: stage x micro batch; "remat" = remat_policy x scan_layers; "gas" =
     #: gradient accumulation; "flash" = flash kernel block sizes.  These are
-    #: the knobs that actually set TPU throughput (PROFILE.md) — the
+    #: the knobs that actually set TPU throughput — the
     #: reference's fast mode only covers the first group.
     stages: List[str] = ["batch", "remat", "gas", "flash"]
     gas_candidates: List[int] = [1, 2, 4, 8, 16]

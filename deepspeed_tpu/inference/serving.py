@@ -223,6 +223,7 @@ from ..telemetry import MetricsRegistry, ProfilerWindow, TraceTimeline
 from ..telemetry.slo import SLOTracker
 from ..utils.logging import log_dist
 from ..utils.lru import LRUCache
+from ..utils.platform import on_tpu
 from .paged import (SCRATCH_BLOCK, BlockAllocator, GroupedBlockAllocator,
                     HostBlockStore, NvmeBlockStore, PrefixCache,
                     TransportError, chain_key, chain_keys)
@@ -1305,7 +1306,15 @@ class ServingEngine:
                 from .engine import InferenceEngine
 
                 if not isinstance(draft, InferenceEngine):
-                    draft = InferenceEngine(draft, engine._config)
+                    draft = InferenceEngine(
+                        draft, engine._config,
+                        device_group=engine.device_group)
+                elif set(draft.mesh.devices.flat) != \
+                        set(engine.mesh.devices.flat):
+                    raise ValueError(
+                        "the draft engine sits on other devices than the "
+                        "target — build it with the target's device_group "
+                        "(or pass the draft as a ModelSpec)")
                 _validate_decode_hooks(draft.module, role="draft model",
                                        kv_quant=self.kv_quant,
                                        sampling=self.sampling)
@@ -1792,7 +1801,7 @@ class ServingEngine:
     def _donate(self):
         # donating the pool avoids a full cache copy per step; XLA:CPU
         # ignores donation with a warning, so only ask for it on TPU
-        return (1,) if jax.default_backend() == "tpu" else ()
+        return (1,) if on_tpu() else ()
 
     def _constrain_pool(self, cache):
         """dp_tp only: pin the cache OUTPUT of every decode/prefill program

@@ -30,6 +30,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..parallel.topology import TP_AXIS
 from ..runtime.model import ModelSpec
+from ..utils.platform import on_tpu
 
 PyTree = Any
 
@@ -59,7 +60,7 @@ class GPT2Config:
     #: flash kernel block sizes; larger blocks amortize grid overhead when
     #: head_dim is small (d=64 -> half-width MXU ops)
     #: 1024x1024 is the measured best for both the v2 (S<=1024) and v3
-    #: (S>=2048) kernel paths on v5e (PROFILE.md rounds 3-4)
+    #: (S>=2048) kernel paths on v5e (rounds 3-4 builder runs)
     flash_block_q: int = 1024
     flash_block_k: int = 1024
     #: sequence-parallel attention impl when mesh sp>1: auto|ulysses|ring
@@ -310,7 +311,7 @@ def _block(cfg: GPT2Config, x, layer, mask, rng, dropout: float):
 
     use_flash = cfg.use_flash
     if use_flash is None:
-        use_flash = jax.default_backend() == "tpu"
+        use_flash = on_tpu()
     if seq_parallel.sp_size() > 1 and dropout > 0.0:
         global _warned_sp_dropout
         if not _warned_sp_dropout:
@@ -325,11 +326,10 @@ def _block(cfg: GPT2Config, x, layer, mask, rng, dropout: float):
         attn = seq_parallel.sequence_parallel_attention(
             q, k, v, causal=True, impl=getattr(cfg, "sp_impl", "auto"))
     elif use_flash and dropout == 0.0 and mask is None:
-        from ..ops.flash_attention import flash_attention
-
-        attn = flash_attention(q, k, v, causal=True,
-                               block_q=getattr(cfg, "flash_block_q", 512),
-                               block_k=getattr(cfg, "flash_block_k", 1024))
+        attn = seq_parallel.mesh_flash_attention(
+            q, k, v, causal=True,
+            block_q=getattr(cfg, "flash_block_q", 512),
+            block_k=getattr(cfg, "flash_block_k", 1024))
     else:
         if mask is None:
             mask = jnp.tril(jnp.ones((s, s), bool))[None, None, :, :]

@@ -22,6 +22,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..parallel.topology import TP_AXIS
 from ..runtime.model import ModelSpec
+from ..utils.platform import on_tpu
 
 PyTree = Any
 
@@ -150,11 +151,9 @@ def _attention(cfg: LlamaConfig, q, k, v):
             q, k, v, causal=True, impl=cfg.sp_impl)
     use_flash = cfg.use_flash
     if use_flash is None:
-        use_flash = jax.default_backend() == "tpu"
+        use_flash = on_tpu()
     if use_flash:
-        from ..ops.flash_attention import flash_attention
-
-        return flash_attention(q, k, v, causal=True)
+        return seq_parallel.mesh_flash_attention(q, k, v, causal=True)
     rep = cfg.num_heads // cfg.num_kv_heads
     if rep > 1:
         k = jnp.repeat(k, rep, axis=1)

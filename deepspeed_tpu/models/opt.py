@@ -32,6 +32,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..parallel.topology import TP_AXIS
 from ..runtime.model import ModelSpec
+from ..utils.platform import on_tpu
 
 PyTree = Any
 _POS_OFFSET = 2  # HF OPTLearnedPositionalEmbedding.offset
@@ -184,10 +185,11 @@ def _attention(cfg: OPTConfig, q, k, v):
     """Causal attention on [B, H, S, hd]; flash on TPU, einsum elsewhere."""
     use_flash = cfg.use_flash
     if use_flash is None:
-        use_flash = jax.default_backend() == "tpu"
+        use_flash = on_tpu()
     if use_flash:
-        from ..ops.flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=True)
+        from ..parallel.sequence import mesh_flash_attention
+
+        return mesh_flash_attention(q, k, v, causal=True)
     s = q.shape[2]
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(cfg.head_dim)
     mask = jnp.tril(jnp.ones((s, s), bool))[None, None]

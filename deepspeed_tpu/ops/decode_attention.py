@@ -35,6 +35,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
+from ..utils.platform import interpret_kernels, on_tpu
 from . import paged_kv
 from .paged_kv import (_paged_gather, head_shard_map, head_shards,
                        is_quantized_pool, pool_payload, tp_axis)
@@ -42,13 +43,6 @@ from .paged_kv import (_paged_gather, head_shard_map, head_shards,
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 LANES = 128
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # --------------------------------------------------------- window context
@@ -144,7 +138,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     broadcast before the call), read for the row this grid step covers, so
     chunk skipping scales FLOPs with each slot's own valid length.
 
-    ``ks_ref``/``vs_ref`` (int8-KV pools only): [1, 1, block_k] per-token
+    ``ks_ref``/``vs_ref`` (int8-KV pools only): [1, 1, 1, block_k] per-token
     dequant scales riding next to the code chunks.  They fold into the
     math on its 2-D lane-dim tiles — ``q·(code*s_k) = (q·code)*s_k`` on
     the score columns, ``Σ p·(code*s_v) = (p*s_v)·code`` on the prob
@@ -172,7 +166,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale                              # [rep, bk]
         if ks_ref is not None:
-            s = s * ks_ref[...].reshape(1, -1).astype(jnp.float32)
+            s = s * ks_ref[0, 0].astype(jnp.float32)    # [1, bk] row
         idx = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(idx <= pos, s, NEG_INF)
 
@@ -183,7 +177,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
         p = jnp.exp(s - m_new)                        # [rep, bk]
         l_new = l_scr[...][:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         v = v_ref[0, 0].astype(jnp.float32)           # [bk, D]
-        pv = p * vs_ref[...].reshape(1, -1).astype(jnp.float32) \
+        pv = p * vs_ref[0, 0].astype(jnp.float32) \
             if vs_ref is not None else p
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             pv, v, (((1,), (0,)), ((), ())),
@@ -213,7 +207,7 @@ def decode_attention_pallas(q, k_cache, v_cache, q_pos, *,
     while s % block_k:  # largest divisor of s not above the requested block
         block_k -= 1
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = interpret_kernels()
 
     qg = q[:, :, 0, :].reshape(b, hkv, rep, d)        # [B, HKV, rep, D]
     pos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1), (b,))
@@ -234,7 +228,7 @@ def decode_attention_pallas(q, k_cache, v_cache, q_pos, *,
             pltpu.VMEM((rep, LANES), jnp.float32),    # l
             pltpu.VMEM((rep, d), jnp.float32),        # acc
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(pos, qg, k_cache, v_cache)
@@ -244,7 +238,7 @@ def decode_attention_pallas(q, k_cache, v_cache, q_pos, *,
 def decode_attention(q, k_cache, v_cache, q_pos, *,
                      sm_scale: Optional[float] = None):
     """Dispatch: Pallas kernel for single-token decode on TPU, XLA otherwise."""
-    if q.shape[2] == 1 and jax.default_backend() == "tpu":
+    if q.shape[2] == 1 and on_tpu():
         return decode_attention_pallas(q, k_cache, v_cache, q_pos,
                                        sm_scale=sm_scale)
     return decode_attention_reference(q, k_cache, v_cache, q_pos,
@@ -279,8 +273,6 @@ def _tp_shard_heads(body, q, k_pool, v_pool, block_tables, q_pos):
     the localization exact, so no cross-shard gather ever happens."""
     n = head_shards(pool_payload(k_pool).shape[1], q.shape[1])
     if paged_kv.dp_groups() > 1:
-        from jax.experimental.shard_map import shard_map
-
         mesh, _, gsize = paged_kv.dp_state()
         dp = paged_kv.dp_axis()
         pos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1),
@@ -293,9 +285,9 @@ def _tp_shard_heads(body, q, k_pool, v_pool, block_tables, q_pos):
             bt = paged_kv.localize_block_tables(bt, gsize)
             return body(q, kp, vp, bt, pos)
 
-        return shard_map(dp_body, mesh=mesh,
-                         in_specs=(qs, ps, ps, rs, rs),
-                         out_specs=qs, check_rep=False)(
+        return jax.shard_map(dp_body, mesh=mesh,
+                             in_specs=(qs, ps, ps, rs, rs),
+                             out_specs=qs, check_vma=False)(
             q, k_pool, v_pool,
             jnp.asarray(block_tables, jnp.int32), pos)
     if n <= 1:
@@ -344,7 +336,7 @@ def _paged_decode_kernel(pos_ref, bt_ref, q_ref, *refs, sm_scale: float,
     contiguous chunk), including the ``pl.when`` skip of blocks past the
     row's valid prefix.
 
-    ``quant``: the pool is int8 — two extra scale operands ([1, 1, bs]
+    ``quant``: the pool is int8 — two extra scale operands ([1, 1, 1, bs]
     rows of the per-block scale table, same index maps) ride next to the
     code blocks and dequantize in-kernel, so HBM traffic is codes +
     scales only.
@@ -360,19 +352,26 @@ def _paged_decode_kernel(pos_ref, bt_ref, q_ref, *refs, sm_scale: float,
                    ks_ref=ks_ref, vs_ref=vs_ref)
 
 
-def _paged_pool_operands(k_pool, v_pool, bs, d):
+def _paged_pool_operands(k_pool, v_pool):
     """(operand list, BlockSpec list, quant flag) for a k/v pool pair —
     float pools contribute two operands, int8 records four (codes +
     per-block scale rows), all walking the same ``bt_ref[i, k]`` physical-
     block index map."""
     quant = is_quantized_pool(k_pool)
+    nb, hkv, bs, d = pool_payload(k_pool).shape
     blk = pl.BlockSpec((1, 1, bs, d),
                        lambda i, j, k, pos_ref, bt_ref: (bt_ref[i, k], j, 0, 0))
     if not quant:
         return [k_pool, v_pool], [blk, blk], False
-    sblk = pl.BlockSpec((1, 1, bs),
-                        lambda i, j, k, pos_ref, bt_ref: (bt_ref[i, k], j, 0))
-    return ([k_pool["qp"], k_pool["ps"], v_pool["qp"], v_pool["ps"]],
+    # scale rows ride as [NB, HKV, 1, bs] views: Mosaic wants a block's
+    # last two dims tile-aligned or equal to the array's, and a (1, bs)
+    # tail of the 3-D table is neither (refused at lowering)
+    sblk = pl.BlockSpec(
+        (1, 1, 1, bs),
+        lambda i, j, k, pos_ref, bt_ref: (bt_ref[i, k], j, 0, 0))
+    ks = k_pool["ps"].reshape(nb, hkv, 1, bs)
+    vs = v_pool["ps"].reshape(nb, hkv, 1, bs)
+    return ([k_pool["qp"], ks, v_pool["qp"], vs],
             [blk, sblk, blk, sblk], True)
 
 
@@ -390,7 +389,7 @@ def _paged_decode_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
     qg = q[:, :, 0, :].reshape(b, hkv, rep, d)        # [B, HKV, rep, D]
     pos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1), (b,))
     bt = jnp.maximum(jnp.asarray(block_tables, jnp.int32), 0)
-    pools, pool_specs, quant = _paged_pool_operands(k_pool, v_pool, bs, d)
+    pools, pool_specs, quant = _paged_pool_operands(k_pool, v_pool)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                        # pos, block table
@@ -412,7 +411,7 @@ def _paged_decode_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
                           block_size=bs, quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(pos, bt, qg, *pools)
@@ -430,7 +429,7 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
         "pallas paged decode is single-token; use the XLA path"
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = interpret_kernels()
     body = functools.partial(_paged_decode_pallas, sm_scale=scale,
                              interpret=interpret)
     return _tp_shard_heads(body, q, k_pool, v_pool, block_tables, q_pos)
@@ -450,7 +449,7 @@ def _paged_verify_kernel(pos_ref, bt_ref, q_ref, *refs, sm_scale: float,
     yet-unverified draft tail.  Blocks wholly past ``base + T - 1`` are
     skipped, so FLOPs track each row's own valid length.
 
-    ``quant``: int8 pool — [1, 1, bs] scale rows ride next to the code
+    ``quant``: int8 pool — [1, 1, 1, bs] scale rows ride next to the code
     blocks and fold into the score/prob columns exactly like the decode
     kernel (``_decode_kernel`` docstring).
     """
@@ -480,7 +479,7 @@ def _paged_verify_kernel(pos_ref, bt_ref, q_ref, *refs, sm_scale: float,
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale                              # [rep*T, bk]
         if ks_ref is not None:
-            s = s * ks_ref[...].reshape(1, -1).astype(jnp.float32)
+            s = s * ks_ref[0, 0].astype(jnp.float32)    # [1, bk] row
         key_idx = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         q_off = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) % t
         s = jnp.where(key_idx <= base + q_off, s, NEG_INF)
@@ -492,7 +491,7 @@ def _paged_verify_kernel(pos_ref, bt_ref, q_ref, *refs, sm_scale: float,
         p = jnp.exp(s - m_new)                        # [rep*T, bk]
         l_new = l_scr[...][:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         v = v_ref[0, 0].astype(jnp.float32)           # [bk, D]
-        pv = p * vs_ref[...].reshape(1, -1).astype(jnp.float32) \
+        pv = p * vs_ref[0, 0].astype(jnp.float32) \
             if vs_ref is not None else p
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             pv, v, (((1,), (0,)), ((), ())),
@@ -527,7 +526,7 @@ def _paged_verify_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
     qg = q.reshape(b, hkv, rep, t, d).reshape(b, hkv, rep * t, d)
     pos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1), (b,))
     bt = jnp.maximum(jnp.asarray(block_tables, jnp.int32), 0)
-    pools, pool_specs, quant = _paged_pool_operands(k_pool, v_pool, bs, d)
+    pools, pool_specs, quant = _paged_pool_operands(k_pool, v_pool)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                        # pos, block table
@@ -549,7 +548,7 @@ def _paged_verify_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
                           block_size=bs, t=t, quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rep * t, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(pos, bt, qg, *pools)
@@ -571,7 +570,7 @@ def paged_verify_attention_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
         f"verify kernel takes windows up to {VERIFY_T_MAX}, got T={t}"
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = interpret_kernels()
     body = functools.partial(_paged_verify_pallas, sm_scale=scale,
                              interpret=interpret)
     return _tp_shard_heads(body, q, k_pool, v_pool, block_tables, q_pos)
@@ -592,7 +591,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, q_pos, *,
                                   q.shape[2]) > 1:
             return sp_attention.sp_prefill_attention(
                 q, k_pool, v_pool, block_tables, q_pos, sm_scale=sm_scale)
-    if jax.default_backend() == "tpu" and window_state() is None:
+    if window_state() is None and on_tpu():
         if q.shape[2] == 1:
             return paged_decode_attention_pallas(
                 q, k_pool, v_pool, block_tables, q_pos, sm_scale=sm_scale)

@@ -11,7 +11,8 @@ Layout: q, k, v are [B, H, S, D]; the grid walks (B*H, Sq/bq, Sk/bk) with the KV
 dimension innermost ("arbitrary") so the accumulator scratch carries across KV
 blocks.  f32 accumulation regardless of input dtype (bf16 in, bf16 out).
 
-Interpret mode (CPU testing) is selected automatically off the backend.
+Interpret mode is the default only where the CPU platform was requested
+(``utils/platform.py``); on a TPU every kernel here is compiled by Mosaic.
 """
 
 from __future__ import annotations
@@ -25,16 +26,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from ..utils.platform import interpret_kernels
+
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 LANES = 128
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -153,7 +149,7 @@ def _fwd(q, k, v, sm_scale: float, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q, d), jnp.float32),      # acc
         ],
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*inputs)
@@ -321,7 +317,7 @@ def _bwd_dq_call(q, k, v, do, lse_b, delta_b, *, sm_scale, causal, block_q,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*inputs)
@@ -378,7 +374,7 @@ def _bwd_dkv_call(q, k, v, do, lse_b, delta_b, *, sm_scale, causal, block_q,
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*([q, k, v, do, lse_b, delta_b] +
@@ -457,13 +453,13 @@ def _v2_eligible(kv_pad: int, d: int) -> bool:
 def _v3_eligible(kv_pad: int, d: int) -> bool:
     """v3 kernels (chunked-grid, compact row stats): the long-sequence path.
 
-    Measured on v5e (round 4, PROFILE.md): ~8-10% faster than the v1
+    Measured on v5e (round-4 builder runs): ~8-10% faster than the v1
     two-kernel path at S in [2048, 8192] — fwd folds the softmax scale and
     log2(e) into q and uses exp2; bwd reads lse/delta as compact 8-sublane
     operands instead of v1's [bh, S, 128]-broadcast f32 arrays (~200MB of
     HBM traffic per layer at bench shapes).  A fused one-kernel backward and
     a resident-KV chunk-loop variant were both probed and lost (fused: VMEM
-    cliff at S=8192 + slower at 4096; see PROFILE.md round-4 notes).
+    cliff at S=8192 + slower at 4096).
     """
     import os
 
@@ -480,7 +476,7 @@ def _v2_compiler_params(dimension_semantics):
     import os
 
     vmem_mb = os.environ.get("DS_V2_VMEM_MB")
-    return _CompilerParams(
+    return pltpu.CompilerParams(
         dimension_semantics=dimension_semantics,
         vmem_limit_bytes=(int(float(vmem_mb) * 2**20) if vmem_mb else None))
 
@@ -649,7 +645,7 @@ def _bwd_v2(q, k, v, o, do, sm_scale, causal, block_q, interpret, true_kv_len,
 #
 # Same chunked-grid structure as v1 (scratch-carried online softmax in the
 # forward; separate dq / dkv backward kernels) but with the v2 tricks that
-# carry over to chunking, each A/B-measured on-chip (PROFILE.md round 4):
+# carry over to chunking, each A/B-measured on-chip (round-4 builder runs):
 #  - softmax scale AND log2(e) folded into q once per block ([bq, d] pass
 #    instead of a [bq, bk] f32 multiply per chunk); exp2 everywhere.
 #  - row stats live in COMPACT [bh, 1, S] f32 arrays.  The forward writes
@@ -754,7 +750,7 @@ def _fwd_v3(q, k, v, sm_scale, causal, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((bh, q_len, d), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, q_len), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -885,7 +881,7 @@ def _bwd_v3(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
         out_specs=qspec,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -907,7 +903,7 @@ def _bwd_v3(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
                         pltpu.VMEM((block_k, d), jnp.float32)],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -1020,7 +1016,7 @@ def flash_attention(q, k, v, causal: bool = True,
     to the block size; padded keys are masked, padded query rows sliced off.
     """
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = interpret_kernels()
     b, h, q_len, d = q.shape
     hkv = k.shape[1]
     if hkv != h:

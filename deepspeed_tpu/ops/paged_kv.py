@@ -145,11 +145,9 @@ def head_shard_map(fn, in_specs, out_specs):
     """``shard_map`` over the configured mesh.  Callers place
     :func:`tp_axis` on HEAD dims only, so the body is embarrassingly
     parallel across chips — no collective ever appears inside
-    (``check_rep=False``: outputs are sharded, not replicated)."""
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(fn, mesh=_TP_MESH, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    (``check_vma=False``: outputs are sharded, not replicated)."""
+    return jax.shard_map(fn, mesh=_TP_MESH, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ------------------------------------------------------------- dp context
@@ -327,8 +325,6 @@ def paged_cache_update(ck, cv, k, v, pos, block_tables, valid=None):
         # dp_tp serving: rows + physical blocks shard over dp (heads over
         # tp when divisible); each shard scatters into its own pool chunk
         # through localized tables — no cross-shard traffic
-        from jax.experimental.shard_map import shard_map
-
         hp = P(_DP_AXIS, _TP_AXIS) if n > 1 else P(_DP_AXIS)
         dpsp = P(_DP_AXIS)
         gsize = _DP_GSIZE
@@ -339,10 +335,10 @@ def paged_cache_update(ck, cv, k, v, pos, block_tables, valid=None):
             bt = localize_block_tables(bt, gsize)
             return _paged_cache_update(ck, cv, k, v, pos, bt, valid)
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=_DP_MESH,
             in_specs=(hp, hp, hp, hp, dpsp, dpsp, dpsp),
-            out_specs=(hp, hp), check_rep=False)(
+            out_specs=(hp, hp), check_vma=False)(
                 ck, cv, k, v, pos,
                 jnp.asarray(block_tables, jnp.int32), valid)
     if n <= 1:

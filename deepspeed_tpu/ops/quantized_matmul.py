@@ -34,13 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from ..utils.platform import interpret_kernels
 
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 #: Trace-time gates set by the engine that owns the current trace (same
@@ -149,7 +144,7 @@ def _qmm_call(x2d, q3, scale3, out_dtype, block_k, block_n, interpret):
         out_specs=pl.BlockSpec((b, block_n), lambda n, ki: (0, n)),
         out_shape=jax.ShapeDtypeStruct((b, n_dim), out_dtype),
         scratch_shapes=[pltpu.VMEM((b, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x3, q3, scale3)
@@ -166,7 +161,7 @@ def quantized_matmul(x, rec: dict, out_dtype=None, *, block_k: int = None,
     (OPT-1.3B 68.5 vs 82.2 tok/s; OPT-6.7B 10.1 vs 12.1) — the in-kernel
     int8->bf16 convert is a cross-tiling relayout costing ~7us/MB, 6x the
     fetch+VPU theory, and XLA runs its 5-byte/param materializing pipeline
-    at full HBM bandwidth (PROFILE.md round-4 second pass).  The kernel is
+    at full HBM bandwidth (round-4 builder measurement).  The kernel is
     kept as the scaffold for a true s8-MXU (W8A8) path, which avoids the
     relayout entirely.  Also falls back when the shapes don't tile, the
     record is asymmetric, or the row count exceeds the accumulator budget
@@ -217,7 +212,7 @@ def quantized_matmul(x, rec: dict, out_dtype=None, *, block_k: int = None,
     x2d = x.reshape(rows, k_dim)
     out = _qmm_call(x2d, q.reshape(k_dim, n_dim // g, g),
                     scale.reshape(k_dim, n_dim // g, 1),
-                    out_dtype, bk, bn, _use_interpret())
+                    out_dtype, bk, bn, interpret_kernels())
     return out.reshape(lead + (n_dim,))
 
 
@@ -302,7 +297,7 @@ def _w8a8_call(x2d, qk, kscale, out_dtype, block_k, interpret,
         out_specs=pl.BlockSpec((b, n_dim), lambda n, ki: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, n_dim), out_dtype),
         scratch_shapes=[pltpu.VMEM((b, n_dim), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=vmem_limit),
         interpret=interpret,
@@ -336,7 +331,7 @@ def _w8a8_local(x2d, qk, kscale3, block_k=None, out_dtype=None):
     bk, _ = _w8a8_pick_bk(k_dim, kscale3.shape[0], n_dim, block_k)
     if (bk > 0 and n_dim % 128 == 0
             and os.environ.get("DS_W8A8", "1") != "0"):
-        return _w8a8_call(x2d, qk, kscale3, out_dtype, bk, _use_interpret(),
+        return _w8a8_call(x2d, qk, kscale3, out_dtype, bk, interpret_kernels(),
                           vmem_limit=_qmm_vmem_limit())
     deq = quant.dequantize_k({"qk": qk, "kscale": kscale3}, x2d.dtype)
     return jax.lax.dot(x2d, deq, preferred_element_type=out_dtype)
@@ -441,23 +436,14 @@ def _w8a8_tp_body(x2d, qk, kscale3):
 #: shardy's propagation knows a K-sharded weight still yields a full [B, N]
 #: result; the partition lowering owns the actual psum.
 _w8a8_tp_call = custom_partitioning(_w8a8_tp_body)
-try:
-    _w8a8_tp_call.def_partition(
-        partition=_w8a8_partition,
-        infer_sharding_from_operands=_w8a8_infer_sharding,
-        propagate_user_sharding=lambda mesh, user_shape: user_shape.sharding,
-        sharding_rule="b k, k n, s u n -> b n",
-        reduction_factors=("k", "s"),
-        need_replication_factors=("u",),
-    )
-except TypeError:
-    # older jax: def_partition predates the shardy sharding_rule kwargs —
-    # GSPMD propagation alone still gets the sharded lowering right
-    _w8a8_tp_call.def_partition(
-        partition=_w8a8_partition,
-        infer_sharding_from_operands=_w8a8_infer_sharding,
-        propagate_user_sharding=lambda mesh, user_shape: user_shape.sharding,
-    )
+_w8a8_tp_call.def_partition(
+    partition=_w8a8_partition,
+    infer_sharding_from_operands=_w8a8_infer_sharding,
+    propagate_user_sharding=lambda mesh, user_shape: user_shape.sharding,
+    sharding_rule="b k, k n, s u n -> b n",
+    reduction_factors=("k", "s"),
+    need_replication_factors=("u",),
+)
 
 
 def w8a8_matmul(x, rec: dict, out_dtype=None, *, block_k: int = None,
@@ -543,7 +529,7 @@ def _w8a8_stacked_call(idx, x2d, qks, kscales, out_dtype, block_k,
         functools.partial(_w8a8_stacked_kernel, nk=grid[1], k_group=k_group),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_dim), out_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=vmem_limit),
         interpret=interpret,
@@ -585,7 +571,7 @@ def w8a8_matmul_stacked(x, rec: dict, layer_idx, out_dtype=None, *,
         x2d = x.reshape(rows, k_dim)
         out = _w8a8_stacked_call(layer_idx, x2d, qk, kscale,
                                  out_dtype or x.dtype, bk,
-                                 _use_interpret(),
+                                 interpret_kernels(),
                                  vmem_limit=_qmm_vmem_limit())
         return out.reshape(lead + (n_dim,))
     layer = {

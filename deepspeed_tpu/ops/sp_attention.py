@@ -125,8 +125,6 @@ def sp_prefill_attention(q, k_pool, v_pool, block_tables, q_pos, *,
     actually scales quadratically with context, splits sp-ways over query
     heads after the first all-to-all.
     """
-    from jax.experimental.shard_map import shard_map
-
     from .decode_attention import decode_attention_reference
 
     mesh, ax = _SP_MESH, _SP_AXIS
@@ -164,8 +162,8 @@ def sp_prefill_attention(q, k_pool, v_pool, block_tables, q_pos, *,
                                   tiled=True)
 
     pos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1), (b,))
-    return shard_map(body, mesh=mesh, in_specs=(qs, ps, ps, P(), P()),
-                     out_specs=qs, check_rep=False)(
+    return jax.shard_map(body, mesh=mesh, in_specs=(qs, ps, ps, P(), P()),
+                         out_specs=qs, check_vma=False)(
         q, k_pool, v_pool, jnp.asarray(block_tables, jnp.int32), pos)
 
 

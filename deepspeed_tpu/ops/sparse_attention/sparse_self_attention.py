@@ -17,7 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..flash_attention import _sparse_attention_bh, _use_interpret
+from ...utils.platform import interpret_kernels
+from ..flash_attention import _sparse_attention_bh
 from .sparsity_config import SparsityConfig
 
 
@@ -40,7 +41,7 @@ def sparse_attention(q, k, v, layout, block: int,
         f"seq {s} != layout blocks {n} x block {block}")
     assert layout.shape[0] in (1, h)
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = interpret_kernels()
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     layout = jnp.asarray(layout, jnp.float32)

@@ -48,13 +48,10 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops import flash_attention as fa
+from ..utils.platform import interpret_kernels
 from .topology import DATA_AXES, SP_AXIS, TP_AXIS
 
 NEG_INF = -jnp.inf
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _repeat_kv(q, k, v):
@@ -466,6 +463,18 @@ def _qkvo_spec(mesh, q_shape, batch_axes, head_axis, sp_axis):
     return P(b_axes, h_axes, sp_axis, None)
 
 
+def _qkv_specs(mesh, q_shape, kv_shape, batch_axes, head_axis, sp_axis):
+    """``(q_spec, kv_spec)`` for one attention call.  GQA with kv heads not
+    divisible by tp: per-shard q heads would fall below the kv head count —
+    keep both head dims replicated instead."""
+    q_spec = _qkvo_spec(mesh, q_shape, batch_axes, head_axis, sp_axis)
+    kv_spec = _qkvo_spec(mesh, kv_shape, batch_axes, head_axis, sp_axis)
+    if q_spec[1] != kv_spec[1]:
+        q_spec = P(q_spec[0], None, sp_axis, None)
+        kv_spec = P(kv_spec[0], None, sp_axis, None)
+    return q_spec, kv_spec
+
+
 #: whole-chunk fallback cap: a [bq, bk] f32 score tile + scratch must fit VMEM
 _MAX_RING_BLOCK = 512
 
@@ -511,7 +520,7 @@ def ring_attention(q, k, v, causal: bool = True,
     h, hkv = q.shape[1], k.shape[1]
     assert h % hkv == 0, f"GQA needs num_heads {h} % kv_heads {hkv} == 0"
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_kernels()
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if sp == 1:
@@ -527,13 +536,8 @@ def ring_attention(q, k, v, causal: bool = True,
         raise ValueError(f"zigzag ring attention needs an even per-device "
                          f"chunk, got {c}")
 
-    q_spec = _qkvo_spec(mesh, q.shape, batch_axes, head_axis, sp_axis)
-    kv_spec = _qkvo_spec(mesh, k.shape, batch_axes, head_axis, sp_axis)
-    if q_spec[1] != kv_spec[1]:
-        # GQA with kv heads not divisible by tp: per-shard q heads would fall
-        # below the kv head count — keep both head dims replicated instead
-        q_spec = P(q_spec[0], None, sp_axis, None)
-        kv_spec = P(kv_spec[0], None, sp_axis, None)
+    q_spec, kv_spec = _qkv_specs(mesh, q.shape, k.shape, batch_axes,
+                                 head_axis, sp_axis)
 
     if use_zz:
         bq = _ring_block(c // 2, block_q)
@@ -581,7 +585,7 @@ def ulysses_attention(q, k, v, causal: bool = True,
     sp = mesh.shape[sp_axis]
     tp = mesh.shape[head_axis] if head_axis in mesh.shape else 1
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_kernels()
     if sp == 1:
         k, v = _repeat_kv(q, k, v)
         return fa.flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
@@ -619,6 +623,38 @@ def ulysses_attention(q, k, v, causal: bool = True,
     return fn(q, k, v)
 
 
+def mesh_flash_attention(q, k, v, causal: bool = True,
+                         sm_scale: Optional[float] = None, mesh=None,
+                         batch_axes=DATA_AXES, head_axis: str = TP_AXIS,
+                         **kw):
+    """``flash_attention`` placed on the mesh by hand: batch over the data
+    axes and heads over ``tp`` inside ``shard_map``, each chip running the
+    kernel on its own slice.
+
+    A Mosaic custom call has no partitioning rule.  Left bare inside a
+    GSPMD-partitioned step (the interpreted kernel is plain XLA and
+    partitions, which is why the CPU-sim mesh never showed it), XLA gathers
+    q/k/v and every chip computes attention for the whole global batch.
+    Attention is independent per (sequence, head), so no collective appears
+    here.  Runs the kernel directly on one device, when no mesh axis
+    divides the batch/head dims, or when the caller is already inside a
+    ``shard_map`` (its per-shard arrays are local already)."""
+    mesh = _resolve_mesh(mesh)
+    if mesh.size > 1 and not jax.sharding.get_abstract_mesh().manual_axes:
+        q_spec, kv_spec = _qkv_specs(mesh, q.shape, k.shape, batch_axes,
+                                     head_axis, None)
+        if _axis_size(mesh, q_spec[0]) * _axis_size(mesh, q_spec[1]) > 1:
+            def local(q, k, v):
+                return fa.flash_attention(q, k, v, causal=causal,
+                                          sm_scale=sm_scale, **kw)
+
+            return jax.shard_map(local, mesh=mesh,
+                                 in_specs=(q_spec, kv_spec, kv_spec),
+                                 out_specs=q_spec, check_vma=False)(q, k, v)
+    return fa.flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
+                              **kw)
+
+
 def sequence_parallel_attention(q, k, v, causal: bool = True,
                                 sm_scale: Optional[float] = None,
                                 impl: str = "auto", mesh=None,
@@ -634,11 +670,12 @@ def sequence_parallel_attention(q, k, v, causal: bool = True,
     mesh = _resolve_mesh(mesh)
     sp = mesh.shape[sp_axis]
     if sp == 1 or q.shape[2] % sp != 0:
-        # no sp axis, or sequence doesn't chunk evenly: plain (replicated-seq)
-        # flash attention — XLA SPMD handles any input sharding correctly
-        k, v = _repeat_kv(q, k, v)
-        return fa.flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                                  interpret=interpret, **kw)
+        # no sp axis, or sequence doesn't chunk evenly: replicated-seq
+        # flash attention, batch/heads still placed by hand
+        return mesh_flash_attention(
+            q, k, v, causal=causal, sm_scale=sm_scale, mesh=mesh,
+            batch_axes=batch_axes, head_axis=head_axis, interpret=interpret,
+            **kw)
     tp = mesh.shape[head_axis] if head_axis in mesh.shape else 1
     h = q.shape[1]
     ulysses_ok = h % tp == 0 and (h // tp) % sp == 0

@@ -188,18 +188,26 @@ class MeshTopology:
     def mesh(self):
         """Lazily build the jax Mesh (device placement via mesh_utils for ICI locality)."""
         if self._mesh is None:
+            import jax
             import numpy as np
             from jax.sharding import Mesh
 
             shape = tuple(self.axis_sizes[a] for a in MESH_AXES)
-            try:
+            if self._devices[0].platform == "cpu" or \
+                    len(self._devices) < jax.device_count():
+                # virtual host devices have no interconnect to place for;
+                # a sub-host group (one serving replica's chips) is not a
+                # full physical torus, so it keeps enumeration order
+                dev_array = np.asarray(self._devices).reshape(shape)
+            else:
                 from jax.experimental import mesh_utils
 
+                # raises when the shape does not map onto the physical
+                # topology — a silently mis-placed mesh would put tp
+                # neighbours across the slow links
                 dev_array = mesh_utils.create_device_mesh(
                     shape, devices=self._devices,
                     allow_split_physical_axes=self._allow_split)
-            except Exception:
-                dev_array = np.asarray(self._devices).reshape(shape)
             self._mesh = Mesh(dev_array, MESH_AXES)
         return self._mesh
 

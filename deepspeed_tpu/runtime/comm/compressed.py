@@ -132,7 +132,6 @@ class CompressedBackend:
         self._run = self._build_run()  # jitted ONCE; retraces only per shape
 
     def _build_run(self):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         @jax.jit
@@ -142,7 +141,7 @@ class CompressedBackend:
                     xw[0], wew[0], sew[0], self.axis_name)
                 return m[None], nwe[None], nse[None]
 
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=self.mesh,
                 in_specs=(P(self.axis_name),) * 3,
                 out_specs=(P(self.axis_name),) * 3)(x, we, se)

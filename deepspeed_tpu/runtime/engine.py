@@ -45,6 +45,7 @@ from ..parallel.topology import (DATA_AXES, SP_AXIS, MeshTopology,
                                  topology_from_config)
 from ..telemetry import MetricsRegistry
 from ..utils.logging import log_dist, logger
+from ..utils.platform import host_cpu_device
 from ..utils.timer import (BACKWARD_GLOBAL_TIMER, FORWARD_GLOBAL_TIMER,
                            STEP_GLOBAL_TIMER, TRAIN_BATCH_TIMER,
                            SynchronizedWallClockTimer, ThroughputTimer)
@@ -610,10 +611,10 @@ class DeepSpeedEngine:
         from .zero.param_stream import StreamedParamStore
 
         path = self._pp_blocks_path()
-        # local_devices: under multi-controller, jax.devices()[0] can be
-        # another process's device — device_get of the init would fail there
-        cpu = jax.local_devices(backend="cpu")[0]
-        with jax.default_device(cpu):
+        # this process's own CPU device: under multi-controller,
+        # jax.devices()[0] can be another process's device — device_get of
+        # the init would fail there
+        with jax.default_device(host_cpu_device()):
             params_full = jax.jit(
                 lambda r: _cast_floating(self.model_spec.init_fn(r),
                                          jnp.float32))(self._init_rng)
@@ -1069,7 +1070,6 @@ class DeepSpeedEngine:
         warmup uses the dense path (``_advance_onebit`` retraces at the
         boundary, the same pattern as compression schedule_offsets).
         """
-        from jax.experimental.shard_map import shard_map
         from jax.flatten_util import ravel_pytree
 
         from ..parallel.topology import DP_AXIS
@@ -1120,12 +1120,12 @@ class DeepSpeedEngine:
             return mean_flat, loss, nwe[None], nse[None]
 
         P_ = P
-        sm = shard_map(
+        sm = jax.shard_map(
             local_grads, mesh=mesh,
             in_specs=(P_(), P_(), P_(), P_(None, DP_AXIS), P_(),
                       P_(DP_AXIS), P_(DP_AXIS)),
             out_specs=(P_(), P_(), P_(DP_AXIS), P_(DP_AXIS)),
-            check_rep=False)
+            check_vma=False)
 
         def train_step(state, batch, base_rng):
             mean_flat, mean_loss, nwe, nse = sm(
@@ -1366,9 +1366,9 @@ class DeepSpeedEngine:
         ``[k, gas, micro_global, ...]``.  Semantically identical to ``k``
         ``train_batch`` calls — the update happens every ``gas``
         microbatches, RNG folds per step — but the k steps execute as one
-        ``lax.scan``, so per-step host dispatch latency (dominant on
-        remote/tunneled backends; the problem the reference solves with
-        CUDA-graph capture, ``inference/engine.py:479``) is paid once per k.
+        ``lax.scan``, so per-step host dispatch latency (the problem the
+        reference solves with CUDA-graph capture,
+        ``inference/engine.py:479``) is paid once per k.
 
         Falls back to per-step ``train_batch`` when a host-side feature
         needs to observe every step (offload optimizer, compression
@@ -1543,9 +1543,9 @@ class DeepSpeedEngine:
 
     def _finalize_metrics(self, metrics, steps: int = 1) -> None:
         # Lazy: metrics stay device-side until someone reads them.  A
-        # device_get here would force a host round-trip EVERY step (hundreds
-        # of ms on remote/tunneled backends), serializing the pipeline; the
-        # log/monitor branches below force them only every steps_per_print.
+        # device_get here would force a host round-trip EVERY step,
+        # serializing the pipeline; the log/monitor branches below force
+        # them only every steps_per_print.
         # ``steps``: report-window width for multi-step intervals (a k-step
         # train_batches can jump over the == 0 boundary).
         self._cached_metrics = _LazyMetrics(metrics)

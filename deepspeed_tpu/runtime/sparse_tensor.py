@@ -155,7 +155,6 @@ def _sel_bwd(res, ct):
         # inserts whatever exchange the sharding requires
         grad = scatter(flat_ids, flat_ct)
     else:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def exchange(idl, ctl):
@@ -163,10 +162,10 @@ def _sel_bwd(res, ct):
             gv = jax.lax.all_gather(ctl, axes, tiled=True)
             return scatter(gi, gv)
 
-        grad = shard_map(
+        grad = jax.shard_map(
             exchange, mesh=mesh,
             in_specs=(P(axes), P(axes, None)),
-            out_specs=P(), check_rep=False)(flat_ids, flat_ct)
+            out_specs=P(), check_vma=False)(flat_ids, flat_ct)
     return grad, np.zeros(ids.shape, jax.dtypes.float0)
 
 

@@ -230,11 +230,12 @@ class ServingFlopsProfiler:
             return {"rows": srv.slots, "width": srv.spec_tokens}
         return {"rows": 0, "width": 0}
 
-    def _cost_analysis_flops(self, family: str,
-                             width: Optional[int] = None
-                             ) -> Optional[float]:
-        """``Lowered.cost_analysis()`` of the raw body — lowering only,
-        never a compile; ``None`` when the backend reports nothing."""
+    def lower(self, family: str, width: Optional[int] = None):
+        """``jax.stages.Lowered`` of the raw program body at the live
+        program's fixed shapes — lowering only: it never compiles and never
+        ticks the sentry.  ``None`` when the engine has not built that
+        program.  ``.as_text()`` shows which attention implementation the
+        program took (a Mosaic ``tpu_custom_call`` vs gather + XLA)."""
         import jax
 
         body = self.srv._program_bodies.get(family)
@@ -242,17 +243,27 @@ class ServingFlopsProfiler:
             body = body.get(width)
         if body is None:
             return None
+        args = self._abstract_args(family, width)
+        ctx = getattr(self.srv, "_decode_ctx", self.srv._tp_ctx) \
+            if family == "decode" else self.srv._tp_ctx
+        with ctx():
+            return jax.jit(body).lower(*args)
+
+    def _cost_analysis_flops(self, family: str,
+                             width: Optional[int] = None
+                             ) -> Optional[float]:
+        """``Lowered.cost_analysis()`` of the raw body — lowering only,
+        never a compile; ``None`` when the backend reports nothing."""
         if family == "decode" and getattr(self.srv, "_K", 1) > 1:
             # fused multi-step decode: the lowered body holds the whole
             # while_loop but calls are billed per iteration — the backend
             # cost would be off by up to K.  Use the analytic estimate.
             return None
         try:
-            args = self._abstract_args(family, width)
-            ctx = getattr(self.srv, "_decode_ctx", self.srv._tp_ctx) \
-                if family == "decode" else self.srv._tp_ctx
-            with ctx():
-                ca = jax.jit(body).lower(*args).cost_analysis()
+            lowered = self.lower(family, width)
+            if lowered is None:
+                return None
+            ca = lowered.cost_analysis()
             if isinstance(ca, (list, tuple)):
                 ca = ca[0] if ca else {}
             flops = float((ca or {}).get("flops", 0.0) or 0.0)

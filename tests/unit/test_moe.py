@@ -98,9 +98,7 @@ def test_moe_expert_parallel_sharded(eight_devices):
 
     mesh = MeshTopology(ep=4).mesh
     rules = moe_tp_rules(cfg)
-    # jax.set_mesh is a recent addition; older jax enters the mesh context
-    # through the Mesh object itself (shardings here are explicit anyway)
-    with (jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh):
+    with jax.set_mesh(mesh):
         sharded = jax.tree_util.tree_map(
             lambda a, s: jax.device_put(a, NamedSharding(mesh, s)), params, rules,
             is_leaf=lambda v: isinstance(v, P))

@@ -20,7 +20,6 @@ def test_compressed_allreduce_error_feedback(eight_devices):
     """Per-step the reduction is lossy, but error feedback makes the
     *accumulated* sum track the true accumulated mean (the 1-bit Adam
     convergence argument)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from deepspeed_tpu.parallel.topology import MeshTopology
@@ -37,7 +36,7 @@ def test_compressed_allreduce_error_feedback(eight_devices):
             m, nwe, nse = compressed_allreduce(xw[0], wew[0], sew[0], "dp")
             return m[None], nwe[None], nse[None]
 
-        return shard_map(body, mesh=mesh, in_specs=(P("dp"),) * 3,
+        return jax.shard_map(body, mesh=mesh, in_specs=(P("dp"),) * 3,
                          out_specs=(P("dp"),) * 3)(xs, wes, ses)
 
     with mesh:

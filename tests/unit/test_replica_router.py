@@ -610,21 +610,36 @@ def test_threaded_router_smoke(tiny):
         np.testing.assert_array_equal(outs[r.uid], seq[r.uid])
 
 
-def test_init_router_shares_weights(tiny):
-    spec, cfg, _ = tiny
-    deepspeed_tpu.comm.reset_topology()
-    router = deepspeed_tpu.init_router(
-        spec, config={"dtype": "fp32",
-                      "tensor_parallel": {"tp_size": 1}},
-        replicas=2, slots=2, max_seq_len=64, block_size=8,
-        prefill_chunk=16, debug_checks=True)
-    assert len(router.replicas) == 2
-    p0 = router.replicas[0].engine.params
-    p1 = router.replicas[1].engine.params
+def test_init_router_places_replicas_and_shares_weights(tiny):
+    """Each replica occupies its own tp x sp device group (a replica left
+    on a dp=n mesh would replicate weights, pool and compute on every
+    chip — and on a TPU its Pallas kernels are refused); replicas whose
+    groups coincide share ONE weight pytree."""
     import jax
-    for a, b in zip(jax.tree_util.tree_leaves(p0),
-                    jax.tree_util.tree_leaves(p1)):
+
+    spec, cfg, _ = tiny
+    kw = dict(config={"dtype": "fp32", "tensor_parallel": {"tp_size": 1}},
+              replicas=2, slots=2, max_seq_len=64, block_size=8,
+              prefill_chunk=16, debug_checks=True)
+    deepspeed_tpu.comm.reset_topology()
+    shared = deepspeed_tpu.init_router(spec, device_group=0, **kw)
+    for a, b in zip(
+            jax.tree_util.tree_leaves(shared.replicas[0].engine.params),
+            jax.tree_util.tree_leaves(shared.replicas[1].engine.params)):
         assert a is b                      # one pytree, zero duplication
+    deepspeed_tpu.comm.reset_topology()
+    router = deepspeed_tpu.init_router(spec, **kw)
+    assert len(router.replicas) == 2
+    for i, rep in enumerate(router.replicas):
+        want = {jax.devices()[i]}
+        assert set(rep.engine.mesh.devices.flat) == want
+        for leaf in jax.tree_util.tree_leaves((rep.engine.params,
+                                               rep._cache)):
+            assert leaf.devices() == want
+    for a, b in zip(
+            jax.tree_util.tree_leaves(router.replicas[0].engine.params),
+            jax.tree_util.tree_leaves(router.replicas[1].engine.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     rng = np.random.default_rng(3)
     reqs = [Request(uid=i, prompt=rng.integers(0, cfg.vocab_size, 12),
                     max_new_tokens=5) for i in range(3)]

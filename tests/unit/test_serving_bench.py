@@ -1,7 +1,7 @@
 """serving_bench-derived acceptance checks (slow lane: runs a full trace
 through both serving paths — minutes on a CPU-sim box).
 
-Asserts the PROFILE.md claims reproduce: aggregate-throughput speedup of the
+Asserts the continuous-batching claims reproduce: aggregate-throughput speedup of the
 continuous-batching scheduler over sequential ``generate``, O(#buckets)
 compile count, and token parity.  Timing-based, hence ``slow`` — tier-1
 covers the functional pieces in test_serving.py.

@@ -48,7 +48,6 @@ def test_static_budget_truncates_smallest():
 
 def test_sparse_allreduce_matches_dense_psum(eight_devices):
     """Sparse all-gather+densify == dense psum mean over the dp axis."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from deepspeed_tpu.parallel.topology import MeshTopology
@@ -65,7 +64,7 @@ def test_sparse_allreduce_matches_dense_psum(eight_devices):
             st = SparseTensor.from_dense(xw[0], k=4)
             return sparse_allreduce_dense_result(st, "dp")[None]
 
-        return shard_map(body, mesh=mesh, in_specs=P("dp"),
+        return jax.shard_map(body, mesh=mesh, in_specs=P("dp"),
                          out_specs=P("dp"))(x)
 
     with mesh:
@@ -75,7 +74,6 @@ def test_sparse_allreduce_matches_dense_psum(eight_devices):
 
 
 def test_sparse_allreduce_sum_mode(eight_devices):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from deepspeed_tpu.parallel.topology import MeshTopology
@@ -90,7 +88,7 @@ def test_sparse_allreduce_sum_mode(eight_devices):
             st = SparseTensor.from_dense(xw[0], k=1)
             return sparse_allreduce(st, "dp", average=False).to_dense()[None]
 
-        return shard_map(body, mesh=mesh, in_specs=P("dp"),
+        return jax.shard_map(body, mesh=mesh, in_specs=P("dp"),
                          out_specs=P("dp"))(x)
 
     with mesh:
