@@ -151,9 +151,13 @@ preemption, finish, plus the sentry's trace/retrace and the invariant-
 audit events from ``analysis/``) records a per-request timeline
 (``trace_capacity=``, 0 = off) exportable as Chrome ``trace_event`` JSON
 via ``dump_trace(path)`` — open it in Perfetto to see exactly where a
-slow request spent its time.  ``serve(profile_dir=...)`` additionally
-brackets the first ``profile_iters`` scheduler iterations with a
-``jax.profiler`` trace window for device-level deep dives.  PR 12 adds
+slow request spent its time.  Each iteration is one ``step`` span tiled
+by four host phases (:meth:`ServingEngine.step`), every span doubling as
+a ``ds.serve.*`` profiler annotation (ring on or off), so
+``serve(profile_dir=...)`` — which brackets the first ``profile_iters``
+scheduler iterations with a ``jax.profiler`` trace window — shows the
+scheduler's phases on the device's clock
+(``python -m deepspeed_tpu.telemetry.idle_gaps``).  PR 12 adds
 the per-``slo_class`` attainment accounting behind ``slo_report()``
 (``telemetry/slo.py``; ``slo_targets=``), the FLOPs/MFU profiler behind
 ``flops_report()`` (``telemetry/flops.py``; raw program bodies lowered
@@ -220,6 +224,7 @@ from ..ops.decode_attention import VERIFY_T_MAX
 from ..ops.paged_kv import blocks_for
 from ..parallel.topology import DP_AXIS, SP_AXIS, TP_AXIS
 from ..telemetry import MetricsRegistry, ProfilerWindow, TraceTimeline
+from ..telemetry import trace as trace_mod
 from ..telemetry.slo import SLOTracker
 from ..utils.logging import log_dist
 from ..utils.lru import LRUCache
@@ -776,7 +781,8 @@ class ServingEngine:
                     ``trace_event`` JSON by :meth:`dump_trace`.  ``0``
                     disables event recording entirely (one predicate per
                     would-be event); the metrics registry backing
-                    ``stats()`` is always on.
+                    ``stats()`` and the spans' profiler annotations are
+                    always on.
     slo_targets:    per-``slo_class`` latency targets + attainment
                     objective overrides, merged over
                     ``telemetry/slo.py DEFAULT_SLO_TARGETS`` — every
@@ -1573,6 +1579,10 @@ class ServingEngine:
         #: route -> admit arrow (telemetry/trace.py flow events)
         self._flow_ids: Dict[Any, int] = {}
         self.timeline = TraceTimeline(capacity=trace_capacity)
+        # readable after this engine is gone (telemetry/trace.py kept())
+        trace_mod.keep("serve", self.timeline)
+        #: KV-manager busy seconds of the current step (``step.kv_s``)
+        self._kv_s = 0.0
         if self.timeline.enabled:
             # bounded lane table: one span lane per SLOT (a request's span
             # lands on the slot that finished it) — lane count never grows
@@ -1823,15 +1833,16 @@ class ServingEngine:
         masked argmax — so one traced program serves mixed
         greedy+sampled+constrained batches with zero recompiles and the
         temp=0 rows stay bit-identical to the legacy greedy path."""
-        if samp is None:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        temps, topks, topps, seeds, counts, masks = samp
-        greedy, lp = sampling_ops.filtered_logprobs(
-            logits, temps, topks, topps, masks)
-        keys = sampling_ops.slot_keys(seeds, counts,
-                                      sampling_ops.SALT_TOKEN)
-        return jnp.where(temps > 0,
-                         sampling_ops.sample_tokens(lp, keys), greedy)
+        with jax.named_scope("sample"):
+            if samp is None:
+                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            temps, topks, topps, seeds, counts, masks = samp
+            greedy, lp = sampling_ops.filtered_logprobs(
+                logits, temps, topks, topps, masks)
+            keys = sampling_ops.slot_keys(seeds, counts,
+                                          sampling_ops.SALT_TOKEN)
+            return jnp.where(temps > 0,
+                             sampling_ops.sample_tokens(lp, keys), greedy)
 
     @staticmethod
     def _pack_samp(tail):
@@ -2465,26 +2476,25 @@ class ServingEngine:
             return 0                      # dropped: contents recomputable
         m = self.swap_batch
         stored = 0
-        swap_t0 = self.timeline.now_us()
         for i in range(0, len(blocks), m):
             chunk_b = blocks[i:i + m]
             chunk_k = keys[i:i + m]
             ids = np.zeros(m, np.int32)
             ids[:len(chunk_b)] = chunk_b
-            with self._tp_ctx():
-                staged = self._get_demote_fn()(self._swap_pools(),
-                                               jnp.asarray(ids))
-            host = jax.device_get(staged)          # one D2H per batch
+            ids_dev = jnp.asarray(ids)
+            # each batch's round trip (gather program + D2H) as an X span:
+            # the FLOPs profiler's busy-fraction breakdown reads "swap"
+            with self.timeline.span("swap", direction="out",
+                                    blocks=len(chunk_b)):
+                with self._tp_ctx():
+                    staged = self._get_demote_fn()(self._swap_pools(),
+                                                   ids_dev)
+                host = jax.device_get(staged)      # one D2H per batch
             leaves = jax.tree_util.tree_leaves(host)
             for j, key in enumerate(chunk_k):
                 if self._host.put(key, [lf[:, j] for lf in leaves]) \
                         is not None:
                     stored += 1
-        if blocks:
-            # the demotion round trip as an X span: the FLOPs profiler's
-            # busy-fraction breakdown reads "swap" span durations
-            self.timeline.complete("swap", swap_t0, direction="out",
-                                   blocks=len(blocks))
         if stored:
             self._c_swap_out.inc(stored)
             self._c_swap_bytes.inc(stored * self._host.block_nbytes)
@@ -2801,7 +2811,6 @@ class ServingEngine:
                 chunks = chunks + self._stage_chunks(keys[staged_n:])
         promoted: List[int] = []
         wait_s = 0.0
-        swap_t0 = self.timeline.now_us()
         for ci, (chunk_keys, staged) in enumerate(chunks):
             ids = np.zeros(self.swap_batch, np.int32)
             got: List[int] = []
@@ -2818,10 +2827,15 @@ class ServingEngine:
                 got.append(b)
             if got:
                 ids[:len(got)] = got
-                wait_s += self._promote_wait(staged)
-                with self._tp_ctx():
-                    self._set_swap_pools(self._get_promote_fn()(
-                        self._swap_pools(), staged, jnp.asarray(ids)))
+                ids_dev = jnp.asarray(ids)
+                # waiting on the staged H2D copy, then the scatter's
+                # dispatch (asynchronous: nothing reads it back here)
+                with self.timeline.span("swap", direction="in",
+                                        blocks=len(got)):
+                    wait_s += self._promote_wait(staged)
+                    with self._tp_ctx():
+                        self._set_swap_pools(self._get_promote_fn()(
+                            self._swap_pools(), staged, ids_dev))
                 for key in chunk_keys[:len(got)]:
                     self._host.pop(key)     # residency moved to device
                 promoted.extend(got)
@@ -2835,8 +2849,6 @@ class ServingEngine:
                     self._unflag_keys(later_keys)
                 break
         if promoted:
-            self.timeline.complete("swap", swap_t0, direction="in",
-                                   blocks=len(promoted))
             # a sharing pending request may have the just-popped keys
             # staged too: drop those records NOW — their staging is stale
             # (the sharer's own admission would probe the chain on device
@@ -2865,6 +2877,19 @@ class ServingEngine:
         return promoted
 
     # ----------------------------------------------------------- block plumbing
+    def _kv(self, fn, *args, **kwargs):
+        """Call into the KV manager (allocator, prefix trie) on the
+        scheduler's behalf, its seconds added to this step's ``kv_s``.
+        Only the scheduler's outermost entry points go through here
+        (``_ensure_blocks`` with the evictions and preemptions under it,
+        the trie's probe/lookup/register, a finished slot's release), so
+        no second is counted twice."""
+        t0 = time.perf_counter()
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            self._kv_s += time.perf_counter() - t0
+
     def _decref(self, b: int) -> None:
         """Release one reference; when the block actually frees, retire
         its scale-ledger entry in the same step (kv8 — the device scale
@@ -3046,7 +3071,7 @@ class ServingEngine:
                     total_need,
                     self._landmark_blocks + self.resident_window_blocks
                     + blocks_for(self.prefill_chunk, self.block_size))
-            n_hit = self._prefix.probe(prompt_eff, plen - 1) \
+            n_hit = self._kv(self._prefix.probe, prompt_eff, plen - 1) \
                 if self._prefix is not None else 0
 
             def _avail():
@@ -3054,7 +3079,7 @@ class ServingEngine:
                     return self._alloc.group_free(grp) - \
                         reserved_g.get(grp, 0)
                 return self._alloc.free_blocks - reserved + \
-                    (self._prefix.evictable(self._alloc)
+                    (self._kv(self._prefix.evictable, self._alloc)
                      if self._prefix is not None else 0)
 
             if total_need - n_hit > _avail():
@@ -3063,7 +3088,8 @@ class ServingEngine:
             hits: List[int] = []
             if self._prefix is not None:
                 # cap below the full prompt: >= 1 tail token must prefill
-                hits = self._prefix.lookup(prompt_eff, plen - 1, self._alloc)
+                hits = self._kv(self._prefix.lookup, prompt_eff, plen - 1,
+                                self._alloc)
             # re-check post-claim: hit blocks that were evictable no longer
             # count toward avail, so the probe gate can be optimistic by
             # up to n_hit blocks
@@ -3296,7 +3322,7 @@ class ServingEngine:
                 continue
             st = self._active.pop(slot)
             nblocks = len(self._held[slot])
-            self._release_slot(slot)
+            self._kv(self._release_slot, slot)
             self._live_uids.discard(uid)
             self._trace_times.pop(uid, None)
             self._c_cancelled.inc()
@@ -3310,68 +3336,97 @@ class ServingEngine:
         cancellations, admit, advance prefills, run the decode (or
         draft–verify) round, stage prefetches, audit.  Returns whether
         work remains — drive it in a loop (``serve``), from a replica
-        worker thread (``deepspeed_tpu/serving/``), or by hand."""
+        worker thread (``deepspeed_tpu/serving/``), or by hand.
+
+        On the timeline (and, while a profile is being taken, on the
+        profiler's clock as ``ds.serve.*``) an iteration is one ``step``
+        span tiled by four host phases — ``step.admit``, ``step.prefill``,
+        ``step.decode``, ``step.post`` — inside which the in-flight spans
+        (``prefill``, ``decode``, ``spec_*``, ``swap``) mark when a device
+        program is running: a phase's time outside them is host time the
+        device sits idle for."""
         if self._fault_injector is not None:
             # chaos harness (serving/faults.py): may raise SimulatedCrash
             # (the router/worker converts it into fail-and-re-home),
             # stall this replica, or flip bits in the host arena — all on
             # the armed plan's deterministic schedule
             self._fault_injector.on_step(self)
-        self._process_cancellations()
-        if not self._pending and not self._active:
-            if self._host is not None:
-                self._discard_all_staged()  # no queue left to consume them
-            self._g_queue_depth.set(0)
-            return False
-        params = self.engine.params
-        self._c_iterations.inc()
-        admitted0, preempted0 = self.admitted, self.preempted
-        self._admit()
-        self._refresh_masks()
-        self._run_prefill(params)
-        if self.role == "prefill":
-            # disaggregated mode: prefill-complete slots leave the decode
-            # rotation NOW — the decode dispatch below only ever advances
-            # prefilling/empty slots on this replica
-            self._extract_handoffs()
+        if not (self._cancel_flags or self._pending or self._active):
+            return self._step_idle()
+        with self.timeline.span("step") as step_args:
+            return self._step_phases(step_args)
+
+    def _step_idle(self) -> bool:
+        if self._host is not None:
+            self._discard_all_staged()  # no queue left to consume them
+        self._g_queue_depth.set(0)
+        return False
+
+    def _step_phases(self, step_args: Dict[str, Any]) -> bool:
+        """The body of :meth:`step` under its ``step`` span; fills the
+        span's arguments (``step_args``) at the end of the iteration."""
+        span = self.timeline.span
+        self._kv_s = 0.0
+        with span("step.admit"):
+            self._process_cancellations()
+            if not self._pending and not self._active:
+                return self._step_idle()   # the cancellations emptied it
+            params = self.engine.params
+            self._c_iterations.inc()
+            self.timeline.step = self.iterations
+            admitted0, preempted0 = self.admitted, self.preempted
+            self._admit()
+            self._refresh_masks()
+        with span("step.prefill") as phase:
+            phase["groups"] = self._run_prefill(params)
+            if self.role == "prefill":
+                # disaggregated mode: prefill-complete slots leave the
+                # decode rotation NOW — the decode dispatch below only ever
+                # advances prefilling/empty slots on this replica
+                self._extract_handoffs()
         # one decode step over every slot (per-sequence positions);
         # prefilling/empty slots point at the scratch block.  In
         # speculative mode the single-token step is replaced by a
         # draft–verify round committing up to K+1 tokens per slot.
-        # a slot that finished prefill above decodes in this SAME
-        # iteration — its first generated token must gate the second, so
-        # constrained rows rebuild between the two dispatches
-        self._refresh_masks()
-        if self.spec_tokens:
-            self._run_spec_decode(params)
-        elif self._K > 1:
-            self._run_fused_decode(params)
-        else:
-            self._run_plain_decode(params)
-        # resident-window maintenance AFTER both phases committed this
-        # iteration's tokens: mid-prefill giant prompts slide too (the
-        # next chunk's program then masks the demoted middle)
-        self._slide_windows()
-        if self._host is not None:
-            # stage next iteration's promotions NOW: the H2D copies
-            # run while the next decode step computes (module
-            # docstring "Tiered KV cache" — the param_stream overlap)
-            self._issue_prefetch(self._pending)
-        self._g_queue_depth.set(len(self._pending))
-        if self._step_log is not None:
-            self._step_log.append({
-                "iteration": self.iterations,
-                "admitted": self.admitted - admitted0,
-                "evicted": self.preempted - preempted0,
-                "blocks_in_use": self._alloc.blocks_in_use,
-            })
-        if self.debug_checks:
-            # O(blocks) host-state audit between scheduler rounds —
-            # the scheduler's state is only guaranteed consistent at
-            # iteration boundaries (analysis/invariants.py; the audit
-            # drops its own event on the timeline)
-            audit_serving_engine(self, self._active)
-            self._c_invariant_checks.inc()
+        with span("step.decode") as phase:
+            # a slot that finished prefill above decodes in this SAME
+            # iteration — its first generated token must gate the second,
+            # so constrained rows rebuild between the two dispatches
+            self._refresh_masks()
+            if self.spec_tokens:
+                phase["slots"] = self._run_spec_decode(params)
+            elif self._K > 1:
+                phase["slots"] = self._run_fused_decode(params)
+            else:
+                phase["slots"] = self._run_plain_decode(params)
+        with span("step.post"):
+            # resident-window maintenance AFTER both phases committed this
+            # iteration's tokens: mid-prefill giant prompts slide too (the
+            # next chunk's program then masks the demoted middle)
+            self._slide_windows()
+            if self._host is not None:
+                # stage next iteration's promotions NOW: the H2D copies
+                # run while the next decode step computes (module
+                # docstring "Tiered KV cache" — the param_stream overlap)
+                self._issue_prefetch(self._pending)
+            self._g_queue_depth.set(len(self._pending))
+            step_args.update(
+                iteration=self.iterations,
+                admitted=self.admitted - admitted0,
+                evicted=self.preempted - preempted0,
+                blocks_in_use=self._alloc.blocks_in_use,
+                active=len(self._active), pending=len(self._pending),
+                kv_s=self._kv_s)
+            if self._step_log is not None:
+                self._step_log.append({k: step_args[k] for k in (
+                    "iteration", "admitted", "evicted", "blocks_in_use")})
+            if self.debug_checks:
+                # O(blocks) host-state audit between scheduler rounds —
+                # the scheduler's state is only guaranteed consistent at
+                # iteration boundaries (analysis/invariants.py; the audit
+                # drops its own event on the timeline)
+                audit_serving_engine(self, self._active)
+                self._c_invariant_checks.inc()
         return bool(self._pending or self._active)
 
     def drain(self) -> List[_PendingItem]:
@@ -3747,34 +3802,37 @@ class ServingEngine:
                 f"req {req.uid}", tm["admit_us"], tid=slot + 1,
                 uid=str(req.uid), new_tokens=int(gen.size),
                 eos=bool(eos_hit), ttft_s=ttft)
-        self._release_slot(slot)
+        self._kv(self._release_slot, slot)
         self._live_uids.discard(req.uid)
         if st.handle is not None:
             st.handle._on_finish(result)
 
-    def _run_plain_decode(self, params):
-        """One single-token decode step over every decode-phase slot."""
+    def _run_plain_decode(self, params) -> int:
+        """One single-token decode step over every decode-phase slot;
+        returns how many slots it advanced (the decode runners all do)."""
         active = self._active
         dec = sorted(
             (s for s, st in active.items() if st.phase == "decode"),
             key=lambda s: active[s].admit_seq)
         for slot in dec:
             if slot in active:
-                self._ensure_blocks(slot, int(self._lengths[slot]) + 1)
+                self._kv(self._ensure_blocks, slot,
+                         int(self._lengths[slot]) + 1)
         dec = sorted(s for s, st in active.items()
                      if st.phase == "decode")
         if not dec:
-            return
+            return 0
         bt = np.zeros_like(self._tables)
         bt[dec] = self._tables[dec]
+        args = (params, self._cache, jnp.asarray(self._tokens),
+                jnp.asarray(self._lengths), jnp.asarray(bt))
+        if self.resident_window_blocks:
+            args += (jnp.asarray(self._window_start),)
+        args += self._samp_args(self._decode_counts())
+        decode_fn = self._get_decode_fn()
         with self.timeline.span("decode", slots=len(dec)):
-            args = (params, self._cache, jnp.asarray(self._tokens),
-                    jnp.asarray(self._lengths), jnp.asarray(bt))
-            if self.resident_window_blocks:
-                args += (jnp.asarray(self._window_start),)
-            args += self._samp_args(self._decode_counts())
             with self._decode_ctx():
-                nxt, self._cache = self._get_decode_fn()(*args)
+                nxt, self._cache = decode_fn(*args)
             nxt = np.asarray(nxt)
         self._c_decode_steps.inc()
         for slot in dec:
@@ -3789,6 +3847,7 @@ class ServingEngine:
                 self._finish_slot(slot)
             else:
                 self._tokens[slot] = tok
+        return len(dec)
 
     def _fence_harvest(self, *arrays):
         """The fused decode path's ONE host<->device synchronization point
@@ -3831,11 +3890,12 @@ class ServingEngine:
                     # it between dispatches
                     w = 1
                 want[slot] = w
-                self._ensure_blocks(slot, min(ln + w, self._cache_len))
+                self._kv(self._ensure_blocks, slot,
+                         min(ln + w, self._cache_len))
         dec = sorted(s for s, st in active.items()
                      if st.phase == "decode")
         if not dec:
-            return
+            return 0
         budgets = np.zeros(self.slots, np.int32)
         eos_ids = np.full(self.slots, -1, np.int32)
         actv = np.zeros(self.slots, bool)
@@ -3855,17 +3915,18 @@ class ServingEngine:
                 eos_ids[slot] = int(st.eos)
         dec = [s for s in dec if actv[s]]
         if not dec:
-            return
+            return 0
         bt = np.zeros_like(self._tables)
         bt[dec] = self._tables[dec]
+        args = (params, self._cache, jnp.asarray(self._tokens),
+                jnp.asarray(self._lengths), jnp.asarray(bt),
+                jnp.asarray(actv), jnp.asarray(budgets),
+                jnp.asarray(eos_ids),
+                *self._samp_args(self._decode_counts()))
+        decode_fn = self._get_decode_fn()
         with self.timeline.span("decode", slots=len(dec), fused=K):
             with self._decode_ctx():
-                out, self._cache = self._get_decode_fn()(
-                    params, self._cache, jnp.asarray(self._tokens),
-                    jnp.asarray(self._lengths), jnp.asarray(bt),
-                    jnp.asarray(actv), jnp.asarray(budgets),
-                    jnp.asarray(eos_ids),
-                    *self._samp_args(self._decode_counts()))
+                out, self._cache = decode_fn(*args)
             out, = self._fence_harvest(out)
         # ----- the fence catch-up: replay each slot's committed window
         # tokens through the exact K=1 commit sequence (emission order,
@@ -3894,6 +3955,7 @@ class ServingEngine:
         # FLOPs billing identical to single-step mode
         self._c_decode_steps.inc(trips)
         self._c_fused_iterations.inc(trips)
+        return len(dec)
 
     def _run_spec_decode(self, params):
         """One speculative draft–verify round over every decode-phase slot.
@@ -3921,27 +3983,30 @@ class ServingEngine:
                 st = active[slot]
                 ln = int(self._lengths[slot])
                 cap = max(st.pos_cap, ln + 1)
-                self._ensure_blocks(slot,
-                                    min(ln + k + 1, cap, self._cache_len))
+                self._kv(self._ensure_blocks, slot,
+                         min(ln + k + 1, cap, self._cache_len))
         dec = sorted(s for s, st in active.items()
                      if st.phase == "decode")
         if not dec:
-            return
+            return 0
         bt = np.zeros_like(self._tables)
         bt[dec] = self._tables[dec]
         samp = self._samp_args(self._decode_counts())
-        with self.timeline.span(
-                "spec_propose", slots=len(dec),
-                mode="draft" if self._draft is not None else "ngram"):
-            if self._draft is not None:
+        bt_dev, len_dev = jnp.asarray(bt), jnp.asarray(self._lengths)
+        if self._draft is not None:
+            args = (self._draft.params, self._dcache,
+                    jnp.asarray(self._tokens), len_dev, bt_dev, *samp)
+            draft_fn = self._get_draft_fn()
+            with self.timeline.span("spec_propose", slots=len(dec),
+                                    mode="draft"):
                 with self._tp_ctx():
-                    drafts, self._dcache = self._get_draft_fn()(
-                        self._draft.params, self._dcache,
-                        jnp.asarray(self._tokens),
-                        jnp.asarray(self._lengths), jnp.asarray(bt),
-                        *samp)
+                    drafts, self._dcache = draft_fn(*args)
                 drafts = np.asarray(drafts)
-            else:
+        else:
+            # the n-gram proposer is host work under the documented span
+            # name: no program is in flight, the device idles through it
+            with self.timeline.span("spec_propose", slots=len(dec),
+                                    mode="ngram"):
                 drafts = np.zeros((self.slots, k), np.int32)
                 for slot in dec:
                     st = active[slot]
@@ -3953,11 +4018,12 @@ class ServingEngine:
         ids[dec, 0] = self._tokens[dec]
         ids[dec, 1:] = drafts[dec]
         valid[dec] = k + 1
+        args = (params, self._cache, jnp.asarray(ids), bt_dev, len_dev,
+                jnp.asarray(valid), *samp)
+        verify_fn = self._get_verify_fn()
         with self.timeline.span("spec_verify", slots=len(dec), window=k + 1):
             with self._tp_ctx():
-                out = self._get_verify_fn()(
-                    params, self._cache, jnp.asarray(ids), jnp.asarray(bt),
-                    jnp.asarray(self._lengths), jnp.asarray(valid), *samp)
+                out = verify_fn(*args)
             if self.sampling:
                 scored, accept, plain, resid, self._cache = out
                 accept = np.asarray(accept)
@@ -4025,19 +4091,21 @@ class ServingEngine:
                 self._tokens[slot] = emitted[-1]
         self.timeline.instant("spec_accept", accept_lens=accept_lens,
                               drafted=k * len(dec))
+        return len(dec)
 
     # ---------------------------------------------------------------- prefill
-    def _run_prefill(self, params):
+    def _run_prefill(self, params) -> int:
         """Advance prefilling slots: one fixed-width chunk per slot per
         iteration (chunked mode), or the whole prompt in its bucket's
         program (bucketed fallback).  Both modes run ``prefill_batch`` rows
-        per call; pad rows write to scratch."""
+        per call; pad rows write to scratch.  Returns the number of
+        prefill calls made."""
         active = self._active
         pre = [s for s, st in sorted(active.items(),
                                      key=lambda kv: kv[1].admit_seq)
                if st.phase == "prefill"]
         if not pre:
-            return
+            return 0
         if self.chunked_prefill:
             groups = []
             ready = []
@@ -4046,7 +4114,7 @@ class ServingEngine:
                     continue               # preempted by an earlier alloc
                 st = active[slot]
                 v = min(self.prefill_chunk, st.plen_eff - st.base)
-                if self._ensure_blocks(slot, st.base + v):
+                if self._kv(self._ensure_blocks, slot, st.base + v):
                     ready.append(slot)
             for i in range(0, len(ready), self.prefill_batch):
                 group = [s for s in ready[i:i + self.prefill_batch]
@@ -4059,7 +4127,7 @@ class ServingEngine:
                 if slot not in active:
                     continue
                 st = active[slot]
-                if self._ensure_blocks(slot, st.plen_eff):
+                if self._kv(self._ensure_blocks, slot, st.plen_eff):
                     by_bucket.setdefault(self._prefill_width(st.plen_eff),
                                          []).append(slot)
             groups = []
@@ -4071,11 +4139,14 @@ class ServingEngine:
                     if group:
                         groups.append((bucket, group))
 
+        calls = 0
         for width, group in groups:
             group = [s for s in group if s in active]
             if not group:
                 continue
             self._run_prefill_group(width, group, params)
+            calls += 1
+        return calls
 
     def _run_prefill_group(self, width, group, params):
         """One prefill call: each row advances its slot by ``min(width,
@@ -4098,29 +4169,30 @@ class ServingEngine:
             valid[row] = v
             rows.append((slot, v))
         samp = self._samp_args_rows(group, j)
+        packed = (jnp.asarray(ids), jnp.asarray(bt), jnp.asarray(base),
+                  jnp.asarray(valid))
+        if self._draft is not None:
+            args = (params, self._draft.params, self._cache, self._dcache,
+                    *packed, *samp)
+        else:
+            args = (params, self._cache, *packed)
+            if self.resident_window_blocks:
+                # per-ROW window starts (prefill batches rows from
+                # arbitrary slots); pad rows stay 0 = fully visible
+                ws = np.zeros(j, np.int32)
+                for row, slot in enumerate(group):
+                    ws[row] = self._window_start[slot]
+                args += (jnp.asarray(ws),)
+            args += samp
+        prefill_fn = self._get_prefill_fn(width)
         with self.timeline.span("prefill", width=width, rows=len(group),
                                 slots=list(map(int, group))):
             if self._draft is not None:
                 with self._tp_ctx():
-                    first, self._cache, self._dcache = \
-                        self._get_prefill_fn(width)(
-                            params, self._draft.params, self._cache,
-                            self._dcache, jnp.asarray(ids), jnp.asarray(bt),
-                            jnp.asarray(base), jnp.asarray(valid), *samp)
+                    first, self._cache, self._dcache = prefill_fn(*args)
             else:
-                args = (params, self._cache, jnp.asarray(ids),
-                        jnp.asarray(bt), jnp.asarray(base),
-                        jnp.asarray(valid))
-                if self.resident_window_blocks:
-                    # per-ROW window starts (prefill batches rows from
-                    # arbitrary slots); pad rows stay 0 = fully visible
-                    ws = np.zeros(j, np.int32)
-                    for row, slot in enumerate(group):
-                        ws[row] = self._window_start[slot]
-                    args += (jnp.asarray(ws),)
-                args += samp
                 with self._tp_ctx(), self._sp_ctx():
-                    first, self._cache = self._get_prefill_fn(width)(*args)
+                    first, self._cache = prefill_fn(*args)
             first = np.asarray(first)
         if self.sp_degree > 1:
             nbytes = sp_attention.alltoall_bytes(
@@ -4158,9 +4230,8 @@ class ServingEngine:
                         run += 1
                     nfull = run
                 if nfull:
-                    self._prefix.register(st.prompt_eff,
-                                          self._tables[slot, :nfull],
-                                          self._alloc)
+                    self._kv(self._prefix.register, st.prompt_eff,
+                             self._tables[slot, :nfull], self._alloc)
             if self.spec_tokens and self.sampling and st.prior \
                     and st.req.sampled:
                 # spec-sampled RESUME: the original stream's token at

@@ -301,53 +301,55 @@ def _block(cfg: GPT2Config, x, layer, mask, rng, dropout: float):
                                    getattr(cfg, "act_quant_type",
                                            "symmetric"))
 
-    y = _aq(_layer_norm(x, layer["ln1_scale"], layer["ln1_bias"]))
-    qkv = _qmm(y, layer["qkv_w"]) + layer["qkv_b"].astype(y.dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-    k = k.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-    v = v.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-    from ..parallel import sequence as seq_parallel
+    with jax.named_scope("layer/attn"):
+        y = _aq(_layer_norm(x, layer["ln1_scale"], layer["ln1_bias"]))
+        qkv = _qmm(y, layer["qkv_w"]) + layer["qkv_b"].astype(y.dtype)
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q = q.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
+        k = k.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
+        v = v.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
+        from ..parallel import sequence as seq_parallel
 
-    use_flash = cfg.use_flash
-    if use_flash is None:
-        use_flash = on_tpu()
-    if seq_parallel.sp_size() > 1 and dropout > 0.0:
-        global _warned_sp_dropout
-        if not _warned_sp_dropout:
-            _warned_sp_dropout = True
-            from ..utils.logging import logger
+        use_flash = cfg.use_flash
+        if use_flash is None:
+            use_flash = on_tpu()
+        if seq_parallel.sp_size() > 1 and dropout > 0.0:
+            global _warned_sp_dropout
+            if not _warned_sp_dropout:
+                _warned_sp_dropout = True
+                from ..utils.logging import logger
 
-            logger.warning(
-                "mesh sp>1 with attention dropout>0: sequence-parallel "
-                "attention requires dropout=0; falling back to the "
-                "dense path (quadratic in S)")
-    if seq_parallel.sp_size() > 1 and dropout == 0.0 and mask is None:
-        attn = seq_parallel.sequence_parallel_attention(
-            q, k, v, causal=True, impl=getattr(cfg, "sp_impl", "auto"))
-    elif use_flash and dropout == 0.0 and mask is None:
-        attn = seq_parallel.mesh_flash_attention(
-            q, k, v, causal=True,
-            block_q=getattr(cfg, "flash_block_q", 512),
-            block_k=getattr(cfg, "flash_block_k", 1024))
-    else:
-        if mask is None:
-            mask = jnp.tril(jnp.ones((s, s), bool))[None, None, :, :]
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(hd)
-        scores = jnp.where(mask, scores.astype(jnp.float32), -1e9)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        if dropout > 0.0 and rng is not None:
-            keep = jax.random.bernoulli(rng, 1.0 - dropout, probs.shape)
-            probs = probs * keep / (1.0 - dropout)
-        attn = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
-    attn = _aq(attn.transpose(0, 2, 1, 3).reshape(b, s, d))
-    x = x + _qmm(attn, layer["o_w"], x.dtype) + layer["o_b"].astype(x.dtype)
-
-    y = _aq(_layer_norm(x, layer["ln2_scale"], layer["ln2_bias"]))
-    hid = _aq(jax.nn.gelu(_qmm(y, layer["fc_w"]) +
-                          layer["fc_b"].astype(y.dtype)))
-    x = x + _qmm(hid, layer["proj_w"], x.dtype) + \
-        layer["proj_b"].astype(x.dtype)
+                logger.warning(
+                    "mesh sp>1 with attention dropout>0: sequence-parallel "
+                    "attention requires dropout=0; falling back to the "
+                    "dense path (quadratic in S)")
+        if seq_parallel.sp_size() > 1 and dropout == 0.0 and mask is None:
+            attn = seq_parallel.sequence_parallel_attention(
+                q, k, v, causal=True, impl=getattr(cfg, "sp_impl", "auto"))
+        elif use_flash and dropout == 0.0 and mask is None:
+            attn = seq_parallel.mesh_flash_attention(
+                q, k, v, causal=True,
+                block_q=getattr(cfg, "flash_block_q", 512),
+                block_k=getattr(cfg, "flash_block_k", 1024))
+        else:
+            if mask is None:
+                mask = jnp.tril(jnp.ones((s, s), bool))[None, None, :, :]
+            scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(hd)
+            scores = jnp.where(mask, scores.astype(jnp.float32), -1e9)
+            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+            if dropout > 0.0 and rng is not None:
+                keep = jax.random.bernoulli(rng, 1.0 - dropout, probs.shape)
+                probs = probs * keep / (1.0 - dropout)
+            attn = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+        attn = _aq(attn.transpose(0, 2, 1, 3).reshape(b, s, d))
+        x = x + _qmm(attn, layer["o_w"], x.dtype) + \
+            layer["o_b"].astype(x.dtype)
+    with jax.named_scope("layer/mlp"):
+        y = _aq(_layer_norm(x, layer["ln2_scale"], layer["ln2_bias"]))
+        hid = _aq(jax.nn.gelu(_qmm(y, layer["fc_w"]) +
+                              layer["fc_b"].astype(y.dtype)))
+        x = x + _qmm(hid, layer["proj_w"], x.dtype) + \
+            layer["proj_b"].astype(x.dtype)
     return x
 
 
@@ -356,8 +358,9 @@ def forward(cfg: GPT2Config, params: PyTree, input_ids, rng=None,
     """Token logits. input_ids: [B, S] int32."""
     params = _dequant_resident(params)
     x = _trunk(cfg, params, input_ids, rng=rng, train=train)
-    x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
-    logits = x @ params["wte"].T.astype(x.dtype)
+    with jax.named_scope("head"):
+        x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
+        logits = x @ params["wte"].T.astype(x.dtype)
     return logits
 
 
@@ -422,20 +425,21 @@ def _block_cached_body(cfg: GPT2Config, x, get, mm, ck, cv, pos,
     b, t, d = x.shape
     h, hd = cfg.num_heads, cfg.head_dim
 
-    y = _layer_norm(x, get("ln1_scale"), get("ln1_bias"))
-    qkv = mm(y, "qkv_w", None) + get("qkv_b").astype(y.dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
-    k = k.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
-    v = v.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
-    attn, ck, cv = _cached_attention(q, k, v, ck, cv, pos, block_tables,
-                                     chunk_valid)
-    attn = attn.transpose(0, 2, 1, 3).reshape(b, t, d)
-    x = x + mm(attn, "o_w", x.dtype) + get("o_b").astype(x.dtype)
-
-    y = _layer_norm(x, get("ln2_scale"), get("ln2_bias"))
-    hid = jax.nn.gelu(mm(y, "fc_w", None) + get("fc_b").astype(y.dtype))
-    x = x + mm(hid, "proj_w", x.dtype) + get("proj_b").astype(x.dtype)
+    with jax.named_scope("layer/attn"):
+        y = _layer_norm(x, get("ln1_scale"), get("ln1_bias"))
+        qkv = mm(y, "qkv_w", None) + get("qkv_b").astype(y.dtype)
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q = q.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
+        k = k.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
+        v = v.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
+        attn, ck, cv = _cached_attention(q, k, v, ck, cv, pos, block_tables,
+                                         chunk_valid)
+        attn = attn.transpose(0, 2, 1, 3).reshape(b, t, d)
+        x = x + mm(attn, "o_w", x.dtype) + get("o_b").astype(x.dtype)
+    with jax.named_scope("layer/mlp"):
+        y = _layer_norm(x, get("ln2_scale"), get("ln2_bias"))
+        hid = jax.nn.gelu(mm(y, "fc_w", None) + get("fc_b").astype(y.dtype))
+        x = x + mm(hid, "proj_w", x.dtype) + get("proj_b").astype(x.dtype)
     return x, ck, cv
 
 
@@ -533,20 +537,23 @@ def forward_cached(cfg: GPT2Config, params, input_ids, cache, pos,
     d = cfg.hidden_size
     pos = jnp.asarray(pos, jnp.int32)
     per_row = lengths is not None and t == 1
-    if per_row:
-        lengths = jnp.asarray(lengths, jnp.int32)
-        step_pos = lengths
-        wpe = params["wpe"][jnp.clip(lengths, 0, cfg.max_seq_len - 1)][:, None]
-    elif block_tables is not None and pos.ndim == 1:
-        # chunked prefill: per-row base positions for a T-token window
-        step_pos = pos
-        idx = jnp.clip(pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :],
-                       0, cfg.max_seq_len - 1)
-        wpe = params["wpe"][idx]                                  # [B, T, D]
-    else:
-        step_pos = pos
-        wpe = jax.lax.dynamic_slice(params["wpe"], (pos, 0), (t, d))
-    x = (params["wte"][input_ids] + wpe).astype(params["wte"].dtype)
+    with jax.named_scope("embed"):
+        if per_row:
+            lengths = jnp.asarray(lengths, jnp.int32)
+            step_pos = lengths
+            wpe = params["wpe"][jnp.clip(lengths, 0,
+                                         cfg.max_seq_len - 1)][:, None]
+        elif block_tables is not None and pos.ndim == 1:
+            # chunked prefill: per-row base positions for a T-token window
+            step_pos = pos
+            idx = jnp.clip(
+                pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :],
+                0, cfg.max_seq_len - 1)
+            wpe = params["wpe"][idx]                              # [B, T, D]
+        else:
+            step_pos = pos
+            wpe = jax.lax.dynamic_slice(params["wpe"], (pos, 0), (t, d))
+        x = (params["wte"][input_ids] + wpe).astype(params["wte"].dtype)
     from ..ops.sp_attention import shard_seq
 
     # sequence-parallel prefill hook: token-shard hidden states over the
@@ -563,8 +570,9 @@ def forward_cached(cfg: GPT2Config, params, input_ids, cache, pos,
         x, params["blocks"], cache["k"], cache["v"], cfg.num_layers)
     if not all_positions:
         x = _gather_last(x, lengths if not per_row else None)
-    x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
-    logits = x @ params["wte"].T.astype(x.dtype)
+    with jax.named_scope("head"):
+        x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
+        logits = x @ params["wte"].T.astype(x.dtype)
     return logits, {"k": ks, "v": vs}
 
 
@@ -591,9 +599,7 @@ def _wte_lookup(cfg: GPT2Config, params, input_ids):
 def _trunk(cfg: GPT2Config, params, input_ids, rng=None, train: bool = True):
     """Embeddings + all blocks; returns pre-final-LN activations [B, S, D]."""
     b, s = input_ids.shape
-    compute_dtype = params["wte"].dtype
-    x = _wte_lookup(cfg, params, input_ids) + params["wpe"][:s]
-    x = x.astype(compute_dtype)
+    x = _embed(cfg, params, input_ids)
     dropout = cfg.dropout if train else 0.0
 
     block_fn = _block
@@ -690,12 +696,14 @@ def tp_rules(cfg: GPT2Config, abstract_params: PyTree) -> PyTree:
     return specs
 
 
+@jax.named_scope("embed")
 def _embed(cfg: GPT2Config, params, input_ids):
     s = input_ids.shape[1]
     x = _wte_lookup(cfg, params, input_ids) + params["wpe"][:s]
     return x.astype(params["wte"].dtype)
 
 
+@jax.named_scope("head")
 def _head_loss(cfg: GPT2Config, params, x, targets):
     """Final LN + tied head + CE, as ``lse - label_logit`` so no [T, V]
     log-softmax tensor is ever materialized (XLA fuses the f32 upcast into
@@ -795,6 +803,7 @@ def _fused_ce_bwd(n_chunks, res, ct):
 _fused_ce.defvjp(_fused_ce_fwd, _fused_ce_bwd)
 
 
+@jax.named_scope("head")
 def _head_loss_fused(cfg: GPT2Config, params, x, targets):
     """LN + tied-head CE via the chunked fused-backward formulation."""
     x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])

@@ -207,33 +207,35 @@ def _block(cfg: OPTConfig, x, layer):
     # INT8 weight-only serving: quantized records run the fused Pallas
     # dequant-matmul (ops/quantized_matmul) — no bf16 weight copy in HBM
 
-    res = x
-    y = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"]) \
-        if cfg.do_layer_norm_before else x
-    qkv = _qmm(y, layer["qkv_w"]) + layer["qkv_b"].astype(y.dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-    k = k.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-    v = v.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-    attn = _attention(cfg, q, k, v)
-    attn = attn.transpose(0, 2, 1, 3).reshape(b, s, d)
-    x = res + _qmm(attn, layer["o_w"], x.dtype) + \
-        layer["o_b"].astype(x.dtype)
-    if not cfg.do_layer_norm_before:
-        x = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"])
-
-    res = x
-    y = _layer_norm(x, layer["ln2_scale"], layer["ln2_bias"]) \
-        if cfg.do_layer_norm_before else x
-    hid = jax.nn.relu(_qmm(y, layer["fc_w"]) +
-                      layer["fc_b"].astype(y.dtype))
-    x = res + _qmm(hid, layer["proj_w"], x.dtype) + \
-        layer["proj_b"].astype(x.dtype)
-    if not cfg.do_layer_norm_before:
-        x = _layer_norm(x, layer["ln2_scale"], layer["ln2_bias"])
+    with jax.named_scope("layer/attn"):
+        res = x
+        y = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"]) \
+            if cfg.do_layer_norm_before else x
+        qkv = _qmm(y, layer["qkv_w"]) + layer["qkv_b"].astype(y.dtype)
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q = q.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
+        k = k.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
+        v = v.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
+        attn = _attention(cfg, q, k, v)
+        attn = attn.transpose(0, 2, 1, 3).reshape(b, s, d)
+        x = res + _qmm(attn, layer["o_w"], x.dtype) + \
+            layer["o_b"].astype(x.dtype)
+        if not cfg.do_layer_norm_before:
+            x = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"])
+    with jax.named_scope("layer/mlp"):
+        res = x
+        y = _layer_norm(x, layer["ln2_scale"], layer["ln2_bias"]) \
+            if cfg.do_layer_norm_before else x
+        hid = jax.nn.relu(_qmm(y, layer["fc_w"]) +
+                          layer["fc_b"].astype(y.dtype))
+        x = res + _qmm(hid, layer["proj_w"], x.dtype) + \
+            layer["proj_b"].astype(x.dtype)
+        if not cfg.do_layer_norm_before:
+            x = _layer_norm(x, layer["ln2_scale"], layer["ln2_bias"])
     return x
 
 
+@jax.named_scope("embed")
 def _embed(cfg: OPTConfig, params, input_ids, pos0: int = 0):
     """Token + learned position embeddings.  ``pos0``: shared base position
     (scalar), or int32 [B] per-sequence offsets — T == 1 for
@@ -260,6 +262,7 @@ def _embed(cfg: OPTConfig, params, input_ids, pos0: int = 0):
     return (x + pos).astype(params["embed_tokens"].dtype)
 
 
+@jax.named_scope("head")
 def _head(cfg: OPTConfig, params, x):
     """Final LN (pre-LN models) + tied lm head; x: [..., D] -> logits."""
     if cfg.do_layer_norm_before:
@@ -304,30 +307,31 @@ def _block_cached_body(cfg: OPTConfig, x, get, mm, ck, cv, pos,
     b, t, d = x.shape
     h, hd = cfg.num_heads, cfg.head_dim
 
-    res = x
-    y = _layer_norm(x, get("ln1_scale"), get("ln1_bias")) \
-        if cfg.do_layer_norm_before else x
-    qkv = mm(y, "qkv_w", None) + get("qkv_b").astype(y.dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
-    k = k.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
-    v = v.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
     from .gpt2 import _cached_attention
 
-    attn, ck, cv = _cached_attention(q, k, v, ck, cv, pos, block_tables,
-                                     chunk_valid)
-    attn = attn.transpose(0, 2, 1, 3).reshape(b, t, d)
-    x = res + mm(attn, "o_w", x.dtype) + get("o_b").astype(x.dtype)
-    if not cfg.do_layer_norm_before:
-        x = _layer_norm(x, get("ln1_scale"), get("ln1_bias"))
-
-    res = x
-    y = _layer_norm(x, get("ln2_scale"), get("ln2_bias")) \
-        if cfg.do_layer_norm_before else x
-    hid = jax.nn.relu(mm(y, "fc_w", None) + get("fc_b").astype(y.dtype))
-    x = res + mm(hid, "proj_w", x.dtype) + get("proj_b").astype(x.dtype)
-    if not cfg.do_layer_norm_before:
-        x = _layer_norm(x, get("ln2_scale"), get("ln2_bias"))
+    with jax.named_scope("layer/attn"):
+        res = x
+        y = _layer_norm(x, get("ln1_scale"), get("ln1_bias")) \
+            if cfg.do_layer_norm_before else x
+        qkv = mm(y, "qkv_w", None) + get("qkv_b").astype(y.dtype)
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q = q.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
+        k = k.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
+        v = v.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
+        attn, ck, cv = _cached_attention(q, k, v, ck, cv, pos, block_tables,
+                                         chunk_valid)
+        attn = attn.transpose(0, 2, 1, 3).reshape(b, t, d)
+        x = res + mm(attn, "o_w", x.dtype) + get("o_b").astype(x.dtype)
+        if not cfg.do_layer_norm_before:
+            x = _layer_norm(x, get("ln1_scale"), get("ln1_bias"))
+    with jax.named_scope("layer/mlp"):
+        res = x
+        y = _layer_norm(x, get("ln2_scale"), get("ln2_bias")) \
+            if cfg.do_layer_norm_before else x
+        hid = jax.nn.relu(mm(y, "fc_w", None) + get("fc_b").astype(y.dtype))
+        x = res + mm(hid, "proj_w", x.dtype) + get("proj_b").astype(x.dtype)
+        if not cfg.do_layer_norm_before:
+            x = _layer_norm(x, get("ln2_scale"), get("ln2_bias"))
     return x, ck, cv
 
 

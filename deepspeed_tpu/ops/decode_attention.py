@@ -231,6 +231,7 @@ def decode_attention_pallas(q, k_cache, v_cache, q_pos, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="decode_attn",
     )(pos, qg, k_cache, v_cache)
     return out.reshape(b, h, 1, d)
 
@@ -414,6 +415,7 @@ def _paged_decode_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode_attn",
     )(pos, bt, qg, *pools)
     return out.reshape(b, h, 1, d)
 
@@ -551,6 +553,7 @@ def _paged_verify_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_verify_attn",
     )(pos, bt, qg, *pools)
     return out.reshape(b, hkv, rep, t, d).reshape(b, h, t, d)
 

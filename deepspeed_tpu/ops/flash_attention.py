@@ -152,6 +152,7 @@ def _fwd(q, k, v, sm_scale: float, causal: bool, block_q: int, block_k: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(*inputs)
     return o, lse[:, :, 0]
 
@@ -320,6 +321,7 @@ def _bwd_dq_call(q, k, v, do, lse_b, delta_b, *, sm_scale, causal, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*inputs)
     return dq
 
@@ -377,6 +379,7 @@ def _bwd_dkv_call(q, k, v, do, lse_b, delta_b, *, sm_scale, causal, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*([q, k, v, do, lse_b, delta_b] +
         ([layout.astype(jnp.int32)] if layout is not None else [])))
     return dk, dv
@@ -531,6 +534,7 @@ def _fwd_v2(q, k, v, sm_scale, causal, block_q, interpret, true_kv_len,
         out_shape=jax.ShapeDtypeStruct((bh, q_len, d), q.dtype),
         compiler_params=_v2_compiler_params(("parallel", "parallel")),
         interpret=interpret,
+        name="flash_fwd_resident",
     )(q, k, v)
 
 
@@ -636,6 +640,7 @@ def _bwd_v2(q, k, v, o, do, sm_scale, causal, block_q, interpret, true_kv_len,
         ],
         compiler_params=_v2_compiler_params(("parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_fused",
     )(q, k, v, o, do)
     return dq, dk, dv
 
@@ -753,6 +758,7 @@ def _fwd_v3(q, k, v, sm_scale, causal, block_q, block_k, interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd_chunked",
     )(q, k, v)
     return o, lse
 
@@ -884,6 +890,7 @@ def _bwd_v3(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq_chunked",
     )(q, k, v, do, lse, delta)
 
     dkv_kernel = functools.partial(
@@ -906,6 +913,7 @@ def _bwd_v3(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv_chunked",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

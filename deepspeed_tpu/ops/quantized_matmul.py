@@ -147,6 +147,7 @@ def _qmm_call(x2d, q3, scale3, out_dtype, block_k, block_n, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="qmm_weight_only",
     )(x3, q3, scale3)
 
 
@@ -301,6 +302,7 @@ def _w8a8_call(x2d, qk, kscale, out_dtype, block_k, interpret,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=vmem_limit),
         interpret=interpret,
+        name="qmm_w8a8_matmul",
     )(x3, qk, kscale)
 
 
@@ -533,6 +535,7 @@ def _w8a8_stacked_call(idx, x2d, qks, kscales, out_dtype, block_k,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=vmem_limit),
         interpret=interpret,
+        name="qmm_w8a8_stacked",
     )(jnp.asarray(idx, jnp.int32).reshape(1), x3, qks, kscales)
 
 

@@ -44,6 +44,7 @@ from ..ops.optimizers import get_optimizer
 from ..parallel.topology import (DATA_AXES, SP_AXIS, MeshTopology,
                                  topology_from_config)
 from ..telemetry import MetricsRegistry
+from ..telemetry.trace import annotation
 from ..utils.logging import log_dist, logger
 from ..utils.platform import host_cpu_device
 from ..utils.timer import (BACKWARD_GLOBAL_TIMER, FORWARD_GLOBAL_TIMER,
@@ -1275,18 +1276,23 @@ class DeepSpeedEngine:
         ``data_iter`` yielding micro-global batches (reference
         ``PipelineEngine.train_batch`` signature).
         """
-        if batch is None:
-            it = data_iter or self._ensure_data_iterator()
-            micros = [next(it) for _ in range(self.gradient_accumulation_steps())]
-            batch = self._stack_micros(micros)
-        else:
-            first = jax.tree_util.tree_leaves(batch)[0]
-            if first.shape[0] == self.train_batch_size() and \
-                    self.gradient_accumulation_steps() * self.micro_batch_global() \
-                    == self.train_batch_size():
-                batch = self._reshape_global_batch(batch)
-        batch = self._apply_curriculum(batch)
-        batch = self._shard_batch(batch, leading_gas_dim=True)
+        # host spans on the profiler's clock while a profile is being
+        # taken (telemetry/trace.py annotation: a flag test otherwise);
+        # batch_prep ends with the host-to-device copy of the batch
+        with annotation("ds.train.batch_prep"):
+            if batch is None:
+                it = data_iter or self._ensure_data_iterator()
+                micros = [next(it) for _ in
+                          range(self.gradient_accumulation_steps())]
+                batch = self._stack_micros(micros)
+            else:
+                first = jax.tree_util.tree_leaves(batch)[0]
+                if first.shape[0] == self.train_batch_size() and \
+                        self.gradient_accumulation_steps() * \
+                        self.micro_batch_global() == self.train_batch_size():
+                    batch = self._reshape_global_batch(batch)
+            batch = self._apply_curriculum(batch)
+            batch = self._shard_batch(batch, leading_gas_dim=True)
 
         # compression schedule_offsets: advance the trace-time step marker
         # when a mechanism's offset is crossed and retrace (reference applies
@@ -1326,11 +1332,16 @@ class DeepSpeedEngine:
 
         self.tput_timer.start()
         self.timers(TRAIN_BATCH_TIMER).start()
-        if self.offload_enabled:
-            self.state, metrics = self._train_step_offload(self.state, batch)
-        else:
-            self.state, metrics = self._train_step_fn(self.state, batch,
-                                                      self._dropout_rng)
+        # StepTraceAnnotation: profile viewers group the trace by step
+        with jax.profiler.StepTraceAnnotation(
+                "ds.train.step", step_num=self.global_steps), \
+                annotation("ds.train.dispatch"):
+            if self.offload_enabled:
+                self.state, metrics = self._train_step_offload(self.state,
+                                                               batch)
+            else:
+                self.state, metrics = self._train_step_fn(
+                    self.state, batch, self._dropout_rng)
         self.global_steps += 1
         self.micro_steps += self.gradient_accumulation_steps()
         self.global_samples += self.train_batch_size()

@@ -10,8 +10,12 @@ Coordinated pieces (design notes in each module):
    engine's monitor events are views over one registry each.
  - :mod:`~deepspeed_tpu.telemetry.trace` — a bounded ring buffer of
    per-request scheduler events exportable as Chrome ``trace_event``
-   JSON (Perfetto) with cross-lane flow events, plus the
+   JSON (Perfetto) with cross-lane flow events; every span doubles as
+   a ``jax.profiler`` annotation (``ds.<role>.<name>``), plus the
    ``jax.profiler`` window bracket.
+ - :mod:`~deepspeed_tpu.telemetry.idle_gaps` — from one profile, the
+   device's idle time partitioned over the program's ``ds.*`` spans
+   (``python -m deepspeed_tpu.telemetry.idle_gaps <profile_dir>``).
  - :mod:`~deepspeed_tpu.telemetry.aggregate` — fleet federation: merge
    the router + replica registries into one ``replica=``-labeled
    registry (bucket-wise-summed histograms) and the per-replica trace

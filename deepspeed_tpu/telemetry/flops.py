@@ -37,7 +37,10 @@ invocation counters (``decode_steps`` / ``prefill_calls`` /
 ``serving_mfu`` gauge against a configurable ``peak_flops`` (per-chip
 peak × chips — the MFU denominator), and decomposes wall time into
 ``serving_busy_fraction{phase=prefill|decode|swap|idle}`` from the
-``X``-span durations already on the trace timeline.  Everything is
+in-flight ``X``-span durations already on the trace timeline: the share
+of the window a prefill / decode / swap program was in flight (dispatch
+to results on the host), and ``idle`` the rest — the HOST's share, during
+which the device has nothing to run.  Everything is
 host-side; cost analysis runs only when explicitly invoked (a report is
 an O(ring) walk plus, on first use, one lowering per program family).
 """
@@ -114,10 +117,17 @@ def analytic_program_flops(family: str, dims: Dict[str, int], *,
 def busy_fractions(timeline, window_s: Optional[float] = None
                    ) -> Dict[str, float]:
     """Decompose the timeline window into prefill/decode/swap/idle
-    fractions from the ``X``-span durations already on the ring.  The
-    window defaults to first-event → last-event-end over the live ring
-    (a wrapped ring reports its retained window — check
-    ``trace_events_dropped``)."""
+    fractions from the in-flight ``X``-span durations already on the ring.
+    Those spans open just before a jitted call and close when its results
+    are on the host, so ``prefill`` / ``decode`` / ``swap`` are the shares
+    of the window a program of that kind was IN FLIGHT (an upper bound on
+    the device's busy share: dispatch latency and the copy-back are
+    inside), and ``idle`` is the host's share — scheduling, packing,
+    commit loops, the caller's own code — not idleness of the device
+    alone.  The device's own busy time needs a profile
+    (``telemetry/idle_gaps.py``).  The window defaults to first-event →
+    last-event-end over the live ring (a wrapped ring reports its retained
+    window — check ``trace_events_dropped``)."""
     events = timeline.events()
     spans = {phase: 0.0 for phase in _PHASE_SPANS}
     lo = hi = None
@@ -170,8 +180,9 @@ class ServingFlopsProfiler:
         self._g_busy = {
             phase: m.gauge(
                 "serving_busy_fraction",
-                "fraction of the timeline window spent in each scheduler "
-                "phase", phase=phase)
+                "fraction of the timeline window a program of that phase "
+                "was in flight (dispatch to results on the host); idle = "
+                "the host's share, no program in flight", phase=phase)
             for phase in ("prefill", "decode", "swap", "idle")}
 
     # -------------------------------------------------------- per-program cost

@@ -25,6 +25,18 @@ registered thread lane.  Load the file at https://ui.perfetto.dev (or
 ``chrome://tracing``) — requests appear as one span lane each, scheduler
 phases as a shared lane (walkthrough: ``docs/observability.md``).
 
+One span, two clocks: :meth:`TraceTimeline.span` records into the ring on
+the host clock (always) AND enters a ``jax.profiler`` annotation named
+``ds.<role>.<name>`` for the same interval (:func:`annotation`, the one
+place the program constructs one).  An annotation is a flag test while no
+profile is being taken; while one is — ``serve(profile_dir=...)``, a
+benchmark's traced window — the span lands on the profiler's own clock
+beside the device planes, where ``telemetry/idle_gaps.py`` lays the
+device's idle gaps against it.  ``capacity=0`` turns the ring off, never
+the annotations.  :func:`keep` / :func:`kept` hold the newest timeline of
+each role reachable after its engine is gone (the ring and its epoch only
+— a timeline references nothing of its engine).
+
 :class:`ProfilerWindow` is the deep-dive escalation: it brackets a region
 with ``jax.profiler.start_trace`` / ``stop_trace`` so a slow window seen
 in the host timeline can be re-run with full XLA/device traces
@@ -42,12 +54,39 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
 
+import jax
+
 from ..utils.logging import logger
 
-__all__ = ["TraceTimeline", "ProfilerWindow", "validate_chrome_trace"]
+__all__ = ["TraceTimeline", "ProfilerWindow", "validate_chrome_trace",
+           "annotation", "keep", "kept"]
 
 #: tid of the shared scheduler lane (request lanes are allocated upward)
 SCHEDULER_TID = 0
+
+#: role -> the newest timeline built for it (see :func:`keep`)
+_KEPT: Dict[str, "TraceTimeline"] = {}
+
+
+def annotation(name: str):
+    """Context manager that puts ``name`` on the profiler's host line for
+    the interval it is entered — the program's ONE constructor of profiler
+    annotations (``ds.serve.*`` through :meth:`TraceTimeline.span`,
+    ``ds.train.*`` from ``train_batch``).  While no profile is being taken
+    entering it is a flag test."""
+    return jax.profiler.TraceAnnotation(name)
+
+
+def keep(role: str, timeline: "TraceTimeline") -> None:
+    """Hold ``timeline`` as the newest of its ``role`` (``serve``): whoever
+    drove an engine reads its spans through :func:`kept` after the engine is
+    closed or collected.  One slot per role, replaced by the next engine."""
+    _KEPT[role] = timeline
+
+
+def kept(role: str) -> Optional["TraceTimeline"]:
+    """The newest timeline :func:`keep` was given for ``role``, or None."""
+    return _KEPT.get(role)
 
 
 class TraceTimeline:
@@ -61,7 +100,14 @@ class TraceTimeline:
     pid:       the exported ``pid`` (multi-process launchers pass
                ``jax.process_index()`` so merged traces stay distinct).
     clock:     second-denominated monotonic clock (injectable for tests).
+
+    ``role`` names the spans' profiler annotations (``ds.<role>.<name>``);
+    ``step``, once its owner sets it, is stamped on every ``X`` event as
+    ``args["step"]`` — the scheduler iteration that caused the span.
     """
+
+    role = "serve"
+    step: Optional[int] = None
 
     def __init__(self, capacity: int = 16384, pid: int = 0, clock=None):
         if capacity < 0:
@@ -141,6 +187,8 @@ class TraceTimeline:
         ev = {"name": name, "ph": "X", "ts": start_us,
               "dur": max(end - start_us, 0.0),
               "pid": self.pid, "tid": tid}
+        if self.step is not None:
+            args.setdefault("step", self.step)
         if args:
             ev["args"] = args
         self._push(ev)
@@ -182,17 +230,19 @@ class TraceTimeline:
 
     @contextmanager
     def span(self, name: str, tid: int = SCHEDULER_TID, **args):
-        """Context manager emitting an ``X`` event around the body; the
-        body can mutate ``args`` in place (accept-lengths are known only
-        after the verify pass returns)."""
-        if not self.enabled:
-            yield args
-            return
-        start = self.now_us()
-        try:
-            yield args
-        finally:
-            self.complete(name, start, tid=tid, **args)
+        """Context manager emitting an ``X`` event around the body AND a
+        profiler annotation ``ds.<role>.<name>`` for the same interval
+        (module docstring); the body can mutate ``args`` in place
+        (accept-lengths are known only after the verify pass returns)."""
+        with annotation(f"ds.{self.role}.{name}"):
+            if not self.enabled:
+                yield args
+                return
+            start = self.now_us()
+            try:
+                yield args
+            finally:
+                self.complete(name, start, tid=tid, **args)
 
     # ---------------------------------------------------------------- export
     def __len__(self) -> int:
@@ -381,9 +431,12 @@ class ProfilerWindow:
         if self.active:
             return True
         try:
-            import jax
-
-            jax.profiler.start_trace(self.profile_dir)
+            # no Python frames: with them a 50-iteration window is huge
+            # and slows the very host path it is there to show
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(self.profile_dir,
+                                     profiler_options=options)
             self.active = True
         except Exception as e:  # unavailable backend / nested trace
             logger.warning(f"jax.profiler window not started: {e}")
@@ -394,8 +447,6 @@ class ProfilerWindow:
             return
         self.active = False
         try:
-            import jax
-
             jax.profiler.stop_trace()
         except Exception as e:
             logger.warning(f"jax.profiler window not stopped cleanly: {e}")
