@@ -5,7 +5,7 @@ on the chip.
     python chip_smoke.py            # on a machine with a TPU (chiprun)
     python chip_smoke.py --phases kernels,train   # a subset, while debugging
 
-One process (it spawns nothing: a chip belongs to one process), five phases,
+One process (it spawns nothing: a chip belongs to one process), six phases,
 each printing ``ok`` or its exception; any failure makes the exit code
 non-zero and suppresses the result line:
 
@@ -22,6 +22,11 @@ non-zero and suppresses the result line:
  - ``serve-q``  the same widths with ``quantize="w8a8+kv8"`` (int8 KV pool
                 + s8-MXU decode matmuls), judged by the repo's
                 bounded-divergence contract.
+ - ``serving-memory``  the decode and prefill programs compiled at the
+                benchmark's chat-cell shapes (24 slots x 1,024): their
+                ``memory_analysis().temp_size_in_bytes`` and the number of
+                pool-slice-sized ``copy`` instructions, failing on a
+                pool-sized temporary (the pool is updated in place).
  - ``train``    ``initialize`` of GPT-2 125M with ``bench.py``'s kernel
                 configuration, three ``train_batch`` steps on one seeded
                 batch; loss finite and falling.
@@ -225,18 +230,27 @@ def phase_kernels(sz: Sizes, report: Dict[str, Any]) -> None:
 
     checks: List[Tuple[str, Callable[[], Dict[str, float]]]] = []
 
+    def as_engine_holds_it(p):
+        """A stacked pool (layer 0 zeros, ``p`` at layer 1), lane-packed."""
+        return paged_kv.pack_pool(jax.tree_util.tree_map(
+            lambda a: jnp.stack([jnp.zeros_like(a), a]), p))
+
     def paged(t, kernel, kind):
         kp, vp = pools[kind]
         q = jax.random.normal(keys[2], (slots, h, t, hd), jnp.bfloat16)
-        fn = jax.jit(lambda q, kp, vp, bt, pos: kernel(q, kp, vp, bt, pos))
-        _mosaic(fn.lower(q, kp, vp, bt, pos).as_text(),
+        # the kernel reads the whole pool at a (non-zero) layer index; the
+        # reference reads that layer's pool alone, unpacked, in float32
+        fn = jax.jit(lambda q, kp, vp, bt, pos: kernel(q, kp, vp, bt, pos,
+                                                       layer=1))
+        kps, vps = as_engine_holds_it(kp), as_engine_holds_it(vp)
+        _mosaic(fn.lower(q, kps, vps, bt, pos).as_text(),
                 f"paged T={t} {kind}", require)
         want = exact(
             lambda q, kp, vp: da.paged_decode_attention_reference(
                 q.astype(jnp.float32), f32_pool(kp), f32_pool(vp), bt, pos),
             q, kp, vp)
         return {f"paged_T{t}_{kind}": _close(
-            f"paged attention T={t} {kind}", fn(q, kp, vp, bt, pos), want,
+            f"paged attention T={t} {kind}", fn(q, kps, vps, bt, pos), want,
             ATTN_TOL)}
 
     for t, kernel in ((1, da.paged_decode_attention_pallas),
@@ -374,8 +388,11 @@ def paged_logits(srv, tokens: np.ndarray, n_decode: int) -> np.ndarray:
     else:
         cache = jax.tree_util.tree_map(
             lambda a: jnp.zeros(a.shape, a.dtype), cache)
+    # lane-packed, as the engine holds its own pool (the benchmark's copy
+    # of this function passes the hook's shape: the same code, unpacked)
     cache = jax.tree_util.tree_map(
-        lambda a: jax.device_put(a, srv._pool_sharding), cache)
+        lambda a: jax.device_put(a, srv._pool_sharding),
+        paged_kv.pack_pool(cache))
     bt = jnp.asarray(1 + np.arange(b * nbper).reshape(b, nbper), jnp.int32)
 
     @jax.jit
@@ -584,6 +601,126 @@ def phase_serve_quant(sz: Sizes, report: Dict[str, Any]) -> None:
     phase_serve(sz, report, quantize="w8a8+kv8")
 
 
+# ------------------------------------------------- serving programs' memory
+#: the benchmark's chat cell (chipbench/workloads/opt13b-chat-closed.json):
+#: 24 slots x 1,024 tokens in 32-token blocks, [4, 128] prefill chunks
+CHAT_SLOTS, CHAT_CTX, CHAT_BLOCK, CHAT_CHUNK = 24, 1024, 32, (4, 128)
+
+
+def serving_programs(cfg, sharding, *, slots=CHAT_SLOTS, ctx=CHAT_CTX,
+                     block=CHAT_BLOCK, chunk=CHAT_CHUNK, kv8=False,
+                     verify_t=4):
+    """The paged serving programs of an OPT ``cfg`` as ``{name: (fn,
+    abstract args)}`` — the bodies ``ServingEngine`` jits
+    (``forward_cached`` over the donated pool, argument 1, + a token rule),
+    at the shapes of a ``slots`` x ``ctx`` engine with the pool lane-packed
+    as the engine holds it (``paged_kv.pack_pool``), on ShapeDtypeStructs
+    only: nothing is allocated.  ``sharding=None`` leaves the arguments
+    plain shapes (``jax.export`` from the CPU)."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models import opt
+    from deepspeed_tpu.ops import paged_kv
+
+    spec = opt.build(cfg)
+    fwd = spec.decode_hooks["forward_cached"]
+    nbper = paged_kv.blocks_for(ctx, block)
+
+    def sds(a, where=sharding):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=where)
+
+    def i32(*shape):
+        return sds(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    params = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda: jax.tree_util.tree_map(
+            lambda a: a.astype(jnp.bfloat16),
+            spec.init_fn(jax.random.PRNGKey(0)))))
+    pool = jax.eval_shape(lambda: spec.decode_hooks["init_cache"](
+        1 + slots * nbper, block, jnp.bfloat16))
+    if kv8:
+        pool = jax.eval_shape(paged_kv.quantize_pool, pool)
+    pool = jax.tree_util.tree_map(sds, jax.eval_shape(paged_kv.pack_pool,
+                                                      pool))
+
+    def decode_step(params, cache, tokens, lengths, bt):
+        logits, cache = fwd(params, tokens[:, None], cache, 0,
+                            lengths=lengths, block_tables=bt)
+        return jnp.argmax(logits, -1).astype(jnp.int32), cache
+
+    def prefill(params, cache, ids, bt, base, valid, all_positions=False):
+        logits, cache = fwd(params, ids, cache, base, lengths=valid,
+                            block_tables=bt, all_positions=all_positions)
+        return jnp.argmax(logits, -1).astype(jnp.int32), cache
+
+    def verify(*args):
+        return prefill(*args, all_positions=True)
+
+    j, w = chunk
+    return {
+        "decode_step": (decode_step, (params, pool, i32(slots), i32(slots),
+                                      i32(slots, nbper))),
+        "prefill": (prefill, (params, pool, i32(j, w), i32(j, nbper),
+                              i32(j), i32(j))),
+        "verify": (verify, (params, pool, i32(slots, verify_t),
+                            i32(slots, nbper), i32(slots), i32(slots))),
+    }
+
+
+def pool_payload_struct(cache):
+    """The K pool's payload array (the int8 codes of a kv8 record) of a
+    ``{"k", "v"}`` cache tree."""
+    from deepspeed_tpu.ops import paged_kv
+
+    return paged_kv.pool_payload(cache["k"])
+
+
+def compile_serving_program(fn, args):
+    """Compile one of :func:`serving_programs` with the pool donated;
+    ``(compiled, pool-slice-sized copy instructions in its text)``."""
+    import jax
+
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    payload = pool_payload_struct(args[1]).shape            # [L, NB, ...]
+    slice_dims = ",".join(str(d) for d in payload[1:])
+    copies = [line for line in compiled.as_text().splitlines()
+              if " copy(" in line and slice_dims in line.split(" copy(")[0]]
+    return compiled, copies
+
+
+def phase_serving_memory(sz: Sizes, report: Dict[str, Any]) -> None:
+    """The decode and prefill programs at the chat cell's shapes must hold
+    no temporary of the pool's size: the pool is carried whole and updated
+    in place (one layer's slice of it is the yardstick: 100 MB)."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    from deepspeed_tpu.utils.platform import on_tpu
+
+    if not on_tpu():
+        # XLA:CPU's buffers and layouts say nothing about the chip's
+        log("serving-memory: a TPU-only check, skipped")
+        return
+    progs = serving_programs(sz.opt, SingleDeviceSharding(jax.devices()[0]))
+    out, failed = {}, []
+    for name in ("decode_step", "prefill"):
+        fn, args = progs[name]
+        compiled, copies = compile_serving_program(fn, args)
+        payload = pool_payload_struct(args[1])
+        layer_slice = int(np.prod(payload.shape[1:])) * payload.dtype.itemsize
+        temp = int(compiled.memory_analysis().temp_size_in_bytes)
+        out[name] = {"temp_bytes": temp, "pool_slice_copies": len(copies),
+                     "layer_slice_bytes": layer_slice}
+        log(f"serving program {name} at {CHAT_SLOTS} slots x {CHAT_CTX}: "
+            f"temporaries {temp / 1e6:.2f} MB, {len(copies)} copies of a "
+            f"pool slice (one layer's slice: {layer_slice / 1e6:.1f} MB)")
+        if temp >= layer_slice or copies:
+            failed.append(name)
+    report["serving_memory"] = out
+    assert not failed, f"pool-sized temporaries or copies in {failed}: {out}"
+
+
 # ------------------------------------------------------------------- train
 def phase_train(sz: Sizes, report: Dict[str, Any]) -> None:
     import jax
@@ -647,6 +784,7 @@ PHASES: List[Tuple[str, Callable[[Sizes, Dict[str, Any]], None]]] = [
     ("kernels", phase_kernels),
     ("serve", phase_serve),
     ("serve-q", phase_serve_quant),
+    ("serving-memory", phase_serving_memory),
     ("train", phase_train),
 ]
 

@@ -16,7 +16,11 @@ leverage serving optimisations on top of continuous batching:
    discarded KV there, so every device program keeps a fixed shape.
    Attention reaches the pool through the table: a gather-based XLA path
    for prefill/CPU and a Pallas kernel that walks the table in-kernel via
-   scalar prefetch for TPU decode (``ops/decode_attention.py``).
+   scalar prefetch for TPU decode (``ops/decode_attention.py``).  The
+   engine holds the pool lane-packed (``paged_kv.pack_pool``: the same
+   bytes, ``[L, NB, HKV, bs/g, g*hd]``) and every program carries it whole
+   through its layer loop, reading and writing ``(layer, block)`` in place
+   (``ops/paged_kv.py`` has the contract and why).
  - **Prefix cache** (SGLang RadixAttention at block granularity): a token
    trie over *full* blocks.  A new request whose prompt shares a
    block-aligned prefix with any previously prefilled sequence reuses
@@ -1156,19 +1160,21 @@ class ServingEngine:
             # own LIVE scale rows so the debug audit can prove scale
             # allocation stays in lockstep with blocks (scale-lockstep
             # invariant, analysis/invariants.py)
-            abstract = jax.eval_shape(
+            mk_pool = lambda: paged_kv.quantize_pool(jax.eval_shape(
                 lambda: self._init_cache(num_blocks, self.block_size,
-                                         engine._config.jnp_dtype))
-            pool = paged_kv.quantize_pool(abstract)
+                                         engine._config.jnp_dtype)))
             self._kv_dtype = "int8"
         else:
-            pool = self._init_cache(num_blocks, self.block_size,
-                                    engine._config.jnp_dtype)
-            self._kv_dtype = jnp.dtype(
-                jax.tree_util.tree_leaves(pool)[0].dtype).name
+            mk_pool = lambda: self._init_cache(
+                num_blocks, self.block_size, engine._config.jnp_dtype)
+            self._kv_dtype = jnp.dtype(jax.tree_util.tree_leaves(
+                jax.eval_shape(mk_pool))[0].dtype).name
+        # the hook's (logical) shape [L, NB, HKV, bs, hd]; the pool itself
+        # is held lane-packed (:meth:`_commit_pool`)
         self._pool_shape = tuple(paged_kv.pool_payload(
             jax.tree_util.tree_leaves(
-                pool, is_leaf=paged_kv.is_quantized_pool)[0]).shape)
+                jax.eval_shape(mk_pool),
+                is_leaf=paged_kv.is_quantized_pool)[0]).shape)
         self._kv_scale_live: set = set()
         hkv = int(self._pool_shape[2])
         divisible = self.tp_degree > 1 and hkv % self.tp_degree == 0
@@ -1208,8 +1214,7 @@ class ServingEngine:
                 engine.mesh, P(None, None, TP_AXIS)) \
                 if self.kv_sharded else rep
         self._pool_sharding = pool_sharding
-        self._cache = jax.tree_util.tree_map(
-            lambda x: jax.device_put(x, pool_sharding), pool)
+        self._cache = self._commit_pool(mk_pool, pool_sharding)
         # host-side block tables; entry 0 = scratch doubles as "unset"
         self._tables = np.zeros((self.slots, self._nbper), np.int32)
         self._held: List[List[int]] = [[] for _ in range(self.slots)]
@@ -1339,12 +1344,12 @@ class ServingEngine:
                     # its quantization story — rollout reads/writes move
                     # int8 + scales too (abstract build, same double-
                     # footprint argument as the target pool above)
-                    dpool = paged_kv.quantize_pool(jax.eval_shape(mk_dpool))
-                else:
-                    dpool = mk_dpool()
+                    mk_float = mk_dpool
+                    mk_dpool = lambda: paged_kv.quantize_pool(
+                        jax.eval_shape(mk_float))
                 dhkv = int(paged_kv.pool_payload(
                     jax.tree_util.tree_leaves(
-                        dpool,
+                        jax.eval_shape(mk_dpool),
                         is_leaf=paged_kv.is_quantized_pool)[0]).shape[2])
                 d_div = dhkv % self.tp_degree == 0
                 if self.kv_sharded and not d_div:
@@ -1363,8 +1368,7 @@ class ServingEngine:
                 # (the paged ops fall back per-shape — ops/paged_kv.py)
                 self._dcache_sharded = self.kv_sharded and d_div
                 dsharding = pool_sharding if self._dcache_sharded else rep
-                self._dcache = jax.tree_util.tree_map(
-                    lambda x: jax.device_put(x, dsharding), dpool)
+                self._dcache = self._commit_pool(mk_dpool, dsharding)
             else:
                 self._proposer = NGramProposer(self.spec_tokens,
                                                max_n=ngram_max,
@@ -1812,6 +1816,16 @@ class ServingEngine:
         # donating the pool avoids a full cache copy per step; XLA:CPU
         # ignores donation with a warning, so only ask for it on TPU
         return (1,) if on_tpu() else ()
+
+    @staticmethod
+    def _commit_pool(mk_pool, sharding):
+        """Build a pool on its sharding, lane-packed (``ops/paged_kv.py``
+        "Layout": the same bytes as the hook's ``[L, NB, HKV, bs, hd]``,
+        in the view whose TPU layout the paged kernels read) — in one
+        jitted program, so neither an unpacked nor an unsharded copy of it
+        ever exists."""
+        return jax.jit(lambda: paged_kv.pack_pool(mk_pool()),
+                       out_shardings=sharding)()
 
     def _constrain_pool(self, cache):
         """dp_tp only: pin the cache OUTPUT of every decode/prefill program

@@ -208,7 +208,8 @@ def init_cache(cfg: BloomConfig, batch_size: int, max_len: int,
 
 
 def _alibi_cached_attention(cfg: BloomConfig, q, k, v, ck, cv, pos,
-                            block_tables=None, chunk_valid=None):
+                            block_tables=None, chunk_valid=None,
+                            layer=None):
     """Write new KV + ALiBi attention, on either cache layout (contract in
     gpt2._cached_attention).  Pure XLA on both layouts: the additive ALiBi
     bias rules out the shared position-masked decode kernels, so the paged
@@ -223,11 +224,13 @@ def _alibi_cached_attention(cfg: BloomConfig, q, k, v, ck, cv, pos,
         kk, vv = ck, cv
     else:
         ck, cv = paged_cache_update(ck, cv, k, v, pos, block_tables,
-                                    valid=chunk_valid)
+                                    valid=chunk_valid, layer=layer)
         # int8 records dequantize to the query dtype (kv8 serving) so the
         # residual stream keeps the model's compute dtype
-        kk = paged_gather(ck, block_tables, out_dtype=q.dtype)
-        vv = paged_gather(cv, block_tables, out_dtype=q.dtype)
+        kk = paged_gather(ck, block_tables, out_dtype=q.dtype, layer=layer,
+                          head_dim=q.shape[-1])
+        vv = paged_gather(cv, block_tables, out_dtype=q.dtype, layer=layer,
+                          head_dim=q.shape[-1])
 
     t, s = q.shape[2], kk.shape[2]
     pos = jnp.asarray(pos, jnp.int32)
@@ -258,7 +261,7 @@ def _alibi_cached_attention(cfg: BloomConfig, q, k, v, ck, cv, pos,
 
 
 def _block_cached_body(cfg: BloomConfig, x, get, mm, ck, cv, pos,
-                       block_tables=None, chunk_valid=None):
+                       block_tables=None, chunk_valid=None, layer=None):
     """One BLOOM block over a KV cache, parameterized by weight access
     (same shape as gpt2._block_cached_body so the scan and layer-indexed
     quantized decode paths share it)."""
@@ -272,7 +275,7 @@ def _block_cached_body(cfg: BloomConfig, x, get, mm, ck, cv, pos,
     k = k.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
     v = v.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
     attn, ck, cv = _alibi_cached_attention(cfg, q, k, v, ck, cv, pos,
-                                           block_tables, chunk_valid)
+                                           block_tables, chunk_valid, layer)
     attn = attn.transpose(0, 2, 1, 3).reshape(b, t, d)
     x = x + mm(attn, "o_w", x.dtype) + get("o_b").astype(x.dtype)
 
@@ -316,10 +319,11 @@ def forward_cached(cfg: BloomConfig, params, input_ids, cache, pos,
     x = shard_seq(x)
 
     x, ks, vs = decode_over_layers(
-        lambda x, get, mm, ck, cv: _block_cached_body(
+        lambda x, get, mm, ck, cv, layer: _block_cached_body(
             cfg, x, get, mm, ck, cv, step_pos, block_tables=block_tables,
-            chunk_valid=chunk_valid),
-        x, params["blocks"], cache["k"], cache["v"], cfg.num_layers)
+            chunk_valid=chunk_valid, layer=layer),
+        x, params["blocks"], cache["k"], cache["v"], cfg.num_layers,
+        paged=block_tables is not None)
     if not all_positions:
         x = _gather_last(x, lengths if not per_row else None)
     x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])

@@ -392,14 +392,17 @@ def cache_update(ck, cv, k, v, pos):
 
 
 def _cached_attention(q, k, v, ck, cv, pos, block_tables=None,
-                      chunk_valid=None):
+                      chunk_valid=None, layer=None):
     """Write new KV + attend, on either cache layout.  Contiguous
-    (``block_tables is None``): ck/cv are [B, H, S, hd] per-sequence
-    regions.  Paged: ck/cv are the shared [NB, H, bs, hd] pool and each
-    row reaches its tokens through ``block_tables`` int32 [B, NBPER];
-    ``chunk_valid`` (int32 [B]) marks how many of a T>1 chunk's tokens are
-    real — pads write to the scratch block.  Shared by every decode-hook
-    model family."""
+    (``block_tables is None``): ck/cv are one layer's [B, H, S, hd]
+    per-sequence regions.  Paged: ck/cv are the WHOLE stacked
+    [L, NB, H, bs, hd] pool and ``layer`` the (traced) index of the layer
+    being run — the write and the read address the pool in place as
+    (layer, physical block, head, offset) through ``block_tables`` int32
+    [B, NBPER] (``ops/paged_kv.py``), so the caller carries the pool
+    through its layer loop untouched.  ``chunk_valid`` (int32 [B]) marks
+    how many of a T>1 chunk's tokens are real — pads write to the scratch
+    block.  Shared by every decode-hook model family."""
     from ..ops.decode_attention import decode_attention, \
         paged_decode_attention
 
@@ -409,17 +412,19 @@ def _cached_attention(q, k, v, ck, cv, pos, block_tables=None,
     from ..ops.paged_kv import paged_cache_update
 
     ck, cv = paged_cache_update(ck, cv, k, v, pos, block_tables,
-                                valid=chunk_valid)
-    return paged_decode_attention(q, ck, cv, block_tables, pos), ck, cv
+                                valid=chunk_valid, layer=layer)
+    return paged_decode_attention(q, ck, cv, block_tables, pos,
+                                  layer=layer), ck, cv
 
 
 def _block_cached_body(cfg: GPT2Config, x, get, mm, ck, cv, pos,
-                       block_tables=None, chunk_valid=None):
+                       block_tables=None, chunk_valid=None, layer=None):
     """One block with KV-cache read/write, parameterized by weight access
     (``get(name)`` small leaf, ``mm(y, name, dtype)`` matmul) so the scan
     and layer-indexed decode paths share the math.  x: [B, T, D]; ck/cv:
-    [B, H, S, hd] — or the paged pool slice [NB, H, bs, hd] when
-    ``block_tables`` is given; pos: traced global position of x[:, 0] —
+    [B, H, S, hd] — or, when ``block_tables`` is given, the whole paged
+    pool [L, NB, H, bs, hd] plus this block's ``layer`` index (contract in
+    :func:`_cached_attention`); pos: traced global position of x[:, 0] —
     scalar, or int32 [B] per-row positions (continuous-batching decode
     T=1, or paged chunked-prefill bases T>1)."""
     b, t, d = x.shape
@@ -433,7 +438,7 @@ def _block_cached_body(cfg: GPT2Config, x, get, mm, ck, cv, pos,
         k = k.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
         v = v.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
         attn, ck, cv = _cached_attention(q, k, v, ck, cv, pos, block_tables,
-                                         chunk_valid)
+                                         chunk_valid, layer)
         attn = attn.transpose(0, 2, 1, 3).reshape(b, t, d)
         x = x + mm(attn, "o_w", x.dtype) + get("o_b").astype(x.dtype)
     with jax.named_scope("layer/mlp"):
@@ -443,13 +448,53 @@ def _block_cached_body(cfg: GPT2Config, x, get, mm, ck, cv, pos,
     return x, ck, cv
 
 
+def scan_layers_cached(step, x, blocks, cache_k, cache_v, paged: bool):
+    """``lax.scan`` of ``step(x, layer_params, ck, cv, l) -> (x, ck, cv)``
+    over the stacked ``blocks``, on either cache layout.
+
+    Contiguous (``paged=False``): the stacked [L, B, H, S, hd] cache rides
+    as ``xs`` beside the weights, each step gets its own layer's slice
+    (``l`` is None) and the updated slices re-stack as ``ys``.
+
+    Paged: the stacked pool [L, NB, H, bs, hd] is the loop CARRY and each
+    step gets the whole pool plus its layer index ``l`` — nothing slices a
+    layer out of the pool or re-stacks it, so the compiled ``while`` updates
+    the (donated) pool buffer in place (``ops/paged_kv.py`` has the
+    contract)."""
+    if not paged:
+        def sbody(x, xs):
+            layer, ck, cv = xs
+            x, ck, cv = step(x, layer, ck, cv, None)
+            return x, (ck, cv)
+
+        x, (ks, vs) = jax.lax.scan(sbody, x, (blocks, cache_k, cache_v))
+        return x, ks, vs
+
+    def pbody(carry, xs):
+        x, pk, pv = carry
+        layer, l = xs
+        return step(x, layer, pk, pv, l), None
+
+    n = jax.tree_util.tree_leaves(blocks)[0].shape[0]
+    (x, cache_k, cache_v), _ = jax.lax.scan(
+        pbody, (x, cache_k, cache_v),
+        (blocks, jnp.arange(n, dtype=jnp.int32)))
+    return x, cache_k, cache_v
+
+
 def decode_over_layers(body, x, blocks, cache_k, cache_v, num_layers,
-                       probe: str = "qkv_w"):
-    """Run ``body(x, get, mm, ck, cv) -> (x, ck, cv)`` over all layers:
-    a ``lax.scan`` over pre-sliced layers normally, or — quantized serving
-    with the stacked s8 kernel available — a layer-indexed ``fori_loop``
-    whose matmuls select the layer in-kernel (scalar prefetch), so no
-    per-layer int8 weight copy is ever materialized in HBM."""
+                       probe: str = "qkv_w", paged: bool = False):
+    """Run ``body(x, get, mm, ck, cv, layer) -> (x, ck, cv)`` over all
+    layers: a ``lax.scan`` over pre-sliced layers normally, or — quantized
+    serving with the stacked s8 kernel available — a layer-indexed
+    ``fori_loop`` whose matmuls select the layer in-kernel (scalar
+    prefetch), so no per-layer int8 weight copy is ever materialized in
+    HBM.
+
+    ``paged`` (the caller passes ``block_tables is not None``): both loop
+    forms carry the whole stacked pool and hand the body the pool plus the
+    layer index (:func:`scan_layers_cached`).  Contiguous caches keep the
+    per-layer slice (``layer`` is None there)."""
     from ..ops import quantization as quant
 
     stack_l = jax.tree_util.tree_leaves(
@@ -474,34 +519,22 @@ def decode_over_layers(body, x, blocks, cache_k, cache_v, num_layers,
             def mm(y, name, dtype):
                 return _qmm_indexed(y, blocks[name], l, dtype)
 
-            # cache leaves may be int8 pool records (dicts of codes +
-            # scales, ops/paged_kv) — index/update every leaf of the layer
-            # slice; plain arrays are single-leaf trees, identical HLO
-            ck = jax.tree_util.tree_map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, l, keepdims=False),
-                ck_all)
-            cv = jax.tree_util.tree_map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, l, keepdims=False),
-                cv_all)
-            x, ck, cv = body(x, get, mm, ck, cv)
-            ck_all = jax.tree_util.tree_map(
-                lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, l, 0),
-                ck_all, ck)
-            cv_all = jax.tree_util.tree_map(
-                lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, l, 0),
-                cv_all, cv)
-            return x, ck_all, cv_all
+            if paged:
+                return body(x, get, mm, ck_all, cv_all, l)
+            ck = jax.lax.dynamic_index_in_dim(ck_all, l, keepdims=False)
+            cv = jax.lax.dynamic_index_in_dim(cv_all, l, keepdims=False)
+            x, ck, cv = body(x, get, mm, ck, cv, None)
+            return (x,
+                    jax.lax.dynamic_update_index_in_dim(ck_all, ck, l, 0),
+                    jax.lax.dynamic_update_index_in_dim(cv_all, cv, l, 0))
 
         return jax.lax.fori_loop(0, num_layers, ibody,
                                  (x, cache_k, cache_v))
 
-    def sbody(x, xs):
-        layer, ck, cv = xs
-        x, ck, cv = body(x, *layer_accessors(layer), ck, cv)
-        return x, (ck, cv)
-
-    x, (ks, vs) = jax.lax.scan(sbody, x, (blocks, cache_k, cache_v))
-    return x, ks, vs
+    return scan_layers_cached(
+        lambda x, layer, ck, cv, l: body(x, *layer_accessors(layer),
+                                         ck, cv, l),
+        x, blocks, cache_k, cache_v, paged)
 
 
 def forward_cached(cfg: GPT2Config, params, input_ids, cache, pos,
@@ -564,10 +597,11 @@ def forward_cached(cfg: GPT2Config, params, input_ids, cache, pos,
         if (block_tables is not None and lengths is not None and t > 1) \
         else None
     x, ks, vs = decode_over_layers(
-        lambda x, get, mm, ck, cv: _block_cached_body(
+        lambda x, get, mm, ck, cv, layer: _block_cached_body(
             cfg, x, get, mm, ck, cv, step_pos, block_tables=block_tables,
-            chunk_valid=chunk_valid),
-        x, params["blocks"], cache["k"], cache["v"], cfg.num_layers)
+            chunk_valid=chunk_valid, layer=layer),
+        x, params["blocks"], cache["k"], cache["v"], cfg.num_layers,
+        paged=block_tables is not None)
     if not all_positions:
         x = _gather_last(x, lengths if not per_row else None)
     with jax.named_scope("head"):

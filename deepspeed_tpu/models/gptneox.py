@@ -221,7 +221,8 @@ def forward_cached(cfg: GPTNeoXConfig, params, input_ids, cache, pos):
     pos = jnp.asarray(pos, jnp.int32)
     x = params["embed_in"][input_ids].astype(params["embed_in"].dtype)
 
-    def body(x, get, mm, ck, cv):
+    def body(x, get, mm, ck, cv, layer):
+        del layer                 # contiguous cache: ck/cv are the slice
         x, (ck, cv) = _block(cfg, x, None, pos=pos, cache=(ck, cv),
                              get=get, mm=mm)
         return x, ck, cv

@@ -243,13 +243,14 @@ def _rope_cached(cfg: LlamaConfig, x, pos):
 
 
 def _block_cached_body(cfg: LlamaConfig, x, get, mm, ck, cv, pos,
-                       mlp=None, block_tables=None, chunk_valid=None):
+                       mlp=None, block_tables=None, chunk_valid=None,
+                       layer=None):
     """Cached-attention block parameterized by weight access (``get(name)``
     small leaf, ``mm(y, name, dtype)`` matmul — shared by the scan and
     layer-indexed quantized decode paths, see gpt2.decode_over_layers).
     ``mlp(y) -> y`` overrides the dense SwiGLU (mixtral's MoE FFN).
-    ``block_tables``/``chunk_valid`` switch ck/cv to the paged-pool layout
-    (contract in gpt2._cached_attention)."""
+    ``block_tables``/``chunk_valid`` switch ck/cv to the whole paged pool,
+    addressed in place at ``layer`` (contract in gpt2._cached_attention)."""
     b, t, d = x.shape
     h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
 
@@ -263,7 +264,7 @@ def _block_cached_body(cfg: LlamaConfig, x, get, mm, ck, cv, pos,
     from .gpt2 import _cached_attention
 
     attn, ck, cv = _cached_attention(q, k, v, ck, cv, pos, block_tables,
-                                     chunk_valid)
+                                     chunk_valid, layer)
     attn = attn.transpose(0, 2, 1, 3).reshape(b, t, h * hd)
     x = x + mm(attn, "o_w", x.dtype)
 
@@ -277,13 +278,15 @@ def _block_cached_body(cfg: LlamaConfig, x, get, mm, ck, cv, pos,
 
 
 def _block_cached(cfg: LlamaConfig, x, layer, ck, cv, pos, mlp_fn=None,
-                  block_tables=None, chunk_valid=None):
+                  block_tables=None, chunk_valid=None, index=None):
+    """``layer`` is the pre-sliced weight dict, ``index`` its position in
+    the stack (paged pools only)."""
     from .gpt2 import layer_accessors
 
     return _block_cached_body(
         cfg, x, *layer_accessors(layer), ck, cv, pos,
         mlp=None if mlp_fn is None else (lambda y: mlp_fn(layer, y)),
-        block_tables=block_tables, chunk_valid=chunk_valid)
+        block_tables=block_tables, chunk_valid=chunk_valid, layer=index)
 
 
 def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
@@ -303,7 +306,8 @@ def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
     ``block_tables`` (optional int32 [B, NBPER]) switches to the block-paged
     cache layout; with T > 1 ``pos`` may be int32 [B] per-row chunk bases
     (the rope offsets follow each row's base — chunked prefill)."""
-    from .gpt2 import _dequant_resident, _gather_last, decode_over_layers
+    from .gpt2 import (_dequant_resident, _gather_last, decode_over_layers,
+                       scan_layers_cached)
 
     params = _dequant_resident(params)
     pos = jnp.asarray(pos, jnp.int32)
@@ -313,6 +317,7 @@ def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
     chunk_valid = jnp.asarray(lengths, jnp.int32) \
         if (block_tables is not None and lengths is not None and t > 1) \
         else None
+    paged = block_tables is not None
     x = params["embed"][input_ids].astype(params["embed"].dtype)
     from ..ops.sp_attention import shard_seq
 
@@ -321,23 +326,20 @@ def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
 
     if mlp_fn is None:
         x, ks, vs = decode_over_layers(
-            lambda x, get, mm, ck, cv: _block_cached_body(
+            lambda x, get, mm, ck, cv, layer: _block_cached_body(
                 cfg, x, get, mm, ck, cv, step_pos,
-                block_tables=block_tables, chunk_valid=chunk_valid),
+                block_tables=block_tables, chunk_valid=chunk_valid,
+                layer=layer),
             x, params["blocks"], cache["k"], cache["v"], cfg.num_layers,
-            probe="q_w")
+            probe="q_w", paged=paged)
     else:
         # mixtral's MoE FFN needs the whole layer dict: scan path only
-        def body(x, xs):
-            layer, ck, cv = xs
-            x, ck, cv = _block_cached(cfg, x, layer, ck, cv, step_pos,
-                                      mlp_fn=mlp_fn,
-                                      block_tables=block_tables,
-                                      chunk_valid=chunk_valid)
-            return x, (ck, cv)
-
-        x, (ks, vs) = jax.lax.scan(body, x, (params["blocks"], cache["k"],
-                                             cache["v"]))
+        x, ks, vs = scan_layers_cached(
+            lambda x, layer, ck, cv, l: _block_cached(
+                cfg, x, layer, ck, cv, step_pos, mlp_fn=mlp_fn,
+                block_tables=block_tables, chunk_valid=chunk_valid,
+                index=l),
+            x, params["blocks"], cache["k"], cache["v"], paged)
     if not all_positions:
         x = _gather_last(x, lengths if not per_row else None)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)

@@ -297,13 +297,13 @@ def init_cache(cfg: OPTConfig, batch_size: int, max_len: int,
 
 
 def _block_cached_body(cfg: OPTConfig, x, get, mm, ck, cv, pos,
-                       block_tables=None, chunk_valid=None):
+                       block_tables=None, chunk_valid=None, layer=None):
     """One decoder layer over a KV cache, parameterized by how per-layer
     weights are fetched: ``get(name)`` returns a small leaf, ``mm(y, name,
     dtype)`` runs ``y @ weight`` — the scan path indexes a pre-sliced layer
     dict, the quantized indexed path selects the layer in-kernel.
-    ``block_tables``/``chunk_valid`` switch ck/cv to the paged-pool layout
-    (contract in gpt2._cached_attention)."""
+    ``block_tables``/``chunk_valid`` switch ck/cv to the whole paged pool,
+    addressed in place at ``layer`` (contract in gpt2._cached_attention)."""
     b, t, d = x.shape
     h, hd = cfg.num_heads, cfg.head_dim
 
@@ -319,7 +319,7 @@ def _block_cached_body(cfg: OPTConfig, x, get, mm, ck, cv, pos,
         k = k.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
         v = v.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
         attn, ck, cv = _cached_attention(q, k, v, ck, cv, pos, block_tables,
-                                         chunk_valid)
+                                         chunk_valid, layer)
         attn = attn.transpose(0, 2, 1, 3).reshape(b, t, d)
         x = res + mm(attn, "o_w", x.dtype) + get("o_b").astype(x.dtype)
         if not cfg.do_layer_norm_before:
@@ -372,10 +372,11 @@ def forward_cached(cfg: OPTConfig, params, input_ids, cache, pos,
     x = shard_seq(x)
 
     x, ks, vs = decode_over_layers(
-        lambda x, get, mm, ck, cv: _block_cached_body(
+        lambda x, get, mm, ck, cv, layer: _block_cached_body(
             cfg, x, get, mm, ck, cv, step_pos, block_tables=block_tables,
-            chunk_valid=chunk_valid),
-        x, params["blocks"], cache["k"], cache["v"], cfg.num_layers)
+            chunk_valid=chunk_valid, layer=layer),
+        x, params["blocks"], cache["k"], cache["v"], cfg.num_layers,
+        paged=block_tables is not None)
     if not all_positions:
         x = _gather_last(x, lengths if not per_row else None)
     return _head(cfg, params, x), {"k": ks, "v": vs}

@@ -1,24 +1,66 @@
 """Device-side ops for the block-paged KV cache (vLLM PagedAttention
 layout, JAX/TPU edition).
 
-Layout contract (per layer slice of the stacked pool):
+Layout contract — the WHOLE stacked pool, addressed in place:
 
- - pool leaf: ``[NB, HKV, block_size, hd]`` — the batch dim of the
-   contiguous layout becomes the physical-block dim and the length dim
-   becomes the in-block offset, so the models' ``init_cache(num_blocks,
-   block_size, dtype)`` hook builds a pool unchanged.
+ - pool leaf: ``[L, NB, HKV, block_size, hd]`` — the models'
+   ``init_cache(num_blocks, block_size, dtype)`` hook builds it unchanged
+   (the batch dim of the contiguous layout becomes the physical-block dim
+   and the length dim the in-block offset).  Every op here takes the whole
+   leaf plus a ``layer`` index and touches ``(layer, physical block, head,
+   offset)`` in place; nothing slices a layer out of the pool, re-stacks it
+   or changes its layout.  The models' layer loops therefore CARRY the pool
+   (``models/gpt2.py:scan_layers_cached``) and a program that donates it
+   gets it back in the same buffer.  A 4-D ``[NB, HKV, bs, hd]`` pool with
+   ``layer=None`` is the same code on a one-layer view (:func:`whole_pool`).
  - block table: ``int32 [B, NBPER]`` — each row maps a sequence's logical
    block index (``position // block_size``) to a physical block.  Entry 0
    is the reserved scratch block (``inference/paged.py``), which doubles as
-   the "unset" marker: reads of unset blocks are masked by position, writes
-   of invalid tokens are routed there explicitly.
+   the "unset" marker: reads of unset blocks are masked by position, and a
+   window that reaches past a row's allocated entries lands there.  One
+   table serves every layer today; the layer index is an OPERAND of every
+   op (and a scalar-prefetch operand of the kernels' index maps), so
+   per-layer-kind tables (ROADMAP 2.8) are a change of index map —
+   ``bt[kind_of[layer], b, i]`` — not of layout.
+
+**Layout** (what "in place" takes on a TPU).  A Mosaic kernel reads its
+operand row-major — ``[L][NB][HKV][...]``, a block's tiles contiguous —
+and tiles the last two dims (16 x 128 for bf16).  XLA:TPU's own layout for
+the hook's ``[..., bs=32, hd=64]`` array is block-id-MINOR (``{1,4,3,2,0}``:
+a 64-wide minor dim would leave half of the 128 lanes empty, so it puts the
+769 blocks there instead, padded to 896), and from there every consumer
+got a layout of its own: the six 100 MB copies per layer of PR 24/25
+(slice -> the scatter's layout -> the kernel's, hd padded to 128 lanes ->
+the re-stack's).  So a serving engine holds its pool LANE-PACKED
+(:func:`pack_pool`): the same bytes viewed as ``[L, NB, HKV, bs/g, g*hd]``
+with ``g = 128 // hd`` (2 for hd=64; 1, i.e. no change, from hd=128 up), a
+block's g consecutive ``bs/g``-token spans side by side in the lanes.  That
+view's minor dim is 128, so XLA's layout for it IS the row-major one, with
+no padding (4.84 GB for the chat cell's 769 blocks, where XLA's layout of
+the unpacked array took 5.64 GB and the kernel's 9.68 GB), the kernels read
+it as it lies (a span is a lane slice of the tile:
+``ops/decode_attention.py:_attend_chunk``), and XLA has no reason left to
+move it.  Two more things keep it so: the write reads, merges and
+scatters back WHOLE blocks (:func:`_write_blocks`) — index dims (layer,
+block), the pool's two major dims, so row-major is the layout that scatter
+wants too, where a scatter of token vectors at ``[layer, phys, :, off]``
+also indexes the in-block offset and has XLA re-lay-out the pool around
+it — and nothing but that write, the gathers and the kernels ever takes the
+pool as an operand.  Every op reads the packing off the shapes (pool minor
+dim over the model's head dim), so a pool exactly as ``init_cache`` built
+it — the benchmark's teacher-forced comparison passes one — goes through
+the same code with ``g = 1`` (and, on a TPU, through whatever copies XLA's
+layout of that array costs: fine for a comparison, not for serving).
+An int8 record packs its codes the same way; its scale table ``[L, NB, HKV,
+bs]`` is small (1/64 of the codes) and keeps the hook's shape.
 
 Speculative-decoding windows lean on two properties of this contract:
 
  - **Scratch routing is the write-side safety net**: a T = K+1 verify
    window may reach positions past a row's allocated table entries (the
    tail of a draft that cannot fit the request's remaining budget) — those
-   writes land in scratch block 0 and are never read back unmasked, so the
+   writes land in scratch block 0 (entry 0) and are never read back
+   unmasked; invalid tokens of a window are simply not written, so the
    verify program keeps one fixed shape for every row regardless of how
    much budget each row has left.
  - **Rollback is free**: rejected draft tokens leave stale KV at positions
@@ -44,8 +86,8 @@ axis (GQA with HKV < tp) simply skip the wrapping — ``head_shards``
 returns 1 and the op runs replicated, bit-identical to tp=1.
 
 **Quantized pool records (int8 KV, PR 7)**: a pool leaf may be a dict
-``{"qp": int8 [NB, HKV, bs, hd], "ps": bf16 [NB, HKV, bs]}`` instead of a
-float array — int8 codes plus a per-block scale table whose rows live and
+``{"qp": int8 [L, NB, HKV, bs, hd], "ps": bf16 [L, NB, HKV, bs]}`` instead
+of a float array — int8 codes plus a per-block scale table whose rows live and
 die with the blocks (one ``[HKV, bs]`` scale row per block per K/V per
 layer; within the row each (head, slot) token vector carries its own
 scale).  The granularity is chosen by two constraints:
@@ -70,9 +112,9 @@ and the serving engine's host-side ledger + ``analysis/invariants.py``
 Rollback of rejected speculative tokens stays free: re-quantizing the
 same deterministic values yields the same codes and scales.
 
-Everything here is pure XLA (scatter / gather), shared by prefill and the
-CPU/correctness decode path; the TPU kernels that walk the block table
-in-kernel live in ``ops/decode_attention.py``
+Everything here is pure XLA (block read-modify-write / gather), shared by
+prefill and the CPU/correctness decode path; the TPU kernels that walk the
+(layer, block table) index in-kernel live in ``ops/decode_attention.py``
 (``paged_decode_attention_pallas`` / ``paged_verify_attention_pallas``)
 and shard through the same context.
 """
@@ -83,6 +125,7 @@ import contextlib
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 # ------------------------------------------------------------- tp context
@@ -259,51 +302,156 @@ def quantize_pool(pool, scale_dtype=None):
     return jax.tree_util.tree_map(one, pool)
 
 
-def _scatter_one(pool, win, phys, off):
-    """Scatter a [B, HKV, T, ...] window into one pool leaf at the [B, T]
-    (physical block, in-block offset) targets — quantizing on write when
-    the leaf is an int8 record.  Advanced indices at dims 0 and 2 around
-    the ':' slice put the [B, T] index shape in front: value layout is
-    [B, T, HKV, ...].  Duplicate targets only ever occur on the scratch
-    block (any write order is fine — scratch is never read unmasked)."""
+def whole_pool(pool, layer):
+    """``(stacked pool, int32 layer index)`` for a pool operand of the
+    paged ops.  With ``layer`` given, ``pool`` already is the stacked
+    ``[L, NB, ...]`` pool.  ``layer=None`` is the one-layer entry point
+    (kernel tests, ad-hoc callers): a ``[NB, ...]`` pool is viewed as the
+    stack ``[1, NB, ...]`` at layer 0 — a reshape, no second code path."""
+    if layer is not None:
+        return pool, jnp.asarray(layer, jnp.int32)
+    return (jax.tree_util.tree_map(lambda a: a[None], pool),
+            jnp.zeros((), jnp.int32))
+
+
+#: lanes of a TPU vector register: the minor dim a pool's blocks are packed to
+LANES = 128
+
+
+def lane_pack(block_size: int, head_dim: int) -> int:
+    """How many ``head_dim``-wide token rows share one 128-lane row of a
+    lane-packed block: ``128 // head_dim`` when that divides both, else 1
+    (head dims of 128 and up are lane-dense as they are)."""
+    hd = int(head_dim)
+    g = LANES // hd if 0 < hd < LANES and LANES % hd == 0 else 1
+    return g if int(block_size) % g == 0 else 1
+
+
+def pack_pool(pool):
+    """The lane-packed view of a pool built by ``init_cache``: every payload
+    leaf ``[L, NB, HKV, bs, hd]`` becomes ``[L, NB, HKV, bs/g, g*hd]``
+    (``g = lane_pack(bs, hd)``; the scale table of an int8 record stays as
+    it is).  Row ``r`` of a packed block holds tokens ``r, r + bs/g, ...``
+    side by side — the block's g consecutive ``bs/g``-token spans, one per
+    ``hd``-wide lane group — so a span is a contiguous lane slice of the
+    block and of its scale row alike.  Same bytes; module docstring
+    ("Layout") says why a serving engine holds its pool this way.  The ops
+    below read the packing off the shapes (:func:`_unpack_block`), so they
+    take either view."""
+    def one(leaf):
+        if is_quantized_pool(leaf):
+            return {"qp": one(leaf["qp"]), "ps": leaf["ps"]}
+        *lead, bs, hd = leaf.shape
+        g = lane_pack(bs, hd)
+        return leaf.reshape(*lead, g, bs // g, hd).swapaxes(-3, -2) \
+            .reshape(*lead, bs // g, g * hd)
+
+    return jax.tree_util.tree_map(one, pool, is_leaf=is_quantized_pool)
+
+
+def _unpack_block(blocks, head_dim: int):
+    """``[..., bs/g, g*hd] -> [..., bs, hd]`` (token order) for blocks read
+    off a pool in either view; the packing ``g`` is read off the shapes."""
+    *lead, rows, width = blocks.shape
+    g = width // head_dim
+    if g == 1:
+        return blocks
+    return blocks.reshape(*lead, rows, g, head_dim).swapaxes(-3, -2) \
+        .reshape(*lead, rows * g, head_dim)
+
+
+def _pack_block(blocks, like):
+    """Inverse of :func:`_unpack_block`: ``[..., bs, hd]`` into the
+    ``[..., R, W]`` view of the stored block ``like``."""
+    *lead, bs, hd = blocks.shape
+    rows, width = like.shape[-2:]
+    g = width // hd
+    if g == 1:
+        return blocks
+    return blocks.reshape(*lead, g, rows, hd).swapaxes(-3, -2) \
+        .reshape(*lead, rows, width)
+
+
+def _write_blocks(leaf, win, layer, phys, start, nvalid):
+    """Write a [B, HKV, T, ...] window into one stacked pool array
+    ``[L, NB, HKV, ...]`` (payload in either view, or a scale table) by
+    whole physical blocks: ``phys`` int32 [B, J] names the J blocks row b's
+    window reaches and ``start`` [B, J] the window token that lands at
+    offset 0 of each (may be negative); tokens outside ``[0, nvalid[b])``
+    keep what the block held.  Three ops: gather the ``[B, J]`` touched
+    blocks at ``[layer, phys]``, merge the rows' tokens in, scatter the
+    blocks back.  The scatter's index dims are the pool's two MAJOR dims
+    (layer, block) and its window a whole block, so the pool's row-major
+    layout is the one it wants and a loop-carried pool is updated in place
+    — where a scatter of token vectors at ``[layer, phys, :, off]`` indexes
+    the in-block offset too and has XLA re-lay-out the whole pool around
+    it.  A real block is written by one row only (shared prefix blocks are
+    full, hence never written); rows meet in the scratch block alone, where
+    any order will do."""
+    t = win.shape[2]
+    tok = win.shape[3:]                       # (hd,) payload, () scales
+    bs = int(np.prod(leaf.shape[3:])) // int(np.prod(tok, dtype=np.int64))
+    ti = start[:, :, None] + jnp.arange(bs, dtype=jnp.int32)     # [B, J, bs]
+    take = (ti >= 0) & (ti < nvalid[:, None, None])
+    # new[b, j, :, o] = win[b, :, ti[b, j, o]]: [B, J, bs, HKV, ...]
+    new = win[jnp.arange(win.shape[0])[:, None, None], :,
+              jnp.clip(ti, 0, t - 1)]
+    new = jnp.moveaxis(new, 2, 3).astype(leaf.dtype)   # [B, J, HKV, bs, ..]
+    old = leaf[layer, phys]                            # [B, J, HKV, R, W]
+    take = take.reshape(take.shape[:2] + (1, bs) + (1,) * len(tok))
+    blk = jnp.where(take, new, _unpack_block(old, tok[0]) if tok else old)
+    if tok:
+        blk = _pack_block(blk, old)
+    return leaf.at[layer, phys].set(blk)
+
+
+def _write_one(pool, win, layer, phys, start, nvalid):
+    """:func:`_write_blocks` for one pool leaf — quantizing on write when
+    the leaf is an int8 record (codes and their scale rows take the same
+    walk)."""
     if not is_quantized_pool(pool):
-        return pool.at[phys, :, off].set(
-            win.transpose(0, 2, 1, 3).astype(pool.dtype))
+        return _write_blocks(pool, win, layer, phys, start, nvalid)
     from . import quantization as quant
 
     codes, scale = quant.quantize_kv(win, pool["ps"].dtype)
-    return {"qp": pool["qp"].at[phys, :, off].set(
-                codes.transpose(0, 2, 1, 3)),
-            "ps": pool["ps"].at[phys, :, off].set(
-                scale.transpose(0, 2, 1))}
+    return {"qp": _write_blocks(pool["qp"], codes, layer, phys, start,
+                                nvalid),
+            "ps": _write_blocks(pool["ps"], scale, layer, phys, start,
+                                nvalid)}
 
 
-def _paged_cache_update(ck, cv, k, v, pos, block_tables, valid=None):
-    """Single-shard scatter body of :func:`paged_cache_update` — also the
-    whole op when the pool is replicated (tp=1 / GQA fallback)."""
+def _paged_cache_update(ck, cv, k, v, pos, block_tables, valid, layer):
+    """Single-shard body of :func:`paged_cache_update` on the stacked
+    pool — also the whole op when the pool is replicated (tp=1 / GQA
+    fallback)."""
     b, hkv, t, hd = k.shape
-    bs = pool_payload(ck).shape[2]
+    bs = int(np.prod(pool_payload(ck).shape[3:])) // hd
     nbper = block_tables.shape[1]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
-    p = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]      # [B, T]
-    ok = (jnp.arange(t, dtype=jnp.int32)[None, :] <
-          jnp.asarray(valid, jnp.int32)[:, None]) if valid is not None \
-        else jnp.ones((b, t), bool)
-    li = p // bs
-    ok = ok & (li >= 0) & (li < nbper)
+    nvalid = jnp.full((b,), t, jnp.int32) if valid is None \
+        else jnp.clip(jnp.asarray(valid, jnp.int32), 0, t)
+    # a T-token window starting anywhere reaches at most this many blocks
+    nj = 1 if t == 1 else (t + bs - 2) // bs + 1
+    li = pos[:, None] // bs + jnp.arange(nj, dtype=jnp.int32)[None, :]
+    ok = (li >= 0) & (li < nbper)                                   # [B, J]
     phys = jnp.take_along_axis(block_tables.astype(jnp.int32),
                                jnp.clip(li, 0, nbper - 1), axis=1)
-    phys = jnp.where(ok, jnp.maximum(phys, 0), 0)                   # [B, T]
-    off = jnp.where(ok, p % bs, 0)                                  # [B, T]
-    ck = _scatter_one(ck, k, phys, off)
-    cv = _scatter_one(cv, v, phys, off)
+    phys = jnp.where(ok, jnp.maximum(phys, 0), 0)
+    # blocks past the table's reach take no token: start past the window
+    start = jnp.where(ok, li * bs - pos[:, None], t)
+    ck = _write_one(ck, k, layer, phys, start, nvalid)
+    cv = _write_one(cv, v, layer, phys, start, nvalid)
     return ck, cv
 
 
-def paged_cache_update(ck, cv, k, v, pos, block_tables, valid=None):
-    """Scatter a window of new keys/values into the paged pool.
+def paged_cache_update(ck, cv, k, v, pos, block_tables, valid=None,
+                       layer=None):
+    """Scatter a window of new keys/values into the paged pool, in place.
 
-    ck/cv:         [NB, HKV, block_size, hd] pool (one layer)
+    ck/cv:         the stacked pool [L, NB, HKV, block_size, hd] with
+                   ``layer`` the (traced) int32 index of the layer written
+                   — or one layer's [NB, HKV, block_size, hd] with
+                   ``layer=None`` (:func:`whole_pool`)
     k/v:           [B, HKV, T, hd] — T new tokens per row
     pos:           int32 scalar or [B] — global position of ``k[:, :, 0]``
                    per row (T == 1 decode: each row's own position; T > 1
@@ -313,69 +461,80 @@ def paged_cache_update(ck, cv, k, v, pos, block_tables, valid=None):
                    real (default all T).  Invalid tokens, and positions
                    past the table's reach, write to scratch block 0.
 
-    Under a configured tp context (module docstring) the scatter runs in
-    ``shard_map``: each chip writes its own head shard of the pool (the
-    k/v window arrives already head-sharded from the column-parallel kv
-    projections); positions/tables replicate.
+    Returns the pool in the shape it came in.  Under a configured tp
+    context (module docstring) the scatter runs in ``shard_map``: each chip
+    writes its own head shard of the pool (the k/v window arrives already
+    head-sharded from the column-parallel kv projections);
+    positions/tables/layer replicate.
     """
+    one_layer = layer is None
+    (ck, layer), (cv, _) = whole_pool(ck, layer), whole_pool(cv, layer)
     b = k.shape[0]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
-    n = head_shards(pool_payload(ck).shape[1], k.shape[1])
+    bt = jnp.asarray(block_tables, jnp.int32)
+    n = head_shards(pool_payload(ck).shape[2], k.shape[1])
+    sharded = _DP_GROUPS > 1 or n > 1
+    if sharded and valid is None:
+        valid = jnp.full((b,), k.shape[2], jnp.int32)
     if _DP_GROUPS > 1:
         # dp_tp serving: rows + physical blocks shard over dp (heads over
         # tp when divisible); each shard scatters into its own pool chunk
         # through localized tables — no cross-shard traffic
-        hp = P(_DP_AXIS, _TP_AXIS) if n > 1 else P(_DP_AXIS)
-        dpsp = P(_DP_AXIS)
+        tp = _TP_AXIS if n > 1 else None
+        ps, hp, dpsp = P(None, _DP_AXIS, tp), P(_DP_AXIS, tp), P(_DP_AXIS)
         gsize = _DP_GSIZE
-        valid = jnp.full((b,), k.shape[2], jnp.int32) if valid is None \
-            else jnp.asarray(valid, jnp.int32)
 
-        def body(ck, cv, k, v, pos, bt, valid):
+        def body(ck, cv, k, v, pos, bt, valid, layer):
             bt = localize_block_tables(bt, gsize)
-            return _paged_cache_update(ck, cv, k, v, pos, bt, valid)
+            return _paged_cache_update(ck, cv, k, v, pos, bt, valid, layer)
 
-        return jax.shard_map(
+        ck, cv = jax.shard_map(
             body, mesh=_DP_MESH,
-            in_specs=(hp, hp, hp, hp, dpsp, dpsp, dpsp),
-            out_specs=(hp, hp), check_vma=False)(
-                ck, cv, k, v, pos,
-                jnp.asarray(block_tables, jnp.int32), valid)
-    if n <= 1:
-        return _paged_cache_update(ck, cv, k, v, pos, block_tables, valid)
-    # P(None, tp) is a valid spec for every record leaf too: qp
-    # [NB, HKV, bs, hd] and ps [NB, HKV, bs] both carry the head dim at
-    # index 1, and shard_map broadcasts a PartitionSpec leaf over the
-    # record's pytree prefix
-    hs = P(None, _TP_AXIS)
-    valid = jnp.full((b,), k.shape[2], jnp.int32) if valid is None \
-        else jnp.asarray(valid, jnp.int32)
-    return head_shard_map(
-        _paged_cache_update, (hs, hs, hs, hs, P(), P(), P()), (hs, hs))(
-            ck, cv, k, v, pos, jnp.asarray(block_tables, jnp.int32), valid)
+            in_specs=(ps, ps, hp, hp, dpsp, dpsp, dpsp, P()),
+            out_specs=(ps, ps), check_vma=False)(
+                ck, cv, k, v, pos, bt, jnp.asarray(valid, jnp.int32), layer)
+    elif n > 1:
+        # P(None, None, tp) is a valid spec for every record leaf too: qp
+        # [L, NB, HKV, bs, hd] and ps [L, NB, HKV, bs] both carry the head
+        # dim at index 2, and shard_map broadcasts a PartitionSpec leaf
+        # over the record's pytree prefix
+        ps, hs = P(None, None, _TP_AXIS), P(None, _TP_AXIS)
+        ck, cv = head_shard_map(
+            _paged_cache_update,
+            (ps, ps, hs, hs, P(), P(), P(), P()), (ps, ps))(
+                ck, cv, k, v, pos, bt, jnp.asarray(valid, jnp.int32), layer)
+    else:
+        ck, cv = _paged_cache_update(ck, cv, k, v, pos, bt, valid, layer)
+    if one_layer:
+        ck, cv = jax.tree_util.tree_map(lambda a: a[0], (ck, cv))
+    return ck, cv
 
 
-def _paged_gather(pool_leaf, block_tables, out_dtype=None):
-    """Single-shard gather body of :func:`paged_gather` — called directly
-    by the in-``shard_map`` attention bodies (``ops/decode_attention.py``)
-    so sharded callers never re-enter the wrapper.  Quantized records
-    gather codes + scales and dequantize (f32 expand, one cast to
+def _paged_gather(pool_leaf, block_tables, layer, head_dim,
+                  out_dtype=None):
+    """Single-shard gather body of :func:`paged_gather` on the stacked
+    pool (either view; ``head_dim`` tells them apart):
+    ``pool[layer, block_tables]`` — called directly by the
+    in-``shard_map`` attention bodies (``ops/decode_attention.py``) so
+    sharded callers never re-enter the wrapper.  Only the rows' own blocks
+    are read; no layer slice of the pool is materialized.  Quantized
+    records gather codes + scales and dequantize (f32 expand, one cast to
     ``out_dtype`` — pass the query/compute dtype so bf16 models keep a
     bf16 residual stream, exactly like a float pool of that dtype); HBM
     moves int8 + scales, the expansion happens on-chip.  ``out_dtype``
     never touches a float pool (bit-identical reads)."""
+    bt = jnp.maximum(block_tables, 0)
+    b, nbper = bt.shape
     if is_quantized_pool(pool_leaf):
         from . import quantization as quant
 
-        codes = _paged_gather(pool_leaf["qp"], block_tables)  # [B,HKV,S,hd]
-        b, nbper = block_tables.shape
-        hkv, bs = pool_leaf["ps"].shape[1], pool_leaf["ps"].shape[2]
-        s = pool_leaf["ps"][jnp.maximum(block_tables, 0)]  # [B,NBPER,HKV,bs]
+        codes = _paged_gather(pool_leaf["qp"], bt, layer, head_dim)
+        s = pool_leaf["ps"][layer, bt]                  # [B,NBPER,HKV,bs]
+        hkv, bs = s.shape[2], s.shape[3]
         s = s.transpose(0, 2, 1, 3).reshape(b, hkv, nbper * bs)
         return quant.dequantize_kv(codes, s, out_dtype or jnp.float32)
-    nb, hkv, bs, hd = pool_leaf.shape
-    b, nbper = block_tables.shape
-    g = pool_leaf[jnp.maximum(block_tables, 0)]     # [B, NBPER, HKV, bs, hd]
+    g = _unpack_block(pool_leaf[layer, bt], head_dim)  # [B,NBPER,HKV,bs,hd]
+    _, _, hkv, bs, hd = g.shape
     return g.transpose(0, 2, 1, 3, 4).reshape(b, hkv, nbper * bs, hd)
 
 
@@ -434,9 +593,18 @@ def paged_block_scatter(pool, staged, ids):
     leaves = jax.tree_util.tree_leaves(pool)
     n = head_shards(*[l.shape[2] for l in leaves])
 
+    def put(leaf, staged_leaf, i):
+        # one in-place dynamic_update_slice per staged block column:
+        # indifferent to the pool's layout, where a scatter over the
+        # block dim alone (layers as window) could have XLA copy the pool
+        for m in range(staged_leaf.shape[1]):
+            leaf = jax.lax.dynamic_update_slice(
+                leaf, staged_leaf[:, m:m + 1].astype(leaf.dtype),
+                (0, i[m]) + (0,) * (leaf.ndim - 2))
+        return leaf
+
     def scatter(p, s, i):
-        return jax.tree_util.tree_map(
-            lambda pl, sl: pl.at[:, i].set(sl.astype(pl.dtype)), p, s)
+        return jax.tree_util.tree_map(lambda pl, sl: put(pl, sl, i), p, s)
 
     if n <= 1:
         return scatter(pool, staged, ids)
@@ -444,21 +612,30 @@ def paged_block_scatter(pool, staged, ids):
     return head_shard_map(scatter, (hs, hs, P()), hs)(pool, staged, ids)
 
 
-def paged_gather(pool_leaf, block_tables, out_dtype=None):
-    """Materialize each row's logical cache view from the pool:
-    ``[NB, HKV, bs, hd]`` through ``int32 [B, NBPER]`` tables ->
-    ``[B, HKV, NBPER*bs, hd]`` (int8 records dequantize — to ``out_dtype``
-    when given, f32 otherwise; float pools ignore ``out_dtype``).  Unset
-    (scratch) entries gather garbage that sits past every row's valid
-    length — callers mask by position.  Under a configured tp context each
-    chip gathers only its own head shard (output sharded
-    ``[B, HKV/tp, S, hd]`` per chip)."""
+def paged_gather(pool_leaf, block_tables, out_dtype=None, layer=None,
+                 head_dim=None):
+    """Materialize each row's logical cache view from the pool: the
+    stacked ``[L, NB, HKV, bs, hd]`` pool at ``layer`` (or one layer's
+    ``[NB, HKV, bs, hd]`` with ``layer=None``) through ``int32 [B, NBPER]``
+    tables -> ``[B, HKV, NBPER*bs, hd]`` (int8 records dequantize — to
+    ``out_dtype`` when given, f32 otherwise; float pools ignore
+    ``out_dtype``).  ``head_dim`` (the model's) is needed to read a
+    lane-packed pool (:func:`pack_pool`); without it the pool is taken as
+    ``init_cache`` built it.  Unset (scratch) entries gather garbage that
+    sits past every row's valid length — callers mask by position.  Under
+    a configured tp context each chip gathers only its own head shard
+    (output sharded ``[B, HKV/tp, S, hd]`` per chip)."""
     import functools
 
-    n = head_shards(pool_payload(pool_leaf).shape[1])
+    pool_leaf, layer = whole_pool(pool_leaf, layer)
+    if head_dim is None:
+        head_dim = pool_payload(pool_leaf).shape[-1]
+    bt = jnp.asarray(block_tables, jnp.int32)
+    n = head_shards(pool_payload(pool_leaf).shape[2])
+    gather = functools.partial(_paged_gather, head_dim=head_dim,
+                               out_dtype=out_dtype)
     if n <= 1:
-        return _paged_gather(pool_leaf, block_tables, out_dtype)
-    hs = P(None, _TP_AXIS)
+        return gather(pool_leaf, bt, layer)
     return head_shard_map(
-        functools.partial(_paged_gather, out_dtype=out_dtype),
-        (hs, P()), hs)(pool_leaf, jnp.asarray(block_tables, jnp.int32))
+        gather, (P(None, None, _TP_AXIS), P(), P()), P(None, _TP_AXIS))(
+            pool_leaf, bt, layer)

@@ -110,12 +110,14 @@ def shard_seq(x):
 
 
 def sp_prefill_attention(q, k_pool, v_pool, block_tables, q_pos, *,
-                         sm_scale: Optional[float] = None):
+                         sm_scale: Optional[float] = None, layer=None):
     """Ulysses sequence-parallel paged prefill attention.
 
     q:            [B, H, T, D] — a T-token prefill chunk (T % sp == 0)
-    k/v_pool:     [NB, HKV, block_size, D] shared paged pool (optionally
-                  tp-head-sharded; sp composes with tp in ONE shard_map)
+    k/v_pool:     [L, NB, HKV, block_size, D] stacked paged pool read at
+                  ``layer`` (one layer's pool with ``layer=None``;
+                  optionally tp-head-sharded; sp composes with tp in ONE
+                  shard_map)
     block_tables: int32 [B, NBPER]
     q_pos:        scalar or int32 [B] — per-row chunk base positions
 
@@ -128,8 +130,10 @@ def sp_prefill_attention(q, k_pool, v_pool, block_tables, q_pos, *,
     from .decode_attention import decode_attention_reference
 
     mesh, ax = _SP_MESH, _SP_AXIS
+    (k_pool, layer), (v_pool, _) = (paged_kv.whole_pool(k_pool, layer),
+                                    paged_kv.whole_pool(v_pool, layer))
     b, h, t, d = q.shape
-    hkv = pool_payload(k_pool).shape[1]
+    hkv = pool_payload(k_pool).shape[2]
     sp = sp_shards(h, hkv, t)
     if sp <= 1:
         raise ValueError("sp_prefill_attention called without a dividing "
@@ -140,14 +144,14 @@ def sp_prefill_attention(q, k_pool, v_pool, block_tables, q_pos, *,
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     tp_ax = tp_axis() if tp > 1 else None
     qs = P(None, tp_ax, ax)        # [B, H, T, D]: heads over tp, T over sp
-    ps = P(None, tp_ax)            # pool leaves: heads over tp only
+    ps = P(None, None, tp_ax)      # pool leaves: heads over tp only
 
-    def body(q, kp, vp, bt, pos):
+    def body(q, kp, vp, bt, pos, layer):
         # q arrives [B, H/tp, T/sp, D]; heads -> sequence
         q = jax.lax.all_to_all(q, ax, split_axis=1, concat_axis=2,
                                tiled=True)                # [B, hq_loc, T, D]
-        k = _paged_gather(kp, bt, out_dtype=q.dtype)      # [B, HKV/tp, S, D]
-        v = _paged_gather(vp, bt, out_dtype=q.dtype)
+        k = _paged_gather(kp, bt, layer, d, out_dtype=q.dtype)
+        v = _paged_gather(vp, bt, layer, d, out_dtype=q.dtype)  # [B,HKV/tp,S,D]
         if rep > 1:
             k = jnp.repeat(k, rep, axis=1)                # [B, H/tp, S, D]
             v = jnp.repeat(v, rep, axis=1)
@@ -162,9 +166,10 @@ def sp_prefill_attention(q, k_pool, v_pool, block_tables, q_pos, *,
                                   tiled=True)
 
     pos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1), (b,))
-    return jax.shard_map(body, mesh=mesh, in_specs=(qs, ps, ps, P(), P()),
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(qs, ps, ps, P(), P(), P()),
                          out_specs=qs, check_vma=False)(
-        q, k_pool, v_pool, jnp.asarray(block_tables, jnp.int32), pos)
+        q, k_pool, v_pool, jnp.asarray(block_tables, jnp.int32), pos, layer)
 
 
 def alltoall_bytes(n_layers: int, rows: int, width: int, heads: int,

@@ -533,3 +533,73 @@ def test_quantized_paged_kernel_sharded_matches_reference(h, hkv, tp):
             q, kp, vp, jnp.asarray(bt), lengths)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# ------------------------------------------- stacked pool, addressed in place
+def _stacked_pool(rng, layers, b, hkv, s, d, bs, kv8):
+    """An L-layer pool whose every layer holds DIFFERENT contiguous caches
+    behind one shared random block table, filled through the write path at
+    each layer index (int8 records quantize on write):
+    ``(k_pool, v_pool, bt)``."""
+    from deepspeed_tpu.ops import paged_kv
+
+    nbper = s // bs
+    nb = 1 + 2 * b * nbper
+    bt = jnp.asarray(rng.permutation(np.arange(1, nb))[:b * nbper]
+                     .reshape(b, nbper).astype(np.int32))
+    kp = jnp.zeros((layers, nb, hkv, bs, d), jnp.float32)
+    if kv8:
+        kp = paged_kv.quantize_pool(kp)
+    vp = kp
+    for layer in range(layers):
+        kc = rng.standard_normal((b, hkv, s, d)).astype(np.float32)
+        vc = rng.standard_normal((b, hkv, s, d)).astype(np.float32)
+        kp, vp = paged_kv.paged_cache_update(
+            kp, vp, jnp.asarray(kc), jnp.asarray(vc),
+            jnp.zeros(b, jnp.int32), bt, layer=layer)
+    return kp, vp, bt
+
+
+@pytest.mark.parametrize("tp", [1, 2])
+@pytest.mark.parametrize("kv8", [False, True], ids=["float", "kv8"])
+@pytest.mark.parametrize("t", [1, 4, 128])
+def test_stacked_pool_attention_reads_its_layer(t, kv8, tp):
+    """ISSUE 26: the paged reads address the whole [L, NB, HKV, bs, D] pool
+    at a (non-zero) layer index — reference and kernels agree with the same
+    read of that layer's pool alone (the one-layer entry point, itself
+    pinned against the dense path above), for a decode token (T=1), a
+    verify window (T=4) and a prefill chunk (T=128: reference only), float
+    and int8 pools, whole and head-sharded over tp=2."""
+    rng = np.random.default_rng(40 + t)
+    layers, b, h, hkv, s, d, bs = 3, 3, 4, 2, 256, 32, 32
+    kp, vp, bt = _stacked_pool(rng, layers, b, hkv, s, d, bs, kv8)
+    q = jnp.asarray(rng.standard_normal((b, h, t, d)), jnp.float32)
+    pos = jnp.asarray([0, 37, s - t], jnp.int32)
+    one = lambda p, l: jax.tree_util.tree_map(lambda a: a[l], p)  # noqa: E731
+    import contextlib
+
+    from deepspeed_tpu.ops import paged_kv
+
+    ctx = paged_kv.tp_context(_tp_mesh(tp)) if tp > 1 \
+        else contextlib.nullcontext()
+    with ctx:
+        for layer in (1, 2):
+            want = paged_decode_attention_reference(
+                q, one(kp, layer), one(vp, layer), bt, pos)
+            ref = jax.jit(lambda q, kp, vp, l: paged_decode_attention_reference(
+                q, kp, vp, bt, pos, layer=l))(q, kp, vp, jnp.int32(layer))
+            np.testing.assert_array_equal(np.asarray(ref), np.asarray(want))
+            kernel = {1: paged_decode_attention_pallas,
+                      4: paged_verify_attention_pallas}.get(t)
+            if kernel is None:
+                continue
+            got = jax.jit(lambda q, kp, vp, l: kernel(
+                q, kp, vp, bt, pos, interpret=True, layer=l))(
+                    q, kp, vp, jnp.int32(layer))
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=2e-5, atol=2e-5)
+        # layers differ, so a read of the wrong layer cannot pass
+        other = paged_decode_attention_reference(q, one(kp, 0), one(vp, 0),
+                                                 bt, pos)
+        assert not np.allclose(np.asarray(other), np.asarray(want),
+                               atol=1e-3)

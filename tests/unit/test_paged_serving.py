@@ -16,6 +16,7 @@ The Pallas paged-decode kernel's interpret-mode twin lives in
 bench lane is ``test_serving_bench.py`` (slow).
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -194,6 +195,105 @@ def test_paged_gather_update_attention_match_contiguous():
     np.testing.assert_array_equal(got, want)
 
 
+# ------------------------------------------- stacked pool, addressed in place
+def _tp_mesh(n):
+    from jax.sharding import Mesh
+
+    devs = np.array(jax.devices()[:n]).reshape(1, 1, 1, 1, n)
+    return Mesh(devs, ("pp", "dp", "ep", "sp", "tp"))
+
+
+def _random_pool(rng, layers, nb, hkv, bs, d, kv8):
+    """A stacked pool with every byte random (so an untouched byte that
+    changed cannot hide behind a zero)."""
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops import paged_kv
+
+    def leaf():
+        if not kv8:
+            return jnp.asarray(rng.standard_normal((layers, nb, hkv, bs, d)),
+                               jnp.float32)
+        return {"qp": jnp.asarray(rng.integers(
+                    -127, 128, (layers, nb, hkv, bs, d)), jnp.int8),
+                "ps": jnp.asarray(rng.uniform(
+                    0.01, 0.1, (layers, nb, hkv, bs)), paged_kv.SCALE_DTYPE)}
+
+    return leaf(), leaf()
+
+
+@pytest.mark.parametrize("tp", [1, 2])
+@pytest.mark.parametrize("kv8", [False, True], ids=["float", "kv8"])
+@pytest.mark.parametrize("t", [1, 4, 128])
+def test_stacked_pool_write_and_read_in_place(t, kv8, tp):
+    """ISSUE 26: ``paged_cache_update`` writes a decode token (T=1), a
+    verify window (T=4) or a prefill chunk (T=128) into the WHOLE stacked
+    pool at ``[layer, phys, :, off]`` — every other layer, and every block
+    outside the rows' tables (scratch included: all tokens are valid), stays
+    bit-identical; inside the tables only the written offsets change; and
+    the read at that layer returns what was written (``paged_gather``) and
+    attends like the same read of that layer's pool alone."""
+    import contextlib
+
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops import paged_kv
+    from deepspeed_tpu.ops.decode_attention import \
+        paged_decode_attention_reference
+
+    rng = np.random.default_rng(7 + t)
+    layers, b, h, hkv, d, bs, nbper = 3, 3, 4, 2, 16, 16, 12
+    nb = 1 + 2 * b * nbper
+    bt = rng.permutation(np.arange(1, nb))[:b * nbper] \
+        .reshape(b, nbper).astype(np.int32)
+    kp, vp = _random_pool(rng, layers, nb, hkv, bs, d, kv8)
+    kw = jnp.asarray(rng.standard_normal((b, hkv, t, d)), jnp.float32)
+    vw = jnp.asarray(rng.standard_normal((b, hkv, t, d)), jnp.float32)
+    pos = np.array([0, 21, nbper * bs - t], np.int32)   # 21: mid-block
+    layer = 1
+
+    ctx = paged_kv.tp_context(_tp_mesh(tp)) if tp > 1 \
+        else contextlib.nullcontext()
+    with ctx:
+        write = jax.jit(lambda kp, vp, l: paged_kv.paged_cache_update(
+            kp, vp, kw, vw, jnp.asarray(pos), jnp.asarray(bt), layer=l))
+        kp2, vp2 = write(kp, vp, jnp.int32(layer))
+        got_k = paged_kv.paged_gather(kp2, jnp.asarray(bt), layer=layer,
+                                      out_dtype=jnp.float32)
+        q = jnp.asarray(rng.standard_normal((b, h, t, d)), jnp.float32)
+        one = lambda p: jax.tree_util.tree_map(   # noqa: E731
+            lambda a: a[layer], p)
+        attn = paged_decode_attention_reference(
+            q, kp2, vp2, jnp.asarray(bt), jnp.asarray(pos), layer=layer)
+        attn_one = paged_decode_attention_reference(
+            q, one(kp2), one(vp2), jnp.asarray(bt), jnp.asarray(pos))
+    np.testing.assert_array_equal(np.asarray(attn), np.asarray(attn_one))
+
+    # what must not have moved, leaf by leaf (codes and scale rows alike)
+    written = np.zeros((nb, bs), bool)
+    for row in range(b):
+        for i in range(t):
+            p = pos[row] + i
+            written[bt[row, p // bs], p % bs] = True
+    for before, after in zip(jax.tree_util.tree_leaves((kp, vp)),
+                             jax.tree_util.tree_leaves((kp2, vp2))):
+        before, after = np.asarray(before), np.asarray(after)
+        for other in (0, 2):
+            np.testing.assert_array_equal(after[other], before[other])
+        keep = ~written                         # [NB, bs] -> [NB, HKV, bs]
+        keep = np.broadcast_to(keep[:, None, :], before.shape[1:4])
+        np.testing.assert_array_equal(after[layer][keep],
+                                      before[layer][keep])
+        assert (after[layer][~keep] != before[layer][~keep]).any()
+
+    # and the written tokens read back (int8: to its rounding)
+    got_k = np.asarray(got_k)
+    for row in range(b):
+        np.testing.assert_allclose(
+            got_k[row, :, pos[row]:pos[row] + t], np.asarray(kw)[row],
+            atol=0.05 if kv8 else 0, rtol=0)
+
+
 # --------------------------------------------------- chunked-prefill scheduler
 @pytest.fixture(scope="module")
 def tiny_engine():
@@ -298,6 +398,45 @@ def test_chunked_serving_parity_other_families(family):
                                max_new_tokens=r.max_new_tokens)[0]
         np.testing.assert_array_equal(res[r.uid], want,
                                       err_msg=f"uid {r.uid}")
+
+
+@pytest.mark.parametrize("family,kv", [("opt", None), ("bloom", None),
+                                       ("opt", "tp2")])
+def test_paged_greedy_equals_contiguous_generate(family, kv):
+    """ISSUE 26: a greedy sequence served through the paged engine (whole
+    pool carried through a 3-layer loop, written and read at each layer
+    index) equals the same model's CONTIGUOUS-cache ``generate`` token for
+    token — opt through the shared cached attention, bloom through its
+    ALiBi gather path, and opt again with the pool head-sharded over
+    tp=2."""
+    import dataclasses
+
+    deepspeed_tpu.comm.reset_topology()
+    if family == "opt":
+        from deepspeed_tpu.models import opt as m
+
+        cfg = dataclasses.replace(m.OPTConfig.tiny(), num_layers=3)
+    else:
+        from deepspeed_tpu.models import bloom as m
+
+        cfg = dataclasses.replace(m.BloomConfig.tiny(), num_layers=3)
+    tp = 2 if kv == "tp2" else 1
+    engine = deepspeed_tpu.init_inference(
+        m.build(cfg), config={"dtype": "fp32",
+                              "tensor_parallel": {"tp_size": tp}})
+    srv = ServingEngine(engine, slots=3, max_seq_len=64, block_size=8,
+                        prefill_chunk=16, prefill_batch=2)
+    assert srv.kv_sharded == (tp > 1)
+    assert srv._cache["k"].shape[0] == 3          # the stacked pool
+    reqs = _shared_prefix_trace(cfg, 4, prefix_len=10, seed=3, tail=(3, 8),
+                                max_new=(3, 9))
+    res = srv.serve(reqs)
+    for r in reqs:
+        want = engine.generate(r.prompt[None, :],
+                               max_new_tokens=r.max_new_tokens)[0]
+        np.testing.assert_array_equal(res[r.uid], want,
+                                      err_msg=f"uid {r.uid}")
+    deepspeed_tpu.comm.reset_topology()
 
 
 def test_chunked_compile_count_is_two_programs(tiny_engine):

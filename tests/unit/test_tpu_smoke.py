@@ -48,7 +48,8 @@ def test_phases_run_at_tiny_widths_on_cpu(smoke):
     ok, report = smoke.run_phases(_tiny(smoke))
     assert ok, report["phases"]
     assert list(report["phases"]) == ["device", "kernels", "serve",
-                                      "serve-q", "train"]
+                                      "serve-q", "serving-memory", "train"]
+    assert "serving_memory" not in report       # a TPU-only check
     assert report["device"]["platform"] == "cpu"
     # interpreted kernels: no Mosaic call may be claimed off the chip
     assert report["serve_bf16"]["mosaic_calls"] == 0
