@@ -28,7 +28,7 @@ from typing import Any, Dict, List
 
 import numpy as np
 
-from chipbench import costs, reference, traffic
+from chipbench import costs, traffic
 
 KIND = "serve_closed"
 
@@ -97,9 +97,9 @@ def paged_logits(srv, tokens: np.ndarray, n_decode: int) -> np.ndarray:
     return np.stack(rows, axis=1)
 
 
-def check_logits(job, srv, family: str, heads: int) -> Dict[str, Any]:
-    """Engine vs reference on ``slots`` seeded sequences of two prefill
-    chunks plus ``SCORE_DECODE_STEPS`` decode steps."""
+def check_logits(job, srv) -> Dict[str, Any]:
+    """Engine vs the family's plain reference on ``slots`` seeded sequences
+    of two prefill chunks plus ``SCORE_DECODE_STEPS`` decode steps."""
     vocab = costs.arch(job.config)["vocab"]
     chunk = srv.prefill_chunk
     s = 2 * chunk + SCORE_DECODE_STEPS
@@ -110,8 +110,8 @@ def check_logits(job, srv, family: str, heads: int) -> Dict[str, Any]:
     at = [min(base + chunk, n_prefill) - 1
           for base in range(0, n_prefill, chunk)]
     at += list(range(n_prefill, s))
-    want = np.asarray(reference.logits(family, srv.engine.params, tokens,
-                                       heads, at=at), np.float32)
+    want = np.asarray(job.family.logits(job.config, srv.engine.params,
+                                        tokens, at=at), np.float32)
     rmse = float(np.sqrt(np.mean((got - want) ** 2)))
     rel = rmse / float(np.std(want))
     tol = LOGIT_REL_RMSE[job.config["dtype"]]
@@ -146,8 +146,7 @@ def run(job) -> Dict[str, Any]:
             f"{clients_n} callers over {sizing['slots']} slots: a closed "
             "loop with more callers than slots queues at admission, which "
             "this driver does not stamp")
-    model, heads = job.family.build(job.config, job.sizing.get("model"))
-    family = job.config["family"]
+    model = job.family.build(job.config, job.sizing.get("model"))
     dtype = {"bf16": jnp.bfloat16, "fp32": jnp.float32}[job.config["dtype"]]
 
     with job.spans("cb.setup.weights"):
@@ -163,7 +162,7 @@ def run(job) -> Dict[str, Any]:
         del params
         jax.block_until_ready((srv.engine.params, srv._cache))
     with job.spans("cb.setup.check_logits"):
-        check = check_logits(job, srv, family, heads)
+        check = check_logits(job, srv)
     job.note(f"teacher-forced logits vs float32 reference: relative RMSE "
              f"{check['logit_rel_rmse']:.5f} (tolerance "
              f"{check['tolerance']}) over {check['positions']} positions")

@@ -22,7 +22,7 @@ from typing import Any, Dict, List
 
 import numpy as np
 
-from chipbench import costs, reference, traffic
+from chipbench import costs, traffic
 
 KIND = "train_steps"
 
@@ -44,8 +44,7 @@ def run(job) -> Dict[str, Any]:
 
     mix = job.traffic
     seq = int(mix["seq_len"])
-    model, heads = job.family.build(job.config, job.sizing.get("model"))
-    family = job.config["family"]
+    model = job.family.build(job.config, job.sizing.get("model"))
     vocab = costs.arch(job.config)["vocab"]
     n_dev = len(jax.devices())
     ds = dict(job.sizing["ds_config"])
@@ -71,8 +70,8 @@ def run(job) -> Dict[str, Any]:
     two = rng.integers(0, vocab, (2, seq + 1), dtype=np.int32)
     tiled = {"input_ids": np.tile(two, (math.ceil(rows / 2), 1))[:rows]}
     with job.spans("cb.setup.reference"):
-        want = float(reference.next_token_loss(
-            family, engine.state["params"], two, heads))
+        want = float(job.family.next_token_loss(
+            job.config, engine.state["params"], two))
     losses = []
     with job.spans("cb.setup.warm_steps"):
         for _ in range(WARM_STEPS):

@@ -1,14 +1,19 @@
 """``family: opt`` — a ``chipbench/configs`` file to the program's
-``models/opt.py`` configuration.  ``overrides`` are the cell's ``model``
-settings (kernel and remat choices), applied as attributes."""
+``models/opt.py`` configuration, its sizes and parameter count, and its
+plain reference (``chipbench/reference.py``, the ``opt`` row).
+``overrides`` are the cell's ``model`` settings (kernel and remat choices),
+applied as attributes."""
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Sequence
+
+from chipbench import reference
+from chipbench.families import _preln
 
 
 def build(config: Dict[str, Any], overrides: Optional[Dict[str, Any]] = None):
-    """-> (ModelSpec, number of attention heads)"""
+    """-> ModelSpec"""
     from deepspeed_tpu.models import opt
 
     if not config["do_layer_norm_before"]:
@@ -29,4 +34,29 @@ def build(config: Dict[str, Any], overrides: Optional[Dict[str, Any]] = None):
         if not hasattr(cfg, key):
             raise ValueError(f"OPTConfig has no field {key!r}")
         setattr(cfg, key, value)
-    return opt.build(cfg), cfg.num_heads
+    return opt.build(cfg)
+
+
+def arch(config: Dict[str, Any]) -> Dict[str, int]:
+    d, heads = config["hidden_size"], config["num_attention_heads"]
+    return {"layers": config["num_hidden_layers"], "d": d,
+            "heads": heads, "kv_heads": heads, "head_dim": d // heads,
+            "ffn": config["ffn_dim"], "vocab": config["vocab_size"],
+            "positions": config["max_position_embeddings"],
+            # HF OPTLearnedPositionalEmbedding carries 2 extra rows
+            "position_rows": config["max_position_embeddings"] + 2}
+
+
+def num_params(config: Dict[str, Any]) -> int:
+    return _preln.num_params(arch(config))
+
+
+def logits(config: Dict[str, Any], params: Any, tokens,
+           at: Optional[Sequence[int]] = None):
+    return reference.logits("opt", params, tokens,
+                            config["num_attention_heads"], at=at)
+
+
+def next_token_loss(config: Dict[str, Any], params: Any, tokens):
+    return reference.next_token_loss("opt", params, tokens,
+                                     config["num_attention_heads"])

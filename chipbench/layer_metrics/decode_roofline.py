@@ -16,6 +16,6 @@ def read(ctx):
     if not step_s or not ctx["peaks"]:
         return None
     floor_s = costs.decode_bytes_per_step(
-        ctx["config"], ctx["counters"]["mean_valid_kv_tokens"]) \
-        / ctx["peaks"]["hbm_bytes_per_s"]
+        ctx["config"], ctx["counters"]["mean_valid_kv_tokens"],
+        ctx["counters"]) / ctx["peaks"]["hbm_bytes_per_s"]
     return 100.0 * floor_s / step_s

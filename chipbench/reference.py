@@ -13,7 +13,9 @@ Both: LayerNorm eps 1e-5, fused ``qkv`` projection split q|k|v, causal
 softmax attention scaled by 1/sqrt(head_dim), a final LayerNorm and a head
 tied to the token embedding.
 
-It reads the PROGRAM's parameter pytree (so the same seeded weights feed
+It is reached through ``families/opt.py`` and ``families/gpt2.py`` (the
+table below IS those two families' reference; another family brings its own
+``reference_<x>.py``).  It reads the PROGRAM's parameter pytree (so the same seeded weights feed
 both sides) but shares no code with it.  Weights stay in the dtype they are
 served in and are upcast one layer at a time inside the scan, so a 1.3 B
 model's float32 copy (5.3 GB) never exists beside the engine's pool.

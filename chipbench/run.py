@@ -16,7 +16,8 @@ Everything that belongs to one cell is data, found by the names in
     traffic/<traffic>.json      the traffic mix; its ``kind`` names a driver
     workloads/<cell>.json       the engine's sizing for this cell
     drivers/<kind>.py           one traffic driver per kind
-    families/<family>.py        a configuration file -> the program's model
+    families/<family>.py        all that knows a family: the program's model,
+                                its sizes and counts, its plain reference
     layer_metrics/*.py          one small reader per per-layer metric
 
 Without a TPU (or with fewer chips than the cell asks for) it exits with a
@@ -112,6 +113,7 @@ class Job:
     """What a driver is handed."""
 
     def __init__(self, args, spec: Dict[str, Any]):
+        from chipbench import families
         from chipbench.spans import Spans, TraceWindow
 
         self.cell = spec["cell"]
@@ -124,8 +126,7 @@ class Job:
         self.spans = Spans()
         self.tracer = TraceWindow(bool(args.trace), self.seconds,
                                   self.rehearse, args.keep_trace)
-        self.family = importlib.import_module(
-            "chipbench.families." + self.config["family"])
+        self.family = families.load(self.config)
         self.setup_s: Optional[float] = None
         self._compiles = [0]
         self.notes: List[str] = []

@@ -250,8 +250,9 @@ def reduce(trace: Dict[str, Any], top: int = 10) -> Dict[str, Any]:
     ``programs``       module -> durations of its WHOLE executions inside the
                        window (first device; one cut by the trace's start
                        or stop is left out)
-    ``custom_call_s``  module -> seconds in Pallas (Mosaic) kernels (first
-                       device)
+    ``custom_call_s``  ``<module>:mosaic:<kernel>`` (the key ``device_ops``
+                       gives a Pallas kernel) -> seconds in that kernel
+                       inside the module's whole executions (first device)
     ``collective_s`` / ``collective_exposed_s``  time in collective
                        operations, and the part of it during which no
                        other operation ran on that device (device average)
@@ -331,11 +332,16 @@ def reduce(trace: Dict[str, Any], top: int = 10) -> Dict[str, Any]:
             if max(lo, t_first) < ev[1] and ev[1] + ev[2] < min(hi, t_last)]
     for s, e, name in mods:
         programs.setdefault(name, []).append((e - s) * 1e-9)
-    cc = sorted((ev[1], ev[1] + ev[2]) for ev in _line(first, OPS_LINE)
-                if ev[3].get("mosaic"))
+    cc = sorted((ev[1], ev[1] + ev[2], base_name(ev[0]))
+                for ev in _line(first, OPS_LINE) if ev[3].get("mosaic"))
     for s, e, name in mods:
-        custom[name] = custom.get(name, 0.0) + sum(
-            (ce - cs) for cs, ce in cc if cs >= s and ce <= e) * 1e-9
+        inside: Dict[str, float] = {}
+        for cs, ce, kernel in cc:
+            if cs >= s and ce <= e:
+                inside[kernel] = inside.get(kernel, 0.0) + (ce - cs)
+        for kernel, ns in inside.items():
+            key = f"{name}:mosaic:{kernel}"
+            custom[key] = custom.get(key, 0.0) + ns * 1e-9
 
     n = len(devices)
     ranked = sorted(op_sums.items(), key=lambda kv: -kv[1])

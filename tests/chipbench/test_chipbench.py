@@ -10,6 +10,7 @@ leak into the other tests of this pytest worker.
 
 from __future__ import annotations
 
+import hashlib
 import json
 import os
 import re
@@ -17,6 +18,7 @@ import shutil
 import statistics
 import subprocess
 import sys
+import types
 
 import numpy as np
 import pytest
@@ -27,7 +29,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from chipbench import costs, reference, traffic, trace_reduce  # noqa: E402
+from chipbench import costs, families, traffic, trace_reduce  # noqa: E402
 from chipbench import run as cb_run  # noqa: E402
 
 BENCH = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
@@ -154,6 +156,67 @@ def test_a_new_cell_is_files_and_entries_only(tmp_path):
     assert res["correct"] is True
     assert set(res["metrics"]) == {"steps_counted", "step_wall_ms"}
     assert res["metrics"]["steps_counted"]["value"] >= 1
+
+
+def _hashes(folder):
+    out = {}
+    for base, dirs, files in os.walk(folder):
+        dirs[:] = [d for d in dirs if d != "__pycache__"]
+        for f in files:
+            path = os.path.join(base, f)
+            out[os.path.relpath(path, folder)] = hashlib.sha256(
+                open(path, "rb").read()).hexdigest()
+    return out
+
+
+def test_a_new_family_is_files_and_entries_only(tmp_path):
+    """chipbench/README.md, "Adding things": "new files + new entries, no
+    edit to a file that exists" — for a FAMILY.  ``new_family/`` holds what
+    a later ``model_config`` PR would bring: its own ``families/<family>.py``
+    and reference file, a configuration whose keys are neither OPT's nor
+    GPT-2's (KV heads != heads, untied head, RoPE) and two sizing files.
+    Both drivers run it with ``correct: true`` and every file the copy
+    started with hashes the same afterwards."""
+    assert "new files + new entries, no edit to a file that exists" in open(
+        os.path.join(ROOT, "chipbench", "README.md")).read()
+    root = tmp_path / "checkout"
+    shutil.copytree(os.path.join(ROOT, "chipbench"), root / "chipbench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = _hashes(root / "chipbench")
+    shutil.copytree(os.path.join(HERE, "new_family"), root / "chipbench",
+                    dirs_exist_ok=True)
+    added = set(_hashes(root / "chipbench")) - set(before)
+    assert added == set(_hashes(os.path.join(HERE, "new_family")))
+    bench = json.loads(json.dumps(BENCH))
+    cfg = json.load(open(root / "chipbench" / "configs" / "toy-llama.json"))
+    assert not {"n_embd", "n_head", "ffn_dim", "word_embed_proj_dim"} \
+        & set(cfg)
+    bench["configs"].append({"name": "toy-llama", "source": cfg["source"],
+                             "file": "chipbench/configs/toy-llama.json",
+                             "reduced": [], "why": "test"})
+    cells = {"toy-serve": ("chat-closed", "serve_tok_s"),
+             "toy-train": ("train-1k", "train_tok_s")}
+    for name, (mix, _) in cells.items():
+        bench["workloads"].append({"name": name, "config": "toy-llama",
+                                   "traffic": mix, "chips": 1, "why": "test"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        for name, (_, e2e) in cells.items():
+            if "workloads" in m and e2e in (m["name"], m.get("moves")):
+                m["workloads"].append(name)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    for name, (_, e2e) in cells.items():
+        for trace in (0, 1):
+            res = _result(_run(
+                ["--workload", name, "--seed", str(2 ** 31 + 77),
+                 "--seconds", "1", "--trace", str(trace), "--rehearse"],
+                root=str(root), tmp=tmp_path / "cache"))
+            assert res["correct"] is True and res["failed"] == 0, name
+            assert res["metrics"], name
+            if not trace:
+                assert res["metrics"][e2e]["value"] > 0
+    after = _hashes(root / "chipbench")
+    assert {k: after[k] for k in before} == before
+    assert set(after) - set(before) == added
 
 
 # -------------------------------------------------- data vs BENCHMARK.json
@@ -324,13 +387,126 @@ def test_costs_against_hand_arithmetic():
 
 
 def test_costs_count_the_programs_parameters():
-    from chipbench.families import gpt2 as fam_gpt2, opt as fam_opt
-
-    for fam, name in ((fam_opt, "opt-1.3b"), (fam_gpt2, "gpt2-medium")):
+    for name in ("opt-1.3b", "gpt2-medium"):
         cfg = _config(name)
-        model, heads = fam.build(cfg)
-        assert model.model_config.num_params() == costs.num_params(cfg)
-        assert heads == costs.arch(cfg)["heads"]
+        program = families.load(cfg).build(cfg).model_config
+        assert program.num_params() == costs.num_params(cfg)
+        assert program.num_heads == costs.arch(cfg)["heads"]
+
+
+#: what ``costs`` returned for the two configurations before a family was a
+#: plug-in (PR 26): the rooflines and ``train_tok_s``'s FLOPs divide by these
+PINS = {
+    "opt-1.3b": {"num_params": 1_315_758_080, "weight_bytes": 2_631_516_160,
+                 "kv_bytes_per_token": 196_608, "seq": 2048,
+                 "train_flops_per_token": 9_102_508_032.0,
+                 "arch": {"layers": 24, "d": 2048, "heads": 32,
+                          "kv_heads": 32, "head_dim": 64, "ffn": 8192,
+                          "vocab": 50272, "positions": 2048,
+                          "position_rows": 2050}},
+    "gpt2-medium": {"num_params": 354_823_168, "weight_bytes": 709_646_336,
+                    "kv_bytes_per_token": 98_304, "seq": 1024,
+                    "train_flops_per_token": 2_430_928_896.0,
+                    "arch": {"layers": 24, "d": 1024, "heads": 16,
+                             "kv_heads": 16, "head_dim": 64, "ffn": 4096,
+                             "vocab": 50257, "positions": 1024,
+                             "position_rows": 1024}},
+}
+
+
+@pytest.mark.parametrize("name", sorted(PINS))
+def test_costs_return_the_integers_they_returned(name):
+    cfg, pin = _config(name), PINS[name]
+    assert costs.arch(cfg) == pin["arch"]
+    assert costs.num_params(cfg) == pin["num_params"]
+    assert costs.active_params(cfg) == pin["num_params"]    # dense: all
+    assert costs.weight_bytes(cfg) == pin["weight_bytes"]
+    assert costs.kv_bytes_per_token(cfg) == pin["kv_bytes_per_token"]
+    assert costs.train_flops_per_token(cfg, pin["seq"]) == \
+        pin["train_flops_per_token"]
+    assert costs.decode_bytes_per_step(cfg, 1000.0, {"anything": 1}) == \
+        pin["weight_bytes"] + 1000.0 * pin["kv_bytes_per_token"]
+
+
+def _toy_family(monkeypatch, name, **functions):
+    """A family that exists only in this process."""
+    module = types.ModuleType("chipbench.families." + name)
+    sizes = {"layers": 4, "d": 512, "heads": 8, "kv_heads": 2,
+             "head_dim": 64, "vocab": 1000, "positions": 256}
+    base = {"build": lambda config, overrides=None: None,
+            "arch": lambda config: dict(sizes),
+            "num_params": lambda config: 10_000_000,
+            "logits": lambda config, params, tokens, at=None: None,
+            "next_token_loss": lambda config, params, tokens: None}
+    for fn, body in {**base, **functions}.items():
+        if body is not None:
+            setattr(module, fn, body)
+    monkeypatch.setitem(sys.modules, module.__name__, module)
+    return {"family": name, "dtype": "bf16"}
+
+
+def test_costs_conventions_on_a_gqa_expert_toy(monkeypatch):
+    """KV bytes follow ``kv_heads x head_dim`` (not ``d``), training FLOPs
+    the parameters a token multiplies with, and a decode step's weight
+    bytes what the family says the step reads — from the counters."""
+    dense = _toy_family(monkeypatch, "toy_gqa")
+    assert costs.kv_bytes_per_token(dense) == 2 * 4 * 2 * 64 * 2 == 2048
+    assert costs.kv_bytes_per_token(dense) != 2 * 4 * 512 * 2
+    assert costs.active_params(dense) == 10_000_000
+    assert costs.train_flops_per_token(dense, 128) == \
+        6.0 * 10_000_000 + 12.0 * 4 * (8 * 64) * 128
+    assert costs.decode_bytes_per_step(dense, 10.0) == \
+        2 * 10_000_000 + 2048 * 10.0
+    sparse = _toy_family(
+        monkeypatch, "toy_experts",
+        active_params=lambda config: 3_000_000,
+        decode_weight_bytes=lambda config, counters:
+            2 * (1_000_000 + 500_000 * counters["experts_touched"]))
+    assert costs.num_params(sparse) == 10_000_000
+    assert costs.weight_bytes(sparse) == 20_000_000
+    assert costs.train_flops_per_token(sparse, 128) == \
+        6.0 * 3_000_000 + 12.0 * 4 * (8 * 64) * 128
+    assert costs.decode_bytes_per_step(
+        sparse, 10.0, {"experts_touched": 6}) == 8_000_000 + 2048 * 10.0
+
+
+@pytest.mark.parametrize("lacks", ["num_params", "logits", "the file",
+                                   "a size"])
+def test_a_family_that_lacks_a_function_is_a_named_error(monkeypatch, lacks):
+    """The error names the file and the function to add."""
+    if lacks == "the file":
+        cfg, want = {"family": "no_such_family"}, \
+            r"no chipbench/families/no_such_family\.py"
+    elif lacks == "a size":
+        cfg = _toy_family(monkeypatch, "toy_short",
+                          arch=lambda config: {"layers": 2, "d": 64})
+        want = r"chipbench/families/toy_short\.py: arch\(\) reports no " \
+            r".*'kv_heads'"
+    else:
+        cfg = _toy_family(monkeypatch, "toy_lacks", **{lacks: None})
+        want = rf"chipbench/families/toy_lacks\.py lacks {lacks}\(\)"
+    with pytest.raises(NotImplementedError, match=want):
+        costs.kv_bytes_per_token(cfg)
+
+
+def test_the_shared_files_name_no_family():
+    """ISSUE 27's grep: the families are named only under ``families/``,
+    ``configs/`` and in ``reference.py``'s own two-row table."""
+    cb = os.path.join(ROOT, "chipbench")
+    shared = [os.path.join(cb, f) for f in ("costs.py", "run.py")]
+    for folder in ("drivers", "layer_metrics"):
+        shared += [os.path.join(cb, folder, f)
+                   for f in sorted(os.listdir(os.path.join(cb, folder)))
+                   if f.endswith(".py")]
+    names = {f[:-3] for f in os.listdir(os.path.join(cb, "families"))
+             if f.endswith(".py") and not f.startswith("_")}
+    assert {"opt", "gpt2"} <= names
+    quoted = re.compile("|".join(rf"[\"']{re.escape(n)}[\"']"
+                                 for n in sorted(names)))
+    for path in shared:
+        text = open(path).read()
+        assert not quoted.search(text), path
+        assert "fam ==" not in text and "import reference" not in text, path
 
 
 def test_peaks_refuse_an_unknown_chip():
@@ -349,13 +525,14 @@ def test_reference_agrees_with_the_programs_float32_forward(family):
     order, position offset, activation) shows here and not on the chip."""
     import jax
 
-    from chipbench.families import gpt2 as fam_gpt2, opt as fam_opt
     from deepspeed_tpu.models import gpt2, opt
 
-    fam, mod, name = {"opt": (fam_opt, opt, "opt-1.3b"),
-                      "gpt2": (fam_gpt2, gpt2, "gpt2-medium")}[family]
+    mod, name = {"opt": (opt, "opt-1.3b"),
+                 "gpt2": (gpt2, "gpt2-medium")}[family]
     cfg = cb_run._rehearsed(_config(name), True)
-    model, heads = fam.build(cfg, {"use_flash": False})
+    fam = families.load(cfg)
+    assert cfg["family"] == family
+    model = fam.build(cfg, {"use_flash": False})
     params = model.init_fn(jax.random.PRNGKey(3))
     # biases and LayerNorm offsets are zero at init: make them matter
     keys = iter(jax.random.split(jax.random.PRNGKey(4), 64))
@@ -368,13 +545,13 @@ def test_reference_agrees_with_the_programs_float32_forward(family):
         want = np.asarray(mod.forward(model.model_config, params, ids))
         want_loss = float(model.loss_fn(params, {"input_ids": ids},
                                         train=False))
-    got = np.asarray(reference.logits(family, params, ids, heads))
+    got = np.asarray(fam.logits(cfg, params, ids))
     assert got.shape == want.shape
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
     at = [0, 5, 16]
-    some = np.asarray(reference.logits(family, params, ids, heads, at=at))
+    some = np.asarray(fam.logits(cfg, params, ids, at=at))
     np.testing.assert_allclose(some, want[:, at], rtol=2e-4, atol=2e-4)
-    got_loss = float(reference.next_token_loss(family, params, ids, heads))
+    got_loss = float(fam.next_token_loss(cfg, params, ids))
     assert abs(got_loss - want_loss) < 1e-4, (got_loss, want_loss)
 
 
@@ -428,7 +605,8 @@ def test_reduce_on_the_small_trace(small_trace):
     assert r["programs"]["jit_decode_step"] == pytest.approx(
         [160 * ns, 100 * ns])
     assert r["programs"]["jit_train_step"] == pytest.approx([100 * ns])
-    assert r["custom_call_s"]["jit_decode_step"] == pytest.approx(60 * ns)
+    assert r["custom_call_s"] == pytest.approx(
+        {"jit_decode_step:mosaic:paged_decode_attn": 60 * ns})
     # device 0: all-gather-done [300, 340) holds the core up for 40 ns; the
     # collective itself runs from its start on the async line, [280, 340)
     assert r["collective_s"] == pytest.approx(60 / 2 * ns)
@@ -437,7 +615,7 @@ def test_reduce_on_the_small_trace(small_trace):
     ops = dict(map(tuple, r["device_ops"]))
     assert ops == pytest.approx({
         "jit_decode_step:fusion": (200 + 100) / 2 * ns,
-        "jit_decode_step:mosaic:custom-call": 60 / 2 * ns,
+        "jit_decode_step:mosaic:paged_decode_attn": 60 / 2 * ns,
         "jit_train_step:fusion": 60 / 2 * ns,
         "jit_train_step:all-gather-done": 40 / 2 * ns,
         "jit_train_step:while": 0.0})
@@ -469,6 +647,18 @@ def test_layer_metrics_on_the_small_trace(small_trace):
         100 * kv / 30e-9)
     assert readers["decode_roofline"](ctx) == pytest.approx(
         100 * (kv + costs.weight_bytes(opt) / 819e9) / 130e-9)
+    # a second Mosaic kernel in the decode program (an expert matmul), or
+    # the attention kernel of another program, is not charged to attention:
+    # the reading above summed every Mosaic call of ^jit_decode before
+    more = dict(r["custom_call_s"])
+    more["jit_decode_step:mosaic:moe_grouped_matmul"] = 45e-9
+    more["jit_verify:mosaic:paged_decode_attn"] = 45e-9
+    assert readers["paged_attn_roofline"](
+        {**ctx, "trace": {**r, "custom_call_s": more}}) == pytest.approx(
+        100 * kv / 30e-9)
+    assert readers["paged_attn_roofline"]({**ctx, "trace": {
+        **r, "custom_call_s": {"jit_decode_step:mosaic:other": 9e-9}}}) \
+        is None
 
 
 def test_a_trace_without_device_operations_is_refused(small_trace):
