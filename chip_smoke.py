@@ -89,6 +89,8 @@ class Sizes:
     """Everything that differs between the chip run and the CPU test."""
     opt: Any                       # OPTConfig served
     gpt2: Any                      # GPT2Config trained
+    moe: Any = None                # MixtralConfig: a second head shape for
+                                   # the paged kernels + the routed FFN
     dtype: str = "bf16"
     prompt_lens: Tuple[int, ...] = (5, 40, 130, 300)
     shared_prefix: int = 96        # block-aligned, shared by two requests
@@ -111,7 +113,10 @@ def full_sizes() -> Sizes:
     g.remat, g.use_flash, g.remat_policy = True, True, "dots_flash"
     g.scan_layers = False
     g.flash_block_q, g.flash_block_k = 1024, 1024
-    return Sizes(opt=opt.OPTConfig.opt_1_3b(), gpt2=g)
+    from deepspeed_tpu.models import mixtral
+
+    return Sizes(opt=opt.OPTConfig.opt_1_3b(), gpt2=g,
+                 moe=mixtral.MixtralConfig.olmoe_1b_7b())
 
 
 def log(msg: str) -> None:
@@ -196,25 +201,7 @@ def phase_kernels(sz: Sizes, report: Dict[str, Any]) -> None:
     cfg = sz.opt
     slots = 8                                      # init_serving defaults
     bs = sz.serving_kwargs.get("block_size", 32)
-    ctx = cfg.max_seq_len
-    h, hd = cfg.num_heads, cfg.head_dim
-    nbper = paged_kv.blocks_for(ctx, bs)
-    nb = 1 + slots * nbper
     keys = jax.random.split(jax.random.PRNGKey(0), 8)
-    rng = np.random.default_rng(0)
-    # every row owns a shuffled set of physical blocks (block 0 = scratch)
-    bt = jnp.asarray(1 + rng.permutation(slots * nbper).reshape(slots, nbper),
-                     jnp.int32)
-    # first token, a block edge, mid-block, the last position, and the rest
-    pos = jnp.asarray(([0, bs - 1, bs, ctx - 4]
-                       + list(rng.integers(1, ctx - 4, slots)))[:slots],
-                      jnp.int32)
-    kf = jax.random.normal(keys[0], (nb, h, bs, hd), jnp.float32)
-    vf = jax.random.normal(keys[1], (nb, h, bs, hd), jnp.float32)
-    pools = {"bf16": (kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16))}
-    qk, sk = quant.quantize_kv(kf, paged_kv.SCALE_DTYPE)
-    qv, sv = quant.quantize_kv(vf, paged_kv.SCALE_DTYPE)
-    pools["kv8"] = ({"qp": qk, "ps": sk}, {"qp": qv, "ps": sv})
 
     def f32_pool(p):
         if paged_kv.is_quantized_pool(p):
@@ -231,34 +218,115 @@ def phase_kernels(sz: Sizes, report: Dict[str, Any]) -> None:
     checks: List[Tuple[str, Callable[[], Dict[str, float]]]] = []
 
     def as_engine_holds_it(p):
-        """A stacked pool (layer 0 zeros, ``p`` at layer 1), lane-packed."""
+        """A stacked pool (layer 0 zeros, ``p`` at layer 1), lane-packed
+        (``g = 128 // hd`` spans a row: ``g = 1`` at head_dim 128)."""
         return paged_kv.pack_pool(jax.tree_util.tree_map(
             lambda a: jnp.stack([jnp.zeros_like(a), a]), p))
 
-    def paged(t, kernel, kind):
-        kp, vp = pools[kind]
-        q = jax.random.normal(keys[2], (slots, h, t, hd), jnp.bfloat16)
-        # the kernel reads the whole pool at a (non-zero) layer index; the
-        # reference reads that layer's pool alone, unpacked, in float32
-        fn = jax.jit(lambda q, kp, vp, bt, pos: kernel(q, kp, vp, bt, pos,
-                                                       layer=1))
-        kps, vps = as_engine_holds_it(kp), as_engine_holds_it(vp)
-        _mosaic(fn.lower(q, kps, vps, bt, pos).as_text(),
-                f"paged T={t} {kind}", require)
-        want = exact(
-            lambda q, kp, vp: da.paged_decode_attention_reference(
-                q.astype(jnp.float32), f32_pool(kp), f32_pool(vp), bt, pos),
-            q, kp, vp)
-        return {f"paged_T{t}_{kind}": _close(
-            f"paged attention T={t} {kind}", fn(q, kps, vps, bt, pos), want,
-            ATTN_TOL)}
+    def paged_checks(tag, h, hd, ctx, kinds):
+        """Paged attention at one head shape: the decode (T=1) and verify
+        (T=4) kernels, and the T>1 gather path a prefill chunk takes
+        through the dispatcher, each against the float32 reference."""
+        nbper = paged_kv.blocks_for(ctx, bs)
+        nb = 1 + slots * nbper
+        rng = np.random.default_rng(0)
+        # every row owns a shuffled set of physical blocks (0 = scratch)
+        bt = jnp.asarray(
+            1 + rng.permutation(slots * nbper).reshape(slots, nbper),
+            jnp.int32)
+        # first token, a block edge, mid-block, the last position, the rest
+        pos = jnp.asarray(([0, bs - 1, bs, ctx - 4]
+                           + list(rng.integers(1, ctx - 4, slots)))[:slots],
+                          jnp.int32)
+        kf = jax.random.normal(keys[0], (nb, h, bs, hd), jnp.float32)
+        vf = jax.random.normal(keys[1], (nb, h, bs, hd), jnp.float32)
+        pools = {"bf16": (kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16))}
+        if "kv8" in kinds:
+            qk, sk = quant.quantize_kv(kf, paged_kv.SCALE_DTYPE)
+            qv, sv = quant.quantize_kv(vf, paged_kv.SCALE_DTYPE)
+            pools["kv8"] = ({"qp": qk, "ps": sk}, {"qp": qv, "ps": sv})
 
-    for t, kernel in ((1, da.paged_decode_attention_pallas),
-                      (4, da.paged_verify_attention_pallas)):
-        for kind in pools:
-            checks.append((f"paged T={t} {kind}",
-                           lambda t=t, kernel=kernel, kind=kind:
-                           paged(t, kernel, kind)))
+        def paged(t, kernel, kind, q_pos, mosaic=True):
+            kp, vp = pools[kind]
+            q = jax.random.normal(keys[2], (slots, h, t, hd), jnp.bfloat16)
+            # the kernel reads the whole pool at a (non-zero) layer index;
+            # the reference reads that layer's pool alone, unpacked, float32
+            fn = jax.jit(lambda q, kp, vp, bt, pos: kernel(
+                q, kp, vp, bt, pos, layer=1))
+            kps, vps = as_engine_holds_it(kp), as_engine_holds_it(vp)
+            if mosaic:
+                _mosaic(fn.lower(q, kps, vps, bt, q_pos).as_text(),
+                        f"paged T={t} {kind} {tag}", require)
+            want = exact(
+                lambda q, kp, vp: da.paged_decode_attention_reference(
+                    q.astype(jnp.float32), f32_pool(kp), f32_pool(vp), bt,
+                    q_pos), q, kp, vp)
+            return {f"paged_T{t}_{kind}{tag}": _close(
+                f"paged attention T={t} {kind} {tag}",
+                fn(q, kps, vps, bt, q_pos), want, ATTN_TOL)}
+
+        for t, kernel in ((1, da.paged_decode_attention_pallas),
+                          (4, da.paged_verify_attention_pallas)):
+            for kind in pools:
+                checks.append((f"paged T={t} {kind} {tag}",
+                               lambda t=t, kernel=kernel, kind=kind:
+                               paged(t, kernel, kind, pos)))
+        # a prefill chunk: T > 4 takes the dispatcher's gather path (XLA
+        # over the packed pool's gathered views, no Mosaic call)
+        t = sz.serving_kwargs.get("prefill_chunk", 128)
+        base = jnp.minimum(pos, ctx - t)
+        checks.append((f"paged prefill T={t} {tag}", lambda: paged(
+            t, da.paged_decode_attention, "bf16", base, mosaic=False)))
+
+    paged_checks("", cfg.num_heads, cfg.head_dim, cfg.max_seq_len,
+                 ("bf16", "kv8"))
+    if sz.moe is not None:
+        m = sz.moe
+        paged_checks(f"_h{m.num_kv_heads}x{m.head_dim}", m.num_kv_heads,
+                     m.head_dim, min(m.max_seq_len, 1024), ("bf16",))
+
+        def routed():
+            """The dropless routed FFN at one decode step's shape (64 rows,
+            top-k of E) against a dense float32 loop over experts."""
+            from deepspeed_tpu.moe import routed as moe_routed
+
+            d, f, e, k = m.hidden_size, m.ffn_size, m.num_experts, m.top_k
+            ks = jax.random.split(keys[3], 5)
+            y = jax.random.normal(ks[0], (64, 1, d), jnp.bfloat16)
+            gate_w = (jax.random.normal(ks[1], (d, e)) * 0.02).astype(
+                jnp.bfloat16)
+            w1, w3 = ((jax.random.normal(kk, (e, d, f)) * 0.02).astype(
+                jnp.bfloat16) for kk in ks[2:4])
+            w2 = (jax.random.normal(ks[4], (e, f, d)) * 0.02).astype(
+                jnp.bfloat16)
+            fn = jax.jit(lambda *a: moe_routed.routed_ffn(
+                *a, k, m.norm_topk_prob))
+            got, record = fn(y, gate_w, w1, w3, w2)
+
+            def dense(y, gate_w, w1, w3, w2):
+                x = y.reshape(-1, d).astype(jnp.float32)
+                p, idx = moe_routed.route(y.reshape(-1, d), gate_w, k,
+                                          m.norm_topk_prob)
+                weight = jnp.zeros((x.shape[0], e)).at[
+                    jnp.arange(x.shape[0])[:, None], idx].set(p)
+
+                def one(i, acc):
+                    a, b, c = (t[i].astype(jnp.float32)
+                               for t in (w1, w3, w2))
+                    return acc + ((jax.nn.silu(x @ a) * (x @ b)) @ c) \
+                        * weight[:, i, None]
+
+                return jax.lax.fori_loop(0, e, one, jnp.zeros_like(x))
+
+            want = np.asarray(exact(dense, y, gate_w, w1, w3, w2))
+            got = np.asarray(got, np.float32).reshape(want.shape)
+            rel = float(np.linalg.norm(got - want) / np.linalg.norm(want))
+            # bf16 rounding of gate*up and of the output: 2^-8 each
+            assert rel <= 2e-2, f"routed FFN: relative error {rel:.4f}"
+            assert int(record[1]) == 64 * k, record
+            return {"routed_ffn_rel": rel}
+
+        checks.append(("routed FFN", routed))
 
     def flash():
         """flash v2 forward + fused backward, the train step's kernels"""

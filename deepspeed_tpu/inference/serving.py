@@ -879,6 +879,9 @@ class ServingEngine:
                                        sampling=self.sampling)
         self.engine = engine
         self._fwd = hooks["forward_cached"]
+        #: an expert family's cached forward also returns its per-layer
+        #: routing record (``moe/routed.py RECORD``) when asked
+        self._routing = bool(hooks.get("routing_record"))
         self._init_cache = hooks["init_cache"]
         max_ctx = hooks.get("max_seq_len")
         if max_seq_len is None:
@@ -1446,6 +1449,14 @@ class ServingEngine:
             "window, the ONLY sync of the fused decode path")
         self._c_prefill_calls = m.counter(
             "serving_prefill_calls_total", "prefill program invocations")
+        self._c_moe_rows = m.counter(
+            "serving_moe_expert_rows_total",
+            "(token, expert) rows routed by decode and prefill programs, "
+            "summed over layers (0 for a dense model)")
+        self._c_moe_touched = m.counter(
+            "serving_moe_experts_touched_total",
+            "experts that received at least one row, summed over layers "
+            "and program calls (0 for a dense model)")
         self._c_admitted = m.counter(
             "serving_requests_admitted_total", "requests admitted to slots")
         self._c_preempted = m.counter(
@@ -1839,6 +1850,39 @@ class ServingEngine:
         return jax.tree_util.tree_map(
             lambda x: jax.lax.with_sharding_constraint(x, sharding), cache)
 
+    def _forward(self, *args, **kwargs):
+        """Traced: the model's cached forward as ``(logits, cache,
+        record)`` — ``record`` the int32 ``[L, 3]`` routing record of an
+        expert family (decode hook ``routing_record``), else None."""
+        if self._routing:
+            return self._fwd(*args, routing=True, **kwargs)
+        return (*self._fwd(*args, **kwargs), None)
+
+    @staticmethod
+    def _with_record(tokens, record):
+        """Traced: the routing record rides behind the tokens in the ONE
+        int32 array a step copies back — no second transfer."""
+        if record is None:
+            return tokens
+        return jnp.concatenate([tokens.reshape(-1),
+                                record.reshape(-1).astype(tokens.dtype)])
+
+    def _split_record(self, flat, shape, span_args):
+        """Host: undo :meth:`_with_record` on the copied-back array; the
+        step's routing goes on its in-flight span (``experts_touched`` and
+        ``expert_rows`` summed over layers, ``expert_rows_max`` the largest
+        group of any layer) and into the totals."""
+        if not self._routing:
+            return flat
+        n = int(np.prod(shape))
+        rec = flat[n:].reshape(-1, 3)
+        touched, rows = int(rec[:, 0].sum()), int(rec[:, 1].sum())
+        span_args.update(experts_touched=touched, expert_rows=rows,
+                         expert_rows_max=int(rec[:, 2].max()))
+        self._c_moe_touched.inc(touched)
+        self._c_moe_rows.inc(rows)
+        return flat[:n].reshape(shape)
+
     def _next_tokens(self, logits, samp):
         """The per-row token rule shared by every program body: argmax for
         a greedy-only engine; otherwise a per-row ``where(temp > 0)``
@@ -1947,16 +1991,17 @@ class ServingEngine:
 
     def _get_decode_fn(self):
         if self._decode_fn is None:
-            fwd, prepare = self._fwd, self.engine._prepare
+            fwd, prepare = self._forward, self.engine._prepare
             K, constrain = self._K, self._constrain_pool
-            next_tokens = self._next_tokens
+            next_tokens, with_record = self._next_tokens, self._with_record
 
             def step_core(params, cache, tokens, lengths, block_tables,
                           samp):
-                logits, cache = fwd(prepare(params), tokens[:, None], cache,
-                                    0, lengths=lengths,
-                                    block_tables=block_tables)
-                return next_tokens(logits, samp), constrain(cache)
+                logits, cache, rec = fwd(prepare(params), tokens[:, None],
+                                         cache, 0, lengths=lengths,
+                                         block_tables=block_tables)
+                return with_record(next_tokens(logits, samp), rec), \
+                    constrain(cache)
 
             def fused_core(params, cache, tokens, lengths, block_tables,
                            active, budgets, eos_ids, samp):
@@ -1970,19 +2015,27 @@ class ServingEngine:
                 the loop stays fixed-shape with no gather/compaction.
                 Sampled rows draw step ``i`` with the counter key
                 ``counts + i`` — the same keys the K=1 path uses, so
-                fused and plain sampled streams are token-identical."""
+                fused and plain sampled streams are token-identical.
+                An expert family's routing record is summed over the
+                window's iterations (its largest group: the maximum)."""
                 p = prepare(params)
                 out0 = jnp.full((tokens.shape[0], K), -1, jnp.int32)
+                rec0 = jnp.zeros((int(self._pool_shape[0]), 3), jnp.int32) \
+                    if self._routing else None
 
                 def cond(state):
-                    i, _, _, _, act, _ = state
+                    i, _, _, _, act, _, _ = state
                     return (i < K) & jnp.any(act)
 
                 def body(state):
-                    i, toks, lens, cache, act, out = state
-                    logits, cache = fwd(p, toks[:, None], cache, 0,
-                                        lengths=lens,
-                                        block_tables=block_tables)
+                    i, toks, lens, cache, act, out, rec = state
+                    logits, cache, r = fwd(p, toks[:, None], cache, 0,
+                                           lengths=lens,
+                                           block_tables=block_tables)
+                    if r is not None:
+                        rec = jnp.concatenate(
+                            [rec[:, :2] + r[:, :2],
+                             jnp.maximum(rec[:, 2:], r[:, 2:])], axis=1)
                     cache = constrain(cache)
                     if samp is None:
                         nxt = next_tokens(logits, None)
@@ -1994,12 +2047,12 @@ class ServingEngine:
                     lens = lens + act.astype(lens.dtype)
                     toks = jnp.where(act, nxt, toks)
                     act = act & (nxt != eos_ids) & (i + 1 < budgets)
-                    return (i + 1, toks, lens, cache, act, out)
+                    return (i + 1, toks, lens, cache, act, out, rec)
 
-                _, _, _, cache, _, out = jax.lax.while_loop(
-                    cond, body,
-                    (jnp.int32(0), tokens, lengths, cache, active, out0))
-                return out, cache
+                _, _, _, cache, _, out, rec = jax.lax.while_loop(
+                    cond, body, (jnp.int32(0), tokens, lengths, cache,
+                                 active, out0, rec0))
+                return with_record(out, rec), cache
 
             # *samp is the engine's sampling operand tail — () for
             # sampling=False (the exact legacy programs, bit-path
@@ -2054,11 +2107,12 @@ class ServingEngine:
         With a draft model, the draft's prefill is FUSED into the same
         program (both caches advance through the identical window/table
         contract), so speculative prefill still costs one program."""
-        fwd, prepare = self._fwd, self.engine._prepare
+        fwd, prepare = self._forward, self.engine._prepare
         draft = self._draft
         constrain = self._constrain_pool
 
         next_tokens, pack = self._next_tokens, self._pack_samp
+        with_record = self._with_record
 
         def build():
             def prefill(params, cache, ids, block_tables, base, valid,
@@ -2071,9 +2125,11 @@ class ServingEngine:
                 draws with the SAME counter key (seed, emitted count) the
                 decode path would use — that is what makes preempt/crash
                 resumes, which re-emit through prefill, token-exact."""
-                logits, cache = fwd(prepare(params), ids, cache, base,
-                                    lengths=valid, block_tables=block_tables)
-                return next_tokens(logits, pack(samp)), constrain(cache)
+                logits, cache, rec = fwd(prepare(params), ids, cache, base,
+                                         lengths=valid,
+                                         block_tables=block_tables)
+                return with_record(next_tokens(logits, pack(samp)), rec), \
+                    constrain(cache)
 
             if self.resident_window_blocks:
                 # windowed prefill REPLACES the plain program (+0 budget):
@@ -3844,10 +3900,11 @@ class ServingEngine:
             args += (jnp.asarray(self._window_start),)
         args += self._samp_args(self._decode_counts())
         decode_fn = self._get_decode_fn()
-        with self.timeline.span("decode", slots=len(dec)):
+        with self.timeline.span("decode", slots=len(dec)) as span_args:
             with self._decode_ctx():
                 nxt, self._cache = decode_fn(*args)
-            nxt = np.asarray(nxt)
+            nxt = self._split_record(np.asarray(nxt), (self.slots,),
+                                     span_args)
         self._c_decode_steps.inc()
         for slot in dec:
             st = active[slot]
@@ -3938,10 +3995,12 @@ class ServingEngine:
                 jnp.asarray(eos_ids),
                 *self._samp_args(self._decode_counts()))
         decode_fn = self._get_decode_fn()
-        with self.timeline.span("decode", slots=len(dec), fused=K):
+        with self.timeline.span("decode", slots=len(dec),
+                                fused=K) as span_args:
             with self._decode_ctx():
                 out, self._cache = decode_fn(*args)
             out, = self._fence_harvest(out)
+            out = self._split_record(out, (self.slots, K), span_args)
         # ----- the fence catch-up: replay each slot's committed window
         # tokens through the exact K=1 commit sequence (emission order,
         # finish conditions, TTFT stamps — token- and event-identical)
@@ -4200,14 +4259,14 @@ class ServingEngine:
             args += samp
         prefill_fn = self._get_prefill_fn(width)
         with self.timeline.span("prefill", width=width, rows=len(group),
-                                slots=list(map(int, group))):
+                                slots=list(map(int, group))) as span_args:
             if self._draft is not None:
                 with self._tp_ctx():
                     first, self._cache, self._dcache = prefill_fn(*args)
             else:
                 with self._tp_ctx(), self._sp_ctx():
                     first, self._cache = prefill_fn(*args)
-            first = np.asarray(first)
+            first = self._split_record(np.asarray(first), (j,), span_args)
         if self.sp_degree > 1:
             nbytes = sp_attention.alltoall_bytes(
                 int(self._pool_shape[0]), len(group), width,
@@ -4407,6 +4466,8 @@ class ServingEngine:
             "backend_compiles": backend_compiles(),
             "iterations": self.iterations,
             "decode_steps": self.decode_steps,
+            "moe_expert_rows": int(self._c_moe_rows.value),
+            "moe_experts_touched": int(self._c_moe_touched.value),
             "engine_mode": self.engine_mode,
             "fused_iterations": int(self._c_fused_iterations.value),
             "host_fence_waits": int(self._c_host_fence_waits.value),

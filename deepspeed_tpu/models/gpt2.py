@@ -450,7 +450,9 @@ def _block_cached_body(cfg: GPT2Config, x, get, mm, ck, cv, pos,
 
 def scan_layers_cached(step, x, blocks, cache_k, cache_v, paged: bool):
     """``lax.scan`` of ``step(x, layer_params, ck, cv, l) -> (x, ck, cv)``
-    over the stacked ``blocks``, on either cache layout.
+    over the stacked ``blocks``, on either cache layout.  A ``step`` that
+    returns a fourth value (a small per-layer record: mixtral's routing
+    counts) gets it back stacked ``[L, ...]`` as a fourth result.
 
     Contiguous (``paged=False``): the stacked [L, B, H, S, hd] cache rides
     as ``xs`` beside the weights, each step gets its own layer's slice
@@ -464,22 +466,23 @@ def scan_layers_cached(step, x, blocks, cache_k, cache_v, paged: bool):
     if not paged:
         def sbody(x, xs):
             layer, ck, cv = xs
-            x, ck, cv = step(x, layer, ck, cv, None)
-            return x, (ck, cv)
+            x, ck, cv, *aux = step(x, layer, ck, cv, None)
+            return x, (ck, cv, *aux)
 
-        x, (ks, vs) = jax.lax.scan(sbody, x, (blocks, cache_k, cache_v))
-        return x, ks, vs
+        x, out = jax.lax.scan(sbody, x, (blocks, cache_k, cache_v))
+        return (x, *out)
 
     def pbody(carry, xs):
         x, pk, pv = carry
         layer, l = xs
-        return step(x, layer, pk, pv, l), None
+        x, pk, pv, *aux = step(x, layer, pk, pv, l)
+        return (x, pk, pv), tuple(aux)
 
     n = jax.tree_util.tree_leaves(blocks)[0].shape[0]
-    (x, cache_k, cache_v), _ = jax.lax.scan(
+    carry, aux = jax.lax.scan(
         pbody, (x, cache_k, cache_v),
         (blocks, jnp.arange(n, dtype=jnp.int32)))
-    return x, cache_k, cache_v
+    return (*carry, *aux)
 
 
 def decode_over_layers(body, x, blocks, cache_k, cache_v, num_layers,

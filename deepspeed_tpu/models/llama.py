@@ -38,6 +38,10 @@ class LlamaConfig:
     ffn_size: int = 14336
     rope_theta: float = 500000.0
     rms_eps: float = 1e-5
+    #: OLMoE-style q/k-norm: ONE RMSNorm over all ``heads * head_dim``
+    #: projected features (not per head), after the projection and before
+    #: the split into heads and RoPE
+    qk_norm: bool = False
     remat: bool = True
     use_flash: Optional[bool] = None
     #: ZeRO-3 liveness: gather this many layers per scan step (engine sets
@@ -73,7 +77,9 @@ class LlamaConfig:
         attn = d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd + \
             self.num_heads * hd * d
         mlp = 3 * d * f
-        return v * d + l * (attn + mlp + 2 * d) + d + d * v
+        norms = 2 * d + (self.num_heads + self.num_kv_heads) * hd \
+            * self.qk_norm
+        return v * d + l * (attn + mlp + norms) + d + d * v
 
 
 def init_params(cfg: LlamaConfig, rng) -> PyTree:
@@ -86,7 +92,7 @@ def init_params(cfg: LlamaConfig, rng) -> PyTree:
     def normal(key, shape, s=std):
         return (jax.random.normal(key, shape) * s).astype(jnp.float32)
 
-    return {
+    params = {
         "embed": normal(keys[0], (cfg.vocab_size, d)),
         "blocks": {
             "attn_norm": jnp.ones((l, d)),
@@ -102,6 +108,10 @@ def init_params(cfg: LlamaConfig, rng) -> PyTree:
         "final_norm": jnp.ones((d,)),
         "lm_head": normal(keys[8], (d, cfg.vocab_size)),
     }
+    if cfg.qk_norm:
+        params["blocks"]["q_norm"] = jnp.ones((l, hq))
+        params["blocks"]["k_norm"] = jnp.ones((l, hkv))
+    return params
 
 
 def rms_norm(x, scale, eps: float = 1e-5):
@@ -166,7 +176,10 @@ def _attention(cfg: LlamaConfig, q, k, v):
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
-def block_apply(cfg: LlamaConfig, layer: PyTree, x, cos, sin):
+def attn_apply(cfg: LlamaConfig, layer: PyTree, x, cos, sin):
+    """The attention half of a block (pre-norm, q/k/v, optional q/k-norm,
+    RoPE, causal attention, output projection, residual) — llama's and
+    mixtral's uncached forwards share it."""
     # matmuls route through gpt2._qmm: dense leaves trace to the identical
     # ``x @ w.astype`` HLO; INT8 records (quant-aware serving prefill)
     # dequantize at point of use instead of crashing on a dict leaf
@@ -175,22 +188,34 @@ def block_apply(cfg: LlamaConfig, layer: PyTree, x, cos, sin):
     b, s, d = x.shape
     h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
 
-    y = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-    q = _qmm(y, layer["q_w"]).reshape(b, s, h, hd)
-    k = _qmm(y, layer["k_w"]).reshape(b, s, hkv, hd)
-    v = _qmm(y, layer["v_w"]).reshape(b, s, hkv, hd)
-    q = apply_rope(q.transpose(0, 2, 1, 3), cos, sin)
-    k = apply_rope(k.transpose(0, 2, 1, 3), cos, sin)
-    v = v.transpose(0, 2, 1, 3)
-    attn = _attention(cfg, q, k, v)
-    attn = attn.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
-    x = x + _qmm(attn, layer["o_w"], x.dtype)
+    with jax.named_scope("layer/attn"):
+        y = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
+        q, k = _qmm(y, layer["q_w"]), _qmm(y, layer["k_w"])
+        if cfg.qk_norm:
+            # over ALL heads' features; under GSPMD tp the mean is global
+            # (XLA reduces the sum of squares over the tp axis)
+            q = rms_norm(q, layer["q_norm"], cfg.rms_eps)
+            k = rms_norm(k, layer["k_norm"], cfg.rms_eps)
+        q = q.reshape(b, s, h, hd)
+        k = k.reshape(b, s, hkv, hd)
+        v = _qmm(y, layer["v_w"]).reshape(b, s, hkv, hd)
+        q = apply_rope(q.transpose(0, 2, 1, 3), cos, sin)
+        k = apply_rope(k.transpose(0, 2, 1, 3), cos, sin)
+        v = v.transpose(0, 2, 1, 3)
+        attn = _attention(cfg, q, k, v)
+        attn = attn.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
+        return x + _qmm(attn, layer["o_w"], x.dtype)
 
-    y = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
-    gate = jax.nn.silu(_qmm(y, layer["w1"]))
-    up = _qmm(y, layer["w3"])
-    x = x + _qmm(gate * up, layer["w2"], x.dtype)
-    return x
+
+def block_apply(cfg: LlamaConfig, layer: PyTree, x, cos, sin):
+    from .gpt2 import _qmm
+
+    x = attn_apply(cfg, layer, x, cos, sin)
+    with jax.named_scope("layer/mlp"):
+        y = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
+        gate = jax.nn.silu(_qmm(y, layer["w1"]))
+        up = _qmm(y, layer["w3"])
+        return x + _qmm(gate * up, layer["w2"], x.dtype)
 
 
 def forward(cfg: LlamaConfig, params: PyTree, input_ids, rng=None,
@@ -248,32 +273,41 @@ def _block_cached_body(cfg: LlamaConfig, x, get, mm, ck, cv, pos,
     """Cached-attention block parameterized by weight access (``get(name)``
     small leaf, ``mm(y, name, dtype)`` matmul — shared by the scan and
     layer-indexed quantized decode paths, see gpt2.decode_over_layers).
-    ``mlp(y) -> y`` overrides the dense SwiGLU (mixtral's MoE FFN).
+    ``mlp(y) -> (y, aux)`` overrides the dense SwiGLU (mixtral's routed
+    FFN; ``aux`` is its per-layer routing record) and makes this return
+    ``(x, ck, cv, aux)``.
     ``block_tables``/``chunk_valid`` switch ck/cv to the whole paged pool,
     addressed in place at ``layer`` (contract in gpt2._cached_attention)."""
     b, t, d = x.shape
     h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
 
-    y = rms_norm(x, get("attn_norm"), cfg.rms_eps)
-    q = mm(y, "q_w", None).reshape(b, t, h, hd)
-    k = mm(y, "k_w", None).reshape(b, t, hkv, hd)
-    v = mm(y, "v_w", None).reshape(b, t, hkv, hd)
-    q = _rope_cached(cfg, q.transpose(0, 2, 1, 3), pos)
-    k = _rope_cached(cfg, k.transpose(0, 2, 1, 3), pos)
-    v = v.transpose(0, 2, 1, 3)
-    from .gpt2 import _cached_attention
+    with jax.named_scope("layer/attn"):
+        y = rms_norm(x, get("attn_norm"), cfg.rms_eps)
+        q, k = mm(y, "q_w", None), mm(y, "k_w", None)
+        if cfg.qk_norm:
+            q = rms_norm(q, get("q_norm"), cfg.rms_eps)
+            k = rms_norm(k, get("k_norm"), cfg.rms_eps)
+        q = q.reshape(b, t, h, hd)
+        k = k.reshape(b, t, hkv, hd)
+        v = mm(y, "v_w", None).reshape(b, t, hkv, hd)
+        q = _rope_cached(cfg, q.transpose(0, 2, 1, 3), pos)
+        k = _rope_cached(cfg, k.transpose(0, 2, 1, 3), pos)
+        v = v.transpose(0, 2, 1, 3)
+        from .gpt2 import _cached_attention
 
-    attn, ck, cv = _cached_attention(q, k, v, ck, cv, pos, block_tables,
-                                     chunk_valid, layer)
-    attn = attn.transpose(0, 2, 1, 3).reshape(b, t, h * hd)
-    x = x + mm(attn, "o_w", x.dtype)
+        attn, ck, cv = _cached_attention(q, k, v, ck, cv, pos, block_tables,
+                                         chunk_valid, layer)
+        attn = attn.transpose(0, 2, 1, 3).reshape(b, t, h * hd)
+        x = x + mm(attn, "o_w", x.dtype)
 
     y = rms_norm(x, get("mlp_norm"), cfg.rms_eps)
     if mlp is not None:
-        return x + mlp(y), ck, cv
-    gate = jax.nn.silu(mm(y, "w1", None))
-    up = mm(y, "w3", None)
-    x = x + mm(gate * up, "w2", x.dtype)
+        out, aux = mlp(y)
+        return x + out, ck, cv, aux
+    with jax.named_scope("layer/mlp"):
+        gate = jax.nn.silu(mm(y, "w1", None))
+        up = mm(y, "w3", None)
+        x = x + mm(gate * up, "w2", x.dtype)
     return x, ck, cv
 
 
@@ -289,13 +323,28 @@ def _block_cached(cfg: LlamaConfig, x, layer, ck, cv, pos, mlp_fn=None,
         block_tables=block_tables, chunk_valid=chunk_valid, layer=index)
 
 
+def live_tokens(input_ids, lengths=None, block_tables=None):
+    """bool ``[B, T]``: which input positions are somebody's tokens.  A
+    paged decode step (T == 1) runs every slot, idle ones with an all-scratch
+    (zero) block table; a paged prefill chunk (T > 1) is right-padded to
+    ``lengths``.  Without a paged table every position counts."""
+    b, t = input_ids.shape
+    if block_tables is None:
+        return jnp.ones((b, t), bool)
+    if t == 1 or lengths is None:
+        return jnp.broadcast_to(block_tables[:, :1] != 0, (b, t))
+    return jnp.arange(t)[None, :] < jnp.asarray(lengths)[:, None]
+
+
 def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
                    lengths=None, block_tables=None, mlp_fn=None,
                    all_positions=False):
     """Incremental forward: logits for the LAST input position + updated
     cache — or for EVERY position when ``all_positions`` is set ([B, T, V],
-    the speculative-verify head).  ``mlp_fn`` threads through to :func:`_block_cached` (mixtral
-    delegates here with its MoE FFN).  Quantized serving (no mlp_fn) takes
+    the speculative-verify head).  ``mlp_fn(layer, y) -> (y, record)``
+    threads through to :func:`_block_cached` (mixtral delegates here with
+    its routed FFN) and adds a third result, the per-layer records stacked
+    ``[L, ...]``.  Quantized serving (no mlp_fn) takes
     the layer-indexed stacked-kernel path via gpt2.decode_over_layers.
 
     ``lengths`` (optional int32 [B]): per-sequence valid lengths for
@@ -334,7 +383,7 @@ def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
             probe="q_w", paged=paged)
     else:
         # mixtral's MoE FFN needs the whole layer dict: scan path only
-        x, ks, vs = scan_layers_cached(
+        x, ks, vs, records = scan_layers_cached(
             lambda x, layer, ck, cv, l: _block_cached(
                 cfg, x, layer, ck, cv, step_pos, mlp_fn=mlp_fn,
                 block_tables=block_tables, chunk_valid=chunk_valid,
@@ -343,7 +392,10 @@ def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
     if not all_positions:
         x = _gather_last(x, lengths if not per_row else None)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    return x @ params["lm_head"].astype(x.dtype), {"k": ks, "v": vs}
+    logits = x @ params["lm_head"].astype(x.dtype)
+    if mlp_fn is None:
+        return logits, {"k": ks, "v": vs}
+    return logits, {"k": ks, "v": vs}, records
 
 
 def loss_from_batch(cfg: LlamaConfig, params, batch, rng=None,
@@ -370,6 +422,8 @@ def tp_rules(cfg: LlamaConfig, abstract_params: PyTree) -> PyTree:
         "embed": P(TP_AXIS, None),
         "blocks": {
             "attn_norm": P(),
+            # q/k-norm scales stay whole: the norm spans every head
+            **({"q_norm": P(), "k_norm": P()} if cfg.qk_norm else {}),
             "q_w": P(None, None, TP_AXIS),
             "k_w": P(None, None, TP_AXIS),
             "v_w": P(None, None, TP_AXIS),

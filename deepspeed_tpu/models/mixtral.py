@@ -1,11 +1,19 @@
-"""Mixtral (sparse-MoE Llama), TPU-native.
+"""Mixtral and OLMoE (sparse-MoE Llama), TPU-native.
 
 Driver config #4 (BASELINE.json: Mixtral 8x7B expert-parallel + ZeRO-2).
-Llama attention blocks with the FFN replaced by a top-2-gated MoE
+Llama attention blocks with the FFN replaced by a top-k-gated MoE
 (``deepspeed_tpu.moe``): expert weights are stacked [L, E, ...] with the expert
 dim sharded over the ``ep`` mesh axis, so scan-over-layers + vmapped experts +
 all-to-all dispatch compose with ZeRO and TP.  Reference analog:
 ``deepspeed/moe/layer.py`` MoE inserted per-block + MoE-aware ZeRO.
+
+Two FFN paths: TRAINING keeps DeepSpeed's capacity gating (``moe/layer.py``:
+top-1/top-2, tokens over capacity dropped, load-balancing loss); every
+INFERENCE forward — uncached, cached, paged — takes the dropless
+``moe/routed.py`` (any ``top_k``, with or without renormalising the chosen
+weights), so the cached and uncached forwards agree by construction.
+OLMoE (``MixtralConfig.olmoe_1b_7b``) is the same block with q/k-norm
+(``LlamaConfig.qk_norm``), 64 experts, top-8 and no renormalisation.
 """
 
 from __future__ import annotations
@@ -18,7 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..moe.layer import MoEConfig, moe_apply
-from ..moe.sharded_moe import top2gating, top1gating, dispatch_tokens, combine_tokens
+from ..moe.routed import routed_ffn
 from ..parallel.topology import EP_AXIS, TP_AXIS
 from ..runtime.model import ModelSpec
 from . import llama as L
@@ -29,21 +37,38 @@ PyTree = Any
 @dataclasses.dataclass
 class MixtralConfig(L.LlamaConfig):
     num_experts: int = 8
+    #: experts per token: any value in [1, num_experts] for inference;
+    #: training's capacity gating takes 1 or 2
     top_k: int = 2
+    #: renormalise the chosen top-k router weights to sum to 1 (Mixtral);
+    #: False keeps the softmax-over-all-experts weights (OLMoE)
+    norm_topk_prob: bool = True
+    #: training capacity (tokens over it are dropped); inference is dropless
     capacity_factor: float = 1.25
-    #: eval/inference capacity. The default (2.0) is the reference's
-    #: capacity-bucket posture: rare high-load tokens may drop at prefill,
-    #: memory stays O(S*E*C) with C ~ S*k*2/E.  Set to ``num_experts`` for
-    #: provably drop-free routing (HF Mixtral semantics; C grows to S*k, so
-    #: dispatch memory becomes O(E*S^2) — fine for short prompts/tests).
-    eval_capacity_factor: float = 2.0
     router_aux_loss_coef: float = 0.02
+
+    def __post_init__(self):
+        if not 1 <= self.top_k <= self.num_experts:
+            raise ValueError(f"top_k={self.top_k} outside "
+                             f"[1, num_experts={self.num_experts}]")
 
     @staticmethod
     def mixtral_8x7b() -> "MixtralConfig":
         return MixtralConfig(vocab_size=32000, num_layers=32, num_heads=32,
                              num_kv_heads=8, hidden_size=4096, ffn_size=14336,
                              rope_theta=1e6, num_experts=8, top_k=2)
+
+    @staticmethod
+    def olmoe_1b_7b() -> "MixtralConfig":
+        """allenai/OLMoE-1B-7B-0125-Instruct: 16 layers, d 2048, 16 MHA
+        heads x 128 with q/k-norm, 64 SwiGLU experts of width 1024, top-8
+        of a softmax over all 64 without renormalisation."""
+        return MixtralConfig(vocab_size=50304, max_seq_len=4096,
+                             num_layers=16, num_heads=16, num_kv_heads=16,
+                             hidden_size=2048, ffn_size=1024,
+                             rope_theta=10000.0, rms_eps=1e-5, qk_norm=True,
+                             num_experts=64, top_k=8, norm_topk_prob=False,
+                             router_aux_loss_coef=0.01)
 
     @staticmethod
     def tiny(vocab_size: int = 512) -> "MixtralConfig":
@@ -60,12 +85,19 @@ class MixtralConfig(L.LlamaConfig):
         return base + self.num_layers * (
             (self.num_experts - 1) * per_layer_mlp + d * self.num_experts)
 
+    def active_params(self) -> int:
+        """Parameters one token multiplies with: everything but the
+        experts it was not routed to."""
+        idle = (self.num_experts - self.top_k) * 3 * self.hidden_size \
+            * self.ffn_size
+        return self.num_params() - self.num_layers * idle
+
     def moe_cfg(self) -> MoEConfig:
+        """The TRAINING gate (capacity buckets, k in {1, 2})."""
         return MoEConfig(hidden_size=self.hidden_size,
                          ffn_hidden_size=self.ffn_size,
                          num_experts=self.num_experts, k=self.top_k,
                          capacity_factor=self.capacity_factor,
-                         eval_capacity_factor=self.eval_capacity_factor,
                          activation="silu_glu")
 
 
@@ -89,26 +121,13 @@ def init_params(cfg: MixtralConfig, rng) -> PyTree:
 
 
 def _moe_block(cfg: MixtralConfig, layer: PyTree, x, cos, sin, train: bool = True):
-    """Llama attention + MoE FFN; returns (x, aux_loss).  Matmuls route
-    through gpt2._qmm: dense leaves trace to the identical HLO, INT8
-    records (quant-aware serving) dequantize / run the s8 kernel at point
-    of use instead of crashing on a dict leaf."""
-    from .gpt2 import _qmm
-
-    b, s, d = x.shape
-    y = L.rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-    h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = _qmm(y, layer["q_w"]).reshape(b, s, h, hd)
-    k = _qmm(y, layer["k_w"]).reshape(b, s, hkv, hd)
-    v = _qmm(y, layer["v_w"]).reshape(b, s, hkv, hd)
-    q = L.apply_rope(q.transpose(0, 2, 1, 3), cos, sin)
-    k = L.apply_rope(k.transpose(0, 2, 1, 3), cos, sin)
-    attn = L._attention(cfg, q, k, v.transpose(0, 2, 1, 3))
-    attn = attn.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
-    x = x + _qmm(attn, layer["o_w"], x.dtype)
-
+    """Llama attention + MoE FFN; returns (x, aux_loss)."""
+    x = L.attn_apply(cfg, layer, x, cos, sin)
     y = L.rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
-    moe_out, aux = _moe_ffn(cfg, layer, y, train=train)
+    if train:
+        moe_out, aux = _moe_ffn(cfg, layer, y)
+    else:
+        moe_out, aux = _routed(cfg, layer, y)[0], jnp.zeros((), jnp.float32)
     return x + moe_out, aux
 
 
@@ -153,29 +172,79 @@ def loss_from_batch(cfg: MixtralConfig, params, batch, rng=None,
     return lm_loss + cfg.router_aux_loss_coef * aux
 
 
-def _moe_ffn(cfg: MixtralConfig, layer, y, train: bool):
+def _moe_ffn(cfg: MixtralConfig, layer, y):
+    """Training FFN: DeepSpeed's capacity gating -> (y, aux_loss)."""
     moe_params = {
         "gate_w": layer["gate_w"],
         "experts": {"w1": layer["experts_w1"], "w3": layer["experts_w3"],
                     "w2": layer["experts_w2"]},
     }
-    return moe_apply(cfg.moe_cfg(), moe_params, y, train=train)
+    return moe_apply(cfg.moe_cfg(), moe_params, y, train=True)
+
+
+_EXPERT_LEAVES = ("experts_w1", "experts_w3", "experts_w2")
+
+
+def _expert_kernel(blocks) -> bool:
+    """Whether the inference FFN runs the Pallas grouped matmul
+    (``moe/grouped_matmul.py``) or ``jax.lax.ragged_dot``: the kernel needs
+    dense expert leaves (an INT8 record is expanded a layer at a time) on
+    one shard (a Pallas call has no partitioning rule — under a ``tp`` or
+    ``ep`` mesh GSPMD would gather the experts onto every chip;
+    ``ragged_dot`` partitions)."""
+    from .. import comm
+    from ..ops import paged_kv
+    from ..ops import quantization as quant
+
+    topo = comm.get_topology()
+    sharded = paged_kv.tp_mesh() is not None \
+        or topo.tensor_parallel_size * topo.expert_parallel_size > 1
+    return not sharded and not any(quant.is_record(blocks[k])
+                                   for k in _EXPERT_LEAVES)
+
+
+def _routed(cfg: MixtralConfig, layer, y, live=None, stacks=None):
+    """Inference FFN: dropless routing -> (y, routing record [3]).
+    ``stacks``: the whole ``[L, E, ..]`` expert leaves, read in place at
+    ``layer["layer_index"]`` by the grouped-matmul kernel; without them
+    ``layer`` holds its own ``[E, ..]`` slices."""
+    whole = stacks is not None
+    w1, w3, w2 = ((stacks if whole else layer)[k] for k in _EXPERT_LEAVES)
+    return routed_ffn(y, layer["gate_w"], w1, w3, w2, cfg.top_k,
+                      cfg.norm_topk_prob, live=live,
+                      layer=layer["layer_index"] if whole else None,
+                      kernel=whole or _expert_kernel(layer))
 
 
 def forward_cached(cfg: MixtralConfig, params, input_ids, cache, pos,
-                   lengths=None, block_tables=None, all_positions=False):
+                   lengths=None, block_tables=None, all_positions=False,
+                   routing: bool = False):
     """Incremental MoE forward (reference ``moe_inference.py``: expert
-    routing runs per decode token too) — llama's cached path with the MoE
-    FFN hooked in.  ``lengths`` (per-sequence positions for
+    routing runs per decode token too) — llama's cached path with the
+    routed FFN hooked in.  ``lengths`` (per-sequence positions for
     continuous-batching slots), ``block_tables`` (block-paged cache
     layout), and ``all_positions`` (speculative K+1 verify head) pass
     straight through: expert routing is position- and
-    layout-independent."""
-    return L.forward_cached(
+    layout-independent.  ``routing`` adds a third result: int32
+    ``[L, 3]``, per layer the experts with at least one row, the routed
+    rows and the largest group (``moe/routed.py RECORD``), counted over the
+    live tokens (``llama.live_tokens``)."""
+    live = L.live_tokens(input_ids, lengths, block_tables)
+    blocks, stacks = params["blocks"], None
+    if _expert_kernel(blocks):
+        # the expert stacks stay out of the layer scan: each layer's slice
+        # of them would be copied out for the kernel (134 MB a matmul at
+        # OLMoE's widths); the scan carries the layer's index instead
+        stacks = {k: blocks[k] for k in _EXPERT_LEAVES}
+        blocks = {k: v for k, v in blocks.items() if k not in stacks}
+        blocks["layer_index"] = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+        params = {**params, "blocks": blocks}
+    logits, cache, records = L.forward_cached(
         cfg, params, input_ids, cache, pos, lengths=lengths,
         block_tables=block_tables,
-        mlp_fn=lambda lyr, y: _moe_ffn(cfg, lyr, y, train=False)[0],
+        mlp_fn=lambda lyr, y: _routed(cfg, lyr, y, live, stacks),
         all_positions=all_positions)
+    return (logits, cache, records) if routing else (logits, cache)
 
 
 def tp_rules(cfg: MixtralConfig, abstract_params: PyTree) -> PyTree:
@@ -207,9 +276,12 @@ def build(cfg: Optional[MixtralConfig] = None, **overrides) -> ModelSpec:
         "init_cache": lambda b, s, dtype=jnp.bfloat16: L.init_cache(
             cfg, b, s, dtype),
         "forward_cached": lambda params, ids, cache, pos, lengths=None,
-            block_tables=None, all_positions=False:
+            block_tables=None, all_positions=False, routing=False:
             forward_cached(cfg, params, ids, cache, pos, lengths,
-                           block_tables, all_positions),
+                           block_tables, all_positions, routing),
+        # ``forward_cached(..., routing=True)`` returns the per-layer
+        # routing record as a third result (the serving engine's ring)
+        "routing_record": True,
         "max_seq_len": cfg.max_seq_len,
         "supports_lengths": True,
         "supports_paged": True,
@@ -225,13 +297,12 @@ def build(cfg: Optional[MixtralConfig] = None, **overrides) -> ModelSpec:
     return ModelSpec(
         init_fn=init_fn, model_config=cfg, loss_fn=loss_fn, apply_fn=apply_fn,
         tp_rules=lambda ap: tp_rules(cfg, ap),
-        flops_per_token=6.0 * (cfg.num_params() / cfg.num_experts *
-                               (cfg.top_k + 1)),
+        flops_per_token=6.0 * cfg.active_params(),
         decode_hooks=decode_hooks,
         # w8a8 serving: attention projections run the s8 path through the
         # shared mm accessors; stacked expert weights store int8 and
-        # dequantize per layer at point of use inside moe_apply (the MoE
-        # dispatch einsums have no K-grouped kernel — yet)
+        # dequantize per layer at point of use inside routed_ffn (the
+        # grouped matmuls have no s8 kernel — yet)
         quant_aware=True,
         blocks_key=("blocks",),
         name=f"mixtral-{cfg.num_layers}l-{cfg.num_experts}e")

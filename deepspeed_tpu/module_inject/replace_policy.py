@@ -168,9 +168,8 @@ def _mixtral_translate(hf):
         num_kv_heads=hf.num_key_value_heads, hidden_size=hf.hidden_size,
         ffn_size=hf.intermediate_size,
         rope_theta=getattr(hf, "rope_theta", 1e6),
-        num_experts=hf.num_local_experts, top_k=hf.num_experts_per_tok,
-        # drop-free routing = HF semantics (see MixtralConfig docstring)
-        eval_capacity_factor=float(hf.num_local_experts))
+        # inference is dropless (moe/routed.py): HF's routing semantics
+        num_experts=hf.num_local_experts, top_k=hf.num_experts_per_tok)
 
 
 def _mixtral_convert(cfg, sd) -> PyTree:

@@ -1,0 +1,120 @@
+"""Dropless routed FFN for inference: every routed (token, expert) pair is
+computed, for any ``k`` in ``[1, E]``.
+
+``sharded_moe.py`` buckets tokens into per-expert capacity slots with dense
+one-hot einsums over ``[G, S, E, C]`` and drops what overflows — the right
+shape for training under ``ep`` (fixed-size all-to-all), the wrong one for
+serving: a decode batch arrives as ``[slots, 1, D]``, every slot is its own
+group, and each of the E experts runs over ``slots x min_capacity`` rows of
+which ``slots x k / E`` are real.  Here the ``T x k`` pairs are sorted by
+expert and the three expert matmuls run as **grouped** matmuls over the
+ragged groups, so an expert's weights are read once whatever its group's
+size and an expert nobody chose is not read at all.  Two grouped matmuls,
+chosen by the caller from what it can observe (``models/mixtral.py``):
+
+* ``moe/grouped_matmul.moe_gmm`` — the Pallas kernel (interpreted on the
+  CPU, so the tests run the same code).  It takes the WHOLE ``[L, E, ..]``
+  weight stacks and the layer index, so a layer scan never slices 134 MB
+  operands out of them.
+* ``jax.lax.ragged_dot`` — XLA's own (a Mosaic kernel of its own on the
+  TPU, a reference loop on the CPU).  It has a partitioning rule, so it
+  serves ``tp``/``ep`` meshes, and it takes one layer's weights, so it
+  serves INT8 records that are expanded a layer at a time.
+
+    p = softmax_fp32(y Wr);  S = top-k of p;  (renormalize: p_S / sum p_S)
+    out = sum_{e in S} p_e * (silu(y W1_e) * (y W3_e)) W2_e
+"""
+
+from __future__ import annotations
+
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+
+from .grouped_matmul import moe_gmm
+
+#: columns of the routing record ``routed_ffn`` returns
+RECORD = ("experts_touched", "expert_rows", "expert_rows_max")
+
+
+def _dense(w, dtype):
+    """Expert weights may arrive as INT8 records (quant-aware serving):
+    expanded here, per layer at point of use, as ``moe_apply`` does."""
+    from ..ops import quantization as quant
+
+    if quant.is_k_quantized(w):
+        return quant.dequantize_k(w, dtype)
+    if quant.is_quantized(w):
+        return quant.dequantize(w, dtype)
+    return w.astype(dtype)
+
+
+def route(y2d, gate_w, k: int, renormalize: bool):
+    """Router of ``[T, D]`` tokens: float32 logits and softmax over ALL
+    experts, then top-k.  -> (weights float32 [T, k], experts int32 [T, k])."""
+    logits = jnp.dot(y2d, _dense(gate_w, y2d.dtype),
+                     preferred_element_type=jnp.float32)
+    top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    if renormalize:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return top_p, top_e.astype(jnp.int32)
+
+
+def _grouped(xs, w, group_sizes, layer, kernel: bool):
+    """``xs`` rows sorted by expert x that expert's ``w[layer]``."""
+    if kernel:
+        return moe_gmm(xs, w.astype(xs.dtype), group_sizes, layer)
+    if layer is not None:
+        w = jax.tree_util.tree_map(lambda a: a[layer], w)
+    return jax.lax.ragged_dot(xs, _dense(w, xs.dtype), group_sizes)
+
+
+def routed_ffn(y, gate_w, w1, w3, w2, k: int, renormalize: bool,
+               live=None, layer=None, kernel: bool = True
+               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """SwiGLU experts over ``y [..., D]``: ``gate_w [D, E]``, ``w1``/``w3``
+    ``[E, D, F]``, ``w2 [E, F, D]`` — or, with ``layer`` (traced index),
+    the whole stacks ``[L, E, ..]``.  No capacity, no drop.  ``kernel``
+    picks the grouped matmul (module docstring).
+
+    Returns ``(out, record)``: ``out`` like ``y``; ``record`` int32 ``[3]``
+    (``RECORD``): experts with at least one row, routed rows, the largest
+    group — counted over the ``live`` tokens only (bool, ``y``'s leading
+    shape; padding rows and idle slots are computed like any row but are
+    nobody's traffic).  ``live=None`` counts every token."""
+    shape, d = y.shape, y.shape[-1]
+    x = y.reshape(-1, d)
+    t, e = x.shape[0], gate_w.shape[-1]
+    if not 1 <= k <= e:
+        raise ValueError(f"top_k={k} outside [1, num_experts={e}]")
+
+    with jax.named_scope("layer/moe/route"):
+        top_p, top_e = route(x, gate_w, k, renormalize)
+        flat_e = top_e.reshape(-1)                               # [T*k]
+        # stable: pairs of one expert keep token order (deterministic sums)
+        order = jnp.argsort(flat_e, stable=True)
+        hits = flat_e[:, None] == jnp.arange(e, dtype=jnp.int32)[None, :]
+        group_sizes = hits.sum(0, dtype=jnp.int32)               # [E]
+        xs = x[order // k]                                       # [T*k, D]
+
+    with jax.named_scope("layer/moe/experts"):
+        gate = _grouped(xs, w1, group_sizes, layer, kernel)
+        up = _grouped(xs, w3, group_sizes, layer, kernel)
+        out = _grouped(jax.nn.silu(gate) * up, w2, group_sizes, layer,
+                       kernel)
+
+    with jax.named_scope("layer/moe/combine"):
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(t * k, dtype=order.dtype))
+        pairs = out[inverse].reshape(t, k, d).astype(jnp.float32)
+        mixed = jnp.einsum("tk,tkd->td", top_p, pairs).astype(y.dtype)
+
+        if live is None:
+            counts = group_sizes
+        else:
+            alive = jnp.repeat(live.reshape(-1), k)              # [T*k]
+            counts = (hits & alive[:, None]).sum(0, dtype=jnp.int32)
+        record = jnp.stack([(counts > 0).sum(dtype=jnp.int32),
+                            counts.sum(dtype=jnp.int32), counts.max()])
+    return mixed.reshape(shape), record

@@ -20,7 +20,7 @@ from deepspeed_tpu.ops import quantized_matmul as qmm
 
 #: (name, query heads == KV heads, head_dim, context): MHA families
 ATTN_SHAPES = [("opt-1.3b", 32, 64, 2048), ("opt-6.7b", 32, 128, 2048),
-               ("gpt2-125m", 12, 64, 1024)]
+               ("gpt2-125m", 12, 64, 1024), ("olmoe-1b-7b", 16, 128, 1024)]
 SLOTS, BLOCK = 8, 32          # init_serving defaults
 
 
@@ -64,6 +64,21 @@ def test_contiguous_decode_lowers(name, h, hd, ctx):
     _lower_tpu(lambda q, k, v, pos: da.decode_attention_pallas(
         q, k, v, pos, interpret=False), q, cache, cache,
         _sds((SLOTS,), jnp.int32))
+
+
+@pytest.mark.parametrize("rows", [512, 4096], ids=["decode", "prefill"])
+@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)],
+                         ids=["gate-up", "down"])
+def test_grouped_expert_matmul_lowers(rows, k, n):
+    """``moe_gmm`` at OLMoE's widths: 64 experts' whole 8-layer stacks, a
+    decode step's 64 x top-8 rows and a [4, 128] prefill chunk's."""
+    from deepspeed_tpu.moe.grouped_matmul import moe_gmm
+
+    text = _lower_tpu(
+        lambda x, w, gs, l: moe_gmm(x, w, gs, l, interpret=False),
+        _sds((rows, k), jnp.bfloat16), _sds((8, 64, k, n), jnp.bfloat16),
+        _sds((64,), jnp.int32), _sds((), jnp.int32))
+    assert 'kernel_name = "moe_gmm"' in text
 
 
 def test_flash_train_step_kernels_lower():

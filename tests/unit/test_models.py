@@ -135,9 +135,9 @@ def test_mixtral_experts_sharded(eight_devices):
 
 
 def test_mixtral_matches_hf():
-    """HF MixtralForCausalLM ingestion: drop-free eval routing must
-    reproduce HF's top-2 expert mixing (policy sets eval_capacity_factor
-    = num_experts)."""
+    """HF MixtralForCausalLM ingestion: the inference forward routes
+    droplessly (``moe/routed.py``: softmax over all experts, top-2,
+    renormalised), which is HF's top-2 expert mixing — no capacity to set."""
     transformers = pytest.importorskip("transformers")
     torch = pytest.importorskip("torch")
 
@@ -151,6 +151,8 @@ def test_mixtral_matches_hf():
         hf = transformers.MixtralForCausalLM(cfg)
     hf.eval()
     spec, params = deepspeed_tpu.module_inject.replace_module(hf_model=hf)
+    assert spec.model_config.norm_topk_prob and spec.model_config.top_k == 2
+    assert not hasattr(spec.model_config, "eval_capacity_factor")
     ids = np.random.default_rng(0).integers(2, 96, (2, 12)).astype(np.int32)
     ours = np.asarray(spec.apply_fn(params, {"input_ids": ids}))
     with torch.no_grad():

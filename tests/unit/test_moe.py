@@ -117,8 +117,9 @@ def test_mixtral_kv_cache_decode_matches_forward():
 
     cfg = mixtral.MixtralConfig.tiny()
     cfg.use_flash = False
-    # exact decode parity needs drop-free eval routing (documented mode)
-    cfg.eval_capacity_factor = float(cfg.num_experts)
+    # every inference forward routes droplessly (moe/routed.py): decode
+    # parity holds by construction, no capacity setting involved
+    assert not hasattr(cfg, "eval_capacity_factor")
     params = mixtral.init_params(cfg, jax.random.PRNGKey(0))
     ids = np.random.default_rng(1).integers(0, 512, (2, 12)).astype(np.int32)
     full = np.asarray(mixtral.forward_with_aux(cfg, params, ids,

@@ -299,6 +299,43 @@ def test_the_programs_keep_the_names_the_reduction_finds_them_by(
     assert lower(tiny, kind) == kind
 
 
+def test_an_expert_familys_spans_carry_the_routing_names():
+    """``chipbench``'s ``expert_rows_per_read`` and ``families/olmoe.py``
+    read ``experts_touched`` / ``expert_rows`` off the in-flight ``decode``
+    and ``prefill`` spans; ``stats()`` and the registry carry the totals;
+    the routed FFN's three phases are named scopes of the programs."""
+    from deepspeed_tpu import comm
+    from deepspeed_tpu.models import mixtral
+    from deepspeed_tpu.moe import routed
+
+    comm.reset_topology()
+    cfg = mixtral.MixtralConfig.tiny()
+    cfg.use_flash = False
+    srv = deepspeed_tpu.init_serving(mixtral.build(cfg),
+                                     config={"dtype": "fp32"}, **SERVE_KW)
+    srv.serve(_requests(cfg, n=3))
+    flights = [e for e in srv.timeline.events() if e["ph"] == "X"
+               and e["name"] in IN_FLIGHT]
+    assert {e["name"] for e in flights} == set(IN_FLIGHT)
+    for e in flights:
+        assert set(routed.RECORD) <= set(e["args"]), e
+        assert e["args"]["expert_rows"] >= e["args"]["experts_touched"] > 0
+        assert e["args"]["expert_rows"] >= e["args"]["expert_rows_max"] > 0
+    st = srv.stats()
+    assert st["moe_expert_rows"] == sum(e["args"]["expert_rows"]
+                                        for e in flights)
+    assert st["moe_experts_touched"] == sum(e["args"]["experts_touched"]
+                                            for e in flights)
+    text = jax.jit(srv._program_bodies["decode"]).lower(
+        srv.engine.params, srv._cache, jnp.zeros(3, jnp.int32),
+        jnp.zeros(3, jnp.int32), jnp.zeros((3, srv._nbper), jnp.int32),
+        *srv._samp_args(np.zeros(3, np.int32))).as_text(debug_info=True)
+    for scope in ("layer/moe/route", "layer/moe/experts",
+                  "layer/moe/combine", "layer/attn"):
+        assert scope in text, scope
+    srv.close()
+
+
 def _lower_tpu(fn, *args):
     return jax.export.export(jax.jit(fn), platforms=["tpu"])(*args) \
         .mlir_module()
@@ -330,6 +367,14 @@ def test_lowered_kernels_carry_their_own_names():
                 q, k, v, bt, pos, interpret=False),
             sds((slots, h, t, hd), jnp.bfloat16), pool, pool, bt, pos))
     assert got == {"paged_decode_attn", "paged_verify_attn"}
+
+    # the experts' grouped matmul (chipbench's expert_ffn_ms selects it)
+    from deepspeed_tpu.moe.grouped_matmul import moe_gmm
+
+    assert _kernel_names(_lower_tpu(
+        lambda x, w, gs: moe_gmm(x, w, gs, jnp.int32(1), interpret=False),
+        sds((64, 128), jnp.bfloat16), sds((2, 8, 128, 256), jnp.bfloat16),
+        sds((8,), jnp.int32))) == {"moe_gmm"}
 
     def loss(q, k, v, block):
         o = fa.flash_attention(q, k, v, causal=True, block_q=block,

@@ -51,6 +51,9 @@ ENGINE_STATS_KEYS = frozenset({
     "handoffs",
     "iterations", "kv_dtype", "kv_pool_bytes", "kv_pool_bytes_per_chip",
     "kv_pool_shape", "kv_scale_bytes", "kv_sharded", "mode",
+    # PR 28: routed (token, expert) rows and experts touched, summed over
+    # layers and program calls; 0 for a dense model
+    "moe_expert_rows", "moe_experts_touched",
     "num_blocks", "nvme_blocks", "nvme_blocks_in_use", "nvme_loads",
     "nvme_spills", "prefetch_misses", "prefetch_wait_p50_s",
     "prefetch_wait_p95_s", "prefill_calls", "prefix_cache_entries",

@@ -37,8 +37,15 @@ def _tiny(smoke):
     # script's several-chips path (topology=n, dp=n) runs here too
     o = opt.OPTConfig(vocab_size=512, max_seq_len=128, num_layers=2,
                       num_heads=8, hidden_size=128, ffn_size=256)
+    from deepspeed_tpu.models import mixtral
+
+    # head_dim 128 (the g = 1 branch of the packed pool), top-4 of 8
+    moe = mixtral.MixtralConfig(
+        vocab_size=512, max_seq_len=128, num_layers=2, num_heads=2,
+        num_kv_heads=2, hidden_size=256, ffn_size=64, num_experts=8,
+        top_k=4, norm_topk_prob=False, qk_norm=True)
     return smoke.Sizes(
-        opt=o, gpt2=g, dtype="bf16", prompt_lens=(3, 20, 40),
+        opt=o, gpt2=g, moe=moe, dtype="bf16", prompt_lens=(3, 20, 40),
         shared_prefix=16, new_tokens=(4, 6), score_len=40, score_decode=8,
         micro_bs=2, seq=32, gas=2, sync_dim=128, sync_iters=4,
         serving_kwargs={"block_size": 8, "prefill_chunk": 16})
