@@ -20,6 +20,17 @@ Two paths, one API:
    ``block_k`` chunks with an online softmax (f32 accumulation, no [S] score
    materialisation).  Chunks past the valid prefix are skipped with ``pl.when``
    so FLOPs scale with the *valid* length, not the workspace size.
+
+The serving path is the block-paged pool further down (``paged_decode_
+attention``).  Its kernels share the contiguous kernel's online softmax
+(:func:`_attend_chunk`) but not its iteration space: a grid step there is
+one ROW, which loops over its own valid blocks and copies each block — every
+KV head of it, contiguous in the lane-packed pool — out of HBM itself,
+double-buffered (:func:`_paged_walk_kernel`).  Time follows the tokens a row
+holds, not ``max_seq_len`` (a grid over (row, head, logical block) spent
+~0.17 us a step whether or not the step held a key: 24,576 steps a layer
+for 24 rows of ~160 tokens, 1 % of the HBM roofline; the walk reads the same
+bytes in ~140 copies of 256 KB at 30-45 %, PERF.md PR 29).
 """
 
 from __future__ import annotations
@@ -127,93 +138,101 @@ def decode_attention_reference(q, k_cache, v_cache, q_pos, *,
 # ---------------------------------------------------------------------------
 # Pallas single-token decode kernel
 # ---------------------------------------------------------------------------
-def _span_rows(scale_row, span, chunk):
-    """[rows, chunk] scales for span-major query rows: row i takes lanes
-    ``span[i]*chunk ..`` of the [1, g*chunk] per-token scale row."""
-    g = scale_row.shape[1] // chunk
-    out = scale_row[:, :chunk]
-    for h in range(1, g):
-        out = jnp.where(span == h, scale_row[:, h * chunk:(h + 1) * chunk],
+def _span_rows(scales, span, chunk, spans):
+    """[H, rows, chunk] scales for span-major query rows: row i takes lanes
+    ``span[i]*chunk ..`` of its head's per-token scale row (``scales``
+    [H, >= spans*chunk], token order; lanes past the block are padding)."""
+    scales = scales.astype(jnp.float32)[:, None, :]
+    out = scales[:, :, :chunk]
+    for h in range(1, spans):
+        out = jnp.where(span == h, scales[:, :, h * chunk:(h + 1) * chunk],
                         out)
-    return out.astype(jnp.float32)
+    return out
 
 
-def _span_queries(qg, spans: int):
-    """``[B, HKV, rows, D] -> [B, HKV, spans*rows, spans*D]``: every query
+def _span_queries(qg, spans: int, width: int):
+    """``[B, HKV, rows, D] -> [B, HKV, spans*rows, width]``: every query
     once per span, span-major, its D values in the span's lane group and
     zeros elsewhere (the operand :func:`_attend_chunk` expects; the identity
-    for ``spans == 1``)."""
-    if spans == 1:
-        return qg
+    for ``spans == 1`` and ``width == D``).  ``width`` is ``spans * D``, or
+    more where the pool's rows were padded to whole lane tiles."""
     d = qg.shape[-1]
     return jnp.concatenate(
-        [jnp.pad(qg, ((0, 0), (0, 0), (0, 0), (h * d, (spans - 1 - h) * d)))
+        [jnp.pad(qg, ((0, 0), (0, 0), (0, 0), (h * d, width - (h + 1) * d)))
          for h in range(spans)], axis=2)
 
 
-def _attend_chunk(q_ref, k_ref, v_ref, ks_ref, vs_ref, keep, start, sm_scale,
+def _attend_chunk(q, k, v, ks, vs, keep, start, sm_scale,
                   m_scr, l_scr, acc_scr, *, spans: int):
-    """One online-softmax update of ``m/l/acc`` with a KV chunk — the body
-    the decode and verify kernels share.
+    """One online-softmax update of ``m/l/acc`` with a KV chunk of H heads
+    — the body the contiguous, paged-decode and paged-verify kernels share
+    (the contiguous kernel passes H = 1, the paged ones every KV head of a
+    block at once).
 
-    ``k_ref``/``v_ref`` blocks [1, 1, R, g*D]: a chunk of ``g*R`` keys held
-    as ``g = spans`` consecutive R-key SPANS side by side in the lanes
+    ``k``/``v`` [H, R, g*D]: per head a chunk of ``g*R`` keys held as ``g =
+    spans`` consecutive R-key SPANS side by side in the lanes
     (``paged_kv.pack_pool``; g == 1 is the plain [bk, D] chunk of a
-    contiguous cache or an unpacked pool).  ``q_ref`` [1, 1, g*rows, g*D]
-    carries each query g times, span-major (:func:`_span_queries`), so ONE
+    contiguous cache or an unpacked pool).  ``q`` [H, g*rows, g*D] carries
+    each query g times, span-major (:func:`_span_queries`), so ONE batched
     ``q·kᵀ`` over the whole tile gives row ``h*rows + i`` the scores of
     query i against span h (keys ``start + h*R ..``) — no lane slicing of
     the tile: the arithmetic of the g == 1 body on g times the rows.  Each
     span row keeps its own (m, l, acc) like an independent query;
     :func:`_finish_chunks` merges the g partial softmaxes of a query once,
     at the end.  ``keep(idx, query) -> bool`` is the caller's causal mask
-    on key positions ``idx`` for query rows ``query`` (both [g*rows, R]).
+    on key positions ``idx`` for query rows ``query`` (both [H, g*rows, R]).
 
-    ``ks_ref``/``vs_ref`` (int8-KV pools only): [1, 1, 1, g*R] per-token
-    dequant scales in token order, so span h reads lanes ``h*R ..``.  They
-    fold into the math on its 2-D lane-dim tiles — ``q·(code*s_k) =
-    (q·code)*s_k`` on the score columns, ``Σ p·(code*s_v) = (p*s_v)·code``
-    on the prob columns — so no dequantized copy is ever materialized and
-    the online softmax (which normalizes over UNscaled probabilities) is
-    untouched."""
-    q = q_ref[0, 0].astype(jnp.float32)               # [g*rows, g*D]
-    k = k_ref[0, 0].astype(jnp.float32)               # [R, g*D]
-    v = v_ref[0, 0].astype(jnp.float32)
-    r, per = k.shape[0], q.shape[0] // spans
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+    ``ks``/``vs`` (int8-KV pools only): [H, g*R] per-token dequant scales
+    in token order, so span h reads lanes ``h*R ..``.  They fold into the
+    math on its lane-dim tiles — ``q·(code*s_k) = (q·code)*s_k`` on the
+    score columns, ``Σ p·(code*s_v) = (p*s_v)·code`` on the prob columns —
+    so no dequantized copy is ever materialized and the online softmax
+    (which normalizes over UNscaled probabilities) is untouched."""
+    q = q.astype(jnp.float32)                         # [H, g*rows, g*D]
+    k = k.astype(jnp.float32)                         # [H, R, g*D]
+    v = v.astype(jnp.float32)
+    r, per = k.shape[1], q.shape[1] // spans
+    s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                             preferred_element_type=jnp.float32)
-    s = s * sm_scale                                  # [g*rows, R]
-    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    s = s * sm_scale                                  # [H, g*rows, R]
+    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     span = row // per
-    if ks_ref is not None:
-        s = s * _span_rows(ks_ref[0, 0], span, r)
-    idx = start + span * r + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    if ks is not None:
+        s = s * _span_rows(ks, span, r, spans)
+    idx = start + span * r + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
     s = jnp.where(keep(idx, row % per), s, NEG_INF)
 
-    m_prev = m_scr[...][:, :1]                        # [g*rows, 1]
+    m_prev = m_scr[...][:, :, :1]                     # [H, g*rows, 1]
     m_cur = jnp.max(s, axis=-1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)                            # [g*rows, R]
-    l_new = l_scr[...][:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    pv = p * _span_rows(vs_ref[0, 0], span, r) if vs_ref is not None else p
+    p = jnp.exp(s - m_new)                            # [H, g*rows, R]
+    l_new = l_scr[...][:, :, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    pv = p * _span_rows(vs, span, r, spans) if vs is not None else p
     acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        pv, v, (((1,), (0,)), ((), ())),
+        pv, v, (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)
     m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
     l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
 
+def _start_chunks(m_scr, l_scr, acc_scr):
+    """Empty online-softmax state: no key seen yet."""
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
 def _finish_chunks(o_ref, m_scr, l_scr, acc_scr, *, spans: int):
-    """Write ``acc / l`` — after merging, for ``spans > 1``, each query's
-    per-span partial softmaxes (flash-decoding's split-K merge: weights
-    ``exp(m_h - max_h m_h)``; a span that saw no unmasked key has ``m_h =
-    NEG_INF`` and weighs 0).  Span h's numerator sits in lane group h of its
-    rows of ``acc``."""
+    """Write ``acc / l`` to ``o_ref`` [1, H, rows, D] — after merging, for
+    ``spans > 1``, each query's per-span partial softmaxes (flash-decoding's
+    split-K merge: weights ``exp(m_h - max_h m_h)``; a span that saw no
+    unmasked key has ``m_h = NEG_INF`` and weighs 0).  Span h's numerator
+    sits in lane group h of its rows of ``acc``."""
     per, d = o_ref.shape[2], o_ref.shape[3]
-    m, l, acc = m_scr[...][:, :1], l_scr[...][:, :1], acc_scr[...]
-    parts = [(m[h * per:(h + 1) * per], l[h * per:(h + 1) * per],
-              acc[h * per:(h + 1) * per, h * d:(h + 1) * d])
+    m, l, acc = m_scr[...][:, :, :1], l_scr[...][:, :, :1], acc_scr[...]
+    parts = [(m[:, h * per:(h + 1) * per], l[:, h * per:(h + 1) * per],
+              acc[:, h * per:(h + 1) * per, h * d:(h + 1) * d])
              for h in range(spans)]
     m_all = parts[0][0]
     for m_h, _, _ in parts[1:]:
@@ -222,18 +241,16 @@ def _finish_chunks(o_ref, m_scr, l_scr, acc_scr, *, spans: int):
     for m_h, l_h, acc_h in parts:
         w = jnp.exp(m_h - m_all)
         num, den = num + w * acc_h, den + w * l_h
-    o_ref[0, 0] = (num / jnp.where(den == 0.0, 1.0, den)).astype(o_ref.dtype)
+    o_ref[0] = (num / jnp.where(den == 0.0, 1.0, den)).astype(o_ref.dtype)
 
 
 def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-                   *, sm_scale: float, block_k: int, spans: int = 1,
-                   ks_ref=None, vs_ref=None):
+                   *, sm_scale: float, block_k: int):
     """Grid: (B, HKV, S // block_k), KV innermost so scratch carries across.
 
-    q_ref: the ``rep`` query heads sharing this KV head, [1, 1, rep, D] (or
-    span-expanded, :func:`_attend_chunk`); o_ref: [1, 1, rep, D].
-    k_ref/v_ref: one ``block_k``-key chunk of the cache, [1, 1, block_k, D]
-    or lane-packed in ``spans`` spans.
+    q_ref: the ``rep`` query heads sharing this KV head, [1, 1, rep, D];
+    o_ref: [1, 1, rep, D].  k_ref/v_ref: one ``block_k``-key chunk of the
+    cache, [1, 1, block_k, D].
     pos_ref: int32 [B] in SMEM — per-row query position (a scalar q_pos is
     broadcast before the call), read for the row this grid step covers, so
     chunk skipping scales FLOPs with each slot's own valid length.
@@ -244,21 +261,19 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
     @pl.when(kb == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        _start_chunks(m_scr, l_scr, acc_scr)
 
     start = kb * block_k
 
     @pl.when(start <= pos)  # skip chunks entirely past the valid prefix
     def _compute():
-        _attend_chunk(q_ref, k_ref, v_ref, ks_ref, vs_ref,
+        _attend_chunk(q_ref[0], k_ref[0], v_ref[0], None, None,
                       lambda idx, query: idx <= pos, start, sm_scale,
-                      m_scr, l_scr, acc_scr, spans=spans)
+                      m_scr, l_scr, acc_scr, spans=1)
 
     @pl.when(kb == nk - 1)
     def _finish():
-        _finish_chunks(o_ref, m_scr, l_scr, acc_scr, spans=spans)
+        _finish_chunks(o_ref, m_scr, l_scr, acc_scr, spans=1)
 
 
 def decode_attention_pallas(q, k_cache, v_cache, q_pos, *,
@@ -293,9 +308,9 @@ def decode_attention_pallas(q, k_cache, v_cache, q_pos, *,
         out_specs=pl.BlockSpec((1, 1, rep, d), lambda i, j, k: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((rep, LANES), jnp.float32),    # m
-            pltpu.VMEM((rep, LANES), jnp.float32),    # l
-            pltpu.VMEM((rep, d), jnp.float32),        # acc
+            pltpu.VMEM((1, rep, LANES), jnp.float32),     # m
+            pltpu.VMEM((1, rep, LANES), jnp.float32),     # l
+            pltpu.VMEM((1, rep, d), jnp.float32),         # acc
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -317,12 +332,14 @@ def decode_attention(q, k_cache, v_cache, q_pos, *,
 
 # ---------------------------------------------------------------------------
 # Block-paged attention (vLLM PagedAttention layout; ops/paged_kv.py holds
-# the layout contract).  KV lives in the stacked pool [L, NB, HKV, bs, D],
-# addressed in place as (layer, physical block, head, offset); each row
-# reaches its tokens through an int32 [B, NBPER] block table.  Every entry
-# point takes the whole pool plus ``layer`` — or one layer's [NB, HKV, bs,
-# D] with ``layer=None``, viewed as a one-layer stack
-# (``paged_kv.whole_pool``).
+# the layout contract).  KV lives in the stacked pool [L, NB, HKV, bs, D]
+# (lane-packed [L, NB, HKV, bs/g, g*D] in a serving engine), addressed in
+# place as (layer, physical block): each row reaches its tokens through an
+# int32 [B, NBPER] block table.  Every entry point takes the whole pool plus
+# ``layer`` — or one layer's [NB, HKV, bs, D] with ``layer=None``, viewed as
+# a one-layer stack (``paged_kv.whole_pool``).  The kernels take the pool as
+# an HBM operand and copy out of it, per row, the blocks that row holds —
+# all KV heads of a block in one DMA (``_paged_walk_kernel``).
 #
 # Tensor parallelism: when ops/paged_kv carries a configured tp context and
 # the head counts divide its axis, each paged-attention entry point runs
@@ -401,132 +418,222 @@ def paged_decode_attention_reference(q, k_pool, v_pool, block_tables, q_pos,
                            layer)
 
 
-def _pool_refs(refs, quant: bool):
-    """``(k, ks, v, vs, o, m, l, acc)`` from a paged kernel's refs after
-    ``q_ref`` — float pools carry no scale operands (``ks = vs = None``)."""
-    if quant:
-        return refs
-    k_ref, v_ref, *rest = refs
-    return (k_ref, None, v_ref, None, *rest)
+#: VMEM the paged kernels give their K/V landing buffers (two slots each)
+#: and the float32 copies :func:`_attend_chunk` makes of one block's tiles
+_WALK_VMEM_BUDGET = 8 << 20
 
 
-def _paged_decode_kernel(layer_ref, pos_ref, bt_ref, q_ref, *refs,
-                         sm_scale: float, block_size: int, spans: int,
-                         quant: bool = False):
-    """Grid: (B, HKV, NBPER), logical blocks innermost so scratch carries.
+def _walk_head_tile(hkv: int, r: int, width: int, itemsize: int) -> int:
+    """KV heads one grid step of the paged kernels takes, for a shard's
+    ``[hkv, r, width]`` blocks, from the shapes alone: all of them (OPT-1.3B
+    and OLMoE: 128 KB of K and of V a block in bf16, 0.8 MB of the budget)
+    unless ONE block of all heads, double-buffered, would overrun
+    :data:`_WALK_VMEM_BUDGET`.  Then the heads are split over a second grid
+    dim, in halves that stay a multiple of 16 (the sublane tile of the int8
+    records' bf16 scale rows, which the copies then slice by head).
+
+    A loop iteration is always ONE block: fetching 2-8 blocks an iteration
+    (the body unrolled per block) bought 5-13 % of the kernel at 128 KB
+    blocks, nothing at a tp shard's 16 KB, and cost 2.3 s of every start in
+    the two programs that hold the kernel (PERF.md PR 29)."""
+    def need(ht):
+        return ht * r * width * (4 * itemsize + 2 * 4)
+
+    ht = hkv
+    while need(ht) > _WALK_VMEM_BUDGET and ht % 32 == 0:
+        ht //= 2
+    return ht
+
+
+def _paged_walk_kernel(layer_ref, pos_ref, bt_ref, q_ref, *refs,
+                       sm_scale: float, t: int, spans: int, quant: bool):
+    """The paged decode (``t == 1``) and verify (``t`` window positions)
+    kernel.  Grid ``(B, HKV // ht)``: one step is one row's whole attention
+    for ``ht`` KV heads (all of the shard's, :func:`_walk_head_tile`).
 
     ``layer_ref`` int32 [1], ``pos_ref`` int32 [B] and ``bt_ref`` int32
-    [B, NBPER] arrive via scalar prefetch — the k/v BlockSpec index maps
-    read ``(layer_ref[0], bt_ref[b, i])`` so each grid step DMAs the row's
-    *physical* block of this layer straight from the stacked pool.  The
-    paging indirection lives entirely in those index maps: the body is the
-    contiguous kernel's online softmax unchanged (a logical block at grid
-    step ``kb`` holds positions ``kb*block_size ..``, exactly like a
-    contiguous chunk), including the ``pl.when`` skip of blocks past the
-    row's valid prefix.
+    [B, NBPER] arrive via scalar prefetch.  The pool operands stay in HBM
+    (``pl.ANY``): the kernel walks the row's VALID logical blocks only —
+    ``n = cdiv(last query position + 1, block_size)`` of them, read off
+    ``pos_ref``, never an entry of the table past them — and copies block
+    ``i`` itself, ``pool.at[layer, bt[b, i]]`` -> a ``[ht, bs/g, g*D]`` VMEM
+    buffer: every head of the block in ONE contiguous DMA (K and V; an int8
+    pool's ``[ht, bs]`` scale rows ride beside them).  The buffers have two
+    slots, so block ``i + 1`` lands while block ``i`` is attended.  A row
+    costs its own length: no grid step and no copy is spent on the part of
+    ``max_seq_len`` it does not hold.
 
-    ``quant``: the pool is int8 — two extra scale operands ([1, 1, 1, bs]
-    rows of the per-block scale table, same index maps) ride next to the
-    code blocks and dequantize in-kernel, so HBM traffic is codes +
-    scales only.
-    """
-    del layer_ref, bt_ref            # consumed by the BlockSpec index maps
-    k_ref, ks_ref, v_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = \
-        _pool_refs(refs, quant)
-    _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                   acc_scr, sm_scale=sm_scale, block_k=block_size,
-                   spans=spans, ks_ref=ks_ref, vs_ref=vs_ref)
+    ``q_ref`` [1, ht, g*rows, g*D] (span-expanded, :func:`_span_queries`),
+    ``rows = rep * t``: query row ``r*t + i`` is head ``r`` of its KV group
+    at window offset ``i``, global position ``base + i`` with ``base =
+    pos_ref[b]`` (decode: the one query at ``base``; verify: the window was
+    just scattered at ``base .. base + t - 1``).  The causal mask is per
+    query ROW (``key <= base + row % t``): a verify query sees the row's
+    history plus the window up to itself, never the unverified draft tail.
+    The arithmetic is :func:`_attend_chunk`'s, a block of all heads at a
+    time; ``o_ref`` [1, ht, rows, D] is written once, after the walk.
+
+    Refs after ``q_ref``: the pool operands (K, [K scales], V, [V scales]),
+    ``o_ref``, one two-slot landing buffer per pool operand, the DMA
+    semaphores ``[2, operands]``, then m / l / acc."""
+    n_ops = 4 if quant else 2
+    pools, o_ref = refs[:n_ops], refs[n_ops]
+    bufs = refs[n_ops + 1:2 * n_ops + 1]
+    sem, m_scr, l_scr, acc_scr = refs[2 * n_ops + 1:]
+    _, ht, r, _ = bufs[0].shape
+    bs = r * spans
+    b, layer = pl.program_id(0), layer_ref[0]
+    base = pos_ref[b]
+    # blocks that hold a key some query of the row may see: keys <= last
+    n = jnp.clip((base + t - 1 + bs) // bs, 0, bt_ref.shape[1])
+    whole = pools[0].shape[2] == ht
+    heads = pl.ds(pl.program_id(1) * ht, ht)
+
+    def copies(i, slot):
+        """Block ``i``'s copies into ``slot`` (``i < n``)."""
+        out = []
+        for op, (pool, buf) in enumerate(zip(pools, bufs)):
+            # an operand that is this layer's rows alone (_lane_rows)
+            # is a one-layer stack
+            src = (layer if pool.shape[0] > 1 else 0, bt_ref[b, i])
+            out.append(pltpu.make_async_copy(
+                pool.at[src if whole else src + (heads,)], buf.at[slot],
+                sem.at[slot, op]))
+        return out
+
+    def fetch(i, slot):
+        @pl.when(i < n)
+        def _start():
+            for copy in copies(i, slot):
+                copy.start()
+
+    def attend(i, carry):
+        slot = i % 2
+        fetch(i + 1, 1 - slot)
+        for copy in copies(i, slot):
+            copy.wait()
+        tiles = [buf[slot] for buf in bufs]
+        k, ks, v, vs = tiles if quant else (tiles[0], None, tiles[1], None)
+        _attend_chunk(q_ref[0], k, v, ks, vs,
+                      lambda idx, query: idx <= base + query % t,
+                      i * bs, sm_scale, m_scr, l_scr, acc_scr, spans=spans)
+        return carry
+
+    _start_chunks(m_scr, l_scr, acc_scr)
+    fetch(0, 0)
+    jax.lax.fori_loop(0, n, attend, None)
+    _finish_chunks(o_ref, m_scr, l_scr, acc_scr, spans=spans)
 
 
-def _paged_pool_operands(k_pool, v_pool, layer):
-    """(operand list, BlockSpec list, quant flag) for a stacked k/v pool
-    pair at ``layer`` — float pools contribute two operands, int8 records
-    four (codes + per-block scale rows), all walking the same ``(layer,
-    bt_ref[i, k])`` index map.  The pool payload rides whole: the layer dim
-    is squeezed out of its block (``None``) and indexed by the prefetched
-    ``layer_ref``, so the kernels see the [1, 1, bs, D] tiles they always
-    saw and no layer slice of the pool ever exists outside them."""
+def _lane_rows(leaf, layer, head_dim=None):
+    """A pool operand Mosaic can copy blocks out of: its minor dim whole
+    128-lane rows.  Mosaic takes a copy out of an HBM operand in whole lane
+    tiles only (a ``[HKV, 16, 64]`` or ``[HKV, 32]`` slice is refused at
+    lowering), so the engine's lane-packed payload — minor dim ``g*hd``, a
+    multiple of 128 — rides as it is, the WHOLE stack, and costs nothing
+    here.  Anything else rides as a copy of this layer's rows alone, a
+    one-layer stack ``[1, NB, HKV, ...]``: a payload exactly as
+    ``init_cache`` built it (``head_dim`` given: the benchmark's comparison
+    and the kernel tests pass one) is lane-packed first
+    (``paged_kv.pack_pool``: 128 // hd spans a row), then — like an int8
+    record's scale table ``[L, NB, HKV, bs]`` — zero-padded to whole rows.
+    1/L of the pool a call: fine for a comparison, not for serving."""
+    if leaf.shape[-1] % LANES == 0:
+        return leaf
+    rows = jax.lax.dynamic_index_in_dim(leaf, layer, keepdims=True)
+    if head_dim == rows.shape[-1]:
+        rows = paged_kv.pack_pool(rows)
+    return jnp.pad(rows, ((0, 0),) * (rows.ndim - 1)
+                   + ((0, -rows.shape[-1] % LANES),))
+
+
+def _paged_launch(q, k_pool, v_pool, block_tables, q_pos, layer, *,
+                  sm_scale: float):
+    """The one launch of the paged kernels, ``q`` [B, H, T, D] against one
+    shard's stacked pool at ``layer``, as ``(kernel, pallas_call keywords,
+    operands)``: decode and verify each keep a ``pl.pallas_call`` site of
+    their own only to give it their kernel's name as a constant (the trace
+    readers select by it).  Shapes may be the full head count or one
+    tp shard's slice — grid, GQA grouping and the walk's head tile
+    (:func:`_walk_head_tile`) are computed from the local arrays either
+    way, and the pool's packing is read off its minor dim.
+
+    Grid ``(B, HKV // ht)`` over queries regrouped ``[B, HKV, rep*T, D]``
+    (row ``r*T + i`` = head ``r`` of the KV group at window offset ``i`` —
+    the repeat-based GQA grouping); scalar prefetch of (layer, pos, block
+    table); the pool operands in HBM (``memory_space=pl.ANY``) — the
+    lane-packed payload the whole stack as it lies, so no slice or view of
+    it exists outside the kernel (:func:`_lane_rows` has the exceptions)."""
+    b, h, t, d = q.shape
     quant = is_quantized_pool(k_pool)
-    _, nb, hkv, rows, width = pool_payload(k_pool).shape   # either view
-    blk = pl.BlockSpec(
-        (None, 1, 1, rows, width),
-        lambda i, j, k, layer_ref, pos_ref, bt_ref:
-        (layer_ref[0], bt_ref[i, k], j, 0, 0))
-    if not quant:
-        return [k_pool, v_pool], [blk, blk], False
-    # scale rows ride as [NB, HKV, 1, bs] views of the layer's rows of the
-    # (small) table: Mosaic wants a block's last two dims tile-aligned or
-    # equal to the array's, and a (1, bs) tail of the table is neither
-    # (refused at lowering).  The view re-tiles what it views, so it is
-    # taken of this layer's 1/L of the table, not of the carried whole.
-    bs = k_pool["ps"].shape[3]
-    sblk = pl.BlockSpec(
-        (1, 1, 1, bs),
-        lambda i, j, k, layer_ref, pos_ref, bt_ref: (bt_ref[i, k], j, 0, 0))
-    ks, vs = (jax.lax.dynamic_index_in_dim(p["ps"], layer, keepdims=False)
-              .reshape(nb, hkv, 1, bs) for p in (k_pool, v_pool))
-    return ([k_pool["qp"], ks, v_pool["qp"], vs],
-            [blk, sblk, blk, sblk], True)
-
-
-def _paged_launch(qg, k_pool, v_pool, block_tables, q_pos, layer):
-    """What the decode and verify launches share, as ``(pallas_call
-    keywords, operands, static kernel keywords)``: grid (B, HKV, NBPER)
-    over ``qg`` [B, HKV, rows, D], scalar prefetch of (layer, pos, block
-    table), pool operands by :func:`_paged_pool_operands`, the queries
-    span-expanded to the pool's packing (read off its minor dim).  Each
-    launch site keeps its own ``pl.pallas_call`` with its kernel's name as
-    a constant (the trace readers select by it)."""
-    b, hkv, rows, d = qg.shape
-    nbper = block_tables.shape[1]
-    r, width = pool_payload(k_pool).shape[3:]
-    spans = width // d
+    _, nb, _, r_in, w_in = pool_payload(k_pool).shape
+    pools = [_lane_rows(pool_payload(p), layer, d) for p in (k_pool, v_pool)]
+    if quant:
+        pools = [pools[0], _lane_rows(k_pool["ps"], layer),
+                 pools[1], _lane_rows(v_pool["ps"], layer)]
+    hkv, r, width = pools[0].shape[2:]
+    rows, spans = h // hkv * t, r_in * w_in // d // r     # block_size over R
+    ht = _walk_head_tile(hkv, r, width, pools[0].dtype.itemsize)
+    qg = _span_queries(q.reshape(b, hkv, rows, d), spans, width)
     pos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1), (b,))
-    bt = jnp.maximum(jnp.asarray(block_tables, jnp.int32), 0)
-    pools, pool_specs, quant = _paged_pool_operands(k_pool, v_pool, layer)
+    # a copy is issued for entries of a row's valid prefix only; clipped all
+    # the same, so that no table can send a DMA outside the pool
+    bt = jnp.clip(jnp.asarray(block_tables, jnp.int32), 0, nb - 1)
 
     def row_block(n_rows, n_lanes):
-        return pl.BlockSpec((1, 1, n_rows, n_lanes),
-                            lambda i, j, k, layer_ref, pos_ref, bt_ref:
+        return pl.BlockSpec((1, ht, n_rows, n_lanes),
+                            lambda i, j, layer_ref, pos_ref, bt_ref:
                             (i, j, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,                    # layer, pos, block table
-        grid=(b, hkv, nbper),
-        in_specs=[row_block(spans * rows, width)] + pool_specs,
+        grid=(b, hkv // ht),
+        in_specs=[row_block(spans * rows, width)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
         out_specs=row_block(rows, d),
-        scratch_shapes=[
-            pltpu.VMEM((spans * rows, LANES), jnp.float32),   # m
-            pltpu.VMEM((spans * rows, LANES), jnp.float32),   # l
-            pltpu.VMEM((spans * rows, width), jnp.float32),   # acc
+        scratch_shapes=[pltpu.VMEM((2, ht) + p.shape[3:], p.dtype)
+                        for p in pools] + [
+            pltpu.SemaphoreType.DMA((2, len(pools))),
+            pltpu.VMEM((ht, spans * rows, LANES), jnp.float32),   # m
+            pltpu.VMEM((ht, spans * rows, LANES), jnp.float32),   # l
+            pltpu.VMEM((ht, spans * rows, width), jnp.float32),   # acc
         ],
     )
+    kernel = functools.partial(_paged_walk_kernel, sm_scale=sm_scale, t=t,
+                               spans=spans, quant=quant)
     call = dict(
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")))
-    operands = (jnp.asarray(layer, jnp.int32).reshape(1), pos, bt,
-                _span_queries(qg, spans), *pools)
-    return call, operands, dict(block_size=r * spans, spans=spans,
-                                quant=quant)
+            dimension_semantics=("parallel", "parallel")))
+    return kernel, call, (jnp.asarray(layer, jnp.int32).reshape(1), pos, bt,
+                          qg, *pools)
 
 
-def _paged_decode_pallas(q, k_pool, v_pool, block_tables, q_pos, layer, *,
-                         sm_scale: float, interpret: bool):
-    """Single-shard kernel launch of :func:`paged_decode_attention_pallas`
-    (shapes may be the full head count or one tp shard's slice — the grid
-    and GQA grouping are computed from the local arrays either way)."""
-    b, h, t, d = q.shape
-    hkv = pool_payload(k_pool).shape[2]
-    rep = h // hkv
-    qg = q[:, :, 0, :].reshape(b, hkv, rep, d)        # [B, HKV, rep, D]
-    call, operands, static = _paged_launch(qg, k_pool, v_pool, block_tables,
-                                           q_pos, layer)
-    out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, sm_scale=sm_scale, **static),
-        interpret=interpret, name="paged_decode_attn", **call)(*operands)
-    return out.reshape(b, h, 1, d)
+def _paged_decode_call(q, *args, interpret: bool, **kwargs):
+    kernel, call, operands = _paged_launch(q, *args, **kwargs)
+    return pl.pallas_call(kernel, interpret=interpret,
+                          name="paged_decode_attn", **call)(
+        *operands).reshape(q.shape)
+
+
+def _paged_verify_call(q, *args, interpret: bool, **kwargs):
+    kernel, call, operands = _paged_launch(q, *args, **kwargs)
+    return pl.pallas_call(kernel, interpret=interpret,
+                          name="paged_verify_attn", **call)(
+        *operands).reshape(q.shape)
+
+
+def _paged_attention_pallas(launch, q, k_pool, v_pool, block_tables, q_pos,
+                            sm_scale, interpret, layer):
+    """``launch`` (one of the two call sites above) per head shard under a
+    configured tp / dp context (:func:`_tp_shard_heads`)."""
+    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    if interpret is None:
+        interpret = interpret_kernels()
+    body = functools.partial(launch, sm_scale=scale, interpret=interpret)
+    return _tp_shard_heads(body, q, k_pool, v_pool, block_tables, q_pos,
+                           layer)
 
 
 def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
@@ -534,88 +641,20 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
                                   interpret: Optional[bool] = None,
                                   layer=None):
     """Single-token paged decode: q [B, H, 1, D] against the stacked block
-    pool at ``layer``, walking each row's block table in-kernel via scalar
-    prefetch.  Under a configured tp context each chip launches the kernel
-    on its own head shard of q and the pool."""
+    pool at ``layer``, each row walking its own valid blocks in-kernel
+    (:func:`_paged_walk_kernel`, as ``paged_decode_attn``).  Under a
+    configured tp context each chip launches the kernel on its own head
+    shard of q and the pool."""
     assert q.shape[2] == 1, \
         "pallas paged decode is single-token; use the XLA path"
-    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    if interpret is None:
-        interpret = interpret_kernels()
-    body = functools.partial(_paged_decode_pallas, sm_scale=scale,
-                             interpret=interpret)
-    return _tp_shard_heads(body, q, k_pool, v_pool, block_tables, q_pos,
-                           layer)
-
-
-def _paged_verify_kernel(layer_ref, pos_ref, bt_ref, q_ref, *refs,
-                         sm_scale: float, block_size: int, t: int,
-                         spans: int, quant: bool = False):
-    """Multi-token (T = K+1 speculative verify window) variant of the paged
-    decode kernel.  Grid: (B, HKV, NBPER), logical blocks innermost.
-
-    q_ref: [1, 1, rep*T, D] (span-expanded for a lane-packed pool,
-    :func:`_attend_chunk`) — query row ``r*T + i`` is head ``r`` of this KV
-    group at window offset ``i``, so its global position is ``base + i``
-    with ``base = pos_ref[b]`` (the row's committed length — the verify
-    window was just scattered at ``base .. base+T-1``).  The causal mask is
-    per query ROW (``key <= base + row % T``): every verify query sees the
-    row's history plus the window prefix up to itself, never the
-    yet-unverified draft tail.  Blocks wholly past ``base + T - 1`` are
-    skipped, so FLOPs track each row's own valid length.
-
-    ``quant``: int8 pool — [1, 1, 1, bs] scale rows ride next to the code
-    blocks and fold into the score/prob columns exactly like the decode
-    kernel (:func:`_attend_chunk`).
-    """
-    del layer_ref, bt_ref            # consumed by the BlockSpec index maps
-    k_ref, ks_ref, v_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = \
-        _pool_refs(refs, quant)
-    kb = pl.program_id(2)
-    nk = pl.num_programs(2)
-    base = pos_ref[pl.program_id(0)]
-
-    @pl.when(kb == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    start = kb * block_size
-
-    @pl.when(start <= base + t - 1)  # skip blocks past the window's last row
-    def _compute():
-        _attend_chunk(q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                      lambda idx, query: idx <= base + query % t,
-                      start, sm_scale, m_scr, l_scr, acc_scr, spans=spans)
-
-    @pl.when(kb == nk - 1)
-    def _finish():
-        _finish_chunks(o_ref, m_scr, l_scr, acc_scr, spans=spans)
+    return _paged_attention_pallas(_paged_decode_call, q, k_pool, v_pool,
+                                   block_tables, q_pos, sm_scale, interpret,
+                                   layer)
 
 
 #: widest window the verify kernel takes; larger T (chunked prefill) uses
 #: the gather-based reference path
 VERIFY_T_MAX = 16
-
-
-def _paged_verify_pallas(q, k_pool, v_pool, block_tables, q_pos, layer, *,
-                         sm_scale: float, interpret: bool):
-    """Single-shard kernel launch of :func:`paged_verify_attention_pallas`
-    (shapes may be the full head count or one tp shard's slice)."""
-    b, h, t, d = q.shape
-    hkv = pool_payload(k_pool).shape[2]
-    rep = h // hkv
-    # [B, H, T, D] -> [B, HKV, rep*T, D]: row r*T + i = (head r of the KV
-    # group, window offset i) — matches the repeat-based GQA grouping
-    qg = q.reshape(b, hkv, rep, t, d).reshape(b, hkv, rep * t, d)
-    call, operands, static = _paged_launch(qg, k_pool, v_pool, block_tables,
-                                           q_pos, layer)
-    out = pl.pallas_call(
-        functools.partial(_paged_verify_kernel, sm_scale=sm_scale, t=t,
-                          **static),
-        interpret=interpret, name="paged_verify_attn", **call)(*operands)
-    return out.reshape(b, hkv, rep, t, d).reshape(b, h, t, d)
 
 
 def paged_verify_attention_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
@@ -624,21 +663,17 @@ def paged_verify_attention_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
                                   layer=None):
     """Speculative-verify paged attention: q [B, H, T, D] with T = K+1
     window positions per row, each row's window starting at its own
-    ``q_pos[b]`` base (scalar q_pos broadcasts).  Same scalar-prefetch
-    (layer, block-table) walk as the single-token kernel; the T query rows
-    ride in the row dim of one [rep*T, D] tile per (row, KV-head) grid
-    step.  Under a configured tp context each chip launches the kernel on
-    its own head shard of q and the pool."""
+    ``q_pos[b]`` base (scalar q_pos broadcasts).  The decode kernel's walk
+    with ``rep*T`` query rows a KV head, the per-row causal mask and a trip
+    count from ``base + T - 1`` (:func:`_paged_walk_kernel`, as
+    ``paged_verify_attn``).  Under a configured tp context each chip
+    launches the kernel on its own head shard of q and the pool."""
     t = q.shape[2]
     assert 1 <= t <= VERIFY_T_MAX, \
         f"verify kernel takes windows up to {VERIFY_T_MAX}, got T={t}"
-    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    if interpret is None:
-        interpret = interpret_kernels()
-    body = functools.partial(_paged_verify_pallas, sm_scale=scale,
-                             interpret=interpret)
-    return _tp_shard_heads(body, q, k_pool, v_pool, block_tables, q_pos,
-                           layer)
+    return _paged_attention_pallas(_paged_verify_call, q, k_pool, v_pool,
+                                   block_tables, q_pos, sm_scale, interpret,
+                                   layer)
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, q_pos, *,

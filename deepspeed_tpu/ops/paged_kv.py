@@ -19,9 +19,9 @@ Layout contract — the WHOLE stacked pool, addressed in place:
    the "unset" marker: reads of unset blocks are masked by position, and a
    window that reaches past a row's allocated entries lands there.  One
    table serves every layer today; the layer index is an OPERAND of every
-   op (and a scalar-prefetch operand of the kernels' index maps), so
-   per-layer-kind tables (ROADMAP 2.8) are a change of index map —
-   ``bt[kind_of[layer], b, i]`` — not of layout.
+   op (and a scalar-prefetch operand of the kernels, which address their
+   copies with it), so per-layer-kind tables (ROADMAP 2.8) are a change of
+   that address — ``bt[kind_of[layer], b, i]`` — not of layout.
 
 **Layout** (what "in place" takes on a TPU).  A Mosaic kernel reads its
 operand row-major — ``[L][NB][HKV][...]``, a block's tiles contiguous —
@@ -40,7 +40,15 @@ no padding (4.84 GB for the chat cell's 769 blocks, where XLA's layout of
 the unpacked array took 5.64 GB and the kernel's 9.68 GB), the kernels read
 it as it lies (a span is a lane slice of the tile:
 ``ops/decode_attention.py:_attend_chunk``), and XLA has no reason left to
-move it.  Two more things keep it so: the write reads, merges and
+move it.  It is also what makes a block ONE copy: in this layout all KV
+heads of a (layer, block) are contiguous — ``[HKV, bs/g, 128]``, 128 KB in
+bf16 for OPT-1.3B's 32 x 64 and OLMoE's 16 x 128 alike — so the paged
+attention kernels (``_paged_walk_kernel``) leave the pool in HBM and, row
+by row, fetch just the blocks the row's positions reach: ``pool.at[layer,
+bt[b, i]]`` for ``i < cdiv(pos + 1, block_size)``, one DMA a block and
+side, the next block in flight while this one is attended.  A table entry
+past a row's valid prefix is never read, and a row costs its own length,
+not ``max_seq_len``.  Two more things keep it so: the write reads, merges and
 scatters back WHOLE blocks (:func:`_write_blocks`) — index dims (layer,
 block), the pool's two major dims, so row-major is the layout that scatter
 wants too, where a scatter of token vectors at ``[layer, phys, :, off]``
@@ -50,9 +58,13 @@ pool as an operand.  Every op reads the packing off the shapes (pool minor
 dim over the model's head dim), so a pool exactly as ``init_cache`` built
 it — the benchmark's teacher-forced comparison passes one — goes through
 the same code with ``g = 1`` (and, on a TPU, through whatever copies XLA's
-layout of that array costs: fine for a comparison, not for serving).
-An int8 record packs its codes the same way; its scale table ``[L, NB, HKV,
-bs]`` is small (1/64 of the codes) and keeps the hook's shape.
+layout of that array costs, plus the kernels' own copy of the layer's
+blocks into whole lane rows — Mosaic copies out of HBM in whole 128-lane
+tiles only, ``decode_attention._lane_rows``: fine for a comparison, not
+for serving).  An int8 record packs its codes the same way; its scale table
+``[L, NB, HKV, bs]`` is small (1/64 of the codes) and keeps the hook's
+shape: the kernels take a layer's rows of it lane-padded, a copy of 1/L of
+the table a call.
 
 Speculative-decoding windows lean on two properties of this contract:
 
@@ -113,8 +125,9 @@ Rollback of rejected speculative tokens stays free: re-quantizing the
 same deterministic values yields the same codes and scales.
 
 Everything here is pure XLA (block read-modify-write / gather), shared by
-prefill and the CPU/correctness decode path; the TPU kernels that walk the
-(layer, block table) index in-kernel live in ``ops/decode_attention.py``
+prefill and the CPU/correctness decode path; the TPU kernels that walk a
+row's valid blocks of the (layer, block table) index in-kernel live in
+``ops/decode_attention.py``
 (``paged_decode_attention_pallas`` / ``paged_verify_attention_pallas``)
 and shard through the same context.
 """

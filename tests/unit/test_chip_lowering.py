@@ -57,6 +57,48 @@ def test_paged_decode_and_verify_lower(name, h, hd, ctx, kv8):
             q, k, v, bt, pos, interpret=False), q, pool, pool, bt, pos)
 
 
+#: (cell, slots, KV heads, head_dim, max_seq_len, layers): the serving cells
+CELL_SHAPES = [("opt13b-chat-closed", 24, 32, 64, 1024, 24),
+               ("opt13b-longprompt-closed", 8, 32, 64, 2048, 24),
+               ("olmoe-decode-closed", 64, 16, 128, 1024, 8)]
+
+
+def _cell_operands(slots, h, hd, ctx, layers, kv8, t, sharding=None):
+    """(q, pool, block table, positions) of a cell: the stacked pool
+    lane-packed as the engine holds it, ``[L, NB, H, bs/g, g*hd]``."""
+    from deepspeed_tpu.ops import paged_kv
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    nbper = ctx // BLOCK
+    nb = 1 + slots * nbper
+    g = paged_kv.lane_pack(BLOCK, hd)
+    packed = (layers, nb, h, BLOCK // g, g * hd)
+    pool = sds(packed, jnp.bfloat16) if not kv8 else {
+        "qp": sds(packed, jnp.int8),
+        "ps": sds((layers, nb, h, BLOCK), jnp.bfloat16)}
+    return (sds((slots, h, t, hd), jnp.bfloat16), pool,
+            sds((slots, nbper), jnp.int32), sds((slots,), jnp.int32))
+
+
+@pytest.mark.parametrize("kv8", [False, True], ids=["bf16", "kv8"])
+@pytest.mark.parametrize("cell,slots,h,hd,ctx,layers", CELL_SHAPES)
+def test_paged_walk_lowers_at_the_cells_shapes(cell, slots, h, hd, ctx,
+                                               layers, kv8):
+    """ISSUE 29: decode and verify at the shapes the benchmark's serving
+    cells run, the packed pool a whole ``ANY`` operand at a layer index."""
+    for t, kernel, name in ((1, da.paged_decode_attention_pallas,
+                             "paged_decode_attn"),
+                            (4, da.paged_verify_attention_pallas,
+                             "paged_verify_attn")):
+        q, pool, bt, pos = _cell_operands(slots, h, hd, ctx, layers, kv8, t)
+        text = _lower_tpu(lambda q, k, v, bt, pos, kernel=kernel: kernel(
+            q, k, v, bt, pos, interpret=False, layer=1), q, pool, pool, bt,
+            pos)
+        assert f'kernel_name = "{name}"' in text
+
+
 @pytest.mark.parametrize("name,h,hd,ctx", ATTN_SHAPES)
 def test_contiguous_decode_lowers(name, h, hd, ctx):
     q = _sds((SLOTS, h, 1, hd), jnp.bfloat16)
@@ -267,3 +309,24 @@ def test_compiled_serving_programs_hold_no_pool_sized_temporary(
         unpadded = sum(int(np.prod(a.shape)) * a.dtype.itemsize
                        for a in leaves)
         assert mem.alias_size_in_bytes >= unpadded, (name, mem)
+
+
+@pytest.mark.parametrize("kv8", [False, True], ids=["bf16", "kv8"])
+@pytest.mark.parametrize("cell,slots,h,hd,ctx,layers", CELL_SHAPES)
+def test_paged_walk_compiles_at_the_cells_shapes(cell, slots, h, hd, ctx,
+                                                 layers, kv8, one_chip):
+    """Mosaic's own compile of the walk for a described v5e (its DMA
+    alignment rules and VMEM budget: what lowering alone does not check),
+    and no temporary beside a packed float pool: the kernel reads the
+    whole stack where it lies."""
+    for t, kernel in ((1, da.paged_decode_attention_pallas),
+                      (4, da.paged_verify_attention_pallas)):
+        args = _cell_operands(slots, h, hd, ctx, layers, kv8, t, one_chip)
+        q, pool, bt, pos = args
+        compiled = jax.jit(lambda q, k, v, bt, pos, kernel=kernel: kernel(
+            q, k, v, bt, pos, interpret=False, layer=1)).lower(
+                q, pool, pool, bt, pos).compile()
+        if not kv8:
+            # kv8: this layer's scale rows ride lane-padded (a copy of
+            # 1/L of the small table)
+            assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20

@@ -15,7 +15,10 @@ from deepspeed_tpu.ops.decode_attention import (
     paged_decode_attention_pallas, paged_decode_attention_reference,
     paged_verify_attention_pallas)
 
-pytestmark = pytest.mark.slow  # Pallas interpret mode: minutes on CPU
+#: Pallas interpret mode: minutes on CPU.  Marked per test, so that the
+#: (small, fast) cases of the paged walk at the end of this file stay in the
+#: quick lane.
+slow = pytest.mark.slow
 
 
 def _dense_reference(q, k, v, q_pos):
@@ -34,6 +37,7 @@ def _dense_reference(q, k, v, q_pos):
     return np.einsum("bhts,bhsd->bhtd", p, v)
 
 
+@slow
 @pytest.mark.parametrize("h,hkv", [(4, 4), (8, 2)])
 def test_reference_path_matches_dense(h, hkv):
     rng = np.random.default_rng(0)
@@ -47,6 +51,7 @@ def test_reference_path_matches_dense(h, hkv):
                                rtol=2e-5, atol=2e-5)
 
 
+@slow
 def test_reference_path_prefill_matches_dense():
     rng = np.random.default_rng(1)
     b, h, s, d, t = 1, 4, 64, 16, 9
@@ -59,6 +64,7 @@ def test_reference_path_prefill_matches_dense():
                                rtol=2e-5, atol=2e-5)
 
 
+@slow
 @pytest.mark.parametrize("h,hkv,pos", [(4, 4, 0), (4, 4, 63), (8, 2, 200)])
 def test_pallas_kernel_matches_reference(h, hkv, pos):
     rng = np.random.default_rng(2)
@@ -72,6 +78,7 @@ def test_pallas_kernel_matches_reference(h, hkv, pos):
                                rtol=2e-5, atol=2e-5)
 
 
+@slow
 @pytest.mark.parametrize("h,hkv", [(4, 4), (8, 2)])
 def test_pallas_kernel_per_sequence_lengths(h, hkv):
     """Ragged lengths[B] (continuous-batching slots): Pallas == reference ==
@@ -95,6 +102,7 @@ def test_pallas_kernel_per_sequence_lengths(h, hkv):
                                    np.asarray(row), rtol=1e-6, atol=1e-6)
 
 
+@slow
 def test_pallas_kernel_ragged_under_jit_traced_lengths():
     """One compiled program serves every lengths vector (jit-traced)."""
     rng = np.random.default_rng(8)
@@ -116,6 +124,7 @@ def test_pallas_kernel_ragged_under_jit_traced_lengths():
                                    rtol=2e-5, atol=2e-5)
 
 
+@slow
 @pytest.mark.parametrize("family", ["gpt2", "llama"])
 def test_forward_cached_ragged_matches_full_recompute(family):
     """Per-sequence lengths through forward_cached: ragged bucketed prefill
@@ -162,6 +171,7 @@ def test_forward_cached_ragged_matches_full_recompute(family):
         toks = jnp.argmax(logits, -1).astype(jnp.int32)
 
 
+@slow
 def test_pallas_kernel_under_jit_traced_pos():
     rng = np.random.default_rng(3)
     b, h, s, d = 1, 4, 128, 32
@@ -181,6 +191,7 @@ def test_pallas_kernel_under_jit_traced_pos():
                                    rtol=2e-5, atol=2e-5)
 
 
+@slow
 @pytest.mark.parametrize("family", ["gpt2", "llama"])
 def test_forward_cached_matches_forward(family):
     """Cached incremental forward == full forward, token by token."""
@@ -213,6 +224,7 @@ def test_forward_cached_matches_forward(family):
                                    rtol=2e-4, atol=2e-4)
 
 
+@slow
 def test_generate_kv_cache_matches_recompute():
     """InferenceEngine KV-cache generation == full-recompute generation."""
     import deepspeed_tpu
@@ -254,6 +266,7 @@ def _paged_from_contiguous(kc, vc, nb, bs, rng):
     return kp, vp, bt
 
 
+@slow
 @pytest.mark.parametrize("h,hkv", [(4, 4), (8, 2)])
 def test_paged_pallas_kernel_matches_reference(h, hkv):
     """The block-table-walking kernel (scalar prefetch) == the gather-based
@@ -279,6 +292,7 @@ def test_paged_pallas_kernel_matches_reference(h, hkv):
                                rtol=2e-5, atol=2e-5)
 
 
+@slow
 @pytest.mark.parametrize("h,hkv,t", [(4, 4, 4), (8, 2, 5)])
 def test_paged_verify_pallas_kernel_matches_reference(h, hkv, t):
     """The K+1 speculative verify window (T query rows per slot, each row's
@@ -307,6 +321,7 @@ def test_paged_verify_pallas_kernel_matches_reference(h, hkv, t):
                                rtol=2e-5, atol=2e-5)
 
 
+@slow
 def test_paged_verify_pallas_kernel_under_jit_traced_bases():
     """One compiled verify program serves every (bases, block_table) pair —
     the speculative serving loop's contract."""
@@ -333,6 +348,7 @@ def test_paged_verify_pallas_kernel_under_jit_traced_bases():
                                    rtol=2e-5, atol=2e-5)
 
 
+@slow
 def test_paged_pallas_kernel_under_jit_traced_tables():
     """One compiled program serves every (lengths, block_table) pair — the
     serving loop's decode contract."""
@@ -369,6 +385,7 @@ def _tp_mesh(n):
     return Mesh(devs, ("pp", "dp", "ep", "sp", "tp"))
 
 
+@slow
 @pytest.mark.parametrize("h,hkv,tp", [(4, 4, 2), (8, 4, 4), (8, 2, 2)])
 def test_paged_pallas_kernel_sharded_matches_reference(h, hkv, tp):
     """Under a configured tp context each chip launches the decode kernel
@@ -397,6 +414,7 @@ def test_paged_pallas_kernel_sharded_matches_reference(h, hkv, tp):
                                rtol=1e-6, atol=1e-6)
 
 
+@slow
 @pytest.mark.parametrize("h,hkv,tp,t", [(4, 4, 2, 4), (8, 2, 2, 5)])
 def test_paged_verify_pallas_kernel_sharded_matches_reference(h, hkv, tp, t):
     """The K+1 verify window shards over heads exactly like single-token
@@ -420,6 +438,7 @@ def test_paged_verify_pallas_kernel_sharded_matches_reference(h, hkv, tp, t):
                                rtol=2e-5, atol=2e-5)
 
 
+@slow
 def test_paged_ops_gqa_below_tp_fall_back_replicated():
     """HKV smaller than the tp axis cannot shard: head_shards reports 1 and
     the ops run the replicated path — identical results, no error."""
@@ -459,6 +478,7 @@ def _quantized_from_contiguous(kc, vc, nb, bs, rng):
     return kp, vp, bt
 
 
+@slow
 @pytest.mark.parametrize("h,hkv", [(4, 4), (8, 2)])
 def test_quantized_paged_pallas_kernel_matches_reference(h, hkv):
     """int8 pool records through the decode kernel: the in-kernel
@@ -487,6 +507,7 @@ def test_quantized_paged_pallas_kernel_matches_reference(h, hkv):
                                atol=5e-2)
 
 
+@slow
 @pytest.mark.parametrize("h,hkv,t", [(4, 4, 4), (8, 2, 5)])
 def test_quantized_verify_pallas_kernel_matches_reference(h, hkv, t):
     """The K+1 verify window over an int8 pool: per-row bases, straddled
@@ -508,6 +529,7 @@ def test_quantized_verify_pallas_kernel_matches_reference(h, hkv, t):
                                rtol=2e-5, atol=2e-5)
 
 
+@slow
 @pytest.mark.parametrize("h,hkv,tp", [(8, 4, 4), (8, 2, 2)])
 def test_quantized_paged_kernel_sharded_matches_reference(h, hkv, tp):
     """int8 records shard whole under the tp context — codes AND the
@@ -560,6 +582,7 @@ def _stacked_pool(rng, layers, b, hkv, s, d, bs, kv8):
     return kp, vp, bt
 
 
+@slow
 @pytest.mark.parametrize("tp", [1, 2])
 @pytest.mark.parametrize("kv8", [False, True], ids=["float", "kv8"])
 @pytest.mark.parametrize("t", [1, 4, 128])
@@ -603,3 +626,124 @@ def test_stacked_pool_attention_reads_its_layer(t, kv8, tp):
                                                  bt, pos)
         assert not np.allclose(np.asarray(other), np.asarray(want),
                                atol=1e-3)
+
+
+# ------------------------------------- the walk over a row's valid blocks
+# ISSUE 29: the paged kernels loop over each row's VALID blocks and copy
+# every KV head of a block themselves.  Small shapes, quick lane.
+WALK_BS, WALK_CTX, WALK_LAYERS = 32, 160, 2          # 5 blocks a row
+
+
+def _walk_case(rng, t, hd, rep, kv8, bases):
+    """A 2-layer pool, lane-packed as the engine holds it (hd 64: g = 2,
+    hd 128: g = 1), rows of the given ``bases`` (query positions ``base ..
+    base + t - 1``).  Returns ``(q, k_pool, v_pool, poisoned table, clean
+    table)``: past each row's valid prefix the poisoned table holds ids of
+    blocks full of NaN (an int8 record's scale rows are NaN there), the
+    clean one the scratch id 0 — a kernel that copies one entry too many
+    returns NaN, the gather reference reads the clean table."""
+    from deepspeed_tpu.ops import paged_kv
+
+    b, hkv, bs = len(bases), 2, WALK_BS
+    kp, vp, bt = _stacked_pool(rng, WALK_LAYERS, b, hkv, WALK_CTX, hd, bs,
+                               kv8)
+    bt = np.asarray(bt)
+    nb = paged_kv.pool_payload(kp).shape[1]
+    poison = np.setdiff1d(np.arange(1, nb), bt)[:2]      # blocks no row owns
+
+    def poisoned(pool):
+        if kv8:
+            return {"qp": pool["qp"],
+                    "ps": pool["ps"].at[:, poison].set(jnp.nan)}
+        return pool.at[:, poison].set(jnp.nan)
+
+    kp, vp = (paged_kv.pack_pool(poisoned(p)) for p in (kp, vp))
+    valid = (np.asarray(bases) + t - 1) // bs + 1    # blocks a row may read
+    past = np.arange(bt.shape[1])[None, :] >= valid[:, None]
+    garbage = poison[rng.integers(0, 2, bt.shape)]
+    q = jnp.asarray(rng.standard_normal((b, hkv * rep, t, hd)), jnp.float32)
+    return (q, kp, vp, jnp.asarray(np.where(past, garbage, bt), jnp.int32),
+            jnp.asarray(np.where(past, 0, bt), jnp.int32))
+
+
+@pytest.mark.parametrize("kv8", [False, True], ids=["float", "kv8"])
+@pytest.mark.parametrize("rep", [1, 4])
+@pytest.mark.parametrize("hd", [128, 64], ids=["g1", "g2"])
+def test_paged_walk_visits_each_rows_valid_blocks_only(hd, rep, kv8):
+    """One batch of rows of length 1, a block less one, exactly a block,
+    a block and one, ``max_seq_len`` (five trips of the double-buffered
+    loop), and a slot with no live token (all its entries unset) beside
+    them — at a non-zero layer, the table's entries past each row's valid
+    prefix garbage."""
+    rng = np.random.default_rng(50 + hd + rep)
+    bases = [0, WALK_BS - 2, WALK_BS - 1, WALK_BS, WALK_CTX - 1, 0]
+    q, kp, vp, bt, clean = _walk_case(rng, 1, hd, rep, kv8, bases)
+    bt, clean = bt.at[-1].set(0), clean.at[-1].set(0)     # the empty slot
+    pos = jnp.asarray(bases, jnp.int32)
+    want = paged_decode_attention_reference(q, kp, vp, clean, pos, layer=1)
+    got = paged_decode_attention_pallas(q, kp, vp, bt, pos, layer=1,
+                                        interpret=True)
+    assert np.isfinite(np.asarray(got)).all(), "read past a valid prefix"
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("kv8", [False, True], ids=["float", "kv8"])
+@pytest.mark.parametrize("hd,rep", [(128, 1), (64, 1), (64, 4)],
+                         ids=["g1", "g2", "g2-rep4"])
+def test_paged_walk_verify_window_across_a_block_boundary(hd, rep, kv8):
+    """T = 4 verify windows that start a block, straddle a boundary (the
+    trip count comes from ``base + T - 1``, one block more than the base
+    holds), end a block and end the context."""
+    t = 4
+    rng = np.random.default_rng(60 + hd + rep)
+    bases = [0, WALK_BS - 2, 2 * WALK_BS - t, WALK_CTX - t]
+    q, kp, vp, bt, clean = _walk_case(rng, t, hd, rep, kv8, bases)
+    pos = jnp.asarray(bases, jnp.int32)
+    want = paged_decode_attention_reference(q, kp, vp, clean, pos, layer=1)
+    got = paged_verify_attention_pallas(q, kp, vp, bt, pos, layer=1,
+                                        interpret=True)
+    assert np.isfinite(np.asarray(got)).all(), "read past a valid prefix"
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize(
+    "hkv,r,width,itemsize,want",
+    [(32, 16, 128, 2, 32),        # OPT-1.3B: 128 KB a block and side
+     (16, 32, 128, 2, 16),        # OLMoE
+     (32, 16, 128, 1, 32),        # int8 codes
+     (8, 16, 128, 2, 8),          # a tp=4 shard of OPT
+     (32, 32, 128, 4, 32),        # float32: 512 KB a block
+     (64, 128, 256, 4, 16),       # 8 MB a block: the heads are split
+     (24, 128, 256, 4, 24)],      # ... only in halves a multiple of 16
+    ids=["opt", "olmoe", "int8", "tp-shard", "f32", "split-heads",
+         "odd-heads"])
+def test_paged_walk_head_tile_is_read_off_the_shapes(hkv, r, width, itemsize,
+                                                     want):
+    from deepspeed_tpu.ops import decode_attention as da
+
+    assert da._walk_head_tile(hkv, r, width, itemsize) == want
+
+
+def test_paged_walk_splits_heads_over_the_grid(monkeypatch):
+    """A block of all heads over the VMEM budget: the heads go to a second
+    grid dim in halves, each grid step copying its own head slice."""
+    from deepspeed_tpu.ops import decode_attention as da
+
+    rng = np.random.default_rng(70)
+    b, h, s, d, bs = 2, 32, 64, 32, 16
+    monkeypatch.setattr(da, "_WALK_VMEM_BUDGET", 6 * 16 * bs * d * 4)
+    assert da._walk_head_tile(h, bs, d, 4) == 16
+    kc = rng.standard_normal((b, h, s, d)).astype(np.float32)
+    vc = rng.standard_normal((b, h, s, d)).astype(np.float32)
+    kp, vp, bt = _paged_from_contiguous(kc, vc, 2 * b * (s // bs), bs, rng)
+    q = jnp.asarray(rng.standard_normal((b, h, 1, d)), jnp.float32)
+    pos = jnp.asarray([s - 1, 20], jnp.int32)
+    want = decode_attention_reference(q, jnp.asarray(kc), jnp.asarray(vc),
+                                      pos)
+    got = paged_decode_attention_pallas(
+        q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt), pos,
+        interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
