@@ -27,12 +27,12 @@ non-zero and suppresses the result line:
                 ``memory_analysis().temp_size_in_bytes`` and the number of
                 pool-slice-sized ``copy`` instructions, failing on a
                 pool-sized temporary (the pool is updated in place).
- - ``train``    ``initialize`` of GPT-2 125M with ``bench.py``'s kernel
-                configuration, three ``train_batch`` steps on one seeded
+ - ``train``    ``initialize`` of GPT-2 125M (flash v2 at 1024x1024 blocks,
+                ``dots_flash`` remat, unrolled layers), three ``train_batch`` steps on one seeded
                 batch; loss finite and falling.
 
 On more than one chip the same phases run with the batch over ``dp=n``
-(ZeRO stage as ``bench.py`` picks it) and the serving engines at
+(ZeRO-1 from two chips on, ZeRO-0 on one) and the serving engines at
 ``topology=n``, and the script checks that pool shards and bytes in use
 are spread over every chip.
 
@@ -109,7 +109,7 @@ def full_sizes() -> Sizes:
     from deepspeed_tpu.models import gpt2, opt
 
     g = gpt2.GPT2Config.gpt2_125m()
-    # bench.py's TPU kernel configuration
+    # the TPU kernel configuration of the training cells
     g.remat, g.use_flash, g.remat_policy = True, True, "dots_flash"
     g.scan_layers = False
     g.flash_block_q, g.flash_block_k = 1024, 1024

@@ -235,8 +235,8 @@ def init_router(model=None, config=None, params=None, *, replicas=2,
 
 
 def init_serving(model=None, config=None, params=None, *, slots=8,
-                 max_seq_len=None, prompt_buckets=None, prefill_batch=4,
-                 block_size=32, num_blocks=None, chunked_prefill=None,
+                 max_seq_len=None, prefill_batch=4,
+                 block_size=32, num_blocks=None,
                  prefill_chunk=128, prefix_caching=True, decode_steps=1,
                  engine_mode="replicas", sp=1, resident_window_blocks=0,
                  spec_tokens=0,
@@ -244,8 +244,7 @@ def init_serving(model=None, config=None, params=None, *, slots=8,
                  role="both", nvme_blocks=0, nvme_high_watermark=0.9,
                  nvme_path=None,
                  ngram_max=3, ngram_min=1,
-                 sampling=True, spec_verifier="rejection",
-                 logit_masks=False,
+                 sampling=True, logit_masks=False,
                  shard_kv=None, topology=None, device_group=None,
                  debug_checks=False,
                  trace_capacity=16384, slo_targets=None, peak_flops=None,
@@ -257,8 +256,7 @@ def init_serving(model=None, config=None, params=None, *, slots=8,
     shared block-aligned prompt prefixes are reused from the prefix cache
     with zero recompute, and prompts prefill in fixed chunks (one compiled
     prefill program) — instead of ``generate``'s run-to-longest static
-    batches.  Passing ``prompt_buckets`` selects the bucket-ladder prefill
-    fallback (no prefix reuse).
+    batches.
 
     ``decode_steps=K`` fuses K decode iterations into ONE on-device
     ``lax.while_loop`` program (the host-loop kill): per-slot eos/budget
@@ -270,10 +268,10 @@ def init_serving(model=None, config=None, params=None, *, slots=8,
     compiled decode program serves what otherwise takes dp router-fronted
     replicas.  See docs/inference.md "Multi-step fused decode".
 
-    ``spec_tokens=K`` turns on speculative decoding (chunked mode only):
-    each decode iteration drafts K tokens per slot — with a small
-    same-tokenizer ``draft`` model (ModelSpec or ``init_inference``
-    engine), or the model-free n-gram prompt-lookup proposer — and
+    ``spec_tokens=K`` turns on speculative decoding: each decode iteration
+    drafts K tokens per slot — with a small same-tokenizer ``draft`` model
+    (ModelSpec or ``init_inference`` engine), or the model-free n-gram
+    prompt-lookup proposer — and
     verifies the K+1 window in one batched target pass, committing the
     longest target-matching prefix.  Outputs stay token-exact with plain
     greedy decode at any acceptance rate.
@@ -345,8 +343,8 @@ def init_serving(model=None, config=None, params=None, *, slots=8,
     operand vectors inside the same compiled programs — greedy requests
     are the ``temperature=0`` rows, so mixed traces keep the compile
     contract with zero recompiles, and speculative decoding verifies
-    sampled streams with the distribution-exact rejection sampler
-    (``spec_verifier="rejection"``).  ``logit_masks=True`` adds the
+    sampled streams with the distribution-exact rejection sampler.
+    ``logit_masks=True`` adds the
     constrained-decoding lane: requests carrying a ``mask_builder``
     (``inference/constrain.py``) sample under a host-built
     ``[slots, vocab]`` allow-mask (e.g. guaranteed-valid JSON).
@@ -433,10 +431,8 @@ def init_serving(model=None, config=None, params=None, *, slots=8,
     engine = init_inference(model, config, params,
                             device_group=device_group, **kwargs)
     return ServingEngine(engine, slots=slots, max_seq_len=max_seq_len,
-                         prompt_buckets=prompt_buckets,
                          prefill_batch=prefill_batch, block_size=block_size,
                          num_blocks=num_blocks,
-                         chunked_prefill=chunked_prefill,
                          prefill_chunk=prefill_chunk,
                          prefix_caching=prefix_caching,
                          decode_steps=decode_steps, engine_mode=engine_mode,
@@ -448,8 +444,7 @@ def init_serving(model=None, config=None, params=None, *, slots=8,
                          nvme_high_watermark=nvme_high_watermark,
                          nvme_path=nvme_path,
                          ngram_max=ngram_max, ngram_min=ngram_min,
-                         sampling=sampling, spec_verifier=spec_verifier,
-                         logit_masks=logit_masks,
+                         sampling=sampling, logit_masks=logit_masks,
                          shard_kv=shard_kv, debug_checks=debug_checks,
                          trace_capacity=trace_capacity,
                          slo_targets=slo_targets, peak_flops=peak_flops)

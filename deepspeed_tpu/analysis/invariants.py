@@ -132,8 +132,8 @@ def audit_paged_state(allocator, tables, held, *,
                    (entry 0 = scratch doubles as "unset").
     held:          per-slot list of owned block ids (the host ownership
                    record the release path decrefs).
-    prefix:        optional :class:`PrefixCache` (``None`` in bucketed /
-                   prefix-off mode).
+    prefix:        optional :class:`PrefixCache` (``None`` with
+                   ``prefix_caching=False``).
     active_needs:  ``slot -> committed token count`` for live slots; slots
                    absent from the map must be fully released.
     block_size:    tokens per block (converts needs to table spans).

@@ -49,8 +49,8 @@ __all__ = ["ParityError", "TrialRunner", "tune_serving"]
 #: config keys forwarded to the ServingEngine ctor (``topology`` is an
 #: ``init_serving``-level knob — the shared engine already has its mesh)
 _SERVING_KEYS = (
-    "slots", "max_seq_len", "prompt_buckets", "prefill_batch",
-    "block_size", "num_blocks", "chunked_prefill", "prefill_chunk",
+    "slots", "max_seq_len", "prefill_batch",
+    "block_size", "num_blocks", "prefill_chunk",
     "prefix_caching", "spec_tokens", "quantize", "host_blocks",
     "swap_batch", "ngram_max", "ngram_min", "shard_kv", "trace_capacity",
     "slo_targets", "peak_flops",

@@ -1,8 +1,8 @@
 """The serving knob space: per-knob domains + constraint predicates.
 
 ``init_serving`` has grown ~10 interacting knobs (ROADMAP item 5's "knob
-explosion"); most combinations are either invalid (speculative decoding
-in bucketed-prefill mode), physically impossible (a KV pool past the HBM
+explosion"); most combinations are either invalid (a host KV tier
+without the prefix cache), physically impossible (a KV pool past the HBM
 ceiling), or violate a checked contract (compile budget, HKV
 divisibility).  Searching them naively wastes most of the trial budget
 discovering what static reasoning already knows, so this module encodes
@@ -53,9 +53,7 @@ BASE_SERVING_CONFIG: Dict[str, Any] = {
     "max_seq_len": None,
     "block_size": 32,
     "num_blocks": None,
-    "chunked_prefill": True,
     "prefill_chunk": 128,
-    "prompt_buckets": None,
     "prefill_batch": 4,
     "prefix_caching": True,
     "spec_tokens": 0,
@@ -74,7 +72,6 @@ BASE_SERVING_CONFIG: Dict[str, Any] = {
     "sp": 1,
     "resident_window_blocks": 0,
     "sampling": True,
-    "spec_verifier": "rejection",
     "logit_masks": False,
     "trace_capacity": 16384,
 }
@@ -159,10 +156,10 @@ def kv_pool_bytes(config: Dict[str, Any], geom: ModelGeom) -> int:
 
 
 def compile_budget(config: Dict[str, Any]) -> int:
-    """Mirror of the ctor's compiled-program budget: 2 chunked (prefill +
-    decode / n-gram verify), buckets + 2 bucketed, + 2 swap programs with
-    a host tier.  (A draft model would add 1; the space searches the
-    zero-extra-programs n-gram proposer.)
+    """Mirror of the ctor's compiled-program budget: 2 (prefill +
+    decode / n-gram verify), + 2 swap programs with a host tier.  (A draft
+    model would add 1; the space searches the zero-extra-programs n-gram
+    proposer.)
 
     ``decode_steps > 1`` does NOT add a program: the fused multi-step
     while_loop REPLACES the per-token decode program (same sentry name,
@@ -176,20 +173,12 @@ def compile_budget(config: Dict[str, Any]) -> int:
     sp prefill reshapes the SAME chunked prefill program through
     shard_map, and the windowed decode/prefill bodies REPLACE the plain
     ones one-for-one (one extra traced operand, same sentry names).
-    ``sampling`` / ``spec_verifier`` / ``logit_masks`` are +0 too: the
-    per-slot sampling params (and the optional ``[slots, vocab]`` mask)
-    ride as extra fixed-shape operands of the SAME programs, and the
+    ``sampling`` / ``logit_masks`` are +0 too: the per-slot sampling
+    params (and the optional ``[slots, vocab]`` mask) ride as extra
+    fixed-shape operands of the SAME programs, and a sampling engine's
     rejection verifier replaces the greedy matcher inside the one verify
     program."""
-    if config.get("spec_tokens"):
-        budget = 2
-    elif config.get("chunked_prefill", True):
-        budget = 2
-    else:
-        budget = len(config.get("prompt_buckets") or ()) + 2
-    if config.get("host_blocks"):
-        budget += 2
-    return budget
+    return 4 if config.get("host_blocks") else 2
 
 
 # ---------------------------------------------------------- constraints
@@ -222,13 +211,6 @@ def _c_shard_kv(config, space) -> Optional[str]:
     return None
 
 
-def _c_spec_bucketed(config, space) -> Optional[str]:
-    if config.get("spec_tokens") and not config.get("chunked_prefill",
-                                                    True):
-        return "spec_tokens > 0 requires chunked-prefill mode"
-    return None
-
-
 def _c_spec_window(config, space) -> Optional[str]:
     k = int(config.get("spec_tokens") or 0)
     if k and k + 1 > VERIFY_T_MAX:
@@ -238,11 +220,9 @@ def _c_spec_window(config, space) -> Optional[str]:
 
 
 def _c_tiered_prefix(config, space) -> Optional[str]:
-    if config.get("host_blocks") and not (
-            config.get("chunked_prefill", True)
-            and config.get("prefix_caching", True)):
-        return ("host_blocks > 0 requires chunked prefill with "
-                "prefix_caching=True")
+    if config.get("host_blocks") and not config.get("prefix_caching",
+                                                    True):
+        return "host_blocks > 0 requires prefix_caching=True"
     return None
 
 
@@ -350,8 +330,6 @@ def _c_engine_mode(config, space) -> Optional[str]:
         return (f"engine_mode={mode!r} — expected 'replicas' or 'dp_tp'")
     if mode != "dp_tp":
         return None
-    if not config.get("chunked_prefill", True):
-        return "engine_mode='dp_tp' requires chunked-prefill mode"
     for knob in ("spec_tokens", "host_blocks"):
         if int(config.get(knob) or 0):
             return (f"engine_mode='dp_tp' does not compose with "
@@ -372,8 +350,6 @@ def _c_sp(config, space) -> Optional[str]:
         return f"sp must be >= 1, got {sp}"
     if sp == 1:
         return None
-    if not config.get("chunked_prefill", True):
-        return "sp > 1 requires chunked-prefill mode"
     chunk = int(config.get("prefill_chunk") or 0)
     if chunk % sp:
         return (f"prefill_chunk={chunk} must divide by sp={sp} — every "
@@ -394,11 +370,9 @@ def _c_resident_window(config, space) -> Optional[str]:
         return f"resident_window_blocks must be >= 0, got {win}"
     if not win:
         return None
-    if not (config.get("chunked_prefill", True)
-            and config.get("prefix_caching", True)):
-        return ("resident_window_blocks > 0 requires chunked prefill "
-                "with prefix_caching=True (slid blocks demote through "
-                "the chain-keyed host tier)")
+    if not config.get("prefix_caching", True):
+        return ("resident_window_blocks > 0 requires prefix_caching=True "
+                "(slid blocks demote through the chain-keyed host tier)")
     if not int(config.get("host_blocks") or 0):
         return ("resident_window_blocks > 0 needs the host tier "
                 "(host_blocks > 0) to hold demoted cold context")
@@ -429,20 +403,6 @@ def _c_resident_window(config, space) -> Optional[str]:
     return None
 
 
-def _c_spec_sampling(config, space) -> Optional[str]:
-    verifier = config.get("spec_verifier") or "rejection"
-    if verifier not in ("rejection", "greedy"):
-        return (f"spec_verifier={verifier!r} — expected 'rejection' or "
-                "'greedy'")
-    if (int(config.get("spec_tokens") or 0)
-            and config.get("sampling", True) and verifier == "greedy"):
-        return ("speculative decoding on a sampling engine requires the "
-                "rejection verifier (spec_verifier='rejection') — the "
-                "greedy prefix-matcher would silently reshape sampled "
-                "output distributions")
-    return None
-
-
 def _c_logit_masks(config, space) -> Optional[str]:
     if not config.get("logit_masks"):
         return None
@@ -463,7 +423,6 @@ CONSTRAINTS: Tuple[Tuple[str, Callable], ...] = (
     ("kv_pool_memory", _c_memory),
     ("compile_budget", _c_compile),
     ("shard_kv_divisibility", _c_shard_kv),
-    ("spec_bucketed_exclusive", _c_spec_bucketed),
     ("spec_window", _c_spec_window),
     ("tiered_needs_prefix_cache", _c_tiered_prefix),
     ("swap_batch_bounds", _c_swap_batch),
@@ -476,7 +435,6 @@ CONSTRAINTS: Tuple[Tuple[str, Callable], ...] = (
     ("engine_mode_exclusive", _c_engine_mode),
     ("sp_prefill_exclusive", _c_sp),
     ("resident_window_span", _c_resident_window),
-    ("spec_sampling_needs_rejection", _c_spec_sampling),
     ("logit_masks_excludes_dp_tp", _c_logit_masks),
 )
 
@@ -605,7 +563,7 @@ def workload_space(geom: ModelGeom, trace, *, pool_frac: float = 0.0,
     """A :class:`ServingKnobSpace` sized to a :class:`~deepspeed_tpu
     .autotuning.trace.ServingTrace` workload.
 
-    ``pool_frac > 0`` applies the BENCH_r09 pool-pressure protocol: the
+    ``pool_frac > 0`` puts the pool under pressure: the
     device memory ceiling is set to the bytes of a default-``block_size``
     pool holding ``pool_frac`` of the trace's unique working set, the
     base ``num_blocks`` becomes ``"mem"`` (every candidate fills its own

@@ -164,8 +164,8 @@ class ServingTrace:
         prefix counted ONCE, plus every unique tail and completion
         (recorded entries count their full prompt; shared structure is
         not recoverable from verbatim tokens without re-hashing).  This
-        is the BENCH_r09 pool-pressure sizing unit: a device pool at a
-        fraction of it forces eviction/preemption/tiering."""
+        is the pool-pressure sizing unit: a device pool at a fraction of
+        it forces eviction/preemption/tiering."""
         toks = self.sessions * self.prefix_len
         for e in self.entries:
             if e.tokens is not None:
@@ -256,7 +256,7 @@ def sessions_trace(n_requests: int, *, vocab: int, seed: int = 0,
                    slo_classes: Optional[Sequence[Optional[str]]] = None,
                    temperature: float = 0.0, top_k: int = 0,
                    top_p: float = 1.0) -> ServingTrace:
-    """The BENCH_r09 returning-session workload as a :class:`ServingTrace`:
+    """A returning-session workload as a :class:`ServingTrace`:
     ``sessions`` distinct shared prefixes dealt round-robin (request ``i``
     returns to session ``i % sessions`` with a fresh tail), per-request
     tail/decode budgets drawn deterministically from ``seed``.

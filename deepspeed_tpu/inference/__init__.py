@@ -1,1 +1,1 @@
-from .serving import Request, ServingEngine, default_buckets  # noqa: F401
+from .serving import Request, ServingEngine  # noqa: F401

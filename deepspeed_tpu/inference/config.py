@@ -54,8 +54,8 @@ class QuantizationConfig(DeepSpeedConfigModel):
 
 class ZeroInferenceConfig(DeepSpeedConfigModel):
     """ZeRO-Inference analog (reference: zero stage-3 ``offload_param`` to
-    CPU driving inference-only forwards — the OPT-30B-on-one-GPU
-    configuration of BASELINE.md): the stacked transformer blocks stay
+    CPU driving inference-only forwards — the reference's
+    OPT-30B-on-one-GPU configuration): the stacked transformer blocks stay
     HOST-resident and stream through HBM one layer at a time during
     prefill/decode, so the servable model size is bounded by host DRAM,
     not device HBM.  Large batches amortize the per-step weight traffic

@@ -1,11 +1,11 @@
 """Continuous-batching serving: block-paged KV cache, prefix reuse, and
 chunked prefill over an iteration-level scheduler.
 
-PR 1's slot pool reserved one contiguous ``max_seq_len`` KV region per slot
-and re-ran a full bucketed prefill for every admitted prompt — worst-case
-memory per sequence, and shared prompt prefixes (system prompts, few-shot
-headers) recomputed on every request.  This engine layers the two highest-
-leverage serving optimisations on top of continuous batching:
+A slot pool that reserves one contiguous ``max_seq_len`` KV region per slot
+and prefills every admitted prompt in full pays worst-case memory per
+sequence and recomputes shared prompt prefixes (system prompts, few-shot
+headers) on every request.  This engine layers the two highest-leverage
+serving optimisations on top of continuous batching:
 
  - **Block-paged KV pool** (vLLM PagedAttention): one statically-shaped
    ``[L, num_blocks, HKV, block_size, hd]`` cache plus per-slot ``int32``
@@ -36,11 +36,8 @@ leverage serving optimisations on top of continuous batching:
    blocks).
  - **Chunked prefill**: prompts advance through the cache in fixed-size
    windows (``prefill_chunk`` tokens, ``prefill_batch`` sequences per
-   call) interleaved with decode steps, replacing the bucket ladder — the
-   whole serving loop compiles exactly **1 prefill + 1 decode program**
-   regardless of trace shape.  The bucket ladder survives as a fallback
-   (``chunked_prefill=False``, auto-selected when ``prompt_buckets`` is
-   passed): per-bucket programs over the same paged pool, no prefix reuse.
+   call) interleaved with decode steps — the whole serving loop compiles
+   exactly **1 prefill + 1 decode program** regardless of trace shape.
 
 Scheduling is iteration-level and strict-FIFO as before: every iteration
 admits waiting requests into free slots (gated on block availability —
@@ -50,9 +47,8 @@ all slots with per-sequence positions (``lengths: int32[B]``).
 ``compile_count`` / ``compiled_programs`` remain the compile probe;
 ``stats()`` adds prefix-hit, block-occupancy, and preemption counters.
 
-**Speculative decoding** (``spec_tokens=K > 0``, chunked mode only)
-replaces the single-token decode step with a draft–verify round
-(``inference/spec.py``): a proposer guesses K tokens per decode slot — a
+**Speculative decoding** (``spec_tokens=K > 0``) replaces the single-token
+decode step with a draft–verify round (``inference/spec.py``): a proposer guesses K tokens per decode slot — a
 small same-family draft model running K greedy steps in ONE compiled
 program over its own paged pool (sharing the target's block tables, so
 allocation/preemption/prefix-reuse bookkeeping is written once), or the
@@ -167,9 +163,8 @@ the per-``slo_class`` attainment accounting behind ``slo_report()``
 ``flops_report()`` (``telemetry/flops.py``; raw program bodies lowered
 for ``cost_analysis`` — zero new compiled programs), and the router's
 cross-ring flow linkage (``note_flow`` → admission emits the Chrome
-flow finish).  Overhead contract: near-free when idle, ≤2% aggregate
-tok/s when fully enabled (pinned by the ``--telemetry-bench``
-serving-bench lane, BENCH_r08; re-verified fleet-wide in BENCH_r12).
+flow finish).  Disabled, every hook is one ``is None`` predicate; the
+cost when enabled has not been measured on a chip.
 
 **Incremental serving API** (PR 11): the scheduler state (pending queue,
 active slots) lives on the engine, not inside one ``serve()`` call.
@@ -210,7 +205,7 @@ import tempfile
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -231,7 +226,6 @@ from ..telemetry import MetricsRegistry, ProfilerWindow, TraceTimeline
 from ..telemetry import trace as trace_mod
 from ..telemetry.slo import SLOTracker
 from ..utils.logging import log_dist
-from ..utils.lru import LRUCache
 from ..utils.platform import on_tpu
 from .paged import (SCRATCH_BLOCK, BlockAllocator, GroupedBlockAllocator,
                     HostBlockStore, NvmeBlockStore, PrefixCache,
@@ -329,27 +323,6 @@ def _validate_decode_hooks(module, *, speculative: bool = False,
                 f"{missing} keyword(s) its hook flags promise "
                 f"(signature: forward_cached{sig})")
     return hooks
-
-
-def default_buckets(max_seq_len: int, lo: int = 32) -> Tuple[int, ...]:
-    """Power-of-two prompt-bucket ladder ``[lo, .., max_seq_len]``.
-
-    Robust to the edges the serving engine can hand it: ``lo`` above
-    ``max_seq_len`` clamps to a single ``(max_seq_len,)`` bucket, a
-    non-power-of-two ``max_seq_len`` gets exactly one tail entry (no
-    duplicates), and ``lo < 1`` raises instead of looping forever."""
-    if max_seq_len < 1:
-        raise ValueError(f"max_seq_len must be >= 1, got {max_seq_len}")
-    if lo < 1:
-        raise ValueError(f"bucket floor lo must be >= 1, got {lo}")
-    b = min(lo, max_seq_len)
-    buckets = []
-    while b < max_seq_len:
-        buckets.append(b)
-        b *= 2
-    if not buckets or buckets[-1] != max_seq_len:
-        buckets.append(max_seq_len)
-    return tuple(buckets)
 
 
 @dataclasses.dataclass
@@ -693,19 +666,14 @@ class ServingEngine:
                     ``1 + slots * ceil(max_seq_len/block_size)`` (no
                     oversubscription); smaller pools oversubscribe and rely
                     on prefix eviction + preemption.
-    chunked_prefill: ``True`` = fixed-window chunked prefill (1 compiled
-                    prefill program, prefix reuse available).  ``False`` =
-                    bucket-ladder fallback (per-bucket programs, no
-                    reuse).  Default ``None`` = auto: bucketed iff
-                    ``prompt_buckets`` is passed.
-    prefill_chunk:  chunk window length (chunked mode).
-    prompt_buckets: ascending prompt-length ladder (bucketed mode).
-    prefill_batch:  sequences per prefill call (both modes); short groups
-                    pad with scratch-routed rows.
-    prefix_caching: enable the block trie (chunked mode only).
-    spec_tokens:    speculative draft length K (0 = off; chunked mode
-                    only).  Each decode iteration proposes K tokens per
-                    slot and verifies them in one K+1-token target pass.
+    prefill_chunk:  prefill window length: prompts advance through the
+                    one compiled prefill program this many tokens a call.
+    prefill_batch:  sequences per prefill call; short groups pad with
+                    scratch-routed rows.
+    prefix_caching: enable the block trie.
+    spec_tokens:    speculative draft length K (0 = off).  Each decode
+                    iteration proposes K tokens per slot and verifies
+                    them in one K+1-token target pass.
     shard_kv:       shard the paged pool over the mesh's ``tp`` axis
                     (KV-head dim — module docstring).  Default ``None`` =
                     auto: shard iff tp > 1 and the KV head count divides
@@ -724,7 +692,7 @@ class ServingEngine:
                     "Tiered KV cache"); ``0`` (default) disables tiering
                     — behavior, programs, and scheduling are then
                     byte-identical to the pre-tiering engine.  Requires
-                    chunked-prefill mode with ``prefix_caching`` on (the
+                    ``prefix_caching`` on (the
                     trie is the content-address space promotions graft
                     back into).  Size it at the session working set you
                     want to survive eviction — e.g. the full trace
@@ -800,11 +768,9 @@ class ServingEngine:
 
     def __init__(self, engine, *, slots: int = 8,
                  max_seq_len: Optional[int] = None,
-                 prompt_buckets: Optional[Sequence[int]] = None,
                  prefill_batch: int = 4,
                  block_size: int = 32,
                  num_blocks: Optional[int] = None,
-                 chunked_prefill: Optional[bool] = None,
                  prefill_chunk: int = 128,
                  prefix_caching: bool = True,
                  decode_steps: int = 1,
@@ -824,7 +790,6 @@ class ServingEngine:
                  ngram_min: int = 1,
                  shard_kv: Optional[bool] = None,
                  sampling: bool = True,
-                 spec_verifier: str = "rejection",
                  logit_masks: bool = False,
                  debug_checks: bool = False,
                  trace_capacity: int = 16384,
@@ -845,19 +810,6 @@ class ServingEngine:
                 "spec_tokens=K to enable speculative decoding")
         # ----- on-device sampling stack (PR 20)
         self.sampling = bool(sampling)
-        self.spec_verifier = str(spec_verifier)
-        if self.spec_verifier not in ("rejection", "greedy"):
-            raise ValueError(
-                f"spec_verifier must be 'rejection' or 'greedy', got "
-                f"{spec_verifier!r}")
-        if self.spec_tokens and self.sampling and \
-                self.spec_verifier == "greedy":
-            raise ValueError(
-                "speculative decoding on a sampling engine requires the "
-                "rejection verifier (spec_verifier='rejection') — the "
-                "greedy prefix-matcher would silently reshape sampled "
-                "output distributions; pass sampling=False to keep the "
-                "legacy greedy verifier")
         self.logit_masks = bool(logit_masks)
         if self.logit_masks and not self.sampling:
             raise ValueError(
@@ -902,25 +854,12 @@ class ServingEngine:
             * block_size
         self._nbper = self._cache_len // block_size      # block-table width
 
-        self.chunked_prefill = (prompt_buckets is None) \
-            if chunked_prefill is None else bool(chunked_prefill)
-        if self.chunked_prefill:
-            self.prompt_buckets: Tuple[int, ...] = ()
-            # floor of 2: forward_cached dispatches per-row DECODE on T == 1,
-            # so a width-1 prefill window would be misread as a decode step
-            # (1-token prompts prefill fine in a width-2 window — the pad
-            # column writes to scratch)
-            self.prefill_chunk = max(2, min(int(prefill_chunk),
-                                            self._cache_len))
-        else:
-            buckets = tuple(sorted(prompt_buckets)) if prompt_buckets \
-                else default_buckets(self.max_seq_len)
-            if any(b > self.max_seq_len for b in buckets):
-                raise ValueError(
-                    f"prompt bucket(s) {buckets} exceed max_seq_len "
-                    f"{self.max_seq_len}")
-            self.prompt_buckets = buckets
-            self.prefill_chunk = 0
+        # floor of 2: forward_cached dispatches per-row DECODE on T == 1,
+        # so a width-1 prefill window would be misread as a decode step
+        # (1-token prompts prefill fine in a width-2 window — the pad
+        # column writes to scratch)
+        self.prefill_chunk = max(2, min(int(prefill_chunk),
+                                        self._cache_len))
         self.prefill_batch = int(prefill_batch)
         if self.prefill_batch < 1:
             raise ValueError(
@@ -939,10 +878,6 @@ class ServingEngine:
         dp = int(dict(engine.mesh.shape).get(DP_AXIS, 1)) \
             if self.engine_mode == "dp_tp" else 1
         if self.engine_mode == "dp_tp":
-            if not self.chunked_prefill:
-                raise ValueError(
-                    "engine_mode='dp_tp' requires chunked-prefill mode — "
-                    "drop prompt_buckets / pass chunked_prefill=True")
             if spec_tokens or int(host_blocks) or quantize:
                 raise ValueError(
                     "engine_mode='dp_tp' v1 excludes speculative decoding, "
@@ -976,11 +911,6 @@ class ServingEngine:
                     f"size {mesh_sp} — build the engine with "
                     f"config={{'sequence_parallel': {sp}}} "
                     "(init_serving(sp=...) does this for you)")
-            if not self.chunked_prefill:
-                raise ValueError(
-                    "sp > 1 requires chunked-prefill mode — the Ulysses "
-                    "all-to-all shards the fixed prefill_chunk window; "
-                    "drop prompt_buckets / pass chunked_prefill=True")
             if self.prefill_chunk % self.sp_degree:
                 raise ValueError(
                     f"prefill_chunk ({self.prefill_chunk}) must divide "
@@ -1005,12 +935,6 @@ class ServingEngine:
                 f"resident_window_blocks must be >= 0, got "
                 f"{resident_window_blocks}")
         if self.resident_window_blocks:
-            if not self.chunked_prefill:
-                raise ValueError(
-                    "resident_window_blocks > 0 requires chunked-prefill "
-                    "mode — giant prompts stream through the fixed "
-                    "prefill window; drop prompt_buckets / pass "
-                    "chunked_prefill=True")
             if not int(host_blocks):
                 raise ValueError(
                     "resident_window_blocks > 0 needs the tiered KV cache "
@@ -1092,7 +1016,7 @@ class ServingEngine:
             self._alloc = BlockAllocator(num_blocks)
             self._scratch_blocks = None
         self._prefix = PrefixCache(self.block_size) \
-            if (prefix_caching and self.chunked_prefill) else None
+            if prefix_caching else None
         self.host_blocks = int(host_blocks)
         if self.host_blocks < 0:
             raise ValueError(f"host_blocks must be >= 0, got {host_blocks}")
@@ -1106,10 +1030,9 @@ class ServingEngine:
                 "host arena; lower swap_batch or grow host_blocks")
         if self.host_blocks and self._prefix is None:
             raise ValueError(
-                "the tiered KV cache (host_blocks > 0) needs chunked-"
-                "prefill mode with prefix_caching=True — promoted chains "
-                "re-register in the prefix trie (drop prompt_buckets / "
-                "prefix_caching=False, or host_blocks)")
+                "the tiered KV cache (host_blocks > 0) needs "
+                "prefix_caching=True — promoted chains re-register in the "
+                "prefix trie (drop prefix_caching=False, or host_blocks)")
 
         # ----- disaggregated serving role + NVMe third tier
         self.role = str(role)
@@ -1248,34 +1171,25 @@ class ServingEngine:
                 "vocab_size — the [slots, vocab] mask operand is sized "
                 "from it")
 
-        # compiled-program caches (true LRU, utils/lru.py — shared policy
-        # with InferenceEngine._generate_fns); sized past the ladder so a
-        # large custom bucket set can never thrash-recompile per call
-        self._prefill_fns = LRUCache(
-            capacity=max(16, len(self.prompt_buckets) + 1))
+        # compiled programs, built on first use
+        self._prefill_fn = None
         self._decode_fn = None
         self._verify_fn = None
         self._draft_fn = None
-        #: compile probe — one entry per traced program; chunked mode stays
-        #: at 1 prefill + 1 decode for an entire trace (speculative: 1
-        #: prefill + 1 verify [+ 1 draft rollout] — never more than 3)
+        #: compile probe — one entry per traced program: 1 prefill + 1
+        #: decode for an entire trace (speculative: 1 prefill + 1 verify
+        #: [+ 1 draft rollout] — never more than 3)
         self.compiled_programs: List[Any] = []
 
         # ----- correctness tooling (analysis/): the recompile sentry wraps
         # every jitted body below so trace counts are enforced against the
-        # declared budget — 2 chunked (1 prefill + 1 decode; the n-gram
-        # speculative verify replaces decode), 3 with a draft model (fused
-        # prefill + rollout + verify), O(#buckets)+2 bucketed (ladder +
-        # full-cache-width preemption fallback + decode).  debug_checks
-        # additionally raises at trace time and audits the paged host state
-        # every scheduler iteration.
+        # declared budget — 2 (1 prefill + 1 decode; the n-gram speculative
+        # verify replaces decode), 3 with a draft model (fused prefill +
+        # rollout + verify).  debug_checks additionally raises at trace
+        # time and audits the paged host state every scheduler iteration.
         self.debug_checks = bool(debug_checks)
-        if self.spec_tokens:
-            self.compile_budget = 3 if draft is not None else 2
-        elif self.chunked_prefill:
-            self.compile_budget = 2
-        else:
-            self.compile_budget = len(self.prompt_buckets) + 2
+        self.compile_budget = 3 if self.spec_tokens and draft is not None \
+            else 2
         if self.host_blocks:
             # the tiered KV swap pair: kv_demote (block gather) +
             # kv_promote (block scatter), both fixed-shape at swap_batch —
@@ -1312,10 +1226,6 @@ class ServingEngine:
         self.ngram_max = int(ngram_max)    # kept for resolved_config()
         self.ngram_min = int(ngram_min)
         if self.spec_tokens:
-            if not self.chunked_prefill:
-                raise ValueError(
-                    "speculative decoding requires chunked-prefill mode — "
-                    "drop prompt_buckets / pass chunked_prefill=True")
             if draft is not None:
                 from .engine import InferenceEngine
 
@@ -1583,12 +1493,9 @@ class ServingEngine:
         #: raw (un-sentry-wrapped) program bodies + shape meta, captured
         #: at build time for the FLOPs profiler — lowering a RAW body for
         #: cost_analysis never ticks the sentry and never compiles
-        #: (telemetry/flops.py).  "prefill" maps width -> body (bucketed
-        #: mode builds one program per bucket width — each must be costed
-        #: at ITS width), with per-width invocation counts alongside.
+        #: (telemetry/flops.py).
         self._program_bodies: Dict[str, Any] = {}
         self._program_meta: Dict[str, Any] = {}
-        self._prefill_calls_by_width: Dict[int, int] = {}
         #: router-noted flow ids (uid -> Chrome flow id): admission emits
         #: the matching flow-finish so the merged fleet trace draws the
         #: route -> admit arrow (telemetry/trace.py flow events)
@@ -1636,9 +1543,8 @@ class ServingEngine:
             f"ServingEngine: slots={self.slots}, cache_len="
             f"{self._cache_len}, block_size={self.block_size}, "
             f"num_blocks={num_blocks}, "
-            + (f"chunked prefill (chunk={self.prefill_chunk}, prefix_cache="
-               f"{self._prefix is not None})" if self.chunked_prefill
-               else f"bucketed prefill {self.prompt_buckets}")
+            f"chunked prefill (chunk={self.prefill_chunk}, prefix_cache="
+            f"{self._prefix is not None})"
             + f", prefill_batch={self.prefill_batch}"
             + (f", speculative K={self.spec_tokens} "
                f"({'draft ' + self._draft.module.name if self._draft else 'n-gram'})"
@@ -2101,12 +2007,14 @@ class ServingEngine:
                 else ("decode", self.slots, K))
         return self._decode_fn
 
-    def _get_prefill_fn(self, width: int):
-        """One compiled prefill program per window length: chunked mode uses
-        a single ``prefill_chunk`` width, bucketed mode one per bucket.
-        With a draft model, the draft's prefill is FUSED into the same
-        program (both caches advance through the identical window/table
-        contract), so speculative prefill still costs one program."""
+    def _get_prefill_fn(self):
+        """The one compiled prefill program, ``prefill_chunk`` wide.  With
+        a draft model, the draft's prefill is FUSED into the same program
+        (both caches advance through the identical window/table contract),
+        so speculative prefill still costs one program."""
+        if self._prefill_fn is not None:
+            return self._prefill_fn
+        width = self.prefill_chunk
         fwd, prepare = self._forward, self.engine._prepare
         draft = self._draft
         constrain = self._constrain_pool
@@ -2114,49 +2022,38 @@ class ServingEngine:
         next_tokens, pack = self._next_tokens, self._pack_samp
         with_record = self._with_record
 
-        def build():
-            def prefill(params, cache, ids, block_tables, base, valid,
-                        *samp):
-                """ids [J, width] right-padded; base int32 [J] per-row chunk
-                start (reused-prefix length for fresh slots); valid int32
-                [J] real tokens per row (pads write to scratch block 0).
-                *samp is the ROW-gathered sampling tail (empty for greedy
-                engines): the first emitted token of a sampled request
-                draws with the SAME counter key (seed, emitted count) the
-                decode path would use — that is what makes preempt/crash
-                resumes, which re-emit through prefill, token-exact."""
-                logits, cache, rec = fwd(prepare(params), ids, cache, base,
-                                         lengths=valid,
-                                         block_tables=block_tables)
-                return with_record(next_tokens(logits, pack(samp)), rec), \
-                    constrain(cache)
+        def prefill(params, cache, ids, block_tables, base, valid, *samp):
+            """ids [J, width] right-padded; base int32 [J] per-row chunk
+            start (reused-prefix length for fresh slots); valid int32
+            [J] real tokens per row (pads write to scratch block 0).
+            *samp is the ROW-gathered sampling tail (empty for greedy
+            engines): the first emitted token of a sampled request
+            draws with the SAME counter key (seed, emitted count) the
+            decode path would use — that is what makes preempt/crash
+            resumes, which re-emit through prefill, token-exact."""
+            logits, cache, rec = fwd(prepare(params), ids, cache, base,
+                                     lengths=valid,
+                                     block_tables=block_tables)
+            return with_record(next_tokens(logits, pack(samp)), rec), \
+                constrain(cache)
 
-            if self.resident_window_blocks:
-                # windowed prefill REPLACES the plain program (+0 budget):
-                # later chunks of a giant prompt must not attend into the
-                # demoted middle (those table entries now point at
-                # scratch), so the same window mask gates the T > 1 path
-                lm_tokens = self._landmark_blocks * self.block_size
+        body, donate = prefill, self._donate()
+        if self.resident_window_blocks:
+            # windowed prefill REPLACES the plain program (+0 budget):
+            # later chunks of a giant prompt must not attend into the
+            # demoted middle (those table entries now point at
+            # scratch), so the same window mask gates the T > 1 path
+            lm_tokens = self._landmark_blocks * self.block_size
 
-                def prefill_windowed(params, cache, ids, block_tables,
-                                     base, valid, window_start, *samp):
-                    with decode_attention.window_context(
-                            window_start, lm_tokens):
-                        return prefill(params, cache, ids, block_tables,
-                                       base, valid, *samp)
+            def prefill_windowed(params, cache, ids, block_tables,
+                                 base, valid, window_start, *samp):
+                with decode_attention.window_context(
+                        window_start, lm_tokens):
+                    return prefill(params, cache, ids, block_tables,
+                                   base, valid, *samp)
 
-                self._program_bodies.setdefault("prefill", {})[width] = \
-                    prefill_windowed
-                return jax.jit(
-                    self.sentry.wrap(prefill_windowed,
-                                     f"prefill[w{width}]"),
-                    donate_argnums=self._donate())
-            if draft is None:
-                self._program_bodies.setdefault("prefill", {})[width] = \
-                    prefill
-                return jax.jit(
-                    self.sentry.wrap(prefill, f"prefill[w{width}]"),
-                    donate_argnums=self._donate())
+            body = prefill_windowed
+        elif draft is not None:
             dfwd = draft.module.decode_hooks["forward_cached"]
             dprepare = draft._prepare
 
@@ -2168,17 +2065,15 @@ class ServingEngine:
                                  lengths=valid, block_tables=block_tables)
                 return first, cache, dcache
 
-            self._program_bodies.setdefault("prefill", {})[width] = \
-                prefill_fused
+            body, donate = prefill_fused, (2, 3) if donate else ()
             self._program_meta["prefill_fused"] = True
-            return jax.jit(
-                self.sentry.wrap(prefill_fused, f"prefill[w{width}]"),
-                donate_argnums=(2, 3) if self._donate() else ())
-
-        return self._prefill_fns.get_or_build(
-            width, build,
-            on_build=lambda _: self.compiled_programs.append(
-                ("prefill", width, self.prefill_batch)))
+        self._program_bodies["prefill"] = body
+        self._prefill_fn = jax.jit(
+            self.sentry.wrap(body, f"prefill[w{width}]"),
+            donate_argnums=donate)
+        self.compiled_programs.append(
+            ("prefill", width, self.prefill_batch))
+        return self._prefill_fn
 
     def _get_verify_fn(self):
         """The speculative K+1 verify program: one fixed-shape paged
@@ -3074,26 +2969,6 @@ class ServingEngine:
         return slot in self._active
 
     # --------------------------------------------------------------- schedule
-    def _bucket_for(self, prompt_len: int) -> int:
-        for b in self.prompt_buckets:
-            if prompt_len <= b:
-                return b
-        raise ValueError(
-            f"prompt length {prompt_len} exceeds the largest bucket "
-            f"{self.prompt_buckets[-1]}")
-
-    def _prefill_width(self, prompt_len: int) -> int:
-        """Prefill window for a prompt in bucketed mode: its ladder rung —
-        or the full cache width for a preemption resume whose prompt (with
-        generated tokens folded in) outgrew a custom ladder, instead of
-        failing mid-trace (the prefill program is width-generic, so this
-        costs at most one extra compile).  Floor of 2 for the same T == 1
-        decode-dispatch reason as ``prefill_chunk``."""
-        for b in self.prompt_buckets:
-            if prompt_len <= b:
-                return max(2, b)
-        return self._cache_len
-
     def _admit(self):
         """Head-of-queue-gated admission into free slots (priority order,
         module docstring), gated on block availability (free + prefix-
@@ -3258,8 +3133,6 @@ class ServingEngine:
                 "engine was built without the constrained-decoding lane "
                 "— pass logit_masks=True (the [slots, vocab] mask "
                 "operand is only threaded through the programs then)")
-        if not self.chunked_prefill:
-            self._bucket_for(len(r.prompt))  # raises if no bucket fits
 
     def _session_boundary_reset(self) -> None:
         """First submit into an idle engine: object ids and prefetch gates
@@ -4168,66 +4041,47 @@ class ServingEngine:
 
     # ---------------------------------------------------------------- prefill
     def _run_prefill(self, params) -> int:
-        """Advance prefilling slots: one fixed-width chunk per slot per
-        iteration (chunked mode), or the whole prompt in its bucket's
-        program (bucketed fallback).  Both modes run ``prefill_batch`` rows
-        per call; pad rows write to scratch.  Returns the number of
-        prefill calls made."""
+        """Advance prefilling slots: one ``prefill_chunk``-wide chunk per
+        slot per iteration, ``prefill_batch`` rows per call; pad rows write
+        to scratch.  Returns the number of prefill calls made."""
         active = self._active
         pre = [s for s, st in sorted(active.items(),
                                      key=lambda kv: kv[1].admit_seq)
                if st.phase == "prefill"]
         if not pre:
             return 0
-        if self.chunked_prefill:
-            groups = []
-            ready = []
-            for slot in pre:
-                if slot not in active:
-                    continue               # preempted by an earlier alloc
-                st = active[slot]
-                v = min(self.prefill_chunk, st.plen_eff - st.base)
-                if self._kv(self._ensure_blocks, slot, st.base + v):
-                    ready.append(slot)
-            for i in range(0, len(ready), self.prefill_batch):
-                group = [s for s in ready[i:i + self.prefill_batch]
-                         if s in active]
-                if group:
-                    groups.append((self.prefill_chunk, group))
-        else:
-            by_bucket: Dict[int, list] = {}
-            for slot in pre:
-                if slot not in active:
-                    continue
-                st = active[slot]
-                if self._kv(self._ensure_blocks, slot, st.plen_eff):
-                    by_bucket.setdefault(self._prefill_width(st.plen_eff),
-                                         []).append(slot)
-            groups = []
-            for bucket in sorted(by_bucket):
-                grp = by_bucket[bucket]
-                for i in range(0, len(grp), self.prefill_batch):
-                    group = [s for s in grp[i:i + self.prefill_batch]
-                             if s in active]
-                    if group:
-                        groups.append((bucket, group))
+        groups = []
+        ready = []
+        for slot in pre:
+            if slot not in active:
+                continue               # preempted by an earlier alloc
+            st = active[slot]
+            v = min(self.prefill_chunk, st.plen_eff - st.base)
+            if self._kv(self._ensure_blocks, slot, st.base + v):
+                ready.append(slot)
+        for i in range(0, len(ready), self.prefill_batch):
+            group = [s for s in ready[i:i + self.prefill_batch]
+                     if s in active]
+            if group:
+                groups.append(group)
 
         calls = 0
-        for width, group in groups:
+        for group in groups:
             group = [s for s in group if s in active]
             if not group:
                 continue
-            self._run_prefill_group(width, group, params)
+            self._run_prefill_group(group, params)
             calls += 1
         return calls
 
-    def _run_prefill_group(self, width, group, params):
-        """One prefill call: each row advances its slot by ``min(width,
-        remaining prompt)`` tokens from its own base.  Rows whose window
-        reaches the last prompt token yield that slot's first generated
-        token (logits are gathered per row at ``valid - 1``)."""
+    def _run_prefill_group(self, group, params):
+        """One prefill call: each row advances its slot by
+        ``min(prefill_chunk, remaining prompt)`` tokens from its own base.
+        Rows whose window reaches the last prompt token yield that slot's
+        first generated token (logits are gathered per row at
+        ``valid - 1``)."""
         active = self._active
-        j = self.prefill_batch
+        j, width = self.prefill_batch, self.prefill_chunk
         ids = np.zeros((j, width), np.int32)
         bt = np.zeros((j, self._nbper), np.int32)
         base = np.zeros(j, np.int32)
@@ -4257,7 +4111,7 @@ class ServingEngine:
                     ws[row] = self._window_start[slot]
                 args += (jnp.asarray(ws),)
             args += samp
-        prefill_fn = self._get_prefill_fn(width)
+        prefill_fn = self._get_prefill_fn()
         with self.timeline.span("prefill", width=width, rows=len(group),
                                 slots=list(map(int, group))) as span_args:
             if self._draft is not None:
@@ -4280,8 +4134,6 @@ class ServingEngine:
                                   rows=len(group), bytes=nbytes,
                                   sp=self.sp_degree)
         self._c_prefill_calls.inc()
-        self._prefill_calls_by_width[width] = \
-            self._prefill_calls_by_width.get(width, 0) + 1
         for row, (slot, v) in enumerate(rows):
             st = active[slot]
             st.base += v
@@ -4335,12 +4187,11 @@ class ServingEngine:
         """The engine's resolved serving knobs as a **round-trippable**,
         JSON-able ``init_serving`` kwargs dict: ``init_serving(model,
         **srv.resolved_config())`` rebuilds a behaviorally identical
-        engine (auto knobs — ``chunked_prefill``, ``shard_kv``,
-        ``num_blocks``, SLO targets — come back resolved, so the rebuilt
-        engine does not depend on the defaults in force when this one was
-        built).  This is what autotuner trials, ``best_config.json``, and
-        the bench JSONs persist so a winning config reproduces from
-        artifacts alone.
+        engine (auto knobs — ``shard_kv``, ``num_blocks``, SLO targets —
+        come back resolved, so the rebuilt engine does not depend on the
+        defaults in force when this one was built).  This is what
+        autotuner trials and ``best_config.json`` persist so a winning
+        config reproduces from artifacts alone.
 
         Not captured: the wrapped ``init_inference`` engine itself (model,
         params, dtype, quant group sizes) and a ``draft`` model object —
@@ -4352,20 +4203,17 @@ class ServingEngine:
             "max_seq_len": self.max_seq_len,
             "block_size": self.block_size,
             "num_blocks": int(self._alloc.num_blocks),
-            "chunked_prefill": bool(self.chunked_prefill),
             "prefill_chunk": int(self.prefill_chunk),
             "decode_steps": self._K,
             "engine_mode": self.engine_mode,
             "sp": self.sp_degree,
             "resident_window_blocks": self.resident_window_blocks,
-            "prompt_buckets": list(self.prompt_buckets) or None,
             "prefill_batch": self.prefill_batch,
             "prefix_caching": self._prefix is not None,
             "spec_tokens": self.spec_tokens,
             "ngram_max": self.ngram_max,
             "ngram_min": self.ngram_min,
             "sampling": self.sampling,
-            "spec_verifier": self.spec_verifier,
             "logit_masks": self.logit_masks,
             "quantize": self.quantize,
             "host_blocks": self.host_blocks,
@@ -4455,7 +4303,6 @@ class ServingEngine:
         if self._nvme is not None:
             self._g_nvme_in_use.set(self._host.nvme_blocks_in_use)
         st = {
-            "mode": "chunked" if self.chunked_prefill else "bucketed",
             "compile_count": self.compile_count,
             "compile_budget": self.compile_budget,
             "debug_checks": self.debug_checks,
@@ -4503,7 +4350,6 @@ class ServingEngine:
             # sampling stack (sampling=False: flags off, zeros — schema
             # stays stable)
             "sampling": self.sampling,
-            "spec_verifier": self.spec_verifier,
             "logit_masks": self.logit_masks,
             "sampled_requests": int(
                 self._c_sampled["sampled"].value

@@ -1,8 +1,8 @@
 """ZeRO-Inference analog: serve models bigger than device HBM.
 
 Reference parity: ZeRO-Inference (zero stage-3 ``offload_param: cpu``
-driving inference-only forwards; the OPT-30B-on-one-V100 configuration in
-BASELINE.md, driven by ``benchmarks/inference/gpt-bench.py``).  The
+driving inference-only forwards; the reference's OPT-30B-on-one-V100
+configuration, driven by its ``benchmarks/inference/gpt-bench.py``).  The
 reference keeps full weights in CPU DRAM and streams each layer's
 partition to the GPU as its forward runs, amortizing the traffic with
 large batches.

@@ -13,7 +13,7 @@ for the sharding engine rather than ported from torch modules:
  - ``tp_rules`` emits Megatron-style column/row parallel PartitionSpecs for the
    attention and MLP weights over the ``tp`` mesh axis.
 
-This is driver config #1's model (GPT-2 125M, reference BASELINE.json).
+This is driver config #1's model (GPT-2 125M).
 """
 
 from __future__ import annotations
@@ -553,7 +553,7 @@ def forward_cached(cfg: GPT2Config, params, input_ids, cache, pos,
      - T == 1 (decode): row ``b``'s token sits at global position
        ``lengths[b]`` — per-row cache write, per-row attention prefix.
        ``pos`` is ignored.
-     - T > 1 (ragged bucketed prefill): rows are right-padded to T with
+     - T > 1 (ragged prefill window): rows are right-padded to T with
        ``pos`` as the shared base (0 for fresh slots); causal attention makes
        the pad positions unreachable from valid queries, and the returned
        logits are gathered at each row's own last prompt token

@@ -1,6 +1,6 @@
 """Llama family (Llama-2/3), TPU-native.
 
-Driver configs #2/#3 (BASELINE.json: Llama-3-8B ZeRO-3, Llama-3-70B 3D).
+Driver configs #2/#3 (Llama-3-8B ZeRO-3, Llama-3-70B 3D).
 Same structural choices as gpt2.py — stacked [L, ...] blocks + ``lax.scan``
 (ZeRO-3 gathers one layer ahead), optional remat, Megatron-style TP specs,
 pipeline hooks — with the Llama specifics: RMSNorm, rotary embeddings, grouped-

@@ -1,6 +1,6 @@
 """Mixtral and OLMoE (sparse-MoE Llama), TPU-native.
 
-Driver config #4 (BASELINE.json: Mixtral 8x7B expert-parallel + ZeRO-2).
+Driver config #4 (Mixtral 8x7B expert-parallel + ZeRO-2).
 Llama attention blocks with the FFN replaced by a top-k-gated MoE
 (``deepspeed_tpu.moe``): expert weights are stacked [L, E, ...] with the expert
 dim sharded over the ``ep`` mesh axis, so scan-over-layers + vmapped experts +

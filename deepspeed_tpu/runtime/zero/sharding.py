@@ -63,8 +63,7 @@ def shard_over_zero_axes(shape: Tuple[int, ...], base_spec: Optional[P], mesh: M
         used.update(_flatten_spec_entry(entry))
     # shard over whichever zero axes the TP spec leaves free: an expert
     # leaf already sharded over ep still gets its opt/grad shards divided
-    # over dp (found by the memplan audit — the old early-return left
-    # dp-redundant optimizer copies for every expert parameter)
+    # over dp, so no expert parameter keeps dp-redundant optimizer copies
     remaining = tuple(a for a in zero_axes if a not in used)
     zero_ws = _axes_size(mesh, remaining)
     if zero_ws == 1 or len(shape) == 0:

@@ -37,10 +37,9 @@ only thing that changes behavior.  **Deterministic armed**: schedules
 key off per-replica step counters (not wall clocks) and every random
 draw comes from per-replica ``numpy`` Generator streams derived from
 the plan seed, so the same plan against the same trace injects the
-same faults at the same points — the chaos parity gate in
-``benchmarks/serving_bench.py --chaos`` and
-``tests/unit/test_serving_faults.py`` replays a kill-one-of-two run and
-pins token-EXACT equality with the fault-free twin.
+same faults at the same points — ``tests/unit/test_serving_faults.py``
+replays a kill-one-of-two run and pins token-EXACT equality with the
+fault-free twin.
 
 :class:`RequestRejected` lives here too: the loud, typed result of
 SLO-class-aware load shedding (``ReplicaRouter`` bounded admission —

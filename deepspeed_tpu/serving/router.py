@@ -131,8 +131,7 @@ instead of letting every class's latency collapse together; sheds tick
 ``serving_requests_shed_total{slo_class=}`` and drop a ``shed``
 timeline event.  Higher classes keep admitting (the priority queue
 already ordered them first), so ``realtime``/``interactive`` TTFT holds
-while ``batch`` absorbs the rejections — the BENCH_r14 overload lane
-measures exactly this.
+while ``batch`` absorbs the rejections.
 """
 
 from __future__ import annotations

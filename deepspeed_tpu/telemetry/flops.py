@@ -186,7 +186,7 @@ class ServingFlopsProfiler:
             for phase in ("prefill", "decode", "swap", "idle")}
 
     # -------------------------------------------------------- per-program cost
-    def _abstract_args(self, family: str, width: Optional[int] = None):
+    def _abstract_args(self, family: str):
         """ShapeDtypeStruct argument tree mirroring the live program's
         fixed shapes — no device memory, no transfers."""
         import jax
@@ -217,7 +217,8 @@ class ServingFlopsProfiler:
                         sds(srv._dcache))
             else:
                 head = (params, cache)
-            return head + (i32(j, width), i32(j, nb), i32(j), i32(j))
+            return head + (i32(j, srv.prefill_chunk), i32(j, nb), i32(j),
+                           i32(j))
         if family == "verify":
             w = srv.spec_tokens + 1
             return (params, cache, i32(slots, w), i32(slots, nb),
@@ -227,13 +228,12 @@ class ServingFlopsProfiler:
                     i32(slots), i32(slots, nb))
         raise KeyError(f"unknown program family {family!r}")
 
-    def _shape_meta(self, family: str,
-                    width: Optional[int] = None) -> Dict[str, int]:
+    def _shape_meta(self, family: str) -> Dict[str, int]:
         srv = self.srv
         if family == "decode":
             return {"rows": srv.slots, "width": 1}
         if family == "prefill":
-            return {"rows": srv.prefill_batch, "width": int(width)}
+            return {"rows": srv.prefill_batch, "width": srv.prefill_chunk}
         if family == "verify":
             return {"rows": srv.slots, "width": srv.spec_tokens + 1}
         if family == "draft":
@@ -241,7 +241,7 @@ class ServingFlopsProfiler:
             return {"rows": srv.slots, "width": srv.spec_tokens}
         return {"rows": 0, "width": 0}
 
-    def lower(self, family: str, width: Optional[int] = None):
+    def lower(self, family: str):
         """``jax.stages.Lowered`` of the raw program body at the live
         program's fixed shapes — lowering only: it never compiles and never
         ticks the sentry.  ``None`` when the engine has not built that
@@ -250,19 +250,15 @@ class ServingFlopsProfiler:
         import jax
 
         body = self.srv._program_bodies.get(family)
-        if family == "prefill" and body is not None:
-            body = body.get(width)
         if body is None:
             return None
-        args = self._abstract_args(family, width)
+        args = self._abstract_args(family)
         ctx = getattr(self.srv, "_decode_ctx", self.srv._tp_ctx) \
             if family == "decode" else self.srv._tp_ctx
         with ctx():
             return jax.jit(body).lower(*args)
 
-    def _cost_analysis_flops(self, family: str,
-                             width: Optional[int] = None
-                             ) -> Optional[float]:
+    def _cost_analysis_flops(self, family: str) -> Optional[float]:
         """``Lowered.cost_analysis()`` of the raw body — lowering only,
         never a compile; ``None`` when the backend reports nothing."""
         if family == "decode" and getattr(self.srv, "_K", 1) > 1:
@@ -271,7 +267,7 @@ class ServingFlopsProfiler:
             # cost would be off by up to K.  Use the analytic estimate.
             return None
         try:
-            lowered = self.lower(family, width)
+            lowered = self.lower(family)
             if lowered is None:
                 return None
             ca = lowered.cost_analysis()
@@ -285,51 +281,30 @@ class ServingFlopsProfiler:
                 f"({e}); using the analytic estimate")
             return None
 
-    def _entries(self):
-        """(entry_name, family, width) for every program built so far.
-        Prefill is per-WIDTH: the bucketed ladder builds one program per
-        bucket, and each must be costed (and call-counted) at its own
-        width — a single "last built" entry would mis-account every
-        other bucket by the width ratio.  Chunked mode has exactly one
-        width, so its entry keeps the plain "prefill" name."""
-        srv = self.srv
-        out = []
-        for family, body in srv._program_bodies.items():
-            if family in ("kv_demote", "kv_promote"):
-                continue                      # data movement: zero FLOPs
-            if family == "prefill":
-                for w in sorted(body):
-                    name = "prefill" if srv.chunked_prefill \
-                        else f"prefill[w{w}]"
-                    out.append((name, family, w))
-            else:
-                out.append((family, family, None))
-        return out
-
     def profile_programs(self, refresh: bool = False
                          ) -> Dict[str, Dict[str, Any]]:
         """Per-program FLOPs for every program the engine has built so
         far: ``{"flops_per_call", "flops_analytic", "tokens_per_call",
-        "source"}`` — cached per entry (shapes are fixed once built; a
-        bucket width first compiled after an earlier report is picked up
-        on the next one)."""
+        "source"}`` — cached per program (shapes are fixed once built)."""
         srv = self.srv
         dims = _model_dims(srv.engine.module.model_config)
         ddims = _model_dims(srv._draft.module.model_config) \
             if srv._draft is not None else None
-        for name, family, width in self._entries():
-            if name in self._programs and not refresh:
+        for family in srv._program_bodies:
+            if family in ("kv_demote", "kv_promote"):
+                continue                      # data movement: zero FLOPs
+            if family in self._programs and not refresh:
                 continue
-            meta = self._shape_meta(family, width)
+            meta = self._shape_meta(family)
             fam_dims = ddims if family == "draft" else dims
             comp = analytic_components(
                 family, fam_dims, rows=meta["rows"], width=meta["width"],
                 ctx=srv._cache_len)
             analytic = comp["head"] + comp["layers"]
-            reported = self._cost_analysis_flops(family, width)
+            reported = self._cost_analysis_flops(family)
             flops, source = self._reconcile(
                 family, reported, comp, fam_dims["layers"])
-            self._programs[name] = {
+            self._programs[family] = {
                 "rows": meta["rows"],
                 "width": meta["width"],
                 "flops_analytic": analytic,
@@ -379,15 +354,11 @@ class ServingFlopsProfiler:
         built)."""
         srv = self.srv
         programs = self.profile_programs()
-        calls = {"decode": srv.decode_steps,
+        calls = {"prefill": srv.prefill_calls,
+                 "decode": srv.decode_steps,
                  "verify": srv.spec_rounds,
                  "draft": srv.spec_rounds if srv._draft is not None
                  else 0}
-        for name, family, width in self._entries():
-            if family == "prefill":
-                # per-WIDTH invocation counts: each bucket program is
-                # billed at its own width, never the last-built one's
-                calls[name] = srv._prefill_calls_by_width.get(width, 0)
         total = sum(p["flops_per_call"] * calls.get(f, 0)
                     for f, p in programs.items())
         if total > self._last_total:

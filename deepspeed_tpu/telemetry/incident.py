@@ -91,7 +91,10 @@ __all__ = ["IncidentRecorder", "StallWatchdog", "BUNDLE_SCHEMA_VERSION",
            "MANIFEST_KEYS", "TRIGGER_KINDS", "is_bundle", "load_bundle",
            "replay_bundle", "gpt2_model_meta", "format_thread_stacks"]
 
-BUNDLE_SCHEMA_VERSION = 1
+#: 2: ``replica_configs`` hold the 29 options ``ServingEngine`` takes —
+#: a version-1 bundle's configs carry three it no longer does, so it is
+#: refused by version rather than having keys stripped
+BUNDLE_SCHEMA_VERSION = 2
 BUNDLE_FORMAT = "graft-incident"
 
 TRIGGER_KINDS = ("replica_fail", "invariant_violation", "retrace",
@@ -706,25 +709,39 @@ def is_bundle(path: str) -> bool:
     (``.incident-*.tmp-*``) have no manifest by construction (it is
     written last, the directory renamed after), so a crash mid-dump can
     never produce a false positive."""
+    m = _read_manifest(path)
+    return m is not None and \
+        m.get("schema_version") == BUNDLE_SCHEMA_VERSION
+
+
+def _read_manifest(path: str) -> Optional[Dict[str, Any]]:
+    """The manifest of a bundle directory of THIS format (any schema
+    version), else ``None``."""
     mpath = os.path.join(path, "manifest.json")
     if not os.path.isdir(path) or not os.path.isfile(mpath):
-        return False
+        return None
     try:
         with open(mpath) as f:
             m = json.load(f)
     except (OSError, ValueError):  # graft: noqa(GL013) predicate: unreadable = not a bundle
-        return False
-    return m.get("bundle_format") == BUNDLE_FORMAT and \
-        m.get("schema_version") == BUNDLE_SCHEMA_VERSION
+        return None
+    return m if isinstance(m, dict) and \
+        m.get("bundle_format") == BUNDLE_FORMAT else None
 
 
 def load_bundle(path: str) -> Dict[str, Any]:
     """Parse a bundle directory into ``{stem: payload}`` (JSON files
     parsed, others raw text, plus ``"path"``); raises ``ValueError`` on
-    a non-bundle."""
-    if not is_bundle(path):
+    a non-bundle and on a bundle of another schema version."""
+    m = _read_manifest(path)
+    if m is None:
         raise ValueError(f"{path!r} is not a complete incident bundle "
                          "(missing/invalid manifest.json)")
+    if m.get("schema_version") != BUNDLE_SCHEMA_VERSION:
+        raise ValueError(
+            f"{path!r} is an incident bundle of schema_version "
+            f"{m.get('schema_version')!r}; this build reads "
+            f"{BUNDLE_SCHEMA_VERSION} only")
     out: Dict[str, Any] = {"path": os.path.abspath(path)}
     for fname in sorted(os.listdir(path)):
         fpath = os.path.join(path, fname)

@@ -1,11 +1,8 @@
 """Bounded true-LRU mapping for compiled-program caches.
 
-Both inference engines key jitted programs by shape tuples
-(``InferenceEngine._generate_fns`` per ``(batch, prompt_len, ...)``,
-``ServingEngine._prefill_fns`` per prefill window length).  Hot shapes must
-survive eviction pressure, so a *hit* refreshes the entry (true LRU) instead
-of insertion-order FIFO — this class is the one shared implementation of
-that policy.
+``InferenceEngine._generate_fns`` keys jitted programs by shape tuples
+(``(batch, prompt_len, ...)``).  Hot shapes must survive eviction pressure,
+so a *hit* refreshes the entry (true LRU) instead of insertion-order FIFO.
 
 ``get``/``get_or_build`` are the LRU-touching reads; plain ``[]`` access and
 iteration are order-preserving peeks (oldest first) so tests and probes can

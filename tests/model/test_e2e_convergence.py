@@ -1,17 +1,11 @@
 """End-to-end convergence lane (reference
 ``tests/model/Megatron_GPT2/run_func_test.py``): a REAL byte-level-BPE
 tokenizer trained on a synthetic corpus, a small GPT-2 trained through the
-public engine to a target loss, checkpoint-resume mid-run, and a
-perf/structural check of the headline bench entrypoint.
+public engine to a target loss, and checkpoint-resume mid-run.
 
-CPU-sim, marked slow; chip numbers are the driver's to take (it runs
-``bench.py`` and ``chip_smoke.py`` on the TPU itself).
+CPU-sim, marked slow; chip numbers are the benchmark's (``BENCHMARK.json``
++ ``chipbench/``).
 """
-
-import json
-import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -93,40 +87,3 @@ def test_gpt2_converges_on_real_tokenized_corpus(tmp_path):
     take = rng.integers(0, len(data), bs)
     _, m = engine2.train_batch({"input_ids": data[take]})
     assert float(m["loss"]) < start - 1.0  # resumed mid-curve, not fresh
-
-
-def test_bench_entrypoint_smoke_and_contract():
-    """The headline bench must emit its one-line JSON contract on the
-    asked-for CPU smoke path, name the device beside it, and claim no
-    device number there (``vs_baseline`` is null off the chip)."""
-    env = {k: v for k, v in os.environ.items()
-           if not k.startswith(("JAX_", "XLA_"))}
-    env["JAX_PLATFORMS"] = "cpu"
-    out = subprocess.run(
-        [sys.executable, os.path.join(os.path.dirname(__file__), os.pardir,
-                                      os.pardir, "bench.py")],
-        env=env, capture_output=True, text=True, timeout=1200)
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = out.stdout.strip().splitlines()[-1]
-    rec = json.loads(line)
-    assert set(rec) == {"metric", "value", "unit", "vs_baseline"}
-    assert rec["value"] > 0
-    assert rec["vs_baseline"] is None
-    assert "platform=cpu device_kind=cpu count=1" in out.stdout
-
-
-def test_bench_refuses_unknown_device_kind():
-    """A device the peak table does not know is an error, never a default
-    (the old table assumed 197e12 for any TPU and 1e12 for a CPU)."""
-    import importlib.util
-    import types
-
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(__file__), os.pardir,
-                              os.pardir, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    assert bench.peak_flops_per_chip(
-        types.SimpleNamespace(device_kind="TPU v5 lite")) == 197e12
-    with pytest.raises(ValueError, match="no peak FLOP/s on record"):
-        bench.peak_flops_per_chip(types.SimpleNamespace(device_kind="cpu"))

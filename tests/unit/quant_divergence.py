@@ -1,6 +1,5 @@
 """Bounded-divergence helpers for quantized serving — the ONE definition
-of "close enough" shared by ``tests/unit/test_quant_serving.py`` and the
-``benchmarks/serving_bench.py --quantize`` lane.
+of "close enough" for ``tests/unit/test_quant_serving.py``.
 
 Quantized lanes (int8 KV, w8a8 weights) cannot promise the bit-exact
 greedy parity the full-precision serving stack pins: int8 rounding can

@@ -124,7 +124,7 @@ def test_grouped_expert_matmul_lowers(rows, k, n):
 
 
 def test_flash_train_step_kernels_lower():
-    """bench.py / chip_smoke.py training config: flash v2, 1024x1024
+    """chip_smoke.py training config: flash v2, 1024x1024
     blocks, micro-batch 32 x S=1024, forward and fused backward."""
     q = _sds((32, 12, 1024, 64), jnp.bfloat16)
 

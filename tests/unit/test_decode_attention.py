@@ -127,7 +127,7 @@ def test_pallas_kernel_ragged_under_jit_traced_lengths():
 @slow
 @pytest.mark.parametrize("family", ["gpt2", "llama"])
 def test_forward_cached_ragged_matches_full_recompute(family):
-    """Per-sequence lengths through forward_cached: ragged bucketed prefill
+    """Per-sequence lengths through forward_cached: ragged prefill window
     + per-row decode == full-recompute logits on each row's own sequence."""
     if family == "gpt2":
         from deepspeed_tpu.models import gpt2 as m

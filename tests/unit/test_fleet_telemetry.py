@@ -372,31 +372,23 @@ def test_supervisor_owns_metrics_server(tiny):
     router.stop()
 
 
-def test_flops_bucketed_prefill_billed_per_width(tiny):
-    """Bucketed mode compiles one prefill program per bucket width —
-    each is costed and call-counted at ITS width (a single last-built
-    entry would mis-bill every other bucket by the width ratio)."""
+def test_flops_prefill_billed_per_call_at_the_chunk_width(tiny):
+    """The one prefill program is costed at ``prefill_chunk`` and billed
+    once per CALL: a prompt of several chunks counts several times."""
     spec, cfg, engine = tiny
     srv = ServingEngine(engine, slots=3, max_seq_len=64, block_size=8,
-                        prompt_buckets=(16, 64), prefill_batch=2)
+                        prefill_chunk=16, prefill_batch=2)
     rng = np.random.default_rng(8)
     reqs = [Request(uid=i, prompt=rng.integers(0, cfg.vocab_size, n),
                     max_new_tokens=3)
             for i, n in enumerate((8, 12, 40, 48))]
     srv.serve(reqs)
-    assert set(srv._prefill_calls_by_width) == {16, 64}
     rep = srv.flops_report()
-    entries = {f for f in rep["programs"] if f.startswith("prefill")}
-    assert entries == {"prefill[w16]", "prefill[w64]"}
-    w16 = rep["programs"]["prefill[w16]"]
-    w64 = rep["programs"]["prefill[w64]"]
-    assert w16["width"] == 16 and w64["width"] == 64
-    assert w64["flops_per_call"] > w16["flops_per_call"]
-    assert rep["program_calls"]["prefill[w16]"] == \
-        srv._prefill_calls_by_width[16]
-    # the total is the per-width sum, not any single width x all calls
-    expected = (w16["flops_per_call"] * srv._prefill_calls_by_width[16] +
-                w64["flops_per_call"] * srv._prefill_calls_by_width[64] +
+    assert set(rep["programs"]) == {"prefill", "decode"}
+    pre = rep["programs"]["prefill"]
+    assert pre["width"] == 16 and pre["rows"] == 2
+    assert rep["program_calls"]["prefill"] == srv.prefill_calls > 2
+    expected = (pre["flops_per_call"] * srv.prefill_calls +
                 rep["programs"]["decode"]["flops_per_call"] *
                 srv.decode_steps)
     assert rep["model_flops_total"] == pytest.approx(expected)

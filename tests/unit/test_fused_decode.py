@@ -223,7 +223,3 @@ def test_dp_tp_ctor_restrictions(tiny_engine):
     with pytest.raises(ValueError, match="prefix_caching"):
         ServingEngine(engine, engine_mode="dp_tp", slots=8,
                       max_seq_len=128, block_size=8, prefill_chunk=16)
-    with pytest.raises(ValueError, match="chunked"):
-        ServingEngine(engine, engine_mode="dp_tp", slots=8,
-                      max_seq_len=128, block_size=8,
-                      prompt_buckets=(64, 128), prefix_caching=False)

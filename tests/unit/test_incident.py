@@ -136,6 +136,23 @@ def test_partial_tmp_dir_is_never_a_bundle(tmp_path):
         load_bundle(str(done))
 
 
+def test_bundle_of_another_schema_version_is_refused_by_version(tmp_path):
+    """A version-1 bundle's replica configs carry options the engine no
+    longer takes: it is refused whole, by version, not read with keys
+    stripped."""
+    from deepspeed_tpu.telemetry.incident import (BUNDLE_FORMAT,
+                                                  BUNDLE_SCHEMA_VERSION)
+
+    old = tmp_path / "incident-001-replica_fail"
+    old.mkdir()
+    (old / "manifest.json").write_text(json.dumps(
+        {"bundle_format": BUNDLE_FORMAT,
+         "schema_version": BUNDLE_SCHEMA_VERSION - 1}))
+    assert not is_bundle(str(old))
+    with pytest.raises(ValueError, match="schema_version 1"):
+        load_bundle(str(old))
+
+
 def test_audit_rejects_missing_file(crashed, tmp_path):
     import shutil
     bpath, _ = crashed

@@ -12,8 +12,7 @@ Tier-1 (fast) CPU-sim coverage for the paged path:
    + 1 decode program per trace).
 
 The Pallas paged-decode kernel's interpret-mode twin lives in
-``test_decode_attention.py`` (slow lane); the prefix-heavy end-to-end
-bench lane is ``test_serving_bench.py`` (slow).
+``test_decode_attention.py`` (slow lane).
 """
 
 import jax
@@ -337,7 +336,6 @@ def test_chunked_serving_matches_sequential_generate(tiny_engine):
         np.testing.assert_array_equal(res[r.uid], want,
                                       err_msg=f"uid {r.uid}")
     st = srv.stats()
-    assert st["mode"] == "chunked"
     assert st["prefix_cache_hit_rate"] > 0.2, st
     assert st["prefix_hit_tokens"] % srv.block_size == 0
     for key in ("prefix_cache_hit_rate", "blocks_in_use", "compile_count",
@@ -371,8 +369,8 @@ def test_chunked_serving_parity_with_eos(tiny_engine):
                                       err_msg=f"uid {r.uid}")
 
 
-@pytest.mark.slow  # two engine builds — tier-1 covers gpt2 chunked + all
-@pytest.mark.parametrize("family", ["llama", "opt"])  # families bucketed
+@pytest.mark.slow  # two engine builds — tier-1 covers gpt2 here and these
+@pytest.mark.parametrize("family", ["llama", "opt"])  # in test_serving.py
 def test_chunked_serving_parity_other_families(family):
     """Chunked paged prefill holds beyond gpt2: per-row rope offsets
     (llama) and offset learned positions (opt) in T>1 windows."""
@@ -439,17 +437,19 @@ def test_paged_greedy_equals_contiguous_generate(family, kv):
     deepspeed_tpu.comm.reset_topology()
 
 
-def test_chunked_compile_count_is_two_programs(tiny_engine):
-    """Acceptance: the chunked serving loop compiles exactly 1 prefill + 1
-    decode program for a whole mixed-shape trace — and stays there for new
-    shapes.  Enforced LIVE by the recompile sentry (debug_checks=True
-    raises at trace time past the budget of 2), which also replaces the
-    old per-fn ``_cache_size`` retrace probe: the sentry counts actual
-    Python-body traces, so silent retraces can't hide."""
+@pytest.mark.parametrize("sampling", [True, False],
+                         ids=["sampling", "greedy-only"])
+def test_chunked_compile_count_is_two_programs(tiny_engine, sampling):
+    """Acceptance: the serving loop compiles exactly 1 prefill + 1 decode
+    program for a whole mixed-shape trace — and stays there for new shapes
+    and for repeat traffic, with the sampling operands or without them.
+    Enforced LIVE by the recompile sentry (debug_checks=True raises at
+    trace time past the budget of 2): it counts actual Python-body traces,
+    so silent retraces can't hide."""
     engine, cfg = tiny_engine
     srv = ServingEngine(engine, slots=4, max_seq_len=128, block_size=8,
                         prefill_chunk=16, prefill_batch=2,
-                        debug_checks=True)
+                        sampling=sampling, debug_checks=True)
     assert srv.compile_budget == 2
     rng = np.random.default_rng(3)
     reqs = [Request(uid=i, prompt=rng.integers(0, cfg.vocab_size,
@@ -464,6 +464,13 @@ def test_chunked_compile_count_is_two_programs(tiny_engine):
              for i in range(6)]
     srv.serve(reqs2)                           # new shapes: no new programs
     assert srv.compile_count == 2, srv.compiled_programs
+    srv.serve(reqs)                            # repeat traffic: none either
+    assert srv.compile_count == 2, srv.compiled_programs
+    assert sorted(p[0] for p in srv.compiled_programs) == \
+        ["decode", "prefill"]
+    # each jitted fn holds exactly one executable
+    for fn in (srv._prefill_fn, srv._decode_fn):
+        assert fn._cache_size() == 1
     # sentry ledger: exactly one trace per program, zero beyond budget
     assert srv.sentry.traces == 2, srv.sentry.report()
     assert srv.sentry.retraces_observed == 0
@@ -525,38 +532,6 @@ def test_preemption_under_block_pressure_keeps_parity(tiny_engine):
         if uid not in first:
             first.append(uid)
     assert first == list(range(5))
-
-
-@pytest.mark.slow  # engine build + long generations (preemption churn)
-def test_bucketed_preemption_resume_outgrows_ladder():
-    """Bucketed fallback under block pressure: a preempted request whose
-    prompt + generated tokens outgrow the custom ladder re-prefills through
-    the full-cache-width fallback program instead of failing mid-trace;
-    outputs stay greedy-exact."""
-    deepspeed_tpu.comm.reset_topology()
-    cfg = gpt2.GPT2Config.tiny(max_seq_len=128)
-    engine = deepspeed_tpu.init_inference(
-        gpt2.build(cfg),
-        config={"dtype": "fp32", "tensor_parallel": {"tp_size": 1}})
-    # nbper = 8; 3 slots want 6 blocks each (20 prompt + 24 new) but only
-    # 11 usable exist -> preemption; resumes reach 20+k > 24 tokens, past
-    # the (24,)-ladder
-    # bucketed budget = len(buckets) + 2 (ladder + full-cache-width
-    # preemption fallback + decode) — the sentry enforces it live
-    srv = ServingEngine(engine, slots=3, max_seq_len=64, block_size=8,
-                        prompt_buckets=(24,), prefill_batch=2,
-                        num_blocks=12, debug_checks=True)
-    assert srv.compile_budget == 3
-    rng = np.random.default_rng(7)
-    reqs = [Request(uid=i, prompt=rng.integers(0, cfg.vocab_size, 20),
-                    max_new_tokens=24) for i in range(4)]
-    res = srv.serve(reqs)
-    assert srv.preempted > 0, srv.stats()
-    for r in reqs:
-        want = engine.generate(r.prompt[None, :],
-                               max_new_tokens=r.max_new_tokens)[0]
-        np.testing.assert_array_equal(res[r.uid], want,
-                                      err_msg=f"uid {r.uid}")
 
 
 def test_paged_serving_rejects_legacy_models():

@@ -257,8 +257,8 @@ def _serving_module(tiny, kind):
     srv = ServingEngine(engine, **kw)
     prof = ServingFlopsProfiler(srv)
     if "prefill" in kind:
-        srv._get_prefill_fn(srv.prefill_chunk)
-        return _module_name(prof.lower("prefill", srv.prefill_chunk))
+        srv._get_prefill_fn()
+        return _module_name(prof.lower("prefill"))
     srv._get_decode_fn()
     if kind != "jit_decode_windowed":
         return _module_name(prof.lower("decode"))

@@ -17,8 +17,7 @@ Tier-1 (fast) CPU-sim coverage for the PR 7 quantization stack:
 
 The Pallas quantized decode/verify kernels' interpret twins live in
 ``test_decode_attention.py`` (slow lane); the tp=4 × kv8 parity case in
-``test_tp_serving.py`` (8-device CI job); the bench lane in
-``test_serving_bench.py`` (slow).
+``test_tp_serving.py`` (8-device CI job).
 """
 
 import numpy as np

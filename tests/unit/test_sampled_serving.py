@@ -85,7 +85,7 @@ def test_sampled_streams_deterministic_and_two_programs(tiny_engine):
     assert a.compile_count == 2, a.compiled_programs
     assert a.sentry.retraces_observed == 0
     st = a.stats()
-    assert st["sampling"] is True and st["spec_verifier"] == "rejection"
+    assert st["sampling"] is True
     assert st["sampled_requests"] == len(reqs)
 
 
@@ -162,14 +162,22 @@ def test_spec_draft_sampled_three_programs_and_temp0_parity(tiny_engine):
         ["draft", "prefill", "verify"]
 
 
-def test_greedy_verifier_refused_on_sampling_spec_engine(tiny_engine):
+def test_greedy_only_spec_engine_matches_plain_greedy(tiny_engine):
+    """``sampling=False`` with ``spec_tokens``: the verify program takes the
+    greedy prefix-matcher (no sampling operands), token-exact with plain
+    greedy decode."""
     engine, cfg = tiny_engine
-    with pytest.raises(ValueError, match="rejection verifier"):
-        ServingEngine(engine, spec_tokens=3, spec_verifier="greedy", **_KW)
-    # legacy combination still constructs: greedy verify, sampling off
-    srv = ServingEngine(engine, spec_tokens=3, spec_verifier="greedy",
-                        sampling=False, **_KW)
-    assert srv.stats()["spec_verifier"] == "greedy"
+    srv = ServingEngine(engine, spec_tokens=3, sampling=False, **_KW)
+    reqs = _sampled_trace(cfg, 4, seed=6, greedy_every=1)   # all greedy
+    res = srv.serve(reqs)
+    for r in reqs:
+        want = engine.generate(r.prompt[None, :],
+                               max_new_tokens=r.max_new_tokens)[0]
+        np.testing.assert_array_equal(res[r.uid], want,
+                                      err_msg=f"uid {r.uid}")
+    assert srv.stats()["sampling"] is False
+    assert sorted(p[0] for p in srv.compiled_programs) == \
+        ["prefill", "verify"]
 
 
 # ------------------------------------------------------------ constrained
@@ -326,8 +334,6 @@ def test_request_and_engine_validation(tiny_engine):
 
     with pytest.raises(ValueError, match="sampling"):
         ServingEngine(engine, logit_masks=True, sampling=False, **_KW)
-    with pytest.raises(ValueError, match="spec_verifier"):
-        ServingEngine(engine, spec_verifier="argmax", **_KW)
 
     off = ServingEngine(engine, sampling=False, **_KW)
     with pytest.raises(ValueError, match="sampling=False"):

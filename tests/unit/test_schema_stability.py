@@ -50,7 +50,7 @@ ENGINE_STATS_KEYS = frozenset({
     "invariant_checks_run",
     "handoffs",
     "iterations", "kv_dtype", "kv_pool_bytes", "kv_pool_bytes_per_chip",
-    "kv_pool_shape", "kv_scale_bytes", "kv_sharded", "mode",
+    "kv_pool_shape", "kv_scale_bytes", "kv_sharded",
     # PR 28: routed (token, expert) rows and experts touched, summed over
     # layers and program calls; 0 for a dense model
     "moe_expert_rows", "moe_experts_touched",
@@ -61,7 +61,7 @@ ENGINE_STATS_KEYS = frozenset({
     "prefix_hit_tokens", "prompt_tokens", "quantize", "queue_depth",
     "requests_finished", "resume_recompute_tokens", "retraces_observed",
     "role",
-    "sampling", "spec_verifier", "logit_masks", "sampled_requests",
+    "sampling", "logit_masks", "sampled_requests",
     "spec_draft_rejected",
     "sp", "resident_window_blocks", "context_window_slides",
     "sp_alltoall_bytes",
@@ -75,14 +75,13 @@ ENGINE_STATS_KEYS = frozenset({
 #: dict pinned key-for-key: bench JSONs, ``best_config.json``, and the
 #: autotuner's trial records must stay mutually loadable across PRs
 CONFIG_KEYS = frozenset({
-    "block_size", "chunked_prefill", "debug_checks", "decode_steps",
+    "block_size", "debug_checks", "decode_steps",
     "engine_mode", "host_blocks",
     "max_seq_len", "ngram_max", "ngram_min", "num_blocks",
     "nvme_blocks", "nvme_high_watermark", "nvme_path", "peak_flops",
-    "prefill_batch", "prefill_chunk", "prefix_caching", "prompt_buckets",
+    "prefill_batch", "prefill_chunk", "prefix_caching",
     "quantize", "resident_window_blocks", "role", "sampling", "shard_kv",
-    "slo_targets", "slots", "sp", "spec_tokens", "spec_verifier",
-    "logit_masks",
+    "slo_targets", "slots", "sp", "spec_tokens", "logit_masks",
     "swap_batch", "topology", "trace_capacity",
 })
 
@@ -250,7 +249,7 @@ def test_incident_manifest_keys_pinned():
     from deepspeed_tpu.telemetry import incident
 
     assert incident.MANIFEST_KEYS == MANIFEST_KEYS
-    assert incident.BUNDLE_SCHEMA_VERSION == 1
+    assert incident.BUNDLE_SCHEMA_VERSION == 2
     assert incident.TRIGGER_KINDS == (
         "replica_fail", "invariant_violation", "retrace",
         "checksum_burst", "burn_rate_breach", "watchdog_stall")

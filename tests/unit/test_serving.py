@@ -2,8 +2,8 @@
 satellites that ride with it.
 
 Deterministic CPU tests: scheduler admission/free ordering, no starvation,
-bucketed compile counts (the O(#buckets) acceptance probe), and per-request
-token parity with sequential ``generate`` for greedy decoding.  The ragged
+request validation, and per-request token parity with sequential
+``generate`` for greedy decoding.  The ragged
 ``lengths`` decode-attention contract is covered here on the XLA reference
 path; the Pallas-interpret twin lives in test_decode_attention.py (slow).
 """
@@ -13,8 +13,7 @@ import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu.inference.engine import _fill_after_eos
-from deepspeed_tpu.inference.serving import (Request, ServingEngine,
-                                             default_buckets)
+from deepspeed_tpu.inference.serving import Request, ServingEngine
 from deepspeed_tpu.models import gpt2
 
 
@@ -82,7 +81,7 @@ def test_serving_matches_sequential_generate_greedy():
     ``generate`` (greedy), across mixed prompt lengths and budgets."""
     engine, cfg = _tiny_engine()
     srv = ServingEngine(engine, slots=4, max_seq_len=128,
-                        prompt_buckets=(8, 16, 32), prefill_batch=2)
+                        prefill_chunk=16, prefill_batch=2)
     reqs = _trace(cfg, 10)
     res = srv.serve(reqs)
     for r in reqs:
@@ -97,7 +96,7 @@ def test_serving_matches_sequential_generate_with_eos():
     the output is eos back-filled like generate's)."""
     engine, cfg = _tiny_engine()
     srv = ServingEngine(engine, slots=3, max_seq_len=128,
-                        prompt_buckets=(8, 16, 32), prefill_batch=2)
+                        prefill_chunk=16, prefill_batch=2)
     reqs = _trace(cfg, 6, seed=1, max_new=(4, 10))
     # pick an eos that actually occurs: the first generated token of req 0
     probe = engine.generate(reqs[0].prompt[None, :], max_new_tokens=1)
@@ -128,7 +127,7 @@ def test_serving_parity_other_families(family):
         m.build(cfg), config={"dtype": "fp32",
                               "tensor_parallel": {"tp_size": 1}})
     srv = ServingEngine(engine, slots=3, max_seq_len=64,
-                        prompt_buckets=(8, 16), prefill_batch=2)
+                        prefill_chunk=16, prefill_batch=2)
     reqs = _trace(cfg, 5, seed=2, lo=3, hi=14, max_new=(2, 8))
     res = srv.serve(reqs)
     for r in reqs:
@@ -138,43 +137,12 @@ def test_serving_parity_other_families(family):
                                       err_msg=f"uid {r.uid}")
 
 
-def test_compile_count_bucketed():
-    """Acceptance: the serving loop compiles O(#buckets) programs for a whole
-    mixed-shape trace — and re-serving new shapes in the same buckets
-    compiles nothing new."""
-    engine, cfg = _tiny_engine()
-    srv = ServingEngine(engine, slots=4, max_seq_len=128,
-                        prompt_buckets=(8, 16, 32), prefill_batch=2)
-    def buckets_of(reqs):
-        return {min(b for b in srv.prompt_buckets if len(r.prompt) <= b)
-                for r in reqs}
-
-    reqs = _trace(cfg, 12, seed=3)          # ~12 distinct request shapes
-    srv.serve(reqs)
-    used = buckets_of(reqs)
-    assert srv.compile_count == len(used) + 1, srv.compiled_programs
-    # distinct new shapes: compiles track BUCKETS, not request shapes
-    reqs2 = _trace(cfg, 8, seed=4)
-    srv.serve(reqs2)
-    used |= buckets_of(reqs2)
-    assert srv.compile_count == len(used) + 1, srv.compiled_programs
-    # repeat traffic: zero new programs
-    srv.serve(_trace(cfg, 12, seed=3))
-    assert srv.compile_count == len(used) + 1, srv.compiled_programs
-    # the probe counts traced programs, not calls: each jitted fn must have
-    # exactly one executable (no silent same-key retraces)
-    for fn in list(srv._prefill_fns.values()) + [srv._decode_fn]:
-        cache_size = getattr(fn, "_cache_size", None)
-        if cache_size is not None:
-            assert cache_size() == 1
-
-
 def test_admission_fifo_and_immediate_slot_reuse():
     """Slots: strict FIFO admission (no starvation), and a freed slot is
     reacquired by the next waiting request."""
     engine, cfg = _tiny_engine()
     srv = ServingEngine(engine, slots=2, max_seq_len=128,
-                        prompt_buckets=(8,), prefill_batch=2)
+                        prefill_chunk=8, prefill_batch=2)
     rng = np.random.default_rng(5)
     # short budgets so slots churn: 6 requests through 2 slots
     reqs = [Request(uid=i, prompt=rng.integers(0, cfg.vocab_size, 4),
@@ -193,11 +161,9 @@ def test_admission_fifo_and_immediate_slot_reuse():
 def test_serving_rejects_oversized_and_invalid():
     engine, cfg = _tiny_engine()
     srv = ServingEngine(engine, slots=2, max_seq_len=64,
-                        prompt_buckets=(8, 16), prefill_batch=2)
+                        prefill_chunk=16, prefill_batch=2)
     with pytest.raises(ValueError, match="exceeds max_seq_len"):
         srv.serve([Request(uid=0, prompt=np.arange(16), max_new_tokens=60)])
-    with pytest.raises(ValueError, match="largest bucket"):
-        srv.serve([Request(uid=0, prompt=np.arange(20), max_new_tokens=2)])
     with pytest.raises(ValueError, match="duplicate"):
         srv.serve([Request(uid=0, prompt=np.arange(4), max_new_tokens=2),
                    Request(uid=0, prompt=np.arange(4), max_new_tokens=2)])
@@ -213,28 +179,29 @@ def test_serving_rejects_oversized_and_invalid():
         ServingEngine(legacy)
 
 
-def test_default_buckets_ladder():
-    assert default_buckets(512) == (32, 64, 128, 256, 512)
-    assert default_buckets(96) == (32, 64, 96)
-    assert default_buckets(32) == (32,)
+# ------------------------------------------------------------ removed options
+#: options ``ServingEngine`` / ``init_serving`` took until PR 30: the bucket
+#: ladder's two and a verifier name that selected nothing
+_REMOVED = {"prompt_buckets": (8, 16), "chunked_prefill": True,
+            "spec_verifier": "rejection"}
 
 
-def test_default_buckets_edge_cases():
-    """lo above max_seq_len clamps to one bucket, non-power-of-two tails
-    appear exactly once, and degenerate inputs raise instead of looping."""
-    assert default_buckets(16) == (16,)                 # lo 32 > max 16
-    assert default_buckets(64, lo=100) == (64,)         # explicit lo > max
-    assert default_buckets(1) == (1,)
-    assert default_buckets(48, lo=48) == (48,)          # lo == max, non-pow2
-    assert default_buckets(96, lo=3) == (3, 6, 12, 24, 48, 96)
-    for ladder in (default_buckets(96), default_buckets(640, lo=10),
-                   default_buckets(100, lo=25)):
-        assert len(set(ladder)) == len(ladder), ladder  # no duplicate tail
-        assert list(ladder) == sorted(ladder)
-    with pytest.raises(ValueError, match="lo"):
-        default_buckets(64, lo=0)                       # would loop forever
-    with pytest.raises(ValueError, match="max_seq_len"):
-        default_buckets(0)
+@pytest.mark.parametrize("entry", ["ServingEngine", "init_serving"])
+@pytest.mark.parametrize("option", sorted(_REMOVED))
+def test_removed_option_is_an_unknown_keyword(option, entry):
+    """No alias, no shim: the constructor refuses a removed option by name
+    like any unknown keyword, and ``init_serving`` has no such parameter
+    and forwards none (what lands in its ``**kwargs`` is engine config:
+    ``init_inference`` treats an unknown key there as it always has)."""
+    import inspect
+
+    if entry == "ServingEngine":
+        with pytest.raises(TypeError, match=option):
+            ServingEngine(object(), slots=2, **{option: _REMOVED[option]})
+    else:
+        assert option not in inspect.signature(
+            deepspeed_tpu.init_serving).parameters
+        assert option not in inspect.getsource(deepspeed_tpu.init_serving)
 
 
 # ------------------------------------------------- generate early-exit satellite

@@ -235,24 +235,24 @@ def _geom():
 
 def test_constraint_pruning_counts():
     geom = _geom()
-    base = {"num_blocks": 40}
+    base = {"num_blocks": 40, "host_blocks": 8, "swap_batch": 4}
     # 40-block fp32 pool at block_size 32: 40 * 2*2*4*32*16*4 bytes
     ceiling = 40 * (2 * 2 * 4 * 32 * 16 * 4)
     space = ServingKnobSpace(
         geom, max_seq_len=256, base=base, mem_ceiling_bytes=ceiling,
         domains={"block_size": (32, 64),
                  "spec_tokens": (0, 4, 31),
-                 "chunked_prefill": (True, False)})
+                 "prefix_caching": (True, False)})
     cands = space.candidates()
     assert len(cands) == 2 * 3 * 2
     kept, pruned = space.prune(cands)
     # block_size=64 doubles block bytes past the ceiling: 6 candidates
     # pruned by memory.  Of the remaining block_size=32 half:
-    # chunked_prefill=False kills spec 4/31 (exclusivity, first match)
-    # and spec_tokens=31 kills its chunked variant (window > 16).
+    # spec_tokens=31 kills both its variants (window > 16, first match)
+    # and prefix_caching=False kills spec 0/4 (the host tier needs it).
     assert pruned["kv_pool_memory"] == 6
-    assert pruned["spec_bucketed_exclusive"] == 2
-    assert pruned["spec_window"] == 1
+    assert pruned["spec_window"] == 2
+    assert pruned["tiered_needs_prefix_cache"] == 2
     assert len(kept) + sum(pruned.values()) == len(cands)
     # every kept candidate passes every predicate
     assert all(not space.check(c) for c in kept)
@@ -276,12 +276,10 @@ def test_compile_budget_mirror(tiny_engine):
     every mode the space can emit."""
     engine, _ = tiny_engine
     cases = [
-        dict(),                                        # chunked
+        dict(),
         dict(spec_tokens=4),                           # ngram spec
         dict(host_blocks=16, swap_batch=4),            # tiered
         dict(spec_tokens=4, host_blocks=16, swap_batch=4),
-        dict(chunked_prefill=False, prompt_buckets=(32, 64),
-             prefix_caching=False),                    # bucketed
     ]
     for kw in cases:
         srv = ServingEngine(engine, slots=2, max_seq_len=64, block_size=8,
@@ -299,12 +297,6 @@ def test_every_constraint_has_a_loud_ctor_twin(tiny_engine):
     base = dict(slots=2, max_seq_len=64, block_size=8, prefill_chunk=16)
     cases = [
         # (space constraint, ctor kwargs, message fragment)
-        # chunked_prefill=None = the ctor's auto rule: prompt_buckets
-        # selects bucketed mode, which excludes speculation
-        ("spec_bucketed_exclusive",
-         {**base, "spec_tokens": 3, "prompt_buckets": (64,),
-          "chunked_prefill": None},
-         "chunked-prefill"),
         ("spec_window", {**base, "spec_tokens": 31}, "spec_tokens"),
         ("tiered_needs_prefix_cache",
          {**base, "host_blocks": 8, "swap_batch": 4,
@@ -345,11 +337,6 @@ def test_every_constraint_has_a_loud_ctor_twin(tiny_engine):
          {**base, "resident_window_blocks": 4, "host_blocks": 8,
           "swap_batch": 4, "num_blocks": 5}, "resident"),
         # PR 20: on-device sampling stack + constrained decoding
-        ("spec_sampling_needs_rejection",
-         {**base, "spec_tokens": 2, "spec_verifier": "greedy"},
-         "rejection verifier"),
-        ("spec_sampling_needs_rejection",
-         {**base, "spec_verifier": "argmax"}, "spec_verifier"),
         ("logit_masks_excludes_dp_tp",
          {**base, "logit_masks": True, "sampling": False},
          "sampling"),

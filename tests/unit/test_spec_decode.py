@@ -18,8 +18,7 @@ Tier-1 (fast) CPU-sim coverage:
    configuration combinations.
 
 The Pallas K+1 verify-attention kernel's interpret-mode twin lives in
-``test_decode_attention.py`` (slow lane); the decode-heavy bench lane is
-``test_serving_bench.py`` (slow).
+``test_decode_attention.py`` (slow lane).
 """
 
 import numpy as np
@@ -310,9 +309,6 @@ def test_ctor_validation_names_the_problem(tiny_engine):
     engine, cfg = tiny_engine
     with pytest.raises(ValueError, match="spec_tokens"):
         ServingEngine(engine, draft=object())   # draft without spec_tokens
-    with pytest.raises(ValueError, match="chunked"):
-        ServingEngine(engine, max_seq_len=64, prompt_buckets=(64,),
-                      spec_tokens=4)            # bucketed mode can't verify
     with pytest.raises(ValueError, match="spec_tokens"):
         ServingEngine(engine, spec_tokens=-1)
 

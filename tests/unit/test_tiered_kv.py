@@ -353,14 +353,11 @@ def test_staged_prefetch_records_never_outlive_their_request(tiny_engine):
     assert srv._staged == {}
 
 
-def test_tiered_requires_chunked_prefix_mode(tiny_engine):
+def test_tiered_requires_prefix_caching(tiny_engine):
     engine, _ = tiny_engine
     with pytest.raises(ValueError, match="tiered KV"):
         ServingEngine(engine, slots=2, max_seq_len=64, block_size=8,
                       prefix_caching=False, host_blocks=8)
-    with pytest.raises(ValueError, match="tiered KV"):
-        ServingEngine(engine, slots=2, max_seq_len=64, block_size=8,
-                      prompt_buckets=(64,), host_blocks=8)
 
 
 def test_tiering_off_is_inert_and_stats_schema_stable(tiny_engine):

@@ -1,8 +1,8 @@
 """ZeRO-Inference streamed serving (inference/zero_inference.py).
 
 Reference parity: ZeRO-Inference — zero stage-3 ``offload_param: cpu``
-driving inference-only forwards (the OPT-30B-on-one-GPU configuration of
-BASELINE.md).  The TPU analog keeps stacked blocks host-resident and
+driving inference-only forwards (the reference's OPT-30B-on-one-GPU
+configuration).  The TPU analog keeps stacked blocks host-resident and
 streams one layer at a time through the jitted KV-cache decode step;
 these tests pin token-level parity against the resident engine, which is
 the whole correctness contract of the streamed path.
