@@ -225,8 +225,8 @@ def phase_kernels(sz: Sizes, report: Dict[str, Any]) -> None:
 
     def paged_checks(tag, h, hd, ctx, kinds):
         """Paged attention at one head shape: the decode (T=1) and verify
-        (T=4) kernels, and the T>1 gather path a prefill chunk takes
-        through the dispatcher, each against the float32 reference."""
+        (T=4) kernels, and the prefill kernel a chunk takes through the
+        dispatcher, each against the float32 reference."""
         nbper = paged_kv.blocks_for(ctx, bs)
         nb = 1 + slots * nbper
         rng = np.random.default_rng(0)
@@ -246,7 +246,7 @@ def phase_kernels(sz: Sizes, report: Dict[str, Any]) -> None:
             qv, sv = quant.quantize_kv(vf, paged_kv.SCALE_DTYPE)
             pools["kv8"] = ({"qp": qk, "ps": sk}, {"qp": qv, "ps": sv})
 
-        def paged(t, kernel, kind, q_pos, mosaic=True):
+        def paged(t, kernel, kind, q_pos):
             kp, vp = pools[kind]
             q = jax.random.normal(keys[2], (slots, h, t, hd), jnp.bfloat16)
             # the kernel reads the whole pool at a (non-zero) layer index;
@@ -254,9 +254,8 @@ def phase_kernels(sz: Sizes, report: Dict[str, Any]) -> None:
             fn = jax.jit(lambda q, kp, vp, bt, pos: kernel(
                 q, kp, vp, bt, pos, layer=1))
             kps, vps = as_engine_holds_it(kp), as_engine_holds_it(vp)
-            if mosaic:
-                _mosaic(fn.lower(q, kps, vps, bt, q_pos).as_text(),
-                        f"paged T={t} {kind} {tag}", require)
+            _mosaic(fn.lower(q, kps, vps, bt, q_pos).as_text(),
+                    f"paged T={t} {kind} {tag}", require)
             want = exact(
                 lambda q, kp, vp: da.paged_decode_attention_reference(
                     q.astype(jnp.float32), f32_pool(kp), f32_pool(vp), bt,
@@ -271,12 +270,12 @@ def phase_kernels(sz: Sizes, report: Dict[str, Any]) -> None:
                 checks.append((f"paged T={t} {kind} {tag}",
                                lambda t=t, kernel=kernel, kind=kind:
                                paged(t, kernel, kind, pos)))
-        # a prefill chunk: T > 4 takes the dispatcher's gather path (XLA
-        # over the packed pool's gathered views, no Mosaic call)
+        # a prefill chunk: on a TPU the dispatcher sends T > VERIFY_T_MAX
+        # to the prefill kernel, every row walking its own valid blocks
         t = sz.serving_kwargs.get("prefill_chunk", 128)
         base = jnp.minimum(pos, ctx - t)
         checks.append((f"paged prefill T={t} {tag}", lambda: paged(
-            t, da.paged_decode_attention, "bf16", base, mosaic=False)))
+            t, da.paged_decode_attention, "bf16", base)))
 
     paged_checks("", cfg.num_heads, cfg.head_dim, cfg.max_seq_len,
                  ("bf16", "kv8"))

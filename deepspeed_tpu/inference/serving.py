@@ -2020,7 +2020,7 @@ class ServingEngine:
         constrain = self._constrain_pool
 
         next_tokens, pack = self._next_tokens, self._pack_samp
-        with_record = self._with_record
+        with_record, meta = self._with_record, self._program_meta
 
         def prefill(params, cache, ids, block_tables, base, valid, *samp):
             """ids [J, width] right-padded; base int32 [J] per-row chunk
@@ -2031,9 +2031,12 @@ class ServingEngine:
             draws with the SAME counter key (seed, emitted count) the
             decode path would use — that is what makes preempt/crash
             resumes, which re-emit through prefill, token-exact."""
-            logits, cache, rec = fwd(prepare(params), ids, cache, base,
-                                     lengths=valid,
-                                     block_tables=block_tables)
+            with decode_attention.dispatch_log() as paths:
+                logits, cache, rec = fwd(prepare(params), ids, cache, base,
+                                         lengths=valid,
+                                         block_tables=block_tables)
+            # which read the program was built with, noted as it is traced
+            meta["prefill_attn"] = "+".join(sorted(paths))
             return with_record(next_tokens(logits, pack(samp)), rec), \
                 constrain(cache)
 
@@ -4112,8 +4115,12 @@ class ServingEngine:
                 args += (jnp.asarray(ws),)
             args += samp
         prefill_fn = self._get_prefill_fn()
-        with self.timeline.span("prefill", width=width, rows=len(group),
-                                slots=list(map(int, group))) as span_args:
+        with self.timeline.span(
+                "prefill", width=width, rows=len(group),
+                slots=list(map(int, group)),
+                # blocks the rows' reads walk: cdiv(base + valid, bs) each
+                kv_blocks=int((-(-(base + valid) // self.block_size)).sum()),
+        ) as span_args:
             if self._draft is not None:
                 with self._tp_ctx():
                     first, self._cache, self._dcache = prefill_fn(*args)
@@ -4319,6 +4326,9 @@ class ServingEngine:
             "fused_iterations": int(self._c_fused_iterations.value),
             "host_fence_waits": int(self._c_host_fence_waits.value),
             "prefill_calls": self.prefill_calls,
+            # the read the prefill program was traced with (None before its
+            # first call): "paged_prefill_attn" on a TPU, "gather" on a CPU
+            "prefill_attn": self._program_meta.get("prefill_attn"),
             "admitted": self.admitted,
             "evicted": self.preempted,
             "cancelled": int(self._c_cancelled.value),

@@ -402,7 +402,9 @@ def _cached_attention(q, k, v, ck, cv, pos, block_tables=None,
     [B, NBPER] (``ops/paged_kv.py``), so the caller carries the pool
     through its layer loop untouched.  ``chunk_valid`` (int32 [B]) marks
     how many of a T>1 chunk's tokens are real — pads write to the scratch
-    block.  Shared by every decode-hook model family."""
+    block, and the read of a prefill chunk walks the blocks ``pos +
+    chunk_valid`` reaches and no further.  Shared by every decode-hook
+    model family."""
     from ..ops.decode_attention import decode_attention, \
         paged_decode_attention
 
@@ -413,8 +415,8 @@ def _cached_attention(q, k, v, ck, cv, pos, block_tables=None,
 
     ck, cv = paged_cache_update(ck, cv, k, v, pos, block_tables,
                                 valid=chunk_valid, layer=layer)
-    return paged_decode_attention(q, ck, cv, block_tables, pos,
-                                  layer=layer), ck, cv
+    return paged_decode_attention(q, ck, cv, block_tables, pos, layer=layer,
+                                  valid=chunk_valid), ck, cv
 
 
 def _block_cached_body(cfg: GPT2Config, x, get, mm, ck, cv, pos,

@@ -14,8 +14,8 @@ static shapes).
 Two paths, one API:
  - ``decode_attention_reference``: q of one or more new positions against the
    cache, with position-aware causal masking (query at global position p sees
-   keys ``<= p``) and grouped-query (GQA) head sharing.  Pure XLA — used for
-   prefill and as the CPU/correctness path.
+   keys ``<= p``) and grouped-query (GQA) head sharing.  Pure XLA — the
+   contiguous cache's prefill and the CPU/correctness path.
  - ``decode_attention_pallas``: single-token kernel that streams the cache in
    ``block_k`` chunks with an online softmax (f32 accumulation, no [S] score
    materialisation).  Chunks past the valid prefix are skipped with ``pl.when``
@@ -30,7 +30,13 @@ double-buffered (:func:`_paged_walk_kernel`).  Time follows the tokens a row
 holds, not ``max_seq_len`` (a grid over (row, head, logical block) spent
 ~0.17 us a step whether or not the step held a key: 24,576 steps a layer
 for 24 rows of ~160 tokens, 1 % of the HBM roofline; the walk reads the same
-bytes in ~140 copies of 256 KB at 30-45 %, PERF.md PR 29).
+bytes in ~140 copies of 256 KB at 30-45 %, PERF.md PR 29).  A prefill chunk
+(T = ``prefill_chunk`` query rows a sequence) takes the same walk, several
+blocks a landing tile, under a flash body sized for its query rows
+(:func:`_paged_prefill_kernel`; the gather it replaced read, un-packed and
+transposed all ``max_seq_len`` keys of every row, every layer, every chunk,
+and wrote ``[T, S]`` scores to HBM: 30 of a long-prompt chunk's 41.6 ms,
+PERF.md PR 31).
 """
 
 from __future__ import annotations
@@ -349,12 +355,15 @@ def decode_attention(q, k_cache, v_cache, q_pos, *,
 # collective appears here; the tensor-parallel all-reduce happens after the
 # model's output projection, exactly like the Megatron matmul path.
 # ---------------------------------------------------------------------------
-def _tp_shard_heads(body, q, k_pool, v_pool, block_tables, q_pos, layer):
-    """Run ``body(q, k_pool, v_pool, bt, pos, layer)`` on the stacked pool
-    (``layer=None``: a one-layer pool, lifted here), sharded over the head
-    dims when the configured tp context divides them, else directly.  Int8
-    pool records shard whole: codes and their scale table both carry the
-    head dim at index 2, so the one head spec broadcasts over the record.
+def _tp_shard_heads(body, q, k_pool, v_pool, block_tables, q_pos, layer,
+                    *per_row):
+    """Run ``body(q, k_pool, v_pool, bt, pos, layer, *per_row)`` on the
+    stacked pool (``layer=None``: a one-layer pool, lifted here), sharded
+    over the head dims when the configured tp context divides them, else
+    directly.  ``per_row``: further int32 [B] operands that travel as the
+    positions do (the prefill kernel's ``valid``).  Int8 pool records shard
+    whole: codes and their scale table both carry the head dim at index 2,
+    so the one head spec broadcasts over the record.
 
     Under a configured dp context (``paged_kv.dp_context`` —
     ``engine_mode='dp_tp'`` serving) the batch rows and the pool's
@@ -369,26 +378,27 @@ def _tp_shard_heads(body, q, k_pool, v_pool, block_tables, q_pos, layer):
     n = head_shards(pool_payload(k_pool).shape[2], q.shape[1])
     tp = tp_axis() if n > 1 else None
     if paged_kv.dp_groups() <= 1 and n <= 1:
-        return body(q, k_pool, v_pool, bt, q_pos, layer)
+        return body(q, k_pool, v_pool, bt, q_pos, layer, *per_row)
     pos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1),
                            (q.shape[0],))
     if paged_kv.dp_groups() <= 1:
         return head_shard_map(
             body, (P(None, tp), P(None, None, tp), P(None, None, tp),
-                   P(), P(), P()), P(None, tp))(
-            q, k_pool, v_pool, bt, pos, layer)
+                   P(), P(), P()) + (P(),) * len(per_row), P(None, tp))(
+            q, k_pool, v_pool, bt, pos, layer, *per_row)
     mesh, _, gsize = paged_kv.dp_state()
     dp = paged_kv.dp_axis()
     qs, ps, rs = P(dp, tp), P(None, dp, tp), P(dp)    # q, pool, row args
 
-    def dp_body(q, kp, vp, bt, pos, layer):
+    def dp_body(q, kp, vp, bt, pos, layer, *per_row):
         bt = paged_kv.localize_block_tables(bt, gsize)
-        return body(q, kp, vp, bt, pos, layer)
+        return body(q, kp, vp, bt, pos, layer, *per_row)
 
     return jax.shard_map(dp_body, mesh=mesh,
-                         in_specs=(qs, ps, ps, rs, rs, P()),
+                         in_specs=(qs, ps, ps, rs, rs, P())
+                         + (rs,) * len(per_row),
                          out_specs=qs, check_vma=False)(
-        q, k_pool, v_pool, bt, pos, layer)
+        q, k_pool, v_pool, bt, pos, layer, *per_row)
 
 
 def paged_decode_attention_reference(q, k_pool, v_pool, block_tables, q_pos,
@@ -396,7 +406,9 @@ def paged_decode_attention_reference(q, k_pool, v_pool, block_tables, q_pos,
                                      layer=None):
     """Gather-based paged attention (pure XLA): materialize each row's
     logical cache view through its block table, then run the contiguous
-    reference path.  Serves prefill (T > 1) and the CPU decode path.
+    reference path.  The CPU path for every T and the tests' oracle; on a
+    TPU only what the kernels do not take: a prefill chunk (T > 1) over
+    int8 records, and the resident-window mask.
 
     q:            [B, H, T, D]
     k/v_pool:     [L, NB, HKV, block_size, D] stacked pool + ``layer``
@@ -625,15 +637,15 @@ def _paged_verify_call(q, *args, interpret: bool, **kwargs):
 
 
 def _paged_attention_pallas(launch, q, k_pool, v_pool, block_tables, q_pos,
-                            sm_scale, interpret, layer):
-    """``launch`` (one of the two call sites above) per head shard under a
+                            sm_scale, interpret, layer, *per_row):
+    """``launch`` (one of the kernels' call sites) per head shard under a
     configured tp / dp context (:func:`_tp_shard_heads`)."""
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
         interpret = interpret_kernels()
     body = functools.partial(launch, sm_scale=scale, interpret=interpret)
     return _tp_shard_heads(body, q, k_pool, v_pool, block_tables, q_pos,
-                           layer)
+                           layer, *per_row)
 
 
 def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
@@ -652,8 +664,8 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
                                    layer)
 
 
-#: widest window the verify kernel takes; larger T (chunked prefill) uses
-#: the gather-based reference path
+#: widest window the verify kernel takes; a wider T is a prefill chunk
+#: (:func:`paged_prefill_attention_pallas`)
 VERIFY_T_MAX = 16
 
 
@@ -676,33 +688,353 @@ def paged_verify_attention_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
                                    layer)
 
 
+# ---------------------------------------------------------------------------
+# The prefill chunk: T = ``prefill_chunk`` query positions a row against the
+# row's valid blocks, walked in place like the decode kernel's, attended by
+# a flash body sized for that many query rows.
+# ---------------------------------------------------------------------------
+#: score columns (packed key rows) one softmax update of the prefill kernel
+#: takes — whole 128-lane registers of float32 scores a query row, two of
+#: them: 512 keys a tile at hd 64 (16 blocks), 256 at hd 128 (8).  What a
+#: head pays per update whatever the tile holds (its m / l / acc read and
+#: written back, the chain's latency) is spread over twice the keys of a
+#: 128-column tile: 8.1 -> 6.1 ms a 24-layer call at 1,920 keys, and 2.2 ->
+#: 2.5 ms at an empty prefix, where the one tile is mostly masked (PERF.md
+#: PR 31)
+_PREFILL_COLS = 256
+
+#: heads the prefill kernel attends in one iteration of its loop over a
+#: tile's heads: one head's chain (q·kᵀ, max, exp, p·v, the update of its
+#: acc) is a few hundred cycles of latency with little to overlap, and a
+#: loop keeps iterations apart; four heads' chains interleave.  The body
+#: exists 4 times for it, in each of the two loops (PERF.md PR 31: 17.5 ->
+#: 10.8 ms a 24-layer call at 1,920 keys; 8 heads an iteration spilled)
+_PREFILL_HEAD_UNROLL = 4
+
+#: VMEM one grid step of the prefill kernel may plan for (landing buffers,
+#: m / l / acc, the pipelined query and output blocks); the per-head
+#: temporaries of the body (scores, probabilities: ~0.5 MB) come on top,
+#: under Mosaic's 16 MB default limit
+_PREFILL_VMEM_BUDGET = 10 << 20
+
+
+def _prefill_head_tile(hkv: int, rows: int, spans: int, nt: int, r: int,
+                       width: int, itemsize: int) -> int:
+    """KV heads one grid step of the prefill kernel takes, from the shapes
+    alone: ``rows = rep * T`` query rows a KV head, ``nt`` blocks of
+    ``[r, width]`` a landing tile.  A head costs its share of the two-slot
+    K and V landing buffers, float32 m / l / acc for its query rows, its
+    span-expanded queries and its double-buffered query and output blocks
+    (lane-padded) — 640 KB at OPT-1.3B's ``[4, 128]`` chunk, so 16 of its
+    32 heads a step (10 MB), all 16 of OLMoE's (9 MB); the heads are halved
+    while they overrun :data:`_PREFILL_VMEM_BUDGET`."""
+    def need(ht):
+        return ht * (2 * 2 * nt * r * width * itemsize
+                     + rows * (width + 2 * LANES) * 4
+                     + (spans + 4) * rows * width * itemsize)
+
+    ht = hkv
+    while need(ht) > _PREFILL_VMEM_BUDGET and ht % 2 == 0:
+        ht //= 2
+    return ht
+
+
+def _lanes(x, n: int):
+    """A lane-replicated ``[rows, 128]`` value at ``n`` lanes."""
+    if n % LANES:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+    return x if n == LANES else jnp.concatenate([x] * (n // LANES), axis=1)
+
+
+def _paged_prefill_kernel(layer_ref, base_ref, valid_ref, bt_ref, q_ref,
+                          k_pool, v_pool, o_ref, kbuf, vbuf, sem, qs_scr,
+                          m_scr, l_scr, acc_scr, *,
+                          sm_scale: float, t: int, spans: int):
+    """The paged prefill kernel.  Grid ``(B, HKV // ht)``: one step is one
+    row's chunk of ``t`` query positions, ``base .. base + t - 1`` of which
+    the first ``valid`` are real, for ``ht`` KV heads
+    (:func:`_prefill_head_tile`).
+
+    ``layer_ref`` int32 [1], ``base_ref`` / ``valid_ref`` int32 [B] and
+    ``bt_ref`` int32 [B, NBPER] arrive via scalar prefetch; the pools stay
+    in HBM (``pl.ANY``).  The row walks its ``n = cdiv(base + valid,
+    block_size)`` valid blocks — the chunk was just written into the last
+    of them — ``nt`` blocks a TILE: each block is one DMA of all ``ht``
+    heads, ``pool.at[layer, bt[b, i]]`` -> ``buf[slot, j]``, and tile ``i +
+    1`` lands while tile ``i`` is attended.  Neither a table entry nor a
+    block past ``n`` is read, and a pad row (``valid == 0``) walks nothing
+    and returns zeros.
+
+    A tile is attended head by head (a ``fori_loop``,
+    :data:`_PREFILL_HEAD_UNROLL` heads an iteration): the head's ``nt``
+    landed ``[r, width]`` blocks are ``cols = nt * r`` packed key rows, and
+    ``qs_scr`` [ht, g*rows, width] carries each query of ``q_ref`` [1, ht,
+    rows, D] once per span, its D values in the span's lane group and zeros
+    elsewhere (:func:`_span_queries`'s operand, built here once a grid step
+    by a 0/1 placement matmul, which is exact), so one ``q·kᵀ`` — pool
+    dtype operands, float32 accumulation — gives rows ``h*rows ..`` the
+    scores against span ``h`` of every block, ``[rows, cols]`` a span.  One
+    online-softmax update takes all ``g * cols`` keys of the tile: m and l
+    are per QUERY (shared by its spans; float32, lane-replicated), the
+    probabilities go to the MXU in the pool's dtype as the reference's do,
+    and each span's ``p·v`` lands in its own lane group of ``acc`` [rows,
+    width] (float32), summed over the groups once, at the end.  Tiles
+    wholly below ``base`` need no mask; the rest keep key ``<= base +
+    min(i, valid - 1)`` for query offset ``i`` (pad queries see what the
+    last real one sees), which also hides the slots of a last, partly
+    landed tile — ``vbuf`` is zeroed at the start, so that what the mask
+    zeroes in ``p`` meets no NaN in ``v``."""
+    _, nt, ht, r, width = kbuf.shape
+    rows, d = o_ref.shape[2:]
+    bs, cols = r * spans, nt * r
+    b, layer = pl.program_id(0), layer_ref[0]
+    base, valid = base_ref[b], valid_ref[b]
+    n = jnp.clip((base + valid + bs - 1) // bs, 0, bt_ref.shape[1])
+    n = jnp.where(valid > 0, n, 0)
+    ntiles = (n + nt - 1) // nt
+    clear = jnp.minimum(base // (nt * bs), ntiles)    # tiles below the chunk
+    whole = k_pool.shape[2] == ht
+    heads = pl.ds(pl.program_id(1) * ht, ht)
+    unroll = math.gcd(ht, _PREFILL_HEAD_UNROLL)
+    # the span whose p·v each lane of acc keeps
+    group = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1) // d
+
+    def each_block(i, slot, act):
+        """``act`` on the copies of tile ``i``'s valid blocks."""
+        def one(j, carry):
+            for op, (pool, buf) in enumerate(((k_pool, kbuf), (v_pool, vbuf))):
+                # an operand that is this layer's rows alone (_lane_rows)
+                # is a one-layer stack
+                src = (layer if pool.shape[0] > 1 else 0,
+                       bt_ref[b, i * nt + j])
+                act(pltpu.make_async_copy(
+                    pool.at[src if whole else src + (heads,)],
+                    buf.at[slot, j], sem.at[slot, op]))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(n - i * nt, 0, nt), one, None)
+
+    def attend(masked: bool):
+        def tile(i, carry):
+            slot = i % 2
+            each_block(i + 1, 1 - slot, lambda copy: copy.start())
+            each_block(i, slot, lambda copy: copy.wait())
+            if masked:
+                col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+                # span 0's key of each column; span h's is h*r further
+                key = i * (nt * bs) + col + col // r * (bs - r)
+                row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+                last = base + jnp.minimum(row % t, valid - 1)
+
+            def head(h):
+                k = kbuf[slot, :, h].reshape(cols, width)
+                v = vbuf[slot, :, h].reshape(cols, width)
+                s = jax.lax.dot_general(
+                    qs_scr[h], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * sm_scale
+                parts = [s[g * rows:(g + 1) * rows] for g in range(spans)]
+                if masked:
+                    parts = [jnp.where(key + g * r <= last, part, NEG_INF)
+                             for g, part in enumerate(parts)]
+                # m and l stay lane-replicated [rows, 128], as the scratch
+                # holds them: a [rows, 1] column would be broadcast over
+                # the lanes again at each of its uses
+                m_prev = m_scr[h]
+                top = parts[0]
+                for part in parts[1:]:
+                    top = jnp.maximum(top, part)
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(top, axis=-1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                m_cols, pv, psum = _lanes(m_new, cols), None, None
+                for g, part in enumerate(parts):
+                    p = jnp.exp(part - m_cols)
+                    psum = p if psum is None else psum + p
+                    out = jax.lax.dot_general(
+                        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    pv = out if pv is None else jnp.where(group == g, out, pv)
+                acc_scr[h] = acc_scr[h] * _lanes(alpha, width) + pv
+                m_scr[h] = m_new
+                l_scr[h] = l_scr[h] * alpha + jnp.sum(psum, axis=-1,
+                                                      keepdims=True)
+
+            def heads_at(i, carry):
+                for u in range(unroll):
+                    head(i * unroll + u)
+                return carry
+
+            jax.lax.fori_loop(0, ht // unroll, heads_at, None)
+            return carry
+        return tile
+
+    def span_queries(h, carry):
+        q = q_ref[0, h].astype(qs_scr.dtype)                    # [rows, D]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (d, width), 1)
+        at = jax.lax.broadcasted_iota(jnp.int32, (d, width), 0)
+        for g in range(spans):
+            qs_scr[h, g * rows:(g + 1) * rows] = jnp.dot(
+                q, (lane == at + g * d).astype(q.dtype),
+                preferred_element_type=jnp.float32).astype(q.dtype)
+        return carry
+
+    def finish(h, carry):
+        l, acc = l_scr[h][:, :1], acc_scr[h]
+        num = acc[:, :d]
+        for g in range(1, spans):
+            num = num + acc[:, g * d:(g + 1) * d]
+        o_ref[0, h] = (num / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        return carry
+
+    _start_chunks(m_scr, l_scr, acc_scr)
+    vbuf[...] = jnp.zeros_like(vbuf)
+    each_block(0, 0, lambda copy: copy.start())
+    jax.lax.fori_loop(0, ht, span_queries, None)
+    jax.lax.fori_loop(0, clear, attend(False), None)
+    jax.lax.fori_loop(clear, ntiles, attend(True), None)
+    jax.lax.fori_loop(0, ht, finish, None)
+
+
+def _paged_prefill_call(q, k_pool, v_pool, block_tables, q_pos, layer, valid,
+                        *, sm_scale: float, interpret: bool):
+    """The launch of :func:`_paged_prefill_kernel`, ``q`` [B, H, T, D]
+    against one shard's stacked float pool at ``layer``: the decode
+    kernel's operands (:func:`_paged_launch`) plus ``valid``, the landing
+    tile and the head tile read off the local shapes."""
+    b, h, t, d = q.shape
+    _, nb, _, r_in, w_in = k_pool.shape
+    pools = [_lane_rows(p, layer, d) for p in (k_pool, v_pool)]
+    hkv, r, width = pools[0].shape[2:]
+    rows, spans = h // hkv * t, r_in * w_in // d // r     # block_size over R
+    nt = max(1, min(_PREFILL_COLS // r, block_tables.shape[1]))
+    ht = _prefill_head_tile(hkv, rows, spans, nt, r, width,
+                            pools[0].dtype.itemsize)
+    base = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1), (b,))
+    valid = jnp.clip(valid, 0, t)
+    # clipped as the decode kernel's: no table can send a DMA outside the pool
+    bt = jnp.clip(jnp.asarray(block_tables, jnp.int32), 0, nb - 1)
+
+    def row_block(n_rows, n_lanes):
+        return pl.BlockSpec((1, ht, n_rows, n_lanes),
+                            lambda i, j, *prefetched: (i, j, 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,             # layer, base, valid, block table
+        grid=(b, hkv // ht),
+        in_specs=[row_block(rows, d),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=row_block(rows, d),
+        scratch_shapes=[pltpu.VMEM((2, nt, ht, r, width), p.dtype)
+                        for p in pools] + [
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((ht, spans * rows, width), pools[0].dtype),  # q
+            pltpu.VMEM((ht, rows, LANES), jnp.float32),           # m
+            pltpu.VMEM((ht, rows, LANES), jnp.float32),           # l
+            pltpu.VMEM((ht, rows, width), jnp.float32),           # acc
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_paged_prefill_kernel, sm_scale=sm_scale, t=t,
+                          spans=spans),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret, name="paged_prefill_attn",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), base, valid, bt,
+      q.reshape(b, hkv, rows, d), *pools).reshape(q.shape)
+
+
+def paged_prefill_attention_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
+                                   valid=None,
+                                   sm_scale: Optional[float] = None,
+                                   interpret: Optional[bool] = None,
+                                   layer=None):
+    """A prefill chunk's paged attention: q [B, H, T, D], row b's T
+    positions starting at its own ``q_pos[b]`` (scalar q_pos broadcasts)
+    and just written to the pool, the first ``valid[b]`` of them real
+    (default all T; a row with none is a pad row and comes back zeros).
+    Each row walks its own ``cdiv(base + valid, block_size)`` blocks of the
+    stacked float pool at ``layer`` in-kernel (:func:`_paged_prefill_kernel`,
+    as ``paged_prefill_attn``); pad queries of a real row return what its
+    last real query sees.  Under a configured tp context each chip launches
+    the kernel on its own head shard of q and the pool."""
+    assert not is_quantized_pool(k_pool), \
+        "the prefill kernel takes float pools; int8 records use the gather"
+    b, t = q.shape[0], q.shape[2]
+    valid = jnp.full((b,), t, jnp.int32) if valid is None \
+        else jnp.asarray(valid, jnp.int32)
+    return _paged_attention_pallas(_paged_prefill_call, q, k_pool, v_pool,
+                                   block_tables, q_pos, sm_scale, interpret,
+                                   layer, valid)
+
+
+#: paths :func:`paged_decode_attention` took while a :func:`dispatch_log`
+#: was open — written at TRACE time, like the contexts above
+_DISPATCHED = None
+
+
+@contextlib.contextmanager
+def dispatch_log():
+    """Collect the name of the path each :func:`paged_decode_attention`
+    call inside the block takes (a kernel's name, ``"gather"`` or
+    ``"sp"``).  The serving engine opens it around the traced body of a
+    program to record which read that program was built with."""
+    global _DISPATCHED
+    prev, _DISPATCHED = _DISPATCHED, set()
+    try:
+        yield _DISPATCHED
+    finally:
+        _DISPATCHED = prev
+
+
+def _took(path: str) -> None:
+    if _DISPATCHED is not None:
+        _DISPATCHED.add(path)
+
+
 def paged_decode_attention(q, k_pool, v_pool, block_tables, q_pos, *,
-                           sm_scale: Optional[float] = None, layer=None):
+                           sm_scale: Optional[float] = None, layer=None,
+                           valid=None):
     """Dispatch: block-table-walking Pallas kernels on TPU — single-token
-    decode (T == 1) or the speculative K+1 verify window (T <=
-    ``VERIFY_T_MAX``); gather + XLA reference otherwise (prefill chunks,
-    CPU-sim).  A configured sp context (``ops/sp_attention``) routes
-    prefill chunks through the Ulysses all-to-all path; a resident-window
-    context forces the reference path, which carries the window mask.
-    ``k_pool``/``v_pool`` are the stacked pool with ``layer`` given, one
-    layer's pool otherwise (``paged_kv.whole_pool``)."""
-    if q.shape[2] > 1:
+    decode (T == 1), the speculative K+1 verify window (T <=
+    ``VERIFY_T_MAX``) or a prefill chunk (any wider T, float pools;
+    ``valid`` int32 [B]: the chunk's real tokens per row, as the write
+    took them); gather + XLA reference otherwise (CPU-sim, int8 records
+    under a prefill chunk).  A configured sp context
+    (``ops/sp_attention``) routes prefill chunks through the Ulysses
+    all-to-all path; a resident-window context forces the reference path,
+    which carries the window mask.  ``k_pool``/``v_pool`` are the stacked
+    pool with ``layer`` given, one layer's pool otherwise
+    (``paged_kv.whole_pool``)."""
+    t = q.shape[2]
+    if t > 1:
         from . import sp_attention
 
         hkv = pool_payload(k_pool).shape[1 if layer is None else 2]
-        if sp_attention.sp_shards(q.shape[1], hkv, q.shape[2]) > 1:
+        if sp_attention.sp_shards(q.shape[1], hkv, t) > 1:
+            _took("sp")
             return sp_attention.sp_prefill_attention(
                 q, k_pool, v_pool, block_tables, q_pos, sm_scale=sm_scale,
                 layer=layer)
     if window_state() is None and on_tpu():
-        if q.shape[2] == 1:
+        if t == 1:
+            _took("paged_decode_attn")
             return paged_decode_attention_pallas(
                 q, k_pool, v_pool, block_tables, q_pos, sm_scale=sm_scale,
                 layer=layer)
-        if q.shape[2] <= VERIFY_T_MAX:
+        if t <= VERIFY_T_MAX:
+            _took("paged_verify_attn")
             return paged_verify_attention_pallas(
                 q, k_pool, v_pool, block_tables, q_pos, sm_scale=sm_scale,
                 layer=layer)
+        if not is_quantized_pool(k_pool):
+            _took("paged_prefill_attn")
+            return paged_prefill_attention_pallas(
+                q, k_pool, v_pool, block_tables, q_pos, valid=valid,
+                sm_scale=sm_scale, layer=layer)
+    _took("gather")
     return paged_decode_attention_reference(q, k_pool, v_pool, block_tables,
                                             q_pos, sm_scale=sm_scale,
                                             layer=layer)

@@ -124,11 +124,14 @@ and the serving engine's host-side ledger + ``analysis/invariants.py``
 Rollback of rejected speculative tokens stays free: re-quantizing the
 same deterministic values yields the same codes and scales.
 
-Everything here is pure XLA (block read-modify-write / gather), shared by
-prefill and the CPU/correctness decode path; the TPU kernels that walk a
-row's valid blocks of the (layer, block table) index in-kernel live in
-``ops/decode_attention.py``
-(``paged_decode_attention_pallas`` / ``paged_verify_attention_pallas``)
+Everything here is pure XLA (block read-modify-write / gather): the write
+every program shares, and the gather that is the read of the CPU/correctness
+path, of an int8 record under a prefill chunk and of the resident-window
+mask.  On a TPU every read — a decode token, a verify window, a prefill
+chunk — goes through the kernels that walk a row's valid blocks of the
+(layer, block table) index in-kernel; they live in
+``ops/decode_attention.py`` (``paged_decode_attention_pallas`` /
+``paged_verify_attention_pallas`` / ``paged_prefill_attention_pallas``)
 and shard through the same context.
 """
 

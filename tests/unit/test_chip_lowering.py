@@ -235,9 +235,15 @@ def test_pool_is_donated_and_never_sliced_or_restacked(program, kv8, smoke,
         opt.OPTConfig(**OPT13B), None, kv8=kv8)[program]
     text = jax.export.export(jax.jit(fn, donate_argnums=(1,)),
                              platforms=["tpu"])(*args).mlir_module()
-    # decode and verify read the pool through the Mosaic kernels; prefill's
-    # read is the gather reference (ROADMAP 1.3)
-    assert ("tpu_custom_call" in text) == (program != "prefill")
+    # all three read the pool through a Mosaic kernel: decode and verify
+    # the walk, prefill a float pool's chunk kernel (an int8 record's
+    # chunk stays on the gather reference)
+    kernel = {"decode_step": "paged_decode_attn",
+              "verify": "paged_verify_attn",
+              "prefill": "paged_prefill_attn"}[program]
+    assert (f'kernel_name = "{kernel}"' in text) == \
+        (not (kv8 and program == "prefill"))
+    assert ("tpu_custom_call" in text) == (f'"{kernel}"' in text)
     payload = smoke.pool_payload_struct(args[1]).shape    # [L,NB,H,bs,hd]
 
     main = next(l for l in text.splitlines() if "func.func public @main" in l)
@@ -295,14 +301,18 @@ def test_compiled_serving_programs_hold_no_pool_sized_temporary(
         compiled, copies = smoke.compile_serving_program(fn, args)
         leaves = jax.tree_util.tree_leaves(args[1])
         payload = smoke.pool_payload_struct(args[1])
-        # bf16: under ONE layer's slice of the pool (100.8 MB; prefill's
-        # gathered K/V views are 34 MB).  kv8: the int8 record's scale
+        # bf16: under ONE layer's slice of the pool (100.8 MB), and prefill
+        # — its gathered K/V views of 34 MB gone with the chunk kernel —
+        # under a QUARTER of one (what is left is the chunk's own
+        # activations).  kv8: the int8 record's scale
         # table [L, NB, HKV, bs] still enters and leaves in XLA's layout
         # (4 copies of 38 MB, padded, a step: 303 MB) and prefill holds
         # dequantized f32 views (707 MB) — bound it by the codes of ONE of
         # K and V instead; a copy of a pool slice is caught by name above
         layer_slice = int(np.prod(payload.shape[1:])) * payload.dtype.itemsize
         limit = int(np.prod(payload.shape)) if kv8 else layer_slice
+        if name == "prefill" and not kv8:
+            limit = layer_slice // 4
         mem = compiled.memory_analysis()
         assert not copies, (name, copies[:2])
         assert mem.temp_size_in_bytes < limit, (name, mem.temp_size_in_bytes)
@@ -315,13 +325,18 @@ def test_compiled_serving_programs_hold_no_pool_sized_temporary(
 @pytest.mark.parametrize("cell,slots,h,hd,ctx,layers", CELL_SHAPES)
 def test_paged_walk_compiles_at_the_cells_shapes(cell, slots, h, hd, ctx,
                                                  layers, kv8, one_chip):
-    """Mosaic's own compile of the walk for a described v5e (its DMA
-    alignment rules and VMEM budget: what lowering alone does not check),
-    and no temporary beside a packed float pool: the kernel reads the
-    whole stack where it lies."""
-    for t, kernel in ((1, da.paged_decode_attention_pallas),
-                      (4, da.paged_verify_attention_pallas)):
-        args = _cell_operands(slots, h, hd, ctx, layers, kv8, t, one_chip)
+    """Mosaic's own compile of the walk — decode, verify and, for a float
+    pool, a prefill chunk — for a described v5e (its DMA alignment rules
+    and VMEM budget: what lowering alone does not check), and no temporary
+    beside a packed float pool: the kernel reads the whole stack where it
+    lies."""
+    kernels = [(1, slots, da.paged_decode_attention_pallas),
+               (4, slots, da.paged_verify_attention_pallas)]
+    if not kv8:
+        # a [4, 128] prefill chunk (the cells' prefill_batch x prefill_chunk)
+        kernels.append((128, 4, da.paged_prefill_attention_pallas))
+    for t, rows, kernel in kernels:
+        args = _cell_operands(rows, h, hd, ctx, layers, kv8, t, one_chip)
         q, pool, bt, pos = args
         compiled = jax.jit(lambda q, k, v, bt, pos, kernel=kernel: kernel(
             q, k, v, bt, pos, interpret=False, layer=1)).lower(

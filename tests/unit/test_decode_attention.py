@@ -13,7 +13,7 @@ import pytest
 from deepspeed_tpu.ops.decode_attention import (
     decode_attention_pallas, decode_attention_reference,
     paged_decode_attention_pallas, paged_decode_attention_reference,
-    paged_verify_attention_pallas)
+    paged_prefill_attention_pallas, paged_verify_attention_pallas)
 
 #: Pallas interpret mode: minutes on CPU.  Marked per test, so that the
 #: (small, fast) cases of the paged walk at the end of this file stay in the
@@ -634,10 +634,11 @@ def test_stacked_pool_attention_reads_its_layer(t, kv8, tp):
 WALK_BS, WALK_CTX, WALK_LAYERS = 32, 160, 2          # 5 blocks a row
 
 
-def _walk_case(rng, t, hd, rep, kv8, bases):
+def _walk_case(rng, t, hd, rep, kv8, bases, held=None, ctx=None):
     """A 2-layer pool, lane-packed as the engine holds it (hd 64: g = 2,
     hd 128: g = 1), rows of the given ``bases`` (query positions ``base ..
-    base + t - 1``).  Returns ``(q, k_pool, v_pool, poisoned table, clean
+    base + t - 1``; ``held``: the tokens each row holds, ``base + t`` unless
+    given).  Returns ``(q, k_pool, v_pool, poisoned table, clean
     table)``: past each row's valid prefix the poisoned table holds ids of
     blocks full of NaN (an int8 record's scale rows are NaN there), the
     clean one the scratch id 0 — a kernel that copies one entry too many
@@ -645,8 +646,8 @@ def _walk_case(rng, t, hd, rep, kv8, bases):
     from deepspeed_tpu.ops import paged_kv
 
     b, hkv, bs = len(bases), 2, WALK_BS
-    kp, vp, bt = _stacked_pool(rng, WALK_LAYERS, b, hkv, WALK_CTX, hd, bs,
-                               kv8)
+    kp, vp, bt = _stacked_pool(rng, WALK_LAYERS, b, hkv, ctx or WALK_CTX, hd,
+                               bs, kv8)
     bt = np.asarray(bt)
     nb = paged_kv.pool_payload(kp).shape[1]
     poison = np.setdiff1d(np.arange(1, nb), bt)[:2]      # blocks no row owns
@@ -658,7 +659,8 @@ def _walk_case(rng, t, hd, rep, kv8, bases):
         return pool.at[:, poison].set(jnp.nan)
 
     kp, vp = (paged_kv.pack_pool(poisoned(p)) for p in (kp, vp))
-    valid = (np.asarray(bases) + t - 1) // bs + 1    # blocks a row may read
+    held = np.asarray(bases) + t if held is None else np.asarray(held)
+    valid = (held + bs - 1) // bs                    # blocks a row may read
     past = np.arange(bt.shape[1])[None, :] >= valid[:, None]
     garbage = poison[rng.integers(0, 2, bt.shape)]
     q = jnp.asarray(rng.standard_normal((b, hkv * rep, t, hd)), jnp.float32)
@@ -747,3 +749,157 @@ def test_paged_walk_splits_heads_over_the_grid(monkeypatch):
         interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# ------------------------------------------ a prefill chunk walks them too
+# ISSUE 31: T = prefill_chunk query rows against the row's valid blocks,
+# ``cdiv(base + valid, block_size)`` of them, ``nt`` blocks a landing tile.
+PREFILL_T = 48
+#: (base, valid): a chunk from 0 across a block boundary; from mid-block;
+#: from a block's first token with valid < T; a pad row; the table's last
+#: block; a short chunk across a boundary
+PREFILL_ROWS = [(0, PREFILL_T), (37, PREFILL_T), (2 * WALK_BS, 20), (0, 0),
+                (WALK_CTX - PREFILL_T, PREFILL_T), (WALK_BS - 2, 5)]
+
+
+def _prefill_case(rng, hd, rep, rows=PREFILL_ROWS, t=PREFILL_T, ctx=None):
+    bases, valid = (np.asarray(c, np.int32) for c in zip(*rows))
+    q, kp, vp, bt, clean = _walk_case(rng, t, hd, rep, False, bases,
+                                      held=np.where(valid > 0,
+                                                    bases + valid, 0),
+                                      ctx=ctx)
+    return q, kp, vp, bt, clean, jnp.asarray(bases), jnp.asarray(valid)
+
+
+def _assert_prefill_matches(got, want, valid):
+    """Real queries equal the gather reference's; a pad row is zeros."""
+    got, want = np.asarray(got), np.asarray(want)
+    assert np.isfinite(got).all(), "read past a valid prefix"
+    for b, n in enumerate(np.asarray(valid)):
+        np.testing.assert_allclose(got[b, :, :n], want[b, :, :n],
+                                   rtol=2e-5, atol=2e-5)
+        if n == 0:
+            assert not got[b].any()
+
+
+@pytest.mark.parametrize("cols", [128, 32], ids=["one-tile", "tiles"])
+@pytest.mark.parametrize("rep", [1, 4])
+@pytest.mark.parametrize("hd", [128, 64], ids=["g1", "g2"])
+def test_paged_prefill_walks_each_rows_valid_blocks_only(hd, rep, cols,
+                                                         monkeypatch):
+    """The prefill kernel against the gather reference at a non-zero
+    layer, every block and every table entry past ``cdiv(base + valid,
+    bs)`` poisoned (NaN blocks no row owns): the output is finite and
+    unchanged, so the walk read valid blocks only.  ``cols = 32`` makes a
+    landing tile 1-2 blocks, so a row walks several tiles, unmasked below
+    its base and masked from there."""
+    from deepspeed_tpu.ops import decode_attention as da
+
+    monkeypatch.setattr(da, "_PREFILL_COLS", cols)
+    rng = np.random.default_rng(80 + hd + rep)
+    q, kp, vp, bt, clean, bases, valid = _prefill_case(rng, hd, rep)
+    want = paged_decode_attention_reference(q, kp, vp, clean, bases, layer=1)
+    got = paged_prefill_attention_pallas(q, kp, vp, bt, bases, valid=valid,
+                                         layer=1, interpret=True)
+    _assert_prefill_matches(got, want, valid)
+
+
+def test_paged_prefill_tile_of_two_score_registers_a_row():
+    """The shape the cells run: a landing tile of 256 score columns (8
+    blocks at hd 128), m and l lane-replicated over both 128-lane halves;
+    a 9-block context, so a row walks two tiles."""
+    rng = np.random.default_rng(93)
+    ctx = 9 * WALK_BS
+    q, kp, vp, bt, clean, bases, valid = _prefill_case(
+        rng, 128, 1, rows=[(8 * WALK_BS - 26, PREFILL_T), (0, 30),
+                           (ctx - PREFILL_T, PREFILL_T)], ctx=ctx)
+    want = paged_decode_attention_reference(q, kp, vp, clean, bases, layer=1)
+    got = paged_prefill_attention_pallas(q, kp, vp, bt, bases, valid=valid,
+                                         layer=1, interpret=True)
+    _assert_prefill_matches(got, want, valid)
+
+
+def test_paged_prefill_takes_an_unpacked_pool_and_a_whole_chunk():
+    """The benchmark's comparison passes a pool as ``init_cache`` built it
+    (hd 64, not lane-packed: ``_lane_rows`` packs this layer's rows) and no
+    ``valid`` (every query real), under jit with traced bases."""
+    from deepspeed_tpu.ops import paged_kv
+
+    rng = np.random.default_rng(90)
+    t, b, hkv, d = 32, 3, 2, 64
+    kp, vp, bt = _stacked_pool(rng, WALK_LAYERS, b, hkv, WALK_CTX, d,
+                               WALK_BS, False)
+    q = jnp.asarray(rng.standard_normal((b, hkv, t, d)), jnp.float32)
+    bases = jnp.asarray([0, 41, WALK_CTX - t], jnp.int32)
+    want = paged_decode_attention_reference(q, kp, vp, bt, bases, layer=1)
+    got = jax.jit(lambda q, kp, vp, bases: paged_prefill_attention_pallas(
+        q, kp, vp, bt, bases, layer=1, interpret=True))(q, kp, vp, bases)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    packed = paged_prefill_attention_pallas(
+        q, paged_kv.pack_pool(kp), paged_kv.pack_pool(vp), bt, bases,
+        layer=1, interpret=True)
+    np.testing.assert_allclose(np.asarray(packed), np.asarray(got),
+                               rtol=1e-6, atol=1e-6)
+
+
+def test_paged_prefill_shards_over_heads():
+    """tp = 2 through ``_tp_shard_heads``: each chip walks its own head
+    shard of the pool, ``valid`` replicated beside the bases."""
+    from deepspeed_tpu.ops import paged_kv
+
+    rng = np.random.default_rng(91)
+    q, kp, vp, bt, clean, bases, valid = _prefill_case(
+        rng, 64, 2, rows=[(37, 24), (0, 0), (WALK_CTX - 24, 24)], t=24)
+    want = paged_decode_attention_reference(q, kp, vp, clean, bases, layer=1)
+    with paged_kv.tp_context(_tp_mesh(2)):
+        got = jax.jit(lambda q, kp, vp: paged_prefill_attention_pallas(
+            q, kp, vp, bt, bases, valid=valid, layer=1, interpret=True))(
+                q, kp, vp)
+    _assert_prefill_matches(got, want, valid)
+
+
+@pytest.mark.parametrize(
+    "hkv,rows,spans,nt,r,width,itemsize,want",
+    [(32, 128, 2, 16, 16, 128, 2, 16),    # OPT-1.3B: 640 KB a head
+     (16, 128, 1, 8, 32, 128, 2, 16),     # OLMoE: 608 KB a head
+     (8, 128, 2, 16, 16, 128, 2, 8),      # a tp=4 shard of OPT
+     (8, 512, 1, 8, 32, 128, 2, 4),       # GQA rep 4 at hd 128
+     (32, 128, 1, 8, 32, 128, 4, 8)],     # float32
+    ids=["opt", "olmoe", "tp-shard", "gqa", "f32"])
+def test_paged_prefill_head_tile_is_read_off_the_shapes(
+        hkv, rows, spans, nt, r, width, itemsize, want):
+    from deepspeed_tpu.ops import decode_attention as da
+
+    assert da._prefill_head_tile(hkv, rows, spans, nt, r, width,
+                                 itemsize) == want
+
+
+def test_dispatcher_sends_a_prefill_chunk_to_the_kernel_on_a_tpu(monkeypatch):
+    """``T > VERIFY_T_MAX`` takes the prefill kernel on a TPU — a float
+    pool; an int8 record and a resident-window context keep the gather —
+    and ``dispatch_log`` names the path taken, at trace time."""
+    from deepspeed_tpu.ops import decode_attention as da
+    from deepspeed_tpu.ops import paged_kv
+
+    rng = np.random.default_rng(92)
+    q, kp, vp, bt, clean, bases, valid = _prefill_case(rng, 64, 1)
+    want = paged_decode_attention_reference(q, kp, vp, clean, bases, layer=1)
+    with da.dispatch_log() as off_tpu:
+        da.paged_decode_attention(q, kp, vp, clean, bases, layer=1,
+                                  valid=valid)
+    assert off_tpu == {"gather"}
+    monkeypatch.setattr(da, "on_tpu", lambda: True)
+    monkeypatch.setattr(da, "interpret_kernels", lambda: True)
+    with da.dispatch_log() as paths:
+        got = da.paged_decode_attention(q, kp, vp, bt, bases, layer=1,
+                                        valid=valid)
+    assert paths == {"paged_prefill_attn"}
+    _assert_prefill_matches(got, want, valid)
+    kv8 = paged_kv.pack_pool(paged_kv.quantize_pool(
+        jnp.zeros(kp.shape[:3] + (WALK_BS, 64), jnp.float32)))
+    with da.dispatch_log() as paths:
+        da.paged_decode_attention(q, kv8, kv8, clean, bases, layer=1)
+        with da.window_context(jnp.zeros(len(bases), jnp.int32), 0):
+            da.paged_decode_attention(q, kp, vp, clean, bases, layer=1)
+    assert paths == {"gather"}

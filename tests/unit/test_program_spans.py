@@ -121,6 +121,22 @@ def test_in_flight_spans_nest_inside_their_phase(served):
         assert (p["args"]["slots"] > 0) == bool(inside)
 
 
+def test_prefill_spans_count_the_blocks_their_reads_walk(served, tiny):
+    """ISSUE 31: each ``prefill`` span carries ``kv_blocks``, the sum over
+    its rows of ``cdiv(base + valid, block_size)`` — what the prefill
+    kernel walks — and the program notes, as it is traced, which read it
+    was built with (on a CPU the gather)."""
+    srv, events, _ = served
+    bs, chunk = SERVE_KW["block_size"], SERVE_KW["prefill_chunk"]
+    want = sum(-(-min(end, len(r.prompt)) // bs)
+               for r in _requests(tiny[1])
+               for end in range(chunk, len(r.prompt) + chunk, chunk))
+    spans = _named(events, "prefill")
+    assert all(e["args"]["kv_blocks"] >= e["args"]["rows"] for e in spans)
+    assert sum(e["args"]["kv_blocks"] for e in spans) == want
+    assert srv.stats()["prefill_attn"] == "gather"
+
+
 def test_kv_seconds_and_step_numbers(served):
     _, events, _ = served
     for s in _named(events, "step"):

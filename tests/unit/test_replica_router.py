@@ -223,6 +223,9 @@ class _FakeReplica:
         self._prefix = None
         self._pending = _PendingQueue()
         self._active = {}
+        # the router's workers read it once a replica has nothing queued
+        # or active: without it an idle fake's worker "died" by timing
+        self._cancel_flags = set()
         self._alloc = type("A", (), {"blocks_in_use": 0})()
         self.depth_for = depth_for or (lambda prompt: 0)
         self.prompt_tokens = 0

@@ -51,6 +51,8 @@ ENGINE_STATS_KEYS = frozenset({
     "handoffs",
     "iterations", "kv_dtype", "kv_pool_bytes", "kv_pool_bytes_per_chip",
     "kv_pool_shape", "kv_scale_bytes", "kv_sharded",
+    # PR 31: which read the prefill program was traced with
+    "prefill_attn",
     # PR 28: routed (token, expert) rows and experts touched, summed over
     # layers and program calls; 0 for a dense model
     "moe_expert_rows", "moe_experts_touched",

@@ -75,8 +75,13 @@ def test_threaded_fleet_parity_under_sanitizer(fleet_setup):
         while not stop_scraping.is_set():
             for ep in ("metrics", "stats", "trace"):
                 try:
+                    # /stats and /trace take the fleet and replica locks,
+                    # which a worker holds through a step that compiles:
+                    # on a loaded CPU (tier-1 runs six workers) that wait
+                    # is the test's longest, so it gets the waits the
+                    # results below get, not 10 s
                     with urllib.request.urlopen(
-                            f"{server.url}/{ep}", timeout=10) as resp:
+                            f"{server.url}/{ep}", timeout=120) as resp:
                         body = resp.read().decode("utf-8")
                 except Exception as e:   # noqa: BLE001 — surfaced below
                     scrape_errors.append((ep, repr(e)))
@@ -112,7 +117,7 @@ def test_threaded_fleet_parity_under_sanitizer(fleet_setup):
     for t in subs:
         t.start()
     for t in subs:
-        t.join(timeout=60)
+        t.join(timeout=120)
     assert submit_errors == []
 
     # ---- cancels racing the workers: two extra requests, cancelled
@@ -144,7 +149,7 @@ def test_threaded_fleet_parity_under_sanitizer(fleet_setup):
         if h.status != "cancelled":
             assert h.result(timeout=120) is not None
     stop_scraping.set()
-    scraper_t.join(timeout=30)
+    scraper_t.join(timeout=150)
     router.stop()
 
     # ---- sanitizer: plenty of cross-lock checks, zero violations
