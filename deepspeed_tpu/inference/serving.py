@@ -217,7 +217,8 @@ from ..analysis.concurrency import (LockSanitizer, caller_site,
 from ..analysis.invariants import audit_serving_engine
 from ..analysis.sentry import (RecompileSentry, backend_compiles,
                                install_compile_listener)
-from ..ops import decode_attention, paged_kv, sp_attention
+from ..ops import (decode_attention, paged_kv, sp_attention,
+                   sparse_index_attention)
 from ..ops import sampling as sampling_ops
 from ..ops.decode_attention import VERIFY_T_MAX
 from ..ops.paged_kv import blocks_for
@@ -834,6 +835,11 @@ class ServingEngine:
         #: an expert family's cached forward also returns its per-layer
         #: routing record (``moe/routed.py RECORD``) when asked
         self._routing = bool(hooks.get("routing_record"))
+        #: learned sparse attention (decode hook ``sparse_attention``:
+        #: ``{"topk"}``): the pool has a third leaf (the indexer's keys) and
+        #: a row past ``topk`` keys attends ``topk`` of them; None otherwise
+        self._sparse = hooks.get("sparse_attention")
+        self._sparse_totals = dict.fromkeys(sparse_index_attention.COUNTS, 0)
         self._init_cache = hooks["init_cache"]
         max_ctx = hooks.get("max_seq_len")
         if max_seq_len is None:
@@ -1075,6 +1081,21 @@ class ServingEngine:
         # stacked [L, NB, HKV, bs, hd] buffer) when the mesh carries a tp
         # axis the head count divides, else replicated (module docstring)
         self.tp_degree = int(dict(engine.mesh.shape).get(TP_AXIS, 1))
+        if self._sparse:
+            unserved = [what for what, on in (
+                (f"a tp mesh (tp={self.tp_degree})", self.tp_degree > 1),
+                (f"engine_mode='dp_tp' (dp={self.dp_degree})",
+                 self.dp_degree > 1),
+                (f"sp={self.sp_degree}", self.sp_degree > 1),
+                ("quantize='kv8'", self.kv_quant),
+                ("resident_window_blocks", self.resident_window_blocks),
+                ("a draft model", draft is not None)) if on]
+            if unserved:
+                raise ValueError(
+                    f"{engine.module.name} selects its keys with a learned "
+                    "indexer (decode hook sparse_attention), which is served "
+                    "on one shard over a float pool; not with "
+                    + ", ".join(unserved))
         if self.kv_quant:
             # int8 pool records {qp, ps} (ops/paged_kv): codes + per-block
             # scale table, built from the float pool's ABSTRACT shapes
@@ -1097,10 +1118,14 @@ class ServingEngine:
                 jax.eval_shape(mk_pool))[0].dtype).name
         # the hook's (logical) shape [L, NB, HKV, bs, hd]; the pool itself
         # is held lane-packed (:meth:`_commit_pool`)
-        self._pool_shape = tuple(paged_kv.pool_payload(
-            jax.tree_util.tree_leaves(
-                jax.eval_shape(mk_pool),
-                is_leaf=paged_kv.is_quantized_pool)[0]).shape)
+        # (of its widest leaf, K or V: a sparse-attention family's third
+        # leaf, one narrow head of indexer keys, is smaller)
+        self._pool_shape = max(
+            (tuple(paged_kv.pool_payload(leaf).shape)
+             for leaf in jax.tree_util.tree_leaves(
+                 jax.eval_shape(mk_pool),
+                 is_leaf=paged_kv.is_quantized_pool)),
+            key=lambda shape: int(np.prod(shape)))
         self._kv_scale_live: set = set()
         hkv = int(self._pool_shape[2])
         divisible = self.tp_degree > 1 and hkv % self.tp_degree == 0
@@ -1759,35 +1784,62 @@ class ServingEngine:
     def _forward(self, *args, **kwargs):
         """Traced: the model's cached forward as ``(logits, cache,
         record)`` — ``record`` the int32 ``[L, 3]`` routing record of an
-        expert family (decode hook ``routing_record``), else None."""
+        expert family (decode hook ``routing_record``; with learned sparse
+        attention the pair of that and the selections' int32 counts), else
+        None."""
         if self._routing:
             return self._fwd(*args, routing=True, **kwargs)
         return (*self._fwd(*args, **kwargs), None)
 
     @staticmethod
     def _with_record(tokens, record):
-        """Traced: the routing record rides behind the tokens in the ONE
-        int32 array a step copies back — no second transfer."""
+        """Traced: the routing record (and the selections' counts) ride
+        behind the tokens in the ONE int32 array a step copies back — no
+        second transfer."""
         if record is None:
             return tokens
-        return jnp.concatenate([tokens.reshape(-1),
-                                record.reshape(-1).astype(tokens.dtype)])
+        return jnp.concatenate(
+            [tokens.reshape(-1)] + [r.reshape(-1).astype(tokens.dtype)
+                                    for r in jax.tree_util.tree_leaves(
+                                        record)])
 
     def _split_record(self, flat, shape, span_args):
         """Host: undo :meth:`_with_record` on the copied-back array; the
         step's routing goes on its in-flight span (``experts_touched`` and
         ``expert_rows`` summed over layers, ``expert_rows_max`` the largest
-        group of any layer) and into the totals."""
+        group of any layer) and into the totals, and so do the counts of a
+        learned sparse attention, as the DEVICE made them
+        (``ops/sparse_index_attention.COUNTS``: ``index_keys`` scored,
+        ``kv_selected`` attended, ``kv_valid`` a dense read attends,
+        ``sparse_rows`` past ``topk``, ``kv_read`` K/V rows fetched; one
+        layer's worth — the program's sum over its layers, divided)."""
         if not self._routing:
             return flat
         n = int(np.prod(shape))
-        rec = flat[n:].reshape(-1, 3)
+        tail = flat[n:]
+        if self._sparse:
+            COUNTS = sparse_index_attention.COUNTS
+            layers = int(self._pool_shape[0])
+            counts = {k: int(v) // layers
+                      for k, v in zip(COUNTS, tail[-len(COUNTS):])}
+            tail = tail[:-len(COUNTS)]
+            span_args.update(counts)
+            for key, v in counts.items():
+                self._sparse_totals[key] += v
+        rec = tail.reshape(-1, 3)
         touched, rows = int(rec[:, 0].sum()), int(rec[:, 1].sum())
         span_args.update(experts_touched=touched, expert_rows=rows,
                          expert_rows_max=int(rec[:, 2].max()))
         self._c_moe_touched.inc(touched)
         self._c_moe_rows.inc(rows)
         return flat[:n].reshape(shape)
+
+    def _note_sparse(self, program: str) -> None:
+        """Trace time: which selection ``program``'s learned sparse
+        attention was built with (``stats()["sparse_attn"]``)."""
+        if self._sparse:
+            self._program_meta.setdefault("sparse_attn", {})[program] = \
+                sparse_index_attention.took()
 
     def _next_tokens(self, logits, samp):
         """The per-row token rule shared by every program body: argmax for
@@ -1906,6 +1958,7 @@ class ServingEngine:
                 logits, cache, rec = fwd(prepare(params), tokens[:, None],
                                          cache, 0, lengths=lengths,
                                          block_tables=block_tables)
+                self._note_sparse("decode")
                 return with_record(next_tokens(logits, samp), rec), \
                     constrain(cache)
 
@@ -1928,6 +1981,10 @@ class ServingEngine:
                 out0 = jnp.full((tokens.shape[0], K), -1, jnp.int32)
                 rec0 = jnp.zeros((int(self._pool_shape[0]), 3), jnp.int32) \
                     if self._routing else None
+                if self._sparse:
+                    # the selections' counts, beside the routing record
+                    rec0 = (rec0, jnp.zeros(len(self._sparse_totals),
+                                            jnp.int32))
 
                 def cond(state):
                     i, _, _, _, act, _, _ = state
@@ -1938,10 +1995,16 @@ class ServingEngine:
                     logits, cache, r = fwd(p, toks[:, None], cache, 0,
                                            lengths=lens,
                                            block_tables=block_tables)
+                    self._note_sparse("decode")
                     if r is not None:
+                        (rec, *seen), (r, *counts) = (
+                            x if isinstance(x, tuple) else (x,)
+                            for x in (rec, r))
                         rec = jnp.concatenate(
                             [rec[:, :2] + r[:, :2],
                              jnp.maximum(rec[:, 2:], r[:, 2:])], axis=1)
+                        if counts:
+                            rec = (rec, seen[0] + counts[0])
                     cache = constrain(cache)
                     if samp is None:
                         nxt = next_tokens(logits, None)
@@ -2037,6 +2100,7 @@ class ServingEngine:
                                          block_tables=block_tables)
             # which read the program was built with, noted as it is traced
             meta["prefill_attn"] = "+".join(sorted(paths))
+            self._note_sparse("prefill")
             return with_record(next_tokens(logits, pack(samp)), rec), \
                 constrain(cache)
 
@@ -2110,12 +2174,18 @@ class ServingEngine:
                 accept cap or the all-accepted bonus position — a cap
                 stop never consumed the verdict, so blending on it
                 would bias the emission (see docs/inference.md)."""
-                logits, cache = fwd(prepare(params), ids, cache, base,
-                                    lengths=valid, block_tables=block_tables,
-                                    all_positions=True)
+                # a learned sparse attention's counts ride behind the
+                # scored tokens, as behind a decode step's
+                logits, cache, *rec = (self._forward if self._sparse
+                                       else fwd)(
+                    prepare(params), ids, cache, base, lengths=valid,
+                    block_tables=block_tables, all_positions=True)
+                rec = rec[0] if rec else None
+                self._note_sparse("verify")
                 samp_t = pack(samp)
                 if samp_t is None:
-                    return jnp.argmax(logits, -1).astype(jnp.int32), cache
+                    return self._with_record(
+                        jnp.argmax(logits, -1).astype(jnp.int32), rec), cache
                 temps, topks, topps, seeds, counts, masks = samp_t
                 slots, width = ids.shape          # width == K + 1
                 flat = logits.reshape((-1, logits.shape[-1]))
@@ -2162,7 +2232,8 @@ class ServingEngine:
                 plain = jnp.where(temps[:, None] > 0, plain, scored)
                 resid = jnp.where(temps[:, None] > 0, resid,
                                   scored[:, :k])
-                return scored, accept, plain, resid, cache
+                return self._with_record(scored, rec), accept, plain, \
+                    resid, cache
 
             self._program_bodies["verify"] = verify
             self._verify_fn = jax.jit(self.sentry.wrap(verify, "verify"),
@@ -3970,7 +4041,8 @@ class ServingEngine:
         args = (params, self._cache, jnp.asarray(ids), bt_dev, len_dev,
                 jnp.asarray(valid), *samp)
         verify_fn = self._get_verify_fn()
-        with self.timeline.span("spec_verify", slots=len(dec), window=k + 1):
+        with self.timeline.span("spec_verify", slots=len(dec),
+                                window=k + 1) as span_args:
             with self._tp_ctx():
                 out = verify_fn(*args)
             if self.sampling:
@@ -3981,6 +4053,9 @@ class ServingEngine:
             else:
                 scored, self._cache = out
             scored = np.asarray(scored)
+            if self._sparse:
+                scored = self._split_record(scored, (self.slots, k + 1),
+                                            span_args)
         self._c_spec_rounds.inc()
         # a draft-model proposer caps acceptance at K-1: the K-th draft's
         # KV was never written to the draft pool, so accepting it would
@@ -4329,6 +4404,11 @@ class ServingEngine:
             # the read the prefill program was traced with (None before its
             # first call): "paged_prefill_attn" on a TPU, "gather" on a CPU
             "prefill_attn": self._program_meta.get("prefill_attn"),
+            # a learned-sparse-attention model: what each program's
+            # selection was traced with, and the totals of the spans'
+            # counters (:meth:`_split_record`); None for any other model
+            "sparse_attn": {**self._program_meta.get("sparse_attn", {}),
+                            **self._sparse_totals} if self._sparse else None,
             "admitted": self.admitted,
             "evicted": self.preempted,
             "cancelled": int(self._c_cancelled.value),

@@ -13,6 +13,7 @@ HF modules; here the model IS the TPU-optimised implementation.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Optional
 
@@ -38,10 +39,11 @@ class LlamaConfig:
     ffn_size: int = 14336
     rope_theta: float = 500000.0
     rms_eps: float = 1e-5
-    #: OLMoE-style q/k-norm: ONE RMSNorm over all ``heads * head_dim``
-    #: projected features (not per head), after the projection and before
-    #: the split into heads and RoPE
-    qk_norm: bool = False
+    #: q/k-norm, an RMSNorm of the projected queries and keys before RoPE:
+    #: ``True`` (OLMoE) is ONE norm over all ``heads * head_dim`` projected
+    #: features, before the split into heads; ``"head"`` (Qwen3) is one
+    #: ``[head_dim]`` scale applied to each head's features after the split
+    qk_norm: Any = False
     remat: bool = True
     use_flash: Optional[bool] = None
     #: ZeRO-3 liveness: gather this many layers per scan step (engine sets
@@ -49,10 +51,19 @@ class LlamaConfig:
     scan_group_size: int = 1
     #: sequence-parallel attention impl when mesh sp>1: auto|ulysses|ring
     sp_impl: str = "auto"
+    #: width of one head; ``None`` is ``hidden_size // num_heads``, read
+    #: through :attr:`head_dim` (so that ``dataclasses.replace`` of the
+    #: hidden size or the head count carries no stale width forward)
+    head_width: Optional[int] = None
+
+    def __post_init__(self):
+        if self.qk_norm not in (False, True, "head"):
+            raise ValueError(f"qk_norm={self.qk_norm!r}: False, True (one "
+                             "norm over all heads' features) or 'head'")
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.head_width or self.hidden_size // self.num_heads
 
     @staticmethod
     def llama3_8b() -> "LlamaConfig":
@@ -77,9 +88,35 @@ class LlamaConfig:
         attn = d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd + \
             self.num_heads * hd * d
         mlp = 3 * d * f
-        norms = 2 * d + (self.num_heads + self.num_kv_heads) * hd \
-            * self.qk_norm
+        norms = 2 * d + sum(qk_norm_widths(self))
         return v * d + l * (attn + mlp + norms) + d + d * v
+
+
+def qk_norm_widths(cfg: LlamaConfig):
+    """Widths of the (q_norm, k_norm) scales: none without q/k-norm, every
+    projected feature for ``True``, one head's for ``"head"``."""
+    if not cfg.qk_norm:
+        return ()
+    if cfg.qk_norm == "head":
+        return (cfg.head_dim, cfg.head_dim)
+    return (cfg.num_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim)
+
+
+def qk_normed(cfg: LlamaConfig, q, k, get):
+    """q/k-norm of the projections ``q [..., H*hd]`` / ``k [..., HKV*hd]``
+    as ``cfg.qk_norm`` says; ``get(name)`` gives the layer's scales."""
+    if not cfg.qk_norm:
+        return q, k
+    if cfg.qk_norm == "head":
+        hd = cfg.head_dim
+        return tuple(
+            rms_norm(x.reshape(x.shape[:-1] + (-1, hd)), get(name),
+                     cfg.rms_eps).reshape(x.shape)
+            for x, name in ((q, "q_norm"), (k, "k_norm")))
+    # over ALL heads' features; under GSPMD tp the mean is global (XLA
+    # reduces the sum of squares over the tp axis)
+    return (rms_norm(q, get("q_norm"), cfg.rms_eps),
+            rms_norm(k, get("k_norm"), cfg.rms_eps))
 
 
 def init_params(cfg: LlamaConfig, rng) -> PyTree:
@@ -108,9 +145,8 @@ def init_params(cfg: LlamaConfig, rng) -> PyTree:
         "final_norm": jnp.ones((d,)),
         "lm_head": normal(keys[8], (d, cfg.vocab_size)),
     }
-    if cfg.qk_norm:
-        params["blocks"]["q_norm"] = jnp.ones((l, hq))
-        params["blocks"]["k_norm"] = jnp.ones((l, hkv))
+    for name, width in zip(("q_norm", "k_norm"), qk_norm_widths(cfg)):
+        params["blocks"][name] = jnp.ones((l, width))
     return params
 
 
@@ -120,8 +156,11 @@ def rms_norm(x, scale, eps: float = 1e-5):
     return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
-def rope_angles(cfg: LlamaConfig, seq_len: int, offset: int = 0):
-    hd = cfg.head_dim
+def rope_angles(cfg: LlamaConfig, seq_len: int, offset: int = 0,
+                dim: Optional[int] = None):
+    """cos / sin ``[S, dim/2]`` (``dim``: the rotated width, a head's by
+    default)."""
+    hd = dim or cfg.head_dim
     inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, hd, 2,
                                                     dtype=jnp.float32) / hd))
     pos = jnp.arange(offset, offset + seq_len, dtype=jnp.float32)
@@ -176,10 +215,12 @@ def _attention(cfg: LlamaConfig, q, k, v):
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
-def attn_apply(cfg: LlamaConfig, layer: PyTree, x, cos, sin):
+def attn_apply(cfg: LlamaConfig, layer: PyTree, x, cos, sin, attention=None):
     """The attention half of a block (pre-norm, q/k/v, optional q/k-norm,
     RoPE, causal attention, output projection, residual) — llama's and
-    mixtral's uncached forwards share it."""
+    mixtral's uncached forwards share it.  ``attention(y, q, k, v)``
+    replaces the dense causal attention (mixtral's learned sparse one,
+    which reads the normed input ``y``)."""
     # matmuls route through gpt2._qmm: dense leaves trace to the identical
     # ``x @ w.astype`` HLO; INT8 records (quant-aware serving prefill)
     # dequantize at point of use instead of crashing on a dict leaf
@@ -190,19 +231,16 @@ def attn_apply(cfg: LlamaConfig, layer: PyTree, x, cos, sin):
 
     with jax.named_scope("layer/attn"):
         y = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-        q, k = _qmm(y, layer["q_w"]), _qmm(y, layer["k_w"])
-        if cfg.qk_norm:
-            # over ALL heads' features; under GSPMD tp the mean is global
-            # (XLA reduces the sum of squares over the tp axis)
-            q = rms_norm(q, layer["q_norm"], cfg.rms_eps)
-            k = rms_norm(k, layer["k_norm"], cfg.rms_eps)
+        q, k = qk_normed(cfg, _qmm(y, layer["q_w"]), _qmm(y, layer["k_w"]),
+                         layer.__getitem__)
         q = q.reshape(b, s, h, hd)
         k = k.reshape(b, s, hkv, hd)
         v = _qmm(y, layer["v_w"]).reshape(b, s, hkv, hd)
         q = apply_rope(q.transpose(0, 2, 1, 3), cos, sin)
         k = apply_rope(k.transpose(0, 2, 1, 3), cos, sin)
         v = v.transpose(0, 2, 1, 3)
-        attn = _attention(cfg, q, k, v)
+        attn = _attention(cfg, q, k, v) if attention is None \
+            else attention(y, q, k, v)
         attn = attn.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
         return x + _qmm(attn, layer["o_w"], x.dtype)
 
@@ -253,8 +291,9 @@ def init_cache(cfg: LlamaConfig, batch_size: int, max_len: int,
 
 def _rope_cached(cfg: LlamaConfig, x, pos):
     """Rotary embedding at traced offset ``pos`` (scalar, or int32 [B] for
-    per-sequence decode positions).  x: [B, H, T, hd]."""
-    hd = cfg.head_dim
+    per-sequence decode positions).  x: [B, H, T, hd], rotated over its
+    whole last dim."""
+    hd = x.shape[-1]
     inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, hd, 2,
                                                     dtype=jnp.float32) / hd))
     pos = jnp.asarray(pos)
@@ -269,13 +308,17 @@ def _rope_cached(cfg: LlamaConfig, x, pos):
 
 def _block_cached_body(cfg: LlamaConfig, x, get, mm, ck, cv, pos,
                        mlp=None, block_tables=None, chunk_valid=None,
-                       layer=None):
+                       layer=None, attend=None, extra=None):
     """Cached-attention block parameterized by weight access (``get(name)``
     small leaf, ``mm(y, name, dtype)`` matmul — shared by the scan and
     layer-indexed quantized decode paths, see gpt2.decode_over_layers).
     ``mlp(y) -> (y, aux)`` overrides the dense SwiGLU (mixtral's routed
     FFN; ``aux`` is its per-layer routing record) and makes this return
-    ``(x, ck, cv, aux)``.
+    ``(x, ck, cv, aux)``.  ``attend(y, q, k, v, ck, cv, extra) -> (attn, ck,
+    cv, extra)`` overrides the cache write + attention (mixtral's learned
+    sparse attention, which reads the block's normed input ``y`` and keeps
+    state of its own — a third pool leaf — in ``extra``) and makes this
+    return ``(x, ck, cv, extra, aux)``.
     ``block_tables``/``chunk_valid`` switch ck/cv to the whole paged pool,
     addressed in place at ``layer`` (contract in gpt2._cached_attention)."""
     b, t, d = x.shape
@@ -283,26 +326,28 @@ def _block_cached_body(cfg: LlamaConfig, x, get, mm, ck, cv, pos,
 
     with jax.named_scope("layer/attn"):
         y = rms_norm(x, get("attn_norm"), cfg.rms_eps)
-        q, k = mm(y, "q_w", None), mm(y, "k_w", None)
-        if cfg.qk_norm:
-            q = rms_norm(q, get("q_norm"), cfg.rms_eps)
-            k = rms_norm(k, get("k_norm"), cfg.rms_eps)
+        q, k = qk_normed(cfg, mm(y, "q_w", None), mm(y, "k_w", None), get)
         q = q.reshape(b, t, h, hd)
         k = k.reshape(b, t, hkv, hd)
         v = mm(y, "v_w", None).reshape(b, t, hkv, hd)
         q = _rope_cached(cfg, q.transpose(0, 2, 1, 3), pos)
         k = _rope_cached(cfg, k.transpose(0, 2, 1, 3), pos)
         v = v.transpose(0, 2, 1, 3)
-        from .gpt2 import _cached_attention
+        if attend is not None:
+            attn, ck, cv, extra = attend(y, q, k, v, ck, cv, extra)
+        else:
+            from .gpt2 import _cached_attention
 
-        attn, ck, cv = _cached_attention(q, k, v, ck, cv, pos, block_tables,
-                                         chunk_valid, layer)
+            attn, ck, cv = _cached_attention(q, k, v, ck, cv, pos,
+                                             block_tables, chunk_valid, layer)
         attn = attn.transpose(0, 2, 1, 3).reshape(b, t, h * hd)
         x = x + mm(attn, "o_w", x.dtype)
 
     y = rms_norm(x, get("mlp_norm"), cfg.rms_eps)
     if mlp is not None:
         out, aux = mlp(y)
+        if attend is not None:
+            return x + out, ck, cv, extra, aux
         return x + out, ck, cv, aux
     with jax.named_scope("layer/mlp"):
         gate = jax.nn.silu(mm(y, "w1", None))
@@ -312,15 +357,25 @@ def _block_cached_body(cfg: LlamaConfig, x, get, mm, ck, cv, pos,
 
 
 def _block_cached(cfg: LlamaConfig, x, layer, ck, cv, pos, mlp_fn=None,
-                  block_tables=None, chunk_valid=None, index=None):
+                  block_tables=None, chunk_valid=None, index=None,
+                  attend_fn=None):
     """``layer`` is the pre-sliced weight dict, ``index`` its position in
-    the stack (paged pools only)."""
+    the stack (paged pools only).  With ``attend_fn``, ``ck`` is the pair
+    ``(K, extra)`` the layer loop carries (:func:`forward_cached`)."""
     from .gpt2 import layer_accessors
 
-    return _block_cached_body(
-        cfg, x, *layer_accessors(layer), ck, cv, pos,
+    body = functools.partial(
+        _block_cached_body, cfg, x, *layer_accessors(layer),
         mlp=None if mlp_fn is None else (lambda y: mlp_fn(layer, y)),
         block_tables=block_tables, chunk_valid=chunk_valid, layer=index)
+    if attend_fn is None:
+        return body(ck, cv, pos)
+    x, ck, cv, extra, aux = body(
+        ck[0], cv, pos, extra=ck[1],
+        attend=lambda y, q, k, v, ck, cv, extra: attend_fn(
+            layer, y, q, k, v, ck, cv, extra, pos, block_tables, chunk_valid,
+            index))
+    return x, (ck, extra), cv, aux
 
 
 def live_tokens(input_ids, lengths=None, block_tables=None):
@@ -338,13 +393,18 @@ def live_tokens(input_ids, lengths=None, block_tables=None):
 
 def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
                    lengths=None, block_tables=None, mlp_fn=None,
-                   all_positions=False):
+                   all_positions=False, attend_fn=None, extra=None):
     """Incremental forward: logits for the LAST input position + updated
     cache — or for EVERY position when ``all_positions`` is set ([B, T, V],
     the speculative-verify head).  ``mlp_fn(layer, y) -> (y, record)``
     threads through to :func:`_block_cached` (mixtral delegates here with
     its routed FFN) and adds a third result, the per-layer records stacked
-    ``[L, ...]``.  Quantized serving (no mlp_fn) takes
+    ``[L, ...]``.  ``attend_fn(layer, y, q, k, v, ck, cv, extra, pos,
+    block_tables, chunk_valid, index) -> (attn, ck, cv, extra)`` (with
+    ``mlp_fn``) replaces the cache write + attention of every block:
+    ``extra`` is any pytree of the caller's, carried through the layers
+    beside K and V and handed back as a last result.
+    Quantized serving (no mlp_fn) takes
     the layer-indexed stacked-kernel path via gpt2.decode_over_layers.
 
     ``lengths`` (optional int32 [B]): per-sequence valid lengths for
@@ -387,14 +447,20 @@ def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
             lambda x, layer, ck, cv, l: _block_cached(
                 cfg, x, layer, ck, cv, step_pos, mlp_fn=mlp_fn,
                 block_tables=block_tables, chunk_valid=chunk_valid,
-                index=l),
-            x, params["blocks"], cache["k"], cache["v"], paged)
+                index=l, attend_fn=attend_fn),
+            x, params["blocks"],
+            cache["k"] if attend_fn is None else (cache["k"], extra),
+            cache["v"], paged)
+        if attend_fn is not None:
+            ks, extra = ks
     if not all_positions:
         x = _gather_last(x, lengths if not per_row else None)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = x @ params["lm_head"].astype(x.dtype)
     if mlp_fn is None:
         return logits, {"k": ks, "v": vs}
+    if attend_fn is not None:
+        return logits, {"k": ks, "v": vs}, records, extra
     return logits, {"k": ks, "v": vs}, records
 
 

@@ -14,11 +14,20 @@ INFERENCE forward — uncached, cached, paged — takes the dropless
 weights), so the cached and uncached forwards agree by construction.
 OLMoE (``MixtralConfig.olmoe_1b_7b``) is the same block with q/k-norm
 (``LlamaConfig.qk_norm``), 64 experts, top-8 and no renormalisation.
+Keye-VL-2.0's language model (``MixtralConfig.keye_vl2_30b_a3b``) is the
+same block at a head width of its own (``heads x head_dim != hidden_size``),
+per-head q/k-norm, 128 experts top-8 renormalised, and LEARNED SPARSE
+ATTENTION: a config with ``index_heads > 0`` gives every layer an indexer
+(``idx_q_w`` / ``idx_k_w`` / ``idx_w_w`` / ``idx_k_norm``) whose key is a
+third leaf of the cache and whose scores choose the ``index_topk`` keys a
+query attends (``ops/sparse_index_attention.py``).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 from typing import Any, Optional
 
 import jax
@@ -27,6 +36,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..moe.layer import MoEConfig, moe_apply
 from ..moe.routed import routed_ffn
+from ..ops import sparse_index_attention as sparse_attention
 from ..parallel.topology import EP_AXIS, TP_AXIS
 from ..runtime.model import ModelSpec
 from . import llama as L
@@ -46,8 +56,15 @@ class MixtralConfig(L.LlamaConfig):
     #: training capacity (tokens over it are dropped); inference is dropless
     capacity_factor: float = 1.25
     router_aux_loss_coef: float = 0.02
+    #: learned sparse attention (``ops/sparse_index_attention.py``): heads of the
+    #: indexer (0 = dense attention, no indexer), their width, and the keys
+    #: a query attends
+    index_heads: int = 0
+    index_head_dim: int = 64
+    index_topk: int = 2048
 
     def __post_init__(self):
+        super().__post_init__()
         if not 1 <= self.top_k <= self.num_experts:
             raise ValueError(f"top_k={self.top_k} outside "
                              f"[1, num_experts={self.num_experts}]")
@@ -71,6 +88,21 @@ class MixtralConfig(L.LlamaConfig):
                              router_aux_loss_coef=0.01)
 
     @staticmethod
+    def keye_vl2_30b_a3b() -> "MixtralConfig":
+        """Kwai-Keye/Keye-VL-2.0-30B-A3B's language model (text positions;
+        the vision tower is not built): 48 layers, d 2048, 32 query / 4 KV
+        heads x 128 with per-head q/k-norm, 128 SwiGLU experts of width 768,
+        top-8 renormalised, and an indexer of 16 heads x 64 choosing 2,048
+        keys a query."""
+        return MixtralConfig(vocab_size=151936, max_seq_len=262144,
+                             num_layers=48, num_heads=32, num_kv_heads=4,
+                             head_width=128, hidden_size=2048, ffn_size=768,
+                             rope_theta=1e7, rms_eps=1e-6, qk_norm="head",
+                             num_experts=128, top_k=8, norm_topk_prob=True,
+                             router_aux_loss_coef=0.001, index_heads=16,
+                             index_head_dim=64, index_topk=2048)
+
+    @staticmethod
     def tiny(vocab_size: int = 512) -> "MixtralConfig":
         return MixtralConfig(vocab_size=vocab_size, max_seq_len=128,
                              num_layers=2, num_heads=4, num_kv_heads=2,
@@ -82,8 +114,14 @@ class MixtralConfig(L.LlamaConfig):
         d, f = self.hidden_size, self.ffn_size
         # swap the dense MLP for E experts + router
         per_layer_mlp = 3 * d * f
+        # the indexer: its queries, its key, its head weights, the key's
+        # LayerNorm (scale and bias)
+        di = self.index_head_dim
+        indexer = (d * (self.index_heads * di + di + self.index_heads)
+                   + 2 * di) if self.index_heads else 0
         return base + self.num_layers * (
-            (self.num_experts - 1) * per_layer_mlp + d * self.num_experts)
+            (self.num_experts - 1) * per_layer_mlp + d * self.num_experts
+            + indexer)
 
     def active_params(self) -> int:
         """Parameters one token multiplies with: everything but the
@@ -117,12 +155,47 @@ def init_params(cfg: MixtralConfig, rng) -> PyTree:
     blocks["experts_w1"] = normal(keys[1], (l, e, d, f))
     blocks["experts_w3"] = normal(keys[2], (l, e, d, f))
     blocks["experts_w2"] = normal(keys[3], (l, e, f, d))
+    if cfg.index_heads:
+        hi, di = cfg.index_heads, cfg.index_head_dim
+        ikeys = jax.random.split(jax.random.fold_in(rng, 11), 3)
+        blocks["idx_q_w"] = normal(ikeys[0], (l, d, hi * di))
+        blocks["idx_k_w"] = normal(ikeys[1], (l, d, di))
+        blocks["idx_w_w"] = normal(ikeys[2], (l, d, hi))
+        # the key's LayerNorm: scale row, bias row
+        blocks["idx_k_norm"] = jnp.stack(
+            [jnp.ones((l, di)), jnp.zeros((l, di))], axis=1)
     return params
+
+
+def _indexer(cfg: MixtralConfig, get, mm, y, rope):
+    """The indexer's projections of the normed block input ``y [B, T, d]``:
+    queries ``[B, HI, T, DI]`` and the one key head ``[B, 1, T, DI]``
+    (LayerNorm, then ``rope`` over the whole width) in ``y``'s dtype, head
+    weights float32 ``[B, T, HI]``."""
+    b, t, _ = y.shape
+    hi, di = cfg.index_heads, cfg.index_head_dim
+    qi = mm(y, "idx_q_w", None).reshape(b, t, hi, di).transpose(0, 2, 1, 3)
+    ki = sparse_attention.layer_norm(mm(y, "idx_k_w", None),
+                                     get("idx_k_norm"))[:, None]
+    wi = mm(y, "idx_w_w", None).astype(jnp.float32)
+    return rope(qi), rope(ki), wi
 
 
 def _moe_block(cfg: MixtralConfig, layer: PyTree, x, cos, sin, train: bool = True):
     """Llama attention + MoE FFN; returns (x, aux_loss)."""
-    x = L.attn_apply(cfg, layer, x, cos, sin)
+    attention = None
+    if cfg.index_heads:
+        from .gpt2 import layer_accessors
+
+        icos, isin = L.rope_angles(cfg, x.shape[1], dim=cfg.index_head_dim)
+
+        def attention(y, q, k, v):
+            qi, ki, wi = _indexer(cfg, *layer_accessors(layer), y,
+                                  lambda a: L.apply_rope(a, icos, isin))
+            return sparse_attention.sparse_attention_uncached(
+                q, k, v, qi, wi, ki[:, 0], cfg.index_topk)
+
+    x = L.attn_apply(cfg, layer, x, cos, sin, attention)
     y = L.rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
     if train:
         moe_out, aux = _moe_ffn(cfg, layer, y)
@@ -203,22 +276,65 @@ def _expert_kernel(blocks) -> bool:
                                    for k in _EXPERT_LEAVES)
 
 
-def _routed(cfg: MixtralConfig, layer, y, live=None, stacks=None):
-    """Inference FFN: dropless routing -> (y, routing record [3]).
+def _routed(cfg: MixtralConfig, layer, y, live=None, stacks=None,
+            choices: bool = False):
+    """Inference FFN: dropless routing -> (y, routing record [3]); with
+    ``choices``, (y, (record, the tokens' experts ``[..., top_k]``)).
     ``stacks``: the whole ``[L, E, ..]`` expert leaves, read in place at
     ``layer["layer_index"]`` by the grouped-matmul kernel; without them
     ``layer`` holds its own ``[E, ..]`` slices."""
     whole = stacks is not None
     w1, w3, w2 = ((stacks if whole else layer)[k] for k in _EXPERT_LEAVES)
-    return routed_ffn(y, layer["gate_w"], w1, w3, w2, cfg.top_k,
-                      cfg.norm_topk_prob, live=live,
-                      layer=layer["layer_index"] if whole else None,
-                      kernel=whole or _expert_kernel(layer))
+    out, *record = routed_ffn(
+        y, layer["gate_w"], w1, w3, w2, cfg.top_k, cfg.norm_topk_prob,
+        live=live, layer=layer["layer_index"] if whole else None,
+        kernel=whole or _expert_kernel(layer), choices=choices)
+    return out, (tuple(record) if choices else record[0])
+
+
+def init_cache(cfg: MixtralConfig, batch_size: int, max_len: int,
+               dtype=jnp.bfloat16):
+    """llama's K and V — plus, for a config with an indexer, its key: a
+    third leaf ``[L, B, 1, S, index_head_dim]`` of the same layout (the
+    block-paged pool's ``[L, NB, 1, block_size, DI]``)."""
+    cache = L.init_cache(cfg, batch_size, max_len, dtype)
+    if cfg.index_heads:
+        cache["idx"] = jnp.zeros((cfg.num_layers, batch_size, 1, max_len,
+                                  cfg.index_head_dim), dtype)
+    return cache
+
+
+def _sparse_attend(cfg: MixtralConfig, layer, y, q, k, v, ck, cv, extra, pos,
+                   block_tables, chunk_valid, index):
+    """``llama.forward_cached``'s ``attend_fn`` for a config with an
+    indexer.  ``extra`` is what the layers carry beside K and V: ``"idx"``
+    the indexer-key pool, ``"counts"`` what the selections did so far
+    (``sparse_index_attention.COUNTS``, summed over the layers) and, asked
+    for, ``"keys"``, the buffer of every layer's chosen keys.  The window's
+    K, V and indexer keys go to the same ``(layer, block, offset)``, then
+    the read selects (``ops/sparse_index_attention.py``)."""
+    from ..ops import paged_kv
+    from .gpt2 import layer_accessors
+
+    qi, ki, wi = _indexer(cfg, *layer_accessors(layer), y,
+                          lambda a: L._rope_cached(cfg, a, pos))
+    ck, cv = paged_kv.paged_cache_update(
+        ck, cv, k, v, pos, block_tables, valid=chunk_valid, layer=index)
+    idx_pool = paged_kv.paged_window_update(
+        extra["idx"], ki, pos, block_tables, valid=chunk_valid, layer=index)
+    attn, counts, *keep = sparse_attention.paged_sparse_attention(
+        q, ck, cv, idx_pool, qi, wi, block_tables, pos,
+        topk=cfg.index_topk, layer=index, valid=chunk_valid,
+        return_keep="keys" in extra)
+    extra = {**extra, "idx": idx_pool, "counts": extra["counts"] + counts}
+    if keep:
+        extra["keys"] = extra["keys"].at[index].set(keep[0])
+    return attn, ck, cv, extra
 
 
 def forward_cached(cfg: MixtralConfig, params, input_ids, cache, pos,
                    lengths=None, block_tables=None, all_positions=False,
-                   routing: bool = False):
+                   routing: bool = False, choices: bool = False):
     """Incremental MoE forward (reference ``moe_inference.py``: expert
     routing runs per decode token too) — llama's cached path with the
     routed FFN hooked in.  ``lengths`` (per-sequence positions for
@@ -228,7 +344,15 @@ def forward_cached(cfg: MixtralConfig, params, input_ids, cache, pos,
     layout-independent.  ``routing`` adds a third result: int32
     ``[L, 3]``, per layer the experts with at least one row, the routed
     rows and the largest group (``moe/routed.py RECORD``), counted over the
-    live tokens (``llama.live_tokens``)."""
+    live tokens (``llama.live_tokens``) — with an indexer the pair of that
+    and int32 ``[5]``, what the layers' selections scored, chose and read
+    (``sparse_index_attention.COUNTS``, summed over the layers).
+    ``choices`` (paged caches) adds
+    a last result, the discrete choices of every layer: ``{"experts": int32
+    [L, B, T, top_k]}`` and, with an indexer, ``"keys": bool [L, B, T,
+    max_seq_len]`` (the keys each query attended) — what a comparison with
+    a plain reference hands that reference, so that near-ties the two sides
+    break differently do not count as a difference."""
     live = L.live_tokens(input_ids, lengths, block_tables)
     blocks, stacks = params["blocks"], None
     if _expert_kernel(blocks):
@@ -239,12 +363,39 @@ def forward_cached(cfg: MixtralConfig, params, input_ids, cache, pos,
         blocks = {k: v for k, v in blocks.items() if k not in stacks}
         blocks["layer_index"] = jnp.arange(cfg.num_layers, dtype=jnp.int32)
         params = {**params, "blocks": blocks}
-    logits, cache, records = L.forward_cached(
-        cfg, params, input_ids, cache, pos, lengths=lengths,
-        block_tables=block_tables,
-        mlp_fn=lambda lyr, y: _routed(cfg, lyr, y, live, stacks),
-        all_positions=all_positions)
-    return (logits, cache, records) if routing else (logits, cache)
+    attend_fn = extra = None
+    if cfg.index_heads:
+        if block_tables is None:
+            raise NotImplementedError(
+                "learned sparse attention (index_heads > 0) is served "
+                "through the block-paged pool (init_serving / "
+                "ServingEngine); the contiguous cache of "
+                "InferenceEngine.generate has no indexer-key leaf")
+        # what the layers carry beside K and V (:func:`_sparse_attend`)
+        extra = {"idx": cache["idx"],
+                 "counts": jnp.zeros(len(sparse_attention.COUNTS), jnp.int32)}
+        if choices:
+            s_max = block_tables.shape[1] * (
+                math.prod(cache["k"].shape[3:]) // cfg.head_dim)
+            extra["keys"] = jnp.zeros(
+                (cfg.num_layers,) + input_ids.shape + (s_max,), bool)
+        attend_fn = functools.partial(_sparse_attend, cfg)
+    logits, kv, records, *carried = L.forward_cached(
+        cfg, params, input_ids, {"k": cache["k"], "v": cache["v"]}, pos,
+        lengths=lengths, block_tables=block_tables,
+        mlp_fn=lambda lyr, y: _routed(cfg, lyr, y, live, stacks, choices),
+        all_positions=all_positions, attend_fn=attend_fn, extra=extra)
+    chosen = {}
+    if choices:
+        records, chosen["experts"] = records
+    if carried:
+        extra, = carried
+        kv["idx"] = extra["idx"]
+        records = (records, extra["counts"])
+        if choices:
+            chosen["keys"] = extra["keys"]
+    out = (logits, kv, records) if routing else (logits, kv)
+    return out + (chosen,) if choices else out
 
 
 def tp_rules(cfg: MixtralConfig, abstract_params: PyTree) -> PyTree:
@@ -256,6 +407,10 @@ def tp_rules(cfg: MixtralConfig, abstract_params: PyTree) -> PyTree:
     blocks["experts_w1"] = P(None, EP_AXIS, None, TP_AXIS)
     blocks["experts_w3"] = P(None, EP_AXIS, None, TP_AXIS)
     blocks["experts_w2"] = P(None, EP_AXIS, TP_AXIS, None)
+    if cfg.index_heads:
+        # the indexer is small and its one key head has nothing to split
+        for k in ("idx_q_w", "idx_k_w", "idx_w_w", "idx_k_norm"):
+            blocks[k] = P()
     return rules
 
 
@@ -273,12 +428,13 @@ def build(cfg: Optional[MixtralConfig] = None, **overrides) -> ModelSpec:
         return forward_with_aux(cfg, params, ids, train=False)[0]
 
     decode_hooks = {
-        "init_cache": lambda b, s, dtype=jnp.bfloat16: L.init_cache(
+        "init_cache": lambda b, s, dtype=jnp.bfloat16: init_cache(
             cfg, b, s, dtype),
         "forward_cached": lambda params, ids, cache, pos, lengths=None,
-            block_tables=None, all_positions=False, routing=False:
+            block_tables=None, all_positions=False, routing=False,
+            choices=False:
             forward_cached(cfg, params, ids, cache, pos, lengths,
-                           block_tables, all_positions, routing),
+                           block_tables, all_positions, routing, choices),
         # ``forward_cached(..., routing=True)`` returns the per-layer
         # routing record as a third result (the serving engine's ring)
         "routing_record": True,
@@ -288,11 +444,17 @@ def build(cfg: Optional[MixtralConfig] = None, **overrides) -> ModelSpec:
         "supports_verify": True,
         # the MoE path reads the pool only through the shared llama cached
         # attention (ops/paged_kv), so int8 records pass through untouched
+        # (an indexer's selection reads a float pool: the engine refuses
+        # that pair by name)
         "supports_kv_quant": True,
         # raw next-token logits reach the serving engine's on-device
         # sampler unchanged (per-slot temperature/top-k/top-p)
         "supports_sampling": True,
     }
+    if cfg.index_heads:
+        # learned sparse attention: the cache has a third leaf, and a row
+        # past ``topk`` keys reads ``topk`` of them (the engine's counters)
+        decode_hooks["sparse_attention"] = {"topk": cfg.index_topk}
 
     return ModelSpec(
         init_fn=init_fn, model_config=cfg, loss_fn=loss_fn, apply_fn=apply_fn,

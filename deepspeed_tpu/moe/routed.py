@@ -71,8 +71,8 @@ def _grouped(xs, w, group_sizes, layer, kernel: bool):
 
 
 def routed_ffn(y, gate_w, w1, w3, w2, k: int, renormalize: bool,
-               live=None, layer=None, kernel: bool = True
-               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+               live=None, layer=None, kernel: bool = True,
+               choices: bool = False) -> Tuple[jnp.ndarray, ...]:
     """SwiGLU experts over ``y [..., D]``: ``gate_w [D, E]``, ``w1``/``w3``
     ``[E, D, F]``, ``w2 [E, F, D]`` — or, with ``layer`` (traced index),
     the whole stacks ``[L, E, ..]``.  No capacity, no drop.  ``kernel``
@@ -82,7 +82,9 @@ def routed_ffn(y, gate_w, w1, w3, w2, k: int, renormalize: bool,
     (``RECORD``): experts with at least one row, routed rows, the largest
     group — counted over the ``live`` tokens only (bool, ``y``'s leading
     shape; padding rows and idle slots are computed like any row but are
-    nobody's traffic).  ``live=None`` counts every token."""
+    nobody's traffic).  ``live=None`` counts every token.  ``choices`` adds
+    a third result, the experts each token was routed to: int32 ``[..., k]``
+    (what a comparison with a reference is teacher-forced with)."""
     shape, d = y.shape, y.shape[-1]
     x = y.reshape(-1, d)
     t, e = x.shape[0], gate_w.shape[-1]
@@ -117,4 +119,6 @@ def routed_ffn(y, gate_w, w1, w3, w2, k: int, renormalize: bool,
             counts = (hits & alive[:, None]).sum(0, dtype=jnp.int32)
         record = jnp.stack([(counts > 0).sum(dtype=jnp.int32),
                             counts.sum(dtype=jnp.int32), counts.max()])
+    if choices:
+        return mixed.reshape(shape), record, top_e.reshape(shape[:-1] + (k,))
     return mixed.reshape(shape), record

@@ -970,6 +970,445 @@ def paged_prefill_attention_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
                                    layer, valid)
 
 
+# ---------------------------------------------------------------------------
+# Learned sparse attention (``ops/sparse_index_attention.py``): the indexer's
+# scores over a row's valid keys, read in place out of the third pool leaf,
+# the exact top-k of them without a sort, and the attention over the chosen
+# keys (``paged_index_scores``, ``paged_sparse_select``, ``paged_sparse_attn``:
+# the trace readers sum every kernel named ``paged_index_*`` / ``paged_sparse_*``
+# as the mechanism's time).
+# ---------------------------------------------------------------------------
+#: blocks of indexer keys one landing tile of the scoring kernel takes
+_INDEX_TILE_BLOCKS = 32
+#: queries one grid step of the scoring kernel scores (its output block is
+#: ``[queries, max_seq_len]`` float32)
+_INDEX_QUERY_TILE = 32
+
+
+def _index_scores_kernel(layer_ref, n_ref, bt_ref, q_ref, w_ref, last_ref,
+                         pool, o_ref, buf, sem, *, heads: int):
+    """The indexer's scores of ``tq`` queries of one row.  Grid ``(B, T //
+    tq)``.
+
+    ``layer_ref`` int32 [1], ``n_ref`` int32 [B] (the row's valid blocks)
+    and ``bt_ref`` int32 [B, NBPER] arrive via scalar prefetch; the indexer's
+    key pool ``[L, NB, 1, r, width]`` (one key head, ``g = width // DI``
+    token spans a row) stays in HBM.  The row walks its ``n`` valid blocks
+    ``nt`` a tile, one DMA a block, tile ``i + 1`` landing while tile ``i``
+    is scored: ``q_ref`` [1, 1, g, heads * tq, width] holds each query head
+    once per span (row ``h * tq + t``, its DI values in the span's lane
+    group), so one ``q . k^T`` gives a span's ``[heads * tq, nt * r]`` dot
+    products; ReLU, times ``w_ref`` [1, 1, heads * tq, 128] (the head's
+    weight for that query, lane-replicated, float32), summed over the heads
+    — ``heads`` slabs of ``tq`` rows.  ``o_ref`` [1, ntiles, g, tq, nt * r]
+    float32: column ``j * r + x`` of tile ``i``, span ``s`` is token
+    ``(i * nt + j) * bs + s * r + x``; a key past ``last_ref`` [1, 1, tq,
+    128] (per query, lane-replicated) scores ``-inf`` — which also hides
+    the slots of a last, partly landed tile: a column's score depends on
+    its own key alone — and so does every tile the walk never reached."""
+    _, nt, r, width = buf.shape
+    g = q_ref.shape[2]
+    tq = o_ref.shape[3]
+    bs, cols = r * g, nt * r
+    b, layer = pl.program_id(0), layer_ref[0]
+    n = jnp.clip(n_ref[b], 0, bt_ref.shape[1])
+    ntiles = (n + nt - 1) // nt
+    w = _lanes(w_ref[0, 0], cols)
+    last = _lanes(last_ref[0, 0], cols)
+
+    def each_block(i, slot, act):
+        def one(j, carry):
+            act(pltpu.make_async_copy(
+                pool.at[layer if pool.shape[0] > 1 else 0,
+                        bt_ref[b, i * nt + j], 0],
+                buf.at[slot, j], sem.at[slot]))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(n - i * nt, 0, nt), one, None)
+
+    def tile(i, carry):
+        slot = i % 2
+        each_block(i + 1, 1 - slot, lambda copy: copy.start())
+        each_block(i, slot, lambda copy: copy.wait())
+        k = buf[slot].reshape(cols, width)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+        # span 0's token of each column; span s's is s * r further
+        token = i * (nt * bs) + col + col // r * (bs - r)
+        for span in range(g):
+            dots = jax.lax.dot_general(
+                q_ref[0, 0, span], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dots = jnp.maximum(dots, 0.0) * w
+            score = dots[:tq]
+            for h in range(1, heads):
+                score = score + dots[h * tq:(h + 1) * tq]
+            o_ref[0, i, span] = jnp.where(token + span * r <= last, score,
+                                          -jnp.inf)
+        return carry
+
+    o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, o_ref.dtype)
+    each_block(0, 0, lambda copy: copy.start())
+    jax.lax.fori_loop(0, ntiles, tile, None)
+
+
+def paged_index_scores_pallas(qi, wi, idx_pool, block_tables, last, *,
+                              layer, interpret: Optional[bool] = None):
+    """``I[b, t, s] = sum_h wi[b, t, h] * relu(qi[b, h, t] . kI[b, s])`` in
+    float32 for every key of each row's table, ``-inf`` past ``last[b, t]``
+    (int32 ``[B, T]``: the last key the query may see): ``[B, T,
+    max_seq_len]``.  ``qi [B, HI, T, DI]``, ``wi [B, T, HI]``; ``idx_pool``
+    the stacked float pool leaf of the indexer's keys ``[L, NB, 1, bs, DI]``
+    (either view) at ``layer``, read in place: each row walks its own
+    ``cdiv(max last + 1, bs)`` blocks (:func:`_index_scores_kernel`, as
+    ``paged_index_scores``)."""
+    b, hi, t, di = qi.shape
+    if interpret is None:
+        interpret = interpret_kernels()
+    _, nb, _, r_in, w_in = idx_pool.shape
+    pool = _lane_rows(idx_pool, layer, di)
+    r, width = pool.shape[3:]
+    bs = r_in * w_in // di
+    g = bs // r
+    nbper = block_tables.shape[1]
+    nt = min(_INDEX_TILE_BLOCKS, nbper)
+    while nbper % nt:
+        nt -= 1
+    ntiles, cols = nbper // nt, nt * r
+    tq = t if t <= _INDEX_QUERY_TILE or t % _INDEX_QUERY_TILE \
+        else _INDEX_QUERY_TILE
+    tt = t // tq
+    # queries once per span, head-major rows within a query tile
+    qx = qi.reshape(b, hi, tt, tq, di).transpose(0, 2, 1, 3, 4) \
+        .reshape(b, tt, 1, hi * tq, di).astype(pool.dtype)
+    qx = jnp.concatenate(
+        [jnp.pad(qx, ((0, 0),) * 4 + ((s * di, width - (s + 1) * di),))
+         for s in range(g)], axis=2)                   # [B, tt, g, hi*tq, W]
+    wx = jnp.broadcast_to(
+        wi.astype(jnp.float32).reshape(b, tt, tq, hi).transpose(0, 1, 3, 2)
+        .reshape(b, tt, hi * tq, 1), (b, tt, hi * tq, LANES))
+    lx = jnp.broadcast_to(last.astype(jnp.int32).reshape(b, tt, tq, 1),
+                          (b, tt, tq, LANES))
+    n = jnp.clip((jnp.max(last, axis=1) + bs) // bs, 0, nbper)
+    bt = jnp.clip(jnp.asarray(block_tables, jnp.int32), 0, nb - 1)
+
+    def block(shape):
+        return pl.BlockSpec((1, 1) + shape,
+                            lambda i, j, *prefetched: (i, j) + (0,) * len(
+                                shape))
+
+    out = pl.pallas_call(
+        functools.partial(_index_scores_kernel, heads=hi),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,                 # layer, blocks, table
+            grid=(b, tt),
+            in_specs=[block((g, hi * tq, width)), block((hi * tq, LANES)),
+                      block((tq, LANES)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(
+                (1, ntiles, g, tq, cols),
+                lambda i, j, *prefetched: (i, 0, 0, j, 0)),
+            scratch_shapes=[pltpu.VMEM((2, nt, r, width), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((b, ntiles, g, t, cols),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret, name="paged_index_scores",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), n.astype(jnp.int32), bt,
+      qx, wx, lx, pool)
+    # [B, tile, span, T, (block, row)] -> token order
+    return out.reshape(b, ntiles, g, t, nt, r).transpose(0, 3, 1, 4, 2, 5) \
+        .reshape(b, t, nbper * bs)
+
+
+_INT_MIN = -(2 ** 31)
+
+
+def _sparse_select_kernel(s_ref, theta_ref, last_ref, *, topk: int,
+                          bits: int):
+    """The exact top-``topk`` of each row of ``s_ref`` [tn, S] float32, as
+    a threshold: ``theta_ref`` the ``topk``-th largest value and
+    ``last_ref`` the position of the last entry a stable largest-first
+    order takes (both [tn, 128], lane-replicated).  No sort: float32 bits
+    map to int32 keys of the same order, the threshold key is built bit by
+    bit from the top (a bit stays set while at least ``topk`` keys reach the
+    candidate: 32 counts), and among the keys equal to it the
+    ``topk - count(greater)``-th position, likewise (``bits`` counts)."""
+    x = s_ref[...]
+    x = jnp.where(x == 0.0, 0.0, x)                   # -0.0 ties with 0.0
+    u = jax.lax.bitcast_convert_type(x, jnp.int32)
+    key = jnp.where(u < 0, u ^ jnp.int32(0x7FFFFFFF), u)
+    pos = jax.lax.broadcasted_iota(jnp.int32, key.shape, 1)
+    want = jnp.float32(topk)
+
+    def count(mask):
+        return jnp.sum(mask.astype(jnp.float32), axis=-1, keepdims=True)
+
+    t = jnp.where(count(key >= 0) >= want, jnp.int32(0), jnp.int32(_INT_MIN))
+
+    def value_bit(i, t):
+        cand = t | jnp.left_shift(jnp.int32(1), 30 - i)
+        return jnp.where(count(key >= cand) >= want, cand, t)
+
+    t = jax.lax.fori_loop(0, 31, value_bit, t)
+    need = want - count(key > t)                      # of the ties, >= 1
+    tie = key == t
+
+    def position_bit(i, p):
+        cand = p | jnp.left_shift(jnp.int32(1), bits - 1 - i)
+        return jnp.where(count(tie & (pos < cand)) < need, cand, p)
+
+    p = jax.lax.fori_loop(0, bits, position_bit, jnp.zeros_like(t))
+    theta = jax.lax.bitcast_convert_type(
+        jnp.where(t < 0, t ^ jnp.int32(0x7FFFFFFF), t), jnp.float32)
+    theta_ref[...] = jnp.broadcast_to(theta, theta_ref.shape)
+    last_ref[...] = jnp.broadcast_to(p, last_ref.shape)
+
+
+def paged_sparse_select_pallas(scores, topk: int, *,
+                               interpret: Optional[bool] = None):
+    """``(theta, s_last)`` of float32 ``scores [..., S]`` (``S >= topk``):
+    the ``topk``-th largest value of each row and the position of the last
+    entry taken when equal values go by position, so that entry ``s`` is
+    among the row's top ``topk`` iff ``score > theta or (score == theta and
+    s <= s_last)`` — what ``lax.top_k`` gives as its last value and index
+    (:func:`_sparse_select_kernel`, as ``paged_sparse_select``)."""
+    *lead, s = scores.shape
+    assert s >= topk, (s, topk)
+    if interpret is None:
+        interpret = interpret_kernels()
+    flat = scores.reshape(-1, s).astype(jnp.float32)
+    n = flat.shape[0]
+    tn = next((t for t in (16, 8) if n % t == 0), n)
+    theta, last = pl.pallas_call(
+        functools.partial(_sparse_select_kernel, topk=topk,
+                          bits=max(1, (s - 1).bit_length())),
+        grid=(n // tn,),
+        in_specs=[pl.BlockSpec((tn, s), lambda i: (i, 0))],
+        out_specs=[pl.BlockSpec((tn, LANES), lambda i: (i, 0))] * 2,
+        out_shape=[jax.ShapeDtypeStruct((n, LANES), jnp.float32),
+                   jax.ShapeDtypeStruct((n, LANES), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret, name="paged_sparse_select",
+    )(flat)
+    return theta[:, 0].reshape(lead), last[:, 0].reshape(lead)
+
+
+#: keys one softmax update of the sparse read takes (whole blocks).  One
+#: layer's read alone on the chip, dispatch (~0.9 ms) included, at 256 / 512
+#: / 1,024 keys: 1.43 / 1.25 / 1.14 ms for 16 decode rows of ~7,700 keys,
+#: 1.76 / 1.53 / 1.42 ms for a [4, 128] chunk (PERF.md PR 32)
+_SPARSE_COLS = 512
+#: (KV head, query slab) pairs the sparse read attends in one iteration of
+#: its loop (:data:`_PREFILL_HEAD_UNROLL` has why)
+_SPARSE_UNROLL = 4
+
+
+def _paged_sparse_kernel(layer_ref, n_ref, bt_ref, hit_ref, q_ref, theta_ref,
+                         slast_ref, last_ref, scores, k_pool, v_pool, o_ref,
+                         kbuf, vbuf, sbuf, sem, m_scr, l_scr, acc_scr, *,
+                         sm_scale: float, t: int):
+    """Attention of one row's ``t`` queries over the keys each has CHOSEN
+    (learned sparse attention), for ``ht`` KV heads.  Grid ``(B, HKV //
+    ht)``.
+
+    ``layer_ref`` int32 [1], ``n_ref`` int32 [B] (the row's valid blocks),
+    ``bt_ref`` int32 [B, NBPER] and ``hit_ref`` int32 [B, NBPER] (whether
+    any query of the row chose a key of the block) arrive via scalar
+    prefetch; the K and V pools ``[L, NB, HKV, bs, D]`` and the indexer's
+    ``scores`` [B, t, NBPER * bs] float32 stay in HBM.  The row walks its
+    ``n`` valid blocks ``nt`` a tile and copies, of each tile, the blocks
+    with a hit (one DMA of all ``ht`` heads a block, K and V) and the
+    tile's ``[t, nt * bs]`` slab of scores; tile ``i + 1`` lands while tile
+    ``i`` is attended.  A block no query chose is neither read nor — where
+    the whole tile has none — attended: the K/V bytes read are those of
+    the blocks that hold a chosen key.
+
+    The set is rebuilt from the scores, a tile at a time: query ``i``
+    keeps key ``s`` iff ``s <= last[i]`` and ``score > theta[i]`` or
+    ``score == theta[i]`` and ``s <= s_last[i]`` (``theta_ref`` /
+    ``slast_ref`` / ``last_ref`` [1, t, 128], lane-replicated:
+    ``paged_sparse_select``'s threshold and the last key the query may
+    see), which is ``sparse_index_attention.chosen``.  A slot of the landing
+    buffers that was not copied holds an earlier block or the zeros of the
+    start: every key of it is masked.
+
+    ``q_ref`` / ``o_ref`` [1, ht, rows, D], ``rows = rep * t``, row ``r * t
+    + i`` head ``r`` of the KV group at query ``i``: with ``t`` a multiple
+    of 8 a head's rows are attended ``t`` at a time (a slab: one
+    ``[t, nt * bs]`` mask serves each), a single query (``t == 1``) all
+    ``rep`` at once under the one mask row.  Online softmax in float32, m
+    and l lane-replicated, the probabilities to the MXU in the pool's
+    dtype."""
+    _, nt, ht, bs, d = kbuf.shape
+    rows = o_ref.shape[2]
+    cols = nt * bs
+    slabs = rows // t if t > 1 else 1
+    slab = rows // slabs
+    b, layer = pl.program_id(0), layer_ref[0]
+    n = jnp.clip(n_ref[b], 0, bt_ref.shape[1])
+    ntiles = (n + nt - 1) // nt
+    whole = k_pool.shape[2] == ht
+    heads = pl.ds(pl.program_id(1) * ht, ht)
+    unroll = math.gcd(ht * slabs, _SPARSE_UNROLL)
+    theta = _lanes(theta_ref[0], cols)
+    slast = _lanes(slast_ref[0], cols)
+    last = _lanes(last_ref[0], cols)
+
+    def each_copy(i, slot, act):
+        """``act`` on tile ``i``'s copies: its blocks with a hit, its
+        scores."""
+        def one(j, hits):
+            hit = hit_ref[b, i * nt + j]
+
+            @pl.when(hit > 0)
+            def _block():
+                for op, (pool, buf) in enumerate(((k_pool, kbuf),
+                                                  (v_pool, vbuf))):
+                    src = (layer, bt_ref[b, i * nt + j])
+                    act(pltpu.make_async_copy(
+                        pool.at[src if whole else src + (heads,)],
+                        buf.at[slot, j], sem.at[slot, op]))
+            return hits + hit
+
+        hits = jax.lax.fori_loop(0, jnp.clip(n - i * nt, 0, nt), one,
+                                 jnp.int32(0))
+
+        @pl.when(i < ntiles)
+        def _scores():
+            act(pltpu.make_async_copy(
+                scores.at[b, :, pl.ds(pl.multiple_of(i * cols, cols), cols)],
+                sbuf.at[slot], sem.at[slot, 2]))
+        return hits
+
+    def tile(i, carry):
+        slot = i % 2
+        each_copy(i + 1, 1 - slot, lambda copy: copy.start())
+        hits = each_copy(i, slot, lambda copy: copy.wait())
+
+        @pl.when(hits > 0)
+        def _attend():
+            sc = sbuf[slot]
+            key = i * cols + jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+            keep = (key <= last) & ((sc > theta)
+                                    | ((sc == theta) & (key <= slast)))
+
+            def one(idx):
+                h = idx // slabs
+                at = pl.ds(pl.multiple_of(idx % slabs * slab, slab), slab) \
+                    if slabs > 1 else slice(None)
+                k = kbuf[slot, :, h].reshape(cols, d)
+                v = vbuf[slot, :, h].reshape(cols, d)
+                s = jax.lax.dot_general(
+                    q_ref[0, h, at].astype(k.dtype), k,
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * sm_scale
+                s = jnp.where(keep, s, NEG_INF)
+                m_prev = m_scr[h, at]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                # a query with no key kept so far has m = NEG_INF, where
+                # exp(s - m) is 1: the mask, not the exponent, zeroes it
+                p = jnp.where(keep, jnp.exp(s - _lanes(m_new, cols)), 0.0)
+                acc_scr[h, at] = acc_scr[h, at] * _lanes(alpha, d) \
+                    + jax.lax.dot_general(
+                        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                m_scr[h, at] = m_new
+                l_scr[h, at] = l_scr[h, at] * alpha \
+                    + jnp.sum(p, axis=-1, keepdims=True)
+
+            def some(u, carry):
+                for x in range(unroll):
+                    one(u * unroll + x)
+                return carry
+
+            jax.lax.fori_loop(0, ht * slabs // unroll, some, None)
+        return carry
+
+    def finish(h, carry):
+        l = l_scr[h][:, :1]
+        o_ref[0, h] = (acc_scr[h] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
+        return carry
+
+    _start_chunks(m_scr, l_scr, acc_scr)
+    kbuf[...] = jnp.zeros_like(kbuf)
+    vbuf[...] = jnp.zeros_like(vbuf)
+    each_copy(0, 0, lambda copy: copy.start())
+    jax.lax.fori_loop(0, ntiles, tile, None)
+    jax.lax.fori_loop(0, ht, finish, None)
+
+
+def paged_sparse_attention_pallas(q, k_pool, v_pool, block_tables, scores,
+                                  theta, s_last, last, hit, *, layer,
+                                  sm_scale: Optional[float] = None,
+                                  interpret: Optional[bool] = None):
+    """The read of learned sparse attention: ``q [B, H, T, D]`` (``T`` 1 or
+    a multiple of 8) over the keys each query chose, out of the stacked
+    float pool ``[L, NB, HKV, bs, D]`` at ``layer`` (a head a lane row: ``D``
+    a multiple of 128), read in place.  ``scores [B, T, NBPER * bs]``
+    float32 (``paged_index_scores``), ``theta`` / ``s_last [B, T]``
+    (``paged_sparse_select``) and ``last [B, T]`` (the last key a query may
+    see; -1: a pad row, which comes back zeros) say which keys; ``hit``
+    int32 ``[B, NBPER]`` which blocks hold one — the others are not read
+    (:func:`_paged_sparse_kernel`, as ``paged_sparse_attn``)."""
+    b, h, t, d = q.shape
+    _, nb, hkv, bs, width = k_pool.shape
+    assert width == d and (t == 1 or t % 8 == 0), (k_pool.shape, q.shape)
+    if interpret is None:
+        interpret = interpret_kernels()
+    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
+    nbper = block_tables.shape[1]
+    nt = max(1, min(_SPARSE_COLS // bs, nbper))
+    while nbper % nt:
+        nt -= 1
+    rows = h // hkv * t
+    ht = _prefill_head_tile(hkv, rows, 1, nt, bs, d, k_pool.dtype.itemsize)
+    n = jnp.clip((jnp.max(last, axis=1) + bs) // bs, 0, nbper)
+    bt = jnp.clip(jnp.asarray(block_tables, jnp.int32), 0, nb - 1)
+
+    def lanes(x, dtype):
+        return jnp.broadcast_to(x.astype(dtype)[..., None], (b, t, LANES))
+
+    def row_block(n_rows, n_lanes):
+        return pl.BlockSpec((1, ht, n_rows, n_lanes),
+                            lambda i, j, *prefetched: (i, j, 0, 0))
+
+    per_query = pl.BlockSpec((1, t, LANES), lambda i, j, *prefetched:
+                             (i, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,               # layer, blocks, table, hits
+        grid=(b, hkv // ht),
+        in_specs=[row_block(rows, d), per_query, per_query, per_query]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * 3,
+        out_specs=row_block(rows, d),
+        scratch_shapes=[
+            pltpu.VMEM((2, nt, ht, bs, d), k_pool.dtype),
+            pltpu.VMEM((2, nt, ht, bs, d), v_pool.dtype),
+            pltpu.VMEM((2, t, nt * bs), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 3)),
+            pltpu.VMEM((ht, rows, LANES), jnp.float32),           # m
+            pltpu.VMEM((ht, rows, LANES), jnp.float32),           # l
+            pltpu.VMEM((ht, rows, d), jnp.float32),               # acc
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_paged_sparse_kernel, sm_scale=scale, t=t),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret, name="paged_sparse_attn",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), n.astype(jnp.int32), bt,
+      jnp.asarray(hit, jnp.int32), q.reshape(b, hkv, rows, d),
+      lanes(theta, jnp.float32), lanes(s_last, jnp.int32),
+      lanes(last, jnp.int32), scores.astype(jnp.float32), k_pool,
+      v_pool).reshape(q.shape)
+
+
 #: paths :func:`paged_decode_attention` took while a :func:`dispatch_log`
 #: was open — written at TRACE time, like the contexts above
 _DISPATCHED = None

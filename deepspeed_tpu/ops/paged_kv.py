@@ -3,11 +3,20 @@ layout, JAX/TPU edition).
 
 Layout contract — the WHOLE stacked pool, addressed in place:
 
- - pool leaf: ``[L, NB, HKV, block_size, hd]`` — the models'
-   ``init_cache(num_blocks, block_size, dtype)`` hook builds it unchanged
+ - pool leaves: each ``[L, NB, heads, block_size, width]`` — the models'
+   ``init_cache(num_blocks, block_size, dtype)`` hook builds them unchanged
    (the batch dim of the contiguous layout becomes the physical-block dim
-   and the length dim the in-block offset).  Every op here takes the whole
-   leaf plus a ``layer`` index and touches ``(layer, physical block, head,
+   and the length dim the in-block offset).  K and V are ``[L, NB, HKV,
+   block_size, hd]``; a family with learned sparse attention
+   (``models/mixtral.py`` with an indexer) adds a third kind of per-token
+   state under the SAME block ids, the indexer's key ``[L, NB, 1,
+   block_size, DI]`` (``DI`` = 64: lane-packed ``g = 2``, 4 KB a block in
+   bf16), written by :func:`paged_window_update` at the ``(layer, block,
+   offset)`` K and V take.  The serving engine treats the pool BY TREE —
+   packing, donation, sharding, swap, prefix sharing and eviction move a
+   block id's slice of every leaf together and name none — so a block is
+   allocated, shared, evicted and swapped with all its leaves.  Every op
+   here takes a whole leaf plus a ``layer`` index and touches ``(layer, physical block, head,
    offset)`` in place; nothing slices a layer out of the pool, re-stacks it
    or changes its layout.  The models' layer loops therefore CARRY the pool
    (``models/gpt2.py:scan_layers_cached``) and a program that donates it
@@ -436,12 +445,9 @@ def _write_one(pool, win, layer, phys, start, nvalid):
                                 nvalid)}
 
 
-def _paged_cache_update(ck, cv, k, v, pos, block_tables, valid, layer):
-    """Single-shard body of :func:`paged_cache_update` on the stacked
-    pool — also the whole op when the pool is replicated (tp=1 / GQA
-    fallback)."""
-    b, hkv, t, hd = k.shape
-    bs = int(np.prod(pool_payload(ck).shape[3:])) // hd
+def _window_blocks(bs: int, b: int, t: int, pos, block_tables, valid):
+    """Where a ``[B, *, T, ...]`` window starting at ``pos`` lands:
+    ``(phys, start, nvalid)`` as :func:`_write_blocks` takes them."""
     nbper = block_tables.shape[1]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     nvalid = jnp.full((b,), t, jnp.int32) if valid is None \
@@ -455,9 +461,37 @@ def _paged_cache_update(ck, cv, k, v, pos, block_tables, valid, layer):
     phys = jnp.where(ok, jnp.maximum(phys, 0), 0)
     # blocks past the table's reach take no token: start past the window
     start = jnp.where(ok, li * bs - pos[:, None], t)
-    ck = _write_one(ck, k, layer, phys, start, nvalid)
-    cv = _write_one(cv, v, layer, phys, start, nvalid)
+    return phys, start, nvalid
+
+
+def _paged_cache_update(ck, cv, k, v, pos, block_tables, valid, layer):
+    """Single-shard body of :func:`paged_cache_update` on the stacked
+    pool — also the whole op when the pool is replicated (tp=1 / GQA
+    fallback)."""
+    b, hkv, t, hd = k.shape
+    bs = int(np.prod(pool_payload(ck).shape[3:])) // hd
+    where = _window_blocks(bs, b, t, pos, block_tables, valid)
+    ck = _write_one(ck, k, layer, *where)
+    cv = _write_one(cv, v, layer, *where)
     return ck, cv
+
+
+def paged_window_update(leaf, win, pos, block_tables, valid=None,
+                        layer=None):
+    """One more per-token pool leaf written as :func:`paged_cache_update`
+    writes K and V: the ``[B, H, T, width]`` window ``win`` lands at the same
+    ``(layer, block, offset)`` of the stacked float leaf ``[L, NB, H,
+    block_size, width]`` (either view).  A learned-sparse-attention model
+    keeps its indexer's keys this way (``ops/sparse_index_attention.py``).  One
+    shard only: a leaf whose head dim is 1 has nothing to split over ``tp``."""
+    if _DP_GROUPS > 1 or is_quantized_pool(leaf):
+        raise NotImplementedError(
+            "paged_window_update takes a float leaf outside a dp context")
+    b, _, t, width = win.shape
+    bs = int(np.prod(leaf.shape[3:])) // width
+    where = _window_blocks(bs, b, t, pos, jnp.asarray(block_tables,
+                                                      jnp.int32), valid)
+    return _write_blocks(leaf, win, jnp.asarray(layer, jnp.int32), *where)
 
 
 def paged_cache_update(ck, cv, k, v, pos, block_tables, valid=None,

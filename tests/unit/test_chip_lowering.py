@@ -345,3 +345,130 @@ def test_paged_walk_compiles_at_the_cells_shapes(cell, slots, h, hd, ctx,
             # kv8: this layer's scale rows ride lane-padded (a copy of
             # 1/L of the small table)
             assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+# ------------------------------------------------ learned sparse attention
+#: the long-context cell (keye-longctx-closed): 16 slots x 16,384 positions,
+#: 6 layers, an indexer of 16 heads x 64 choosing 2,048 keys
+LONGCTX = dict(slots=16, ctx=16384, layers=6, heads=16, width=64, topk=2048)
+
+
+@pytest.mark.parametrize("rows,t", [(16, 1), (4, 128), (16, 4)],
+                         ids=["decode", "prefill-chunk", "verify"])
+def test_sparse_attention_kernels_compile_at_the_long_context_cells_shapes(
+        rows, t, one_chip):
+    """Mosaic's own compile, for a described v5e, of the indexer's scoring
+    walk over the packed third pool leaf (read where it lies: no temporary
+    beside it) and of the threshold selection over its scores."""
+    c = LONGCTX
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    nbper = c["ctx"] // BLOCK
+    pool = sds((c["layers"], 1 + c["slots"] * nbper, 1, BLOCK // 2,
+                2 * c["width"]), jnp.bfloat16)
+    scores = jax.jit(lambda qi, wi, p, bt, last: da.paged_index_scores_pallas(
+        qi, wi, p, bt, last, layer=1, interpret=False)).lower(
+            sds((rows, c["heads"], t, c["width"]), jnp.bfloat16),
+            sds((rows, t, c["heads"]), jnp.float32), pool,
+            sds((rows, nbper), jnp.int32), sds((rows, t), jnp.int32))
+    assert 'kernel_name = "paged_index_scores"' in scores.as_text()
+    compiled = scores.compile()
+    # the scores themselves, once more for the transpose into token order
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= 2 * rows * t * c["ctx"] * 4
+    select = jax.jit(lambda s: da.paged_sparse_select_pallas(
+        s, c["topk"], interpret=False)).lower(
+            sds((rows, t, c["ctx"]), jnp.float32))
+    assert 'kernel_name = "paged_sparse_select"' in select.as_text()
+    assert select.compile().memory_analysis().temp_size_in_bytes < 1 << 20
+    if t % 8 and t != 1:
+        return          # a verify window's read is the XLA walk
+    # the read: K and V blocks of GQA 32/4 x 128 out of the whole stack
+    kv = sds((c["layers"], 1 + c["slots"] * nbper, 4, BLOCK, 128),
+             jnp.bfloat16)
+    read = jax.jit(lambda q, k, v, bt, s, th, sl, last, hit:
+                   da.paged_sparse_attention_pallas(
+                       q, k, v, bt, s, th, sl, last, hit, layer=1,
+                       interpret=False)).lower(
+        sds((rows, 32, t, 128), jnp.bfloat16), kv, kv,
+        sds((rows, nbper), jnp.int32), sds((rows, t, c["ctx"]), jnp.float32),
+        sds((rows, t), jnp.float32), sds((rows, t), jnp.int32),
+        sds((rows, t), jnp.int32), sds((rows, nbper), jnp.int32))
+    assert 'kernel_name = "paged_sparse_attn"' in read.as_text()
+    assert read.compile().memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_compiled_sparse_serving_programs_hold_no_pool_sized_temporary(
+        as_on_tpu, one_chip, monkeypatch):
+    """``test_compiled_serving_programs_hold_no_pool_sized_temporary`` for a
+    model with an indexer, at the long-context cell's shapes: decode and
+    prefill alias all THREE pool leaves, copy no slice of any, and hold
+    temporaries far below one layer's slice of the K pool (268 MB) — the
+    chosen tokens' copies (34 MB) in decode, one layer's indexer scores
+    (``[4, 128, 16384]`` float32, 34 MB) and a walk step in prefill.  A
+    gather indexed ``[layer, block, :, row]`` had XLA re-lay-out the whole
+    K pool first: 1.6 GB of temporaries in decode."""
+    import dataclasses
+
+    from deepspeed_tpu.models import mixtral
+    from deepspeed_tpu.moe import grouped_matmul
+    from deepspeed_tpu.ops import paged_kv, sparse_index_attention
+
+    monkeypatch.setattr(sparse_index_attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(grouped_matmul, "interpret_kernels", lambda: False)
+    c = LONGCTX
+    cfg = dataclasses.replace(mixtral.MixtralConfig.keye_vl2_30b_a3b(),
+                              num_layers=c["layers"], max_seq_len=c["ctx"])
+    spec = mixtral.build(cfg)
+    fwd = spec.decode_hooks["forward_cached"]
+    nbper = c["ctx"] // BLOCK
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return sds(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    params = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda: jax.tree_util.tree_map(
+            lambda a: a.astype(jnp.bfloat16),
+            spec.init_fn(jax.random.PRNGKey(0)))))
+    pool = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda: paged_kv.pack_pool(spec.decode_hooks["init_cache"](
+            1 + c["slots"] * nbper, BLOCK, jnp.bfloat16))))
+    assert set(pool) == {"k", "v", "idx"}
+
+    def decode_step(params, cache, tokens, lengths, bt):
+        logits, cache = fwd(params, tokens[:, None], cache, 0,
+                            lengths=lengths, block_tables=bt)
+        return jnp.argmax(logits, -1).astype(jnp.int32), cache
+
+    def prefill(params, cache, ids, bt, base, valid):
+        logits, cache = fwd(params, ids, cache, base, lengths=valid,
+                            block_tables=bt)
+        return jnp.argmax(logits, -1).astype(jnp.int32), cache
+
+    slots = c["slots"]
+    programs = {
+        "decode_step": (decode_step, (params, pool, i32(slots), i32(slots),
+                                      i32(slots, nbper))),
+        "prefill": (prefill, (params, pool, i32(4, 128), i32(4, nbper),
+                              i32(4), i32(4)))}
+    layer_slice = int(np.prod(pool["k"].shape[1:])) * 2
+    for name, (fn, args) in programs.items():
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+        text = compiled.as_text()
+        for kernel in ("paged_index_scores", "paged_sparse_select"):
+            assert kernel in text, (name, kernel)
+        for leaf in pool.values():
+            dims = ",".join(str(d) for d in leaf.shape[1:])
+            copies = [line for line in text.splitlines() if " copy(" in line
+                      and dims in line.split(" copy(")[0]]
+            assert not copies, (name, copies[:2])
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < layer_slice // 4, (
+            name, mem.temp_size_in_bytes)
+        assert mem.alias_size_in_bytes >= sum(
+            int(np.prod(a.shape)) * 2 for a in pool.values()), (name, mem)

@@ -53,6 +53,9 @@ ENGINE_STATS_KEYS = frozenset({
     "kv_pool_shape", "kv_scale_bytes", "kv_sharded",
     # PR 31: which read the prefill program was traced with
     "prefill_attn",
+    # PR 32: a learned-sparse-attention model's selection paths + counters
+    # (None for any other model)
+    "sparse_attn",
     # PR 28: routed (token, expert) rows and experts touched, summed over
     # layers and program calls; 0 for a dense model
     "moe_expert_rows", "moe_experts_touched",
