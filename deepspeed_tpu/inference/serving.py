@@ -1841,6 +1841,24 @@ class ServingEngine:
             self._program_meta.setdefault("sparse_attn", {})[program] = \
                 sparse_index_attention.took()
 
+    def _note_sampler(self, program: str, samp) -> None:
+        """Trace time: how ``program`` picks its tokens
+        (``stats()["sampler"]``) — ``"argmax"`` for a greedy-only engine,
+        else how ``ops/sampling.py`` finds the filter's thresholds."""
+        self._program_meta.setdefault("sampler", {})[program] = \
+            "argmax" if samp is None else sampling_ops.THRESHOLDS
+
+    def _sampler_rows(self, slots) -> Dict[str, int]:
+        """Span counters of a dispatch over ``slots``: ``sampled_rows``
+        (temperature > 0) and, of those, ``filtered_rows`` (``top_k > 0``
+        or ``top_p < 1``: the rows the sampler's threshold searches run
+        for), from the knob vectors the dispatch uploads."""
+        sampled = self._temps[slots] > 0
+        filtered = sampled & ((self._topks[slots] > 0)
+                              | (self._topps[slots] < 1))
+        return {"sampled_rows": int(sampled.sum()),
+                "filtered_rows": int(filtered.sum())}
+
     def _next_tokens(self, logits, samp):
         """The per-row token rule shared by every program body: argmax for
         a greedy-only engine; otherwise a per-row ``where(temp > 0)``
@@ -1959,6 +1977,7 @@ class ServingEngine:
                                          cache, 0, lengths=lengths,
                                          block_tables=block_tables)
                 self._note_sparse("decode")
+                self._note_sampler("decode", samp)
                 return with_record(next_tokens(logits, samp), rec), \
                     constrain(cache)
 
@@ -1996,6 +2015,7 @@ class ServingEngine:
                                            lengths=lens,
                                            block_tables=block_tables)
                     self._note_sparse("decode")
+                    self._note_sampler("decode", samp)
                     if r is not None:
                         (rec, *seen), (r, *counts) = (
                             x if isinstance(x, tuple) else (x,)
@@ -2101,7 +2121,9 @@ class ServingEngine:
             # which read the program was built with, noted as it is traced
             meta["prefill_attn"] = "+".join(sorted(paths))
             self._note_sparse("prefill")
-            return with_record(next_tokens(logits, pack(samp)), rec), \
+            samp_t = pack(samp)
+            self._note_sampler("prefill", samp_t)
+            return with_record(next_tokens(logits, samp_t), rec), \
                 constrain(cache)
 
         body, donate = prefill, self._donate()
@@ -2183,6 +2205,7 @@ class ServingEngine:
                 rec = rec[0] if rec else None
                 self._note_sparse("verify")
                 samp_t = pack(samp)
+                self._note_sampler("verify", samp_t)
                 if samp_t is None:
                     return self._with_record(
                         jnp.argmax(logits, -1).astype(jnp.int32), rec), cache
@@ -2263,6 +2286,7 @@ class ServingEngine:
                         *samp):
                 dp = dprepare(dparams)
                 samp_t = pack(samp)
+                self._note_sampler("draft", samp_t)
 
                 def rollout_step(carry, i):
                     tok, lens, cache = carry
@@ -3847,7 +3871,8 @@ class ServingEngine:
             args += (jnp.asarray(self._window_start),)
         args += self._samp_args(self._decode_counts())
         decode_fn = self._get_decode_fn()
-        with self.timeline.span("decode", slots=len(dec)) as span_args:
+        with self.timeline.span("decode", slots=len(dec),
+                                **self._sampler_rows(dec)) as span_args:
             with self._decode_ctx():
                 nxt, self._cache = decode_fn(*args)
             nxt = self._split_record(np.asarray(nxt), (self.slots,),
@@ -3942,8 +3967,8 @@ class ServingEngine:
                 jnp.asarray(eos_ids),
                 *self._samp_args(self._decode_counts()))
         decode_fn = self._get_decode_fn()
-        with self.timeline.span("decode", slots=len(dec),
-                                fused=K) as span_args:
+        with self.timeline.span("decode", slots=len(dec), fused=K,
+                                **self._sampler_rows(dec)) as span_args:
             with self._decode_ctx():
                 out, self._cache = decode_fn(*args)
             out, = self._fence_harvest(out)
@@ -4195,6 +4220,7 @@ class ServingEngine:
                 slots=list(map(int, group)),
                 # blocks the rows' reads walk: cdiv(base + valid, bs) each
                 kv_blocks=int((-(-(base + valid) // self.block_size)).sum()),
+                **self._sampler_rows(group),
         ) as span_args:
             if self._draft is not None:
                 with self._tp_ctx():
@@ -4404,6 +4430,9 @@ class ServingEngine:
             # the read the prefill program was traced with (None before its
             # first call): "paged_prefill_attn" on a TPU, "gather" on a CPU
             "prefill_attn": self._program_meta.get("prefill_attn"),
+            # how each built program picks its tokens: "argmax" (greedy-only
+            # engine) or how ops/sampling.py finds the filter's thresholds
+            "sampler": dict(self._program_meta.get("sampler", {})),
             # a learned-sparse-attention model: what each program's
             # selection was traced with, and the totals of the spans'
             # counters (:meth:`_split_record`); None for any other model

@@ -38,10 +38,14 @@ import jax
 import jax.numpy as jnp
 
 __all__ = [
-    "SALT_TOKEN", "SALT_ACCEPT", "SALT_RESIDUAL", "SALT_DRAFT",
+    "SALT_TOKEN", "SALT_ACCEPT", "SALT_RESIDUAL", "SALT_DRAFT", "THRESHOLDS",
     "slot_keys", "grid_keys", "filtered_logprobs", "sample_tokens",
     "accept_uniforms", "token_probs", "residual_logits",
 ]
+
+#: how :func:`filtered_logprobs` finds its top-k / top-p thresholds — what a
+#: serving program built on it reports (``ServingEngine.stats()["sampler"]``)
+THRESHOLDS = "bitwise_search"
 
 SALT_TOKEN = 1
 SALT_ACCEPT = 2
@@ -71,6 +75,57 @@ def grid_keys(seeds, counts, salt, width):
     return flat.reshape((-1, int(width)) + flat.shape[1:])
 
 
+def _kth_largest(x, k):
+    """``[rows, 1]`` float32: the ``k[row]``-th largest of each row of
+    float32 ``x [rows, vocab]`` (``k`` int32 ``[rows, 1]``, ``1 <= k <=
+    vocab``), exactly, without a sort.  Float32 bits map to int32 keys of
+    the same order (``-0.0`` ties with ``0.0``, ``-inf`` orders lowest);
+    the answer is the largest key ``t`` with ``count(key >= t) >= k``,
+    built from the sign bit down: 32 fused compare-and-count passes over
+    the row, whatever its values (``ops/decode_attention.py``'s
+    ``_sparse_select_kernel`` selects the same way).  The keys are formed
+    inside each pass, so no ``[rows, vocab]`` temporary is held."""
+    def reaches(cand):
+        z = jnp.where(x == 0.0, 0.0, x)
+        u = jax.lax.bitcast_convert_type(z, jnp.int32)
+        key = jnp.where(u < 0, u ^ jnp.int32(0x7FFFFFFF), u)
+        return jnp.sum(key >= cand, axis=-1, keepdims=True,
+                       dtype=jnp.int32) >= k
+
+    t = jnp.where(reaches(jnp.int32(0)), jnp.int32(0),
+                  jnp.iinfo(jnp.int32).min)
+
+    def bit(i, t):
+        cand = t | jnp.left_shift(jnp.int32(1), 30 - i)
+        return jnp.where(reaches(cand), cand, t)
+
+    t = jax.lax.fori_loop(0, 31, bit, t)
+    return jax.lax.bitcast_convert_type(
+        jnp.where(t < 0, t ^ jnp.int32(0x7FFFFFFF), t), jnp.float32)
+
+
+def _nucleus_threshold(probs, p):
+    """``[rows, 1]`` float32: the largest value ``t`` of each row of
+    ``probs [rows, vocab]`` (float32, in ``[0, 1]``) whose upper set
+    reaches the nucleus mass, ``sum(probs[probs >= t]) >= p`` (``p``
+    float32 ``[rows, 1]``), or 0 where none does.  The bits of a
+    non-negative float32 order as its value, so ``t`` is built bit by bit
+    like :func:`_kth_largest`'s key, with a masked float32 sum for the
+    count (a sum of non-negative terms in a fixed order never falls when
+    a term is added, so the search is sound in floating point); bit 30
+    would be a value of 2 or more and is never set: 30 passes."""
+    def bit(i, t):
+        cand = t | jnp.left_shift(jnp.int32(1), 29 - i)
+        value = jax.lax.bitcast_convert_type(cand, jnp.float32)
+        mass = jnp.sum(jnp.where(probs >= value, probs, 0.0), axis=-1,
+                       keepdims=True)
+        return jnp.where(mass >= p, cand, t)
+
+    t = jax.lax.fori_loop(0, 30, bit,
+                          jnp.zeros(probs.shape[:-1] + (1,), jnp.int32))
+    return jax.lax.bitcast_convert_type(t, jnp.float32)
+
+
 def filtered_logprobs(logits, temps, top_k, top_p, masks=None):
     """Per-row log-probs of the filtered sampling distribution.
 
@@ -83,27 +138,43 @@ def filtered_logprobs(logits, temps, top_k, top_p, masks=None):
     renormalized top-k distribution (``top_p == 1`` = off; the token
     that crosses the boundary stays in).  Rows with ``temps == 0``
     return the exact one-hot (``0 / -inf``) at the masked argmax —
-    the greedy row of the same traced program."""
+    the greedy row of the same traced program.
+
+    Both thresholds are found by a bitwise search over the row
+    (:func:`_kth_largest`, :func:`_nucleus_threshold`), not by sorting
+    the vocabulary, and only when a sampled row asks: each search sits
+    under a ``lax.cond`` on the knob operands, so a batch with no
+    ``top_k`` set (or no filter at all) skips it at run time, in the one
+    compiled program.  The kept sets are the sorted formulation's —
+    a token stays iff the mass of strictly larger probabilities is below
+    ``top_p`` — in float32 throughout; the nucleus mass is summed in the
+    reduction's order rather than a sorted cumsum's, so a token whose
+    boundary mass lies within float32 rounding of ``top_p`` may fall on
+    the other side.  Ties, the boundary-crossing token, ``top_p == 1``
+    and the one-hot greedy rows are exact."""
     logits = logits.astype(jnp.float32)
-    vocab = logits.shape[-1]
+    rows, vocab = logits.shape
     if masks is not None:
         ok = jnp.any(masks, axis=-1, keepdims=True)
         logits = jnp.where(jnp.where(ok, masks, True), logits, -jnp.inf)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     temps = jnp.asarray(temps, jnp.float32)[:, None]
     scaled = logits / jnp.maximum(temps, 1e-6)
-    srt = jnp.sort(scaled, axis=-1)[:, ::-1]
-    k = jnp.asarray(top_k, jnp.int32)
-    kidx = jnp.clip(jnp.where(k > 0, k, vocab) - 1, 0, vocab - 1)
-    kth = jnp.take_along_axis(srt, kidx[:, None], axis=-1)
+    k = jnp.asarray(top_k, jnp.int32)[:, None]
+    p = jnp.asarray(top_p, jnp.float32)[:, None]
+    k_on = (temps > 0) & (k > 0) & (k < vocab)
+    p_on = (temps > 0) & (p < 1)
+    # a row that does not ask searches for its minimum: everything stays
+    kth = jax.lax.cond(
+        jnp.any(k_on),
+        lambda: _kth_largest(scaled, jnp.where(k_on, k, vocab)),
+        lambda: jnp.full((rows, 1), -jnp.inf))
     keep = scaled >= kth
     probs = jax.nn.softmax(jnp.where(keep, scaled, -jnp.inf), axis=-1)
-    psort = jnp.sort(probs, axis=-1)[:, ::-1]
-    before = jnp.cumsum(psort, axis=-1) - psort
-    p = jnp.asarray(top_p, jnp.float32)[:, None]
-    thr = jnp.min(jnp.where(before < p, psort, jnp.inf),
-                  axis=-1, keepdims=True)
-    keep = keep & (probs >= thr)
+    thr = jax.lax.cond(jnp.any(p_on),
+                       lambda: _nucleus_threshold(probs, p),
+                       lambda: jnp.zeros((rows, 1)))
+    keep = keep & (probs >= jnp.where(p_on, thr, 0.0))
     logprobs = jax.nn.log_softmax(jnp.where(keep, scaled, -jnp.inf),
                                   axis=-1)
     onehot = jnp.where(

@@ -472,3 +472,93 @@ def test_compiled_sparse_serving_programs_hold_no_pool_sized_temporary(
             name, mem.temp_size_in_bytes)
         assert mem.alias_size_in_bytes >= sum(
             int(np.prod(a.shape)) * 2 for a in pool.values()), (name, mem)
+
+
+# ------------------------------------------------------------- the sampler
+#: ``[rows, vocab]`` of the serving cells' sampler calls: chat decode, OLMoE
+#: decode, the long-context cell's decode and its [4, 128] prefill call, and
+#: a verify window of the chat cell (24 slots x K + 1 = 5 positions)
+SAMPLER_SHAPES = [(24, 50272), (64, 50304), (16, 151936), (4, 151936),
+                  (24 * 5, 50272)]
+
+
+@pytest.mark.parametrize("rows,vocab", SAMPLER_SHAPES)
+def test_sampler_compiles_without_a_sort_at_the_cells_shapes(rows, vocab,
+                                                             one_chip):
+    """ISSUE 33: ``filtered_logprobs`` compiled for a described v5e at the
+    cells' shapes (a vocabulary that is no lane multiple among them) holds
+    no ``sort`` — the thresholds come from the two searches' loops — and,
+    beside the log-probs it returns, temporaries under ONE ``[rows, vocab]``
+    float32 array: the searches form their keys inside each pass and hold
+    nothing of that size (the parent's two sorts each held the sorted row
+    set and its permutation)."""
+    from deepspeed_tpu.ops import sampling
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lowered = jax.jit(sampling.filtered_logprobs).lower(
+        sds((rows, vocab), jnp.bfloat16), sds((rows,), jnp.float32),
+        sds((rows,), jnp.int32), sds((rows,), jnp.float32))
+    text = lowered.as_text()
+    assert "stablehlo.sort" not in text and "stablehlo.while" in text
+    assert text.count("stablehlo.case") + text.count("stablehlo.if") == 2
+    compiled = lowered.compile()
+    assert " sort(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * vocab * 4
+
+
+@pytest.mark.parametrize("spec_tokens,programs",
+                         [(0, {"decode", "prefill"}),
+                          (3, {"prefill", "verify"})],
+                         ids=["plain", "speculative"])
+def test_serving_programs_hold_no_sort_and_knobs_never_recompile(
+        spec_tokens, programs):
+    """The decode / prefill / verify programs a dense family's engine built
+    hold no ``sort`` (their bodies lowered at the live shapes, the sampling
+    operands included), and a slot changing its knobs — greedy, top-k,
+    top-p, none — changes operand values only: ``compile_count`` stays."""
+    import deepspeed_tpu
+    from deepspeed_tpu.inference.serving import Request, ServingEngine
+    from deepspeed_tpu.models import gpt2
+    from deepspeed_tpu.telemetry.flops import ServingFlopsProfiler
+
+    cfg = gpt2.GPT2Config.tiny()
+    engine = deepspeed_tpu.init_inference(gpt2.build(cfg),
+                                          config={"dtype": "fp32"})
+    srv = ServingEngine(engine, slots=3, max_seq_len=64, block_size=8,
+                        prefill_chunk=16, spec_tokens=spec_tokens)
+    rng = np.random.default_rng(0)
+
+    def serve(knobs):
+        reqs = [Request(uid=i, prompt=rng.integers(0, cfg.vocab_size, 9,
+                                                   dtype=np.int32),
+                        max_new_tokens=6, temperature=t, top_k=k, top_p=p,
+                        seed=i)
+                for i, (t, k, p) in enumerate(knobs)]
+        out = srv.serve(reqs)
+        assert len(out) == len(knobs) and all(len(v) for v in out.values())
+
+    serve([(0.7, 0, 0.9), (0.0, 0, 1.0)])
+    built = srv.compile_count
+    assert srv.stats()["sampler"] == dict.fromkeys(programs,
+                                                   "bitwise_search")
+    serve([(1.3, 5, 1.0), (0.7, 7, 0.5), (1.0, 0, 1.0)])
+    serve([(0.0, 0, 1.0)])
+    assert srv.compile_count == built
+    assert srv.stats()["retraces_observed"] == 0
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+    abstract = ServingFlopsProfiler(srv)._abstract_args
+    samp = {"decode": srv._samp_args(np.zeros(srv.slots)),
+            "verify": srv._samp_args(np.zeros(srv.slots)),
+            "prefill": srv._samp_args_rows([], srv.prefill_batch)}
+    assert set(srv._program_bodies) == programs
+    for name in programs:
+        text = jax.jit(srv._program_bodies[name]).lower(
+            *abstract(name), *sds(samp[name])).as_text()
+        assert "stablehlo.while" in text, name      # the searches are there
+        assert "stablehlo.sort" not in text, name

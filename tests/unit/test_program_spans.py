@@ -137,6 +137,50 @@ def test_prefill_spans_count_the_blocks_their_reads_walk(served, tiny):
     assert srv.stats()["prefill_attn"] == "gather"
 
 
+#: (temperature, top_k, top_p) a request: greedy, sampled unfiltered, top-p,
+#: top-k, both, and a greedy request whose filter knobs select nothing
+MIXED_KNOBS = [(0.0, 0, 1.0), (1.0, 0, 1.0), (0.7, 0, 0.9), (1.2, 5, 1.0),
+               (0.7, 9, 0.8), (0.0, 4, 0.5), (0.7, 0, 0.9)]
+
+
+@pytest.mark.parametrize("sampling", [True, False],
+                         ids=["mixed-batch", "greedy-only-engine"])
+def test_sampler_rows_ride_the_spans_and_stats_name_the_sampler(
+        tiny, sampling):
+    """ISSUE 33: every built program says how it picks its tokens
+    (``stats()["sampler"]``), and the ``decode`` / ``prefill`` spans carry
+    ``sampled_rows`` (temperature > 0) and ``filtered_rows`` (of those, a
+    ``top_k`` or ``top_p`` set: the rows the threshold searches run for),
+    counted from the knob vectors the dispatch uploads.  A decode span
+    counts a request once per token it emits there (all but its first), a
+    prefill span once per chunk of its prompt."""
+    engine, cfg = tiny
+    srv = ServingEngine(engine, sampling=sampling, **SERVE_KW)
+    assert srv.stats()["sampler"] == {}
+    reqs = _requests(cfg)
+    if sampling:
+        reqs = [Request(uid=r.uid, prompt=r.prompt,
+                        max_new_tokens=r.max_new_tokens, temperature=t,
+                        top_k=k, top_p=p, seed=11 + r.uid)
+                for r, (t, k, p) in zip(reqs, MIXED_KNOBS)]
+    srv.serve(reqs)
+    how = "bitwise_search" if sampling else "argmax"
+    assert srv.stats()["sampler"] == {"prefill": how, "decode": how}
+    events = srv.timeline.events()
+    chunk = SERVE_KW["prefill_chunk"]
+    sampled = [r for r in reqs if r.temperature > 0]
+    filtered = [r for r in sampled if r.top_k > 0 or r.top_p < 1]
+    assert (len(sampled), len(filtered)) == ((5, 4) if sampling else (0, 0))
+    for key, of in (("sampled_rows", sampled), ("filtered_rows", filtered)):
+        assert sum(e["args"][key] for e in _named(events, "decode")) == \
+            sum(r.max_new_tokens - 1 for r in of)
+        assert sum(e["args"][key] for e in _named(events, "prefill")) == \
+            sum(-(-len(r.prompt) // chunk) for r in of)
+    for e in _named(events, "decode") + _named(events, "prefill"):
+        assert e["args"]["filtered_rows"] <= e["args"]["sampled_rows"] \
+            <= e["args"].get("rows", e["args"]["slots"])
+
+
 def test_kv_seconds_and_step_numbers(served):
     _, events, _ = served
     for s in _named(events, "step"):

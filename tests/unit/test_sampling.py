@@ -15,6 +15,9 @@ marginal EXACTLY ``p_target`` for ANY proposer, no draft probabilities
 needed.
 """
 
+import zlib
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -83,6 +86,197 @@ def test_mask_applies_before_filtering_and_empty_row_is_inert():
     assert int(greedy[1]) == int(_np(logits)[1].argmax())
     lp0 = _np(lp)[0]
     assert np.isneginf(np.delete(lp0, [int(greedy[0])])).all()
+
+
+# ----------------------------------- the thresholds against a sorted reference
+BAND = 1e-6      # |mass strictly above a token - top_p| under which a row is
+                 # "at the boundary": float32 summation order may decide it
+
+
+def _sorted_reference(logits, temps, top_k, top_p, masks=None):
+    """The sorted formulation ``filtered_logprobs`` had until PR 33, in
+    NumPy: the k-th largest read off a full descending sort, the nucleus
+    threshold off a sorted exclusive cumsum — summed in float64.  The
+    probabilities the cumsum runs over are the float32 softmax the
+    function itself takes (so ties are the same ties).  Returns the kept
+    sets at ``top_p`` and at ``top_p -/+ 2 BAND``, the float64 log-probs,
+    and each row's distance from the boundary."""
+    logits = np.asarray(logits, np.float32)
+    rows, vocab = logits.shape
+    if masks is not None:
+        ok = masks.any(-1, keepdims=True)
+        logits = np.where(np.where(ok, masks, True), logits, -np.inf)
+    t = np.asarray(temps, np.float32)[:, None]
+    scaled = (logits / np.maximum(t, np.float32(1e-6))).astype(np.float32)
+    srt = np.sort(scaled, axis=-1)[:, ::-1]
+    k = np.asarray(top_k, np.int64)
+    kidx = np.clip(np.where(k > 0, k, vocab) - 1, 0, vocab - 1)
+    keep = scaled >= np.take_along_axis(srt, kidx[:, None], axis=-1)
+    probs = np.asarray(jax.nn.softmax(
+        jnp.where(jnp.asarray(keep), jnp.asarray(scaled), -jnp.inf),
+        axis=-1)).astype(np.float64)
+    psort = np.sort(probs, axis=-1)[:, ::-1]
+    before = np.cumsum(psort, axis=-1) - psort
+    # mass strictly above each entry's VALUE: ties share their first's
+    first = np.concatenate([np.ones((rows, 1), bool),
+                            psort[:, 1:] != psort[:, :-1]], axis=-1)
+    above = np.maximum.accumulate(np.where(first, before, 0.0), axis=-1)
+    p = np.asarray(top_p, np.float64)[:, None]
+
+    def kept(p):
+        thr = np.min(np.where(above < p, psort, np.inf), axis=-1,
+                     keepdims=True)
+        return keep & (probs >= thr)
+
+    sets = [kept(np.where(p >= 1, np.inf, q)) for q in
+            (p, p - 2 * BAND, p + 2 * BAND)]
+    z = np.where(sets[0], scaled.astype(np.float64), -np.inf)
+    z = z - z.max(-1, keepdims=True)
+    lp = z - np.log(np.exp(z).sum(-1, keepdims=True))
+    edge = np.abs(np.concatenate(
+        [above, psort.sum(-1, keepdims=True)], axis=-1) - p).min(-1)
+    return sets, lp, np.where(p[:, 0] >= 1, np.inf, edge)
+
+
+def _peaked(rng, rows, vocab):
+    """A nucleus of 3 tokens: three logits far above a flat rest."""
+    x = rng.normal(size=(rows, vocab)).astype(np.float32) * 0.1
+    for r in range(rows):
+        x[r, rng.choice(vocab, 3, replace=False)] += (12.0, 11.5, 11.0)
+    return x
+
+
+def _flat(rng, rows, vocab, std=0.1):
+    """The benchmark cells' regime: near-uniform logits, a nucleus of
+    ~90 % of the vocabulary at T 0.7 / top-p 0.9."""
+    return rng.normal(size=(rows, vocab)).astype(np.float32) * std
+
+
+def _knob_case(top_k, top_p):
+    def build(rng):
+        x = _flat(rng, 3, 64, 2.0)
+        return x, np.full(3, 0.7), np.full(3, top_k), np.full(3, top_p), None
+    return build
+
+
+def _regime_case(make, vocab):
+    def build(rng):
+        return (make(rng, 3, vocab), np.full(3, 0.7), np.zeros(3, int),
+                np.full(3, 0.9), None)
+    return build
+
+
+def _all_equal(rng):
+    return (np.full((3, 64), 1.25, np.float32), np.full(3, 0.7),
+            np.asarray([0, 7, 0]), np.asarray([0.9, 0.9, 0.1]), None)
+
+
+def _topk_ties(rng):
+    x = np.tile(np.asarray([4.0, 3.0, 3.0, 3.0, 1.0, 0.0, -1.0, 3.0],
+                           np.float32), (3, 1))
+    return x, np.ones(3), np.asarray([2, 3, 5]), np.ones(3), None
+
+
+def _nucleus_ties(rng):
+    pr = np.asarray([0.5, 0.2, 0.2, 0.05, 0.05], np.float32)
+    x = np.tile(np.log(pr), (3, 1))
+    return x, np.ones(3), np.zeros(3, int), np.asarray([0.6, 0.45, 0.92]), \
+        None
+
+
+def _mixed_rows(rng):
+    """Greedy, sampled-unfiltered, top-k, top-p and both, in one batch."""
+    x = np.concatenate([_flat(rng, 3, 257, 2.5), _peaked(rng, 3, 257)])
+    return (x, np.asarray([0.0, 1.0, 0.7, 0.7, 1.3, 0.0]),
+            np.asarray([5, 0, 7, 0, 20, 0]),
+            np.asarray([0.5, 1.0, 1.0, 0.9, 0.8, 1.0]), None)
+
+
+def _masked(rng):
+    """Logit masks (one row all-False = unconstrained), ``-inf`` and
+    ``-0.0`` logits inside the kept range."""
+    x = _flat(rng, 4, 64, 1.5)
+    x[:, 3] = -np.inf
+    x[:, 5], x[:, 6], x[:, 9] = -0.0, 0.0, -0.0
+    masks = rng.random((4, 64)) < 0.5
+    masks[:, [5, 6, 9]] = True
+    masks[1] = False
+    masks[3] = True
+    return (x, np.asarray([0.7, 0.7, 1.0, 0.0]), np.asarray([0, 9, 4, 0]),
+            np.asarray([0.9, 0.9, 1.0, 1.0]), masks)
+
+
+SEARCH_CASES = {
+    **{f"peaked-vocab{v}": _regime_case(_peaked, v)
+       for v in (17, 64, 50272, 151936)},
+    **{f"flat-vocab{v}": _regime_case(_flat, v)
+       for v in (17, 64, 50272, 151936)},
+    **{f"top_k{k}-top_p{p}": _knob_case(k, p)
+       for k in (0, 1, 7, 64) for p in (0.1, 0.9, 1.0)},
+    "all-equal": _all_equal, "ties-at-top-k": _topk_ties,
+    "ties-at-nucleus": _nucleus_ties, "mixed-rows": _mixed_rows,
+    "masks-inf-negzero": _masked,
+}
+
+
+@pytest.mark.parametrize("case", sorted(SEARCH_CASES))
+def test_searched_thresholds_keep_the_sorted_references_set(case):
+    """ISSUE 33: the bitwise searches keep exactly the set the sorted
+    formulation keeps, and give its log-probs.  A row whose boundary mass
+    lies within ``BAND`` of ``top_p`` (named in ``at_boundary``) is held
+    to the band instead: its set lies between the reference's sets at
+    ``top_p -/+ 2 BAND``."""
+    logits, temps, top_k, top_p, masks = SEARCH_CASES[case](
+        np.random.default_rng(zlib.crc32(case.encode())))
+    temps = np.asarray(temps, np.float32)
+    greedy, lp = S.filtered_logprobs(
+        jnp.asarray(logits), jnp.asarray(temps),
+        jnp.asarray(top_k, jnp.int32), jnp.asarray(top_p, jnp.float32),
+        None if masks is None else jnp.asarray(masks))
+    lp = _np(lp)
+    (want, low, high), want_lp, edge = _sorted_reference(
+        logits, temps, top_k, top_p, masks)
+    sampled = np.flatnonzero(temps > 0)
+    at_boundary = [int(r) for r in sampled if edge[r] < BAND]
+    assert len(at_boundary) < max(len(sampled), 1), at_boundary
+    for r in sampled:
+        got = np.isfinite(lp[r])
+        if r in at_boundary:
+            assert (low[r] <= got).all() and (got <= high[r]).all(), r
+            continue
+        np.testing.assert_array_equal(got, want[r], err_msg=f"row {r}")
+        np.testing.assert_allclose(lp[r][got], want_lp[r][got], atol=2e-5)
+    # greedy rows: the exact one-hot at the masked argmax
+    for r in np.flatnonzero(temps == 0):
+        assert lp[r, int(greedy[r])] == 0.0
+        assert np.isneginf(np.delete(lp[r], int(greedy[r]))).all()
+
+
+def test_searches_give_the_sorted_values_bit_for_bit():
+    """The two searches alone against a sort: the k-th largest of rows
+    with ``-inf``, ``-0.0``, ties and negative values, for every k; the
+    nucleus threshold an entry of the row."""
+    rng = np.random.default_rng(5)
+    x = rng.normal(size=(4, 50)).astype(np.float32)
+    x[0, :7], x[1, 3], x[2, 4:9], x[3, 11] = -np.inf, -0.0, 0.5, 0.0
+    x[3, 12] = -0.0
+    srt = np.sort(x, axis=-1)[:, ::-1]
+    for k in (1, 2, 7, 43, 44, 50):
+        got = _np(S._kth_largest(jnp.asarray(x),
+                                 jnp.full((4, 1), k, jnp.int32)))[:, 0]
+        np.testing.assert_array_equal(got, srt[:, k - 1])
+    pr = rng.dirichlet(np.ones(50), size=4).astype(np.float32)
+    for p in (0.05, 0.5, 0.97):
+        thr = _np(S._nucleus_threshold(
+            jnp.asarray(pr), jnp.full((4, 1), p, jnp.float32)))[:, 0]
+        for r in range(4):
+            assert thr[r] in pr[r]
+            assert pr[r][pr[r] >= thr[r]].sum(dtype=np.float64) >= p - 1e-6
+            assert pr[r][pr[r] > thr[r]].sum(dtype=np.float64) < p + 1e-6
+    # no upper set reaches the mass: 0, which keeps everything
+    thr = S._nucleus_threshold(jnp.asarray(pr * 0.5),
+                               jnp.full((4, 1), 0.9, jnp.float32))
+    assert (_np(thr) == 0.0).all()
 
 
 # -------------------------------------------------------- key schedule

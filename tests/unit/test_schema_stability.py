@@ -53,6 +53,8 @@ ENGINE_STATS_KEYS = frozenset({
     "kv_pool_shape", "kv_scale_bytes", "kv_sharded",
     # PR 31: which read the prefill program was traced with
     "prefill_attn",
+    # PR 33: how each built program picks its tokens
+    "sampler",
     # PR 32: a learned-sparse-attention model's selection paths + counters
     # (None for any other model)
     "sparse_attn",
