@@ -253,6 +253,57 @@ class BlockAllocator:
             self._free.append(block)
 
 
+class WindowRing:
+    """The sliding-window layer kind's blocks (``ops/paged_kv.py`` "Layer
+    kinds"): an allocator over a block-id space of its own and one RING
+    table a row, logical block ``i`` at entry ``i % width``.  ``width`` is
+    ``ceil((window + chunk) / block_size) + 1`` — the window behind a
+    dispatch's first query plus the dispatch's own positions, one block
+    more for windows that start mid-block — and the pool holds a whole ring
+    for every row at once, so this kind never runs dry.  A row's live
+    logical blocks are ``[lo[row], hi[row])``."""
+
+    def __init__(self, rows: int, window: int, chunk: int, block_size: int):
+        self.window, self.block_size = int(window), int(block_size)
+        self.width = -(-(self.window + int(chunk)) // self.block_size) + 1
+        self.alloc = BlockAllocator(1 + rows * self.width)
+        self.tables = np.zeros((rows, self.width), np.int32)
+        self.lo = np.zeros(rows, np.int64)
+        self.hi = np.zeros(rows, np.int64)
+        self.peak = 0          # most blocks in use after any advance
+        self.released = 0      # blocks released behind a row's window
+
+    def advance(self, row: int, first_query: int, upto: int) -> None:
+        """For a dispatch whose first query sits at ``first_query`` and
+        whose last written position is ``upto - 1``: release the row's
+        blocks wholly behind ``first_query - window`` (no query from there
+        on keeps a key of them; their entries go back to scratch) — in the
+        dispatch that passes them — then allocate up to ``upto``."""
+        bs, width, table = self.block_size, self.width, self.tables[row]
+        lo = max(first_query - self.window + 1, 0) // bs
+        for li in range(int(self.lo[row]), min(lo, int(self.hi[row]))):
+            self.alloc.decref(int(table[li % width]))
+            table[li % width] = 0
+            self.released += 1
+        self.lo[row] = max(lo, self.lo[row])
+        hi = -(-upto // bs)
+        if hi - self.lo[row] > width:
+            raise RuntimeError(
+                f"row {row}: positions {first_query}..{upto} reach over "
+                f"{hi - self.lo[row]} window blocks, the ring holds {width}")
+        for li in range(max(int(self.hi[row]), int(self.lo[row])), hi):
+            table[li % width] = self.alloc.alloc()
+        self.hi[row] = max(hi, self.hi[row])
+        self.peak = max(self.peak, self.alloc.blocks_in_use)
+
+    def release(self, row: int) -> None:
+        """Free everything the row holds (finished, preempted)."""
+        for b in self.tables[row][self.tables[row] != 0]:
+            self.alloc.decref(int(b))
+        self.tables[row] = 0
+        self.lo[row] = self.hi[row] = 0
+
+
 class GroupedBlockAllocator:
     """:class:`BlockAllocator` partitioned into ``groups`` contiguous
     spans of ``num_blocks // groups`` physical blocks — one span per dp

@@ -188,6 +188,30 @@ priority-ordered: higher ``priority`` (or an ``slo_class`` mapped
 through ``SLO_PRIORITY``) admits first; preemption resumes still jump
 to the very front regardless of class (they hold admission recency).
 
+**Layer kinds** (PR 34): a model that mixes sliding-window and full
+attention layers (decode hook ``window_layers``) is served on a pool with
+leaves BY LAYER KIND (``ops/paged_kv.py`` "Layer kinds"), each kind with
+its own block ids, allocator and table.  The full kind is every model's
+pool: ``_alloc`` / ``_tables`` / ``_held``, admission and preemption as
+they are.  The window kind (``_ring``: ``inference/paged.py WindowRing``)
+is a RING of ``ceil((window + prefill_chunk) / block_size) + 1`` entries a
+slot, sized for every slot at once, so it never runs dry and never
+preempts: ``WindowRing.advance``, called under ``_ensure_blocks`` before
+every dispatch, releases the blocks wholly behind ``position - window`` —
+in the step that passes them — and allocates up to the dispatch's last
+position; a released, preempted or finished slot frees both kinds.  The
+programs take one table per kind (:meth:`ServingEngine._bt`).  What such a
+model is NOT served with is refused by name at construction: the prefix
+trie (``prefix_caching=True``; the default ``None`` turns it off for such
+a model — a shared prefix lacks the window layers' last ``window`` keys),
+the host / NVMe tiers, ``quantize="kv8"``, ``resident_window_blocks``,
+``spec_tokens`` (a window past a row's budget writes through an unset
+table entry into scratch; a ring entry is never unset), ``decode_steps >
+1``, a draft model, and tp / dp / sp meshes.  ``stats()["kv_kinds"]`` has
+both pools, the releases, the reach the scheduler reckons from its rows'
+lengths (``kv_valid`` / ``kv_visible``, :meth:`ServingEngine._kv_reach`)
+and the refusals.
+
 Greedy decoding only: per-request outputs are token-identical to
 sequential ``generate`` (pinned in ``tests/unit/test_serving.py``,
 ``tests/unit/test_paged_serving.py``, ``tests/unit/test_spec_decode.py``,
@@ -215,6 +239,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..analysis.concurrency import (LockSanitizer, caller_site,
                                     ordered_condition)
 from ..analysis.invariants import audit_serving_engine
+from ..moe import routed
 from ..analysis.sentry import (RecompileSentry, backend_compiles,
                                install_compile_listener)
 from ..ops import (decode_attention, paged_kv, sp_attention,
@@ -230,7 +255,7 @@ from ..utils.logging import log_dist
 from ..utils.platform import on_tpu
 from .paged import (SCRATCH_BLOCK, BlockAllocator, GroupedBlockAllocator,
                     HostBlockStore, NvmeBlockStore, PrefixCache,
-                    TransportError, chain_key, chain_keys)
+                    TransportError, WindowRing, chain_key, chain_keys)
 from .spec import NGramProposer, greedy_accept, rejection_accept
 
 
@@ -671,7 +696,11 @@ class ServingEngine:
                     one compiled prefill program this many tokens a call.
     prefill_batch:  sequences per prefill call; short groups pad with
                     scratch-routed rows.
-    prefix_caching: enable the block trie.
+    prefix_caching: enable the block trie.  Default ``None`` = on, unless
+                    the model mixes sliding-window and full layers (decode
+                    hook ``window_layers``): a shared prefix is of no use
+                    there without the window layers' last ``window`` keys,
+                    which the ring has dropped; ``True`` then raises.
     spec_tokens:    speculative draft length K (0 = off).  Each decode
                     iteration proposes K tokens per slot and verifies
                     them in one K+1-token target pass.
@@ -773,7 +802,7 @@ class ServingEngine:
                  block_size: int = 32,
                  num_blocks: Optional[int] = None,
                  prefill_chunk: int = 128,
-                 prefix_caching: bool = True,
+                 prefix_caching: Optional[bool] = None,
                  decode_steps: int = 1,
                  engine_mode: str = "replicas",
                  sp: int = 1,
@@ -840,6 +869,22 @@ class ServingEngine:
         #: a row past ``topk`` keys attends ``topk`` of them; None otherwise
         self._sparse = hooks.get("sparse_attention")
         self._sparse_totals = dict.fromkeys(sparse_index_attention.COUNTS, 0)
+        #: layers of two kinds (decode hook ``window_layers``: ``{"window",
+        #: "layers": {"full", "sliding"}}``): the pool holds
+        #: leaves BY KIND, each kind with its own block ids, allocator and
+        #: table — the full kind as every model's, the window kind a ring
+        #: (module docstring "Layer kinds"); None otherwise
+        self._windows = hooks.get("window_layers")
+        #: :meth:`_kv_reach`'s span args, summed
+        self._window_totals = {"kv_valid": 0, "kv_visible": 0}
+        self._full_peak = 0        # most full-kind blocks in use after a step
+        #: width of a layer's routing record (``moe/routed.py``): a model
+        #: that holds a share of its experts also counts the absent pairs
+        self._rec_width = len(routed.RECORD_HELD) \
+            if hooks.get("experts_held") else len(routed.RECORD)
+        self._rows_absent = 0
+        if prefix_caching is None:
+            prefix_caching = not self._windows
         self._init_cache = hooks["init_cache"]
         max_ctx = hooks.get("max_seq_len")
         if max_seq_len is None:
@@ -978,6 +1023,33 @@ class ServingEngine:
         self._landmark_blocks = _LANDMARK_BLOCKS \
             if self.resident_window_blocks else 0
 
+        if self._windows:
+            # what a model with window layers is REFUSED, each by name, and
+            # whether this construction asked for it (module docstring)
+            tp = int(dict(engine.mesh.shape).get(TP_AXIS, 1))
+            refused = (
+                ("prefix_caching=True", prefix_caching),
+                (f"host_blocks={host_blocks}", int(host_blocks)),
+                ("quantize='kv8'", self.kv_quant),
+                ("resident_window_blocks", self.resident_window_blocks),
+                (f"spec_tokens={self.spec_tokens}", self.spec_tokens),
+                (f"decode_steps={self._K}", self._K > 1),
+                ("a draft model", draft is not None),
+                (f"a tp mesh (tp={tp})", tp > 1),
+                (f"engine_mode='dp_tp' (dp={self.dp_degree})",
+                 self.dp_degree > 1),
+                (f"sp={self.sp_degree}", self.sp_degree > 1))
+            #: ``stats()["kv_kinds"]["refused"]``
+            self._window_refusals = [what.split("=")[0].split(" (")[0]
+                                     for what, _ in refused]
+            unserved = [what for what, on in refused if on]
+            if unserved:
+                raise ValueError(
+                    f"{engine.module.name} mixes sliding-window and full "
+                    "attention layers (decode hook window_layers): its "
+                    "pool holds a second kind of block under a ring table "
+                    "of its own, which is not served with "
+                    + ", ".join(unserved))
         if num_blocks is None:
             num_blocks = self.dp_degree + self.slots * self._nbper
         if self.dp_degree > 1:
@@ -1112,8 +1184,17 @@ class ServingEngine:
                                          engine._config.jnp_dtype)))
             self._kv_dtype = "int8"
         else:
+            kinds = {}
+            if self._windows:
+                # the window kind's allocator and ring tables, a whole ring
+                # a slot (``inference/paged.py WindowRing``)
+                self._ring = WindowRing(
+                    self.slots, self._windows["window"], self.prefill_chunk,
+                    self.block_size)
+                kinds["window_blocks"] = self._ring.alloc.num_blocks
             mk_pool = lambda: self._init_cache(
-                num_blocks, self.block_size, engine._config.jnp_dtype)
+                num_blocks, self.block_size, engine._config.jnp_dtype,
+                **kinds)
             self._kv_dtype = jnp.dtype(jax.tree_util.tree_leaves(
                 jax.eval_shape(mk_pool))[0].dtype).name
         # the hook's (logical) shape [L, NB, HKV, bs, hd]; the pool itself
@@ -1826,10 +1907,13 @@ class ServingEngine:
             span_args.update(counts)
             for key, v in counts.items():
                 self._sparse_totals[key] += v
-        rec = tail.reshape(-1, 3)
+        rec = tail.reshape(-1, self._rec_width)
         touched, rows = int(rec[:, 0].sum()), int(rec[:, 1].sum())
         span_args.update(experts_touched=touched, expert_rows=rows,
                          expert_rows_max=int(rec[:, 2].max()))
+        if self._rec_width > 3:
+            span_args["expert_rows_absent"] = int(rec[:, 3].sum())
+            self._rows_absent += span_args["expert_rows_absent"]
         self._c_moe_touched.inc(touched)
         self._c_moe_rows.inc(rows)
         return flat[:n].reshape(shape)
@@ -1998,7 +2082,8 @@ class ServingEngine:
                 window's iterations (its largest group: the maximum)."""
                 p = prepare(params)
                 out0 = jnp.full((tokens.shape[0], K), -1, jnp.int32)
-                rec0 = jnp.zeros((int(self._pool_shape[0]), 3), jnp.int32) \
+                rec0 = jnp.zeros((int(self._pool_shape[0]),
+                                  self._rec_width), jnp.int32) \
                     if self._routing else None
                 if self._sparse:
                     # the selections' counts, beside the routing record
@@ -2966,6 +3051,8 @@ class ServingEngine:
             self._decref(b)
         self._held[slot] = []
         self._tables[slot] = 0
+        if self._windows:
+            self._ring.release(slot)
         self._tokens[slot] = 0
         self._lengths[slot] = 0
         self._window_start[slot] = 0
@@ -3064,7 +3151,56 @@ class ServingEngine:
                     return False
                 self._tables[slot, li] = b
                 self._held[slot].append(b)
+        if self._windows and slot in self._active:
+            # the window kind's side: the dispatch's first query sits at
+            # the slot's committed length, its last written position is
+            # ``upto - 1``; every slot owns a whole ring, so this never
+            # evicts or preempts
+            self._ring.advance(
+                slot, st.base if st.phase == "prefill"
+                else int(self._lengths[slot]), upto)
         return slot in self._active
+
+    def _kv_reach(self, valid) -> Dict[str, int]:
+        """Span args of a dispatch of a model with window layers, from the
+        scheduler's own bookkeeping: ``valid`` holds, for each live row,
+        the keys valid for its last query (its position + 1).  ``kv_valid``
+        is their sum times the layers, ``kv_visible`` those of them inside
+        each layer's reach — all in a full layer, at most ``window`` in a
+        sliding one.  ARITHMETIC on lengths and the configuration's window:
+        what the traffic lets a windowed read skip, not a reading of what
+        the kernels fetched (the comparison with the plain reference and
+        the kernels' device time are what hold them to the window)."""
+        if not self._windows:
+            return {}
+        valid = np.asarray(valid, np.int64)
+        layers = self._windows["layers"]
+        args = {
+            "kv_valid": int(valid.sum()) * sum(layers.values()),
+            "kv_visible": int(
+                layers["full"] * valid.sum() + layers["sliding"]
+                * np.minimum(valid, self._windows["window"]).sum())}
+        for key, v in args.items():
+            self._window_totals[key] += v
+        return args
+
+    def _bt(self, tables, rows=None):
+        """The block-table operand of a dispatch: the full kind's
+        ``tables`` (already masked to the dispatch's rows) — for a model
+        with window layers, the table of each kind, the window kind's
+        rings gathered for the same rows (``rows``: slot of each row, -1 a
+        pad row; None: row i is slot i, rows whose table is all scratch
+        are idle)."""
+        if not self._windows:
+            return jnp.asarray(tables)
+        if rows is None:
+            ring = np.where(tables[:, :1] != 0, self._ring.tables, 0)
+        else:
+            ring = np.zeros((len(rows), self._ring.width), np.int32)
+            for row, slot in enumerate(rows):
+                if slot >= 0:
+                    ring[row] = self._ring.tables[slot]
+        return {"full": jnp.asarray(tables), "window": jnp.asarray(ring)}
 
     # --------------------------------------------------------------- schedule
     def _admit(self):
@@ -3416,6 +3552,7 @@ class ServingEngine:
             self._c_iterations.inc()
             self.timeline.step = self.iterations
             admitted0, preempted0 = self.admitted, self.preempted
+            released0 = self._ring.released if self._windows else 0
             self._admit()
             self._refresh_masks()
         with span("step.prefill") as phase:
@@ -3458,6 +3595,13 @@ class ServingEngine:
                 blocks_in_use=self._alloc.blocks_in_use,
                 active=len(self._active), pending=len(self._pending),
                 kv_s=self._kv_s)
+            if self._windows:
+                self._full_peak = max(self._full_peak,
+                                      self._alloc.blocks_in_use)
+                step_args.update(
+                    window_num_blocks=self._ring.alloc.num_blocks,
+                    window_blocks_in_use=self._ring.alloc.blocks_in_use,
+                    window_blocks_released=self._ring.released - released0)
             if self._step_log is not None:
                 self._step_log.append({k: step_args[k] for k in (
                     "iteration", "admitted", "evicted", "blocks_in_use")})
@@ -3866,13 +4010,15 @@ class ServingEngine:
         bt = np.zeros_like(self._tables)
         bt[dec] = self._tables[dec]
         args = (params, self._cache, jnp.asarray(self._tokens),
-                jnp.asarray(self._lengths), jnp.asarray(bt))
+                jnp.asarray(self._lengths), self._bt(bt))
         if self.resident_window_blocks:
             args += (jnp.asarray(self._window_start),)
         args += self._samp_args(self._decode_counts())
         decode_fn = self._get_decode_fn()
         with self.timeline.span("decode", slots=len(dec),
-                                **self._sampler_rows(dec)) as span_args:
+                                **self._sampler_rows(dec),
+                                **self._kv_reach(self._lengths[dec] + 1),
+                                ) as span_args:
             with self._decode_ctx():
                 nxt, self._cache = decode_fn(*args)
             nxt = self._split_record(np.asarray(nxt), (self.slots,),
@@ -4199,8 +4345,9 @@ class ServingEngine:
             valid[row] = v
             rows.append((slot, v))
         samp = self._samp_args_rows(group, j)
-        packed = (jnp.asarray(ids), jnp.asarray(bt), jnp.asarray(base),
-                  jnp.asarray(valid))
+        packed = (jnp.asarray(ids),
+                  self._bt(bt, list(group) + [-1] * (j - len(group))),
+                  jnp.asarray(base), jnp.asarray(valid))
         if self._draft is not None:
             args = (params, self._draft.params, self._cache, self._dcache,
                     *packed, *samp)
@@ -4221,6 +4368,7 @@ class ServingEngine:
                 # blocks the rows' reads walk: cdiv(base + valid, bs) each
                 kv_blocks=int((-(-(base + valid) // self.block_size)).sum()),
                 **self._sampler_rows(group),
+                **self._kv_reach((base + valid)[:len(group)]),
         ) as span_args:
             if self._draft is not None:
                 with self._tp_ctx():
@@ -4381,6 +4529,25 @@ class ServingEngine:
                 (self.tp_degree if self._dcache_sharded else 1)
         return out
 
+    def _kv_kinds(self) -> Dict[str, Any]:
+        """``stats()["kv_kinds"]`` (a model with window layers)."""
+        def kind(alloc, layers, table_width, peak):
+            return {"layers": layers, "num_blocks": alloc.num_blocks,
+                    "blocks_in_use": alloc.blocks_in_use,
+                    "peak_blocks_in_use": peak, "table_width": table_width}
+
+        layers = self._windows["layers"]
+        return {
+            "window": self._windows["window"],
+            "full": kind(self._alloc, layers["full"], self._nbper,
+                         self._full_peak),
+            "sliding": {**kind(self._ring.alloc, layers["sliding"],
+                               self._ring.width, self._ring.peak),
+                        "released": self._ring.released},
+            **self._window_totals,
+            "expert_rows_absent": self._rows_absent,
+            "refused": list(self._window_refusals)}
+
     def _latency_stats(self) -> Dict[str, Any]:
         """TTFT/TPOT percentiles over every finished request (cumulative
         across serve calls, like the other counters) — read from the
@@ -4438,6 +4605,11 @@ class ServingEngine:
             # counters (:meth:`_split_record`); None for any other model
             "sparse_attn": {**self._program_meta.get("sparse_attn", {}),
                             **self._sparse_totals} if self._sparse else None,
+            # a model that mixes sliding-window and full layers: both pools
+            # by kind, the window blocks released behind the rows, the
+            # spans' reach counters summed, and what such a model is
+            # refused; None for any other model
+            "kv_kinds": self._kv_kinds() if self._windows else None,
             "admitted": self.admitted,
             "evicted": self.preempted,
             "cancelled": int(self._c_cancelled.value),

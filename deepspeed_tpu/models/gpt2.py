@@ -392,7 +392,7 @@ def cache_update(ck, cv, k, v, pos):
 
 
 def _cached_attention(q, k, v, ck, cv, pos, block_tables=None,
-                      chunk_valid=None, layer=None):
+                      chunk_valid=None, layer=None, window: int = 0):
     """Write new KV + attend, on either cache layout.  Contiguous
     (``block_tables is None``): ck/cv are one layer's [B, H, S, hd]
     per-sequence regions.  Paged: ck/cv are the WHOLE stacked
@@ -403,8 +403,10 @@ def _cached_attention(q, k, v, ck, cv, pos, block_tables=None,
     through its layer loop untouched.  ``chunk_valid`` (int32 [B]) marks
     how many of a T>1 chunk's tokens are real — pads write to the scratch
     block, and the read of a prefill chunk walks the blocks ``pos +
-    chunk_valid`` reaches and no further.  Shared by every decode-hook
-    model family."""
+    chunk_valid`` reaches and no further.  ``window`` (static, paged
+    only): a sliding-window layer — ck/cv and ``block_tables`` are the
+    window kind's leaves and ring (``ops/paged_kv.py`` "Layer kinds").
+    Shared by every decode-hook model family."""
     from ..ops.decode_attention import decode_attention, \
         paged_decode_attention
 
@@ -414,9 +416,10 @@ def _cached_attention(q, k, v, ck, cv, pos, block_tables=None,
     from ..ops.paged_kv import paged_cache_update
 
     ck, cv = paged_cache_update(ck, cv, k, v, pos, block_tables,
-                                valid=chunk_valid, layer=layer)
+                                valid=chunk_valid, layer=layer,
+                                ring=bool(window))
     return paged_decode_attention(q, ck, cv, block_tables, pos, layer=layer,
-                                  valid=chunk_valid), ck, cv
+                                  valid=chunk_valid, window=window), ck, cv
 
 
 def _block_cached_body(cfg: GPT2Config, x, get, mm, ck, cv, pos,

@@ -55,11 +55,42 @@ class LlamaConfig:
     #: through :attr:`head_dim` (so that ``dataclasses.replace`` of the
     #: hidden size or the head count carries no stale width forward)
     head_width: Optional[int] = None
+    #: the block norms: ``"rms"`` or ``"layernorm"`` (Cohere's: the mean
+    #: subtracted, a scale and no bias), both at ``rms_eps``
+    norm: str = "rms"
+    #: ``True``: attention and the FFN both read ONE norm of the block's
+    #: input and both add to the residual (``x + a + m``; no ``mlp_norm``)
+    parallel_block: bool = False
+    #: rotary pairs ``(2i, 2i + 1)`` (GPT-J's convention) instead of
+    #: ``(i, i + head_dim / 2)``
+    rope_interleaved: bool = False
+    #: one period of the layer pattern, ``"sliding"`` | ``"full"`` each
+    #: (``()``: every layer attends everything, rotated).  A ``"sliding"``
+    #: layer rotates q and k and a query keeps its ``sliding_window`` newest
+    #: keys, itself included; a ``"full"`` layer of a patterned model
+    #: attends every key and takes NO rotation.  ``num_layers`` is a whole
+    #: number of periods.
+    layer_kinds: tuple = ()
+    sliding_window: int = 0
+    #: the head is the token table transposed (no ``lm_head`` leaf)
+    tie_embeddings: bool = False
 
     def __post_init__(self):
         if self.qk_norm not in (False, True, "head"):
             raise ValueError(f"qk_norm={self.qk_norm!r}: False, True (one "
                              "norm over all heads' features) or 'head'")
+        if self.norm not in ("rms", "layernorm"):
+            raise ValueError(f"norm={self.norm!r}: 'rms' or 'layernorm'")
+        self.layer_kinds = tuple(self.layer_kinds)
+        if any(k not in ("sliding", "full") for k in self.layer_kinds):
+            raise ValueError(f"layer_kinds={self.layer_kinds!r}: 'sliding' "
+                             "or 'full' each")
+        if self.layer_kinds and self.num_layers % len(self.layer_kinds):
+            raise ValueError(
+                f"num_layers={self.num_layers} is not a whole number of "
+                f"periods of {len(self.layer_kinds)} layers")
+        if "sliding" in self.layer_kinds and self.sliding_window < 1:
+            raise ValueError("sliding layers need sliding_window >= 1")
 
     @property
     def head_dim(self) -> int:
@@ -88,8 +119,10 @@ class LlamaConfig:
         attn = d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd + \
             self.num_heads * hd * d
         mlp = 3 * d * f
-        norms = 2 * d + sum(qk_norm_widths(self))
-        return v * d + l * (attn + mlp + norms) + d + d * v
+        norms = (1 if self.parallel_block else 2) * d \
+            + sum(qk_norm_widths(self))
+        head = 0 if self.tie_embeddings else d * v
+        return v * d + l * (attn + mlp + norms) + d + head
 
 
 def qk_norm_widths(cfg: LlamaConfig):
@@ -147,6 +180,10 @@ def init_params(cfg: LlamaConfig, rng) -> PyTree:
     }
     for name, width in zip(("q_norm", "k_norm"), qk_norm_widths(cfg)):
         params["blocks"][name] = jnp.ones((l, width))
+    if cfg.parallel_block:
+        del params["blocks"]["mlp_norm"]
+    if cfg.tie_embeddings:
+        del params["lm_head"]
     return params
 
 
@@ -154,6 +191,38 @@ def rms_norm(x, scale, eps: float = 1e-5):
     x32 = x.astype(jnp.float32)
     var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
     return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
+
+
+def layer_norm(x, scale, eps: float = 1e-5):
+    """Cohere's LayerNorm: the mean subtracted, a scale, no bias."""
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
+
+
+def block_norm(cfg: LlamaConfig, x, scale):
+    """The norm ``cfg.norm`` names, at ``cfg.rms_eps``."""
+    if cfg.norm == "layernorm":
+        return layer_norm(x, scale, cfg.rms_eps)
+    return rms_norm(x, scale, cfg.rms_eps)
+
+
+def head_logits(cfg: LlamaConfig, params, x):
+    """The final norm's output times the head — the token table itself for
+    ``tie_embeddings``."""
+    if cfg.tie_embeddings:
+        return jnp.einsum("...d,vd->...v", x,
+                          params["embed"].astype(x.dtype))
+    return x @ params["lm_head"].astype(x.dtype)
+
+
+def kind_of(cfg: LlamaConfig, kind):
+    """``(rotated, window)`` of a layer of ``kind`` (``None``: a model
+    without a pattern)."""
+    if kind is None:
+        return True, 0
+    return kind == "sliding", cfg.sliding_window if kind == "sliding" else 0
 
 
 def rope_angles(cfg: LlamaConfig, seq_len: int, offset: int = 0,
@@ -168,8 +237,9 @@ def rope_angles(cfg: LlamaConfig, seq_len: int, offset: int = 0,
     return jnp.cos(angles), jnp.sin(angles)
 
 
-def apply_rope(x, cos, sin):
-    """x: [B, H, S, hd]; rotate pairs (HF half-split convention).
+def apply_rope(x, cos, sin, interleaved: bool = False):
+    """x: [B, H, S, hd]; rotate pairs (HF half-split convention; with
+    ``interleaved`` the pairs are ``(2i, 2i + 1)``).
 
     ``cos``/``sin`` are [S, hd/2] (shared across the batch) or [B, S, hd/2]
     (per-sequence positions — continuous-batching slots each sit at their
@@ -181,20 +251,31 @@ def apply_rope(x, cos, sin):
     stream in bf16 is what makes the MXU path fast.
     """
     hd = x.shape[-1]
-    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
+    if interleaved:
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+    else:
+        x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
     if cos.ndim == 3:
         c = cos[:, None, :, :]
         s = sin[:, None, :, :]
     else:
         c = cos[None, None, :, :]
         s = sin[None, None, :, :]
-    out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+    if interleaved:
+        out = jnp.stack([x1 * c - x2 * s, x2 * c + x1 * s],
+                        axis=-1).reshape(x.shape)
+    else:
+        out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
     return out.astype(x.dtype)
 
 
-def _attention(cfg: LlamaConfig, q, k, v):
+def _attention(cfg: LlamaConfig, q, k, v, window: int = 0):
     from ..parallel import sequence as seq_parallel
 
+    if window:
+        # a sliding layer's uncached attention is the plain masked one (the
+        # served path reads the window through the paged kernels)
+        return _dense_attention(cfg, q, k, v, window)
     if seq_parallel.sp_size() > 1:
         return seq_parallel.sequence_parallel_attention(
             q, k, v, causal=True, impl=cfg.sp_impl)
@@ -203,6 +284,10 @@ def _attention(cfg: LlamaConfig, q, k, v):
         use_flash = on_tpu()
     if use_flash:
         return seq_parallel.mesh_flash_attention(q, k, v, causal=True)
+    return _dense_attention(cfg, q, k, v)
+
+
+def _dense_attention(cfg: LlamaConfig, q, k, v, window: int = 0):
     rep = cfg.num_heads // cfg.num_kv_heads
     if rep > 1:
         k = jnp.repeat(k, rep, axis=1)
@@ -210,17 +295,23 @@ def _attention(cfg: LlamaConfig, q, k, v):
     s_len = q.shape[2]
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(cfg.head_dim)
     mask = jnp.tril(jnp.ones((s_len, k.shape[2]), bool))
+    if window:
+        mask = mask & ~jnp.tril(mask, -window)
     scores = jnp.where(mask[None, None], scores.astype(jnp.float32), -1e9)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
-def attn_apply(cfg: LlamaConfig, layer: PyTree, x, cos, sin, attention=None):
+def attn_apply(cfg: LlamaConfig, layer: PyTree, x, cos, sin, attention=None,
+               kind=None, delta: bool = False):
     """The attention half of a block (pre-norm, q/k/v, optional q/k-norm,
     RoPE, causal attention, output projection, residual) — llama's and
     mixtral's uncached forwards share it.  ``attention(y, q, k, v)``
     replaces the dense causal attention (mixtral's learned sparse one,
-    which reads the normed input ``y``)."""
+    which reads the normed input ``y``).  ``kind``: the layer's kind in a
+    patterned model (:func:`kind_of`).  ``delta`` (a parallel block): the
+    pair ``(normed input, attention output)`` instead of the new
+    residual."""
     # matmuls route through gpt2._qmm: dense leaves trace to the identical
     # ``x @ w.astype`` HLO; INT8 records (quant-aware serving prefill)
     # dequantize at point of use instead of crashing on a dict leaf
@@ -229,28 +320,42 @@ def attn_apply(cfg: LlamaConfig, layer: PyTree, x, cos, sin, attention=None):
     b, s, d = x.shape
     h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
 
+    rotated, window = kind_of(cfg, kind)
     with jax.named_scope("layer/attn"):
-        y = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
+        y = block_norm(cfg, x, layer["attn_norm"])
         q, k = qk_normed(cfg, _qmm(y, layer["q_w"]), _qmm(y, layer["k_w"]),
                          layer.__getitem__)
         q = q.reshape(b, s, h, hd)
         k = k.reshape(b, s, hkv, hd)
         v = _qmm(y, layer["v_w"]).reshape(b, s, hkv, hd)
-        q = apply_rope(q.transpose(0, 2, 1, 3), cos, sin)
-        k = apply_rope(k.transpose(0, 2, 1, 3), cos, sin)
+        q = q.transpose(0, 2, 1, 3)
+        if rotated:
+            q = apply_rope(q, cos, sin, cfg.rope_interleaved)
+        k = k.transpose(0, 2, 1, 3)
+        if rotated:
+            k = apply_rope(k, cos, sin, cfg.rope_interleaved)
         v = v.transpose(0, 2, 1, 3)
-        attn = _attention(cfg, q, k, v) if attention is None \
+        attn = _attention(cfg, q, k, v, window) if attention is None \
             else attention(y, q, k, v)
         attn = attn.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
-        return x + _qmm(attn, layer["o_w"], x.dtype)
+        out = _qmm(attn, layer["o_w"], x.dtype)
+        return (y, out) if delta else x + out
 
 
 def block_apply(cfg: LlamaConfig, layer: PyTree, x, cos, sin):
     from .gpt2 import _qmm
 
-    x = attn_apply(cfg, layer, x, cos, sin)
+    if cfg.layer_kinds:
+        raise NotImplementedError(
+            "a layer pattern (layer_kinds) is built by models/mixtral.py; "
+            "the dense Llama block has none")
+    if cfg.parallel_block:
+        y, a = attn_apply(cfg, layer, x, cos, sin, delta=True)
+        x = x + a
+    else:
+        x = attn_apply(cfg, layer, x, cos, sin)
+        y = block_norm(cfg, x, layer["mlp_norm"])
     with jax.named_scope("layer/mlp"):
-        y = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
         gate = jax.nn.silu(_qmm(y, layer["w1"]))
         up = _qmm(y, layer["w3"])
         return x + _qmm(gate * up, layer["w2"], x.dtype)
@@ -277,8 +382,8 @@ def forward(cfg: LlamaConfig, params: PyTree, input_ids, rng=None,
 
     x = scan_layers_grouped(step, x, params["blocks"],
                             getattr(cfg, "scan_group_size", 1))
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    return x @ params["lm_head"].astype(x.dtype)
+    x = block_norm(cfg, x, params["final_norm"])
+    return head_logits(cfg, params, x)
 
 
 def init_cache(cfg: LlamaConfig, batch_size: int, max_len: int,
@@ -292,7 +397,7 @@ def init_cache(cfg: LlamaConfig, batch_size: int, max_len: int,
 def _rope_cached(cfg: LlamaConfig, x, pos):
     """Rotary embedding at traced offset ``pos`` (scalar, or int32 [B] for
     per-sequence decode positions).  x: [B, H, T, hd], rotated over its
-    whole last dim."""
+    whole last dim (in the pairing ``cfg.rope_interleaved`` names)."""
     hd = x.shape[-1]
     inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, hd, 2,
                                                     dtype=jnp.float32) / hd))
@@ -303,12 +408,13 @@ def _rope_cached(cfg: LlamaConfig, x, pos):
     else:
         p = pos.astype(jnp.float32)[:, None] + t[None, :]        # [B, T]
         angles = p[..., None] * inv_freq[None, None, :]          # [B, T, hd/2]
-    return apply_rope(x, jnp.cos(angles), jnp.sin(angles))
+    return apply_rope(x, jnp.cos(angles), jnp.sin(angles),
+                      cfg.rope_interleaved)
 
 
 def _block_cached_body(cfg: LlamaConfig, x, get, mm, ck, cv, pos,
                        mlp=None, block_tables=None, chunk_valid=None,
-                       layer=None, attend=None, extra=None):
+                       layer=None, attend=None, extra=None, kind=None):
     """Cached-attention block parameterized by weight access (``get(name)``
     small leaf, ``mm(y, name, dtype)`` matmul — shared by the scan and
     layer-indexed quantized decode paths, see gpt2.decode_over_layers).
@@ -320,30 +426,41 @@ def _block_cached_body(cfg: LlamaConfig, x, get, mm, ck, cv, pos,
     state of its own — a third pool leaf — in ``extra``) and makes this
     return ``(x, ck, cv, extra, aux)``.
     ``block_tables``/``chunk_valid`` switch ck/cv to the whole paged pool,
-    addressed in place at ``layer`` (contract in gpt2._cached_attention)."""
+    addressed in place at ``layer`` (contract in gpt2._cached_attention).
+    ``kind`` (a patterned model, :func:`kind_of`): whether q and k are
+    rotated and how far a query reaches; ck/cv, ``block_tables`` and
+    ``layer`` are then that kind's own."""
     b, t, d = x.shape
     h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    rotated, window = kind_of(cfg, kind)
 
     with jax.named_scope("layer/attn"):
-        y = rms_norm(x, get("attn_norm"), cfg.rms_eps)
+        y = block_norm(cfg, x, get("attn_norm"))
         q, k = qk_normed(cfg, mm(y, "q_w", None), mm(y, "k_w", None), get)
         q = q.reshape(b, t, h, hd)
         k = k.reshape(b, t, hkv, hd)
         v = mm(y, "v_w", None).reshape(b, t, hkv, hd)
-        q = _rope_cached(cfg, q.transpose(0, 2, 1, 3), pos)
-        k = _rope_cached(cfg, k.transpose(0, 2, 1, 3), pos)
+        q = q.transpose(0, 2, 1, 3)
+        if rotated:
+            q = _rope_cached(cfg, q, pos)
+        k = k.transpose(0, 2, 1, 3)
+        if rotated:
+            k = _rope_cached(cfg, k, pos)
         v = v.transpose(0, 2, 1, 3)
         if attend is not None:
             attn, ck, cv, extra = attend(y, q, k, v, ck, cv, extra)
         else:
             from .gpt2 import _cached_attention
 
-            attn, ck, cv = _cached_attention(q, k, v, ck, cv, pos,
-                                             block_tables, chunk_valid, layer)
+            attn, ck, cv = _cached_attention(
+                q, k, v, ck, cv, pos, block_tables, chunk_valid, layer,
+                window=window)
         attn = attn.transpose(0, 2, 1, 3).reshape(b, t, h * hd)
         x = x + mm(attn, "o_w", x.dtype)
 
-    y = rms_norm(x, get("mlp_norm"), cfg.rms_eps)
+    if not cfg.parallel_block:
+        # (a parallel block's FFN reads the ONE norm of the block's input)
+        y = block_norm(cfg, x, get("mlp_norm"))
     if mlp is not None:
         out, aux = mlp(y)
         if attend is not None:
@@ -358,16 +475,18 @@ def _block_cached_body(cfg: LlamaConfig, x, get, mm, ck, cv, pos,
 
 def _block_cached(cfg: LlamaConfig, x, layer, ck, cv, pos, mlp_fn=None,
                   block_tables=None, chunk_valid=None, index=None,
-                  attend_fn=None):
+                  attend_fn=None, kind=None):
     """``layer`` is the pre-sliced weight dict, ``index`` its position in
-    the stack (paged pools only).  With ``attend_fn``, ``ck`` is the pair
-    ``(K, extra)`` the layer loop carries (:func:`forward_cached`)."""
+    the stack (paged pools only; in its KIND's stack for a patterned
+    model).  With ``attend_fn``, ``ck`` is the pair ``(K, extra)`` the
+    layer loop carries (:func:`forward_cached`)."""
     from .gpt2 import layer_accessors
 
     body = functools.partial(
         _block_cached_body, cfg, x, *layer_accessors(layer),
         mlp=None if mlp_fn is None else (lambda y: mlp_fn(layer, y)),
-        block_tables=block_tables, chunk_valid=chunk_valid, layer=index)
+        block_tables=block_tables, chunk_valid=chunk_valid, layer=index,
+        kind=kind)
     if attend_fn is None:
         return body(ck, cv, pos)
     x, ck, cv, extra, aux = body(
@@ -386,9 +505,58 @@ def live_tokens(input_ids, lengths=None, block_tables=None):
     b, t = input_ids.shape
     if block_tables is None:
         return jnp.ones((b, t), bool)
+    if isinstance(block_tables, dict):     # a table per layer kind
+        block_tables = block_tables["full"]
     if t == 1 or lengths is None:
         return jnp.broadcast_to(block_tables[:, :1] != 0, (b, t))
     return jnp.arange(t)[None, :] < jnp.asarray(lengths)[:, None]
+
+
+#: the pool leaves and the table of each layer kind of a patterned model
+KIND_LEAVES = {"full": ("k", "v", "full"), "sliding": ("kw", "vw", "window")}
+
+
+def scan_periods_cached(cfg: LlamaConfig, step, x, blocks, cache,
+                        block_tables):
+    """The layer loop of a patterned model (``cfg.layer_kinds``) over the
+    block-paged pool: a ``lax.scan`` over PERIODS whose body is the
+    period's layers written out, so that each layer's kind — rotated or
+    not, how far it reaches, which leaves and which table it addresses —
+    is static in the program, where a ``lax.cond`` on a traced kind would
+    hold both branches and both pools in every layer.  All layers have the
+    same weight shapes, so the ``[L, ...]`` stacks stay, and layer ``period
+    * P + j`` is read out of them at a traced index.  ``step(x, layer, ck, cv, index, table, kind) -> (x,
+    ck, cv, aux)`` with ``index`` the layer's place among its KIND's layers
+    (``ops/paged_kv.py`` "Layer kinds"); ``cache`` holds ``k`` / ``v``
+    (full) and ``kw`` / ``vw`` (window), ``block_tables`` the tables
+    ``"full"`` / ``"window"``.  -> ``(x, cache, aux stacked [L, ...])``."""
+    kinds = cfg.layer_kinds
+    p, n = len(kinds), cfg.num_layers
+
+    def body(carry, period):
+        x, pools = carry
+        pools, auxes = dict(pools), []
+        for j, kind in enumerate(kinds):
+            same = [i for i in range(p) if kinds[i] == kind]
+            ck, cv, table = KIND_LEAVES[kind]
+            # the layer's weights, read where they lie in the stacks (a
+            # static slice of a parameter is a COPY on a TPU: 134 MB for
+            # one layer's q or o projection at Command A+'s widths)
+            layer = jax.tree_util.tree_map(
+                lambda a: jax.lax.dynamic_index_in_dim(
+                    a, period * p + j, keepdims=False), blocks)
+            x, pools[ck], pools[cv], aux = step(
+                x, layer, pools[ck], pools[cv],
+                period * len(same) + same.index(j), block_tables[table],
+                kind)
+            auxes.append(aux)
+        return (x, pools), jax.tree_util.tree_map(
+            lambda *a: jnp.stack(a), *auxes)
+
+    (x, cache), aux = jax.lax.scan(
+        body, (x, cache), jnp.arange(n // p, dtype=jnp.int32))
+    return x, cache, jax.tree_util.tree_map(
+        lambda a: a.reshape((n,) + a.shape[2:]), aux)
 
 
 def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
@@ -433,7 +601,20 @@ def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
     # sequence-parallel prefill hook (no-op outside an sp context)
     x = shard_seq(x)
 
-    if mlp_fn is None:
+    if cfg.layer_kinds:
+        if not paged or mlp_fn is None or attend_fn is not None:
+            raise NotImplementedError(
+                "a layer pattern with sliding-window layers (layer_kinds) "
+                "is served through the block-paged pool (init_serving / "
+                "ServingEngine) by models/mixtral.py: the contiguous cache "
+                "of InferenceEngine.generate has one kind of state")
+        x, kv, records = scan_periods_cached(
+            cfg, lambda x, layer, ck, cv, l, table, kind: _block_cached(
+                cfg, x, layer, ck, cv, step_pos, mlp_fn=mlp_fn,
+                block_tables=table, chunk_valid=chunk_valid, index=l,
+                kind=kind),
+            x, params["blocks"], cache, block_tables)
+    elif mlp_fn is None:
         x, ks, vs = decode_over_layers(
             lambda x, get, mm, ck, cv, layer: _block_cached_body(
                 cfg, x, get, mm, ck, cv, step_pos,
@@ -455,8 +636,10 @@ def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
             ks, extra = ks
     if not all_positions:
         x = _gather_last(x, lengths if not per_row else None)
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    logits = x @ params["lm_head"].astype(x.dtype)
+    x = block_norm(cfg, x, params["final_norm"])
+    logits = head_logits(cfg, params, x)
+    if cfg.layer_kinds:
+        return logits, kv, records
     if mlp_fn is None:
         return logits, {"k": ks, "v": vs}
     if attend_fn is not None:
@@ -525,8 +708,8 @@ def build(cfg: Optional[LlamaConfig] = None, **overrides) -> ModelSpec:
         return block_apply(cfg, layer, x, cos, sin)
 
     def pp_head_loss(params, x, targets):
-        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-        logits = (x @ params["lm_head"].astype(x.dtype)).astype(jnp.float32)
+        x = block_norm(cfg, x, params["final_norm"])
+        logits = head_logits(cfg, params, x).astype(jnp.float32)
         logp = jax.nn.log_softmax(logits, axis=-1)
         valid = targets >= 0  # -100 = ignore (HF convention)
         safe = jnp.where(valid, targets, 0)

@@ -21,6 +21,16 @@ ATTENTION: a config with ``index_heads > 0`` gives every layer an indexer
 (``idx_q_w`` / ``idx_k_w`` / ``idx_w_w`` / ``idx_k_norm``) whose key is a
 third leaf of the cache and whose scores choose the ``index_topk`` keys a
 query attends (``ops/sparse_index_attention.py``).
+Command A+ (``MixtralConfig.command_a_plus``) is the PARALLEL block
+(``parallel_block``: attention and the expert layer read one Cohere
+LayerNorm of the block's input and both add to the residual) over a layer
+PATTERN (``layer_kinds``: three sliding-window layers with interleaved
+rotary to one full-attention layer without rotation, each kind on pool
+leaves and a block table of its own), 128 sigmoid-scored experts top-8
+(``router_score``) beside four SHARED experts every token runs, combined by
+averaging (``shared_experts``), a tied head — and an
+expert layer that can be told which experts it HOLDS (``experts_held``: one
+chip's share of an expert-parallel group, ``moe/routed.py``).
 """
 
 from __future__ import annotations
@@ -62,12 +72,44 @@ class MixtralConfig(L.LlamaConfig):
     index_heads: int = 0
     index_head_dim: int = 64
     index_topk: int = 2048
+    #: the router's scores over all experts: their ``"softmax"``, or each
+    #: logit's own ``"sigmoid"``
+    router_score: str = "softmax"
+    #: dense SwiGLU experts of width ``ffn_size`` that EVERY token runs,
+    #: beside the routed ones; their outputs' AVERAGE is added to the
+    #: routed sum
+    shared_experts: int = 0
+    #: ``(first, count)``: the routed experts whose weights this model
+    #: HOLDS (one chip's share of an expert-parallel group); the router
+    #: still scores all ``num_experts`` and the expert layer returns the
+    #: held experts' partial sum (``moe/routed.py``).  ``None``: all.
+    experts_held: Optional[tuple] = None
 
     def __post_init__(self):
         super().__post_init__()
         if not 1 <= self.top_k <= self.num_experts:
             raise ValueError(f"top_k={self.top_k} outside "
                              f"[1, num_experts={self.num_experts}]")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_score={self.router_score!r}: "
+                             "'softmax' or 'sigmoid'")
+        if self.experts_held is not None:
+            first, count = self.experts_held = tuple(
+                int(v) for v in self.experts_held)
+            if not (0 <= first and 1 <= count
+                    and first + count <= self.num_experts):
+                raise ValueError(
+                    f"experts_held={self.experts_held} outside the "
+                    f"{self.num_experts} experts")
+        if self.layer_kinds and self.index_heads:
+            raise ValueError("a layer pattern (layer_kinds) and a learned "
+                             "indexer (index_heads) are not built together")
+
+    @property
+    def experts_here(self) -> int:
+        """The routed experts whose weights the model holds."""
+        return self.experts_held[1] if self.experts_held else \
+            self.num_experts
 
     @staticmethod
     def mixtral_8x7b() -> "MixtralConfig":
@@ -103,6 +145,29 @@ class MixtralConfig(L.LlamaConfig):
                              index_head_dim=64, index_topk=2048)
 
     @staticmethod
+    def command_a_plus() -> "MixtralConfig":
+        """CohereLabs/command-a-plus-05-2026's language model
+        (``cohere2_moe``, 218B-A25B; the vision tower is not built): 32
+        parallel blocks under Cohere's LayerNorm, d 4096, 128 query / 8 KV
+        heads x 128, ``[sliding, sliding, sliding, full] x 8`` with a
+        4,096-key window and interleaved rotary (theta 50,000) on the
+        sliding layers and no rotation on the full ones, 128 sigmoid-scored
+        SwiGLU experts of width 4,096 top-8 renormalised beside four
+        averaged shared experts, a tied head.  The published model: one
+        chip's share of it (``experts_held``, fewer layers, a vocabulary
+        slice) is a deployment's to state."""
+        return MixtralConfig(vocab_size=262144, max_seq_len=200000,
+                             num_layers=32, num_heads=128, num_kv_heads=8,
+                             head_width=128, hidden_size=4096, ffn_size=4096,
+                             rope_theta=50000.0, rms_eps=1e-5,
+                             norm="layernorm", parallel_block=True,
+                             rope_interleaved=True,
+                             layer_kinds=("sliding",) * 3 + ("full",),
+                             sliding_window=4096, tie_embeddings=True,
+                             num_experts=128, top_k=8, norm_topk_prob=True,
+                             router_score="sigmoid", shared_experts=4)
+
+    @staticmethod
     def tiny(vocab_size: int = 512) -> "MixtralConfig":
         return MixtralConfig(vocab_size=vocab_size, max_seq_len=128,
                              num_layers=2, num_heads=4, num_kv_heads=2,
@@ -120,14 +185,14 @@ class MixtralConfig(L.LlamaConfig):
         indexer = (d * (self.index_heads * di + di + self.index_heads)
                    + 2 * di) if self.index_heads else 0
         return base + self.num_layers * (
-            (self.num_experts - 1) * per_layer_mlp + d * self.num_experts
-            + indexer)
+            (self.experts_here + self.shared_experts - 1) * per_layer_mlp
+            + d * self.num_experts + indexer)
 
     def active_params(self) -> int:
         """Parameters one token multiplies with: everything but the
-        experts it was not routed to."""
-        idle = (self.num_experts - self.top_k) * 3 * self.hidden_size \
-            * self.ffn_size
+        experts it was not routed to (of a held share, in proportion)."""
+        idle = (self.num_experts - self.top_k) * self.experts_here \
+            // self.num_experts * 3 * self.hidden_size * self.ffn_size
         return self.num_params() - self.num_layers * idle
 
     def moe_cfg(self) -> MoEConfig:
@@ -152,9 +217,18 @@ def init_params(cfg: MixtralConfig, rng) -> PyTree:
     for k in ("w1", "w2", "w3"):
         del blocks[k]
     blocks["gate_w"] = normal(keys[0], (l, d, e))
+    e = cfg.experts_here
     blocks["experts_w1"] = normal(keys[1], (l, e, d, f))
     blocks["experts_w3"] = normal(keys[2], (l, e, d, f))
     blocks["experts_w2"] = normal(keys[3], (l, e, f, d))
+    if cfg.shared_experts:
+        # the shared experts side by side: one SwiGLU of width Sh * f
+        # (expert j is columns / rows ``j * f .. (j + 1) * f``)
+        sf = cfg.shared_experts * f
+        skeys = jax.random.split(jax.random.fold_in(rng, 13), 3)
+        blocks["shared_w1"] = normal(skeys[0], (l, d, sf))
+        blocks["shared_w3"] = normal(skeys[1], (l, d, sf))
+        blocks["shared_w2"] = normal(skeys[2], (l, sf, d))
     if cfg.index_heads:
         hi, di = cfg.index_heads, cfg.index_head_dim
         ikeys = jax.random.split(jax.random.fold_in(rng, 11), 3)
@@ -181,8 +255,10 @@ def _indexer(cfg: MixtralConfig, get, mm, y, rope):
     return rope(qi), rope(ki), wi
 
 
-def _moe_block(cfg: MixtralConfig, layer: PyTree, x, cos, sin, train: bool = True):
-    """Llama attention + MoE FFN; returns (x, aux_loss)."""
+def _moe_block(cfg: MixtralConfig, layer: PyTree, x, cos, sin,
+               train: bool = True, kind=None):
+    """Llama attention + MoE FFN; returns (x, aux_loss).  ``kind``: the
+    layer's kind in a patterned model."""
     attention = None
     if cfg.index_heads:
         from .gpt2 import layer_accessors
@@ -195,8 +271,13 @@ def _moe_block(cfg: MixtralConfig, layer: PyTree, x, cos, sin, train: bool = Tru
             return sparse_attention.sparse_attention_uncached(
                 q, k, v, qi, wi, ki[:, 0], cfg.index_topk)
 
-    x = L.attn_apply(cfg, layer, x, cos, sin, attention)
-    y = L.rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
+    if cfg.parallel_block:
+        y, a = L.attn_apply(cfg, layer, x, cos, sin, attention, kind,
+                            delta=True)
+        x = x + a
+    else:
+        x = L.attn_apply(cfg, layer, x, cos, sin, attention, kind)
+        y = L.block_norm(cfg, x, layer["mlp_norm"])
     if train:
         moe_out, aux = _moe_ffn(cfg, layer, y)
     else:
@@ -210,18 +291,36 @@ def forward_with_aux(cfg: MixtralConfig, params: PyTree, input_ids,
     x = params["embed"][input_ids].astype(params["embed"].dtype)
     cos, sin = L.rope_angles(cfg, s)
 
-    def body(carry, layer):
+    if train and (cfg.experts_held is not None or cfg.shared_experts
+                  or cfg.router_score != "softmax"):
+        raise NotImplementedError(
+            "training's capacity gate (moe/layer.py) is a softmax router "
+            "over experts that are all here; shared experts, a sigmoid "
+            "router and a held share (experts_held) are inference paths")
+    kinds = cfg.layer_kinds or (None,)
+    blocks = params["blocks"]
+    if cfg.layer_kinds:
+        # a scan over periods, the period's layers written out (static kinds)
+        blocks = jax.tree_util.tree_map(
+            lambda a: a.reshape((cfg.num_layers // len(kinds), len(kinds))
+                                + a.shape[1:]), blocks)
+
+    def body(carry, layers):
         x, aux_sum = carry
         fn = _moe_block
         if cfg.remat:
-            fn = jax.checkpoint(_moe_block, static_argnums=(0, 5))
-        x, aux = fn(cfg, layer, x, cos, sin, train)
-        return (x, aux_sum + aux), None
+            fn = jax.checkpoint(_moe_block, static_argnums=(0, 5, 6))
+        for j, kind in enumerate(kinds):
+            layer = layers if kind is None else jax.tree_util.tree_map(
+                lambda a: a[j], layers)
+            x, aux = fn(cfg, layer, x, cos, sin, train, kind)
+            aux_sum = aux_sum + aux
+        return (x, aux_sum), None
 
     (x, aux_sum), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                   params["blocks"])
-    x = L.rms_norm(x, params["final_norm"], cfg.rms_eps)
-    logits = x @ params["lm_head"].astype(x.dtype)
+                                   blocks)
+    x = L.block_norm(cfg, x, params["final_norm"])
+    logits = L.head_logits(cfg, params, x)
     return logits, aux_sum / cfg.num_layers
 
 
@@ -288,15 +387,54 @@ def _routed(cfg: MixtralConfig, layer, y, live=None, stacks=None,
     out, *record = routed_ffn(
         y, layer["gate_w"], w1, w3, w2, cfg.top_k, cfg.norm_topk_prob,
         live=live, layer=layer["layer_index"] if whole else None,
-        kernel=whole or _expert_kernel(layer), choices=choices)
+        kernel=whole or _expert_kernel(layer), choices=choices,
+        held=cfg.experts_held, score=cfg.router_score)
+    if cfg.shared_experts:
+        out = out + _shared(cfg, layer, y)
     return out, (tuple(record) if choices else record[0])
 
 
+def _shared(cfg: MixtralConfig, layer, y):
+    """The shared experts every token runs: plain dense SwiGLU matmuls over
+    the experts side by side (``shared_w1`` / ``shared_w3`` ``[d, Sh * f]``,
+    ``shared_w2 [Sh * f, d]``: the SUM of the ``Sh`` experts' outputs),
+    averaged."""
+    from .gpt2 import _qmm
+
+    with jax.named_scope("layer/moe/shared"):
+        gate = jax.nn.silu(_qmm(y, layer["shared_w1"]))
+        out = _qmm(gate * _qmm(y, layer["shared_w3"]), layer["shared_w2"],
+                   y.dtype)
+        return out / cfg.shared_experts
+
+
 def init_cache(cfg: MixtralConfig, batch_size: int, max_len: int,
-               dtype=jnp.bfloat16):
+               dtype=jnp.bfloat16, window_blocks: Optional[int] = None):
     """llama's K and V — plus, for a config with an indexer, its key: a
     third leaf ``[L, B, 1, S, index_head_dim]`` of the same layout (the
-    block-paged pool's ``[L, NB, 1, block_size, DI]``)."""
+    block-paged pool's ``[L, NB, 1, block_size, DI]``).  A patterned model
+    (``layer_kinds``; block-paged only) holds leaves BY LAYER KIND, each
+    kind with a block-id space of its own (``ops/paged_kv.py`` "Layer
+    kinds"): ``k`` / ``v`` ``[L_full, num_blocks, ...]`` for the
+    full-attention layers, ``kw`` / ``vw`` ``[L_win, window_blocks, ...]``
+    for the sliding-window ones."""
+    if cfg.layer_kinds:
+        if window_blocks is None:
+            raise NotImplementedError(
+                "a layer pattern with sliding-window layers (layer_kinds) "
+                "is served through the block-paged pool (init_serving / "
+                "ServingEngine): the contiguous cache of "
+                "InferenceEngine.generate has one kind of state")
+        periods = cfg.num_layers // len(cfg.layer_kinds)
+        cache = {}
+        for kind, blocks in (("full", batch_size), ("sliding", window_blocks)):
+            n = periods * cfg.layer_kinds.count(kind)
+            if n:
+                shape = (n, blocks, cfg.num_kv_heads, max_len, cfg.head_dim)
+                ck, cv, _ = L.KIND_LEAVES[kind]
+                cache[ck] = jnp.zeros(shape, dtype)
+                cache[cv] = jnp.zeros(shape, dtype)
+        return cache
     cache = L.init_cache(cfg, batch_size, max_len, dtype)
     if cfg.index_heads:
         cache["idx"] = jnp.zeros((cfg.num_layers, batch_size, 1, max_len,
@@ -353,6 +491,12 @@ def forward_cached(cfg: MixtralConfig, params, input_ids, cache, pos,
     max_seq_len]`` (the keys each query attended) — what a comparison with
     a plain reference hands that reference, so that near-ties the two sides
     break differently do not count as a difference."""
+    if cfg.layer_kinds and not isinstance(block_tables, dict):
+        raise NotImplementedError(
+            "a layer pattern with sliding-window layers (layer_kinds) is "
+            "served through the block-paged pool with a block table per "
+            "layer kind (init_serving / ServingEngine); the contiguous "
+            "cache of InferenceEngine.generate has one kind of state")
     live = L.live_tokens(input_ids, lengths, block_tables)
     blocks, stacks = params["blocks"], None
     if _expert_kernel(blocks):
@@ -381,8 +525,9 @@ def forward_cached(cfg: MixtralConfig, params, input_ids, cache, pos,
                 (cfg.num_layers,) + input_ids.shape + (s_max,), bool)
         attend_fn = functools.partial(_sparse_attend, cfg)
     logits, kv, records, *carried = L.forward_cached(
-        cfg, params, input_ids, {"k": cache["k"], "v": cache["v"]}, pos,
-        lengths=lengths, block_tables=block_tables,
+        cfg, params, input_ids,
+        cache if cfg.layer_kinds else {"k": cache["k"], "v": cache["v"]},
+        pos, lengths=lengths, block_tables=block_tables,
         mlp_fn=lambda lyr, y: _routed(cfg, lyr, y, live, stacks, choices),
         all_positions=all_positions, attend_fn=attend_fn, extra=extra)
     chosen = {}
@@ -407,6 +552,14 @@ def tp_rules(cfg: MixtralConfig, abstract_params: PyTree) -> PyTree:
     blocks["experts_w1"] = P(None, EP_AXIS, None, TP_AXIS)
     blocks["experts_w3"] = P(None, EP_AXIS, None, TP_AXIS)
     blocks["experts_w2"] = P(None, EP_AXIS, TP_AXIS, None)
+    if cfg.shared_experts:
+        blocks["shared_w1"] = P(None, None, TP_AXIS)
+        blocks["shared_w3"] = P(None, None, TP_AXIS)
+        blocks["shared_w2"] = P(None, TP_AXIS, None)
+    if cfg.parallel_block:
+        del blocks["mlp_norm"]
+    if cfg.tie_embeddings:
+        del rules["lm_head"]
     if cfg.index_heads:
         # the indexer is small and its one key head has nothing to split
         for k in ("idx_q_w", "idx_k_w", "idx_w_w", "idx_k_norm"):
@@ -428,8 +581,8 @@ def build(cfg: Optional[MixtralConfig] = None, **overrides) -> ModelSpec:
         return forward_with_aux(cfg, params, ids, train=False)[0]
 
     decode_hooks = {
-        "init_cache": lambda b, s, dtype=jnp.bfloat16: init_cache(
-            cfg, b, s, dtype),
+        "init_cache": lambda b, s, dtype=jnp.bfloat16, **kinds: init_cache(
+            cfg, b, s, dtype, **kinds),
         "forward_cached": lambda params, ids, cache, pos, lengths=None,
             block_tables=None, all_positions=False, routing=False,
             choices=False:
@@ -455,6 +608,18 @@ def build(cfg: Optional[MixtralConfig] = None, **overrides) -> ModelSpec:
         # learned sparse attention: the cache has a third leaf, and a row
         # past ``topk`` keys reads ``topk`` of them (the engine's counters)
         decode_hooks["sparse_attention"] = {"topk": cfg.index_topk}
+    if cfg.experts_held is not None:
+        # the routing record's fourth column (``routed.RECORD_HELD``)
+        decode_hooks["experts_held"] = cfg.experts_held
+    if "sliding" in cfg.layer_kinds:
+        # the pool holds leaves by layer kind, each kind under a block
+        # table of its own (``init_cache(..., window_blocks=)``;
+        # ``forward_cached`` takes ``{"full", "window"}`` tables)
+        decode_hooks["window_layers"] = {
+            "window": cfg.sliding_window,
+            "layers": {kind: cfg.num_layers // len(cfg.layer_kinds)
+                       * cfg.layer_kinds.count(kind)
+                       for kind in ("full", "sliding")}}
 
     return ModelSpec(
         init_fn=init_fn, model_config=cfg, loss_fn=loss_fn, apply_fn=apply_fn,

@@ -23,6 +23,19 @@ chosen by the caller from what it can observe (``models/mixtral.py``):
 
     p = softmax_fp32(y Wr);  S = top-k of p;  (renormalize: p_S / sum p_S)
     out = sum_{e in S} p_e * (silu(y W1_e) * (y W3_e)) W2_e
+
+**An expert layer that holds a share of its experts** (``held = (first,
+count)``: this chip's experts ``first .. first + count - 1`` of the ``E``
+the router scores — one chip of an expert-parallel group).  The router is
+whole: scores and top-k over ALL ``E`` outputs, the chosen weights
+normalised over all ``k`` chosen.  The pairs whose expert is not held are
+sorted BEHIND the held groups and multiplied with nothing (``group_sizes``
+covers the held experts only, ``w1`` / ``w3`` / ``w2`` are ``[.., count,
+..]``; ``moe_gmm``: "rows past ``sum(group_sizes)`` belong to nobody"), and
+the result is the held experts' PARTIAL sum — what this chip would send
+into the group's exchange.  No code stands in for the absent chips: on one
+chip the layer runs without its exchange.  ``held=None`` is the program
+above, bit for bit.
 """
 
 from __future__ import annotations
@@ -34,8 +47,10 @@ import jax.numpy as jnp
 
 from .grouped_matmul import moe_gmm
 
-#: columns of the routing record ``routed_ffn`` returns
+#: columns of the routing record ``routed_ffn`` returns; with ``held`` a
+#: fourth, the live pairs routed to experts that are not held
 RECORD = ("experts_touched", "expert_rows", "expert_rows_max")
+RECORD_HELD = RECORD + ("expert_rows_absent",)
 
 
 def _dense(w, dtype):
@@ -50,12 +65,20 @@ def _dense(w, dtype):
     return w.astype(dtype)
 
 
-def route(y2d, gate_w, k: int, renormalize: bool):
-    """Router of ``[T, D]`` tokens: float32 logits and softmax over ALL
-    experts, then top-k.  -> (weights float32 [T, k], experts int32 [T, k])."""
+def route(y2d, gate_w, k: int, renormalize: bool, score: str = "softmax"):
+    """Router of ``[T, D]`` tokens: float32 logits and scores over ALL
+    experts — their softmax, or with ``score="sigmoid"`` each logit's own
+    sigmoid — then top-k (ties to the lower expert id).
+    -> (weights float32 [T, k], experts int32 [T, k])."""
     logits = jnp.dot(y2d, _dense(gate_w, y2d.dtype),
                      preferred_element_type=jnp.float32)
-    top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    if score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"router score {score!r}: 'softmax' or 'sigmoid'")
+    top_p, top_e = jax.lax.top_k(scores, k)
     if renormalize:
         top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     return top_p, top_e.astype(jnp.int32)
@@ -72,7 +95,8 @@ def _grouped(xs, w, group_sizes, layer, kernel: bool):
 
 def routed_ffn(y, gate_w, w1, w3, w2, k: int, renormalize: bool,
                live=None, layer=None, kernel: bool = True,
-               choices: bool = False) -> Tuple[jnp.ndarray, ...]:
+               choices: bool = False, held=None,
+               score: str = "softmax") -> Tuple[jnp.ndarray, ...]:
     """SwiGLU experts over ``y [..., D]``: ``gate_w [D, E]``, ``w1``/``w3``
     ``[E, D, F]``, ``w2 [E, F, D]`` — or, with ``layer`` (traced index),
     the whole stacks ``[L, E, ..]``.  No capacity, no drop.  ``kernel``
@@ -84,7 +108,11 @@ def routed_ffn(y, gate_w, w1, w3, w2, k: int, renormalize: bool,
     shape; padding rows and idle slots are computed like any row but are
     nobody's traffic).  ``live=None`` counts every token.  ``choices`` adds
     a third result, the experts each token was routed to: int32 ``[..., k]``
-    (what a comparison with a reference is teacher-forced with)."""
+    (what a comparison with a reference is teacher-forced with; ids among
+    all ``E``).  ``held = (first, count)``: the weights are this chip's
+    ``count`` experts and ``out`` their partial sum (module docstring); the
+    record is ``RECORD_HELD``, its first three columns over the held
+    experts.  ``score``: the router's (:func:`route`)."""
     shape, d = y.shape, y.shape[-1]
     x = y.reshape(-1, d)
     t, e = x.shape[0], gate_w.shape[-1]
@@ -92,8 +120,14 @@ def routed_ffn(y, gate_w, w1, w3, w2, k: int, renormalize: bool,
         raise ValueError(f"top_k={k} outside [1, num_experts={e}]")
 
     with jax.named_scope("layer/moe/route"):
-        top_p, top_e = route(x, gate_w, k, renormalize)
+        top_p, top_e = route(x, gate_w, k, renormalize, score)
         flat_e = top_e.reshape(-1)                               # [T*k]
+        if held is not None:
+            # this chip's experts as groups 0 .. count-1; a pair of any
+            # other expert takes the key ``count`` and sorts behind them
+            first, e = held
+            flat_e = flat_e - first
+            flat_e = jnp.where((flat_e >= 0) & (flat_e < e), flat_e, e)
         # stable: pairs of one expert keep token order (deterministic sums)
         order = jnp.argsort(flat_e, stable=True)
         hits = flat_e[:, None] == jnp.arange(e, dtype=jnp.int32)[None, :]
@@ -105,6 +139,11 @@ def routed_ffn(y, gate_w, w1, w3, w2, k: int, renormalize: bool,
         up = _grouped(xs, w3, group_sizes, layer, kernel)
         out = _grouped(jax.nn.silu(gate) * up, w2, group_sizes, layer,
                        kernel)
+        if held is not None:
+            # the rows behind the held groups were multiplied with nothing
+            # and hold whatever the kernel's output buffer held
+            mine = jnp.arange(t * k, dtype=jnp.int32) < group_sizes.sum()
+            out = jnp.where(mine[:, None], out, 0)
 
     with jax.named_scope("layer/moe/combine"):
         inverse = jnp.zeros_like(order).at[order].set(
@@ -117,8 +156,13 @@ def routed_ffn(y, gate_w, w1, w3, w2, k: int, renormalize: bool,
         else:
             alive = jnp.repeat(live.reshape(-1), k)              # [T*k]
             counts = (hits & alive[:, None]).sum(0, dtype=jnp.int32)
-        record = jnp.stack([(counts > 0).sum(dtype=jnp.int32),
-                            counts.sum(dtype=jnp.int32), counts.max()])
+        record = [(counts > 0).sum(dtype=jnp.int32),
+                  counts.sum(dtype=jnp.int32), counts.max()]
+        if held is not None:
+            pairs_live = t * k if live is None \
+                else k * live.sum(dtype=jnp.int32)
+            record.append(pairs_live - record[1])
+        record = jnp.stack(record)
     if choices:
         return mixed.reshape(shape), record, top_e.reshape(shape[:-1] + (k,))
     return mixed.reshape(shape), record

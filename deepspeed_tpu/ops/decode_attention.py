@@ -401,9 +401,38 @@ def _tp_shard_heads(body, q, k_pool, v_pool, block_tables, q_pos, layer,
         q, k_pool, v_pool, bt, pos, layer, *per_row)
 
 
+def _ring_attention_reference(q, k, v, q_pos, valid, window: int, bs: int,
+                              sm_scale: float):
+    """A window layer's attention over keys gathered IN RING ORDER: ``k`` /
+    ``v`` [B, HKV, R*bs, D] are the row's ring entries ``0 .. R-1``, entry
+    ``e`` holding the newest logical block ``i <= last`` with ``i % R == e``
+    (``last``: the block of the row's last query).  Query ``p`` keeps key
+    ``j`` iff ``0 <= p - j < window``; an entry whose block is older than
+    that (released, or never written) holds keys no query keeps."""
+    b, h, t, d = q.shape
+    hkv, s = k.shape[1], k.shape[2]
+    ring = s // bs
+    if h != hkv:
+        k = jnp.repeat(k, h // hkv, axis=1)
+        v = jnp.repeat(v, h // hkv, axis=1)
+    pos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1), (b,))
+    last = (pos + jnp.maximum(valid, 1) - 1) // bs
+    entry = jnp.arange(ring, dtype=jnp.int32)
+    li = last[:, None] - (last[:, None] - entry[None, :]) % ring   # [B, R]
+    key = (li[:, :, None] * bs
+           + jnp.arange(bs, dtype=jnp.int32)).reshape(b, 1, s)
+    query = (pos[:, None] + jnp.arange(t, dtype=jnp.int32))[:, :, None]
+    mask = (key >= 0) & (key <= query) & (key > query - window)  # [B, T, S]
+    scores = jnp.einsum("bhtd,bhsd->bhts", q, k).astype(jnp.float32)
+    scores = jnp.where(mask[:, None], scores * sm_scale, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhts,bhsd->bhtd", probs, v)
+
+
 def paged_decode_attention_reference(q, k_pool, v_pool, block_tables, q_pos,
                                      *, sm_scale: Optional[float] = None,
-                                     layer=None):
+                                     layer=None, window: int = 0,
+                                     valid=None):
     """Gather-based paged attention (pure XLA): materialize each row's
     logical cache view through its block table, then run the contiguous
     reference path.  The CPU path for every T and the tests' oracle; on a
@@ -415,19 +444,33 @@ def paged_decode_attention_reference(q, k_pool, v_pool, block_tables, q_pos,
                   (or [NB, HKV, block_size, D] with ``layer=None``)
     block_tables: int32 [B, NBPER]
     q_pos:        scalar or int32 [B] — global position of q[:, :, 0]
+    window:       a sliding-window layer's reach (0: none): the table is
+                  that layer kind's RING (``ops/paged_kv.py`` "Layer
+                  kinds") and a query keeps the ``window`` newest keys,
+                  itself included; ``valid`` int32 [B] then says how many
+                  of the T queries are real (default all): the ring holds
+                  the blocks up to the last real one
     """
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    b, t = q.shape[0], q.shape[2]
+    per_row = () if not window else (
+        jnp.full((b,), t, jnp.int32) if valid is None
+        else jnp.clip(jnp.asarray(valid, jnp.int32), 0, t),)
 
-    def body(q, kp, vp, bt, pos, layer):
+    def body(q, kp, vp, bt, pos, layer, *valid):
         # int8 records dequantize to the query dtype so downstream
         # residual math keeps the model's compute dtype (float pools
         # ignore the hint — reads stay bit-identical)
         k = _paged_gather(kp, bt, layer, q.shape[-1], out_dtype=q.dtype)
         v = _paged_gather(vp, bt, layer, q.shape[-1], out_dtype=q.dtype)
+        if window:
+            return _ring_attention_reference(
+                q, k, v, pos, valid[0], window, k.shape[2] // bt.shape[1],
+                scale)
         return decode_attention_reference(q, k, v, pos, sm_scale=scale)
 
     return _tp_shard_heads(body, q, k_pool, v_pool, block_tables, q_pos,
-                           layer)
+                           layer, *per_row)
 
 
 #: VMEM the paged kernels give their K/V landing buffers (two slots each)
@@ -458,7 +501,8 @@ def _walk_head_tile(hkv: int, r: int, width: int, itemsize: int) -> int:
 
 
 def _paged_walk_kernel(layer_ref, pos_ref, bt_ref, q_ref, *refs,
-                       sm_scale: float, t: int, spans: int, quant: bool):
+                       sm_scale: float, t: int, spans: int, quant: bool,
+                       window: int = 0):
     """The paged decode (``t == 1``) and verify (``t`` window positions)
     kernel.  Grid ``(B, HKV // ht)``: one step is one row's whole attention
     for ``ht`` KV heads (all of the shard's, :func:`_walk_head_tile`).
@@ -485,6 +529,14 @@ def _paged_walk_kernel(layer_ref, pos_ref, bt_ref, q_ref, *refs,
     The arithmetic is :func:`_attend_chunk`'s, a block of all heads at a
     time; ``o_ref`` [1, ht, rows, D] is written once, after the walk.
 
+    ``window`` (static; 0: a full-attention layer, the program above): a
+    sliding-window layer.  Query ``p`` keeps keys ``p - window < key <= p``,
+    so the walk starts at the block of the row's FIRST VISIBLE KEY,
+    ``max(0, base - window + 1) // block_size``, masks the keys before each
+    query's own bound inside it, and reads the table as the RING it is
+    (``ops/paged_kv.py`` "Layer kinds"): logical block ``i`` at entry ``i %
+    NBPER``.  A row costs ``min(length, window)`` keys.
+
     Refs after ``q_ref``: the pool operands (K, [K scales], V, [V scales]),
     ``o_ref``, one two-slot landing buffer per pool operand, the DMA
     semaphores ``[2, operands]``, then m / l / acc."""
@@ -497,7 +549,17 @@ def _paged_walk_kernel(layer_ref, pos_ref, bt_ref, q_ref, *refs,
     b, layer = pl.program_id(0), layer_ref[0]
     base = pos_ref[b]
     # blocks that hold a key some query of the row may see: keys <= last
-    n = jnp.clip((base + t - 1 + bs) // bs, 0, bt_ref.shape[1])
+    if window:
+        width = bt_ref.shape[1]
+        n = jnp.maximum((base + t - 1 + bs) // bs, 0)
+        first = jnp.maximum(jnp.maximum(base - window + 1, 0) // bs,
+                            n - width)
+        keep = lambda idx, query: (idx <= base + query % t) \
+            & (idx > base + query % t - window)       # noqa: E731
+    else:
+        n = jnp.clip((base + t - 1 + bs) // bs, 0, bt_ref.shape[1])
+        first = 0
+        keep = lambda idx, query: idx <= base + query % t     # noqa: E731
     whole = pools[0].shape[2] == ht
     heads = pl.ds(pl.program_id(1) * ht, ht)
 
@@ -507,7 +569,8 @@ def _paged_walk_kernel(layer_ref, pos_ref, bt_ref, q_ref, *refs,
         for op, (pool, buf) in enumerate(zip(pools, bufs)):
             # an operand that is this layer's rows alone (_lane_rows)
             # is a one-layer stack
-            src = (layer if pool.shape[0] > 1 else 0, bt_ref[b, i])
+            src = (layer if pool.shape[0] > 1 else 0,
+                   bt_ref[b, i % width if window else i])
             out.append(pltpu.make_async_copy(
                 pool.at[src if whole else src + (heads,)], buf.at[slot],
                 sem.at[slot, op]))
@@ -526,14 +589,13 @@ def _paged_walk_kernel(layer_ref, pos_ref, bt_ref, q_ref, *refs,
             copy.wait()
         tiles = [buf[slot] for buf in bufs]
         k, ks, v, vs = tiles if quant else (tiles[0], None, tiles[1], None)
-        _attend_chunk(q_ref[0], k, v, ks, vs,
-                      lambda idx, query: idx <= base + query % t,
+        _attend_chunk(q_ref[0], k, v, ks, vs, keep,
                       i * bs, sm_scale, m_scr, l_scr, acc_scr, spans=spans)
         return carry
 
     _start_chunks(m_scr, l_scr, acc_scr)
-    fetch(0, 0)
-    jax.lax.fori_loop(0, n, attend, None)
+    fetch(first, first % 2)
+    jax.lax.fori_loop(first, n, attend, None)
     _finish_chunks(o_ref, m_scr, l_scr, acc_scr, spans=spans)
 
 
@@ -560,7 +622,7 @@ def _lane_rows(leaf, layer, head_dim=None):
 
 
 def _paged_launch(q, k_pool, v_pool, block_tables, q_pos, layer, *,
-                  sm_scale: float):
+                  sm_scale: float, window: int = 0):
     """The one launch of the paged kernels, ``q`` [B, H, T, D] against one
     shard's stacked pool at ``layer``, as ``(kernel, pallas_call keywords,
     operands)``: decode and verify each keep a ``pl.pallas_call`` site of
@@ -612,7 +674,7 @@ def _paged_launch(q, k_pool, v_pool, block_tables, q_pos, layer, *,
         ],
     )
     kernel = functools.partial(_paged_walk_kernel, sm_scale=sm_scale, t=t,
-                               spans=spans, quant=quant)
+                               spans=spans, quant=quant, window=window)
     call = dict(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
@@ -637,13 +699,16 @@ def _paged_verify_call(q, *args, interpret: bool, **kwargs):
 
 
 def _paged_attention_pallas(launch, q, k_pool, v_pool, block_tables, q_pos,
-                            sm_scale, interpret, layer, *per_row):
+                            sm_scale, interpret, layer, *per_row,
+                            window: int = 0):
     """``launch`` (one of the kernels' call sites) per head shard under a
-    configured tp / dp context (:func:`_tp_shard_heads`)."""
+    configured tp / dp context (:func:`_tp_shard_heads`).  ``window``: a
+    sliding-window layer's reach (static; 0: a full-attention layer)."""
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
         interpret = interpret_kernels()
-    body = functools.partial(launch, sm_scale=scale, interpret=interpret)
+    body = functools.partial(launch, sm_scale=scale, interpret=interpret,
+                             window=window)
     return _tp_shard_heads(body, q, k_pool, v_pool, block_tables, q_pos,
                            layer, *per_row)
 
@@ -651,7 +716,7 @@ def _paged_attention_pallas(launch, q, k_pool, v_pool, block_tables, q_pos,
 def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
                                   sm_scale: Optional[float] = None,
                                   interpret: Optional[bool] = None,
-                                  layer=None):
+                                  layer=None, window: int = 0):
     """Single-token paged decode: q [B, H, 1, D] against the stacked block
     pool at ``layer``, each row walking its own valid blocks in-kernel
     (:func:`_paged_walk_kernel`, as ``paged_decode_attn``).  Under a
@@ -661,7 +726,7 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
         "pallas paged decode is single-token; use the XLA path"
     return _paged_attention_pallas(_paged_decode_call, q, k_pool, v_pool,
                                    block_tables, q_pos, sm_scale, interpret,
-                                   layer)
+                                   layer, window=window)
 
 
 #: widest window the verify kernel takes; a wider T is a prefill chunk
@@ -672,7 +737,7 @@ VERIFY_T_MAX = 16
 def paged_verify_attention_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
                                   sm_scale: Optional[float] = None,
                                   interpret: Optional[bool] = None,
-                                  layer=None):
+                                  layer=None, window: int = 0):
     """Speculative-verify paged attention: q [B, H, T, D] with T = K+1
     window positions per row, each row's window starting at its own
     ``q_pos[b]`` base (scalar q_pos broadcasts).  The decode kernel's walk
@@ -685,7 +750,7 @@ def paged_verify_attention_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
         f"verify kernel takes windows up to {VERIFY_T_MAX}, got T={t}"
     return _paged_attention_pallas(_paged_verify_call, q, k_pool, v_pool,
                                    block_tables, q_pos, sm_scale, interpret,
-                                   layer)
+                                   layer, window=window)
 
 
 # ---------------------------------------------------------------------------
@@ -749,7 +814,8 @@ def _lanes(x, n: int):
 def _paged_prefill_kernel(layer_ref, base_ref, valid_ref, bt_ref, q_ref,
                           k_pool, v_pool, o_ref, kbuf, vbuf, sem, qs_scr,
                           m_scr, l_scr, acc_scr, *,
-                          sm_scale: float, t: int, spans: int):
+                          sm_scale: float, t: int, spans: int,
+                          window: int = 0):
     """The paged prefill kernel.  Grid ``(B, HKV // ht)``: one step is one
     row's chunk of ``t`` query positions, ``base .. base + t - 1`` of which
     the first ``valid`` are real, for ``ht`` KV heads
@@ -783,16 +849,33 @@ def _paged_prefill_kernel(layer_ref, base_ref, valid_ref, bt_ref, q_ref,
     min(i, valid - 1)`` for query offset ``i`` (pad queries see what the
     last real one sees), which also hides the slots of a last, partly
     landed tile — ``vbuf`` is zeroed at the start, so that what the mask
-    zeroes in ``p`` meets no NaN in ``v``."""
+    zeroes in ``p`` meets no NaN in ``v``.
+
+    ``window`` (static; 0: a full-attention layer, the program above): a
+    sliding-window layer, as in :func:`_paged_walk_kernel`.  The walk
+    starts at the TILE that holds the first visible key of the chunk's
+    first query, every tile is masked on both sides (``last - window < key
+    <= last`` per query row), and the table is read as a ring.  A query
+    whose window starts after a tile's last key sees nothing of that tile:
+    its state stays empty (``m`` at ``NEG_INF``) and what such a tile adds
+    to ``l`` / ``acc`` is scaled by ``alpha = 0`` when the query's first
+    real key arrives — every query sees at least itself."""
     _, nt, ht, r, width = kbuf.shape
     rows, d = o_ref.shape[2:]
     bs, cols = r * spans, nt * r
     b, layer = pl.program_id(0), layer_ref[0]
     base, valid = base_ref[b], valid_ref[b]
-    n = jnp.clip((base + valid + bs - 1) // bs, 0, bt_ref.shape[1])
+    if window:
+        n = jnp.maximum((base + valid + bs - 1) // bs, 0)
+    else:
+        n = jnp.clip((base + valid + bs - 1) // bs, 0, bt_ref.shape[1])
     n = jnp.where(valid > 0, n, 0)
     ntiles = (n + nt - 1) // nt
     clear = jnp.minimum(base // (nt * bs), ntiles)    # tiles below the chunk
+    if window:
+        # the tile of the first query's first visible key
+        tile0 = jnp.minimum(
+            jnp.maximum(base - window + 1, 0) // (nt * bs), ntiles)
     whole = k_pool.shape[2] == ht
     heads = pl.ds(pl.program_id(1) * ht, ht)
     unroll = math.gcd(ht, _PREFILL_HEAD_UNROLL)
@@ -805,8 +888,9 @@ def _paged_prefill_kernel(layer_ref, base_ref, valid_ref, bt_ref, q_ref,
             for op, (pool, buf) in enumerate(((k_pool, kbuf), (v_pool, vbuf))):
                 # an operand that is this layer's rows alone (_lane_rows)
                 # is a one-layer stack
+                at = i * nt + j
                 src = (layer if pool.shape[0] > 1 else 0,
-                       bt_ref[b, i * nt + j])
+                       bt_ref[b, at % bt_ref.shape[1] if window else at])
                 act(pltpu.make_async_copy(
                     pool.at[src if whole else src + (heads,)],
                     buf.at[slot, j], sem.at[slot, op]))
@@ -833,7 +917,12 @@ def _paged_prefill_kernel(layer_ref, base_ref, valid_ref, bt_ref, q_ref,
                     qs_scr[h], k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * sm_scale
                 parts = [s[g * rows:(g + 1) * rows] for g in range(spans)]
-                if masked:
+                if masked and window:
+                    parts = [jnp.where((key + g * r <= last)
+                                       & (key + g * r > last - window),
+                                       part, NEG_INF)
+                             for g, part in enumerate(parts)]
+                elif masked:
                     parts = [jnp.where(key + g * r <= last, part, NEG_INF)
                              for g, part in enumerate(parts)]
                 # m and l stay lane-replicated [rows, 128], as the scratch
@@ -888,15 +977,21 @@ def _paged_prefill_kernel(layer_ref, base_ref, valid_ref, bt_ref, q_ref,
 
     _start_chunks(m_scr, l_scr, acc_scr)
     vbuf[...] = jnp.zeros_like(vbuf)
-    each_block(0, 0, lambda copy: copy.start())
-    jax.lax.fori_loop(0, ht, span_queries, None)
-    jax.lax.fori_loop(0, clear, attend(False), None)
-    jax.lax.fori_loop(clear, ntiles, attend(True), None)
+    if window:
+        each_block(tile0, tile0 % 2, lambda copy: copy.start())
+        jax.lax.fori_loop(0, ht, span_queries, None)
+        jax.lax.fori_loop(tile0, ntiles, attend(True), None)
+    else:
+        each_block(0, 0, lambda copy: copy.start())
+        jax.lax.fori_loop(0, ht, span_queries, None)
+        jax.lax.fori_loop(0, clear, attend(False), None)
+        jax.lax.fori_loop(clear, ntiles, attend(True), None)
     jax.lax.fori_loop(0, ht, finish, None)
 
 
 def _paged_prefill_call(q, k_pool, v_pool, block_tables, q_pos, layer, valid,
-                        *, sm_scale: float, interpret: bool):
+                        *, sm_scale: float, interpret: bool,
+                        window: int = 0):
     """The launch of :func:`_paged_prefill_kernel`, ``q`` [B, H, T, D]
     against one shard's stacked float pool at ``layer``: the decode
     kernel's operands (:func:`_paged_launch`) plus ``valid``, the landing
@@ -936,7 +1031,7 @@ def _paged_prefill_call(q, k_pool, v_pool, block_tables, q_pos, layer, valid,
     )
     return pl.pallas_call(
         functools.partial(_paged_prefill_kernel, sm_scale=sm_scale, t=t,
-                          spans=spans),
+                          spans=spans, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -950,7 +1045,7 @@ def paged_prefill_attention_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
                                    valid=None,
                                    sm_scale: Optional[float] = None,
                                    interpret: Optional[bool] = None,
-                                   layer=None):
+                                   layer=None, window: int = 0):
     """A prefill chunk's paged attention: q [B, H, T, D], row b's T
     positions starting at its own ``q_pos[b]`` (scalar q_pos broadcasts)
     and just written to the pool, the first ``valid[b]`` of them real
@@ -967,7 +1062,7 @@ def paged_prefill_attention_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
         else jnp.asarray(valid, jnp.int32)
     return _paged_attention_pallas(_paged_prefill_call, q, k_pool, v_pool,
                                    block_tables, q_pos, sm_scale, interpret,
-                                   layer, valid)
+                                   layer, valid, window=window)
 
 
 # ---------------------------------------------------------------------------
@@ -1435,7 +1530,7 @@ def _took(path: str) -> None:
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, q_pos, *,
                            sm_scale: Optional[float] = None, layer=None,
-                           valid=None):
+                           valid=None, window: int = 0):
     """Dispatch: block-table-walking Pallas kernels on TPU — single-token
     decode (T == 1), the speculative K+1 verify window (T <=
     ``VERIFY_T_MAX``) or a prefill chunk (any wider T, float pools;
@@ -1446,9 +1541,17 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, q_pos, *,
     all-to-all path; a resident-window context forces the reference path,
     which carries the window mask.  ``k_pool``/``v_pool`` are the stacked
     pool with ``layer`` given, one layer's pool otherwise
-    (``paged_kv.whole_pool``)."""
+    (``paged_kv.whole_pool``).  ``window`` (static): a sliding-window
+    layer's reach over its kind's ring table (``ops/paged_kv.py`` "Layer
+    kinds"), taken by the same kernels and the same reference; 0 is a
+    full-attention layer and the program it always was."""
     t = q.shape[2]
-    if t > 1:
+    if window and (window_state() is not None
+                   or is_quantized_pool(k_pool)):
+        raise NotImplementedError(
+            "a sliding-window layer reads a float pool, outside a "
+            "resident-window context")
+    if t > 1 and not window:
         from . import sp_attention
 
         hkv = pool_payload(k_pool).shape[1 if layer is None else 2]
@@ -1462,18 +1565,19 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, q_pos, *,
             _took("paged_decode_attn")
             return paged_decode_attention_pallas(
                 q, k_pool, v_pool, block_tables, q_pos, sm_scale=sm_scale,
-                layer=layer)
+                layer=layer, window=window)
         if t <= VERIFY_T_MAX:
             _took("paged_verify_attn")
             return paged_verify_attention_pallas(
                 q, k_pool, v_pool, block_tables, q_pos, sm_scale=sm_scale,
-                layer=layer)
+                layer=layer, window=window)
         if not is_quantized_pool(k_pool):
             _took("paged_prefill_attn")
             return paged_prefill_attention_pallas(
                 q, k_pool, v_pool, block_tables, q_pos, valid=valid,
-                sm_scale=sm_scale, layer=layer)
+                sm_scale=sm_scale, layer=layer, window=window)
     _took("gather")
     return paged_decode_attention_reference(q, k_pool, v_pool, block_tables,
                                             q_pos, sm_scale=sm_scale,
-                                            layer=layer)
+                                            layer=layer, window=window,
+                                            valid=valid)

@@ -26,11 +26,22 @@ Layout contract — the WHOLE stacked pool, addressed in place:
    block index (``position // block_size``) to a physical block.  Entry 0
    is the reserved scratch block (``inference/paged.py``), which doubles as
    the "unset" marker: reads of unset blocks are masked by position, and a
-   window that reaches past a row's allocated entries lands there.  One
-   table serves every layer today; the layer index is an OPERAND of every
-   op (and a scalar-prefetch operand of the kernels, which address their
-   copies with it), so per-layer-kind tables (ROADMAP 2.8) are a change of
-   that address — ``bt[kind_of[layer], b, i]`` — not of layout.
+   window that reaches past a row's allocated entries lands there.
+ - **Layer kinds.**  One table serves every layer of a model whose layers
+   are all of one kind.  A model that mixes full-attention and
+   sliding-window layers (``LlamaConfig.layer_kinds``) holds one set of
+   leaves PER KIND, each with its own block-id space, its own table and
+   its own layer axis: the full kind ``k`` / ``v`` ``[L_full, NB_full,
+   ...]`` under a table of ``ceil(max_seq_len / block_size)`` entries, the
+   window kind ``kw`` / ``vw`` ``[L_win, NB_win, ...]`` under a RING of
+   ``ceil((window + prefill_chunk) / block_size) + 1`` entries: logical
+   block ``i`` of a row sits at entry ``i % width``, and the scheduler
+   releases a block once every position of it is behind ``position -
+   window`` (``inference/serving.py``).  The ops take ``ring=True`` (the
+   write) or ``window=W`` (the reads, ``ops/decode_attention.py``) for a
+   window layer and are today's programs without; which table and which
+   leaves a layer addresses is static in the model's layer loop
+   (``models/llama.py:scan_periods_cached``).
 
 **Layout** (what "in place" takes on a TPU).  A Mosaic kernel reads its
 operand row-major — ``[L][NB][HKV][...]``, a block's tiles contiguous —
@@ -445,9 +456,12 @@ def _write_one(pool, win, layer, phys, start, nvalid):
                                 nvalid)}
 
 
-def _window_blocks(bs: int, b: int, t: int, pos, block_tables, valid):
+def _window_blocks(bs: int, b: int, t: int, pos, block_tables, valid,
+                   ring: bool = False):
     """Where a ``[B, *, T, ...]`` window starting at ``pos`` lands:
-    ``(phys, start, nvalid)`` as :func:`_write_blocks` takes them."""
+    ``(phys, start, nvalid)`` as :func:`_write_blocks` takes them.  ``ring``:
+    the table is a RING of its width (a window layer's, module docstring
+    "Layer kinds"): logical block ``i`` sits at entry ``i % width``."""
     nbper = block_tables.shape[1]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     nvalid = jnp.full((b,), t, jnp.int32) if valid is None \
@@ -455,22 +469,25 @@ def _window_blocks(bs: int, b: int, t: int, pos, block_tables, valid):
     # a T-token window starting anywhere reaches at most this many blocks
     nj = 1 if t == 1 else (t + bs - 2) // bs + 1
     li = pos[:, None] // bs + jnp.arange(nj, dtype=jnp.int32)[None, :]
-    ok = (li >= 0) & (li < nbper)                                   # [B, J]
-    phys = jnp.take_along_axis(block_tables.astype(jnp.int32),
-                               jnp.clip(li, 0, nbper - 1), axis=1)
+    if ring:
+        ok, at = li >= 0, li % nbper
+    else:
+        ok, at = (li >= 0) & (li < nbper), jnp.clip(li, 0, nbper - 1)
+    phys = jnp.take_along_axis(block_tables.astype(jnp.int32), at, axis=1)
     phys = jnp.where(ok, jnp.maximum(phys, 0), 0)
     # blocks past the table's reach take no token: start past the window
     start = jnp.where(ok, li * bs - pos[:, None], t)
     return phys, start, nvalid
 
 
-def _paged_cache_update(ck, cv, k, v, pos, block_tables, valid, layer):
+def _paged_cache_update(ck, cv, k, v, pos, block_tables, valid, layer,
+                        ring: bool = False):
     """Single-shard body of :func:`paged_cache_update` on the stacked
     pool — also the whole op when the pool is replicated (tp=1 / GQA
     fallback)."""
     b, hkv, t, hd = k.shape
     bs = int(np.prod(pool_payload(ck).shape[3:])) // hd
-    where = _window_blocks(bs, b, t, pos, block_tables, valid)
+    where = _window_blocks(bs, b, t, pos, block_tables, valid, ring)
     ck = _write_one(ck, k, layer, *where)
     cv = _write_one(cv, v, layer, *where)
     return ck, cv
@@ -495,7 +512,7 @@ def paged_window_update(leaf, win, pos, block_tables, valid=None,
 
 
 def paged_cache_update(ck, cv, k, v, pos, block_tables, valid=None,
-                       layer=None):
+                       layer=None, ring: bool = False):
     """Scatter a window of new keys/values into the paged pool, in place.
 
     ck/cv:         the stacked pool [L, NB, HKV, block_size, hd] with
@@ -510,6 +527,9 @@ def paged_cache_update(ck, cv, k, v, pos, block_tables, valid=None,
     valid:         optional int32 [B] — tokens of the T-window that are
                    real (default all T).  Invalid tokens, and positions
                    past the table's reach, write to scratch block 0.
+    ring:          the table is a window layer's ring (module docstring
+                   "Layer kinds"): logical block ``i`` is entry ``i %
+                   width``, and no position is past its reach.
 
     Returns the pool in the shape it came in.  Under a configured tp
     context (module docstring) the scatter runs in ``shard_map``: each chip
@@ -524,6 +544,9 @@ def paged_cache_update(ck, cv, k, v, pos, block_tables, valid=None,
     bt = jnp.asarray(block_tables, jnp.int32)
     n = head_shards(pool_payload(ck).shape[2], k.shape[1])
     sharded = _DP_GROUPS > 1 or n > 1
+    if ring and sharded:
+        raise NotImplementedError(
+            "a ring table (window layers) is written on one shard")
     if sharded and valid is None:
         valid = jnp.full((b,), k.shape[2], jnp.int32)
     if _DP_GROUPS > 1:
@@ -554,7 +577,8 @@ def paged_cache_update(ck, cv, k, v, pos, block_tables, valid=None,
             (ps, ps, hs, hs, P(), P(), P(), P()), (ps, ps))(
                 ck, cv, k, v, pos, bt, jnp.asarray(valid, jnp.int32), layer)
     else:
-        ck, cv = _paged_cache_update(ck, cv, k, v, pos, bt, valid, layer)
+        ck, cv = _paged_cache_update(ck, cv, k, v, pos, bt, valid, layer,
+                                     ring)
     if one_layer:
         ck, cv = jax.tree_util.tree_map(lambda a: a[0], (ck, cv))
     return ck, cv

@@ -204,8 +204,17 @@ class ServingFlopsProfiler:
         params = sds(srv.engine.params)
         cache = sds(srv._cache)
         slots, nb = srv.slots, srv._nbper
+
+        def tables(rows):
+            """The block-table operand: one table, or one per layer kind
+            (``ServingEngine._bt``)."""
+            if getattr(srv, "_windows", None):
+                return {"full": i32(rows, nb),
+                        "window": i32(rows, srv._ring.width)}
+            return i32(rows, nb)
+
         if family == "decode":
-            args = (params, cache, i32(slots), i32(slots), i32(slots, nb))
+            args = (params, cache, i32(slots), i32(slots), tables(slots))
             if getattr(srv, "_K", 1) > 1:    # fused multi-step decode adds
                 args += (jax.ShapeDtypeStruct((slots,), jnp.bool_),
                          i32(slots), i32(slots))   # active, budgets, eos_ids
@@ -217,7 +226,7 @@ class ServingFlopsProfiler:
                         sds(srv._dcache))
             else:
                 head = (params, cache)
-            return head + (i32(j, srv.prefill_chunk), i32(j, nb), i32(j),
+            return head + (i32(j, srv.prefill_chunk), tables(j), i32(j),
                            i32(j))
         if family == "verify":
             w = srv.spec_tokens + 1

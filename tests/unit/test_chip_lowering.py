@@ -562,3 +562,209 @@ def test_serving_programs_hold_no_sort_and_knobs_never_recompile(
             *abstract(name), *sds(samp[name])).as_text()
         assert "stablehlo.while" in text, name      # the searches are there
         assert "stablehlo.sort" not in text, name
+
+
+# ------------------------------------- layers of two kinds (window + full)
+#: the RAG-chat cell (commanda-ragchat-closed): 24 slots x 16,384 positions,
+#: 128 query / 8 KV heads x 128, a 4,096-key window behind a 128-token chunk
+MIXED = dict(slots=24, ctx=16384, heads=128, kv_heads=8, hd=128,
+             window=4096, chunk=128)
+
+
+@pytest.mark.parametrize("kind", ["full", "sliding"])
+@pytest.mark.parametrize("rows,t", [(24, 1), (4, 128), (24, 4)],
+                         ids=["decode", "prefill-chunk", "verify"])
+def test_window_walks_compile_at_the_rag_chat_cells_shapes(kind, rows, t,
+                                                           one_chip):
+    """Mosaic's own compile, for a described v5e, of the decode / verify
+    walk and the prefill walk at GQA 128 / 8 x 128 (2,048 query rows a KV
+    head in a ``[4, 128]`` chunk: one head a grid step) for both layer
+    kinds: a full layer over the full kind's table, a sliding layer with
+    its first-visible-key bound over the window kind's ring.  Each reads
+    the whole stack where it lies."""
+    c = MIXED
+    ring = -(-(c["window"] + c["chunk"]) // BLOCK) + 1
+    window = c["window"] if kind == "sliding" else 0
+    layers, nbper = (3, ring) if window else (1, c["ctx"] // BLOCK)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((layers, 1 + c["slots"] * nbper, c["kv_heads"], BLOCK,
+                c["hd"]), jnp.bfloat16)
+    kernel = {1: da.paged_decode_attention_pallas,
+              4: da.paged_verify_attention_pallas,
+              128: da.paged_prefill_attention_pallas}[t]
+    how = {"window": window} if window else {}
+    lowered = jax.jit(lambda q, k, v, bt, pos: kernel(
+        q, k, v, bt, pos, interpret=False, layer=0, **how)).lower(
+            sds((rows, c["heads"], t, c["hd"]), jnp.bfloat16), pool, pool,
+            sds((rows, nbper), jnp.int32), sds((rows,), jnp.int32))
+    assert "tpu_custom_call" in lowered.as_text()
+    assert lowered.compile().memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+#: sha256 (first 16 hex digits) of the decode / prefill programs of the
+#: families the benchmark served before layers had kinds and experts could
+#: be held, lowered for a described v5e at the small shapes of
+#: ``_old_family_programs`` ON THE PARENT of PR 34 — each Mosaic kernel's
+#: payload masked: it embeds the source lines of ``ops/decode_attention.py``
+#: / ``moe/routed.py``, which moved
+OLD_PROGRAMS = {
+    "opt.decode": "78eabd77c60910d4", "opt.prefill": "53fd52642e7e55d8",
+    "mixtral.decode": "8467a26b00170ab3",
+    "mixtral.prefill": "a37b77eef821b5da",
+    "olmoe.decode": "d751d15943d60771", "olmoe.prefill": "9cc3b8a954958161",
+    "keye.decode": "b7b2ca0372299f39", "keye.prefill": "2826e865b36a1898"}
+
+
+def _old_family(name):
+    import dataclasses
+
+    from deepspeed_tpu.models import mixtral, opt
+
+    small = dict(num_layers=2, max_seq_len=256, vocab_size=512,
+                 hidden_size=256, ffn_size=128, num_experts=8)
+    if name == "opt":
+        return opt.build(dataclasses.replace(opt.OPTConfig.tiny(),
+                                             max_seq_len=256))
+    if name == "mixtral":
+        return mixtral.build(dataclasses.replace(
+            mixtral.MixtralConfig.tiny(), max_seq_len=256))
+    if name == "olmoe":
+        return mixtral.build(dataclasses.replace(
+            mixtral.MixtralConfig.olmoe_1b_7b(), num_heads=2,
+            num_kv_heads=2, **small))
+    return mixtral.build(dataclasses.replace(
+        mixtral.MixtralConfig.keye_vl2_30b_a3b(), num_heads=4,
+        num_kv_heads=2, index_heads=2, index_topk=64, **small))
+
+
+@pytest.mark.parametrize("name", sorted(OLD_PROGRAMS))
+def test_the_old_programs_are_the_old_programs(name, as_on_tpu, one_chip,
+                                               monkeypatch):
+    """ISSUE 34: with every new field at its default and ``held=None``,
+    OPT, Mixtral, OLMoE and Keye lower, for a described v5e, to the text
+    they lowered to on the parent (``OLD_PROGRAMS``)."""
+    import hashlib
+    import re
+
+    from deepspeed_tpu.moe import grouped_matmul
+    from deepspeed_tpu.ops import paged_kv, sparse_index_attention
+
+    monkeypatch.setattr(sparse_index_attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(grouped_matmul, "interpret_kernels", lambda: False)
+    family, program = name.split(".")
+    spec = _old_family(family)
+    fwd = spec.decode_hooks["forward_cached"]
+    kw = {"routing": True} if spec.decode_hooks.get("routing_record") else {}
+    slots, nbper = 4, 256 // BLOCK
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return sds(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    params = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda: jax.tree_util.tree_map(
+            lambda a: a.astype(jnp.bfloat16),
+            spec.init_fn(jax.random.PRNGKey(0)))))
+    pool = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda: paged_kv.pack_pool(spec.decode_hooks["init_cache"](
+            1 + slots * nbper, BLOCK, jnp.bfloat16))))
+
+    def decode_step(params, cache, tokens, lengths, bt):
+        return fwd(params, tokens[:, None], cache, 0, lengths=lengths,
+                   block_tables=bt, **kw)
+
+    def prefill(params, cache, ids, bt, base, valid):
+        return fwd(params, ids, cache, base, lengths=valid, block_tables=bt,
+                   **kw)
+
+    fn, args = {
+        "decode": (decode_step, (params, pool, i32(slots), i32(slots),
+                                 i32(slots, nbper))),
+        "prefill": (prefill, (params, pool, i32(2, 128), i32(2, nbper),
+                              i32(2), i32(2)))}[program]
+    text = jax.jit(fn, donate_argnums=(1,)).lower(*args).as_text()
+    assert "tpu_custom_call" in text
+    masked = re.sub(r'backend_config = "[^"]*"',
+                    'backend_config = "<kernel>"', text)
+    assert hashlib.sha256(masked.encode()).hexdigest()[:16] \
+        == OLD_PROGRAMS[name]
+
+
+def test_compiled_two_kind_serving_programs_fit_and_alias_both_pools(
+        as_on_tpu, one_chip, monkeypatch):
+    """The RAG-chat cell's decode and prefill programs (Command A+ at its
+    published widths, this chip's share: 4 layers, 16 held of 128 experts,
+    32,768 vocabulary rows) compile for a described v5e, alias all four
+    pool leaves — two kinds — and hold temporaries under a gigabyte beside
+    9.47 GB of weights and 2.87 GB of pools."""
+    import json
+    import os
+
+    from chipbench.families import commanda
+    from deepspeed_tpu.moe import grouped_matmul
+    from deepspeed_tpu.ops import paged_kv
+
+    monkeypatch.setattr(grouped_matmul, "interpret_kernels", lambda: False)
+    root = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir)
+    with open(os.path.join(root, "chipbench", "configs",
+                           "command-a-plus-05-2026.json")) as f:
+        config = json.load(f)
+    config.pop("rehearse")
+    spec = commanda.build(config)
+    fwd = spec.decode_hooks["forward_cached"]
+    c = MIXED
+    nbper = c["ctx"] // BLOCK
+    ring = -(-(c["window"] + c["chunk"]) // BLOCK) + 1
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def tables(rows):
+        return {"full": sds(jax.ShapeDtypeStruct((rows, nbper), jnp.int32)),
+                "window": sds(jax.ShapeDtypeStruct((rows, ring), jnp.int32))}
+
+    def i32(*shape):
+        return sds(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    params = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda: jax.tree_util.tree_map(
+            lambda a: a.astype(jnp.bfloat16),
+            spec.init_fn(jax.random.PRNGKey(0)))))
+    pool = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda: paged_kv.pack_pool(spec.decode_hooks["init_cache"](
+            1 + c["slots"] * nbper, BLOCK, jnp.bfloat16,
+            window_blocks=1 + c["slots"] * ring))))
+    assert set(pool) == {"k", "v", "kw", "vw"}
+    assert pool["k"].shape[:2] == (1, 12289)
+    assert pool["kw"].shape[:2] == (3, 3193)
+
+    def decode_step(params, cache, tokens, lengths, bt):
+        logits, cache, rec = fwd(params, tokens[:, None], cache, 0,
+                                 lengths=lengths, block_tables=bt,
+                                 routing=True)
+        return jnp.argmax(logits, -1).astype(jnp.int32), cache, rec
+
+    def prefill(params, cache, ids, bt, base, valid):
+        logits, cache, rec = fwd(params, ids, cache, base, lengths=valid,
+                                 block_tables=bt, routing=True)
+        return jnp.argmax(logits, -1).astype(jnp.int32), cache, rec
+
+    slots = c["slots"]
+    programs = {
+        "paged_decode_attn": (decode_step, (
+            params, pool, i32(slots), i32(slots), tables(slots))),
+        "paged_prefill_attn": (prefill, (
+            params, pool, i32(4, 128), tables(4), i32(4), i32(4)))}
+    pool_bytes = sum(int(np.prod(a.shape)) * 2 for a in pool.values())
+    for kernel, (fn, args) in programs.items():
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+        text = compiled.as_text()
+        assert kernel in text and "moe_gmm" in text
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < 1 << 30, (kernel, mem)
+        assert mem.alias_size_in_bytes >= pool_bytes, (kernel, mem)

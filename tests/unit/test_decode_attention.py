@@ -903,3 +903,134 @@ def test_dispatcher_sends_a_prefill_chunk_to_the_kernel_on_a_tpu(monkeypatch):
         with da.window_context(jnp.zeros(len(bases), jnp.int32), 0):
             da.paged_decode_attention(q, kp, vp, clean, bases, layer=1)
     assert paths == {"gather"}
+
+
+# ------------------------------------------ a sliding-window layer's reads
+import math  # noqa: E402
+
+from deepspeed_tpu.ops import decode_attention as da  # noqa: E402
+
+
+def _ring_case(t, window, bs, ring, bases, valid=None, hd=128, h=4, hkv=2,
+               seed=0):
+    """Rows of a window layer over a RING table: row ``r`` holds ``bases[r]
+    + t`` positions, its live logical blocks (from the first query's first
+    visible key to the last real query) at ring entries ``i % ring`` with
+    block ids of their own, every other entry unset and the whole pool
+    garbage.  -> (q, k_pool, v_pool, ring table, positions, the plain dense
+    windowed attention of every row)."""
+    rng = np.random.default_rng(seed)
+    b = len(bases)
+    nb = 1 + b * ring
+    kp = rng.standard_normal((1, nb, hkv, bs, hd)).astype(np.float32)
+    vp = rng.standard_normal((1, nb, hkv, bs, hd)).astype(np.float32)
+    bt = np.zeros((b, ring), np.int32)
+    q = rng.standard_normal((b, h, t, hd)).astype(np.float32)
+    want = []
+    for r, base in enumerate(bases):
+        nv = t if valid is None else valid[r]
+        s = base + t
+        k = rng.standard_normal((hkv, s, hd)).astype(np.float32)
+        v = rng.standard_normal((hkv, s, hd)).astype(np.float32)
+        first, last = max(0, base - window + 1) // bs, (base + nv - 1) // bs
+        assert last - first + 1 <= ring
+        for li in range(first, last + 1):
+            phys = 1 + r * ring + li % ring
+            bt[r, li % ring] = phys
+            n = min((li + 1) * bs, s) - li * bs
+            kp[0, phys, :, :n] = k[:, li * bs:li * bs + n]
+            vp[0, phys, :, :n] = v[:, li * bs:li * bs + n]
+        kk, vv = np.repeat(k, h // hkv, 0), np.repeat(v, h // hkv, 0)
+        sc = np.einsum("htd,hsd->hts", q[r], kk) / math.sqrt(hd)
+        key, pos = np.arange(s)[None, :], (base + np.arange(t))[:, None]
+        sc = np.where(((key <= pos) & (key > pos - window))[None], sc, -1e30)
+        p = np.exp(sc - sc.max(-1, keepdims=True))
+        want.append(np.einsum("hts,hsd->htd", p / p.sum(-1, keepdims=True),
+                              vv))
+    return (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(bt), jnp.asarray(bases, jnp.int32), np.stack(want))
+
+
+#: row positions against a 20-key window over blocks of 8: the window
+#: starts before the row's first key (0, 3, 19), at a block edge (27: first
+#: visible key 8), mid-block (21, 100), and the ring of 5 entries has
+#: wrapped (64, 100)
+_WINDOW_BASES = [0, 3, 19, 21, 27, 64, 100]
+
+
+@pytest.mark.parametrize("t", [1, 4], ids=["decode", "verify"])
+def test_window_bound_in_the_paged_walk(t):
+    """ISSUE 34: ``_paged_walk_kernel`` with a sliding layer's bound — the
+    walk starts at the block of the first visible key through the ring
+    table and masks the keys before each query's own bound inside it — and
+    the XLA reference with the same bound both equal plain windowed
+    attention."""
+    q, kp, vp, bt, pos, want = _ring_case(t, 20, 8, 5, _WINDOW_BASES)
+    ref = da.paged_decode_attention_reference(q, kp, vp, bt, pos, layer=0,
+                                              window=20)
+    kernel = da.paged_decode_attention_pallas if t == 1 \
+        else da.paged_verify_attention_pallas
+    got = kernel(q, kp, vp, bt, pos, layer=0, window=20, interpret=True)
+    np.testing.assert_allclose(np.asarray(ref), want, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+
+
+@pytest.mark.parametrize("hd,h,hkv,t,window,ring,bases,valid", [
+    (64, 4, 2, 16, 24, 7, [0, 8, 30, 64, 100], None),
+    (128, 4, 2, 16, 24, 7, [0, 8, 32, 64, 96], [16, 3, 16, 9, 1]),
+    (128, 8, 2, 32, 40, 12, [0, 32, 64, 320], None),
+], ids=["g2-mid-block", "ragged-chunks", "tiles"])
+def test_window_bound_in_the_paged_prefill_walk(hd, h, hkv, t, window, ring,
+                                                bases, valid):
+    """ISSUE 34: ``_paged_prefill_kernel`` with a sliding layer's bound, per
+    query row of the chunk: rows whose window starts before their first
+    key, mid-block and at a block edge, ragged chunks (the ring holds the
+    blocks up to the last REAL query) and walks of several tiles."""
+    q, kp, vp, bt, pos, want = _ring_case(t, window, 8, ring, bases, valid,
+                                          hd=hd, h=h, hkv=hkv)
+    nv = None if valid is None else jnp.asarray(valid, jnp.int32)
+    ref = np.array(da.paged_decode_attention_reference(
+        q, kp, vp, bt, pos, layer=0, window=window, valid=nv))
+    got = np.array(da.paged_prefill_attention_pallas(
+        q, kp, vp, bt, pos, valid=nv, layer=0, window=window,
+        interpret=True))
+    for r, n in enumerate(valid or ()):
+        # a pad query's output is nobody's
+        ref[r, :, n:] = got[r, :, n:] = want[r, :, n:] = 0
+    np.testing.assert_allclose(ref, want, atol=2e-5)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_ring_write_lands_where_the_windowed_read_looks():
+    """``paged_cache_update(ring=True)`` writes logical block ``i`` at ring
+    entry ``i % width``: a row written chunk by chunk through a static ring
+    (each entry rewritten as the ring wraps) reads back, through the
+    windowed reference, as plain windowed attention over all it wrote."""
+    from deepspeed_tpu.ops import paged_kv
+
+    rng = np.random.default_rng(1)
+    hkv, hd, bs, ring, window, chunk, s = 2, 16, 8, 6, 24, 16, 80
+    k = rng.standard_normal((1, hkv, s, hd)).astype(np.float32)
+    v = rng.standard_normal((1, hkv, s, hd)).astype(np.float32)
+    q = rng.standard_normal((1, 4, s, hd)).astype(np.float32)
+    ck = jnp.zeros((1, 1 + ring, hkv, bs, hd))
+    cv = jnp.zeros_like(ck)
+    bt = jnp.asarray(1 + np.arange(ring)[None], jnp.int32)
+    outs = []
+    for base in range(0, s, chunk):
+        pos = jnp.asarray([base], jnp.int32)
+        ck, cv = paged_kv.paged_cache_update(
+            ck, cv, jnp.asarray(k[:, :, base:base + chunk]),
+            jnp.asarray(v[:, :, base:base + chunk]), pos, bt, layer=0,
+            ring=True)
+        outs.append(np.asarray(da.paged_decode_attention_reference(
+            jnp.asarray(q[:, :, base:base + chunk]), ck, cv, bt, pos,
+            layer=0, window=window)))
+    got = np.concatenate(outs, axis=2)[0]
+    kk, vv = np.repeat(k[0], 2, 0), np.repeat(v[0], 2, 0)
+    sc = np.einsum("htd,hsd->hts", q[0], kk) / math.sqrt(hd)
+    key, pos = np.arange(s)[None, :], np.arange(s)[:, None]
+    sc = np.where(((key <= pos) & (key > pos - window))[None], sc, -1e30)
+    p = np.exp(sc - sc.max(-1, keepdims=True))
+    want = np.einsum("hts,hsd->htd", p / p.sum(-1, keepdims=True), vv)
+    np.testing.assert_allclose(got, want, atol=2e-5)

@@ -58,6 +58,9 @@ ENGINE_STATS_KEYS = frozenset({
     # PR 32: a learned-sparse-attention model's selection paths + counters
     # (None for any other model)
     "sparse_attn",
+    # PR 34: a model that mixes sliding-window and full layers: its two
+    # pools by kind, the reach counters, the refusals (None otherwise)
+    "kv_kinds",
     # PR 28: routed (token, expert) rows and experts touched, summed over
     # layers and program calls; 0 for a dense model
     "moe_expert_rows", "moe_experts_touched",
