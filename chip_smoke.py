@@ -327,17 +327,17 @@ def phase_kernels(sz: Sizes, report: Dict[str, Any]) -> None:
 
         checks.append(("routed FFN", routed))
 
-    def flash():
-        """flash v2 forward + fused backward, the train step's kernels"""
-        g = sz.gpt2
-        q, k, v = (jax.random.normal(kk, (2, g.num_heads, sz.seq, g.head_dim),
+    def flash(tag, batch, heads, hd, seq, **blocks):
+        """flash forward + backward against the einsum reference: the
+        GPT-2 train step's call (the blocks its config names) and a call
+        that names none at the serving model's longest sequence (OPT-1.3B:
+        S = 2048, hd 64 — the blocks ``flash_attention`` chooses)"""
+        q, k, v = (jax.random.normal(kk, (batch, heads, seq, hd),
                                      jnp.bfloat16) for kk in keys[3:6])
         w = jax.random.normal(keys[6], q.shape, jnp.float32)
 
         def flash_loss(q, k, v):
-            o = fa.flash_attention(q, k, v, causal=True,
-                                   block_q=g.flash_block_q,
-                                   block_k=g.flash_block_k)
+            o = fa.flash_attention(q, k, v, causal=True, **blocks)
             return (o.astype(jnp.float32) * w).sum(), o
 
         def ref_loss(q, k, v):
@@ -348,20 +348,27 @@ def phase_kernels(sz: Sizes, report: Dict[str, Any]) -> None:
 
         fgrad = jax.jit(jax.grad(flash_loss, argnums=(0, 1, 2),
                                  has_aux=True))
-        n_calls = _mosaic(fgrad.lower(q, k, v).as_text(), "flash fwd+bwd",
-                          require)
+        n_calls = _mosaic(fgrad.lower(q, k, v).as_text(),
+                          f"flash fwd+bwd{tag}", require)
         assert not require or n_calls >= 2, n_calls
         (dq, dk, dv), o = fgrad(q, k, v)
         (rq, rk, rv), ro = exact(
             jax.grad(ref_loss, argnums=(0, 1, 2), has_aux=True), q, k, v)
-        res = {"flash_fwd": _close("flash forward", o, ro, ATTN_TOL)}
+        res = {f"flash{tag}_fwd": _close(f"flash forward{tag}", o, ro,
+                                         ATTN_TOL)}
         for name, a, b in (("dq", dq, rq), ("dk", dk, rk), ("dv", dv, rv)):
             scale = float(jnp.max(jnp.abs(b)))
-            res[f"flash_{name}_rel"] = _close(
-                f"flash {name}", a / scale, b / scale, FLASH_GRAD_REL)
+            res[f"flash{tag}_{name}_rel"] = _close(
+                f"flash {name}{tag}", a / scale, b / scale, FLASH_GRAD_REL)
         return res
 
-    checks.append(("flash fwd+bwd", flash))
+    g = sz.gpt2
+    checks.append(("flash fwd+bwd", lambda: flash(
+        "", 2, g.num_heads, g.head_dim, sz.seq, block_q=g.flash_block_q,
+        block_k=g.flash_block_k)))
+    checks.append((f"flash fwd+bwd S={cfg.max_seq_len} default blocks",
+                   lambda: flash(f"_s{cfg.max_seq_len}", 1, cfg.num_heads,
+                                 cfg.head_dim, cfg.max_seq_len)))
 
     def w8a8(k_dim, n_dim):
         """2-D kernel vs dequantize+matmul; stacked == 2-D exactly"""

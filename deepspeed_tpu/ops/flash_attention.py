@@ -17,9 +17,11 @@ Interpret mode is the default only where the CPU platform was requested
 
 from __future__ import annotations
 
+import collections
 import functools
 import math
-from typing import Optional
+import threading
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -435,12 +437,6 @@ _V2_MAX_KV = 1024
 _V2_MAX_SCORE_ELEMS = 2 ** 20
 
 
-def _v2_block_q(block_q: int, kv_pad: int) -> int:
-    # cap only — never RAISE block_q (it may legitimately be the whole
-    # padded q length for short sequences)
-    return max(8, min(block_q, _V2_MAX_SCORE_ELEMS // max(kv_pad, 1)))
-
-
 def _v2_eligible(kv_pad: int, d: int) -> bool:
     import os
 
@@ -470,6 +466,129 @@ def _v3_eligible(kv_pad: int, d: int) -> bool:
         return False
     min_kv = int(os.environ.get("DS_FLASH_V3_MIN_KV", _V2_MAX_KV + 1))
     return kv_pad >= min_kv and kv_pad % 8 == 0 and d <= 256
+
+
+# ---------------------------------------------------------------------------
+# block sizes: ``_resolve_blocks`` is the only place one is decided.
+#
+# A grid step of these kernels costs ~0.4 us on a v5e whatever it computes.
+# At 128 x 128 and hd 64 that was twenty times the step's MXU work and 62 % of
+# OPT's S=2048 training step (PERF.md section 6, PR 35), so a caller that
+# names no blocks gets the largest the backward's scoped VMEM and the
+# lengths' padding allow.
+# ---------------------------------------------------------------------------
+#: Mosaic's scoped-VMEM limit for one kernel on a v5e, and the part of it the
+#: chosen blocks may plan for: ``_bwd_vmem_bytes`` reads within a tenth of
+#: what the compiler's own refusals name, over and under
+_VMEM_LIMIT = 16 * 2 ** 20
+_VMEM_BUDGET = _VMEM_LIMIT * 3 // 4
+#: candidate blocks, largest first.  2048 rows on a side are refused by
+#: Mosaic beside 1024 on the other at S = 2048, and were slower on the chip
+#: where they compile (2048 x 512, 512 x 2048, 2048 x 1024 at S = 4096)
+_BLOCKS = (1024, 512, 256, 128)
+
+#: the Mosaic kernels of each generation, under the names a trace shows
+KERNELS = {"v1": ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"),
+           "v2": ("flash_fwd_resident", "flash_bwd_fused"),
+           "v3": ("flash_fwd_chunked", "flash_bwd_dq_chunked",
+                  "flash_bwd_dkv_chunked")}
+#: one resolution: the operands' lengths and head width, the generation they
+#: dispatch to, the blocks, and whether the rule chose them or the caller
+#: gave them
+Choice = collections.namedtuple(
+    "Choice", "q_len kv_len d generation block_q block_k how")
+_CHOICES: Dict[Choice, int] = {}
+_CHOICES_LOCK = threading.Lock()
+
+
+def choices(since: Optional[Dict[Choice, int]] = None) -> Dict[Choice, int]:
+    """Every distinct block resolution traced in this process -> the number
+    of ``flash_attention`` calls traced with it; given ``since``, an earlier
+    return value, only what was traced after it.  Resolution happens at trace
+    time, once a program: a record of which kernels at which blocks a
+    program runs (``DeepSpeedEngine`` logs its step's), not a rate."""
+    with _CHOICES_LOCK:
+        now = dict(_CHOICES)
+    if since is None:
+        return now
+    return {c: n - since.get(c, 0) for c, n in now.items()
+            if n > since.get(c, 0)}
+
+
+def _generation(kv_pad: int, d: int) -> str:
+    if _v2_eligible(kv_pad, d):
+        return "v2"
+    return "v3" if _v3_eligible(kv_pad, d) else "v1"
+
+
+def _bwd_vmem_bytes(block_q: int, block_k: int, d: int, itemsize: int) -> int:
+    """Scoped VMEM of the hungriest kernel, the backward that accumulates dk
+    and dv (``flash_bwd_dkv_chunked``; ``flash_bwd_fused`` with ``block_k`` the
+    whole resident length): the live ``[block_q, block_k]`` float32 scores and
+    their copy in the operands' dtype for the MXU, the double-buffered
+    operand and result blocks (up to four a side), two float32 accumulators.
+    ``d`` occupies whole 128-lane rows."""
+    row = -(-d // LANES) * LANES
+    tile = block_q * block_k * (4 + itemsize)
+    q_side = block_q * 4 * 2 * row * itemsize
+    k_side = block_k * (4 * 2 * row * itemsize + 2 * row * 4)
+    return tile + q_side + k_side
+
+
+def _len_block(length: int) -> int:
+    """The largest candidate that pads ``length`` by under an eighth (1024
+    would turn 1,100 rows into 2,048); a length within the smallest is one
+    block of its own."""
+    if length <= _BLOCKS[-1]:
+        return max(length, 1)
+    for block in _BLOCKS:
+        if (-length) % block * 8 <= length:
+            return block
+    return _BLOCKS[-1]
+
+
+def _resolve_blocks(q_len: int, kv_len: int, d: int, itemsize: int,
+                    block_q: Optional[int], block_k: Optional[int]):
+    """-> (Choice, pad_q, pad_k).  A block the caller gave is honoured as it
+    always was (clipped to its length; v2 caps ``block_q`` so the resident
+    scores stay under ``_V2_MAX_SCORE_ELEMS``).  One left ``None`` is chosen
+    from what the operands show — lengths, head width, element size:
+    ``_len_block`` of its length, then halved (never under 128) until
+    ``_bwd_vmem_bytes`` fits ``_VMEM_BUDGET``.  v1 has no cell and no chip
+    reading: it keeps the 128 it always ran at."""
+    bq = _len_block(q_len) if block_q is None else min(block_q, max(q_len, 1))
+    bk = _len_block(kv_len) if block_k is None else min(block_k,
+                                                        max(kv_len, 1))
+    if _generation(kv_len + (-kv_len) % bk, d) == "v1":
+        bq = min(bq, _BLOCKS[-1]) if block_q is None else bq
+        bk = min(bk, _BLOCKS[-1]) if block_k is None else bk
+    pad_q, pad_k = (-q_len) % bq, (-kv_len) % bk
+    kv_pad = kv_len + pad_k
+    generation = _generation(kv_pad, d)
+
+    def fits(bq, bk):
+        return _bwd_vmem_bytes(bq, bk, d, itemsize) <= _VMEM_BUDGET
+
+    def halvable(block, given):
+        return given is None and block > _BLOCKS[-1]
+
+    if generation == "v2":  # K and V resident: block_k only set the padding
+        while halvable(bq, block_q) and not fits(bq, kv_pad):
+            bq //= 2
+        bq = max(8, min(bq, _V2_MAX_SCORE_ELEMS // kv_pad))
+    elif generation == "v3":
+        # the larger side gives way, the query side on a tie: on the chip
+        # 512 x 1024 beat 1024 x 512 at every shape (PERF.md section 6)
+        while not fits(bq, bk):
+            if halvable(bq, block_q) and (bq >= bk
+                                          or not halvable(bk, block_k)):
+                bq //= 2
+            elif halvable(bk, block_k):
+                bk //= 2
+            else:
+                break
+    how = "chosen" if block_q is None or block_k is None else "given"
+    return (Choice(q_len, kv_len, d, generation, bq, bk, how), pad_q, pad_k)
 
 
 def _v2_compiler_params(dimension_semantics):
@@ -925,8 +1044,7 @@ def _bwd_v3(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
 def _flash_attention_bh(q, k, v, sm_scale, causal, block_q, block_k, interpret,
                         true_kv_len, head_rep):
     if _v2_eligible(k.shape[1], q.shape[2]):
-        return _fwd_v2(q, k, v, sm_scale, causal,
-                       _v2_block_q(block_q, k.shape[1]), interpret,
+        return _fwd_v2(q, k, v, sm_scale, causal, block_q, interpret,
                        true_kv_len, head_rep)
     if _v3_eligible(k.shape[1], q.shape[2]):
         o, _ = _fwd_v3(q, k, v, sm_scale, causal, block_q, block_k, interpret,
@@ -942,8 +1060,7 @@ def _flash_fwd_rule(q, k, v, sm_scale, causal, block_q, block_k, interpret,
     from jax.ad_checkpoint import checkpoint_name
 
     if _v2_eligible(k.shape[1], q.shape[2]):
-        o = _fwd_v2(q, k, v, sm_scale, causal,
-                    _v2_block_q(block_q, k.shape[1]), interpret,
+        o = _fwd_v2(q, k, v, sm_scale, causal, block_q, interpret,
                     true_kv_len, head_rep)
         # no lse residual: the fused backward recomputes row stats in-kernel
         o = checkpoint_name(o, "flash_out")
@@ -968,8 +1085,7 @@ def _flash_bwd_rule(sm_scale, causal, block_q, block_k, interpret, true_kv_len,
                     head_rep, res, g):
     if len(res) == 4:  # v2 path (see _flash_fwd_rule)
         q, k, v, o = res
-        return _bwd_v2(q, k, v, o, g, sm_scale, causal,
-                       _v2_block_q(block_q, k.shape[1]), interpret,
+        return _bwd_v2(q, k, v, o, g, sm_scale, causal, block_q, interpret,
                        true_kv_len, head_rep)
     if res[4].ndim == 3:  # v3 path: compact [bh, 1, S] exp2-domain lse
         q, k, v, o, lse = res
@@ -1016,12 +1132,16 @@ _sparse_attention_bh.defvjp(_sparse_fwd_rule, _sparse_bwd_rule)
 
 
 def flash_attention(q, k, v, causal: bool = True,
-                    sm_scale: Optional[float] = None, block_q: int = 128,
-                    block_k: int = 128, interpret: Optional[bool] = None):
+                    sm_scale: Optional[float] = None,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
+                    interpret: Optional[bool] = None):
     """Fused attention. q: [B, H, Sq, D]; k, v: [B, Hkv, Sk, D] (GQA: Hkv | H).
 
     Returns [B, H, Sq, D] in q's dtype.  Sequence lengths are padded internally
     to the block size; padded keys are masked, padded query rows sliced off.
+    ``block_q`` / ``block_k`` left ``None`` are chosen from the operands
+    (``_resolve_blocks``); ``choices()`` records every resolution.
     """
     if interpret is None:
         interpret = interpret_kernels()
@@ -1034,10 +1154,11 @@ def flash_attention(q, k, v, causal: bool = True,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
 
-    block_q = min(block_q, max(q_len, 1))
-    block_k = min(block_k, max(kv_len, 1))
-    pad_q = (-q_len) % block_q
-    pad_k = (-kv_len) % block_k
+    choice, pad_q, pad_k = _resolve_blocks(q_len, kv_len, d, q.dtype.itemsize,
+                                           block_q, block_k)
+    with _CHOICES_LOCK:
+        _CHOICES[choice] = _CHOICES.get(choice, 0) + 1
+    block_q, block_k = choice.block_q, choice.block_k
     qp = jnp.pad(q, ((0, 0), (0, 0), (0, pad_q), (0, 0))) if pad_q else q
     kp = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0))) if pad_k else k
     vp = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0))) if pad_k else v

@@ -29,6 +29,7 @@ bf16_optimizer.py:38).
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Any, Callable, Dict, Iterator, Optional, Tuple, Union
 
@@ -903,6 +904,40 @@ class DeepSpeedEngine:
         return {k: rep for k in
                 ("loss", "grad_norm", "overflow", "loss_scale", "skipped")}
 
+    def _step_entry(self, fn, name: str, budget: Optional[int] = 1):
+        """``sentry.wrap`` of a step body that also records, each time the
+        body is traced, which flash kernels at which blocks the program
+        got: ``flash_attention`` resolves its blocks at trace time
+        (``ops/flash_attention.choices``), so what this trace added to that
+        table is what the compiled step runs.  One log line a distinct
+        resolution, its blocks on the ``train_flash_block_q/k`` gauges;
+        ``flash_choices[name]`` keeps them whole for a reader without the
+        log."""
+        from ..ops import flash_attention as fa
+
+        @functools.wraps(fn)
+        def traced(*args):
+            before = fa.choices()
+            out = fn(*args)
+            ran = fa.choices(since=before)
+            self.flash_choices[name] = ran
+            for c, n in ran.items():
+                log_dist(
+                    f"{name}: flash attention {c.generation} "
+                    f"({' + '.join(fa.KERNELS[c.generation])}) at blocks "
+                    f"{c.block_q} x {c.block_k} ({c.how}) for q {c.q_len} x "
+                    f"kv {c.kv_len}, hd {c.d}: {n} call(s) traced",
+                    ranks=[0])
+                for side, block in (("q", c.block_q), ("k", c.block_k)):
+                    self.metrics.gauge(
+                        f"train_flash_block_{side}",
+                        "flash attention block of a compiled step (phase: "
+                        "the program; mode: kernel generation, chosen|given)",
+                        phase=name, mode=f"{c.generation}_{c.how}").set(block)
+            return out
+
+        return self.sentry.wrap(traced, name, budget)
+
     def _build_step_fns(self) -> None:
         # recompile sentry (analysis/sentry.py): the config pins batch
         # shapes, so the fused train step compiles exactly once (budget 1)
@@ -910,6 +945,7 @@ class DeepSpeedEngine:
         # retraces_observed.  multi-step/eval legitimately specialize per
         # shape (scan length = leading batch dim): budget None, count only.
         self.sentry = RecompileSentry(name="training")
+        self.flash_choices: Dict[str, Dict[Any, int]] = {}
         gas = self.gradient_accumulation_steps()
         fp16 = self.fp16_enabled
         micro_loss = self._micro_loss_closure()
@@ -1019,11 +1055,11 @@ class DeepSpeedEngine:
             return jax.lax.scan(body, state, batches)
 
         self._train_multi_fn = jax.jit(
-            self.sentry.wrap(multi_step, "train_multi", budget=None),
+            self._step_entry(multi_step, "train_multi", budget=None),
             out_shardings=(self.state_shardings, metrics_shardings),
             donate_argnums=(0,))
         self._train_step_fn = jax.jit(
-            self.sentry.wrap(train_step, "train_step"),
+            self._step_entry(train_step, "train_step"),
             out_shardings=(self.state_shardings, metrics_shardings),
             donate_argnums=(0,))
         if self.onebit_comm_enabled and self._onebit_compressed:
@@ -1051,7 +1087,7 @@ class DeepSpeedEngine:
             out_shardings=(self.state_shardings, metrics_shardings),
             donate_argnums=(0,))
         self._eval_step_fn = jax.jit(
-            self.sentry.wrap(eval_step, "eval_step", budget=None))
+            self._step_entry(eval_step, "eval_step", budget=None))
         self._tree_add_fn = jax.jit(
             lambda a, b: jax.tree_util.tree_map(jnp.add, a, b),
             donate_argnums=(0,))
@@ -1149,11 +1185,11 @@ class DeepSpeedEngine:
                 lambda st, b: train_step(st, b, base_rng), state, batches)
 
         self._train_step_fn = jax.jit(
-            self.sentry.wrap(train_step, "train_step_onebit"),
+            self._step_entry(train_step, "train_step_onebit"),
             out_shardings=(self.state_shardings, metrics_shardings),
             donate_argnums=(0,))
         self._train_multi_fn = jax.jit(
-            self.sentry.wrap(multi_step, "train_multi_onebit", budget=None),
+            self._step_entry(multi_step, "train_multi_onebit", budget=None),
             out_shardings=(self.state_shardings, metrics_shardings),
             donate_argnums=(0,))
 

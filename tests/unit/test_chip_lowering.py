@@ -137,6 +137,39 @@ def test_flash_train_step_kernels_lower():
     assert text.count("tpu_custom_call") >= 2      # fwd + fused bwd
 
 
+def test_given_flash_blocks_lower_as_if_no_rule_existed(monkeypatch):
+    """ISSUE 35: ``gpt2m-train-1k`` names its blocks (1024 x 1024), so the
+    rule that chooses blocks is never asked: the call lowers to the same
+    text with ``_resolve_blocks`` replaced by the arithmetic
+    ``flash_attention`` did before the rule existed."""
+    q = _sds((8, 16, 1024, 64), jnp.bfloat16)       # the cell's micro-batch
+
+    def loss(q, k, v):
+        o = fa.flash_attention(q, k, v, causal=True, block_q=1024,
+                               block_k=1024, interpret=False)
+        return o.astype(jnp.float32).sum()
+
+    def as_before(q_len, kv_len, d, itemsize, block_q, block_k):
+        bq, bk = min(block_q, max(q_len, 1)), min(block_k, max(kv_len, 1))
+        pad_q, pad_k = (-q_len) % bq, (-kv_len) % bk
+        if fa._v2_eligible(kv_len + pad_k, d):
+            bq = max(8, min(bq, fa._V2_MAX_SCORE_ELEMS // (kv_len + pad_k)))
+        return fa.Choice(q_len, kv_len, d, "-", bq, bk, "-"), pad_q, pad_k
+
+    texts, noted = [], []
+    # one call site for both: the kernels' payloads embed the call stack
+    for resolve in (fa._resolve_blocks, as_before):
+        monkeypatch.setattr(fa, "_resolve_blocks", resolve)
+        before = fa.choices()
+        texts.append(_lower_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, q, q))
+        noted.append(list(fa.choices(since=before)))
+    with_rule, without_rule = texts
+    assert noted[0] == [fa.Choice(1024, 1024, 64, "v2", 1024, 1024, "given")]
+    for kernel in fa.KERNELS["v2"]:
+        assert f'kernel_name = "{kernel}"' in with_rule
+    assert with_rule == without_rule
+
+
 @pytest.mark.parametrize("d,f", [(2048, 8192), (4096, 16384), (5120, 20480)],
                          ids=["opt-1.3b", "opt-6.7b", "opt-13b"])
 def test_w8a8_kernels_lower(d, f, monkeypatch):
@@ -284,6 +317,43 @@ def one_chip():
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     return SingleDeviceSharding(topo.devices[0])
+
+
+#: (shape [B, H, S, hd], KV heads, generation): the four-chip training
+#: cell's call a chip, Llama-class calls at hd 128 (one GQA), and the
+#: resident path OPT / Llama take at S <= 1024
+FLASH_TRAIN_SHAPES = [((8, 32, 2048, 64), 32, "v3"),
+                      ((2, 32, 4096, 128), 32, "v3"),
+                      ((2, 32, 4096, 128), 8, "v3"),
+                      ((1, 16, 8192, 128), 16, "v3"),
+                      ((16, 32, 1024, 64), 32, "v2"),
+                      ((8, 16, 1024, 128), 16, "v2")]
+
+
+@pytest.mark.parametrize("shape,hkv,generation", FLASH_TRAIN_SHAPES)
+def test_flash_default_blocks_compile_for_a_v5e(shape, hkv, generation,
+                                                one_chip):
+    """ISSUE 35: forward + backward at the blocks ``flash_attention``
+    chooses for itself, through Mosaic's own compile for a described v5e —
+    the scoped-VMEM cliff (2048-row blocks are refused) caught without a
+    chip."""
+    b, h, s_len, d = shape
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, hkv, s_len, d), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        o = fa.flash_attention(q, k, v, causal=True, interpret=False)
+        return o.astype(jnp.float32).sum()
+
+    before = fa.choices()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    (choice,) = fa.choices(since=before)
+    assert choice.how == "chosen" and choice.generation == generation
+    assert min(choice.block_q, choice.block_k) >= 512, choice
+    for kernel in fa.KERNELS[generation]:
+        assert kernel in text, (kernel, choice)
 
 
 @pytest.mark.parametrize("kv8", [False, True], ids=["bf16", "kv8"])

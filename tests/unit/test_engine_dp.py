@@ -48,6 +48,52 @@ def test_train_loss_decreases():
     assert losses[-1] < losses[0], f"loss did not decrease: {losses}"
 
 
+@pytest.mark.parametrize("family,how", [("opt", "chosen"), ("gpt2", "given")])
+def test_compiled_step_names_its_flash_kernels_and_blocks(family, how,
+                                                          caplog):
+    """ISSUE 35: the blocks are resolved when the step is traced, so the
+    engine can say once which kernels at which blocks its program runs —
+    OPT names none (the rule chooses), GPT-2 names its own."""
+    import logging
+
+    from deepspeed_tpu.models import opt
+    from deepspeed_tpu.utils.logging import logger
+
+    if family == "opt":
+        cfg = opt.OPTConfig.tiny()
+        cfg.use_flash = True
+        spec = opt.build(cfg)
+    else:
+        cfg = gpt2.GPT2Config.tiny()
+        cfg.use_flash = True
+        spec = gpt2.build(cfg)
+    deepspeed_tpu.comm.reset_topology()
+    engine, _, _, _ = deepspeed_tpu.initialize(model=spec,
+                                               config=base_config())
+    assert engine.flash_choices == {}               # nothing traced yet
+    logger.addHandler(caplog.handler)
+    try:
+        with caplog.at_level(logging.INFO, logger=logger.name):
+            for _ in range(2):                      # the second call: no trace
+                engine.train_batch(make_batch(np.random.default_rng(0),
+                                              engine.train_batch_size()))
+    finally:
+        logger.removeHandler(caplog.handler)
+    (choice, calls), = engine.flash_choices["train_step"].items()
+    assert (choice.q_len, choice.kv_len, choice.how) == (32, 32, how)
+    assert (choice.block_q, choice.block_k) == (32, 32) and calls >= 1
+    lines = [r.getMessage() for r in caplog.records
+             if "flash attention" in r.getMessage()]
+    assert len(lines) == 1, lines                   # once, at the compile
+    assert f"blocks 32 x 32 ({how})" in lines[0]
+    assert "train_step: flash attention v2 (flash_fwd_resident + " \
+        "flash_bwd_fused)" in lines[0]
+    snap = engine.metrics.prometheus_text()
+    for side in "qk":
+        assert (f'train_flash_block_{side}{{mode="v2_{how}",'
+                f'phase="train_step"}} 32') in snap, snap
+
+
 def test_train_batches_matches_per_step():
     """k steps via one train_batches dispatch == k train_batch calls."""
     deepspeed_tpu.comm.reset_topology()

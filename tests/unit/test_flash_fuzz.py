@@ -24,9 +24,13 @@ for _ in range(10):
     CASES.append((2, h_kv * rep, h_kv, s, d, causal))
 
 
+# blocks the caller names (128 x 128: several chunks a row at these lengths,
+# as every case ran before flash_attention chose its own) and the chosen ones
+@pytest.mark.parametrize("blocks", [{}, {"block_q": 128, "block_k": 128}],
+                         ids=["chosen", "given128"])
 @pytest.mark.parametrize("kernel_ver", ["v2", "v1", "v3"])
 @pytest.mark.parametrize("b,h,hkv,s,d,causal", CASES)
-def test_fuzz_matches_reference(b, h, hkv, s, d, causal, kernel_ver,
+def test_fuzz_matches_reference(b, h, hkv, s, d, causal, kernel_ver, blocks,
                                 monkeypatch):
     # pin ALL branches: an ambient DS_FLASH_V2/V3 from a debugging shell
     # must not silently collapse the matrix onto one path
@@ -40,14 +44,14 @@ def test_fuzz_matches_reference(b, h, hkv, s, d, causal, kernel_ver,
     q = jax.random.normal(ks[0], (b, h, s, d))
     k = jax.random.normal(ks[1], (b, hkv, s, d))
     v = jax.random.normal(ks[2], (b, hkv, s, d))
-    out = flash_attention(q, k, v, causal=causal, interpret=True)
+    out = flash_attention(q, k, v, causal=causal, interpret=True, **blocks)
     ref = mha_reference(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
     def loss_f(q, k, v):
         return jnp.sum(flash_attention(q, k, v, causal=causal,
-                                       interpret=True) ** 2)
+                                       interpret=True, **blocks) ** 2)
 
     def loss_r(q, k, v):
         return jnp.sum(mha_reference(q, k, v, causal=causal) ** 2)
