@@ -247,7 +247,7 @@ def init_serving(model=None, config=None, params=None, *, slots=8,
                  sampling=True, logit_masks=False,
                  shard_kv=None, topology=None, device_group=None,
                  debug_checks=False,
-                 trace_capacity=16384, slo_targets=None, peak_flops=None,
+                 trace_capacity=131072, slo_targets=None, peak_flops=None,
                  **kwargs):
     """Continuous-batching serving entry: an ``init_inference`` engine
     wrapped in the block-paged scheduler (``inference/serving.py``).

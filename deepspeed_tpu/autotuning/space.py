@@ -73,7 +73,7 @@ BASE_SERVING_CONFIG: Dict[str, Any] = {
     "resident_window_blocks": 0,
     "sampling": True,
     "logit_masks": False,
-    "trace_capacity": 16384,
+    "trace_capacity": 131072,
 }
 
 #: conservative default domains — callers override per workload (the

@@ -225,6 +225,7 @@ import contextlib
 import dataclasses
 import inspect
 import os
+import statistics
 import tempfile
 import threading
 import time
@@ -251,7 +252,7 @@ from ..parallel.topology import DP_AXIS, SP_AXIS, TP_AXIS
 from ..telemetry import MetricsRegistry, ProfilerWindow, TraceTimeline
 from ..telemetry import trace as trace_mod
 from ..telemetry.slo import SLOTracker
-from ..utils.logging import log_dist
+from ..utils.logging import log_dist, logger
 from ..utils.platform import on_tpu
 from .paged import (SCRATCH_BLOCK, BlockAllocator, GroupedBlockAllocator,
                     HostBlockStore, NvmeBlockStore, PrefixCache,
@@ -271,6 +272,28 @@ class RequestFailedError(RuntimeError):
         self.uid = uid
         self.reason = reason
 
+
+#: the pieces of a step the segments time (``TraceTimeline.segment``):
+#: ``plan`` / ``upload`` / ``commit`` on the ``step.prefill`` and
+#: ``step.decode`` phases, ``enqueue`` / ``wait`` on the in-flight spans
+SEGMENTS = ("plan", "upload", "enqueue", "wait", "commit")
+#: a step is a STALL when it lasts over ``STALL_FACTOR`` x the median of
+#: the last ``STALL_HISTORY`` steps of its shape (with / without a prefill
+#: group) — the factor ``chipbench``'s ``step_stall_share`` puts on a
+#: window's own median; a shape's median is taken every ``STALL_REFRESH``
+#: steps of it (so none is judged before that many), and at most one
+#: warning is logged per ``STALL_WARN_EVERY_S``
+STALL_FACTOR = 3.0
+STALL_HISTORY = 256
+STALL_REFRESH = 32
+STALL_WARN_EVERY_S = 10.0
+#: what explains a stall, the first that covers over half its excess:
+#: the scheduler's thread off the CPU outside the in-flight spans (blocked
+#: in a runtime call such as an upload, or descheduled), the collector,
+#: the wait for a program's results, else the host's own code
+STALL_CAUSES = ("offcpu", "gc", "device_wait", "host")
+#: what a stalled step is compared in (``ServingEngine._stall``)
+_STALL_FIELDS = ("wall",) + SEGMENTS + ("offcpu", "gc")
 
 #: legal ``quantize=`` values (order-normalized; ``None`` = full precision)
 _QUANT_MODES = ("kv8", "w8a8", "w8a8+kv8")
@@ -784,7 +807,12 @@ class ServingEngine:
                     disables event recording entirely (one predicate per
                     would-be event); the metrics registry backing
                     ``stats()`` and the spans' profiler annotations are
-                    always on.
+                    always on.  The default
+                    (``telemetry/trace.py DEFAULT_CAPACITY``) holds two
+                    minutes of the densest serving cell at twice its
+                    step rate; the step's thread-CPU / collector
+                    accounting and the ``stall`` events ride the ring's
+                    clock and go off with it.
     slo_targets:    per-``slo_class`` latency targets + attainment
                     objective overrides, merged over
                     ``telemetry/slo.py DEFAULT_SLO_TARGETS`` — every
@@ -822,7 +850,7 @@ class ServingEngine:
                  sampling: bool = True,
                  logit_masks: bool = False,
                  debug_checks: bool = False,
-                 trace_capacity: int = 16384,
+                 trace_capacity: int = trace_mod.DEFAULT_CAPACITY,
                  slo_targets: Optional[Dict[str, Dict[str, float]]] = None,
                  peak_flops: Optional[float] = None):
         self.spec_tokens = int(spec_tokens)
@@ -1516,6 +1544,13 @@ class ServingEngine:
         self._c_invariant_checks = m.counter(
             "serving_invariant_checks_total",
             "paged-state audits run (analysis/invariants.py)")
+        self._c_step_stalls = {
+            cause: m.counter(
+                "serving_step_stalls_total",
+                "scheduler steps over 3x the running median of their "
+                "shape, by what explains most of the excess",
+                cause=cause)
+            for cause in STALL_CAUSES}
         # tiered-KV swap traffic (zero-valued, never incremented when the
         # tier is off — the cells exist so dashboards see a stable schema)
         self._c_swap_out = m.counter(
@@ -1609,9 +1644,37 @@ class ServingEngine:
         self.timeline = TraceTimeline(capacity=trace_capacity)
         # readable after this engine is gone (telemetry/trace.py kept())
         trace_mod.keep("serve", self.timeline)
-        #: KV-manager busy seconds of the current step (``step.kv_s``)
-        self._kv_s = 0.0
+        #: the argument dict of the ``step`` span being recorded: what
+        #: accrues per step (``kv_s``, ``flight_s``, ``flight_cpu_s``)
+        #: lands here; outside a step (and with the ring off), on a dict
+        #: nobody reads
+        self._no_step = {"kv_s": 0.0, "flight_s": 0.0, "flight_cpu_s": 0.0}
+        self._step_args: Dict[str, Any] = self._no_step
+        #: the argument dict of the host phase (``step.prefill`` /
+        #: ``step.decode``) being recorded: where the runners' ``plan`` /
+        #: ``upload`` / ``commit`` segments land
+        self._phase: Dict[str, Any] = {}
+        #: argument dicts of this step's phase and in-flight spans, for
+        #: the stall check's per-segment sums
+        self._seg_args: List[Dict[str, Any]] = []
+        #: per step shape (with / without a prefill group): the last
+        #: ``STALL_HISTORY`` steps (``_note_step``'s rows) and the duration
+        #: over which a step of that shape is a stall (None: too few yet)
+        self._prefilled = False
+        self._step_history = {shape: deque(maxlen=STALL_HISTORY)
+                              for shape in (False, True)}
+        self._stall_over: Dict[bool, Optional[float]] = {False: None,
+                                                         True: None}
+        self._stall_seen = {False: 0, True: 0}
+        self._stall_warned = float("-inf")
+        #: blocks evicted from the prefix trie since the last
+        #: ``evict_block`` instant (one a host phase that evicted)
+        self._evicted_blocks = 0
+        #: collector pauses inside this engine's steps (``step.gc_s``)
+        self._gc = trace_mod.GcWatch(self.timeline)
+        self._closed = False
         if self.timeline.enabled:
+            self._gc.install()
             # bounded lane table: one span lane per SLOT (a request's span
             # lands on the slot that finished it) — lane count never grows
             # with traffic, unlike per-uid lanes
@@ -1682,6 +1745,17 @@ class ServingEngine:
         unlink an auto-minted spill file (a caller-provided ``nvme_path``
         is the caller's to keep).  Idempotent; the device pool and
         compiled programs are garbage-collected as usual."""
+        self._gc.remove()
+        tl = self.timeline
+        if tl.dropped and not self._closed:
+            span_s = max(tl.now_us() * 1e-6, 1e-9)
+            logger.warning(
+                f"ServingEngine: the trace ring (trace_capacity="
+                f"{tl.capacity}) wrapped: {tl.dropped} of {tl.emitted} "
+                f"events dropped at {tl.emitted / span_s:.0f} events/s "
+                f"over {span_s:.0f} s — raise trace_capacity to hold a "
+                "longer window")
+        self._closed = True
         nvme, self._nvme = self._nvme, None
         if nvme is not None:
             nvme.close()
@@ -2632,8 +2706,8 @@ class ServingEngine:
             ids_dev = jnp.asarray(ids)
             # each batch's round trip (gather program + D2H) as an X span:
             # the FLOPs profiler's busy-fraction breakdown reads "swap"
-            with self.timeline.span("swap", direction="out",
-                                    blocks=len(chunk_b)):
+            with self._in_flight("swap", direction="out",
+                                 blocks=len(chunk_b)):
                 with self._tp_ctx():
                     staged = self._get_demote_fn()(self._swap_pools(),
                                                    ids_dev)
@@ -2674,15 +2748,15 @@ class ServingEngine:
                 keys.append(key)
         if blocks:
             self._demote_blocks(blocks, keys)
-        for e, key in zip(entries, ekeys):
-            b = int(e.block)
+        for e in entries:
             self._prefix.evict_entry(e, self._alloc)
-            self._kv_scale_live.discard(b)
-            # demoted=True iff the tier really holds the bytes now (a
-            # saturated arena can refuse the store — then this eviction
-            # discarded contents, exactly like the untiered path)
-            self.timeline.instant("evict_block", block=b,
-                                  demoted=self._host.has(key))
+            self._kv_scale_live.discard(int(e.block))
+        # one event a batch.  demoted counts the blocks whose bytes the
+        # tier really holds now (a saturated arena can refuse the store —
+        # then the eviction discarded contents, like the untiered path)
+        self.timeline.instant(
+            "evict_block", blocks=len(entries),
+            demoted=sum(self._host.has(key) for key in ekeys))
         return len(entries)
 
     def _demote_slot_blocks(self, slot: int, st: "_SlotState") -> None:
@@ -2978,8 +3052,8 @@ class ServingEngine:
                 ids_dev = jnp.asarray(ids)
                 # waiting on the staged H2D copy, then the scatter's
                 # dispatch (asynchronous: nothing reads it back here)
-                with self.timeline.span("swap", direction="in",
-                                        blocks=len(got)):
+                with self._in_flight("swap", direction="in",
+                                     blocks=len(got)):
                     wait_s += self._promote_wait(staged)
                     with self._tp_ctx():
                         self._set_swap_pools(self._get_promote_fn()(
@@ -3036,7 +3110,8 @@ class ServingEngine:
         try:
             return fn(*args, **kwargs)
         finally:
-            self._kv_s += time.perf_counter() - t0
+            # the segments' accumulator, by hand: this runs once a slot
+            self._step_args["kv_s"] += time.perf_counter() - t0
 
     def _decref(self, b: int) -> None:
         """Release one reference; when the block actually frees, retire
@@ -3115,8 +3190,7 @@ class ServingEngine:
                     evicted = self._prefix.evict_one(self._alloc)
                     if evicted:
                         self._kv_scale_live.discard(evicted)
-                        self.timeline.instant("evict_block",
-                                              block=int(evicted))
+                        self._evicted_blocks += 1   # the phase's event
                         continue
             cands = self._active if self.dp_degree == 1 else \
                 {s: st for s, st in self._active.items()
@@ -3530,8 +3604,33 @@ class ServingEngine:
             self._fault_injector.on_step(self)
         if not (self._cancel_flags or self._pending or self._active):
             return self._step_idle()
-        with self.timeline.span("step") as step_args:
-            return self._step_phases(step_args)
+        tl = self.timeline
+        if not tl.enabled:
+            with tl.span("step") as step_args:
+                return self._step_phases(step_args)
+        # ring on: the step's own cost beside its wall clock — thread-CPU
+        # seconds (and, from _in_flight, the part of them and of the wall
+        # clock inside in-flight spans), the collector's runs
+        watch = self._gc
+        watch.thread = threading.get_ident()
+        gc_s0, gc_n0 = watch.seconds, watch.runs
+        t0, cpu0 = time.perf_counter(), time.thread_time()
+        try:
+            with tl.span("step", kv_s=0.0, flight_s=0.0,
+                         flight_cpu_s=0.0) as step_args:
+                self._step_args = step_args
+                self._seg_args = []
+                try:
+                    more = self._step_phases(step_args)
+                finally:
+                    step_args.update(cpu_s=time.thread_time() - cpu0,
+                                     gc_s=watch.seconds - gc_s0,
+                                     gc_n=watch.runs - gc_n0)
+        finally:
+            watch.thread = None
+            self._step_args = self._no_step
+        self._note_step(step_args, time.perf_counter() - t0)
+        return more
 
     def _step_idle(self) -> bool:
         if self._host is not None:
@@ -3542,8 +3641,7 @@ class ServingEngine:
     def _step_phases(self, step_args: Dict[str, Any]) -> bool:
         """The body of :meth:`step` under its ``step`` span; fills the
         span's arguments (``step_args``) at the end of the iteration."""
-        span = self.timeline.span
-        self._kv_s = 0.0
+        span, seg = self.timeline.span, self.timeline.segment
         with span("step.admit"):
             self._process_cancellations()
             if not self._pending and not self._active:
@@ -3556,7 +3654,11 @@ class ServingEngine:
             self._admit()
             self._refresh_masks()
         with span("step.prefill") as phase:
+            self._phase = phase
+            self._seg_args.append(phase)
             phase["groups"] = self._run_prefill(params)
+            if self._evicted_blocks:
+                self._note_evictions()
             if self.role == "prefill":
                 # disaggregated mode: prefill-complete slots leave the
                 # decode rotation NOW — the decode dispatch below only ever
@@ -3566,17 +3668,23 @@ class ServingEngine:
         # prefilling/empty slots point at the scratch block.  In
         # speculative mode the single-token step is replaced by a
         # draft–verify round committing up to K+1 tokens per slot.
+        self._prefilled = bool(phase["groups"])
         with span("step.decode") as phase:
+            self._phase = phase
+            self._seg_args.append(phase)
             # a slot that finished prefill above decodes in this SAME
             # iteration — its first generated token must gate the second,
             # so constrained rows rebuild between the two dispatches
-            self._refresh_masks()
+            with seg("step.decode.plan", phase):
+                self._refresh_masks()
             if self.spec_tokens:
                 phase["slots"] = self._run_spec_decode(params)
             elif self._K > 1:
                 phase["slots"] = self._run_fused_decode(params)
             else:
                 phase["slots"] = self._run_plain_decode(params)
+            if self._evicted_blocks:
+                self._note_evictions()
         with span("step.post"):
             # resident-window maintenance AFTER both phases committed this
             # iteration's tokens: mid-prefill giant prompts slide too (the
@@ -3593,8 +3701,7 @@ class ServingEngine:
                 admitted=self.admitted - admitted0,
                 evicted=self.preempted - preempted0,
                 blocks_in_use=self._alloc.blocks_in_use,
-                active=len(self._active), pending=len(self._pending),
-                kv_s=self._kv_s)
+                active=len(self._active), pending=len(self._pending))
             if self._windows:
                 self._full_peak = max(self._full_peak,
                                       self._alloc.blocks_in_use)
@@ -3613,6 +3720,95 @@ class ServingEngine:
                 audit_serving_engine(self, self._active)
                 self._c_invariant_checks.inc()
         return bool(self._pending or self._active)
+
+    def _note_evictions(self) -> None:
+        """One ``evict_block`` instant for every trie block the host phase
+        that just ran evicted to reserve its rows' blocks — not one a
+        block: an admission into a full pool evicts about as many blocks
+        as it allocates (the tiered path's batches say so themselves,
+        ``_demote_evict_batch``)."""
+        self.timeline.instant("evict_block", blocks=self._evicted_blocks,
+                              demoted=0)
+        self._evicted_blocks = 0
+
+    @contextlib.contextmanager
+    def _in_flight(self, name: str, **args):
+        """An in-flight span (:meth:`step`): a device program runs from the
+        jitted call inside it to its results on the host.  Ring on, the
+        span's wall and thread-CPU seconds are also added to the step's
+        ``flight_s`` / ``flight_cpu_s`` — the step's duration and ``cpu_s``
+        less these are the host's own time and the CPU it got for it."""
+        tl = self.timeline
+        with tl.span(name, **args) as span_args:
+            if not tl.enabled:
+                yield span_args
+                return
+            self._seg_args.append(span_args)
+            t0, cpu0 = time.perf_counter(), time.thread_time()
+            try:
+                yield span_args
+            finally:
+                step = self._step_args
+                step["flight_s"] += time.perf_counter() - t0
+                step["flight_cpu_s"] += time.thread_time() - cpu0
+
+    def _note_step(self, step_args: Dict[str, Any], wall_s: float) -> None:
+        """Ring on, after every step: file it under its shape — duration,
+        seconds off the CPU outside the in-flight spans, collector seconds
+        and the argument dicts its segments are on — and, if it lasted over
+        ``STALL_FACTOR`` x that shape's running median, name the stall
+        (:meth:`_stall`)."""
+        if "iteration" not in step_args:
+            return                          # the cancellations emptied it
+        own_s = wall_s - step_args["flight_s"]
+        own_cpu_s = step_args["cpu_s"] - step_args["flight_cpu_s"]
+        row = (wall_s, max(own_s - own_cpu_s, 0.0), step_args["gc_s"],
+               self._seg_args)
+        shape = self._prefilled
+        history = self._step_history[shape]
+        over = self._stall_over[shape]
+        if over is not None and wall_s > over:
+            self._stall(step_args["iteration"], row, history)
+        history.append(row)
+        seen = self._stall_seen[shape] = self._stall_seen[shape] + 1
+        if seen % STALL_REFRESH == 0:
+            self._stall_over[shape] = STALL_FACTOR * statistics.median(
+                r[0] for r in history)
+
+    def _stall(self, iteration: int, row, history) -> None:
+        """A step that stalled: a ``stall`` instant on the ring saying how
+        long against what median, which segment grew most against its own
+        median, and the cause (``STALL_CAUSES``); a tick of
+        ``serving_step_stalls_total{cause}``; a rate-limited warning.  The
+        segments are summed here, for the stalled step and the history it
+        is held against: a stall is rare and has lost milliseconds."""
+        def fields(r):
+            wall_s, offcpu_s, gc_s, seg_args = r
+            return (wall_s, *(sum(a.get(k + "_s", 0.0) for a in seg_args)
+                              for k in SEGMENTS), offcpu_s, gc_s)
+
+        now = dict(zip(_STALL_FIELDS, fields(row)))
+        median = dict(zip(_STALL_FIELDS, (
+            statistics.median(col) for col in zip(*map(fields, history)))))
+        grew = {f: now[f] - median[f] for f in _STALL_FIELDS}
+        cause = next(
+            (c for c, f in (("offcpu", "offcpu"), ("gc", "gc"),
+                            ("device_wait", "wait"))
+             if grew[f] > grew["wall"] / 2), "host")
+        event = dict(
+            iteration=iteration, cause=cause,
+            wall_ms=round(now["wall"] * 1e3, 3),
+            median_ms=round(median["wall"] * 1e3, 3),
+            segment=max(SEGMENTS, key=grew.get),
+            offcpu_ms=round(now["offcpu"] * 1e3, 3),
+            gc_ms=round(now["gc"] * 1e3, 3),
+            wait_ms=round(now["wait"] * 1e3, 3))
+        self.timeline.instant("stall", **event)
+        self._c_step_stalls[cause].inc()
+        t = time.perf_counter()
+        if t - self._stall_warned >= STALL_WARN_EVERY_S:
+            self._stall_warned = t
+            logger.warning(f"ServingEngine: stalled step {event}")
 
     def drain(self) -> List[_PendingItem]:
         """Quiesce this engine for a replica handoff (router drain
@@ -3994,48 +4190,61 @@ class ServingEngine:
 
     def _run_plain_decode(self, params) -> int:
         """One single-token decode step over every decode-phase slot;
-        returns how many slots it advanced (the decode runners all do)."""
+        returns how many slots it advanced (the decode runners all do).
+        Every runner times its pieces as segments (``SEGMENTS``): ``plan``
+        / ``upload`` / ``commit`` onto ``self._phase``, the argument dict
+        of the host phase it runs in, ``enqueue`` / ``wait`` onto its
+        in-flight span."""
         active = self._active
-        dec = sorted(
-            (s for s, st in active.items() if st.phase == "decode"),
-            key=lambda s: active[s].admit_seq)
-        for slot in dec:
-            if slot in active:
-                self._kv(self._ensure_blocks, slot,
-                         int(self._lengths[slot]) + 1)
-        dec = sorted(s for s, st in active.items()
-                     if st.phase == "decode")
-        if not dec:
-            return 0
-        bt = np.zeros_like(self._tables)
-        bt[dec] = self._tables[dec]
-        args = (params, self._cache, jnp.asarray(self._tokens),
-                jnp.asarray(self._lengths), self._bt(bt))
-        if self.resident_window_blocks:
-            args += (jnp.asarray(self._window_start),)
-        args += self._samp_args(self._decode_counts())
-        decode_fn = self._get_decode_fn()
-        with self.timeline.span("decode", slots=len(dec),
-                                **self._sampler_rows(dec),
-                                **self._kv_reach(self._lengths[dec] + 1),
-                                ) as span_args:
-            with self._decode_ctx():
+        seg, phase = self.timeline.segment, self._phase
+        with seg("step.decode.plan", phase):
+            dec = sorted(
+                (s for s, st in active.items() if st.phase == "decode"),
+                key=lambda s: active[s].admit_seq)
+            for slot in dec:
+                if slot in active:
+                    self._kv(self._ensure_blocks, slot,
+                             int(self._lengths[slot]) + 1)
+            dec = sorted(s for s, st in active.items()
+                         if st.phase == "decode")
+            if not dec:
+                return 0
+            bt = np.zeros_like(self._tables)
+            bt[dec] = self._tables[dec]
+            counts = self._decode_counts()
+            decode_fn = self._get_decode_fn()
+            span_kw = {**self._sampler_rows(dec),
+                       **self._kv_reach(self._lengths[dec] + 1)}
+        with seg("step.decode.upload", phase):
+            args = (params, self._cache, jnp.asarray(self._tokens),
+                    jnp.asarray(self._lengths), self._bt(bt))
+            if self.resident_window_blocks:
+                args += (jnp.asarray(self._window_start),)
+            args += self._samp_args(counts)
+        with self._in_flight("decode", slots=len(dec),
+                             **span_kw) as span_args:
+            with seg("decode.enqueue", span_args), self._decode_ctx():
                 nxt, self._cache = decode_fn(*args)
-            nxt = self._split_record(np.asarray(nxt), (self.slots,),
-                                     span_args)
-        self._c_decode_steps.inc()
-        for slot in dec:
-            st = active[slot]
-            self._lengths[slot] += 1   # the fed token is now cached
-            tok = int(nxt[slot])
-            st.out.append(tok)
-            self._emit_tokens(st, (tok,))
-            self._mark_first(st)
-            if (st.eos is not None and tok == st.eos) \
-                    or st.gen_count >= st.req.max_new_tokens:
-                self._finish_slot(slot)
-            else:
-                self._tokens[slot] = tok
+            with seg("decode.wait", span_args):
+                nxt = self._split_record(np.asarray(nxt), (self.slots,),
+                                         span_args)
+        with seg("step.decode.commit", phase):
+            # the call's operands are released here, on the commit's
+            # account, not when the frame dies outside every segment
+            del args
+            self._c_decode_steps.inc()
+            for slot in dec:
+                st = active[slot]
+                self._lengths[slot] += 1   # the fed token is now cached
+                tok = int(nxt[slot])
+                st.out.append(tok)
+                self._emit_tokens(st, (tok,))
+                self._mark_first(st)
+                if (st.eos is not None and tok == st.eos) \
+                        or st.gen_count >= st.req.max_new_tokens:
+                    self._finish_slot(slot)
+                else:
+                    self._tokens[slot] = tok
         return len(dec)
 
     def _fence_harvest(self, *arrays):
@@ -4062,90 +4271,98 @@ class ServingEngine:
         iteration boundary exactly as in single-step mode."""
         K = self._K
         active = self._active
-        dec = sorted(
-            (s for s, st in active.items() if st.phase == "decode"),
-            key=lambda s: active[s].admit_seq)
-        want: Dict[int, int] = {}
-        for slot in dec:
-            if slot in active and active[slot].phase == "decode":
+        seg, phase = self.timeline.segment, self._phase
+        with seg("step.decode.plan", phase):
+            dec = sorted(
+                (s for s, st in active.items() if st.phase == "decode"),
+                key=lambda s: active[s].admit_seq)
+            want: Dict[int, int] = {}
+            for slot in dec:
+                if slot in active and active[slot].phase == "decode":
+                    st = active[slot]
+                    ln = int(self._lengths[slot])
+                    w = max(1, min(K, st.req.max_new_tokens - st.gen_count))
+                    if self._masks is not None \
+                            and st.req.mask_builder is not None:
+                        # constrained slots advance ONE token per dispatch:
+                        # the mask row is a host-built function of every
+                        # token emitted so far, and the host can only
+                        # refresh it between dispatches
+                        w = 1
+                    want[slot] = w
+                    self._kv(self._ensure_blocks, slot,
+                             min(ln + w, self._cache_len))
+            dec = sorted(s for s, st in active.items()
+                         if st.phase == "decode")
+            if not dec:
+                return 0
+            budgets = np.zeros(self.slots, np.int32)
+            eos_ids = np.full(self.slots, -1, np.int32)
+            actv = np.zeros(self.slots, bool)
+            for slot in dec:
                 st = active[slot]
                 ln = int(self._lengths[slot])
-                w = max(1, min(K, st.req.max_new_tokens - st.gen_count))
-                if self._masks is not None \
-                        and st.req.mask_builder is not None:
-                    # constrained slots advance ONE token per dispatch:
-                    # the mask row is a host-built function of every
-                    # token emitted so far, and the host can only refresh
-                    # it between dispatches
-                    w = 1
-                want[slot] = w
-                self._kv(self._ensure_blocks, slot,
-                         min(ln + w, self._cache_len))
-        dec = sorted(s for s, st in active.items()
-                     if st.phase == "decode")
-        if not dec:
-            return 0
-        budgets = np.zeros(self.slots, np.int32)
-        eos_ids = np.full(self.slots, -1, np.int32)
-        actv = np.zeros(self.slots, bool)
-        for slot in dec:
-            st = active[slot]
-            ln = int(self._lengths[slot])
-            # the device budget is additionally clamped to the held span —
-            # a window can never write past the blocks it reserved
-            span = int(np.count_nonzero(self._tables[slot])) \
-                * self.block_size
-            b = min(want.get(slot, K), max(span - ln, 0))
-            if b < 1:
-                continue
-            budgets[slot] = b
-            actv[slot] = True
-            if st.eos is not None:
-                eos_ids[slot] = int(st.eos)
-        dec = [s for s in dec if actv[s]]
-        if not dec:
-            return 0
-        bt = np.zeros_like(self._tables)
-        bt[dec] = self._tables[dec]
-        args = (params, self._cache, jnp.asarray(self._tokens),
-                jnp.asarray(self._lengths), jnp.asarray(bt),
-                jnp.asarray(actv), jnp.asarray(budgets),
-                jnp.asarray(eos_ids),
-                *self._samp_args(self._decode_counts()))
-        decode_fn = self._get_decode_fn()
-        with self.timeline.span("decode", slots=len(dec), fused=K,
-                                **self._sampler_rows(dec)) as span_args:
-            with self._decode_ctx():
+                # the device budget is additionally clamped to the held
+                # span — a window can never write past the blocks it
+                # reserved
+                span = int(np.count_nonzero(self._tables[slot])) \
+                    * self.block_size
+                b = min(want.get(slot, K), max(span - ln, 0))
+                if b < 1:
+                    continue
+                budgets[slot] = b
+                actv[slot] = True
+                if st.eos is not None:
+                    eos_ids[slot] = int(st.eos)
+            dec = [s for s in dec if actv[s]]
+            if not dec:
+                return 0
+            bt = np.zeros_like(self._tables)
+            bt[dec] = self._tables[dec]
+            counts = self._decode_counts()
+            decode_fn = self._get_decode_fn()
+            span_kw = self._sampler_rows(dec)
+        with seg("step.decode.upload", phase):
+            args = (params, self._cache, jnp.asarray(self._tokens),
+                    jnp.asarray(self._lengths), jnp.asarray(bt),
+                    jnp.asarray(actv), jnp.asarray(budgets),
+                    jnp.asarray(eos_ids), *self._samp_args(counts))
+        with self._in_flight("decode", slots=len(dec), fused=K,
+                             **span_kw) as span_args:
+            with seg("decode.enqueue", span_args), self._decode_ctx():
                 out, self._cache = decode_fn(*args)
-            out, = self._fence_harvest(out)
-            out = self._split_record(out, (self.slots, K), span_args)
+            with seg("decode.wait", span_args):
+                out, = self._fence_harvest(out)
+                out = self._split_record(out, (self.slots, K), span_args)
         # ----- the fence catch-up: replay each slot's committed window
         # tokens through the exact K=1 commit sequence (emission order,
         # finish conditions, TTFT stamps — token- and event-identical)
-        trips = 0
-        for slot in dec:
-            st = active[slot]
-            emitted = 0
-            for i in range(K):
-                tok = int(out[slot, i])
-                if tok < 0:
-                    break
-                emitted += 1
-                self._lengths[slot] += 1
-                st.out.append(tok)
-                self._emit_tokens(st, (tok,))
-                self._mark_first(st)
-                if (st.eos is not None and tok == st.eos) \
-                        or st.gen_count >= st.req.max_new_tokens:
-                    self._finish_slot(slot)
-                    break
-                self._tokens[slot] = tok
-            trips = max(trips, emitted)
-        # decode_steps counts executed device ITERATIONS (the while_loop
-        # trip count = the deepest slot's window), keeping per-iteration
-        # FLOPs billing identical to single-step mode
-        self._c_decode_steps.inc(trips)
-        self._c_fused_iterations.inc(trips)
+        with seg("step.decode.commit", phase):
+            del args                       # released on the commit's account
+            trips = 0
+            for slot in dec:
+                st = active[slot]
+                emitted = 0
+                for i in range(K):
+                    tok = int(out[slot, i])
+                    if tok < 0:
+                        break
+                    emitted += 1
+                    self._lengths[slot] += 1
+                    st.out.append(tok)
+                    self._emit_tokens(st, (tok,))
+                    self._mark_first(st)
+                    if (st.eos is not None and tok == st.eos) \
+                            or st.gen_count >= st.req.max_new_tokens:
+                        self._finish_slot(slot)
+                        break
+                    self._tokens[slot] = tok
+                trips = max(trips, emitted)
+            # decode_steps counts executed device ITERATIONS (the
+            # while_loop trip count = the deepest slot's window), keeping
+            # per-iteration FLOPs billing identical to single-step mode
+            self._c_decode_steps.inc(trips)
+            self._c_fused_iterations.inc(trips)
         return len(dec)
 
     def _run_spec_decode(self, params):
@@ -4166,67 +4383,90 @@ class ServingEngine:
         """
         k = self.spec_tokens
         active = self._active
-        dec = sorted(
-            (s for s, st in active.items() if st.phase == "decode"),
-            key=lambda s: active[s].admit_seq)
-        for slot in dec:
-            if slot in active and active[slot].phase == "decode":
-                st = active[slot]
-                ln = int(self._lengths[slot])
-                cap = max(st.pos_cap, ln + 1)
-                self._kv(self._ensure_blocks, slot,
-                         min(ln + k + 1, cap, self._cache_len))
-        dec = sorted(s for s, st in active.items()
-                     if st.phase == "decode")
-        if not dec:
-            return 0
-        bt = np.zeros_like(self._tables)
-        bt[dec] = self._tables[dec]
-        samp = self._samp_args(self._decode_counts())
-        bt_dev, len_dev = jnp.asarray(bt), jnp.asarray(self._lengths)
+        seg, phase = self.timeline.segment, self._phase
+        with seg("step.decode.plan", phase):
+            dec = sorted(
+                (s for s, st in active.items() if st.phase == "decode"),
+                key=lambda s: active[s].admit_seq)
+            for slot in dec:
+                if slot in active and active[slot].phase == "decode":
+                    st = active[slot]
+                    ln = int(self._lengths[slot])
+                    cap = max(st.pos_cap, ln + 1)
+                    self._kv(self._ensure_blocks, slot,
+                             min(ln + k + 1, cap, self._cache_len))
+            dec = sorted(s for s, st in active.items()
+                         if st.phase == "decode")
+            if not dec:
+                return 0
+            bt = np.zeros_like(self._tables)
+            bt[dec] = self._tables[dec]
+            counts = self._decode_counts()
+        with seg("step.decode.upload", phase):
+            samp = self._samp_args(counts)
+            bt_dev, len_dev = jnp.asarray(bt), jnp.asarray(self._lengths)
         if self._draft is not None:
-            args = (self._draft.params, self._dcache,
-                    jnp.asarray(self._tokens), len_dev, bt_dev, *samp)
+            with seg("step.decode.upload", phase):
+                args = (self._draft.params, self._dcache,
+                        jnp.asarray(self._tokens), len_dev, bt_dev, *samp)
             draft_fn = self._get_draft_fn()
-            with self.timeline.span("spec_propose", slots=len(dec),
-                                    mode="draft"):
-                with self._tp_ctx():
+            with self._in_flight("spec_propose", slots=len(dec),
+                                 mode="draft") as span_args:
+                with seg("spec_propose.enqueue", span_args), self._tp_ctx():
                     drafts, self._dcache = draft_fn(*args)
-                drafts = np.asarray(drafts)
+                with seg("spec_propose.wait", span_args):
+                    drafts = np.asarray(drafts)
         else:
             # the n-gram proposer is host work under the documented span
             # name: no program is in flight, the device idles through it
+            # — planning, by the segments' account
             with self.timeline.span("spec_propose", slots=len(dec),
-                                    mode="ngram"):
+                                    mode="ngram"), \
+                    seg("step.decode.plan", phase):
                 drafts = np.zeros((self.slots, k), np.int32)
                 for slot in dec:
                     st = active[slot]
                     drafts[slot] = self._proposer.propose(
                         np.concatenate([st.prompt_eff,
                                         np.asarray(st.out, np.int32)]))
-        ids = np.zeros((self.slots, k + 1), np.int32)
-        valid = np.zeros(self.slots, np.int32)
-        ids[dec, 0] = self._tokens[dec]
-        ids[dec, 1:] = drafts[dec]
-        valid[dec] = k + 1
-        args = (params, self._cache, jnp.asarray(ids), bt_dev, len_dev,
-                jnp.asarray(valid), *samp)
-        verify_fn = self._get_verify_fn()
-        with self.timeline.span("spec_verify", slots=len(dec),
-                                window=k + 1) as span_args:
-            with self._tp_ctx():
+        with seg("step.decode.plan", phase):
+            ids = np.zeros((self.slots, k + 1), np.int32)
+            valid = np.zeros(self.slots, np.int32)
+            ids[dec, 0] = self._tokens[dec]
+            ids[dec, 1:] = drafts[dec]
+            valid[dec] = k + 1
+            verify_fn = self._get_verify_fn()
+        with seg("step.decode.upload", phase):
+            args = (params, self._cache, jnp.asarray(ids), bt_dev, len_dev,
+                    jnp.asarray(valid), *samp)
+        with self._in_flight("spec_verify", slots=len(dec),
+                             window=k + 1) as span_args:
+            with seg("spec_verify.enqueue", span_args), self._tp_ctx():
                 out = verify_fn(*args)
-            if self.sampling:
-                scored, accept, plain, resid, self._cache = out
-                accept = np.asarray(accept)
-                plain = np.asarray(plain)
-                resid = np.asarray(resid)
-            else:
-                scored, self._cache = out
-            scored = np.asarray(scored)
-            if self._sparse:
-                scored = self._split_record(scored, (self.slots, k + 1),
-                                            span_args)
+            with seg("spec_verify.wait", span_args):
+                if self.sampling:
+                    scored, accept, plain, resid, self._cache = out
+                    accept = np.asarray(accept)
+                    plain = np.asarray(plain)
+                    resid = np.asarray(resid)
+                else:
+                    scored, self._cache = out
+                    accept = plain = resid = None
+                scored = np.asarray(scored)
+                if self._sparse:
+                    scored = self._split_record(
+                        scored, (self.slots, k + 1), span_args)
+        with seg("step.decode.commit", phase):
+            del args, samp, bt_dev, len_dev, out   # on the commit's account
+            return self._commit_spec_round(dec, ids, scored, accept, plain,
+                                           resid)
+
+    def _commit_spec_round(self, dec, ids, scored, accept, plain,
+                           resid) -> int:
+        """The commit loop of :meth:`_run_spec_decode`: per slot, the
+        longest accepted draft prefix plus the correction token."""
+        k = self.spec_tokens
+        active = self._active
         self._c_spec_rounds.inc()
         # a draft-model proposer caps acceptance at K-1: the K-th draft's
         # KV was never written to the draft pool, so accepting it would
@@ -4294,25 +4534,26 @@ class ServingEngine:
         slot per iteration, ``prefill_batch`` rows per call; pad rows write
         to scratch.  Returns the number of prefill calls made."""
         active = self._active
-        pre = [s for s, st in sorted(active.items(),
-                                     key=lambda kv: kv[1].admit_seq)
-               if st.phase == "prefill"]
-        if not pre:
-            return 0
-        groups = []
-        ready = []
-        for slot in pre:
-            if slot not in active:
-                continue               # preempted by an earlier alloc
-            st = active[slot]
-            v = min(self.prefill_chunk, st.plen_eff - st.base)
-            if self._kv(self._ensure_blocks, slot, st.base + v):
-                ready.append(slot)
-        for i in range(0, len(ready), self.prefill_batch):
-            group = [s for s in ready[i:i + self.prefill_batch]
-                     if s in active]
-            if group:
-                groups.append(group)
+        with self.timeline.segment("step.prefill.plan", self._phase):
+            pre = [s for s, st in sorted(active.items(),
+                                         key=lambda kv: kv[1].admit_seq)
+                   if st.phase == "prefill"]
+            if not pre:
+                return 0
+            groups = []
+            ready = []
+            for slot in pre:
+                if slot not in active:
+                    continue               # preempted by an earlier alloc
+                st = active[slot]
+                v = min(self.prefill_chunk, st.plen_eff - st.base)
+                if self._kv(self._ensure_blocks, slot, st.base + v):
+                    ready.append(slot)
+            for i in range(0, len(ready), self.prefill_batch):
+                group = [s for s in ready[i:i + self.prefill_batch]
+                         if s in active]
+                if group:
+                    groups.append(group)
 
         calls = 0
         for group in groups:
@@ -4330,53 +4571,67 @@ class ServingEngine:
         first generated token (logits are gathered per row at
         ``valid - 1``)."""
         active = self._active
+        seg, phase = self.timeline.segment, self._phase
         j, width = self.prefill_batch, self.prefill_chunk
-        ids = np.zeros((j, width), np.int32)
-        bt = np.zeros((j, self._nbper), np.int32)
-        base = np.zeros(j, np.int32)
-        valid = np.zeros(j, np.int32)
-        rows = []
-        for row, slot in enumerate(group):
-            st = active[slot]
-            v = min(width, st.plen_eff - st.base)
-            ids[row, :v] = st.prompt_eff[st.base:st.base + v]
-            bt[row] = self._tables[slot]
-            base[row] = st.base
-            valid[row] = v
-            rows.append((slot, v))
-        samp = self._samp_args_rows(group, j)
-        packed = (jnp.asarray(ids),
-                  self._bt(bt, list(group) + [-1] * (j - len(group))),
-                  jnp.asarray(base), jnp.asarray(valid))
-        if self._draft is not None:
-            args = (params, self._draft.params, self._cache, self._dcache,
-                    *packed, *samp)
-        else:
-            args = (params, self._cache, *packed)
-            if self.resident_window_blocks:
-                # per-ROW window starts (prefill batches rows from
-                # arbitrary slots); pad rows stay 0 = fully visible
-                ws = np.zeros(j, np.int32)
-                for row, slot in enumerate(group):
-                    ws[row] = self._window_start[slot]
-                args += (jnp.asarray(ws),)
-            args += samp
-        prefill_fn = self._get_prefill_fn()
-        with self.timeline.span(
-                "prefill", width=width, rows=len(group),
-                slots=list(map(int, group)),
+        with seg("step.prefill.plan", phase):
+            ids = np.zeros((j, width), np.int32)
+            bt = np.zeros((j, self._nbper), np.int32)
+            base = np.zeros(j, np.int32)
+            valid = np.zeros(j, np.int32)
+            rows = []
+            for row, slot in enumerate(group):
+                st = active[slot]
+                v = min(width, st.plen_eff - st.base)
+                ids[row, :v] = st.prompt_eff[st.base:st.base + v]
+                bt[row] = self._tables[slot]
+                base[row] = st.base
+                valid[row] = v
+                rows.append((slot, v))
+            prefill_fn = self._get_prefill_fn()
+            span_kw = dict(
+                width=width, rows=len(group), slots=list(map(int, group)),
                 # blocks the rows' reads walk: cdiv(base + valid, bs) each
                 kv_blocks=int((-(-(base + valid) // self.block_size)).sum()),
                 **self._sampler_rows(group),
-                **self._kv_reach((base + valid)[:len(group)]),
-        ) as span_args:
+                **self._kv_reach((base + valid)[:len(group)]))
+        with seg("step.prefill.upload", phase):
+            samp = self._samp_args_rows(group, j)
+            packed = (jnp.asarray(ids),
+                      self._bt(bt, list(group) + [-1] * (j - len(group))),
+                      jnp.asarray(base), jnp.asarray(valid))
             if self._draft is not None:
-                with self._tp_ctx():
-                    first, self._cache, self._dcache = prefill_fn(*args)
+                args = (params, self._draft.params, self._cache,
+                        self._dcache, *packed, *samp)
             else:
-                with self._tp_ctx(), self._sp_ctx():
-                    first, self._cache = prefill_fn(*args)
-            first = self._split_record(np.asarray(first), (j,), span_args)
+                args = (params, self._cache, *packed)
+                if self.resident_window_blocks:
+                    # per-ROW window starts (prefill batches rows from
+                    # arbitrary slots); pad rows stay 0 = fully visible
+                    ws = np.zeros(j, np.int32)
+                    for row, slot in enumerate(group):
+                        ws[row] = self._window_start[slot]
+                    args += (jnp.asarray(ws),)
+                args += samp
+        with self._in_flight("prefill", **span_kw) as span_args:
+            with seg("prefill.enqueue", span_args), self._tp_ctx():
+                if self._draft is not None:
+                    first, self._cache, self._dcache = prefill_fn(*args)
+                else:
+                    with self._sp_ctx():
+                        first, self._cache = prefill_fn(*args)
+            with seg("prefill.wait", span_args):
+                first = self._split_record(np.asarray(first), (j,),
+                                           span_args)
+        with seg("step.prefill.commit", phase):
+            del args, packed, samp         # released on the commit's account
+            self._commit_prefill_group(group, rows, first)
+
+    def _commit_prefill_group(self, group, rows, first) -> None:
+        """The commit loop of :meth:`_run_prefill_group`: advance each
+        row's slot; a row that reached its last prompt token registers its
+        full blocks with the trie and emits its first token."""
+        active = self._active
+        width = self.prefill_chunk
         if self.sp_degree > 1:
             nbytes = sp_attention.alltoall_bytes(
                 int(self._pool_shape[0]), len(group), width,

@@ -17,26 +17,49 @@ crosses, never awarded whole to one of them.  Time no span covers is
 
 The window is the outermost caller span named ``*.window`` if the profile
 has one (the benchmark's ``cb.window``), else first to last device
-operation.  Reading the result: ``ds.serve.step.decode`` is host time in
-the decode phase outside any in-flight span (packing, the per-slot commit
-loop); ``ds.serve.decode`` itself is idle INSIDE the in-flight span —
-dispatch latency before the first operation and the copy-back after the
-last; a caller's span (``cb.harvest``) is the caller's own code.
+operation.  Reading the result: the segments of a phase
+(``TraceTimeline.segment``: ``ds.serve.step.decode.plan`` / ``.upload`` /
+``.commit``, the same under ``step.prefill``) are host time of that phase
+outside any in-flight span; what is left on the bare
+``ds.serve.step.decode`` is the phase's time in no segment.  Inside an
+in-flight span, ``ds.serve.decode.enqueue`` is idle while the jitted call
+has not returned (dispatch), ``ds.serve.decode.wait`` idle while the host
+waits for the tokens (the tail of the dispatch latency before the first
+operation, the copy-back after the last); a caller's span
+(``cb.harvest``) is the caller's own code.
+
+A second table, :func:`in_call`, puts the in-call idle time on ONE clock:
+for every in-flight annotation (``ds.serve.decode``, ``ds.serve.prefill``,
+...) the device module executions (``XLA Modules`` line) that belong to it,
+with the **lead** (annotation start to the first module's start: dispatch
+latency), the **lag** (the last module's end to the annotation's end:
+copy-back and wake-up) and their sum.  A module that does NOT lie inside
+its annotation means the profile's host and device clocks disagree: the
+count is printed with the worst offset, lead and lag are signed, and a
+constant offset between the clocks moves one into the other while their
+sum stands.
 """
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
 import sys
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
+import numpy as np
+
 Interval = Tuple[float, float]                 # (start_ns, end_ns)
 Span = Tuple[str, float, float]                # (name, start_ns, end_ns)
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
+#: the program's in-flight spans (``ServingEngine.step``), as annotations
+IN_FLIGHT = tuple("ds.serve." + n for n in (
+    "prefill", "decode", "spec_propose", "spec_verify", "swap"))
 #: host spans that take part: the program's, and a caller's own
 SPAN_PREFIXES = ("ds.", "cb.")
 OUTSIDE = "outside_any_span"
@@ -53,16 +76,17 @@ def find_xplane(path: str) -> str:
 
 
 def load(path: str, prefixes: Iterable[str] = SPAN_PREFIXES
-         ) -> Tuple[List[Interval], List[Span]]:
+         ) -> Tuple[List[Interval], List[Span], List[Span]]:
     """(operation intervals of the first device plane, host spans whose
-    name starts with one of ``prefixes``), both in nanoseconds on the
-    profile's clock."""
+    name starts with one of ``prefixes``, module executions of the same
+    device plane), all in nanoseconds on the profile's clock."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(find_xplane(path))
     prefixes = tuple(prefixes)
     ops: Optional[List[Interval]] = None
     spans: List[Span] = []
+    modules: List[Span] = []
     for plane in data.planes:
         if DEVICE_PLANE.match(plane.name):
             if ops is not None:
@@ -72,6 +96,10 @@ def load(path: str, prefixes: Iterable[str] = SPAN_PREFIXES
                     ops = [(float(ev.start_ns),
                             float(ev.start_ns + ev.duration_ns))
                            for ev in line.events]
+                elif line.name == MODULES_LINE:
+                    modules = [(ev.name, float(ev.start_ns),
+                                float(ev.start_ns + ev.duration_ns))
+                               for ev in line.events]
             continue
         for line in plane.lines:
             for ev in line.events:
@@ -82,7 +110,7 @@ def load(path: str, prefixes: Iterable[str] = SPAN_PREFIXES
         raise ValueError("the profile has no device plane with an "
                          f"{OPS_LINE!r} line: nothing ran on the device, "
                          "or the profiler saw none")
-    return ops, spans
+    return ops, spans, modules
 
 
 def union(intervals: Iterable[Interval]) -> List[Interval]:
@@ -174,6 +202,54 @@ def idle_by_span(ops: List[Interval], spans: List[Span]) -> Dict[str, Any]:
                         for n, v in ranked]}
 
 
+def in_call(spans: List[Span], modules: List[Span],
+            window: Optional[Interval] = None) -> Dict[str, Dict[str, Any]]:
+    """Per in-flight annotation name (:data:`IN_FLIGHT`), over the
+    ``calls`` a device module ran in (a module belongs to the annotation
+    its midpoint lies in): ``lead_ms`` / ``lag_ms`` / ``overhead_ms``
+    (lead + lag) as ``[median, 95th percentile]`` and ``device_ms`` (first
+    module's start to the last's end, median).  Lead and lag are SIGNED: a
+    module that starts before its annotation or ends after it — the
+    profile's host and device clocks disagree, a constant offset moves one
+    into the other and leaves their sum alone — reads negative and is
+    counted in ``outside``, with ``worst_outside_ms`` the furthest any
+    reached.  ``empty``: calls no module ran in.  Only the annotations
+    that start inside ``window`` are read."""
+    mods = sorted(modules, key=lambda m: m[1] + m[2])
+    mids = [(m[1] + m[2]) / 2 for m in mods]
+    out: Dict[str, Dict[str, Any]] = {}
+    lo, hi = window or (float("-inf"), float("inf"))
+    for name, s, e in spans:
+        if name not in IN_FLIGHT or not lo <= s < hi:
+            continue
+        row = out.setdefault(name, {"lead": [], "lag": [], "device": [],
+                                    "outside": 0, "worst": 0.0, "empty": 0})
+        mine = mods[bisect.bisect_left(mids, s):bisect.bisect_right(mids, e)]
+        if not mine:
+            row["empty"] += 1
+            continue
+        first, last = min(m[1] for m in mine), max(m[2] for m in mine)
+        row["lead"].append(first - s)
+        row["lag"].append(e - last)
+        row["device"].append(last - first)
+        if first < s or last > e:
+            row["outside"] += 1
+            row["worst"] = max(row["worst"], s - first, last - e)
+
+    def ms(values, *qs):
+        return [float(np.quantile(values, q)) * 1e-6 for q in qs] \
+            if values else None
+
+    return {name: {
+        "calls": len(r["lead"]), "lead_ms": ms(r["lead"], 0.5, 0.95),
+        "lag_ms": ms(r["lag"], 0.5, 0.95),
+        "overhead_ms": ms([a + b for a, b in zip(r["lead"], r["lag"])],
+                          0.5, 0.95),
+        "device_ms": (ms(r["device"], 0.5) or [None])[0],
+        "outside": r["outside"], "worst_outside_ms": r["worst"] * 1e-6,
+        "empty": r["empty"]} for name, r in out.items()}
+
+
 def main(argv: Optional[List[str]] = None) -> int:
     import argparse
 
@@ -181,12 +257,29 @@ def main(argv: Optional[List[str]] = None) -> int:
         description="the device's idle time by host span, from a profile")
     ap.add_argument("path", help="profile directory or .xplane.pb")
     args = ap.parse_args(argv)
-    res = idle_by_span(*load(args.path))
+    ops, spans, modules = load(args.path)
+    res = idle_by_span(ops, spans)
     print(f"window {res['window_s']:.3f} s, device idle "
           f"{res['idle_s']:.4f} s "
           f"({100 * res['idle_s'] / res['window_s']:.2f} %)")
     for name, sec, share in res["by_span"]:
         print(f"  {name:<32} {sec * 1e3:10.2f} ms  {100 * share:6.2f} %")
+    print("in-flight calls against the device's modules (ms: median / "
+          "95th percentile)")
+    for name, r in in_call(spans, modules, window_of(ops, spans)).items():
+        if not r["calls"]:
+            print(f"  {name:<22} no call a module ran in")
+        else:
+            print(f"  {name:<22} {r['calls']:6d} calls  lead "
+                  f"{r['lead_ms'][0]:.3f} / {r['lead_ms'][1]:.3f}  lag "
+                  f"{r['lag_ms'][0]:.3f} / {r['lag_ms'][1]:.3f}  lead + lag "
+                  f"{r['overhead_ms'][0]:.3f} / {r['overhead_ms'][1]:.3f}  "
+                  f"on the device {r['device_ms']:.3f}")
+        print(f"  {'':<22} modules outside their call: {r['outside']}"
+              + (f" (worst by {r['worst_outside_ms']:.3f} ms)"
+                 if r["outside"] else "")
+              + (f"; calls no module ran in: {r['empty']}"
+                 if r["empty"] else ""))
     return 0
 
 

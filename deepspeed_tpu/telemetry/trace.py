@@ -37,6 +37,14 @@ the annotations.  :func:`keep` / :func:`kept` hold the newest timeline of
 each role reachable after its engine is gone (the ring and its epoch only
 — a timeline references nothing of its engine).
 
+A span costs a ring event; what happens INSIDE a span several times a step
+must not.  :meth:`TraceTimeline.segment` is the second primitive: the same
+``ds.<role>.<name>`` annotation, and — ring on — the interval's seconds
+ADDED to an argument of the enclosing span (``step.decode``'s ``plan_s``,
+``decode``'s ``wait_s``), no event of its own — the idiom the engine's
+``kv_s`` keeps by hand on the ``step`` span.  :class:`GcWatch` puts the
+collector's pauses on the same clock.
+
 :class:`ProfilerWindow` is the deep-dive escalation: it brackets a region
 with ``jax.profiler.start_trace`` / ``stop_trace`` so a slow window seen
 in the host timeline can be re-run with full XLA/device traces
@@ -47,6 +55,7 @@ iterations).  Failures to start the profiler degrade to a logged warning
 
 from __future__ import annotations
 
+import gc
 import json
 import threading
 import time
@@ -58,11 +67,19 @@ import jax
 
 from ..utils.logging import logger
 
-__all__ = ["TraceTimeline", "ProfilerWindow", "validate_chrome_trace",
-           "annotation", "keep", "kept"]
+__all__ = ["TraceTimeline", "ProfilerWindow", "GcWatch",
+           "validate_chrome_trace", "annotation", "keep", "kept"]
 
 #: tid of the shared scheduler lane (request lanes are allocated upward)
 SCHEDULER_TID = 0
+
+#: events the serving ring holds by default.  The requirement: two minutes
+#: of the densest serving cell at twice its step rate of PR 35 — the chat
+#: cell, ~95 steps/s x ~7.5 events + ~20 requests/s x 3 = ~800 events/s,
+#: 96,000 in 120 s — rounded up to a power of two.  An event is two small
+#: dicts and a few floats: see ``docs/observability.md`` for the measured
+#: footprint of a full ring
+DEFAULT_CAPACITY = 131072
 
 #: role -> the newest timeline built for it (see :func:`keep`)
 _KEPT: Dict[str, "TraceTimeline"] = {}
@@ -75,6 +92,38 @@ def annotation(name: str):
     ``ds.train.*`` from ``train_batch``).  While no profile is being taken
     entering it is a flag test."""
     return jax.profiler.TraceAnnotation(name)
+
+
+class _Segment:
+    """``with`` block of :meth:`TraceTimeline.segment`: enters ``note`` (a
+    profiler annotation) and adds the body's seconds on ``clock`` to
+    ``into[key]`` (``into`` None: the ring is off).  A timeline keeps ONE
+    per segment name and hands it out again with the next ``into`` — a
+    segment runs several times a step and is never inside itself.  The
+    annotation is made anew at every entry: one decides AS IT IS MADE
+    whether a profile is being taken, so a kept one would stay silent in
+    every profile started after it."""
+
+    __slots__ = ("into", "_clock", "_key", "_name", "_note", "_t0")
+
+    def __init__(self, clock, key, name):
+        self._clock, self._key, self._name = clock, key, name
+        self.into = None
+
+    def __enter__(self):
+        self._note = annotation(self._name)
+        self._note.__enter__()
+        if self.into is not None:
+            self._t0 = self._clock()
+        return self.into
+
+    def __exit__(self, *exc):
+        into = self.into
+        if into is not None:
+            into[self._key] = into.get(self._key, 0.0) \
+                + self._clock() - self._t0
+        self._note.__exit__(*exc)
+        return False
 
 
 def keep(role: str, timeline: "TraceTimeline") -> None:
@@ -109,7 +158,8 @@ class TraceTimeline:
     role = "serve"
     step: Optional[int] = None
 
-    def __init__(self, capacity: int = 16384, pid: int = 0, clock=None):
+    def __init__(self, capacity: int = DEFAULT_CAPACITY, pid: int = 0,
+                 clock=None):
         if capacity < 0:
             raise ValueError(f"capacity must be >= 0, got {capacity}")
         self.capacity = int(capacity)
@@ -120,6 +170,8 @@ class TraceTimeline:
         self._events: deque = deque(maxlen=max(self.capacity, 1))
         self.emitted = 0
         self.dropped = 0
+        #: segment name -> its one ``_Segment``
+        self._segments: Dict[str, _Segment] = {}
         self._thread_names: Dict[int, str] = {SCHEDULER_TID: "scheduler"}
         self._next_tid = 1
         # lane allocation is check-then-act (look up name, else mint a
@@ -243,6 +295,22 @@ class TraceTimeline:
                 yield args
             finally:
                 self.complete(name, start, tid=tid, **args)
+
+    def segment(self, name: str, into: Dict[str, Any]):
+        """Context manager for a piece of a span that is NOT an event: the
+        profiler annotation ``ds.<role>.<name>`` for the interval and, ring
+        on, its seconds added to ``into[<last part of name>_s]`` —
+        ``into`` being the argument dict the ENCLOSING span yielded
+        (``segment("step.decode.upload", phase)`` grows
+        ``phase["upload_s"]``).  Pushes nothing; ring off it is the
+        annotation's flag test and one predicate."""
+        seg = self._segments.get(name)
+        if seg is None:
+            seg = self._segments[name] = _Segment(
+                self._clock, name.rsplit(".", 1)[-1] + "_s",
+                f"ds.{self.role}.{name}")
+        seg.into = into if self.enabled else None
+        return seg
 
     # ---------------------------------------------------------------- export
     def __len__(self) -> int:
@@ -412,6 +480,53 @@ def validate_chrome_trace(doc: Dict[str, Any],
     summary["handoff_unmatched"] = orphan_handoffs + \
         sum(handoff_parked.values())
     return summary
+
+
+class GcWatch:
+    """The collector's pauses on a timeline's clock: seconds and count of
+    the runs that began while :attr:`thread` named the calling thread.
+
+    ``gc.callbacks`` is the PROCESS's list, and a collection runs on
+    whichever thread's allocation tripped it, so the hook credits a run
+    only to the owner whose thread it interrupted (an engine sets
+    ``thread`` for the length of a ``step``): replicas stepping in worker
+    threads do not take each other's pauses.  A run over :attr:`EVENT_S`
+    also goes on the ring as a ``gc`` X-event (args ``generation``,
+    ``collected``).  The hook references this object and the timeline only
+    — never the engine, which must stay collectable to ``close()`` it."""
+
+    #: a run at least this long is a ring event of its own
+    EVENT_S = 1e-3
+
+    def __init__(self, timeline: "TraceTimeline"):
+        self.timeline = timeline
+        self.thread: Optional[int] = None
+        self.seconds = 0.0
+        self.runs = 0
+        self._start_us: Optional[float] = None
+
+    def install(self) -> None:
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+
+    def remove(self) -> None:
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, phase: str, info: Dict[str, Any]) -> None:
+        if self.thread != threading.get_ident():
+            return
+        if phase == "start":
+            self._start_us = self.timeline.now_us()
+        elif self._start_us is not None:
+            start, self._start_us = self._start_us, None
+            dur_s = (self.timeline.now_us() - start) * 1e-6
+            self.seconds += dur_s
+            self.runs += 1
+            if dur_s >= self.EVENT_S:
+                self.timeline.complete(
+                    "gc", start, generation=info.get("generation"),
+                    collected=info.get("collected"))
 
 
 class ProfilerWindow:

@@ -4,7 +4,10 @@
 Ring side (host clock): one ``step`` span per scheduler iteration, tiled by
 the four host phases, the in-flight spans nested inside their phase, the KV
 manager's seconds on the step, ``step`` on every span, ``submit``/``admit``
-paired by ``uid``, and the ring readable after the engine is closed.
+paired by ``uid``, and the ring readable after the engine is closed.  Inside the phases and the
+in-flight spans the SEGMENTS (``TraceTimeline.segment``): seconds on the
+spans' arguments, annotations in a profile, no ring event; the step's
+thread-CPU and collector seconds; a stalled step named as it happens.
 Profiler side: the same spans as ``ds.serve.*`` / ``ds.train.*`` annotations
 in a ``jax.profiler`` trace, ring on or off.  Plus the names the benchmark's
 reduction finds things by: the jitted programs' module names and the Pallas
@@ -13,6 +16,7 @@ kernels' own names.
 
 import gc
 import re
+import time
 import types
 
 import jax
@@ -21,6 +25,7 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu
+from deepspeed_tpu.inference import serving
 from deepspeed_tpu.inference.serving import Request, ServingEngine
 from deepspeed_tpu.models import gpt2
 from deepspeed_tpu.telemetry import idle_gaps, trace
@@ -237,6 +242,292 @@ def test_the_ring_outlives_the_engine_and_holds_none_of_it(tiny):
     assert trace.kept("serve") is other.timeline
 
 
+# ------------------------------------------------- segments (ISSUE 36)
+PHASE_SEGMENTS = ("plan_s", "upload_s", "commit_s")
+CALL_SEGMENTS = ("enqueue_s", "wait_s")
+
+
+def _runner_engine(tiny, runner):
+    engine, cfg = tiny
+    kw = dict(SERVE_KW)
+    if runner == "fused":
+        kw["decode_steps"] = 4
+    elif runner == "spec-ngram":
+        kw["spec_tokens"] = 2
+    elif runner == "spec-draft":
+        dcfg = gpt2.GPT2Config(vocab_size=cfg.vocab_size, max_seq_len=64,
+                               num_layers=1, num_heads=2, hidden_size=32)
+        kw.update(spec_tokens=2, draft=gpt2.build(dcfg))
+    return ServingEngine(engine, **kw)
+
+
+@pytest.mark.parametrize("runner", ["plain", "fused", "spec-ngram",
+                                    "spec-draft"])
+def test_every_runner_times_its_segments(tiny, runner):
+    """Table B of ISSUE 36, one vocabulary in every runner: a phase that
+    made a call carries ``plan_s`` / ``upload_s`` / ``commit_s`` and their
+    sum is within the phase's time outside its in-flight spans; an
+    in-flight span carries ``enqueue_s`` / ``wait_s`` within its duration;
+    the step carries its thread-CPU and collector seconds.  And a segment
+    is not an event: the ring holds the same names as before."""
+    srv = _runner_engine(tiny, runner)
+    srv.serve(_requests(tiny[1]))
+    events = srv.timeline.events()
+    flights = [e for e in events if e["ph"] == "X" and e["name"] in (
+        "prefill", "decode", "spec_propose", "spec_verify")
+        and e["args"].get("mode") != "ngram"]
+    want = {"prefill", "decode"} if runner in ("plain", "fused") else \
+        {"prefill", "spec_verify"} | (
+            {"spec_propose"} if runner == "spec-draft" else set())
+    assert {f["name"] for f in flights} == want
+    for f in flights:
+        assert set(CALL_SEGMENTS) <= set(f["args"]), f
+        assert 0 < f["args"]["enqueue_s"] + f["args"]["wait_s"] \
+            <= f["dur"] * 1e-6
+    called = 0
+    for phase in _named(events, "step.prefill") + \
+            _named(events, "step.decode"):
+        inside = [f for f in flights
+                  if f["args"]["step"] == phase["args"]["step"]
+                  and phase["ts"] <= f["ts"]
+                  and f["ts"] + f["dur"] <= phase["ts"] + phase["dur"]]
+        if not inside:
+            continue
+        called += 1
+        assert set(PHASE_SEGMENTS) <= set(phase["args"]), phase
+        own = phase["dur"] - sum(f["dur"] for f in inside)
+        assert 0 < sum(phase["args"][k] for k in PHASE_SEGMENTS) \
+            <= own * 1e-6
+    assert called >= len(_named(events, "step"))
+    for s in _named(events, "step"):
+        a = s["args"]
+        assert set(a) >= {"cpu_s", "flight_cpu_s", "flight_s", "gc_s",
+                          "gc_n", "kv_s"}
+        assert 0 <= a["flight_cpu_s"] <= a["cpu_s"]
+        assert 0 < a["flight_s"] <= s["dur"] * 1e-6
+        assert a["gc_s"] >= 0 and (a["gc_n"] > 0) == (a["gc_s"] > 0)
+    names = {e["name"].split(" ")[0] for e in events}
+    assert names <= {"step", *PHASES, "prefill", "decode", "spec_propose",
+                     "spec_verify", "spec_accept", "submit", "admit", "req",
+                     "jit_trace", "gc"}, names
+    srv.close()
+
+
+def test_a_segment_pushes_no_ring_event_and_evictions_come_one_a_call(tiny):
+    """Events per step are what they were before the segments: ``step``,
+    four phases and one X-event a call, whatever ran inside them.  A
+    request's three (``submit``, ``admit``, ``req``) and, in a pool that
+    evicts, ONE ``evict_block`` per ``_ensure_blocks`` call that evicted,
+    carrying how many blocks (one instant a block before ISSUE 36)."""
+    engine, cfg = tiny
+    srv = ServingEngine(engine, num_blocks=13, **SERVE_KW)
+    reqs = _requests(cfg, n=12, seed=3)
+    srv.serve(reqs)
+    tl, events = srv.timeline, srv.timeline.events()
+    assert tl.dropped == 0 and tl.emitted == len(events)
+    x = [e for e in events if e["ph"] == "X" and e["name"] != "gc"
+         and not e["name"].startswith("req ")]
+    calls = len(_named(events, "prefill")) + len(_named(events, "decode"))
+    assert len(x) == 5 * srv.iterations + calls
+    evictions = [e for e in events if e["name"] == "evict_block"]
+    assert evictions and all(e["ph"] == "i" for e in evictions)
+    assert all(set(e["args"]) == {"blocks", "demoted"} for e in evictions)
+    blocks = sum(e["args"]["blocks"] for e in evictions)
+    assert blocks == srv.stats()["prefix_cache_evictions"] > len(evictions)
+    other = [e for e in events if e["ph"] == "i" and e["name"] not in (
+        "evict_block", "jit_trace", "preempt")]
+    assert sorted(e["name"] for e in other) == \
+        ["admit"] * len(reqs) + ["submit"] * len(reqs)
+    srv.close()
+
+
+def test_a_segment_adds_to_its_spans_argument_and_kv_s_does_the_same(tiny):
+    """A segment adds its seconds to an argument of the span it is given,
+    pushes nothing, and with the ring off leaves the dict alone; the KV
+    manager's ``kv_s`` is the same accumulation on the ``step`` span,
+    within the step and over zero when the step reserved blocks."""
+    tl = trace.TraceTimeline(capacity=8)
+    args = {}
+    for _ in range(2):
+        with tl.segment("step.decode.plan", args) as into:
+            assert into is args
+            time.sleep(0.002)
+    assert 0.004 <= args["plan_s"] < 0.1 and set(args) == {"plan_s"}
+    assert tl.emitted == 0
+    off = trace.TraceTimeline(capacity=0)
+    with off.segment("step.decode.upload", args) as into:
+        assert into is None
+    assert set(args) == {"plan_s"}
+    srv = ServingEngine(tiny[0], **SERVE_KW)
+    srv.serve(_requests(tiny[1], n=3))
+    for s in _named(srv.timeline.events(), "step"):
+        assert 0 < s["args"]["kv_s"] < s["dur"] * 1e-6
+    srv.close()
+
+
+# ---------------------------------------------------------- a stalled step
+def _steady(tiny, n_tokens=56):
+    """An engine on three long requests: after their prefill step, some
+    ``n_tokens`` decode-only steps of one shape."""
+    engine, cfg = tiny
+    srv = ServingEngine(engine, **SERVE_KW)
+    rng = np.random.default_rng(1)
+    handles = [srv.submit(Request(
+        uid=i, prompt=rng.integers(0, cfg.vocab_size, 6, dtype=np.int32),
+        max_new_tokens=n_tokens)) for i in range(3)]
+    return srv, handles
+
+
+def _stalls(srv):
+    return [e for e in srv.timeline.events()
+            if e["ph"] == "i" and e["name"] == "stall"]
+
+
+def _run_until_done(srv):
+    while srv.step():
+        pass
+    srv.close()
+
+
+def test_a_step_that_slept_is_a_stall_off_the_cpu_in_its_commit(tiny):
+    srv, handles = _steady(tiny)
+    on_tokens = handles[0]._on_tokens
+
+    def slow_client(toks):
+        on_tokens(toks)
+        if len(handles[0].tokens()) == 45:
+            time.sleep(0.2)
+
+    handles[0]._on_tokens = slow_client
+    _run_until_done(srv)
+    stall, = [e["args"] for e in _stalls(srv) if e["args"]["wall_ms"] > 200]
+    assert stall["cause"] == "offcpu" and stall["segment"] == "commit"
+    assert stall["offcpu_ms"] > 150 and stall["gc_ms"] < 50
+    assert stall["wall_ms"] > serving.STALL_FACTOR * stall["median_ms"] > 0
+    step, = [s for s in _named(srv.timeline.events(), "step")
+             if s["args"]["iteration"] == stall["iteration"]]
+    assert step["dur"] * 1e-3 == pytest.approx(stall["wall_ms"], rel=0.05)
+    counts = {c: v.value for c, v in srv._c_step_stalls.items()}
+    assert counts["offcpu"] >= 1 and set(counts) == set(serving.STALL_CAUSES)
+    assert sum(counts.values()) == len(_stalls(srv))
+    assert 'serving_step_stalls_total{cause="offcpu"}' in \
+        srv.metrics.prometheus_text()
+
+
+def test_a_collection_inside_a_step_fills_gc_s_and_is_an_event(tiny):
+    srv, handles = _steady(tiny)
+    on_tokens = handles[1]._on_tokens
+
+    def littering_client(toks):
+        on_tokens(toks)
+        if len(handles[1].tokens()) == 45:
+            junk = []
+            for _ in range(300_000):        # cycles only the collector frees
+                a = []
+                a.append(a)
+                junk.append(a)
+            del junk
+            gc.collect()
+
+    handles[1]._on_tokens = littering_client
+    _run_until_done(srv)
+    events = srv.timeline.events()
+    runs = [e for e in events if e["ph"] == "X" and e["name"] == "gc"
+            and e["args"]["collected"] >= 299_000]
+    assert len(runs) == 1 and runs[0]["args"]["generation"] == 2
+    step, = [s for s in _named(events, "step")
+             if s["args"]["iteration"] == runs[0]["args"]["step"]]
+    assert step["args"]["gc_n"] >= 1
+    assert step["args"]["gc_s"] >= runs[0]["dur"] * 1e-6 > 1e-3
+    assert step["ts"] <= runs[0]["ts"] and \
+        runs[0]["ts"] + runs[0]["dur"] <= step["ts"] + step["dur"]
+    # a collection in ANOTHER thread's time, or outside a step, is not ours
+    before = srv._gc.seconds
+    gc.collect()
+    assert srv._gc.seconds == before
+
+
+def test_a_call_whose_tokens_come_late_is_a_device_wait(tiny):
+    srv, _ = _steady(tiny)
+    decode_fn = srv._get_decode_fn()
+    calls = [0]
+
+    class Late:
+        """Tokens that take 0.2 s to reach the host."""
+
+        def __init__(self, array):
+            self.array = array
+
+        def __array__(self, dtype=None, copy=None):
+            time.sleep(0.2)
+            return np.asarray(self.array)
+
+    def stubbed(*args):
+        nxt, cache = decode_fn(*args)
+        calls[0] += 1
+        return (Late(nxt) if calls[0] == 45 else nxt), cache
+
+    srv._get_decode_fn = lambda: stubbed
+    _run_until_done(srv)
+    stall, = [e["args"] for e in _stalls(srv) if e["args"]["wall_ms"] > 200]
+    assert stall["cause"] == "device_wait" and stall["segment"] == "wait"
+    assert stall["wait_ms"] > 150 and stall["offcpu_ms"] < 50
+
+
+def test_close_takes_the_collector_hook_out(tiny):
+    engine, cfg = tiny
+    before = list(gc.callbacks)
+    a = ServingEngine(engine, **SERVE_KW)
+    b = ServingEngine(engine, **SERVE_KW)
+    off = ServingEngine(engine, trace_capacity=0, **SERVE_KW)
+    assert len(gc.callbacks) == len(before) + 2      # the ring off: no hook
+    a.serve(_requests(cfg, n=2))
+    a.close()
+    a.close()                                        # idempotent
+    assert len(gc.callbacks) == len(before) + 1
+    # an engine nobody closed takes its hook with it when it is collected
+    del b, off
+    gc.collect()
+    assert gc.callbacks == before
+
+
+def test_a_ring_that_wrapped_says_so_once_at_close(tiny, caplog):
+    import logging
+
+    engine, cfg = tiny
+    srv = ServingEngine(engine, trace_capacity=16, **SERVE_KW)
+    srv.serve(_requests(cfg, n=3))
+    assert srv.stats()["trace_events_dropped"] > 0
+    log = logging.getLogger("deepspeed_tpu")
+    log.addHandler(caplog.handler)
+    try:
+        srv.close()
+        srv.close()
+    finally:
+        log.removeHandler(caplog.handler)
+    said = [r.getMessage() for r in caplog.records
+            if "trace ring" in r.getMessage()]
+    assert len(said) == 1 and "trace_capacity=16" in said[0] \
+        and "events/s" in said[0]
+
+
+def test_the_default_ring_holds_two_minutes_of_the_densest_cell():
+    """ISSUE 36 E: the capacity follows from a requirement (twice the chat
+    cell's ~47 steps/s of PR 35, ~7.5 events a step, plus ~20 requests/s
+    x 3, for 120 s), and every default names the same number."""
+    import inspect
+
+    from deepspeed_tpu.autotuning import space
+
+    need = 120 * (95 * 7.5 + 20 * 3)
+    assert need <= trace.DEFAULT_CAPACITY == 131072 < 2 * need
+    assert trace.TraceTimeline().capacity == trace.DEFAULT_CAPACITY
+    for fn in (ServingEngine.__init__, deepspeed_tpu.init_serving):
+        assert inspect.signature(fn).parameters["trace_capacity"].default \
+            == trace.DEFAULT_CAPACITY
+    assert space.BASE_SERVING_CONFIG["trace_capacity"] == trace.DEFAULT_CAPACITY
+
+
 # ------------------------------------------------------- the profiler's clock
 def _host_event_names(profile_dir):
     from jax.profiler import ProfileData
@@ -261,6 +552,9 @@ def test_spans_land_in_a_profile_with_the_ring_off(tiny, tmp_path):
             "optimizer": {"type": "adam", "params": {"lr": 1e-3}}})
     batch = {"input_ids": np.zeros(
         (train.train_batch_size(), 16), np.int32)}
+    # every span and segment has run before the profile starts: an
+    # annotation kept from then would be silent in it
+    srv.serve(_requests(cfg, n=2, seed=1))
     srv.serve(_requests(cfg, n=2), profile_dir=str(tmp_path))
     window = trace.ProfilerWindow(str(tmp_path / "train"))
     assert window.start()
@@ -272,6 +566,12 @@ def test_spans_land_in_a_profile_with_the_ring_off(tiny, tmp_path):
     assert {"ds.serve.step", "ds.serve.step.admit", "ds.serve.step.prefill",
             "ds.serve.step.decode", "ds.serve.step.post", "ds.serve.prefill",
             "ds.serve.decode"} <= names
+    # ... and the segments inside them (ISSUE 36): annotations only
+    assert {f"ds.serve.step.{phase}.{seg}"
+            for phase in ("prefill", "decode")
+            for seg in ("plan", "upload", "commit")} <= names
+    assert {f"ds.serve.{call}.{seg}" for call in ("prefill", "decode")
+            for seg in ("enqueue", "wait")} <= names
     assert {"ds.train.step", "ds.train.batch_prep",
             "ds.train.dispatch"} <= names
 
@@ -296,6 +596,49 @@ def test_idle_gaps_are_partitioned_by_the_innermost_span():
     res = idle_gaps.idle_by_span(ops, spans[1:])
     assert round(res["window_s"] * 1e9, 6) == 58
     assert round(res["idle_s"] * 1e9, 6) == 32
+
+
+def test_in_call_puts_lead_and_lag_on_one_clock():
+    """``idle_gaps.in_call``: per in-flight annotation, the module
+    executions that belong to it (midpoint inside); lead = annotation start
+    to the first module, lag = the last module's end to the annotation's
+    end, both SIGNED; a module that reaches outside its annotation is
+    counted, with the worst offset, and so is a call no module ran in.
+    Segments are not calls."""
+    ms = 1e6                                        # the profile is in ns
+    spans = [("cb.window", 0, 1000 * ms),
+             ("ds.serve.decode", 10 * ms, 30 * ms),
+             ("ds.serve.decode.enqueue", 10 * ms, 11 * ms),
+             ("ds.serve.decode.wait", 11 * ms, 30 * ms),
+             ("ds.serve.decode", 40 * ms, 62 * ms),
+             ("ds.serve.decode", 70 * ms, 95 * ms),
+             ("ds.serve.decode", 100 * ms, 120 * ms),    # clocks disagree
+             ("ds.serve.decode", 130 * ms, 150 * ms),    # nothing ran
+             ("ds.serve.decode", 2000 * ms, 2020 * ms),  # outside window
+             ("ds.serve.prefill", 200 * ms, 260 * ms)]
+    modules = [("jit_decode_step", 12 * ms, 29 * ms),     # lead 2, lag 1
+               ("jit_decode_step", 43 * ms, 60 * ms),     # lead 3, lag 2
+               ("jit_decode_step", 74 * ms, 92 * ms),     # lead 4, lag 3
+               ("jit_decode_step", 99.5 * ms, 115 * ms),  # lead -0.5, lag 5
+               ("jit_prefill", 205 * ms, 230 * ms),       # two modules in
+               ("jit_other", 231 * ms, 250 * ms)]         # one call
+    res = idle_gaps.in_call(spans, modules, (0, 1000 * ms))
+    assert set(res) == {"ds.serve.decode", "ds.serve.prefill"}
+    dec = res["ds.serve.decode"]
+    assert dec["calls"] == 4
+    assert dec["lead_ms"] == pytest.approx([2.5, 3.85])   # -0.5, 2, 3, 4
+    assert dec["lag_ms"] == pytest.approx([2.5, 4.7])     # 1, 2, 3, 5
+    assert dec["overhead_ms"] == pytest.approx([4.75, 6.7])   # 3, 4.5, 5, 7
+    assert dec["device_ms"] == pytest.approx(17.0)
+    assert (dec["outside"], dec["empty"]) == (1, 1)
+    assert dec["worst_outside_ms"] == pytest.approx(0.5)
+    pre = res["ds.serve.prefill"]
+    assert pre["calls"] == 1 and pre["outside"] == pre["empty"] == 0
+    assert pre["lead_ms"] == pytest.approx([5.0, 5.0])
+    assert pre["lag_ms"] == pytest.approx([10.0, 10.0])
+    assert pre["device_ms"] == pytest.approx(45.0)
+    # no window: every call is read
+    assert idle_gaps.in_call(spans, modules)["ds.serve.decode"]["empty"] == 2
 
 
 # ------------------------------------------------ names the benchmark reads
