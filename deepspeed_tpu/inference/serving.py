@@ -223,6 +223,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import inspect
 import os
 import statistics
@@ -254,6 +255,7 @@ from ..telemetry import trace as trace_mod
 from ..telemetry.slo import SLOTracker
 from ..utils.logging import log_dist, logger
 from ..utils.platform import on_tpu
+from .operands import OperandLayout
 from .paged import (SCRATCH_BLOCK, BlockAllocator, GroupedBlockAllocator,
                     HostBlockStore, NvmeBlockStore, PrefixCache,
                     TransportError, WindowRing, chain_key, chain_keys)
@@ -694,6 +696,48 @@ class _SlotState:
         allocating blocks the request can never use (rollback-aware
         accounting — see ops/paged_kv.py)."""
         return self.plen_eff - len(self.prior) + self.req.max_new_tokens
+
+
+class _InFlight:
+    """``with`` block of :meth:`ServingEngine._in_flight`; yields the
+    span's argument dict.  What :meth:`TraceTimeline.span` does (an ``X``
+    event and the annotation ``ds.serve.<name>``) with the span's own
+    bookkeeping INSIDE its interval: the start is the first thing read,
+    the end the last but for the event itself, and the commit segment
+    that follows begins at that end (:meth:`TraceTimeline.lap`).  With
+    the blocking puts gone the host's own time in a phase is ~0.6 ms, and
+    the microseconds on either side of a call were a tenth of it."""
+
+    __slots__ = ("_srv", "_name", "_args", "_note", "_start", "_t0", "_cpu0")
+
+    def __init__(self, srv, name, args):
+        self._srv, self._name, self._args = srv, name, args
+
+    def __enter__(self):
+        tl = self._srv.timeline
+        if tl.enabled:
+            self._start = tl.now_us()
+            self._srv._seg_args.append(self._args)
+        self._note = trace_mod.annotation(f"ds.{tl.role}.{self._name}")
+        self._note.__enter__()
+        if tl.enabled:
+            tl.lap()                       # its first segment begins now
+            self._t0, self._cpu0 = time.perf_counter(), time.thread_time()
+        return self._args
+
+    def __exit__(self, *exc):
+        srv = self._srv
+        tl = srv.timeline
+        if tl.enabled:
+            step = srv._step_args
+            step["flight_cpu_s"] += time.thread_time() - self._cpu0
+            step["flight_s"] += time.perf_counter() - self._t0
+        self._note.__exit__(*exc)
+        if tl.enabled:
+            end = tl.now_us()
+            tl.complete(self._name, self._start, end_us=end, **self._args)
+            tl.lap(end)                    # the commit begins at its end
+        return False
 
 
 class ServingEngine:
@@ -1637,6 +1681,10 @@ class ServingEngine:
         #: (telemetry/flops.py).
         self._program_bodies: Dict[str, Any] = {}
         self._program_meta: Dict[str, Any] = {}
+        #: per program, where its small per-call operands lie in the ONE
+        #: host buffer a call carries (inference/operands.py) — made with
+        #: the program, never inside a step
+        self._layouts: Dict[str, OperandLayout] = {}
         #: router-noted flow ids (uid -> Chrome flow id): admission emits
         #: the matching flow-finish so the merged fleet trace draws the
         #: route -> admit arrow (telemetry/trace.py flow events)
@@ -2047,18 +2095,17 @@ class ServingEngine:
         return t + (None,) if len(t) == 5 else t
 
     def _samp_args(self, counts):
-        """The per-dispatch sampling operand tail (slot-indexed): the
-        per-slot knob vectors + this dispatch's emitted-count vector
-        (+ the mask matrix when the engine carries the mask operand).
-        Empty for sampling=False engines — callers splat it, so greedy
-        engines keep the exact legacy call signature."""
+        """The per-dispatch sampling operand tail (slot-indexed), on the
+        host: the per-slot knob vectors + this dispatch's emitted-count
+        vector (+ the mask matrix when the engine carries the mask
+        operand).  Empty for sampling=False engines — callers splat it, so
+        greedy engines keep the exact legacy call signature."""
         if not self.sampling:
             return ()
-        args = (jnp.asarray(self._temps), jnp.asarray(self._topks),
-                jnp.asarray(self._topps), jnp.asarray(self._seeds),
-                jnp.asarray(np.asarray(counts, np.int32)))
+        args = (self._temps, self._topks, self._topps, self._seeds,
+                np.asarray(counts, np.int32))
         if self.logit_masks:
-            args += (jnp.asarray(self._masks),)
+            args += (self._masks,)
         return args
 
     def _decode_counts(self):
@@ -2088,15 +2135,65 @@ class ServingEngine:
             topps[row] = self._topps[slot]
             seeds[row] = self._seeds[slot]
             counts[row] = self._active[slot].gen_count
-        args = (jnp.asarray(temps), jnp.asarray(topks),
-                jnp.asarray(topps), jnp.asarray(seeds),
-                jnp.asarray(counts))
+        args = (temps, topks, topps, seeds, counts)
         if self.logit_masks:
             masks = np.ones((rows, self._vocab), bool)
             for row, slot in enumerate(group):
                 masks[row] = self._masks[slot]
-            args += (jnp.asarray(masks),)
+            args += (masks,)
         return args
+
+    # -------------------------------------------------- one buffer a call
+    def _operand_spec(self, rows, head, tail=()):
+        """A program's packed operands in its body's positional order, for
+        its :class:`OperandLayout`: ``head`` (name -> width of an int32
+        operand: None a ``[rows]`` vector, else ``[rows, width]`` ids), the
+        block tables — one ``[rows, nbper]`` table, or one a layer kind —
+        ``tail`` (names of more int32 ``[rows]`` operands) and, on a
+        sampling engine, the five sampling vectors.  The mask matrix of a
+        ``logit_masks`` engine is no part of it: it is large, changes
+        rarely and stays an operand of its own."""
+        def sds(width=None, dtype=np.int32):
+            return jax.ShapeDtypeStruct(
+                (rows,) if width is None else (rows, width), dtype)
+
+        spec = {name: sds(width) for name, width in head.items()}
+        spec["block_tables"] = sds(self._nbper) if not self._windows else {
+            "full": sds(self._nbper), "window": sds(self._ring.width)}
+        spec.update((name, sds()) for name in tail)
+        if self.sampling:
+            spec.update(temps=sds(dtype=np.float32), topks=sds(),
+                        topps=sds(dtype=np.float32),
+                        seeds=sds(dtype=np.uint32), counts=sds())
+        return spec
+
+    def _packed(self, program, body, spec, device_operands=2):
+        """``body`` as its jitted call takes it: after the
+        ``device_operands`` that live on the device (params, the pools),
+        ONE int32 buffer holding the operands of ``spec``, unpacked at the
+        head of the program, then whatever rides beside it (the mask
+        matrix).  ``body`` itself stays the program below the unpacking
+        (``_program_bodies``: what the FLOPs profiler and the lowering
+        tests read).  The layout is made here, once a program."""
+        layout = self._layouts[program] = OperandLayout(spec)
+        n = device_operands
+
+        @functools.wraps(body)
+        def packed(*args):
+            return body(*args[:n], *layout.unpack(args[n]), *args[n + 1:])
+
+        return packed
+
+    def _host_operands(self, program, *operands):
+        """One call's host arrays: ``operands`` (numpy, the body's order)
+        written into the program's buffer, and beside it what the layout
+        does not hold (the mask matrix).  Also the ``puts`` /
+        ``operand_bytes`` arguments of the call's in-flight span."""
+        layout = self._layouts[program]
+        n = len(layout.names)
+        host = (layout.fill(*operands[:n]), *operands[n:])
+        return host, {"puts": len(host),
+                      "operand_bytes": sum(a.nbytes for a in host)}
 
     def _refresh_masks(self) -> None:
         """Rebuild every constrained slot's ``[vocab]`` mask row from its
@@ -2205,7 +2302,8 @@ class ServingEngine:
             # *samp is the engine's sampling operand tail — () for
             # sampling=False (the exact legacy programs, bit-path
             # identical), (temps, topks, topps, seeds, counts[, masks])
-            # otherwise; _samp_args builds it to match per dispatch
+            # otherwise; _samp_args builds it to match per dispatch, and
+            # _packed hands it over out of the call's one buffer
             pack = self._pack_samp
 
             def decode_step(params, cache, tokens, lengths, block_tables,
@@ -2242,8 +2340,16 @@ class ServingEngine:
 
                 body_fn = decode_windowed
             self._program_bodies["decode"] = body_fn
-            self._decode_fn = jax.jit(self.sentry.wrap(body_fn, "decode"),
-                                      donate_argnums=self._donate())
+            tail = ("window_start",) if self.resident_window_blocks else \
+                ("active", "budgets", "eos_ids") if K > 1 else ()
+            spec = self._operand_spec(
+                self.slots, {"tokens": None, "lengths": None}, tail)
+            if K > 1:
+                spec["active"] = jax.ShapeDtypeStruct((self.slots,), bool)
+            self._decode_fn = jax.jit(
+                self.sentry.wrap(self._packed("decode", body_fn, spec),
+                                 "decode"),
+                donate_argnums=self._donate())
             self.compiled_programs.append(
                 ("decode", self.slots) if K == 1
                 else ("decode", self.slots, K))
@@ -2316,8 +2422,15 @@ class ServingEngine:
             body, donate = prefill_fused, (2, 3) if donate else ()
             self._program_meta["prefill_fused"] = True
         self._program_bodies["prefill"] = body
+        spec = self._operand_spec(
+            self.prefill_batch, {"ids": width},
+            ("base", "valid") + (("window_start",)
+                                 if self.resident_window_blocks else ()))
         self._prefill_fn = jax.jit(
-            self.sentry.wrap(body, f"prefill[w{width}]"),
+            self.sentry.wrap(
+                self._packed("prefill", body, spec,
+                             device_operands=2 if draft is None else 4),
+                f"prefill[w{width}]"),
             donate_argnums=donate)
         self.compiled_programs.append(
             ("prefill", width, self.prefill_batch))
@@ -2418,8 +2531,12 @@ class ServingEngine:
                     resid, cache
 
             self._program_bodies["verify"] = verify
-            self._verify_fn = jax.jit(self.sentry.wrap(verify, "verify"),
-                                      donate_argnums=self._donate())
+            spec = self._operand_spec(self.slots, {"ids": k + 1},
+                                      ("base", "valid"))
+            self._verify_fn = jax.jit(
+                self.sentry.wrap(self._packed("verify", verify, spec),
+                                 "verify"),
+                donate_argnums=self._donate())
             self.compiled_programs.append(
                 ("verify", self.slots, self.spec_tokens + 1))
         return self._verify_fn
@@ -2472,8 +2589,11 @@ class ServingEngine:
                 return drafts.T, dcache            # [slots, K]
 
             self._program_bodies["draft"] = propose
+            spec = self._operand_spec(
+                self.slots, {"tokens": None, "lengths": None})
             self._draft_fn = jax.jit(
-                self.sentry.wrap(propose, "draft"),
+                self.sentry.wrap(self._packed("draft", propose, spec),
+                                 "draft"),
                 donate_argnums=(1,) if self._donate() else ())
             self.compiled_programs.append(("draft", self.slots, k))
         return self._draft_fn
@@ -3259,14 +3379,14 @@ class ServingEngine:
         return args
 
     def _bt(self, tables, rows=None):
-        """The block-table operand of a dispatch: the full kind's
-        ``tables`` (already masked to the dispatch's rows) — for a model
-        with window layers, the table of each kind, the window kind's
+        """The block-table operand of a dispatch, on the host: the full
+        kind's ``tables`` (already masked to the dispatch's rows) — for a
+        model with window layers, the table of each kind, the window kind's
         rings gathered for the same rows (``rows``: slot of each row, -1 a
         pad row; None: row i is slot i, rows whose table is all scratch
         are idle)."""
         if not self._windows:
-            return jnp.asarray(tables)
+            return tables
         if rows is None:
             ring = np.where(tables[:, :1] != 0, self._ring.tables, 0)
         else:
@@ -3274,7 +3394,7 @@ class ServingEngine:
             for row, slot in enumerate(rows):
                 if slot >= 0:
                     ring[row] = self._ring.tables[slot]
-        return {"full": jnp.asarray(tables), "window": jnp.asarray(ring)}
+        return {"full": tables, "window": ring}
 
     # --------------------------------------------------------------- schedule
     def _admit(self):
@@ -3731,26 +3851,15 @@ class ServingEngine:
                               demoted=0)
         self._evicted_blocks = 0
 
-    @contextlib.contextmanager
     def _in_flight(self, name: str, **args):
         """An in-flight span (:meth:`step`): a device program runs from the
         jitted call inside it to its results on the host.  Ring on, the
         span's wall and thread-CPU seconds are also added to the step's
         ``flight_s`` / ``flight_cpu_s`` — the step's duration and ``cpu_s``
-        less these are the host's own time and the CPU it got for it."""
-        tl = self.timeline
-        with tl.span(name, **args) as span_args:
-            if not tl.enabled:
-                yield span_args
-                return
-            self._seg_args.append(span_args)
-            t0, cpu0 = time.perf_counter(), time.thread_time()
-            try:
-                yield span_args
-            finally:
-                step = self._step_args
-                step["flight_s"] += time.perf_counter() - t0
-                step["flight_cpu_s"] += time.thread_time() - cpu0
+        less these are the host's own time and the CPU it got for it.
+        The runners make it inside their ``upload`` segment and enter it
+        next, so nothing but two clock reads lies between the two."""
+        return _InFlight(self, name, args)
 
     def _note_step(self, step_args: Dict[str, Any], wall_s: float) -> None:
         """Ring on, after every step: file it under its shape — duration,
@@ -4216,13 +4325,14 @@ class ServingEngine:
             span_kw = {**self._sampler_rows(dec),
                        **self._kv_reach(self._lengths[dec] + 1)}
         with seg("step.decode.upload", phase):
-            args = (params, self._cache, jnp.asarray(self._tokens),
-                    jnp.asarray(self._lengths), self._bt(bt))
-            if self.resident_window_blocks:
-                args += (jnp.asarray(self._window_start),)
-            args += self._samp_args(counts)
-        with self._in_flight("decode", slots=len(dec),
-                             **span_kw) as span_args:
+            host, puts = self._host_operands(
+                "decode", self._tokens, self._lengths, self._bt(bt),
+                *((self._window_start,) if self.resident_window_blocks
+                  else ()), *self._samp_args(counts))
+            args = (params, self._cache, *host)
+            flight = self._in_flight("decode", slots=len(dec), **puts,
+                                     **span_kw)
+        with flight as span_args:
             with seg("decode.enqueue", span_args), self._decode_ctx():
                 nxt, self._cache = decode_fn(*args)
             with seg("decode.wait", span_args):
@@ -4231,7 +4341,7 @@ class ServingEngine:
         with seg("step.decode.commit", phase):
             # the call's operands are released here, on the commit's
             # account, not when the frame dies outside every segment
-            del args
+            del args, host
             self._c_decode_steps.inc()
             for slot in dec:
                 st = active[slot]
@@ -4245,6 +4355,7 @@ class ServingEngine:
                     self._finish_slot(slot)
                 else:
                     self._tokens[slot] = tok
+            del nxt                        # and the call's results
         return len(dec)
 
     def _fence_harvest(self, *arrays):
@@ -4323,12 +4434,13 @@ class ServingEngine:
             decode_fn = self._get_decode_fn()
             span_kw = self._sampler_rows(dec)
         with seg("step.decode.upload", phase):
-            args = (params, self._cache, jnp.asarray(self._tokens),
-                    jnp.asarray(self._lengths), jnp.asarray(bt),
-                    jnp.asarray(actv), jnp.asarray(budgets),
-                    jnp.asarray(eos_ids), *self._samp_args(counts))
-        with self._in_flight("decode", slots=len(dec), fused=K,
-                             **span_kw) as span_args:
+            host, puts = self._host_operands(
+                "decode", self._tokens, self._lengths, self._bt(bt), actv,
+                budgets, eos_ids, *self._samp_args(counts))
+            args = (params, self._cache, *host)
+            flight = self._in_flight("decode", slots=len(dec), fused=K,
+                                     **puts, **span_kw)
+        with flight as span_args:
             with seg("decode.enqueue", span_args), self._decode_ctx():
                 out, self._cache = decode_fn(*args)
             with seg("decode.wait", span_args):
@@ -4338,7 +4450,7 @@ class ServingEngine:
         # tokens through the exact K=1 commit sequence (emission order,
         # finish conditions, TTFT stamps — token- and event-identical)
         with seg("step.decode.commit", phase):
-            del args                       # released on the commit's account
+            del args, host                 # released on the commit's account
             trips = 0
             for slot in dec:
                 st = active[slot]
@@ -4363,6 +4475,7 @@ class ServingEngine:
             # per-iteration FLOPs billing identical to single-step mode
             self._c_decode_steps.inc(trips)
             self._c_fused_iterations.inc(trips)
+            del out                        # and the call's results
         return len(dec)
 
     def _run_spec_decode(self, params):
@@ -4402,26 +4515,29 @@ class ServingEngine:
             bt = np.zeros_like(self._tables)
             bt[dec] = self._tables[dec]
             counts = self._decode_counts()
-        with seg("step.decode.upload", phase):
             samp = self._samp_args(counts)
-            bt_dev, len_dev = jnp.asarray(bt), jnp.asarray(self._lengths)
         if self._draft is not None:
-            with seg("step.decode.upload", phase):
-                args = (self._draft.params, self._dcache,
-                        jnp.asarray(self._tokens), len_dev, bt_dev, *samp)
             draft_fn = self._get_draft_fn()
-            with self._in_flight("spec_propose", slots=len(dec),
-                                 mode="draft") as span_args:
+            with seg("step.decode.upload", phase):
+                # the draft never sees the mask matrix (constrained slots
+                # accept no draft), so only the five vectors ride
+                host, puts = self._host_operands(
+                    "draft", self._tokens, self._lengths, bt, *samp[:5])
+                args = (self._draft.params, self._dcache, *host)
+                flight = self._in_flight("spec_propose", slots=len(dec),
+                                         mode="draft", **puts)
+            with flight as span_args:
                 with seg("spec_propose.enqueue", span_args), self._tp_ctx():
                     drafts, self._dcache = draft_fn(*args)
                 with seg("spec_propose.wait", span_args):
                     drafts = np.asarray(drafts)
         else:
             # the n-gram proposer is host work under the documented span
-            # name: no program is in flight, the device idles through it
-            # — planning, by the segments' account
+            # name: no program is in flight (nothing is handed over), the
+            # device idles through it — planning, by the segments' account
             with self.timeline.span("spec_propose", slots=len(dec),
-                                    mode="ngram"), \
+                                    mode="ngram", puts=0,
+                                    operand_bytes=0), \
                     seg("step.decode.plan", phase):
                 drafts = np.zeros((self.slots, k), np.int32)
                 for slot in dec:
@@ -4437,10 +4553,12 @@ class ServingEngine:
             valid[dec] = k + 1
             verify_fn = self._get_verify_fn()
         with seg("step.decode.upload", phase):
-            args = (params, self._cache, jnp.asarray(ids), bt_dev, len_dev,
-                    jnp.asarray(valid), *samp)
-        with self._in_flight("spec_verify", slots=len(dec),
-                             window=k + 1) as span_args:
+            host, puts = self._host_operands(
+                "verify", ids, bt, self._lengths, valid, *samp)
+            args = (params, self._cache, *host)
+            flight = self._in_flight("spec_verify", slots=len(dec),
+                                     window=k + 1, **puts)
+        with flight as span_args:
             with seg("spec_verify.enqueue", span_args), self._tp_ctx():
                 out = verify_fn(*args)
             with seg("spec_verify.wait", span_args):
@@ -4457,7 +4575,7 @@ class ServingEngine:
                     scored = self._split_record(
                         scored, (self.slots, k + 1), span_args)
         with seg("step.decode.commit", phase):
-            del args, samp, bt_dev, len_dev, out   # on the commit's account
+            del args, host, out            # on the commit's account
             return self._commit_spec_round(dec, ids, scored, accept, plain,
                                            resid)
 
@@ -4595,24 +4713,24 @@ class ServingEngine:
                 **self._sampler_rows(group),
                 **self._kv_reach((base + valid)[:len(group)]))
         with seg("step.prefill.upload", phase):
-            samp = self._samp_args_rows(group, j)
-            packed = (jnp.asarray(ids),
-                      self._bt(bt, list(group) + [-1] * (j - len(group))),
-                      jnp.asarray(base), jnp.asarray(valid))
+            operands = [ids,
+                        self._bt(bt, list(group) + [-1] * (j - len(group))),
+                        base, valid]
+            if self.resident_window_blocks:
+                # per-ROW window starts (prefill batches rows from
+                # arbitrary slots); pad rows stay 0 = fully visible
+                ws = np.zeros(j, np.int32)
+                ws[:len(group)] = self._window_start[list(group)]
+                operands.append(ws)
+            host, puts = self._host_operands(
+                "prefill", *operands, *self._samp_args_rows(group, j))
             if self._draft is not None:
                 args = (params, self._draft.params, self._cache,
-                        self._dcache, *packed, *samp)
+                        self._dcache, *host)
             else:
-                args = (params, self._cache, *packed)
-                if self.resident_window_blocks:
-                    # per-ROW window starts (prefill batches rows from
-                    # arbitrary slots); pad rows stay 0 = fully visible
-                    ws = np.zeros(j, np.int32)
-                    for row, slot in enumerate(group):
-                        ws[row] = self._window_start[slot]
-                    args += (jnp.asarray(ws),)
-                args += samp
-        with self._in_flight("prefill", **span_kw) as span_args:
+                args = (params, self._cache, *host)
+            flight = self._in_flight("prefill", **puts, **span_kw)
+        with flight as span_args:
             with seg("prefill.enqueue", span_args), self._tp_ctx():
                 if self._draft is not None:
                     first, self._cache, self._dcache = prefill_fn(*args)
@@ -4623,8 +4741,9 @@ class ServingEngine:
                 first = self._split_record(np.asarray(first), (j,),
                                            span_args)
         with seg("step.prefill.commit", phase):
-            del args, packed, samp         # released on the commit's account
+            del args, host                 # released on the commit's account
             self._commit_prefill_group(group, rows, first)
+            del first                      # and the call's results
 
     def _commit_prefill_group(self, group, rows, first) -> None:
         """The commit loop of :meth:`_run_prefill_group`: advance each
@@ -4855,6 +4974,14 @@ class ServingEngine:
             # how each built program picks its tokens: "argmax" (greedy-only
             # engine) or how ops/sampling.py finds the filter's thresholds
             "sampler": dict(self._program_meta.get("sampler", {})),
+            # per built program, the ONE host buffer a call carries: its
+            # fields, its bytes, and the host arrays a call hands over (the
+            # buffer, and the mask matrix beside it where one rides)
+            "operands": {
+                name: {"fields": len(lay.fields), "bytes": lay.nbytes,
+                       "puts": 2 if self.logit_masks and name != "draft"
+                       else 1}
+                for name, lay in self._layouts.items()},
             # a learned-sparse-attention model: what each program's
             # selection was traced with, and the totals of the spans'
             # counters (:meth:`_split_record`); None for any other model

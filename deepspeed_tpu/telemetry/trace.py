@@ -96,32 +96,42 @@ def annotation(name: str):
 
 class _Segment:
     """``with`` block of :meth:`TraceTimeline.segment`: enters ``note`` (a
-    profiler annotation) and adds the body's seconds on ``clock`` to
-    ``into[key]`` (``into`` None: the ring is off).  A timeline keeps ONE
-    per segment name and hands it out again with the next ``into`` — a
-    segment runs several times a step and is never inside itself.  The
-    annotation is made anew at every entry: one decides AS IT IS MADE
-    whether a profile is being taken, so a kept one would stay silent in
-    every profile started after it."""
+    profiler annotation) and adds the body's seconds on the timeline's
+    clock to ``into[key]`` (``into`` None: the ring is off).  A timeline
+    keeps ONE per segment name and hands it out again with the next
+    ``into`` — a segment runs several times a step and is never inside
+    itself.  The annotation is made anew at every entry: one decides AS IT
+    IS MADE whether a profile is being taken, so a kept one would stay
+    silent in every profile started after it.
 
-    __slots__ = ("into", "_clock", "_key", "_name", "_note", "_t0")
+    Segments TILE: one that is entered with nothing but bookkeeping since
+    the last boundary — the exit of a segment, the end of a span — begins
+    AT that boundary (the timeline's ``_lap``), not at its own clock
+    read, so the microseconds of leaving one ``with`` and entering the
+    next belong to the segment that follows.  A span's entry clears the
+    boundary: what is entered first inside a span begins now."""
 
-    def __init__(self, clock, key, name):
-        self._clock, self._key, self._name = clock, key, name
+    __slots__ = ("into", "_tl", "_key", "_name", "_note", "_t0")
+
+    def __init__(self, timeline, key, name):
+        self._tl, self._key, self._name = timeline, key, name
         self.into = None
 
     def __enter__(self):
         self._note = annotation(self._name)
         self._note.__enter__()
         if self.into is not None:
-            self._t0 = self._clock()
+            tl = self._tl
+            lap, tl._lap = tl._lap, None
+            self._t0 = tl._clock() if lap is None else lap
         return self.into
 
     def __exit__(self, *exc):
         into = self.into
         if into is not None:
-            into[self._key] = into.get(self._key, 0.0) \
-                + self._clock() - self._t0
+            tl = self._tl
+            tl._lap = now = tl._clock()
+            into[self._key] = into.get(self._key, 0.0) + now - self._t0
         self._note.__exit__(*exc)
         return False
 
@@ -172,6 +182,9 @@ class TraceTimeline:
         self.dropped = 0
         #: segment name -> its one ``_Segment``
         self._segments: Dict[str, _Segment] = {}
+        #: the clock at the last boundary a segment may begin at (the exit
+        #: of a segment, the end of a span); None: none since a span began
+        self._lap: Optional[float] = None
         self._thread_names: Dict[int, str] = {SCHEDULER_TID: "scheduler"}
         self._next_tid = 1
         # lane allocation is check-then-act (look up name, else mint a
@@ -290,11 +303,23 @@ class TraceTimeline:
             if not self.enabled:
                 yield args
                 return
+            self.lap()
             start = self.now_us()
             try:
                 yield args
             finally:
-                self.complete(name, start, tid=tid, **args)
+                # the end is read as the body ends, before the event is
+                # built: a segment entered next begins here
+                end = self.now_us()
+                self.complete(name, start, tid=tid, end_us=end, **args)
+                self.lap(end)
+
+    def lap(self, ts_us: Optional[float] = None) -> None:
+        """Set the boundary the next segment begins at (:class:`_Segment`)
+        to ``ts_us`` on the event clock — the end of the span that was
+        just emitted — or, with None, clear it: a span has begun, what is
+        entered first inside it begins at its own clock read."""
+        self._lap = None if ts_us is None else ts_us * 1e-6 + self._t0
 
     def segment(self, name: str, into: Dict[str, Any]):
         """Context manager for a piece of a span that is NOT an event: the
@@ -307,7 +332,7 @@ class TraceTimeline:
         seg = self._segments.get(name)
         if seg is None:
             seg = self._segments[name] = _Segment(
-                self._clock, name.rsplit(".", 1)[-1] + "_s",
+                self, name.rsplit(".", 1)[-1] + "_s",
                 f"ds.{self.role}.{name}")
         seg.into = into if self.enabled else None
         return seg

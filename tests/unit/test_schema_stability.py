@@ -55,6 +55,8 @@ ENGINE_STATS_KEYS = frozenset({
     "prefill_attn",
     # PR 33: how each built program picks its tokens
     "sampler",
+    # PR 38: per built program, the one host buffer a call carries
+    "operands",
     # PR 32: a learned-sparse-attention model's selection paths + counters
     # (None for any other model)
     "sparse_attn",
