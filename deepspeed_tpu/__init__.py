@@ -236,7 +236,7 @@ def init_router(model=None, config=None, params=None, *, replicas=2,
 
 def init_serving(model=None, config=None, params=None, *, slots=8,
                  max_seq_len=None, prefill_batch=4,
-                 block_size=32, num_blocks=None,
+                 block_size=None, num_blocks=None,
                  prefill_chunk=128, prefix_caching=None, decode_steps=1,
                  engine_mode="replicas", sp=1, resident_window_blocks=0,
                  spec_tokens=0,

@@ -212,6 +212,26 @@ both pools, the releases, the reach the scheduler reckons from its rows'
 lengths (``kv_valid`` / ``kv_visible``, :meth:`ServingEngine._kv_reach`)
 and the refusals.
 
+**The latent kind** (PR 39): a model with latent attention (decode hook
+``latent_attention``; ``models/llama.py`` ``kv_lora_rank > 0``) is served
+on a pool of ONE leaf, ``latent [L, NB, 1, block_size, W]`` — a token's
+normed latent and the one rotated key all heads share, 320 values padded to
+384 lanes, and no K / V (``ops/paged_kv.py`` "The latent kind").  A latent
+block is a block: one block-id space, one table, ``_alloc`` / ``_tables`` /
+``_held``, admission, preemption, eviction, the prefix trie and the host
+tier go by tree as for any model, and a verify window (``spec_tokens``) is
+the same kernel at ``T = K + 1``.  Where the caller names no ``block_size``
+the block is ``paged_kv.latent_block_tokens``'s, 512 tokens at 768 B (a
+32-token block would be 24 KB, and a block visit costs ~0.4 us whatever it
+moves).  Refused by name at construction, each with its
+reason: ``quantize`` (kv8, w8a8), a tp mesh, ``engine_mode="dp_tp"``, ``sp
+> 1``, a draft model, ``resident_window_blocks``, ``decode_steps > 1``.
+``stats()["kv_latent"]`` names the kind, the token's width and bytes, the
+block, the read each program was traced with and the refusals; the
+``decode`` / ``prefill`` spans carry ``kv_valid`` (valid keys x layers),
+``kv_blocks``, ``kv_pairs`` (query-key pairs x layers) and ``latent_bytes``
+(:meth:`ServingEngine._kv_reach`).
+
 Greedy decoding only: per-request outputs are token-identical to
 sequential ``generate`` (pinned in ``tests/unit/test_serving.py``,
 ``tests/unit/test_paged_serving.py``, ``tests/unit/test_spec_decode.py``,
@@ -754,7 +774,10 @@ class ServingEngine:
     max_seq_len:    per-sequence budget (prompt + completion), clamped to
                     the model context length.
     block_size:     tokens per KV block (paging granularity — also the
-                    prefix-reuse granularity).
+                    prefix-reuse granularity).  Default ``None`` = 32, and
+                    for a model with latent attention what
+                    ``paged_kv.latent_block_tokens`` makes of the leaf's
+                    bytes a token (512 at 768 B).
     num_blocks:     physical pool size incl. the scratch block.  Default
                     ``1 + slots * ceil(max_seq_len/block_size)`` (no
                     oversubscription); smaller pools oversubscribe and rely
@@ -871,7 +894,7 @@ class ServingEngine:
     def __init__(self, engine, *, slots: int = 8,
                  max_seq_len: Optional[int] = None,
                  prefill_batch: int = 4,
-                 block_size: int = 32,
+                 block_size: Optional[int] = None,
                  num_blocks: Optional[int] = None,
                  prefill_chunk: int = 128,
                  prefix_caching: Optional[bool] = None,
@@ -947,6 +970,13 @@ class ServingEngine:
         #: table — the full kind as every model's, the window kind a ring
         #: (module docstring "Layer kinds"); None otherwise
         self._windows = hooks.get("window_layers")
+        #: latent attention (decode hook ``latent_attention``: ``{"rank",
+        #: "rope", "width"}``): the pool is ONE leaf holding ``width``
+        #: values a token a layer and no K / V (module docstring "The
+        #: latent kind"); None otherwise
+        self._latent = hooks.get("latent_attention")
+        self._latent_totals = {"kv_valid": 0, "kv_blocks": 0,
+                               "kv_pairs": 0, "latent_bytes": 0}
         #: :meth:`_kv_reach`'s span args, summed
         self._window_totals = {"kv_valid": 0, "kv_visible": 0}
         self._full_peak = 0        # most full-kind blocks in use after a step
@@ -969,6 +999,12 @@ class ServingEngine:
         self.slots = int(slots)
         if self.slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
+        if block_size is None:
+            block_size = paged_kv.latent_block_tokens(
+                self._latent["width"],
+                jnp.dtype(engine._config.jnp_dtype).itemsize,
+                self.max_seq_len) if self._latent \
+                else paged_kv.DEFAULT_BLOCK_TOKENS
         if block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         self.block_size = int(block_size)
@@ -1240,6 +1276,41 @@ class ServingEngine:
                     "indexer (decode hook sparse_attention), which is served "
                     "on one shard over a float pool; not with "
                     + ", ".join(unserved))
+        if self._latent:
+            # what a latent model is REFUSED, each by name with its reason,
+            # and whether this construction asked for it
+            refused = (
+                ("quantize='kv8'", self.kv_quant,
+                 "a quantized latent is a different model: the value is a "
+                 "projection of the same vector the key is"),
+                (f"quantized weights ({self.weight_quant})", self.weight_quant,
+                 "the absorbed read takes kv_b_w as the two up-projections "
+                 "it holds, not as an int8 record"),
+                (f"a tp mesh (tp={self.tp_degree})", self.tp_degree > 1,
+                 "the latent has no head axis to shard: it is replicated "
+                 "under tp by design (head-sharded up-projections around a "
+                 "replicated pool), a path that is not built"),
+                (f"engine_mode='dp_tp' (dp={self.dp_degree})",
+                 self.dp_degree > 1, "the latent write and read run on "
+                 "one shard"),
+                (f"sp={self.sp_degree}", self.sp_degree > 1,
+                 "sequence-parallel prefill all-to-alls heads of K and V"),
+                ("a draft model", draft is not None,
+                 "the draft's pool would be a second kind beside it"),
+                ("resident_window_blocks", self.resident_window_blocks,
+                 "the latent kernel carries no resident-window mask"),
+                (f"decode_steps={self._K}", self._K > 1,
+                 "the fused window is not tested over the latent kind"))
+            #: ``stats()["kv_latent"]["refused"]``
+            self._latent_refusals = [what.split("=")[0].split(" (")[0]
+                                     for what, _, _ in refused]
+            unserved = [f"{what} ({why})" for what, on, why in refused if on]
+            if unserved:
+                raise ValueError(
+                    f"{engine.module.name} caches a latent a token (decode "
+                    "hook latent_attention): its pool is one leaf without "
+                    "a head axis, read absorbed, which is not served with "
+                    + "; ".join(unserved))
         if self.kv_quant:
             # int8 pool records {qp, ps} (ops/paged_kv): codes + per-block
             # scale table, built from the float pool's ABSTRACT shapes
@@ -1280,6 +1351,9 @@ class ServingEngine:
                  is_leaf=paged_kv.is_quantized_pool)),
             key=lambda shape: int(np.prod(shape)))
         self._kv_scale_live: set = set()
+        #: bytes an absorbed read needs of one key in one layer
+        self._latent_token_bytes = self._latent["width"] \
+            * jnp.dtype(self._kv_dtype).itemsize if self._latent else 0
         hkv = int(self._pool_shape[2])
         divisible = self.tp_degree > 1 and hkv % self.tp_degree == 0
         if shard_kv and self.tp_degree > 1 and not divisible:
@@ -2047,6 +2121,14 @@ class ServingEngine:
             self._program_meta.setdefault("sparse_attn", {})[program] = \
                 sparse_index_attention.took()
 
+    def _note_latent(self, program: str, paths) -> None:
+        """Trace time: which read ``program``'s latent attention was built
+        with (``stats()["kv_latent"]["latent_attn"]``): a
+        ``paged_latent_*`` kernel on a TPU, ``"latent_gather"`` on a CPU."""
+        if self._latent:
+            self._program_meta.setdefault("latent_attn", {})[program] = \
+                "+".join(sorted(paths))
+
     def _note_sampler(self, program: str, samp) -> None:
         """Trace time: how ``program`` picks its tokens
         (``stats()["sampler"]``) — ``"argmax"`` for a greedy-only engine,
@@ -2228,9 +2310,12 @@ class ServingEngine:
 
             def step_core(params, cache, tokens, lengths, block_tables,
                           samp):
-                logits, cache, rec = fwd(prepare(params), tokens[:, None],
-                                         cache, 0, lengths=lengths,
-                                         block_tables=block_tables)
+                with decode_attention.dispatch_log() as paths:
+                    logits, cache, rec = fwd(prepare(params),
+                                             tokens[:, None], cache, 0,
+                                             lengths=lengths,
+                                             block_tables=block_tables)
+                self._note_latent("decode", paths)
                 self._note_sparse("decode")
                 self._note_sampler("decode", samp)
                 return with_record(next_tokens(logits, samp), rec), \
@@ -2385,6 +2470,7 @@ class ServingEngine:
                                          block_tables=block_tables)
             # which read the program was built with, noted as it is traced
             meta["prefill_attn"] = "+".join(sorted(paths))
+            self._note_latent("prefill", paths)
             self._note_sparse("prefill")
             samp_t = pack(samp)
             self._note_sampler("prefill", samp_t)
@@ -2470,11 +2556,13 @@ class ServingEngine:
                 would bias the emission (see docs/inference.md)."""
                 # a learned sparse attention's counts ride behind the
                 # scored tokens, as behind a decode step's
-                logits, cache, *rec = (self._forward if self._sparse
-                                       else fwd)(
-                    prepare(params), ids, cache, base, lengths=valid,
-                    block_tables=block_tables, all_positions=True)
+                with decode_attention.dispatch_log() as paths:
+                    logits, cache, *rec = (self._forward if self._sparse
+                                           else fwd)(
+                        prepare(params), ids, cache, base, lengths=valid,
+                        block_tables=block_tables, all_positions=True)
                 rec = rec[0] if rec else None
+                self._note_latent("verify", paths)
                 self._note_sparse("verify")
                 samp_t = pack(samp)
                 self._note_sampler("verify", samp_t)
@@ -3355,7 +3443,7 @@ class ServingEngine:
                 else int(self._lengths[slot]), upto)
         return slot in self._active
 
-    def _kv_reach(self, valid) -> Dict[str, int]:
+    def _kv_reach(self, valid, queries=None) -> Dict[str, int]:
         """Span args of a dispatch of a model with window layers, from the
         scheduler's own bookkeeping: ``valid`` holds, for each live row,
         the keys valid for its last query (its position + 1).  ``kv_valid``
@@ -3365,6 +3453,25 @@ class ServingEngine:
         what the traffic lets a windowed read skip, not a reading of what
         the kernels fetched (the comparison with the plain reference and
         the kernels' device time are what hold them to the window)."""
+        if self._latent:
+            # a latent model: the valid keys x layers, the blocks those
+            # rows hold, and the bytes an absorbed read NEEDS of them
+            # (``width`` values a key a layer in the pool's dtype)
+            # and ``kv_pairs``, the (query, key) pairs x layers its scores
+            # cover: a row's ``queries`` newest positions (default 1, a
+            # decode step) see ``valid``, ``valid - 1``, .. keys
+            valid = np.asarray(valid, np.int64)
+            q = np.ones_like(valid) if queries is None \
+                else np.asarray(queries, np.int64)
+            layers = int(self._pool_shape[0])
+            args = {"kv_valid": int(valid.sum()) * layers,
+                    "kv_blocks": int((-(-valid // self.block_size)).sum()),
+                    "kv_pairs": int((q * valid - q * (q - 1) // 2).sum())
+                    * layers}
+            args["latent_bytes"] = args["kv_valid"] * self._latent_token_bytes
+            for key, v in args.items():
+                self._latent_totals[key] += v
+            return args
         if not self._windows:
             return {}
         valid = np.asarray(valid, np.int64)
@@ -4706,12 +4813,15 @@ class ServingEngine:
                 valid[row] = v
                 rows.append((slot, v))
             prefill_fn = self._get_prefill_fn()
-            span_kw = dict(
-                width=width, rows=len(group), slots=list(map(int, group)),
+            span_kw = {
+                "width": width, "rows": len(group),
+                "slots": list(map(int, group)),
                 # blocks the rows' reads walk: cdiv(base + valid, bs) each
-                kv_blocks=int((-(-(base + valid) // self.block_size)).sum()),
+                "kv_blocks": int(
+                    (-(-(base + valid) // self.block_size)).sum()),
                 **self._sampler_rows(group),
-                **self._kv_reach((base + valid)[:len(group)]))
+                **self._kv_reach((base + valid)[:len(group)],
+                                 valid[:len(group)])}
         with seg("step.prefill.upload", phase):
             operands = [ids,
                         self._bt(bt, list(group) + [-1] * (j - len(group))),
@@ -4922,6 +5032,21 @@ class ServingEngine:
             "expert_rows_absent": self._rows_absent,
             "refused": list(self._window_refusals)}
 
+    def _kv_latent(self) -> Dict[str, Any]:
+        """``stats()["kv_latent"]`` (a model with latent attention)."""
+        lanes = int(self._pool_shape[4])
+        item = self._latent_token_bytes // self._latent["width"]
+        return {
+            "kind": "latent", "layers": int(self._pool_shape[0]),
+            # values a token a layer, and the lanes the pool gives them
+            "token_width": self._latent["width"], "pool_width": lanes,
+            "token_bytes": self._latent_token_bytes,
+            "block_size": self.block_size,
+            "block_bytes": self.block_size * lanes * item,
+            "latent_attn": dict(self._program_meta.get("latent_attn", {})),
+            **self._latent_totals,
+            "refused": list(self._latent_refusals)}
+
     def _latency_stats(self) -> Dict[str, Any]:
         """TTFT/TPOT percentiles over every finished request (cumulative
         across serve calls, like the other counters) — read from the
@@ -4992,6 +5117,11 @@ class ServingEngine:
             # spans' reach counters summed, and what such a model is
             # refused; None for any other model
             "kv_kinds": self._kv_kinds() if self._windows else None,
+            # a model with latent attention: the pool's kind, a token's
+            # width and bytes, the block, what each program's read was
+            # traced with, the spans' counters summed, and what such a
+            # model is refused; None for any other model
+            "kv_latent": self._kv_latent() if self._latent else None,
             "admitted": self.admitted,
             "evicted": self.preempted,
             "cancelled": int(self._c_cancelled.value),

@@ -74,6 +74,31 @@ class LlamaConfig:
     sliding_window: int = 0
     #: the head is the token table transposed (no ``lm_head`` leaf)
     tie_embeddings: bool = False
+    #: LATENT ATTENTION (MLA; ``kv_lora_rank > 0``, else today's ``q_w / k_w
+    #: / v_w``): queries through a low-rank pair with a norm between
+    #: (``q_a_w [d, q_lora_rank]``, ``q_a_norm``, ``q_b_w``), keys and values
+    #: through ONE joint down-projection ``kv_a_w [d, kv_lora_rank +
+    #: qk_rope_dim]`` whose first ``kv_lora_rank`` outputs are normed
+    #: (``kv_a_norm``: the latent ``c``) and whose last ``qk_rope_dim`` are
+    #: the one rotated key every head shares; ``kv_b_w [kv_lora_rank, H *
+    #: (qk_nope_dim + v_dim)]`` expands ``c`` to each head's unrotated key
+    #: part and its value.  A head's score width is ``qk_nope_dim +
+    #: qk_rope_dim`` (= :attr:`head_dim`), its value width ``v_head_dim``
+    #: (0: ``head_dim``) — kept apart.  The cache holds ``[c | k_r]`` a
+    #: token a layer and nothing else (``ops/paged_kv.py`` "The latent kind")
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    #: rotary scaling: ``None``, or YaRN's six numbers ``{"factor",
+    #: "original_max_position_embeddings", "beta_fast", "beta_slow",
+    #: "mscale", "mscale_all_dim"}`` (:func:`rope_inv_freq`)
+    rope_scaling: Optional[dict] = None
+    #: position-dependent query temperature ``(beta, period)``: the query at
+    #: position ``p`` is multiplied by ``1 + beta * ln(1 + floor(p /
+    #: period))``; ``None``: none
+    query_temperature: Optional[tuple] = None
 
     def __post_init__(self):
         if self.qk_norm not in (False, True, "head"):
@@ -91,10 +116,50 @@ class LlamaConfig:
                 f"periods of {len(self.layer_kinds)} layers")
         if "sliding" in self.layer_kinds and self.sliding_window < 1:
             raise ValueError("sliding layers need sliding_window >= 1")
+        if self.rope_scaling is not None:
+            missing = [k for k in YARN_KEYS if k not in self.rope_scaling]
+            if missing:
+                raise ValueError(f"rope_scaling (YaRN) lacks {missing}; it "
+                                 f"holds {list(YARN_KEYS)}")
+        if self.query_temperature is not None:
+            self.query_temperature = tuple(self.query_temperature)
+        if self.latent:
+            if min(self.q_lora_rank, self.qk_nope_dim, self.qk_rope_dim) < 1 \
+                    or self.qk_rope_dim % 2:
+                raise ValueError(
+                    "latent attention (kv_lora_rank > 0) needs q_lora_rank, "
+                    "qk_nope_dim and an even qk_rope_dim")
+            if self.head_dim != self.qk_nope_dim + self.qk_rope_dim:
+                raise ValueError(
+                    f"head_dim {self.head_dim} is not qk_nope_dim + "
+                    f"qk_rope_dim ({self.qk_nope_dim} + {self.qk_rope_dim}):"
+                    " pass head_width")
+            if self.qk_norm or self.layer_kinds:
+                raise ValueError("latent attention is built without "
+                                 "qk_norm and without a layer pattern")
 
     @property
     def head_dim(self) -> int:
         return self.head_width or self.hidden_size // self.num_heads
+
+    @property
+    def latent(self) -> bool:
+        """Latent attention (MLA): the cache holds one latent a token."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def rope_dim(self) -> int:
+        """The rotated width of a head: all of it, or ``qk_rope_dim``."""
+        return self.qk_rope_dim if self.latent else self.head_dim
+
+    @property
+    def value_dim(self) -> int:
+        return self.v_head_dim or self.head_dim
+
+    @property
+    def latent_width(self) -> int:
+        """Values cached a token a layer: ``[c | k_r]``."""
+        return self.kv_lora_rank + self.qk_rope_dim
 
     @staticmethod
     def llama3_8b() -> "LlamaConfig":
@@ -118,11 +183,34 @@ class LlamaConfig:
         hd = self.head_dim
         attn = d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd + \
             self.num_heads * hd * d
+        if self.latent:
+            attn = sum(math.prod(shape) for shape in
+                       latent_shapes(self).values())
         mlp = 3 * d * f
         norms = (1 if self.parallel_block else 2) * d \
             + sum(qk_norm_widths(self))
         head = 0 if self.tie_embeddings else d * v
         return v * d + l * (attn + mlp + norms) + d + head
+
+
+#: what ``LlamaConfig.rope_scaling`` holds
+YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast",
+             "beta_slow", "mscale", "mscale_all_dim")
+
+
+def latent_shapes(cfg: LlamaConfig):
+    """One layer's attention leaves of a latent model, by name (module of
+    the published names: ``q_a_proj``, ``q_a_layernorm``, ``q_b_proj``,
+    ``kv_a_proj_with_mqa``, ``kv_a_layernorm``, ``kv_b_proj``, ``o_proj``;
+    a projection is stored ``[in, out]``)."""
+    d, h = cfg.hidden_size, cfg.num_heads
+    return {"q_a_w": (d, cfg.q_lora_rank), "q_a_norm": (cfg.q_lora_rank,),
+            "q_b_w": (cfg.q_lora_rank, h * cfg.head_dim),
+            "kv_a_w": (d, cfg.latent_width),
+            "kv_a_norm": (cfg.kv_lora_rank,),
+            "kv_b_w": (cfg.kv_lora_rank,
+                       h * (cfg.qk_nope_dim + cfg.value_dim)),
+            "o_w": (h * cfg.value_dim, d)}
 
 
 def qk_norm_widths(cfg: LlamaConfig):
@@ -180,6 +268,19 @@ def init_params(cfg: LlamaConfig, rng) -> PyTree:
     }
     for name, width in zip(("q_norm", "k_norm"), qk_norm_widths(cfg)):
         params["blocks"][name] = jnp.ones((l, width))
+    if cfg.latent:
+        blocks = params["blocks"]
+        for name in ("q_w", "k_w", "v_w"):
+            del blocks[name]
+        shapes = latent_shapes(cfg)
+        lkeys = jax.random.split(jax.random.fold_in(rng, 17), len(shapes))
+        for key, (name, shape) in zip(lkeys, shapes.items()):
+            if name.endswith("_norm"):
+                blocks[name] = jnp.ones((l,) + shape)
+            else:
+                blocks[name] = normal(
+                    key, (l,) + shape,
+                    std / math.sqrt(2 * l) if name == "o_w" else std)
     if cfg.parallel_block:
         del params["blocks"]["mlp_norm"]
     if cfg.tie_embeddings:
@@ -225,16 +326,82 @@ def kind_of(cfg: LlamaConfig, kind):
     return kind == "sliding", cfg.sliding_window if kind == "sliding" else 0
 
 
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_inv_freq(cfg: LlamaConfig, dim: int):
+    """Inverse frequencies ``[dim/2]`` of a rotation over ``dim`` values:
+    ``theta^(-2i/dim)``, or under ``cfg.rope_scaling`` YaRN's blend of that
+    (extrapolation) with ``1 / (factor * theta^(2i/dim))`` (interpolation)
+    along a linear ramp between the two correction dimensions (HF
+    ``_compute_yarn_parameters``, bounds truncated to whole dimensions)."""
+    inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, dim, 2,
+                                                    dtype=jnp.float32) / dim))
+    if cfg.rope_scaling is None:
+        return inv_freq
+    y = cfg.rope_scaling
+    factor = float(y["factor"])
+
+    def correction_dim(rotations):
+        return dim * math.log(y["original_max_position_embeddings"]
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(cfg.rope_theta))
+
+    low = max(math.floor(correction_dim(y["beta_fast"])), 0)
+    high = min(math.ceil(correction_dim(y["beta_slow"])), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return inv_freq / factor * ramp + inv_freq * (1.0 - ramp)
+
+
+def rope_attention_factor(cfg: LlamaConfig) -> float:
+    """What YaRN multiplies cos and sin by: ``mscale(factor, mscale) /
+    mscale(factor, mscale_all_dim)`` (1 without scaling)."""
+    y = cfg.rope_scaling
+    if y is None:
+        return 1.0
+    return _yarn_mscale(y["factor"], y["mscale"]) \
+        / _yarn_mscale(y["factor"], y["mscale_all_dim"])
+
+
+def _cos_sin(cfg: LlamaConfig, angles):
+    f = rope_attention_factor(cfg)
+    if f == 1.0:
+        return jnp.cos(angles), jnp.sin(angles)
+    return jnp.cos(angles) * f, jnp.sin(angles) * f
+
+
+def latent_scale(cfg: LlamaConfig) -> float:
+    """The softmax scale of a latent model: ``1 / sqrt(head_dim)``, times
+    ``m^2`` under YaRN with ``m = 0.1 * mscale_all_dim * ln(factor) + 1``
+    (DeepSeek-V3's convention for these keys)."""
+    scale = 1.0 / math.sqrt(cfg.head_dim)
+    y = cfg.rope_scaling
+    if y is not None and y["mscale_all_dim"]:
+        scale *= _yarn_mscale(y["factor"], y["mscale_all_dim"]) ** 2
+    return scale
+
+
+def query_temperature(cfg: LlamaConfig, positions):
+    """float32 ``t(p) = 1 + beta * ln(1 + floor(p / period))`` at integer
+    ``positions`` (``cfg.query_temperature``)."""
+    beta, period = cfg.query_temperature
+    return 1.0 + beta * jnp.log1p(
+        (jnp.asarray(positions, jnp.int32) // int(period)).astype(jnp.float32))
+
+
 def rope_angles(cfg: LlamaConfig, seq_len: int, offset: int = 0,
                 dim: Optional[int] = None):
     """cos / sin ``[S, dim/2]`` (``dim``: the rotated width, a head's by
-    default)."""
-    hd = dim or cfg.head_dim
-    inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, hd, 2,
-                                                    dtype=jnp.float32) / hd))
+    default — of a latent model its ``qk_rope_dim``)."""
+    hd = dim or cfg.rope_dim
+    inv_freq = rope_inv_freq(cfg, hd)
     pos = jnp.arange(offset, offset + seq_len, dtype=jnp.float32)
     angles = pos[:, None] * inv_freq[None, :]          # [S, hd/2]
-    return jnp.cos(angles), jnp.sin(angles)
+    return _cos_sin(cfg, angles)
 
 
 def apply_rope(x, cos, sin, interleaved: bool = False):
@@ -302,6 +469,106 @@ def _dense_attention(cfg: LlamaConfig, q, k, v, window: int = 0):
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
+def _latent_project(cfg: LlamaConfig, y, get, mm, rope):
+    """The projections of a latent layer over its normed input ``y [B, T,
+    d]``: the heads' unrotated and rotated query parts ``qn [B, H, T,
+    qk_nope_dim]`` / ``qr [B, H, T, qk_rope_dim]``, the normed latent ``c
+    [B, T, kv_lora_rank]`` and the ONE rotated key ``kr [B, 1, T,
+    qk_rope_dim]`` all heads share.  ``rope(a)`` rotates ``[B, *, T,
+    qk_rope_dim]`` at the tokens' positions."""
+    b, t, _ = y.shape
+    h, nope = cfg.num_heads, cfg.qk_nope_dim
+    cq = rms_norm(mm(y, "q_a_w", None), get("q_a_norm"), cfg.rms_eps)
+    q = mm(cq, "q_b_w", None).reshape(b, t, h, cfg.head_dim) \
+        .transpose(0, 2, 1, 3)
+    kv = mm(y, "kv_a_w", None)
+    c = rms_norm(kv[..., :cfg.kv_lora_rank], get("kv_a_norm"), cfg.rms_eps)
+    kr = rope(kv[:, None, :, cfg.kv_lora_rank:])
+    return q[..., :nope], rope(q[..., nope:]), c, kr
+
+
+def _latent_up(cfg: LlamaConfig, kv_b_w, dtype):
+    """``kv_b_w [kv_lora_rank, H * (qk_nope_dim + v_dim)]`` read as the two
+    up-projections it holds, ``W_uk [c, H, nope]`` and ``W_uv [c, H, v]``
+    (views of the ONE stored leaf: no second copy of it is a parameter)."""
+    w = kv_b_w.astype(dtype).reshape(
+        cfg.kv_lora_rank, cfg.num_heads, cfg.qk_nope_dim + cfg.value_dim)
+    return w[..., :cfg.qk_nope_dim], w[..., cfg.qk_nope_dim:]
+
+
+def _latent_temperature(cfg: LlamaConfig, positions):
+    """The factor on a latent layer's scores, float32, broadcastable over
+    ``[B, H, T, *]``: the softmax scale times ``t(p)`` at ``positions``
+    (``[T]`` or ``[B, T]``)."""
+    if cfg.query_temperature is None:
+        return latent_scale(cfg)
+    t = query_temperature(cfg, positions) * latent_scale(cfg)
+    return t[:, None] if t.ndim == 1 else t[:, None, :, None]
+
+
+def _latent_attention(cfg: LlamaConfig, layer, y, cos, sin):
+    """A latent layer's UNCACHED attention over ``y [B, S, d]`` in the
+    EXPANDED form — every head's keys ``[kn | k_r]`` and values written out
+    from the latent — positions ``0 .. S-1``; ``[B, S, H * v_dim]``."""
+    from .gpt2 import layer_accessors
+
+    b, s, _ = y.shape
+    h = cfg.num_heads
+    get, mm = layer_accessors(layer)
+    qn, qr, c, kr = _latent_project(
+        cfg, y, get, mm, lambda a: apply_rope(a, cos, sin,
+                                              cfg.rope_interleaved))
+    w_uk, w_uv = _latent_up(cfg, get("kv_b_w"), y.dtype)
+    kn = jnp.einsum("bsc,chn->bhsn", c, w_uk)
+    v = jnp.einsum("bsc,chv->bhsv", c, w_uv)
+    scores = (jnp.einsum("bhqn,bhkn->bhqk", qn, kn)
+              + jnp.einsum("bhqr,bkr->bhqk", qr, kr[:, 0])) \
+        .astype(jnp.float32) * _latent_temperature(cfg, jnp.arange(s))
+    scores = jnp.where(jnp.tril(jnp.ones((s, s), bool))[None, None],
+                       scores, -1e9)
+    probs = jax.nn.softmax(scores, axis=-1).astype(y.dtype)
+    return jnp.einsum("bhqk,bhkv->bqhv", probs, v).reshape(
+        b, s, h * cfg.value_dim)
+
+
+def _latent_cached(cfg: LlamaConfig, y, get, mm, pool, pos, block_tables,
+                   chunk_valid, layer):
+    """A latent layer's cache write + attention over the block-paged pool,
+    in the ABSORBED form: the window's ``[c | k_r]`` goes to ``(layer,
+    block, offset)`` of the latent leaf (``ops/paged_kv.py`` "The latent
+    kind"), the unrotated query part goes through ``W_uk`` into latent
+    space, every head scores the ONE shared tile, the output is ``p . c``
+    in latent space and leaves through ``W_uv``
+    (``ops/decode_attention.paged_latent_attention``).  -> ``([B, T, H *
+    v_dim], pool)``."""
+    from ..ops.decode_attention import paged_latent_attention
+    from ..ops.paged_kv import paged_window_update
+
+    b, t, _ = y.shape
+    rank = cfg.kv_lora_rank
+    qn, qr, c, kr = _latent_project(cfg, y, get, mm,
+                                    lambda a: _rope_cached(cfg, a, pos))
+    pad = pool.shape[-1] - cfg.latent_width
+    pool = paged_window_update(
+        pool, jnp.pad(jnp.concatenate([c[:, None], kr], axis=-1),
+                      ((0, 0),) * 3 + ((0, pad),)),
+        pos, block_tables, valid=chunk_valid, layer=layer)
+    w_uk, w_uv = _latent_up(cfg, get("kv_b_w"), y.dtype)
+    p = jnp.asarray(pos, jnp.int32)
+    positions = (p + jnp.arange(t)) if p.ndim == 0 \
+        else p[:, None] + jnp.arange(t)[None, :]
+    with jax.named_scope("latent_up"):
+        ql = jnp.einsum("bhtn,chn->bhtc", qn, w_uk)
+        q = jnp.concatenate([ql, qr], axis=-1).astype(jnp.float32) \
+            * _latent_temperature(cfg, positions)
+        q = jnp.pad(q.astype(y.dtype), ((0, 0),) * 3 + ((0, pad),))
+    o = paged_latent_attention(q, pool, block_tables, pos, rank=rank,
+                               layer=layer, valid=chunk_valid)
+    with jax.named_scope("latent_up"):
+        out = jnp.einsum("bhtc,chv->bthv", o, w_uv)
+    return out.reshape(b, t, cfg.num_heads * cfg.value_dim), pool
+
+
 def attn_apply(cfg: LlamaConfig, layer: PyTree, x, cos, sin, attention=None,
                kind=None, delta: bool = False):
     """The attention half of a block (pre-norm, q/k/v, optional q/k-norm,
@@ -321,6 +588,12 @@ def attn_apply(cfg: LlamaConfig, layer: PyTree, x, cos, sin, attention=None,
     h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
 
     rotated, window = kind_of(cfg, kind)
+    if cfg.latent:
+        with jax.named_scope("layer/attn"):
+            y = block_norm(cfg, x, layer["attn_norm"])
+            out = _qmm(_latent_attention(cfg, layer, y, cos, sin),
+                       layer["o_w"], x.dtype)
+            return (y, out) if delta else x + out
     with jax.named_scope("layer/attn"):
         y = block_norm(cfg, x, layer["attn_norm"])
         q, k = qk_normed(cfg, _qmm(y, layer["q_w"]), _qmm(y, layer["k_w"]),
@@ -388,7 +661,17 @@ def forward(cfg: LlamaConfig, params: PyTree, input_ids, rng=None,
 
 def init_cache(cfg: LlamaConfig, batch_size: int, max_len: int,
                dtype=jnp.bfloat16):
-    """Static KV workspace: [L, B, HKV, S, hd] (GQA — KV heads only)."""
+    """Static KV workspace: [L, B, HKV, S, hd] (GQA — KV heads only).  A
+    latent model (``cfg.latent``; block-paged only) holds ONE leaf and no
+    ``k`` / ``v``: ``latent [L, B, 1, S, W]``, a token's ``[c | k_r]``
+    zero-padded to whole 128-lane rows (``ops/paged_kv.py`` "The latent
+    kind")."""
+    if cfg.latent:
+        from ..ops.paged_kv import latent_pool_width
+
+        return {"latent": jnp.zeros(
+            (cfg.num_layers, batch_size, 1, max_len,
+             latent_pool_width(cfg.latent_width)), dtype)}
     shape = (cfg.num_layers, batch_size, cfg.num_kv_heads, max_len,
              cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -399,8 +682,7 @@ def _rope_cached(cfg: LlamaConfig, x, pos):
     per-sequence decode positions).  x: [B, H, T, hd], rotated over its
     whole last dim (in the pairing ``cfg.rope_interleaved`` names)."""
     hd = x.shape[-1]
-    inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, hd, 2,
-                                                    dtype=jnp.float32) / hd))
+    inv_freq = rope_inv_freq(cfg, hd)
     pos = jnp.asarray(pos)
     t = jnp.arange(x.shape[2], dtype=jnp.float32)
     if pos.ndim == 0:
@@ -408,32 +690,17 @@ def _rope_cached(cfg: LlamaConfig, x, pos):
     else:
         p = pos.astype(jnp.float32)[:, None] + t[None, :]        # [B, T]
         angles = p[..., None] * inv_freq[None, None, :]          # [B, T, hd/2]
-    return apply_rope(x, jnp.cos(angles), jnp.sin(angles),
-                      cfg.rope_interleaved)
+    return apply_rope(x, *_cos_sin(cfg, angles), cfg.rope_interleaved)
 
 
-def _block_cached_body(cfg: LlamaConfig, x, get, mm, ck, cv, pos,
-                       mlp=None, block_tables=None, chunk_valid=None,
-                       layer=None, attend=None, extra=None, kind=None):
-    """Cached-attention block parameterized by weight access (``get(name)``
-    small leaf, ``mm(y, name, dtype)`` matmul — shared by the scan and
-    layer-indexed quantized decode paths, see gpt2.decode_over_layers).
-    ``mlp(y) -> (y, aux)`` overrides the dense SwiGLU (mixtral's routed
-    FFN; ``aux`` is its per-layer routing record) and makes this return
-    ``(x, ck, cv, aux)``.  ``attend(y, q, k, v, ck, cv, extra) -> (attn, ck,
-    cv, extra)`` overrides the cache write + attention (mixtral's learned
-    sparse attention, which reads the block's normed input ``y`` and keeps
-    state of its own — a third pool leaf — in ``extra``) and makes this
-    return ``(x, ck, cv, extra, aux)``.
-    ``block_tables``/``chunk_valid`` switch ck/cv to the whole paged pool,
-    addressed in place at ``layer`` (contract in gpt2._cached_attention).
-    ``kind`` (a patterned model, :func:`kind_of`): whether q and k are
-    rotated and how far a query reaches; ck/cv, ``block_tables`` and
-    ``layer`` are then that kind's own."""
+def _attend_cached(cfg: LlamaConfig, x, get, mm, ck, cv, pos, block_tables,
+                   chunk_valid, layer, attend, extra, kind):
+    """The attention half of :func:`_block_cached_body` for a model that
+    caches a key and a value a KV head: ``(x + attention, the normed input,
+    ck, cv, extra)``."""
     b, t, d = x.shape
     h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     rotated, window = kind_of(cfg, kind)
-
     with jax.named_scope("layer/attn"):
         y = block_norm(cfg, x, get("attn_norm"))
         q, k = qk_normed(cfg, mm(y, "q_w", None), mm(y, "k_w", None), get)
@@ -456,7 +723,39 @@ def _block_cached_body(cfg: LlamaConfig, x, get, mm, ck, cv, pos,
                 q, k, v, ck, cv, pos, block_tables, chunk_valid, layer,
                 window=window)
         attn = attn.transpose(0, 2, 1, 3).reshape(b, t, h * hd)
-        x = x + mm(attn, "o_w", x.dtype)
+        return x + mm(attn, "o_w", x.dtype), y, ck, cv, extra
+
+
+def _block_cached_body(cfg: LlamaConfig, x, get, mm, ck, cv, pos,
+                       mlp=None, block_tables=None, chunk_valid=None,
+                       layer=None, attend=None, extra=None, kind=None):
+    """Cached-attention block parameterized by weight access (``get(name)``
+    small leaf, ``mm(y, name, dtype)`` matmul — shared by the scan and
+    layer-indexed quantized decode paths, see gpt2.decode_over_layers).
+    ``mlp(y) -> (y, aux)`` overrides the dense SwiGLU (mixtral's routed
+    FFN; ``aux`` is its per-layer routing record) and makes this return
+    ``(x, ck, cv, aux)``.  ``attend(y, q, k, v, ck, cv, extra) -> (attn, ck,
+    cv, extra)`` overrides the cache write + attention (mixtral's learned
+    sparse attention, which reads the block's normed input ``y`` and keeps
+    state of its own — a third pool leaf — in ``extra``) and makes this
+    return ``(x, ck, cv, extra, aux)``.
+    ``block_tables``/``chunk_valid`` switch ck/cv to the whole paged pool,
+    addressed in place at ``layer`` (contract in gpt2._cached_attention).
+    ``kind`` (a patterned model, :func:`kind_of`): whether q and k are
+    rotated and how far a query reaches; ck/cv, ``block_tables`` and
+    ``layer`` are then that kind's own."""
+    if cfg.latent:
+        # its own projections and its own cache write: ``ck`` is the latent
+        # leaf, ``cv`` rides through untouched (the pool has no second leaf)
+        with jax.named_scope("layer/attn"):
+            y = block_norm(cfg, x, get("attn_norm"))
+            attn, ck = _latent_cached(cfg, y, get, mm, ck, pos, block_tables,
+                                      chunk_valid, layer)
+            x = x + mm(attn, "o_w", x.dtype)
+    else:
+        x, y, ck, cv, extra = _attend_cached(
+            cfg, x, get, mm, ck, cv, pos, block_tables, chunk_valid, layer,
+            attend, extra, kind)
 
     if not cfg.parallel_block:
         # (a parallel block's FFN reads the ONE norm of the block's input)
@@ -600,6 +899,15 @@ def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
 
     # sequence-parallel prefill hook (no-op outside an sp context)
     x = shard_seq(x)
+    # a latent model's pool is ONE leaf; it rides where K does and nothing
+    # rides where V does
+    first = "latent" if cfg.latent else "k"
+    if cfg.latent and (not paged or attend_fn is not None):
+        raise NotImplementedError(
+            "latent attention (kv_lora_rank > 0) is served through the "
+            "block-paged pool (init_serving / ServingEngine): the "
+            "contiguous cache of InferenceEngine.generate holds a key and "
+            "a value a head, not a latent")
 
     if cfg.layer_kinds:
         if not paged or mlp_fn is None or attend_fn is not None:
@@ -620,8 +928,9 @@ def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
                 cfg, x, get, mm, ck, cv, step_pos,
                 block_tables=block_tables, chunk_valid=chunk_valid,
                 layer=layer),
-            x, params["blocks"], cache["k"], cache["v"], cfg.num_layers,
-            probe="q_w", paged=paged)
+            x, params["blocks"], cache[first], cache.get("v"),
+            cfg.num_layers, probe="q_a_w" if cfg.latent else "q_w",
+            paged=paged)
     else:
         # mixtral's MoE FFN needs the whole layer dict: scan path only
         x, ks, vs, records = scan_layers_cached(
@@ -630,8 +939,8 @@ def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
                 block_tables=block_tables, chunk_valid=chunk_valid,
                 index=l, attend_fn=attend_fn),
             x, params["blocks"],
-            cache["k"] if attend_fn is None else (cache["k"], extra),
-            cache["v"], paged)
+            cache[first] if attend_fn is None else (cache["k"], extra),
+            cache.get("v"), paged)
         if attend_fn is not None:
             ks, extra = ks
     if not all_positions:
@@ -640,11 +949,12 @@ def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
     logits = head_logits(cfg, params, x)
     if cfg.layer_kinds:
         return logits, kv, records
+    kv = {"latent": ks} if cfg.latent else {"k": ks, "v": vs}
     if mlp_fn is None:
-        return logits, {"k": ks, "v": vs}
+        return logits, kv
     if attend_fn is not None:
-        return logits, {"k": ks, "v": vs}, records, extra
-    return logits, {"k": ks, "v": vs}, records
+        return logits, kv, records, extra
+    return logits, kv, records
 
 
 def loss_from_batch(cfg: LlamaConfig, params, batch, rng=None,
@@ -667,7 +977,7 @@ def loss_from_batch(cfg: LlamaConfig, params, batch, rng=None,
 
 
 def tp_rules(cfg: LlamaConfig, abstract_params: PyTree) -> PyTree:
-    return {
+    rules = {
         "embed": P(TP_AXIS, None),
         "blocks": {
             "attn_norm": P(),
@@ -685,6 +995,32 @@ def tp_rules(cfg: LlamaConfig, abstract_params: PyTree) -> PyTree:
         "final_norm": P(),
         "lm_head": P(None, TP_AXIS),
     }
+    if cfg.latent:
+        # every engine turns this tree into its parameters' shardings at
+        # construction, tp or not (``InferenceEngine``, ``DeepSpeedEngine``),
+        # so it has to name the leaves a latent model has.  The
+        # down-projections and their norms are whole on every chip (the
+        # latent is replicated under tp); the up-projections and ``o_w``
+        # split by head: what the uncached forward runs under a tp mesh
+        # (the SERVING engine refuses a tp mesh for a latent model)
+        blocks = rules["blocks"]
+        for name in ("q_w", "k_w", "v_w"):
+            del blocks[name]
+        blocks.update(q_a_w=P(), q_a_norm=P(), kv_a_w=P(), kv_a_norm=P(),
+                      q_b_w=P(None, None, TP_AXIS),
+                      kv_b_w=P(None, None, TP_AXIS))
+    return rules
+
+
+def latent_hook(cfg: LlamaConfig) -> dict:
+    """The decode hook a latent model carries (none otherwise):
+    ``{"latent_attention": {"rank", "rope", "width"}}`` — the pool has ONE
+    leaf, ``width = rank + rope`` values a token a layer."""
+    if not cfg.latent:
+        return {}
+    return {"latent_attention": {"rank": cfg.kv_lora_rank,
+                                 "rope": cfg.qk_rope_dim,
+                                 "width": cfg.latent_width}}
 
 
 def build(cfg: Optional[LlamaConfig] = None, **overrides) -> ModelSpec:
@@ -727,6 +1063,7 @@ def build(cfg: Optional[LlamaConfig] = None, **overrides) -> ModelSpec:
             "head_loss_fn": pp_head_loss,
         },
         decode_hooks={
+            **latent_hook(cfg),
             "init_cache": lambda b, s, dtype=jnp.bfloat16: init_cache(
                 cfg, b, s, dtype),
             "forward_cached": lambda params, ids, cache, pos, lengths=None,

@@ -31,6 +31,12 @@ leaves and a block table of its own), 128 sigmoid-scored experts top-8
 averaging (``shared_experts``), a tied head — and an
 expert layer that can be told which experts it HOLDS (``experts_held``: one
 chip's share of an expert-parallel group, ``moe/routed.py``).
+Mistral Small 4 (``MixtralConfig.mistral_small_4``) is the sequential block
+with LATENT ATTENTION (``llama.LlamaConfig.kv_lora_rank``: low-rank queries,
+one joint key / value latent a token, rotary on half of a head under YaRN,
+a position-dependent query temperature; the cache holds ``[c | k_r]`` a
+token a layer, ``ops/paged_kv.py`` "The latent kind", read absorbed) over
+128 softmax-scored experts top-4 renormalised beside one shared expert.
 """
 
 from __future__ import annotations
@@ -104,6 +110,9 @@ class MixtralConfig(L.LlamaConfig):
         if self.layer_kinds and self.index_heads:
             raise ValueError("a layer pattern (layer_kinds) and a learned "
                              "indexer (index_heads) are not built together")
+        if self.latent and self.index_heads:
+            raise ValueError("latent attention (kv_lora_rank) and a learned "
+                             "indexer (index_heads) are not built together")
 
     @property
     def experts_here(self) -> int:
@@ -166,6 +175,32 @@ class MixtralConfig(L.LlamaConfig):
                              sliding_window=4096, tie_embeddings=True,
                              num_experts=128, top_k=8, norm_topk_prob=True,
                              router_score="sigmoid", shared_experts=4)
+
+    @staticmethod
+    def mistral_small_4() -> "MixtralConfig":
+        """mistralai/Mistral-Small-4-119B-2603's language model
+        (``mistral4``, 119B-A6.5B; the vision encoder is not built): 36
+        sequential RMSNorm blocks, d 4096, 32 heads of latent attention —
+        queries through rank 1,024, keys and values through ONE latent of
+        256 beside one shared rotated key of 64 (scores over 64 + 64, values
+        of 128), pairwise rotary under YaRN (theta 10,000, factor 128 over
+        8,192) and the query temperature ``1 + 0.1 ln(1 + floor(p /
+        8192))`` — 128 softmax-scored SwiGLU experts of width 2,048 top-4
+        renormalised beside one shared expert, an untied head.  The
+        published model: one chip's share of it (``experts_held``, fewer
+        layers, a vocabulary slice) is a deployment's to state."""
+        return MixtralConfig(
+            vocab_size=131072, max_seq_len=1048576, num_layers=36,
+            num_heads=32, num_kv_heads=32, head_width=128, hidden_size=4096,
+            ffn_size=2048, rope_theta=10000.0, rms_eps=1e-6,
+            rope_interleaved=True, q_lora_rank=1024, kv_lora_rank=256,
+            qk_nope_dim=64, qk_rope_dim=64, v_head_dim=128,
+            rope_scaling={"factor": 128.0,
+                          "original_max_position_embeddings": 8192,
+                          "beta_fast": 32.0, "beta_slow": 1.0, "mscale": 1.0,
+                          "mscale_all_dim": 1.0},
+            query_temperature=(0.1, 8192), num_experts=128, top_k=4,
+            norm_topk_prob=True, router_score="softmax", shared_experts=1)
 
     @staticmethod
     def tiny(vocab_size: int = 512) -> "MixtralConfig":
@@ -526,7 +561,8 @@ def forward_cached(cfg: MixtralConfig, params, input_ids, cache, pos,
         attend_fn = functools.partial(_sparse_attend, cfg)
     logits, kv, records, *carried = L.forward_cached(
         cfg, params, input_ids,
-        cache if cfg.layer_kinds else {"k": cache["k"], "v": cache["v"]},
+        cache if cfg.layer_kinds or cfg.latent
+        else {"k": cache["k"], "v": cache["v"]},
         pos, lengths=lengths, block_tables=block_tables,
         mlp_fn=lambda lyr, y: _routed(cfg, lyr, y, live, stacks, choices),
         all_positions=all_positions, attend_fn=attend_fn, extra=extra)
@@ -604,6 +640,8 @@ def build(cfg: Optional[MixtralConfig] = None, **overrides) -> ModelSpec:
         # sampler unchanged (per-slot temperature/top-k/top-p)
         "supports_sampling": True,
     }
+    # latent attention: the pool is ONE leaf, a latent a token
+    decode_hooks.update(L.latent_hook(cfg))
     if cfg.index_heads:
         # learned sparse attention: the cache has a third leaf, and a row
         # past ``topk`` keys reads ``topk`` of them (the engine's counters)

@@ -1504,6 +1504,215 @@ def paged_sparse_attention_pallas(q, k_pool, v_pool, block_tables, scores,
       v_pool).reshape(q.shape)
 
 
+# ---------------------------------------------------------------------------
+# Latent attention (MLA), absorbed: every head's query, taken into latent
+# space, scores the ONE ``[c | k_r]`` tile a block holds, and the value is a
+# slice of the same tile (``ops/paged_kv.py`` "The latent kind").
+# ---------------------------------------------------------------------------
+#: query POSITIONS one grid step of the latent kernel takes (times the
+#: heads: 512 query rows at 32 heads — a ``[512, 384] x [384, bs]`` score
+#: matmul a block, 0.5 MB of float32 scores at 256 keys a block)
+_LATENT_QUERY_TILE = 16
+
+
+def paged_latent_attention_reference(q, pool, block_tables, q_pos, *,
+                                     rank: int, layer=None):
+    """Gather-based absorbed latent attention (pure XLA): the CPU path and
+    the tests' oracle.
+
+    q:            [B, H, T, W] — each head's query in latent space beside
+                  its rotated part, ``[q_n W_uk | q_r]``, zero-padded to the
+                  pool's width, the softmax scale already on it
+    pool:         the latent leaf [L, NB, 1, block_size, W] + ``layer`` (or
+                  one layer's [NB, 1, block_size, W] with ``layer=None``)
+    block_tables: int32 [B, NBPER]
+    q_pos:        scalar or int32 [B] — global position of q[:, :, 0]
+    -> [B, H, T, rank]: ``softmax(q . tile^T) . tile[:, :rank]``, a query
+    at position ``p`` keeping keys ``<= p``."""
+    pool, layer = paged_kv.whole_pool(pool, layer)
+    b, _, t, w = q.shape
+    lat = _paged_gather(pool, jnp.asarray(block_tables, jnp.int32), layer,
+                        w)[:, 0]                                 # [B, S, W]
+    pos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1), (b,))
+    query = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+    keep = jnp.arange(lat.shape[1])[None, None, :] <= query[:, :, None]
+    scores = jnp.einsum("bhtw,bsw->bhts", q, lat).astype(jnp.float32)
+    scores = jnp.where(keep[:, None], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhts,bsc->bhtc", probs, lat[..., :rank])
+
+
+def _paged_latent_kernel(layer_ref, pos_ref, valid_ref, bt_ref, q_ref,
+                         pool_ref, o_ref, buf, sem, m_scr, l_scr, acc_scr, *,
+                         heads: int, tq: int, rank: int):
+    """The latent kind's decode (``T == 1``), verify and prefill kernel.
+    Grid ``(B, T / tq)``: one step is ``tq`` query positions of one row,
+    all ``heads`` of them — ``rows = tq * heads`` query rows, row ``r`` the
+    head ``r % heads`` at window offset ``j * tq + r // heads`` — against
+    the row's VALID blocks, walked as :func:`_paged_walk_kernel` walks them:
+    ``layer_ref`` int32 [1], ``pos_ref`` / ``valid_ref`` int32 [B] (the
+    window's first position, its real queries) and ``bt_ref`` int32 [B,
+    NBPER] by scalar prefetch; the pool stays in HBM and block ``i`` is ONE
+    copy, ``pool.at[layer, bt[b, i]]`` -> a ``[1, bs, W]`` buffer of two
+    slots, the next block in flight while this one is attended.  The tile
+    is read ONCE for both sides: ``q [rows, W] . tile^T`` are the scores of
+    every head (the pad lanes meet zeros), ``p . tile[:, :rank]`` the output
+    in latent space.  A step walks the blocks up to its own last real
+    query, ``cdiv(base + min((j + 1) * tq, valid), bs)`` — none if its
+    queries are all pad; the mask is per query row (``key <= base +
+    offset``).  Matmuls take the pool's dtype in and float32 out."""
+    b, j = pl.program_id(0), pl.program_id(1)
+    layer, base = layer_ref[0], pos_ref[b]
+    bs = buf.shape[2]
+    first = j * tq
+    last = jnp.minimum(first + tq, valid_ref[b]) - 1
+    n = jnp.where(last >= first,
+                  jnp.clip((base + last + bs) // bs, 0, bt_ref.shape[1]), 0)
+
+    def copy(i, slot):
+        return pltpu.make_async_copy(pool_ref.at[layer, bt_ref[b, i]],
+                                     buf.at[slot], sem.at[slot])
+
+    def fetch(i, slot):
+        @pl.when(i < n)
+        def _start():
+            copy(i, slot).start()
+
+    def attend(i, carry):
+        slot = i % 2
+        fetch(i + 1, 1 - slot)
+        copy(i, slot).wait()
+        tile = buf[slot, 0]                                      # [bs, W]
+        s = jax.lax.dot_general(q_ref[0], tile, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        key = i * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(key <= base + first + row // heads, s, NEG_INF)
+        m_prev = m_scr[...][:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)                                   # [rows, bs]
+        l_new = l_scr[...][:, :1] * alpha + jnp.sum(p, axis=-1,
+                                                    keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(tile.dtype), tile[:, :rank], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        return carry
+
+    _start_chunks(m_scr, l_scr, acc_scr)
+    fetch(0, 0)
+    jax.lax.fori_loop(0, n, attend, None)
+    den = l_scr[...][:, :1]
+    o_ref[0] = (acc_scr[...] / jnp.where(den == 0.0, 1.0, den)) \
+        .astype(o_ref.dtype)
+
+
+def latent_kernel_name(t: int) -> str:
+    """The name the latent kernel is launched under for a window of ``t``
+    query positions (one reader sums ``paged_latent_*``)."""
+    return "paged_latent_attn" if t == 1 else \
+        "paged_latent_verify" if t <= VERIFY_T_MAX else "paged_latent_prefill"
+
+
+# one ``pl.pallas_call`` site a name: ``name=latent_kernel_name(t)`` at one
+# site would do for the trace, but
+# ``tests/chipbench/test_program_span_metrics.py`` requires every site's
+# ``name=`` to be a string constant it can read from the source (as
+# ``_paged_decode_call`` / ``_paged_verify_call`` above)
+def _latent_attn_call(kernel, call, operands):
+    return pl.pallas_call(kernel, name="paged_latent_attn", **call)(*operands)
+
+
+def _latent_verify_call(kernel, call, operands):
+    return pl.pallas_call(kernel, name="paged_latent_verify",
+                          **call)(*operands)
+
+
+def _latent_prefill_call(kernel, call, operands):
+    return pl.pallas_call(kernel, name="paged_latent_prefill",
+                          **call)(*operands)
+
+
+_LATENT_CALLS = {"paged_latent_attn": _latent_attn_call,
+                 "paged_latent_verify": _latent_verify_call,
+                 "paged_latent_prefill": _latent_prefill_call}
+
+
+def paged_latent_attention_pallas(q, pool, block_tables, q_pos, *, rank: int,
+                                  layer=None, valid=None,
+                                  interpret: Optional[bool] = None):
+    """:func:`paged_latent_attention_reference`'s contract through
+    :func:`_paged_latent_kernel`, on one shard, for any ``T`` (padded to
+    whole query tiles); ``valid`` int32 [B]: how many of the ``T`` queries
+    are real (default all — a pad query's output is unspecified)."""
+    pool, layer = paged_kv.whole_pool(pool, layer)
+    b, h, t, w = q.shape
+    nb, bs = pool.shape[1], pool.shape[3]
+    assert pool.shape[2] == 1 and pool.shape[4] == w and w % LANES == 0, \
+        f"latent pool {pool.shape} against queries {q.shape}"
+    if interpret is None:
+        interpret = interpret_kernels()
+    tq = min(t, _LATENT_QUERY_TILE)
+    tp = -(-t // tq) * tq
+    rows = tq * h
+    qq = jnp.pad(q.transpose(0, 2, 1, 3), ((0, 0), (0, tp - t), (0, 0),
+                                           (0, 0)))
+    qq = qq.reshape(b, tp * h, w).astype(pool.dtype)
+    pos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1), (b,))
+    nvalid = jnp.full((b,), t, jnp.int32) if valid is None \
+        else jnp.clip(jnp.asarray(valid, jnp.int32), 0, t)
+    bt = jnp.clip(jnp.asarray(block_tables, jnp.int32), 0, nb - 1)
+
+    def tile(lanes):
+        return pl.BlockSpec((1, rows, lanes),
+                            lambda i, j, *prefetched: (i, j, 0))
+
+    call = dict(
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,          # layer, pos, valid, block table
+            grid=(b, tp // tq),
+            in_specs=[tile(w), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=tile(rank),
+            scratch_shapes=[
+                pltpu.VMEM((2, 1, bs, w), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((rows, LANES), jnp.float32),           # m
+                pltpu.VMEM((rows, LANES), jnp.float32),           # l
+                pltpu.VMEM((rows, rank), jnp.float32)]),          # acc
+        out_shape=jax.ShapeDtypeStruct((b, tp * h, rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret)
+    out = _LATENT_CALLS[latent_kernel_name(t)](
+        functools.partial(_paged_latent_kernel, heads=h, tq=tq, rank=rank),
+        call, (jnp.asarray(layer, jnp.int32).reshape(1), pos, nvalid, bt, qq,
+               pool))
+    return out.reshape(b, tp, h, rank)[:, :t].transpose(0, 2, 1, 3)
+
+
+def paged_latent_attention(q, pool, block_tables, q_pos, *, rank: int,
+                           layer=None, valid=None):
+    """Dispatch: the walking kernel on a TPU (decode, verify window and
+    prefill chunk alike, :func:`latent_kernel_name`), gather + XLA
+    otherwise.  One shard over a float pool, outside a resident-window
+    context: the serving engine refuses the rest by name."""
+    if is_quantized_pool(pool) or window_state() is not None \
+            or paged_kv.tp_mesh() is not None or paged_kv.dp_groups() > 1:
+        raise NotImplementedError(
+            "the latent kind is read from a float pool on one shard, "
+            "outside a resident-window context")
+    if on_tpu():
+        _took(latent_kernel_name(q.shape[2]))
+        return paged_latent_attention_pallas(q, pool, block_tables, q_pos,
+                                             rank=rank, layer=layer,
+                                             valid=valid)
+    _took("latent_gather")
+    return paged_latent_attention_reference(q, pool, block_tables, q_pos,
+                                            rank=rank, layer=layer)
+
+
 #: paths :func:`paged_decode_attention` took while a :func:`dispatch_log`
 #: was open — written at TRACE time, like the contexts above
 _DISPATCHED = None

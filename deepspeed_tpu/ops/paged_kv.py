@@ -43,6 +43,35 @@ Layout contract — the WHOLE stacked pool, addressed in place:
    leaves a layer addresses is static in the model's layer loop
    (``models/llama.py:scan_periods_cached``).
 
+ - **The latent kind.**  A model with latent attention (MLA,
+   ``LlamaConfig.kv_lora_rank > 0``) caches no key and no value a head: a
+   token's whole state in a layer is the normed latent ``c`` and the ONE
+   rotated key ``k_r`` every head shares, ``[c | k_r]`` — 256 + 64 = 320
+   values, 640 B in bf16, where the same widths expanded to 32 heads' K
+   and V would be 16,384 B.  Its pool is ONE leaf and no ``k`` / ``v``:
+   ``latent [L, NB, 1, block_size, W]``, written by
+   :func:`paged_window_update` and read by
+   ``ops/decode_attention.paged_latent_attention``, under one block-id
+   space and one table (every layer is of the one kind).  The unit axis
+   where the others have their KV heads is what keeps the contract above
+   — block at dim 1, heads at dim 2 of every leaf — so that packing,
+   donation, swap, prefix sharing and eviction move it by tree as they
+   move any pool; there is nothing on it for ``tp`` to split.  ``W`` is
+   :func:`latent_pool_width`: the token's 320 values zero-padded to 384,
+   three whole lane rows.  A ``[.., bs, 320]`` array is not smaller: XLA's
+   tiled layout pads its minor dim to 384 in HBM all the same, and Mosaic
+   copies out of an HBM operand in whole 128-lane tiles only, so the pad
+   is what both would do, said once.  (Two leaves, ``c`` of 256 and
+   ``k_r`` of 64 lane-packed two tokens a row, would hold the 640 B
+   exactly — at two copies a block and a second, packed tile in the
+   kernel; not built.)  The score reads all 384 lanes of a tile (the pad
+   meets zeros in the query), the value its first 256: one copy a block
+   serves both.  A 768-byte token makes a 32-token block 24 KB, a fifth of
+   the 128 KB the walk was tuned at, and a block visit costs ~0.4 us
+   whatever it moves: a serving engine that is given no ``block_size``
+   takes :func:`latent_block_tokens` for this kind, 512 tokens of 768 B
+   (PERF.md section 6, PR 39, has the table).
+
 **Layout** (what "in place" takes on a TPU).  A Mosaic kernel reads its
 operand row-major — ``[L][NB][HKV][...]``, a block's tiles contiguous —
 and tiles the last two dims (16 x 128 for bf16).  XLA:TPU's own layout for
@@ -352,6 +381,33 @@ def whole_pool(pool, layer):
 
 #: lanes of a TPU vector register: the minor dim a pool's blocks are packed to
 LANES = 128
+
+
+def latent_pool_width(width: int) -> int:
+    """Lanes a token of the latent kind takes in the pool: its ``width``
+    values (``kv_lora_rank + qk_rope_dim``) zero-padded to whole 128-lane
+    rows (module docstring "The latent kind")."""
+    return -(-int(width) // LANES) * LANES
+
+
+#: tokens of the default block of a pool with K and V a head, and the bytes
+#: a layer's block of the latent kind may come to by default: the walk
+#: spends ~0.4 us a block visit whatever the block moves and 0.48 us on
+#: 384 KB of it at 819 GB/s — 43.0 / 12.5 / 7.7 / 5.4 ms a decode execution
+#: at 32 / 128 / 256 / 512 tokens a block (PERF.md section 6, PR 39)
+DEFAULT_BLOCK_TOKENS = 32
+LATENT_BLOCK_BYTES = 1 << 19
+
+
+def latent_block_tokens(width: int, itemsize: int, max_seq_len: int) -> int:
+    """The block a latent pool gets where the caller names none: the
+    largest power of two of tokens whose block stays within
+    ``LATENT_BLOCK_BYTES`` a layer and an eighth of ``max_seq_len`` (a row's
+    last block is half empty in the mean: 256 of ~8,000 keys at 512), and
+    never under the other kinds' default."""
+    fit = min(LATENT_BLOCK_BYTES // (latent_pool_width(width) * int(itemsize)),
+              int(max_seq_len) // 8)
+    return max(DEFAULT_BLOCK_TOKENS, 1 << max(fit, 1).bit_length() - 1)
 
 
 def lane_pack(block_size: int, head_dim: int) -> int:

@@ -685,7 +685,11 @@ OLD_PROGRAMS = {
     "mixtral.decode": "8467a26b00170ab3",
     "mixtral.prefill": "a37b77eef821b5da",
     "olmoe.decode": "d751d15943d60771", "olmoe.prefill": "9cc3b8a954958161",
-    "keye.decode": "b7b2ca0372299f39", "keye.prefill": "2826e865b36a1898"}
+    "keye.decode": "b7b2ca0372299f39", "keye.prefill": "2826e865b36a1898",
+    # Command A+ (layers of two kinds, a table a kind), ON THE PARENT of
+    # PR 39: before a layer could cache a latent
+    "commanda.decode": "1949c22302d0b31b",
+    "commanda.prefill": "cfd7f4cc16d5488d"}
 
 
 def _old_family(name):
@@ -705,6 +709,11 @@ def _old_family(name):
         return mixtral.build(dataclasses.replace(
             mixtral.MixtralConfig.olmoe_1b_7b(), num_heads=2,
             num_kv_heads=2, **small))
+    if name == "commanda":
+        return mixtral.build(dataclasses.replace(
+            mixtral.MixtralConfig.command_a_plus(), num_heads=4,
+            num_kv_heads=2, sliding_window=64, shared_experts=2,
+            **{**small, "num_layers": 4}))
     return mixtral.build(dataclasses.replace(
         mixtral.MixtralConfig.keye_vl2_30b_a3b(), num_heads=4,
         num_kv_heads=2, index_heads=2, index_topk=64, **small))
@@ -713,9 +722,10 @@ def _old_family(name):
 @pytest.mark.parametrize("name", sorted(OLD_PROGRAMS))
 def test_the_old_programs_are_the_old_programs(name, as_on_tpu, one_chip,
                                                monkeypatch):
-    """ISSUE 34: with every new field at its default and ``held=None``,
-    OPT, Mixtral, OLMoE and Keye lower, for a described v5e, to the text
-    they lowered to on the parent (``OLD_PROGRAMS``)."""
+    """ISSUE 34, 39: with every new field at its default (``held=None``;
+    no latent ranks, no rope scaling, no query temperature), OPT, Mixtral,
+    OLMoE, Keye and Command A+ lower, for a described v5e, to the text they
+    lowered to on the parent (``OLD_PROGRAMS``)."""
     import hashlib
     import re
 
@@ -740,9 +750,18 @@ def test_the_old_programs_are_the_old_programs(name, as_on_tpu, one_chip,
         lambda: jax.tree_util.tree_map(
             lambda a: a.astype(jnp.bfloat16),
             spec.init_fn(jax.random.PRNGKey(0)))))
+    kinds, ring = {}, 0
+    if "window_layers" in spec.decode_hooks:
+        # a table a layer kind: the window kind's a ring of 6 blocks a row
+        ring = (64 + 128) // BLOCK
+        kinds = {"window_blocks": 1 + slots * ring}
     pool = jax.tree_util.tree_map(sds, jax.eval_shape(
         lambda: paged_kv.pack_pool(spec.decode_hooks["init_cache"](
-            1 + slots * nbper, BLOCK, jnp.bfloat16))))
+            1 + slots * nbper, BLOCK, jnp.bfloat16, **kinds))))
+
+    def table(rows):
+        return {"full": i32(rows, nbper), "window": i32(rows, ring)} \
+            if ring else i32(rows, nbper)
 
     def decode_step(params, cache, tokens, lengths, bt):
         return fwd(params, tokens[:, None], cache, 0, lengths=lengths,
@@ -754,8 +773,8 @@ def test_the_old_programs_are_the_old_programs(name, as_on_tpu, one_chip,
 
     fn, args = {
         "decode": (decode_step, (params, pool, i32(slots), i32(slots),
-                                 i32(slots, nbper))),
-        "prefill": (prefill, (params, pool, i32(2, 128), i32(2, nbper),
+                                 table(slots))),
+        "prefill": (prefill, (params, pool, i32(2, 128), table(2),
                               i32(2), i32(2)))}[program]
     text = jax.jit(fn, donate_argnums=(1,)).lower(*args).as_text()
     assert "tpu_custom_call" in text
@@ -831,6 +850,112 @@ def test_compiled_two_kind_serving_programs_fit_and_alias_both_pools(
         "paged_prefill_attn": (prefill, (
             params, pool, i32(4, 128), tables(4), i32(4), i32(4)))}
     pool_bytes = sum(int(np.prod(a.shape)) * 2 for a in pool.values())
+    for kernel, (fn, args) in programs.items():
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+        text = compiled.as_text()
+        assert kernel in text and "moe_gmm" in text
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < 1 << 30, (kernel, mem)
+        assert mem.alias_size_in_bytes >= pool_bytes, (kernel, mem)
+
+
+#: the long-decode cell (``mistral4-longdecode-closed``): Mistral Small 4 at
+#: its published widths — 32 heads over a latent of 256 + a rope key of 64
+#: in 384 lanes — 64 slots x 16,384 at the cell's block of 512
+LATENT = dict(slots=64, ctx=16384, heads=32, width=384, rank=256, block=512,
+              layers=6)
+
+
+@pytest.mark.parametrize("block", [32, 256, 512])
+@pytest.mark.parametrize("rows,t", [(64, 1), (4, 128), (64, 4)],
+                         ids=["decode", "prefill-chunk", "verify"])
+def test_latent_walks_compile_at_the_long_decode_cells_shapes(rows, t, block,
+                                                              one_chip):
+    """ISSUE 39: Mosaic's own compile, for a described v5e, of the latent
+    kernel as the decode step, the verify window and the ``[4, 128]``
+    prefill chunk launch it (``paged_latent_attn`` / ``_verify`` /
+    ``_prefill``), at the default block and at the cell's: one ``[block,
+    384]`` tile a copy out of the whole stack where it lies, no
+    temporary."""
+    c = LATENT
+    nbper = c["ctx"] // block
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((c["layers"], 1 + c["slots"] * nbper, 1, block, c["width"]),
+               jnp.bfloat16)
+    lowered = jax.jit(lambda q, p, bt, pos, valid:
+                      da.paged_latent_attention_pallas(
+                          q, p, bt, pos, rank=c["rank"], layer=0,
+                          valid=valid, interpret=False)).lower(
+        sds((rows, c["heads"], t, c["width"]), jnp.bfloat16), pool,
+        sds((rows, nbper), jnp.int32), sds((rows,), jnp.int32),
+        sds((rows,), jnp.int32))
+    text = lowered.as_text()
+    assert "tpu_custom_call" in text and da.latent_kernel_name(t) in text
+    assert lowered.compile().memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_compiled_latent_serving_programs_fit_and_alias_the_pool(
+        as_on_tpu, one_chip, monkeypatch):
+    """The long-decode cell's decode and prefill programs (Mistral Small 4
+    at its published widths, this chip's share: 6 layers, 16 held of 128
+    experts, 16,384 vocabulary rows) compile for a described v5e, alias the
+    ONE pool leaf and hold temporaries under a gigabyte beside 5.75 GB of
+    weights and 4.83 GB of pool."""
+    import json
+    import os
+
+    from chipbench.families import mistral4
+    from deepspeed_tpu.moe import grouped_matmul
+    from deepspeed_tpu.ops import paged_kv
+
+    monkeypatch.setattr(grouped_matmul, "interpret_kernels", lambda: False)
+    root = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir)
+    with open(os.path.join(root, "chipbench", "configs",
+                           "mistral-small-4-119b-2603.json")) as f:
+        config = json.load(f)
+    config.pop("rehearse")
+    spec = mistral4.build(config)
+    fwd = spec.decode_hooks["forward_cached"]
+    c = LATENT
+    nbper = c["ctx"] // c["block"]
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return sds(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    params = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda: jax.tree_util.tree_map(
+            lambda a: a.astype(jnp.bfloat16),
+            spec.init_fn(jax.random.PRNGKey(0)))))
+    pool = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda: paged_kv.pack_pool(spec.decode_hooks["init_cache"](
+            1 + c["slots"] * nbper, c["block"], jnp.bfloat16))))
+    assert set(pool) == {"latent"}
+    assert pool["latent"].shape == (6, 2049, 1, 512, 384)
+
+    def decode_step(params, cache, tokens, lengths, bt):
+        logits, cache, rec = fwd(params, tokens[:, None], cache, 0,
+                                 lengths=lengths, block_tables=bt,
+                                 routing=True)
+        return jnp.argmax(logits, -1).astype(jnp.int32), cache, rec
+
+    def prefill(params, cache, ids, bt, base, valid):
+        logits, cache, rec = fwd(params, ids, cache, base, lengths=valid,
+                                 block_tables=bt, routing=True)
+        return jnp.argmax(logits, -1).astype(jnp.int32), cache, rec
+
+    slots = c["slots"]
+    programs = {
+        "paged_latent_attn": (decode_step, (
+            params, pool, i32(slots), i32(slots), i32(slots, nbper))),
+        "paged_latent_prefill": (prefill, (
+            params, pool, i32(4, 128), i32(4, nbper), i32(4), i32(4)))}
+    pool_bytes = int(np.prod(pool["latent"].shape)) * 2
     for kernel, (fn, args) in programs.items():
         compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
         text = compiled.as_text()

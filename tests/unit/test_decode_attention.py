@@ -1034,3 +1034,125 @@ def test_ring_write_lands_where_the_windowed_read_looks():
     p = np.exp(sc - sc.max(-1, keepdims=True))
     want = np.einsum("hts,hsd->htd", p / p.sum(-1, keepdims=True), vv)
     np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# The latent kind (PR 39): the absorbed walk over a pool of ONE leaf
+# ---------------------------------------------------------------------------
+def _latent_case(block, t, rank=256, width=384, heads=4, seed=0):
+    """Three rows over a latent pool ``[2, NB, 1, block, width]`` (lanes
+    past 320 zero, as the write pads them): one ending mid-block, one whose
+    last query sits on a block's last slot, one idle (all-scratch table,
+    position 0)."""
+    from deepspeed_tpu.ops import decode_attention as da
+
+    rng = np.random.default_rng(seed)
+    nbper = 4
+    pool = rng.standard_normal((2, 1 + 3 * nbper, 1, block, width))
+    pool[..., 320:] = 0
+    q = rng.standard_normal((3, heads, t, width)) * 0.1
+    q[..., 320:] = 0
+    bt = 1 + np.arange(3 * nbper).reshape(3, nbper)
+    bt[2] = 0
+    pos = np.asarray([block + block // 2 - t // 2, 3 * block - t, 0])
+    return (da, jnp.asarray(q, jnp.float32), jnp.asarray(pool, jnp.float32),
+            jnp.asarray(bt, jnp.int32), jnp.asarray(pos, jnp.int32), rank)
+
+
+def _latent_naive(q, pool, bt, pos, layer, rank):
+    """float64, key by key: every head scores the row's ``[c | k_r]``
+    vectors and takes the softmax-weighted sum of their first ``rank``."""
+    q, pool = np.asarray(q, np.float64), np.asarray(pool, np.float64)
+    b, h, t, w = q.shape
+    out = np.zeros((b, h, t, rank))
+    for r in range(b):
+        keys = pool[layer, np.asarray(bt[r])].reshape(-1, w)
+        for i in range(t):
+            n = int(pos[r]) + i + 1
+            s = q[r, :, i] @ keys[:n].T
+            p = np.exp(s - s.max(-1, keepdims=True))
+            out[r, :, i] = (p / p.sum(-1, keepdims=True)) @ keys[:n, :rank]
+    return out
+
+
+@pytest.mark.parametrize("block", [32, 256])
+@pytest.mark.parametrize("t", [1, 4, 40], ids=["decode", "verify",
+                                               "prefill-chunk"])
+def test_latent_walk_equals_its_reference(block, t):
+    """``paged_latent_*`` (interpreted) and the XLA reference against a
+    key-by-key softmax, at block sizes 32 and 256: rows ending mid-block
+    and at a block's edge; an idle row stays finite."""
+    da, q, pool, bt, pos, rank = _latent_case(block, t)
+    want = _latent_naive(q, pool, bt, pos, 1, rank)
+    ref = da.paged_latent_attention_reference(q, pool, bt, pos, rank=rank,
+                                              layer=1)
+    got = da.paged_latent_attention_pallas(q, pool, bt, pos, rank=rank,
+                                           layer=1, interpret=True)
+    np.testing.assert_allclose(ref[:2], want[:2], atol=2e-5)
+    np.testing.assert_allclose(got[:2], want[:2], atol=2e-5)
+    assert np.isfinite(np.asarray(got)).all()
+
+
+def test_latent_walk_stops_at_a_rows_real_queries():
+    """``valid``: a chunk's pad queries cost no block and their tiles are
+    not walked — a tile wholly past ``valid`` comes back zero — while the
+    real queries read what they read without it."""
+    da, q, pool, bt, pos, rank = _latent_case(32, 40)
+    valid = jnp.asarray([40, 17, 0], jnp.int32)
+    want = _latent_naive(q, pool, bt, pos, 0, rank)
+    got = np.asarray(da.paged_latent_attention_pallas(
+        q, pool, bt, pos, rank=rank, layer=0, valid=valid, interpret=True))
+    np.testing.assert_allclose(got[0], want[0], atol=2e-5)
+    np.testing.assert_allclose(got[1, :, :17], want[1, :, :17], atol=2e-5)
+    assert np.all(got[1, :, 32:] == 0) and np.all(got[2] == 0)
+
+
+def test_latent_write_lands_where_the_walk_reads():
+    """``paged_window_update`` puts a chunk's ``[c | k_r]`` at ``(layer,
+    block, offset)`` of the one leaf, across a block edge and for the real
+    tokens only; the walk then reads exactly those keys."""
+    from deepspeed_tpu.ops import paged_kv
+
+    da, q, pool, bt, _, rank = _latent_case(32, 1)
+    pool = jnp.zeros_like(pool)
+    rng = np.random.default_rng(3)
+    new = np.zeros((3, 1, 40, 384), np.float32)
+    new[..., :320] = rng.standard_normal((3, 1, 40, 320))
+    base = jnp.asarray([20, 0, 0], jnp.int32)
+    valid = jnp.asarray([40, 9, 0], jnp.int32)
+    pool = paged_kv.paged_window_update(pool, jnp.asarray(new), base, bt,
+                                        valid=valid, layer=1)
+    flat = np.asarray(pool[1, np.asarray(bt[0])]).reshape(-1, 384)
+    np.testing.assert_array_equal(flat[20:60], new[0, 0])
+    assert not flat[:20].any() and not flat[60:].any()
+    row1 = np.asarray(pool[1, np.asarray(bt[1])]).reshape(-1, 384)
+    np.testing.assert_array_equal(row1[:9], new[1, 0, :9])
+    assert not row1[9:].any() and not np.asarray(pool[0]).any()
+    got = da.paged_latent_attention_pallas(
+        q, pool, bt, jnp.asarray([59, 8, 0], jnp.int32), rank=rank, layer=1,
+        interpret=True)
+    want = _latent_naive(q, pool, bt, [59, 8, 0], 1, rank)
+    np.testing.assert_allclose(got[:2], want[:2], atol=2e-5)
+
+
+def test_dispatcher_sends_the_latent_read_to_its_kernel_on_a_tpu(monkeypatch):
+    """On a TPU every window width takes ``paged_latent_*`` (named by the
+    width); on the CPU the gather; a tp context is refused by name."""
+    from deepspeed_tpu.ops import paged_kv
+
+    da, q, pool, bt, pos, rank = _latent_case(32, 1)
+    with da.dispatch_log() as paths:
+        da.paged_latent_attention(q, pool, bt, pos, rank=rank, layer=0)
+    assert paths == {"latent_gather"}
+    monkeypatch.setattr(da, "on_tpu", lambda: True)
+    for t, name in ((1, "paged_latent_attn"), (4, "paged_latent_verify"),
+                    (40, "paged_latent_prefill")):
+        assert da.latent_kernel_name(t) == name
+    with da.dispatch_log() as paths:
+        da.paged_latent_attention(q, pool, bt, pos, rank=rank, layer=0)
+    assert paths == {"paged_latent_attn"}
+    with paged_kv.tp_context(object()):
+        with pytest.raises(NotImplementedError, match="one shard"):
+            da.paged_latent_attention(q, pool, bt, pos, rank=rank, layer=0)
+    assert paged_kv.latent_pool_width(320) == 384
+    assert paged_kv.latent_pool_width(256) == 256

@@ -63,6 +63,10 @@ ENGINE_STATS_KEYS = frozenset({
     # PR 34: a model that mixes sliding-window and full layers: its two
     # pools by kind, the reach counters, the refusals (None otherwise)
     "kv_kinds",
+    # PR 39: a model with latent attention: the pool's kind, a token's
+    # width and bytes, the block, the reads' paths, the counters, the
+    # refusals (None otherwise)
+    "kv_latent",
     # PR 28: routed (token, expert) rows and experts touched, summed over
     # layers and program calls; 0 for a dense model
     "moe_expert_rows", "moe_experts_touched",
