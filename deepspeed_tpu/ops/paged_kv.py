@@ -98,12 +98,25 @@ bt[b, i]]`` for ``i < cdiv(pos + 1, block_size)``, one DMA a block and
 side, the next block in flight while this one is attended.  A table entry
 past a row's valid prefix is never read, and a row costs its own length,
 not ``max_seq_len``.  Two more things keep it so: the write reads, merges and
-scatters back WHOLE blocks (:func:`_write_blocks`) — index dims (layer,
-block), the pool's two major dims, so row-major is the layout that scatter
-wants too, where a scatter of token vectors at ``[layer, phys, :, off]``
-also indexes the in-block offset and has XLA re-lay-out the pool around
-it — and nothing but that write, the gathers and the kernels ever takes the
-pool as an operand.  Every op reads the packing off the shapes (pool minor
+scatters back WHOLE blocks (:func:`_write_blocks`), in the stored view:
+token offset o of a block is row ``o % (bs/g)``, lane group ``o // (bs/g)``,
+and the window's tokens are laid into those lanes under a mask, so a
+gathered block is never brought to token order (PR 41: un-packing and
+re-packing the touched blocks was ten half-lane passes a layer, 3.85 ms of
+a 24-row decode step at hd 64 where the merge in place takes 1.05) — index
+dims (layer, block), the pool's two major dims, so row-major is the layout
+that scatter wants too, where a scatter of token vectors at ``[layer, phys,
+:, off]`` also indexes the in-block offset and has XLA re-lay-out the pool
+around it — and nothing but that write, the gathers and the kernels ever
+takes the pool as an operand.  (Row-granular writes were tried again in
+PR 41 and refused: a decode row touches ONE stored 128-lane row a head, 1/16
+of the block, but ``leaf.at[layer, phys, :, r].set(..)`` compiles, for a
+described v5e at the chat cell's shapes, to four pool-sized copies a layer
+and 2.4 GB of temporaries, and ran 29.9 ms where whole blocks run 1.05.  The
+whole-block write is three passes over the touched blocks — gather, merge,
+scatter — ~1.0 ms against the 0.36 ms its bytes take; under that lies only
+writing the row from inside the walk kernel, the pool aliased in and out.)
+Every op reads the packing off the shapes (pool minor
 dim over the model's head dim), so a pool exactly as ``init_cache`` built
 it — the benchmark's teacher-forced comparison passes one — goes through
 the same code with ``g = 1`` (and, on a TPU, through whatever copies XLA's
@@ -452,18 +465,6 @@ def _unpack_block(blocks, head_dim: int):
         .reshape(*lead, rows * g, head_dim)
 
 
-def _pack_block(blocks, like):
-    """Inverse of :func:`_unpack_block`: ``[..., bs, hd]`` into the
-    ``[..., R, W]`` view of the stored block ``like``."""
-    *lead, bs, hd = blocks.shape
-    rows, width = like.shape[-2:]
-    g = width // hd
-    if g == 1:
-        return blocks
-    return blocks.reshape(*lead, g, rows, hd).swapaxes(-3, -2) \
-        .reshape(*lead, rows, width)
-
-
 def _write_blocks(leaf, win, layer, phys, start, nvalid):
     """Write a [B, HKV, T, ...] window into one stacked pool array
     ``[L, NB, HKV, ...]`` (payload in either view, or a scale table) by
@@ -472,7 +473,14 @@ def _write_blocks(leaf, win, layer, phys, start, nvalid):
     offset 0 of each (may be negative); tokens outside ``[0, nvalid[b])``
     keep what the block held.  Three ops: gather the ``[B, J]`` touched
     blocks at ``[layer, phys]``, merge the rows' tokens in, scatter the
-    blocks back.  The scatter's index dims are the pool's two MAJOR dims
+    blocks back.  The merge happens in the view the pool STORES — a block
+    ``[R, W]`` of ``g = W // hd`` lane groups holds token offset ``o`` at
+    row ``o % R``, lane group ``o // R`` — so the gathered blocks are never
+    brought to token order: the window is tiled over the lanes once and
+    lane group ``q`` takes tokens ``start + q * R + r`` under its lanes'
+    mask (``g = 1``: one group, no mask; a one-token window needs no gather
+    of its own, its token is broadcast over the block under the mask).
+    The scatter's index dims are the pool's two MAJOR dims
     (layer, block) and its window a whole block, so the pool's row-major
     layout is the one it wants and a loop-carried pool is updated in place
     — where a scatter of token vectors at ``[layer, phys, :, off]`` indexes
@@ -482,18 +490,32 @@ def _write_blocks(leaf, win, layer, phys, start, nvalid):
     any order will do."""
     t = win.shape[2]
     tok = win.shape[3:]                       # (hd,) payload, () scales
-    bs = int(np.prod(leaf.shape[3:])) // int(np.prod(tok, dtype=np.int64))
-    ti = start[:, :, None] + jnp.arange(bs, dtype=jnp.int32)     # [B, J, bs]
-    take = (ti >= 0) & (ti < nvalid[:, None, None])
-    # new[b, j, :, o] = win[b, :, ti[b, j, o]]: [B, J, bs, HKV, ...]
-    new = win[jnp.arange(win.shape[0])[:, None, None], :,
-              jnp.clip(ti, 0, t - 1)]
-    new = jnp.moveaxis(new, 2, 3).astype(leaf.dtype)   # [B, J, HKV, bs, ..]
-    old = leaf[layer, phys]                            # [B, J, HKV, R, W]
-    take = take.reshape(take.shape[:2] + (1, bs) + (1,) * len(tok))
-    blk = jnp.where(take, new, _unpack_block(old, tok[0]) if tok else old)
-    if tok:
-        blk = _pack_block(blk, old)
+    rows = leaf.shape[3]                      # R: bs // g
+    g = leaf.shape[4] // tok[0] if tok else 1
+    if g > 1:
+        win = jnp.tile(win, g)                # a copy of the token a group
+        group = jnp.arange(leaf.shape[4], dtype=jnp.int32) // tok[0]
+
+    def tokens(q):
+        """(take [B, J, R], new [B, J | 1, HKV, R | 1, ...]) of lane group q"""
+        ti = start[:, :, None] + jnp.arange(q * rows, (q + 1) * rows,
+                                            dtype=jnp.int32)
+        take = (ti >= 0) & (ti < nvalid[:, None, None])
+        if t == 1:
+            return take, win[:, None].astype(leaf.dtype)
+        new = win[jnp.arange(win.shape[0])[:, None, None], :,
+                  jnp.clip(ti, 0, t - 1)]              # [B, J, R, HKV, ...]
+        return take, jnp.moveaxis(new, 2, 3).astype(leaf.dtype)
+
+    # the window's contribution first, the blocks after: the order g = 1
+    # has always traced in (its lowered text is pinned, test_chip_lowering)
+    groups = [tokens(q) for q in range(g)]
+    blk = leaf[layer, phys]                   # [B, J, HKV, R, W] as stored
+    for q, (take, new) in enumerate(groups):
+        take = take.reshape(take.shape[:2] + (1, rows) + (1,) * len(tok))
+        if g > 1:
+            take = take & (group == q)
+        blk = jnp.where(take, new, blk)
     return leaf.at[layer, phys].set(blk)
 
 

@@ -391,6 +391,65 @@ def test_compiled_serving_programs_hold_no_pool_sized_temporary(
         assert mem.alias_size_in_bytes >= unpadded, (name, mem)
 
 
+def _narrow_block_passes(text, blocks):
+    """``copy`` / ``transpose`` instructions of a compiled program that
+    hold as many elements as the write's gathered ``blocks`` ``[B, J, HKV,
+    R, W]`` under a minor dim narrower than the 128 lanes: blocks being
+    brought to token order ``[.., bs, hd]`` or back."""
+    import re
+
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s+(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                     r"(copy|transpose)\(", line)
+        if not m:
+            continue
+        dims = [int(d) for d in m.group(1).split(",")]
+        if int(np.prod(dims)) == int(np.prod(blocks)) and dims[-1] < 128:
+            found.append(line.strip()[:120])
+    return found
+
+
+def test_compiled_write_never_brings_a_block_to_token_order(
+        smoke, as_on_tpu, one_chip):
+    """ISSUE 41: the chat cell's decode and prefill programs at hd 64 (two
+    tokens a lane row), compiled for a described v5e, merge the window's
+    tokens in the blocks' STORED view.  The parent (PR 39) un-packed the
+    gathered blocks to token order and packed the merged ones again: this
+    count read **8 in decode** (six ``copy`` kernels a layer —
+    ``[24,1,32,2,16,64]``, ``[24,1,32,32,64]``, ``[24,1,32,16,2,64]``, for K
+    and for V, beside four ``reshape`` kernels — and the window's two
+    token-order gathers ``[24,32,32,64]``) and **6 in prefill**
+    (``[4,5,32,2,16,64]``, ``[4,5,32,16,2,64]``, ``[4,5,32,32,64]``).  Now
+    0 and 0; the pool is still aliased whole and no temporary comes near a
+    layer's slice of it.  (What a chunk keeps: four full-lane passes a
+    layer, the gathered blocks to the window gather's ``[.., R, HKV, W]``
+    order and back, as a 128-wide head always had them.)"""
+    from deepspeed_tpu.models import opt
+
+    progs = smoke.serving_programs(opt.OPTConfig(**OPT13B), one_chip)
+    payload = smoke.pool_payload_struct(progs["prefill"][1][1])
+    assert payload.shape[-2:] == (smoke.CHAT_BLOCK // 2, 128)     # g = 2
+    layer_slice = int(np.prod(payload.shape[1:])) * payload.dtype.itemsize
+    for name in ("decode_step", "prefill"):
+        fn, args = progs[name]
+        compiled, copies = smoke.compile_serving_program(fn, args)
+        rows, t = args[2].shape if name == "prefill" else (*args[2].shape, 1)
+        bs = smoke.CHAT_BLOCK
+        nj = 1 if t == 1 else (t + bs - 2) // bs + 1    # _window_blocks
+        blocks = (rows, nj) + payload.shape[2:]
+        text = compiled.as_text()
+        # not vacuous: the touched blocks are gathered, as they are stored
+        gathered = ",".join(map(str, (rows * nj,) + blocks[2:]))
+        assert f"bf16[{gathered}]" in text, name
+        assert _narrow_block_passes(text, blocks) == [], name
+        mem = compiled.memory_analysis()
+        assert not copies, (name, copies[:2])
+        assert mem.temp_size_in_bytes < layer_slice // 4, name
+        assert mem.alias_size_in_bytes >= 2 * int(np.prod(payload.shape)) \
+            * payload.dtype.itemsize, (name, mem)
+
+
 @pytest.mark.parametrize("kv8", [False, True], ids=["bf16", "kv8"])
 @pytest.mark.parametrize("cell,slots,h,hd,ctx,layers", CELL_SHAPES)
 def test_paged_walk_compiles_at_the_cells_shapes(cell, slots, h, hd, ctx,
@@ -677,18 +736,23 @@ def test_window_walks_compile_at_the_rag_chat_cells_shapes(kind, rows, t,
 #: sha256 (first 16 hex digits) of the decode / prefill programs of the
 #: families the benchmark served before layers had kinds and experts could
 #: be held, lowered for a described v5e at the small shapes of
-#: ``_old_family_programs`` ON THE PARENT of PR 34 — each Mosaic kernel's
-#: payload masked: it embeds the source lines of ``ops/decode_attention.py``
-#: / ``moe/routed.py``, which moved
+#: ``_old_family_programs`` — each Mosaic kernel's payload masked: it embeds
+#: the source lines of ``ops/decode_attention.py`` / ``moe/routed.py``,
+#: which moved.  The two ``*.prefill`` pins of the hd-128 families are still
+#: the text of the PARENT of PR 34 (OLMoE) / PR 39 (Command A+: layers of
+#: two kinds, a table a kind): at ``g = 1`` the write of PR 41 emits the op
+#: sequence the old one did.  The other eight are taken on the tree of
+#: PR 41: ``opt.*`` / ``mixtral.*`` (tiny configs, hd 16: g = 8) and
+#: ``keye.*`` (its 64-wide indexer leaf: g = 2) merge in the stored view,
+#: and every ``*.decode`` — a one-token window is broadcast over its block,
+#: at g = 1 too, where the parent gathered it row by row
 OLD_PROGRAMS = {
-    "opt.decode": "78eabd77c60910d4", "opt.prefill": "53fd52642e7e55d8",
-    "mixtral.decode": "8467a26b00170ab3",
-    "mixtral.prefill": "a37b77eef821b5da",
-    "olmoe.decode": "d751d15943d60771", "olmoe.prefill": "9cc3b8a954958161",
-    "keye.decode": "b7b2ca0372299f39", "keye.prefill": "2826e865b36a1898",
-    # Command A+ (layers of two kinds, a table a kind), ON THE PARENT of
-    # PR 39: before a layer could cache a latent
-    "commanda.decode": "1949c22302d0b31b",
+    "opt.decode": "b52e4bc5d86a603e", "opt.prefill": "0fe6ed036104ea84",
+    "mixtral.decode": "6479cf5460fbfff5",
+    "mixtral.prefill": "44cd3dadda841e0a",
+    "olmoe.decode": "e9cbda30c55880c6", "olmoe.prefill": "9cc3b8a954958161",
+    "keye.decode": "b594d5fea767ffda", "keye.prefill": "b14058bd110ef1a1",
+    "commanda.decode": "dae34abf7d70a110",
     "commanda.prefill": "cfd7f4cc16d5488d"}
 
 
@@ -722,10 +786,10 @@ def _old_family(name):
 @pytest.mark.parametrize("name", sorted(OLD_PROGRAMS))
 def test_the_old_programs_are_the_old_programs(name, as_on_tpu, one_chip,
                                                monkeypatch):
-    """ISSUE 34, 39: with every new field at its default (``held=None``;
+    """ISSUE 34, 39, 41: with every new field at its default (``held=None``;
     no latent ranks, no rope scaling, no query temperature), OPT, Mixtral,
-    OLMoE, Keye and Command A+ lower, for a described v5e, to the text they
-    lowered to on the parent (``OLD_PROGRAMS``)."""
+    OLMoE, Keye and Command A+ lower, for a described v5e, to the text
+    pinned in ``OLD_PROGRAMS`` (which says on which tree each was taken)."""
     import hashlib
     import re
 

@@ -303,14 +303,17 @@ def test_what_the_selection_does_not_serve_is_refused_by_name(model):
 
 
 # ------------------------------------------- Mixtral and OLMoE are untouched
-#: sha256[:16] of the lowered (StableHLO) paged decode and prefill programs
-#: at the PARENT of PR 32 (d7d610f), produced by this very function with the
-#: parent tree first on ``sys.path`` (jax 0.9.0, CPU lowering)
+#: sha256[:16] of the lowered (StableHLO) paged decode and prefill programs,
+#: produced by this very function (jax 0.9.0, CPU lowering).  Taken at the
+#: PARENT of PR 32 (d7d610f) and held by every tree up to PR 39; re-taken on
+#: the tree of PR 41, whose paged write merges its tokens in the blocks'
+#: stored view — both families pack here (hd 16 under a block of 8: g = 8),
+#: so the write's ops, and nothing else of these programs, changed
 PARENT_PROGRAMS = {
-    ("mixtral", "decode"): "47a8a448d030698c",
-    ("mixtral", "prefill"): "fcbb9a5b2ce5d218",
-    ("olmoe", "decode"): "4de5dc6d794daf2f",
-    ("olmoe", "prefill"): "d0539122b392c362",
+    ("mixtral", "decode"): "6d9129a516dc3e00",
+    ("mixtral", "prefill"): "69c586494d97f42d",
+    ("olmoe", "decode"): "d23a643b78f53ee3",
+    ("olmoe", "prefill"): "64dfd51e482ebbf7",
 }
 OLD_FAMILIES = {
     "mixtral": mixtral.MixtralConfig.tiny(),
