@@ -80,8 +80,9 @@ def enable_compile_cache(checkout_root: str) -> str:
     """Turn on JAX's persistent compilation cache and return its directory.
 
     Called by the scripts that run on the chip (``chip_smoke.py``,
-    ``chipbench/run.py``) before their first compile — never at package
-    import.
+    ``chipbench/run.py``) and by the test harness (``tests/conftest.py``,
+    whose root is a directory under the system's temp directory) before
+    their first compile — never at package import.
     Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already uses it and this
     sets no other directory; otherwise the cache lives at the fixed,
     git-ignored ``<checkout_root>/.jax_cache`` (the path is part of the

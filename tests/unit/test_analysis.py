@@ -684,15 +684,6 @@ def test_sentry_abstract_signature_distinguishes_dtype_and_statics():
     assert d and "int32" in d[0] and "float32" in d[0]
 
 
-@pytest.fixture(scope="module")
-def tiny_engine():
-    deepspeed_tpu.comm.reset_topology()
-    cfg = gpt2.GPT2Config.tiny(max_seq_len=128)
-    return deepspeed_tpu.init_inference(
-        gpt2.build(cfg),
-        config={"dtype": "fp32", "tensor_parallel": {"tp_size": 1}}), cfg
-
-
 def _mixed_trace(cfg, n, seed):
     rng = np.random.default_rng(seed)
     return [Request(uid=i, prompt=rng.integers(0, cfg.vocab_size,

@@ -19,6 +19,7 @@ from deepspeed_tpu.inference.serving import Request
 from deepspeed_tpu.models import mixtral
 from deepspeed_tpu.moe import routed
 from deepspeed_tpu.ops import paged_kv
+from tiny import assert_greedy
 
 WINDOW, BLOCK, CHUNK = 24, 8, 16
 HELD = (4, 4)
@@ -61,15 +62,11 @@ def model():
     return cfg, spec, params
 
 
-def _greedy(config, params, prompt, n):
-    ids, out = list(prompt), []
-    for _ in range(n):
-        lg = np.asarray(ref.logits(config, params,
-                                   np.asarray(ids, np.int32)[None],
-                                   at=[len(ids) - 1]))
-        out.append(int(lg[0, 0].argmax()))
-        ids.append(out[-1])
-    return out
+def _exact(cfg, params, reqs, out):
+    """Every served token is the reference's greedy one (``tiny.py``: one
+    teacher-forced call over prompt + output, not a roll-out)."""
+    assert_greedy(lambda ids: ref.logits(_config(cfg), params, ids), reqs,
+                  out)
 
 
 def test_uncached_forward_equals_the_reference(model):
@@ -100,7 +97,8 @@ def test_paged_prefill_and_decode_equal_the_reference_across_a_wrapped_ring(
                               jnp.int32),
           "window": jnp.asarray(1 + np.arange(b * ring).reshape(b, ring),
                                 jnp.int32)}
-    fwd, got, at = hooks["forward_cached"], [], []
+    # (jitted: a program a shape, not a compile an op)
+    fwd, got, at = jax.jit(hooks["forward_cached"]), [], []
     for base in range(0, 48, CHUNK):
         lg, cache = fwd(params, jnp.asarray(toks[:, base:base + CHUNK]),
                         cache, jnp.full((b,), base, jnp.int32),
@@ -134,9 +132,7 @@ def test_engine_serves_it_token_exact_and_the_ring_releases_blocks(model):
     released blocks behind the rows, and ``stats()`` names both pools."""
     cfg, spec, params = model
     srv, reqs, out = _serve(spec, params, [70, 33, 50, 9])
-    for r in reqs:
-        assert [int(t) for t in out[r.uid][len(r.prompt):]] \
-            == _greedy(_config(cfg), params, r.prompt, 12), r.uid
+    _exact(cfg, params, reqs, out)
     st = srv.stats()
     kinds = st["kv_kinds"]
     assert kinds["full"]["layers"] == 1 and kinds["sliding"]["layers"] == 3
@@ -170,9 +166,7 @@ def test_preempted_row_past_its_window_is_readmitted_token_exact(model):
     srv, reqs, out = _serve(spec, params, [60, 58, 62], new=30,
                             num_blocks=1 + 28)
     assert srv.stats()["evicted"] > 0
-    for r in reqs:
-        assert [int(t) for t in out[r.uid][len(r.prompt):]] \
-            == _greedy(_config(cfg), params, r.prompt, 30), r.uid
+    _exact(cfg, params, reqs, out)
     assert srv.stats()["kv_kinds"]["sliding"]["blocks_in_use"] == 0
 
 

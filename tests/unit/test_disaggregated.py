@@ -22,21 +22,11 @@ from deepspeed_tpu.analysis.invariants import (PagedStateError,
 from deepspeed_tpu.inference.paged import (HostBlockStore, NvmeBlockStore,
                                            block_checksum)
 from deepspeed_tpu.inference.serving import Request, ServingEngine
-from deepspeed_tpu.models import gpt2
 from deepspeed_tpu.serving import ReplicaRouter, plan_roles
+from tiny import sequential
 
 
 # ---------------------------------------------------------------- fixtures
-@pytest.fixture(scope="module")
-def tiny():
-    cfg = gpt2.GPT2Config.tiny(max_seq_len=128)
-    spec = gpt2.build(cfg)
-    deepspeed_tpu.comm.reset_topology()
-    engine = deepspeed_tpu.init_inference(
-        spec, config={"dtype": "fp32", "tensor_parallel": {"tp_size": 1}})
-    return spec, cfg, engine
-
-
 _SRV_KW = dict(slots=3, max_seq_len=64, block_size=8, prefill_chunk=16,
                prefill_batch=2, debug_checks=True)
 
@@ -55,12 +45,6 @@ def _trace(cfg, n=8, seed=0, prompt_len=24, max_new=8):
     return [Request(uid=i,
                     prompt=rng.integers(0, cfg.vocab_size, prompt_len),
                     max_new_tokens=max_new) for i in range(n)]
-
-
-def _sequential(engine, reqs):
-    return {r.uid: engine.generate(r.prompt[None, :],
-                                   max_new_tokens=r.max_new_tokens)[0]
-            for r in reqs}
 
 
 def _run(router, reqs):
@@ -103,7 +87,7 @@ def test_disaggregated_token_parity_and_handoffs(tiny):
     it; the audit stays green throughout (debug_checks on)."""
     spec, cfg, engine = tiny
     reqs = _trace(cfg, n=8)
-    seq = _sequential(engine, reqs)
+    seq = sequential(engine, reqs)
 
     colo = ReplicaRouter([_mk_srv(spec, engine.params) for _ in range(2)],
                          debug_checks=True)
@@ -201,7 +185,7 @@ def test_nvme_session_resume_zero_prefix_recompute(tiny):
     sequential run."""
     spec, cfg, engine = tiny
     reqs = _trace(cfg, n=8, prompt_len=32, max_new=6)
-    seq = _sequential(engine, reqs)
+    seq = sequential(engine, reqs)
     srv = _mk_srv(spec, engine.params, **_NVME_KW)
     out = srv.serve(reqs)
     for r in reqs:

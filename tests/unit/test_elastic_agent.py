@@ -9,6 +9,8 @@ import pytest
 
 from deepspeed_tpu.elasticity.elastic_agent import ElasticAgent
 
+pytestmark = pytest.mark.usefixtures("workers_reaped")
+
 CFG = {"elasticity": {"enabled": True, "max_train_batch_size": 16,
                       "micro_batch_sizes": [1, 2], "min_gpus": 1,
                       "max_gpus": 16, "min_time": 0,

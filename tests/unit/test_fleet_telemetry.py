@@ -42,16 +42,6 @@ from deepspeed_tpu.telemetry.aggregate import FLEET_LABEL
 
 
 # ---------------------------------------------------------------- fixtures
-@pytest.fixture(scope="module")
-def tiny():
-    cfg = gpt2.GPT2Config.tiny(max_seq_len=128)
-    spec = gpt2.build(cfg)
-    deepspeed_tpu.comm.reset_topology()
-    engine = deepspeed_tpu.init_inference(
-        spec, config={"dtype": "fp32", "tensor_parallel": {"tp_size": 1}})
-    return spec, cfg, engine
-
-
 def _mk_engine(spec, params):
     return deepspeed_tpu.init_inference(
         spec, config={"dtype": "fp32", "tensor_parallel": {"tp_size": 1}},

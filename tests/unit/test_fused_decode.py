@@ -24,18 +24,8 @@ Tier-1 (fast) CPU-sim coverage:
 import numpy as np
 import pytest
 
-import deepspeed_tpu
 from deepspeed_tpu.inference.serving import Request, ServingEngine
-from deepspeed_tpu.models import gpt2
-
-
-@pytest.fixture(scope="module")
-def tiny_engine():
-    deepspeed_tpu.comm.reset_topology()
-    cfg = gpt2.GPT2Config.tiny(max_seq_len=128)
-    return deepspeed_tpu.init_inference(
-        gpt2.build(cfg),
-        config={"dtype": "fp32", "tensor_parallel": {"tp_size": 1}}), cfg
+from tiny import assert_sequential
 
 
 def _trace(cfg, n, prefix_len=24, seed=0, tail=(3, 10), max_new=(2, 12)):
@@ -74,11 +64,7 @@ def test_fused_parity_chunked_and_fence_accounting(tiny_engine):
     sK = ServingEngine(engine, decode_steps=4, **kw)
     rK = sK.serve(_fresh(reqs))
     _assert_same(r1, rK, reqs)
-    for r in reqs:
-        want = engine.generate(r.prompt[None, :],
-                               max_new_tokens=r.max_new_tokens)[0]
-        np.testing.assert_array_equal(rK[r.uid], want,
-                                      err_msg=f"uid {r.uid}")
+    assert_sequential(engine, reqs, rK)
     st1, stK = s1.stats(), sK.stats()
     # fused REPLACES the per-token program: same budget, no extra compile
     assert stK["compile_count"] == 2 == st1["compile_count"]
@@ -109,12 +95,7 @@ def test_fused_parity_eos_inside_window(tiny_engine):
     eos = int(probe[0, len(reqs[0].prompt)])   # fires on request 0's 1st
     sK = ServingEngine(engine, decode_steps=8, **kw)
     rK = sK.serve(reqs, eos_token_id=eos)
-    for r in reqs:
-        want = engine.generate(r.prompt[None, :],
-                               max_new_tokens=r.max_new_tokens,
-                               eos_token_id=eos)[0]
-        np.testing.assert_array_equal(rK[r.uid], want,
-                                      err_msg=f"uid {r.uid}")
+    assert_sequential(engine, reqs, rK, eos_token_id=eos)
     # request 0's FIRST generated token is eos — the stop fired at
     # iteration 0 of an 8-wide window (mid-window, not at the fence
     # boundary), and the post-eos fill matches generate's contract
@@ -148,11 +129,7 @@ def test_fused_parity_tiered_host_kv(tiny_engine):
     sK = ServingEngine(engine, decode_steps=4, **kw)
     rK = sK.serve(_fresh(reqs))
     _assert_same(r1, rK, reqs)
-    for r in reqs:
-        want = engine.generate(r.prompt[None, :],
-                               max_new_tokens=r.max_new_tokens)[0]
-        np.testing.assert_array_equal(rK[r.uid], want,
-                                      err_msg=f"uid {r.uid}")
+    assert_sequential(engine, reqs, rK)
     st = sK.stats()
     assert st["swap_out"] > 0 and st["swap_in"] > 0
     assert st["compile_count"] == 4       # base 2 + demote + promote
@@ -172,11 +149,7 @@ def test_fused_preemption_at_fence_keeps_parity(tiny_engine):
     res = srv.serve(reqs)
     assert srv.preempted > 0, srv.stats()  # pressure actually happened
     assert set(res) == set(range(5))
-    for r in reqs:
-        want = engine.generate(r.prompt[None, :],
-                               max_new_tokens=r.max_new_tokens)[0]
-        np.testing.assert_array_equal(res[r.uid], want,
-                                      err_msg=f"uid {r.uid}")
+    assert_sequential(engine, reqs, res)
 
 
 def test_spec_dispatch_wins_over_decode_steps(tiny_engine):

@@ -18,6 +18,7 @@ import deepspeed_tpu
 from deepspeed_tpu.inference.serving import Request
 from deepspeed_tpu.models import llama, mixtral
 from deepspeed_tpu.ops import paged_kv
+from tiny import assert_greedy
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir,
                                     os.pardir))
@@ -63,28 +64,22 @@ def _serving(model, **kw):
                                       params=params, **kw)
 
 
-_PAD = 128
-
-
 @functools.lru_cache(maxsize=None)
 def _uncached(apply_fn):
     return jax.jit(lambda params, ids: apply_fn(params, ids))
 
 
-def _greedy(model, prompt, n):
-    """Greedy continuation by the UNCACHED forward (itself held to the
-    reference below), on a sequence padded to one length: a position's
-    logits depend on nothing after it."""
+def _exact(model, reqs, out):
+    """Every served token is the greedy one of the UNCACHED forward (itself
+    held to the reference below; ``tiny.py``: one teacher-forced call over
+    prompt + output, not a roll-out)."""
     spec, params = model
-    toks = list(map(int, prompt))
-    assert len(toks) + n <= _PAD
-    with jax.default_matmul_precision("highest"):
-        for _ in range(n):
-            ids = np.zeros((1, _PAD), np.int32)
-            ids[0, :len(toks)] = toks
-            lg = _uncached(spec.apply_fn)(params, jnp.asarray(ids))
-            toks.append(int(jnp.argmax(lg[0, len(toks) - 1])))
-    return toks[len(prompt):]
+
+    def logits_of(ids):
+        with jax.default_matmul_precision("highest"):
+            return _uncached(spec.apply_fn)(params, jnp.asarray(ids))
+
+    assert_greedy(logits_of, reqs, out)
 
 
 # ----------------------------------------------------------------- configs
@@ -180,8 +175,8 @@ def test_paged_path_agrees_with_the_reference(model, what):
         # continuation: its K+1 verify window selects per query
         prompt = rng.integers(0, 512, 70).astype(np.int32)
         srv = _serving(model, spec_tokens=3, sampling=False)
-        out = srv.serve([Request(uid=0, prompt=prompt, max_new_tokens=10)])
-        assert list(map(int, out[0]))[-10:] == _greedy(model, prompt, 10)
+        reqs = [Request(uid=0, prompt=prompt, max_new_tokens=10)]
+        _exact(model, reqs, srv.serve(reqs))
         assert srv.stats()["sparse_attn"]["verify"] is not None
         assert srv.stats()["spec_rounds"] > 0
         return
@@ -207,8 +202,7 @@ def test_served_requests_are_the_greedy_continuation_and_counters_add_up(
                     max_new_tokens=8) for i, n in enumerate((100, 20, 70))]
     # (uid 0 is the longest and finishes last, alone)
     out = srv.serve(reqs)
-    for r in reqs:
-        assert list(map(int, out[r.uid]))[-8:] == _greedy(model, r.prompt, 8)
+    _exact(model, reqs, out)
     stats = srv.stats()
     assert stats["sparse_attn"]["decode"] == "gather+top_k+walk"
     assert stats["sparse_attn"]["prefill"] == "gather+top_k+walk"
@@ -249,15 +243,13 @@ def test_third_leaf_survives_prefix_reuse_eviction_and_swap(model):
     shared = rng.integers(0, 512, 40).astype(np.int32)       # past topk
     prompts = [np.concatenate([shared, rng.integers(0, 512, n).astype(
         np.int32)]) for n in (5, 9, 3, 7, 4, 8)]
-    want = [_greedy(model, p, 6) for p in prompts]
 
     def reqs(base):
         return [Request(uid=base + i, prompt=p, max_new_tokens=6)
                 for i, p in enumerate(prompts)]
 
     def check(out, base):
-        for i in range(len(prompts)):
-            assert list(map(int, out[base + i]))[-6:] == want[i], (base, i)
+        _exact(model, reqs(base), out)
 
     # prefix reuse: later requests reuse the shared prefix's blocks — K, V
     # AND the indexer's keys (an indexer key that was lost, stale or another

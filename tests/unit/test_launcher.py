@@ -160,7 +160,7 @@ def test_elastic_flag_requires_config(tmp_path):
                      "--launcher", "local", "train.py"])
 
 
-def test_elastic_cli_restarts_dead_worker(tmp_path):
+def test_elastic_cli_restarts_dead_worker(tmp_path, workers_reaped):
     """CLI path end to end: a worker dies mid-run, the agent re-elects and
     restarts the group; workers of the second generation (keyed off the
     agent-injected DS_ELASTIC_RESTART_COUNT) finish cleanly."""

@@ -21,6 +21,7 @@ from deepspeed_tpu.inference.serving import Request
 from deepspeed_tpu.models import llama, mixtral
 from deepspeed_tpu.moe import routed
 from deepspeed_tpu.ops import paged_kv
+from tiny import assert_greedy
 
 BLOCK, CHUNK, ORIGINAL = 8, 16, 16
 HELD = (4, 4)
@@ -66,15 +67,11 @@ def model():
     return cfg, spec, params
 
 
-def _greedy(config, params, prompt, n):
-    ids, out = list(prompt), []
-    for _ in range(n):
-        lg = np.asarray(ref.logits(config, params,
-                                   np.asarray(ids, np.int32)[None],
-                                   at=[len(ids) - 1]))
-        out.append(int(lg[0, 0].argmax()))
-        ids.append(out[-1])
-    return out
+def _exact(cfg, params, reqs, out):
+    """Every served token is the reference's greedy one (``tiny.py``: one
+    teacher-forced call over prompt + output, not a roll-out)."""
+    assert_greedy(lambda ids: ref.logits(_config(cfg), params, ids), reqs,
+                  out)
 
 
 def test_uncached_forward_equals_the_reference(model):
@@ -112,7 +109,8 @@ def _paged(spec, params, toks, block):
     assert set(cache) == {"latent"}
     assert cache["latent"].shape == (2, 1 + b * nbper, 1, block, 128)
     bt = jnp.asarray(1 + np.arange(b * nbper).reshape(b, nbper), jnp.int32)
-    fwd, got, at = hooks["forward_cached"], [], []
+    # (jitted: a program a shape, not a compile an op)
+    fwd, got, at = jax.jit(hooks["forward_cached"]), [], []
     for base in range(0, 48, CHUNK):
         lg, cache = fwd(params, jnp.asarray(toks[:, base:base + CHUNK]),
                         cache, jnp.full((b,), base, jnp.int32),
@@ -154,7 +152,9 @@ def test_absorbed_equals_expanded_layer_by_layer(model):
     cos, sin = llama.rope_angles(cfg, s)
     nbper = -(-s // BLOCK)
     bt = jnp.asarray(1 + np.arange(b * nbper).reshape(b, nbper), jnp.int32)
-    for l in range(cfg.num_layers):
+
+    def both(l):
+        """Layer ``l``'s two bodies as one program (not an op at a time)."""
         layer = jax.tree_util.tree_map(lambda a: a[l], params["blocks"])
         want = llama._latent_attention(cfg, layer, y, cos, sin)
         pool = mixtral.init_cache(cfg, 1 + b * nbper, BLOCK,
@@ -166,6 +166,10 @@ def test_absorbed_equals_expanded_layer_by_layer(model):
         last, pool = llama._latent_cached(
             cfg, y[:, s - 1:], *layer_accessors(layer), pool,
             jnp.full((b,), s - 1, jnp.int32), bt, None, l)
+        return want, head, last, pool
+
+    for l in range(cfg.num_layers):
+        want, head, last, pool = jax.jit(both, static_argnums=0)(l)
         np.testing.assert_allclose(jnp.concatenate([head, last], 1), want,
                                    rtol=1e-4, atol=1e-4)
         # what is cached is the latent and the one rotated key, 24 of the
@@ -187,19 +191,13 @@ def _serve(spec, params, lengths, new=12, prompts=None, **how):
     return srv, reqs, srv.serve(reqs)
 
 
-def _exact(cfg, params, reqs, out, new):
-    for r in reqs:
-        assert [int(t) for t in out[r.uid][len(r.prompt):]] \
-            == _greedy(_config(cfg), params, r.prompt, new), r.uid
-
-
 def test_engine_serves_it_token_exact_and_names_the_pool(model):
     """Four requests over three slots through ``ServingEngine``: greedy
     tokens equal the reference's, ``stats()`` names the latent kind, and
     the spans carry what the readers read."""
     cfg, spec, params = model
     srv, reqs, out = _serve(spec, params, [70, 33, 50, 9])
-    _exact(cfg, params, reqs, out, 12)
+    _exact(cfg, params, reqs, out)
     st = srv.stats()
     assert set(srv._cache) == {"latent"}
     lat = st["kv_latent"]
@@ -239,7 +237,7 @@ def test_preempted_row_is_readmitted_token_exact(model):
     srv, reqs, out = _serve(spec, params, [60, 58, 62], new=30,
                             num_blocks=1 + 28)
     assert srv.stats()["evicted"] > 0
-    _exact(cfg, params, reqs, out, 30)
+    _exact(cfg, params, reqs, out)
 
 
 def test_two_requests_share_a_prefix_through_the_trie(model):
@@ -256,7 +254,7 @@ def test_two_requests_share_a_prefix_through_the_trie(model):
         debug_checks=True)
     reqs = [Request(i, p, 8) for i, p in enumerate(prompts)]
     out = srv.serve(reqs)
-    _exact(cfg, params, reqs, out, 8)
+    _exact(cfg, params, reqs, out)
     st = srv.stats()
     assert st["prefix_hit_tokens"] == 40 and st["prefix_cache_entries"] > 0
 
@@ -266,7 +264,7 @@ def test_a_verify_window_is_the_same_kernel_at_k_plus_one(model):
     absorbed body at T = 4 and the emitted tokens stay the reference's."""
     cfg, spec, params = model
     srv, reqs, out = _serve(spec, params, [33, 21], new=16, spec_tokens=3)
-    _exact(cfg, params, reqs, out, 16)
+    _exact(cfg, params, reqs, out)
     assert srv.stats()["kv_latent"]["latent_attn"]["verify"] \
         == "latent_gather"
 
@@ -277,7 +275,7 @@ def test_the_host_tier_moves_the_leaf_as_it_is(model):
     cfg, spec, params = model
     srv, reqs, out = _serve(spec, params, [60, 58, 62], new=30,
                             num_blocks=1 + 28, host_blocks=64, swap_batch=4)
-    _exact(cfg, params, reqs, out, 30)
+    _exact(cfg, params, reqs, out)
     st = srv.stats()
     assert st["swap_out"] > 0 and st["swap_in"] > 0
 
@@ -364,9 +362,8 @@ def test_an_engine_given_no_block_size_derives_a_latent_pools(model):
         max_seq_len=1024, **how)
     assert srv.stats()["block_size"] == srv.block_size == want
     assert srv.stats()["kv_latent"]["block_size"] == want
-    out = srv.serve([Request(0, np.arange(70, dtype=np.int32) % 128, 6)])
-    assert [int(t) for t in out[0][70:]] == _greedy(
-        _config(cfg), params, np.arange(70, dtype=np.int32) % 128, 6)
+    reqs = [Request(0, np.arange(70, dtype=np.int32) % 128, 6)]
+    _exact(cfg, params, reqs, srv.serve(reqs))
     srv.close()
     given = deepspeed_tpu.init_serving(spec, params=params, max_seq_len=128,
                                        block_size=BLOCK, **how)

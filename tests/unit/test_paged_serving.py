@@ -23,8 +23,8 @@ import deepspeed_tpu
 from deepspeed_tpu.inference.paged import (SCRATCH_BLOCK, BlockAllocator,
                                            PrefixCache)
 from deepspeed_tpu.inference.serving import Request, ServingEngine
-from deepspeed_tpu.models import gpt2
 from deepspeed_tpu.utils.lru import LRUCache
+from tiny import assert_sequential
 
 
 # ------------------------------------------------------------- BlockAllocator
@@ -253,19 +253,26 @@ def test_stacked_pool_write_and_read_in_place(t, kv8, tp):
 
     ctx = paged_kv.tp_context(_tp_mesh(tp)) if tp > 1 \
         else contextlib.nullcontext()
-    with ctx:
-        write = jax.jit(lambda kp, vp, l: paged_kv.paged_cache_update(
-            kp, vp, kw, vw, jnp.asarray(pos), jnp.asarray(bt), layer=l))
-        kp2, vp2 = write(kp, vp, jnp.int32(layer))
+    q = jnp.asarray(rng.standard_normal((b, h, t, d)), jnp.float32)
+
+    def program(kp, vp, l):
+        """The write and the three reads as ONE program (not an op at a
+        time under the mesh: each would be a compile of its own)."""
+        kp2, vp2 = paged_kv.paged_cache_update(
+            kp, vp, kw, vw, jnp.asarray(pos), jnp.asarray(bt), layer=l)
         got_k = paged_kv.paged_gather(kp2, jnp.asarray(bt), layer=layer,
                                       out_dtype=jnp.float32)
-        q = jnp.asarray(rng.standard_normal((b, h, t, d)), jnp.float32)
         one = lambda p: jax.tree_util.tree_map(   # noqa: E731
             lambda a: a[layer], p)
         attn = paged_decode_attention_reference(
             q, kp2, vp2, jnp.asarray(bt), jnp.asarray(pos), layer=layer)
         attn_one = paged_decode_attention_reference(
             q, one(kp2), one(vp2), jnp.asarray(bt), jnp.asarray(pos))
+        return kp2, vp2, got_k, attn, attn_one
+
+    with ctx:
+        kp2, vp2, got_k, attn, attn_one = jax.jit(program)(
+            kp, vp, jnp.int32(layer))
     np.testing.assert_array_equal(np.asarray(attn), np.asarray(attn_one))
 
     # what must not have moved, leaf by leaf (codes and scale rows alike)
@@ -294,19 +301,6 @@ def test_stacked_pool_write_and_read_in_place(t, kv8, tp):
 
 
 # --------------------------------------------------- chunked-prefill scheduler
-@pytest.fixture(scope="module")
-def tiny_engine():
-    """One shared tiny-gpt2 engine for the scheduler tests: serve() drains
-    its slots completely, so ServingEngines stack on it safely, and the
-    generate-parity programs stay in its LRU across tests (tier-1 window
-    budget)."""
-    deepspeed_tpu.comm.reset_topology()
-    cfg = gpt2.GPT2Config.tiny(max_seq_len=128)
-    return deepspeed_tpu.init_inference(
-        gpt2.build(cfg),
-        config={"dtype": "fp32", "tensor_parallel": {"tp_size": 1}}), cfg
-
-
 def _shared_prefix_trace(cfg, n, prefix_len=24, seed=0, tail=(3, 10),
                          max_new=(2, 10)):
     rng = np.random.default_rng(seed)
@@ -330,11 +324,7 @@ def test_chunked_serving_matches_sequential_generate(tiny_engine):
     reqs = _shared_prefix_trace(cfg, 6)
     steps = []
     res = srv.serve(reqs, step_log=steps)
-    for r in reqs:
-        want = engine.generate(r.prompt[None, :],
-                               max_new_tokens=r.max_new_tokens)[0]
-        np.testing.assert_array_equal(res[r.uid], want,
-                                      err_msg=f"uid {r.uid}")
+    assert_sequential(engine, reqs, res)
     st = srv.stats()
     assert st["prefix_cache_hit_rate"] > 0.2, st
     assert st["prefix_hit_tokens"] % srv.block_size == 0
@@ -361,12 +351,7 @@ def test_chunked_serving_parity_with_eos(tiny_engine):
     probe = engine.generate(reqs[0].prompt[None, :], max_new_tokens=1)
     eos = int(probe[0, len(reqs[0].prompt)])
     res = srv.serve(reqs, eos_token_id=eos)
-    for r in reqs:
-        want = engine.generate(r.prompt[None, :],
-                               max_new_tokens=r.max_new_tokens,
-                               eos_token_id=eos)[0]
-        np.testing.assert_array_equal(res[r.uid], want,
-                                      err_msg=f"uid {r.uid}")
+    assert_sequential(engine, reqs, res, eos_token_id=eos)
 
 
 @pytest.mark.slow  # two engine builds — tier-1 covers gpt2 here and these
@@ -391,11 +376,7 @@ def test_chunked_serving_parity_other_families(family):
     reqs = _shared_prefix_trace(cfg, 5, prefix_len=10, seed=2, tail=(3, 8),
                                 max_new=(2, 8))
     res = srv.serve(reqs)
-    for r in reqs:
-        want = engine.generate(r.prompt[None, :],
-                               max_new_tokens=r.max_new_tokens)[0]
-        np.testing.assert_array_equal(res[r.uid], want,
-                                      err_msg=f"uid {r.uid}")
+    assert_sequential(engine, reqs, res)
 
 
 @pytest.mark.parametrize("family,kv", [("opt", None), ("bloom", None),
@@ -429,11 +410,7 @@ def test_paged_greedy_equals_contiguous_generate(family, kv):
     reqs = _shared_prefix_trace(cfg, 4, prefix_len=10, seed=3, tail=(3, 8),
                                 max_new=(3, 9))
     res = srv.serve(reqs)
-    for r in reqs:
-        want = engine.generate(r.prompt[None, :],
-                               max_new_tokens=r.max_new_tokens)[0]
-        np.testing.assert_array_equal(res[r.uid], want,
-                                      err_msg=f"uid {r.uid}")
+    assert_sequential(engine, reqs, res)
     deepspeed_tpu.comm.reset_topology()
 
 
@@ -521,11 +498,7 @@ def test_preemption_under_block_pressure_keeps_parity(tiny_engine):
     res = srv.serve(reqs, admission_log=log)
     assert srv.preempted > 0, srv.stats()      # pressure actually happened
     assert set(res) == set(range(5))           # everyone finished
-    for r in reqs:
-        want = engine.generate(r.prompt[None, :],
-                               max_new_tokens=r.max_new_tokens)[0]
-        np.testing.assert_array_equal(res[r.uid], want,
-                                      err_msg=f"uid {r.uid}")
+    assert_sequential(engine, reqs, res)
     # FIRST admissions stay FIFO (re-admissions of evicted uids may repeat)
     first = []
     for uid, _ in log:

@@ -26,10 +26,10 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.analysis.invariants import PagedStateError
 from deepspeed_tpu.inference.serving import Request, ServingEngine
-from deepspeed_tpu.models import gpt2
 from deepspeed_tpu.ops import paged_kv
 from quant_divergence import (assert_bounded_divergence, max_logit_rmse,
                               token_match_rate)
+from tiny import sequential
 
 #: documented divergence bounds for the tiny fp32 CPU-sim models (random
 #: weights — near-uniform logits, the WORST case for argmax stability;
@@ -125,15 +125,6 @@ def test_quantized_paged_attention_reference_tracks_float():
 
 
 # --------------------------------------------------------------- scheduling
-@pytest.fixture(scope="module")
-def tiny_engine():
-    deepspeed_tpu.comm.reset_topology()
-    cfg = gpt2.GPT2Config.tiny(max_seq_len=128)
-    return deepspeed_tpu.init_inference(
-        gpt2.build(cfg),
-        config={"dtype": "fp32", "tensor_parallel": {"tp_size": 1}}), cfg
-
-
 def _trace(cfg, n=6, seed=1, prefix_len=24, tail=(3, 10), max_new=(2, 10)):
     rng = np.random.default_rng(seed)
     prefix = rng.integers(0, cfg.vocab_size, prefix_len)
@@ -145,12 +136,6 @@ def _trace(cfg, n=6, seed=1, prefix_len=24, tail=(3, 10), max_new=(2, 10)):
             for i in range(n)]
 
 
-def _sequential(engine, reqs):
-    return {r.uid: engine.generate(r.prompt[None, :],
-                                   max_new_tokens=r.max_new_tokens)[0]
-            for r in reqs}
-
-
 def test_kv8_serving_bounded_divergence_and_stats(tiny_engine):
     """kv8 end-to-end on gpt2: bounded token divergence vs sequential
     generate, ≤2-program compile contract live-enforced, quantized memory
@@ -158,7 +143,7 @@ def test_kv8_serving_bounded_divergence_and_stats(tiny_engine):
     scale-lockstep) green throughout."""
     engine, cfg = tiny_engine
     reqs = _trace(cfg)
-    want = _sequential(engine, reqs)
+    want = sequential(engine, reqs)
     srv = ServingEngine(engine, slots=4, max_seq_len=128, block_size=8,
                         prefill_chunk=16, prefill_batch=2, quantize="kv8",
                         debug_checks=True)
@@ -190,7 +175,7 @@ def test_kv8_speculative_and_preemption_pressure(tiny_engine):
     the scale ledger tracks every free/realloc."""
     engine, cfg = tiny_engine
     reqs = _trace(cfg, seed=3)
-    want = _sequential(engine, reqs)
+    want = sequential(engine, reqs)
     srv = ServingEngine(engine, slots=4, max_seq_len=128, block_size=8,
                         prefill_chunk=16, prefill_batch=2, quantize="kv8",
                         spec_tokens=3, debug_checks=True)
@@ -203,7 +188,7 @@ def test_kv8_speculative_and_preemption_pressure(tiny_engine):
     rng = np.random.default_rng(5)
     preqs = [Request(uid=i, prompt=rng.integers(0, cfg.vocab_size, 17),
                      max_new_tokens=28) for i in range(5)]
-    pwant = _sequential(engine, preqs)
+    pwant = sequential(engine, preqs)
     srv_p = ServingEngine(engine, slots=3, max_seq_len=64, block_size=8,
                           prefill_chunk=32, prefill_batch=2, num_blocks=12,
                           quantize="kv8", debug_checks=True)
@@ -221,7 +206,7 @@ def test_quantize_none_is_bit_identical(tiny_engine):
     bit-equal tokens, float pool, no scale table."""
     engine, cfg = tiny_engine
     reqs = _trace(cfg, seed=7)
-    want = _sequential(engine, reqs)
+    want = sequential(engine, reqs)
     srv = ServingEngine(engine, slots=4, max_seq_len=128, block_size=8,
                         prefill_chunk=16, prefill_batch=2, quantize=None,
                         debug_checks=True)
@@ -277,7 +262,7 @@ def test_quant_serving_all_families(family):
         config={"dtype": "fp32", "tensor_parallel": {"tp_size": 1}})
     reqs = _trace(cfg, n=4, seed=2, prefix_len=10, tail=(3, 8),
                   max_new=(2, 8))
-    want = _sequential(engine, reqs)
+    want = sequential(engine, reqs)
 
     kw = dict(slots=3, max_seq_len=64, block_size=8, prefill_chunk=16,
               prefill_batch=2, debug_checks=True)

@@ -32,21 +32,11 @@ from deepspeed_tpu.analysis.invariants import (PagedStateError,
 from deepspeed_tpu.inference.serving import (Request, RequestHandle,
                                              SLO_PRIORITY, ServingEngine,
                                              _PendingItem, _PendingQueue)
-from deepspeed_tpu.models import gpt2
 from deepspeed_tpu.serving import ReplicaRouter, RouterSupervisor
+from tiny import sequential
 
 
 # ---------------------------------------------------------------- fixtures
-@pytest.fixture(scope="module")
-def tiny():
-    cfg = gpt2.GPT2Config.tiny(max_seq_len=128)
-    spec = gpt2.build(cfg)
-    deepspeed_tpu.comm.reset_topology()
-    engine = deepspeed_tpu.init_inference(
-        spec, config={"dtype": "fp32", "tensor_parallel": {"tp_size": 1}})
-    return spec, cfg, engine
-
-
 def _mk_engine(spec, params, **cfg_extra):
     config = {"dtype": "fp32", "tensor_parallel": {"tp_size": 1}}
     config.update(cfg_extra)
@@ -72,12 +62,6 @@ def _session_trace(cfg, n=9, sessions=3, seed=0, prefix_len=24,
         for i in range(n)]
 
 
-def _sequential(engine, reqs):
-    return {r.uid: engine.generate(r.prompt[None, :],
-                                   max_new_tokens=r.max_new_tokens)[0]
-            for r in reqs}
-
-
 # ------------------------------------------------- incremental engine API
 def test_pending_queue_priority_and_front():
     q = _PendingQueue()
@@ -101,7 +85,7 @@ def test_pending_queue_priority_and_front():
 def test_incremental_submit_step_matches_serve(tiny):
     spec, cfg, engine = tiny
     _, reqs = _session_trace(cfg)
-    seq = _sequential(engine, reqs)
+    seq = sequential(engine, reqs)
 
     srv = ServingEngine(engine, **_SRV_KW)
     handles = [srv.submit(r) for r in reqs]
@@ -457,7 +441,7 @@ def test_router_ctor_validation():
 def test_router_two_replicas_parity_and_affinity(tiny):
     spec, cfg, engine = tiny
     prefixes, reqs = _session_trace(cfg)
-    seq = _sequential(engine, reqs)
+    seq = sequential(engine, reqs)
     srvs = [ServingEngine(_mk_engine(spec, engine.params), **_SRV_KW)
             for _ in range(2)]
     router = ReplicaRouter(srvs, debug_checks=True)
@@ -492,7 +476,7 @@ def test_kv_pull_migration_zero_recompute(tiny):
     prefix recompute (only the mandatory sub-block tail prefills)."""
     spec, cfg, engine = tiny
     prefixes, reqs = _session_trace(cfg)
-    seq = _sequential(engine, reqs)
+    seq = sequential(engine, reqs)
     router = ReplicaRouter(_tiered_pair(spec, engine.params),
                            debug_checks=True)
     outs = router.serve(reqs)
@@ -575,7 +559,7 @@ def test_drain_midflight_no_requests_dropped(tiny):
     on the surviving replica, token-exact, on the original handles."""
     spec, cfg, engine = tiny
     prefixes, reqs = _session_trace(cfg, n=6, max_new=16)
-    seq = _sequential(engine, reqs)
+    seq = sequential(engine, reqs)
     router = ReplicaRouter(_tiered_pair(spec, engine.params),
                            debug_checks=True)
     handles = [router.submit(r) for r in reqs]
@@ -601,7 +585,7 @@ def test_threaded_router_smoke(tiny):
     their replica locks."""
     spec, cfg, engine = tiny
     _, reqs = _session_trace(cfg, n=4)
-    seq = _sequential(engine, reqs)
+    seq = sequential(engine, reqs)
     srvs = [ServingEngine(_mk_engine(spec, engine.params), **_SRV_KW)
             for _ in range(2)]
     router = ReplicaRouter(srvs, threaded=True)
@@ -647,6 +631,6 @@ def test_init_router_places_replicas_and_shares_weights(tiny):
     reqs = [Request(uid=i, prompt=rng.integers(0, cfg.vocab_size, 12),
                     max_new_tokens=5) for i in range(3)]
     outs = router.serve(reqs)
-    seq = _sequential(router.replicas[0].engine, reqs)
+    seq = sequential(router.replicas[0].engine, reqs)
     for r in reqs:
         np.testing.assert_array_equal(outs[r.uid], seq[r.uid])

@@ -27,20 +27,11 @@ import json
 import numpy as np
 import pytest
 
-import deepspeed_tpu
 from deepspeed_tpu.inference.constrain import (JsonMaskBuilder,
                                                ascii_token_strings)
 from deepspeed_tpu.inference.serving import Request, ServingEngine
 from deepspeed_tpu.models import gpt2
-
-
-@pytest.fixture(scope="module")
-def tiny_engine():
-    deepspeed_tpu.comm.reset_topology()
-    cfg = gpt2.GPT2Config.tiny(max_seq_len=128)
-    return deepspeed_tpu.init_inference(
-        gpt2.build(cfg),
-        config={"dtype": "fp32", "tensor_parallel": {"tp_size": 1}}), cfg
+from tiny import assert_sequential
 
 
 _KW = dict(slots=4, max_seq_len=128, block_size=8, prefill_chunk=16,
@@ -96,13 +87,7 @@ def test_temp0_rows_bit_identical_to_greedy_engine(tiny_engine):
     on = ServingEngine(engine, **_KW)
     off = ServingEngine(engine, sampling=False, **_KW)
     res_on, res_off = on.serve(reqs), off.serve(reqs)
-    for r in reqs:
-        want = engine.generate(r.prompt[None, :],
-                               max_new_tokens=r.max_new_tokens)[0]
-        np.testing.assert_array_equal(res_on[r.uid], want,
-                                      err_msg=f"on uid {r.uid}")
-        np.testing.assert_array_equal(res_off[r.uid], want,
-                                      err_msg=f"off uid {r.uid}")
+    assert_sequential(engine, reqs, res_on, res_off)
     assert on.stats()["sampled_requests"] == 0
 
 
@@ -129,10 +114,8 @@ def test_spec_ngram_sampled_deterministic_two_programs(tiny_engine):
     for r in reqs:
         np.testing.assert_array_equal(res_a[r.uid], res_b[r.uid],
                                       err_msg=f"uid {r.uid}")
-        if not r.sampled:                   # temp-0 rows stay greedy
-            want = engine.generate(r.prompt[None, :],
-                                   max_new_tokens=r.max_new_tokens)[0]
-            np.testing.assert_array_equal(res_a[r.uid], want)
+    assert_sequential(engine, [r for r in reqs if not r.sampled],
+                      res_a)  # temp-0 rows stay greedy
     assert a.compile_count == 2, a.compiled_programs
     st = a.stats()
     assert st["spec_rounds"] > 0 and 0.0 <= st["acceptance_rate"] <= 1.0
@@ -153,10 +136,8 @@ def test_spec_draft_sampled_three_programs_and_temp0_parity(tiny_engine):
     for r in reqs:
         np.testing.assert_array_equal(res_a[r.uid], res_b[r.uid],
                                       err_msg=f"uid {r.uid}")
-        if not r.sampled:
-            want = engine.generate(r.prompt[None, :],
-                                   max_new_tokens=r.max_new_tokens)[0]
-            np.testing.assert_array_equal(res_a[r.uid], want)
+    assert_sequential(engine, [r for r in reqs if not r.sampled],
+                      res_a)
     assert a.compile_count == 3, a.compiled_programs
     assert sorted(p[0] for p in a.compiled_programs) == \
         ["draft", "prefill", "verify"]
@@ -170,11 +151,7 @@ def test_greedy_only_spec_engine_matches_plain_greedy(tiny_engine):
     srv = ServingEngine(engine, spec_tokens=3, sampling=False, **_KW)
     reqs = _sampled_trace(cfg, 4, seed=6, greedy_every=1)   # all greedy
     res = srv.serve(reqs)
-    for r in reqs:
-        want = engine.generate(r.prompt[None, :],
-                               max_new_tokens=r.max_new_tokens)[0]
-        np.testing.assert_array_equal(res[r.uid], want,
-                                      err_msg=f"uid {r.uid}")
+    assert_sequential(engine, reqs, res)
     assert srv.stats()["sampling"] is False
     assert sorted(p[0] for p in srv.compiled_programs) == \
         ["prefill", "verify"]

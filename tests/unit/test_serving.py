@@ -15,6 +15,7 @@ import deepspeed_tpu
 from deepspeed_tpu.inference.engine import _fill_after_eos
 from deepspeed_tpu.inference.serving import Request, ServingEngine
 from deepspeed_tpu.models import gpt2
+from tiny import assert_sequential
 
 
 def _tiny_engine(max_seq_len=128):
@@ -76,25 +77,21 @@ def test_fill_after_eos_matches_rowwise_loop():
 
 
 # -------------------------------------------------------------------- scheduler
-def test_serving_matches_sequential_generate_greedy():
+def test_serving_matches_sequential_generate_greedy(tiny_engine):
     """Acceptance: per-request outputs token-identical to sequential
     ``generate`` (greedy), across mixed prompt lengths and budgets."""
-    engine, cfg = _tiny_engine()
+    engine, cfg = tiny_engine
     srv = ServingEngine(engine, slots=4, max_seq_len=128,
                         prefill_chunk=16, prefill_batch=2)
     reqs = _trace(cfg, 10)
     res = srv.serve(reqs)
-    for r in reqs:
-        want = engine.generate(r.prompt[None, :],
-                               max_new_tokens=r.max_new_tokens)[0]
-        np.testing.assert_array_equal(res[r.uid], want,
-                                      err_msg=f"uid {r.uid}")
+    assert_sequential(engine, reqs, res)
 
 
-def test_serving_matches_sequential_generate_with_eos():
+def test_serving_matches_sequential_generate_with_eos(tiny_engine):
     """Same parity when sequences stop early at eos (slot frees early and
     the output is eos back-filled like generate's)."""
-    engine, cfg = _tiny_engine()
+    engine, cfg = tiny_engine
     srv = ServingEngine(engine, slots=3, max_seq_len=128,
                         prefill_chunk=16, prefill_batch=2)
     reqs = _trace(cfg, 6, seed=1, max_new=(4, 10))
@@ -102,12 +99,7 @@ def test_serving_matches_sequential_generate_with_eos():
     probe = engine.generate(reqs[0].prompt[None, :], max_new_tokens=1)
     eos = int(probe[0, len(reqs[0].prompt)])
     res = srv.serve(reqs, eos_token_id=eos)
-    for r in reqs:
-        want = engine.generate(r.prompt[None, :],
-                               max_new_tokens=r.max_new_tokens,
-                               eos_token_id=eos)[0]
-        np.testing.assert_array_equal(res[r.uid], want,
-                                      err_msg=f"uid {r.uid}")
+    assert_sequential(engine, reqs, res, eos_token_id=eos)
 
 
 @pytest.mark.parametrize("family", ["llama", "opt"])
@@ -130,17 +122,13 @@ def test_serving_parity_other_families(family):
                         prefill_chunk=16, prefill_batch=2)
     reqs = _trace(cfg, 5, seed=2, lo=3, hi=14, max_new=(2, 8))
     res = srv.serve(reqs)
-    for r in reqs:
-        want = engine.generate(r.prompt[None, :],
-                               max_new_tokens=r.max_new_tokens)[0]
-        np.testing.assert_array_equal(res[r.uid], want,
-                                      err_msg=f"uid {r.uid}")
+    assert_sequential(engine, reqs, res)
 
 
-def test_admission_fifo_and_immediate_slot_reuse():
+def test_admission_fifo_and_immediate_slot_reuse(tiny_engine):
     """Slots: strict FIFO admission (no starvation), and a freed slot is
     reacquired by the next waiting request."""
-    engine, cfg = _tiny_engine()
+    engine, cfg = tiny_engine
     srv = ServingEngine(engine, slots=2, max_seq_len=128,
                         prefill_chunk=8, prefill_batch=2)
     rng = np.random.default_rng(5)
@@ -158,8 +146,8 @@ def test_admission_fifo_and_immediate_slot_reuse():
         assert sum(1 for _, slot in log if slot == s) >= 2
 
 
-def test_serving_rejects_oversized_and_invalid():
-    engine, cfg = _tiny_engine()
+def test_serving_rejects_oversized_and_invalid(tiny_engine):
+    engine, cfg = tiny_engine
     srv = ServingEngine(engine, slots=2, max_seq_len=64,
                         prefill_chunk=16, prefill_batch=2)
     with pytest.raises(ValueError, match="exceeds max_seq_len"):
@@ -230,7 +218,7 @@ def test_generate_early_exit_matches_full_loop():
 def test_generate_fns_lru_moves_hit_to_end():
     """Satellite: a cache hit refreshes the entry, so hot shapes survive
     eviction pressure (true LRU, not insertion-order FIFO)."""
-    engine, cfg = _tiny_engine()
+    engine, cfg = _tiny_engine()                # fresh: its LRU's order is read
     ids = np.ones((1, 4), np.int32)
     engine.generate(ids, max_new_tokens=2)      # key A
     engine.generate(ids, max_new_tokens=3)      # key B

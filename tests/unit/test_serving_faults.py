@@ -40,23 +40,13 @@ from deepspeed_tpu.inference.paged import (HostBlockStore, TransportError,
 from deepspeed_tpu.inference.serving import (Request, RequestFailedError,
                                              RequestHandle, ServingEngine,
                                              _PendingItem, _PendingQueue)
-from deepspeed_tpu.models import gpt2
 from deepspeed_tpu.serving import (FaultInjector, FaultPlan, ReplicaRouter,
                                    RequestRejected, RouterSupervisor,
                                    SimulatedCrash)
+from tiny import sequential
 
 
 # ---------------------------------------------------------------- fixtures
-@pytest.fixture(scope="module")
-def tiny():
-    cfg = gpt2.GPT2Config.tiny(max_seq_len=128)
-    spec = gpt2.build(cfg)
-    deepspeed_tpu.comm.reset_topology()
-    engine = deepspeed_tpu.init_inference(
-        spec, config={"dtype": "fp32", "tensor_parallel": {"tp_size": 1}})
-    return spec, cfg, engine
-
-
 _SRV_KW = dict(slots=3, max_seq_len=64, block_size=8, prefill_chunk=16,
                prefill_batch=2, debug_checks=True)
 
@@ -88,12 +78,6 @@ def _session_trace(cfg, n=9, sessions=3, seed=0, prefix_len=24,
                                   int(rng.integers(3, 8)))]),
                 max_new_tokens=max_new)
         for i in range(n)]
-
-
-def _sequential(engine, reqs):
-    return {r.uid: engine.generate(r.prompt[None, :],
-                                   max_new_tokens=r.max_new_tokens)[0]
-            for r in reqs}
 
 
 # -------------------------------------------------------------- plan units
@@ -223,7 +207,7 @@ def test_corruption_detected_100pct_and_never_served(tiny):
     parity throughout)."""
     spec, cfg, engine = tiny
     _, reqs = _session_trace(cfg, n=4, max_new=8)
-    seq = _sequential(engine, reqs)
+    seq = sequential(engine, reqs)
     srv = _mk_srv(spec, engine.params)
     outs = srv.serve(reqs)
     for r in reqs:
@@ -291,7 +275,7 @@ def test_crash_rehoming_token_exact_midflight(tiny):
     step), and per-replica compile budgets unchanged."""
     spec, cfg, engine = tiny
     _, reqs = _session_trace(cfg, n=9, max_new=12)
-    seq = _sequential(engine, reqs)
+    seq = sequential(engine, reqs)
 
     # fault-free twin first (identical fleet construction)
     free = _chaos_fleet(spec, engine.params)
@@ -356,7 +340,7 @@ def test_crash_rehoming_resumes_streams_on_same_handles(tiny):
     the identical continuation (greedy fold-in)."""
     spec, cfg, engine = tiny
     _, reqs = _session_trace(cfg, n=4, max_new=14)
-    seq = _sequential(engine, reqs)
+    seq = sequential(engine, reqs)
     router = _chaos_fleet(spec, engine.params, n=2)
     router.arm_faults(FaultPlan(seed=0,
                                 crashes=[{"replica": 0, "at_step": 6}]))
@@ -388,7 +372,7 @@ def test_crash_rehoming_salvages_survivor_kv(tiny):
     world."""
     spec, cfg, engine = tiny
     _, reqs = _session_trace(cfg, n=8, max_new=10)
-    seq = _sequential(engine, reqs)
+    seq = sequential(engine, reqs)
     router = ReplicaRouter([_mk_srv(spec, engine.params)
                             for _ in range(2)], policy="round_robin",
                            debug_checks=True)
@@ -478,7 +462,7 @@ def test_crash_rehoming_token_exact_sampled_spec(tiny):
 def test_transient_pull_faults_retry_with_parity(tiny):
     spec, cfg, engine = tiny
     prefixes, reqs = _session_trace(cfg)
-    seq = _sequential(engine, reqs)
+    seq = sequential(engine, reqs)
     router = _chaos_fleet(spec, engine.params, pull_retries=4)
     inj = router.arm_faults(FaultPlan(
         seed=5, transport={"ops": ["export"], "transient_rate": 1.0,
@@ -508,7 +492,7 @@ def test_transient_pull_faults_retry_with_parity(tiny):
 def test_permanent_pull_fault_falls_back_to_recompute(tiny):
     spec, cfg, engine = tiny
     prefixes, reqs = _session_trace(cfg, n=6)
-    seq = _sequential(engine, reqs)
+    seq = sequential(engine, reqs)
     router = _chaos_fleet(spec, engine.params)
     router.arm_faults(FaultPlan(
         seed=6, transport={"ops": ["export"], "permanent_rate": 1.0,
@@ -533,7 +517,7 @@ def test_engine_swap_transport_fault_drops_demotion(tiny):
     fault falls back to prefill recompute — parity holds either way."""
     spec, cfg, engine = tiny
     _, reqs = _session_trace(cfg, n=4, max_new=8)
-    seq = _sequential(engine, reqs)
+    seq = sequential(engine, reqs)
     srv = _mk_srv(spec, engine.params)
     srv.serve(reqs)
     srv.drain()
@@ -937,7 +921,7 @@ def test_prefill_crash_mid_handoff_rehomes_token_exact(tiny):
     reference, zero hung handles, and clean post-failure audits."""
     spec, cfg, engine = tiny
     _, reqs = _session_trace(cfg, n=9, max_new=12)
-    seq = _sequential(engine, reqs)
+    seq = sequential(engine, reqs)
 
     roles = ("prefill", "prefill", "decode")
     router = ReplicaRouter(
@@ -1027,7 +1011,7 @@ def test_nvme_corruption_recomputes_with_parity(tiny, tmp_path):
     prompts = [rng.integers(0, cfg.vocab_size, 32) for _ in range(8)]
     reqs = [Request(uid=i, prompt=p, max_new_tokens=6)
             for i, p in enumerate(prompts)]
-    seq = _sequential(engine, reqs)
+    seq = sequential(engine, reqs)
     srv = _mk_srv(spec, engine.params, slots=2, num_blocks=12,
                   host_blocks=8, swap_batch=2, nvme_blocks=32,
                   nvme_high_watermark=0.5,

@@ -28,6 +28,7 @@ import deepspeed_tpu
 from deepspeed_tpu.inference.serving import Request, ServingEngine
 from deepspeed_tpu.inference.spec import NGramProposer, greedy_accept
 from deepspeed_tpu.models import gpt2
+from tiny import assert_sequential
 
 
 # -------------------------------------------------------------- greedy_accept
@@ -103,18 +104,6 @@ def test_ngram_proposer_backoff_and_fallback():
 
 
 # --------------------------------------------------------------- end-to-end
-@pytest.fixture(scope="module")
-def tiny_engine():
-    """One shared tiny-gpt2 engine: serve() drains its slots, so multiple
-    ServingEngines stack on it safely (same pattern as
-    test_paged_serving.py)."""
-    deepspeed_tpu.comm.reset_topology()
-    cfg = gpt2.GPT2Config.tiny(max_seq_len=128)
-    return deepspeed_tpu.init_inference(
-        gpt2.build(cfg),
-        config={"dtype": "fp32", "tensor_parallel": {"tp_size": 1}}), cfg
-
-
 def _trace(cfg, n, seed=0, plen=(5, 30), max_new=(6, 24)):
     rng = np.random.default_rng(seed)
     return [Request(uid=i,
@@ -138,13 +127,7 @@ def test_spec_ngram_matches_plain_and_sequential(tiny_engine):
                          debug_checks=True)
     res_p = plain.serve(reqs)
     res_s = spec.serve(reqs)
-    for r in reqs:
-        want = engine.generate(r.prompt[None, :],
-                               max_new_tokens=r.max_new_tokens)[0]
-        np.testing.assert_array_equal(res_p[r.uid], want,
-                                      err_msg=f"plain uid {r.uid}")
-        np.testing.assert_array_equal(res_s[r.uid], want,
-                                      err_msg=f"spec uid {r.uid}")
+    assert_sequential(engine, reqs, res_p, res_s)
     st = spec.stats()
     assert st["speculative"] == "ngram" and st["spec_tokens"] == 4
     assert st["spec_rounds"] > 0
@@ -172,11 +155,7 @@ def test_spec_draft_model_matches_sequential(tiny_engine):
                          draft=gpt2.build(dcfg), debug_checks=True)
     reqs = _trace(cfg, 5, seed=1)
     res = spec.serve(reqs)
-    for r in reqs:
-        want = engine.generate(r.prompt[None, :],
-                               max_new_tokens=r.max_new_tokens)[0]
-        np.testing.assert_array_equal(res[r.uid], want,
-                                      err_msg=f"uid {r.uid}")
+    assert_sequential(engine, reqs, res)
     assert spec.compile_count == 3, spec.compiled_programs
     kinds = sorted(p[0] for p in spec.compiled_programs)
     assert kinds == ["draft", "prefill", "verify"]
@@ -194,12 +173,7 @@ def test_spec_eos_inside_window_end_to_end(tiny_engine):
                          prefill_chunk=16, prefill_batch=2, spec_tokens=4,
                          debug_checks=True)
     res = spec.serve(reqs, eos_token_id=eos)
-    for r in reqs:
-        want = engine.generate(r.prompt[None, :],
-                               max_new_tokens=r.max_new_tokens,
-                               eos_token_id=eos)[0]
-        np.testing.assert_array_equal(res[r.uid], want,
-                                      err_msg=f"uid {r.uid}")
+    assert_sequential(engine, reqs, res, eos_token_id=eos)
 
 
 def test_spec_compile_contract_holds_across_traces(tiny_engine):
@@ -236,11 +210,7 @@ def test_spec_preemption_pressure_keeps_parity(tiny_engine):
                     max_new_tokens=28) for i in range(5)]
     res = srv.serve(reqs)
     assert srv.preempted > 0, srv.stats()
-    for r in reqs:
-        want = engine.generate(r.prompt[None, :],
-                               max_new_tokens=r.max_new_tokens)[0]
-        np.testing.assert_array_equal(res[r.uid], want,
-                                      err_msg=f"uid {r.uid}")
+    assert_sequential(engine, reqs, res)
 
 
 def test_spec_parity_bloom_family():
@@ -263,11 +233,7 @@ def test_spec_parity_bloom_family():
                          prefill_chunk=16, prefill_batch=2, spec_tokens=3,
                          debug_checks=True)
     res = spec.serve(reqs)
-    for r in reqs:
-        want = engine.generate(r.prompt[None, :],
-                               max_new_tokens=r.max_new_tokens)[0]
-        np.testing.assert_array_equal(res[r.uid], want,
-                                      err_msg=f"uid {r.uid}")
+    assert_sequential(engine, reqs, res)
     assert spec.compile_count == 2
 
 
@@ -297,11 +263,7 @@ def test_spec_parity_other_families(family):
                     max_new_tokens=int(rng.integers(3, 10)))
             for i in range(4)]
     res = spec.serve(reqs)
-    for r in reqs:
-        want = engine.generate(r.prompt[None, :],
-                               max_new_tokens=r.max_new_tokens)[0]
-        np.testing.assert_array_equal(res[r.uid], want,
-                                      err_msg=f"uid {r.uid}")
+    assert_sequential(engine, reqs, res)
 
 
 # ---------------------------------------------------------------- validation

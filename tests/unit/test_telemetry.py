@@ -256,15 +256,6 @@ def test_validate_chrome_trace_pairs_disagg_handoffs():
 
 
 # ----------------------------------------------------------- serving engine
-@pytest.fixture(scope="module")
-def tiny_engine():
-    deepspeed_tpu.comm.reset_topology()
-    cfg = gpt2.GPT2Config.tiny(max_seq_len=128)
-    return deepspeed_tpu.init_inference(
-        gpt2.build(cfg),
-        config={"dtype": "fp32", "tensor_parallel": {"tp_size": 1}}), cfg
-
-
 def _trace(cfg, n, seed=0, max_new=(2, 12)):
     rng = np.random.default_rng(seed)
     return [Request(uid=i, prompt=rng.integers(0, cfg.vocab_size,
@@ -440,8 +431,12 @@ def test_training_engine_registry_routes_monitor_csv(tmp_path):
     assert len(rows.splitlines()) == 4        # header + 3 report steps
 
 
-def test_inference_profile_model_time_feeds_histogram(tiny_engine):
-    engine, cfg = tiny_engine
+def test_inference_profile_model_time_feeds_histogram(tiny):
+    # a fresh engine (on the shared one's parameters): profiling, once on,
+    # stays on, and every later forward of the engine would be timed
+    engine = deepspeed_tpu.init_inference(
+        tiny[0], config={"dtype": "fp32", "tensor_parallel": {"tp_size": 1}},
+        params=tiny[2].params)
     engine.profile_model_time()
     engine.forward({"input_ids": np.zeros((1, 8), np.int32)})
     times = engine.model_times()

@@ -19,22 +19,11 @@ import threading
 import urllib.request
 
 import numpy as np
-import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu.analysis.invariants import audit_router
 from deepspeed_tpu.inference.serving import Request
-from deepspeed_tpu.models import gpt2
-
-
-@pytest.fixture(scope="module")
-def fleet_setup():
-    cfg = gpt2.GPT2Config.tiny(max_seq_len=128)
-    spec = gpt2.build(cfg)
-    deepspeed_tpu.comm.reset_topology()
-    engine = deepspeed_tpu.init_inference(
-        spec, config={"dtype": "fp32", "tensor_parallel": {"tp_size": 1}})
-    return spec, cfg, engine
+from tiny import sequential
 
 
 def _session_trace(cfg, n=10, sessions=3, seed=3, prefix_len=24):
@@ -50,12 +39,10 @@ def _session_trace(cfg, n=10, sessions=3, seed=3, prefix_len=24):
             for i in range(n)]
 
 
-def test_threaded_fleet_parity_under_sanitizer(fleet_setup):
-    spec, cfg, engine = fleet_setup
+def test_threaded_fleet_parity_under_sanitizer(tiny):
+    spec, cfg, engine = tiny
     reqs = _session_trace(cfg)
-    sequential = {r.uid: engine.generate(r.prompt[None, :],
-                                         max_new_tokens=r.max_new_tokens)[0]
-                  for r in reqs}
+    want = sequential(engine, reqs)
 
     deepspeed_tpu.comm.reset_topology()
     router = deepspeed_tpu.init_router(
@@ -144,7 +131,7 @@ def test_threaded_fleet_parity_under_sanitizer(fleet_setup):
     for r in reqs:
         out = handles[r.uid].result(timeout=120)
         assert out is not None
-        np.testing.assert_array_equal(out, sequential[r.uid])
+        np.testing.assert_array_equal(out, want[r.uid])
     for h in extra_handles:
         if h.status != "cancelled":
             assert h.result(timeout=120) is not None

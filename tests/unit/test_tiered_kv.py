@@ -42,6 +42,7 @@ from deepspeed_tpu.inference.paged import (HostBlockStore, chain_key,
 from deepspeed_tpu.inference.serving import Request, ServingEngine
 from deepspeed_tpu.models import gpt2
 from deepspeed_tpu.ops import paged_kv
+from tiny import sequential
 
 
 # ------------------------------------------------------------- store units
@@ -157,15 +158,6 @@ def test_paged_block_gather_scatter_roundtrip_float_and_quantized():
 
 
 # ----------------------------------------------------------------- serving
-@pytest.fixture(scope="module")
-def tiny_engine():
-    cfg = gpt2.GPT2Config.tiny(max_seq_len=128)
-    deepspeed_tpu.comm.reset_topology()
-    return deepspeed_tpu.init_inference(
-        gpt2.build(cfg),
-        config={"dtype": "fp32", "tensor_parallel": {"tp_size": 1}}), cfg
-
-
 def _pressure_trace(cfg, n=6, seed=5, prefix_len=24, max_new=28):
     """Shared prefix + completions long enough that a 10-block pool (on
     3 slots / block_size 8) must evict the trie and preempt."""
@@ -184,12 +176,6 @@ _PRESSURE_KW = dict(slots=3, max_seq_len=64, block_size=8,
                     debug_checks=True)
 
 
-def _sequential(engine, reqs):
-    return {r.uid: engine.generate(r.prompt[None, :],
-                                   max_new_tokens=r.max_new_tokens)[0]
-            for r in reqs}
-
-
 def test_tiered_parity_under_pressure_and_compile_contract(tiny_engine):
     """Acceptance: the tiered engine under real block pressure is token-
     identical to sequential generate and to the untiered engine, swaps
@@ -198,7 +184,7 @@ def test_tiered_parity_under_pressure_and_compile_contract(tiny_engine):
     is exactly base + 2 swap programs (strict sentry)."""
     engine, cfg = tiny_engine
     reqs = _pressure_trace(cfg)
-    seq = _sequential(engine, reqs)
+    seq = sequential(engine, reqs)
 
     srv = ServingEngine(engine, host_blocks=64, swap_batch=4,
                         **_PRESSURE_KW)
@@ -234,7 +220,7 @@ def test_tiered_warm_pass_promotes_evicted_prefix(tiny_engine):
     part of the prefetch traffic is staged ahead (misses < promotions)."""
     engine, cfg = tiny_engine
     reqs = _pressure_trace(cfg, seed=7)
-    seq = _sequential(engine, reqs)
+    seq = sequential(engine, reqs)
     srv = ServingEngine(engine, host_blocks=64, swap_batch=4,
                         **_PRESSURE_KW)
     srv.serve(reqs)
@@ -280,7 +266,7 @@ def test_tiered_speculative_parity(tiny_engine):
     within its 2 + 2 swap-program budget."""
     engine, cfg = tiny_engine
     reqs = _pressure_trace(cfg, seed=11)
-    seq = _sequential(engine, reqs)
+    seq = sequential(engine, reqs)
     srv = ServingEngine(engine, spec_tokens=3, host_blocks=64,
                         swap_batch=4, **_PRESSURE_KW)
     out = srv.serve(reqs)

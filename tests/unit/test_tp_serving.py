@@ -29,6 +29,7 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.inference.serving import Request, ServingEngine
 from deepspeed_tpu.models import gpt2, llama
+from tiny import assert_sequential
 
 
 def _mk_engine(tp, cfg):
@@ -39,13 +40,13 @@ def _mk_engine(tp, cfg):
 
 
 @pytest.fixture(scope="module")
-def tiny_cfg():
-    return gpt2.GPT2Config.tiny(max_seq_len=128)
+def tiny_cfg(tiny):
+    return tiny[1]
 
 
 @pytest.fixture(scope="module")
-def tp1_engine(tiny_cfg):
-    return _mk_engine(1, tiny_cfg)
+def tp1_engine(tiny):
+    return tiny[2]
 
 
 @pytest.fixture(scope="module")
@@ -90,11 +91,7 @@ def test_tp4_parity_prefix_heavy_and_pool_shards(tp1_engine, tp4_engine,
         assert leaf.shape[2] == hkv
         for shard in leaf.addressable_shards:
             assert shard.data.shape[2] == hkv // 4
-    for r in _trace(tiny_cfg, 6, seed=0):
-        want = tp1_engine.generate(r.prompt[None, :],
-                                   max_new_tokens=r.max_new_tokens)[0]
-        np.testing.assert_array_equal(r1[r.uid], want, err_msg=f"tp1 {r.uid}")
-        np.testing.assert_array_equal(r4[r.uid], want, err_msg=f"tp4 {r.uid}")
+    assert_sequential(tp1_engine, _trace(tiny_cfg, 6, seed=0), r1, r4)
     assert s4.compile_count == 2, s4.compiled_programs
     # scheduler state is head-sharding-invariant: identical counters
     assert s4.prefix_hit_tokens == s1.prefix_hit_tokens
@@ -266,10 +263,7 @@ def test_draft_pool_shards_with_target(tp4_engine, tiny_cfg):
     reqs = _trace(tiny_cfg, 4, seed=2)
     res = srv.serve(reqs)
     assert srv.compile_count <= 3, srv.compiled_programs
-    for r in reqs:
-        want = tp4_engine.generate(r.prompt[None, :],
-                                   max_new_tokens=r.max_new_tokens)[0]
-        np.testing.assert_array_equal(res[r.uid], want, err_msg=f"{r.uid}")
+    assert_sequential(tp4_engine, reqs, res)
 
 
 def test_tiered_mixed_sharding_sharded_target_replicated_draft(tp4_engine,
@@ -291,10 +285,7 @@ def test_tiered_mixed_sharding_sharded_target_replicated_draft(tp4_engine,
     st = srv.stats()
     assert st["swap_out"] > 0 and st["swap_in"] > 0
     assert srv.compile_count <= srv.compile_budget == 5
-    for r in reqs:
-        want = tp4_engine.generate(r.prompt[None, :],
-                                   max_new_tokens=r.max_new_tokens)[0]
-        np.testing.assert_array_equal(res[r.uid], want, err_msg=f"{r.uid}")
+    assert_sequential(tp4_engine, reqs, res)
 
 
 def test_draft_indivisible_heads_raise_with_shard_kv(tp4_engine, tiny_cfg):

@@ -51,6 +51,7 @@ def _tiny(smoke):
         serving_kwargs={"block_size": 8, "prefill_chunk": 16})
 
 
+@pytest.mark.limit(360)   # every smoke kernel, interpreted: 110 s of 152 (PR 43)
 def test_phases_run_at_tiny_widths_on_cpu(smoke):
     ok, report = smoke.run_phases(_tiny(smoke))
     assert ok, report["phases"]
