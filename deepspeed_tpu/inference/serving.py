@@ -179,7 +179,12 @@ paged-state invariants are guaranteed to hold.  ``drain()`` quiesces the
 engine for a replica handoff: every active slot preempts (with the host
 tier, committed blocks demote first), the remaining prefix-cache content
 demotes, and the whole pending queue is handed back for re-submission
-elsewhere (``deepspeed_tpu/serving/`` routes it).  The batch
+elsewhere (``deepspeed_tpu/serving/`` routes it).  From PR 44 the
+scheduler keeps ONE call of lookahead (:meth:`ServingEngine._launch`): the
+tokens of call n reach their handles during ``step()`` n+1, behind the
+enqueue of call n+1, and ``step()`` says ``False`` only once nothing is in
+flight; ``drain()`` / ``close()`` / a cancel / a preemption take the
+results first, ``salvage()`` leaves them (they were never streamed).  The batch
 ``serve(list)`` entry point survives as a thin wrapper — submit all,
 loop ``step()``, gather results — with byte-identical scheduling, and
 tolerates an empty request list without tracing anything.  Admission
@@ -316,6 +321,18 @@ STALL_WARN_EVERY_S = 10.0
 STALL_CAUSES = ("offcpu", "gc", "device_wait", "host")
 #: what a stalled step is compared in (``ServingEngine._stall``)
 _STALL_FIELDS = ("wall",) + SEGMENTS + ("offcpu", "gc")
+#: why a call's results were taken before the next call was on the device's
+#: queue (:meth:`ServingEngine._settle`): what the engine is (its audits, a
+#: runner or a tier with a fence of its own, a replica that hands its rows
+#: on), what its rows need (a mask made on the host from their tokens), or
+#: what is about to be done to a row that is in flight
+EARLY_SETTLE_CAUSES = ("debug_checks", "speculative", "fused", "kv_tier",
+                       "handoff", "mask_builder", "preempt", "cancel",
+                       "drain", "close")
+#: a decode row's entry in the call's token operand when its input token is
+#: the one the call before made and the host has not seen: the program
+#: takes the row's entry of the engine's device-resident token vector
+TOKEN_ON_DEVICE = -1
 
 #: legal ``quantize=`` values (order-normalized; ``None`` = full precision)
 _QUANT_MODES = ("kv8", "w8a8", "w8a8+kv8")
@@ -699,6 +716,11 @@ class _SlotState:
     #: window (blocks in [landmark, window_blk) are demoted + masked);
     #: stays 0 when resident_window_blocks == 0 or nothing has slid yet
     window_blk: int = 0
+    #: tokens of this request a call on the device has made and the host
+    #: has not harvested (``ServingEngine._settle``): they are in no list
+    #: yet, but the cache length, the sampler's count and the budget of the
+    #: next call are planned as if they were
+    ahead: int = 0
 
     @property
     def plen_eff(self) -> int:
@@ -718,46 +740,20 @@ class _SlotState:
         return self.plen_eff - len(self.prior) + self.req.max_new_tokens
 
 
-class _InFlight:
-    """``with`` block of :meth:`ServingEngine._in_flight`; yields the
-    span's argument dict.  What :meth:`TraceTimeline.span` does (an ``X``
-    event and the annotation ``ds.serve.<name>``) with the span's own
-    bookkeeping INSIDE its interval: the start is the first thing read,
-    the end the last but for the event itself, and the commit segment
-    that follows begins at that end (:meth:`TraceTimeline.lap`).  With
-    the blocking puts gone the host's own time in a phase is ~0.6 ms, and
-    the microseconds on either side of a call were a tenth of it."""
+class _Flight:
+    """A call the device has been handed whose results the host has not
+    taken: the scheduler's ONE call of lookahead
+    (:meth:`ServingEngine._launch` / :meth:`ServingEngine._settle`).
+    ``args`` is the argument dict of its in-flight span (``decode`` /
+    ``prefill``), ``out`` the flat tokens-and-record array it returns,
+    ``shape`` that of the tokens in it, ``commit`` what the harvest hands
+    the tokens to, ``held`` the call's operands, kept until then."""
 
-    __slots__ = ("_srv", "_name", "_args", "_note", "_start", "_t0", "_cpu0")
+    __slots__ = ("name", "args", "out", "shape", "commit", "held")
 
-    def __init__(self, srv, name, args):
-        self._srv, self._name, self._args = srv, name, args
-
-    def __enter__(self):
-        tl = self._srv.timeline
-        if tl.enabled:
-            self._start = tl.now_us()
-            self._srv._seg_args.append(self._args)
-        self._note = trace_mod.annotation(f"ds.{tl.role}.{self._name}")
-        self._note.__enter__()
-        if tl.enabled:
-            tl.lap()                       # its first segment begins now
-            self._t0, self._cpu0 = time.perf_counter(), time.thread_time()
-        return self._args
-
-    def __exit__(self, *exc):
-        srv = self._srv
-        tl = srv.timeline
-        if tl.enabled:
-            step = srv._step_args
-            step["flight_cpu_s"] += time.thread_time() - self._cpu0
-            step["flight_s"] += time.perf_counter() - self._t0
-        self._note.__exit__(*exc)
-        if tl.enabled:
-            end = tl.now_us()
-            tl.complete(self._name, self._start, end_us=end, **self._args)
-            tl.lap(end)                    # the commit begins at its end
-        return False
+    def __init__(self, name, args, shape, commit):
+        self.name, self.args, self.shape = name, args, shape
+        self.commit, self.out, self.held = commit, None, None
 
 
 class ServingEngine:
@@ -1398,6 +1394,13 @@ class ServingEngine:
         self._held: List[List[int]] = [[] for _ in range(self.slots)]
         self._tokens = np.zeros(self.slots, np.int32)
         self._lengths = np.zeros(self.slots, np.int32)
+        #: every slot's newest token where the device made it: the decode
+        #: program returns it, the prefill program writes its rows' first
+        #: tokens into it, and the decode program reads a row's input from
+        #: it where the host has not harvested that token yet
+        #: (``TOKEN_ON_DEVICE``) — ONE ``[slots]`` int32 vector whatever
+        #: program made it, committed like the pool
+        self._devtok = jax.device_put(np.zeros(self.slots, np.int32), rep)
         #: resident-window serving: per-slot first attention-visible token
         #: past the landmark prefix (== landmark span while nothing has
         #: been demoted; rows of idle slots stay 0 and are never read by a
@@ -1669,6 +1672,19 @@ class ServingEngine:
                 "shape, by what explains most of the excess",
                 cause=cause)
             for cause in STALL_CAUSES}
+        self._c_calls = m.counter(
+            "serving_calls_total",
+            "decode and prefill calls put on the device's queue")
+        self._c_calls_ahead = m.counter(
+            "serving_calls_ahead_total",
+            "of them, put there while the call before was still in flight")
+        self._c_early_settles = {
+            cause: m.counter(
+                "serving_early_settles_total",
+                "calls whose results were taken before the next call was "
+                "enqueued, by what needed them",
+                cause=cause)
+            for cause in EARLY_SETTLE_CAUSES}
         # tiered-KV swap traffic (zero-valued, never incremented when the
         # tier is off — the cells exist so dashboards see a stable schema)
         self._c_swap_out = m.counter(
@@ -1779,6 +1795,15 @@ class ServingEngine:
         #: argument dicts of this step's phase and in-flight spans, for
         #: the stall check's per-segment sums
         self._seg_args: List[Dict[str, Any]] = []
+        #: the call on the device's queue whose results the host has not
+        #: taken (:meth:`_launch` / :meth:`_settle`): at most ONE
+        self._flight: Optional[_Flight] = None
+        #: while the host is inside the runtime for a call (handing one
+        #: over, waiting for one's results): what :meth:`_enter_runtime`
+        #: read as it went in
+        self._runtime = None
+        #: whether this step has put a call on the device's queue
+        self._launched = False
         #: per step shape (with / without a prefill group): the last
         #: ``STALL_HISTORY`` steps (``_note_step``'s rows) and the duration
         #: over which a step of that shape is a stall (None: too few yet)
@@ -1867,6 +1892,7 @@ class ServingEngine:
         unlink an auto-minted spill file (a caller-provided ``nvme_path``
         is the caller's to keep).  Idempotent; the device pool and
         compiled programs are garbage-collected as usual."""
+        self._settle("close")
         self._gc.remove()
         tl = self.timeline
         if tl.dropped and not self._closed:
@@ -1912,6 +1938,15 @@ class ServingEngine:
         if self.sp_degree > 1:
             return sp_attention.sp_context(self.engine.mesh)
         return contextlib.nullcontext()
+
+    def _prefill_ctx(self):
+        """:meth:`_tp_ctx` plus, for a prefill that is not fused with a
+        draft's, :meth:`_sp_ctx`."""
+        stack = contextlib.ExitStack()
+        stack.enter_context(self._tp_ctx())
+        if self._draft is None:
+            stack.enter_context(self._sp_ctx())
+        return stack
 
     def _decode_ctx(self):
         """:meth:`_tp_ctx` plus the dp grouping for ``engine_mode='dp_tp'``:
@@ -2058,6 +2093,16 @@ class ServingEngine:
         return jax.tree_util.tree_map(
             lambda x: jax.lax.with_sharding_constraint(x, sharding), cache)
 
+    def _pin_tokens(self, tokens):
+        """Traced, on a mesh: the device-resident token vector leaves every
+        program as the engine committed it (replicated) — an input's
+        sharding is part of the jit cache key, as for the pool
+        (:meth:`_constrain_pool`)."""
+        if self.engine.mesh.size == 1:
+            return tokens
+        return jax.lax.with_sharding_constraint(
+            tokens, NamedSharding(self.engine.mesh, P()))
+
     def _forward(self, *args, **kwargs):
         """Traced: the model's cached forward as ``(logits, cache,
         record)`` — ``record`` the int32 ``[L, 3]`` routing record of an
@@ -2187,7 +2232,10 @@ class ServingEngine:
         args = (self._temps, self._topks, self._topps, self._seeds,
                 np.asarray(counts, np.int32))
         if self.logit_masks:
-            args += (self._masks,)
+            # a snapshot, as of the call's one buffer (``OperandLayout.fill``
+            # has why): the matrix is written again (a released row) while
+            # the call is still in flight
+            args += (self._masks.copy(),)
         return args
 
     def _decode_counts(self):
@@ -2196,7 +2244,7 @@ class ServingEngine:
         counts = np.zeros(self.slots, np.int32)
         for slot, st in self._active.items():
             if st.phase == "decode":
-                counts[slot] = st.gen_count
+                counts[slot] = st.gen_count + st.ahead
         return counts
 
     def _samp_args_rows(self, group, rows):
@@ -2216,7 +2264,8 @@ class ServingEngine:
             topks[row] = self._topks[slot]
             topps[row] = self._topps[slot]
             seeds[row] = self._seeds[slot]
-            counts[row] = self._active[slot].gen_count
+            st = self._active[slot]
+            counts[row] = st.gen_count + st.ahead
         args = (temps, topks, topps, seeds, counts)
         if self.logit_masks:
             masks = np.ones((rows, self._vocab), bool)
@@ -2431,9 +2480,28 @@ class ServingEngine:
                 self.slots, {"tokens": None, "lengths": None}, tail)
             if K > 1:
                 spec["active"] = jax.ShapeDtypeStruct((self.slots,), bool)
+            call, n_dev = body_fn, 2
+            if K == 1:
+                slots, pin = self.slots, self._pin_tokens
+
+                @functools.wraps(body_fn)  # the program keeps its name
+                def decode_ahead(params, cache, devtok, tokens, *rest):
+                    """``body_fn`` on the device-resident token vector: a
+                    row whose entry of ``tokens`` says ``TOKEN_ON_DEVICE``
+                    is fed its entry of ``devtok``, the token the call
+                    before made; the tokens this call makes are the new
+                    vector, beside the flat array the host copies back."""
+                    flat, cache = body_fn(
+                        params, cache,
+                        jnp.where(tokens == TOKEN_ON_DEVICE, devtok, tokens),
+                        *rest)
+                    return flat, cache, pin(flat[:slots])
+
+                call, n_dev = decode_ahead, 3
             self._decode_fn = jax.jit(
-                self.sentry.wrap(self._packed("decode", body_fn, spec),
-                                 "decode"),
+                self.sentry.wrap(
+                    self._packed("decode", call, spec,
+                                 device_operands=n_dev), "decode"),
                 donate_argnums=self._donate())
             self.compiled_programs.append(
                 ("decode", self.slots) if K == 1
@@ -2511,11 +2579,28 @@ class ServingEngine:
         spec = self._operand_spec(
             self.prefill_batch, {"ids": width},
             ("base", "valid") + (("window_start",)
-                                 if self.resident_window_blocks else ()))
+                                 if self.resident_window_blocks else ())
+            + ("slot",))
+        n_dev = 2 if draft is None else 4
+        at = list(spec).index("slot")
+        j, pin = self.prefill_batch, self._pin_tokens
+
+        @functools.wraps(body)             # the program keeps its name
+        def prefill_ahead(*args):
+            """``body`` writing its rows' tokens into the device-resident
+            token vector (the operand behind the pools) at ``slot``, each
+            row's slot — a pad row's is out of range and dropped; a row
+            with prompt left writes a token nobody reads.  The vector is
+            returned last."""
+            devtok, rest = args[n_dev], args[n_dev + 1:]
+            out = body(*args[:n_dev], *rest[:at], *rest[at + 1:])
+            return (*out, pin(devtok.at[rest[at]].set(out[0][:j],
+                                                      mode="drop")))
+
         self._prefill_fn = jax.jit(
             self.sentry.wrap(
-                self._packed("prefill", body, spec,
-                             device_operands=2 if draft is None else 4),
+                self._packed("prefill", prefill_ahead, spec,
+                             device_operands=n_dev + 1),
                 f"prefill[w{width}]"),
             donate_argnums=donate)
         self.compiled_programs.append(
@@ -3400,6 +3485,14 @@ class ServingEngine:
                         self._kv_scale_live.discard(evicted)
                         self._evicted_blocks += 1   # the phase's event
                         continue
+            if self._flight is not None:
+                # a victim is chosen among committed rows: the call in
+                # flight may finish some (their blocks come free) and its
+                # rows' tokens fold into a victim's resume prompt
+                self._settle("preempt")
+                if requester not in self._active:
+                    return None
+                continue
             cands = self._active if self.dp_degree == 1 else \
                 {s: st for s, st in self._active.items()
                  if self._slot_group(s) == grp}
@@ -3789,6 +3882,7 @@ class ServingEngine:
         the trie), and emit the audited ``cancelled`` event."""
         if not self._cancel_flags:
             return
+        self._settle("cancel")             # a cancelled row may be in flight
         flags, self._cancel_flags = self._cancel_flags, set()
         for uid in flags:
             slot = next((s for s, st in self._active.items()
@@ -3816,13 +3910,25 @@ class ServingEngine:
         work remains — drive it in a loop (``serve``), from a replica
         worker thread (``deepspeed_tpu/serving/``), or by hand.
 
+        ONE call of lookahead (:meth:`_launch`): a decode or prefill call
+        is put on the device's queue BEFORE the results of the call before
+        it are taken, so ``step()`` returns with its last call in flight
+        and that call's tokens reach their handles early in the NEXT
+        ``step()``, once the next call is enqueued; a step with nothing to
+        enqueue takes them itself, so ``False`` is only ever said by a
+        settled engine.  What a plan needs of committed state settles the
+        call at once instead (``EARLY_SETTLE_CAUSES``) — today's order,
+        chosen from what the engine can see, by no option.
+
         On the timeline (and, while a profile is being taken, on the
         profiler's clock as ``ds.serve.*``) an iteration is one ``step``
         span tiled by four host phases — ``step.admit``, ``step.prefill``,
         ``step.decode``, ``step.post`` — inside which the in-flight spans
-        (``prefill``, ``decode``, ``spec_*``, ``swap``) mark when a device
-        program is running: a phase's time outside them is host time the
-        device sits idle for."""
+        (``prefill``, ``decode``, ``spec_*``, ``swap``) mark the host's
+        stays in the runtime, handing a program over and waiting for one's
+        results: a phase's time outside them is the host's own work, which
+        the device sits idle for only when no call is in flight behind
+        it (``ahead`` on the span, ``stats()["lookahead"]``)."""
         if self._fault_injector is not None:
             # chaos harness (serving/faults.py): may raise SimulatedCrash
             # (the router/worker converts it into fail-and-re-home),
@@ -3874,6 +3980,7 @@ class ServingEngine:
             if not self._pending and not self._active:
                 return self._step_idle()   # the cancellations emptied it
             params = self.engine.params
+            self._launched = False
             self._c_iterations.inc()
             self.timeline.step = self.iterations
             admitted0, preempted0 = self.admitted, self.preempted
@@ -3916,6 +4023,12 @@ class ServingEngine:
             # resident-window maintenance AFTER both phases committed this
             # iteration's tokens: mid-prefill giant prompts slide too (the
             # next chunk's program then masks the demoted middle)
+            if not self._launched or not self._active:
+                # nothing to hand the device: the rows wait for the tokens
+                # of the call that is in flight (budgets, finishes) — or
+                # every row of it has finished (it carries a row whose eos
+                # was seen behind its enqueue): nothing is left to plan
+                self._settle()
             self._slide_windows()
             if self._host is not None:
                 # stage next iteration's promotions NOW: the H2D copies
@@ -3958,15 +4071,158 @@ class ServingEngine:
                               demoted=0)
         self._evicted_blocks = 0
 
+    def _enter_runtime(self, name: str) -> None:
+        """The host goes into the runtime on a call's behalf — to hand one
+        to the device, to wait for one's results — under the annotation
+        ``ds.serve.<name>``; :meth:`_leave_runtime` ends the stay.  Ring
+        on, a stay's wall and thread-CPU seconds are added to the step's
+        ``flight_s`` / ``flight_cpu_s``: the step's duration and ``cpu_s``
+        less these are the host's OWN time and the CPU it got for it."""
+        tl = self.timeline
+        start = tl.now_us() if tl.enabled else 0.0
+        note = trace_mod.annotation(f"ds.{tl.role}.{name}")
+        note.__enter__()
+        t0 = cpu0 = 0.0
+        if tl.enabled:
+            tl.lap()                       # its first segment begins now
+            t0, cpu0 = time.perf_counter(), time.thread_time()
+        self._runtime = (start, note, t0, cpu0)
+
+    def _leave_runtime(self, name: str, args: Dict[str, Any]) -> None:
+        """The stay ends, with the results of call ``name`` on the host:
+        it is that call's in-flight span on the ring (``args``)."""
+        (start, note, t0, cpu0), self._runtime = self._runtime, None
+        tl = self.timeline
+        if tl.enabled:
+            step = self._step_args
+            step["flight_cpu_s"] += time.thread_time() - cpu0
+            step["flight_s"] += time.perf_counter() - t0
+        note.__exit__(None, None, None)
+        if tl.enabled:
+            end = tl.now_us()
+            tl.complete(name, start, end_us=end, **args)
+            tl.lap(end)                    # the commit begins at its end
+
+    @contextlib.contextmanager
     def _in_flight(self, name: str, **args):
-        """An in-flight span (:meth:`step`): a device program runs from the
-        jitted call inside it to its results on the host.  Ring on, the
-        span's wall and thread-CPU seconds are also added to the step's
-        ``flight_s`` / ``flight_cpu_s`` — the step's duration and ``cpu_s``
-        less these are the host's own time and the CPU it got for it.
-        The runners make it inside their ``upload`` segment and enter it
-        next, so nothing but two clock reads lies between the two."""
-        return _InFlight(self, name, args)
+        """An in-flight span (:meth:`step`) around a call that is made and
+        harvested in one stay in the runtime — the speculative and fused
+        runners, which keep their own fence; yields the span's argument
+        dict.  The runners make it inside their ``upload`` segment and
+        enter it next, so nothing but two clock reads lies between."""
+        self._enter_runtime(name)
+        if self.timeline.enabled:
+            self._seg_args.append(args)
+        try:
+            yield args
+        finally:
+            self._leave_runtime(name, args)
+
+    def _settle_now(self) -> Optional[str]:
+        """Why the call just enqueued cannot stay in flight while the next
+        one is planned (``EARLY_SETTLE_CAUSES``), from what the engine is
+        and what its rows carry — or None: the plan of the next call needs
+        nothing of this one that the host does not know."""
+        if self.debug_checks:
+            return "debug_checks"          # the audit reads committed state
+        if self.spec_tokens:
+            return "speculative"           # a round plans on its tokens
+        if self._K > 1:
+            return "fused"
+        if self._host is not None or self.resident_window_blocks:
+            return "kv_tier"               # demotions key blocks by tokens
+        if self.role == "prefill":
+            return "handoff"               # a finished prompt leaves now
+        if self._masks is not None and any(
+                st.req.mask_builder is not None
+                for st in self._active.values()):
+            return "mask_builder"          # a host function of the tokens
+        return None
+
+    def _launch(self, flight: _Flight, fn, ctx) -> None:
+        """Hand ``flight``'s call (``fn`` on ``flight.held``, the runner's
+        frame keeping none of it) to the device, THEN take the results of
+        the call before it (:meth:`_harvest`): the device goes from one
+        program into the next while the host harvests, commits and plans.
+        The caller has advanced what is certain of the call's outcome
+        (lengths, bases, phases, ``ahead``); what is not — the tokens —
+        stays on the device (``_devtok``) until this call is settled in
+        its turn, at once if :meth:`_settle_now` names a cause.
+
+        On the ring a call's ``decode`` / ``prefill`` span is the stay in
+        the runtime that ENDED with its results on the host: from the
+        enqueue that preceded its harvest — the next call's when it was
+        ``ahead`` of this harvest, this call's own when it was settled at
+        once — to the end of the harvest; the host's own work between two
+        stays (plan, upload, commit) lies outside every such span, as it
+        always did.  ``enqueue_s`` is the call's own hand-over, ``wait_s``
+        how long its harvest blocked."""
+        tl = self.timeline
+        before, self._flight = self._flight, flight
+        cause = self._settle_now()
+        # a harvest follows the enqueue, of the call before or of this one:
+        # one stay in the runtime.  Else (the first call of an idle engine)
+        # the call is handed over with the device idle, as its operands
+        # were packed: on the phase's ``upload`` segment
+        stay = before is not None or cause is not None
+        if stay:
+            self._enter_runtime((before or flight).name)
+        # the step that made the call, not the one that harvests it
+        flight.args.update(ahead=int(before is not None),
+                           step=self.iterations)
+        self._c_calls.inc()
+        self._c_calls_ahead.inc(flight.args["ahead"])
+        self._launched = True
+        enqueued: Dict[str, float] = {}
+        with contextlib.nullcontext() if stay else tl.segment(
+                f"step.{flight.name}.upload", self._phase), \
+                tl.segment(f"{flight.name}.enqueue", enqueued), ctx:
+            out = fn(*flight.held)
+        flight.out, self._cache, self._devtok = out[0], out[1], out[-1]
+        if len(out) == 4:                  # the prefill fused with a draft's
+            self._dcache = out[2]
+        del out
+        if tl.enabled:
+            flight.args["enqueue_s"] = enqueued["enqueue_s"]
+            if stay:
+                self._seg_args.append(enqueued)
+        if before is not None:
+            self._harvest(before)
+        if cause is not None:
+            self._settle(cause)
+
+    def _settle(self, cause: Optional[str] = None) -> None:
+        """Take the results of the call in flight, if one is, and commit
+        them.  ``cause`` says what needed them before the next call was
+        enqueued (``EARLY_SETTLE_CAUSES``) and is counted; without one
+        this is a step that has nothing to enqueue."""
+        flight, self._flight = self._flight, None
+        if flight is None:
+            return
+        if cause is not None:
+            self._c_early_settles[cause].inc()
+        self._harvest(flight)
+
+    def _harvest(self, flight: _Flight) -> None:
+        """``flight``'s tokens onto the host, and their commit: the plain
+        path's ONE fence (:meth:`_launch` behind the next call's enqueue,
+        :meth:`_settle` wherever else)."""
+        tl = self.timeline
+        if self._runtime is None:
+            self._enter_runtime(flight.name)
+        waited: Dict[str, float] = {}
+        with tl.segment(f"{flight.name}.wait", waited):
+            out = self._split_record(np.asarray(flight.out), flight.shape,
+                                     flight.args)
+        if tl.enabled:
+            flight.args["wait_s"] = waited["wait_s"]
+            self._seg_args.append(waited)
+        self._leave_runtime(flight.name, flight.args)
+        with tl.segment(f"step.{flight.name}.commit", self._phase):
+            # the call's operands and results are released here, on the
+            # commit's account
+            flight.held = flight.out = None
+            flight.commit(out)
 
     def _note_step(self, step_args: Dict[str, Any], wall_s: float) -> None:
         """Ring on, after every step: file it under its shape — duration,
@@ -4036,6 +4292,7 @@ class ServingEngine:
         (``ReplicaRouter._submit_item`` on another replica).  After a
         drain the device pool is fully free; the host tier is the
         replica's exportable session store (``host_chain_export``)."""
+        self._settle("drain")
         self._process_cancellations()
         for slot in sorted(self._active,
                            key=lambda s: -self._active[s].admit_seq):
@@ -4084,6 +4341,10 @@ class ServingEngine:
         :meth:`drain` produces.  Deferred cancel flags are honored: a
         cancelled request resolves here instead of re-homing."""
         cancels, self._cancel_flags = self._cancel_flags, set()
+        # a call in flight is left where it is: the device is not to be
+        # trusted, and its tokens were never streamed — the survivor makes
+        # them again (greedy, or the same (seed, count) key)
+        self._flight = None
         items: List[_PendingItem] = []
         for slot in sorted(self._active,
                            key=lambda s: self._active[s].admit_seq):
@@ -4410,60 +4671,76 @@ class ServingEngine:
         Every runner times its pieces as segments (``SEGMENTS``): ``plan``
         / ``upload`` / ``commit`` onto ``self._phase``, the argument dict
         of the host phase it runs in, ``enqueue`` / ``wait`` onto its
-        in-flight span."""
+        in-flight span.
+
+        The call is planned while the one before it may still be in flight
+        (:meth:`_launch`): a row whose newest token is on the device only
+        (``ahead``) is fed from there (``TOKEN_ON_DEVICE``), at the length
+        and the sampler's count that token gives it, and left out if that
+        token spends its budget.  Whether it is its ``eos`` the host
+        cannot know: such a row rides once more, into a block its slot
+        holds, and :meth:`_commit_decode` drops what it made."""
         active = self._active
         seg, phase = self.timeline.segment, self._phase
+
+        def rows():
+            return [s for s, st in active.items() if st.phase == "decode"
+                    and st.gen_count + st.ahead < st.req.max_new_tokens]
+
         with seg("step.decode.plan", phase):
-            dec = sorted(
-                (s for s, st in active.items() if st.phase == "decode"),
-                key=lambda s: active[s].admit_seq)
-            for slot in dec:
+            for slot in sorted(rows(), key=lambda s: active[s].admit_seq):
                 if slot in active:
                     self._kv(self._ensure_blocks, slot,
                              int(self._lengths[slot]) + 1)
-            dec = sorted(s for s, st in active.items()
-                         if st.phase == "decode")
+            dec = sorted(rows())
             if not dec:
                 return 0
             bt = np.zeros_like(self._tables)
             bt[dec] = self._tables[dec]
+            tokens = self._tokens.copy()
+            tokens[[s for s in dec if active[s].ahead]] = TOKEN_ON_DEVICE
             counts = self._decode_counts()
             decode_fn = self._get_decode_fn()
             span_kw = {**self._sampler_rows(dec),
                        **self._kv_reach(self._lengths[dec] + 1)}
         with seg("step.decode.upload", phase):
             host, puts = self._host_operands(
-                "decode", self._tokens, self._lengths, self._bt(bt),
+                "decode", tokens, self._lengths, self._bt(bt),
                 *((self._window_start,) if self.resident_window_blocks
                   else ()), *self._samp_args(counts))
-            args = (params, self._cache, *host)
-            flight = self._in_flight("decode", slots=len(dec), **puts,
-                                     **span_kw)
-        with flight as span_args:
-            with seg("decode.enqueue", span_args), self._decode_ctx():
-                nxt, self._cache = decode_fn(*args)
-            with seg("decode.wait", span_args):
-                nxt = self._split_record(np.asarray(nxt), (self.slots,),
-                                         span_args)
-        with seg("step.decode.commit", phase):
-            # the call's operands are released here, on the commit's
-            # account, not when the frame dies outside every segment
-            del args, host
-            self._c_decode_steps.inc()
-            for slot in dec:
-                st = active[slot]
-                self._lengths[slot] += 1   # the fed token is now cached
-                tok = int(nxt[slot])
-                st.out.append(tok)
-                self._emit_tokens(st, (tok,))
-                self._mark_first(st)
-                if (st.eos is not None and tok == st.eos) \
-                        or st.gen_count >= st.req.max_new_tokens:
-                    self._finish_slot(slot)
-                else:
-                    self._tokens[slot] = tok
-            del nxt                        # and the call's results
+            fed = [(slot, active[slot]) for slot in dec]
+            flight = _Flight("decode", dict(slots=len(dec), **puts,
+                                            **span_kw), (self.slots,),
+                             functools.partial(self._commit_decode, fed))
+            for slot, st in fed:
+                self._lengths[slot] += 1   # the fed token will be cached
+                st.ahead += 1
+            flight.held = (params, self._cache, self._devtok, *host)
+            del host
+            ctx = self._decode_ctx()
+        self._launch(flight, decode_fn, ctx)
         return len(dec)
+
+    def _commit_decode(self, rows, nxt) -> None:
+        """The commit loop of :meth:`_run_plain_decode`, when the call's
+        tokens are on the host.  A row whose request finished while this
+        call was in flight (the token before was its ``eos``) is dropped:
+        nothing of it is emitted or counted."""
+        active = self._active
+        self._c_decode_steps.inc()
+        for slot, st in rows:
+            st.ahead -= 1
+            if active.get(slot) is not st:
+                continue
+            tok = int(nxt[slot])
+            st.out.append(tok)
+            self._emit_tokens(st, (tok,))
+            self._mark_first(st)
+            if (st.eos is not None and tok == st.eos) \
+                    or st.gen_count >= st.req.max_new_tokens:
+                self._finish_slot(slot)
+            else:
+                self._tokens[slot] = tok
 
     def _fence_harvest(self, *arrays):
         """The fused decode path's ONE host<->device synchronization point
@@ -4832,38 +5109,70 @@ class ServingEngine:
                 ws = np.zeros(j, np.int32)
                 ws[:len(group)] = self._window_start[list(group)]
                 operands.append(ws)
+            # where each row's token goes in the device-resident vector;
+            # a pad row's slot is out of range
+            at = np.full(j, self.slots, np.int32)
+            at[:len(group)] = group
             host, puts = self._host_operands(
-                "prefill", *operands, *self._samp_args_rows(group, j))
+                "prefill", *operands, at, *self._samp_args_rows(group, j))
             if self._draft is not None:
                 args = (params, self._draft.params, self._cache,
-                        self._dcache, *host)
+                        self._dcache, self._devtok, *host)
             else:
-                args = (params, self._cache, *host)
-            flight = self._in_flight("prefill", **puts, **span_kw)
-        with flight as span_args:
-            with seg("prefill.enqueue", span_args), self._tp_ctx():
-                if self._draft is not None:
-                    first, self._cache, self._dcache = prefill_fn(*args)
-                else:
-                    with self._sp_ctx():
-                        first, self._cache = prefill_fn(*args)
-            with seg("prefill.wait", span_args):
-                first = self._split_record(np.asarray(first), (j,),
-                                           span_args)
-        with seg("step.prefill.commit", phase):
-            del args, host                 # released on the commit's account
-            self._commit_prefill_group(group, rows, first)
-            del first                      # and the call's results
+                args = (params, self._cache, self._devtok, *host)
+            del host
+            flight = _Flight(
+                "prefill", dict(**puts, **span_kw), (j,),
+                functools.partial(self._commit_prefill_group, len(group),
+                                  self._advance_prefill_rows(rows)))
+            flight.held = args
+            del args
+            ctx = self._prefill_ctx()
+        self._launch(flight, prefill_fn, ctx)
 
-    def _commit_prefill_group(self, group, rows, first) -> None:
-        """The commit loop of :meth:`_run_prefill_group`: advance each
-        row's slot; a row that reached its last prompt token registers its
-        full blocks with the trie and emits its first token."""
+    def _advance_prefill_rows(self, rows):
+        """What is certain of a prefill call as it is enqueued: each row's
+        slot has its chunk cached; a row that reached its last prompt token
+        is a decode row from here, its first token ``ahead``.  Returns
+        those rows as ``(row, slot, state, emits)``, for the commit."""
+        done = []
+        for row, (slot, v) in enumerate(rows):
+            st = self._active[slot]
+            st.base += v
+            if st.base < st.plen_eff:
+                continue                   # more chunks to go
+            st.phase = "decode"
+            emits = not (self.spec_tokens and self.sampling and st.prior
+                         and st.req.sampled)
+            if emits:
+                self._lengths[slot] = st.plen_eff
+                st.ahead += 1
+            else:
+                # spec-sampled RESUME: the original stream's token at
+                # emission index len(prior) came out of a verify round
+                # (accept/residual salts), not the prefill TOKEN salt —
+                # so don't emit here.  Back up one position instead: feed
+                # the last resumed token as the pending window head with
+                # lengths = plen_eff - 1 (the verify scatter rewrites
+                # that position's KV with identical values), and the next
+                # round starts at count len(prior) — the exact boundary
+                # the original round structure had, so replay is
+                # round-identical and token-exact
+                self._tokens[slot] = int(st.prompt_eff[-1])
+                self._lengths[slot] = st.plen_eff - 1
+            done.append((row, slot, st, emits))
+        return done
+
+    def _commit_prefill_group(self, nrows, done, first) -> None:
+        """The commit loop of :meth:`_run_prefill_group`, when the call's
+        tokens are on the host: a row that reached its last prompt token
+        (``done``, :meth:`_advance_prefill_rows`) registers its full
+        blocks with the trie and emits its first token."""
         active = self._active
         width = self.prefill_chunk
         if self.sp_degree > 1:
             nbytes = sp_attention.alltoall_bytes(
-                int(self._pool_shape[0]), len(group), width,
+                int(self._pool_shape[0]), nrows, width,
                 getattr(self.engine.module.model_config, "num_heads",
                         int(self._pool_shape[2])),
                 int(self._pool_shape[4]),
@@ -4871,15 +5180,13 @@ class ServingEngine:
                 self.sp_degree)
             self._c_sp_a2a_bytes.inc(nbytes)
             self.timeline.instant("sp_prefill", width=width,
-                                  rows=len(group), bytes=nbytes,
+                                  rows=nrows, bytes=nbytes,
                                   sp=self.sp_degree)
         self._c_prefill_calls.inc()
-        for row, (slot, v) in enumerate(rows):
-            st = active[slot]
-            st.base += v
-            if st.base < st.plen_eff:
-                continue                   # more chunks to go
-            st.phase = "decode"
+        for row, slot, st, emits in done:
+            st.ahead -= emits
+            if active.get(slot) is not st:
+                continue
             if self._prefix is not None:
                 # cache the prompt's FULL blocks (the trailing partial block
                 # will also hold generated tokens — never shared)
@@ -4897,27 +5204,13 @@ class ServingEngine:
                 if nfull:
                     self._kv(self._prefix.register, st.prompt_eff,
                              self._tables[slot, :nfull], self._alloc)
-            if self.spec_tokens and self.sampling and st.prior \
-                    and st.req.sampled:
-                # spec-sampled RESUME: the original stream's token at
-                # emission index len(prior) came out of a verify round
-                # (accept/residual salts), not the prefill TOKEN salt —
-                # so don't emit here.  Back up one position instead: feed
-                # the last resumed token as the pending window head with
-                # lengths = plen_eff - 1 (the verify scatter rewrites
-                # that position's KV with identical values), and the next
-                # round starts at count len(prior) — the exact boundary
-                # the original round structure had, so replay is
-                # round-identical and token-exact
-                self._tokens[slot] = int(st.prompt_eff[-1])
-                self._lengths[slot] = st.plen_eff - 1
+            if not emits:
                 continue
             tok = int(first[row])
             st.out.append(tok)
             self._emit_tokens(st, (tok,))
             self._mark_first(st)
             self._tokens[slot] = tok
-            self._lengths[slot] = st.plen_eff
             if (st.eos is not None and tok == st.eos) \
                     or st.gen_count >= st.req.max_new_tokens:
                 self._finish_slot(slot)
@@ -5107,6 +5400,15 @@ class ServingEngine:
                        "puts": 2 if self.logit_masks and name != "draft"
                        else 1}
                 for name, lay in self._layouts.items()},
+            # the one call of lookahead (:meth:`_launch`): decode and
+            # prefill calls enqueued, of them while the call before was
+            # still in flight, and the calls settled early by what needed
+            # their results (``EARLY_SETTLE_CAUSES``)
+            "lookahead": {
+                "calls": int(self._c_calls.value),
+                "ahead": int(self._c_calls_ahead.value),
+                "early": {cause: int(c.value) for cause, c
+                          in self._c_early_settles.items() if c.value}},
             # a learned-sparse-attention model: what each program's
             # selection was traced with, and the totals of the spans'
             # counters (:meth:`_split_record`); None for any other model

@@ -438,6 +438,26 @@ def probe(xs):
     assert _codes(pragma) == []
 
 
+def test_gl012_the_plain_decode_paths_fence_is_settle():
+    """ISSUE 44: the scheduler keeps ONE call in flight and takes its
+    results in ``_settle`` (``_harvest`` under it) — the plain path's
+    fence, sanctioned by name like ``_fence_harvest``; the same loop under
+    any other name still fires."""
+    body = """
+import jax.numpy as jnp
+
+class Engine:
+    def {name}(self, flights):
+        for flight in flights:
+            if int(jnp.sum(flight.out)) < 0:    # a scalar per call
+                break
+"""
+    for name in ("_settle", "_harvest", "_fence_harvest"):
+        assert "GL012" not in _codes(body.format(name=name)), name
+    for name in ("_commit_decode", "_run_plain_decode", "step"):
+        assert _codes(body.format(name=name)).count("GL012") == 1, name
+
+
 def test_gl013_swallowed_exception_fires_scoped_and_pragma():
     """GL013: an ``except`` in fleet-path code (serving/, telemetry/,
     inference/serving.py) that neither re-raises, nor uses the caught

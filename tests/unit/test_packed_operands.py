@@ -220,7 +220,10 @@ def _separately(srv):
         return body
 
     def host_operands(program, *operands):
-        dev = jax.tree_util.tree_map(jnp.asarray, operands)
+        # a COPY of each: the engine writes its vectors again (lengths, a
+        # released row) while the call is still in flight (ISSUE 44), and
+        # XLA:CPU aliases an aligned numpy operand
+        dev = jax.tree_util.tree_map(jnp.array, operands)
         return dev, {"puts": len(jax.tree_util.tree_leaves(dev)),
                      "operand_bytes": 0}
 
@@ -485,25 +488,27 @@ def test_the_three_host_segments_still_cover_the_phases_self_time(
     ``step.prefill`` + ``step.decode`` (duration less in-flight spans),
     at a batch of the cells' order (16 slots): with the puts gone the
     rest — entering and leaving the phase's own span — is a larger share
-    of a smaller time, and still under a twentieth of it, because the
+    of a smaller time, and still under a tenth of it, because the
     segments tile: each begins where the last one, or the in-flight span,
     ended."""
     engine, cfg = models("tiny")
     srv = ServingEngine(engine, **{**SERVE_KW, "slots": 16})
     srv.serve(_requests(cfg, "plain", n=4, seed=1))
+    warm = len(srv.timeline.events())      # both programs built and run
     for r in _requests(cfg, "plain", n=80, seed=2):
         srv.submit(r)
     for _ in range(60):
         srv.step()
-    events = srv.timeline.events()
+    events = srv.timeline.events()[warm:]
     flights = [e for e in events if e["ph"] == "X"
                and e["name"] in ("prefill", "decode")]
     own = covered = 0.0
     for phase in (e for e in events if e["ph"] == "X"
                   and e["name"] in ("step.prefill", "step.decode")):
+        # a call's span lies where its harvest did: in the phase that
+        # made it or, behind the next call's enqueue, in a later one
         inside = [f for f in flights
-                  if f["args"]["step"] == phase["args"]["step"]
-                  and phase["ts"] <= f["ts"]
+                  if phase["ts"] <= f["ts"]
                   and f["ts"] + f["dur"] <= phase["ts"] + phase["dur"]]
         if not inside:
             continue
@@ -511,5 +516,8 @@ def test_the_three_host_segments_still_cover_the_phases_self_time(
         covered += sum(phase["args"][k]
                        for k in ("plan_s", "upload_s", "commit_s"))
         assert phase["args"]["upload_s"] > 0
-    assert 0.95 * own <= covered <= own, (covered, own)
+    # (a twentieth before ISSUE 44; the harvest of the call before now
+    # runs behind the enqueue, three frames deeper, and the first call
+    # after an idle engine is handed over with nothing to harvest)
+    assert 0.90 * own <= covered <= own, (covered, own)
     srv.close()

@@ -56,7 +56,7 @@ ENGINE_STATS_KEYS = frozenset({
     # PR 33: how each built program picks its tokens
     "sampler",
     # PR 38: per built program, the one host buffer a call carries
-    "operands",
+    "operands", "lookahead",
     # PR 32: a learned-sparse-attention model's selection paths + counters
     # (None for any other model)
     "sparse_attn",
