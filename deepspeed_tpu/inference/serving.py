@@ -1346,6 +1346,15 @@ class ServingEngine:
                  jax.eval_shape(mk_pool),
                  is_leaf=paged_kv.is_quantized_pool)),
             key=lambda shape: int(np.prod(shape)))
+        #: the tile of the decode / verify walk at this pool's stored
+        #: shapes: blocks a loop iteration, score columns a softmax update
+        #: (``ops/decode_attention.py`` ``walk_tile_blocks``; None for a
+        #: latent pool, which is read by a walk of its own)
+        bs, hd = int(self._pool_shape[3]), int(self._pool_shape[4])
+        rows = bs // paged_kv.lane_pack(bs, hd)
+        tile = decode_attention.walk_tile_blocks(rows, self._nbper)
+        self._decode_attn = None if self._latent else \
+            {"tile_blocks": tile, "cols": tile * rows}
         self._kv_scale_live: set = set()
         #: bytes an absorbed read needs of one key in one layer
         self._latent_token_bytes = self._latent["width"] \
@@ -3536,6 +3545,19 @@ class ServingEngine:
                 else int(self._lengths[slot]), upto)
         return slot in self._active
 
+    def _kv_walk(self, valid) -> Dict[str, int]:
+        """Span args of a decode dispatch, from its rows' lengths:
+        ``kv_blocks``, the blocks the rows' reads walk (``cdiv(valid,
+        block_size)`` each), and ``kv_tiles``, the loop iterations the walk
+        makes of them (``cdiv(blocks, tile)``): their quotient says how
+        full the tiles run."""
+        if self._decode_attn is None:
+            return {}
+        blocks = -(-np.asarray(valid, np.int64) // self.block_size)
+        tile = self._decode_attn["tile_blocks"]
+        return {"kv_blocks": int(blocks.sum()),
+                "kv_tiles": int((-(-blocks // tile)).sum())}
+
     def _kv_reach(self, valid, queries=None) -> Dict[str, int]:
         """Span args of a dispatch of a model with window layers, from the
         scheduler's own bookkeeping: ``valid`` holds, for each live row,
@@ -4702,6 +4724,7 @@ class ServingEngine:
             counts = self._decode_counts()
             decode_fn = self._get_decode_fn()
             span_kw = {**self._sampler_rows(dec),
+                       **self._kv_walk(self._lengths[dec] + 1),
                        **self._kv_reach(self._lengths[dec] + 1)}
         with seg("step.decode.upload", phase):
             host, puts = self._host_operands(
@@ -5389,6 +5412,10 @@ class ServingEngine:
             # the read the prefill program was traced with (None before its
             # first call): "paged_prefill_attn" on a TPU, "gather" on a CPU
             "prefill_attn": self._program_meta.get("prefill_attn"),
+            # the tile of the decode / verify walk at this pool's shapes
+            # (None for a latent pool); the ``decode`` spans carry
+            # ``kv_blocks`` and ``kv_tiles``
+            "decode_attn": self._decode_attn and dict(self._decode_attn),
             # how each built program picks its tokens: "argmax" (greedy-only
             # engine) or how ops/sampling.py finds the filter's thresholds
             "sampler": dict(self._program_meta.get("sampler", {})),

@@ -26,11 +26,16 @@ attention``).  Its kernels share the contiguous kernel's online softmax
 (:func:`_attend_chunk`) but not its iteration space: a grid step there is
 one ROW, which loops over its own valid blocks and copies each block — every
 KV head of it, contiguous in the lane-packed pool — out of HBM itself,
-double-buffered (:func:`_paged_walk_kernel`).  Time follows the tokens a row
-holds, not ``max_seq_len`` (a grid over (row, head, logical block) spent
-~0.17 us a step whether or not the step held a key: 24,576 steps a layer
-for 24 rows of ~160 tokens, 1 % of the HBM roofline; the walk reads the same
-bytes in ~140 copies of 256 KB at 30-45 %, PERF.md PR 29).  A prefill chunk
+double-buffered, a TILE of blocks a loop iteration: one softmax update over
+all the tile's keys, 128 score columns wide (:func:`_paged_walk_kernel`).
+Time follows the tokens a row holds, not ``max_seq_len`` (a grid over (row,
+head, logical block) spent ~0.17 us a step whether or not the step held a
+key: 24,576 steps a layer for 24 rows of ~160 tokens, 1 % of the HBM
+roofline; the walk of PR 29 read the same bytes in ~140 copies of 256 KB at
+30-45 %, one update a block, 0.89 us a visit against 0.31 us of bytes; a
+tile's visits cost 0.35 us a block, 75-87 % of the roofline at rows of 24-56
+blocks, and what is left is ~2 us a row before its first tile has landed —
+my chip runs, PR 45, PERF.md).  A prefill chunk
 (T = ``prefill_chunk`` query rows a sequence) takes the same walk, several
 blocks a landing tile, under a flash body sized for its query rows
 (:func:`_paged_prefill_kernel`; the gather it replaced read, un-packed and
@@ -144,15 +149,25 @@ def decode_attention_reference(q, k_cache, v_cache, q_pos, *,
 # ---------------------------------------------------------------------------
 # Pallas single-token decode kernel
 # ---------------------------------------------------------------------------
-def _span_rows(scales, span, chunk, spans):
-    """[H, rows, chunk] scales for span-major query rows: row i takes lanes
-    ``span[i]*chunk ..`` of its head's per-token scale row (``scales``
-    [H, >= spans*chunk], token order; lanes past the block are padding)."""
-    scales = scales.astype(jnp.float32)[:, None, :]
-    out = scales[:, :, :chunk]
-    for h in range(1, spans):
-        out = jnp.where(span == h, scales[:, :, h * chunk:(h + 1) * chunk],
-                        out)
+def _lanes(x, n: int):
+    """A lane-replicated ``[..., 128]`` value at ``n`` lanes."""
+    if n % LANES:
+        return jnp.broadcast_to(x[..., :1], x.shape[:-1] + (n,))
+    return x if n == LANES else jnp.concatenate([x] * (n // LANES), axis=-1)
+
+
+def _span_cols(scales, span, r: int, spans: int):
+    """[H, rows, cols] scales for span-major query rows over a TILE of
+    blocks: ``scales`` holds, per block of the tile, its [H, >= spans*r]
+    per-token scale rows in token order (lanes past the block are padding);
+    row i takes, of every block, lanes ``span[i]*r ..`` — the tokens its
+    span of that block's packed rows holds."""
+    out = None
+    for h in range(spans):
+        part = jnp.concatenate(
+            [s.astype(jnp.float32)[:, h * r:(h + 1) * r] for s in scales],
+            axis=-1)[:, None, :]
+        out = part if out is None else jnp.where(span == h, part, out)
     return out
 
 
@@ -169,57 +184,68 @@ def _span_queries(qg, spans: int, width: int):
 
 
 def _attend_chunk(q, k, v, ks, vs, keep, start, sm_scale,
-                  m_scr, l_scr, acc_scr, *, spans: int):
-    """One online-softmax update of ``m/l/acc`` with a KV chunk of H heads
-    — the body the contiguous, paged-decode and paged-verify kernels share
-    (the contiguous kernel passes H = 1, the paged ones every KV head of a
-    block at once).
+                  m_scr, l_scr, acc_scr, *, spans: int, r: int):
+    """ONE online-softmax update of ``m/l/acc`` with a TILE of KV blocks of
+    H heads — the body the contiguous, paged-decode and paged-verify kernels
+    share (the contiguous kernel passes H = 1 and its one ``block_k`` chunk,
+    the paged ones every KV head of ``nt`` blocks at once).
 
-    ``k``/``v`` [H, R, g*D]: per head a chunk of ``g*R`` keys held as ``g =
-    spans`` consecutive R-key SPANS side by side in the lanes
-    (``paged_kv.pack_pool``; g == 1 is the plain [bk, D] chunk of a
-    contiguous cache or an unpacked pool).  ``q`` [H, g*rows, g*D] carries
+    ``k``/``v`` [H, cols, g*D]: per head the ``cols = nt * r`` packed key
+    rows of ``nt`` consecutive blocks, each block ``r`` rows that hold its
+    ``g*r`` keys as ``g = spans`` consecutive r-key SPANS side by side in
+    the lanes (``paged_kv.pack_pool``; g == 1 is the plain [bk, D] chunk of
+    a contiguous cache or an unpacked pool).  ``q`` [H, g*rows, g*D] carries
     each query g times, span-major (:func:`_span_queries`), so ONE batched
     ``q·kᵀ`` over the whole tile gives row ``h*rows + i`` the scores of
-    query i against span h (keys ``start + h*R ..``) — no lane slicing of
-    the tile: the arithmetic of the g == 1 body on g times the rows.  Each
-    span row keeps its own (m, l, acc) like an independent query;
-    :func:`_finish_chunks` merges the g partial softmaxes of a query once,
-    at the end.  ``keep(idx, query) -> bool`` is the caller's causal mask
-    on key positions ``idx`` for query rows ``query`` (both [H, g*rows, R]).
+    query i against span h of every block (column ``j*r + x``: key ``start
+    + j*g*r + h*r + x``) — no lane slicing of the tile: the arithmetic of
+    the g == 1 body on g times the rows, full 128-lane score registers at
+    128 columns.  Each span row keeps its own (m, l, acc) like an
+    independent query; :func:`_finish_chunks` merges the g partial
+    softmaxes of a query once, at the end.  ``keep(idx, query) -> bool`` is
+    the caller's causal mask on key positions ``idx`` for query rows
+    ``query`` (both [1, g*rows, cols]: every head's mask is the same).
 
-    ``ks``/``vs`` (int8-KV pools only): [H, g*R] per-token dequant scales
-    in token order, so span h reads lanes ``h*R ..``.  They fold into the
-    math on its lane-dim tiles — ``q·(code*s_k) = (q·code)*s_k`` on the
-    score columns, ``Σ p·(code*s_v) = (p*s_v)·code`` on the prob columns —
-    so no dequantized copy is ever materialized and the online softmax
-    (which normalizes over UNscaled probabilities) is untouched."""
-    q = q.astype(jnp.float32)                         # [H, g*rows, g*D]
-    k = k.astype(jnp.float32)                         # [H, R, g*D]
-    v = v.astype(jnp.float32)
-    r, per = k.shape[1], q.shape[1] // spans
+    The MXU takes ``q``, ``k``, ``v`` in the pool's dtype (an int8 pool's
+    codes arrive here in the query's: exact) and accumulates in float32; the
+    probabilities go to it in that dtype too, as
+    :func:`decode_attention_reference`'s do.  m and l are float32 and stay
+    lane-replicated [H, g*rows, 128], as the scratch holds them, and are
+    read, rescaled and written back ONCE a tile.
+
+    ``ks``/``vs`` (int8-KV pools only): per block of the tile its [H, g*r]
+    per-token dequant scales in token order, so span h reads lanes ``h*r
+    ..`` of each (:func:`_span_cols`).  They fold into the math on its
+    lane-dim tiles — ``q·(code*s_k) = (q·code)*s_k`` on the score columns,
+    ``Σ p·(code*s_v) = (p*s_v)·code`` on the prob columns — so no
+    dequantized copy is ever materialized and the online softmax (which
+    normalizes over UNscaled probabilities) is untouched."""
+    q = q.astype(k.dtype)
+    cols, per = k.shape[1], q.shape[1] // spans
     s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                             preferred_element_type=jnp.float32)
-    s = s * sm_scale                                  # [H, g*rows, R]
-    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = s * sm_scale                                  # [H, g*rows, cols]
+    row = jax.lax.broadcasted_iota(jnp.int32, (1, q.shape[1], 1), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, cols), 2)
     span = row // per
     if ks is not None:
-        s = s * _span_rows(ks, span, r, spans)
-    idx = start + span * r + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        s = s * _span_cols(ks, span, r, spans)
+    # span 0's key of each column; span h's is h*r further
+    idx = start + col + col // r * ((spans - 1) * r) + span * r
     s = jnp.where(keep(idx, row % per), s, NEG_INF)
 
-    m_prev = m_scr[...][:, :, :1]                     # [H, g*rows, 1]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
+    m_prev = m_scr[...]                               # [H, g*rows, 128]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)                            # [H, g*rows, R]
-    l_new = l_scr[...][:, :, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    pv = p * _span_rows(vs, span, r, spans) if vs is not None else p
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        pv, v, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+    p = jnp.exp(s - _lanes(m_new, cols))              # [H, g*rows, cols]
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    m_scr[...] = m_new
+    if vs is not None:
+        p = p * _span_cols(vs, span, r, spans)
+    acc_scr[...] = acc_scr[...] * _lanes(alpha, acc_scr.shape[-1]) \
+        + jax.lax.dot_general(p.astype(v.dtype), v,
+                              (((2,), (1,)), ((0,), (0,))),
+                              preferred_element_type=jnp.float32)
 
 
 def _start_chunks(m_scr, l_scr, acc_scr):
@@ -275,7 +301,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     def _compute():
         _attend_chunk(q_ref[0], k_ref[0], v_ref[0], None, None,
                       lambda idx, query: idx <= pos, start, sm_scale,
-                      m_scr, l_scr, acc_scr, spans=1)
+                      m_scr, l_scr, acc_scr, spans=1, r=block_k)
 
     @pl.when(kb == nk - 1)
     def _finish():
@@ -473,26 +499,44 @@ def paged_decode_attention_reference(q, k_pool, v_pool, block_tables, q_pos,
                            layer, *per_row)
 
 
-#: VMEM the paged kernels give their K/V landing buffers (two slots each)
-#: and the float32 copies :func:`_attend_chunk` makes of one block's tiles
+#: score columns (packed key rows) ONE softmax update of the decode / verify
+#: walk takes: a whole 128-lane register of float32 scores a query row — 8
+#: blocks a tile at OPT-1.3B's ``[16, 128]`` blocks (256 keys), 4 at the
+#: ``[32, 128]`` of OLMoE and Command A+ (128 keys).  The kernel alone, us a
+#: row at 64 / 128 / 256 columns (one update a block before: my chip runs,
+#: PR 45): OPT, 6 blocks a row 3.91 / 3.94 / 5.15 (5.48), 56 blocks 21.2 /
+#: 21.2 / 22.6 (40.2) — at 256 its 8 MB of landing buffers split the heads;
+#: OLMoE, 6 blocks 3.64 / 3.32 / 3.50 (4.74), 56 blocks 24.4 / 20.6 / 20.8
+#: (37.1); Command A+ (8 KV heads x 16 query rows), 56 blocks 18.9 / 12.0 /
+#: 11.1 (32.2).  A row of ONE block costs what it did (2.1 / 1.5 / 1.4 us)
+_WALK_COLS = 128
+
+#: VMEM the decode / verify walk gives its K/V landing buffers (two slots of
+#: ``nt`` blocks each) and the query-dtype copies :func:`_attend_chunk` makes
+#: of an int8 pool's tiles (a float pool's go to the MXU as they landed)
 _WALK_VMEM_BUDGET = 8 << 20
 
 
-def _walk_head_tile(hkv: int, r: int, width: int, itemsize: int) -> int:
-    """KV heads one grid step of the paged kernels takes, for a shard's
-    ``[hkv, r, width]`` blocks, from the shapes alone: all of them (OPT-1.3B
-    and OLMoE: 128 KB of K and of V a block in bf16, 0.8 MB of the budget)
-    unless ONE block of all heads, double-buffered, would overrun
-    :data:`_WALK_VMEM_BUDGET`.  Then the heads are split over a second grid
-    dim, in halves that stay a multiple of 16 (the sublane tile of the int8
-    records' bf16 scale rows, which the copies then slice by head).
+def walk_tile_blocks(r: int, nbper: int) -> int:
+    """Blocks one loop iteration of the decode / verify walk lands and
+    attends — its TILE — from the shapes alone: as many ``r``-row blocks as
+    give one softmax update :data:`_WALK_COLS` score columns, never more
+    than a row's table holds."""
+    return max(1, min(_WALK_COLS // r, nbper))
 
-    A loop iteration is always ONE block: fetching 2-8 blocks an iteration
-    (the body unrolled per block) bought 5-13 % of the kernel at 128 KB
-    blocks, nothing at a tp shard's 16 KB, and cost 2.3 s of every start in
-    the two programs that hold the kernel (PERF.md PR 29)."""
+
+def _walk_head_tile(hkv: int, r: int, width: int, itemsize: int,
+                    nt: int = 1) -> int:
+    """KV heads one grid step of the paged kernels takes, for a shard's
+    ``[hkv, r, width]`` blocks ``nt`` to a tile, from the shapes alone: all
+    of them (OPT-1.3B: 8 blocks of 128 KB of K and of V in bf16, 4 MB of
+    landing buffers; OLMoE: 4, 2 MB) unless a tile of all heads,
+    double-buffered, would overrun :data:`_WALK_VMEM_BUDGET`.  Then the
+    heads are split over a second grid dim, in halves that stay a multiple
+    of 16 (the sublane tile of the int8 records' bf16 scale rows, which the
+    copies then slice by head)."""
     def need(ht):
-        return ht * r * width * (4 * itemsize + 2 * 4)
+        return ht * nt * r * width * (4 * itemsize + 2 * 2)
 
     ht = hkv
     while need(ht) > _WALK_VMEM_BUDGET and ht % 32 == 0:
@@ -511,13 +555,16 @@ def _paged_walk_kernel(layer_ref, pos_ref, bt_ref, q_ref, *refs,
     [B, NBPER] arrive via scalar prefetch.  The pool operands stay in HBM
     (``pl.ANY``): the kernel walks the row's VALID logical blocks only —
     ``n = cdiv(last query position + 1, block_size)`` of them, read off
-    ``pos_ref``, never an entry of the table past them — and copies block
-    ``i`` itself, ``pool.at[layer, bt[b, i]]`` -> a ``[ht, bs/g, g*D]`` VMEM
+    ``pos_ref``, never an entry of the table past them — ``nt`` blocks a
+    TILE (:func:`walk_tile_blocks`; read here off the landing buffers'
+    shape).  It copies block ``i`` itself, ``pool.at[layer, bt[b, i]]`` ->
+    its place ``buf[slot, j]`` in its tile, a ``[ht, bs/g, g*D]`` VMEM
     buffer: every head of the block in ONE contiguous DMA (K and V; an int8
-    pool's ``[ht, bs]`` scale rows ride beside them).  The buffers have two
-    slots, so block ``i + 1`` lands while block ``i`` is attended.  A row
-    costs its own length: no grid step and no copy is spent on the part of
-    ``max_seq_len`` it does not hold.
+    pool's ``[ht, bs]`` scale rows ride beside them).  The buffers have two slots, so tile ``i
+    + 1`` lands while tile ``i`` is attended; of the last tile only the
+    blocks the row holds are copied.  A row costs its own length: no grid
+    step and no copy is spent on the part of ``max_seq_len`` it does not
+    hold.
 
     ``q_ref`` [1, ht, g*rows, g*D] (span-expanded, :func:`_span_queries`),
     ``rows = rep * t``: query row ``r*t + i`` is head ``r`` of its KV group
@@ -526,16 +573,27 @@ def _paged_walk_kernel(layer_ref, pos_ref, bt_ref, q_ref, *refs,
     just scattered at ``base .. base + t - 1``).  The causal mask is per
     query ROW (``key <= base + row % t``): a verify query sees the row's
     history plus the window up to itself, never the unverified draft tail.
-    The arithmetic is :func:`_attend_chunk`'s, a block of all heads at a
-    time; ``o_ref`` [1, ht, rows, D] is written once, after the walk.
+    A loop iteration is ONE online-softmax update over all ``nt *
+    block_size`` keys of its tile (:func:`_attend_chunk`: a head's ``nt``
+    landed blocks are ``cols = nt * bs/g`` consecutive packed key rows):
+    the body exists once a tile, whatever ``nt``.  The mask also hides the
+    slots of a last, partly landed tile; their V (and an int8 pool's V
+    scales) are zeroed first, so that what the mask zeroes in ``p`` meets
+    no NaN there.  ``o_ref`` [1, ht, rows, D] is written once, after the
+    walk.
 
     ``window`` (static; 0: a full-attention layer, the program above): a
     sliding-window layer.  Query ``p`` keeps keys ``p - window < key <= p``,
     so the walk starts at the block of the row's FIRST VISIBLE KEY,
-    ``max(0, base - window + 1) // block_size``, masks the keys before each
-    query's own bound inside it, and reads the table as the RING it is
-    (``ops/paged_kv.py`` "Layer kinds"): logical block ``i`` at entry ``i %
-    NBPER``.  A row costs ``min(length, window)`` keys.
+    ``max(0, base - window + 1) // block_size`` — its tiles count from
+    there, aligned to nothing — masks the keys before each query's own
+    bound, and reads the table as the RING it is (``ops/paged_kv.py``
+    "Layer kinds"): logical block ``i`` at entry ``i % NBPER``.  A row
+    costs ``min(length, window)`` keys.  A verify query whose window starts
+    after a tile's last key sees nothing of that tile: its state stays
+    empty (``m`` at ``NEG_INF``) and what such a tile adds to ``l`` /
+    ``acc`` is scaled by ``alpha = 0`` when the query's first real key
+    arrives — every query sees at least itself.
 
     Refs after ``q_ref``: the pool operands (K, [K scales], V, [V scales]),
     ``o_ref``, one two-slot landing buffer per pool operand, the DMA
@@ -544,7 +602,7 @@ def _paged_walk_kernel(layer_ref, pos_ref, bt_ref, q_ref, *refs,
     pools, o_ref = refs[:n_ops], refs[n_ops]
     bufs = refs[n_ops + 1:2 * n_ops + 1]
     sem, m_scr, l_scr, acc_scr = refs[2 * n_ops + 1:]
-    _, ht, r, _ = bufs[0].shape
+    _, nt, ht, r, _ = bufs[0].shape
     bs = r * spans
     b, layer = pl.program_id(0), layer_ref[0]
     base = pos_ref[b]
@@ -563,39 +621,52 @@ def _paged_walk_kernel(layer_ref, pos_ref, bt_ref, q_ref, *refs,
     whole = pools[0].shape[2] == ht
     heads = pl.ds(pl.program_id(1) * ht, ht)
 
-    def copies(i, slot):
-        """Block ``i``'s copies into ``slot`` (``i < n``)."""
-        out = []
-        for op, (pool, buf) in enumerate(zip(pools, bufs)):
-            # an operand that is this layer's rows alone (_lane_rows)
-            # is a one-layer stack
-            src = (layer if pool.shape[0] > 1 else 0,
-                   bt_ref[b, i % width if window else i])
-            out.append(pltpu.make_async_copy(
-                pool.at[src if whole else src + (heads,)], buf.at[slot],
-                sem.at[slot, op]))
-        return out
+    def landed(i):
+        """Blocks of tile ``i`` the row holds (0 past its last tile)."""
+        return jnp.clip(n - first - i * nt, 0, nt)
 
-    def fetch(i, slot):
-        @pl.when(i < n)
-        def _start():
-            for copy in copies(i, slot):
-                copy.start()
+    def each_block(i, slot, act):
+        """``act`` on the copies of tile ``i``'s valid blocks."""
+        def one(j, carry):
+            at = first + i * nt + j
+            for op, (pool, buf) in enumerate(zip(pools, bufs)):
+                # an operand that is this layer's rows alone (_lane_rows)
+                # is a one-layer stack
+                src = (layer if pool.shape[0] > 1 else 0,
+                       bt_ref[b, at % width if window else at])
+                act(pltpu.make_async_copy(
+                    pool.at[src if whole else src + (heads,)],
+                    buf.at[slot, j], sem.at[slot, op]))
+            return carry
+
+        jax.lax.fori_loop(0, landed(i), one, None)
 
     def attend(i, carry):
         slot = i % 2
-        fetch(i + 1, 1 - slot)
-        for copy in copies(i, slot):
-            copy.wait()
-        tiles = [buf[slot] for buf in bufs]
+        each_block(i + 1, 1 - slot, lambda copy: copy.start())
+
+        def blank(j, carry):
+            for buf in bufs[n_ops // 2:]:             # V, [V scales]
+                buf[slot, j] = jnp.zeros(buf.shape[2:], buf.dtype)
+            return carry
+
+        jax.lax.fori_loop(landed(i), nt, blank, None)
+        each_block(i, slot, lambda copy: copy.wait())
+        tiles = [[buf[slot, j] for j in range(nt)] for buf in bufs]
         k, ks, v, vs = tiles if quant else (tiles[0], None, tiles[1], None)
-        _attend_chunk(q_ref[0], k, v, ks, vs, keep,
-                      i * bs, sm_scale, m_scr, l_scr, acc_scr, spans=spans)
+        # a head's nt landed blocks, one under the other: [ht, nt * r, g*D]
+        # (an int8 pool's codes in the query's dtype first: its 32-row
+        # tiles do not stack at r = 16)
+        k, v = (jnp.concatenate([x.astype(q_ref.dtype) if quant else x
+                                 for x in blocks], axis=1)
+                for blocks in (k, v))
+        _attend_chunk(q_ref[0], k, v, ks, vs, keep, (first + i * nt) * bs,
+                      sm_scale, m_scr, l_scr, acc_scr, spans=spans, r=r)
         return carry
 
     _start_chunks(m_scr, l_scr, acc_scr)
-    fetch(first, first % 2)
-    jax.lax.fori_loop(first, n, attend, None)
+    each_block(0, 0, lambda copy: copy.start())
+    jax.lax.fori_loop(0, (n - first + nt - 1) // nt, attend, None)
     _finish_chunks(o_ref, m_scr, l_scr, acc_scr, spans=spans)
 
 
@@ -628,9 +699,10 @@ def _paged_launch(q, k_pool, v_pool, block_tables, q_pos, layer, *,
     operands)``: decode and verify each keep a ``pl.pallas_call`` site of
     their own only to give it their kernel's name as a constant (the trace
     readers select by it).  Shapes may be the full head count or one
-    tp shard's slice — grid, GQA grouping and the walk's head tile
-    (:func:`_walk_head_tile`) are computed from the local arrays either
-    way, and the pool's packing is read off its minor dim.
+    tp shard's slice — grid, GQA grouping, the walk's tile of blocks
+    (:func:`walk_tile_blocks`) and its head tile (:func:`_walk_head_tile`)
+    are computed from the local arrays either way, and the pool's packing
+    is read off its minor dim.
 
     Grid ``(B, HKV // ht)`` over queries regrouped ``[B, HKV, rep*T, D]``
     (row ``r*T + i`` = head ``r`` of the KV group at window offset ``i`` —
@@ -647,7 +719,8 @@ def _paged_launch(q, k_pool, v_pool, block_tables, q_pos, layer, *,
                  pools[1], _lane_rows(v_pool["ps"], layer)]
     hkv, r, width = pools[0].shape[2:]
     rows, spans = h // hkv * t, r_in * w_in // d // r     # block_size over R
-    ht = _walk_head_tile(hkv, r, width, pools[0].dtype.itemsize)
+    nt = walk_tile_blocks(r, block_tables.shape[1])
+    ht = _walk_head_tile(hkv, r, width, pools[0].dtype.itemsize, nt)
     qg = _span_queries(q.reshape(b, hkv, rows, d), spans, width)
     pos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1), (b,))
     # a copy is issued for entries of a row's valid prefix only; clipped all
@@ -665,7 +738,7 @@ def _paged_launch(q, k_pool, v_pool, block_tables, q_pos, layer, *,
         in_specs=[row_block(spans * rows, width)]
         + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
         out_specs=row_block(rows, d),
-        scratch_shapes=[pltpu.VMEM((2, ht) + p.shape[3:], p.dtype)
+        scratch_shapes=[pltpu.VMEM((2, nt, ht) + p.shape[3:], p.dtype)
                         for p in pools] + [
             pltpu.SemaphoreType.DMA((2, len(pools))),
             pltpu.VMEM((ht, spans * rows, LANES), jnp.float32),   # m
@@ -802,13 +875,6 @@ def _prefill_head_tile(hkv: int, rows: int, spans: int, nt: int, r: int,
     while need(ht) > _PREFILL_VMEM_BUDGET and ht % 2 == 0:
         ht //= 2
     return ht
-
-
-def _lanes(x, n: int):
-    """A lane-replicated ``[rows, 128]`` value at ``n`` lanes."""
-    if n % LANES:
-        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
-    return x if n == LANES else jnp.concatenate([x] * (n // LANES), axis=1)
 
 
 def _paged_prefill_kernel(layer_ref, base_ref, valid_ref, bt_ref, q_ref,

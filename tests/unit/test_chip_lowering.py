@@ -99,6 +99,48 @@ def test_paged_walk_lowers_at_the_cells_shapes(cell, slots, h, hd, ctx,
         assert f'kernel_name = "{name}"' in text
 
 
+def _kernel_primitives(jaxpr, acc):
+    """Primitive -> count over a kernel's jaxpr, loop bodies included."""
+    for eqn in jaxpr.eqns:
+        acc[eqn.primitive.name] = acc.get(eqn.primitive.name, 0) + 1
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _kernel_primitives(sub, acc)
+    return acc
+
+
+@pytest.mark.parametrize("kv8", [False, True], ids=["bf16", "kv8"])
+def test_paged_walk_program_does_not_grow_with_its_tile(kv8, monkeypatch):
+    """ISSUE 45: the walk's body is a TILE's, not a block's — at 1, 8 and 16
+    blocks a loop iteration (chat's shapes) the kernel holds the same two
+    matmuls, the same max / exp / sum and the same copy sites; only the
+    loads of the landed blocks are one a block (PR 29 unrolled the update
+    per block: 2.3 s of every start at 8)."""
+    q, pool, bt, pos = _cell_operands(24, 32, 64, 1024, 24, kv8, 1)
+    counts = {}
+    for cols in (16, 128, 256):
+        monkeypatch.setattr(da, "_WALK_COLS", cols)
+        assert da.walk_tile_blocks(16, 32) == cols // 16
+        jaxpr = jax.make_jaxpr(
+            lambda q, k, v, bt, pos: da.paged_decode_attention_pallas(
+                q, k, v, bt, pos, interpret=False, layer=1))(
+                    q, pool, pool, bt, pos).jaxpr
+        kernel, = (eqn.params["jaxpr"] for eqn in jaxpr.eqns
+                   if eqn.primitive.name == "pallas_call")
+        counts[cols] = _kernel_primitives(kernel, {})
+    work = ("dot_general", "exp", "reduce_max", "reduce_sum", "dma_start",
+            "dma_wait", "select_n", "mul", "swap")
+    assert counts[16]["dot_general"] == 2
+    for cols in (128, 256):
+        assert {k: counts[cols][k] for k in work} \
+            == {k: counts[16][k] for k in work}, (cols, counts)
+    if not kv8:
+        # an int8 pool's scale rows are sliced a block as well
+        assert sum(counts[128].values()) < 1.1 * sum(counts[16].values())
+
+
 @pytest.mark.parametrize("name,h,hd,ctx", ATTN_SHAPES)
 def test_contiguous_decode_lowers(name, h, hd, ctx):
     q = _sds((SLOTS, h, 1, hd), jnp.bfloat16)

@@ -639,9 +639,10 @@ def _walk_case(rng, t, hd, rep, kv8, bases, held=None, ctx=None):
     hd 128: g = 1), rows of the given ``bases`` (query positions ``base ..
     base + t - 1``; ``held``: the tokens each row holds, ``base + t`` unless
     given).  Returns ``(q, k_pool, v_pool, poisoned table, clean
-    table)``: past each row's valid prefix the poisoned table holds ids of
-    blocks full of NaN (an int8 record's scale rows are NaN there), the
-    clean one the scratch id 0 — a kernel that copies one entry too many
+    table)``: every block but the scratch id 0 and those of a row's valid
+    prefix is full of NaN (an int8 record's scale rows are NaN there); past
+    each row's valid prefix the poisoned table holds ids of such blocks, the
+    clean one the scratch id — a kernel that copies one entry too many
     returns NaN, the gather reference reads the clean table."""
     from deepspeed_tpu.ops import paged_kv
 
@@ -650,7 +651,10 @@ def _walk_case(rng, t, hd, rep, kv8, bases, held=None, ctx=None):
                                bs, kv8)
     bt = np.asarray(bt)
     nb = paged_kv.pool_payload(kp).shape[1]
-    poison = np.setdiff1d(np.arange(1, nb), bt)[:2]      # blocks no row owns
+    held = np.asarray(bases) + t if held is None else np.asarray(held)
+    valid = (held + bs - 1) // bs                    # blocks a row may read
+    past = np.arange(bt.shape[1])[None, :] >= valid[:, None]
+    poison = np.setdiff1d(np.arange(1, nb), bt[~past])
 
     def poisoned(pool):
         if kv8:
@@ -659,10 +663,7 @@ def _walk_case(rng, t, hd, rep, kv8, bases, held=None, ctx=None):
         return pool.at[:, poison].set(jnp.nan)
 
     kp, vp = (paged_kv.pack_pool(poisoned(p)) for p in (kp, vp))
-    held = np.asarray(bases) + t if held is None else np.asarray(held)
-    valid = (held + bs - 1) // bs                    # blocks a row may read
-    past = np.arange(bt.shape[1])[None, :] >= valid[:, None]
-    garbage = poison[rng.integers(0, 2, bt.shape)]
+    garbage = poison[rng.integers(0, len(poison), bt.shape)]
     q = jnp.asarray(rng.standard_normal((b, hkv * rep, t, hd)), jnp.float32)
     return (q, kp, vp, jnp.asarray(np.where(past, garbage, bt), jnp.int32),
             jnp.asarray(np.where(past, 0, bt), jnp.int32))
@@ -708,6 +709,82 @@ def test_paged_walk_verify_window_across_a_block_boundary(hd, rep, kv8):
     assert np.isfinite(np.asarray(got)).all(), "read past a valid prefix"
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# ISSUE 45: a loop iteration of the walk is a TILE of ``nt`` blocks and
+# ONE online-softmax update over all their keys.
+def _tile_case(rng, t, hd, rep, kv8):
+    """:func:`_walk_case` with rows that hold 0, 1, ``nt - 1``, ``nt``, ``nt
+    + 1`` and ``2 nt + 1`` blocks (``nt``: the tile at these shapes), the
+    last block full, partly full or holding ONE key.  -> (its five, the
+    rows' positions, nt)."""
+    from deepspeed_tpu.ops import decode_attention as da, paged_kv
+
+    bs = WALK_BS
+    nt = da.walk_tile_blocks(bs // paged_kv.lane_pack(bs, hd), 1 << 10)
+    # (blocks, keys in the last of them)
+    rows = [(0, 0), (1, max(t, 1)), (1, bs), (nt - 1, 5), (nt, bs),
+            (nt + 1, 1), (2 * nt + 1, 7), (2 * nt + 1, bs)]
+    held = np.asarray([max(0, (n - 1) * bs + last) for n, last in rows])
+    return (*_walk_case(rng, t, hd, rep, kv8, held - t, held=held,
+                        ctx=(2 * nt + 1) * bs),
+            jnp.asarray(held - t, jnp.int32), nt)
+
+
+def _window_tile_case(t):
+    """Rows of a 40-key window layer over blocks of 8 and a ring of 7, the
+    walk's tile cut to 4 blocks (by the caller): the window starts before
+    the row's first key (0, 3), its first visible key lies mid-block and
+    the walk takes two tiles (45, 100), and the ring wraps inside the
+    second tile (64) and the first (131).  Every block that is not live for
+    its row — the scratch id too — is NaN."""
+    q, kp, vp, bt, pos, want = _ring_case(t, 40, 8, 7,
+                                          [0, 3, 45, 64, 100, 131], seed=3)
+    dead = np.setdiff1d(np.arange(kp.shape[1]), np.asarray(bt)[bt > 0])
+    return (q, kp.at[:, dead].set(jnp.nan), vp.at[:, dead].set(jnp.nan), bt,
+            pos, want)
+
+
+@pytest.mark.parametrize("hd,rep,t,kv8,window", [
+    (128, 1, 1, False, 0), (64, 1, 1, False, 0),
+    (128, 1, 4, False, 0), (64, 1, 4, False, 0),
+    (128, 16, 1, False, 0), (128, 16, 4, False, 0), (64, 4, 4, False, 0),
+    (64, 1, 1, True, 0), (128, 4, 4, True, 0), (64, 4, 4, True, 0),
+    (128, 2, 1, False, 40), (128, 2, 4, False, 40),
+], ids=["g1", "g2", "g1-verify", "g2-verify", "gqa16", "gqa16-verify",
+        "g2-rep4-verify", "kv8-g2", "kv8-g1-verify", "kv8-g2-verify",
+        "window", "window-verify"])
+def test_paged_walk_attends_a_tile_of_blocks_an_update(hd, rep, t, kv8,
+                                                       window, monkeypatch):
+    """The walk's tile against the gather reference (a window layer: against
+    plain windowed attention), every block and table entry outside a row's
+    valid blocks NaN: rows that end a block short of a tile, on it, a block
+    and ONE key past it, and two tiles and a block long; decode and a T = 4
+    verify window; packed (g = 2) and plain blocks; a GQA group of 16; int8
+    records; a ring that wraps inside a tile."""
+    from deepspeed_tpu.ops import decode_attention as da
+
+    kernel = paged_decode_attention_pallas if t == 1 \
+        else paged_verify_attention_pallas
+    if window:
+        monkeypatch.setattr(da, "_WALK_COLS", 32)        # 4 blocks of 8
+        q, kp, vp, bt, pos, want = _window_tile_case(t)
+        assert da.walk_tile_blocks(8, bt.shape[1]) == 4
+        got = np.asarray(kernel(q, kp, vp, bt, pos, layer=0, window=window,
+                                interpret=True))
+    else:
+        rng = np.random.default_rng(90 + hd + rep + t)
+        q, kp, vp, bt, clean, pos, nt = _tile_case(rng, t, hd, rep, kv8)
+        assert nt == {128: 4, 64: 8}[hd]
+        want = np.array(paged_decode_attention_reference(
+            q, kp, vp, clean, pos, layer=1))
+        got = np.asarray(kernel(q, kp, vp, bt, pos, layer=1,
+                                interpret=True))
+        # the row that holds nothing walks nothing and returns zeros
+        assert not got[0].any()
+        want[0] = 0
+    assert np.isfinite(got).all(), "read outside a row's valid blocks"
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize(

@@ -153,6 +153,30 @@ def test_prefill_spans_count_the_blocks_their_reads_walk(served, tiny):
     assert srv.stats()["prefill_attn"] == "gather"
 
 
+def test_decode_spans_count_the_blocks_and_tiles_their_reads_walk(served,
+                                                                  tiny):
+    """ISSUE 45: each ``decode`` span carries ``kv_blocks``, the sum over
+    its rows of ``cdiv(valid, block_size)`` — what the decode walk copies —
+    and ``kv_tiles``, the loop iterations it makes of them at the tile
+    ``stats()["decode_attn"]`` names (read off the pool's stored shapes)."""
+    from deepspeed_tpu.ops import decode_attention, paged_kv
+
+    srv, events, _ = served
+    bs = SERVE_KW["block_size"]
+    hd = tiny[1].hidden_size // tiny[1].num_heads
+    r = bs // paged_kv.lane_pack(bs, hd)
+    nt = decode_attention.walk_tile_blocks(r, SERVE_KW["max_seq_len"] // bs)
+    assert srv.stats()["decode_attn"] == {"tile_blocks": nt, "cols": nt * r}
+    spans = _named(events, "decode")
+    for a in (e["args"] for e in spans):
+        assert a["slots"] <= a["kv_tiles"] <= a["kv_blocks"] \
+            <= a["kv_tiles"] * nt
+    # a request's decode calls read prompt + 1 .. prompt + budget - 1 keys
+    want = sum(-(-(len(r.prompt) + j) // bs) for r in _requests(tiny[1])
+               for j in range(1, r.max_new_tokens))
+    assert sum(e["args"]["kv_blocks"] for e in spans) == want
+
+
 #: (temperature, top_k, top_p) a request: greedy, sampled unfiltered, top-p,
 #: top-k, both, and a greedy request whose filter knobs select nothing
 MIXED_KNOBS = [(0.0, 0, 1.0), (1.0, 0, 1.0), (0.7, 0, 0.9), (1.2, 5, 1.0),

@@ -53,6 +53,8 @@ ENGINE_STATS_KEYS = frozenset({
     "kv_pool_shape", "kv_scale_bytes", "kv_sharded",
     # PR 31: which read the prefill program was traced with
     "prefill_attn",
+    # PR 45: the tile of the decode / verify walk at the pool's shapes
+    "decode_attn",
     # PR 33: how each built program picks its tokens
     "sampler",
     # PR 38: per built program, the one host buffer a call carries
