@@ -787,7 +787,11 @@ def test_window_walks_compile_at_the_rag_chat_cells_shapes(kind, rows, t,
 #: PR 41: ``opt.*`` / ``mixtral.*`` (tiny configs, hd 16: g = 8) and
 #: ``keye.*`` (its 64-wide indexer leaf: g = 2) merge in the stored view,
 #: and every ``*.decode`` — a one-token window is broadcast over its block,
-#: at g = 1 too, where the parent gathered it row by row
+#: at g = 1 too, where the parent gathered it row by row.  The last eight
+#: (``mistral4.*``: the latent pool kind, ONE leaf; ``gpt2.*``; ``llama.*``:
+#: dense, GQA 4 / 2; ``bloom.*``: ALiBi, pure XLA on the pool, no kernel)
+#: are taken on the PARENT of PR 46 (``a0db4cf``), before the cached forward
+#: moved to ``models/cached.py``
 OLD_PROGRAMS = {
     "opt.decode": "b52e4bc5d86a603e", "opt.prefill": "0fe6ed036104ea84",
     "mixtral.decode": "6479cf5460fbfff5",
@@ -795,19 +799,42 @@ OLD_PROGRAMS = {
     "olmoe.decode": "e9cbda30c55880c6", "olmoe.prefill": "9cc3b8a954958161",
     "keye.decode": "b594d5fea767ffda", "keye.prefill": "b14058bd110ef1a1",
     "commanda.decode": "dae34abf7d70a110",
-    "commanda.prefill": "cfd7f4cc16d5488d"}
+    "commanda.prefill": "cfd7f4cc16d5488d",
+    "mistral4.decode": "2d73686d9b1d63a7",
+    "mistral4.prefill": "33a83a8b55a6460a",
+    "gpt2.decode": "56db7e72e98e31c7", "gpt2.prefill": "aaca73ee22c8ad8f",
+    "llama.decode": "dc639e668cbe000a", "llama.prefill": "b8356961e3983d22",
+    "bloom.decode": "05eea69436e8aefe", "bloom.prefill": "9e7f54fb3e7d2def"}
+
+
+def _assert_pinned(pins, name, text):
+    import hashlib
+
+    found = hashlib.sha256(text.encode()).hexdigest()[:16]
+    assert found == pins[name], \
+        f"{name} lowers to a text that hashes to {found}, not {pins[name]}"
 
 
 def _old_family(name):
     import dataclasses
 
-    from deepspeed_tpu.models import mixtral, opt
+    from deepspeed_tpu.models import bloom, gpt2, llama, mixtral, opt
 
     small = dict(num_layers=2, max_seq_len=256, vocab_size=512,
                  hidden_size=256, ffn_size=128, num_experts=8)
-    if name == "opt":
-        return opt.build(dataclasses.replace(opt.OPTConfig.tiny(),
-                                             max_seq_len=256))
+    dense = {"opt": (opt, opt.OPTConfig), "gpt2": (gpt2, gpt2.GPT2Config),
+             "llama": (llama, llama.LlamaConfig),
+             "bloom": (bloom, bloom.BloomConfig)}
+    if name in dense:
+        module, config = dense[name]
+        return module.build(dataclasses.replace(config.tiny(),
+                                                max_seq_len=256))
+    if name == "mistral4":
+        # the latent widths as published (a token is 256 + 64 values in
+        # three lane rows, ONE pool leaf); fewer heads, a narrower query rank
+        return mixtral.build(dataclasses.replace(
+            mixtral.MixtralConfig.mistral_small_4(), num_heads=4,
+            num_kv_heads=4, q_lora_rank=128, **small))
     if name == "mixtral":
         return mixtral.build(dataclasses.replace(
             mixtral.MixtralConfig.tiny(), max_seq_len=256))
@@ -831,8 +858,10 @@ def test_the_old_programs_are_the_old_programs(name, as_on_tpu, one_chip,
     """ISSUE 34, 39, 41: with every new field at its default (``held=None``;
     no latent ranks, no rope scaling, no query temperature), OPT, Mixtral,
     OLMoE, Keye and Command A+ lower, for a described v5e, to the text
-    pinned in ``OLD_PROGRAMS`` (which says on which tree each was taken)."""
-    import hashlib
+    pinned in ``OLD_PROGRAMS`` (which says on which tree each was taken).
+    ISSUE 46: so do Mistral Small 4 (the latent kind), GPT-2, dense Llama
+    and BLOOM — a move of the cached forward's library changes no program
+    a cell runs."""
     import re
 
     from deepspeed_tpu.moe import grouped_matmul
@@ -883,11 +912,54 @@ def test_the_old_programs_are_the_old_programs(name, as_on_tpu, one_chip,
         "prefill": (prefill, (params, pool, i32(2, 128), table(2),
                               i32(2), i32(2)))}[program]
     text = jax.jit(fn, donate_argnums=(1,)).lower(*args).as_text()
-    assert "tpu_custom_call" in text
-    masked = re.sub(r'backend_config = "[^"]*"',
-                    'backend_config = "<kernel>"', text)
-    assert hashlib.sha256(masked.encode()).hexdigest()[:16] \
-        == OLD_PROGRAMS[name]
+    # (BLOOM's ALiBi read is plain XLA over the gathered pool)
+    assert ("tpu_custom_call" in text) == (family != "bloom")
+    _assert_pinned(OLD_PROGRAMS, name, re.sub(
+        r'backend_config = "[^"]*"', 'backend_config = "<kernel>"', text))
+
+
+#: sha256 (first 16 hex digits) of the CONTIGUOUS ``forward_cached`` — what
+#: ``InferenceEngine.generate`` runs: the prefill call (T = 8, ``pos`` the
+#: constant 0) and the decode call (T = 1, ``pos`` a traced scalar) — of the
+#: seven families that serve it, lowered on the CPU (the reference
+#: attention, no kernel) at their ``tiny`` shapes, batch 2, a 128-token
+#: float32 cache.  Taken on the PARENT of PR 46 (``a0db4cf``).  To re-take
+#: a pin (of this table or of ``OLD_PROGRAMS``), run its case: the failure
+#: names the hash the tree lowers to
+OLD_CONTIGUOUS = {
+    "gpt2.prefill": "cf1732aa2e5283b6", "gpt2.decode": "b5813769756b1ec5",
+    "opt.prefill": "78300f29f61f0b7b", "opt.decode": "104b70c90eb19130",
+    "llama.prefill": "fca207d6315d2c71", "llama.decode": "2c5154942d92a665",
+    "bloom.prefill": "f6678df8b3ca7100", "bloom.decode": "a90ed9b2d761d990",
+    "gptj.prefill": "fb999b79d2cc2edc", "gptj.decode": "1a8a5159d88af014",
+    "gptneo.prefill": "832f31a013eb992b", "gptneo.decode": "1995354317bdee54",
+    "gptneox.prefill": "1d03ba0a28de78f1",
+    "gptneox.decode": "6db43c40a2c1d350"}
+
+
+@pytest.mark.parametrize("name", sorted(OLD_CONTIGUOUS))
+def test_the_contiguous_forward_cached_is_the_old_one(name):
+    """ISSUE 46: the static-batch cached forward of every family lowers to
+    the StableHLO text it lowered to before its library moved."""
+    import importlib
+
+    family, program = name.split(".")
+    module = importlib.import_module(f"deepspeed_tpu.models.{family}")
+    config = {"gpt2": "GPT2Config", "opt": "OPTConfig",
+              "llama": "LlamaConfig", "bloom": "BloomConfig",
+              "gptj": "GPTJConfig", "gptneo": "GPTNeoConfig",
+              "gptneox": "GPTNeoXConfig"}[family]
+    spec = module.build(getattr(module, config).tiny())
+    hooks = spec.decode_hooks
+    params = jax.eval_shape(lambda: spec.init_fn(jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: hooks["init_cache"](2, 128, jnp.float32))
+    if program == "prefill":
+        lowered = jax.jit(lambda p, ids, c: hooks["forward_cached"](
+            p, ids, c, 0)).lower(params, _sds((2, 8), jnp.int32), cache)
+    else:
+        lowered = jax.jit(hooks["forward_cached"]).lower(
+            params, _sds((2, 1), jnp.int32), cache, _sds((), jnp.int32))
+    _assert_pinned(OLD_CONTIGUOUS, name, lowered.as_text())
 
 
 def test_compiled_two_kind_serving_programs_fit_and_alias_both_pools(
