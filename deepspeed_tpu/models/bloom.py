@@ -14,6 +14,7 @@ power-of-two head prefix, interpolated for the rest).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional
 
@@ -22,8 +23,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ..ops.decode_attention import window_state
+from ..ops.paged_kv import paged_cache_update, paged_gather
+from ..ops.sp_attention import shard_seq
 from ..parallel.topology import TP_AXIS
 from ..runtime.model import ModelSpec
+from .cached import (cache_update, decode_over_layers, dequant_resident,
+                     gather_last, init_kv_cache, layer_accessors, window)
 
 PyTree = Any
 
@@ -142,13 +148,10 @@ def _attention(cfg: BloomConfig, q, k, v, q_offset=0):
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
-def _block(cfg: BloomConfig, x, layer, pos=0, cache=None, get=None,
-           mm=None):
-    if get is None or mm is None:
-        from .gpt2 import layer_accessors
-
-        get, mm = layer_accessors(layer)
-
+def _block(cfg: BloomConfig, x, layer):
+    """One uncached block (training / the plain forward);
+    :func:`_block_cached_body` is the same math over a KV cache."""
+    get, mm = layer_accessors(layer)
     b, s, d = x.shape
     h, hd = cfg.num_heads, cfg.head_dim
 
@@ -158,24 +161,13 @@ def _block(cfg: BloomConfig, x, layer, pos=0, cache=None, get=None,
     q = q.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
     k = k.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
     v = v.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-    if cache is not None:
-        ck, cv = cache
-        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                          (0, 0, pos, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                          (0, 0, pos, 0))
-        attn = _attention(cfg, q, ck, cv, q_offset=pos)
-        cache = (ck, cv)
-    else:
-        attn = _attention(cfg, q, k, v)
-    attn = attn.transpose(0, 2, 1, 3).reshape(b, s, d)
+    attn = _attention(cfg, q, k, v).transpose(0, 2, 1, 3).reshape(b, s, d)
     x = x + mm(attn, "o_w", x.dtype) + get("o_b").astype(x.dtype)
 
     y = _layer_norm(x, get("ln2_scale"), get("ln2_bias"))
     hid = jax.nn.gelu(mm(y, "fc_w", None) + get("fc_b").astype(y.dtype),
                       approximate=False)
-    x = x + mm(hid, "proj_w", x.dtype) + get("proj_b").astype(x.dtype)
-    return x, cache
+    return x + mm(hid, "proj_w", x.dtype) + get("proj_b").astype(x.dtype)
 
 
 def _embed(cfg: BloomConfig, params, input_ids):
@@ -185,16 +177,13 @@ def _embed(cfg: BloomConfig, params, input_ids):
 
 def forward(cfg: BloomConfig, params: PyTree, input_ids, rng=None,
             train: bool = True):
-    from .gpt2 import _dequant_resident
-
-    params = _dequant_resident(params)
+    params = dequant_resident(params)
     x = _embed(cfg, params, input_ids)
 
     def body(x, xs):
         layer, = xs
-        fn = jax.checkpoint(lambda xx, ll: _block(cfg, xx, ll)[0]) \
-            if cfg.remat else (lambda xx, ll: _block(cfg, xx, ll)[0])
-        return fn(x, layer), None
+        fn = functools.partial(_block, cfg)
+        return (jax.checkpoint(fn) if cfg.remat else fn)(x, layer), None
 
     x, _ = jax.lax.scan(body, x, (params["blocks"],))
     x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
@@ -203,22 +192,19 @@ def forward(cfg: BloomConfig, params: PyTree, input_ids, rng=None,
 
 def init_cache(cfg: BloomConfig, batch_size: int, max_len: int,
                dtype=jnp.bfloat16):
-    shape = (cfg.num_layers, batch_size, cfg.num_heads, max_len, cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    return init_kv_cache(cfg.num_layers, batch_size, cfg.num_heads, max_len,
+                         cfg.head_dim, dtype)
 
 
 def _alibi_cached_attention(cfg: BloomConfig, q, k, v, ck, cv, pos,
                             block_tables=None, chunk_valid=None,
                             layer=None):
     """Write new KV + ALiBi attention, on either cache layout (contract in
-    gpt2._cached_attention).  Pure XLA on both layouts: the additive ALiBi
+    cached.cached_attention).  Pure XLA on both layouts: the additive ALiBi
     bias rules out the shared position-masked decode kernels, so the paged
     path gathers each row's logical view through its block table and biases
     by absolute positions (``pos`` scalar, or int32 [B] per-row — decode
     offsets, chunked-prefill bases, or speculative verify-window bases)."""
-    from ..ops.paged_kv import paged_cache_update, paged_gather
-    from .gpt2 import cache_update
-
     if block_tables is None:
         ck, cv = cache_update(ck, cv, k, v, pos)
         kk, vv = ck, cv
@@ -243,8 +229,6 @@ def _alibi_cached_attention(cfg: BloomConfig, q, k, v, ck, cv, pos,
         slopes[None, :, None, None] * rel[:, None]
     mask = kpos[None, None, :] <= qpos[:, :, None]        # [B | 1, T, S]
     mask = mask[:, None]                                  # [B | 1, 1, T, S]
-    from ..ops.decode_attention import window_state
-
     win = window_state()
     if win is not None:
         # resident-window serving: the demoted middle region
@@ -263,8 +247,7 @@ def _alibi_cached_attention(cfg: BloomConfig, q, k, v, ck, cv, pos,
 def _block_cached_body(cfg: BloomConfig, x, get, mm, ck, cv, pos,
                        block_tables=None, chunk_valid=None, layer=None):
     """One BLOOM block over a KV cache, parameterized by weight access
-    (same shape as gpt2._block_cached_body so the scan and layer-indexed
-    quantized decode paths share it)."""
+    (``cached.decode_over_layers``'s body)."""
     b, t, d = x.shape
     h, hd = cfg.num_heads, cfg.head_dim
 
@@ -289,43 +272,25 @@ def _block_cached_body(cfg: BloomConfig, x, get, mm, ck, cv, pos,
 def forward_cached(cfg: BloomConfig, params, input_ids, cache, pos,
                    lengths=None, block_tables=None, all_positions=False):
     """Incremental forward: logits for the LAST input position + updated
-    cache — or every position when ``all_positions`` is set ([B, T, V],
-    speculative verify head).
-
-    Follows the gpt2.forward_cached contract: ``lengths`` (int32 [B]) gives
-    per-sequence positions for continuous-batching slots (T == 1 decode at
-    ``lengths[b]``; T > 1 ragged prefill with per-row logit gather at
-    ``lengths[b] - 1``); ``block_tables`` switches to the block-paged cache
-    layout with ``pos`` as per-row window bases.  ALiBi has no position
-    table, so only the attention bias (absolute positions) moves with the
-    per-row offsets — the embedding is position-free."""
-    from .gpt2 import _dequant_resident, _gather_last, decode_over_layers
-
-    params = _dequant_resident(params)
-    pos = jnp.asarray(pos, jnp.int32)
-    t = input_ids.shape[1]
-    per_row = lengths is not None and t == 1
-    step_pos = jnp.asarray(lengths, jnp.int32) if per_row else pos
-    chunk_valid = jnp.asarray(lengths, jnp.int32) \
-        if (block_tables is not None and lengths is not None and t > 1) \
-        else None
-    x = _embed(cfg, params, input_ids)
-    from ..ops.sp_attention import shard_seq
-
+    cache — or every position when ``all_positions`` is set.  The contract
+    of ``lengths`` / ``block_tables`` is ``cached.window``'s.  ALiBi has no
+    position table, so only the attention bias (absolute positions) moves
+    with the per-row offsets — the embedding is position-free."""
+    params = dequant_resident(params)
+    w = window(input_ids, pos, lengths, block_tables)
     # sequence-parallel prefill hook: BLOOM's ALiBi attention has no
     # Ulysses all-to-all path (the additive bias rules out the shared
     # kernels), so sp here token-shards the projection/MLP chain and lets
     # GSPMD partition the bias-attention einsums
-    x = shard_seq(x)
-
+    x = shard_seq(_embed(cfg, params, input_ids))
     x, ks, vs = decode_over_layers(
         lambda x, get, mm, ck, cv, layer: _block_cached_body(
-            cfg, x, get, mm, ck, cv, step_pos, block_tables=block_tables,
-            chunk_valid=chunk_valid, layer=layer),
+            cfg, x, get, mm, ck, cv, w.step_pos, block_tables=block_tables,
+            chunk_valid=w.chunk_valid, layer=layer),
         x, params["blocks"], cache["k"], cache["v"], cfg.num_layers,
-        paged=block_tables is not None)
+        paged=w.paged)
     if not all_positions:
-        x = _gather_last(x, lengths if not per_row else None)
+        x = gather_last(x, w.gather)
     x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
     return x @ params["word_embeddings"].T.astype(x.dtype), \
         {"k": ks, "v": vs}
@@ -466,7 +431,7 @@ def build(cfg: Optional[BloomConfig] = None, **overrides) -> ModelSpec:
     pipeline_hooks = {
         "blocks_key": ("blocks",),
         "embed_fn": lambda params, ids: _embed(cfg, params, ids),
-        "block_fn": lambda layer, x, rng=None: _block(cfg, x, layer)[0],
+        "block_fn": lambda layer, x, rng=None: _block(cfg, x, layer),
         "head_loss_fn": lambda params, x, tgt: _head_loss(cfg, params, x,
                                                           tgt),
         "dropout": 0.0,  # dropout unimplemented (build() rejects > 0)

@@ -21,16 +21,18 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-import os
 from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..ops.sp_attention import shard_seq
 from ..parallel.topology import TP_AXIS
 from ..runtime.model import ModelSpec
 from ..utils.platform import on_tpu
+from .cached import (cached_attention, decode_over_layers, dequant_resident,
+                     gather_last, init_kv_cache, qmm, window)
 
 PyTree = Any
 
@@ -179,110 +181,6 @@ def _remat_policy(cfg):
 _warned_sp_dropout = False
 
 
-def _maybe_dequant(layer, dtype):
-    """Expand INT8 weight records (ops/quantization) for ONE layer slice —
-    the point-of-use dequant that keeps peak memory at one layer of
-    full-precision weights when the engine stores blocks as int8."""
-    from ..ops import quantization as quant
-
-    return jax.tree_util.tree_map(
-        lambda v: quant.dequantize(v, dtype) if quant.is_quantized(v) else v,
-        layer, is_leaf=quant.is_quantized)
-
-
-def _qmm(x, leaf, dtype=None):
-    """``x @ leaf`` where ``leaf`` may be an int8 record: K-grouped (W8A8)
-    records run the s8-MXU kernel, N-grouped weight-only records run the
-    dequant path (or the opt-in fused kernel — ops/quantized_matmul);
-    dense leaves take the plain matmul."""
-    from ..ops import quantization as quant
-
-    dtype = dtype or x.dtype
-    if quant.is_k_quantized(leaf):
-        from ..ops.quantized_matmul import w8a8_matmul
-
-        return w8a8_matmul(x, leaf, out_dtype=dtype)
-    if quant.is_quantized(leaf):
-        from ..ops.quantized_matmul import quantized_matmul
-
-        return quantized_matmul(x, leaf, out_dtype=dtype)
-    return x @ leaf.astype(dtype)
-
-
-def _qmm_indexed(x, leaf, l, dtype=None):
-    """``x @ leaf[l]`` for STACKED per-layer leaves selected by a (possibly
-    traced) layer index: K-grouped records run the stacked s8 kernel with
-    the layer chosen in-kernel (scalar prefetch — no per-layer weight copy
-    in HBM); other leaf kinds dynamic-slice the layer and take the same
-    path as :func:`_qmm`."""
-    from ..ops import quantization as quant
-
-    dtype = dtype or x.dtype
-    if quant.is_k_quantized(leaf):
-        from ..ops.quantized_matmul import w8a8_matmul_stacked
-
-        return w8a8_matmul_stacked(x, leaf, l, out_dtype=dtype)
-    if quant.is_quantized(leaf):
-        from ..ops.quantized_matmul import quantized_matmul
-
-        sliced = {k: jax.lax.dynamic_index_in_dim(v, l, keepdims=False)
-                  for k, v in leaf.items()}
-        return quantized_matmul(x, sliced, out_dtype=dtype)
-    w = jax.lax.dynamic_index_in_dim(leaf, l, keepdims=False)
-    return x @ w.astype(dtype)
-
-
-def layer_accessors(layer):
-    """Default weight accessors for an accessor-parameterized block body:
-    ``get(name)`` reads a small leaf from the pre-sliced layer dict, ``mm(y,
-    name, dtype)`` runs the matmul through :func:`_qmm` (identical HLO for
-    dense leaves; point-of-use dequant / w8a8 kernel for INT8 records).
-    The quantized indexed decode path substitutes stacked-kernel accessors
-    instead (:func:`decode_over_layers`)."""
-    def mm(y, name, dtype):
-        return _qmm(y, layer[name], dtype)
-
-    return layer.__getitem__, mm
-
-
-def use_indexed_decode(blocks, probe: str = "qkv_w",
-                       rows: int = 1) -> bool:
-    """Trace-time dispatch for quantized serving: run the layer-INDEXED
-    decode loop (stacked s8 kernel selects the layer in-kernel — no
-    per-layer int8 weight copy in HBM) instead of the scan.  False when the
-    stacked kernel wouldn't engage (TP, kernel off, or ``rows`` beyond the
-    kernel's decode-shaped cap — prefill traces and big batches) — there
-    the indexed loop would only add KV-stack slice/update traffic.
-    ``DS_INDEXED_DECODE=0`` is the kill switch (on-chip A/B)."""
-    from ..ops import quantization as quant
-    from ..ops.quantized_matmul import W8A8_MAX_ROWS, stacked_kernel_enabled
-
-    return (quant.is_k_quantized(blocks[probe])
-            and stacked_kernel_enabled()
-            and rows <= W8A8_MAX_ROWS
-            and os.environ.get("DS_INDEXED_DECODE", "1") != "0")
-
-
-def _dequant_resident(params, dtype=None):
-    """Dequantize the small resident params (embeddings, final LN) up front;
-    the stacked ``blocks`` stay int8 and expand per layer in ``_block``."""
-    from ..ops import quantization as quant
-
-    leaves = jax.tree_util.tree_leaves(params, is_leaf=quant.is_quantized)
-    if not any(quant.is_quantized(v) for v in leaves):
-        return params
-    if dtype is None:
-        # compute dtype = dtype of the small unquantized float leaves
-        # (norm scales stay below quantize_pytree's min_size filter)
-        dtype = next((v.dtype for v in leaves
-                      if not quant.is_quantized(v)
-                      and jnp.issubdtype(v.dtype, jnp.floating)),
-                     jnp.bfloat16)
-    out = {k: (_maybe_dequant(v, dtype) if k != "blocks" else v)
-           for k, v in params.items()}
-    return out
-
-
 def _block(cfg: GPT2Config, x, layer, mask, rng, dropout: float):
     """One transformer block. x: [B, S, D]; layer: per-layer param slice.
     ``mask=None`` means pure causal; the flash/SP fast paths require it (they
@@ -303,7 +201,7 @@ def _block(cfg: GPT2Config, x, layer, mask, rng, dropout: float):
 
     with jax.named_scope("layer/attn"):
         y = _aq(_layer_norm(x, layer["ln1_scale"], layer["ln1_bias"]))
-        qkv = _qmm(y, layer["qkv_w"]) + layer["qkv_b"].astype(y.dtype)
+        qkv = qmm(y, layer["qkv_w"]) + layer["qkv_b"].astype(y.dtype)
         q, k, v = jnp.split(qkv, 3, axis=-1)
         q = q.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
         k = k.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
@@ -342,13 +240,13 @@ def _block(cfg: GPT2Config, x, layer, mask, rng, dropout: float):
                 probs = probs * keep / (1.0 - dropout)
             attn = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
         attn = _aq(attn.transpose(0, 2, 1, 3).reshape(b, s, d))
-        x = x + _qmm(attn, layer["o_w"], x.dtype) + \
+        x = x + qmm(attn, layer["o_w"], x.dtype) + \
             layer["o_b"].astype(x.dtype)
     with jax.named_scope("layer/mlp"):
         y = _aq(_layer_norm(x, layer["ln2_scale"], layer["ln2_bias"]))
-        hid = _aq(jax.nn.gelu(_qmm(y, layer["fc_w"]) +
+        hid = _aq(jax.nn.gelu(qmm(y, layer["fc_w"]) +
                               layer["fc_b"].astype(y.dtype)))
-        x = x + _qmm(hid, layer["proj_w"], x.dtype) + \
+        x = x + qmm(hid, layer["proj_w"], x.dtype) + \
             layer["proj_b"].astype(x.dtype)
     return x
 
@@ -356,7 +254,7 @@ def _block(cfg: GPT2Config, x, layer, mask, rng, dropout: float):
 def forward(cfg: GPT2Config, params: PyTree, input_ids, rng=None,
             train: bool = True):
     """Token logits. input_ids: [B, S] int32."""
-    params = _dequant_resident(params)
+    params = dequant_resident(params)
     x = _trunk(cfg, params, input_ids, rng=rng, train=train)
     with jax.named_scope("head"):
         x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
@@ -366,60 +264,8 @@ def forward(cfg: GPT2Config, params: PyTree, input_ids, rng=None,
 
 def init_cache(cfg: GPT2Config, batch_size: int, max_len: int,
                dtype=jnp.bfloat16):
-    """Static KV workspace (reference ``inference_context.h``): [L,B,H,S,hd]."""
-    shape = (cfg.num_layers, batch_size, cfg.num_heads, max_len, cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-
-
-def cache_update(ck, cv, k, v, pos):
-    """Write new keys/values into the cache at ``pos``: a scalar writes one
-    contiguous [T]-span shared by every row (the classic static-batch decode);
-    an int32 [B] vector writes each row's single new entry at its own
-    position (continuous-batching slots, T must be 1).  Shared by every
-    decode-hook model family."""
-    pos = jnp.asarray(pos, jnp.int32)
-    if pos.ndim == 0:
-        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                          (0, 0, pos, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                          (0, 0, pos, 0))
-        return ck, cv
-    assert k.shape[2] == 1, "per-sequence positions require T == 1"
-    rows = jnp.arange(k.shape[0])
-    ck = ck.at[rows, :, pos].set(k[:, :, 0].astype(ck.dtype))
-    cv = cv.at[rows, :, pos].set(v[:, :, 0].astype(cv.dtype))
-    return ck, cv
-
-
-def _cached_attention(q, k, v, ck, cv, pos, block_tables=None,
-                      chunk_valid=None, layer=None, window: int = 0):
-    """Write new KV + attend, on either cache layout.  Contiguous
-    (``block_tables is None``): ck/cv are one layer's [B, H, S, hd]
-    per-sequence regions.  Paged: ck/cv are the WHOLE stacked
-    [L, NB, H, bs, hd] pool and ``layer`` the (traced) index of the layer
-    being run — the write and the read address the pool in place as
-    (layer, physical block, head, offset) through ``block_tables`` int32
-    [B, NBPER] (``ops/paged_kv.py``), so the caller carries the pool
-    through its layer loop untouched.  ``chunk_valid`` (int32 [B]) marks
-    how many of a T>1 chunk's tokens are real — pads write to the scratch
-    block, and the read of a prefill chunk walks the blocks ``pos +
-    chunk_valid`` reaches and no further.  ``window`` (static, paged
-    only): a sliding-window layer — ck/cv and ``block_tables`` are the
-    window kind's leaves and ring (``ops/paged_kv.py`` "Layer kinds").
-    Shared by every decode-hook model family."""
-    from ..ops.decode_attention import decode_attention, \
-        paged_decode_attention
-
-    if block_tables is None:
-        ck, cv = cache_update(ck, cv, k, v, pos)
-        return decode_attention(q, ck, cv, pos), ck, cv
-    from ..ops.paged_kv import paged_cache_update
-
-    ck, cv = paged_cache_update(ck, cv, k, v, pos, block_tables,
-                                valid=chunk_valid, layer=layer,
-                                ring=bool(window))
-    return paged_decode_attention(q, ck, cv, block_tables, pos, layer=layer,
-                                  valid=chunk_valid, window=window), ck, cv
+    return init_kv_cache(cfg.num_layers, batch_size, cfg.num_heads, max_len,
+                         cfg.head_dim, dtype)
 
 
 def _block_cached_body(cfg: GPT2Config, x, get, mm, ck, cv, pos,
@@ -429,9 +275,7 @@ def _block_cached_body(cfg: GPT2Config, x, get, mm, ck, cv, pos,
     and layer-indexed decode paths share the math.  x: [B, T, D]; ck/cv:
     [B, H, S, hd] — or, when ``block_tables`` is given, the whole paged
     pool [L, NB, H, bs, hd] plus this block's ``layer`` index (contract in
-    :func:`_cached_attention`); pos: traced global position of x[:, 0] —
-    scalar, or int32 [B] per-row positions (continuous-batching decode
-    T=1, or paged chunked-prefill bases T>1)."""
+    ``cached.cached_attention``); pos: ``cached.Window.step_pos``."""
     b, t, d = x.shape
     h, hd = cfg.num_heads, cfg.head_dim
 
@@ -442,8 +286,8 @@ def _block_cached_body(cfg: GPT2Config, x, get, mm, ck, cv, pos,
         q = q.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
         k = k.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
         v = v.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
-        attn, ck, cv = _cached_attention(q, k, v, ck, cv, pos, block_tables,
-                                         chunk_valid, layer)
+        attn, ck, cv = cached_attention(q, k, v, ck, cv, pos, block_tables,
+                                        chunk_valid, layer)
         attn = attn.transpose(0, 2, 1, 3).reshape(b, t, d)
         x = x + mm(attn, "o_w", x.dtype) + get("o_b").astype(x.dtype)
     with jax.named_scope("layer/mlp"):
@@ -453,181 +297,48 @@ def _block_cached_body(cfg: GPT2Config, x, get, mm, ck, cv, pos,
     return x, ck, cv
 
 
-def scan_layers_cached(step, x, blocks, cache_k, cache_v, paged: bool):
-    """``lax.scan`` of ``step(x, layer_params, ck, cv, l) -> (x, ck, cv)``
-    over the stacked ``blocks``, on either cache layout.  A ``step`` that
-    returns a fourth value (a small per-layer record: mixtral's routing
-    counts) gets it back stacked ``[L, ...]`` as a fourth result.
-
-    Contiguous (``paged=False``): the stacked [L, B, H, S, hd] cache rides
-    as ``xs`` beside the weights, each step gets its own layer's slice
-    (``l`` is None) and the updated slices re-stack as ``ys``.
-
-    Paged: the stacked pool [L, NB, H, bs, hd] is the loop CARRY and each
-    step gets the whole pool plus its layer index ``l`` — nothing slices a
-    layer out of the pool or re-stacks it, so the compiled ``while`` updates
-    the (donated) pool buffer in place (``ops/paged_kv.py`` has the
-    contract)."""
-    if not paged:
-        def sbody(x, xs):
-            layer, ck, cv = xs
-            x, ck, cv, *aux = step(x, layer, ck, cv, None)
-            return x, (ck, cv, *aux)
-
-        x, out = jax.lax.scan(sbody, x, (blocks, cache_k, cache_v))
-        return (x, *out)
-
-    def pbody(carry, xs):
-        x, pk, pv = carry
-        layer, l = xs
-        x, pk, pv, *aux = step(x, layer, pk, pv, l)
-        return (x, pk, pv), tuple(aux)
-
-    n = jax.tree_util.tree_leaves(blocks)[0].shape[0]
-    carry, aux = jax.lax.scan(
-        pbody, (x, cache_k, cache_v),
-        (blocks, jnp.arange(n, dtype=jnp.int32)))
-    return (*carry, *aux)
-
-
-def decode_over_layers(body, x, blocks, cache_k, cache_v, num_layers,
-                       probe: str = "qkv_w", paged: bool = False):
-    """Run ``body(x, get, mm, ck, cv, layer) -> (x, ck, cv)`` over all
-    layers: a ``lax.scan`` over pre-sliced layers normally, or — quantized
-    serving with the stacked s8 kernel available — a layer-indexed
-    ``fori_loop`` whose matmuls select the layer in-kernel (scalar
-    prefetch), so no per-layer int8 weight copy is ever materialized in
-    HBM.
-
-    ``paged`` (the caller passes ``block_tables is not None``): both loop
-    forms carry the whole stacked pool and hand the body the pool plus the
-    layer index (:func:`scan_layers_cached`).  Contiguous caches keep the
-    per-layer slice (``layer`` is None there)."""
-    from ..ops import quantization as quant
-
-    stack_l = jax.tree_util.tree_leaves(
-        blocks, is_leaf=quant.is_record)[0]
-    if quant.is_record(stack_l):
-        stack_l = stack_l.get("qk", stack_l.get("q"))
-    stack_l = stack_l.shape[0]
-    if stack_l != num_layers:
-        # fail-fast like lax.scan would: the fori_loop path's clamped
-        # dynamic indexing would otherwise silently re-run the last layer
-        raise ValueError(
-            f"stacked blocks carry {stack_l} layers but num_layers="
-            f"{num_layers}")
-    if use_indexed_decode(blocks, probe, rows=x.shape[0] * x.shape[1]):
-        def ibody(l, carry):
-            x, ck_all, cv_all = carry
-
-            def get(name):
-                return jax.lax.dynamic_index_in_dim(blocks[name], l,
-                                                    keepdims=False)
-
-            def mm(y, name, dtype):
-                return _qmm_indexed(y, blocks[name], l, dtype)
-
-            if paged:
-                return body(x, get, mm, ck_all, cv_all, l)
-            ck = jax.lax.dynamic_index_in_dim(ck_all, l, keepdims=False)
-            cv = jax.lax.dynamic_index_in_dim(cv_all, l, keepdims=False)
-            x, ck, cv = body(x, get, mm, ck, cv, None)
-            return (x,
-                    jax.lax.dynamic_update_index_in_dim(ck_all, ck, l, 0),
-                    jax.lax.dynamic_update_index_in_dim(cv_all, cv, l, 0))
-
-        return jax.lax.fori_loop(0, num_layers, ibody,
-                                 (x, cache_k, cache_v))
-
-    return scan_layers_cached(
-        lambda x, layer, ck, cv, l: body(x, *layer_accessors(layer),
-                                         ck, cv, l),
-        x, blocks, cache_k, cache_v, paged)
+def _embed_cached(cfg: GPT2Config, params, input_ids, pos):
+    """Token + learned position embeddings of a cached window whose first
+    token sits at ``pos`` (``cached.Window.step_pos``): one slice of ``wpe``
+    for a shared scalar, a clipped lookup a row for int32 [B]."""
+    t = input_ids.shape[1]
+    with jax.named_scope("embed"):
+        if pos.ndim == 0:
+            wpe = jax.lax.dynamic_slice(params["wpe"], (pos, 0),
+                                        (t, cfg.hidden_size))
+        elif t == 1:
+            wpe = params["wpe"][jnp.clip(pos, 0,
+                                         cfg.max_seq_len - 1)][:, None]
+        else:
+            idx = jnp.clip(
+                pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :],
+                0, cfg.max_seq_len - 1)
+            wpe = params["wpe"][idx]                              # [B, T, D]
+        return (params["wte"][input_ids] + wpe).astype(params["wte"].dtype)
 
 
 def forward_cached(cfg: GPT2Config, params, input_ids, cache, pos,
                    lengths=None, block_tables=None, all_positions=False):
     """Incremental forward: logits for the LAST input position + updated
-    cache — or for EVERY input position when ``all_positions`` is set (the
-    speculative-decoding verify head: a K+1-token window is scored in one
-    pass, returning [B, T, V] so the scheduler can compare the target's
-    greedy choice at each draft position).
-
-    ``lengths`` (optional int32 [B]) is the per-sequence valid length for
-    continuous-batching slots:
-     - T == 1 (decode): row ``b``'s token sits at global position
-       ``lengths[b]`` — per-row cache write, per-row attention prefix.
-       ``pos`` is ignored.
-     - T > 1 (ragged prefill window): rows are right-padded to T with
-       ``pos`` as the shared base (0 for fresh slots); causal attention makes
-       the pad positions unreachable from valid queries, and the returned
-       logits are gathered at each row's own last prompt token
-       (``lengths[b] - 1``) instead of column T-1.
-
-    ``block_tables`` (optional int32 [B, NBPER]) switches the cache to the
-    block-paged layout (``ops/paged_kv.py``): cache leaves are the shared
-    ``[L, NB, H, block_size, hd]`` pool and each row reaches its tokens
-    through its table.  T == 1 keeps the decode contract above; T > 1 is a
-    *chunked-prefill* window — ``pos`` may then be int32 [B] per-row chunk
-    bases (tokens already cached, e.g. a reused prefix) and ``lengths`` the
-    per-row count of real tokens in the window (pad tokens write to the
-    scratch block).
-    """
-    params = _dequant_resident(params)
-    b, t = input_ids.shape
-    d = cfg.hidden_size
-    pos = jnp.asarray(pos, jnp.int32)
-    per_row = lengths is not None and t == 1
-    with jax.named_scope("embed"):
-        if per_row:
-            lengths = jnp.asarray(lengths, jnp.int32)
-            step_pos = lengths
-            wpe = params["wpe"][jnp.clip(lengths, 0,
-                                         cfg.max_seq_len - 1)][:, None]
-        elif block_tables is not None and pos.ndim == 1:
-            # chunked prefill: per-row base positions for a T-token window
-            step_pos = pos
-            idx = jnp.clip(
-                pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :],
-                0, cfg.max_seq_len - 1)
-            wpe = params["wpe"][idx]                              # [B, T, D]
-        else:
-            step_pos = pos
-            wpe = jax.lax.dynamic_slice(params["wpe"], (pos, 0), (t, d))
-        x = (params["wte"][input_ids] + wpe).astype(params["wte"].dtype)
-    from ..ops.sp_attention import shard_seq
-
+    cache — or for EVERY input position when ``all_positions`` is set.  The
+    contract of ``lengths`` / ``block_tables`` is ``cached.window``'s."""
+    params = dequant_resident(params)
+    w = window(input_ids, pos, lengths, block_tables)
     # sequence-parallel prefill hook: token-shard hidden states over the
     # mesh sp axis (no-op outside an sp context or when T == 1)
-    x = shard_seq(x)
-
-    chunk_valid = jnp.asarray(lengths, jnp.int32) \
-        if (block_tables is not None and lengths is not None and t > 1) \
-        else None
+    x = shard_seq(_embed_cached(cfg, params, input_ids, w.step_pos))
     x, ks, vs = decode_over_layers(
         lambda x, get, mm, ck, cv, layer: _block_cached_body(
-            cfg, x, get, mm, ck, cv, step_pos, block_tables=block_tables,
-            chunk_valid=chunk_valid, layer=layer),
+            cfg, x, get, mm, ck, cv, w.step_pos, block_tables=block_tables,
+            chunk_valid=w.chunk_valid, layer=layer),
         x, params["blocks"], cache["k"], cache["v"], cfg.num_layers,
-        paged=block_tables is not None)
+        paged=w.paged)
     if not all_positions:
-        x = _gather_last(x, lengths if not per_row else None)
+        x = gather_last(x, w.gather)
     with jax.named_scope("head"):
         x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
         logits = x @ params["wte"].T.astype(x.dtype)
     return logits, {"k": ks, "v": vs}
-
-
-def _gather_last(x, lengths):
-    """Last valid hidden state per row: column T-1 when ``lengths`` is None
-    (uniform batch / per-row decode where T == 1), else each row's
-    ``lengths[b] - 1`` (ragged prefill).  Shared by the model families'
-    ``forward_cached`` heads."""
-    if lengths is None:
-        return x[:, -1]
-    t = x.shape[1]
-    idx = jnp.clip(jnp.asarray(lengths, jnp.int32) - 1, 0, t - 1)
-    return x[jnp.arange(x.shape[0]), idx]
 
 
 def _wte_lookup(cfg: GPT2Config, params, input_ids):

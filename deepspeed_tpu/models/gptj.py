@@ -22,6 +22,8 @@ from jax.sharding import PartitionSpec as P
 
 from ..parallel.topology import TP_AXIS
 from ..runtime.model import ModelSpec
+from .cached import (cache_update, decode_over_layers, dequant_resident,
+                     init_kv_cache, layer_accessors)
 
 PyTree = Any
 
@@ -143,8 +145,6 @@ def _attention(cfg: GPTJConfig, q, k, v, q_offset=0):
 
 def _block(cfg: GPTJConfig, x, layer, pos=0, cache=None, get=None, mm=None):
     if get is None or mm is None:
-        from .gpt2 import layer_accessors
-
         get, mm = layer_accessors(layer)
 
     b, s, d = x.shape
@@ -157,11 +157,7 @@ def _block(cfg: GPTJConfig, x, layer, pos=0, cache=None, get=None, mm=None):
     q = _rope_interleaved(cfg, q, offset=pos)
     k = _rope_interleaved(cfg, k, offset=pos)
     if cache is not None:
-        ck, cv = cache
-        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                          (0, 0, pos, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                          (0, 0, pos, 0))
+        ck, cv = cache_update(*cache, k, v, pos)
         attn = _attention(cfg, q, ck, cv, q_offset=pos)
         cache = (ck, cv)
     else:
@@ -178,9 +174,7 @@ def _block(cfg: GPTJConfig, x, layer, pos=0, cache=None, get=None, mm=None):
 
 def forward(cfg: GPTJConfig, params: PyTree, input_ids, rng=None,
             train: bool = True):
-    from .gpt2 import _dequant_resident
-
-    params = _dequant_resident(params)
+    params = dequant_resident(params)
     x = params["wte"][input_ids].astype(params["wte"].dtype)
 
     def body(x, xs):
@@ -197,14 +191,12 @@ def forward(cfg: GPTJConfig, params: PyTree, input_ids, rng=None,
 
 def init_cache(cfg: GPTJConfig, batch_size: int, max_len: int,
                dtype=jnp.bfloat16):
-    shape = (cfg.num_layers, batch_size, cfg.num_heads, max_len, cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    return init_kv_cache(cfg.num_layers, batch_size, cfg.num_heads, max_len,
+                         cfg.head_dim, dtype)
 
 
 def forward_cached(cfg: GPTJConfig, params, input_ids, cache, pos):
-    from .gpt2 import _dequant_resident, decode_over_layers
-
-    params = _dequant_resident(params)
+    params = dequant_resident(params)
     pos = jnp.asarray(pos, jnp.int32)
     x = params["wte"][input_ids].astype(params["wte"].dtype)
 

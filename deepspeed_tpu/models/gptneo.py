@@ -26,6 +26,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..parallel.topology import TP_AXIS
 from ..runtime.model import ModelSpec
+from .cached import cache_update, dequant_resident, init_kv_cache, qmm
 
 PyTree = Any
 
@@ -147,36 +148,30 @@ def _attention(cfg: GPTNeoConfig, q, k, v, local: bool, q_offset=0):
 
 
 def _block(cfg: GPTNeoConfig, x, layer, local: bool, pos=0, cache=None):
-    # matmuls route through gpt2._qmm (identical HLO for dense leaves;
+    # matmuls route through cached.qmm (identical HLO for dense leaves;
     # point-of-use dequant / per-layer w8a8 kernel for INT8 records — the
     # unrolled loop slices layers statically, so records arrive per-layer
     # and the stacked indexed path is unnecessary here)
-    from .gpt2 import _qmm
-
     b, s, d = x.shape
     h, hd = cfg.num_heads, cfg.head_dim
 
     y = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"])
-    q = _qmm(y, layer["q_w"]).reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-    k = _qmm(y, layer["k_w"]).reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-    v = _qmm(y, layer["v_w"]).reshape(b, s, h, hd).transpose(0, 2, 1, 3)
+    q = qmm(y, layer["q_w"]).reshape(b, s, h, hd).transpose(0, 2, 1, 3)
+    k = qmm(y, layer["k_w"]).reshape(b, s, h, hd).transpose(0, 2, 1, 3)
+    v = qmm(y, layer["v_w"]).reshape(b, s, h, hd).transpose(0, 2, 1, 3)
     if cache is not None:
-        ck, cv = cache
-        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                          (0, 0, pos, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                          (0, 0, pos, 0))
+        ck, cv = cache_update(*cache, k, v, pos)
         attn = _attention(cfg, q, ck, cv, local, q_offset=pos)
         cache = (ck, cv)
     else:
         attn = _attention(cfg, q, k, v, local)
     attn = attn.transpose(0, 2, 1, 3).reshape(b, s, d)
-    x = x + _qmm(attn, layer["o_w"], x.dtype) + layer["o_b"].astype(x.dtype)
+    x = x + qmm(attn, layer["o_w"], x.dtype) + layer["o_b"].astype(x.dtype)
 
     y = _layer_norm(x, layer["ln2_scale"], layer["ln2_bias"])
-    hid = jax.nn.gelu(_qmm(y, layer["fc_w"]) +
+    hid = jax.nn.gelu(qmm(y, layer["fc_w"]) +
                       layer["fc_b"].astype(y.dtype), approximate=True)
-    x = x + _qmm(hid, layer["proj_w"], x.dtype) + \
+    x = x + qmm(hid, layer["proj_w"], x.dtype) + \
         layer["proj_b"].astype(x.dtype)
     return x, cache
 
@@ -203,9 +198,7 @@ def _run_blocks(cfg: GPTNeoConfig, params, x, pos=0, cache=None):
 
 def forward(cfg: GPTNeoConfig, params: PyTree, input_ids, rng=None,
             train: bool = True):
-    from .gpt2 import _dequant_resident
-
-    params = _dequant_resident(params)
+    params = dequant_resident(params)
     b, s = input_ids.shape
     x = (params["wte"][input_ids] + params["wpe"][:s]).astype(
         params["wte"].dtype)
@@ -216,14 +209,12 @@ def forward(cfg: GPTNeoConfig, params: PyTree, input_ids, rng=None,
 
 def init_cache(cfg: GPTNeoConfig, batch_size: int, max_len: int,
                dtype=jnp.bfloat16):
-    shape = (cfg.num_layers, batch_size, cfg.num_heads, max_len, cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    return init_kv_cache(cfg.num_layers, batch_size, cfg.num_heads, max_len,
+                         cfg.head_dim, dtype)
 
 
 def forward_cached(cfg: GPTNeoConfig, params, input_ids, cache, pos):
-    from .gpt2 import _dequant_resident
-
-    params = _dequant_resident(params)
+    params = dequant_resident(params)
     b, t = input_ids.shape
     d = cfg.hidden_size
     pos = jnp.asarray(pos, jnp.int32)

@@ -21,9 +21,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..ops.sp_attention import shard_seq
 from ..parallel.topology import TP_AXIS
 from ..runtime.model import ModelSpec
 from ..utils.platform import on_tpu
+from . import cached          # (``window`` names a layer's reach in this file)
+from .cached import (cached_attention, decode_over_layers, dequant_resident,
+                     gather_last, init_kv_cache, layer_accessors, qmm,
+                     scan_layers_cached, scan_periods_cached)
 
 PyTree = Any
 
@@ -510,8 +515,6 @@ def _latent_attention(cfg: LlamaConfig, layer, y, cos, sin):
     """A latent layer's UNCACHED attention over ``y [B, S, d]`` in the
     EXPANDED form — every head's keys ``[kn | k_r]`` and values written out
     from the latent — positions ``0 .. S-1``; ``[B, S, H * v_dim]``."""
-    from .gpt2 import layer_accessors
-
     b, s, _ = y.shape
     h = cfg.num_heads
     get, mm = layer_accessors(layer)
@@ -579,11 +582,9 @@ def attn_apply(cfg: LlamaConfig, layer: PyTree, x, cos, sin, attention=None,
     patterned model (:func:`kind_of`).  ``delta`` (a parallel block): the
     pair ``(normed input, attention output)`` instead of the new
     residual."""
-    # matmuls route through gpt2._qmm: dense leaves trace to the identical
+    # matmuls route through cached.qmm: dense leaves trace to the identical
     # ``x @ w.astype`` HLO; INT8 records (quant-aware serving prefill)
     # dequantize at point of use instead of crashing on a dict leaf
-    from .gpt2 import _qmm
-
     b, s, d = x.shape
     h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
 
@@ -591,16 +592,16 @@ def attn_apply(cfg: LlamaConfig, layer: PyTree, x, cos, sin, attention=None,
     if cfg.latent:
         with jax.named_scope("layer/attn"):
             y = block_norm(cfg, x, layer["attn_norm"])
-            out = _qmm(_latent_attention(cfg, layer, y, cos, sin),
-                       layer["o_w"], x.dtype)
+            out = qmm(_latent_attention(cfg, layer, y, cos, sin),
+                      layer["o_w"], x.dtype)
             return (y, out) if delta else x + out
     with jax.named_scope("layer/attn"):
         y = block_norm(cfg, x, layer["attn_norm"])
-        q, k = qk_normed(cfg, _qmm(y, layer["q_w"]), _qmm(y, layer["k_w"]),
+        q, k = qk_normed(cfg, qmm(y, layer["q_w"]), qmm(y, layer["k_w"]),
                          layer.__getitem__)
         q = q.reshape(b, s, h, hd)
         k = k.reshape(b, s, hkv, hd)
-        v = _qmm(y, layer["v_w"]).reshape(b, s, hkv, hd)
+        v = qmm(y, layer["v_w"]).reshape(b, s, hkv, hd)
         q = q.transpose(0, 2, 1, 3)
         if rotated:
             q = apply_rope(q, cos, sin, cfg.rope_interleaved)
@@ -611,13 +612,11 @@ def attn_apply(cfg: LlamaConfig, layer: PyTree, x, cos, sin, attention=None,
         attn = _attention(cfg, q, k, v, window) if attention is None \
             else attention(y, q, k, v)
         attn = attn.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
-        out = _qmm(attn, layer["o_w"], x.dtype)
+        out = qmm(attn, layer["o_w"], x.dtype)
         return (y, out) if delta else x + out
 
 
 def block_apply(cfg: LlamaConfig, layer: PyTree, x, cos, sin):
-    from .gpt2 import _qmm
-
     if cfg.layer_kinds:
         raise NotImplementedError(
             "a layer pattern (layer_kinds) is built by models/mixtral.py; "
@@ -629,17 +628,15 @@ def block_apply(cfg: LlamaConfig, layer: PyTree, x, cos, sin):
         x = attn_apply(cfg, layer, x, cos, sin)
         y = block_norm(cfg, x, layer["mlp_norm"])
     with jax.named_scope("layer/mlp"):
-        gate = jax.nn.silu(_qmm(y, layer["w1"]))
-        up = _qmm(y, layer["w3"])
-        return x + _qmm(gate * up, layer["w2"], x.dtype)
+        gate = jax.nn.silu(qmm(y, layer["w1"]))
+        up = qmm(y, layer["w3"])
+        return x + qmm(gate * up, layer["w2"], x.dtype)
 
 
 def forward(cfg: LlamaConfig, params: PyTree, input_ids, rng=None,
             train: bool = True):
     del rng, train  # no dropout in llama pretraining config
-    from .gpt2 import _dequant_resident
-
-    params = _dequant_resident(params)
+    params = dequant_resident(params)
     b, s = input_ids.shape
     x = params["embed"][input_ids].astype(params["embed"].dtype)
     cos, sin = rope_angles(cfg, s)
@@ -672,9 +669,8 @@ def init_cache(cfg: LlamaConfig, batch_size: int, max_len: int,
         return {"latent": jnp.zeros(
             (cfg.num_layers, batch_size, 1, max_len,
              latent_pool_width(cfg.latent_width)), dtype)}
-    shape = (cfg.num_layers, batch_size, cfg.num_kv_heads, max_len,
-             cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    return init_kv_cache(cfg.num_layers, batch_size, cfg.num_kv_heads,
+                         max_len, cfg.head_dim, dtype)
 
 
 def _rope_cached(cfg: LlamaConfig, x, pos):
@@ -717,9 +713,7 @@ def _attend_cached(cfg: LlamaConfig, x, get, mm, ck, cv, pos, block_tables,
         if attend is not None:
             attn, ck, cv, extra = attend(y, q, k, v, ck, cv, extra)
         else:
-            from .gpt2 import _cached_attention
-
-            attn, ck, cv = _cached_attention(
+            attn, ck, cv = cached_attention(
                 q, k, v, ck, cv, pos, block_tables, chunk_valid, layer,
                 window=window)
         attn = attn.transpose(0, 2, 1, 3).reshape(b, t, h * hd)
@@ -731,7 +725,7 @@ def _block_cached_body(cfg: LlamaConfig, x, get, mm, ck, cv, pos,
                        layer=None, attend=None, extra=None, kind=None):
     """Cached-attention block parameterized by weight access (``get(name)``
     small leaf, ``mm(y, name, dtype)`` matmul — shared by the scan and
-    layer-indexed quantized decode paths, see gpt2.decode_over_layers).
+    layer-indexed quantized decode paths, see cached.decode_over_layers).
     ``mlp(y) -> (y, aux)`` overrides the dense SwiGLU (mixtral's routed
     FFN; ``aux`` is its per-layer routing record) and makes this return
     ``(x, ck, cv, aux)``.  ``attend(y, q, k, v, ck, cv, extra) -> (attn, ck,
@@ -740,7 +734,7 @@ def _block_cached_body(cfg: LlamaConfig, x, get, mm, ck, cv, pos,
     state of its own — a third pool leaf — in ``extra``) and makes this
     return ``(x, ck, cv, extra, aux)``.
     ``block_tables``/``chunk_valid`` switch ck/cv to the whole paged pool,
-    addressed in place at ``layer`` (contract in gpt2._cached_attention).
+    addressed in place at ``layer`` (contract in cached.cached_attention).
     ``kind`` (a patterned model, :func:`kind_of`): whether q and k are
     rotated and how far a query reaches; ck/cv, ``block_tables`` and
     ``layer`` are then that kind's own."""
@@ -779,8 +773,6 @@ def _block_cached(cfg: LlamaConfig, x, layer, ck, cv, pos, mlp_fn=None,
     the stack (paged pools only; in its KIND's stack for a patterned
     model).  With ``attend_fn``, ``ck`` is the pair ``(K, extra)`` the
     layer loop carries (:func:`forward_cached`)."""
-    from .gpt2 import layer_accessors
-
     body = functools.partial(
         _block_cached_body, cfg, x, *layer_accessors(layer),
         mlp=None if mlp_fn is None else (lambda y: mlp_fn(layer, y)),
@@ -796,68 +788,6 @@ def _block_cached(cfg: LlamaConfig, x, layer, ck, cv, pos, mlp_fn=None,
     return x, (ck, extra), cv, aux
 
 
-def live_tokens(input_ids, lengths=None, block_tables=None):
-    """bool ``[B, T]``: which input positions are somebody's tokens.  A
-    paged decode step (T == 1) runs every slot, idle ones with an all-scratch
-    (zero) block table; a paged prefill chunk (T > 1) is right-padded to
-    ``lengths``.  Without a paged table every position counts."""
-    b, t = input_ids.shape
-    if block_tables is None:
-        return jnp.ones((b, t), bool)
-    if isinstance(block_tables, dict):     # a table per layer kind
-        block_tables = block_tables["full"]
-    if t == 1 or lengths is None:
-        return jnp.broadcast_to(block_tables[:, :1] != 0, (b, t))
-    return jnp.arange(t)[None, :] < jnp.asarray(lengths)[:, None]
-
-
-#: the pool leaves and the table of each layer kind of a patterned model
-KIND_LEAVES = {"full": ("k", "v", "full"), "sliding": ("kw", "vw", "window")}
-
-
-def scan_periods_cached(cfg: LlamaConfig, step, x, blocks, cache,
-                        block_tables):
-    """The layer loop of a patterned model (``cfg.layer_kinds``) over the
-    block-paged pool: a ``lax.scan`` over PERIODS whose body is the
-    period's layers written out, so that each layer's kind — rotated or
-    not, how far it reaches, which leaves and which table it addresses —
-    is static in the program, where a ``lax.cond`` on a traced kind would
-    hold both branches and both pools in every layer.  All layers have the
-    same weight shapes, so the ``[L, ...]`` stacks stay, and layer ``period
-    * P + j`` is read out of them at a traced index.  ``step(x, layer, ck, cv, index, table, kind) -> (x,
-    ck, cv, aux)`` with ``index`` the layer's place among its KIND's layers
-    (``ops/paged_kv.py`` "Layer kinds"); ``cache`` holds ``k`` / ``v``
-    (full) and ``kw`` / ``vw`` (window), ``block_tables`` the tables
-    ``"full"`` / ``"window"``.  -> ``(x, cache, aux stacked [L, ...])``."""
-    kinds = cfg.layer_kinds
-    p, n = len(kinds), cfg.num_layers
-
-    def body(carry, period):
-        x, pools = carry
-        pools, auxes = dict(pools), []
-        for j, kind in enumerate(kinds):
-            same = [i for i in range(p) if kinds[i] == kind]
-            ck, cv, table = KIND_LEAVES[kind]
-            # the layer's weights, read where they lie in the stacks (a
-            # static slice of a parameter is a COPY on a TPU: 134 MB for
-            # one layer's q or o projection at Command A+'s widths)
-            layer = jax.tree_util.tree_map(
-                lambda a: jax.lax.dynamic_index_in_dim(
-                    a, period * p + j, keepdims=False), blocks)
-            x, pools[ck], pools[cv], aux = step(
-                x, layer, pools[ck], pools[cv],
-                period * len(same) + same.index(j), block_tables[table],
-                kind)
-            auxes.append(aux)
-        return (x, pools), jax.tree_util.tree_map(
-            lambda *a: jnp.stack(a), *auxes)
-
-    (x, cache), aux = jax.lax.scan(
-        body, (x, cache), jnp.arange(n // p, dtype=jnp.int32))
-    return x, cache, jax.tree_util.tree_map(
-        lambda a: a.reshape((n,) + a.shape[2:]), aux)
-
-
 def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
                    lengths=None, block_tables=None, mlp_fn=None,
                    all_positions=False, attend_fn=None, extra=None):
@@ -871,34 +801,15 @@ def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
     ``mlp_fn``) replaces the cache write + attention of every block:
     ``extra`` is any pytree of the caller's, carried through the layers
     beside K and V and handed back as a last result.
-    Quantized serving (no mlp_fn) takes
-    the layer-indexed stacked-kernel path via gpt2.decode_over_layers.
-
-    ``lengths`` (optional int32 [B]): per-sequence valid lengths for
-    continuous-batching slots — T == 1 decodes each row at its own position
-    ``lengths[b]`` (rope offset, cache write, attention prefix); T > 1 is
-    ragged right-padded prefill, gathering each row's logits at
-    ``lengths[b] - 1`` (see gpt2.forward_cached for the full contract).
-    ``block_tables`` (optional int32 [B, NBPER]) switches to the block-paged
-    cache layout; with T > 1 ``pos`` may be int32 [B] per-row chunk bases
-    (the rope offsets follow each row's base — chunked prefill)."""
-    from .gpt2 import (_dequant_resident, _gather_last, decode_over_layers,
-                       scan_layers_cached)
-
-    params = _dequant_resident(params)
-    pos = jnp.asarray(pos, jnp.int32)
-    t = input_ids.shape[1]
-    per_row = lengths is not None and t == 1
-    step_pos = jnp.asarray(lengths, jnp.int32) if per_row else pos
-    chunk_valid = jnp.asarray(lengths, jnp.int32) \
-        if (block_tables is not None and lengths is not None and t > 1) \
-        else None
-    paged = block_tables is not None
-    x = params["embed"][input_ids].astype(params["embed"].dtype)
-    from ..ops.sp_attention import shard_seq
-
+    Quantized serving (no mlp_fn) takes the layer-indexed stacked-kernel
+    path via ``cached.decode_over_layers``.  The contract of ``lengths`` /
+    ``block_tables`` is ``cached.window``'s; the rope offsets follow each
+    row's position."""
+    params = dequant_resident(params)
+    step_pos, chunk_valid, gather, paged = cached.window(
+        input_ids, pos, lengths, block_tables)
     # sequence-parallel prefill hook (no-op outside an sp context)
-    x = shard_seq(x)
+    x = shard_seq(params["embed"][input_ids].astype(params["embed"].dtype))
     # a latent model's pool is ONE leaf; it rides where K does and nothing
     # rides where V does
     first = "latent" if cfg.latent else "k"
@@ -917,7 +828,8 @@ def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
                 "ServingEngine) by models/mixtral.py: the contiguous cache "
                 "of InferenceEngine.generate has one kind of state")
         x, kv, records = scan_periods_cached(
-            cfg, lambda x, layer, ck, cv, l, table, kind: _block_cached(
+            cfg.layer_kinds, cfg.num_layers,
+            lambda x, layer, ck, cv, l, table, kind: _block_cached(
                 cfg, x, layer, ck, cv, step_pos, mlp_fn=mlp_fn,
                 block_tables=table, chunk_valid=chunk_valid, index=l,
                 kind=kind),
@@ -944,7 +856,7 @@ def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
         if attend_fn is not None:
             ks, extra = ks
     if not all_positions:
-        x = _gather_last(x, lengths if not per_row else None)
+        x = gather_last(x, gather)
     x = block_norm(cfg, x, params["final_norm"])
     logits = head_logits(cfg, params, x)
     if cfg.layer_kinds:

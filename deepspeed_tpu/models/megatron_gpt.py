@@ -22,6 +22,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
+from . import gpt2
 from .gpt2 import GPT2Config
 
 PyTree = Any
@@ -193,8 +194,6 @@ def load(ckpt_files: List[str], cfg: Optional[GPT2Config] = None,
     ``(ModelSpec, params)``.  Accepts raw state dicts or the Megatron
     wrapper dict ({'model': ..., 'checkpoint_version': ...})."""
     import torch
-
-    from . import gpt2
 
     raw = [torch.load(f, map_location="cpu", weights_only=False)
            for f in ckpt_files]

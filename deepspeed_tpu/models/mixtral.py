@@ -56,6 +56,7 @@ from ..ops import sparse_index_attention as sparse_attention
 from ..parallel.topology import EP_AXIS, TP_AXIS
 from ..runtime.model import ModelSpec
 from . import llama as L
+from .cached import KIND_LEAVES, layer_accessors, live_tokens, qmm
 
 PyTree = Any
 
@@ -296,8 +297,6 @@ def _moe_block(cfg: MixtralConfig, layer: PyTree, x, cos, sin,
     layer's kind in a patterned model."""
     attention = None
     if cfg.index_heads:
-        from .gpt2 import layer_accessors
-
         icos, isin = L.rope_angles(cfg, x.shape[1], dim=cfg.index_head_dim)
 
         def attention(y, q, k, v):
@@ -434,12 +433,10 @@ def _shared(cfg: MixtralConfig, layer, y):
     the experts side by side (``shared_w1`` / ``shared_w3`` ``[d, Sh * f]``,
     ``shared_w2 [Sh * f, d]``: the SUM of the ``Sh`` experts' outputs),
     averaged."""
-    from .gpt2 import _qmm
-
     with jax.named_scope("layer/moe/shared"):
-        gate = jax.nn.silu(_qmm(y, layer["shared_w1"]))
-        out = _qmm(gate * _qmm(y, layer["shared_w3"]), layer["shared_w2"],
-                   y.dtype)
+        gate = jax.nn.silu(qmm(y, layer["shared_w1"]))
+        out = qmm(gate * qmm(y, layer["shared_w3"]), layer["shared_w2"],
+                  y.dtype)
         return out / cfg.shared_experts
 
 
@@ -466,7 +463,7 @@ def init_cache(cfg: MixtralConfig, batch_size: int, max_len: int,
             n = periods * cfg.layer_kinds.count(kind)
             if n:
                 shape = (n, blocks, cfg.num_kv_heads, max_len, cfg.head_dim)
-                ck, cv, _ = L.KIND_LEAVES[kind]
+                ck, cv, _ = KIND_LEAVES[kind]
                 cache[ck] = jnp.zeros(shape, dtype)
                 cache[cv] = jnp.zeros(shape, dtype)
         return cache
@@ -487,8 +484,6 @@ def _sparse_attend(cfg: MixtralConfig, layer, y, q, k, v, ck, cv, extra, pos,
     K, V and indexer keys go to the same ``(layer, block, offset)``, then
     the read selects (``ops/sparse_index_attention.py``)."""
     from ..ops import paged_kv
-    from .gpt2 import layer_accessors
-
     qi, ki, wi = _indexer(cfg, *layer_accessors(layer), y,
                           lambda a: L._rope_cached(cfg, a, pos))
     ck, cv = paged_kv.paged_cache_update(
@@ -517,7 +512,7 @@ def forward_cached(cfg: MixtralConfig, params, input_ids, cache, pos,
     layout-independent.  ``routing`` adds a third result: int32
     ``[L, 3]``, per layer the experts with at least one row, the routed
     rows and the largest group (``moe/routed.py RECORD``), counted over the
-    live tokens (``llama.live_tokens``) — with an indexer the pair of that
+    live tokens (``cached.live_tokens``) — with an indexer the pair of that
     and int32 ``[5]``, what the layers' selections scored, chose and read
     (``sparse_index_attention.COUNTS``, summed over the layers).
     ``choices`` (paged caches) adds
@@ -532,7 +527,7 @@ def forward_cached(cfg: MixtralConfig, params, input_ids, cache, pos,
             "served through the block-paged pool with a block table per "
             "layer kind (init_serving / ServingEngine); the contiguous "
             "cache of InferenceEngine.generate has one kind of state")
-    live = L.live_tokens(input_ids, lengths, block_tables)
+    live = live_tokens(input_ids, lengths, block_tables)
     blocks, stacks = params["blocks"], None
     if _expert_kernel(blocks):
         # the expert stacks stay out of the layer scan: each layer's slice

@@ -30,9 +30,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ..ops.sp_attention import shard_seq
 from ..parallel.topology import TP_AXIS
 from ..runtime.model import ModelSpec
 from ..utils.platform import on_tpu
+from .cached import (cached_attention, decode_over_layers, dequant_resident,
+                     gather_last, init_kv_cache, layer_accessors, qmm, window)
 
 PyTree = Any
 _POS_OFFSET = 2  # HF OPTLearnedPositionalEmbedding.offset
@@ -200,8 +203,6 @@ def _attention(cfg: OPTConfig, q, k, v):
 
 def _block(cfg: OPTConfig, x, layer):
     """One OPT decoder layer. Pre-LN (do_layer_norm_before) or post-LN."""
-    from .gpt2 import _qmm
-
     b, s, d = x.shape
     h, hd = cfg.num_heads, cfg.head_dim
     # INT8 weight-only serving: quantized records run the fused Pallas
@@ -211,14 +212,14 @@ def _block(cfg: OPTConfig, x, layer):
         res = x
         y = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"]) \
             if cfg.do_layer_norm_before else x
-        qkv = _qmm(y, layer["qkv_w"]) + layer["qkv_b"].astype(y.dtype)
+        qkv = qmm(y, layer["qkv_w"]) + layer["qkv_b"].astype(y.dtype)
         q, k, v = jnp.split(qkv, 3, axis=-1)
         q = q.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
         k = k.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
         v = v.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
         attn = _attention(cfg, q, k, v)
         attn = attn.transpose(0, 2, 1, 3).reshape(b, s, d)
-        x = res + _qmm(attn, layer["o_w"], x.dtype) + \
+        x = res + qmm(attn, layer["o_w"], x.dtype) + \
             layer["o_b"].astype(x.dtype)
         if not cfg.do_layer_norm_before:
             x = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"])
@@ -226,9 +227,9 @@ def _block(cfg: OPTConfig, x, layer):
         res = x
         y = _layer_norm(x, layer["ln2_scale"], layer["ln2_bias"]) \
             if cfg.do_layer_norm_before else x
-        hid = jax.nn.relu(_qmm(y, layer["fc_w"]) +
+        hid = jax.nn.relu(qmm(y, layer["fc_w"]) +
                           layer["fc_b"].astype(y.dtype))
-        x = res + _qmm(hid, layer["proj_w"], x.dtype) + \
+        x = res + qmm(hid, layer["proj_w"], x.dtype) + \
             layer["proj_b"].astype(x.dtype)
         if not cfg.do_layer_norm_before:
             x = _layer_norm(x, layer["ln2_scale"], layer["ln2_bias"])
@@ -275,9 +276,7 @@ def _head(cfg: OPTConfig, params, x):
 def forward(cfg: OPTConfig, params: PyTree, input_ids, rng=None,
             train: bool = True):
     """Token logits. input_ids: [B, S] int32."""
-    from .gpt2 import _dequant_resident
-
-    params = _dequant_resident(params)
+    params = dequant_resident(params)
     x = _embed(cfg, params, input_ids)
 
     def body(x, xs):
@@ -292,8 +291,8 @@ def forward(cfg: OPTConfig, params: PyTree, input_ids, rng=None,
 
 def init_cache(cfg: OPTConfig, batch_size: int, max_len: int,
                dtype=jnp.bfloat16):
-    shape = (cfg.num_layers, batch_size, cfg.num_heads, max_len, cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    return init_kv_cache(cfg.num_layers, batch_size, cfg.num_heads, max_len,
+                         cfg.head_dim, dtype)
 
 
 def _block_cached_body(cfg: OPTConfig, x, get, mm, ck, cv, pos,
@@ -303,11 +302,9 @@ def _block_cached_body(cfg: OPTConfig, x, get, mm, ck, cv, pos,
     dtype)`` runs ``y @ weight`` — the scan path indexes a pre-sliced layer
     dict, the quantized indexed path selects the layer in-kernel.
     ``block_tables``/``chunk_valid`` switch ck/cv to the whole paged pool,
-    addressed in place at ``layer`` (contract in gpt2._cached_attention)."""
+    addressed in place at ``layer`` (contract in cached.cached_attention)."""
     b, t, d = x.shape
     h, hd = cfg.num_heads, cfg.head_dim
-
-    from .gpt2 import _cached_attention
 
     with jax.named_scope("layer/attn"):
         res = x
@@ -318,8 +315,8 @@ def _block_cached_body(cfg: OPTConfig, x, get, mm, ck, cv, pos,
         q = q.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
         k = k.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
         v = v.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
-        attn, ck, cv = _cached_attention(q, k, v, ck, cv, pos, block_tables,
-                                         chunk_valid, layer)
+        attn, ck, cv = cached_attention(q, k, v, ck, cv, pos, block_tables,
+                                        chunk_valid, layer)
         attn = attn.transpose(0, 2, 1, 3).reshape(b, t, d)
         x = res + mm(attn, "o_w", x.dtype) + get("o_b").astype(x.dtype)
         if not cfg.do_layer_norm_before:
@@ -336,49 +333,27 @@ def _block_cached_body(cfg: OPTConfig, x, get, mm, ck, cv, pos,
 
 
 def _block_cached(cfg: OPTConfig, x, layer, ck, cv, pos):
-    from .gpt2 import layer_accessors
-
     return _block_cached_body(cfg, x, *layer_accessors(layer), ck, cv, pos)
 
 
 def forward_cached(cfg: OPTConfig, params, input_ids, cache, pos,
                    lengths=None, block_tables=None, all_positions=False):
     """Incremental forward: logits for the LAST position + updated cache —
-    or for EVERY position when ``all_positions`` is set ([B, T, V], the
-    speculative-verify head).  Quantized serving runs the layer-indexed
-    loop (stacked s8 kernel, gpt2.decode_over_layers) instead of the scan.
-
-    ``lengths`` (optional int32 [B]): per-sequence valid lengths for
-    continuous-batching slots — T == 1 decodes each row at position
-    ``lengths[b]``; T > 1 is ragged right-padded prefill with per-row logit
-    gather at ``lengths[b] - 1`` (contract in gpt2.forward_cached).
-    ``block_tables`` (optional int32 [B, NBPER]) switches to the block-paged
-    cache layout; with T > 1 ``pos`` may be int32 [B] per-row chunk bases
-    (learned position embeddings follow each row's base)."""
-    from .gpt2 import _dequant_resident, _gather_last, decode_over_layers
-
-    params = _dequant_resident(params)
-    pos = jnp.asarray(pos, jnp.int32)
-    t = input_ids.shape[1]
-    per_row = lengths is not None and t == 1
-    step_pos = jnp.asarray(lengths, jnp.int32) if per_row else pos
-    chunk_valid = jnp.asarray(lengths, jnp.int32) \
-        if (block_tables is not None and lengths is not None and t > 1) \
-        else None
-    x = _embed(cfg, params, input_ids, pos0=step_pos)
-    from ..ops.sp_attention import shard_seq
-
+    or for EVERY position when ``all_positions`` is set.  The contract of
+    ``lengths`` / ``block_tables`` is ``cached.window``'s; the learned
+    position embeddings follow each row's base."""
+    params = dequant_resident(params)
+    w = window(input_ids, pos, lengths, block_tables)
     # sequence-parallel prefill hook (no-op outside an sp context)
-    x = shard_seq(x)
-
+    x = shard_seq(_embed(cfg, params, input_ids, pos0=w.step_pos))
     x, ks, vs = decode_over_layers(
         lambda x, get, mm, ck, cv, layer: _block_cached_body(
-            cfg, x, get, mm, ck, cv, step_pos, block_tables=block_tables,
-            chunk_valid=chunk_valid, layer=layer),
+            cfg, x, get, mm, ck, cv, w.step_pos, block_tables=block_tables,
+            chunk_valid=w.chunk_valid, layer=layer),
         x, params["blocks"], cache["k"], cache["v"], cfg.num_layers,
-        paged=block_tables is not None)
+        paged=w.paged)
     if not all_positions:
-        x = _gather_last(x, lengths if not per_row else None)
+        x = gather_last(x, w.gather)
     return _head(cfg, params, x), {"k": ks, "v": vs}
 
 
@@ -546,14 +521,10 @@ def build(cfg: Optional[OPTConfig] = None, **overrides) -> ModelSpec:
     }
 
     def _stream_embed(params, ids, pos):
-        from .gpt2 import _dequant_resident
-
-        return _embed(cfg, _dequant_resident(params), ids, pos0=pos)
+        return _embed(cfg, dequant_resident(params), ids, pos0=pos)
 
     def _stream_head(params, x_last):
-        from .gpt2 import _dequant_resident
-
-        return _head(cfg, _dequant_resident(params), x_last)
+        return _head(cfg, dequant_resident(params), x_last)
 
     stream_hooks = {
         "embed": _stream_embed,

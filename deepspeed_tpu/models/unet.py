@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..runtime.model import ModelSpec
-from .vae import (_conv_init, _gn_init, conv2d, group_norm)
+from .vae import (conv_init, gn_init, conv2d, group_norm)
 
 PyTree = Any
 
@@ -113,11 +113,11 @@ def timestep_embedding(timesteps, dim: int, max_period: float = 10000.0):
 
 def _resnet_init(key, cin, cout, temb_dim):
     k1, k2, k3, k4 = jax.random.split(key, 4)
-    p = {"norm1": _gn_init(cin), "conv1": _conv_init(k1, cin, cout, 3),
+    p = {"norm1": gn_init(cin), "conv1": conv_init(k1, cin, cout, 3),
          "time_emb": _dense_init(k2, temb_dim, cout),
-         "norm2": _gn_init(cout), "conv2": _conv_init(k3, cout, cout, 3)}
+         "norm2": gn_init(cout), "conv2": conv_init(k3, cout, cout, 3)}
     if cin != cout:
-        p["shortcut"] = _conv_init(k4, cin, cout, 1)
+        p["shortcut"] = conv_init(k4, cin, cout, 1)
     return p
 
 
@@ -179,10 +179,10 @@ def _tx_block(p, x, context, heads: int):
 
 def _transformer_init(key, c, ctx_dim, heads, head_dim):
     ks = jax.random.split(key, 3)
-    return {"norm": _gn_init(c),
-            "proj_in": _conv_init(ks[0], c, c, 1),
+    return {"norm": gn_init(c),
+            "proj_in": conv_init(ks[0], c, c, 1),
             "block": _tx_block_init(ks[1], c, ctx_dim, heads, head_dim),
-            "proj_out": _conv_init(ks[2], c, c, 1)}
+            "proj_out": conv_init(ks[2], c, c, 1)}
 
 
 def transformer_2d(p, x, context, groups: int, heads: int):
@@ -207,7 +207,7 @@ def init_params(cfg: UNetConfig, rng) -> PyTree:
     p: Dict[str, Any] = {
         "time_mlp1": _dense_init(next(keys), chans[0], temb),
         "time_mlp2": _dense_init(next(keys), temb, temb),
-        "conv_in": _conv_init(next(keys), cfg.in_channels, chans[0], 3),
+        "conv_in": conv_init(next(keys), cfg.in_channels, chans[0], 3),
     }
     down = []
     c = chans[0]
@@ -224,7 +224,7 @@ def init_params(cfg: UNetConfig, rng) -> PyTree:
                     ch // heads))
         c = ch
         if i < len(chans) - 1:
-            blk["down"] = _conv_init(next(keys), ch, ch, 3)
+            blk["down"] = conv_init(next(keys), ch, ch, 3)
         down.append(blk)
     p["down"] = down
     p["mid"] = {"res1": _resnet_init(next(keys), c, c, temb),
@@ -255,11 +255,11 @@ def init_params(cfg: UNetConfig, rng) -> PyTree:
                     ch // heads))
         c = ch
         if i < len(rev) - 1:
-            blk["up"] = _conv_init(next(keys), ch, ch, 3)
+            blk["up"] = conv_init(next(keys), ch, ch, 3)
         up.append(blk)
     p["up"] = up
-    p["norm_out"] = _gn_init(chans[0])
-    p["conv_out"] = _conv_init(next(keys), chans[0], cfg.out_channels, 3)
+    p["norm_out"] = gn_init(chans[0])
+    p["conv_out"] = conv_init(next(keys), chans[0], cfg.out_channels, 3)
     return p
 
 

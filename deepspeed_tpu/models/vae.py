@@ -60,7 +60,7 @@ class VAEConfig:
 
 
 # ----------------------------------------------------------------- primitives
-def _conv_init(key, cin, cout, k):
+def conv_init(key, cin, cout, k):
     fan_in = cin * k * k
     w = jax.random.normal(key, (cout, cin, k, k)) / np.sqrt(fan_in)
     return {"w": w.astype(jnp.float32), "b": jnp.zeros((cout,))}
@@ -86,16 +86,16 @@ def group_norm(p, x, groups: int, eps: float = 1e-6):
             p["bias"][None, :, None, None]).astype(x.dtype)
 
 
-def _gn_init(c):
+def gn_init(c):
     return {"scale": jnp.ones((c,)), "bias": jnp.zeros((c,))}
 
 
 def _resnet_init(key, cin, cout):
     k1, k2, k3 = jax.random.split(key, 3)
-    p = {"norm1": _gn_init(cin), "conv1": _conv_init(k1, cin, cout, 3),
-         "norm2": _gn_init(cout), "conv2": _conv_init(k2, cout, cout, 3)}
+    p = {"norm1": gn_init(cin), "conv1": conv_init(k1, cin, cout, 3),
+         "norm2": gn_init(cout), "conv2": conv_init(k2, cout, cout, 3)}
     if cin != cout:
-        p["shortcut"] = _conv_init(k3, cin, cout, 1)
+        p["shortcut"] = conv_init(k3, cin, cout, 1)
     return p
 
 
@@ -114,7 +114,7 @@ def _attn_init(key, c):
     dense = lambda k: {"w": (jax.random.normal(k, (c, c)) /
                              np.sqrt(c)).astype(jnp.float32),
                        "b": jnp.zeros((c,))}
-    return {"norm": _gn_init(c), "q": dense(ks[0]), "k": dense(ks[1]),
+    return {"norm": gn_init(c), "q": dense(ks[0]), "k": dense(ks[1]),
             "v": dense(ks[2]), "proj": dense(ks[3])}
 
 
@@ -140,8 +140,8 @@ def init_params(cfg: VAEConfig, rng) -> PyTree:
     keys = iter(jax.random.split(rng, 200))
 
     # encoder
-    enc: Dict[str, Any] = {"conv_in": _conv_init(next(keys), cfg.in_channels,
-                                                 chans[0], 3)}
+    enc: Dict[str, Any] = {"conv_in": conv_init(next(keys), cfg.in_channels,
+                                                chans[0], 3)}
     down = []
     c = chans[0]
     for i, ch in enumerate(chans):
@@ -149,18 +149,18 @@ def init_params(cfg: VAEConfig, rng) -> PyTree:
                            for j in range(cfg.layers_per_block)]}
         c = ch
         if i < len(chans) - 1:
-            blk["down"] = _conv_init(next(keys), ch, ch, 3)
+            blk["down"] = conv_init(next(keys), ch, ch, 3)
         down.append(blk)
     enc["down"] = down
     enc["mid"] = {"res1": _resnet_init(next(keys), c, c),
                   "attn": _attn_init(next(keys), c),
                   "res2": _resnet_init(next(keys), c, c)}
-    enc["norm_out"] = _gn_init(c)
-    enc["conv_out"] = _conv_init(next(keys), c, 2 * cfg.latent_channels, 3)
+    enc["norm_out"] = gn_init(c)
+    enc["conv_out"] = conv_init(next(keys), c, 2 * cfg.latent_channels, 3)
 
     # decoder (mirrored)
-    dec: Dict[str, Any] = {"conv_in": _conv_init(next(keys),
-                                                 cfg.latent_channels, c, 3)}
+    dec: Dict[str, Any] = {"conv_in": conv_init(next(keys),
+                                                cfg.latent_channels, c, 3)}
     dec["mid"] = {"res1": _resnet_init(next(keys), c, c),
                   "attn": _attn_init(next(keys), c),
                   "res2": _resnet_init(next(keys), c, c)}
@@ -170,17 +170,17 @@ def init_params(cfg: VAEConfig, rng) -> PyTree:
                            for j in range(cfg.layers_per_block + 1)]}
         c = ch
         if i < len(chans) - 1:
-            blk["up"] = _conv_init(next(keys), ch, ch, 3)
+            blk["up"] = conv_init(next(keys), ch, ch, 3)
         up.append(blk)
     dec["up"] = up
-    dec["norm_out"] = _gn_init(c)
-    dec["conv_out"] = _conv_init(next(keys), c, cfg.in_channels, 3)
+    dec["norm_out"] = gn_init(c)
+    dec["conv_out"] = conv_init(next(keys), c, cfg.in_channels, 3)
 
     return {"encoder": enc, "decoder": dec,
-            "quant_conv": _conv_init(next(keys), 2 * cfg.latent_channels,
-                                     2 * cfg.latent_channels, 1),
-            "post_quant_conv": _conv_init(next(keys), cfg.latent_channels,
-                                          cfg.latent_channels, 1)}
+            "quant_conv": conv_init(next(keys), 2 * cfg.latent_channels,
+                                    2 * cfg.latent_channels, 1),
+            "post_quant_conv": conv_init(next(keys), cfg.latent_channels,
+                                         cfg.latent_channels, 1)}
 
 
 # ----------------------------------------------------------------- forward

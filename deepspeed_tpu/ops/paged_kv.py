@@ -19,7 +19,7 @@ Layout contract — the WHOLE stacked pool, addressed in place:
    here takes a whole leaf plus a ``layer`` index and touches ``(layer, physical block, head,
    offset)`` in place; nothing slices a layer out of the pool, re-stacks it
    or changes its layout.  The models' layer loops therefore CARRY the pool
-   (``models/gpt2.py:scan_layers_cached``) and a program that donates it
+   (``models/cached.py:scan_layers_cached``) and a program that donates it
    gets it back in the same buffer.  A 4-D ``[NB, HKV, bs, hd]`` pool with
    ``layer=None`` is the same code on a one-layer view (:func:`whole_pool`).
  - block table: ``int32 [B, NBPER]`` — each row maps a sequence's logical
@@ -41,7 +41,7 @@ Layout contract — the WHOLE stacked pool, addressed in place:
    write) or ``window=W`` (the reads, ``ops/decode_attention.py``) for a
    window layer and are today's programs without; which table and which
    leaves a layer addresses is static in the model's layer loop
-   (``models/llama.py:scan_periods_cached``).
+   (``models/cached.py:scan_periods_cached``).
 
  - **The latent kind.**  A model with latent attention (MLA,
    ``LlamaConfig.kv_lora_rank > 0``) caches no key and no value a head: a

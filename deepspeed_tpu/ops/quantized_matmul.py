@@ -7,7 +7,7 @@ the reference never materializes an fp16 copy of an int8 weight in HBM —
 dequant happens in the GEMM's shared-memory staging.
 
 TPU design: XLA cannot fuse an elementwise producer into a ``dot`` operand,
-so the point-of-use ``dequantize() @`` pattern (models/gpt2.py
+so the point-of-use ``dequantize() @`` pattern (models/cached.py
 ``_maybe_dequant``) round-trips a full bf16 copy of every weight through HBM
 each decode step: int8 read + bf16 write + bf16 read = ~5 bytes/param where
 the int8 payload is 1.  At bs=1 decode — pure HBM-bandwidth-bound matvecs —
@@ -49,7 +49,7 @@ from ..utils.platform import interpret_kernels
 #: the s8 kernel on its weight slice, no communication) and row-parallel
 #: (K sharded — local partial on the s8 kernel, one psum after).
 #: decode-shaped row cap shared by w8a8_matmul / w8a8_matmul_stacked and
-#: the models' indexed-decode gate (gpt2.use_indexed_decode)
+#: the models' indexed-decode gate (models/cached.py use_indexed_decode)
 W8A8_MAX_ROWS = 8
 
 

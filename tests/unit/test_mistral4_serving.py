@@ -143,7 +143,7 @@ def test_absorbed_equals_expanded_layer_by_layer(model):
     """Each layer's attention alone: the cached ABSORBED body over a paged
     pool (one chunk, then a decode step) against the uncached EXPANDED
     body, on the same normed input — float32 rounding apart."""
-    from deepspeed_tpu.models.gpt2 import layer_accessors
+    from deepspeed_tpu.models.cached import layer_accessors
 
     cfg, _, params = model
     b, s = 2, 41

@@ -1,0 +1,96 @@
+"""ISSUE 46: ``models/cached.py`` is the one home of the cached forward, and
+a family reaches it (and nothing of a sibling's) by public names at module
+level.  An ``ast`` walk over ``deepspeed_tpu/models/*.py``: at the parent of
+PR 46 seven families imported ``gpt2``'s private helpers from inside their
+functions, 31 times — the next architecture cannot hook in sideways
+unnoticed."""
+
+import ast
+import pathlib
+
+import deepspeed_tpu.models as models
+
+ROOT = pathlib.Path(models.__file__).parent
+MODULES = {p.stem: ast.parse(p.read_text())
+           for p in sorted(ROOT.glob("*.py")) if p.stem != "__init__"}
+FAMILIES = sorted(set(MODULES) - {"cached"})
+#: the family-to-family edges that stay: two are inheritance
+#: (``MixtralConfig(LlamaConfig)``; Megatron's checkpoints load as GPT-2),
+#: and the diffusion pair shares its convolution and group-norm layers
+ALLOWED = {("mixtral", "llama"), ("megatron_gpt", "gpt2"), ("unet", "vae")}
+
+
+def _sibling_imports(tree):
+    """``(sibling module, imported name, node, inside a function)`` of every
+    import of another module of ``models/`` (relative, or by the package's
+    full name)."""
+    inside = {id(n) for f in ast.walk(tree)
+              if isinstance(f, (ast.FunctionDef, ast.AsyncFunctionDef,
+                                ast.Lambda))
+              for n in ast.walk(f)}
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            for a in node.names:
+                if a.name.startswith("deepspeed_tpu.models."):
+                    yield a.name.split(".")[2], None, node, id(node) in inside
+        if not isinstance(node, ast.ImportFrom):
+            continue
+        module = node.module or ""
+        if node.level == 0 and module.startswith("deepspeed_tpu.models"):
+            module = module[len("deepspeed_tpu.models"):].lstrip(".")
+        elif node.level != 1:
+            continue
+        for a in node.names:
+            # ``from . import llama`` names the sibling; ``from .llama
+            # import x`` a name of its
+            sibling, name = (module.split(".")[0], a.name) if module \
+                else (a.name, None)
+            if sibling in MODULES:
+                yield sibling, name, node, id(node) in inside
+
+
+def _where(stem, node):
+    return f"models/{stem}.py:{node.lineno}"
+
+
+def test_cached_imports_no_family():
+    found = [_where("cached", n) + f" imports {sib}"
+             for sib, _, n, _ in _sibling_imports(MODULES["cached"])]
+    assert not found, found
+
+
+def test_no_family_imports_a_private_name_of_a_sibling():
+    found = [f"{_where(stem, n)} imports {sib}.{name}"
+             for stem in FAMILIES
+             for sib, name, n, _ in _sibling_imports(MODULES[stem])
+             if name and name.startswith("_")]
+    assert not found, found
+
+
+def test_the_family_to_family_imports_are_the_three_that_stay():
+    edges = {(stem, sib) for stem in FAMILIES
+             for sib, _, _, _ in _sibling_imports(MODULES[stem])
+             if sib != "cached"}
+    assert edges == ALLOWED, sorted(edges ^ ALLOWED)
+
+
+def test_no_sibling_is_imported_inside_a_function_body():
+    found = [f"{_where(stem, n)} imports {sib} inside a function"
+             for stem in MODULES
+             for sib, _, n, local in _sibling_imports(MODULES[stem]) if local]
+    assert not found, found
+
+
+def test_the_window_contract_is_decided_in_one_place():
+    """One ``per_row = `` in the package, in ``cached.window``; ``llama.py``
+    defines none of the loop it used to lend."""
+    hits = [p.name for p in sorted(ROOT.glob("*.py"))
+            for line in p.read_text().splitlines() if "per_row = " in line]
+    assert hits == ["cached.py"], hits
+    defined = {n.name for n in ast.walk(MODULES["llama"])
+               if isinstance(n, ast.FunctionDef)} | {
+        t.id for n in ast.walk(MODULES["llama"])
+        if isinstance(n, ast.Assign) for t in n.targets
+        if isinstance(t, ast.Name)}
+    assert not defined & {"scan_periods_cached", "KIND_LEAVES",
+                          "live_tokens"}

@@ -583,7 +583,7 @@ def _tiny_model(family):
 def test_indexed_decode_matches_scan_path(family, monkeypatch):
     """forward_cached's layer-indexed loop (quantized serving) produces the
     same tokens as the scan path (DS_INDEXED_DECODE=0 kill switch) over the
-    same quantized records — the dispatch is shared (gpt2.decode_over_layers)
+    same quantized records — the dispatch is shared (cached.decode_over_layers)
     so every quant-aware family goes through it."""
     import deepspeed_tpu
 
@@ -613,7 +613,7 @@ def test_indexed_decode_gate_respects_kernel_state(monkeypatch):
     """use_indexed_decode is False whenever the stacked kernel would fall
     back (TP mode, kernel off, DS_W8A8=0, unquantized blocks) — the indexed
     loop must not run without its benefit."""
-    from deepspeed_tpu.models.gpt2 import use_indexed_decode
+    from deepspeed_tpu.models.cached import use_indexed_decode
     from deepspeed_tpu.ops import quantized_matmul as qmm
     from deepspeed_tpu.ops import quantization as quant
 
