@@ -444,19 +444,23 @@ def apply_rope(x, cos, sin, interleaved: bool = False):
 def _attention(cfg: LlamaConfig, q, k, v, window: int = 0):
     from ..parallel import sequence as seq_parallel
 
-    if window:
-        # a sliding layer's uncached attention is the plain masked one (the
-        # served path reads the window through the paged kernels)
-        return _dense_attention(cfg, q, k, v, window)
     if seq_parallel.sp_size() > 1:
+        if window:
+            # no sequence-parallel attention knows a window: the plain
+            # masked one
+            return _dense_attention(cfg, q, k, v, window)
         return seq_parallel.sequence_parallel_attention(
             q, k, v, causal=True, impl=cfg.sp_impl)
     use_flash = cfg.use_flash
     if use_flash is None:
         use_flash = on_tpu()
     if use_flash:
-        return seq_parallel.mesh_flash_attention(q, k, v, causal=True)
-    return _dense_attention(cfg, q, k, v)
+        # a sliding layer's window is the flash kernels' own (blocks outside
+        # the band skipped, forward and backward); the dense masked path is
+        # the CPU's and the tests' oracle
+        return seq_parallel.mesh_flash_attention(
+            q, k, v, causal=True, **({"window": window} if window else {}))
+    return _dense_attention(cfg, q, k, v, window)
 
 
 def _dense_attention(cfg: LlamaConfig, q, k, v, window: int = 0):

@@ -7,11 +7,16 @@ dim sharded over the ``ep`` mesh axis, so scan-over-layers + vmapped experts +
 all-to-all dispatch compose with ZeRO and TP.  Reference analog:
 ``deepspeed/moe/layer.py`` MoE inserted per-block + MoE-aware ZeRO.
 
-Two FFN paths: TRAINING keeps DeepSpeed's capacity gating (``moe/layer.py``:
-top-1/top-2, tokens over capacity dropped, load-balancing loss); every
-INFERENCE forward — uncached, cached, paged — takes the dropless
-``moe/routed.py`` (any ``top_k``, with or without renormalising the chosen
-weights), so the cached and uncached forwards agree by construction.
+Two FFN paths: every INFERENCE forward — uncached, cached, paged — takes the
+dropless ``moe/routed.py`` (any ``top_k``, with or without renormalising
+the chosen weights), so the cached and uncached forwards agree by
+construction; TRAINING takes the same dropless layer, differentiated, where
+the configuration says so (``capacity_factor=None``: any ``top_k``, a held
+share, the Switch-form balance loss over the router's full softmax, the
+head's loss in chunks, the routing's counters returned beside the loss), and
+DeepSpeed's capacity gating (``moe/layer.py``: top-1/top-2, tokens over
+capacity dropped, load-balancing loss) where it names a capacity (Mixtral
+8x7B's 1.25).
 OLMoE (``MixtralConfig.olmoe_1b_7b``) is the same block with q/k-norm
 (``LlamaConfig.qk_norm``), 64 experts, top-8 and no renormalisation.
 Keye-VL-2.0's language model (``MixtralConfig.keye_vl2_30b_a3b``) is the
@@ -37,6 +42,13 @@ one joint key / value latent a token, rotary on half of a head under YaRN,
 a position-dependent query temperature; the cache holds ``[c | k_r]`` a
 token a layer, ``ops/paged_kv.py`` "The latent kind", read absorbed) over
 128 softmax-scored experts top-4 renormalised beside one shared expert.
+SmallThinker (``MixtralConfig.smallthinker_21b_a3b``) is the sequential
+RMSNorm block over the pattern ``[full, sliding, sliding, sliding]`` (a
+4,096-key window and rotate-half rotary on the sliding layers, no rotation on
+the full one), 28 query heads on 4 KV heads, 64 softmax-scored ReGLU experts
+(``ffn_act="relu"``) top-6 renormalised, and a router that reads the
+ATTENTION's normed input (``router_input="attn"``), so that routing does not
+wait on attention; it trains dropless.
 """
 
 from __future__ import annotations
@@ -51,7 +63,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..moe.layer import MoEConfig, moe_apply
-from ..moe.routed import routed_ffn
+from ..moe.routed import ACTS, RECORD, RECORD_HELD, routed_ffn
+from ..ops.chunked_ce import chunked_ce, whole_chunks
 from ..ops import sparse_index_attention as sparse_attention
 from ..parallel.topology import EP_AXIS, TP_AXIS
 from ..runtime.model import ModelSpec
@@ -59,6 +72,9 @@ from . import llama as L
 from .cached import KIND_LEAVES, layer_accessors, live_tokens, qmm
 
 PyTree = Any
+#: dropless training takes the head's loss over ``[tokens / CE_CHUNKS,
+#: vocab]`` float32 logits at a time (``ops/chunked_ce.py``)
+CE_CHUNKS = 8
 
 
 @dataclasses.dataclass
@@ -70,9 +86,16 @@ class MixtralConfig(L.LlamaConfig):
     #: renormalise the chosen top-k router weights to sum to 1 (Mixtral);
     #: False keeps the softmax-over-all-experts weights (OLMoE)
     norm_topk_prob: bool = True
-    #: training capacity (tokens over it are dropped); inference is dropless
-    capacity_factor: float = 1.25
+    #: training capacity (tokens over it are dropped); ``None``: training is
+    #: dropless too (``moe/routed.py``).  Inference is always dropless
+    capacity_factor: Optional[float] = 1.25
     router_aux_loss_coef: float = 0.02
+    #: what the router reads: ``"ffn"``, the expert layer's own normed
+    #: input; ``"attn"``, the attention's normed input of the same block
+    #: (sequential blocks: routing no longer waits on attention)
+    router_input: str = "ffn"
+    #: the experts' gate activation: ``"silu"`` (SwiGLU) or ``"relu"`` (ReGLU)
+    ffn_act: str = "silu"
     #: learned sparse attention (``ops/sparse_index_attention.py``): heads of the
     #: indexer (0 = dense attention, no indexer), their width, and the keys
     #: a query attends
@@ -100,6 +123,12 @@ class MixtralConfig(L.LlamaConfig):
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError(f"router_score={self.router_score!r}: "
                              "'softmax' or 'sigmoid'")
+        if self.router_input not in ("ffn", "attn"):
+            raise ValueError(f"router_input={self.router_input!r}: 'ffn' "
+                             "or 'attn'")
+        if self.ffn_act not in ACTS:
+            raise ValueError(f"ffn_act={self.ffn_act!r}: one of "
+                             f"{sorted(ACTS)}")
         if self.experts_held is not None:
             first, count = self.experts_held = tuple(
                 int(v) for v in self.experts_held)
@@ -204,6 +233,34 @@ class MixtralConfig(L.LlamaConfig):
             norm_topk_prob=True, router_score="softmax", shared_experts=1)
 
     @staticmethod
+    def smallthinker_21b_a3b() -> "MixtralConfig":
+        """PowerInfer/SmallThinker-21BA3B-Instruct: 52 sequential RMSNorm
+        blocks, d 2560, 28 query / 4 KV heads x 128, ``[full, sliding,
+        sliding, sliding] x 13`` — the full layer unrotated, the sliding ones
+        under a 4,096-key window and rotate-half rotary (theta 1.5e6) — 64
+        softmax-scored ReGLU experts of width 768 top-6 renormalised, the
+        router fed the attention's normed input, no shared expert, an untied
+        head.  Trains dropless (balance coefficient 0.001: assumed, the
+        published config names none).  The published model: one chip's share
+        of it (``experts_held``, fewer layers, a vocabulary slice) is a
+        deployment's to state."""
+        return MixtralConfig(vocab_size=151936, max_seq_len=16384,
+                             num_layers=52, num_heads=28, num_kv_heads=4,
+                             head_width=128, hidden_size=2560, ffn_size=768,
+                             rope_theta=1.5e6, rms_eps=1e-6,
+                             layer_kinds=("full",) + ("sliding",) * 3,
+                             sliding_window=4096, num_experts=64, top_k=6,
+                             norm_topk_prob=True, router_score="softmax",
+                             router_input="attn", ffn_act="relu",
+                             capacity_factor=None,
+                             router_aux_loss_coef=0.001)
+
+    @property
+    def dropless(self) -> bool:
+        """Training routes without a capacity (``capacity_factor=None``)."""
+        return self.capacity_factor is None
+
+    @staticmethod
     def tiny(vocab_size: int = 512) -> "MixtralConfig":
         return MixtralConfig(vocab_size=vocab_size, max_seq_len=128,
                              num_layers=2, num_heads=4, num_kv_heads=2,
@@ -292,9 +349,11 @@ def _indexer(cfg: MixtralConfig, get, mm, y, rope):
 
 
 def _moe_block(cfg: MixtralConfig, layer: PyTree, x, cos, sin,
-               train: bool = True, kind=None):
-    """Llama attention + MoE FFN; returns (x, aux_loss).  ``kind``: the
-    layer's kind in a patterned model."""
+               train: bool = True, kind=None, choices: bool = False):
+    """Llama attention + MoE FFN; returns (x, aux_loss) — (x, aux_loss,
+    routing record) from a dropless training layer, (x, aux_loss, the
+    tokens' experts ``[B, S, top_k]``) with ``choices`` (inference).
+    ``kind``: the layer's kind in a patterned model."""
     attention = None
     if cfg.index_heads:
         icos, isin = L.rope_angles(cfg, x.shape[1], dim=cfg.index_head_dim)
@@ -305,32 +364,69 @@ def _moe_block(cfg: MixtralConfig, layer: PyTree, x, cos, sin,
             return sparse_attention.sparse_attention_uncached(
                 q, k, v, qi, wi, ki[:, 0], cfg.index_topk)
 
+    router_x = None
     if cfg.parallel_block:
         y, a = L.attn_apply(cfg, layer, x, cos, sin, attention, kind,
                             delta=True)
         x = x + a
+    elif cfg.router_input == "attn":
+        router_x, a = L.attn_apply(cfg, layer, x, cos, sin, attention, kind,
+                                   delta=True)
+        x = x + a
+        y = L.block_norm(cfg, x, layer["mlp_norm"])
     else:
         x = L.attn_apply(cfg, layer, x, cos, sin, attention, kind)
         y = L.block_norm(cfg, x, layer["mlp_norm"])
+    if train and cfg.dropless:
+        moe_out, (record, aux) = _routed(cfg, layer, y, router_x=router_x,
+                                         train=True)
+        return x + moe_out, aux, record
     if train:
         moe_out, aux = _moe_ffn(cfg, layer, y)
     else:
-        moe_out, aux = _routed(cfg, layer, y)[0], jnp.zeros((), jnp.float32)
+        moe_out, chosen = _routed(cfg, layer, y, router_x=router_x,
+                                  choices=choices)
+        aux = jnp.zeros((), jnp.float32)
+        if choices:
+            return x + moe_out, aux, chosen[1]
     return x + moe_out, aux
 
 
-def forward_with_aux(cfg: MixtralConfig, params: PyTree, input_ids,
-                     train: bool = True):
+def _trunk(cfg: MixtralConfig, params: PyTree, input_ids, train: bool,
+           choices: bool = False):
+    """Embedding, the blocks, the final norm -> ``(x [B, S, d], the layers'
+    mean balance loss, record)``; ``record`` is the layers' routing records
+    summed (int32, ``moe/routed.py RECORD`` / ``RECORD_HELD``) from dropless
+    training, with ``choices`` (inference) every layer's chosen experts
+    ``[L, B, S, top_k]``, else ``None``."""
     b, s = input_ids.shape
     x = params["embed"][input_ids].astype(params["embed"].dtype)
     cos, sin = L.rope_angles(cfg, s)
 
-    if train and (cfg.experts_held is not None or cfg.shared_experts
-                  or cfg.router_score != "softmax"):
+    counted = train and cfg.dropless
+    if counted:
+        from .. import comm
+
+        if cfg.shared_experts or cfg.router_score != "softmax":
+            raise NotImplementedError(
+                "dropless training's balance loss is the softmax router's, "
+                "over routed experts alone: shared experts and a sigmoid "
+                "router are inference paths")
+        if comm.get_topology().expert_parallel_size > 1:
+            raise NotImplementedError(
+                "dropless training under an ep mesh axis: the exchange of "
+                "the routed rows between the chips of an expert-parallel "
+                "group is not built (one chip trains its held share, "
+                "experts_held, without it)")
+    elif train and (cfg.experts_held is not None or cfg.shared_experts
+                    or cfg.router_score != "softmax"
+                    or cfg.router_input != "ffn" or cfg.ffn_act != "silu"):
         raise NotImplementedError(
             "training's capacity gate (moe/layer.py) is a softmax router "
-            "over experts that are all here; shared experts, a sigmoid "
-            "router and a held share (experts_held) are inference paths")
+            "over SwiGLU experts that are all here, fed the expert layer's "
+            "own input; shared experts, a sigmoid router, a held share "
+            "(experts_held), router_input and ffn_act are the dropless "
+            "layer's (capacity_factor=None)")
     kinds = cfg.layer_kinds or (None,)
     blocks = params["blocks"]
     if cfg.layer_kinds:
@@ -340,26 +436,65 @@ def forward_with_aux(cfg: MixtralConfig, params: PyTree, input_ids,
                                 + a.shape[1:]), blocks)
 
     def body(carry, layers):
-        x, aux_sum = carry
+        x, aux_sum, *rec_sum = carry
         fn = _moe_block
         if cfg.remat:
-            fn = jax.checkpoint(_moe_block, static_argnums=(0, 5, 6))
+            fn = jax.checkpoint(_moe_block, static_argnums=(0, 5, 6, 7))
+        chosen = []
         for j, kind in enumerate(kinds):
             layer = layers if kind is None else jax.tree_util.tree_map(
                 lambda a: a[j], layers)
-            x, aux = fn(cfg, layer, x, cos, sin, train, kind)
+            x, aux, *rec = fn(cfg, layer, x, cos, sin, train, kind, choices)
             aux_sum = aux_sum + aux
-        return (x, aux_sum), None
+            if choices:
+                chosen.append(rec[0])
+            else:
+                rec_sum = [a + r for a, r in zip(rec_sum, rec)]
+        return (x, aux_sum, *rec_sum), (jnp.stack(chosen) if choices
+                                        else None)
 
-    (x, aux_sum), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                   blocks)
+    init = (x, jnp.zeros((), jnp.float32))
+    if counted:
+        init += (jnp.zeros(len(record_columns(cfg)), jnp.int32),)
+    (x, aux_sum, *rec_sum), chosen = jax.lax.scan(body, init, blocks)
     x = L.block_norm(cfg, x, params["final_norm"])
-    logits = L.head_logits(cfg, params, x)
-    return logits, aux_sum / cfg.num_layers
+    if choices:
+        # [periods, layers a period, B, S, k] -> [L, B, S, k]
+        return x, aux_sum / cfg.num_layers, chosen.reshape(
+            (cfg.num_layers,) + chosen.shape[2:])
+    return x, aux_sum / cfg.num_layers, rec_sum[0] if counted else None
+
+
+def record_columns(cfg: MixtralConfig):
+    """The routing record's columns (``moe/routed.py``)."""
+    return RECORD if cfg.experts_held is None else RECORD_HELD
+
+
+def forward_with_aux(cfg: MixtralConfig, params: PyTree, input_ids,
+                     train: bool = True):
+    x, aux, _ = _trunk(cfg, params, input_ids, train)
+    return L.head_logits(cfg, params, x), aux
+
+
+def forward_hidden(cfg: MixtralConfig, params: PyTree, input_ids):
+    """The uncached inference forward up to the head: ``(the final norm's
+    output [B, S, d], every layer's chosen experts int32 [L, B, S, top_k])``
+    — what a comparison with a plain reference takes where a sequence's
+    logits would not fit whole (it multiplies the positions it compares
+    with the head itself) and hands that reference the program's own expert
+    sets."""
+    x, _, chosen = _trunk(cfg, params, input_ids, False, choices=True)
+    return x, chosen
 
 
 def loss_from_batch(cfg: MixtralConfig, params, batch, rng=None,
                     train: bool = True):
+    """The next-token loss plus ``router_aux_loss_coef`` x the balance loss.
+    A dropless configuration in training returns ``(loss, record)``:
+    ``record`` is float32 scalars — the routing record's columns summed over
+    the layers, ``lm_loss`` and ``router_aux`` (the engine carries it out of
+    the step, ``runtime/engine.py``) — and takes the head's loss in chunks
+    (``ops/chunked_ce.py``: a sequence's float32 logits are never whole)."""
     if isinstance(batch, (tuple, list)):
         input_ids, labels = batch
     else:
@@ -368,6 +503,18 @@ def loss_from_batch(cfg: MixtralConfig, params, batch, rng=None,
     if labels is None:
         labels = input_ids[:, 1:]
         input_ids = input_ids[:, :-1]
+    if train and cfg.dropless:
+        x, aux, rec = _trunk(cfg, params, input_ids, train)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        n = labels.size
+        with jax.named_scope("head"):
+            lm_loss = chunked_ce(head.astype(x.dtype),
+                                 x.reshape(n, x.shape[-1]),
+                                 labels.reshape(n),
+                                 whole_chunks(n, CE_CHUNKS))
+        record = dict(zip(record_columns(cfg), rec.astype(jnp.float32)))
+        record.update(lm_loss=lm_loss, router_aux=aux)
+        return lm_loss + cfg.router_aux_loss_coef * aux, record
     logits, aux = forward_with_aux(cfg, params, input_ids, train=train)
     logits = logits.astype(jnp.float32)
     valid = labels >= 0
@@ -410,9 +557,12 @@ def _expert_kernel(blocks) -> bool:
 
 
 def _routed(cfg: MixtralConfig, layer, y, live=None, stacks=None,
-            choices: bool = False):
-    """Inference FFN: dropless routing -> (y, routing record [3]); with
-    ``choices``, (y, (record, the tokens' experts ``[..., top_k]``)).
+            choices: bool = False, router_x=None, train: bool = False):
+    """The dropless FFN: -> (y, routing record [3]); with ``choices``, (y,
+    (record, the tokens' experts ``[..., top_k]``)); with ``train`` (the
+    differentiated layer: its balance term asked for, its pairs combined
+    choice-major), (y, (record, the layer's balance term)).  ``router_x``: the
+    router's own input (``router_input="attn"``).
     ``stacks``: the whole ``[L, E, ..]`` expert leaves, read in place at
     ``layer["layer_index"]`` by the grouped-matmul kernel; without them
     ``layer`` holds its own ``[E, ..]`` slices."""
@@ -422,10 +572,11 @@ def _routed(cfg: MixtralConfig, layer, y, live=None, stacks=None,
         y, layer["gate_w"], w1, w3, w2, cfg.top_k, cfg.norm_topk_prob,
         live=live, layer=layer["layer_index"] if whole else None,
         kernel=whole or _expert_kernel(layer), choices=choices,
-        held=cfg.experts_held, score=cfg.router_score)
+        held=cfg.experts_held, score=cfg.router_score, act=cfg.ffn_act,
+        router_x=router_x, balance=train, choice_major=train)
     if cfg.shared_experts:
         out = out + _shared(cfg, layer, y)
-    return out, (tuple(record) if choices else record[0])
+    return out, (tuple(record) if choices or train else record[0])
 
 
 def _shared(cfg: MixtralConfig, layer, y):

@@ -1,5 +1,6 @@
-"""Dropless routed FFN for inference: every routed (token, expert) pair is
-computed, for any ``k`` in ``[1, E]``.
+"""Dropless routed FFN: every routed (token, expert) pair is computed, for
+any ``k`` in ``[1, E]`` — every inference forward, and training where the
+configuration says dropless (``models/mixtral.py``: ``capacity_factor=None``).
 
 ``sharded_moe.py`` buckets tokens into per-expert capacity slots with dense
 one-hot einsums over ``[G, S, E, C]`` and drops what overflows — the right
@@ -21,8 +22,20 @@ chosen by the caller from what it can observe (``models/mixtral.py``):
   serves ``tp``/``ep`` meshes, and it takes one layer's weights, so it
   serves INT8 records that are expanded a layer at a time.
 
-    p = softmax_fp32(y Wr);  S = top-k of p;  (renormalize: p_S / sum p_S)
-    out = sum_{e in S} p_e * (silu(y W1_e) * (y W3_e)) W2_e
+    p = softmax_fp32(r Wr);  S = top-k of p;  (renormalize: p_S / sum p_S)
+    out = sum_{e in S} p_e * (act(y W1_e) * (y W3_e)) W2_e
+
+(``r`` is ``y`` unless the caller hands the router tokens of its own,
+``router_x``; ``act`` is ``silu`` or ``relu``.)
+
+**Differentiable.**  The gather into expert order, the scatter back and the
+float32 combine are plain XLA; ``moe_gmm`` carries a ``custom_vjp`` (its two
+transposes are kernels of their own) and ``ragged_dot`` XLA's.  The top-k
+choice is piecewise constant: the router learns through the chosen weights
+and, with ``balance=True``, through the Switch-form balance term ``E sum_e
+f_e P_e`` (``f_e``: the share of the ``T k`` pairs routed to ``e``, a
+constant; ``P_e``: the mean over tokens of the float32 scores over ALL ``E``
+outputs, whichever experts are held).
 
 **An expert layer that holds a share of its experts** (``held = (first,
 count)``: this chip's experts ``first .. first + count - 1`` of the ``E``
@@ -65,22 +78,30 @@ def _dense(w, dtype):
     return w.astype(dtype)
 
 
-def route(y2d, gate_w, k: int, renormalize: bool, score: str = "softmax"):
+#: the experts' gate activation, by the configuration's name for it
+ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def route(y2d, gate_w, k: int, renormalize: bool, score: str = "softmax",
+          scores: bool = False):
     """Router of ``[T, D]`` tokens: float32 logits and scores over ALL
     experts — their softmax, or with ``score="sigmoid"`` each logit's own
     sigmoid — then top-k (ties to the lower expert id).
-    -> (weights float32 [T, k], experts int32 [T, k])."""
+    -> (weights float32 [T, k], experts int32 [T, k]); with ``scores`` a
+    third, the float32 scores ``[T, E]`` (what a balance loss averages)."""
     logits = jnp.dot(y2d, _dense(gate_w, y2d.dtype),
                      preferred_element_type=jnp.float32)
     if score == "sigmoid":
-        scores = jax.nn.sigmoid(logits)
+        all_p = jax.nn.sigmoid(logits)
     elif score == "softmax":
-        scores = jax.nn.softmax(logits, axis=-1)
+        all_p = jax.nn.softmax(logits, axis=-1)
     else:
         raise ValueError(f"router score {score!r}: 'softmax' or 'sigmoid'")
-    top_p, top_e = jax.lax.top_k(scores, k)
+    top_p, top_e = jax.lax.top_k(all_p, k)
     if renormalize:
         top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if scores:
+        return top_p, top_e.astype(jnp.int32), all_p
     return top_p, top_e.astype(jnp.int32)
 
 
@@ -96,8 +117,10 @@ def _grouped(xs, w, group_sizes, layer, kernel: bool):
 def routed_ffn(y, gate_w, w1, w3, w2, k: int, renormalize: bool,
                live=None, layer=None, kernel: bool = True,
                choices: bool = False, held=None,
-               score: str = "softmax") -> Tuple[jnp.ndarray, ...]:
-    """SwiGLU experts over ``y [..., D]``: ``gate_w [D, E]``, ``w1``/``w3``
+               score: str = "softmax", act: str = "silu", router_x=None,
+               balance: bool = False,
+               choice_major: bool = False) -> Tuple[jnp.ndarray, ...]:
+    """Gated (``act``: SwiGLU, ReGLU) experts over ``y [..., D]``: ``gate_w [D, E]``, ``w1``/``w3``
     ``[E, D, F]``, ``w2 [E, F, D]`` — or, with ``layer`` (traced index),
     the whole stacks ``[L, E, ..]``.  No capacity, no drop.  ``kernel``
     picks the grouped matmul (module docstring).
@@ -112,16 +135,30 @@ def routed_ffn(y, gate_w, w1, w3, w2, k: int, renormalize: bool,
     all ``E``).  ``held = (first, count)``: the weights are this chip's
     ``count`` experts and ``out`` their partial sum (module docstring); the
     record is ``RECORD_HELD``, its first three columns over the held
-    experts.  ``score``: the router's (:func:`route`)."""
+    experts.  ``score``: the router's (:func:`route`).  ``router_x``: the
+    tokens the router reads, ``y``'s shape (``None``: ``y`` itself).
+    ``balance`` adds a last result, this layer's balance term (module
+    docstring; float32 scalar, 1 under even routing).  ``choice_major``:
+    the layout the combine gathers the pairs in — ``[k, T, D]``, what a
+    differentiated layer wants on a TPU, for the serving programs'
+    ``[T, k, D]``; the same float32 sum either way."""
     shape, d = y.shape, y.shape[-1]
     x = y.reshape(-1, d)
     t, e = x.shape[0], gate_w.shape[-1]
     if not 1 <= k <= e:
         raise ValueError(f"top_k={k} outside [1, num_experts={e}]")
+    if act not in ACTS:
+        raise ValueError(f"expert activation {act!r}: one of {sorted(ACTS)}")
 
     with jax.named_scope("layer/moe/route"):
-        top_p, top_e = route(x, gate_w, k, renormalize, score)
+        rx = x if router_x is None else router_x.reshape(-1, d)
+        top_p, top_e, *all_p = route(rx, gate_w, k, renormalize, score,
+                                     scores=balance)
         flat_e = top_e.reshape(-1)                               # [T*k]
+        if balance:
+            share = jnp.zeros(e, jnp.float32).at[flat_e].add(1.0 / (t * k))
+            aux = e * jnp.sum(jax.lax.stop_gradient(share)
+                              * jnp.mean(all_p[0], axis=0))
         if held is not None:
             # this chip's experts as groups 0 .. count-1; a pair of any
             # other expert takes the key ``count`` and sorts behind them
@@ -137,7 +174,7 @@ def routed_ffn(y, gate_w, w1, w3, w2, k: int, renormalize: bool,
     with jax.named_scope("layer/moe/experts"):
         gate = _grouped(xs, w1, group_sizes, layer, kernel)
         up = _grouped(xs, w3, group_sizes, layer, kernel)
-        out = _grouped(jax.nn.silu(gate) * up, w2, group_sizes, layer,
+        out = _grouped(ACTS[act](gate) * up, w2, group_sizes, layer,
                        kernel)
         if held is not None:
             # the rows behind the held groups were multiplied with nothing
@@ -148,8 +185,20 @@ def routed_ffn(y, gate_w, w1, w3, w2, k: int, renormalize: bool,
     with jax.named_scope("layer/moe/combine"):
         inverse = jnp.zeros_like(order).at[order].set(
             jnp.arange(t * k, dtype=order.dtype))
-        pairs = out[inverse].reshape(t, k, d).astype(jnp.float32)
-        mixed = jnp.einsum("tk,tkd->td", top_p, pairs).astype(y.dtype)
+        if choice_major:
+            # the same float32 sum, written so that XLA folds the
+            # widening into the reduction — a float32 [T, k, D] (0.5 GB a
+            # layer at 8,192 tokens, top-6, d 2,560), and its cotangent,
+            # are never whole — over pairs gathered CHOICE-major, [k, T, D]:
+            # a [T, 6, D] view of [T * 6, D] is a relayout on a TPU (six
+            # rows in an eight-row tile), forward and backward
+            pairs = out[inverse.reshape(t, k).T]
+            mixed = jnp.sum(top_p.T[:, :, None] * pairs.astype(jnp.float32),
+                            axis=0).astype(y.dtype)
+        else:
+            pairs = out[inverse].reshape(t, k, d)
+            mixed = jnp.einsum("tk,tkd->td", top_p,
+                               pairs.astype(jnp.float32)).astype(y.dtype)
 
         if live is None:
             counts = group_sizes
@@ -163,6 +212,7 @@ def routed_ffn(y, gate_w, w1, w3, w2, k: int, renormalize: bool,
                 else k * live.sum(dtype=jnp.int32)
             record.append(pairs_live - record[1])
         record = jnp.stack(record)
+    result = (mixed.reshape(shape), record)
     if choices:
-        return mixed.reshape(shape), record, top_e.reshape(shape[:-1] + (k,))
-    return mixed.reshape(shape), record
+        result += (top_e.reshape(shape[:-1] + (k,)),)
+    return result + (aux,) if balance else result

@@ -36,11 +36,50 @@ LANES = 128
 
 
 # ---------------------------------------------------------------------------
+# a sliding window (``window`` > 0, causal): the query at ``p`` sees the keys
+# ``max(0, p - window + 1) .. p``.  Every ``window`` branch below is taken at
+# trace time, so ``window = 0`` is the program it always was.
+# ---------------------------------------------------------------------------
+def _in_band(run, qi, ki, block_q: int, block_k: int, window: int):
+    """``run`` and: block ``(qi, ki)`` holds a key some query of it still
+    sees — its last column lies inside the first row's window."""
+    if not window:
+        return run
+    return jnp.logical_and(
+        run, ki * block_k + block_k - 1 > qi * block_q - window)
+
+
+def _band_mask(mask, row, col, window: int):
+    if not window:
+        return mask
+    return jnp.logical_and(mask, row - col < window)
+
+
+def _band_k(i, j, block_q: int, block_k: int, window: int):
+    """Key block ``j`` of query block ``i``, held to the blocks the band
+    touches: a step outside it names a block it already has, and fetches
+    nothing."""
+    if not window:
+        return j
+    lo = jnp.maximum(i * block_q - window + 1, 0) // block_k
+    return jnp.clip(j, lo, (i * block_q + block_q - 1) // block_k)
+
+
+def _band_q(j, i, block_q: int, block_k: int, window: int, nq: int):
+    """Query block ``i`` of key block ``j``, likewise: the band of a key
+    block ends ``window`` queries after its last key."""
+    if not window:
+        return i
+    hi = jnp.minimum((j * block_k + block_k + window - 2) // block_q, nq - 1)
+    return jnp.clip(i, (j * block_k) // block_q, hi)
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 def _fwd_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
                 block_k: int, kv_len: int, num_k_blocks: int,
-                has_layout: bool = False):
+                has_layout: bool = False, window: int = 0):
     if has_layout:
         (q_ref, k_ref, v_ref, layout_ref, o_ref, lse_ref,
          m_scr, l_scr, acc_scr) = refs
@@ -58,6 +97,7 @@ def _fwd_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
     # causal: block (qi, ki) contributes iff some col <= some row;
     # a sparsity layout gates blocks on top (ops/sparse_attention)
     run = (ki * block_k <= qi * block_q + block_q - 1) if causal else True
+    run = _in_band(run, qi, ki, block_q, block_k, window)
     if has_layout:
         # per-head layout slice in SMEM (a (1,1,1) VMEM block would violate
         # Mosaic's (8,128) tiling floor — surfaced on hardware only; a
@@ -82,7 +122,8 @@ def _fwd_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
         if causal:
             row = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            mask = jnp.logical_and(mask, row >= col)
+            mask = _band_mask(jnp.logical_and(mask, row >= col), row, col,
+                              window)
         s = jnp.where(mask, s, DEFAULT_MASK_VALUE)
 
         m_prev = m_scr[...][:, :1]                      # [bq, 1]
@@ -108,7 +149,8 @@ def _fwd_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
 
 
 def _fwd(q, k, v, sm_scale: float, causal: bool, block_q: int, block_k: int,
-         interpret: bool, true_kv_len: int, head_rep: int = 1, layout=None):
+         interpret: bool, true_kv_len: int, head_rep: int = 1, layout=None,
+         window: int = 0):
     """``head_rep``: GQA ratio — q has ``bh`` leading entries, k/v have
     ``bh // head_rep``; the KV index map divides so repeated heads read the
     same KV block in place (no ``jnp.repeat`` materialization).
@@ -121,7 +163,8 @@ def _fwd(q, k, v, sm_scale: float, causal: bool, block_q: int, block_k: int,
 
     kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
                                block_q=block_q, block_k=block_k, kv_len=kv_len,
-                               num_k_blocks=nk, has_layout=layout is not None)
+                               num_k_blocks=nk, has_layout=layout is not None,
+                               window=window)
     out_shape = [
         jax.ShapeDtypeStruct((bh, q_len, d), q.dtype),          # o
         jax.ShapeDtypeStruct((bh, q_len, LANES), jnp.float32),  # lse (lane-bcast)
@@ -164,7 +207,7 @@ def _fwd(q, k, v, sm_scale: float, causal: bool, block_q: int, block_k: int,
 # ---------------------------------------------------------------------------
 def _bwd_dq_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
                    block_k: int, kv_len: int, num_k_blocks: int,
-                   has_layout: bool = False):
+                   has_layout: bool = False, window: int = 0):
     if has_layout:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, layout_ref,
          dq_ref, dq_scr) = refs
@@ -179,6 +222,7 @@ def _bwd_dq_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
     run = (ki * block_k <= qi * block_q + block_q - 1) if causal else True
+    run = _in_band(run, qi, ki, block_q, block_k, window)
     if has_layout:
         # per-head layout slice in SMEM (a (1,1,1) VMEM block would violate
         # Mosaic's (8,128) tiling floor — surfaced on hardware only; a
@@ -204,7 +248,8 @@ def _bwd_dq_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
         if causal:
             row = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            mask = jnp.logical_and(mask, row >= col)
+            mask = _band_mask(jnp.logical_and(mask, row >= col), row, col,
+                              window)
         p = jnp.where(mask, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -220,7 +265,7 @@ def _bwd_dq_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
 
 def _bwd_dkv_kernel(*refs, sm_scale: float, causal: bool,
                     block_q: int, block_k: int, kv_len: int, num_q_blocks: int,
-                    rep: int = 1, has_layout: bool = False):
+                    rep: int = 1, has_layout: bool = False, window: int = 0):
     """Inner grid dim 2 runs over (head_rep, q_blocks) flattened: for GQA the
     dk/dv of one KV head accumulates contributions from all ``rep`` query
     heads without materializing repeated K/V."""
@@ -240,6 +285,7 @@ def _bwd_dkv_kernel(*refs, sm_scale: float, causal: bool,
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
     run = (ki * block_k <= qi * block_q + block_q - 1) if causal else True
+    run = _in_band(run, qi, ki, block_q, block_k, window)
     if has_layout:
         # per-head layout slice in SMEM (a (1,1,1) VMEM block would violate
         # Mosaic's (8,128) tiling floor — surfaced on hardware only; a
@@ -265,7 +311,8 @@ def _bwd_dkv_kernel(*refs, sm_scale: float, causal: bool,
         if causal:
             row = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            mask = jnp.logical_and(mask, row >= col)
+            mask = _band_mask(jnp.logical_and(mask, row >= col), row, col,
+                              window)
         p = jnp.where(mask, jnp.exp(s - lse), 0.0)                 # [bq, bk]
         dv_scr[...] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -284,7 +331,8 @@ def _bwd_dkv_kernel(*refs, sm_scale: float, causal: bool,
 
 
 def _bwd_dq_call(q, k, v, do, lse_b, delta_b, *, sm_scale, causal, block_q,
-                 block_k, kv_len, interpret, head_rep: int = 1, layout=None):
+                 block_k, kv_len, interpret, head_rep: int = 1, layout=None,
+                 window: int = 0):
     """dq for one (q-chunk, kv-chunk) pair given *global* lse/delta.
 
     Exposed separately so ring attention (parallel/sequence.py) can reuse the
@@ -298,7 +346,8 @@ def _bwd_dq_call(q, k, v, do, lse_b, delta_b, *, sm_scale, causal, block_q,
                                   causal=causal, block_q=block_q,
                                   block_k=block_k, kv_len=kv_len,
                                   num_k_blocks=nk,
-                                  has_layout=layout is not None)
+                                  has_layout=layout is not None,
+                                  window=window)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // rep, j, 0)),
@@ -329,7 +378,8 @@ def _bwd_dq_call(q, k, v, do, lse_b, delta_b, *, sm_scale, causal, block_q,
 
 
 def _bwd_dkv_call(q, k, v, do, lse_b, delta_b, *, sm_scale, causal, block_q,
-                  block_k, kv_len, interpret, head_rep: int = 1, layout=None):
+                  block_k, kv_len, interpret, head_rep: int = 1, layout=None,
+                  window: int = 0):
     """dk, dv for one (q-chunk, kv-chunk) pair given *global* lse/delta.
 
     For GQA (``head_rep > 1``) q/do/lse/delta have ``rep`` times more heads
@@ -344,7 +394,8 @@ def _bwd_dkv_call(q, k, v, do, lse_b, delta_b, *, sm_scale, causal, block_q,
                                    causal=causal, block_q=block_q,
                                    block_k=block_k, kv_len=kv_len,
                                    num_q_blocks=nq, rep=rep,
-                                   has_layout=layout is not None)
+                                   has_layout=layout is not None,
+                                   window=window)
     q_map = lambda b, j, i: (b * rep + i // nq, i % nq, 0)
     in_specs = [
         pl.BlockSpec((1, block_q, d), q_map),
@@ -388,7 +439,7 @@ def _bwd_dkv_call(q, k, v, do, lse_b, delta_b, *, sm_scale, causal, block_q,
 
 
 def _bwd(sm_scale, causal, block_q, block_k, interpret, true_kv_len, head_rep,
-         residuals, g):
+         residuals, g, window: int = 0):
     q, k, v, o, lse = residuals
     do = g
     kv_len = true_kv_len
@@ -399,7 +450,7 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, true_kv_len, head_rep,
 
     kw = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
               block_k=block_k, kv_len=kv_len, interpret=interpret,
-              head_rep=head_rep)
+              head_rep=head_rep, window=window)
     dq = _bwd_dq_call(q, k, v, do, lse_b, delta_b, **kw)
     dk, dv = _bwd_dkv_call(q, k, v, do, lse_b, delta_b, **kw)
     return dq, dk, dv
@@ -493,10 +544,11 @@ KERNELS = {"v1": ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"),
            "v3": ("flash_fwd_chunked", "flash_bwd_dq_chunked",
                   "flash_bwd_dkv_chunked")}
 #: one resolution: the operands' lengths and head width, the generation they
-#: dispatch to, the blocks, and whether the rule chose them or the caller
-#: gave them
+#: dispatch to, the blocks, whether the rule chose them or the caller gave
+#: them, and the sliding window the call was made with (0: none)
 Choice = collections.namedtuple(
-    "Choice", "q_len kv_len d generation block_q block_k how")
+    "Choice", "q_len kv_len d generation block_q block_k how window",
+    defaults=(0,))
 _CHOICES: Dict[Choice, int] = {}
 _CHOICES_LOCK = threading.Lock()
 
@@ -604,7 +656,7 @@ def _v2_compiler_params(dimension_semantics):
 
 
 def _fwd_v2_kernel(q_ref, k_ref, v_ref, o_ref, *, scale2: float, causal: bool,
-                   block_q: int, kv_pad: int, kv_len: int):
+                   block_q: int, kv_pad: int, kv_len: int, window: int = 0):
     qi = pl.program_id(1)
     # fold softmax scale AND log2(e) into q (one [bq, d] pass instead of a
     # [bq, S] one); exp2 is the native transcendental
@@ -618,7 +670,7 @@ def _fwd_v2_kernel(q_ref, k_ref, v_ref, o_ref, *, scale2: float, causal: bool,
     if causal:
         row = qi * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, kv_pad), 0)
-        mask = col <= row
+        mask = _band_mask(col <= row, row, col, window)
         if kv_len != kv_pad:
             mask = jnp.logical_and(mask, col < kv_len)
         s2 = jnp.where(mask, s2, DEFAULT_MASK_VALUE)
@@ -633,13 +685,14 @@ def _fwd_v2_kernel(q_ref, k_ref, v_ref, o_ref, *, scale2: float, causal: bool,
 
 
 def _fwd_v2(q, k, v, sm_scale, causal, block_q, interpret, true_kv_len,
-            head_rep):
+            head_rep, window: int = 0):
     bh, q_len, d = q.shape
     kv_pad = k.shape[1]
     nq = pl.cdiv(q_len, block_q)
     kernel = functools.partial(
         _fwd_v2_kernel, scale2=sm_scale * _LOG2E, causal=causal,
-        block_q=block_q, kv_pad=kv_pad, kv_len=true_kv_len)
+        block_q=block_q, kv_pad=kv_pad, kv_len=true_kv_len,
+        window=window)
     rep = head_rep
     return pl.pallas_call(
         kernel,
@@ -660,7 +713,7 @@ def _fwd_v2(q, k, v, sm_scale, causal, block_q, interpret, true_kv_len,
 def _bwd_v2_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, dq_ref, dk_ref, dv_ref,
                    dk_scr, dv_scr, *, scale2: float, sm_scale: float,
                    causal: bool, block_q: int, kv_pad: int, kv_len: int,
-                   num_q_blocks: int, rep: int):
+                   num_q_blocks: int, rep: int, window: int = 0):
     inner = pl.program_id(1)
     qi = inner % num_q_blocks
 
@@ -682,7 +735,7 @@ def _bwd_v2_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, dq_ref, dk_ref, dv_ref,
     if causal:
         row = qi * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, kv_pad), 0)
-        mask = col <= row
+        mask = _band_mask(col <= row, row, col, window)
         if kv_len != kv_pad:
             mask = jnp.logical_and(mask, col < kv_len)
         s2 = jnp.where(mask, s2, DEFAULT_MASK_VALUE)
@@ -722,7 +775,7 @@ def _bwd_v2_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, dq_ref, dk_ref, dv_ref,
 
 
 def _bwd_v2(q, k, v, o, do, sm_scale, causal, block_q, interpret, true_kv_len,
-            head_rep):
+            head_rep, window: int = 0):
     bh, q_len, d = q.shape
     bh_kv, kv_pad, _ = k.shape
     nq = pl.cdiv(q_len, block_q)
@@ -730,7 +783,7 @@ def _bwd_v2(q, k, v, o, do, sm_scale, causal, block_q, interpret, true_kv_len,
     kernel = functools.partial(
         _bwd_v2_kernel, scale2=sm_scale * _LOG2E, sm_scale=sm_scale,
         causal=causal, block_q=block_q, kv_pad=kv_pad, kv_len=true_kv_len,
-        num_q_blocks=nq, rep=rep)
+        num_q_blocks=nq, rep=rep, window=window)
     q_map = lambda b, i: (b * rep + i // nq, i % nq, 0)
     kv_map = lambda b, i: (b, 0, 0)
     dq, dk, dv = pl.pallas_call(
@@ -792,7 +845,8 @@ def _bwd_v2(q, k, v, o, do, sm_scale, causal, block_q, interpret, true_kv_len,
 
 
 def _fwd_v3_kernel(*refs, scale2: float, causal: bool, block_q: int,
-                   block_k: int, kv_pad: int, kv_len: int, num_k_blocks: int):
+                   block_k: int, kv_pad: int, kv_len: int, num_k_blocks: int,
+                   window: int = 0):
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -804,6 +858,7 @@ def _fwd_v3_kernel(*refs, scale2: float, causal: bool, block_q: int,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     run = (ki * block_k <= qi * block_q + block_q - 1) if causal else True
+    run = _in_band(run, qi, ki, block_q, block_k, window)
 
     @pl.when(run)
     def _compute():
@@ -819,7 +874,8 @@ def _fwd_v3_kernel(*refs, scale2: float, causal: bool, block_q: int,
         if causal:
             row = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            mask = jnp.logical_and(mask, row >= col)
+            mask = _band_mask(jnp.logical_and(mask, row >= col), row, col,
+                              window)
         s2 = jnp.where(mask, s2, DEFAULT_MASK_VALUE)
         m_prev = m_scr[...][:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s2, axis=1, keepdims=True))
@@ -843,7 +899,7 @@ def _fwd_v3_kernel(*refs, scale2: float, causal: bool, block_q: int,
 
 
 def _fwd_v3(q, k, v, sm_scale, causal, block_q, block_k, interpret,
-            true_kv_len, head_rep):
+            true_kv_len, head_rep, window: int = 0):
     bh, q_len, d = q.shape
     kv_pad = k.shape[1]
     nq = pl.cdiv(q_len, block_q)
@@ -852,14 +908,16 @@ def _fwd_v3(q, k, v, sm_scale, causal, block_q, block_k, interpret,
     kernel = functools.partial(
         _fwd_v3_kernel, scale2=sm_scale * _LOG2E, causal=causal,
         block_q=block_q, block_k=block_k, kv_pad=kv_pad, kv_len=true_kv_len,
-        num_k_blocks=nk)
+        num_k_blocks=nk, window=window)
+    kv_map = lambda b, i, j: (
+        b // rep, _band_k(i, j, block_q, block_k, window), 0)
     o, lse = pl.pallas_call(
         kernel,
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // rep, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // rep, j, 0)),
+            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, d), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -884,7 +942,7 @@ def _fwd_v3(q, k, v, sm_scale, causal, block_q, block_k, interpret,
 
 def _bwd_v3_dq_kernel(*refs, scale2: float, sm_scale: float, causal: bool,
                       block_q: int, block_k: int, kv_len: int,
-                      num_k_blocks: int):
+                      num_k_blocks: int, window: int = 0):
     (q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, dq_scr) = refs
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -894,6 +952,7 @@ def _bwd_v3_dq_kernel(*refs, scale2: float, sm_scale: float, causal: bool,
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
     run = (ki * block_k <= qi * block_q + block_q - 1) if causal else True
+    run = _in_band(run, qi, ki, block_q, block_k, window)
 
     @pl.when(run)
     def _compute():
@@ -912,7 +971,8 @@ def _bwd_v3_dq_kernel(*refs, scale2: float, sm_scale: float, causal: bool,
         if causal:
             row = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            mask = jnp.logical_and(mask, row >= col)
+            mask = _band_mask(jnp.logical_and(mask, row >= col), row, col,
+                              window)
         s2 = jnp.where(mask, s2, DEFAULT_MASK_VALUE)
         p = jnp.exp2(s2 - lse2)                      # true softmax probs
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
@@ -929,7 +989,7 @@ def _bwd_v3_dq_kernel(*refs, scale2: float, sm_scale: float, causal: bool,
 
 def _bwd_v3_dkv_kernel(*refs, scale2: float, causal: bool, block_q: int,
                        block_k: int, kv_len: int, num_q_blocks: int,
-                       rep: int):
+                       rep: int, window: int = 0):
     (q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref, dv_ref,
      dk_scr, dv_scr) = refs
     ki = pl.program_id(1)
@@ -942,6 +1002,7 @@ def _bwd_v3_dkv_kernel(*refs, scale2: float, causal: bool, block_q: int,
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
     run = (ki * block_k <= qi * block_q + block_q - 1) if causal else True
+    run = _in_band(run, qi, ki, block_q, block_k, window)
 
     @pl.when(run)
     def _compute():
@@ -960,7 +1021,8 @@ def _bwd_v3_dkv_kernel(*refs, scale2: float, causal: bool, block_q: int,
         if causal:
             row = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            mask = jnp.logical_and(mask, row >= col)
+            mask = _band_mask(jnp.logical_and(mask, row >= col), row, col,
+                              window)
         s2 = jnp.where(mask, s2, DEFAULT_MASK_VALUE)
         p = jnp.exp2(s2 - lse2)
         dv_scr[...] += jax.lax.dot_general(
@@ -981,7 +1043,7 @@ def _bwd_v3_dkv_kernel(*refs, scale2: float, causal: bool, block_q: int,
 
 
 def _bwd_v3(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
-            interpret, true_kv_len, head_rep):
+            interpret, true_kv_len, head_rep, window: int = 0):
     bh, q_len, d = q.shape
     bh_kv, kv_pad, _ = k.shape
     nq = pl.cdiv(q_len, block_q)
@@ -995,9 +1057,10 @@ def _bwd_v3(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
     dq_kernel = functools.partial(
         _bwd_v3_dq_kernel, scale2=scale2, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, kv_len=true_kv_len,
-        num_k_blocks=nk)
+        num_k_blocks=nk, window=window)
     qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // rep, j, 0))
+    kspec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (
+        b // rep, _band_k(i, j, block_q, block_k, window), 0))
     lspec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
     dq = pl.pallas_call(
         dq_kernel,
@@ -1014,9 +1077,11 @@ def _bwd_v3(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
 
     dkv_kernel = functools.partial(
         _bwd_v3_dkv_kernel, scale2=scale2, causal=causal, block_q=block_q,
-        block_k=block_k, kv_len=true_kv_len, num_q_blocks=nq, rep=rep)
-    q_map = lambda b, j, i: (b * rep + i // nq, i % nq, 0)
-    l_map = lambda b, j, i: (b * rep + i // nq, 0, i % nq)
+        block_k=block_k, kv_len=true_kv_len, num_q_blocks=nq, rep=rep,
+        window=window)
+    band_q = lambda j, i: _band_q(j, i % nq, block_q, block_k, window, nq)
+    q_map = lambda b, j, i: (b * rep + i // nq, band_q(j, i), 0)
+    l_map = lambda b, j, i: (b * rep + i // nq, 0, band_q(j, i))
     qspec2 = pl.BlockSpec((1, block_q, d), q_map)
     kspec2 = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
     lspec2 = pl.BlockSpec((1, 1, block_q), l_map)
@@ -1040,39 +1105,40 @@ def _bwd_v3(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
 # ---------------------------------------------------------------------------
 # public op
 # ---------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash_attention_bh(q, k, v, sm_scale, causal, block_q, block_k, interpret,
-                        true_kv_len, head_rep):
+                        true_kv_len, head_rep, window=0):
     if _v2_eligible(k.shape[1], q.shape[2]):
         return _fwd_v2(q, k, v, sm_scale, causal, block_q, interpret,
-                       true_kv_len, head_rep)
+                       true_kv_len, head_rep, window)
     if _v3_eligible(k.shape[1], q.shape[2]):
         o, _ = _fwd_v3(q, k, v, sm_scale, causal, block_q, block_k, interpret,
-                       true_kv_len, head_rep)
+                       true_kv_len, head_rep, window)
         return o
     o, _ = _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
-                true_kv_len, head_rep)
+                true_kv_len, head_rep, window=window)
     return o
 
 
 def _flash_fwd_rule(q, k, v, sm_scale, causal, block_q, block_k, interpret,
-                    true_kv_len, head_rep):
+                    true_kv_len, head_rep, window=0):
     from jax.ad_checkpoint import checkpoint_name
 
     if _v2_eligible(k.shape[1], q.shape[2]):
         o = _fwd_v2(q, k, v, sm_scale, causal, block_q, interpret,
-                    true_kv_len, head_rep)
+                    true_kv_len, head_rep, window)
         # no lse residual: the fused backward recomputes row stats in-kernel
         o = checkpoint_name(o, "flash_out")
         return o, (q, k, v, o)
     if _v3_eligible(k.shape[1], q.shape[2]):
         o, lse = _fwd_v3(q, k, v, sm_scale, causal, block_q, block_k,
-                         interpret, true_kv_len, head_rep)
+                         interpret, true_kv_len, head_rep, window)
         o = checkpoint_name(o, "flash_out")
         lse = checkpoint_name(lse, "flash_lse")
         return o, (q, k, v, o, lse)
     o, lse = _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
-                  true_kv_len, head_rep)
+                  true_kv_len, head_rep, window=window)
     # named so remat policies can pin the kernel's residuals: saving o+lse
     # means the backward under jax.checkpoint reuses them instead of
     # re-running the forward kernel (see gpt2._remat_policy)
@@ -1082,17 +1148,17 @@ def _flash_fwd_rule(q, k, v, sm_scale, causal, block_q, block_k, interpret,
 
 
 def _flash_bwd_rule(sm_scale, causal, block_q, block_k, interpret, true_kv_len,
-                    head_rep, res, g):
+                    head_rep, window, res, g):
     if len(res) == 4:  # v2 path (see _flash_fwd_rule)
         q, k, v, o = res
         return _bwd_v2(q, k, v, o, g, sm_scale, causal, block_q, interpret,
-                       true_kv_len, head_rep)
+                       true_kv_len, head_rep, window)
     if res[4].ndim == 3:  # v3 path: compact [bh, 1, S] exp2-domain lse
         q, k, v, o, lse = res
         return _bwd_v3(q, k, v, o, lse, g, sm_scale, causal, block_q,
-                       block_k, interpret, true_kv_len, head_rep)
+                       block_k, interpret, true_kv_len, head_rep, window)
     return _bwd(sm_scale, causal, block_q, block_k, interpret, true_kv_len,
-                head_rep, res, g)
+                head_rep, res, g, window)
 
 
 _flash_attention_bh.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -1135,8 +1201,12 @@ def flash_attention(q, k, v, causal: bool = True,
                     sm_scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None):
+                    interpret: Optional[bool] = None, window: int = 0):
     """Fused attention. q: [B, H, Sq, D]; k, v: [B, Hkv, Sk, D] (GQA: Hkv | H).
+    ``window`` > 0 (static; causal self-attention): a query sees its
+    ``window`` newest keys, itself included — forward, ``dq`` and ``dkv``
+    skip the blocks wholly outside that band and mask inside the blocks on
+    its edges; ``window = 0`` is the program without one.
 
     Returns [B, H, Sq, D] in q's dtype.  Sequence lengths are padded internally
     to the block size; padded keys are masked, padded query rows sliced off.
@@ -1154,8 +1224,14 @@ def flash_attention(q, k, v, causal: bool = True,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
 
+    window = int(window)
+    if window < 0 or (window and not (causal and q_len == kv_len)):
+        raise ValueError(f"window={window}: a sliding window is causal "
+                         f"self-attention's (q {q_len}, kv {kv_len})")
     choice, pad_q, pad_k = _resolve_blocks(q_len, kv_len, d, q.dtype.itemsize,
                                            block_q, block_k)
+    if window:
+        choice = choice._replace(window=window)
     with _CHOICES_LOCK:
         _CHOICES[choice] = _CHOICES.get(choice, 0) + 1
     block_q, block_k = choice.block_q, choice.block_k
@@ -1168,7 +1244,7 @@ def flash_attention(q, k, v, causal: bool = True,
     vf = vp.reshape(b * hkv, kv_len + pad_k, d)
     # kv_len for masking must be the real length: padded keys get masked out
     o = _flash_attention_bh(qf, kf, vf, sm_scale, causal, block_q, block_k,
-                            interpret, kv_len, rep)
+                            interpret, kv_len, rep, window)
     o = o.reshape(b, h, q_len + pad_q, d)
     if pad_q:
         o = o[:, :, :q_len, :]

@@ -64,6 +64,12 @@ PyTree = Any
 MEMORY_OPT_ALLREDUCE_SIZE = 500000000
 
 
+#: ``train_batch``'s metrics hold, under this key, what a model's ``loss_fn``
+#: returned beside its loss (``(loss, record)``: a pytree of float32 scalars,
+#: summed over the step's micro-batches); absent for a scalar loss
+MODEL_RECORD = "model"
+
+
 class _LazyMetrics:
     """Mapping over device-side metrics that defers the host transfer until
     first read.  Keeps the train loop free of per-step device_get round
@@ -77,11 +83,15 @@ class _LazyMetrics:
 
     def _force(self):
         if self._host is None:
-            got = jax.device_get(self._dev)
+            got = jax.device_get({k: v for k, v in self._dev.items()
+                                  if k != MODEL_RECORD})
             self._host = {k: np.asarray(v).item() for k, v in got.items()}
         return self._host
 
     def __getitem__(self, k):
+        if k == MODEL_RECORD:
+            # the model's own record (device arrays): fetched by who reads it
+            return self._dev[k]
         return self._force()[k]
 
     def get(self, k, default=None):
@@ -825,9 +835,12 @@ class DeepSpeedEngine:
         cast = self.fp16_enabled or self.bfloat16_enabled
 
         def micro_loss(params, micro, rng, scale):
+            """-> (scaled loss, (loss, record)); ``record`` is what the
+            model returned beside its loss, ``None`` for a scalar loss."""
             p = _cast_floating(params, compute_dtype) if cast else params
-            loss = loss_fn(p, micro, rng, True)
-            return (loss.astype(jnp.float32) * scale), loss
+            out = loss_fn(p, micro, rng, True)
+            loss, record = out if isinstance(out, tuple) else (out, None)
+            return (loss.astype(jnp.float32) * scale), (loss, record)
 
         return micro_loss
 
@@ -900,9 +913,9 @@ class DeepSpeedEngine:
         return apply_update
 
     def _metrics_shardings(self):
-        rep = NamedSharding(self.mesh, P())
-        return {k: rep for k in
-                ("loss", "grad_norm", "overflow", "loss_scale", "skipped")}
+        """One replicated sharding for every metric of a step (a pytree
+        prefix: the model's record, where there is one, included)."""
+        return NamedSharding(self.mesh, P())
 
     def _step_entry(self, fn, name: str, budget: Optional[int] = 1):
         """``sentry.wrap`` of a step body that also records, each time the
@@ -926,7 +939,9 @@ class DeepSpeedEngine:
                     f"{name}: flash attention {c.generation} "
                     f"({' + '.join(fa.KERNELS[c.generation])}) at blocks "
                     f"{c.block_q} x {c.block_k} ({c.how}) for q {c.q_len} x "
-                    f"kv {c.kv_len}, hd {c.d}: {n} call(s) traced",
+                    f"kv {c.kv_len}, hd {c.d}"
+                    + (f", window {c.window}" if c.window else "")
+                    + f": {n} call(s) traced",
                     ranks=[0])
                 for side, block in (("q", c.block_q), ("k", c.block_k)):
                     self.metrics.gauge(
@@ -953,13 +968,14 @@ class DeepSpeedEngine:
         apply_update = self._make_apply_update()
 
         def grads_of_micro(params, micro, rng, scale):
-            (scaled_loss, loss), grads = jax.value_and_grad(
+            (scaled_loss, (loss, record)), grads = jax.value_and_grad(
                 micro_loss, has_aux=True)(params, micro, rng, scale)
             del scaled_loss
-            return loss, grads
+            return loss, grads, record
 
         def accumulate(state, batch, base_rng):
-            """Scan the GAS microbatches; returns (unscaled fp32 grads, loss)."""
+            """Scan the GAS microbatches; returns (unscaled fp32 grads,
+            loss, the model's record summed over them or None)."""
             params, scaler = state["params"], state["scaler"]
             scale = scaler.cur_scale if fp16 else jnp.asarray(1.0, jnp.float32)
             step_rng = jax.random.fold_in(base_rng, state["step"])
@@ -968,13 +984,13 @@ class DeepSpeedEngine:
                 # fast path: no accumulator (saves a zero-init + add pass
                 # over a full fp32 grad buffer per step)
                 micro = jax.tree_util.tree_map(lambda x: x[0], batch)
-                loss, grads = grads_of_micro(
+                loss, grads, record = grads_of_micro(
                     params, micro, jax.random.fold_in(step_rng, 0), scale)
                 inv = 1.0 / scale
                 grads = jax.tree_util.tree_map(
                     lambda g: g.astype(jnp.float32) * inv, grads)
                 grads = constrain(grads, grad_shardings)
-                return grads, loss.astype(jnp.float32)
+                return grads, loss.astype(jnp.float32), record
 
             zero_grads = jax.tree_util.tree_map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params)
@@ -984,25 +1000,30 @@ class DeepSpeedEngine:
                 acc, loss_sum = carry
                 micro, idx = xs
                 rng = jax.random.fold_in(step_rng, idx)
-                loss, grads = grads_of_micro(params, micro, rng, scale)
+                loss, grads, record = grads_of_micro(params, micro, rng,
+                                                     scale)
                 acc = jax.tree_util.tree_map(
                     lambda a, g: a + g.astype(jnp.float32), acc, grads)
                 acc = constrain(acc, grad_shardings)
-                return (acc, loss_sum + loss.astype(jnp.float32)), None
+                return (acc, loss_sum + loss.astype(jnp.float32)), record
 
-            (grads, loss_sum), _ = jax.lax.scan(
+            (grads, loss_sum), records = jax.lax.scan(
                 body, (zero_grads, jnp.zeros((), jnp.float32)),
                 (batch, jnp.arange(gas)))
             inv = 1.0 / (gas * scale)
             grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
             grads = constrain(grads, grad_shardings)
             mean_loss = loss_sum / gas
-            return grads, mean_loss
+            record = jax.tree_util.tree_map(lambda r: r.sum(0), records)
+            return grads, mean_loss, record
 
         def train_step(state, batch, base_rng):
             """batch: pytree with leading dims [gas, micro_global, ...]."""
-            grads, mean_loss = accumulate(state, batch, base_rng)
-            return apply_update(state, grads, mean_loss)
+            grads, mean_loss, record = accumulate(state, batch, base_rng)
+            new_state, metrics = apply_update(state, grads, mean_loss)
+            if record is not None:
+                metrics = {**metrics, MODEL_RECORD: record}
+            return new_state, metrics
 
         clip = self._config.gradient_clipping
         next_scaler, make_metrics = self._scaler_bookkeeping()
@@ -1026,14 +1047,14 @@ class DeepSpeedEngine:
         def offload_grads_step(state, batch, base_rng):
             """Device half of the offload step: grads + clip + scaler
             bookkeeping in-graph; the optimizer apply happens on host."""
-            grads, mean_loss = accumulate(state, batch, base_rng)
+            grads, mean_loss, _ = accumulate(state, batch, base_rng)
             return offload_finish(state, grads, mean_loss)
 
         def micro_grads(params, scaler, batch, base_rng, idx):
             """One microbatch fwd+bwd for the forward/backward shim path."""
             scale = scaler.cur_scale if fp16 else jnp.asarray(1.0, jnp.float32)
             rng = jax.random.fold_in(base_rng, idx)
-            loss, grads = grads_of_micro(params, batch, rng, scale)
+            loss, grads, _ = grads_of_micro(params, batch, rng, scale)
             grads = jax.tree_util.tree_map(
                 lambda g: g.astype(jnp.float32) / (gas * scale), grads)
             grads = constrain(grads, grad_shardings)
@@ -1042,7 +1063,8 @@ class DeepSpeedEngine:
         def eval_step(params, batch, base_rng):
             p = (_cast_floating(params, self.compute_dtype)
                  if (self.fp16_enabled or self.bfloat16_enabled) else params)
-            return self._loss_impl(p, batch, base_rng, False)
+            out = self._loss_impl(p, batch, base_rng, False)
+            return out[0] if isinstance(out, tuple) else out
 
         rep = NamedSharding(self.mesh, P())
         metrics_shardings = self._metrics_shardings()
@@ -1141,7 +1163,7 @@ class DeepSpeedEngine:
                 acc, loss_sum = carry
                 micro, idx = xs
                 rng = jax.random.fold_in(step_rng, idx)
-                (_, loss), grads = jax.value_and_grad(
+                (_, (loss, _record)), grads = jax.value_and_grad(
                     micro_loss, has_aux=True)(params, micro, rng, scale)
                 acc = acc + ravel_pytree(grads)[0].astype(jnp.float32)
                 return (acc, loss_sum + loss.astype(jnp.float32)), None
@@ -1608,6 +1630,16 @@ class DeepSpeedEngine:
             # and the wall-clock timer histograms (telemetry/metrics.py
             # to_events)
             self._g_train_loss.set(float(self._cached_metrics["loss"]))
+            if MODEL_RECORD in self._cached_metrics:
+                # the model's own record, one gauge a field (the last step
+                # of a multi-step call)
+                record = jax.device_get(self._cached_metrics[MODEL_RECORD])
+                for field, value in record.items():
+                    self.metrics.gauge(
+                        f"train_model_{field}",
+                        "a field of the record the model's loss_fn returned "
+                        "beside its loss, summed over the step's "
+                        "micro-batches").set(float(np.ravel(value)[-1]))
             self._g_train_lr.set(float(self.get_lr()[0]))
             self._g_global_steps.set(self.global_steps)
             if self._g_loss_scale is not None:

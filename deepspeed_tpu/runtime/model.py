@@ -5,7 +5,9 @@ loss (``runtime/engine.py:189,206``).  The TPU-native equivalent of a module is 
 pair of pure functions over a param pytree; :class:`ModelSpec` is that contract:
 
  - ``init_fn(rng)``                       -> params pytree
- - ``loss_fn(params, batch, rng, train)`` -> scalar loss (mean over the batch dim)
+ - ``loss_fn(params, batch, rng, train)`` -> scalar loss (mean over the batch dim),
+   or ``(loss, record)``: ``record`` a pytree of float32 scalars the engine sums
+   over a step's micro-batches into ``train_batch``'s ``metrics["model"]``
  - ``apply_fn(params, batch, rng)``       -> model outputs (logits), for eval/inference
  - ``tp_rules(abstract_params)``          -> pytree of ``PartitionSpec`` carrying
    model-parallel (tp/ep/sp) placement, or None for replicated.  ZeRO sharding is
