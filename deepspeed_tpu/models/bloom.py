@@ -28,6 +28,7 @@ from ..ops.paged_kv import paged_cache_update, paged_gather
 from ..ops.sp_attention import shard_seq
 from ..parallel.topology import TP_AXIS
 from ..runtime.model import ModelSpec
+from ..runtime.remat import checkpoint_block
 from .cached import (cache_update, decode_over_layers, dequant_resident,
                      gather_last, init_kv_cache, layer_accessors, window)
 
@@ -183,7 +184,7 @@ def forward(cfg: BloomConfig, params: PyTree, input_ids, rng=None,
     def body(x, xs):
         layer, = xs
         fn = functools.partial(_block, cfg)
-        return (jax.checkpoint(fn) if cfg.remat else fn)(x, layer), None
+        return (checkpoint_block(fn) if cfg.remat else fn)(x, layer), None
 
     x, _ = jax.lax.scan(body, x, (params["blocks"],))
     x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])

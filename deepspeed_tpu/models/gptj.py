@@ -22,6 +22,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..parallel.topology import TP_AXIS
 from ..runtime.model import ModelSpec
+from ..runtime.remat import checkpoint_block
 from .cached import (cache_update, decode_over_layers, dequant_resident,
                      init_kv_cache, layer_accessors)
 
@@ -179,7 +180,7 @@ def forward(cfg: GPTJConfig, params: PyTree, input_ids, rng=None,
 
     def body(x, xs):
         layer, = xs
-        fn = jax.checkpoint(lambda xx, ll: _block(cfg, xx, ll)[0]) \
+        fn = checkpoint_block(lambda xx, ll: _block(cfg, xx, ll)[0]) \
             if cfg.remat else (lambda xx, ll: _block(cfg, xx, ll)[0])
         return fn(x, layer), None
 

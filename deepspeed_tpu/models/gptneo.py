@@ -26,6 +26,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..parallel.topology import TP_AXIS
 from ..runtime.model import ModelSpec
+from ..runtime.remat import checkpoint_block
 from .cached import cache_update, dequant_resident, init_kv_cache, qmm
 
 PyTree = Any
@@ -186,7 +187,7 @@ def _run_blocks(cfg: GPTNeoConfig, params, x, pos=0, cache=None):
         c = None if cache is None else (cache["k"][i], cache["v"][i])
         fn = _block
         if cfg.remat and cache is None:
-            fn = jax.checkpoint(_block, static_argnums=(0, 3))
+            fn = checkpoint_block(_block, static_argnums=(0, 3))
         x, c = fn(cfg, x, layer, kind == "local", pos, c)
         if cache is not None:
             new_k.append(c[0])

@@ -21,6 +21,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..parallel.topology import TP_AXIS
 from ..runtime.model import ModelSpec
+from ..runtime.remat import checkpoint_block
 from .cached import (cache_update, decode_over_layers, dequant_resident,
                      init_kv_cache, layer_accessors)
 
@@ -193,7 +194,7 @@ def forward(cfg: GPTNeoXConfig, params: PyTree, input_ids, rng=None,
 
     def body(x, xs):
         layer, = xs
-        fn = jax.checkpoint(lambda xx, ll: _block(cfg, xx, ll)[0]) \
+        fn = checkpoint_block(lambda xx, ll: _block(cfg, xx, ll)[0]) \
             if cfg.remat else (lambda xx, ll: _block(cfg, xx, ll)[0])
         return fn(x, layer), None
 

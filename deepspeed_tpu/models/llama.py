@@ -24,6 +24,7 @@ from jax.sharding import PartitionSpec as P
 from ..ops.sp_attention import shard_seq
 from ..parallel.topology import TP_AXIS
 from ..runtime.model import ModelSpec
+from ..runtime.remat import checkpoint_block
 from ..utils.platform import on_tpu
 from . import cached          # (``window`` names a layer's reach in this file)
 from .cached import (cached_attention, decode_over_layers, dequant_resident,
@@ -648,7 +649,7 @@ def forward(cfg: LlamaConfig, params: PyTree, input_ids, rng=None,
     def step(x, layer):
         fn = block_apply
         if cfg.remat:
-            fn = jax.checkpoint(block_apply, static_argnums=(0,))
+            fn = checkpoint_block(block_apply, static_argnums=(0,))
         return fn(cfg, layer, x, cos, sin)
 
     # ZeRO-3 liveness: scan_group_size > 1 gathers G layers per scan step

@@ -68,6 +68,7 @@ from ..ops.chunked_ce import chunked_ce, whole_chunks
 from ..ops import sparse_index_attention as sparse_attention
 from ..parallel.topology import EP_AXIS, TP_AXIS
 from ..runtime.model import ModelSpec
+from ..runtime.remat import checkpoint_block
 from . import llama as L
 from .cached import KIND_LEAVES, layer_accessors, live_tokens, qmm
 
@@ -439,7 +440,7 @@ def _trunk(cfg: MixtralConfig, params: PyTree, input_ids, train: bool,
         x, aux_sum, *rec_sum = carry
         fn = _moe_block
         if cfg.remat:
-            fn = jax.checkpoint(_moe_block, static_argnums=(0, 5, 6, 7))
+            fn = checkpoint_block(_moe_block, static_argnums=(0, 5, 6, 7))
         chosen = []
         for j, kind in enumerate(kinds):
             layer = layers if kind is None else jax.tree_util.tree_map(

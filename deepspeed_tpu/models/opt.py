@@ -33,6 +33,7 @@ from jax.sharding import PartitionSpec as P
 from ..ops.sp_attention import shard_seq
 from ..parallel.topology import TP_AXIS
 from ..runtime.model import ModelSpec
+from ..runtime.remat import checkpoint_block
 from ..utils.platform import on_tpu
 from .cached import (cached_attention, decode_over_layers, dequant_resident,
                      gather_last, init_kv_cache, layer_accessors, qmm, window)
@@ -281,8 +282,8 @@ def forward(cfg: OPTConfig, params: PyTree, input_ids, rng=None,
 
     def body(x, xs):
         layer, = xs
-        block_fn = jax.checkpoint(_block, static_argnums=(0,)) if cfg.remat \
-            else _block
+        block_fn = checkpoint_block(_block, static_argnums=(0,)) \
+            if cfg.remat else _block
         return block_fn(cfg, x, layer), None
 
     x, _ = jax.lax.scan(body, x, (params["blocks"],))
@@ -383,8 +384,8 @@ def loss_from_batch(cfg: OPTConfig, params, batch, rng=None,
 
     def body(x, xs):
         layer, = xs
-        block_fn = jax.checkpoint(_block, static_argnums=(0,)) if cfg.remat \
-            else _block
+        block_fn = checkpoint_block(_block, static_argnums=(0,)) \
+            if cfg.remat else _block
         return block_fn(cfg, x, layer), None
 
     x, _ = jax.lax.scan(body, x, (params["blocks"],))

@@ -925,15 +925,36 @@ class DeepSpeedEngine:
         table is what the compiled step runs.  One log line a distinct
         resolution, its blocks on the ``train_flash_block_q/k`` gauges;
         ``flash_choices[name]`` keeps them whole for a reader without the
-        log."""
+        log.  Beside it, what each checkpointed block of the program KEEPS
+        for its backward (``runtime/remat.py``: ``remat_kept[name]``, one
+        log line, the ``train_remat_kept_bytes`` gauge): ``input`` alone is
+        a step that re-runs its attention in the backward,
+        ``input+flash_lse+flash_out`` one whose flash forward runs once."""
         from ..ops import flash_attention as fa
+        from . import remat
 
         @functools.wraps(fn)
         def traced(*args):
             before = fa.choices()
-            out = fn(*args)
+            with remat.listen() as blocks:
+                out = fn(*args)
             ran = fa.choices(since=before)
             self.flash_choices[name] = ran
+            self.remat_kept[name] = kept = remat.kept(blocks)
+            for k, n in kept.items():
+                log_dist(
+                    f"{name}: a checkpointed block ({k.block}) keeps "
+                    + " + ".join(f"{what} {b:,} B" for what, b in
+                                 (("input", k.input), *k.named,
+                                  ("other", k.other)) if b)
+                    + f" = {k.bytes:,} B a micro-batch: {n} call(s) traced",
+                    ranks=[0])
+                self.metrics.gauge(
+                    "train_remat_kept_bytes",
+                    "bytes one checkpointed block call keeps for its "
+                    "backward, a micro-batch (phase: the program; mode: "
+                    "what is kept)",
+                    phase=name, mode=k.what).set(k.bytes)
             for c, n in ran.items():
                 log_dist(
                     f"{name}: flash attention {c.generation} "
@@ -961,6 +982,7 @@ class DeepSpeedEngine:
         # shape (scan length = leading batch dim): budget None, count only.
         self.sentry = RecompileSentry(name="training")
         self.flash_choices: Dict[str, Dict[Any, int]] = {}
+        self.remat_kept: Dict[str, Dict[Any, int]] = {}
         gas = self.gradient_accumulation_steps()
         fp16 = self.fp16_enabled
         micro_loss = self._micro_loss_closure()

@@ -238,6 +238,64 @@ def test_engine_carries_the_models_record_out_of_the_step(gas):
     comm.reset_topology()
 
 
+@pytest.mark.parametrize("use_flash", [False, True])
+def test_engine_says_what_a_checkpointed_block_keeps(use_flash, monkeypatch,
+                                                     caplog):
+    """ISSUE 49: beside the flash kernels a compiled step got, the engine
+    records what each checkpointed block keeps for its backward — the input
+    alone without a flash kernel (the step re-runs its attention), the input
+    and the kernel's two named outputs with one — in ``remat_kept``, one log
+    line and the ``train_remat_kept_bytes`` gauge."""
+    import logging
+
+    import deepspeed_tpu
+    from deepspeed_tpu import comm
+    from deepspeed_tpu.utils.logging import logger
+
+    # the chunked generation at this length: the one with an lse to keep
+    monkeypatch.setenv("DS_FLASH_V2", "0")
+    monkeypatch.setenv("DS_FLASH_V3_MIN_KV", "8")
+    comm.reset_topology()
+    cfg = tiny(num_layers=4, vocab_size=64, use_flash=use_flash)
+    engine, *_ = deepspeed_tpu.initialize(
+        model=mixtral.build(cfg),
+        config={"train_micro_batch_size_per_gpu": 1,
+                "gradient_accumulation_steps": 1,
+                "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
+                "zero_optimization": {"stage": 0}})
+    rows = engine.train_batch_size()
+    ids = np.random.default_rng(0).integers(0, 64, (rows, SEQ + 1),
+                                            dtype=np.int32)
+    assert engine.remat_kept == {}                  # nothing traced yet
+    logger.addHandler(caplog.handler)
+    try:
+        with caplog.at_level(logging.INFO, logger=logger.name):
+            for _ in range(2):                      # the second call: no trace
+                engine.train_batch({"input_ids": ids})
+    finally:
+        logger.removeHandler(caplog.handler)
+    (kept, calls), = engine.remat_kept["train_step"].items()
+    tokens = rows * SEQ
+    stream = tokens * cfg.hidden_size * 4           # float32 parameters here
+    assert kept.block == "_moe_block" and calls == 4    # one period's layers
+    assert kept.input == stream and kept.other == 0
+    want = {"flash_out": tokens * cfg.num_heads * cfg.head_dim * 4,
+            "flash_lse": tokens * cfg.num_heads * 4} if use_flash else {}
+    assert dict(kept.named) == want
+    what = "input+flash_lse+flash_out" if use_flash else "input"
+    assert kept.what == what
+    lines = [r.getMessage() for r in caplog.records
+             if "a checkpointed block" in r.getMessage()]
+    assert len(lines) == 1, lines                   # once, at the compile
+    assert f"keeps input {stream:,} B" in lines[0]
+    assert ("flash_out" in lines[0]) == ("flash_lse" in lines[0]) == use_flash
+    assert (f'train_remat_kept_bytes{{mode="{what}",phase="train_step"}} '
+            f'{float(kept.bytes)}') in engine.metrics.prometheus_text()
+    # one full and one sliding resolution, as before the record was made
+    assert len(engine.flash_choices["train_step"]) == (2 if use_flash else 0)
+    comm.reset_topology()
+
+
 def test_a_scalar_loss_has_no_record():
     import deepspeed_tpu
     from deepspeed_tpu import comm
