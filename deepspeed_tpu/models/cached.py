@@ -403,9 +403,11 @@ def scan_periods_cached(kinds, num_layers: int, step, x, blocks, cache,
         for j, kind in enumerate(kinds):
             same = [i for i in range(p) if kinds[i] == kind]
             ck, cv, table = KIND_LEAVES[kind]
-            # the layer's weights, read where they lie in the stacks (a
-            # static slice of a parameter is a COPY on a TPU: 134 MB for
-            # one layer's q or o projection at Command A+'s widths)
+            # the layer's weights, read where they lie in the stacks: the
+            # slices fuse into their matmuls, with a constant index (a
+            # one-period scan unrolls) as with a traced one.  (What PR 34
+            # saw copied here was ``q_w``, transposed for a head split XLA
+            # had folded into its dot: ``llama._attend_cached``)
             layer = jax.tree_util.tree_map(
                 lambda a: jax.lax.dynamic_index_in_dim(
                     a, period * p + j, keepdims=False), blocks)

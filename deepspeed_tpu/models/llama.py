@@ -704,10 +704,17 @@ def _attend_cached(cfg: LlamaConfig, x, get, mm, ck, cv, pos, block_tables,
     rotated, window = kind_of(cfg, kind)
     with jax.named_scope("layer/attn"):
         y = block_norm(cfg, x, get("attn_norm"))
-        q, k = qk_normed(cfg, mm(y, "q_w", None), mm(y, "k_w", None), get)
+        # the head split below (and a per-head q/k-norm's) moves these
+        # PRODUCTS, [rows, H hd] of a serving call's few rows: left free, XLA
+        # folds it into the dots and transposes their WEIGHTS instead, every
+        # call (Command A+: 134 MB of q_w a layer; test_chip_lowering.py
+        # ..._write_no_weight_sized_value)
+        q, k, v = jax.lax.optimization_barrier(
+            (mm(y, "q_w", None), mm(y, "k_w", None), mm(y, "v_w", None)))
+        q, k = qk_normed(cfg, q, k, get)
         q = q.reshape(b, t, h, hd)
         k = k.reshape(b, t, hkv, hd)
-        v = mm(y, "v_w", None).reshape(b, t, hkv, hd)
+        v = v.reshape(b, t, hkv, hd)
         q = q.transpose(0, 2, 1, 3)
         if rotated:
             q = _rope_cached(cfg, q, pos)

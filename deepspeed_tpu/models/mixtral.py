@@ -342,7 +342,10 @@ def _indexer(cfg: MixtralConfig, get, mm, y, rope):
     weights float32 ``[B, T, HI]``."""
     b, t, _ = y.shape
     hi, di = cfg.index_heads, cfg.index_head_dim
-    qi = mm(y, "idx_q_w", None).reshape(b, t, hi, di).transpose(0, 2, 1, 3)
+    # (the barrier: the head split moves the product, not ``idx_q_w`` —
+    # ``llama._attend_cached``)
+    qi = jax.lax.optimization_barrier(mm(y, "idx_q_w", None))
+    qi = qi.reshape(b, t, hi, di).transpose(0, 2, 1, 3)
     ki = sparse_attention.layer_norm(mm(y, "idx_k_w", None),
                                      get("idx_k_norm"))[:, None]
     wi = mm(y, "idx_w_w", None).astype(jnp.float32)

@@ -258,15 +258,19 @@ def smoke():
     return mod
 
 
+def _trace_as_on_tpu(patch):
+    from deepspeed_tpu.utils import platform
+
+    for mod in (platform, da):
+        patch.setattr(mod, "on_tpu", lambda: True)
+        patch.setattr(mod, "interpret_kernels", lambda: False)
+
+
 @pytest.fixture
 def as_on_tpu(monkeypatch):
     """Trace the TPU branches (Mosaic kernels, the layout pin) from here:
     the dispatch asks ``on_tpu()``, which sees the CPU this suite runs on."""
-    from deepspeed_tpu.utils import platform
-
-    for mod in (platform, da):
-        monkeypatch.setattr(mod, "on_tpu", lambda: True)
-        monkeypatch.setattr(mod, "interpret_kernels", lambda: False)
+    _trace_as_on_tpu(monkeypatch)
 
 
 def _dims(tensor_type):
@@ -780,30 +784,29 @@ def test_window_walks_compile_at_the_rag_chat_cells_shapes(kind, rows, t,
 #: be held, lowered for a described v5e at the small shapes of
 #: ``_old_family_programs`` — each Mosaic kernel's payload masked: it embeds
 #: the source lines of ``ops/decode_attention.py`` / ``moe/routed.py``,
-#: which moved.  The two ``*.prefill`` pins of the hd-128 families are still
-#: the text of the PARENT of PR 34 (OLMoE) / PR 39 (Command A+: layers of
-#: two kinds, a table a kind): at ``g = 1`` the write of PR 41 emits the op
-#: sequence the old one did.  The other eight are taken on the tree of
-#: PR 41: ``opt.*`` / ``mixtral.*`` (tiny configs, hd 16: g = 8) and
-#: ``keye.*`` (its 64-wide indexer leaf: g = 2) merge in the stored view,
-#: and every ``*.decode`` — a one-token window is broadcast over its block,
-#: at g = 1 too, where the parent gathered it row by row.  The last eight
-#: (``mistral4.*``: the latent pool kind, ONE leaf; ``gpt2.*``; ``llama.*``:
-#: dense, GQA 4 / 2; ``bloom.*``: ALiBi, pure XLA on the pool, no kernel)
-#: are taken on the PARENT of PR 46 (``a0db4cf``), before the cached forward
-#: moved to ``models/cached.py``
+#: which moved.  ``opt.*`` are taken on the tree of PR 41 (tiny config, hd
+#: 16: g = 8 merges in the stored view; a one-token window is broadcast
+#: over its block); ``mistral4.*`` (the latent pool kind, ONE leaf),
+#: ``gpt2.*`` and ``bloom.*`` (ALiBi, pure XLA on the pool, no kernel) on
+#: the PARENT of PR 46 (``a0db4cf``), before the cached forward moved to
+#: ``models/cached.py``.  The ten that pass through
+#: ``llama._attend_cached`` — ``mixtral.*``, ``olmoe.*``, ``keye.*``,
+#: ``commanda.*`` and ``llama.*`` (dense, GQA 4 / 2) — are taken on the tree
+#: of PR 50: each layer's q, k and v products (and Keye's indexer queries)
+#: pass an ``optimization_barrier`` before their head split, and nothing
+#: else of the text moved
 OLD_PROGRAMS = {
     "opt.decode": "b52e4bc5d86a603e", "opt.prefill": "0fe6ed036104ea84",
-    "mixtral.decode": "6479cf5460fbfff5",
-    "mixtral.prefill": "44cd3dadda841e0a",
-    "olmoe.decode": "e9cbda30c55880c6", "olmoe.prefill": "9cc3b8a954958161",
-    "keye.decode": "b594d5fea767ffda", "keye.prefill": "b14058bd110ef1a1",
-    "commanda.decode": "dae34abf7d70a110",
-    "commanda.prefill": "cfd7f4cc16d5488d",
+    "mixtral.decode": "f936948485165684",
+    "mixtral.prefill": "d01b1d839f49b6ec",
+    "olmoe.decode": "2eb416656948bc0f", "olmoe.prefill": "7388632270416586",
+    "keye.decode": "1bb7ff462ebd729f", "keye.prefill": "93c37d6a3e887546",
+    "commanda.decode": "16dda96d9bd57be2",
+    "commanda.prefill": "82fe8e3e6324c1c8",
     "mistral4.decode": "2d73686d9b1d63a7",
     "mistral4.prefill": "33a83a8b55a6460a",
     "gpt2.decode": "56db7e72e98e31c7", "gpt2.prefill": "aaca73ee22c8ad8f",
-    "llama.decode": "dc639e668cbe000a", "llama.prefill": "b8356961e3983d22",
+    "llama.decode": "12867882289301b8", "llama.prefill": "a7ecf11445f38e19",
     "bloom.decode": "05eea69436e8aefe", "bloom.prefill": "9e7f54fb3e7d2def"}
 
 
@@ -923,13 +926,14 @@ def test_the_old_programs_are_the_old_programs(name, as_on_tpu, one_chip,
 #: constant 0) and the decode call (T = 1, ``pos`` a traced scalar) — of the
 #: seven families that serve it, lowered on the CPU (the reference
 #: attention, no kernel) at their ``tiny`` shapes, batch 2, a 128-token
-#: float32 cache.  Taken on the PARENT of PR 46 (``a0db4cf``).  To re-take
+#: float32 cache.  Taken on the PARENT of PR 46 (``a0db4cf``); ``llama.*``
+#: on the tree of PR 50 (the barrier of ``_attend_cached``).  To re-take
 #: a pin (of this table or of ``OLD_PROGRAMS``), run its case: the failure
 #: names the hash the tree lowers to
 OLD_CONTIGUOUS = {
     "gpt2.prefill": "cf1732aa2e5283b6", "gpt2.decode": "b5813769756b1ec5",
     "opt.prefill": "78300f29f61f0b7b", "opt.decode": "104b70c90eb19130",
-    "llama.prefill": "fca207d6315d2c71", "llama.decode": "2c5154942d92a665",
+    "llama.prefill": "82fd4b2ce11e3ee0", "llama.decode": "be6a053f8d4994c2",
     "bloom.prefill": "f6678df8b3ca7100", "bloom.decode": "a90ed9b2d761d990",
     "gptj.prefill": "fb999b79d2cc2edc", "gptj.decode": "1a8a5159d88af014",
     "gptneo.prefill": "832f31a013eb992b", "gptneo.decode": "1995354317bdee54",
@@ -962,13 +966,12 @@ def test_the_contiguous_forward_cached_is_the_old_one(name):
     _assert_pinned(OLD_CONTIGUOUS, name, lowered.as_text())
 
 
-def test_compiled_two_kind_serving_programs_fit_and_alias_both_pools(
-        as_on_tpu, one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def two_kind_programs(one_chip):
     """The RAG-chat cell's decode and prefill programs (Command A+ at its
     published widths, this chip's share: 4 layers, 16 held of 128 experts,
-    32,768 vocabulary rows) compile for a described v5e, alias all four
-    pool leaves — two kinds — and hold temporaries under a gigabyte beside
-    9.47 GB of weights and 2.87 GB of pools."""
+    32,768 vocabulary rows), compiled for a described v5e: ``{kernel:
+    compiled}`` + the abstract parameters and pool they were lowered at."""
     import json
     import os
 
@@ -976,7 +979,6 @@ def test_compiled_two_kind_serving_programs_fit_and_alias_both_pools(
     from deepspeed_tpu.moe import grouped_matmul
     from deepspeed_tpu.ops import paged_kv
 
-    monkeypatch.setattr(grouped_matmul, "interpret_kernels", lambda: False)
     root = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir)
     with open(os.path.join(root, "chipbench", "configs",
                            "command-a-plus-05-2026.json")) as f:
@@ -1006,9 +1008,6 @@ def test_compiled_two_kind_serving_programs_fit_and_alias_both_pools(
         lambda: paged_kv.pack_pool(spec.decode_hooks["init_cache"](
             1 + c["slots"] * nbper, BLOCK, jnp.bfloat16,
             window_blocks=1 + c["slots"] * ring))))
-    assert set(pool) == {"k", "v", "kw", "vw"}
-    assert pool["k"].shape[:2] == (1, 12289)
-    assert pool["kw"].shape[:2] == (3, 3193)
 
     def decode_step(params, cache, tokens, lengths, bt):
         logits, cache, rec = fwd(params, tokens[:, None], cache, 0,
@@ -1027,14 +1026,124 @@ def test_compiled_two_kind_serving_programs_fit_and_alias_both_pools(
             params, pool, i32(slots), i32(slots), tables(slots))),
         "paged_prefill_attn": (prefill, (
             params, pool, i32(4, 128), tables(4), i32(4), i32(4)))}
+    with pytest.MonkeyPatch.context() as patch:
+        _trace_as_on_tpu(patch)
+        patch.setattr(grouped_matmul, "interpret_kernels", lambda: False)
+        compiled = {
+            kernel: jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+            for kernel, (fn, args) in programs.items()}
+    return compiled, params, pool
+
+
+def test_compiled_two_kind_serving_programs_fit_and_alias_both_pools(
+        two_kind_programs):
+    """The RAG-chat cell's decode and prefill programs compile for a
+    described v5e, alias all four pool leaves — two kinds — and hold
+    temporaries under a gigabyte beside 9.47 GB of weights and 2.87 GB of
+    pools."""
+    compiled, _, pool = two_kind_programs
+    assert set(pool) == {"k", "v", "kw", "vw"}
+    assert pool["k"].shape[:2] == (1, 12289)
+    assert pool["kw"].shape[:2] == (3, 3193)
     pool_bytes = sum(int(np.prod(a.shape)) * 2 for a in pool.values())
-    for kernel, (fn, args) in programs.items():
-        compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
-        text = compiled.as_text()
+    for kernel, program in compiled.items():
+        text = program.as_text()
         assert kernel in text and "moe_gmm" in text
-        mem = compiled.memory_analysis()
+        mem = program.memory_analysis()
         assert mem.temp_size_in_bytes < 1 << 30, (kernel, mem)
         assert mem.alias_size_in_bytes >= pool_bytes, (kernel, mem)
+
+
+#: HLO element type -> bytes, for :func:`_writes_of_size`
+_HLO_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
+                 "u32": 4, "f32": 4}
+
+
+def _writes_of_size(compiled_text, sizes):
+    """``(opcode, name)`` of every instruction of a compiled program's ENTRY
+    computation that WRITES an array of one of ``sizes`` bytes — no
+    ``parameter``, ``bitcast`` or ``get-tuple-element`` (names for what is
+    already there).  What a fusion holds inside costs no memory traffic of
+    its own; these do."""
+    import re
+
+    lines = compiled_text.splitlines()
+    start = next(i for i, line in enumerate(lines)
+                 if line.startswith("ENTRY "))
+    found = []
+    for line in lines[start + 1:lines.index("}", start)]:
+        parts = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
+        if not parts:
+            continue
+        name, result, opcode = parts.groups()
+        if opcode in ("parameter", "bitcast", "get-tuple-element"):
+            continue
+        written = {
+            int(np.prod([int(d) for d in dims.split(",") if d]))
+            * _HLO_ITEMSIZE[dtype]
+            for dtype, dims in re.findall(r"(\w+)\[([\d,]*)\]", result)
+            if dtype in _HLO_ITEMSIZE}
+        if written & set(sizes):
+            found.append((opcode, name))
+    return found
+
+
+def test_compiled_two_kind_serving_programs_write_no_weight_sized_value(
+        two_kind_programs):
+    """ISSUE 50: neither program holds an ENTRY-level operation that writes
+    a value of a block weight's size — a layer's ``q_w`` / ``o_w`` /
+    ``shared_w*`` (134 MB) or its held experts' (537 MB).  The parent held
+    eight in each: one ``slice_bitcast_fusion`` writing every layer's
+    ``q_w[j]`` as its own transpose and four ``copy`` bringing each to row
+    major — XLA folds the head split after the q projection
+    (``llama._attend_cached``) into the dot and then re-lays-out the dot's
+    WEIGHT ``[H, hd, D]`` on every call, 3.5 ms of each, where the product
+    is 0.8 MB (decode) / 16.8 MB (a chunk).  (The slices themselves fuse
+    into their matmuls: a layer of a one-period scan costs no copy.)"""
+    compiled, params, _ = two_kind_programs
+    weights = {name: int(np.prod(a.shape[1:])) * 2
+               for name, a in params["blocks"].items()}
+    large = {size for size in weights.values() if size >= 64 << 20}
+    assert large == {weights["q_w"], weights["experts_w1"]} \
+        and weights["q_w"] == 4096 * 16384 * 2
+    for kernel, program in compiled.items():
+        assert not _writes_of_size(program.as_text(), large), kernel
+
+
+@pytest.mark.parametrize("index", ["static", "traced"])
+@pytest.mark.parametrize("rows,t", [(24, 1), (4, 128)],
+                         ids=["decode", "prefill-chunk"])
+def test_a_head_split_moves_the_projections_product_not_its_weight(
+        rows, t, index, one_chip):
+    """ISSUE 50, in isolation: ``(x @ w[j])`` followed by the head split
+    ``[B, T, H hd] -> [B, H, T, hd]``, compiled for a described v5e at
+    Command A+'s ``q_w``.  Left free, XLA writes ``w[j]`` out transposed
+    (a slice fusion + a ``copy``, 134 MB each) whether ``j`` is a constant
+    or a traced scalar — slicing a stacked parameter is not what costs; behind
+    ``optimization_barrier`` on the product nothing of the weight's size is
+    written.  (If the first half ever fails, the compiler stopped folding
+    and the barrier of ``_attend_cached`` can go.)"""
+    d, h, hd = 4096, 128, 128
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def written(barrier):
+        def fn(x, w, j):
+            y = x @ jax.lax.dynamic_index_in_dim(
+                w, j if index == "traced" else 2, 0, keepdims=False)
+            if barrier:
+                y = jax.lax.optimization_barrier(y)
+            return y.reshape(rows, t, h, hd).transpose(0, 2, 1, 3)
+
+        text = jax.jit(fn).lower(
+            sds((rows, t, d), jnp.bfloat16), sds((4, d, h * hd), jnp.bfloat16),
+            sds((), jnp.int32)).compile().as_text()
+        return _writes_of_size(text, {d * h * hd * 2})
+
+    free = written(barrier=False)
+    assert sorted(opcode for opcode, _ in free) == ["copy", "fusion"], free
+    assert not written(barrier=True)
 
 
 #: the long-decode cell (``mistral4-longdecode-closed``): Mistral Small 4 at

@@ -300,12 +300,14 @@ def test_what_the_selection_does_not_serve_is_refused_by_name(model):
 #: PARENT of PR 32 (d7d610f) and held by every tree up to PR 39; re-taken on
 #: the tree of PR 41, whose paged write merges its tokens in the blocks'
 #: stored view — both families pack here (hd 16 under a block of 8: g = 8),
-#: so the write's ops, and nothing else of these programs, changed
+#: so the write's ops, and nothing else of these programs, changed; and on
+#: the tree of PR 50: one ``optimization_barrier`` a traced block, on its q,
+#: k and v products (``llama._attend_cached``), and nothing else
 PARENT_PROGRAMS = {
-    ("mixtral", "decode"): "6d9129a516dc3e00",
-    ("mixtral", "prefill"): "69c586494d97f42d",
-    ("olmoe", "decode"): "d23a643b78f53ee3",
-    ("olmoe", "prefill"): "64dfd51e482ebbf7",
+    ("mixtral", "decode"): "7a4974795a81b135",
+    ("mixtral", "prefill"): "3f11818526973750",
+    ("olmoe", "decode"): "f3fc86115cc7e2e3",
+    ("olmoe", "prefill"): "d00c0cce053dad56",
 }
 OLD_FAMILIES = {
     "mixtral": mixtral.MixtralConfig.tiny(),
