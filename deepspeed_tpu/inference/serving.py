@@ -237,6 +237,31 @@ block, the read each program was traced with and the refusals; the
 ``kv_blocks``, ``kv_pairs`` (query-key pairs x layers) and ``latent_bytes``
 (:meth:`ServingEngine._kv_reach`).
 
+**The state kind** (PR 51): a model with gated delta-rule layers (decode
+hook ``state_layers``; ``models/kimi_linear.py``) keeps, for each such layer,
+a recurrent state a SLOT and nothing a token: ``state [L, slots, H, dk, dv]``
+float32 and the short convolutions' tails ``conv [L, slots, 1, taps,
+channels]`` ride in the cache tree beside the paged pool (``ops/paged_kv.py``
+"The state kind") — donated and carried with it, not lane-packed — with no
+block ids, no table and no allocator: a slot's rows are its own for as long as it
+holds a request.  The state follows the slot: a prefill window at base 0
+starts from a zero state inside the program (a request ENTERING a slot needs
+no reset call; the span counts it, ``state_resets``), each chunk of a prompt
+carries it on (the pads of a ``[rows, chunk]`` call leave it untouched), a
+decode step advances the live rows once and no idle row's lane, the one
+call of lookahead keeps it (it is part of the donated cache), and
+``_release_slot`` simply forgets it; ``_preempt``'s recompute re-prefills
+from base 0 and is exact.  The programs take ``block_tables = {"full": ...}``
+(decode: row b is slot b) or ``{"full": ..., "slot": int32 [rows]}``
+(prefill; a pad row's slot is out of range).  Refused by name at
+construction, each with its reason (:meth:`ServingEngine._refuse_for_state`):
+the prefix trie, the host / NVMe tiers, ``spec_tokens`` and a draft model,
+``decode_steps > 1``, ``quantize`` (kv8, w8a8), ``resident_window_blocks``
+and tp / dp / sp meshes.  ``stats()["kv_state"]`` has the leaves, their
+bytes (whatever the rows' lengths), the resets, which body each program's
+delta rule lowered to and the refusals; ``stats()["kv_kinds"]`` names the
+state kind beside the paged one.
+
 Greedy decoding only: per-request outputs are token-identical to
 sequential ``generate`` (pinned in ``tests/unit/test_serving.py``,
 ``tests/unit/test_paged_serving.py``, ``tests/unit/test_spec_decode.py``,
@@ -329,6 +354,8 @@ _STALL_FIELDS = ("wall",) + SEGMENTS + ("offcpu", "gc")
 EARLY_SETTLE_CAUSES = ("debug_checks", "speculative", "fused", "kv_tier",
                        "handoff", "mask_builder", "preempt", "cancel",
                        "drain", "close")
+#: the cache leaves of the state kind: indexed by SLOT, never by block
+STATE_LEAVES = paged_kv.STATE_LEAVES
 #: a decode row's entry in the call's token operand when its input token is
 #: the one the call before made and the host has not seen: the program
 #: takes the row's entry of the engine's device-resident token vector
@@ -946,6 +973,21 @@ class ServingEngine:
                 f"{self.weight_quant or 'full-precision'} weights — build "
                 "it with config={'quant': {'enabled': True, 'type': "
                 "'w8a8'}} (init_serving(quantize=...) does this for you)")
+        #: a recurrent state a row (decode hook ``state_layers``:
+        #: ``{"layers", "heads", "key_dim", "value_dim", "conv_taps",
+        #: "channels"}``): leaves indexed by SLOT beside the paged pool, with
+        #: no block ids, no table and no allocator (module docstring "The
+        #: state kind"); None otherwise
+        self._state = (getattr(engine.module, "decode_hooks", None)
+                       or {}).get("state_layers")
+        self._state_totals = {"resets": 0, "state_rows": 0}
+        if self._state:
+            prefix_caching = self._refuse_for_state(
+                engine, prefix_caching=prefix_caching,
+                host_blocks=host_blocks, nvme_blocks=nvme_blocks,
+                draft=draft, decode_steps=decode_steps,
+                engine_mode=engine_mode, sp=sp,
+                resident_window_blocks=resident_window_blocks)
         hooks = _validate_decode_hooks(engine.module,
                                        speculative=bool(self.spec_tokens),
                                        kv_quant=self.kv_quant,
@@ -1331,11 +1373,13 @@ class ServingEngine:
                     self.slots, self._windows["window"], self.prefill_chunk,
                     self.block_size)
                 kinds["window_blocks"] = self._ring.alloc.num_blocks
+            if self._state:
+                kinds["state_rows"] = self.slots
             mk_pool = lambda: self._init_cache(
                 num_blocks, self.block_size, engine._config.jnp_dtype,
                 **kinds)
             self._kv_dtype = jnp.dtype(jax.tree_util.tree_leaves(
-                jax.eval_shape(mk_pool))[0].dtype).name
+                self._paged_leaves(jax.eval_shape(mk_pool)))[0].dtype).name
         # the hook's (logical) shape [L, NB, HKV, bs, hd]; the pool itself
         # is held lane-packed (:meth:`_commit_pool`)
         # (of its widest leaf, K or V: a sparse-attention family's third
@@ -1343,7 +1387,7 @@ class ServingEngine:
         self._pool_shape = max(
             (tuple(paged_kv.pool_payload(leaf).shape)
              for leaf in jax.tree_util.tree_leaves(
-                 jax.eval_shape(mk_pool),
+                 self._paged_leaves(jax.eval_shape(mk_pool)),
                  is_leaf=paged_kv.is_quantized_pool)),
             key=lambda shape: int(np.prod(shape)))
         #: the tile of the decode / verify walk at this pool's stored
@@ -2075,6 +2119,62 @@ class ServingEngine:
     def compile_count(self) -> int:
         return len(self.compiled_programs)
 
+    @staticmethod
+    def _paged_leaves(cache):
+        """``cache`` without the state kind's leaves: what has blocks."""
+        if not isinstance(cache, dict):
+            return cache
+        return {k: v for k, v in cache.items() if k not in STATE_LEAVES}
+
+    def _refuse_for_state(self, engine, *, prefix_caching, host_blocks,
+                          nvme_blocks, draft, decode_steps, engine_mode, sp,
+                          resident_window_blocks):
+        """What a model with a recurrent state a slot is REFUSED, each by
+        name with its reason, and whether this construction asked for it
+        (``stats()["kv_state"]["refused"]``).  -> ``prefix_caching`` as the
+        engine takes it (``None`` turns it off for such a model)."""
+        mesh = dict(engine.mesh.shape)
+        tp, dp = int(mesh.get(TP_AXIS, 1)), int(mesh.get(DP_AXIS, 1)) \
+            if engine_mode == "dp_tp" else 1
+        qcfg = engine._config.quant
+        refused = (
+            ("prefix_caching=True", prefix_caching,
+             "a state can be shared only where it was snapshotted, not at "
+             "any block boundary"),
+            (f"host_blocks={host_blocks}", int(host_blocks),
+             "the tiers move blocks; a slot's state has none"),
+            (f"nvme_blocks={nvme_blocks}", int(nvme_blocks),
+             "the tiers move blocks; a slot's state has none"),
+            (f"spec_tokens={self.spec_tokens}", self.spec_tokens,
+             "a rejected draft token has already moved the state: "
+             "\"rollback is free\" holds for keys and values only"),
+            ("a draft model", draft is not None,
+             "a rejected draft token has already moved the state"),
+            (f"decode_steps={decode_steps}", int(decode_steps) > 1,
+             "a frozen row of the fused window would advance its state"),
+            ("quantize='kv8'", self.kv_quant,
+             "the state is float32 by construction"),
+            (f"quantized weights ({qcfg.type if qcfg.enabled else None})",
+             qcfg.enabled, "the gated delta-rule leaves (decays, "
+             "convolution taps, low-rank gates) have no int8 record"),
+            ("resident_window_blocks", int(resident_window_blocks),
+             "a window slides over blocks; the state has none"),
+            (f"a tp mesh (tp={tp})", tp > 1,
+             "the state's heads are not sharded: one shard"),
+            (f"engine_mode='dp_tp' (dp={dp})", dp > 1,
+             "the state's rows are not sharded: one shard"),
+            (f"sp={sp}", int(sp) > 1,
+             "the chunked delta rule carries its state along the sequence"))
+        self._state_refusals = [what.split("=")[0].split(" (")[0]
+                                for what, _, _ in refused]
+        unserved = [f"{what} ({why})" for what, on, why in refused if on]
+        if unserved:
+            raise ValueError(
+                f"{engine.module.name} keeps a recurrent state a slot "
+                "(decode hook state_layers) beside its paged pool, which "
+                "is not served with " + "; ".join(unserved))
+        return False
+
     def _donate(self):
         # donating the pool avoids a full cache copy per step; XLA:CPU
         # ignores donation with a warning, so only ask for it on TPU
@@ -2087,8 +2187,15 @@ class ServingEngine:
         in the view whose TPU layout the paged kernels read) — in one
         jitted program, so neither an unpacked nor an unsharded copy of it
         ever exists."""
-        return jax.jit(lambda: paged_kv.pack_pool(mk_pool()),
-                       out_shardings=sharding)()
+        def packed():
+            cache = mk_pool()
+            if not isinstance(cache, dict):
+                return paged_kv.pack_pool(cache)
+            # (the state kind's leaves have no block to pack)
+            return {k: v if k in STATE_LEAVES else paged_kv.pack_pool(v)
+                    for k, v in cache.items()}
+
+        return jax.jit(packed, out_shardings=sharding)()
 
     def _constrain_pool(self, cache):
         """dp_tp only: pin the cache OUTPUT of every decode/prefill program
@@ -2181,7 +2288,17 @@ class ServingEngine:
         ``paged_latent_*`` kernel on a TPU, ``"latent_gather"`` on a CPU."""
         if self._latent:
             self._program_meta.setdefault("latent_attn", {})[program] = \
-                "+".join(sorted(paths))
+                "+".join(sorted(p for p in paths if not p.startswith("kda_")))
+        self._note_state(program, paths)
+
+    def _note_state(self, program: str, paths) -> None:
+        """Trace time: which body ``program``'s gated delta-rule layers
+        lowered to (``stats()["kv_state"]["kda"]``): ``kda_step`` /
+        ``kda_chunk_state``, the Pallas kernels, on a TPU; ``kda_*_plain``
+        on a CPU."""
+        if self._state:
+            self._program_meta.setdefault("kda", {})[program] = \
+                "+".join(sorted(p for p in paths if p.startswith("kda_")))
 
     def _note_sampler(self, program: str, samp) -> None:
         """Trace time: how ``program`` picks its tokens
@@ -2300,6 +2417,12 @@ class ServingEngine:
         spec = {name: sds(width) for name, width in head.items()}
         spec["block_tables"] = sds(self._nbper) if not self._windows else {
             "full": sds(self._nbper), "window": sds(self._ring.width)}
+        if self._state:
+            # the state kind's "table": the slot of each row of a prefill
+            # call (a decode step's row b is slot b)
+            spec["block_tables"] = {"full": sds(self._nbper)}
+            if "ids" in head:
+                spec["block_tables"]["slot"] = sds()
         spec.update((name, sds()) for name in tail)
         if self.sampling:
             spec.update(temps=sds(dtype=np.float32), topks=sds(),
@@ -3600,6 +3723,20 @@ class ServingEngine:
             self._window_totals[key] += v
         return args
 
+    def _state_args(self, rows: int, resets: int,
+                    tokens: int) -> Dict[str, int]:
+        """Span args of a dispatch of a model with a recurrent state:
+        ``state_rows``, the rows whose state the call advances,
+        ``state_resets``, those of them that start from a zero state (a
+        prefill window at base 0: a sequence entering its slot), and
+        ``state_tokens``, the real tokens it advances them by."""
+        if not self._state:
+            return {}
+        self._state_totals["state_rows"] += rows
+        self._state_totals["resets"] += resets
+        return {"state_rows": rows, "state_resets": resets,
+                "state_tokens": tokens}
+
     def _bt(self, tables, rows=None):
         """The block-table operand of a dispatch, on the host: the full
         kind's ``tables`` (already masked to the dispatch's rows) — for a
@@ -3607,6 +3744,12 @@ class ServingEngine:
         rings gathered for the same rows (``rows``: slot of each row, -1 a
         pad row; None: row i is slot i, rows whose table is all scratch
         are idle)."""
+        if self._state:
+            if rows is None:
+                return {"full": tables}
+            return {"full": tables, "slot": np.asarray(
+                [slot if slot >= 0 else self.slots for slot in rows],
+                np.int32)}
         if not self._windows:
             return tables
         if rows is None:
@@ -4064,9 +4207,10 @@ class ServingEngine:
                 evicted=self.preempted - preempted0,
                 blocks_in_use=self._alloc.blocks_in_use,
                 active=len(self._active), pending=len(self._pending))
-            if self._windows:
+            if self._windows or self._state:
                 self._full_peak = max(self._full_peak,
                                       self._alloc.blocks_in_use)
+            if self._windows:
                 step_args.update(
                     window_num_blocks=self._ring.alloc.num_blocks,
                     window_blocks_in_use=self._ring.alloc.blocks_in_use,
@@ -4725,7 +4869,8 @@ class ServingEngine:
             decode_fn = self._get_decode_fn()
             span_kw = {**self._sampler_rows(dec),
                        **self._kv_walk(self._lengths[dec] + 1),
-                       **self._kv_reach(self._lengths[dec] + 1)}
+                       **self._kv_reach(self._lengths[dec] + 1),
+                       **self._state_args(len(dec), 0, len(dec))}
         with seg("step.decode.upload", phase):
             host, puts = self._host_operands(
                 "decode", tokens, self._lengths, self._bt(bt),
@@ -5121,7 +5266,10 @@ class ServingEngine:
                     (-(-(base + valid) // self.block_size)).sum()),
                 **self._sampler_rows(group),
                 **self._kv_reach((base + valid)[:len(group)],
-                                 valid[:len(group)])}
+                                 valid[:len(group)]),
+                **self._state_args(
+                    len(group), int((base[:len(group)] == 0).sum()),
+                    int(valid.sum()))}
         with seg("step.prefill.upload", phase):
             operands = [ids,
                         self._bt(bt, list(group) + [-1] * (j - len(group))),
@@ -5336,6 +5484,16 @@ class ServingEngine:
                     "blocks_in_use": alloc.blocks_in_use,
                     "peak_blocks_in_use": peak, "table_width": table_width}
 
+        if self._state:
+            # the paged kind beside the state kind (which has no blocks)
+            paged = "latent" if self._latent else "full"
+            return {paged: kind(self._alloc, int(self._pool_shape[0]),
+                                self._nbper, self._full_peak),
+                    "state": {"layers": self._state["layers"],
+                              "slots": self.slots,
+                              "bytes": self._state_bytes()},
+                    "expert_rows_absent": self._rows_absent,
+                    "refused": list(self._state_refusals)}
         layers = self._windows["layers"]
         return {
             "window": self._windows["window"],
@@ -5347,6 +5505,23 @@ class ServingEngine:
             **self._window_totals,
             "expert_rows_absent": self._rows_absent,
             "refused": list(self._window_refusals)}
+
+    def _state_bytes(self) -> int:
+        """Bytes of the state kind's leaves, all slots."""
+        return int(sum(self._cache[k].size * self._cache[k].dtype.itemsize
+                       for k in STATE_LEAVES))
+
+    def _kv_state(self) -> Dict[str, Any]:
+        """``stats()["kv_state"]`` (a model with a recurrent state a row)."""
+        nbytes = self._state_bytes()
+        return {"kind": "state", "layers": self._state["layers"],
+                "slots": self.slots, "bytes": nbytes,
+                "bytes_per_slot": nbytes // self.slots,
+                "leaves": {k: list(self._cache[k].shape)
+                           for k in STATE_LEAVES},
+                "kda": dict(self._program_meta.get("kda", {})),
+                **self._state_totals,
+                "refused": list(self._state_refusals)}
 
     def _kv_latent(self) -> Dict[str, Any]:
         """``stats()["kv_latent"]`` (a model with latent attention)."""
@@ -5445,7 +5620,13 @@ class ServingEngine:
             # by kind, the window blocks released behind the rows, the
             # spans' reach counters summed, and what such a model is
             # refused; None for any other model
-            "kv_kinds": self._kv_kinds() if self._windows else None,
+            "kv_kinds": self._kv_kinds()
+            if self._windows or self._state else None,
+            # a model with a recurrent state a row: its leaves, their bytes
+            # (whatever the rows' lengths), the resets, which body each
+            # program's delta rule lowered to and what such a model is
+            # refused; None for any other model
+            "kv_state": self._kv_state() if self._state else None,
             # a model with latent attention: the pool's kind, a token's
             # width and bytes, the block, what each program's read was
             # traced with, the spans' counters summed, and what such a

@@ -376,26 +376,44 @@ def decode_over_layers(body, x, blocks, cache_k, cache_v, num_layers,
         x, blocks, cache_k, cache_v, paged)
 
 
-#: the pool leaves and the table of each layer kind of a patterned model
-KIND_LEAVES = {"full": ("k", "v", "full"), "sliding": ("kw", "vw", "window")}
+#: the cache leaves and the table of each layer kind of a patterned model.
+#: ``full`` / ``sliding`` hold a key and a value a KV head a token
+#: (``ops/paged_kv.py`` "Layer kinds"), ``latent`` ONE leaf (a latent a token,
+#: under the full kind's table), ``kda`` no token at all: ``state`` and
+#: ``conv`` are indexed by ROW (``ops/paged_kv.py`` "The state kind") and its
+#: "table" is ``slot``, int32 ``[B]`` — the row of the leaves each row of
+#: the call owns (absent in a decode step, where row ``b`` IS row ``b``)
+KIND_LEAVES = {"full": ("k", "v", "full"), "sliding": ("kw", "vw", "window"),
+               "latent": ("latent", None, "full"),
+               "kda": paged_kv.STATE_LEAVES + ("slot",)}
 
 
 def scan_periods_cached(kinds, num_layers: int, step, x, blocks, cache,
-                        block_tables):
+                        block_tables, head: int = 0):
     """The layer loop of a patterned model (``kinds``: the period, e.g.
     ``("sliding",) * 3 + ("full",)``) over the block-paged pool: a
     ``lax.scan`` over PERIODS whose body is the period's layers written
     out, so that each layer's kind — rotated or not, how far it reaches,
     which leaves and which table it addresses — is static in the program,
     where a ``lax.cond`` on a traced kind would hold both branches and both
-    pools in every layer.  All layers have the same weight shapes, so the
+    pools in every layer.  Where all layers have the same weight shapes the
     ``[L, ...]`` stacks stay, and layer ``period * P + j`` is read out of
-    them at a traced index.  ``step(x, layer, ck, cv, index, table, kind)
-    -> (x, ck, cv, aux)`` with ``index`` the layer's place among its KIND's
-    layers (``ops/paged_kv.py`` "Layer kinds"); ``cache`` holds ``k`` /
-    ``v`` (full) and ``kw`` / ``vw`` (window), ``block_tables`` the tables
-    ``"full"`` / ``"window"``.  -> ``(x, cache, aux stacked [L, ...])``."""
+    them at a traced index.  BY KIND: where the kinds' weights differ in
+    shape (a gated delta-rule layer beside a latent one) ``blocks`` is
+    ``{kind: that kind's [L_kind, ...] stacks}`` — told from the flat stacks
+    by its values being trees — and a layer is read out of its kind's
+    stacks at its place among its kind's layers.  ``head``: that many
+    leading PERIODS are written out before the scan, with a static period
+    number (a model whose first layers differ from the rest: a leading
+    dense FFN).  ``step(x, layer, ck, cv, index, table, kind) -> (x, ck,
+    cv, aux)`` with ``index`` the layer's place among its KIND's layers
+    (``ops/paged_kv.py`` "Layer kinds"; stacks by kind add an eighth
+    argument, the layer's number in the model — an ``int`` in a head
+    period); ``cache`` and ``block_tables`` hold what ``KIND_LEAVES`` names
+    (a leaf or table a kind lacks is ``None``).  -> ``(x, cache, aux
+    stacked [L, ...])``."""
     p, n = len(kinds), num_layers
+    by_kind = all(isinstance(blocks.get(kind), dict) for kind in kinds)
 
     def body(carry, period):
         x, pools = carry
@@ -408,18 +426,35 @@ def scan_periods_cached(kinds, num_layers: int, step, x, blocks, cache,
             # one-period scan unrolls) as with a traced one.  (What PR 34
             # saw copied here was ``q_w``, transposed for a head split XLA
             # had folded into its dot: ``llama._attend_cached``)
-            layer = jax.tree_util.tree_map(
-                lambda a: jax.lax.dynamic_index_in_dim(
-                    a, period * p + j, keepdims=False), blocks)
-            x, pools[ck], pools[cv], aux = step(
-                x, layer, pools[ck], pools[cv],
-                period * len(same) + same.index(j), block_tables[table],
-                kind)
+            if by_kind:
+                index = period * len(same) + same.index(j)
+                layer = jax.tree_util.tree_map(
+                    lambda a: jax.lax.dynamic_index_in_dim(
+                        a, index, keepdims=False), blocks[kind])
+                more = (period * p + j,)
+            else:
+                layer = jax.tree_util.tree_map(
+                    lambda a: jax.lax.dynamic_index_in_dim(
+                        a, period * p + j, keepdims=False), blocks)
+                index, more = period * len(same) + same.index(j), ()
+            x, *leaves, aux = step(
+                x, layer, pools.get(ck), pools.get(cv), index,
+                block_tables.get(table), kind, *more)
+            pools.update((name, leaf) for name, leaf in zip((ck, cv), leaves)
+                         if name is not None)
             auxes.append(aux)
         return (x, pools), jax.tree_util.tree_map(
             lambda *a: jnp.stack(a), *auxes)
 
-    (x, cache), aux = jax.lax.scan(
-        body, (x, cache), jnp.arange(n // p, dtype=jnp.int32))
+    heads = []
+    for period in range(head):
+        (x, cache), aux = body((x, cache), period)
+        heads.append(aux)
+    if head < n // p:
+        (x, cache), aux = jax.lax.scan(
+            body, (x, cache), jnp.arange(head, n // p, dtype=jnp.int32))
+        aux = jax.tree_util.tree_map(
+            lambda a: a.reshape((n - head * p,) + a.shape[2:]), aux)
+        heads.append(aux)
     return x, cache, jax.tree_util.tree_map(
-        lambda a: a.reshape((n,) + a.shape[2:]), aux)
+        lambda *a: jnp.concatenate(a) if len(a) > 1 else a[0], *heads)

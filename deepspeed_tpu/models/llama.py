@@ -15,7 +15,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Optional
+from typing import Any, ClassVar, Optional
 
 import jax
 import jax.numpy as jnp
@@ -75,7 +75,10 @@ class LlamaConfig:
     #: layer rotates q and k and a query keeps its ``sliding_window`` newest
     #: keys, itself included; a ``"full"`` layer of a patterned model
     #: attends every key and takes NO rotation.  ``num_layers`` is a whole
-    #: number of periods.
+    #: number of periods.  ``"latent"`` (a latent layer INSIDE a pattern)
+    #: and ``"kda"`` (a gated delta-rule layer, whose state is a matrix a
+    #: head a ROW and no cache a token) are ``models/kimi_linear.py``'s:
+    #: its weights are stacked BY KIND.
     layer_kinds: tuple = ()
     sliding_window: int = 0
     #: the head is the token table transposed (no ``lm_head`` leaf)
@@ -105,6 +108,12 @@ class LlamaConfig:
     #: position ``p`` is multiplied by ``1 + beta * ln(1 + floor(p /
     #: period))``; ``None``: none
     query_temperature: Optional[tuple] = None
+    #: a latent layer's ``qk_rope_dim`` values of q and of the shared key
+    #: are rotated (``False``: used as they are, NoPE)
+    latent_rope: bool = True
+    #: the weights are stacked BY KIND of layer, not ``[L, ...]`` (set by
+    #: the family whose layers differ in shape)
+    by_kind: ClassVar[bool] = False
 
     def __post_init__(self):
         if self.qk_norm not in (False, True, "head"):
@@ -113,9 +122,18 @@ class LlamaConfig:
         if self.norm not in ("rms", "layernorm"):
             raise ValueError(f"norm={self.norm!r}: 'rms' or 'layernorm'")
         self.layer_kinds = tuple(self.layer_kinds)
-        if any(k not in ("sliding", "full") for k in self.layer_kinds):
-            raise ValueError(f"layer_kinds={self.layer_kinds!r}: 'sliding' "
-                             "or 'full' each")
+        if any(k not in ("sliding", "full", "latent", "kda")
+               for k in self.layer_kinds):
+            raise ValueError(f"layer_kinds={self.layer_kinds!r}: 'sliding', "
+                             "'full', 'latent' or 'kda' each")
+        if {"latent", "kda"} & set(self.layer_kinds) and (
+                {"sliding", "full"} & set(self.layer_kinds)
+                or not self.by_kind):
+            raise ValueError(
+                f"layer_kinds={self.layer_kinds!r}: 'latent' and 'kda' "
+                "layers have weights of their own shapes, stacked by kind "
+                "(models/kimi_linear.py), and are not mixed with 'sliding' "
+                "or 'full' ones")
         if self.layer_kinds and self.num_layers % len(self.layer_kinds):
             raise ValueError(
                 f"num_layers={self.num_layers} is not a whole number of "
@@ -130,19 +148,22 @@ class LlamaConfig:
         if self.query_temperature is not None:
             self.query_temperature = tuple(self.query_temperature)
         if self.latent:
-            if min(self.q_lora_rank, self.qk_nope_dim, self.qk_rope_dim) < 1 \
-                    or self.qk_rope_dim % 2:
+            if min(self.qk_nope_dim, self.qk_rope_dim) < 1 \
+                    or self.q_lora_rank < 0 or self.qk_rope_dim % 2:
                 raise ValueError(
-                    "latent attention (kv_lora_rank > 0) needs q_lora_rank, "
-                    "qk_nope_dim and an even qk_rope_dim")
+                    "latent attention (kv_lora_rank > 0) needs qk_nope_dim, "
+                    "an even qk_rope_dim and q_lora_rank >= 0 (0: a "
+                    "full-rank q_w)")
             if self.head_dim != self.qk_nope_dim + self.qk_rope_dim:
                 raise ValueError(
                     f"head_dim {self.head_dim} is not qk_nope_dim + "
                     f"qk_rope_dim ({self.qk_nope_dim} + {self.qk_rope_dim}):"
                     " pass head_width")
-            if self.qk_norm or self.layer_kinds:
-                raise ValueError("latent attention is built without "
-                                 "qk_norm and without a layer pattern")
+            if self.qk_norm or (self.layer_kinds
+                                and "latent" not in self.layer_kinds):
+                raise ValueError(
+                    "latent attention is built without qk_norm, and inside "
+                    "a layer pattern only as the pattern's 'latent' kind")
 
     @property
     def head_dim(self) -> int:
@@ -210,8 +231,10 @@ def latent_shapes(cfg: LlamaConfig):
     ``kv_a_proj_with_mqa``, ``kv_a_layernorm``, ``kv_b_proj``, ``o_proj``;
     a projection is stored ``[in, out]``)."""
     d, h = cfg.hidden_size, cfg.num_heads
-    return {"q_a_w": (d, cfg.q_lora_rank), "q_a_norm": (cfg.q_lora_rank,),
-            "q_b_w": (cfg.q_lora_rank, h * cfg.head_dim),
+    queries = {"q_a_w": (d, cfg.q_lora_rank), "q_a_norm": (cfg.q_lora_rank,),
+               "q_b_w": (cfg.q_lora_rank, h * cfg.head_dim)} \
+        if cfg.q_lora_rank else {"q_w": (d, h * cfg.head_dim)}
+    return {**queries,
             "kv_a_w": (d, cfg.latent_width),
             "kv_a_norm": (cfg.kv_lora_rank,),
             "kv_b_w": (cfg.kv_lora_rank,
@@ -488,9 +511,14 @@ def _latent_project(cfg: LlamaConfig, y, get, mm, rope):
     qk_rope_dim]`` at the tokens' positions."""
     b, t, _ = y.shape
     h, nope = cfg.num_heads, cfg.qk_nope_dim
-    cq = rms_norm(mm(y, "q_a_w", None), get("q_a_norm"), cfg.rms_eps)
-    q = mm(cq, "q_b_w", None).reshape(b, t, h, cfg.head_dim) \
-        .transpose(0, 2, 1, 3)
+    if cfg.q_lora_rank:
+        cq = rms_norm(mm(y, "q_a_w", None), get("q_a_norm"), cfg.rms_eps)
+        q = mm(cq, "q_b_w", None)
+    else:
+        # a full-rank projection; the head split moves the product
+        # (``_attend_cached``)
+        q = jax.lax.optimization_barrier(mm(y, "q_w", None))
+    q = q.reshape(b, t, h, cfg.head_dim).transpose(0, 2, 1, 3)
     kv = mm(y, "kv_a_w", None)
     c = rms_norm(kv[..., :cfg.kv_lora_rank], get("kv_a_norm"), cfg.rms_eps)
     kr = rope(kv[:, None, :, cfg.kv_lora_rank:])
@@ -524,8 +552,9 @@ def _latent_attention(cfg: LlamaConfig, layer, y, cos, sin):
     h = cfg.num_heads
     get, mm = layer_accessors(layer)
     qn, qr, c, kr = _latent_project(
-        cfg, y, get, mm, lambda a: apply_rope(a, cos, sin,
-                                              cfg.rope_interleaved))
+        cfg, y, get, mm, (lambda a: apply_rope(a, cos, sin,
+                                               cfg.rope_interleaved))
+        if cfg.latent_rope else (lambda a: a))
     w_uk, w_uv = _latent_up(cfg, get("kv_b_w"), y.dtype)
     kn = jnp.einsum("bsc,chn->bhsn", c, w_uk)
     v = jnp.einsum("bsc,chv->bhsv", c, w_uv)
@@ -554,8 +583,9 @@ def _latent_cached(cfg: LlamaConfig, y, get, mm, pool, pos, block_tables,
 
     b, t, _ = y.shape
     rank = cfg.kv_lora_rank
-    qn, qr, c, kr = _latent_project(cfg, y, get, mm,
-                                    lambda a: _rope_cached(cfg, a, pos))
+    qn, qr, c, kr = _latent_project(
+        cfg, y, get, mm, (lambda a: _rope_cached(cfg, a, pos))
+        if cfg.latent_rope else (lambda a: a))
     pad = pool.shape[-1] - cfg.latent_width
     pool = paged_window_update(
         pool, jnp.pad(jnp.concatenate([c[:, None], kr], axis=-1),
@@ -853,7 +883,7 @@ def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
                 block_tables=block_tables, chunk_valid=chunk_valid,
                 layer=layer),
             x, params["blocks"], cache[first], cache.get("v"),
-            cfg.num_layers, probe="q_a_w" if cfg.latent else "q_w",
+            cfg.num_layers, probe="q_a_w" if cfg.q_lora_rank else "q_w",
             paged=paged)
     else:
         # mixtral's MoE FFN needs the whole layer dict: scan path only
@@ -928,10 +958,13 @@ def tp_rules(cfg: LlamaConfig, abstract_params: PyTree) -> PyTree:
         # split by head: what the uncached forward runs under a tp mesh
         # (the SERVING engine refuses a tp mesh for a latent model)
         blocks = rules["blocks"]
-        for name in ("q_w", "k_w", "v_w"):
+        for name in ("k_w", "v_w"):
             del blocks[name]
-        blocks.update(q_a_w=P(), q_a_norm=P(), kv_a_w=P(), kv_a_norm=P(),
-                      q_b_w=P(None, None, TP_AXIS),
+        if cfg.q_lora_rank:             # (a full-rank query keeps ``q_w``)
+            del blocks["q_w"]
+            blocks.update(q_a_w=P(), q_a_norm=P(),
+                          q_b_w=P(None, None, TP_AXIS))
+        blocks.update(kv_a_w=P(), kv_a_norm=P(),
                       kv_b_w=P(None, None, TP_AXIS))
     return rules
 

@@ -115,6 +115,11 @@ class MixtralConfig(L.LlamaConfig):
     #: still scores all ``num_experts`` and the expert layer returns the
     #: held experts' partial sum (``moe/routed.py``).  ``None``: all.
     experts_held: Optional[tuple] = None
+    #: a per-expert SELECTION bias (leaf ``gate_bias [L, E]``): the top-k is
+    #: taken of ``scores + bias``, the weights stay the unbiased scores
+    router_bias: bool = False
+    #: a factor on the chosen (renormalised) router weights
+    routed_scale: float = 1.0
 
     def __post_init__(self):
         super().__post_init__()
@@ -311,6 +316,8 @@ def init_params(cfg: MixtralConfig, rng) -> PyTree:
     for k in ("w1", "w2", "w3"):
         del blocks[k]
     blocks["gate_w"] = normal(keys[0], (l, d, e))
+    if cfg.router_bias:
+        blocks["gate_bias"] = normal(jax.random.fold_in(rng, 19), (l, e))
     e = cfg.experts_here
     blocks["experts_w1"] = normal(keys[1], (l, e, d, f))
     blocks["experts_w3"] = normal(keys[2], (l, e, d, f))
@@ -577,7 +584,9 @@ def _routed(cfg: MixtralConfig, layer, y, live=None, stacks=None,
         live=live, layer=layer["layer_index"] if whole else None,
         kernel=whole or _expert_kernel(layer), choices=choices,
         held=cfg.experts_held, score=cfg.router_score, act=cfg.ffn_act,
-        router_x=router_x, balance=train, choice_major=train)
+        router_x=router_x, balance=train, choice_major=train,
+        bias=layer["gate_bias"] if cfg.router_bias else None,
+        scale=cfg.routed_scale)
     if cfg.shared_experts:
         out = out + _shared(cfg, layer, y)
     return out, (tuple(record) if choices or train else record[0])
@@ -735,6 +744,8 @@ def tp_rules(cfg: MixtralConfig, abstract_params: PyTree) -> PyTree:
     for k in ("w1", "w2", "w3"):
         del blocks[k]
     blocks["gate_w"] = P()
+    if cfg.router_bias:
+        blocks["gate_bias"] = P()
     blocks["experts_w1"] = P(None, EP_AXIS, None, TP_AXIS)
     blocks["experts_w3"] = P(None, EP_AXIS, None, TP_AXIS)
     blocks["experts_w2"] = P(None, EP_AXIS, TP_AXIS, None)

@@ -83,10 +83,14 @@ ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def route(y2d, gate_w, k: int, renormalize: bool, score: str = "softmax",
-          scores: bool = False):
+          scores: bool = False, bias=None, scale: float = 1.0):
     """Router of ``[T, D]`` tokens: float32 logits and scores over ALL
     experts — their softmax, or with ``score="sigmoid"`` each logit's own
-    sigmoid — then top-k (ties to the lower expert id).
+    sigmoid — then top-k (ties to the lower expert id).  ``bias`` (float
+    ``[E]``): a per-expert SELECTION bias — the top-k is taken of ``scores +
+    bias``, the weights are the unbiased scores at the chosen (it chooses
+    and does not weigh).  ``scale``: a factor on the chosen weights, after
+    the renormalisation.
     -> (weights float32 [T, k], experts int32 [T, k]); with ``scores`` a
     third, the float32 scores ``[T, E]`` (what a balance loss averages)."""
     logits = jnp.dot(y2d, _dense(gate_w, y2d.dtype),
@@ -97,9 +101,15 @@ def route(y2d, gate_w, k: int, renormalize: bool, score: str = "softmax",
         all_p = jax.nn.softmax(logits, axis=-1)
     else:
         raise ValueError(f"router score {score!r}: 'softmax' or 'sigmoid'")
-    top_p, top_e = jax.lax.top_k(all_p, k)
+    if bias is None:
+        top_p, top_e = jax.lax.top_k(all_p, k)
+    else:
+        _, top_e = jax.lax.top_k(all_p + bias.astype(jnp.float32), k)
+        top_p = jnp.take_along_axis(all_p, top_e, axis=-1)
     if renormalize:
         top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if scale != 1.0:
+        top_p = top_p * scale
     if scores:
         return top_p, top_e.astype(jnp.int32), all_p
     return top_p, top_e.astype(jnp.int32)
@@ -119,7 +129,8 @@ def routed_ffn(y, gate_w, w1, w3, w2, k: int, renormalize: bool,
                choices: bool = False, held=None,
                score: str = "softmax", act: str = "silu", router_x=None,
                balance: bool = False,
-               choice_major: bool = False) -> Tuple[jnp.ndarray, ...]:
+               choice_major: bool = False, bias=None,
+               scale: float = 1.0) -> Tuple[jnp.ndarray, ...]:
     """Gated (``act``: SwiGLU, ReGLU) experts over ``y [..., D]``: ``gate_w [D, E]``, ``w1``/``w3``
     ``[E, D, F]``, ``w2 [E, F, D]`` — or, with ``layer`` (traced index),
     the whole stacks ``[L, E, ..]``.  No capacity, no drop.  ``kernel``
@@ -137,6 +148,8 @@ def routed_ffn(y, gate_w, w1, w3, w2, k: int, renormalize: bool,
     record is ``RECORD_HELD``, its first three columns over the held
     experts.  ``score``: the router's (:func:`route`).  ``router_x``: the
     tokens the router reads, ``y``'s shape (``None``: ``y`` itself).
+    ``bias`` / ``scale``: the router's selection bias and the factor on its
+    chosen weights (:func:`route`).
     ``balance`` adds a last result, this layer's balance term (module
     docstring; float32 scalar, 1 under even routing).  ``choice_major``:
     the layout the combine gathers the pairs in — ``[k, T, D]``, what a
@@ -153,7 +166,7 @@ def routed_ffn(y, gate_w, w1, w3, w2, k: int, renormalize: bool,
     with jax.named_scope("layer/moe/route"):
         rx = x if router_x is None else router_x.reshape(-1, d)
         top_p, top_e, *all_p = route(rx, gate_w, k, renormalize, score,
-                                     scores=balance)
+                                     scores=balance, bias=bias, scale=scale)
         flat_e = top_e.reshape(-1)                               # [T*k]
         if balance:
             share = jnp.zeros(e, jnp.float32).at[flat_e].add(1.0 / (t * k))

@@ -72,6 +72,26 @@ Layout contract — the WHOLE stacked pool, addressed in place:
    takes :func:`latent_block_tokens` for this kind, 512 tokens of 768 B
    (PERF.md section 6, PR 39, has the table).
 
+ - **The state kind.**  A model with gated delta-rule layers
+   (``models/kimi_linear.py``, ``ops/delta_rule.py``) keeps, for each such
+   layer, no token at all: a row's whole past is a float32 matrix a head,
+   ``state [L_kda, rows, H, dk, dv]`` (2 MiB a row a layer at 32 x 128 x
+   128, whatever the row's length), and the last ``K - 1`` inputs of its
+   short convolutions, ``conv [L_kda, rows, 1, K - 1, channels]``.  These
+   leaves ride in the SAME cache tree as the paged leaves — donated, carried
+   through the layer loop and handed back in the same buffers — but are
+   indexed by ROW (the serving engine's slot), not by block: no block ids,
+   no table, no allocator, nothing to share, swap or evict.  "Block at dim
+   1, heads at dim 2" holds in form (rows at dim 1, heads — a unit axis for
+   ``conv`` — at dim 2) and :func:`pack_pool` is NOT applied to them (there
+   is no block to lane-pack; the engine skips them by name,
+   :data:`STATE_LEAVES`).  A decode step's row ``b`` is row
+   ``b`` of the leaves and the kernel updates the matrices in place
+   (``kda_step``, ``input_output_aliases``); a prefill call names its rows'
+   slots (``block_tables["slot"]``: gathered, advanced by the chunked form,
+   scattered back at ``[layer, slot]``, a pad row's slot out of range and
+   dropped).
+
 **Layout** (what "in place" takes on a TPU).  A Mosaic kernel reads its
 operand row-major — ``[L][NB][HKV][...]``, a block's tiles contiguous —
 and tiles the last two dims (16 x 128 for bf16).  XLA:TPU's own layout for
@@ -394,6 +414,9 @@ def whole_pool(pool, layer):
 
 #: lanes of a TPU vector register: the minor dim a pool's blocks are packed to
 LANES = 128
+#: the cache leaves of the state kind (module docstring): indexed by ROW — a
+#: serving slot — never by block
+STATE_LEAVES = ("state", "conv")
 
 
 def latent_pool_width(width: int) -> int:

@@ -794,7 +794,10 @@ def test_window_walks_compile_at_the_rag_chat_cells_shapes(kind, rows, t,
 #: ``commanda.*`` and ``llama.*`` (dense, GQA 4 / 2) — are taken on the tree
 #: of PR 50: each layer's q, k and v products (and Keye's indexer queries)
 #: pass an ``optimization_barrier`` before their head split, and nothing
-#: else of the text moved
+#: else of the text moved.  ``smallthinker.*`` on the PARENT of PR 51 (``23ed2e6``),
+#: whose stacks-by-kind change of ``cached.scan_periods_cached``, selection
+#: bias and scale of ``routed.route`` and full-rank / unrotated latent
+#: queries of ``llama._latent_project`` move none of these
 OLD_PROGRAMS = {
     "opt.decode": "b52e4bc5d86a603e", "opt.prefill": "0fe6ed036104ea84",
     "mixtral.decode": "f936948485165684",
@@ -805,6 +808,8 @@ OLD_PROGRAMS = {
     "commanda.prefill": "82fe8e3e6324c1c8",
     "mistral4.decode": "2d73686d9b1d63a7",
     "mistral4.prefill": "33a83a8b55a6460a",
+    "smallthinker.decode": "e2cd920468027b8b",
+    "smallthinker.prefill": "a9c1689a9b5317d9",
     "gpt2.decode": "56db7e72e98e31c7", "gpt2.prefill": "aaca73ee22c8ad8f",
     "llama.decode": "12867882289301b8", "llama.prefill": "a7ecf11445f38e19",
     "bloom.decode": "05eea69436e8aefe", "bloom.prefill": "9e7f54fb3e7d2def"}
@@ -849,6 +854,13 @@ def _old_family(name):
         return mixtral.build(dataclasses.replace(
             mixtral.MixtralConfig.command_a_plus(), num_heads=4,
             num_kv_heads=2, sliding_window=64, shared_experts=2,
+            **{**small, "num_layers": 4}))
+    if name == "smallthinker":
+        # [full, sliding x 3] on two pool kinds, ReGLU experts top-6, the
+        # router fed the attention's input; trained dropless, served here
+        return mixtral.build(dataclasses.replace(
+            mixtral.MixtralConfig.smallthinker_21b_a3b(), num_heads=4,
+            num_kv_heads=2, head_width=None, sliding_window=64,
             **{**small, "num_layers": 4}))
     return mixtral.build(dataclasses.replace(
         mixtral.MixtralConfig.keye_vl2_30b_a3b(), num_heads=4,
@@ -1250,3 +1262,120 @@ def test_compiled_latent_serving_programs_fit_and_alias_the_pool(
         mem = compiled.memory_analysis()
         assert mem.temp_size_in_bytes < 1 << 30, (kernel, mem)
         assert mem.alias_size_in_bytes >= pool_bytes, (kernel, mem)
+
+
+# ------------------------------------------------- the state kind (PR 51)
+#: the state-decode cell (kimilinear-statedecode-closed): 192 slots x 4,352
+#: positions, 6 KDA layers of 32 heads x 128 x 128 beside 2 latent layers
+STATE = dict(slots=192, ctx=4352, layers=6, heads=32, width=128)
+
+
+def test_kda_kernels_compile_at_the_state_cells_shapes(one_chip):
+    """Mosaic's own compile, for a described v5e, of ``kda_step`` on the
+    whole ``[6, 192, 32, 128, 128]`` float32 state leaf — aliased in and out,
+    no temporary beside it — and of ``kda_chunk_state`` on a ``[4, 128]``
+    prefill call's rows."""
+    from deepspeed_tpu.ops import delta_rule as dr
+
+    c = STATE
+    h, w, rows = c["heads"], c["width"], c["slots"]
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    leaf = sds(c["layers"], rows, h, w, w)
+    step = jax.jit(
+        lambda q, k, v, g, b, leaf, l: dr.step(q, k, v, g, b, leaf, l,
+                                               kernel=True, interpret=False),
+        donate_argnums=(5,)).lower(
+            sds(rows, h, w), sds(rows, h, w), sds(rows, h, w),
+            sds(rows, h, w), sds(rows, h), leaf, sds(dtype=jnp.int32))
+    assert 'kernel_name = "kda_step"' in step.as_text()
+    mem = step.compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= 4 * int(np.prod(leaf.shape))
+    assert mem.temp_size_in_bytes < 64 << 20, mem
+    chunk = jax.jit(lambda *a: dr.chunked(*a, kernel=True, interpret=False)) \
+        .lower(sds(4, h, 128, w), sds(4, h, 128, w), sds(4, h, 128, w),
+               sds(4, h, 128, w), sds(4, h, 128), sds(4, h, w, w))
+    assert 'kernel_name = "kda_chunk_state"' in chunk.as_text()
+    assert chunk.compile().memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+@pytest.mark.limit(240)
+def test_state_cells_programs_alias_the_whole_cache(as_on_tpu, one_chip,
+                                                    monkeypatch):
+    """The state-decode cell's decode and prefill programs (Kimi Linear at
+    its published widths, this chip's share), compiled for a described v5e:
+    the whole cache tree — latent pool, state and convolution tails, 4.64 GB
+    — is aliased in and out, the three kernels of each program are there,
+    and no temporary comes near a layer's slice of the state."""
+    import json
+    import os
+
+    from chipbench.families import kimi_linear
+    from deepspeed_tpu.moe import grouped_matmul
+    from deepspeed_tpu.ops import delta_rule, paged_kv
+
+    monkeypatch.setattr(grouped_matmul, "interpret_kernels", lambda: False)
+    monkeypatch.setattr(delta_rule, "interpret_kernels", lambda: False)
+    monkeypatch.setattr(delta_rule, "on_tpu", lambda: True)
+    root = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir)
+    with open(os.path.join(root, "chipbench", "configs",
+                           "kimi-linear-48b-a3b.json")) as f:
+        config = json.load(f)
+    config.pop("rehearse")
+    spec = kimi_linear.build(config)
+    fwd = spec.decode_hooks["forward_cached"]
+    c = STATE
+    slots = c["slots"]
+    block = paged_kv.latent_block_tokens(576, 2, c["ctx"])
+    nbper = paged_kv.blocks_for(c["ctx"], block)
+    assert (block, nbper) == (256, 17)
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return sds(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    params = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda: jax.tree_util.tree_map(
+            lambda a: a.astype(jnp.bfloat16),
+            spec.init_fn(jax.random.PRNGKey(0)))))
+    cache = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda: spec.decode_hooks["init_cache"](
+            1 + slots * nbper, block, jnp.bfloat16, state_rows=slots)))
+    assert {k: v.shape for k, v in cache.items()} == {
+        "latent": (2, 3265, 1, 256, 640), "state": (6, 192, 32, 128, 128),
+        "conv": (6, 192, 1, 3, 12288)}
+    assert cache["state"].dtype == jnp.float32
+
+    def decode_step(params, cache, tokens, lengths, bt):
+        logits, cache, rec = fwd(params, tokens[:, None], cache, 0,
+                                 lengths=lengths, block_tables={"full": bt},
+                                 routing=True)
+        return jnp.argmax(logits, -1).astype(jnp.int32), cache, rec
+
+    def prefill(params, cache, ids, bt, slot, base, valid):
+        logits, cache, rec = fwd(
+            params, ids, cache, base, lengths=valid,
+            block_tables={"full": bt, "slot": slot}, routing=True)
+        return jnp.argmax(logits, -1).astype(jnp.int32), cache, rec
+
+    programs = {
+        ("kda_step", "paged_latent_attn"): (decode_step, (
+            params, cache, i32(slots), i32(slots), i32(slots, nbper))),
+        ("kda_chunk_state", "paged_latent_prefill"): (prefill, (
+            params, cache, i32(4, 128), i32(4, nbper), i32(4), i32(4),
+            i32(4)))}
+    cache_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                      for a in cache.values())
+    for kernels, (fn, args) in programs.items():
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+        text = compiled.as_text()
+        for kernel in kernels + ("moe_gmm",):
+            assert kernel in text, kernel
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= cache_bytes, (kernels, mem)
+        # one layer's slice of the state is 403 MB
+        assert mem.temp_size_in_bytes < 300 << 20, (kernels, mem)

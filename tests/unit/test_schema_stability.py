@@ -69,6 +69,10 @@ ENGINE_STATS_KEYS = frozenset({
     # width and bytes, the block, the reads' paths, the counters, the
     # refusals (None otherwise)
     "kv_latent",
+    # PR 51: a model with a recurrent state a slot: its leaves, their bytes,
+    # the resets, which body each program's delta rule lowered to, the
+    # refusals (None otherwise)
+    "kv_state",
     # PR 28: routed (token, expert) rows and experts touched, summed over
     # layers and program calls; 0 for a dense model
     "moe_expert_rows", "moe_experts_touched",
