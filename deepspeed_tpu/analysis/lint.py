@@ -179,7 +179,7 @@ _METRIC_CTORS = frozenset({"counter", "gauge", "histogram"})
 _METRIC_NAMESPACES = ("serving_", "train_", "inference_")
 _METRIC_LABEL_KEYS = frozenset(
     {"replica", "direction", "timer", "slo_class", "slo", "phase",
-     "lock", "tier", "mode", "cause"})
+     "lock", "tier", "mode", "cause", "shape"})
 _METRIC_PARAM_KWARGS = frozenset({"help", "monitor_name", "buckets"})
 
 #: substrings marking a function as a sanctioned blocking-transfer helper

@@ -2,8 +2,8 @@
 contracts the serving and training engines promise.
 
 The paged serving stack's performance story rests on a *compile budget*:
-a whole serving trace is exactly 1 prefill + 1 decode program, a
-speculative trace at most 3.  Today
+a whole serving trace is exactly 1 decode program + a prefill program a
+rung of the prefill ladder, a speculative trace one more.  Today
 the tests assert ``compile_count`` after the fact — but ``compile_count``
 only counts programs the engine *knowingly* built; a silent retrace
 inside one of them (a weak-type flip, a new input shape leaking through,

@@ -39,8 +39,9 @@ import math
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 __all__ = ["ModelGeom", "ServingKnobSpace", "kv_pool_bytes",
-           "compile_budget", "workload_space", "DEFAULT_DOMAINS",
-           "CONSTRAINTS", "BASE_SERVING_CONFIG", "DECODE_STEPS_MAX"]
+           "compile_budget", "prefill_rungs", "workload_space",
+           "DEFAULT_DOMAINS", "CONSTRAINTS", "BASE_SERVING_CONFIG",
+           "DECODE_STEPS_MAX"]
 
 #: the verify kernel's widest speculative window (K+1 <= this);
 #: mirrored from ops/decode_attention.py without importing jax
@@ -155,9 +156,29 @@ def kv_pool_bytes(config: Dict[str, Any], geom: ModelGeom) -> int:
     return resolved_num_blocks(config) * block_bytes(config, geom)
 
 
+def prefill_rungs(config: Dict[str, Any]) -> int:
+    """Mirror of the ctor's prefill ladder (``inference/serving.py
+    prefill_ladder``): the prefill programs of a candidate — ``[prefill_batch,
+    prefill_chunk]`` and, for a row alone in its call, ``[1, prefill_batch *
+    prefill_chunk]`` where that row stays within the cache; ONE with a
+    resident window or a batch of 1.  (The prefill kernel's VMEM plan, which
+    can also refuse the wide row, needs the model's heads and is not
+    mirrored.)"""
+    batch = int(config["prefill_batch"])
+    if config.get("resident_window_blocks") or batch < 2:
+        return 1
+    bs = int(config.get("block_size") or 0)
+    if config.get("max_seq_len") and bs > 0:   # (bs < 1: another predicate's)
+        cache = -(-int(config["max_seq_len"]) // bs) * bs
+        if batch * int(config["prefill_chunk"]) > cache:
+            return 1
+    return 2
+
+
 def compile_budget(config: Dict[str, Any]) -> int:
-    """Mirror of the ctor's compiled-program budget: 2 (prefill +
-    decode / n-gram verify), + 2 swap programs with a host tier.  (A draft
+    """Mirror of the ctor's compiled-program budget: 1 (decode / n-gram
+    verify) + a prefill program a rung of the ladder
+    (:func:`prefill_rungs`), + 2 swap programs with a host tier.  (A draft
     model would add 1; the space searches the zero-extra-programs n-gram
     proposer.)
 
@@ -178,7 +199,7 @@ def compile_budget(config: Dict[str, Any]) -> int:
     fixed-shape operands of the SAME programs, and a sampling engine's
     rejection verifier replaces the greedy matcher inside the one verify
     program."""
-    return 4 if config.get("host_blocks") else 2
+    return (3 if config.get("host_blocks") else 1) + prefill_rungs(config)
 
 
 # ---------------------------------------------------------- constraints

@@ -34,10 +34,16 @@ serving optimisations on top of continuous batching:
    its generated tokens folded into the prompt (greedy decoding makes the
    recompute token-exact, and its re-prefill usually hits its own cached
    blocks).
- - **Chunked prefill**: prompts advance through the cache in fixed-size
-   windows (``prefill_chunk`` tokens, ``prefill_batch`` sequences per
-   call) interleaved with decode steps — the whole serving loop compiles
-   exactly **1 prefill + 1 decode program** regardless of trace shape.
+ - **Chunked prefill**: prompts advance through the cache in calls of a
+   fixed BUDGET of tokens (``prefill_batch * prefill_chunk``) interleaved
+   with decode steps.  A call holding several ready rows is
+   ``[prefill_batch, prefill_chunk]``; one whose row is alone gives the
+   pad rows' tokens to it (``[1, prefill_batch * prefill_chunk]``:
+   :func:`prefill_ladder`), so the weights are read once per budget of
+   REAL prompt tokens.  The whole serving loop compiles exactly **1
+   decode program + 1 prefill program a rung** (two rungs), all built and
+   run once (on pad rows) at the first prefill call, regardless of trace
+   shape.
 
 Scheduling is iteration-level and strict-FIFO as before: every iteration
 admits waiting requests into free slots (gated on block availability —
@@ -199,9 +205,10 @@ leaves BY LAYER KIND (``ops/paged_kv.py`` "Layer kinds"), each kind with
 its own block ids, allocator and table.  The full kind is every model's
 pool: ``_alloc`` / ``_tables`` / ``_held``, admission and preemption as
 they are.  The window kind (``_ring``: ``inference/paged.py WindowRing``)
-is a RING of ``ceil((window + prefill_chunk) / block_size) + 1`` entries a
-slot, sized for every slot at once, so it never runs dry and never
-preempts: ``WindowRing.advance``, called under ``_ensure_blocks`` before
+is a RING of ``ceil((window + T) / block_size) + 1`` entries a slot (``T``
+the widest row of a prefill call, ``prefill_batch * prefill_chunk`` where
+the wide rung is built), sized for every slot at once, so it never runs
+dry and never preempts: ``WindowRing.advance``, called under ``_ensure_blocks`` before
 every dispatch, releases the blocks wholly behind ``position - window`` —
 in the step that passes them — and allocates up to the dispatch's last
 position; a released, preempted or finished slot frees both kinds.  The
@@ -281,7 +288,7 @@ import tempfile
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -505,6 +512,23 @@ SLO_PRIORITY = {"realtime": 2, "interactive": 1, "standard": 0, "batch": -1,
 #: knob is a module constant rather than a ctor parameter to keep the
 #: config surface at a single ``resident_window_blocks`` dial.
 _LANDMARK_BLOCKS = 1
+
+
+def prefill_ladder(batch: int, chunk: int, refuses):
+    """The shapes ``(rows, width)`` a prefill call's budget of ``batch *
+    chunk`` tokens is cut into: ``(batch, chunk)`` and, for a row that is
+    alone in its group, ``(1, batch * chunk)`` — unless ``refuses(width)``
+    names a reason.  -> ``(rungs, why)``: ``why`` is what refused the wide
+    row (None: it is there, or ``batch`` is 1).  The rungs between the two
+    (``(batch / 2, 2 * chunk)``, ..) are not built: every rung is a program
+    of its own, traced and lowered at set-up, and that is seconds of every
+    engine's start (docs/inference.md "Chunked prefill")."""
+    rungs, why = [(batch, chunk)], None
+    if batch > 1:
+        why = refuses(batch * chunk)
+        if why is None:
+            rungs.append((1, batch * chunk))
+    return rungs, why
 
 
 class RequestHandle:
@@ -805,10 +829,19 @@ class ServingEngine:
                     ``1 + slots * ceil(max_seq_len/block_size)`` (no
                     oversubscription); smaller pools oversubscribe and rely
                     on prefix eviction + preemption.
-    prefill_chunk:  prefill window length: prompts advance through the
-                    one compiled prefill program this many tokens a call.
-    prefill_batch:  sequences per prefill call; short groups pad with
-                    scratch-routed rows.
+    prefill_chunk:  the NARROWEST row of a prefill call, in tokens: what a
+                    prompt advances by in a call that holds
+                    ``prefill_batch`` of them.
+    prefill_batch:  the MOST sequences a prefill call holds.  The product
+                    ``prefill_batch * prefill_chunk`` is a call's budget of
+                    tokens: a row that is alone in its call runs ``[1,
+                    prefill_batch * prefill_chunk]`` and advances by what
+                    the pad rows of ``[prefill_batch, prefill_chunk]``
+                    would have wasted.  The wide rung exists only where
+                    the shapes say it is sound (a row within the cache,
+                    the prefill kernel's query rows a KV head, no resident
+                    window); the constructor's log line and
+                    ``stats()["prefill_shapes"]`` name the rungs built.
     prefix_caching: enable the block trie.  Default ``None`` = on, unless
                     the model mixes sliding-window and full layers (decode
                     hook ``window_layers``): a shared prefix is of no use
@@ -1169,6 +1202,19 @@ class ServingEngine:
         self._landmark_blocks = _LANDMARK_BLOCKS \
             if self.resident_window_blocks else 0
 
+        # ----- the prefill call is a BUDGET of prefill_batch x prefill_chunk
+        # tokens, cut into the fewest rows that hold a group's ready rows
+        # (:meth:`_run_prefill`): every rung of the ladder is a program
+        #: ``(rows, width)`` of each prefill program, ``(prefill_batch,
+        #: prefill_chunk)`` first, the wide row last; and what refused
+        #: the wide row (None: it is there, or the batch is 1)
+        self._rungs, self._ladder_stop = prefill_ladder(
+            self.prefill_batch, self.prefill_chunk,
+            functools.partial(self._refuse_rung, engine))
+        #: the widest row of any prefill call: what the window kind's ring
+        #: and a row's blocks ahead of a call are sized by
+        self._prefill_width = self._rungs[-1][1]
+
         if self._windows:
             # what a model with window layers is REFUSED, each by name, and
             # whether this construction asked for it (module docstring)
@@ -1227,7 +1273,7 @@ class ServingEngine:
                 min_need = min(
                     min_need,
                     1 + self._landmark_blocks + self.resident_window_blocks
-                    + blocks_for(self.prefill_chunk, self.block_size))
+                    + blocks_for(self._prefill_width, self.block_size))
             if num_blocks < min_need:
                 raise ValueError(
                     f"num_blocks {num_blocks} cannot hold one full sequence "
@@ -1370,7 +1416,7 @@ class ServingEngine:
                 # the window kind's allocator and ring tables, a whole ring
                 # a slot (``inference/paged.py WindowRing``)
                 self._ring = WindowRing(
-                    self.slots, self._windows["window"], self.prefill_chunk,
+                    self.slots, self._windows["window"], self._prefill_width,
                     self.block_size)
                 kinds["window_blocks"] = self._ring.alloc.num_blocks
             if self._state:
@@ -1382,14 +1428,7 @@ class ServingEngine:
                 self._paged_leaves(jax.eval_shape(mk_pool)))[0].dtype).name
         # the hook's (logical) shape [L, NB, HKV, bs, hd]; the pool itself
         # is held lane-packed (:meth:`_commit_pool`)
-        # (of its widest leaf, K or V: a sparse-attention family's third
-        # leaf, one narrow head of indexer keys, is smaller)
-        self._pool_shape = max(
-            (tuple(paged_kv.pool_payload(leaf).shape)
-             for leaf in jax.tree_util.tree_leaves(
-                 self._paged_leaves(jax.eval_shape(mk_pool)),
-                 is_leaf=paged_kv.is_quantized_pool)),
-            key=lambda shape: int(np.prod(shape)))
+        self._pool_shape = tuple(self._widest_leaf(mk_pool).shape)
         #: the tile of the decode / verify walk at this pool's stored
         #: shapes: blocks a loop iteration, score columns a softmax update
         #: (``ops/decode_attention.py`` ``walk_tile_blocks``; None for a
@@ -1480,7 +1519,11 @@ class ServingEngine:
                 "from it")
 
         # compiled programs, built on first use
-        self._prefill_fn = None
+        #: the prefill program of each rung of the ladder, all built with
+        #: the first (:meth:`_get_prefill_fn`)
+        self._prefill_fns: Dict[Tuple[int, int], Any] = {}
+        #: whether each has run once, on pad rows (:meth:`_warm_prefill`)
+        self._prefill_warm = False
         self._decode_fn = None
         self._verify_fn = None
         self._draft_fn = None
@@ -1498,6 +1541,9 @@ class ServingEngine:
         self.debug_checks = bool(debug_checks)
         self.compile_budget = 3 if self.spec_tokens and draft is not None \
             else 2
+        # one prefill program a rung of the ladder, all built (and run once
+        # on pad rows) with the first of them
+        self.compile_budget += len(self._rungs) - 1
         if self.host_blocks:
             # the tiered KV swap pair: kv_demote (block gather) +
             # kv_promote (block scatter), both fixed-shape at swap_batch —
@@ -1667,6 +1713,18 @@ class ServingEngine:
             "window, the ONLY sync of the fused decode path")
         self._c_prefill_calls = m.counter(
             "serving_prefill_calls_total", "prefill program invocations")
+        self._c_prefill_shapes = {
+            rung: m.counter(
+                "serving_prefill_calls_by_shape_total",
+                "prefill program invocations, by the call's rows x width",
+                shape=self._rung_name(rung))
+            for rung in self._rungs}
+        self._c_prefill_tokens = m.counter(
+            "serving_prefill_call_tokens_total",
+            "real prompt tokens the prefill calls advanced their rows by")
+        self._c_prefill_budget = m.counter(
+            "serving_prefill_call_budget_tokens_total",
+            "tokens the prefill calls had room for (rows x width, summed)")
         self._c_moe_rows = m.counter(
             "serving_moe_expert_rows_total",
             "(token, expert) rows routed by decode and prefill programs, "
@@ -1914,7 +1972,10 @@ class ServingEngine:
             f"num_blocks={num_blocks}, "
             f"chunked prefill (chunk={self.prefill_chunk}, prefix_cache="
             f"{self._prefix is not None})"
-            + f", prefill_batch={self.prefill_batch}"
+            + f", prefill_batch={self.prefill_batch}, prefill calls "
+            + " / ".join(map(self._rung_name, self._rungs))
+            + (f" (no wider rows: {self._ladder_stop})"
+               if self._ladder_stop else "")
             + (f", speculative K={self.spec_tokens} "
                f"({'draft ' + self._draft.module.name if self._draft else 'n-gram'})"
                if self.spec_tokens else "")
@@ -2118,6 +2179,49 @@ class ServingEngine:
     @property
     def compile_count(self) -> int:
         return len(self.compiled_programs)
+
+    @staticmethod
+    def _rung_name(rung) -> str:
+        """``"<rows>x<width>"``: a prefill program's shape as the sentry,
+        the ``prefill`` spans and ``stats()["prefill_shapes"]`` name it."""
+        return f"{rung[0]}x{rung[1]}"
+
+    def _refuse_rung(self, engine, width: int) -> Optional[str]:
+        """Why no prefill program of this engine has rows ``width`` wide
+        (None: one has), from what the constructor can see: the shapes,
+        never a family's name."""
+        if width > self._cache_len:
+            return f"a row of {width} tokens passes the cache " \
+                f"({self._cache_len})"
+        if self.resident_window_blocks:
+            return "a resident window slides once a call, so a call's " \
+                "width is part of what its queries see"
+        if self._latent:
+            return None        # the latent kernel tiles its own queries
+        dtype = engine._config.jnp_dtype
+        kinds = {"window_blocks": 2} if self._windows else \
+            {"state_rows": 1} if self._state else {}
+        leaf = self._widest_leaf(lambda: self._init_cache(
+            2, self.block_size, dtype, **kinds))
+        hkv, bs, hd = map(int, leaf.shape[2:])
+        heads = int(getattr(engine.module.model_config, "num_heads", hkv))
+        if not decode_attention.prefill_row_fits(
+                heads, hkv, bs, hd, leaf.dtype.itemsize, width, self._nbper):
+            return f"the prefill kernel's plan takes no {heads // hkv} x " \
+                f"{width} query rows a KV head"
+        return None
+
+    @classmethod
+    def _widest_leaf(cls, mk_pool):
+        """The hook's (logical) K or V leaf ``[L, NB, HKV, bs, hd]`` of the
+        pool ``mk_pool`` would build, as a shape: the widest payload (a
+        sparse-attention family's third leaf, one narrow head of indexer
+        keys, is smaller)."""
+        return max((paged_kv.pool_payload(leaf)
+                    for leaf in jax.tree_util.tree_leaves(
+                        cls._paged_leaves(jax.eval_shape(mk_pool)),
+                        is_leaf=paged_kv.is_quantized_pool)),
+                   key=lambda leaf: leaf.size)
 
     @staticmethod
     def _paged_leaves(cache):
@@ -2640,14 +2744,19 @@ class ServingEngine:
                 else ("decode", self.slots, K))
         return self._decode_fn
 
-    def _get_prefill_fn(self):
-        """The one compiled prefill program, ``prefill_chunk`` wide.  With
-        a draft model, the draft's prefill is FUSED into the same program
-        (both caches advance through the identical window/table contract),
-        so speculative prefill still costs one program."""
-        if self._prefill_fn is not None:
-            return self._prefill_fn
-        width = self.prefill_chunk
+    def _get_prefill_fn(self, rung=None):
+        """The prefill program of ``rung`` (``(rows, width)``; default
+        ``(prefill_batch, prefill_chunk)``): ONE body at every shape of the
+        ladder (``_rungs``), each a program of its own under the name
+        ``prefill``, all built the first time any is asked for — a rung
+        first met late would otherwise compile in the middle of a run.
+        With a draft model, the draft's prefill is FUSED into the same
+        program (both caches advance through the identical window/table
+        contract), so speculative prefill still costs one program a
+        rung."""
+        rung = self._rungs[0] if rung is None else rung
+        if self._prefill_fns:
+            return self._prefill_fns[rung]
         fwd, prepare = self._forward, self.engine._prepare
         draft = self._draft
         constrain = self._constrain_pool
@@ -2708,36 +2817,75 @@ class ServingEngine:
             body, donate = prefill_fused, (2, 3) if donate else ()
             self._program_meta["prefill_fused"] = True
         self._program_bodies["prefill"] = body
-        spec = self._operand_spec(
-            self.prefill_batch, {"ids": width},
-            ("base", "valid") + (("window_start",)
-                                 if self.resident_window_blocks else ())
-            + ("slot",))
         n_dev = 2 if draft is None else 4
-        at = list(spec).index("slot")
-        j, pin = self.prefill_batch, self._pin_tokens
+        pin = self._pin_tokens
+        for j, width in self._rungs:
+            spec = self._operand_spec(
+                j, {"ids": width},
+                ("base", "valid") + (("window_start",)
+                                     if self.resident_window_blocks else ())
+                + ("slot",))
+            at = list(spec).index("slot")
 
-        @functools.wraps(body)             # the program keeps its name
-        def prefill_ahead(*args):
-            """``body`` writing its rows' tokens into the device-resident
-            token vector (the operand behind the pools) at ``slot``, each
-            row's slot — a pad row's is out of range and dropped; a row
-            with prompt left writes a token nobody reads.  The vector is
-            returned last."""
-            devtok, rest = args[n_dev], args[n_dev + 1:]
-            out = body(*args[:n_dev], *rest[:at], *rest[at + 1:])
-            return (*out, pin(devtok.at[rest[at]].set(out[0][:j],
-                                                      mode="drop")))
+            @functools.wraps(body)         # the program keeps its name
+            def prefill_ahead(*args, j=j, at=at):
+                """``body`` writing its rows' tokens into the
+                device-resident token vector (the operand behind the pools)
+                at ``slot``, each row's slot — a pad row's is out of range
+                and dropped; a row with prompt left writes a token nobody
+                reads.  The vector is returned last."""
+                devtok, rest = args[n_dev], args[n_dev + 1:]
+                out = body(*args[:n_dev], *rest[:at], *rest[at + 1:])
+                return (*out, pin(devtok.at[rest[at]].set(out[0][:j],
+                                                          mode="drop")))
 
-        self._prefill_fn = jax.jit(
-            self.sentry.wrap(
-                self._packed("prefill", prefill_ahead, spec,
-                             device_operands=n_dev + 1),
-                f"prefill[w{width}]"),
-            donate_argnums=donate)
-        self.compiled_programs.append(
-            ("prefill", width, self.prefill_batch))
-        return self._prefill_fn
+            self._prefill_fns[j, width] = jax.jit(
+                self.sentry.wrap(
+                    self._packed(self._prefill_program((j, width)),
+                                 prefill_ahead, spec,
+                                 device_operands=n_dev + 1),
+                    f"prefill[{self._rung_name((j, width))}]"),
+                donate_argnums=donate)
+            self.compiled_programs.append(("prefill", width, j))
+        return self._prefill_fns[rung]
+
+    def _prefill_program(self, rung) -> str:
+        """The name a rung's operand layout is kept under
+        (``_layouts``, ``stats()["operands"]``): ``"prefill"`` for
+        ``(prefill_batch, prefill_chunk)``, ``"prefill[<rows>x<width>]"``
+        for the wider rows."""
+        return "prefill" if rung == self._rungs[0] \
+            else f"prefill[{self._rung_name(rung)}]"
+
+    def _warm_prefill(self, params) -> None:
+        """Every rung's program once on pad rows (which write to scratch
+        and whose tokens land nowhere), before the first real prefill call:
+        from here on no prefill call of any shape compiles."""
+        self._prefill_warm = True
+        for rung in self._rungs:
+            fn = self._get_prefill_fn(rung)
+            j, width = rung
+            operands = [np.zeros((j, width), np.int32),
+                        self._bt(np.zeros((j, self._nbper), np.int32),
+                                 [-1] * j),
+                        np.zeros(j, np.int32), np.zeros(j, np.int32)]
+            if self.resident_window_blocks:
+                operands.append(np.zeros(j, np.int32))
+            host, _ = self._host_operands(
+                self._prefill_program(rung), *operands,
+                np.full(j, self.slots, np.int32),
+                *self._samp_args_rows((), j))
+            if self._draft is not None:
+                args = (params, self._draft.params, self._cache,
+                        self._dcache, self._devtok, *host)
+            else:
+                args = (params, self._cache, self._devtok, *host)
+            with self._prefill_ctx():
+                out = fn(*args)
+            del args
+            self._cache, self._devtok = out[1], out[-1]
+            if len(out) == 4:              # the prefill fused with a draft's
+                self._dcache = out[2]
 
     def _get_verify_fn(self):
         """The speculative K+1 verify program: one fixed-shape paged
@@ -3808,7 +3956,7 @@ class ServingEngine:
                 total_need = min(
                     total_need,
                     self._landmark_blocks + self.resident_window_blocks
-                    + blocks_for(self.prefill_chunk, self.block_size))
+                    + blocks_for(self._prefill_width, self.block_size))
             n_hit = self._kv(self._prefix.probe, prompt_eff, plen - 1) \
                 if self._prefix is not None else 0
 
@@ -5200,9 +5348,12 @@ class ServingEngine:
 
     # ---------------------------------------------------------------- prefill
     def _run_prefill(self, params) -> int:
-        """Advance prefilling slots: one ``prefill_chunk``-wide chunk per
-        slot per iteration, ``prefill_batch`` rows per call; pad rows write
-        to scratch.  Returns the number of prefill calls made."""
+        """Advance prefilling slots, in admission order ``prefill_batch``
+        rows a call; a call is a BUDGET of ``prefill_batch * prefill_chunk``
+        tokens, cut into the fewest rows of the ladder that hold its group
+        (:meth:`_rung_for`), so a short group's rows advance by what its
+        pad rows would have wasted; pad rows write to scratch.  Returns
+        the number of prefill calls made."""
         active = self._active
         with self.timeline.segment("step.prefill.plan", self._phase):
             pre = [s for s, st in sorted(active.items(),
@@ -5228,22 +5379,39 @@ class ServingEngine:
         calls = 0
         for group in groups:
             group = [s for s in group if s in active]
-            if not group:
-                continue
-            self._run_prefill_group(group, params)
-            calls += 1
+            if group and self._run_prefill_group(group, params):
+                calls += 1
         return calls
 
-    def _run_prefill_group(self, group, params):
-        """One prefill call: each row advances its slot by
-        ``min(prefill_chunk, remaining prompt)`` tokens from its own base.
-        Rows whose window reaches the last prompt token yield that slot's
-        first generated token (logits are gathered per row at
-        ``valid - 1``)."""
+    def _rung_for(self, rows: int):
+        """The rung of the ladder with the fewest rows that holds ``rows``
+        ready rows: the widest rows the call's budget gives them."""
+        return next(rung for rung in reversed(self._rungs)
+                    if rung[0] >= rows)
+
+    def _run_prefill_group(self, group, params) -> bool:
+        """One prefill call, at the shape ``[rows, width]`` of the group's
+        rung: each row advances its slot by ``min(width, remaining
+        prompt)`` tokens from its own base.  Rows whose window reaches the
+        last prompt token yield that slot's first generated token (logits
+        are gathered per row at ``valid - 1``).  False if no row of the
+        group was left to run (each lost its blocks to an earlier one)."""
         active = self._active
         seg, phase = self.timeline.segment, self._phase
-        j, width = self.prefill_batch, self.prefill_chunk
+        rung = j, width = self._rung_for(len(group))
         with seg("step.prefill.plan", phase):
+            if width > self.prefill_chunk:
+                # the blocks of the wider rows (:meth:`_run_prefill` made
+                # sure of a ``prefill_chunk`` each); a later row may lose
+                # its own to an earlier one's
+                for slot in group:
+                    st = active.get(slot)
+                    if st is not None:
+                        self._kv(self._ensure_blocks, slot, st.base + min(
+                            width, st.plen_eff - st.base))
+                group = [s for s in group if s in active]
+                if not group:
+                    return False
             ids = np.zeros((j, width), np.int32)
             bt = np.zeros((j, self._nbper), np.int32)
             base = np.zeros(j, np.int32)
@@ -5257,9 +5425,10 @@ class ServingEngine:
                 base[row] = st.base
                 valid[row] = v
                 rows.append((slot, v))
-            prefill_fn = self._get_prefill_fn()
+            prefill_fn = self._get_prefill_fn(rung)
             span_kw = {
                 "width": width, "rows": len(group),
+                "shape": self._rung_name(rung), "tokens": int(valid.sum()),
                 "slots": list(map(int, group)),
                 # blocks the rows' reads walk: cdiv(base + valid, bs) each
                 "kv_blocks": int(
@@ -5270,6 +5439,8 @@ class ServingEngine:
                 **self._state_args(
                     len(group), int((base[:len(group)] == 0).sum()),
                     int(valid.sum()))}
+        if not self._prefill_warm:
+            self._warm_prefill(params)
         with seg("step.prefill.upload", phase):
             operands = [ids,
                         self._bt(bt, list(group) + [-1] * (j - len(group))),
@@ -5285,7 +5456,8 @@ class ServingEngine:
             at = np.full(j, self.slots, np.int32)
             at[:len(group)] = group
             host, puts = self._host_operands(
-                "prefill", *operands, at, *self._samp_args_rows(group, j))
+                self._prefill_program(rung), *operands, at,
+                *self._samp_args_rows(group, j))
             if self._draft is not None:
                 args = (params, self._draft.params, self._cache,
                         self._dcache, self._devtok, *host)
@@ -5294,12 +5466,14 @@ class ServingEngine:
             del host
             flight = _Flight(
                 "prefill", dict(**puts, **span_kw), (j,),
-                functools.partial(self._commit_prefill_group, len(group),
+                functools.partial(self._commit_prefill_group, rung,
+                                  len(group), int(valid.sum()),
                                   self._advance_prefill_rows(rows)))
             flight.held = args
             del args
             ctx = self._prefill_ctx()
         self._launch(flight, prefill_fn, ctx)
+        return True
 
     def _advance_prefill_rows(self, rows):
         """What is certain of a prefill call as it is enqueued: each row's
@@ -5334,13 +5508,18 @@ class ServingEngine:
             done.append((row, slot, st, emits))
         return done
 
-    def _commit_prefill_group(self, nrows, done, first) -> None:
+    def _commit_prefill_group(self, rung, nrows, tokens, done,
+                              first) -> None:
         """The commit loop of :meth:`_run_prefill_group`, when the call's
         tokens are on the host: a row that reached its last prompt token
         (``done``, :meth:`_advance_prefill_rows`) registers its full
-        blocks with the trie and emits its first token."""
+        blocks with the trie and emits its first token.  ``rung`` is the
+        call's shape, ``tokens`` the real tokens of its ``nrows`` rows."""
         active = self._active
-        width = self.prefill_chunk
+        width = rung[1]
+        self._c_prefill_shapes[rung].inc()
+        self._c_prefill_tokens.inc(tokens)
+        self._c_prefill_budget.inc(rung[0] * width)
         if self.sp_degree > 1:
             nbytes = sp_attention.alltoall_bytes(
                 int(self._pool_shape[0]), nrows, width,
@@ -5584,6 +5763,14 @@ class ServingEngine:
             "fused_iterations": int(self._c_fused_iterations.value),
             "host_fence_waits": int(self._c_host_fence_waits.value),
             "prefill_calls": self.prefill_calls,
+            # the ladder (every rung is a built program): calls by their
+            # rows x width, and the real prompt tokens of all calls over
+            # the tokens they had room for (None before the first)
+            "prefill_shapes": {self._rung_name(rung): int(c.value)
+                               for rung, c in self._c_prefill_shapes.items()},
+            "prefill_fill": self._c_prefill_tokens.value
+            / self._c_prefill_budget.value
+            if self._c_prefill_budget.value else None,
             # the read the prefill program was traced with (None before its
             # first call): "paged_prefill_attn" on a TPU, "gather" on a CPU
             "prefill_attn": self._program_meta.get("prefill_attn"),

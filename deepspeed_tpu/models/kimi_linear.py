@@ -47,6 +47,7 @@ refused is ``inference/serving.py``'s to say, by name.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Optional
 
@@ -332,10 +333,13 @@ def _at(stack, index):
         stack)
 
 
-def _ffn(cfg: KimiLinearConfig, blocks, stacks, number, y, live, choices):
+def _ffn(cfg: KimiLinearConfig, blocks, stacks, number, y, live, choices,
+         routed=None):
     """The FFN of layer ``number`` (an ``int`` in a written-out period):
     ``-> (output, routing record, chosen experts or None)``; a dense layer's
-    record is zeros and its choices -1."""
+    record is zeros and its choices -1.  ``routed``: ``mixtral._routed`` as
+    :func:`forward_cached` holds it (one trace a program; default: called as
+    it is)."""
     if isinstance(number, int) and number < cfg.first_dense:
         out = _dense_ffn(_at(blocks["dense"], number), y)
         record = jnp.zeros(len(M.record_columns(cfg)), jnp.int32)
@@ -348,7 +352,8 @@ def _ffn(cfg: KimiLinearConfig, blocks, stacks, number, y, live, choices):
     layer = _at(moe, index)
     if stacks is not None:
         layer["layer_index"] = jnp.asarray(index, jnp.int32)
-    out, record = M._routed(cfg, layer, y, live, stacks, choices)
+    out, record = routed(layer, y, live, stacks) if routed is not None \
+        else M._routed(cfg, layer, y, live, stacks, choices)
     if choices:
         return out, record[0], record[1]
     return out, record, None
@@ -380,13 +385,27 @@ def forward_cached(cfg: KimiLinearConfig, params, input_ids, cache, pos,
         # place (``mixtral.forward_cached``)
         stacks = {k: blocks["moe"][k] for k in M._EXPERT_LEAVES}
 
+    # ONE trace and one lowered function a program for the layers that
+    # repeat: a period writes its layers out, the head period writes them
+    # out again, and all six KDA layers (and all seven routed FFNs) of this
+    # chip's share are the same computation at the same shapes — traced a
+    # layer at a time, a program's build is ~14 s of Python at every start
+    # of the cell's process (PERF.md section 6, PR 52).  The compiler
+    # inlines the calls: the program it optimises is the one it was.
+    # (no donation of their own: they are calls inside the engine's
+    # program, whose jit donates the cache)
+    kda = jax.jit(functools.partial(_kda_cached, cfg), donate_argnums=())
+    routed = jax.jit(lambda layer, y, live, stacks: M._routed(
+        cfg, layer, y, live, stacks, choices))
+
     def step(x, layer, ck, cv, index, table, kind, number):
         get, mm = layer_accessors(layer)
         with jax.named_scope("layer/attn"):
             y = L.rms_norm(x, layer["attn_norm"], cfg.rms_eps)
             if kind == "kda":
-                out, ck, cv = _kda_cached(cfg, layer, y, ck, cv, index, table,
-                                          w.step_pos, live)
+                out, ck, cv = kda(layer, y, ck, cv,
+                                  jnp.asarray(index, jnp.int32), table,
+                                  w.step_pos, live)
             else:
                 attn, ck = L._latent_cached(cfg, y, get, mm, ck, w.step_pos,
                                             table, w.chunk_valid, index)
@@ -394,7 +413,7 @@ def forward_cached(cfg: KimiLinearConfig, params, input_ids, cache, pos,
             x = x + out
         y = L.rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
         out, record, chosen = _ffn(cfg, blocks, stacks, number, y, live,
-                                   choices)
+                                   choices, routed)
         leaves = (ck, cv) if kind == "kda" else (ck,)
         return (x + out, *leaves, (record, chosen) if choices else record)
 

@@ -866,15 +866,37 @@ def _prefill_head_tile(hkv: int, rows: int, spans: int, nt: int, r: int,
     (lane-padded) — 640 KB at OPT-1.3B's ``[4, 128]`` chunk, so 16 of its
     32 heads a step (10 MB), all 16 of OLMoE's (9 MB); the heads are halved
     while they overrun :data:`_PREFILL_VMEM_BUDGET`."""
-    def need(ht):
-        return ht * (2 * 2 * nt * r * width * itemsize
-                     + rows * (width + 2 * LANES) * 4
-                     + (spans + 4) * rows * width * itemsize)
-
+    head = _prefill_head_bytes(rows, spans, nt, r, width, itemsize)
     ht = hkv
-    while need(ht) > _PREFILL_VMEM_BUDGET and ht % 2 == 0:
+    while ht * head > _PREFILL_VMEM_BUDGET and ht % 2 == 0:
         ht //= 2
     return ht
+
+
+def _prefill_head_bytes(rows: int, spans: int, nt: int, r: int, width: int,
+                        itemsize: int) -> int:
+    """VMEM one KV head of a grid step of the prefill kernel costs
+    (:func:`_prefill_head_tile` says what of)."""
+    return (2 * 2 * nt * r * width * itemsize
+            + rows * (width + 2 * LANES) * 4
+            + (spans + 4) * rows * width * itemsize)
+
+
+def prefill_row_fits(heads: int, hkv: int, block_size: int, head_dim: int,
+                     itemsize: int, t: int, nbper: int) -> bool:
+    """Whether the prefill kernel can plan ``t``-wide rows of ``heads``
+    query heads over a lane-packed float pool of ``hkv`` KV heads,
+    ``block_size`` x ``head_dim`` a block: ONE KV head a grid step — the
+    fewest :func:`_prefill_head_tile` can choose — inside
+    :data:`_PREFILL_VMEM_BUDGET`, from the shapes alone
+    (:func:`_paged_prefill_call`'s own arithmetic).  What the serving
+    engine asks before it builds a prefill program with wider rows."""
+    g = paged_kv.lane_pack(block_size, head_dim)
+    r, width = block_size // g, g * head_dim
+    width += -width % LANES
+    nt = max(1, min(_PREFILL_COLS // r, nbper))
+    return _prefill_head_bytes(heads // hkv * t, g, nt, r, width,
+                               itemsize) <= _PREFILL_VMEM_BUDGET
 
 
 def _paged_prefill_kernel(layer_ref, base_ref, valid_ref, bt_ref, q_ref,

@@ -721,19 +721,22 @@ def test_sentry_enforces_serving_compile_contracts(tiny_engine):
     srv = ServingEngine(engine, slots=4, max_seq_len=128, block_size=8,
                         prefill_chunk=16, prefill_batch=2,
                         debug_checks=True)
-    assert srv.compile_budget == 2
+    assert srv.compile_budget == 1 + len(srv._rungs)
     srv.serve(_mixed_trace(cfg, 8, seed=0))
     srv.serve(_mixed_trace(cfg, 4, seed=1))    # new shapes: no new traces
-    assert srv.sentry.traces == 2
+    assert srv.sentry.traces == 1 + len(srv._rungs) == 3
     assert srv.stats()["retraces_observed"] == 0
-    assert sorted(srv.sentry.report()) == ["decode", "prefill[w16]"]
+    assert sorted(srv.sentry.report()) == ["decode", "prefill[1x32]",
+                                           "prefill[2x16]"]
 
     spec = ServingEngine(engine, slots=4, max_seq_len=128, block_size=8,
                          prefill_chunk=16, prefill_batch=2, spec_tokens=4,
                          debug_checks=True)
-    assert spec.compile_budget == 2            # n-gram: prefill + verify
+    # n-gram: a prefill program a rung + verify
+    assert spec.compile_budget == 1 + len(spec._rungs)
     spec.serve(_mixed_trace(cfg, 6, seed=2))
-    assert sorted(spec.sentry.report()) == ["prefill[w16]", "verify"]
+    assert sorted(spec.sentry.report()) == ["prefill[1x32]", "prefill[2x16]",
+                                            "verify"]
     assert spec.stats()["retraces_observed"] == 0
 
 
@@ -746,7 +749,7 @@ def test_serve_debug_checks_override_and_counters(tiny_engine):
     assert srv.debug_checks and srv.sentry.strict
     st = srv.stats()
     assert st["debug_checks"] and st["invariant_checks_run"] > 0
-    assert st["retraces_observed"] == 0 and st["compile_budget"] == 2
+    assert st["retraces_observed"] == 0 and st["compile_budget"] == 1 + len(srv._rungs)
     # debug_checks installs the process-wide compile listener
     assert st["backend_compiles"] is not None and st["backend_compiles"] > 0
 

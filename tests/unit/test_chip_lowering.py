@@ -508,8 +508,10 @@ def test_paged_walk_compiles_at_the_cells_shapes(cell, slots, h, hd, ctx,
     kernels = [(1, slots, da.paged_decode_attention_pallas),
                (4, slots, da.paged_verify_attention_pallas)]
     if not kv8:
-        # a [4, 128] prefill chunk (the cells' prefill_batch x prefill_chunk)
-        kernels.append((128, 4, da.paged_prefill_attention_pallas))
+        # a prefill call at both rungs of the cells' ladder (prefill_batch x
+        # prefill_chunk = [4, 128], and a row alone: [1, 512])
+        kernels += [(t, 512 // t, da.paged_prefill_attention_pallas)
+                    for t in (128, 512) if t <= ctx]
     for t, rows, kernel in kernels:
         args = _cell_operands(rows, h, hd, ctx, layers, kv8, t, one_chip)
         q, pool, bt, pos = args
@@ -1166,8 +1168,9 @@ LATENT = dict(slots=64, ctx=16384, heads=32, width=384, rank=256, block=512,
 
 
 @pytest.mark.parametrize("block", [32, 256, 512])
-@pytest.mark.parametrize("rows,t", [(64, 1), (4, 128), (64, 4)],
-                         ids=["decode", "prefill-chunk", "verify"])
+@pytest.mark.parametrize("rows,t", [(64, 1), (4, 128), (64, 4), (1, 512)],
+                         ids=["decode", "prefill-chunk", "verify",
+                              "prefill-1x512"])
 def test_latent_walks_compile_at_the_long_decode_cells_shapes(rows, t, block,
                                                               one_chip):
     """ISSUE 39: Mosaic's own compile, for a described v5e, of the latent

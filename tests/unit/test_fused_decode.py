@@ -67,7 +67,8 @@ def test_fused_parity_chunked_and_fence_accounting(tiny_engine):
     assert_sequential(engine, reqs, rK)
     st1, stK = s1.stats(), sK.stats()
     # fused REPLACES the per-token program: same budget, no extra compile
-    assert stK["compile_count"] == 2 == st1["compile_count"]
+    assert stK["compile_count"] == 1 + len(sK._rungs) \
+        == st1["compile_count"]
     assert stK["compile_budget"] == st1["compile_budget"]
     assert stK["retraces_observed"] == 0
     # the new stats keys, live
@@ -132,7 +133,8 @@ def test_fused_parity_tiered_host_kv(tiny_engine):
     assert_sequential(engine, reqs, rK)
     st = sK.stats()
     assert st["swap_out"] > 0 and st["swap_in"] > 0
-    assert st["compile_count"] == 4       # base 2 + demote + promote
+    # decode + a prefill program a rung + demote + promote
+    assert st["compile_count"] == 3 + len(sK._rungs)
 
 
 def test_fused_preemption_at_fence_keeps_parity(tiny_engine):

@@ -267,7 +267,8 @@ def test_stats_name_the_state_kind(tiny, served):
     assert st["kv_latent"]["token_width"] == 24
     assert st["kv_latent"]["latent_attn"] == {"prefill": "latent_gather",
                                               "decode": "latent_gather"}
-    assert st["compile_count"] == 2 and st["prefix_cache_entries"] == 0
+    assert st["compile_count"] == 1 + len(srv._rungs) == 3 \
+        and st["prefix_cache_entries"] == 0
     # the spans carry the rows, resets and tokens of the state kind
     spans = [e for e in events if e["ph"] == "X"
              and e["name"] in ("prefill", "decode")]

@@ -212,7 +212,7 @@ def test_engine_serves_it_token_exact_and_names_the_pool(model):
     assert st["kv_kinds"] is None and st["sparse_attn"] is None
     # (the blocks still in use are the prefix trie's: a latent block is
     # kept for the next request like any other)
-    assert st["compile_count"] == 2
+    assert st["compile_count"] == 1 + len(srv._rungs) == 3
     assert st["blocks_in_use"] == st["prefix_cache_entries"] > 0
     for name in ("decode", "prefill"):
         spans = [e["args"] for e in srv.timeline.events()

@@ -300,26 +300,35 @@ def test_a_call_hands_over_one_host_array_and_puts_nothing(
     monkeypatch.setattr(jax, "device_put", lambda *a, **k:
                         (puts.append("device_put"), real_put(*a, **k))[1])
     handed = {}
+
+    def spy_on(program, fn):
+        def spy(*args):
+            handed.setdefault(program, []).append(
+                [a for a in args if isinstance(a, np.ndarray)])
+            return fn(*args)
+        return spy
+
     for program, getter in (("decode", "_get_decode_fn"),
-                            ("prefill", "_get_prefill_fn"),
                             ("verify", "_get_verify_fn"),
                             ("draft", "_get_draft_fn")):
-        fn = getattr(srv, getter)() if program in srv._layouts else None
-        if fn is None:
-            continue
-
-        def spy(*args, _fn=fn, _p=program):
-            handed.setdefault(_p, []).append(
-                [a for a in args if isinstance(a, np.ndarray)])
-            return _fn(*args)
-
-        monkeypatch.setattr(srv, getter, lambda _s=spy: _s)
+        if program in srv._layouts:
+            monkeypatch.setattr(
+                srv, getter,
+                lambda _s=spy_on(program, getattr(srv, getter)()): _s)
+    # a prefill program a rung of the ladder, each with a layout of its own
+    rung_of = {srv._prefill_program(rung): rung for rung in srv._rungs}
+    spies = {rung: spy_on(program, srv._get_prefill_fn(rung))
+             for program, rung in rung_of.items()}
+    monkeypatch.setattr(srv, "_get_prefill_fn",
+                        lambda rung=None: spies[rung or srv._rungs[0]])
     before = len(srv.timeline.events())
     for _ in range(12):
         srv.step()
     assert puts == []
     beside = 2 if runner == "masks" else 1
-    assert set(handed) == set(srv._layouts) and all(handed.values())
+    assert set(srv._layouts) - set(rung_of) <= set(handed) \
+        <= set(srv._layouts)
+    assert set(handed) & set(rung_of) and all(handed.values())
     for program, calls in handed.items():
         layout = srv._layouts[program]
         want = 1 if program == "draft" else beside
@@ -330,13 +339,17 @@ def test_a_call_hands_over_one_host_array_and_puts_nothing(
     flights = [e for e in srv.timeline.events()[before:]
                if e["ph"] == "X" and e["name"] in IN_FLIGHT]
     assert {e["name"] for e in flights} >= {"prefill"}
-    span_of = {"decode": "decode", "prefill": "prefill",
-               "verify": "spec_verify", "draft": "spec_propose"}
-    for program, layout in srv._layouts.items():
-        mine = [e["args"] for e in flights if e["name"] == span_of[program]
-                and e["args"].get("mode") != "ngram"]
+    span_of = {"decode": "decode", "verify": "spec_verify",
+               "draft": "spec_propose"}
+    for program in handed:
+        layout, rung = srv._layouts[program], rung_of.get(program)
+        mine = [e["args"] for e in flights
+                if e["name"] == span_of.get(program, "prefill")
+                and e["args"].get("mode") != "ngram"
+                and (rung is None
+                     or e["args"]["shape"] == srv._rung_name(rung))]
         assert mine, program
-        masks = srv.slots if program != "prefill" else srv.prefill_batch
+        masks = srv.slots if rung is None else rung[0]
         extra = masks * srv._vocab \
             if runner == "masks" and program != "draft" else 0
         for args in mine:
@@ -370,16 +383,25 @@ def test_the_buffer_may_be_overwritten_right_after_the_enqueue(
     srv.close()
     srv, _ = _engine(models, "plain")
     getter = f"_get_{program}_fn"
-    fn = getattr(srv, getter)()
+    real = getattr(srv, getter)
+    real()                                 # builds the program(s)
     calls = [0]
 
-    def scribbling(*args):
-        out = fn(*args)
-        srv._layouts[program].buffer[:] = -1
-        calls[0] += 1
-        return out
+    def scribbling(*rung):
+        """The program (of ``rung``, for a prefill call), scribbling."""
+        fn = real(*rung)
+        layout = srv._layouts[srv._prefill_program(rung[0])
+                              if rung and rung[0] else program]
 
-    setattr(srv, getter, lambda: scribbling)
+        def call(*args):
+            out = fn(*args)
+            layout.buffer[:] = -1
+            calls[0] += 1
+            return out
+
+        return call
+
+    setattr(srv, getter, scribbling)
     got = srv.serve(_requests(cfg, "plain"))
     assert calls[0] > 5
     for uid in want:
@@ -398,8 +420,10 @@ def test_a_layout_is_made_once_a_program_and_nothing_compiles_in_fifty_steps(
     srv, cfg = _engine(models, runner)
     assert made == []                      # with the program, not the engine
     srv.serve(_requests(cfg, runner, n=4, seed=1))
-    programs = {"plain": {"decode", "prefill"},
-                "spec-draft": {"prefill", "draft", "verify"}}[runner]
+    programs = {"plain": {"decode"},
+                "spec-draft": {"draft", "verify"}}[runner] \
+        | {srv._prefill_program(rung) for rung in srv._rungs}
+    assert len(srv._rungs) == 2            # every rung's layout, at once
     assert set(srv._layouts) == programs and len(made) == len(programs)
     layouts = dict(srv._layouts)
     built, traces = srv.compile_count, srv.sentry.traces
@@ -418,7 +442,7 @@ def test_a_layout_is_made_once_a_program_and_nothing_compiles_in_fifty_steps(
     assert srv.compile_count == built and srv.sentry.traces == traces
     assert srv.stats()["retraces_observed"] == 0
     # one int32 operand behind the device's own, whatever the step held
-    for name, fn in (("decode", srv._decode_fn), ("prefill", srv._prefill_fn),
+    for name, fn in (("decode", srv._decode_fn), *srv._prefill_fns.items(),
                      ("verify", srv._verify_fn), ("draft", srv._draft_fn)):
         if fn is not None:
             assert fn._cache_size() == 1, name

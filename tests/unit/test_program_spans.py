@@ -137,16 +137,41 @@ def test_in_flight_spans_nest_inside_a_phase(served):
         assert (p["args"]["slots"] > 0) == bool(inside)
 
 
+def _prefill_rows(events):
+    """``(uid, prompt tokens cached after the call)`` of every row of every
+    ``prefill`` span, replayed from the ring: an ``admit`` instant puts a
+    request in a slot (past its prefix hit), a span advances each of its
+    ``slots`` by
+    ``min(width, prompt left)`` — a call's width follows its ready rows
+    (ISSUE 52), so a prompt's calls are not ``cdiv(prompt, prefill_chunk)``."""
+    held, rows = {}, []
+    for e in sorted((e for e in events if e["name"] in ("admit", "prefill")),
+                    key=lambda e: e["ts"]):
+        a = e["args"]
+        if e["name"] == "admit":
+            held[a["slot"]] = [a["uid"], a["prompt_tokens"],
+                               a["prefix_hit_tokens"]]
+            continue
+        for slot in a["slots"]:
+            row = held[slot]
+            row[2] += min(a["width"], row[1] - row[2])
+            rows.append((row[0], row[2]))
+    assert all(done == total for _, total, done in held.values())
+    return rows
+
+
 def test_prefill_spans_count_the_blocks_their_reads_walk(served, tiny):
     """ISSUE 31: each ``prefill`` span carries ``kv_blocks``, the sum over
     its rows of ``cdiv(base + valid, block_size)`` — what the prefill
     kernel walks — and the program notes, as it is traced, which read it
     was built with (on a CPU the gather)."""
     srv, events, _ = served
-    bs, chunk = SERVE_KW["block_size"], SERVE_KW["prefill_chunk"]
-    want = sum(-(-min(end, len(r.prompt)) // bs)
-               for r in _requests(tiny[1])
-               for end in range(chunk, len(r.prompt) + chunk, chunk))
+    bs = SERVE_KW["block_size"]
+    rows = _prefill_rows(events)
+    # a request's last call ends at its prompt's length
+    assert dict(rows) == {str(r.uid): len(r.prompt)
+                          for r in _requests(tiny[1])}
+    want = sum(-(-done // bs) for _, done in rows)
     spans = _named(events, "prefill")
     assert all(e["args"]["kv_blocks"] >= e["args"]["rows"] for e in spans)
     assert sum(e["args"]["kv_blocks"] for e in spans) == want
@@ -193,7 +218,7 @@ def test_sampler_rows_ride_the_spans_and_stats_name_the_sampler(
     ``top_k`` or ``top_p`` set: the rows the threshold searches run for),
     counted from the knob vectors the dispatch uploads.  A decode span
     counts a request once per token it emits there (all but its first), a
-    prefill span once per chunk of its prompt."""
+    prefill span once per call its prompt went through."""
     engine, cfg = tiny
     srv = ServingEngine(engine, sampling=sampling, **SERVE_KW)
     assert srv.stats()["sampler"] == {}
@@ -207,7 +232,7 @@ def test_sampler_rows_ride_the_spans_and_stats_name_the_sampler(
     how = "bitwise_search" if sampling else "argmax"
     assert srv.stats()["sampler"] == {"prefill": how, "decode": how}
     events = srv.timeline.events()
-    chunk = SERVE_KW["prefill_chunk"]
+    calls = [uid for uid, _ in _prefill_rows(events)]
     sampled = [r for r in reqs if r.temperature > 0]
     filtered = [r for r in sampled if r.top_k > 0 or r.top_p < 1]
     assert (len(sampled), len(filtered)) == ((5, 4) if sampling else (0, 0))
@@ -215,7 +240,7 @@ def test_sampler_rows_ride_the_spans_and_stats_name_the_sampler(
         assert sum(e["args"][key] for e in _named(events, "decode")) == \
             sum(r.max_new_tokens - 1 for r in of)
         assert sum(e["args"][key] for e in _named(events, "prefill")) == \
-            sum(-(-len(r.prompt) // chunk) for r in of)
+            sum(calls.count(str(r.uid)) for r in of)
     for e in _named(events, "decode") + _named(events, "prefill"):
         assert e["args"]["filtered_rows"] <= e["args"]["sampled_rows"] \
             <= e["args"].get("rows", e["args"]["slots"])

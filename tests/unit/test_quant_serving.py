@@ -147,7 +147,7 @@ def test_kv8_serving_bounded_divergence_and_stats(tiny_engine):
     srv = ServingEngine(engine, slots=4, max_seq_len=128, block_size=8,
                         prefill_chunk=16, prefill_batch=2, quantize="kv8",
                         debug_checks=True)
-    assert srv.compile_budget == 2
+    assert srv.compile_budget == 1 + len(srv._rungs)
     res = srv.serve(_trace(cfg))
     rate = assert_bounded_divergence(want, res, KV8_MIN_MATCH, "kv8")
     assert rate > 0  # helper returns the measured rate for logging
@@ -155,7 +155,8 @@ def test_kv8_serving_bounded_divergence_and_stats(tiny_engine):
     assert st["quantize"] == "kv8" and st["kv_dtype"] == "int8"
     assert st["weight_quant"] is None
     assert st["kv_scale_bytes"] > 0
-    assert st["compile_count"] == 2, srv.compiled_programs
+    assert st["compile_count"] == 1 + len(srv._rungs), \
+        srv.compiled_programs
     assert st["retraces_observed"] == 0
     assert st["invariant_checks_run"] > 0
     # quant-adjusted pool accounting: int8 codes + scale table, and the
@@ -181,7 +182,7 @@ def test_kv8_speculative_and_preemption_pressure(tiny_engine):
                         spec_tokens=3, debug_checks=True)
     res = srv.serve(_trace(cfg, seed=3))
     assert_bounded_divergence(want, res, KV8_MIN_MATCH, "kv8+spec")
-    assert srv.compile_count <= 2, srv.compiled_programs
+    assert srv.compile_count <= 1 + len(srv._rungs), srv.compiled_programs
     assert srv.stats()["acceptance_rate"] >= 0.0
 
     # oversubscribed pool: preemption + prefix eviction under kv8
@@ -270,7 +271,7 @@ def test_quant_serving_all_families(family):
     res = srv.serve(_trace(cfg, n=4, seed=2, prefix_len=10, tail=(3, 8),
                            max_new=(2, 8)))
     assert_bounded_divergence(want, res, KV8_MIN_MATCH, f"{family} kv8")
-    assert srv.compile_count <= 2
+    assert srv.compile_count <= 1 + len(srv._rungs)
 
     deepspeed_tpu.comm.reset_topology()
     srv_w = deepspeed_tpu.init_serving(
@@ -286,7 +287,7 @@ def test_quant_serving_all_families(family):
                               f"{family} w8a8+kv8")
     st = srv_w.stats()
     assert st["weight_quant"] == "w8a8" and st["kv_dtype"] == "int8"
-    assert srv_w.compile_count <= 2
+    assert srv_w.compile_count <= 1 + len(srv_w._rungs)
     # teacher-forced logit error stays bounded (no argmax-cascade luck)
     rmse = max_logit_rmse(engine, srv_w.engine,
                           [r.prompt for r in reqs[:2]])

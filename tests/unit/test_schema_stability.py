@@ -78,7 +78,8 @@ ENGINE_STATS_KEYS = frozenset({
     "moe_expert_rows", "moe_experts_touched",
     "num_blocks", "nvme_blocks", "nvme_blocks_in_use", "nvme_loads",
     "nvme_spills", "prefetch_misses", "prefetch_wait_p50_s",
-    "prefetch_wait_p95_s", "prefill_calls", "prefix_cache_entries",
+    "prefetch_wait_p95_s", "prefill_calls", "prefill_fill", "prefill_shapes",
+    "prefix_cache_entries",
     "prefix_cache_evictions", "prefix_cache_hit_rate",
     "prefix_hit_tokens", "prompt_tokens", "quantize", "queue_depth",
     "requests_finished", "resume_recompute_tokens", "retraces_observed",

@@ -280,12 +280,18 @@ def test_compile_budget_mirror(tiny_engine):
         dict(spec_tokens=4),                           # ngram spec
         dict(host_blocks=16, swap_batch=4),            # tiered
         dict(spec_tokens=4, host_blocks=16, swap_batch=4),
+        # the prefill ladder: a program a rung
+        dict(prefill_batch=1), dict(prefill_batch=6),
+        dict(prefill_chunk=32), dict(max_seq_len=20),
+        dict(host_blocks=16, swap_batch=4, resident_window_blocks=4),
     ]
     for kw in cases:
-        srv = ServingEngine(engine, slots=2, max_seq_len=64, block_size=8,
-                            prefill_chunk=16, **kw)
+        kw = {**dict(slots=2, max_seq_len=64, block_size=8,
+                     prefill_chunk=16), **kw}
+        srv = ServingEngine(engine, **kw)
         cfg = {**BASE_SERVING_CONFIG, **kw}
         assert compile_budget(cfg) == srv.compile_budget, kw
+        srv.close()
 
 
 # ----------------------------- constraint <-> ctor validation audit

@@ -538,7 +538,8 @@ def test_request_failed_error_when_no_survivor(tiny):
     """fail() on the only replica: nothing can re-home, so handles
     resolve LOUDLY with RequestFailedError — never a hang."""
     spec, cfg, engine = tiny
-    _, reqs = _session_trace(cfg, n=3)
+    # prompts past a prefill call's budget (2 x 16): none has a token yet
+    _, reqs = _session_trace(cfg, n=3, prefix_len=40)
     router = ReplicaRouter([_mk_srv(spec, engine.params)],
                            debug_checks=True)
     handles = [router.submit(r) for r in reqs]
@@ -958,7 +959,9 @@ def test_last_decode_worker_lost_fails_handoffs_loudly(tiny):
     handoffs must resolve their handles with RequestFailedError — not
     bounce forever between prefill workers, not hang the caller."""
     spec, cfg, engine = tiny
-    _, reqs = _session_trace(cfg, n=4, max_new=8)
+    # prompts past a prefill call's budget (2 x 16): after one step every
+    # request is still the prefill worker's
+    _, reqs = _session_trace(cfg, n=4, max_new=8, prefix_len=40)
     router = ReplicaRouter(
         [_mk_srv(spec, engine.params, role=r)
          for r in ("prefill", "decode")], debug_checks=True)

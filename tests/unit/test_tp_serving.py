@@ -92,7 +92,7 @@ def test_tp4_parity_prefix_heavy_and_pool_shards(tp1_engine, tp4_engine,
         for shard in leaf.addressable_shards:
             assert shard.data.shape[2] == hkv // 4
     assert_sequential(tp1_engine, _trace(tiny_cfg, 6, seed=0), r1, r4)
-    assert s4.compile_count == 2, s4.compiled_programs
+    assert s4.compile_count == 1 + len(s4._rungs), s4.compiled_programs
     # scheduler state is head-sharding-invariant: identical counters
     assert s4.prefix_hit_tokens == s1.prefix_hit_tokens
     assert s4.decode_steps == s1.decode_steps
@@ -106,7 +106,7 @@ def test_tp4_parity_speculative_and_compile_contract(tp1_engine, tp4_engine,
                                  spec_tokens=3)
     for uid in r1:
         np.testing.assert_array_equal(r1[uid], r4[uid], err_msg=f"uid {uid}")
-    assert s4.compile_count <= 3, s4.compiled_programs
+    assert s4.compile_count <= 2 + len(s4._rungs), s4.compiled_programs
     assert s4.compile_count == s1.compile_count
     assert s4.spec_rounds == s1.spec_rounds
 
@@ -152,7 +152,7 @@ def test_tp4_kv8_parity_and_sharded_scale_table(tp1_engine, tp4_engine,
     assert st4["kv_dtype"] == "int8" and st4["kv_scale_bytes"] > 0
     assert st4["kv_pool_bytes"] == st1["kv_pool_bytes"]
     assert st4["kv_pool_bytes_per_chip"] == st1["kv_pool_bytes"] // 4
-    assert s4.compile_count == 2, s4.compiled_programs
+    assert s4.compile_count == 1 + len(s4._rungs), s4.compiled_programs
 
 
 def test_tp4_tiered_kv_parity_per_shard_transfers(tp1_engine, tp4_engine,
@@ -176,7 +176,7 @@ def test_tp4_tiered_kv_parity_per_shard_transfers(tp1_engine, tp4_engine,
     assert st4["swap_out"] > 0 and st4["swap_in"] > 0
     assert (st4["swap_out"], st4["swap_in"]) == \
         (st1["swap_out"], st1["swap_in"])
-    assert s4.compile_count == 4 and s4.compile_budget == 4
+    assert s4.compile_count == s4.compile_budget == 3 + len(s4._rungs)
     for uid in r1:
         np.testing.assert_array_equal(r1[uid], r4[uid], err_msg=f"uid {uid}")
     sq1 = ServingEngine(tp1_engine, quantize="kv8", **kw)
@@ -262,7 +262,7 @@ def test_draft_pool_shards_with_target(tp4_engine, tiny_cfg):
     assert srv._dcache["k"].addressable_shards[0].data.shape[2] == 1
     reqs = _trace(tiny_cfg, 4, seed=2)
     res = srv.serve(reqs)
-    assert srv.compile_count <= 3, srv.compiled_programs
+    assert srv.compile_count <= 2 + len(srv._rungs), srv.compiled_programs
     assert_sequential(tp4_engine, reqs, res)
 
 
@@ -280,11 +280,12 @@ def test_tiered_mixed_sharding_sharded_target_replicated_draft(tp4_engine,
                         spec_tokens=3, draft=gpt2.build(dcfg),
                         host_blocks=64, swap_batch=4, debug_checks=True)
     assert srv.kv_sharded and not srv._dcache_sharded
-    reqs = _trace(tiny_cfg, 5, seed=4, max_new=(16, 24))
+    # (a trace whose preempted rows come back to blocks in the host tier)
+    reqs = _trace(tiny_cfg, 5, seed=5, max_new=(16, 24))
     res = srv.serve(reqs)
     st = srv.stats()
     assert st["swap_out"] > 0 and st["swap_in"] > 0
-    assert srv.compile_count <= srv.compile_budget == 5
+    assert srv.compile_count <= srv.compile_budget == 4 + len(srv._rungs)
     assert_sequential(tp4_engine, reqs, res)
 
 
@@ -513,7 +514,8 @@ def test_dp_tp_engine_token_identity_vs_router_fronted(tiny_cfg):
                                       err_msg=f"uid {r.uid}")
     st = srv_dp.stats()
     assert st["engine_mode"] == "dp_tp"
-    assert st["compile_count"] == 2      # ONE decode + ONE prefill program
+    # ONE decode program + ONE prefill program a rung
+    assert st["compile_count"] == 1 + len(srv_dp._rungs)
     assert st["retraces_observed"] == 0
 
     # fused multi-step composes with the 2-D mesh: same tokens again
