@@ -7,26 +7,52 @@ Public API mirrors the reference DeepSpeed surface (``deepspeed/__init__.py``):
 
 from __future__ import annotations
 
-from typing import Optional, Union
+import functools as _functools
+import time as _time
 
-from . import comm
-from . import models
-from . import module_inject
-from . import ops
-from . import zero
-from .runtime import lr_schedules
-from .runtime.config import DeepSpeedConfig
-from .runtime.engine import DeepSpeedEngine
-from .runtime.model import ModelSpec, OnDevice, from_flax, from_functions
-from .parallel.topology import (MeshTopology, PipeModelDataParallelTopology,
-                                ProcessTopology, topology_from_config)
-from .utils.logging import log_dist, logger
+_T_IMPORT = _time.perf_counter()    # the start-up ring's ``import`` span
+
+from typing import Optional, Union  # noqa: E402
+
+from . import comm  # noqa: E402
+from . import models  # noqa: E402
+from . import module_inject  # noqa: E402
+from . import ops  # noqa: E402
+from . import zero  # noqa: E402
+from .runtime import lr_schedules  # noqa: E402
+from .runtime.config import DeepSpeedConfig  # noqa: E402
+from .runtime.engine import DeepSpeedEngine  # noqa: E402
+from .runtime.model import ModelSpec, OnDevice, from_flax, from_functions  # noqa: E402
+from .parallel.topology import (  # noqa: E402
+    MeshTopology, PipeModelDataParallelTopology, ProcessTopology,
+    topology_from_config)
+from .telemetry import trace as _trace  # noqa: E402
+from .utils.logging import log_dist, logger  # noqa: E402
 
 __version__ = "0.1.0"
 __git_hash__ = None
 __git_branch__ = None
 
+# the package's own import, top to bottom, is the first span of the
+# process's start-up ring (telemetry/trace.py setup_timeline): what of a
+# process's start is ``import deepspeed_tpu`` and what came before it
+# (``import jax``, the backend's start) can be told apart
+_setup = _trace.setup_timeline(epoch_s=_T_IMPORT)
+_setup.complete("import", (_T_IMPORT - _setup.epoch_s) * 1e6,
+                package=__name__)
 
+
+def _on_setup_ring(fn):
+    """``fn``, whole, as a span of the start-up ring under its own name
+    (the engines' constructors put their phases inside it)."""
+    @_functools.wraps(fn)
+    def spanned(*args, **kwargs):
+        with _trace.setup_timeline().span(fn.__name__):
+            return fn(*args, **kwargs)
+    return spanned
+
+
+@_on_setup_ring
 def initialize(args=None,
                model: Optional[ModelSpec] = None,
                optimizer=None,
@@ -234,6 +260,7 @@ def init_router(model=None, config=None, params=None, *, replicas=2,
     return router
 
 
+@_on_setup_ring
 def init_serving(model=None, config=None, params=None, *, slots=8,
                  max_seq_len=None, prefill_batch=4,
                  block_size=None, num_blocks=None,

@@ -57,7 +57,8 @@ GL008     metric family registration outside the telemetry naming
           literal name): counters must end in ``_total`` (the Prometheus
           monotone-counter convention scrapers reset-detect on), every
           family must carry a subsystem namespace prefix (``serving_`` /
-          ``train_`` / ``inference_`` — the federated fleet registry
+          ``train_`` / ``inference_``, or the process's ``program_`` /
+          ``compile_`` — the federated fleet registry
           stays greppable by subsystem), gauges/histograms must NOT end
           in ``_total``, and label keys must come from the documented
           closed set (``docs/observability.md``) — an ad-hoc label key
@@ -176,7 +177,11 @@ RULES: Dict[str, str] = {
 #: tails, family namespace prefixes, the closed label-key set, and the
 #: registry-method keywords that are NOT labels
 _METRIC_CTORS = frozenset({"counter", "gauge", "histogram"})
-_METRIC_NAMESPACES = ("serving_", "train_", "inference_")
+#: (``program_`` / ``compile_``: the PROCESS's families — what JAX builds
+#: and what the compile cache answers belong to no engine,
+#: ``telemetry/metrics.py process_registry``)
+_METRIC_NAMESPACES = ("serving_", "train_", "inference_", "program_",
+                      "compile_")
 _METRIC_LABEL_KEYS = frozenset(
     {"replica", "direction", "timer", "slo_class", "slo", "phase",
      "lock", "tier", "mode", "cause", "shape"})

@@ -30,16 +30,26 @@ without paying for enforcement.  Either way the wrapper's overhead is
 zero on the hot path — the wrapped body only executes while tracing.
 
 As corroborating global telemetry, :func:`install_compile_listener` hooks
-``jax.monitoring``'s ``/jax/core/compile`` duration events (the lowering
-hooks XLA itself reports through) and counts backend compilations
-process-wide; this catches compiles that never went through a registered
-entry point.
+``jax.monitoring`` — the program's one listener (:class:`BuildListener`),
+installed by both engines as they are constructed.  It counts backend
+compilations process-wide (``backend_compiles()``: this catches compiles
+that never went through a registered entry point) and KEEPS what JAX says
+of every function it builds: the ``trace`` / ``lower`` / ``compile`` time
+spans, by function, as X-events on the process's start-up ring
+(``telemetry/trace.py setup_timeline``, beside the engines' ``build``
+spans, whose ``program`` they carry), whether the persistent cache hit, and
+the counters ``program_build_seconds_total{phase}`` /
+``compile_cache_hits_total`` / ``compile_cache_misses_total`` on the
+process's registry.  The sentry's own ``jit_trace`` / ``retrace`` instants
+stay where they were, on the engine's ring.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
+import threading
+import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 
@@ -226,41 +236,148 @@ class RecompileSentry:
 
 
 # ----------------------------------------------------- global compile probe
-#: the full prefix matters: "/jax/core/compile" alone would also match the
-#: jaxpr-trace and MLIR-lowering duration events (3 counts per compile)
-_BACKEND_COMPILE_PREFIX = "/jax/core/compile/backend_compile"
+#: ``jax.monitoring`` event -> the phase of a function's build it times.  The
+#: full names matter: ``backend_compile`` is also what :func:`backend_compiles`
+#: counts, once a compile and not once a phase
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_BUILD_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    _BACKEND_COMPILE: "compile",
+}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
-class _CompileCounter:
-    __slots__ = ("count",)
+class BuildListener:
+    """What ``jax.monitoring`` says of every function JAX builds, put on the
+    process's start-up ring (``telemetry/trace.py setup_timeline``) and
+    counted on the process's registry (``telemetry/metrics.py
+    process_registry``); :func:`install_compile_listener` registers ONE.
 
-    def __init__(self):
-        self.count = 0
+    JAX times a build's phases around its own code (``dispatch.
+    log_elapsed_time``): a scalar as a phase begins, a duration and a time
+    span — ``(event, start, end, fun_name=)`` on ``time.time()`` — as it
+    ends.  :meth:`on_span` turns the spans into the ring's ``trace`` /
+    ``lower`` / ``compile`` X-events with ``args.fn`` (``jit(...)``
+    stripped) and, while an engine's ``build`` span is open, ``program``.
+    ``jnp`` functions called WHILE a function is traced are traced
+    themselves, thousands to a program: :meth:`on_begin` counts how deep
+    the calling thread is in phases, and only a phase that ends with none
+    open around it is kept — the others lie inside it.  The persistent
+    cache says ``cache_hits`` or ``cache_misses`` (and a hit's
+    ``cache_retrieval_time_sec``) just before the ``compile`` span they
+    belong to ends: they ride on it as ``cache`` = ``hit`` | ``miss`` |
+    ``off`` (no cache, or an entry under JAX's thresholds: neither was
+    said) and ``retrieval_s``.
+
+    The clock.  The ring runs on ``clock`` (``time.perf_counter``), JAX
+    stamps with ``wall`` (``time.time``): ONE pair of reads, here, gives
+    the offset every event is moved by.  The pair is good to the
+    microsecond between its two reads; what it cannot see is the wall clock
+    being steered afterwards — NTP slews it by up to 0.5 ms a second (at
+    worst 60 ms over a two-minute start, as a rule a hundredth of that) and
+    a step moves every later event by the step.  Durations are JAX's own
+    differences and do not suffer; positions do, which is why whoever nests
+    these events into the engines' spans does it by midpoint
+    (``telemetry/trace.py _nest``) and by the ``program`` stamp.
+    """
+
+    def __init__(self, clock=time.perf_counter, wall=time.time,
+                 registry=None):
+        from ..telemetry.metrics import process_registry
+
+        self.count = 0                      # backend compiles, process-wide
+        self.offset_s = clock() - wall()
+        self._depth = threading.local()
+        self._cache: Optional[str] = None
+        self._retrieval_s: Optional[float] = None
+        m = registry if registry is not None else process_registry()
+        self._seconds = {
+            phase: m.counter(
+                "program_build_seconds_total",
+                "seconds JAX spent building functions, outermost phases "
+                "only (trace: Python to jaxpr; lower: jaxpr to MLIR; "
+                "compile: XLA / Mosaic, or the persistent cache's "
+                "retrieval)", phase=phase)
+            for phase in _BUILD_EVENTS.values()}
+        self._hits = m.counter(
+            "compile_cache_hits_total",
+            "executables the persistent compilation cache returned")
+        self._misses = m.counter(
+            "compile_cache_misses_total",
+            "executables compiled and written to the persistent cache")
+
+    def on_begin(self, event, value, **kwargs):
+        if event in _BUILD_EVENTS:
+            self._depth.n = getattr(self._depth, "n", 0) + 1
+
+    def on_event(self, event, **kwargs):
+        if event == _CACHE_HIT:
+            self._cache = "hit"
+            self._hits.inc()
+        elif event == _CACHE_MISS:
+            self._cache = "miss"
+            self._misses.inc()
+
+    def on_duration(self, event, duration, **kwargs):
+        if event == _CACHE_RETRIEVAL:
+            self._retrieval_s = duration
+        elif event == _BACKEND_COMPILE:
+            self.count += 1
+
+    def on_span(self, event, start, end, fun_name="", **kwargs):
+        phase = _BUILD_EVENTS.get(event)
+        if phase is None:
+            return
+        depth = self._depth.n = max(getattr(self._depth, "n", 1) - 1, 0)
+        args: Dict[str, Any] = {}
+        if phase == "compile":
+            # said since the compile before this one ended: this one's
+            args["cache"] = self._cache or "off"
+            if self._retrieval_s is not None and self._cache == "hit":
+                args["retrieval_s"] = self._retrieval_s
+            self._cache = self._retrieval_s = None
+        if depth:
+            return                          # inside the phase still open
+        from ..telemetry.trace import setup_timeline
+
+        timeline = setup_timeline()
+        fn = str(fun_name)
+        if fn.startswith("jit(") and fn.endswith(")"):
+            fn = fn[4:-1]
+        if timeline.building is not None:
+            args["program"] = timeline.building
+        self._seconds[phase].inc(end - start)
+        at = (start + self.offset_s - timeline.epoch_s) * 1e6
+        timeline.complete(phase, at, end_us=at + (end - start) * 1e6,
+                          fn=fn, **args)
 
 
-_counter: Optional[_CompileCounter] = None
+_listener: Optional[BuildListener] = None
 
 
-def install_compile_listener() -> _CompileCounter:
-    """Process-wide backend-compile counter through ``jax.monitoring``'s
-    duration events (idempotent; the listener is a string-prefix check per
-    compile — nothing on the step path)."""
-    global _counter
-    if _counter is None:
+def install_compile_listener() -> BuildListener:
+    """Register the process's :class:`BuildListener` with
+    ``jax.monitoring`` — the program's one registration site (idempotent;
+    both engines call it as they are constructed).  Its body runs when
+    something is being built and never in a step: a compiled program's call
+    reaches none of JAX's build phases."""
+    global _listener
+    if _listener is None:
         import jax.monitoring
 
-        counter = _CompileCounter()
-
-        def _on_duration(event, duration, **kwargs):
-            if event.startswith(_BACKEND_COMPILE_PREFIX):
-                counter.count += 1
-
-        jax.monitoring.register_event_duration_secs_listener(_on_duration)
-        _counter = counter
-    return _counter
+        _listener = listener = BuildListener()
+        jax.monitoring.register_scalar_listener(listener.on_begin)
+        jax.monitoring.register_event_listener(listener.on_event)
+        jax.monitoring.register_event_duration_secs_listener(
+            listener.on_duration)
+        jax.monitoring.register_event_time_span_listener(listener.on_span)
+    return _listener
 
 
 def backend_compiles() -> Optional[int]:
     """Compiles observed process-wide since the listener was installed
     (``None`` before :func:`install_compile_listener`)."""
-    return _counter.count if _counter is not None else None
+    return _listener.count if _listener is not None else None

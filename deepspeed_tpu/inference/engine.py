@@ -28,9 +28,10 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import comm as dist
-from ..analysis.sentry import RecompileSentry
+from ..analysis.sentry import RecompileSentry, install_compile_listener
 from ..parallel.topology import MeshTopology
 from ..runtime.model import ModelSpec
+from ..telemetry import trace as trace_mod
 from ..utils.logging import log_dist
 from ..utils.lru import LRUCache
 from ..utils.platform import host_cpu_device, on_tpu
@@ -128,10 +129,16 @@ class InferenceEngine:
         dist.configure(topology=self.topology)
         self.mesh = self.topology.mesh
 
-        if params is None:
-            # init_fn: immune to a user-held OnDevice('meta') context
-            params = model.init_fn(jax.random.PRNGKey(0))
-        params = _cast_floating_skip_records(params, config.jnp_dtype)
+        # the start-up ring (telemetry/trace.py setup_timeline): what the
+        # cast and the placement take, and every function JAX builds here
+        setup = trace_mod.setup_timeline()
+        install_compile_listener()
+        with setup.span("params_cast", dtype=str(config.dtype),
+                        given=params is not None):
+            if params is None:
+                # init_fn: immune to a user-held OnDevice('meta') context
+                params = model.init_fn(jax.random.PRNGKey(0))
+            params = _cast_floating_skip_records(params, config.jnp_dtype)
         tp_specs = model.tp_rules(jax.eval_shape(lambda: params)) \
             if model.tp_rules else None
         rep = NamedSharding(self.mesh, P())
@@ -301,8 +308,12 @@ class InferenceEngine:
         self._streamed = None
         if config.zero_inference.enabled:
             params, shardings = self._init_zero_inference(params, shardings)
-        self.params = jax.tree_util.tree_map(
-            lambda x, s: jax.device_put(x, s), params, shardings)
+        with setup.span("params_place") as placed:
+            self.params = jax.block_until_ready(jax.tree_util.tree_map(
+                lambda x, s: jax.device_put(x, s), params, shardings))
+            leaves = jax.tree_util.tree_leaves(self.params)
+            placed.update(bytes=sum(int(x.nbytes) for x in leaves),
+                          leaves=len(leaves))
 
         prepare = self._prepare
         # recompile sentry (analysis/sentry.py): forward legitimately

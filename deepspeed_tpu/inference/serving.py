@@ -299,8 +299,7 @@ from ..analysis.concurrency import (LockSanitizer, caller_site,
                                     ordered_condition)
 from ..analysis.invariants import audit_serving_engine
 from ..moe import routed
-from ..analysis.sentry import (RecompileSentry, backend_compiles,
-                               install_compile_listener)
+from ..analysis.sentry import RecompileSentry, backend_compiles
 from ..ops import (decode_attention, paged_kv, sp_attention,
                    sparse_index_attention)
 from ..ops import sampling as sampling_ops
@@ -308,6 +307,7 @@ from ..ops.decode_attention import VERIFY_T_MAX
 from ..ops.paged_kv import blocks_for
 from ..parallel.topology import DP_AXIS, SP_AXIS, TP_AXIS
 from ..telemetry import MetricsRegistry, ProfilerWindow, TraceTimeline
+from ..telemetry.metrics import process_registry
 from ..telemetry import trace as trace_mod
 from ..telemetry.slo import SLOTracker
 from ..utils.logging import log_dist, logger
@@ -1480,7 +1480,8 @@ class ServingEngine:
                 engine.mesh, P(None, None, TP_AXIS)) \
                 if self.kv_sharded else rep
         self._pool_sharding = pool_sharding
-        self._cache = self._commit_pool(mk_pool, pool_sharding)
+        self._cache = self._commit_pool(mk_pool, pool_sharding,
+                                        pool="target", blocks=num_blocks)
         # host-side block tables; entry 0 = scratch doubles as "unset"
         self._tables = np.zeros((self.slots, self._nbper), np.int32)
         self._held: List[List[int]] = [[] for _ in range(self.slots)]
@@ -1566,11 +1567,10 @@ class ServingEngine:
         # zero overhead, same contract as the sentry)
         self._lock_sanitizer = LockSanitizer() if self.debug_checks \
             else None
-        if self.debug_checks:
-            # process-wide jax.monitoring compile counter (idempotent):
-            # corroborates the sentry by also seeing programs built OUTSIDE
-            # registered entry points; surfaced as stats()["backend_compiles"]
-            install_compile_listener()
+        # (the process-wide jax.monitoring listener that corroborates the
+        # sentry — it also sees programs built OUTSIDE registered entry
+        # points, stats()["backend_compiles"] — came with the wrapped
+        # engine: inference/engine.py installs it, unconditionally)
 
         # ----- speculative decoding state
         self._draft = None                 # draft InferenceEngine
@@ -1635,7 +1635,8 @@ class ServingEngine:
                 # (the paged ops fall back per-shape — ops/paged_kv.py)
                 self._dcache_sharded = self.kv_sharded and d_div
                 dsharding = pool_sharding if self._dcache_sharded else rep
-                self._dcache = self._commit_pool(mk_dpool, dsharding)
+                self._dcache = self._commit_pool(
+                    mk_dpool, dsharding, pool="draft", blocks=num_blocks)
             else:
                 self._proposer = NGramProposer(self.spec_tokens,
                                                max_n=ngram_max,
@@ -1699,6 +1700,9 @@ class ServingEngine:
         # forever and re-sorted on every stats() call); _latencies keeps a
         # small deque of recent per-request records as a debug view.
         m = self.metrics = MetricsRegistry()
+        # what the process builds and its compile cache answers belongs to
+        # no engine: the process's registry rides in this one's exposition
+        m.include(process_registry())
         self._c_iterations = m.counter(
             "serving_iterations_total", "scheduler iterations run")
         self._c_decode_steps = m.counter(
@@ -2144,6 +2148,21 @@ class ServingEngine:
             path, process_name=f"serving:{self.engine.module.name}")
 
     # ------------------------------------------------------------ compiled fns
+    def _first_call(self, fn, program: str, holder, key, **sizes):
+        """Jitted ``fn`` under the name the sentry registered, its FIRST
+        call a ``build`` span of the start-up ring with ``sizes``
+        (``telemetry/trace.py FirstCall``).  That call over, the bare
+        function takes the wrapper's place, ``holder[key]``, and no later
+        call passes through it; the call that builds the LAST of a plain
+        or speculative engine's programs (``decode`` / ``verify``) also
+        logs the start-up line."""
+        def built(bare):
+            holder[key] = bare
+            if program in ("decode", "verify"):
+                log_dist(trace_mod.setup_line(), ranks=[0])
+
+        return trace_mod.FirstCall(fn, program, built, **sizes)
+
     def note_flow(self, uid, flow_id: int) -> None:
         """Register a Chrome flow id for a routed request: admission will
         emit the matching flow-finish (``f``) event, linking the router's
@@ -2285,12 +2304,14 @@ class ServingEngine:
         return (1,) if on_tpu() else ()
 
     @staticmethod
-    def _commit_pool(mk_pool, sharding):
+    def _commit_pool(mk_pool, sharding, **sizes):
         """Build a pool on its sharding, lane-packed (``ops/paged_kv.py``
         "Layout": the same bytes as the hook's ``[L, NB, HKV, bs, hd]``,
         in the view whose TPU layout the paged kernels read) — in one
         jitted program, so neither an unpacked nor an unsharded copy of it
-        ever exists."""
+        ever exists.  A ``pool`` span of the start-up ring, until the pool
+        is there: ``sizes`` (``pool``, ``blocks``) and its bytes, in all
+        and by kind where it has kinds."""
         def packed():
             cache = mk_pool()
             if not isinstance(cache, dict):
@@ -2299,7 +2320,18 @@ class ServingEngine:
             return {k: v if k in STATE_LEAVES else paged_kv.pack_pool(v)
                     for k, v in cache.items()}
 
-        return jax.jit(packed, out_shardings=sharding)()
+        with trace_mod.setup_timeline().span("pool", **sizes) as made:
+            pool = jax.block_until_ready(
+                jax.jit(packed, out_shardings=sharding)())
+
+            def nbytes(tree):
+                return sum(int(x.nbytes)
+                           for x in jax.tree_util.tree_leaves(tree))
+
+            made["bytes"] = nbytes(pool)
+            if isinstance(pool, dict):
+                made["kinds"] = {k: nbytes(v) for k, v in pool.items()}
+        return pool
 
     def _constrain_pool(self, cache):
         """dp_tp only: pin the cache OUTPUT of every decode/prefill program
@@ -2734,11 +2766,12 @@ class ServingEngine:
                     return flat, cache, pin(flat[:slots])
 
                 call, n_dev = decode_ahead, 3
-            self._decode_fn = jax.jit(
+            self._decode_fn = self._first_call(jax.jit(
                 self.sentry.wrap(
                     self._packed("decode", call, spec,
                                  device_operands=n_dev), "decode"),
-                donate_argnums=self._donate())
+                donate_argnums=self._donate()),
+                "decode", vars(self), "_decode_fn", slots=self.slots)
             self.compiled_programs.append(
                 ("decode", self.slots) if K == 1
                 else ("decode", self.slots, K))
@@ -2839,13 +2872,16 @@ class ServingEngine:
                 return (*out, pin(devtok.at[rest[at]].set(out[0][:j],
                                                           mode="drop")))
 
-            self._prefill_fns[j, width] = jax.jit(
+            self._prefill_fns[j, width] = self._first_call(jax.jit(
                 self.sentry.wrap(
                     self._packed(self._prefill_program((j, width)),
                                  prefill_ahead, spec,
                                  device_operands=n_dev + 1),
                     f"prefill[{self._rung_name((j, width))}]"),
-                donate_argnums=donate)
+                donate_argnums=donate),
+                f"prefill[{self._rung_name((j, width))}]",
+                self._prefill_fns, (j, width),
+                shape=self._rung_name((j, width)))
             self.compiled_programs.append(("prefill", width, j))
         return self._prefill_fns[rung]
 
@@ -2986,10 +3022,12 @@ class ServingEngine:
             self._program_bodies["verify"] = verify
             spec = self._operand_spec(self.slots, {"ids": k + 1},
                                       ("base", "valid"))
-            self._verify_fn = jax.jit(
+            self._verify_fn = self._first_call(jax.jit(
                 self.sentry.wrap(self._packed("verify", verify, spec),
                                  "verify"),
-                donate_argnums=self._donate())
+                donate_argnums=self._donate()),
+                "verify", vars(self), "_verify_fn",
+                slots=self.slots, window=k + 1)
             self.compiled_programs.append(
                 ("verify", self.slots, self.spec_tokens + 1))
         return self._verify_fn
@@ -3044,10 +3082,11 @@ class ServingEngine:
             self._program_bodies["draft"] = propose
             spec = self._operand_spec(
                 self.slots, {"tokens": None, "lengths": None})
-            self._draft_fn = jax.jit(
+            self._draft_fn = self._first_call(jax.jit(
                 self.sentry.wrap(self._packed("draft", propose, spec),
                                  "draft"),
-                donate_argnums=(1,) if self._donate() else ())
+                donate_argnums=(1,) if self._donate() else ()),
+                "draft", vars(self), "_draft_fn", slots=self.slots, tokens=k)
             self.compiled_programs.append(("draft", self.slots, k))
         return self._draft_fn
 
@@ -3090,9 +3129,11 @@ class ServingEngine:
             def kv_demote(cache, ids):
                 return paged_kv.paged_block_gather(cache, ids)
 
-            self._demote_fn = jax.jit(
+            self._demote_fn = self._first_call(jax.jit(
                 self.sentry.wrap(kv_demote, "kv_demote"),
-                donate_argnums=())        # the pool lives on
+                donate_argnums=()),       # the pool lives on
+                "kv_demote", vars(self), "_demote_fn",
+                blocks=self.swap_batch)
             self.compiled_programs.append(("kv_demote", self.swap_batch))
         return self._demote_fn
 
@@ -3103,9 +3144,11 @@ class ServingEngine:
             def kv_promote(cache, staged, ids):
                 return paged_kv.paged_block_scatter(cache, staged, ids)
 
-            self._promote_fn = jax.jit(
+            self._promote_fn = self._first_call(jax.jit(
                 self.sentry.wrap(kv_promote, "kv_promote"),
-                donate_argnums=(0,) if self._donate() else ())
+                donate_argnums=(0,) if self._donate() else ()),
+                "kv_promote", vars(self), "_promote_fn",
+                blocks=self.swap_batch)
             self.compiled_programs.append(("kv_promote", self.swap_batch))
         return self._promote_fn
 
@@ -4878,8 +4921,6 @@ class ServingEngine:
         if debug_checks is not None:
             self.debug_checks = bool(debug_checks)
             self.sentry.strict = self.debug_checks
-            if self.debug_checks:
-                install_compile_listener()
         requests = list(requests)
         if not requests:
             return {}
@@ -5753,8 +5794,12 @@ class ServingEngine:
             "invariant_checks_run": self.invariant_checks_run,
             "retraces_observed": self.sentry.retraces_observed,
             # process-wide, cumulative since the listener was installed
-            # (None until a debug_checks engine installs it)
+            # (the wrapped engine's construction installs it)
             "backend_compiles": backend_compiles(),
+            # the process's start-up ring in numbers: seconds by phase and
+            # by program, what else JAX built, what the compile cache
+            # answered (telemetry/trace.py setup_summary)
+            "setup": trace_mod.setup_summary(),
             "iterations": self.iterations,
             "decode_steps": self.decode_steps,
             "moe_expert_rows": int(self._c_moe_rows.value),

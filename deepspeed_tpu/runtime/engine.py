@@ -40,11 +40,13 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import comm as dist
-from ..analysis.sentry import RecompileSentry
+from ..analysis.sentry import RecompileSentry, install_compile_listener
 from ..ops.optimizers import get_optimizer
 from ..parallel.topology import (DATA_AXES, SP_AXIS, MeshTopology,
                                  topology_from_config)
 from ..telemetry import MetricsRegistry
+from ..telemetry import trace as trace_mod
+from ..telemetry.metrics import process_registry
 from ..telemetry.trace import annotation
 from ..utils.logging import log_dist, logger
 from ..utils.platform import host_cpu_device
@@ -151,6 +153,95 @@ class DeepSpeedEngine:
         assert isinstance(model, ModelSpec), (
             "model must be a deepspeed_tpu ModelSpec (see runtime/model.py); "
             "wrap flax modules with deepspeed_tpu.runtime.model.from_flax")
+        # the start-up ring (telemetry/trace.py setup_timeline): this
+        # constructor's phases as spans — inside ``initialize``, when that
+        # is what called it — and, from the listener, every function JAX
+        # builds in them
+        setup = trace_mod.setup_timeline()
+        install_compile_listener()
+        with setup.span("configure"):
+            self._configure(model, optimizer, lr_scheduler, training_data,
+                            collate_fn, config, config_class)
+
+        # sharded state
+        self._init_rng = jax.random.PRNGKey(self._config.seed or 42)
+        self._dropout_rng = jax.random.PRNGKey((self._config.seed or 42) + 1)
+        with setup.span("build_state") as built:
+            self._build_state()
+            jax.block_until_ready(self.state)
+            built.update(
+                n_params=sum(int(x.size) for x in jax.tree_util.tree_leaves(
+                    self.state["params"])),
+                **{f"{k}_bytes": sum(
+                    int(x.nbytes) for x in jax.tree_util.tree_leaves(
+                        self.state[k])) for k in ("params", "opt_state")})
+        with setup.span("build_step_fns"):
+            self._configure_stage3_liveness()
+            self._build_step_fns()
+
+        # data
+        self.training_dataloader = self.deepspeed_io(training_data) \
+            if training_data is not None else None
+        self._data_iterator: Optional[Iterator] = None
+
+        # timers/monitor/telemetry: one metrics registry backs the wall-
+        # clock timer histograms, the train loss/lr/throughput gauges, and
+        # the MonitorMaster event routing (_finalize_metrics writes the
+        # registry snapshot through the CSV/TensorBoard/W&B backends on
+        # report steps — telemetry/, docs/observability.md)
+        self.metrics = MetricsRegistry()
+        # what the process builds and its compile cache answers belongs to
+        # no engine: the process's registry rides in this one's exposition
+        self.metrics.include(process_registry())
+        self.timers = SynchronizedWallClockTimer(registry=self.metrics)
+        self.tput_timer = ThroughputTimer(
+            batch_size=self.train_batch_size(),
+            steps_per_output=self._config.steps_per_print or 10)
+        self.wall_clock_breakdown_enabled = self._config.wall_clock_breakdown
+        self._g_train_loss = self.metrics.gauge(
+            "train_loss", "last reported global-step loss",
+            monitor_name="Train/Samples/train_loss")
+        self._g_train_lr = self.metrics.gauge(
+            "train_lr", "last reported learning rate",
+            monitor_name="Train/Samples/lr")
+        # fp16-only: an unconditional family would emit a dead-constant
+        # loss_scale series (and CSV file) for every full-precision run
+        self._g_loss_scale = self.metrics.gauge(
+            "train_loss_scale", "fp16 dynamic loss scale",
+            monitor_name="Train/Samples/loss_scale") \
+            if self.fp16_enabled else None
+        self._g_samples_per_sec = self.metrics.gauge(
+            "train_samples_per_sec",
+            "running-average training throughput (ThroughputTimer)",
+            monitor_name="Train/Samples/throughput")
+        self._g_global_steps = self.metrics.gauge(
+            "train_global_steps", "optimizer steps completed")
+        from ..monitor.monitor import MonitorMaster
+
+        self.monitor = MonitorMaster(self._config.monitor_config)
+        self._metrics_server = None
+
+        # (imports the checkpoint library and starts its checkpointer)
+        with setup.span("checkpoint_manager"):
+            self.checkpoint_manager = CheckpointManager(self)
+
+        # micro-step accumulation buffers (forward/backward/step shim path)
+        self._accum_grads: Optional[PyTree] = None
+        self._accum_losses = []
+        self._pending_batch = None
+
+        log_dist(
+            f"DeepSpeedEngine: mesh={self.topology}, zero_stage={self.zero_stage}, "
+            f"dtype={self._config.precision_dtype}, "
+            f"micro_bs/chip={self.train_micro_batch_size_per_gpu()}, "
+            f"gas={self.gradient_accumulation_steps()}, "
+            f"global_bs={self.train_batch_size()}", ranks=[0])
+
+    def _configure(self, model, optimizer, lr_scheduler, training_data,
+                   collate_fn, config, config_class) -> None:
+        """The constructor's first phase, before anything is on a device:
+        the configuration, the mesh, what the precision, ZeRO, offload and
+        data-routing options ask for, the schedule and the optimizer."""
         dist.init_distributed()
 
         raw_config = config if config is not None else {}
@@ -361,66 +452,6 @@ class DeepSpeedEngine:
                            "exchange; the optimizer's frozen-variance "
                            "semantics still apply)")
 
-        # sharded state
-        self._init_rng = jax.random.PRNGKey(self._config.seed or 42)
-        self._dropout_rng = jax.random.PRNGKey((self._config.seed or 42) + 1)
-        self._build_state()
-        self._configure_stage3_liveness()
-        self._build_step_fns()
-
-        # data
-        self.training_dataloader = self.deepspeed_io(training_data) \
-            if training_data is not None else None
-        self._data_iterator: Optional[Iterator] = None
-
-        # timers/monitor/telemetry: one metrics registry backs the wall-
-        # clock timer histograms, the train loss/lr/throughput gauges, and
-        # the MonitorMaster event routing (_finalize_metrics writes the
-        # registry snapshot through the CSV/TensorBoard/W&B backends on
-        # report steps — telemetry/, docs/observability.md)
-        self.metrics = MetricsRegistry()
-        self.timers = SynchronizedWallClockTimer(registry=self.metrics)
-        self.tput_timer = ThroughputTimer(
-            batch_size=self.train_batch_size(),
-            steps_per_output=self._config.steps_per_print or 10)
-        self.wall_clock_breakdown_enabled = self._config.wall_clock_breakdown
-        self._g_train_loss = self.metrics.gauge(
-            "train_loss", "last reported global-step loss",
-            monitor_name="Train/Samples/train_loss")
-        self._g_train_lr = self.metrics.gauge(
-            "train_lr", "last reported learning rate",
-            monitor_name="Train/Samples/lr")
-        # fp16-only: an unconditional family would emit a dead-constant
-        # loss_scale series (and CSV file) for every full-precision run
-        self._g_loss_scale = self.metrics.gauge(
-            "train_loss_scale", "fp16 dynamic loss scale",
-            monitor_name="Train/Samples/loss_scale") \
-            if self.fp16_enabled else None
-        self._g_samples_per_sec = self.metrics.gauge(
-            "train_samples_per_sec",
-            "running-average training throughput (ThroughputTimer)",
-            monitor_name="Train/Samples/throughput")
-        self._g_global_steps = self.metrics.gauge(
-            "train_global_steps", "optimizer steps completed")
-        from ..monitor.monitor import MonitorMaster
-
-        self.monitor = MonitorMaster(self._config.monitor_config)
-        self._metrics_server = None
-
-        self.checkpoint_manager = CheckpointManager(self)
-
-        # micro-step accumulation buffers (forward/backward/step shim path)
-        self._accum_grads: Optional[PyTree] = None
-        self._accum_losses = []
-        self._pending_batch = None
-
-        log_dist(
-            f"DeepSpeedEngine: mesh={self.topology}, zero_stage={self.zero_stage}, "
-            f"dtype={self._config.precision_dtype}, "
-            f"micro_bs/chip={self.train_micro_batch_size_per_gpu()}, "
-            f"gas={self.gradient_accumulation_steps()}, "
-            f"global_bs={self.train_batch_size()}", ranks=[0])
-
     # ------------------------------------------------------------------ config
     def train_batch_size(self) -> int:
         return self._config.train_batch_size
@@ -509,7 +540,9 @@ class DeepSpeedEngine:
     def _build_state(self) -> None:
         if self.param_stream_enabled:
             self._build_state_streamed()
-            self._init_offload_optimizer()
+            with trace_mod.setup_timeline().span("optimizer_state",
+                                                 where="host"):
+                self._init_offload_optimizer()
             return
 
         def onebit_errors(params):
@@ -570,7 +603,9 @@ class DeepSpeedEngine:
         log_dist(f"initialized {n_params/1e6:.2f}M parameters", ranks=[0])
 
         if self.offload_enabled:
-            self._init_offload_optimizer()
+            with trace_mod.setup_timeline().span("optimizer_state",
+                                                 where="host"):
+                self._init_offload_optimizer()
 
     def _configure_stage3_liveness(self) -> None:
         """Map ``stage3_prefetch_bucket_size`` / ``stage3_max_live_parameters``
@@ -974,6 +1009,29 @@ class DeepSpeedEngine:
 
         return self.sentry.wrap(traced, name, budget)
 
+    def _first_call(self, fn, program: str, attr: str):
+        """Jitted step ``fn`` under the name the sentry registered, its
+        FIRST call a ``build`` span of the start-up ring
+        (``telemetry/trace.py FirstCall``: trace, lowering, compile, load
+        and the first step, until the new state is there).  That call
+        over, the bare function takes the wrapper's place (``attr``), so
+        no later step passes through it, and the engine logs the start-up
+        line."""
+        def built(bare):
+            setattr(self, attr, bare)
+            log_dist(trace_mod.setup_line(), ranks=[0])
+
+        return trace_mod.FirstCall(
+            fn, program, built, gas=self.gradient_accumulation_steps(),
+            micro_batch=self.train_micro_batch_size_per_gpu())
+
+    def setup_report(self) -> Optional[Dict[str, Any]]:
+        """The process's start-up ring in numbers — seconds by phase of
+        ``initialize`` and by program, what else JAX built and where, what
+        the compile cache answered (``telemetry/trace.py
+        setup_summary``)."""
+        return trace_mod.setup_summary()
+
     def _build_step_fns(self) -> None:
         # recompile sentry (analysis/sentry.py): the config pins batch
         # shapes, so the fused train step compiles exactly once (budget 1)
@@ -1098,14 +1156,14 @@ class DeepSpeedEngine:
 
             return jax.lax.scan(body, state, batches)
 
-        self._train_multi_fn = jax.jit(
+        self._train_multi_fn = self._first_call(jax.jit(
             self._step_entry(multi_step, "train_multi", budget=None),
             out_shardings=(self.state_shardings, metrics_shardings),
-            donate_argnums=(0,))
-        self._train_step_fn = jax.jit(
+            donate_argnums=(0,)), "train_multi", "_train_multi_fn")
+        self._train_step_fn = self._first_call(jax.jit(
             self._step_entry(train_step, "train_step"),
             out_shardings=(self.state_shardings, metrics_shardings),
-            donate_argnums=(0,))
+            donate_argnums=(0,)), "train_step", "_train_step_fn")
         if self.onebit_comm_enabled and self._onebit_compressed:
             self._install_onebit_step(metrics_shardings)
         if self.offload_enabled:
@@ -1228,14 +1286,14 @@ class DeepSpeedEngine:
             return jax.lax.scan(
                 lambda st, b: train_step(st, b, base_rng), state, batches)
 
-        self._train_step_fn = jax.jit(
+        self._train_step_fn = self._first_call(jax.jit(
             self._step_entry(train_step, "train_step_onebit"),
             out_shardings=(self.state_shardings, metrics_shardings),
-            donate_argnums=(0,))
-        self._train_multi_fn = jax.jit(
+            donate_argnums=(0,)), "train_step_onebit", "_train_step_fn")
+        self._train_multi_fn = self._first_call(jax.jit(
             self._step_entry(multi_step, "train_multi_onebit", budget=None),
             out_shardings=(self.state_shardings, metrics_shardings),
-            donate_argnums=(0,))
+            donate_argnums=(0,)), "train_multi_onebit", "_train_multi_fn")
 
     # ---------------------------------------------------------------- batching
     def _batch_sharding(self, leading_gas_dim, x=None):

@@ -326,10 +326,10 @@ class PipelineEngine(DeepSpeedEngine):
             p = _cast_floating(params, compute_dtype) if cast else params
             return self.model_spec.loss_fn(p, batch, base_rng, False)
 
-        self._train_step_fn = jax.jit(
+        self._train_step_fn = self._first_call(jax.jit(
             train_step,
             out_shardings=(self.state_shardings, self._metrics_shardings()),
-            donate_argnums=(0,))
+            donate_argnums=(0,)), "train_step", "_train_step_fn")
         self._eval_step_fn = jax.jit(eval_step)
         self._micro_grads_fn = None
         self._apply_update_fn = None

@@ -43,7 +43,7 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "DEFAULT_TIME_BUCKETS_S",
+    "DEFAULT_TIME_BUCKETS_S", "process_registry",
 ]
 
 #: default histogram bounds for second-denominated latencies: log-spaced
@@ -228,6 +228,19 @@ class MetricsRegistry:
         # leaves that never take another lock (docs/static_analysis.md
         # "graft-race").
         self._reg_lock = threading.Lock()
+        #: registries whose families this one's reader walks show after
+        #: its own (:meth:`include`)
+        self._included: List["MetricsRegistry"] = []
+
+    def include(self, other: "MetricsRegistry") -> None:
+        """Show ``other``'s families after this registry's own in
+        ``snapshot()`` / ``prometheus_text()`` / ``to_events()`` — how an
+        engine's exposition carries the process's families
+        (:func:`process_registry`) without owning them.  ``families()``,
+        which a federation copies from, stays this registry's own: the
+        fleet names the process registry as a source of its own."""
+        if other is not self and other not in self._included:
+            self._included.append(other)
 
     # ------------------------------------------------------------- creation
     def _get(self, name: str, kind: str, help: str,
@@ -295,8 +308,11 @@ class MetricsRegistry:
         iterate dicts a worker thread is inserting into); cell reads
         then happen lock-free outside it."""
         with self._reg_lock:
-            return [(fam, list(fam.series.items()))
+            walk = [(fam, list(fam.series.items()))
                     for fam in self._families.values()]
+        for other in self._included:
+            walk += other._walk()
+        return walk
 
     def snapshot(self) -> Dict[str, Any]:
         """JSON-able view of every series (the ``--emit-metrics`` bench
@@ -371,3 +387,19 @@ class MetricsRegistry:
                 else:
                     events.append((name, cell.value, step))
         return events
+
+
+_PROCESS: Optional[MetricsRegistry] = None
+
+
+def process_registry() -> MetricsRegistry:
+    """The ONE registry of what belongs to the process and to no engine —
+    what JAX spent building functions and what the persistent compile
+    cache answered (``analysis/sentry.py BuildListener`` owns the
+    families).  Every engine's registry includes it
+    (:meth:`MetricsRegistry.include`), so ``srv.metrics.prometheus_text()``
+    and the training engine's ``/metrics`` carry it."""
+    global _PROCESS
+    if _PROCESS is None:
+        _PROCESS = MetricsRegistry()
+    return _PROCESS

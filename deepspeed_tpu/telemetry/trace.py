@@ -67,8 +67,9 @@ import jax
 
 from ..utils.logging import logger
 
-__all__ = ["TraceTimeline", "ProfilerWindow", "GcWatch",
-           "validate_chrome_trace", "annotation", "keep", "kept"]
+__all__ = ["TraceTimeline", "ProfilerWindow", "GcWatch", "FirstCall",
+           "validate_chrome_trace", "annotation", "keep", "kept",
+           "setup_timeline", "setup_summary", "setup_line"]
 
 #: tid of the shared scheduler lane (request lanes are allocated upward)
 SCHEDULER_TID = 0
@@ -80,6 +81,19 @@ SCHEDULER_TID = 0
 #: dicts and a few floats: see ``docs/observability.md`` for the measured
 #: footprint of a full ring
 DEFAULT_CAPACITY = 131072
+
+#: events the process's start-up ring holds (:func:`setup_timeline`).  The
+#: requirement: everything one process builds before its first steps —
+#: JAX hands over three events a function it builds (``trace`` / ``lower``
+#: / ``compile``), an engine's start builds some hundreds of functions one
+#: by one (eager operations, initialisers, casts: ~300 in the largest
+#: serving cell) beside its few programs and two dozen spans, and a process
+#: may hold a router's worth of engines (four) — 4 x ~1,000 events,
+#: doubled and rounded up to a power of two
+SETUP_CAPACITY = 8192
+
+#: the JAX events the start-up ring holds, by phase of a function's build
+BUILD_PHASES = ("trace", "lower", "compile")
 
 #: role -> the newest timeline built for it (see :func:`keep`)
 _KEPT: Dict[str, "TraceTimeline"] = {}
@@ -148,6 +162,68 @@ def kept(role: str) -> Optional["TraceTimeline"]:
     return _KEPT.get(role)
 
 
+def setup_timeline(epoch_s: Optional[float] = None) -> "TraceTimeline":
+    """The process's ONE start-up ring — ``kept("setup")``, role ``setup``,
+    :data:`SETUP_CAPACITY` events — made by whoever asks first: the
+    package's import (which hands it the clock read at its top as
+    ``epoch_s``, so that ``import`` starts at 0), an engine's construction
+    or ``analysis/sentry.install_compile_listener``.  It holds what happens
+    BEFORE an engine's first steps: the engines' own boundaries as spans
+    (``import``, ``init_serving``, ``initialize``, ``build`` ...; the table
+    is in ``docs/observability.md`` "Start-up") and, from the listener,
+    every function JAX builds as ``trace`` / ``lower`` / ``compile``
+    X-events.  Nothing is pushed unless something is being built, so a
+    step pays nothing for it."""
+    timeline = _KEPT.get("setup")
+    if timeline is None:
+        timeline = TraceTimeline(capacity=SETUP_CAPACITY)
+        timeline.role = "setup"
+        if epoch_s is not None:
+            timeline._t0 = epoch_s
+        keep("setup", timeline)
+    return timeline
+
+
+class FirstCall:
+    """A jitted function whose FIRST call is a ``build`` span of the
+    start-up ring: from the call's entry to its results being ready, with
+    ``program`` (the name the engine's sentry registered) and whatever
+    sizes it (``args``).  While the span is open the ring says which
+    program is being built (``TraceTimeline.building``), and the listener
+    stamps it on the ``trace`` / ``lower`` / ``compile`` events JAX hands
+    over: they join the span by name as well as by time.  What is left of
+    the span beside them is the executable's load onto the chip and the
+    first execution.  ``then(fn)``, if given, is called with the bare
+    function once the first call is over — the engine puts it where this
+    wrapper was, so no later call passes through here; one that does (a
+    reference taken before the first call) is handed straight on.
+    Attributes (``lower``, ``_cache_size``) are the function's own."""
+
+    __slots__ = ("_fn", "_program", "_args", "_then", "_called")
+
+    def __init__(self, fn, program: str, then=None, **args):
+        self._fn, self._program, self._args = fn, program, args
+        self._then, self._called = then, False
+
+    def __call__(self, *args, **kwargs):
+        if self._called:
+            return self._fn(*args, **kwargs)
+        self._called = True
+        timeline = setup_timeline()
+        with timeline.span("build", program=self._program, **self._args):
+            outer, timeline.building = timeline.building, self._program
+            try:
+                out = jax.block_until_ready(self._fn(*args, **kwargs))
+            finally:
+                timeline.building = outer
+        if self._then is not None:
+            self._then(self._fn)
+        return out
+
+    def __getattr__(self, name):
+        return getattr(self._fn, name)
+
+
 class TraceTimeline:
     """Bounded ring buffer of trace events with Chrome export.
 
@@ -160,13 +236,19 @@ class TraceTimeline:
                ``jax.process_index()`` so merged traces stay distinct).
     clock:     second-denominated monotonic clock (injectable for tests).
 
-    ``role`` names the spans' profiler annotations (``ds.<role>.<name>``);
-    ``step``, once its owner sets it, is stamped on every ``X`` event as
-    ``args["step"]`` — the scheduler iteration that caused the span.
+    ``role`` names the spans' profiler annotations (``ds.<role>.<name>``:
+    ``serve`` for an engine's ring, ``setup`` for the process's start-up
+    ring, :func:`setup_timeline`); ``step``, once its owner sets it, is
+    stamped on every ``X`` event as ``args["step"]`` — the scheduler
+    iteration that caused the span.
     """
 
     role = "serve"
     step: Optional[int] = None
+    #: the program whose ``build`` span is open (:class:`FirstCall`)
+    building: Optional[str] = None
+    #: :func:`setup_summary` of the ring as it stood after that many events
+    summarized: tuple = (None, None)
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY, pid: int = 0,
                  clock=None):
@@ -505,6 +587,165 @@ def validate_chrome_trace(doc: Dict[str, Any],
     summary["handoff_unmatched"] = orphan_handoffs + \
         sum(handoff_parked.values())
     return summary
+
+
+def _nest(spans: List[Dict[str, Any]]) -> None:
+    """Give each span (``t0`` / ``t1``, seconds) its ``parent``: the
+    innermost other span that holds its midpoint — spans of one thread nest
+    by time, and a midpoint forgives the clock conversion of the events JAX
+    timed (``analysis/sentry.py``) a millisecond at either end."""
+    open_: List[Dict[str, Any]] = []
+    for e in sorted(spans, key=lambda e: (e["t0"], -e["t1"])):
+        mid = (e["t0"] + e["t1"]) / 2
+        while open_ and not open_[-1]["t0"] <= mid <= open_[-1]["t1"]:
+            open_.pop()
+        e["parent"] = open_[-1] if open_ else None
+        open_.append(e)
+
+
+def setup_summary() -> Optional[Dict[str, Any]]:
+    """The start-up ring in numbers an operator reads without Perfetto
+    (``ServingEngine.stats()["setup"]``, ``DeepSpeedEngine.setup_report()``;
+    None before anything made the ring):
+
+    ``phases``      seconds by top-level span (``import``, ``init_serving``,
+                    ``initialize``, ``build`` — the programs' first calls
+                    after their engine was made)
+    ``within``      each top-level span other than ``build`` split into the
+                    spans directly inside it by name, ``jit`` (functions
+                    JAX built directly inside it, below) and ``self``: the
+                    parts sum to the phase
+    ``programs``    by registered program: ``trace_s`` / ``lower_s`` /
+                    ``compile_s`` of the events its ``build`` span holds,
+                    ``cache`` (``hit`` | ``miss`` | ``off``),
+                    ``retrieval_s`` of a hit, ``first_run_s`` (the span less
+                    those events: the load and the first execution) and
+                    ``build_s``, the span
+    ``other_jit``   what JAX spent building functions that are NOT a
+                    registered program (eager operations, initialisers,
+                    casts, a driver's own jits), by the innermost span they
+                    fell in (``(outside)``: in none): ``seconds``,
+                    ``functions`` and the three ``slowest``
+    ``cache``       ``hits`` / ``misses`` / ``off`` over every ``compile``
+                    event, ``retrieval_s``, and ``missed``: the functions
+                    that missed.  ``warm`` says something hit: a start that
+                    is warm AND misses has a cache key that does not hold
+                    from one start to the next, and ``missed`` names it
+    ``events`` / ``dropped``   the ring's fill and what fell off it
+
+    The ring holds only the OUTERMOST event of each build (``jnp``
+    functions called while a program is traced are themselves traced; the
+    listener drops them), so every JAX event's parent here is a span.
+    Computed again only when the ring has grown."""
+    timeline = _KEPT.get("setup")
+    if timeline is None:
+        return None
+    if timeline.summarized[0] == timeline.emitted:
+        return timeline.summarized[1]
+    emitted = timeline.emitted
+    events = [{**e, "t0": e["ts"] * 1e-6,
+               "t1": (e["ts"] + e["dur"]) * 1e-6,
+               "args": e.get("args", {})}
+              for e in timeline.events() if e["ph"] == "X"]
+    _nest(events)
+    spans = [e for e in events if e["name"] not in BUILD_PHASES]
+    built = [e for e in events if e["name"] in BUILD_PHASES]
+
+    def seconds(e):
+        return e["t1"] - e["t0"]
+
+    phases: Dict[str, float] = {}
+    within: Dict[str, Dict[str, float]] = {}
+    for top in (e for e in spans if e["parent"] is None):
+        phases[top["name"]] = phases.get(top["name"], 0.0) + seconds(top)
+        if top["name"] == "build":
+            continue
+        parts = within.setdefault(top["name"], {"jit": 0.0, "self": 0.0})
+        inside = 0.0
+        for e in spans:
+            if e["parent"] is top:
+                parts[e["name"]] = parts.get(e["name"], 0.0) + seconds(e)
+                inside += seconds(e)
+        jit = sum(seconds(e) for e in built if e["parent"] is top)
+        parts["jit"] += jit
+        parts["self"] += seconds(top) - inside - jit
+    programs: Dict[str, Dict[str, Any]] = {}
+    for span in (e for e in spans if e["name"] == "build"):
+        name = span["args"].get("program", "?")
+        row = programs.setdefault(name, {
+            "trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0,
+            "cache": "off", "first_run_s": 0.0, "build_s": 0.0})
+        mine = [e for e in built if e["parent"] is span]
+        for e in mine:
+            row[e["name"] + "_s"] += seconds(e)
+            if e["name"] == "compile":
+                row["cache"] = e["args"].get("cache", "off")
+                if "retrieval_s" in e["args"]:
+                    row["retrieval_s"] = row.get("retrieval_s", 0.0) \
+                        + e["args"]["retrieval_s"]
+        row["build_s"] += seconds(span)
+        row["first_run_s"] += seconds(span) - sum(seconds(e) for e in mine)
+    other: Dict[str, Dict[str, Any]] = {}
+    for e in built:
+        at = e["parent"]
+        if at is not None and at["name"] == "build":
+            continue
+        row = other.setdefault(at["name"] if at else "(outside)",
+                               {"seconds": 0.0, "by_fn": {}})
+        row["seconds"] += seconds(e)
+        fn = e["args"].get("fn", "?")
+        row["by_fn"][fn] = row["by_fn"].get(fn, 0.0) + seconds(e)
+    for row in other.values():
+        by_fn = row.pop("by_fn")
+        row["functions"] = len(by_fn)
+        row["slowest"] = sorted(by_fn.items(), key=lambda kv: -kv[1])[:3]
+    compiles = [e for e in built if e["name"] == "compile"]
+    how = [e["args"].get("cache", "off") for e in compiles]
+    cache = {"hits": how.count("hit"), "misses": how.count("miss"),
+             "off": how.count("off"),
+             "retrieval_s": sum(e["args"].get("retrieval_s", 0.0)
+                                for e in compiles),
+             "missed": sorted({e["args"].get("fn", "?") for e in compiles
+                               if e["args"].get("cache") == "miss"})}
+    cache["warm"] = cache["hits"] > 0
+    summary = {"phases": phases, "within": within, "programs": programs,
+               "other_jit": other, "cache": cache,
+               "events": len(timeline), "dropped": timeline.dropped}
+    timeline.summarized = (emitted, summary)
+    return summary
+
+
+def setup_line(summary: Optional[Dict[str, Any]] = None) -> str:
+    """:func:`setup_summary` as the ONE line an engine logs once its
+    programs have run (seconds, one decimal past the millisecond)."""
+    summary = summary or setup_summary() or {}
+
+    def s(x):
+        return f"{x:.3f}"
+
+    parts = [f"{name} {s(sec)} s" + (" (" + ", ".join(
+        f"{k} {s(v)}" for k, v in summary["within"][name].items()) + ")"
+        if name in summary.get("within", {}) else "")
+        for name, sec in summary.get("phases", {}).items()
+        if name != "build"]
+    for name, row in summary.get("programs", {}).items():
+        parts.append(
+            f"{name}: trace {s(row['trace_s'])} + lower "
+            f"{s(row['lower_s'])} + compile {s(row['compile_s'])} "
+            f"(cache {row['cache']}) + first run {s(row['first_run_s'])}")
+    jit = summary.get("other_jit", {})
+    if jit:
+        parts.append("other functions built: " + ", ".join(
+            f"{where} {s(row['seconds'])} s in {row['functions']}"
+            for where, row in jit.items()))
+    cache = summary.get("cache", {})
+    if cache:
+        parts.append(f"compile cache {cache['hits']} hits, "
+                     f"{cache['misses']} misses, {cache['off']} uncached")
+        if cache["warm"] and cache["missed"]:
+            parts.append("MISSED on a warm start (a cache key that does "
+                         "not hold): " + ", ".join(cache["missed"][:8]))
+    return "start-up: " + "; ".join(parts)
 
 
 class GcWatch:

@@ -73,6 +73,9 @@ ENGINE_STATS_KEYS = frozenset({
     # the resets, which body each program's delta rule lowered to, the
     # refusals (None otherwise)
     "kv_state",
+    # PR 53: the process's start-up ring in numbers
+    # (telemetry/trace.py setup_summary)
+    "setup",
     # PR 28: routed (token, expert) rows and experts touched, summed over
     # layers and program calls; 0 for a dense model
     "moe_expert_rows", "moe_experts_touched",
