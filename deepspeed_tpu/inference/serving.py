@@ -36,20 +36,23 @@ serving optimisations on top of continuous batching:
    blocks).
  - **Chunked prefill**: prompts advance through the cache in calls of a
    fixed BUDGET of tokens (``prefill_batch * prefill_chunk``) interleaved
-   with decode steps.  A call holding several ready rows is
-   ``[prefill_batch, prefill_chunk]``; one whose row is alone gives the
-   pad rows' tokens to it (``[1, prefill_batch * prefill_chunk]``:
-   :func:`prefill_ladder`), so the weights are read once per budget of
-   REAL prompt tokens.  The whole serving loop compiles exactly **1
+   with decode steps.  A call is ``[prefill_batch, prefill_chunk]`` or
+   ``[1, prefill_batch * prefill_chunk]`` (:func:`prefill_ladder`),
+   whichever carries more REAL prompt tokens of its group's ready rows: a
+   row alone gets the pad rows' tokens, two or three long rows take turns
+   at the wide row (``ServingEngine._rung_for``; a row waits at most
+   ``prefill_batch - 1`` calls), so the weights are read once per budget
+   of real prompt tokens.  The whole serving loop compiles exactly **1
    decode program + 1 prefill program a rung** (two rungs), all built and
    run once (on pad rows) at the first prefill call, regardless of trace
    shape.
 
 Scheduling is iteration-level and strict-FIFO as before: every iteration
 admits waiting requests into free slots (gated on block availability —
-the queue head blocks admission, no starvation), advances every
-prefilling slot by one chunk, then runs one single-token decode step over
-all slots with per-sequence positions (``lengths: int32[B]``).
+the queue head blocks admission, no starvation), makes one prefill call
+for every group of ``prefill_batch`` prefilling slots, then runs one
+single-token decode step over all slots with per-sequence positions
+(``lengths: int32[B]``).
 ``compile_count`` / ``compiled_programs`` remain the compile probe;
 ``stats()`` adds prefix-hit, block-occupancy, and preemption counters.
 
@@ -516,13 +519,15 @@ _LANDMARK_BLOCKS = 1
 
 def prefill_ladder(batch: int, chunk: int, refuses):
     """The shapes ``(rows, width)`` a prefill call's budget of ``batch *
-    chunk`` tokens is cut into: ``(batch, chunk)`` and, for a row that is
-    alone in its group, ``(1, batch * chunk)`` — unless ``refuses(width)``
-    names a reason.  -> ``(rungs, why)``: ``why`` is what refused the wide
-    row (None: it is there, or ``batch`` is 1).  The rungs between the two
+    chunk`` tokens is cut into: ``(batch, chunk)`` and, for a row that runs
+    alone, ``(1, batch * chunk)`` — unless ``refuses(width)`` names a
+    reason.  -> ``(rungs, why)``: ``why`` is what refused the wide row
+    (None: it is there, or ``batch`` is 1).  The rungs between the two
     (``(batch / 2, 2 * chunk)``, ..) are not built: every rung is a program
     of its own, traced and lowered at set-up, and that is seconds of every
-    engine's start (docs/inference.md "Chunked prefill")."""
+    engine's start; a short group's rows take turns at the wide row
+    instead (``ServingEngine._rung_for``; docs/inference.md "Chunked
+    prefill")."""
     rungs, why = [(batch, chunk)], None
     if batch > 1:
         why = refuses(batch * chunk)
@@ -772,6 +777,10 @@ class _SlotState:
     #: yet, but the cache length, the sampler's count and the budget of the
     #: next call are planned as if they were
     ahead: int = 0
+    #: prefill calls of this row's group in a row that it was ready for and
+    #: did not run in (``ServingEngine._rung_for``): its place in the
+    #: group's turn order, at most ``prefill_batch - 1``
+    waited: int = 0
 
     @property
     def plen_eff(self) -> int:
@@ -834,7 +843,8 @@ class ServingEngine:
                     ``prefill_batch`` of them.
     prefill_batch:  the MOST sequences a prefill call holds.  The product
                     ``prefill_batch * prefill_chunk`` is a call's budget of
-                    tokens: a row that is alone in its call runs ``[1,
+                    tokens: a row that is alone in its group, or whose
+                    turn it is among two or three long ones, runs ``[1,
                     prefill_batch * prefill_chunk]`` and advances by what
                     the pad rows of ``[prefill_batch, prefill_chunk]``
                     would have wasted.  The wide rung exists only where
@@ -1203,8 +1213,8 @@ class ServingEngine:
             if self.resident_window_blocks else 0
 
         # ----- the prefill call is a BUDGET of prefill_batch x prefill_chunk
-        # tokens, cut into the fewest rows that hold a group's ready rows
-        # (:meth:`_run_prefill`): every rung of the ladder is a program
+        # tokens, at the rung that carries most of a group's ready rows
+        # (:meth:`_rung_for`): every rung of the ladder is a program
         #: ``(rows, width)`` of each prefill program, ``(prefill_batch,
         #: prefill_chunk)`` first, the wide row last; and what refused
         #: the wide row (None: it is there, or the batch is 1)
@@ -1723,6 +1733,10 @@ class ServingEngine:
                 "prefill program invocations, by the call's rows x width",
                 shape=self._rung_name(rung))
             for rung in self._rungs}
+        self._c_prefill_turns = m.counter(
+            "serving_prefill_turns_total",
+            "prefill calls whose group had more ready rows than the call's "
+            "shape holds: the others waited their turn")
         self._c_prefill_tokens = m.counter(
             "serving_prefill_call_tokens_total",
             "real prompt tokens the prefill calls advanced their rows by")
@@ -5390,11 +5404,11 @@ class ServingEngine:
     # ---------------------------------------------------------------- prefill
     def _run_prefill(self, params) -> int:
         """Advance prefilling slots, in admission order ``prefill_batch``
-        rows a call; a call is a BUDGET of ``prefill_batch * prefill_chunk``
-        tokens, cut into the fewest rows of the ladder that hold its group
-        (:meth:`_rung_for`), so a short group's rows advance by what its
-        pad rows would have wasted; pad rows write to scratch.  Returns
-        the number of prefill calls made."""
+        rows a group and one call a group; a call is a BUDGET of
+        ``prefill_batch * prefill_chunk`` tokens at the rung of the ladder
+        that carries the most REAL tokens (:meth:`_rung_for`), and the rows
+        of the group a narrower rung cannot hold wait their turn; pad rows
+        write to scratch.  Returns the number of prefill calls made."""
         active = self._active
         with self.timeline.segment("step.prefill.plan", self._phase):
             pre = [s for s, st in sorted(active.items(),
@@ -5424,23 +5438,51 @@ class ServingEngine:
                 calls += 1
         return calls
 
-    def _rung_for(self, rows: int):
-        """The rung of the ladder with the fewest rows that holds ``rows``
-        ready rows: the widest rows the call's budget gives them."""
-        return next(rung for rung in reversed(self._rungs)
-                    if rung[0] >= rows)
+    def _rung_for(self, group):
+        """The shape of ``group``'s call and who runs in it: ``(rung,
+        rows)``.  The rows take TURNS — whoever has been passed over most
+        calls in a row first (``waited``), admission order between equals —
+        and every rung ``(j, w)`` is reckoned by what its call would carry,
+        ``min(w, prompt left)`` summed over the first ``j`` rows in turn:
+        the rung that carries most runs, on a tie the one with more rows.
+        A group of two or three long rows so gives one of them the whole
+        budget (``[1, prefill_batch * prefill_chunk]``) call after call, a
+        group that is full, or nearly done, shares ``[prefill_batch,
+        prefill_chunk]``, and a ladder of one rung has nothing to choose.
+
+        Waiting is bounded by construction: a row that is passed over moves
+        ahead of every row that ran, so a group of ``n`` passes a row over
+        at most ``n - 1`` calls in a row; and because a group's members
+        change as rows finish around it, a rung is not eligible unless it
+        runs every row that has waited ``prefill_batch - 1`` calls (the
+        first rung holds any group): no ready row is passed over more than
+        ``prefill_batch - 1`` calls in a row.  ``group`` is, and ``rows``
+        come back, in admission order."""
+        active = self._active
+        turn = sorted(group, key=lambda s: -active[s].waited)
+        left = [active[s].plen_eff - active[s].base for s in turn]
+        must = sum(active[s].waited >= self.prefill_batch - 1 for s in turn)
+        rung = max((r for r in self._rungs if r[0] >= must),
+                   key=lambda r: (sum(min(r[1], n) for n in left[:r[0]]),
+                                  r[0]))
+        runs = set(turn[:rung[0]])
+        return rung, [s for s in group if s in runs]
 
     def _run_prefill_group(self, group, params) -> bool:
-        """One prefill call, at the shape ``[rows, width]`` of the group's
-        rung: each row advances its slot by ``min(width, remaining
-        prompt)`` tokens from its own base.  Rows whose window reaches the
-        last prompt token yield that slot's first generated token (logits
-        are gathered per row at ``valid - 1``).  False if no row of the
-        group was left to run (each lost its blocks to an earlier one)."""
+        """One prefill call for ``group``'s ready rows, at the shape
+        ``[rows, width]`` and for the rows :meth:`_rung_for` names: each
+        advances its slot by ``min(width, remaining prompt)`` tokens from
+        its own base; the others keep their blocks and their phase and wait
+        (``waited``).  Rows whose window reaches the last prompt token
+        yield that slot's first generated token (logits are gathered per
+        row at ``valid - 1``).  False if no row was left to run (each lost
+        its blocks to an earlier one)."""
         active = self._active
         seg, phase = self.timeline.segment, self._phase
-        rung = j, width = self._rung_for(len(group))
         with seg("step.prefill.plan", phase):
+            ready = group
+            rung, group = self._rung_for(ready)
+            j, width = rung
             if width > self.prefill_chunk:
                 # the blocks of the wider rows (:meth:`_run_prefill` made
                 # sure of a ``prefill_chunk`` each); a later row may lose
@@ -5453,6 +5495,10 @@ class ServingEngine:
                 group = [s for s in group if s in active]
                 if not group:
                     return False
+            for slot in ready:
+                if slot in active:
+                    st = active[slot]
+                    st.waited = 0 if slot in group else st.waited + 1
             ids = np.zeros((j, width), np.int32)
             bt = np.zeros((j, self._nbper), np.int32)
             base = np.zeros(j, np.int32)
@@ -5468,7 +5514,7 @@ class ServingEngine:
                 rows.append((slot, v))
             prefill_fn = self._get_prefill_fn(rung)
             span_kw = {
-                "width": width, "rows": len(group),
+                "width": width, "rows": len(group), "ready": len(ready),
                 "shape": self._rung_name(rung), "tokens": int(valid.sum()),
                 "slots": list(map(int, group)),
                 # blocks the rows' reads walk: cdiv(base + valid, bs) each
@@ -5509,6 +5555,7 @@ class ServingEngine:
                 "prefill", dict(**puts, **span_kw), (j,),
                 functools.partial(self._commit_prefill_group, rung,
                                   len(group), int(valid.sum()),
+                                  int(len(ready) > j),
                                   self._advance_prefill_rows(rows)))
             flight.held = args
             del args
@@ -5549,16 +5596,18 @@ class ServingEngine:
             done.append((row, slot, st, emits))
         return done
 
-    def _commit_prefill_group(self, rung, nrows, tokens, done,
+    def _commit_prefill_group(self, rung, nrows, tokens, turn, done,
                               first) -> None:
         """The commit loop of :meth:`_run_prefill_group`, when the call's
         tokens are on the host: a row that reached its last prompt token
         (``done``, :meth:`_advance_prefill_rows`) registers its full
         blocks with the trie and emits its first token.  ``rung`` is the
-        call's shape, ``tokens`` the real tokens of its ``nrows`` rows."""
+        call's shape, ``tokens`` the real tokens of its ``nrows`` rows,
+        ``turn`` whether ready rows of its group waited."""
         active = self._active
         width = rung[1]
         self._c_prefill_shapes[rung].inc()
+        self._c_prefill_turns.inc(turn)
         self._c_prefill_tokens.inc(tokens)
         self._c_prefill_budget.inc(rung[0] * width)
         if self.sp_degree > 1:
@@ -5816,6 +5865,9 @@ class ServingEngine:
             "prefill_fill": self._c_prefill_tokens.value
             / self._c_prefill_budget.value
             if self._c_prefill_budget.value else None,
+            # calls in which ready rows of the group waited their turn: the
+            # rung that carried most held fewer rows than the group
+            "prefill_turns": int(self._c_prefill_turns.value),
             # the read the prefill program was traced with (None before its
             # first call): "paged_prefill_attn" on a TPU, "gather" on a CPU
             "prefill_attn": self._program_meta.get("prefill_attn"),

@@ -199,8 +199,10 @@ def test_served_requests_are_the_greedy_continuation_and_counters_add_up(
     srv = _serving(model)
     rng = np.random.default_rng(0)
     reqs = [Request(uid=i, prompt=rng.integers(0, 512, n).astype(np.int32),
-                    max_new_tokens=8) for i, n in enumerate((100, 20, 70))]
-    # (uid 0 is the longest and finishes last, alone)
+                    max_new_tokens=8) for i, n in enumerate((20, 100, 70))]
+    # (the first call holds all three under topk, ``[4, 16]``: 16 + 16 + 16
+    # tokens against the 20 a wide row would carry; uid 1 is the longest
+    # and finishes last, alone)
     out = srv.serve(reqs)
     _exact(model, reqs, out)
     stats = srv.stats()
@@ -216,7 +218,7 @@ def test_served_requests_are_the_greedy_continuation_and_counters_add_up(
         for key in totals:
             totals[key] += e["args"][key]
     assert {k: stats["sparse_attn"][k] for k in totals} == totals
-    # the last decode step ran the one row still alive, uid 0, alone: its
+    # the last decode step ran the one row still alive, uid 1, alone: its
     # context is prompt + generated - 1 keys, of which it attends topk
     last = [e for e in spans if e["name"] == "decode"][-1]["args"]
     assert last["slots"] == 1 and last["kv_valid"] == 100 + 7

@@ -1,15 +1,18 @@
 """The prefill call as a BUDGET of ``prefill_batch * prefill_chunk`` tokens
-(ISSUE 52): a call whose row is alone runs ``[1, 4w]``, the wide rung of
-the ladder, where several rows run ``[4, w]``.  Tiny float32 engines on the
-CPU: a request's tokens do not depend on the shapes its prompt went through,
-for every layer kind the engine serves; the policy, its counters and what is
-sized by the widest row."""
+(ISSUE 52) at the rung of the ladder that carries the most real tokens
+(ISSUE 54): a row alone, or in its turn among two or three long ones, runs
+``[1, 4w]``; a full group, or one that is nearly done, shares ``[4, w]``.
+Tiny float32 engines on the CPU: a request's tokens do not depend on the
+shapes or the order of the calls its prompt went through, for every layer
+kind the engine serves; the rule, the bound on waiting, the counters and
+what is sized by the widest row."""
 
 import contextlib
 import json
 import logging
 import os
 import sys
+import types
 
 import jax
 import numpy as np
@@ -83,7 +86,7 @@ def _only(srv, rung):
     """``srv`` making every prefill call at ``rung``, its groups taken
     ``rung[0]`` rows at a time."""
     batch = srv.prefill_batch
-    srv._rung_for, srv.prefill_batch = (lambda rows: rung), rung[0]
+    srv._rung_for, srv.prefill_batch = (lambda group: (rung, group)), rung[0]
     try:
         yield
     finally:
@@ -91,10 +94,23 @@ def _only(srv, rung):
         srv.prefill_batch = batch
 
 
+def _forget(srv):
+    """Empty ``srv``'s prefix trie, so the next run prefills whole prompts
+    again (an idle engine: the trie alone holds its blocks)."""
+    while srv._prefix is not None and srv._prefix.evict_one(srv._alloc):
+        pass
+
+
+def _calls(srv, since=0):
+    """The span arguments of every prefill call after ``since`` events, in
+    the order the calls were made."""
+    return [e["args"] for e in srv.timeline.events()[since:]
+            if e["ph"] == "X" and e["name"] == "prefill"]
+
+
 def _shapes(srv, since):
     """The ``shape`` of every prefill call after ``since`` events."""
-    return [e["args"]["shape"] for e in srv.timeline.events()[since:]
-            if e["ph"] == "X" and e["name"] == "prefill"]
+    return [c["shape"] for c in _calls(srv, since)]
 
 
 @pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
@@ -102,11 +118,16 @@ def _shapes(srv, since):
 def test_a_requests_tokens_do_not_depend_on_its_prefill_calls_shapes(
         engines, kind, sampled):
     """Every request through ``[4, w]`` calls and through ``[1, 4w]``
-    calls, through the ladder beside other prefilling rows, and through the
-    ladder alone: the same tokens."""
+    calls, through the ladder beside other prefilling rows (which take
+    turns at the wide row), and through the ladder alone: the same
+    tokens."""
     srv, vocab = engines(kind)
     assert srv._rungs == RUNGS and srv._ladder_stop is None
-    reqs = lambda: _requests(vocab, LENGTHS, sampled)  # noqa: E731
+
+    def reqs():
+        _forget(srv)
+        return _requests(vocab, LENGTHS, sampled)
+
     with _only(srv, RUNGS[0]):
         since = len(srv.timeline.events())
         want = srv.serve(reqs())
@@ -119,15 +140,22 @@ def test_a_requests_tokens_do_not_depend_on_its_prefill_calls_shapes(
         for uid in want:
             np.testing.assert_array_equal(got[uid], want[uid],
                                           err_msg=f"{rung}: uid {uid}")
-    # the ladder itself: rows together (a row left alone runs the wide rung)
-    since = len(srv.timeline.events())
+    # the ladder itself: rows together (three long rows take turns at the
+    # wide rung; what is left of them shares the narrow one)
+    since, turns = len(srv.timeline.events()), srv.stats()["prefill_turns"]
     together = srv.serve(reqs())
-    assert "4x8" in _shapes(srv, since)
+    assert set(_shapes(srv, since)) == {"4x8", "1x32"}
+    assert srv.stats()["prefill_turns"] > turns
+    # alone: wide calls, and the narrow rung for a tail it carries as well
     alone = {}
-    since = len(srv.timeline.events())
+    since, turns = len(srv.timeline.events()), srv.stats()["prefill_turns"]
     for r in reqs():
         alone.update(srv.serve([r]))
-    assert set(_shapes(srv, since)) == {"1x32"}
+    # (37, 70, 9, 52, 33 tokens: 1 + 2 + 1 + 2 + 1 calls carry more than
+    # the narrow rung would)
+    assert [c["shape"] for c in _calls(srv, since)
+            if c["tokens"] > CHUNK] == ["1x32"] * 7
+    assert srv.stats()["prefill_turns"] == turns
     for uid in want:
         np.testing.assert_array_equal(together[uid], want[uid])
         np.testing.assert_array_equal(alone[uid], want[uid])
@@ -161,31 +189,166 @@ def test_a_preempted_request_resumes_through_a_wide_rung(tiny_engine):
     srv.close()
 
 
-@pytest.mark.parametrize("rows,shapes", [
-    (1, ["1x32"]), (2, ["4x8"]), (3, ["4x8"]), (4, ["4x8"]),
-    (5, ["4x8", "1x32"]), (9, ["4x8", "4x8", "1x32"])])
+@pytest.mark.parametrize("rows,steps", [
+    (1, [["1x32"]]),
+    (2, [["1x32"], ["1x32"], ["4x8"]]),
+    (3, [["1x32"], ["1x32"], ["1x32"], ["4x8"]]),
+    (4, [["4x8"]]),
+    (5, [["4x8", "1x32"]]),
+    (9, [["4x8", "4x8", "1x32"]])])
 def test_ready_rows_are_cut_into_the_calls_the_issue_names(tiny_engine, rows,
-                                                           shapes):
-    """One step with ``rows`` fresh prompts: groups of ``prefill_batch`` in
-    admission order, each at the rung with the fewest rows that holds it."""
+                                                           steps):
+    """``rows`` fresh prompts of 40 tokens: groups of ``prefill_batch`` in
+    admission order, each at the rung that carries most — a full group
+    ``[4, 8]``, a row alone ``[1, 32]``, two or three one ``[1, 32]`` each
+    by turns and then what is left of them (8 each) in one ``[4, 8]``.
+    ``steps``: the shapes of the calls of the first steps that made any."""
     srv, cfg = _tiny(tiny_engine)
     handles = [srv.submit(r) for r in _requests(
         cfg.vocab_size, [40] * rows, False, 2)]
     while srv.step():                      # (a call's span lands as it ends)
         pass
-    spans = [e["args"] for e in srv.timeline.events()
-             if e["ph"] == "X" and e["name"] == "prefill"]
-    # the calls of the first step that made any (``step``: the iteration
-    # that MADE the call, not the one that harvested it)
-    calls = [c for c in spans if c["step"] == spans[0]["step"]]
-    assert [c["shape"] for c in calls] == shapes
-    # admission order: the slots of the calls, concatenated, are 0 .. rows-1
-    assert sum((c["slots"] for c in calls), []) == list(range(rows))
-    for c in calls:
+    spans = _calls(srv)
+    # ``step``: the iteration that MADE the call, not the one that
+    # harvested it
+    first = spans[0]["step"]
+    calls = [[c for c in spans if c["step"] == first + i]
+             for i in range(len(steps))]
+    assert [[c["shape"] for c in step] for step in calls] == steps
+    # admission order: the slots of a step's calls are 0 .. rows-1, less
+    # those that wait their turn (a group of two or three: one runs)
+    turns = 1 < rows < 4
+    for i, step in enumerate(calls):
+        assert sum((c["slots"] for c in step), []) == (
+            list(range(rows)) if not turns or i == rows else [i])
+    left = dict.fromkeys(range(rows), 40)
+    for c in spans:
         j, width = map(int, c["shape"].split("x"))
-        assert c["width"] == width and c["rows"] <= j
-        assert c["tokens"] == c["rows"] * min(width, 40)
+        assert c["width"] == width and c["rows"] == len(c["slots"]) <= j
+        assert c["rows"] <= c["ready"] <= BATCH
+        assert c["tokens"] == sum(min(width, left[s]) for s in c["slots"])
+        for s in c["slots"]:
+            left[s] -= min(width, left[s])
+    assert not any(left.values()) and all(h.done for h in handles)
+    assert (srv.stats()["prefill_turns"] > 0) == turns
+    srv.close()
+
+
+def _rule(left, waited=None, rungs=RUNGS, batch=BATCH):
+    """``_rung_for`` on a group of rows with ``left`` prompt tokens to go
+    and ``waited`` calls passed over, slots 0.. in admission order."""
+    waited = waited or [0] * len(left)
+    stub = types.SimpleNamespace(
+        _rungs=rungs, prefill_batch=batch,
+        _active={s: types.SimpleNamespace(plen_eff=n, base=0, waited=w)
+                 for s, (n, w) in enumerate(zip(left, waited))})
+    return serving.ServingEngine._rung_for(stub, list(range(len(left))))
+
+
+@pytest.mark.parametrize("left,waited,want", [
+    ([3, 6], None, ((4, 8), [0, 1])),      # rows nearly done: 9 against 3
+    ([5, 100], None, ((4, 8), [0, 1])),    # 5 + 8 against 5
+    ([2, 3, 4], None, ((4, 8), [0, 1, 2])),
+    ([8, 9, 9], None, ((4, 8), [0, 1, 2])),        # 24 against 8
+    ([30, 9, 9], [0, 1, 1], ((4, 8), [0, 1, 2])),  # in turn: 25 against 9
+])
+def test_the_rule_picks_the_narrow_rung_when_it_carries_more(left, waited,
+                                                             want):
+    assert _rule(left, waited) == want
+
+
+@pytest.mark.parametrize("left,waited,want", [
+    ([40, 40], None, ((1, 32), [0])),      # 32 against 16
+    ([40, 40], [0, 1], ((1, 32), [1])),    # whoever waited longest
+    ([100, 5], None, ((1, 32), [0])),      # 32 against 13
+    ([40, 40, 40], [1, 0, 1], ((1, 32), [0])),   # admission between equals
+    ([9, 30, 9], [1, 2, 1], ((1, 32), [1])),     # 30 against 24
+])
+def test_the_rule_picks_the_wide_rung_when_it_carries_more(left, waited,
+                                                           want):
+    assert _rule(left, waited) == want
+
+
+@pytest.mark.parametrize("left,rungs,want", [
+    ([40] * 4, RUNGS, ((4, 8), [0, 1, 2, 3])),   # 32 = 32: today's call
+    ([16, 16], RUNGS, ((4, 8), [0, 1])),         # 16 = 16
+    ([5], RUNGS, ((4, 8), [0])),                 # a tail either carries
+    ([40], RUNGS, ((1, 32), [0])),
+    # the rule is written on the ladder: with the rung between, three long
+    # rows run two of them (32 = 32 against the wide row, and more rows)
+    ([40] * 3, [(4, 8), (2, 16), (1, 32)], ((2, 16), [0, 1])),
+    ([40, 9, 9], [(4, 8), (2, 16), (1, 32)], ((1, 32), [0])),
+    ([300, 300], [(4, 128), (1, 512)], ((1, 512), [0])),
+    ([50, 100], [(4, 128), (1, 512)], ((4, 128), [0, 1])),
+    ([7], [(1, 64)], ((1, 64), [0])),            # prefill_batch 1
+])
+def test_on_a_tie_the_rung_with_more_rows_runs(left, rungs, want):
+    assert _rule(left, rungs=rungs, batch=rungs[0][0]) == want
+
+
+@pytest.mark.parametrize("waited,want", [
+    # two rows have waited prefill_batch - 1 calls: the wide rung would pass
+    # one of them over again, so it is not eligible
+    ([3, 3, 0], ((4, 8), [0, 1, 2])),
+    ([0, 3, 0], ((1, 32), [1])),
+    ([2, 2, 0], ((1, 32), [0])),
+])
+def test_no_rung_passes_over_a_row_that_has_waited_its_bound(waited, want):
+    assert _rule([40, 40, 40], waited) == want
+
+
+def _watched(srv):
+    """``srv`` stepped until idle; -> for every slot the steps in which a
+    call ran it, and the largest ``waited`` any prefilling row showed."""
+    worst = 0
+    while srv.step():
+        worst = max([worst] + [st.waited for st in srv._active.values()
+                               if st.phase == "prefill"])
+    ran = {}
+    for c in _calls(srv):
+        for slot in c["slots"]:
+            ran.setdefault(slot, []).append(c["step"])
+    return ran, worst
+
+
+@pytest.mark.parametrize("lengths", [
+    [100, 5], [90, 70, 50], [90, 70, 50, 5, 100, 30, 64],
+    [120, 16, 120], [64, 120, 16, 120, 16, 120, 120, 30, 90]])
+def test_a_ready_row_is_passed_over_a_bounded_number_of_calls(tiny_engine,
+                                                              lengths):
+    """Every group makes a call a step, so a row's turns lie at most
+    ``len(group)`` steps apart: it is passed over at most ``len(group) - 1
+    <= prefill_batch - 1`` calls in a row, whatever the rows around it
+    have left."""
+    srv, cfg = _tiny(tiny_engine)
+    handles = [srv.submit(r) for r in _requests(
+        cfg.vocab_size, lengths, False, 2)]
+    ran, worst = _watched(srv)
+    assert srv.stats()["prefill_turns"] > 0
+    assert worst <= min(len(lengths), BATCH) - 1
+    first = min(min(steps) for steps in ran.values())
+    for slot, steps in ran.items():
+        gaps = np.diff([first - 1] + steps)
+        assert gaps.max() <= min(len(lengths), BATCH), (slot, steps)
     assert all(h.done for h in handles)
+    srv.close()
+
+
+def test_a_short_prompt_behind_a_long_one_waits_one_call(tiny_engine):
+    """5 tokens admitted behind 100: the long row takes the first call
+    whole, the second call is the short row's (it went to the front of the
+    turn order) — its first token after two calls, not after the long
+    prompt's thirteen chunks."""
+    srv, cfg = _tiny(tiny_engine)
+    long, short = [srv.submit(r) for r in _requests(
+        cfg.vocab_size, [100, 5], False, 2)]
+    while not short.tokens():
+        assert srv.step()
+    assert srv.stats()["prefill_calls"] <= 2 and not long.tokens()
+    while srv.step():
+        pass
+    assert [(c["shape"], c["slots"]) for c in _calls(srv)] == [
+        ("1x32", [0]), ("4x8", [0, 1]), ("1x32", [0]), ("1x32", [0])]
     srv.close()
 
 
@@ -193,15 +356,51 @@ def test_stats_count_the_shapes_and_the_fill(tiny_engine):
     srv, cfg = _tiny(tiny_engine)
     st = srv.stats()
     assert st["prefill_shapes"] == {"4x8": 0, "1x32": 0}
-    assert st["prefill_fill"] is None
+    assert st["prefill_fill"] is None and st["prefill_turns"] == 0
     srv.serve(_requests(cfg.vocab_size, [40, 40, 40, 40, 40], False, 2))
     st = srv.stats()
-    # five rows: [4, 8] x 5 chunks beside [1, 32] + [1, 32] (8 of its 32)
-    assert st["prefill_shapes"] == {"4x8": 5, "1x32": 2}
-    assert st["prefill_calls"] == 7
+    # five rows: [4, 8] x 5 chunks beside [1, 32] + [4, 8] (the fifth row's
+    # last 8 tokens: either rung carries them, the one with more rows runs)
+    assert st["prefill_shapes"] == {"4x8": 6, "1x32": 1}
+    assert st["prefill_calls"] == 7 and st["prefill_turns"] == 0
     assert st["prefill_fill"] == pytest.approx(5 * 40 / (7 * 32))
     text = srv.metrics.prometheus_text()
-    assert 'serving_prefill_calls_by_shape_total{shape="1x32"} 2' in text
+    assert 'serving_prefill_calls_by_shape_total{shape="1x32"} 1' in text
+    srv.close()
+
+
+def test_stats_spans_and_the_exposition_count_the_turns(tiny_engine):
+    """Two rows of 64: four ``[1, 32]`` calls by turns; the last runs with
+    its row alone (the other is done), so three of them made a row wait."""
+    srv, cfg = _tiny(tiny_engine)
+    srv.serve(_requests(cfg.vocab_size, [64, 64], False, 2))
+    st = srv.stats()
+    assert st["prefill_shapes"] == {"4x8": 0, "1x32": 4}
+    assert st["prefill_turns"] == 3 and st["prefill_fill"] == 1.0
+    assert [(c["slots"], c["ready"], c["rows"]) for c in _calls(srv)] == [
+        ([0], 2, 1), ([1], 2, 1), ([0], 2, 1), ([1], 1, 1)]
+    text = srv.metrics.prometheus_text()
+    assert "serving_prefill_turns_total 3" in text
+    assert "# TYPE serving_prefill_turns_total counter" in text
+    srv.close()
+
+
+def test_tokens_are_a_roomy_engines_with_turns_and_a_preemption(tiny_engine):
+    """Sampled requests through a pool that cannot hold them all: rows take
+    turns at the wide rung, one is preempted and resumes, and every token
+    is the roomy engine's (whose rows take turns too, at other times)."""
+    lengths = [61, 47, 66, 59]
+    roomy, cfg = _tiny(tiny_engine, slots=4)
+    want = roomy.serve(_requests(cfg.vocab_size, lengths, True, 24))
+    assert roomy.stats()["evicted"] == 0
+    roomy.close()
+    srv, _ = _tiny(tiny_engine, slots=4, num_blocks=1 + 30)
+    got = srv.serve(_requests(cfg.vocab_size, lengths, True, 24))
+    st = srv.stats()
+    assert st["evicted"] > 0 and st["prefill_turns"] > 0
+    assert st["prefill_shapes"]["1x32"] > 0 and st["prefill_shapes"]["4x8"] > 0
+    for uid in want:
+        np.testing.assert_array_equal(got[uid], want[uid])
     srv.close()
 
 
@@ -238,6 +437,20 @@ def test_the_window_ring_holds_the_window_and_the_widest_row(engines):
         == srv._ring.width
 
 
+def _narrow_plan(monkeypatch, cfg):
+    """The prefill kernel's VMEM budget made the smallest under which its
+    plan takes the tiny engine's narrow rows (8 tokens): it then takes no
+    32-token row.  -> the budget."""
+    heads, hd = cfg.num_heads, cfg.hidden_size // cfg.num_heads
+    fits = lambda t: decode_attention.prefill_row_fits(  # noqa: E731
+        heads, heads, 8, hd, 4, t, 128 // 8)
+    budget = next(b for b in range(1 << 10, 1 << 24, 1 << 10)
+                  if monkeypatch.setattr(
+                      decode_attention, "_PREFILL_VMEM_BUDGET", b) or fits(8))
+    assert fits(8) and not fits(32)
+    return budget
+
+
 def _logged(caplog, build):
     """``build()`` and the constructor's log line."""
     logger.addHandler(caplog.handler)
@@ -259,13 +472,7 @@ def test_a_ladder_stops_where_the_kernel_takes_no_wider_rows(
     srv, cfg = _tiny(tiny_engine)
     assert srv._rungs == [(4, 8), (1, 32)] and srv._ladder_stop is None
     srv.close()
-    heads, hd = cfg.num_heads, cfg.hidden_size // cfg.num_heads
-    fits = lambda t: decode_attention.prefill_row_fits(  # noqa: E731
-        heads, heads, 8, hd, 4, t, 128 // 8)
-    budget = next(b for b in range(1 << 10, 1 << 24, 1 << 10)
-                  if monkeypatch.setattr(
-                      decode_attention, "_PREFILL_VMEM_BUDGET", b) or fits(8))
-    assert fits(8) and not fits(32)
+    budget = _narrow_plan(monkeypatch, cfg)
     (srv, cfg), line = _logged(caplog, lambda: _tiny(tiny_engine))
     assert srv._rungs == [(4, 8)], budget
     assert "the prefill kernel's plan takes no 1 x 32 query rows" \
@@ -345,4 +552,49 @@ def test_a_resident_window_keeps_the_one_shape(tiny_engine):
                    resident_window_blocks=4, max_seq_len=128)
     assert srv._rungs == [(4, 8)] and "resident window" in srv._ladder_stop
     assert srv.compile_budget == 4
+    srv.close()
+
+
+def _one_rung(tiny_engine, monkeypatch, how):
+    if how == "kernel_plan":
+        _narrow_plan(monkeypatch, tiny_engine[1])
+        return _tiny(tiny_engine)
+    if how == "resident_window":
+        return _tiny(tiny_engine, host_blocks=32, swap_batch=4,
+                     resident_window_blocks=6)
+    return _tiny(tiny_engine, **how)
+
+
+@pytest.mark.parametrize("how,lengths", [
+    ("kernel_plan", [40, 23, 64, 9, 30, 17]),
+    ("resident_window", [40, 23, 64, 9, 30, 17]),
+    ({"max_seq_len": 24}, [20, 13, 22, 9, 17, 5]),
+    ({"prefill_batch": 1}, [40, 23, 9])],
+    ids=["kernel_plan", "resident_window", "cache", "batch_1"])
+def test_a_one_rung_engine_makes_the_parents_calls(tiny_engine, monkeypatch,
+                                                   how, lengths):
+    """A ladder of one rung has nothing to choose between: groups of
+    ``prefill_batch`` ready rows in admission order, every row of a group
+    in its call, a chunk each — the parent's calls, shape for shape; no row
+    ever waits."""
+    srv, cfg = _one_rung(tiny_engine, monkeypatch, how)
+    (j, width), = srv._rungs
+    handles = [srv.submit(r) for r in _requests(
+        cfg.vocab_size, lengths, False, 2)]
+    _, worst = _watched(srv)
+    assert worst == 0 and srv.stats()["prefill_turns"] == 0
+    # the parent's rule, replayed: slot i holds request i
+    left, want = dict(enumerate(lengths)), []
+    while any(left.values()):
+        ready = [s for s in sorted(left) if left[s]]
+        for i in range(0, len(ready), j):
+            group = ready[i:i + j]
+            want.append((group, sum(min(width, left[s]) for s in group)))
+        for s in ready:
+            left[s] -= min(width, left[s])
+    calls = _calls(srv)
+    assert [(c["slots"], c["tokens"]) for c in calls] == want
+    assert all(c["shape"] == srv._rung_name((j, width))
+               and c["ready"] == c["rows"] for c in calls)
+    assert all(h.done for h in handles)
     srv.close()

@@ -82,6 +82,7 @@ ENGINE_STATS_KEYS = frozenset({
     "num_blocks", "nvme_blocks", "nvme_blocks_in_use", "nvme_loads",
     "nvme_spills", "prefetch_misses", "prefetch_wait_p50_s",
     "prefetch_wait_p95_s", "prefill_calls", "prefill_fill", "prefill_shapes",
+    "prefill_turns",
     "prefix_cache_entries",
     "prefix_cache_evictions", "prefix_cache_hit_rate",
     "prefix_hit_tokens", "prompt_tokens", "quantize", "queue_depth",
