@@ -247,10 +247,13 @@ block, the read each program was traced with and the refusals; the
 ``kv_blocks``, ``kv_pairs`` (query-key pairs x layers) and ``latent_bytes``
 (:meth:`ServingEngine._kv_reach`).
 
-**The state kind** (PR 51): a model with gated delta-rule layers (decode
-hook ``state_layers``; ``models/kimi_linear.py``) keeps, for each such layer,
-a recurrent state a SLOT and nothing a token: ``state [L, slots, H, dk, dv]``
-float32 and the short convolutions' tails ``conv [L, slots, 1, taps,
+**The state kind** (PR 51, PR 55): a model with recurrent layers (decode
+hook ``state_layers``: gated delta-rule layers, ``models/kimi_linear.py``,
+beside a latent pool; state-space layers, ``models/granite_hybrid.py``, beside
+the ``full`` kind's K and V) keeps, for each such layer,
+a recurrent state a SLOT and nothing a token: ``state [L, slots, ...]``
+float32 (the trailing shape is the family's) and the short convolutions'
+tails ``conv [L, slots, 1, taps,
 channels]`` ride in the cache tree beside the paged pool (``ops/paged_kv.py``
 "The state kind") — donated and carried with it, not lane-packed — with no
 block ids, no table and no allocator: a slot's rows are its own for as long as it
@@ -269,8 +272,10 @@ the prefix trie, the host / NVMe tiers, ``spec_tokens`` and a draft model,
 ``decode_steps > 1``, ``quantize`` (kv8, w8a8), ``resident_window_blocks``
 and tp / dp / sp meshes.  ``stats()["kv_state"]`` has the leaves, their
 bytes (whatever the rows' lengths), the resets, which body each program's
-delta rule lowered to and the refusals; ``stats()["kv_kinds"]`` names the
-state kind beside the paged one.
+recurrence lowered to — under the hook's ``bodies`` (``"kda"``: ``kda_step``
+/ ``kda_chunk_state``; ``"ssd"``: ``ssd_step`` / ``ssd_chunk_state``) — and
+the refusals; ``stats()["kv_kinds"]`` names the state kind beside the paged
+one.  The engine names no family: what it knows of one is the hook.
 
 Greedy decoding only: per-request outputs are token-identical to
 sequential ``generate`` (pinned in ``tests/unit/test_serving.py``,
@@ -1018,7 +1023,7 @@ class ServingEngine:
                 "'w8a8'}} (init_serving(quantize=...) does this for you)")
         #: a recurrent state a row (decode hook ``state_layers``:
         #: ``{"layers", "heads", "key_dim", "value_dim", "conv_taps",
-        #: "channels"}``): leaves indexed by SLOT beside the paged pool, with
+        #: "channels", "bodies"}``): leaves indexed by SLOT beside the paged pool, with
         #: no block ids, no table and no allocator (module docstring "The
         #: state kind"); None otherwise
         self._state = (getattr(engine.module, "decode_hooks", None)
@@ -2292,8 +2297,8 @@ class ServingEngine:
             ("quantize='kv8'", self.kv_quant,
              "the state is float32 by construction"),
             (f"quantized weights ({qcfg.type if qcfg.enabled else None})",
-             qcfg.enabled, "the gated delta-rule leaves (decays, "
-             "convolution taps, low-rank gates) have no int8 record"),
+             qcfg.enabled, "the state kind's leaves (decays, convolution "
+             "taps, gates) have no int8 record"),
             ("resident_window_blocks", int(resident_window_blocks),
              "a window slides over blocks; the state has none"),
             (f"a tp mesh (tp={tp})", tp > 1,
@@ -2301,7 +2306,7 @@ class ServingEngine:
             (f"engine_mode='dp_tp' (dp={dp})", dp > 1,
              "the state's rows are not sharded: one shard"),
             (f"sp={sp}", int(sp) > 1,
-             "the chunked delta rule carries its state along the sequence"))
+             "the chunked recurrence carries its state along the sequence"))
         self._state_refusals = [what.split("=")[0].split(" (")[0]
                                 for what, _, _ in refused]
         unserved = [f"{what} ({why})" for what, on, why in refused if on]
@@ -2438,17 +2443,25 @@ class ServingEngine:
         ``paged_latent_*`` kernel on a TPU, ``"latent_gather"`` on a CPU."""
         if self._latent:
             self._program_meta.setdefault("latent_attn", {})[program] = \
-                "+".join(sorted(p for p in paths if not p.startswith("kda_")))
+                "+".join(sorted(p for p in paths if not self._state_body(p)))
         self._note_state(program, paths)
 
+    def _state_body(self, path: str) -> bool:
+        """Whether ``path`` (an ``ops/decode_attention.dispatch_log`` name)
+        is a body of this model's state kind: the hook's ``bodies`` is the
+        prefix its family's recurrence goes by."""
+        return bool(self._state) \
+            and path.startswith(self._state["bodies"] + "_")
+
     def _note_state(self, program: str, paths) -> None:
-        """Trace time: which body ``program``'s gated delta-rule layers
-        lowered to (``stats()["kv_state"]["kda"]``): ``kda_step`` /
-        ``kda_chunk_state``, the Pallas kernels, on a TPU; ``kda_*_plain``
-        on a CPU."""
+        """Trace time: which body ``program``'s state-kind layers lowered to
+        (``stats()["kv_state"][<bodies>]``, the hook's ``bodies``: ``"kda"``
+        a gated delta rule, ``"ssd"`` a state-space scan): the Pallas kernels
+        ``<bodies>_step`` / ``<bodies>_chunk_state`` on a TPU,
+        ``<bodies>_*_plain`` on a CPU."""
         if self._state:
-            self._program_meta.setdefault("kda", {})[program] = \
-                "+".join(sorted(p for p in paths if p.startswith("kda_")))
+            self._program_meta.setdefault("state_bodies", {})[program] = \
+                "+".join(sorted(p for p in paths if self._state_body(p)))
 
     def _note_sampler(self, program: str, samp) -> None:
         """Trace time: how ``program`` picks its tokens
@@ -5788,7 +5801,8 @@ class ServingEngine:
                 "bytes_per_slot": nbytes // self.slots,
                 "leaves": {k: list(self._cache[k].shape)
                            for k in STATE_LEAVES},
-                "kda": dict(self._program_meta.get("kda", {})),
+                self._state["bodies"]: dict(
+                    self._program_meta.get("state_bodies", {})),
                 **self._state_totals,
                 "refused": list(self._state_refusals)}
 
@@ -5908,7 +5922,7 @@ class ServingEngine:
             if self._windows or self._state else None,
             # a model with a recurrent state a row: its leaves, their bytes
             # (whatever the rows' lengths), the resets, which body each
-            # program's delta rule lowered to and what such a model is
+            # program's recurrence lowered to and what such a model is
             # refused; None for any other model
             "kv_state": self._kv_state() if self._state else None,
             # a model with latent attention: the pool's kind, a token's

@@ -171,7 +171,8 @@ def cache_update(ck, cv, k, v, pos):
 
 
 def cached_attention(q, k, v, ck, cv, pos, block_tables=None,
-                     chunk_valid=None, layer=None, window: int = 0):
+                     chunk_valid=None, layer=None, window: int = 0,
+                     sm_scale: Optional[float] = None):
     """Write new KV + attend, on either cache layout.  Contiguous
     (``block_tables is None``): ck/cv are one layer's [B, H, S, hd]
     per-sequence regions.  Paged: ck/cv are the WHOLE stacked
@@ -184,16 +185,17 @@ def cached_attention(q, k, v, ck, cv, pos, block_tables=None,
     block, and the read of a prefill chunk walks the blocks ``pos +
     chunk_valid`` reaches and no further.  ``window`` (static, paged
     only): a sliding-window layer — ck/cv and ``block_tables`` are the
-    window kind's leaves and ring (``ops/paged_kv.py`` "Layer kinds")."""
+    window kind's leaves and ring (``ops/paged_kv.py`` "Layer kinds").
+    ``sm_scale``: the scores' scale where it is not ``head_dim ** -0.5``."""
     if block_tables is None:
         ck, cv = cache_update(ck, cv, k, v, pos)
-        return da.decode_attention(q, ck, cv, pos), ck, cv
+        return da.decode_attention(q, ck, cv, pos, sm_scale=sm_scale), ck, cv
     ck, cv = paged_kv.paged_cache_update(ck, cv, k, v, pos, block_tables,
                                          valid=chunk_valid, layer=layer,
                                          ring=bool(window))
     return da.paged_decode_attention(q, ck, cv, block_tables, pos,
                                      layer=layer, valid=chunk_valid,
-                                     window=window), ck, cv
+                                     window=window, sm_scale=sm_scale), ck, cv
 
 
 # ------------------------------------------------------------------ the window
@@ -379,13 +381,17 @@ def decode_over_layers(body, x, blocks, cache_k, cache_v, num_layers,
 #: the cache leaves and the table of each layer kind of a patterned model.
 #: ``full`` / ``sliding`` hold a key and a value a KV head a token
 #: (``ops/paged_kv.py`` "Layer kinds"), ``latent`` ONE leaf (a latent a token,
-#: under the full kind's table), ``kda`` no token at all: ``state`` and
-#: ``conv`` are indexed by ROW (``ops/paged_kv.py`` "The state kind") and its
-#: "table" is ``slot``, int32 ``[B]`` — the row of the leaves each row of
-#: the call owns (absent in a decode step, where row ``b`` IS row ``b``)
+#: under the full kind's table); the STATE kind's layers — ``kda`` (a gated
+#: delta rule, ``models/kimi_linear.py``) and ``ssm`` (a state-space scan,
+#: ``models/granite_hybrid.py``): the leaves' shapes are the family's —
+#: hold no token at all: ``state`` and ``conv`` are indexed by ROW
+#: (``ops/paged_kv.py`` "The state kind") and their "table" is ``slot``,
+#: int32 ``[B]`` — the row of the leaves each row of the call owns (absent
+#: in a decode step, where row ``b`` IS row ``b``)
 KIND_LEAVES = {"full": ("k", "v", "full"), "sliding": ("kw", "vw", "window"),
                "latent": ("latent", None, "full"),
-               "kda": paged_kv.STATE_LEAVES + ("slot",)}
+               "kda": paged_kv.STATE_LEAVES + ("slot",),
+               "ssm": paged_kv.STATE_LEAVES + ("slot",)}
 
 
 def scan_periods_cached(kinds, num_layers: int, step, x, blocks, cache,

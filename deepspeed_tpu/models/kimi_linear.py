@@ -531,7 +531,10 @@ def build(cfg: Optional[KimiLinearConfig] = None, **overrides) -> ModelSpec:
         "state_layers": {
             "layers": cfg.layers_of("kda"), "heads": cfg.kda_heads,
             "key_dim": hd, "value_dim": hd, "conv_taps": cfg.kda_conv - 1,
-            "channels": 3 * cfg.kda_width},
+            "channels": 3 * cfg.kda_width,
+            # the prefix of the names the rule's bodies go by
+            # (``ops/delta_rule.py``; ``stats()["kv_state"]["kda"]``)
+            "bodies": "kda"},
     }
     if cfg.experts_held is not None:
         decode_hooks["experts_held"] = cfg.experts_held

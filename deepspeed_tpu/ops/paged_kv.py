@@ -72,12 +72,12 @@ Layout contract — the WHOLE stacked pool, addressed in place:
    takes :func:`latent_block_tokens` for this kind, 512 tokens of 768 B
    (PERF.md section 6, PR 39, has the table).
 
- - **The state kind.**  A model with gated delta-rule layers
-   (``models/kimi_linear.py``, ``ops/delta_rule.py``) keeps, for each such
+ - **The state kind.**  A model with recurrent layers keeps, for each such
    layer, no token at all: a row's whole past is a float32 matrix a head,
-   ``state [L_kda, rows, H, dk, dv]`` (2 MiB a row a layer at 32 x 128 x
-   128, whatever the row's length), and the last ``K - 1`` inputs of its
-   short convolutions, ``conv [L_kda, rows, 1, K - 1, channels]``.  These
+   ``state [L, rows, ...]`` (2 MiB a row a layer in both families that ride
+   it, whatever the row's length), and the last ``K - 1`` inputs of its
+   short convolutions, ``conv [L, rows, 1, K - 1, channels]``.  What is the
+   KIND's: these
    leaves ride in the SAME cache tree as the paged leaves — donated, carried
    through the layer loop and handed back in the same buffers — but are
    indexed by ROW (the serving engine's slot), not by block: no block ids,
@@ -87,10 +87,16 @@ Layout contract — the WHOLE stacked pool, addressed in place:
    is no block to lane-pack; the engine skips them by name,
    :data:`STATE_LEAVES`).  A decode step's row ``b`` is row
    ``b`` of the leaves and the kernel updates the matrices in place
-   (``kda_step``, ``input_output_aliases``); a prefill call names its rows'
+   (``input_output_aliases``); a prefill call names its rows'
    slots (``block_tables["slot"]``: gathered, advanced by the chunked form,
    scattered back at ``[layer, slot]``, a pad row's slot out of range and
-   dropped).
+   dropped).  What is a FAMILY's: the shapes behind the rows, the
+   recurrence and its kernels — a gated delta rule with a decay a key
+   channel (``models/kimi_linear.py``, ``ops/delta_rule.py``: ``state [L_kda,
+   rows, H, dk, dv]``, ``kda_step`` / ``kda_chunk_state``), a state-space scan
+   with a scalar decay a head (``models/granite_hybrid.py``, ``ops/ssd.py``:
+   ``state [L_ssm, rows, H / g, N, g P]``, head-packed so that its minor dim
+   fills the lanes; ``ssd_step`` / ``ssd_chunk_state``).
 
 **Layout** (what "in place" takes on a TPU).  A Mosaic kernel reads its
 operand row-major — ``[L][NB][HKV][...]``, a block's tiles contiguous —

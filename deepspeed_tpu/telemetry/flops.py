@@ -186,9 +186,14 @@ class ServingFlopsProfiler:
             for phase in ("prefill", "decode", "swap", "idle")}
 
     # -------------------------------------------------------- per-program cost
-    def _abstract_args(self, family: str):
+    def _abstract_args(self, family: str, rung=None, sampling=False):
         """ShapeDtypeStruct argument tree mirroring the live program's
-        fixed shapes — no device memory, no transfers."""
+        fixed shapes — no device memory, no transfers.  ``rung``: the
+        ``(rows, width)`` of a prefill program (default ``(prefill_batch,
+        prefill_chunk)``).  ``sampling``: the five sampling vectors of a
+        sampling engine's decode / prefill program too (the mask matrix of
+        a ``logit_masks`` engine is not among them); without them the body
+        takes its greedy branch."""
         import jax
         import jax.numpy as jnp
 
@@ -205,29 +210,35 @@ class ServingFlopsProfiler:
         cache = sds(srv._cache)
         slots, nb = srv.slots, srv._nbper
 
-        def tables(rows):
-            """The block-table operand: one table, or one per layer kind
-            (``ServingEngine._bt``)."""
-            if getattr(srv, "_windows", None):
-                return {"full": i32(rows, nb),
-                        "window": i32(rows, srv._ring.width)}
-            return i32(rows, nb)
+        def tables(rows, prefill=False):
+            """The block-table operand as the engine packs it
+            (``ServingEngine._operand_spec``): one table, or one per layer
+            kind — a model with a recurrent state a row: the full kind's
+            table and, in a prefill call, the slot of each row."""
+            return srv._operand_spec(
+                rows, {"ids": 1} if prefill else {})["block_tables"]
+
+        def samp(rows):
+            """The sampling vectors, in the body's order."""
+            if not (sampling and srv.sampling):
+                return ()
+            return tuple(list(srv._operand_spec(rows, {}).values())[1:])
 
         if family == "decode":
             args = (params, cache, i32(slots), i32(slots), tables(slots))
             if getattr(srv, "_K", 1) > 1:    # fused multi-step decode adds
                 args += (jax.ShapeDtypeStruct((slots,), jnp.bool_),
                          i32(slots), i32(slots))   # active, budgets, eos_ids
-            return args
+            return args + samp(slots)
         if family == "prefill":
-            j = srv.prefill_batch
+            j, width = rung or (srv.prefill_batch, srv.prefill_chunk)
             if srv._draft is not None:       # fused target+draft prefill
                 head = (params, sds(srv._draft.params), cache,
                         sds(srv._dcache))
             else:
                 head = (params, cache)
-            return head + (i32(j, srv.prefill_chunk), tables(j), i32(j),
-                           i32(j))
+            return head + (i32(j, width), tables(j, prefill=True), i32(j),
+                           i32(j)) + samp(j)
         if family == "verify":
             w = srv.spec_tokens + 1
             return (params, cache, i32(slots, w), i32(slots, nb),
@@ -250,18 +261,21 @@ class ServingFlopsProfiler:
             return {"rows": srv.slots, "width": srv.spec_tokens}
         return {"rows": 0, "width": 0}
 
-    def lower(self, family: str):
+    def lower(self, family: str, rung=None, sampling=False):
         """``jax.stages.Lowered`` of the raw program body at the live
-        program's fixed shapes — lowering only: it never compiles and never
+        program's fixed shapes (``rung``, ``sampling``:
+        :meth:`_abstract_args`) — lowering only: it never compiles and never
         ticks the sentry.  ``None`` when the engine has not built that
         program.  ``.as_text()`` shows which attention implementation the
-        program took (a Mosaic ``tpu_custom_call`` vs gather + XLA)."""
+        program took (a Mosaic ``tpu_custom_call`` vs gather + XLA) and, of
+        a model with a recurrent state a row, which kernels its state-kind
+        layers lowered to and the element type of every operand."""
         import jax
 
         body = self.srv._program_bodies.get(family)
         if body is None:
             return None
-        args = self._abstract_args(family)
+        args = self._abstract_args(family, rung, sampling)
         ctx = getattr(self.srv, "_decode_ctx", self.srv._tp_ctx) \
             if family == "decode" else self.srv._tp_ctx
         with ctx():

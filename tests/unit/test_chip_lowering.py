@@ -1304,6 +1304,24 @@ def test_kda_kernels_compile_at_the_state_cells_shapes(one_chip):
     assert chunk.compile().memory_analysis().temp_size_in_bytes < 256 << 20
 
 
+def _programs_alias_the_whole_cache(programs, cache, also=()):
+    """Compile ``programs`` (``{kernel names: (fn, args)}``, the cache tree
+    argument 1, donated) for the described chip: every named kernel (and
+    ``also``) is in the compiled text, the whole of ``cache`` is aliased in
+    and out and the temporaries stay under 300 MB.  The cache's bytes."""
+    cache_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                      for a in cache.values())
+    for kernels, (fn, args) in programs.items():
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+        text = compiled.as_text()
+        for kernel in kernels + tuple(also):
+            assert kernel in text, kernel
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= cache_bytes, (kernels, mem)
+        assert mem.temp_size_in_bytes < 300 << 20, (kernels, mem)
+    return cache_bytes
+
+
 @pytest.mark.limit(240)
 def test_state_cells_programs_alias_the_whole_cache(as_on_tpu, one_chip,
                                                     monkeypatch):
@@ -1371,14 +1389,205 @@ def test_state_cells_programs_alias_the_whole_cache(as_on_tpu, one_chip,
         ("kda_chunk_state", "paged_latent_prefill"): (prefill, (
             params, cache, i32(4, 128), i32(4, nbper), i32(4), i32(4),
             i32(4)))}
-    cache_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
-                      for a in cache.values())
-    for kernels, (fn, args) in programs.items():
-        compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
-        text = compiled.as_text()
-        for kernel in kernels + ("moe_gmm",):
-            assert kernel in text, kernel
-        mem = compiled.memory_analysis()
-        assert mem.alias_size_in_bytes >= cache_bytes, (kernels, mem)
-        # one layer's slice of the state is 403 MB
-        assert mem.temp_size_in_bytes < 300 << 20, (kernels, mem)
+    # one layer's slice of the state is 403 MB
+    _programs_alias_the_whole_cache(programs, cache, also=("moe_gmm",))
+
+
+# ------------------------------------ the state kind beside K and V (PR 55)
+#: the state chat cell (granite4h-chat-closed): 64 slots x 1,024 positions,
+#: 36 state-space layers of 64 heads x 64 x 128 beside 4 attention layers
+SSM = dict(slots=64, ctx=1024, block=32, layers=36, heads=64, width=64,
+           state=128)
+
+
+def test_ssd_kernels_compile_at_the_state_chat_cells_shapes(one_chip):
+    """Mosaic's own compile, for a described v5e, of ``ssd_step`` on the
+    whole ``[36, 64, 32, 128, 128]`` float32 state leaf (4.8 GB) — aliased in
+    and out, no temporary beside it — and of ``ssd_chunk_state`` on every
+    rung of the prefill ladder."""
+    from deepspeed_tpu.ops import ssd
+
+    c = SSM
+    h, p, n, rows = c["heads"], c["width"], c["state"], c["slots"]
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    leaf = sds(c["layers"], rows, *ssd.packed_shape(h, p, n))
+    assert leaf.shape == (36, 64, 32, 128, 128)
+    step = jax.jit(
+        lambda x, dt, a, b, cc, leaf, l: ssd.step(
+            x, dt, a, b, cc, leaf, l, kernel=True, interpret=False),
+        donate_argnums=(5,)).lower(
+            sds(rows, h, p), sds(rows, h), sds(h), sds(rows, n), sds(rows, n),
+            leaf, sds(dtype=jnp.int32))
+    assert 'kernel_name = "ssd_step"' in step.as_text()
+    mem = step.compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= 4 * int(np.prod(leaf.shape))
+    assert mem.temp_size_in_bytes < 64 << 20, mem
+    for b, t in ((4, 128), (2, 256), (1, 512)):
+        chunk = jax.jit(
+            lambda *a: ssd.chunked(*a, kernel=True, interpret=False)).lower(
+                sds(b, t, h, p), sds(b, t, h), sds(h), sds(b, t, n),
+                sds(b, t, n), sds(b, *ssd.packed_shape(h, p, n)))
+        assert 'kernel_name = "ssd_chunk_state"' in chunk.as_text()
+        assert chunk.compile().memory_analysis().temp_size_in_bytes \
+            < 64 << 20
+
+
+@pytest.mark.limit(240)
+def test_state_chat_cells_programs_alias_the_whole_cache(as_on_tpu, one_chip,
+                                                         monkeypatch):
+    """The state chat cell's decode program and its widest prefill program
+    (Granite 4.0-H Micro whole), compiled for a described v5e: the whole
+    cache tree — K and V lane-packed, the state and the convolution tails,
+    5.4 GB — is aliased in and out beside 6.4 GB of weights, both kinds'
+    kernels are there, and no temporary comes near a layer's slice of the
+    state (134 MB) times a few: no program holds a second copy of the
+    leaf."""
+    import json
+    import os
+
+    from chipbench.families import granite_hybrid
+    from deepspeed_tpu.ops import paged_kv, ssd
+
+    monkeypatch.setattr(ssd, "interpret_kernels", lambda: False)
+    monkeypatch.setattr(ssd, "on_tpu", lambda: True)
+    root = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir)
+    with open(os.path.join(root, "chipbench", "configs",
+                           "granite-4.0-h-micro.json")) as f:
+        config = json.load(f)
+    config.pop("rehearse")
+    spec = granite_hybrid.build(config)
+    fwd = spec.decode_hooks["forward_cached"]
+    c = SSM
+    slots, nbper = c["slots"], paged_kv.blocks_for(c["ctx"], c["block"])
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return sds(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    params = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda: jax.tree_util.tree_map(
+            lambda a: a.astype(jnp.bfloat16),
+            spec.init_fn(jax.random.PRNGKey(0)))))
+    cache = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: {
+        k: v if k in paged_kv.STATE_LEAVES else paged_kv.pack_pool(v)
+        for k, v in spec.decode_hooks["init_cache"](
+            1 + slots * nbper, c["block"], jnp.bfloat16,
+            state_rows=slots).items()}))
+    assert {k: v.shape for k, v in cache.items()} == {
+        "k": (4, 2049, 8, 16, 128), "v": (4, 2049, 8, 16, 128),
+        "state": (36, 64, 32, 128, 128), "conv": (36, 64, 1, 3, 4352)}
+
+    def decode_step(params, cache, tokens, lengths, bt):
+        logits, cache = fwd(params, tokens[:, None], cache, 0,
+                            lengths=lengths, block_tables={"full": bt})
+        return jnp.argmax(logits, -1).astype(jnp.int32), cache
+
+    def prefill(params, cache, ids, bt, slot, base, valid):
+        logits, cache = fwd(params, ids, cache, base, lengths=valid,
+                            block_tables={"full": bt, "slot": slot})
+        return jnp.argmax(logits, -1).astype(jnp.int32), cache
+
+    programs = {
+        ("ssd_step", "paged_decode_attn"): (decode_step, (
+            params, cache, i32(slots), i32(slots), i32(slots, nbper))),
+        ("ssd_chunk_state", "paged_prefill_attn"): (prefill, (
+            params, cache, i32(1, 512), i32(1, nbper), i32(1), i32(1),
+            i32(1)))}
+    assert round(_programs_alias_the_whole_cache(programs, cache) / 1e9,
+                 2) == 5.43
+
+
+@pytest.mark.limit(180)
+def test_state_chat_cells_timed_programs_are_tied_to_the_float32_pass(
+        as_on_tpu, one_chip, monkeypatch):
+    """``serve_ssm.check_state_programs`` on a bf16 engine's OWN decode and
+    prefill bodies, lowered for a described v5e at the rehearsal's widths:
+    their state-kind layers call ``ssd_step`` / ``ssd_chunk_state`` on
+    float32 operands and a float32 leaf whatever the activations' dtype, so
+    the programs the window times are the ones the float32 limit is read
+    off — and one bfloat16 operand in one of them is refused."""
+    import json
+    import os
+
+    import deepspeed_tpu
+    from chipbench.drivers import serve_ssm
+    from chipbench.families import granite_hybrid
+    from deepspeed_tpu.ops import ssd
+    from deepspeed_tpu.telemetry.flops import ServingFlopsProfiler
+
+    monkeypatch.setattr(ssd, "interpret_kernels", lambda: False)
+    monkeypatch.setattr(ssd, "on_tpu", lambda: True)
+    root = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir)
+    with open(os.path.join(root, "chipbench", "configs",
+                           "granite-4.0-h-micro.json")) as f:
+        config = json.load(f)
+    config.update(config.pop("rehearse"))
+    spec = granite_hybrid.build(config)
+    params = jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16),
+                                    spec.init_fn(jax.random.PRNGKey(0)))
+    srv = deepspeed_tpu.init_serving(
+        spec, config={"dtype": "bf16"}, params=params, slots=4,
+        max_seq_len=128, block_size=16, prefill_chunk=16)
+    srv._get_decode_fn(), srv._get_prefill_fn()   # the bodies; no compile
+    abstract, doctor = ServingFlopsProfiler._abstract_args, [lambda t: t]
+
+    class Lowered:
+        def __init__(self, lowered):
+            self.lowered = lowered
+
+        def as_text(self):
+            return doctor[0](self.lowered.as_text())
+
+    def lower(self, family, rung=None, sampling=False):
+        args = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            abstract(self, family, rung, sampling))
+        with srv._tp_ctx():
+            return Lowered(jax.jit(srv._program_bodies[family]).lower(*args))
+
+    monkeypatch.setattr(ServingFlopsProfiler, "lower", lower)
+    notes = []
+    job = type("Job", (), {"note": staticmethod(notes.append)})
+    profiler, programs = ServingFlopsProfiler(srv), {}
+    for rung in (None, *srv._rungs):
+        family = "decode" if rung is None else "prefill"
+        text = profiler.lower(family, rung, sampling=True).as_text()
+        programs[serve_ssm._program_name(rung)] = {
+            "family": family, "rung": rung,
+            "bodies": srv.stats()["kv_state"]["ssd"][family],
+            "kernels": serve_ssm.state_kernels(text, "ssd")}
+    assert set(programs) == {"decode", "prefill[4x16]", "prefill[1x64]"}
+    step, = programs["decode"]["kernels"]
+    assert step[0] == "ssd_step" and set(step[1]) == {"i32", "f32"} \
+        and set(step[2]) == {"f32"}
+    chunk, = programs["prefill[1x64]"]["kernels"]
+    assert chunk[0] == "ssd_chunk_state" and set(chunk[1] + chunk[2]) \
+        == {"f32"}
+    # the float32 pass drives the narrow rung alone: the wide row's program
+    # is held to its family's
+    programs = {"served": programs, "float32": {
+        k: v for k, v in programs.items() if k != "prefill[1x64]"}}
+    got = serve_ssm.check_state_programs(job, srv, programs)
+    assert got["ok"] and got["state_leaf"] == "float32", notes
+    assert all(got[name]["held"] for name in programs["served"])
+
+    def one_bf16_operand(text):
+        lines = text.splitlines()
+        for at, line in enumerate(lines):
+            if 'kernel_name = "ssd_chunk_state"' in line:
+                head, types = line.rsplit("} : ", 1)
+                lines[at] = head + "} : " + types.replace("f32>", "bf16>", 1)
+        return "\n".join(lines)
+
+    doctor[0] = one_bf16_operand
+    got = serve_ssm.check_state_programs(job, srv, programs)
+    assert not got["ok"] and got["decode"]["held"]
+    assert not got["prefill[1x64]"]["held"] and "REFUSED" in notes[-1]
+    assert "bf16" in got["prefill[1x64]"]["kernels"][0][1]
+    srv.close()

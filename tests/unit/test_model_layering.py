@@ -18,9 +18,11 @@ FAMILIES = sorted(set(MODULES) - {"cached"})
 #: (``MixtralConfig(LlamaConfig)``; ``KimiLinearConfig(MixtralConfig)``, whose
 #: latent layers and routed FFN are those two files' — PR 51; Megatron's
 #: checkpoints load as GPT-2), and the diffusion pair shares its convolution
-#: and group-norm layers
+#: and group-norm layers; Granite 4.0-H takes ``llama``'s RMSNorm by its
+#: public name and nothing else of a sibling's (PR 55)
 ALLOWED = {("mixtral", "llama"), ("megatron_gpt", "gpt2"), ("unet", "vae"),
-           ("kimi_linear", "mixtral"), ("kimi_linear", "llama")}
+           ("kimi_linear", "mixtral"), ("kimi_linear", "llama"),
+           ("granite_hybrid", "llama")}
 
 
 def _sibling_imports(tree):
@@ -97,3 +99,26 @@ def test_the_window_contract_is_decided_in_one_place():
         if isinstance(t, ast.Name)}
     assert not defined & {"scan_periods_cached", "KIND_LEAVES",
                           "live_tokens"}
+
+
+def test_the_state_kinds_families_share_the_kind_and_not_each_other():
+    """Two families ride the state kind (PR 51, PR 55): both layer names map
+    to the SAME two leaves and the ``slot`` table in ``cached.KIND_LEAVES``,
+    neither family imports the other, and the second takes one public name
+    of ``llama`` (its RMSNorm) and adds no field to ``LlamaConfig``."""
+    from deepspeed_tpu.models import cached
+    from deepspeed_tpu.ops import paged_kv
+
+    assert cached.KIND_LEAVES["kda"] == cached.KIND_LEAVES["ssm"] \
+        == paged_kv.STATE_LEAVES + ("slot",)
+    names = {(sib, name) for sib, name, _, _
+             in _sibling_imports(MODULES["granite_hybrid"])}
+    assert names == {("cached", None), ("cached", "live_tokens"),
+                     ("cached", "qmm"), ("cached", "scan_periods_cached"),
+                     ("llama", "rms_norm")}, names
+    assert not any(sib == "granite_hybrid" for stem in FAMILIES
+                   for sib, _, _, _ in _sibling_imports(MODULES[stem]))
+    llama_fields = {t.target.id for n in ast.walk(MODULES["llama"])
+                    if isinstance(n, ast.ClassDef) and n.name == "LlamaConfig"
+                    for t in n.body if isinstance(t, ast.AnnAssign)}
+    assert not {f for f in llama_fields if "ssm" in f or "multiplier" in f}
