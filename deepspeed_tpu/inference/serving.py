@@ -1452,7 +1452,8 @@ class ServingEngine:
         rows = bs // paged_kv.lane_pack(bs, hd)
         tile = decode_attention.walk_tile_blocks(rows, self._nbper)
         self._decode_attn = None if self._latent else \
-            {"tile_blocks": tile, "cols": tile * rows}
+            {"tile_blocks": tile, "cols": tile * rows,
+             "rows_ahead": decode_attention.WALK_ROWS_AHEAD}
         self._kv_scale_live: set = set()
         #: bytes an absorbed read needs of one key in one layer
         self._latent_token_bytes = self._latent["width"] \
@@ -3886,18 +3887,25 @@ class ServingEngine:
                 else int(self._lengths[slot]), upto)
         return slot in self._active
 
-    def _kv_walk(self, valid) -> Dict[str, int]:
-        """Span args of a decode dispatch, from its rows' lengths:
-        ``kv_blocks``, the blocks the rows' reads walk (``cdiv(valid,
-        block_size)`` each), and ``kv_tiles``, the loop iterations the walk
-        makes of them (``cdiv(blocks, tile)``): their quotient says how
-        full the tiles run."""
+    def _kv_walk(self, rows) -> Dict[str, int]:
+        """Span args of a decode dispatch, from its live ``rows`` (slots)
+        and their lengths: ``kv_blocks``, the blocks the rows' reads walk
+        (``cdiv(valid, block_size)`` each), ``kv_tiles``, the loop
+        iterations the walk makes of them (``cdiv(blocks, tile)``): their
+        quotient says how full the tiles run — and ``kv_first_tiles_ahead``,
+        the rows whose first tile the grid step before theirs started
+        (``_paged_walk_kernel``): every live row but row 0 of a call (a dp
+        group's rows are a call of their own)."""
         if self._decode_attn is None:
             return {}
-        blocks = -(-np.asarray(valid, np.int64) // self.block_size)
+        blocks = -(-(self._lengths[rows].astype(np.int64) + 1)
+                   // self.block_size)
         tile = self._decode_attn["tile_blocks"]
+        call = self.slots // self.dp_degree
         return {"kv_blocks": int(blocks.sum()),
-                "kv_tiles": int((-(-blocks // tile)).sum())}
+                "kv_tiles": int((-(-blocks // tile)).sum()),
+                "kv_first_tiles_ahead": int(
+                    np.count_nonzero(np.asarray(rows) % call))}
 
     def _kv_reach(self, valid, queries=None) -> Dict[str, int]:
         """Span args of a dispatch of a model with window layers, from the
@@ -5084,7 +5092,7 @@ class ServingEngine:
             counts = self._decode_counts()
             decode_fn = self._get_decode_fn()
             span_kw = {**self._sampler_rows(dec),
-                       **self._kv_walk(self._lengths[dec] + 1),
+                       **self._kv_walk(dec),
                        **self._kv_reach(self._lengths[dec] + 1),
                        **self._state_args(len(dec), 0, len(dec))}
         with seg("step.decode.upload", phase):
@@ -5885,9 +5893,10 @@ class ServingEngine:
             # the read the prefill program was traced with (None before its
             # first call): "paged_prefill_attn" on a TPU, "gather" on a CPU
             "prefill_attn": self._program_meta.get("prefill_attn"),
-            # the tile of the decode / verify walk at this pool's shapes
-            # (None for a latent pool); the ``decode`` spans carry
-            # ``kv_blocks`` and ``kv_tiles``
+            # the tile of the decode / verify walk at this pool's shapes and
+            # the grid steps its copies run ahead (None for a latent pool);
+            # the ``decode`` spans carry ``kv_blocks``, ``kv_tiles`` and
+            # ``kv_first_tiles_ahead``
             "decode_attn": self._decode_attn and dict(self._decode_attn),
             # how each built program picks its tokens: "argmax" (greedy-only
             # engine) or how ops/sampling.py finds the filter's thresholds

@@ -511,6 +511,11 @@ def paged_decode_attention_reference(q, k_pool, v_pool, block_tables, q_pos,
 #: 11.1 (32.2).  A row of ONE block costs what it did (2.1 / 1.5 / 1.4 us)
 _WALK_COLS = 128
 
+#: grid steps the decode / verify walk's copies run ahead of its updates: a
+#: row's last tile starts tile 0 of the NEXT grid step into the landing
+#: buffers' other slot (two slots: one step ahead and no further)
+WALK_ROWS_AHEAD = 1
+
 #: VMEM the decode / verify walk gives its K/V landing buffers (two slots of
 #: ``nt`` blocks each) and the query-dtype copies :func:`_attend_chunk` makes
 #: of an int8 pool's tiles (a float pool's go to the MXU as they landed)
@@ -560,11 +565,25 @@ def _paged_walk_kernel(layer_ref, pos_ref, bt_ref, q_ref, *refs,
     shape).  It copies block ``i`` itself, ``pool.at[layer, bt[b, i]]`` ->
     its place ``buf[slot, j]`` in its tile, a ``[ht, bs/g, g*D]`` VMEM
     buffer: every head of the block in ONE contiguous DMA (K and V; an int8
-    pool's ``[ht, bs]`` scale rows ride beside them).  The buffers have two slots, so tile ``i
-    + 1`` lands while tile ``i`` is attended; of the last tile only the
-    blocks the row holds are copied.  A row costs its own length: no grid
-    step and no copy is spent on the part of ``max_seq_len`` it does not
-    hold.
+    pool's ``[ht, bs]`` scale rows ride beside them).  The buffers have two
+    slots, so tile ``i + 1`` lands while tile ``i`` is attended; of the last
+    tile only the blocks the row holds are copied.  A row costs its own
+    length: no grid step and no copy is spent on the part of
+    ``max_seq_len`` it does not hold.
+
+    The two slots carry a tile ACROSS grid steps (the grid runs in order:
+    ``arbitrary``): where a row's last tile has no next one to start, it
+    starts tile 0 of the NEXT grid step — the next row's, or the next head
+    tile's — into the slot it does not hold itself, and that step starts
+    nothing for its tile 0 and only waits.  So the flight of a row's first
+    tile, which nothing of its own row can hide (a chat row is ONE tile),
+    lies under the update, the output's write and the prologue of the step
+    before.  One rule: step ``s`` starts whatever step ``s + 1``'s tile 0
+    is — no block, for a row that holds none — and step ``s + 1`` waits for
+    exactly that, both from :func:`reach` of the row's index; only a
+    launch's first step starts its own, the last starts nothing.  A row's
+    first slot is therefore no constant: it follows the tiles walked before
+    it (``slot_ref``, SMEM scratch; a row of no tile leaves it as it was).
 
     ``q_ref`` [1, ht, g*rows, g*D] (span-expanded, :func:`_span_queries`),
     ``rows = rep * t``: query row ``r*t + i`` is head ``r`` of its KV group
@@ -597,63 +616,102 @@ def _paged_walk_kernel(layer_ref, pos_ref, bt_ref, q_ref, *refs,
 
     Refs after ``q_ref``: the pool operands (K, [K scales], V, [V scales]),
     ``o_ref``, one two-slot landing buffer per pool operand, the DMA
-    semaphores ``[2, operands]``, then m / l / acc."""
+    semaphores ``[2, operands]``, the slot of this step's tile 0 (int32 [1]
+    in SMEM), then m / l / acc."""
     n_ops = 4 if quant else 2
     pools, o_ref = refs[:n_ops], refs[n_ops]
     bufs = refs[n_ops + 1:2 * n_ops + 1]
-    sem, m_scr, l_scr, acc_scr = refs[2 * n_ops + 1:]
+    sem, slot_ref, m_scr, l_scr, acc_scr = refs[2 * n_ops + 1:]
     _, nt, ht, r, _ = bufs[0].shape
     bs = r * spans
+    width = bt_ref.shape[1]
+    splits = pools[0].shape[2] // ht              # grid steps a row
     b, layer = pl.program_id(0), layer_ref[0]
     base = pos_ref[b]
-    # blocks that hold a key some query of the row may see: keys <= last
     if window:
-        width = bt_ref.shape[1]
-        n = jnp.maximum((base + t - 1 + bs) // bs, 0)
-        first = jnp.maximum(jnp.maximum(base - window + 1, 0) // bs,
-                            n - width)
         keep = lambda idx, query: (idx <= base + query % t) \
             & (idx > base + query % t - window)       # noqa: E731
     else:
-        n = jnp.clip((base + t - 1 + bs) // bs, 0, bt_ref.shape[1])
-        first = 0
         keep = lambda idx, query: idx <= base + query % t     # noqa: E731
-    whole = pools[0].shape[2] == ht
-    heads = pl.ds(pl.program_id(1) * ht, ht)
 
-    def landed(i):
-        """Blocks of tile ``i`` the row holds (0 past its last tile)."""
-        return jnp.clip(n - first - i * nt, 0, nt)
+    def reach(row):
+        """``(n, first)`` of ``row``: it walks its blocks ``first .. n - 1``,
+        those that hold a key some query of it may see (keys <= its last
+        query's).  THE arithmetic of a row's copies, for the step that
+        starts them and the step that waits for them."""
+        at = pos_ref[row]
+        if not window:
+            return jnp.clip((at + t - 1 + bs) // bs, 0, width), 0
+        n = jnp.maximum((at + t - 1 + bs) // bs, 0)
+        return n, jnp.maximum(jnp.maximum(at - window + 1, 0) // bs,
+                              n - width)
 
-    def each_block(i, slot, act):
-        """``act`` on the copies of tile ``i``'s valid blocks."""
+    def tile(step, i):
+        """Tile ``i`` of grid step ``step`` (row-major over the grid; one
+        past the last: no tile), as what its copies need: ``(row, first
+        head, first block, blocks the row holds of it)`` — 0 past its last
+        tile."""
+        row = jnp.minimum(step // splits, pl.num_programs(0) - 1)
+        n, first = reach(row)
+        return (row, step % splits * ht, first + i * nt,
+                jnp.where(step < pl.num_programs(0) * splits,
+                          jnp.clip(n - first - i * nt, 0, nt), 0))
+
+    def each_block(of, slot, act):
+        """``act`` on the copies of the valid blocks of the tile ``of``."""
+        row, head, start, held = of
+
         def one(j, carry):
-            at = first + i * nt + j
+            at = start + j
             for op, (pool, buf) in enumerate(zip(pools, bufs)):
                 # an operand that is this layer's rows alone (_lane_rows)
                 # is a one-layer stack
                 src = (layer if pool.shape[0] > 1 else 0,
-                       bt_ref[b, at % width if window else at])
+                       bt_ref[row, at % width if window else at])
                 act(pltpu.make_async_copy(
-                    pool.at[src if whole else src + (heads,)],
+                    pool.at[src if splits == 1 else src + (pl.ds(head, ht),)],
                     buf.at[slot, j], sem.at[slot, op]))
             return carry
 
-        jax.lax.fori_loop(0, landed(i), one, None)
+        jax.lax.fori_loop(0, held, one, None)
+
+    def choose(pred, a, b):
+        return tuple(jnp.where(pred, x, y) for x, y in zip(a, b))
+
+    step = b * splits + pl.program_id(1)
+    n, first = reach(b)
+    tiles = (n - first + nt - 1) // nt
+    ahead = tile(step + 1, 0)
+
+    # a launch's first step starts its own tile 0; every other step finds
+    # it started (the step before's ``ahead``) and only waits.  A row of no
+    # tile passes the duty on here, where no loop iteration does
+    @pl.when(step == 0)
+    def _():
+        slot_ref[0] = 0
+
+    slot0 = slot_ref[0]
+    *which, held = choose(tiles > 0, tile(step, 0), ahead)
+    each_block((*which, jnp.where((step == 0) | (tiles == 0), held, 0)),
+               slot0, lambda copy: copy.start())
 
     def attend(i, carry):
-        slot = i % 2
-        each_block(i + 1, 1 - slot, lambda copy: copy.start())
+        slot = (slot0 + i) % 2
+        # the tile after this one lands meanwhile: the row's next, or, on
+        # its last, tile 0 of the NEXT grid step
+        each_block(choose(i + 1 < tiles, tile(step, i + 1), ahead),
+                   1 - slot, lambda copy: copy.start())
+        mine = tile(step, i)
 
         def blank(j, carry):
             for buf in bufs[n_ops // 2:]:             # V, [V scales]
                 buf[slot, j] = jnp.zeros(buf.shape[2:], buf.dtype)
             return carry
 
-        jax.lax.fori_loop(landed(i), nt, blank, None)
-        each_block(i, slot, lambda copy: copy.wait())
-        tiles = [[buf[slot, j] for j in range(nt)] for buf in bufs]
-        k, ks, v, vs = tiles if quant else (tiles[0], None, tiles[1], None)
+        jax.lax.fori_loop(mine[3], nt, blank, None)
+        each_block(mine, slot, lambda copy: copy.wait())
+        landed = [[buf[slot, j] for j in range(nt)] for buf in bufs]
+        k, ks, v, vs = landed if quant else (landed[0], None, landed[1], None)
         # a head's nt landed blocks, one under the other: [ht, nt * r, g*D]
         # (an int8 pool's codes in the query's dtype first: its 32-row
         # tiles do not stack at r = 16)
@@ -665,8 +723,8 @@ def _paged_walk_kernel(layer_ref, pos_ref, bt_ref, q_ref, *refs,
         return carry
 
     _start_chunks(m_scr, l_scr, acc_scr)
-    each_block(0, 0, lambda copy: copy.start())
-    jax.lax.fori_loop(0, (n - first + nt - 1) // nt, attend, None)
+    jax.lax.fori_loop(0, tiles, attend, None)
+    slot_ref[0] = (slot0 + tiles) % 2
     _finish_chunks(o_ref, m_scr, l_scr, acc_scr, spans=spans)
 
 
@@ -741,6 +799,7 @@ def _paged_launch(q, k_pool, v_pool, block_tables, q_pos, layer, *,
         scratch_shapes=[pltpu.VMEM((2, nt, ht) + p.shape[3:], p.dtype)
                         for p in pools] + [
             pltpu.SemaphoreType.DMA((2, len(pools))),
+            pltpu.SMEM((1,), jnp.int32),          # the slot of tile 0
             pltpu.VMEM((ht, spans * rows, LANES), jnp.float32),   # m
             pltpu.VMEM((ht, spans * rows, LANES), jnp.float32),   # l
             pltpu.VMEM((ht, spans * rows, width), jnp.float32),   # acc
@@ -751,8 +810,10 @@ def _paged_launch(q, k_pool, v_pool, block_tables, q_pos, layer, *,
     call = dict(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
+        # a tile is carried from a grid step to the next: the steps run in
+        # order on one core (a tp mesh shards the heads outside the kernel)
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")))
+            dimension_semantics=("arbitrary", "arbitrary")))
     return kernel, call, (jnp.asarray(layer, jnp.int32).reshape(1), pos, bt,
                           qg, *pools)
 

@@ -121,7 +121,8 @@ bf16 for OPT-1.3B's 32 x 64 and OLMoE's 16 x 128 alike — so the paged
 attention kernels (``_paged_walk_kernel``) leave the pool in HBM and, row
 by row, fetch just the blocks the row's positions reach: ``pool.at[layer,
 bt[b, i]]`` for ``i < cdiv(pos + 1, block_size)``, one DMA a block and
-side, the next block in flight while this one is attended.  A table entry
+side, the next block in flight while this one is attended — across rows
+too: a row's last tile starts the next row's first.  A table entry
 past a row's valid prefix is never read, and a row costs its own length,
 not ``max_seq_len``.  Two more things keep it so: the write reads, merges and
 scatters back WHOLE blocks (:func:`_write_blocks`), in the stored view:

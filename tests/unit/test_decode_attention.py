@@ -634,7 +634,7 @@ def test_stacked_pool_attention_reads_its_layer(t, kv8, tp):
 WALK_BS, WALK_CTX, WALK_LAYERS = 32, 160, 2          # 5 blocks a row
 
 
-def _walk_case(rng, t, hd, rep, kv8, bases, held=None, ctx=None):
+def _walk_case(rng, t, hd, rep, kv8, bases, held=None, ctx=None, hkv=2):
     """A 2-layer pool, lane-packed as the engine holds it (hd 64: g = 2,
     hd 128: g = 1), rows of the given ``bases`` (query positions ``base ..
     base + t - 1``; ``held``: the tokens each row holds, ``base + t`` unless
@@ -646,7 +646,7 @@ def _walk_case(rng, t, hd, rep, kv8, bases, held=None, ctx=None):
     returns NaN, the gather reference reads the clean table."""
     from deepspeed_tpu.ops import paged_kv
 
-    b, hkv, bs = len(bases), 2, WALK_BS
+    b, bs = len(bases), WALK_BS
     kp, vp, bt = _stacked_pool(rng, WALK_LAYERS, b, hkv, ctx or WALK_CTX, hd,
                                bs, kv8)
     bt = np.asarray(bt)
@@ -713,15 +713,20 @@ def test_paged_walk_verify_window_across_a_block_boundary(hd, rep, kv8):
 
 # ISSUE 45: a loop iteration of the walk is a TILE of ``nt`` blocks and
 # ONE online-softmax update over all their keys.
+def _walk_tile(hd):
+    """The walk's tile (blocks) at ``WALK_BS``-token blocks of ``hd``."""
+    from deepspeed_tpu.ops import decode_attention as da, paged_kv
+
+    return da.walk_tile_blocks(
+        WALK_BS // paged_kv.lane_pack(WALK_BS, hd), 1 << 10)
+
+
 def _tile_case(rng, t, hd, rep, kv8):
     """:func:`_walk_case` with rows that hold 0, 1, ``nt - 1``, ``nt``, ``nt
     + 1`` and ``2 nt + 1`` blocks (``nt``: the tile at these shapes), the
     last block full, partly full or holding ONE key.  -> (its five, the
     rows' positions, nt)."""
-    from deepspeed_tpu.ops import decode_attention as da, paged_kv
-
-    bs = WALK_BS
-    nt = da.walk_tile_blocks(bs // paged_kv.lane_pack(bs, hd), 1 << 10)
+    bs, nt = WALK_BS, _walk_tile(hd)
     # (blocks, keys in the last of them)
     rows = [(0, 0), (1, max(t, 1)), (1, bs), (nt - 1, 5), (nt, bs),
             (nt + 1, 1), (2 * nt + 1, 7), (2 * nt + 1, bs)]
@@ -785,6 +790,152 @@ def test_paged_walk_attends_a_tile_of_blocks_an_update(hd, rep, t, kv8,
         want[0] = 0
     assert np.isfinite(got).all(), "read outside a row's valid blocks"
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+# ISSUE 56: on a row's last tile the walk starts tile 0 of the NEXT grid
+# step, into the slot that tile does not hold; the next step only waits.
+def _simulated_chip(mode="on_wait"):
+    """Pallas' TPU interpret mode: DMAs and their semaphores simulated, a
+    copy made only when it is waited for (``on_wait``: one that nobody
+    waits for never lands) or at its start (``eager``: one that nobody
+    waits for leaves its semaphore raised at the kernel's exit)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.InterpretParams(dma_execution_mode=mode, detect_races=True)
+
+
+def _races_found() -> bool:
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+
+    return bool(interpret_pallas_call.races.races_found)
+
+
+def _carried_case(rng, t, hd, rep, kv8, blocks, hkv=2):
+    """:func:`_walk_case` with rows of the given ``blocks`` each (0: an
+    idle row, position ``-t``: no block holds a key it may see), the last
+    block of the live ones full, partly full or holding one key in turn.
+    -> (its five, the rows' positions, the idle rows)."""
+    bs = WALK_BS
+    last = [bs, 5, max(t, 1), 7]
+    held = np.asarray([max(0, (n - 1) * bs + max(last[i % 4], t))
+                       for i, n in enumerate(blocks)])
+    held = np.where(np.asarray(blocks) > 0, held, 0)
+    return (*_walk_case(rng, t, hd, rep, kv8, held - t, held=held,
+                        ctx=max(blocks) * bs, hkv=hkv),
+            jnp.asarray(held - t, jnp.int32), np.asarray(blocks) == 0)
+
+
+def _carried_window_case(t):
+    """:func:`_window_tile_case`'s rows (one and two tiles of 4 blocks, the
+    ring wrapping inside a row's FIRST tile at 131 — the tile the step
+    before starts — and inside its second at 64) with idle rows before,
+    between and behind them, their table entries dead blocks."""
+    q, kp, vp, bt, pos, want = _ring_case(t, 40, 8, 7,
+                                          [45, 131, 64, 3, 100, 131], seed=5)
+    dead = np.setdiff1d(np.arange(kp.shape[1]), np.asarray(bt)[bt > 0])
+    at = [0, 2, 2, 6]                                 # idle rows go here
+    idle = np.zeros(len(pos) + len(at), bool)
+    idle[[0, 3, 4, 9]] = True
+    grow = lambda a, fill: jnp.asarray(np.insert(     # noqa: E731
+        np.asarray(a), at, fill, axis=0))
+    return (grow(q, 1.0), kp.at[:, dead].set(jnp.nan),
+            vp.at[:, dead].set(jnp.nan), grow(bt, dead[0]), grow(pos, -t),
+            np.insert(want, at, 0.0, axis=0), idle)
+
+
+@pytest.mark.parametrize("hd,rep,t,kv8,shape", [
+    (128, 1, 1, False, "rows"), (64, 1, 1, False, "rows"),
+    (128, 1, 4, False, "rows"), (64, 1, 4, False, "rows"),
+    (128, 4, 1, False, "rows"), (64, 4, 4, False, "rows"),
+    (64, 1, 1, True, "rows"), (128, 4, 4, True, "rows"),
+    (128, 2, 1, False, "window"), (128, 2, 4, False, "window"),
+    (128, 1, 1, False, "one-row"), (64, 2, 4, False, "one-idle-row"),
+    (128, 1, 1, False, "head-split"), (128, 1, 4, False, "head-split"),
+], ids=["g1", "g2", "g1-verify", "g2-verify", "gqa4", "g2-gqa4-verify",
+        "kv8-g2", "kv8-g1-gqa4-verify", "window", "window-verify", "one-row",
+        "one-idle-row", "head-split", "head-split-verify"])
+def test_paged_walk_carries_a_tile_across_grid_steps(hd, rep, t, kv8, shape,
+                                                     monkeypatch):
+    """A row's last tile starts tile 0 of the next grid step: on a
+    simulated chip whose copies are made only when waited for, with its
+    race detector on and every block outside a row's valid blocks NaN.
+    Idle rows first, last and between live ones (they hand the duty on and
+    keep the slot's parity); rows of ``nt - 1``, ``nt``, ``nt + 1`` and ``2
+    nt + 1`` blocks side by side (1, 1, 2, 3 tiles: both parities are
+    carried); decode and a T = 4 verify window; packed and plain blocks; GQA;
+    int8 records; a window layer whose ring wraps in the carried tile; a
+    launch of one row; a grid that splits a row's heads over two steps."""
+    from deepspeed_tpu.ops import decode_attention as da
+
+    kernel = paged_decode_attention_pallas if t == 1 \
+        else paged_verify_attention_pallas
+    nt = _walk_tile(hd)
+    if shape == "window":
+        monkeypatch.setattr(da, "_WALK_COLS", 32)        # 4 blocks of 8
+        q, kp, vp, bt, pos, want, idle = _carried_window_case(t)
+        got = np.asarray(kernel(q, kp, vp, bt, pos, layer=0, window=40,
+                                interpret=_simulated_chip()))
+    else:
+        hkv = 2
+        blocks = {"rows": [0, nt - 1, nt, nt + 1, 0, 2 * nt + 1, nt + 1, 1,
+                           2 * nt + 1, 0],
+                  "one-row": [nt + 1], "one-idle-row": [0],
+                  "head-split": [1, 0, nt + 1, nt]}[shape]
+        if shape == "head-split":
+            hkv = 32
+            monkeypatch.setattr(
+                da, "_WALK_VMEM_BUDGET",
+                16 * nt * WALK_BS * hd * (4 * 4 + 2 * 2))
+            assert da._walk_head_tile(hkv, WALK_BS, hd, 4, nt) == 16
+        rng = np.random.default_rng(560 + hd + rep + t)
+        q, kp, vp, bt, clean, pos, idle = _carried_case(
+            rng, t, hd, rep, kv8, blocks, hkv=hkv)
+        want = np.array(paged_decode_attention_reference(
+            q, kp, vp, clean, pos, layer=1))
+        want[idle] = 0
+        got = np.asarray(kernel(q, kp, vp, bt, pos, layer=1,
+                                interpret=_simulated_chip()))
+    assert not _races_found()
+    assert np.isfinite(got).all(), "a tile that nobody waited for, or read " \
+        "outside a row's valid blocks"
+    assert not got[idle].any()
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("kv8", [False, True], ids=["float", "kv8"])
+def test_paged_walk_waits_for_every_copy_it_starts(kv8, capfd):
+    """The kernel's text starts a tile at two sites (a launch's first
+    step's own, or the next step's from a row of no tile; the loop's: the
+    row's next tile or the next step's first) and waits at one, an operand
+    each; what it starts it waits for — on a simulated chip whose copies
+    signal at their start, no semaphore is left raised at the kernel's exit,
+    behind rows of 0, 1, 2 and 3 tiles and behind a last row of each."""
+    nt = _walk_tile(128)
+    rng = np.random.default_rng(561)
+    for blocks in ([0, nt + 1, 1, 0, 2 * nt + 1], [nt, 0], [2 * nt]):
+        q, kp, vp, bt, clean, pos, idle = _carried_case(
+            rng, 1, 128, 1, kv8, blocks)
+        got = np.asarray(paged_decode_attention_pallas(
+            q, kp, vp, bt, pos, layer=1,
+            interpret=_simulated_chip("eager")))
+        assert np.isfinite(got).all() and not _races_found()
+    assert "non-zero count" not in capfd.readouterr().out
+
+    def sites(jaxpr, counts):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name in counts:
+                counts[eqn.primitive.name] += 1
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                sites(sub, counts)
+        return counts
+
+    jaxpr = jax.make_jaxpr(lambda *a: paged_decode_attention_pallas(
+        *a, layer=1, interpret=False))(q, kp, vp, bt, pos)
+    kernel, = (eqn.params["jaxpr"] for eqn in jaxpr.jaxpr.eqns
+               if eqn.primitive.name == "pallas_call")
+    operands = 4 if kv8 else 2
+    assert sites(kernel, {"dma_start": 0, "dma_wait": 0}) == {
+        "dma_start": 2 * operands, "dma_wait": operands}
 
 
 @pytest.mark.parametrize(

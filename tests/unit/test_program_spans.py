@@ -191,7 +191,8 @@ def test_decode_spans_count_the_blocks_and_tiles_their_reads_walk(served,
     hd = tiny[1].hidden_size // tiny[1].num_heads
     r = bs // paged_kv.lane_pack(bs, hd)
     nt = decode_attention.walk_tile_blocks(r, SERVE_KW["max_seq_len"] // bs)
-    assert srv.stats()["decode_attn"] == {"tile_blocks": nt, "cols": nt * r}
+    assert srv.stats()["decode_attn"] == {"tile_blocks": nt, "cols": nt * r,
+                                          "rows_ahead": 1}
     spans = _named(events, "decode")
     for a in (e["args"] for e in spans):
         assert a["slots"] <= a["kv_tiles"] <= a["kv_blocks"] \
@@ -200,6 +201,32 @@ def test_decode_spans_count_the_blocks_and_tiles_their_reads_walk(served,
     want = sum(-(-(len(r.prompt) + j) // bs) for r in _requests(tiny[1])
                for j in range(1, r.max_new_tokens))
     assert sum(e["args"]["kv_blocks"] for e in spans) == want
+
+
+@pytest.mark.parametrize("budgets,freed", [((9, 3, 9), [0, 2]),
+                                           ((3, 9, 9), [1, 2])],
+                         ids=["a-free-slot-in-the-middle", "row-0-free"])
+def test_decode_spans_count_the_first_tiles_started_ahead(tiny, budgets,
+                                                          freed, monkeypatch):
+    """ISSUE 56: ``kv_first_tiles_ahead`` on a ``decode`` span is the live
+    rows of its call whose first tile the grid step before theirs started:
+    every live row but row 0 of the call — exact over a run whose calls see
+    all three slots live, then a free slot between two live ones (or row 0
+    free: then every live row's)."""
+    engine, cfg = tiny
+    srv = ServingEngine(engine, **SERVE_KW)
+    assert srv.stats()["decode_attn"]["rows_ahead"] == 1
+    calls, walk = [], srv._kv_walk
+    monkeypatch.setattr(srv, "_kv_walk", lambda rows: (
+        calls.append([int(r) for r in rows]), walk(rows))[1])
+    rng = np.random.default_rng(56)
+    srv.serve([Request(uid=i, prompt=rng.integers(0, cfg.vocab_size, 11,
+                                                  dtype=np.int32),
+                       max_new_tokens=n) for i, n in enumerate(budgets)])
+    args = [e["args"] for e in _named(srv.timeline.events(), "decode")]
+    assert [0, 1, 2] in calls and freed in calls
+    assert [(a["slots"], a["kv_first_tiles_ahead"]) for a in args] \
+        == [(len(rows), len(rows) - (0 in rows)) for rows in calls]
 
 
 #: (temperature, top_k, top_p) a request: greedy, sampled unfiltered, top-p,

@@ -179,6 +179,16 @@ def test_engine_stats_keys_pinned(served):
     assert set(srv.stats().keys()) == ENGINE_STATS_KEYS
 
 
+def test_decode_attn_stats_keys_pinned(served):
+    """``stats()["decode_attn"]``: the decode walk's tile at the pool's
+    shapes (PR 45) and the grid steps its copies run ahead (PR 56: one)."""
+    srv, _ = served
+    walk = srv.stats()["decode_attn"]
+    assert set(walk) == {"tile_blocks", "cols", "rows_ahead"}
+    assert walk["rows_ahead"] == 1 and all(
+        isinstance(v, int) for v in walk.values())
+
+
 def test_engine_stats_keys_pinned_with_draft_pool_extras(served):
     """The only engine stats() extension point: a draft pool adds its
     two byte-accounting keys (PR 5 behavior, unchanged)."""
