@@ -561,17 +561,20 @@ def audit_serving_engine(srv, active) -> None:
         if getattr(srv, "resident_window_blocks", 0) else None
     timeline = getattr(srv, "timeline", None)
     try:
-        audit_paged_state(srv._alloc, srv._tables, srv._held,
-                          prefix=srv._prefix, active_needs=needs,
-                          block_size=srv.block_size,
-                          scale_live=(srv._kv_scale_live
-                                      if getattr(srv, "kv_quant", False)
-                                      else None),
-                          scratch_blocks=getattr(
-                              srv, "_scratch_blocks", None),
-                          window_frontiers=frontiers,
-                          landmark_blocks=getattr(
-                              srv, "_landmark_blocks", 0))
+        # (a cache tree with no paged leaf has no block, table or refcount
+        # to audit: ``inference/paged.py NoBlocks``)
+        if getattr(srv, "_paged", True):
+            audit_paged_state(srv._alloc, srv._tables, srv._held,
+                              prefix=srv._prefix, active_needs=needs,
+                              block_size=srv.block_size,
+                              scale_live=(srv._kv_scale_live
+                                          if getattr(srv, "kv_quant", False)
+                                          else None),
+                              scratch_blocks=getattr(
+                                  srv, "_scratch_blocks", None),
+                              window_frontiers=frontiers,
+                              landmark_blocks=getattr(
+                                  srv, "_landmark_blocks", 0))
         if getattr(srv, "_host", None) is not None:
             audit_host_store(
                 srv._host,

@@ -304,6 +304,16 @@ class WindowRing:
         self.lo[row] = self.hi[row] = 0
 
 
+class NoBlocks:
+    """The allocator's face of a cache tree with NO paged leaf (a model
+    whose every layer keeps a recurrent state a slot: ``ops/paged_kv.py``
+    "The state kind"): there is no block, so none is free, none in use and
+    none can be asked for (it has no ``alloc``) — the scheduler admits such
+    a model's requests by a free slot and never comes here for one."""
+
+    num_blocks = free_blocks = blocks_in_use = version = 0
+
+
 class GroupedBlockAllocator:
     """:class:`BlockAllocator` partitioned into ``groups`` contiguous
     spans of ``num_blocks // groups`` physical blocks — one span per dp

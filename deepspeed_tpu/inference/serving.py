@@ -247,17 +247,20 @@ block, the read each program was traced with and the refusals; the
 ``kv_blocks``, ``kv_pairs`` (query-key pairs x layers) and ``latent_bytes``
 (:meth:`ServingEngine._kv_reach`).
 
-**The state kind** (PR 51, PR 55): a model with recurrent layers (decode
-hook ``state_layers``: gated delta-rule layers, ``models/kimi_linear.py``,
-beside a latent pool; state-space layers, ``models/granite_hybrid.py``, beside
-the ``full`` kind's K and V) keeps, for each such layer,
-a recurrent state a SLOT and nothing a token: ``state [L, slots, ...]``
-float32 (the trailing shape is the family's) and the short convolutions'
-tails ``conv [L, slots, 1, taps,
-channels]`` ride in the cache tree beside the paged pool (``ops/paged_kv.py``
-"The state kind") — donated and carried with it, not lane-packed — with no
-block ids, no table and no allocator: a slot's rows are its own for as long as it
-holds a request.  The state follows the slot: a prefill window at base 0
+**The state kind** (PR 51, PR 55, PR 57): a model with recurrent layers
+(decode hook ``state_layers``: gated delta-rule layers,
+``models/kimi_linear.py``, beside a latent pool; state-space layers,
+``models/granite_hybrid.py``, beside the ``full`` kind's K and V;
+power-retention layers, ``models/brumby.py``, beside NOTHING) keeps, for
+each such layer, a recurrent state a SLOT and nothing a token: ``state [L,
+slots, ...]`` float32 (the trailing shape is the family's) and what the
+family names beside it (``paged_kv.STATE_COMPANIONS``: the short
+convolutions' tails ``conv [L, slots, 1, taps, channels]``, the normaliser
+``z [L, slots, heads, ...]``) ride in the cache tree (``ops/paged_kv.py``
+"The state kind") — donated and carried with the paged pool where there is
+one, not lane-packed — with no block ids, no table and no allocator: a
+slot's rows are its own for as long as it holds a request.  The state
+follows the slot: a prefill window at base 0
 starts from a zero state inside the program (a request ENTERING a slot needs
 no reset call; the span counts it, ``state_resets``), each chunk of a prompt
 carries it on (the pads of a ``[rows, chunk]`` call leave it untouched), a
@@ -273,9 +276,29 @@ the prefix trie, the host / NVMe tiers, ``spec_tokens`` and a draft model,
 and tp / dp / sp meshes.  ``stats()["kv_state"]`` has the leaves, their
 bytes (whatever the rows' lengths), the resets, which body each program's
 recurrence lowered to — under the hook's ``bodies`` (``"kda"``: ``kda_step``
-/ ``kda_chunk_state``; ``"ssd"``: ``ssd_step`` / ``ssd_chunk_state``) — and
+/ ``kda_chunk_state``; ``"ssd"``: ``ssd_step`` / ``ssd_chunk_state``;
+``"power"``: ``power_step`` / ``power_chunk_state``) — and
 the refusals; ``stats()["kv_kinds"]`` names the state kind beside the paged
 one.  The engine names no family: what it knows of one is the hook.
+
+**No paged leaf at all** (PR 57): where the hook's cache tree holds the
+state kind's leaves and nothing else (``_paged`` false) there is no pool:
+none is committed (the start-up ring's ``pool`` span carries the state's
+bytes, ``blocks`` 0), the allocator is ``inference/paged.py NoBlocks`` —
+it has no block and gives none — the block table has no column, a row
+of any length holds no block (:meth:`ServingEngine._blocks_for`), and a
+request is ADMITTED BY A FREE SLOT
+alone, needs nothing as it grows, is never preempted for room and is bounded
+by ``max_seq_len`` only through the positions its rotary takes.  ``slots``
+is what sizes the cache (a slot's state is the same bytes whatever its
+length); ``num_blocks`` / ``block_size`` size nothing.  Both programs take
+``block_tables = {"slot": int32 [rows]}`` — with no table to tell an idle
+row by, a decode step names its live rows too (:meth:`_live_rows`), an idle
+one's slot out of range.  ``stats()`` reports ``num_blocks`` /
+``blocks_in_use`` / ``free_blocks`` 0, ``kv_pool_bytes`` 0 and
+``kv_pool_shape`` ``[]`` (the state's bytes are ``kv_state``'s), ``kv_kinds``
+the state kind alone, and the ``prefill`` spans ``kv_blocks`` 0.  Every one
+of the refusals above holds as it stands.
 
 Greedy decoding only: per-request outputs are token-identical to
 sequential ``generate`` (pinned in ``tests/unit/test_serving.py``,
@@ -322,7 +345,7 @@ from ..utils.logging import log_dist, logger
 from ..utils.platform import on_tpu
 from .operands import OperandLayout
 from .paged import (SCRATCH_BLOCK, BlockAllocator, GroupedBlockAllocator,
-                    HostBlockStore, NvmeBlockStore, PrefixCache,
+                    HostBlockStore, NoBlocks, NvmeBlockStore, PrefixCache,
                     TransportError, WindowRing, chain_key, chain_keys)
 from .spec import NGramProposer, greedy_accept, rejection_accept
 
@@ -1022,10 +1045,11 @@ class ServingEngine:
                 "it with config={'quant': {'enabled': True, 'type': "
                 "'w8a8'}} (init_serving(quantize=...) does this for you)")
         #: a recurrent state a row (decode hook ``state_layers``:
-        #: ``{"layers", "heads", "key_dim", "value_dim", "conv_taps",
-        #: "channels", "bodies"}``): leaves indexed by SLOT beside the paged pool, with
-        #: no block ids, no table and no allocator (module docstring "The
-        #: state kind"); None otherwise
+        #: ``{"layers", "heads", "key_dim", "value_dim", "bodies"}`` and
+        #: what else the family says of itself): leaves indexed by SLOT,
+        #: beside the paged pool where there is one, with no block ids, no
+        #: table and no allocator (module docstring "The state kind");
+        #: None otherwise
         self._state = (getattr(engine.module, "decode_hooks", None)
                        or {}).get("state_layers")
         self._state_totals = {"resets": 0, "state_rows": 0}
@@ -1097,7 +1121,16 @@ class ServingEngine:
         # logical per-sequence capacity, rounded up to whole blocks
         self._cache_len = blocks_for(self.max_seq_len, block_size) \
             * block_size
-        self._nbper = self._cache_len // block_size      # block-table width
+        #: whether the cache tree has a paged leaf at all: a model whose
+        #: EVERY layer is of the state kind has no pool, no block and no
+        #: table — a request is admitted by a free slot alone, needs nothing
+        #: as it grows and is bounded by ``max_seq_len`` only
+        self._paged = not self._state or bool(self._paged_leaves(
+            jax.eval_shape(lambda: self._init_cache(
+                2, self.block_size, engine._config.jnp_dtype,
+                state_rows=1))))
+        # block-table width (a model with no paged leaf: no column)
+        self._nbper = self._cache_len // block_size if self._paged else 0
 
         # floor of 2: forward_cached dispatches per-row DECODE on T == 1,
         # so a width-1 prefill window would be misread as a decode step
@@ -1259,7 +1292,11 @@ class ServingEngine:
                     + ", ".join(unserved))
         if num_blocks is None:
             num_blocks = self.dp_degree + self.slots * self._nbper
-        if self.dp_degree > 1:
+        if not self._paged:
+            num_blocks = 0          # no pool is committed: nothing to count
+            self._alloc = NoBlocks()
+            self._scratch_blocks = None
+        elif self.dp_degree > 1:
             # one allocation group per dp shard: each group owns a
             # contiguous span of physical blocks (its local block 0 is that
             # group's scratch), so every dp shard's gathers and scatters
@@ -1439,26 +1476,33 @@ class ServingEngine:
             mk_pool = lambda: self._init_cache(
                 num_blocks, self.block_size, engine._config.jnp_dtype,
                 **kinds)
+            # (no paged leaf: the state kind's own dtype)
+            shapes = jax.eval_shape(mk_pool)
             self._kv_dtype = jnp.dtype(jax.tree_util.tree_leaves(
-                self._paged_leaves(jax.eval_shape(mk_pool)))[0].dtype).name
+                self._paged_leaves(shapes) or shapes)[0].dtype).name
         # the hook's (logical) shape [L, NB, HKV, bs, hd]; the pool itself
-        # is held lane-packed (:meth:`_commit_pool`)
-        self._pool_shape = tuple(self._widest_leaf(mk_pool).shape)
+        # is held lane-packed (:meth:`_commit_pool`).  () where no leaf is
+        # paged
+        self._pool_shape = tuple(self._widest_leaf(mk_pool).shape) \
+            if self._paged else ()
         #: the tile of the decode / verify walk at this pool's stored
         #: shapes: blocks a loop iteration, score columns a softmax update
         #: (``ops/decode_attention.py`` ``walk_tile_blocks``; None for a
-        #: latent pool, which is read by a walk of its own)
-        bs, hd = int(self._pool_shape[3]), int(self._pool_shape[4])
-        rows = bs // paged_kv.lane_pack(bs, hd)
-        tile = decode_attention.walk_tile_blocks(rows, self._nbper)
-        self._decode_attn = None if self._latent else \
-            {"tile_blocks": tile, "cols": tile * rows,
-             "rows_ahead": decode_attention.WALK_ROWS_AHEAD}
+        #: latent pool, which is read by a walk of its own, and where
+        #: there is no pool to walk)
+        self._decode_attn = None
+        if self._paged and not self._latent:
+            bs, hd = int(self._pool_shape[3]), int(self._pool_shape[4])
+            rows = bs // paged_kv.lane_pack(bs, hd)
+            tile = decode_attention.walk_tile_blocks(rows, self._nbper)
+            self._decode_attn = {
+                "tile_blocks": tile, "cols": tile * rows,
+                "rows_ahead": decode_attention.WALK_ROWS_AHEAD}
         self._kv_scale_live: set = set()
         #: bytes an absorbed read needs of one key in one layer
         self._latent_token_bytes = self._latent["width"] \
             * jnp.dtype(self._kv_dtype).itemsize if self._latent else 0
-        hkv = int(self._pool_shape[2])
+        hkv = int(self._pool_shape[2]) if self._paged else 1
         divisible = self.tp_degree > 1 and hkv % self.tp_degree == 0
         if shard_kv and self.tp_degree > 1 and not divisible:
             raise ValueError(
@@ -2235,8 +2279,10 @@ class ServingEngine:
         if self.resident_window_blocks:
             return "a resident window slides once a call, so a call's " \
                 "width is part of what its queries see"
-        if self._latent:
-            return None        # the latent kernel tiles its own queries
+        if self._latent or not self._paged:
+            # the latent kernel tiles its own queries; a chunked recurrence
+            # takes any whole number of its chunks
+            return None
         dtype = engine._config.jnp_dtype
         kinds = {"window_blocks": 2} if self._windows else \
             {"state_rows": 1} if self._state else {}
@@ -2314,7 +2360,7 @@ class ServingEngine:
         if unserved:
             raise ValueError(
                 f"{engine.module.name} keeps a recurrent state a slot "
-                "(decode hook state_layers) beside its paged pool, which "
+                "(decode hook state_layers), which "
                 "is not served with " + "; ".join(unserved))
         return False
 
@@ -2583,9 +2629,11 @@ class ServingEngine:
             "full": sds(self._nbper), "window": sds(self._ring.width)}
         if self._state:
             # the state kind's "table": the slot of each row of a prefill
-            # call (a decode step's row b is slot b)
-            spec["block_tables"] = {"full": sds(self._nbper)}
-            if "ids" in head:
+            # call (a decode step's row b is slot b; where no table tells
+            # an idle row, the decode step carries it too)
+            spec["block_tables"] = {"full": sds(self._nbper)} \
+                if self._paged else {}
+            if "ids" in head or not self._paged:
                 spec["block_tables"]["slot"] = sds()
         spec.update((name, sds()) for name in tail)
         if self.sampling:
@@ -3857,6 +3905,12 @@ class ServingEngine:
             if victim == requester:
                 return None
 
+    def _blocks_for(self, tokens):
+        """Blocks a row of ``tokens`` positions (an int, or an array of
+        them) holds: none where no leaf is paged — a row of states holds no
+        block, whatever its length."""
+        return -(-tokens // self.block_size) if self._paged else tokens * 0
+
     def _ensure_blocks(self, slot: int, upto: int) -> bool:
         """Make the slot's table cover positions ``[0, upto)``; may preempt
         other slots (or the slot itself — returns False).  Resident-window
@@ -3866,7 +3920,7 @@ class ServingEngine:
         st = self._active.get(slot)
         skip_hi = getattr(st, "window_blk", 0) \
             if self.resident_window_blocks and st is not None else 0
-        for li in range(blocks_for(upto, self.block_size)):
+        for li in range(self._blocks_for(upto)):
             if self._landmark_blocks <= li < skip_hi:
                 continue
             if slot not in self._active:
@@ -3969,13 +4023,15 @@ class ServingEngine:
         model with window layers, the table of each kind, the window kind's
         rings gathered for the same rows (``rows``: slot of each row, -1 a
         pad row; None: row i is slot i, rows whose table is all scratch
-        are idle)."""
+        are idle; a model with no paged leaf has no table and names its
+        live rows in both programs, :meth:`_live_rows`)."""
         if self._state:
             if rows is None:
                 return {"full": tables}
-            return {"full": tables, "slot": np.asarray(
-                [slot if slot >= 0 else self.slots for slot in rows],
-                np.int32)}
+            slot = np.asarray([slot if slot >= 0 else self.slots
+                               for slot in rows], np.int32)
+            return {"full": tables, "slot": slot} if self._paged \
+                else {"slot": slot}
         if not self._windows:
             return tables
         if rows is None:
@@ -3986,6 +4042,15 @@ class ServingEngine:
                 if slot >= 0:
                     ring[row] = self._ring.tables[slot]
         return {"full": tables, "window": ring}
+
+    def _live_rows(self, dec) -> Optional[List[int]]:
+        """``rows`` of :meth:`_bt` for a decode step over the slots ``dec``:
+        None where a paged table tells the idle rows (all scratch), else
+        every row's slot, -1 an idle one."""
+        if self._paged:
+            return None
+        live = set(dec)
+        return [s if s in live else -1 for s in range(self.slots)]
 
     # --------------------------------------------------------------- schedule
     def _admit(self):
@@ -4024,7 +4089,8 @@ class ServingEngine:
             plen = int(prompt_eff.size)
             # gate on a non-mutating probe first: while the queue head is
             # blocked, iterations must not churn refcounts / LRU recency
-            total_need = blocks_for(plen + 1, self.block_size)
+            # (no paged leaf: none, a free slot is all a request needs)
+            total_need = self._blocks_for(plen + 1)
             if self.resident_window_blocks:
                 # resident-window serving admits on the RESIDENT footprint
                 # only — landmark + window + the chunk being prefilled —
@@ -5097,7 +5163,8 @@ class ServingEngine:
                        **self._state_args(len(dec), 0, len(dec))}
         with seg("step.decode.upload", phase):
             host, puts = self._host_operands(
-                "decode", tokens, self._lengths, self._bt(bt),
+                "decode", tokens, self._lengths,
+                self._bt(bt, self._live_rows(dec)),
                 *((self._window_start,) if self.resident_window_blocks
                   else ()), *self._samp_args(counts))
             fed = [(slot, active[slot]) for slot in dec]
@@ -5539,8 +5606,7 @@ class ServingEngine:
                 "shape": self._rung_name(rung), "tokens": int(valid.sum()),
                 "slots": list(map(int, group)),
                 # blocks the rows' reads walk: cdiv(base + valid, bs) each
-                "kv_blocks": int(
-                    (-(-(base + valid) // self.block_size)).sum()),
+                "kv_blocks": int(self._blocks_for(base + valid).sum()),
                 **self._sampler_rows(group),
                 **self._kv_reach((base + valid)[:len(group)],
                                  valid[:len(group)]),
@@ -5739,7 +5805,9 @@ class ServingEngine:
             return int(sum(x.size * x.dtype.itemsize
                            for x in jax.tree_util.tree_leaves(tree)))
 
-        total = _bytes(self._cache)
+        # (a cache tree with no paged leaf has no pool: its bytes are
+        # ``stats()["kv_state"]``'s)
+        total = _bytes(self._cache) if self._paged else 0
         scale_bytes = int(sum(
             leaf["ps"].size * leaf["ps"].dtype.itemsize
             for leaf in jax.tree_util.tree_leaves(
@@ -5777,8 +5845,9 @@ class ServingEngine:
         if self._state:
             # the paged kind beside the state kind (which has no blocks)
             paged = "latent" if self._latent else "full"
-            return {paged: kind(self._alloc, int(self._pool_shape[0]),
-                                self._nbper, self._full_peak),
+            return {**({paged: kind(self._alloc, int(self._pool_shape[0]),
+                                    self._nbper, self._full_peak)}
+                       if self._paged else {}),
                     "state": {"layers": self._state["layers"],
                               "slots": self.slots,
                               "bytes": self._state_bytes()},
@@ -5799,7 +5868,7 @@ class ServingEngine:
     def _state_bytes(self) -> int:
         """Bytes of the state kind's leaves, all slots."""
         return int(sum(self._cache[k].size * self._cache[k].dtype.itemsize
-                       for k in STATE_LEAVES))
+                       for k in STATE_LEAVES if k in self._cache))
 
     def _kv_state(self) -> Dict[str, Any]:
         """``stats()["kv_state"]`` (a model with a recurrent state a row)."""
@@ -5808,7 +5877,7 @@ class ServingEngine:
                 "slots": self.slots, "bytes": nbytes,
                 "bytes_per_slot": nbytes // self.slots,
                 "leaves": {k: list(self._cache[k].shape)
-                           for k in STATE_LEAVES},
+                           for k in STATE_LEAVES if k in self._cache},
                 self._state["bodies"]: dict(
                     self._program_meta.get("state_bodies", {})),
                 **self._state_totals,

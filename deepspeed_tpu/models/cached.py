@@ -382,16 +382,18 @@ def decode_over_layers(body, x, blocks, cache_k, cache_v, num_layers,
 #: ``full`` / ``sliding`` hold a key and a value a KV head a token
 #: (``ops/paged_kv.py`` "Layer kinds"), ``latent`` ONE leaf (a latent a token,
 #: under the full kind's table); the STATE kind's layers — ``kda`` (a gated
-#: delta rule, ``models/kimi_linear.py``) and ``ssm`` (a state-space scan,
-#: ``models/granite_hybrid.py``): the leaves' shapes are the family's —
-#: hold no token at all: ``state`` and ``conv`` are indexed by ROW
+#: delta rule, ``models/kimi_linear.py``), ``ssm`` (a state-space scan,
+#: ``models/granite_hybrid.py``) and ``power`` (power retention,
+#: ``models/brumby.py``): the leaves' shapes are the family's —
+#: hold no token at all: ``state`` and the leaf the family keeps beside it
+#: (``paged_kv.STATE_COMPANIONS``) are indexed by ROW
 #: (``ops/paged_kv.py`` "The state kind") and their "table" is ``slot``,
 #: int32 ``[B]`` — the row of the leaves each row of the call owns (absent
-#: in a decode step, where row ``b`` IS row ``b``)
+#: in a decode step beside a paged table, where row ``b`` IS row ``b``)
 KIND_LEAVES = {"full": ("k", "v", "full"), "sliding": ("kw", "vw", "window"),
                "latent": ("latent", None, "full"),
-               "kda": paged_kv.STATE_LEAVES + ("slot",),
-               "ssm": paged_kv.STATE_LEAVES + ("slot",)}
+               **{kind: ("state", beside, "slot")
+                  for kind, beside in paged_kv.STATE_COMPANIONS.items()}}
 
 
 def scan_periods_cached(kinds, num_layers: int, step, x, blocks, cache,

@@ -74,9 +74,12 @@ Layout contract — the WHOLE stacked pool, addressed in place:
 
  - **The state kind.**  A model with recurrent layers keeps, for each such
    layer, no token at all: a row's whole past is a float32 matrix a head,
-   ``state [L, rows, ...]`` (2 MiB a row a layer in both families that ride
-   it, whatever the row's length), and the last ``K - 1`` inputs of its
-   short convolutions, ``conv [L, rows, 1, K - 1, channels]``.  What is the
+   ``state [L, rows, ...]`` (2 MiB a row a layer in the two families that
+   ride it beside a paged pool, 34 MB in the one that has no pool, whatever
+   the row's length), and what the FAMILY names beside it
+   (:data:`STATE_COMPANIONS`): the last ``K - 1`` inputs of its short
+   convolutions, ``conv [L, rows, 1, K - 1, channels]``, or the running sum
+   that normalises its read, ``z [L, rows, heads, ...]``.  What is the
    KIND's: these
    leaves ride in the SAME cache tree as the paged leaves — donated, carried
    through the layer loop and handed back in the same buffers — but are
@@ -84,19 +87,28 @@ Layout contract — the WHOLE stacked pool, addressed in place:
    no table, no allocator, nothing to share, swap or evict.  "Block at dim
    1, heads at dim 2" holds in form (rows at dim 1, heads — a unit axis for
    ``conv`` — at dim 2) and :func:`pack_pool` is NOT applied to them (there
-   is no block to lane-pack; the engine skips them by name,
-   :data:`STATE_LEAVES`).  A decode step's row ``b`` is row
+   is no block to lane-pack; the engine skips, resets and counts them by the
+   kind, :data:`STATE_LEAVES`).  A cache tree may hold the state kind's
+   leaves and NOTHING else: such a model has no pool, no block and no table
+   at all, and a row of it needs nothing as it grows.  A decode step's row
+   ``b`` is row
    ``b`` of the leaves and the kernel updates the matrices in place
    (``input_output_aliases``); a prefill call names its rows'
    slots (``block_tables["slot"]``: gathered, advanced by the chunked form,
    scattered back at ``[layer, slot]``, a pad row's slot out of range and
-   dropped).  What is a FAMILY's: the shapes behind the rows, the
+   dropped; where there is no paged table to tell an idle row by, a decode
+   step carries ``slot`` too, an idle row's out of range).  What is a
+   FAMILY's: the shapes behind the rows, the
    recurrence and its kernels — a gated delta rule with a decay a key
    channel (``models/kimi_linear.py``, ``ops/delta_rule.py``: ``state [L_kda,
    rows, H, dk, dv]``, ``kda_step`` / ``kda_chunk_state``), a state-space scan
    with a scalar decay a head (``models/granite_hybrid.py``, ``ops/ssd.py``:
    ``state [L_ssm, rows, H / g, N, g P]``, head-packed so that its minor dim
-   fills the lanes; ``ssd_step`` / ``ssd_chunk_state``).
+   fills the lanes; ``ssd_step`` / ``ssd_chunk_state``), a gated sum of the
+   key's degree-2 monomials by the value, read by a group of query heads
+   and divided by its normaliser (``models/brumby.py``,
+   ``ops/power_retention.py``: ``state [L, rows, HKV, hd / 2 + 1, hd, hd]``,
+   ``power_step`` / ``power_chunk_state``).
 
 **Layout** (what "in place" takes on a TPU).  A Mosaic kernel reads its
 operand row-major — ``[L][NB][HKV][...]``, a block's tiles contiguous —
@@ -421,9 +433,13 @@ def whole_pool(pool, layer):
 
 #: lanes of a TPU vector register: the minor dim a pool's blocks are packed to
 LANES = 128
-#: the cache leaves of the state kind (module docstring): indexed by ROW — a
-#: serving slot — never by block
-STATE_LEAVES = ("state", "conv")
+#: the state kind's layer kinds (``models/cached.py KIND_LEAVES``) and the
+#: leaf each family keeps BESIDE the matrix a head, ``state`` (module
+#: docstring): a convolution's tail, a normaliser
+STATE_COMPANIONS = {"kda": "conv", "ssm": "conv", "power": "z"}
+#: the cache leaves of the state kind: indexed by ROW — a serving slot —
+#: never by block
+STATE_LEAVES = ("state",) + tuple(dict.fromkeys(STATE_COMPANIONS.values()))
 
 
 def latent_pool_width(width: int) -> int:

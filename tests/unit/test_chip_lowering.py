@@ -1591,3 +1591,137 @@ def test_state_chat_cells_timed_programs_are_tied_to_the_float32_pass(
     assert not got["prefill[1x64]"]["held"] and "REFUSED" in notes[-1]
     assert "bf16" in got["prefill[1x64]"]["kernels"][0][1]
     srv.close()
+
+
+# ------------------------- power retention on the state kind, no pool (PR 57)
+#: ``brumby-longdoc-closed``'s shapes: Brumby-14B-Base's published widths,
+#: one pipeline stage of ten layers
+POWER = dict(slots=12, layers=10, heads=40, kv_heads=8, width=128)
+
+
+def test_power_kernels_compile_at_the_longdoc_cells_shapes(one_chip):
+    """Mosaic's own compile, for a described v5e, of ``power_step`` on the
+    whole ``[10, 12, 8, 65, 128, 128]`` float32 state leaf (4.1 GB) and its
+    normaliser — both aliased in and out, no temporary beside them — and of
+    ``power_chunk_state`` on every rung of the prefill ladder, the gathered
+    rows' states advanced where they lie."""
+    from deepspeed_tpu.ops import power_retention as pr
+
+    c = POWER
+    hq, h, n, rows = c["heads"], c["kv_heads"], c["width"], c["slots"]
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    leaf = sds(c["layers"], rows, *pr.stored_shape(h, n))
+    zleaf = sds(*leaf.shape[:-2], n)
+    assert leaf.shape == (10, 12, 8, 65, 128, 128)
+    step = jax.jit(
+        lambda q, k, v, lg, leaf, zleaf, l: pr.step(
+            q, k, v, lg, leaf, zleaf, l, kernel=True, interpret=False),
+        donate_argnums=(4, 5)).lower(
+            sds(rows, hq, n), sds(rows, h, n), sds(rows, h, n), sds(rows, h),
+            leaf, zleaf, sds(dtype=jnp.int32))
+    assert 'kernel_name = "power_step"' in step.as_text()
+    mem = step.compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= 4 * (
+        int(np.prod(leaf.shape)) + int(np.prod(zleaf.shape)))
+    assert mem.temp_size_in_bytes < 64 << 20, mem
+    import contextlib
+
+    for b, t in ((4, 128), (2, 256), (1, 512)):
+        # (the comparison's float32 pass traces under full precision)
+        with jax.default_matmul_precision("highest") if b == 1 \
+                else contextlib.nullcontext():
+            chunk = jax.jit(
+                lambda *a: pr.chunked(*a, kernel=True, interpret=False),
+                donate_argnums=(4, 5)).lower(
+                    sds(b, t, hq, n), sds(b, t, h, n), sds(b, t, h, n),
+                    sds(b, t, h), sds(b, *leaf.shape[2:]),
+                    sds(b, *zleaf.shape[2:]))
+        assert 'kernel_name = "power_chunk_state"' in chunk.as_text()
+        mem = chunk.compile().memory_analysis()
+        assert mem.alias_size_in_bytes >= 4 * b * int(
+            np.prod(leaf.shape[2:]))
+        assert mem.temp_size_in_bytes < 64 << 20, mem
+
+
+@pytest.mark.limit(300)
+def test_longdoc_cells_programs_alias_the_whole_cache(as_on_tpu, one_chip,
+                                                      monkeypatch):
+    """The long-document cell's decode program and its three prefill
+    programs (Brumby-14B-Base at its published widths, ten layers), compiled
+    for a described v5e: the whole cache tree — NO paged leaf: the state and
+    its normaliser, 4.12 GB — is aliased in and out beside 9.7 GB of
+    weights, the kernels are there, and no temporary comes near the leaf
+    (a row's state a layer is 34 MB, gathered and scattered a call's rows at
+    a time): no program holds a second copy of it, and a window at base 0
+    resets its rows inside the program."""
+    import json
+    import os
+
+    from chipbench.families import brumby
+    from deepspeed_tpu.ops import paged_kv, power_retention as pr
+
+    monkeypatch.setattr(pr, "interpret_kernels", lambda: False)
+    monkeypatch.setattr(pr, "on_tpu", lambda: True)
+    root = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir)
+    with open(os.path.join(root, "chipbench", "configs",
+                           "Brumby-14B-Base.json")) as f:
+        config = json.load(f)
+    config.pop("rehearse")
+    spec = brumby.build(config)
+    fwd = spec.decode_hooks["forward_cached"]
+    slots = POWER["slots"]
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return sds(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    params = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda: jax.tree_util.tree_map(
+            lambda a: a.astype(jnp.bfloat16),
+            spec.init_fn(jax.random.PRNGKey(0)))))
+    cache = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda: spec.decode_hooks["init_cache"](
+            0, 32, jnp.bfloat16, state_rows=slots)))
+    assert set(cache) <= set(paged_kv.STATE_LEAVES)
+    assert {k: (v.shape, v.dtype.name) for k, v in cache.items()} == {
+        "state": ((10, 12, 8, 65, 128, 128), "float32"),
+        "z": ((10, 12, 8, 65, 128), "float32")}
+
+    def decode_step(params, cache, tokens, lengths, slot):
+        logits, cache = fwd(params, tokens[:, None], cache, 0,
+                            lengths=lengths, block_tables={"slot": slot})
+        return jnp.argmax(logits, -1).astype(jnp.int32), cache
+
+    def prefill(params, cache, ids, slot, base, valid):
+        logits, cache = fwd(params, ids, cache, base, lengths=valid,
+                            block_tables={"slot": slot})
+        return jnp.argmax(logits, -1).astype(jnp.int32), cache
+
+    programs = {"decode": ("power_step", decode_step, (
+        params, cache, i32(slots), i32(slots), i32(slots)))}
+    for rows, width in ((4, 128), (2, 256), (1, 512)):
+        programs[f"prefill[{rows}x{width}]"] = (
+            "power_chunk_state", prefill, (
+                params, cache, i32(rows, width), i32(rows), i32(rows),
+                i32(rows)))
+    cache_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                      for a in cache.values())
+    assert round(cache_bytes / 1e9, 2) == 4.12
+    temps = {}
+    for name, (kernel, fn, args) in programs.items():
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+        assert kernel in compiled.as_text(), name
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= cache_bytes, (name, mem)
+        temps[name] = mem.temp_size_in_bytes
+    # a call's rows' states gathered, reset and advanced (34 MB a row a
+    # layer, three times over at most): 16 GB less 9.72 GB of weights and
+    # the 4.12 GB leaf leave 2 GB
+    assert max(temps.values()) < 640 << 20, temps
+    print("temporaries by program, MB:",
+          {k: round(v / 2 ** 20) for k, v in temps.items()})

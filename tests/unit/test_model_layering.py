@@ -19,10 +19,12 @@ FAMILIES = sorted(set(MODULES) - {"cached"})
 #: latent layers and routed FFN are those two files' — PR 51; Megatron's
 #: checkpoints load as GPT-2), and the diffusion pair shares its convolution
 #: and group-norm layers; Granite 4.0-H takes ``llama``'s RMSNorm by its
-#: public name and nothing else of a sibling's (PR 55)
+#: public name and nothing else of a sibling's (PR 55); Brumby IS the Qwen3
+#: block with another mixer: ``BrumbyConfig(LlamaConfig)`` and ``llama``'s
+#: norm, q/k-norm, rotary and head as that module's attributes (PR 57)
 ALLOWED = {("mixtral", "llama"), ("megatron_gpt", "gpt2"), ("unet", "vae"),
            ("kimi_linear", "mixtral"), ("kimi_linear", "llama"),
-           ("granite_hybrid", "llama")}
+           ("granite_hybrid", "llama"), ("brumby", "llama")}
 
 
 def _sibling_imports(tree):
@@ -110,7 +112,8 @@ def test_the_state_kinds_families_share_the_kind_and_not_each_other():
     from deepspeed_tpu.ops import paged_kv
 
     assert cached.KIND_LEAVES["kda"] == cached.KIND_LEAVES["ssm"] \
-        == paged_kv.STATE_LEAVES + ("slot",)
+        == ("state", "conv", "slot")
+    assert set(cached.KIND_LEAVES["kda"][:2]) <= set(paged_kv.STATE_LEAVES)
     names = {(sib, name) for sib, name, _, _
              in _sibling_imports(MODULES["granite_hybrid"])}
     assert names == {("cached", None), ("cached", "live_tokens"),
@@ -122,3 +125,30 @@ def test_the_state_kinds_families_share_the_kind_and_not_each_other():
                     if isinstance(n, ast.ClassDef) and n.name == "LlamaConfig"
                     for t in n.body if isinstance(t, ast.AnnAssign)}
     assert not {f for f in llama_fields if "ssm" in f or "multiplier" in f}
+
+
+def test_a_third_state_family_names_its_own_leaf_beside_the_matrix():
+    """PR 57: the state kind's leaves are the matrix a head and what the
+    FAMILY keeps beside it (``paged_kv.STATE_COMPANIONS``: a convolution's
+    tail, a normaliser) — one table for the engine, the layer loop and the
+    families; Brumby takes ``llama``'s block by inheritance and by that
+    module's attributes, imports no other family, is imported by none, and
+    adds no field to ``LlamaConfig``."""
+    from deepspeed_tpu.models import cached
+    from deepspeed_tpu.ops import paged_kv
+
+    assert paged_kv.STATE_COMPANIONS == {"kda": "conv", "ssm": "conv",
+                                         "power": "z"}
+    assert paged_kv.STATE_LEAVES == ("state", "conv", "z")
+    for kind, beside in paged_kv.STATE_COMPANIONS.items():
+        assert cached.KIND_LEAVES[kind] == ("state", beside, "slot")
+    names = {(sib, name) for sib, name, _, _
+             in _sibling_imports(MODULES["brumby"])}
+    assert names == {("cached", None), ("cached", "qmm"),
+                     ("cached", "scan_periods_cached"), ("llama", None)}, names
+    assert not any(sib == "brumby" for stem in FAMILIES
+                   for sib, _, _, _ in _sibling_imports(MODULES[stem]))
+    llama_fields = {t.target.id for n in ast.walk(MODULES["llama"])
+                    if isinstance(n, ast.ClassDef) and n.name == "LlamaConfig"
+                    for t in n.body if isinstance(t, ast.AnnAssign)}
+    assert not {f for f in llama_fields if "power" in f or "gate" in f}

@@ -65,7 +65,9 @@ def served():
     program of it run once: ``(srv, cfg, ring, the ring's spans)``."""
     ring = fresh_ring()
     deepspeed_tpu.comm.reset_topology()
-    cfg = gpt2.GPT2Config.tiny(max_seq_len=64)
+    # (a vocabulary no other test file's model has: what ``params_cast``
+    # builds is then never already built by a file this worker ran before)
+    cfg = gpt2.GPT2Config.tiny(vocab_size=520, max_seq_len=64)
     srv = deepspeed_tpu.init_serving(
         gpt2.build(cfg), config={"dtype": "fp32"}, slots=4, max_seq_len=64,
         block_size=8, prefill_chunk=16)
