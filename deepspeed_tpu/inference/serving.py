@@ -243,9 +243,12 @@ reason: ``quantize`` (kv8, w8a8), a tp mesh, ``engine_mode="dp_tp"``, ``sp
 > 1``, a draft model, ``resident_window_blocks``, ``decode_steps > 1``.
 ``stats()["kv_latent"]`` names the kind, the token's width and bytes, the
 block, the read each program was traced with and the refusals; the
-``decode`` / ``prefill`` spans carry ``kv_valid`` (valid keys x layers),
-``kv_blocks``, ``kv_pairs`` (query-key pairs x layers) and ``latent_bytes``
-(:meth:`ServingEngine._kv_reach`).
+``decode`` / ``prefill`` / ``spec_verify`` spans carry ``kv_valid`` (valid
+keys x layers), ``kv_blocks``, ``kv_pairs`` (query-key pairs x layers),
+``latent_bytes``, and of ONE layer's walk ``kv_tiles`` (its loop iterations,
+``stats()["kv_latent"]["tile_blocks"]`` blocks each) and
+``kv_first_tiles_ahead`` (the grid steps whose first tile the step before
+theirs starts) (:meth:`ServingEngine._kv_reach`).
 
 **The state kind** (PR 51, PR 55, PR 57): a model with recurrent layers
 (decode hook ``state_layers``: gated delta-rule layers,
@@ -1085,8 +1088,9 @@ class ServingEngine:
         #: values a token a layer and no K / V (module docstring "The
         #: latent kind"); None otherwise
         self._latent = hooks.get("latent_attention")
-        self._latent_totals = {"kv_valid": 0, "kv_blocks": 0,
-                               "kv_pairs": 0, "latent_bytes": 0}
+        self._latent_totals = dict.fromkeys(
+            ("kv_valid", "kv_blocks", "kv_pairs", "latent_bytes", "kv_tiles",
+             "kv_first_tiles_ahead"), 0)
         #: :meth:`_kv_reach`'s span args, summed
         self._window_totals = {"kv_valid": 0, "kv_visible": 0}
         self._full_peak = 0        # most full-kind blocks in use after a step
@@ -3961,7 +3965,17 @@ class ServingEngine:
                 "kv_first_tiles_ahead": int(
                     np.count_nonzero(np.asarray(rows) % call))}
 
-    def _kv_reach(self, valid, queries=None) -> Dict[str, int]:
+    def _latent_walk(self, t: int) -> Tuple[int, int]:
+        """The latent walk's shape for a window of ``t`` positions at this
+        pool's stored shapes: ``(query positions a grid step, blocks a loop
+        iteration)`` (``ops/decode_attention.latent_walk_shape``)."""
+        return decode_attention.latent_walk_shape(
+            int(self.engine.module.model_config.num_heads), t,
+            int(self._pool_shape[3]), int(self._pool_shape[4]),
+            jnp.dtype(self._kv_dtype).itemsize, self._nbper)
+
+    def _kv_reach(self, valid, queries=None, t: int = 1,
+                  at=None) -> Dict[str, int]:
         """Span args of a dispatch of a model with window layers, from the
         scheduler's own bookkeeping: ``valid`` holds, for each live row,
         the keys valid for its last query (its position + 1).  ``kv_valid``
@@ -3970,7 +3984,10 @@ class ServingEngine:
         sliding one.  ARITHMETIC on lengths and the configuration's window:
         what the traffic lets a windowed read skip, not a reading of what
         the kernels fetched (the comparison with the plain reference and
-        the kernels' device time are what hold them to the window)."""
+        the kernels' device time are what hold them to the window).  A
+        latent model's rows carry ``queries`` real positions each of a
+        window of ``t``, and sit at ``at`` in their call (default: in the
+        order given)."""
         if self._latent:
             # a latent model: the valid keys x layers, the blocks those
             # rows hold, and the bytes an absorbed read NEEDS of them
@@ -3987,6 +4004,22 @@ class ServingEngine:
                     "kv_pairs": int((q * valid - q * (q - 1) // 2).sum())
                     * layers}
             args["latent_bytes"] = args["kv_valid"] * self._latent_token_bytes
+            # the walks of ONE layer's call of ``t`` window positions
+            # (``_paged_latent_kernel``): a grid step is ``tq`` positions of
+            # a row and walks the blocks up to its last real query, ``nt`` a
+            # loop iteration.  ``kv_tiles``: those iterations;
+            # ``kv_first_tiles_ahead``: the steps whose first tile the step
+            # before theirs starts — every step that holds one but the
+            # call's first (``at``: each row's place in its call)
+            tq, nt = self._latent_walk(t)
+            first = np.arange(0, t, tq)
+            reach = np.minimum(first + tq, q[:, None])
+            blocks = -(-((valid - q)[:, None] + reach) // self.block_size)
+            tiles = np.where(reach > first, -(-blocks // nt), 0)
+            at = np.arange(len(valid)) if at is None else np.asarray(at)
+            args["kv_tiles"] = int(tiles.sum())
+            args["kv_first_tiles_ahead"] = int(np.count_nonzero(tiles)) \
+                - int(np.any((at == 0) & (tiles[:, 0] > 0)))
             for key, v in args.items():
                 self._latent_totals[key] += v
             return args
@@ -5159,7 +5192,7 @@ class ServingEngine:
             decode_fn = self._get_decode_fn()
             span_kw = {**self._sampler_rows(dec),
                        **self._kv_walk(dec),
-                       **self._kv_reach(self._lengths[dec] + 1),
+                       **self._kv_reach(self._lengths[dec] + 1, at=dec),
                        **self._state_args(len(dec), 0, len(dec))}
         with seg("step.decode.upload", phase):
             host, puts = self._host_operands(
@@ -5399,8 +5432,11 @@ class ServingEngine:
             host, puts = self._host_operands(
                 "verify", ids, bt, self._lengths, valid, *samp)
             args = (params, self._cache, *host)
-            flight = self._in_flight("spec_verify", slots=len(dec),
-                                     window=k + 1, **puts)
+            flight = self._in_flight(
+                "spec_verify", slots=len(dec), window=k + 1, **puts,
+                **(self._kv_reach(self._lengths[dec] + k + 1,
+                                  np.full(len(dec), k + 1), t=k + 1, at=dec)
+                   if self._latent else {}))
         with flight as span_args:
             with seg("spec_verify.enqueue", span_args), self._tp_ctx():
                 out = verify_fn(*args)
@@ -5609,7 +5645,7 @@ class ServingEngine:
                 "kv_blocks": int(self._blocks_for(base + valid).sum()),
                 **self._sampler_rows(group),
                 **self._kv_reach((base + valid)[:len(group)],
-                                 valid[:len(group)]),
+                                 valid[:len(group)], t=width),
                 **self._state_args(
                     len(group), int((base[:len(group)] == 0).sum()),
                     int(valid.sum()))}
@@ -5895,6 +5931,15 @@ class ServingEngine:
             "block_size": self.block_size,
             "block_bytes": self.block_size * lanes * item,
             "latent_attn": dict(self._program_meta.get("latent_attn", {})),
+            # blocks a loop iteration of each program's walk lands and
+            # attends (its spans carry ``kv_blocks`` / ``kv_tiles`` /
+            # ``kv_first_tiles_ahead``)
+            "tile_blocks": {
+                "decode": self._latent_walk(1)[1],
+                **({"verify": self._latent_walk(self.spec_tokens + 1)[1]}
+                   if self.spec_tokens else {}),
+                "prefill": {self._rung_name(rung): self._latent_walk(
+                    rung[1])[1] for rung in self._rungs}},
             **self._latent_totals,
             "refused": list(self._latent_refusals)}
 

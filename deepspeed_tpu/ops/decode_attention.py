@@ -49,7 +49,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1691,9 +1691,57 @@ def paged_latent_attention_reference(q, pool, block_tables, q_pos, *,
     return jnp.einsum("bhts,bsc->bhtc", probs, lat[..., :rank])
 
 
+#: VMEM the latent walk gives a TILE: its two slots of landed blocks and the
+#: scores of one softmax update over them (:func:`latent_tile_blocks`)
+_LATENT_VMEM_BUDGET = 5 << 20
+
+#: most blocks a tile of the latent walk holds, and most query rows x blocks:
+#: the update's text exists once a WIDTH (``1 .. nt`` blocks), each width's
+#: two products unrolled over its query rows x keys, and a program that is
+#: compiled at a start pays for that text — a decode tile of 8 and a prefill
+#: tile of 4 added 11 s to the 127 s of a start that compiles ten of these
+#: kernels (my chip runs, PR 58, ``kimilinear-statedecode-closed``).  What a
+#: wider tile buys ends here anyway.  The kernel alone (my chip runs, PR 58;
+#: us a BLOCK, rows of 16 blocks in calls of 64; one block a visit before):
+#: Mistral Small 4's ``[512, 384]`` blocks at a tile of 1 / 2 / 4 / 8 blocks
+#: 0.79 / 0.59 / 0.52 / 0.53 (0.83; the copy alone is 0.48), Kimi Linear's
+#: ``[256, 640]`` 0.72 / 0.52 / 0.44 / 0.43 (0.75; 0.40); a ``[1, 512]``
+#: chunk over 16 blocks (512 query rows a step) 1,460 / 1,196 / 1,196 us at
+#: 1 / 2 / 4 (1,437), Kimi's 1,339 / 1,080 / 1,014 (1,330)
+_LATENT_TILE_MAX = 4
+_LATENT_TILE_ROWS = 1024
+
+
+def latent_tile_blocks(rows: int, bs: int, w: int, itemsize: int,
+                       nbper: int) -> int:
+    """Blocks one loop iteration of the latent walk lands and attends — its
+    TILE — from the shapes alone: the most whose two landing slots (``bs x
+    w`` values a block) and whose scores for ``rows`` query rows (float32,
+    and ``p`` again in the pool's dtype for the second product) stay inside
+    :data:`_LATENT_VMEM_BUDGET`, never more than a row's table holds, than
+    :data:`_LATENT_TILE_MAX`, or than gives ``rows`` x blocks
+    :data:`_LATENT_TILE_ROWS`.  So the tile follows the query rows: a decode
+    step (``rows`` = the heads) takes 4 blocks, a prefill step (16 positions
+    of every head) 2."""
+    a_block = bs * (2 * w * itemsize + rows * (4 + itemsize))
+    return max(1, min(_LATENT_VMEM_BUDGET // a_block, _LATENT_TILE_MAX,
+                      _LATENT_TILE_ROWS // rows, nbper))
+
+
+def latent_walk_shape(h: int, t: int, bs: int, w: int, itemsize: int,
+                      nbper: int) -> Tuple[int, int]:
+    """``(tq, nt)`` of the latent walk for ``h`` heads over a window of
+    ``t`` positions: the query positions a grid step takes and the blocks
+    its loop iterations land (:func:`latent_tile_blocks` at ``tq * h``
+    query rows).  The launcher's arithmetic, and what the serving engine
+    counts a call's walks with."""
+    tq = min(t, _LATENT_QUERY_TILE)
+    return tq, latent_tile_blocks(tq * h, bs, w, itemsize, nbper)
+
+
 def _paged_latent_kernel(layer_ref, pos_ref, valid_ref, bt_ref, q_ref,
-                         pool_ref, o_ref, buf, sem, m_scr, l_scr, acc_scr, *,
-                         heads: int, tq: int, rank: int):
+                         pool_ref, o_ref, buf, sem, slot_ref, m_scr, l_scr,
+                         acc_scr, *, heads: int, tq: int, rank: int):
     """The latent kind's decode (``T == 1``), verify and prefill kernel.
     Grid ``(B, T / tq)``: one step is ``tq`` query positions of one row,
     all ``heads`` of them — ``rows = tq * heads`` query rows, row ``r`` the
@@ -1702,57 +1750,144 @@ def _paged_latent_kernel(layer_ref, pos_ref, valid_ref, bt_ref, q_ref,
     ``layer_ref`` int32 [1], ``pos_ref`` / ``valid_ref`` int32 [B] (the
     window's first position, its real queries) and ``bt_ref`` int32 [B,
     NBPER] by scalar prefetch; the pool stays in HBM and block ``i`` is ONE
-    copy, ``pool.at[layer, bt[b, i]]`` -> a ``[1, bs, W]`` buffer of two
-    slots, the next block in flight while this one is attended.  The tile
-    is read ONCE for both sides: ``q [rows, W] . tile^T`` are the scores of
-    every head (the pad lanes meet zeros), ``p . tile[:, :rank]`` the output
-    in latent space.  A step walks the blocks up to its own last real
-    query, ``cdiv(base + min((j + 1) * tq, valid), bs)`` — none if its
-    queries are all pad; the mask is per query row (``key <= base +
-    offset``).  Matmuls take the pool's dtype in and float32 out."""
+    copy, ``pool.at[layer, bt[b, i]]`` -> its place in its TILE of up to
+    ``nt`` blocks (:func:`latent_tile_blocks`; read here off the landing
+    buffer's shape ``[2, nt, bs, W]``).  A tile's copies fly together, on
+    one semaphore a slot, while the tile before is attended.  A step walks
+    the blocks up to its own last real query, ``n = cdiv(base + min((j + 1)
+    * tq, valid), bs)`` — none if its queries are all pad — in ``cdiv(n,
+    nt)`` tiles of near-equal size (:func:`tile`).
+
+    A loop iteration is ONE online-softmax update over its tile's landed
+    keys, the blocks one under the other: ``q [rows, W] . tile^T`` are the
+    scores of every head (the pad lanes meet zeros), ``p . tile[:, :rank]``
+    the output in latent space — the tile is read ONCE for both sides — and
+    ``m`` / ``l`` / ``acc`` are read and written once a tile, whatever
+    ``nt``.  The update's text exists once a WIDTH (``1 .. nt`` blocks,
+    chosen by the blocks the tile holds): a tile of fewer than ``nt`` blocks
+    is attended at the blocks it holds, so the slots that were not copied —
+    whatever an earlier step or nothing at all left there — are never read,
+    and a short tile costs its own keys' products.  The mask is per query
+    row (``key <= base + offset``).  Matmuls take the pool's dtype in and
+    float32 out.
+
+    The two slots carry a tile ACROSS grid steps, as the plain walk's do
+    (the grid runs in order: ``arbitrary``): a step's last tile starts tile
+    0 of the NEXT step — the row's next query tile, or the next row — into
+    the slot it does not hold, and that step starts nothing for its tile 0
+    and only waits, both from :func:`tile` of the step's :func:`reach`.  A step of
+    no block passes the duty on; a launch's first step starts its own, the
+    last starts nothing.  The slot of a step's tile 0 follows the tiles
+    walked before it (``slot_ref``, SMEM scratch)."""
     b, j = pl.program_id(0), pl.program_id(1)
+    per_row = pl.num_programs(1)
+    steps = pl.num_programs(0) * per_row
     layer, base = layer_ref[0], pos_ref[b]
-    bs = buf.shape[2]
+    _, nt, bs, _ = buf.shape
     first = j * tq
-    last = jnp.minimum(first + tq, valid_ref[b]) - 1
-    n = jnp.where(last >= first,
-                  jnp.clip((base + last + bs) // bs, 0, bt_ref.shape[1]), 0)
 
-    def copy(i, slot):
-        return pltpu.make_async_copy(pool_ref.at[layer, bt_ref[b, i]],
-                                     buf.at[slot], sem.at[slot])
+    def reach(step):
+        """``(row, n)`` of grid step ``step`` (row-major over the grid; one
+        past the last: no block): it walks blocks ``0 .. n - 1`` of
+        ``row``.  THE arithmetic of a step's copies, for the step that
+        starts them and the step that waits for them."""
+        row = jnp.minimum(step // per_row, pl.num_programs(0) - 1)
+        at = step % per_row * tq
+        last = jnp.minimum(at + tq, valid_ref[row]) - 1
+        return row, jnp.where(
+            (last >= at) & (step < steps),
+            jnp.clip((pos_ref[row] + last + bs) // bs, 0, bt_ref.shape[1]),
+            0)
 
-    def fetch(i, slot):
-        @pl.when(i < n)
-        def _start():
-            copy(i, slot).start()
+    def tile(of, i):
+        """Tile ``i`` of the step whose :func:`reach` is ``of``, as what
+        its copies need: ``(row, first block, blocks the step holds of
+        it)`` — 0 past its last tile.  A step's ``n`` blocks go in
+        ``cdiv(n, nt)`` tiles of near-EQUAL size (the first ``n % tiles``
+        hold one more): with two slots a tile's copies fly under the update
+        of the tile before, so a short tile behind a long one leaves the
+        copy queue idle, and a long one behind a short one is waited
+        for."""
+        row, n = of
+        tiles = (n + nt - 1) // nt
+        per = n // jnp.maximum(tiles, 1)
+        more = n - per * tiles
+        return (row, i * per + jnp.minimum(i, more),
+                jnp.where(i < tiles, per + (i < more), 0))
+
+    def each_block(of, slot, act):
+        """``act`` on the copies of the valid blocks of the tile ``of``."""
+        row, start, held = of
+
+        def one(k, carry):
+            act(pltpu.make_async_copy(
+                pool_ref.at[layer, bt_ref[row, start + k], 0],
+                buf.at[slot, k], sem.at[slot]))
+            return carry
+
+        jax.lax.fori_loop(0, held, one, None)
+
+    def choose(pred, a, b):
+        return tuple(jnp.where(pred, x, y) for x, y in zip(a, b))
+
+    step = b * per_row + j
+    here = reach(step)
+    tiles = (here[1] + nt - 1) // nt
+    ahead = tile(reach(step + 1), 0)
+
+    # a launch's first step starts its own tile 0; every other step finds
+    # it started (the step before's ``ahead``) and only waits.  A step of
+    # no tile passes the duty on here, where no loop iteration does
+    @pl.when(step == 0)
+    def _():
+        slot_ref[0] = 0
+
+    slot0 = slot_ref[0]
+    *which, held = choose(tiles > 0, tile(here, 0), ahead)
+    each_block((*which, jnp.where((step == 0) | (tiles == 0), held, 0)),
+               slot0, lambda copy: copy.start())
 
     def attend(i, carry):
-        slot = i % 2
-        fetch(i + 1, 1 - slot)
-        copy(i, slot).wait()
-        tile = buf[slot, 0]                                      # [bs, W]
-        s = jax.lax.dot_general(q_ref[0], tile, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        key = i * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(key <= base + first + row // heads, s, NEG_INF)
-        m_prev = m_scr[...][:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                                   # [rows, bs]
-        l_new = l_scr[...][:, :1] * alpha + jnp.sum(p, axis=-1,
-                                                    keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(tile.dtype), tile[:, :rank], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        slot = (slot0 + i) % 2
+        # the tile after this one lands meanwhile: the step's next, or, on
+        # its last, tile 0 of the NEXT grid step
+        each_block(choose(i + 1 < tiles, tile(here, i + 1), ahead),
+                   1 - slot, lambda copy: copy.start())
+        mine = tile(here, i)
+        each_block(mine, slot, lambda copy: copy.wait())
+
+        def update(held):
+            """ONE online-softmax update over the tile's first ``held``
+            (static) blocks, one under the other."""
+            keys = buf[slot, :held].reshape(held * bs, buf.shape[3])
+            s = jax.lax.dot_general(q_ref[0], keys, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            key = mine[1] * bs + jax.lax.broadcasted_iota(jnp.int32,
+                                                          s.shape, 1)
+            s = jnp.where(key <= base + first + row // heads, s, NEG_INF)
+            m_prev = m_scr[...][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)                     # [rows, held * bs]
+            l_new = l_scr[...][:, :1] * alpha + jnp.sum(p, axis=-1,
+                                                        keepdims=True)
+            acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+                p.astype(keys.dtype), keys[:, :rank],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+            l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+        # the update exists once a width: a tile of fewer than ``nt``
+        # blocks is attended at the blocks it holds, so no product and no
+        # mask ever meets a slot that was not copied
+        for held in range(1, nt + 1):
+            pl.when(mine[2] == held)(functools.partial(update, held))
         return carry
 
     _start_chunks(m_scr, l_scr, acc_scr)
-    fetch(0, 0)
-    jax.lax.fori_loop(0, n, attend, None)
+    jax.lax.fori_loop(0, tiles, attend, None)
+    slot_ref[0] = (slot0 + tiles) % 2
     den = l_scr[...][:, :1]
     o_ref[0] = (acc_scr[...] / jnp.where(den == 0.0, 1.0, den)) \
         .astype(o_ref.dtype)
@@ -1803,7 +1938,8 @@ def paged_latent_attention_pallas(q, pool, block_tables, q_pos, *, rank: int,
         f"latent pool {pool.shape} against queries {q.shape}"
     if interpret is None:
         interpret = interpret_kernels()
-    tq = min(t, _LATENT_QUERY_TILE)
+    tq, nt = latent_walk_shape(h, t, bs, w, pool.dtype.itemsize,
+                               block_tables.shape[1])
     tp = -(-t // tq) * tq
     rows = tq * h
     qq = jnp.pad(q.transpose(0, 2, 1, 3), ((0, 0), (0, tp - t), (0, 0),
@@ -1825,14 +1961,17 @@ def paged_latent_attention_pallas(q, pool, block_tables, q_pos, *, rank: int,
             in_specs=[tile(w), pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=tile(rank),
             scratch_shapes=[
-                pltpu.VMEM((2, 1, bs, w), pool.dtype),
+                pltpu.VMEM((2, nt, bs, w), pool.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),          # the slot of tile 0
                 pltpu.VMEM((rows, LANES), jnp.float32),           # m
                 pltpu.VMEM((rows, LANES), jnp.float32),           # l
                 pltpu.VMEM((rows, rank), jnp.float32)]),          # acc
         out_shape=jax.ShapeDtypeStruct((b, tp * h, rank), q.dtype),
+        # a tile is carried from a grid step to the next: the steps run in
+        # order on one core
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret)
     out = _LATENT_CALLS[latent_kernel_name(t)](
         functools.partial(_paged_latent_kernel, heads=h, tq=tq, rank=rank),

@@ -1165,6 +1165,49 @@ def test_a_head_split_moves_the_projections_product_not_its_weight(
 #: in 384 lanes — 64 slots x 16,384 at the cell's block of 512
 LATENT = dict(slots=64, ctx=16384, heads=32, width=384, rank=256, block=512,
               layers=6)
+#: the latent layers of the state-decode cell
+#: (``kimilinear-statedecode-closed``): Kimi Linear's 32 heads over a latent
+#: of 512 + a key of 64 in 640 lanes, 192 slots x 4,352 at blocks of 256
+LATENT_KIMI = dict(slots=192, ctx=4352, heads=32, width=640, rank=512,
+                   block=256, layers=2)
+#: blocks a loop iteration at the cells' blocks: (decode, verify T = 4, a
+#: prefill step) and (decode, a prefill step)
+LATENT_TILES, LATENT_KIMI_TILES = (4, 4, 2), (4, 2)
+
+
+def _latent_walk_compiles(c, rows, t, block, one_chip):
+    """Mosaic's own compile of the latent kernel for ``rows`` rows of ``t``
+    positions at the widths ``c`` and ``block`` tokens a block; the tile
+    the rule gives it is the landing buffer it is launched with, and two
+    slots of it plus one update's scores lie inside the budget the rule
+    names.  -> the tile (blocks)."""
+    nbper = -(-c["ctx"] // block)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((c["layers"], 1 + c["slots"] * nbper, 1, block, c["width"]),
+               jnp.bfloat16)
+    fn = jax.jit(lambda q, p, bt, pos, valid:
+                 da.paged_latent_attention_pallas(
+                     q, p, bt, pos, rank=c["rank"], layer=0, valid=valid,
+                     interpret=False))
+    args = (sds((rows, c["heads"], t, c["width"]), jnp.bfloat16), pool,
+            sds((rows, nbper), jnp.int32), sds((rows,), jnp.int32),
+            sds((rows,), jnp.int32))
+    lowered = fn.lower(*args)
+    text = lowered.as_text()
+    assert "tpu_custom_call" in text and da.latent_kernel_name(t) in text
+    assert lowered.compile().memory_analysis().temp_size_in_bytes < 1 << 20
+    tq, nt = da.latent_walk_shape(c["heads"], t, block, c["width"], 2, nbper)
+    kernel, = (eqn.params["jaxpr"] for eqn in fn.trace(*args).jaxpr.eqns
+               if eqn.primitive.name == "pallas_call")
+    assert (2, nt, block, c["width"]) in [
+        tuple(v.aval.shape) for v in kernel.invars]
+    landing = 2 * nt * block * c["width"] * 2
+    scores = tq * c["heads"] * nt * block * (4 + 2)
+    assert landing + scores <= da._LATENT_VMEM_BUDGET
+    return nt
 
 
 @pytest.mark.parametrize("block", [32, 256, 512])
@@ -1174,29 +1217,27 @@ LATENT = dict(slots=64, ctx=16384, heads=32, width=384, rank=256, block=512,
 def test_latent_walks_compile_at_the_long_decode_cells_shapes(rows, t, block,
                                                               one_chip):
     """ISSUE 39: Mosaic's own compile, for a described v5e, of the latent
-    kernel as the decode step, the verify window and the ``[4, 128]``
-    prefill chunk launch it (``paged_latent_attn`` / ``_verify`` /
-    ``_prefill``), at the default block and at the cell's: one ``[block,
-    384]`` tile a copy out of the whole stack where it lies, no
-    temporary."""
-    c = LATENT
-    nbper = c["ctx"] // block
+    kernel as the decode step, the verify window and the ``[4, 128]`` /
+    ``[1, 512]`` prefill calls launch it (``paged_latent_attn`` / ``_verify``
+    / ``_prefill``), at the default block and at the cell's: ``[block,
+    384]`` tiles copied out of the whole stack where it lies, no temporary.
+    ISSUE 58: a TILE of them a loop iteration, many for the 32 query rows of
+    a decode step and few for the 512 of a prefill step."""
+    nt = _latent_walk_compiles(LATENT, rows, t, block, one_chip)
+    if block == 512:
+        assert nt == {1: LATENT_TILES[0], 4: LATENT_TILES[1]}.get(
+            t, LATENT_TILES[2])
 
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = sds((c["layers"], 1 + c["slots"] * nbper, 1, block, c["width"]),
-               jnp.bfloat16)
-    lowered = jax.jit(lambda q, p, bt, pos, valid:
-                      da.paged_latent_attention_pallas(
-                          q, p, bt, pos, rank=c["rank"], layer=0,
-                          valid=valid, interpret=False)).lower(
-        sds((rows, c["heads"], t, c["width"]), jnp.bfloat16), pool,
-        sds((rows, nbper), jnp.int32), sds((rows,), jnp.int32),
-        sds((rows,), jnp.int32))
-    text = lowered.as_text()
-    assert "tpu_custom_call" in text and da.latent_kernel_name(t) in text
-    assert lowered.compile().memory_analysis().temp_size_in_bytes < 1 << 20
+@pytest.mark.parametrize("rows,t", [(192, 1), (4, 128), (1, 512)],
+                         ids=["decode", "prefill-chunk", "prefill-1x512"])
+def test_latent_walks_compile_at_the_state_decode_cells_shapes(rows, t,
+                                                               one_chip):
+    """ISSUE 58: the same compile at Kimi Linear's widths (``[256, 640]``
+    blocks, rank 512) as the state-decode cell's decode step and its two
+    prefill rungs launch it: the tile follows the shapes."""
+    nt = _latent_walk_compiles(LATENT_KIMI, rows, t, 256, one_chip)
+    assert nt == (LATENT_KIMI_TILES[0] if t == 1 else LATENT_KIMI_TILES[1])
 
 
 def test_compiled_latent_serving_programs_fit_and_alias_the_pool(
@@ -1256,12 +1297,14 @@ def test_compiled_latent_serving_programs_fit_and_alias_the_pool(
         "paged_latent_attn": (decode_step, (
             params, pool, i32(slots), i32(slots), i32(slots, nbper))),
         "paged_latent_prefill": (prefill, (
-            params, pool, i32(4, 128), i32(4, nbper), i32(4), i32(4)))}
+            params, pool, i32(4, 128), i32(4, nbper), i32(4), i32(4))),
+        "paged_latent_prefill 1x512": (prefill, (
+            params, pool, i32(1, 512), i32(1, nbper), i32(1), i32(1)))}
     pool_bytes = int(np.prod(pool["latent"].shape)) * 2
     for kernel, (fn, args) in programs.items():
         compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
         text = compiled.as_text()
-        assert kernel in text and "moe_gmm" in text
+        assert kernel.split()[0] in text and "moe_gmm" in text
         mem = compiled.memory_analysis()
         assert mem.temp_size_in_bytes < 1 << 30, (kernel, mem)
         assert mem.alias_size_in_bytes >= pool_bytes, (kernel, mem)
@@ -1388,7 +1431,11 @@ def test_state_cells_programs_alias_the_whole_cache(as_on_tpu, one_chip,
             params, cache, i32(slots), i32(slots), i32(slots, nbper))),
         ("kda_chunk_state", "paged_latent_prefill"): (prefill, (
             params, cache, i32(4, 128), i32(4, nbper), i32(4), i32(4),
-            i32(4)))}
+            i32(4))),
+        # (the same kernels, the lone row's rung)
+        ("paged_latent_prefill", "kda_chunk_state"): (prefill, (
+            params, cache, i32(1, 512), i32(1, nbper), i32(1), i32(1),
+            i32(1)))}
     # one layer's slice of the state is 403 MB
     _programs_alias_the_whole_cache(programs, cache, also=("moe_gmm",))
 

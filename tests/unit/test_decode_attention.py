@@ -902,6 +902,22 @@ def test_paged_walk_carries_a_tile_across_grid_steps(hd, rep, t, kv8, shape,
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
+def _dma_sites(fn, *args):
+    """``{"dma_start": n, "dma_wait": n}``: the sites of the ONE Pallas
+    kernel ``fn(*args)`` launches that start and that wait for a copy."""
+    def sites(jaxpr, counts):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name in counts:
+                counts[eqn.primitive.name] += 1
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                sites(sub, counts)
+        return counts
+
+    kernel, = (eqn.params["jaxpr"] for eqn in jax.make_jaxpr(fn)(
+        *args).jaxpr.eqns if eqn.primitive.name == "pallas_call")
+    return sites(kernel, {"dma_start": 0, "dma_wait": 0})
+
+
 @pytest.mark.parametrize("kv8", [False, True], ids=["float", "kv8"])
 def test_paged_walk_waits_for_every_copy_it_starts(kv8, capfd):
     """The kernel's text starts a tile at two sites (a launch's first
@@ -921,20 +937,9 @@ def test_paged_walk_waits_for_every_copy_it_starts(kv8, capfd):
         assert np.isfinite(got).all() and not _races_found()
     assert "non-zero count" not in capfd.readouterr().out
 
-    def sites(jaxpr, counts):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name in counts:
-                counts[eqn.primitive.name] += 1
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                sites(sub, counts)
-        return counts
-
-    jaxpr = jax.make_jaxpr(lambda *a: paged_decode_attention_pallas(
-        *a, layer=1, interpret=False))(q, kp, vp, bt, pos)
-    kernel, = (eqn.params["jaxpr"] for eqn in jaxpr.jaxpr.eqns
-               if eqn.primitive.name == "pallas_call")
     operands = 4 if kv8 else 2
-    assert sites(kernel, {"dma_start": 0, "dma_wait": 0}) == {
+    assert _dma_sites(lambda *a: paged_decode_attention_pallas(
+        *a, layer=1, interpret=False), q, kp, vp, bt, pos) == {
         "dma_start": 2 * operands, "dma_wait": operands}
 
 
@@ -1319,6 +1324,164 @@ def test_latent_walk_equals_its_reference(block, t):
     np.testing.assert_allclose(ref[:2], want[:2], atol=2e-5)
     np.testing.assert_allclose(got[:2], want[:2], atol=2e-5)
     assert np.isfinite(np.asarray(got)).all()
+
+
+# ISSUE 58: a loop iteration of the latent walk is a TILE of ``nt`` blocks
+# and ONE online-softmax update over their keys, and a step's last tile
+# starts tile 0 of the NEXT grid step.
+LATENT_BS, LATENT_W, LATENT_RANK, LATENT_HEADS = 32, 256, 128, 2
+
+
+def _latent_tile_case(nt, t, blocks=None, seed=58):
+    """Rows of 1, ``3 nt + 2``, 0, 1, ``nt - 1``, ``nt``, 0, ``nt + 1`` and
+    ``3 nt + 2`` blocks in ONE call (the launch's first tile is partly
+    landed, in buffers nothing has written yet; a carried first tile
+    crosses from a long row to an empty one and back; 4, 1, 1, 2 and 4
+    tiles: both parities of the slot are carried), the last block full,
+    half full or holding ONE key in turn; a row's ``valid`` queries are its newest ``min(t, keys)``
+    positions.  Every block that is not live for its row — the scratch id
+    and what its table names past its last block too — is NaN.  ->
+    ``(q, pool, bt, pos, valid)``."""
+    bs = LATENT_BS
+    blocks = blocks if blocks is not None else \
+        [1, 3 * nt + 2, 0, 1, nt - 1, nt, 0, nt + 1, 3 * nt + 2]
+    rng = np.random.default_rng(seed + nt + t)
+    nbper = max(max(blocks), 1) + 1
+    last = [bs, bs // 2, 1]
+    keys = np.asarray([max(0, (n - 1) * bs + last[i % 3]) if n else 0
+                       for i, n in enumerate(blocks)])
+    pool = rng.standard_normal((2, 1 + len(blocks) * nbper, 1, bs, LATENT_W))
+    bt = 1 + np.arange(len(blocks) * nbper).reshape(len(blocks), nbper)
+    for row, n in enumerate(blocks):
+        pool[:, bt[row, n:]] = np.nan
+        bt[row, n:] = bt[row, nbper - 1]
+    pool[:, 0] = np.nan
+    q = rng.standard_normal((len(blocks), LATENT_HEADS, t, LATENT_W)) * 0.1
+    valid = np.minimum(t, keys)
+    return (jnp.asarray(q, jnp.float32), jnp.asarray(pool, jnp.float32),
+            jnp.asarray(bt, jnp.int32), jnp.asarray(keys - valid, jnp.int32),
+            jnp.asarray(valid, jnp.int32))
+
+
+def _latent_tile_want(q, pool, bt, pos, valid, layer):
+    """:func:`_latent_naive` over each row's real queries; zeros for the
+    pad ones."""
+    q, pool = np.asarray(q, np.float64), np.asarray(pool, np.float64)
+    out = np.zeros(q.shape[:3] + (LATENT_RANK,))
+    for r in range(q.shape[0]):
+        keys = pool[layer, np.asarray(bt[r])].reshape(-1, q.shape[3])
+        for i in range(int(valid[r])):
+            n = int(pos[r]) + i + 1
+            s = q[r, :, i] @ keys[:n].T
+            p = np.exp(s - s.max(-1, keepdims=True))
+            out[r, :, i] = (p / p.sum(-1, keepdims=True)) \
+                @ keys[:n, :LATENT_RANK]
+    return out
+
+
+def _real(out, valid):
+    """``out [B, H, T, rank]`` with every pad query's rows zeroed."""
+    out = np.array(out)
+    for r, v in enumerate(np.asarray(valid)):
+        out[r, :, int(v):] = 0
+    return out
+
+
+@pytest.mark.parametrize("t", [1, 5, 128], ids=["decode", "verify",
+                                                "prefill-chunk"])
+@pytest.mark.parametrize("nt", [1, 2, 4])
+def test_latent_walk_attends_a_tile_of_blocks_an_update(nt, t, monkeypatch):
+    """``paged_latent_*`` at a tile of ``nt`` blocks against a key-by-key
+    softmax and the XLA reference, on a simulated chip: its copies are made
+    only when waited for, its race detector is on, its landing buffers
+    start as NaN and so does every block outside a row's valid ones — a
+    tile's uncopied slots, a tile nobody waited for or a read past a row's
+    blocks would reach the output.  Rows of 0 / 1 / ``nt - 1`` / ``nt`` /
+    ``nt + 1`` / ``3 nt + 2`` blocks side by side; an empty row hands the
+    carried tile on and returns zeros."""
+    from deepspeed_tpu.ops import decode_attention as da
+
+    monkeypatch.setattr(da, "latent_tile_blocks",
+                        lambda *shapes: min(nt, shapes[-1]))
+    q, pool, bt, pos, valid = _latent_tile_case(nt, t)
+    want = _latent_tile_want(q, pool, bt, pos, valid, 1)
+    ref = da.paged_latent_attention_reference(
+        q, jnp.nan_to_num(pool), bt, pos, rank=LATENT_RANK, layer=1)
+    got = np.asarray(da.paged_latent_attention_pallas(
+        q, pool, bt, pos, rank=LATENT_RANK, layer=1, valid=valid,
+        interpret=_simulated_chip()))
+    assert not _races_found()
+    np.testing.assert_allclose(_real(ref, valid), want, atol=2e-5)
+    assert np.isfinite(got).all(), "an uncopied slot of a tile, a tile " \
+        "that nobody waited for, or a read outside a row's valid blocks"
+    np.testing.assert_allclose(_real(got, valid), want, atol=2e-5)
+    assert not got[np.asarray(valid) == 0].any()
+
+
+@pytest.mark.parametrize("t", [1, 40], ids=["decode", "prefill-chunk"])
+def test_latent_walk_waits_for_every_copy_it_starts(t, monkeypatch, capfd):
+    """The latent kernel's text starts a tile at two sites (a launch's
+    first step's own, or the next step's from a step of no tile; the
+    loop's: the step's next tile or the next step's first) and waits at
+    one; what it starts it waits for — on a simulated chip whose copies
+    signal at their start, no semaphore is left raised at the kernel's
+    exit, behind steps of 0, 1, 2 and 3 tiles and behind a last step of
+    each."""
+    from deepspeed_tpu.ops import decode_attention as da
+
+    monkeypatch.setattr(da, "latent_tile_blocks",
+                        lambda *shapes: min(2, shapes[-1]))
+    for blocks in ([0, 3, 1, 0, 5], [2, 0], [4], [0]):
+        q, pool, bt, pos, valid = _latent_tile_case(2, t, blocks)
+        got = np.asarray(da.paged_latent_attention_pallas(
+            q, pool, bt, pos, rank=LATENT_RANK, layer=0, valid=valid,
+            interpret=_simulated_chip("eager")))
+        assert np.isfinite(got).all() and not _races_found()
+        np.testing.assert_allclose(
+            _real(got, valid), _latent_tile_want(q, pool, bt, pos, valid, 0),
+            atol=2e-5)
+    assert "non-zero count" not in capfd.readouterr().out
+
+    assert _dma_sites(lambda *a: da.paged_latent_attention_pallas(
+        *a, rank=LATENT_RANK, layer=0, interpret=False), q, pool, bt,
+        pos) == {"dma_start": 2, "dma_wait": 1}
+
+
+@pytest.mark.parametrize(
+    "rows,bs,w,itemsize,nbper,want",
+    [(32, 512, 384, 2, 32, 4),           # Mistral Small 4, a decode step
+     (512, 512, 384, 2, 32, 2),          # ... a prefill step: 16 positions
+     (32, 256, 640, 2, 32, 4),           # Kimi Linear, a decode step
+     (512, 256, 640, 2, 32, 2),          # ... a prefill step
+     (160, 512, 384, 2, 32, 4),          # a verify window of 5
+     (32, 512, 384, 2, 3, 3),            # never more than the table holds
+     (32, 32, 384, 2, 512, 4),           # ... nor than the update's widths,
+     (32, 1024, 384, 4, 32, 1),          # float32, 1,024 tokens: the budget
+     (8192, 512, 384, 4, 32, 1)],        # never less than one block
+    ids=["mistral4-decode", "mistral4-prefill", "kimi-decode",
+         "kimi-prefill", "verify", "short-table", "small-blocks",
+         "large-blocks", "one-block"])
+def test_latent_tile_is_read_off_the_shapes(rows, bs, w, itemsize, nbper,
+                                            want):
+    """``latent_tile_blocks``: the most blocks whose two landing slots and
+    one update's scores (float32, and ``p`` in the pool's dtype) stay in
+    ``_LATENT_VMEM_BUDGET``, inside ``[1, min(NBPER, _LATENT_TILE_MAX,
+    _LATENT_TILE_ROWS // rows)]`` — the next one would break one of them;
+    more query rows, a smaller tile."""
+    from deepspeed_tpu.ops import decode_attention as da
+
+    nt = da.latent_tile_blocks(rows, bs, w, itemsize, nbper)
+
+    def need(n):
+        return n * bs * (2 * w * itemsize + rows * (4 + itemsize))
+
+    assert nt == want
+    assert nt == 1 or need(nt) <= da._LATENT_VMEM_BUDGET
+    assert need(nt + 1) > da._LATENT_VMEM_BUDGET \
+        or nt + 1 > min(da._LATENT_TILE_MAX, nbper,
+                        max(da._LATENT_TILE_ROWS // rows, 1))
+    assert nt <= da.latent_tile_blocks(max(rows // 2, 1), bs, w, itemsize,
+                                       nbper)
 
 
 def test_latent_walk_stops_at_a_rows_real_queries():

@@ -272,6 +272,29 @@ def test_stats_name_the_state_kind(tiny, served):
     # the spans carry the rows, resets and tokens of the state kind
     spans = [e for e in events if e["ph"] == "X"
              and e["name"] in ("prefill", "decode")]
+    # ... and the tiles of the latent layers' walk (ISSUE 58) at the tile
+    # the rule gives these shapes
+    from deepspeed_tpu.ops import decode_attention as da
+
+    lat = st["kv_latent"]
+    tile = {t: da.latent_walk_shape(
+        tiny[1].model_config.num_heads, t, srv.block_size, lat["pool_width"],
+        4, srv.max_seq_len // srv.block_size)[1] for t in (1, 16, 64)}
+    assert tile[1] == da._LATENT_TILE_MAX
+    assert lat["tile_blocks"] == {
+        "decode": tile[1],
+        "prefill": {srv._rung_name(r): tile[r[1]] for r in srv._rungs}}
+    for e in spans:
+        a = e["args"]
+        if e["name"] == "decode":
+            assert a["slots"] <= a["kv_tiles"] <= a["kv_blocks"] \
+                <= tile[1] * a["kv_tiles"]
+            assert a["slots"] - 1 <= a["kv_first_tiles_ahead"] <= a["slots"]
+        else:
+            assert 0 < a["kv_tiles"] <= a["kv_blocks"] * -(-a["width"] // 16)
+            assert a["kv_tiles"] - 1 == a["kv_first_tiles_ahead"]
+    assert st["kv_latent"]["kv_tiles"] == sum(
+        e["args"]["kv_tiles"] for e in spans)
     assert all({"state_rows", "state_resets", "state_tokens"}
                <= set(e["args"]) for e in spans)
     assert sum(e["args"]["state_resets"] for e in spans) == len(reqs)

@@ -229,6 +229,84 @@ def test_engine_serves_it_token_exact_and_names_the_pool(model):
     assert all(a["kv_pairs"] == a["kv_valid"] for a in dec)
 
 
+def _walks(t, tq, nt, rows):
+    """Grid step by grid step: ``(kv_tiles, kv_first_tiles_ahead)`` of ONE
+    layer's latent walk over ``rows`` = ``(base, real queries, place in the
+    call)`` a window of ``t`` positions, ``tq`` a grid step, ``nt`` blocks
+    a loop iteration."""
+    tiles = ahead = 0
+    for base, queries, at in rows:
+        for first in range(0, min(t, queries), tq):
+            n = -(-(base + min(first + tq, queries)) // BLOCK)
+            tiles += -(-n // nt)
+            ahead += (at, first) != (0, 0)
+    return tiles, ahead
+
+
+def test_the_spans_count_the_latent_walks_tiles(model, monkeypatch):
+    """ISSUE 58: a latent engine's ``decode`` / ``prefill`` / ``spec_verify``
+    spans carry ``kv_tiles`` (the loop iterations ONE layer's walk makes:
+    ``cdiv(blocks, nt)`` over rows and query tiles) and
+    ``kv_first_tiles_ahead`` (the grid steps whose first tile the step
+    before starts: all that hold one but the call's first), at the tile
+    ``stats()["kv_latent"]["tile_blocks"]`` names — ``latent_tile_blocks``
+    of the pool's shapes, here 3 blocks a decode step and 2 a prefill
+    step."""
+    from deepspeed_tpu.ops import decode_attention as da
+
+    cfg, spec, params = model
+    monkeypatch.setattr(da, "_LATENT_VMEM_BUDGET", 30_000)
+    shapes = dict(bs=BLOCK, w=128, itemsize=4, nbper=128 // BLOCK)
+    tiles = {t: da.latent_walk_shape(cfg.num_heads, t, **shapes)
+             for t in (1, 4, CHUNK, 4 * CHUNK)}
+    assert tiles == {1: (1, 3), 4: (4, 3), CHUNK: (16, 2),
+                     4 * CHUNK: (16, 2)}
+    # one request alone, in slot 0: every span is reckoned exactly
+    srv, _, _ = _serve(spec, params, [70], spec_tokens=0)
+    assert srv.stats()["kv_latent"]["tile_blocks"] == {
+        "decode": 3, "prefill": {srv._rung_name(r): 2 for r in srv._rungs}}
+    spans = [e["args"] for e in srv.timeline.events()
+             if e["ph"] == "X" and e["name"] in ("prefill", "decode")]
+    base = 0
+    for a in spans:
+        assert {"kv_tiles", "kv_first_tiles_ahead"} <= set(a)
+        if "width" in a:                                        # prefill
+            want = _walks(a["width"], *tiles[a["width"]],
+                          [(base, a["tokens"], 0)])
+            base += a["tokens"]
+        else:
+            keys = a["kv_valid"] // 2                           # 2 layers
+            assert a["kv_blocks"] == -(-keys // BLOCK)
+            want = (-(-a["kv_blocks"] // 3), 0)
+        assert (a["kv_tiles"], a["kv_first_tiles_ahead"]) == want, a
+    assert base == 70 and max(a["kv_blocks"] for a in spans) == 11
+    lat = srv.stats()["kv_latent"]
+    assert lat["kv_tiles"] == sum(a["kv_tiles"] for a in spans)
+    assert lat["kv_first_tiles_ahead"] == sum(
+        a["kv_first_tiles_ahead"] for a in spans) > 0
+    # rows side by side: the tiles run full (kv_blocks / kv_tiles up to
+    # nt), and every live row but the call's first finds its tile started
+    srv, _, _ = _serve(spec, params, [70, 33, 50, 9], spec_tokens=3)
+    assert srv.stats()["kv_latent"]["tile_blocks"]["verify"] == 3
+    for name in ("decode", "spec_verify"):
+        for e in srv.timeline.events():
+            a = e["args"]
+            if e["ph"] != "X" or e["name"] != name:
+                continue
+            assert a["slots"] <= a["kv_tiles"] <= a["kv_blocks"] \
+                <= 3 * a["kv_tiles"], a
+            assert a["slots"] - 1 <= a["kv_first_tiles_ahead"] \
+                <= a["slots"], a
+    # the arithmetic itself, rows of mixed lengths in one call
+    for t, rows in ((1, [(0, 1, 0), (23, 1, 2), (24, 1, 5), (100, 1, 7)]),
+                    (4, [(30, 4, 1), (5, 4, 2)]),
+                    (64, [(0, 64, 0), (40, 17, 1), (7, 3, 2), (64, 33, 3)])):
+        base, queries, at = map(np.asarray, zip(*rows))
+        got = srv._kv_reach(base + queries, queries, t=t, at=at)
+        assert (got["kv_tiles"], got["kv_first_tiles_ahead"]) \
+            == _walks(t, *tiles[t], rows), (t, rows)
+
+
 def test_preempted_row_is_readmitted_token_exact(model):
     """A pool too small for three long rows: a row is preempted and
     re-admitted (its prompt and what it generated re-prefilled from

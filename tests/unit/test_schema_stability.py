@@ -189,6 +189,44 @@ def test_decode_attn_stats_keys_pinned(served):
         isinstance(v, int) for v in walk.values())
 
 
+def test_latent_kind_stats_and_span_keys_pinned():
+    """A model with latent attention: ``stats()["kv_latent"]`` and what its
+    ``decode`` / ``prefill`` spans carry of the latent walk, key for key —
+    PR 39's, and PR 58's ``tile_blocks`` / ``kv_tiles`` /
+    ``kv_first_tiles_ahead`` (the walk's tile of each program, the loop
+    iterations of one layer's call and the grid steps whose first tile the
+    step before starts)."""
+    from deepspeed_tpu.models import llama
+
+    deepspeed_tpu.comm.reset_topology()
+    srv = deepspeed_tpu.init_serving(
+        llama.build(llama.LlamaConfig(
+            vocab_size=64, max_seq_len=64, num_layers=1, num_heads=2,
+            num_kv_heads=2, head_width=16, hidden_size=32, ffn_size=32,
+            q_lora_rank=16, kv_lora_rank=16, qk_nope_dim=8, qk_rope_dim=8,
+            v_head_dim=8, remat=False)),
+        config={"dtype": "fp32"}, slots=2, max_seq_len=64, block_size=8,
+        prefill_chunk=16)
+    srv.serve([Request(uid=0, prompt=np.arange(20) % 64, max_new_tokens=3)])
+    walk = {"kv_valid", "kv_blocks", "kv_pairs", "latent_bytes", "kv_tiles",
+            "kv_first_tiles_ahead"}
+    lat = srv.stats()["kv_latent"]
+    assert set(lat) == walk | {
+        "kind", "layers", "token_width", "pool_width", "token_bytes",
+        "block_size", "block_bytes", "latent_attn", "tile_blocks", "refused"}
+    assert set(lat["tile_blocks"]) == {"decode", "prefill"}
+    assert set(lat["tile_blocks"]["prefill"]) == set(
+        srv.stats()["prefill_shapes"])
+    for name in ("decode", "prefill"):
+        spans = [e["args"] for e in srv.timeline.events()
+                 if e["ph"] == "X" and e["name"] == name]
+        assert spans and all(
+            walk <= set(a) and all(isinstance(a[k], int) for k in walk)
+            for a in spans), name
+    assert srv.stats()["decode_attn"] is None
+    srv.close()
+
+
 def test_engine_stats_keys_pinned_with_draft_pool_extras(served):
     """The only engine stats() extension point: a draft pool adds its
     two byte-accounting keys (PR 5 behavior, unchanged)."""
