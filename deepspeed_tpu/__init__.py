@@ -261,21 +261,8 @@ def init_router(model=None, config=None, params=None, *, replicas=2,
 
 
 @_on_setup_ring
-def init_serving(model=None, config=None, params=None, *, slots=8,
-                 max_seq_len=None, prefill_batch=4,
-                 block_size=None, num_blocks=None,
-                 prefill_chunk=128, prefix_caching=None, decode_steps=1,
-                 engine_mode="replicas", sp=1, resident_window_blocks=0,
-                 spec_tokens=0,
-                 quantize=None, host_blocks=0, swap_batch=8, draft=None,
-                 role="both", nvme_blocks=0, nvme_high_watermark=0.9,
-                 nvme_path=None,
-                 ngram_max=3, ngram_min=1,
-                 sampling=True, logit_masks=False,
-                 shard_kv=None, topology=None, device_group=None,
-                 debug_checks=False,
-                 trace_capacity=131072, slo_targets=None, peak_flops=None,
-                 **kwargs):
+def init_serving(model=None, config=None, params=None, *, topology=None,
+                 device_group=None, **kwargs):
     """Continuous-batching serving entry: an ``init_inference`` engine
     wrapped in the block-paged scheduler (``inference/serving.py``).
     Mixed-length request traces run at iteration-level granularity over a
@@ -285,15 +272,18 @@ def init_serving(model=None, config=None, params=None, *, slots=8,
     prefill program) — instead of ``generate``'s run-to-longest static
     batches.
 
-    ``decode_steps=K`` fuses K decode iterations into ONE on-device
-    ``lax.while_loop`` program (the host-loop kill): per-slot eos/budget
-    checks run on device behind a fixed-shape active mask and the host
-    catches up once per window at the fence — token-exact with K=1 greedy
-    decode, ~K× fewer Python scheduler iterations per generated token.
+    The keyword options are the serving engine's
+    (:class:`~deepspeed_tpu.inference.serving.ServingEngine`; their names,
+    defaults and ranges, and what does not combine, are stated once, in
+    ``inference/options.py`` — ``inspect.signature(init_serving)`` shows
+    them), plus ``topology`` and ``device_group``; any other keyword must be
+    a field of the ``init_inference`` config (``dtype=``, ``quant=``, ...)
+    and anything else is a ``TypeError`` that names it.
+
     ``engine_mode="dp_tp"`` runs ONE engine over the 2-D ``("dp","tp")``
     mesh (slots + KV blocks dp-sharded, KV heads tp-sharded): one
     compiled decode program serves what otherwise takes dp router-fronted
-    replicas.  See docs/inference.md "Multi-step fused decode".
+    replicas.  See docs/inference.md "dp×tp engine mode".
 
     ``spec_tokens=K`` turns on speculative decoding: each decode iteration
     drafts K tokens per slot — with a small same-tokenizer ``draft`` model
@@ -397,8 +387,19 @@ def init_serving(model=None, config=None, params=None, *, slots=8,
     denominator for ``srv.flops_report()`` (the cost_analysis-backed
     FLOPs/MFU profiler, ``telemetry/flops.py``).  See
     ``docs/observability.md``."""
+    from .inference import options
+    from .inference.config import DeepSpeedInferenceConfig
     from .inference.serving import ServingEngine
 
+    # the serving options (by attribute, at their defaults where not given);
+    # what is left in kwargs must be the engine config's
+    o = options.bind({k: kwargs.pop(k) for k in list(kwargs)
+                      if k in options.OPTIONS}, "init_serving")
+    fields = DeepSpeedInferenceConfig.model_fields
+    for name in set(kwargs) - set(fields) - {
+            f.alias for f in fields.values()}:
+        raise TypeError(
+            f"init_serving() got an unexpected keyword argument {name!r}")
     if topology is not None:
         tp = int(topology) if not isinstance(topology, dict) else \
             int(topology.get("tp", topology.get("tp_size", 1)))
@@ -411,18 +412,18 @@ def init_serving(model=None, config=None, params=None, *, slots=8,
         else:
             config = config.model_copy(deep=True)
             config.tensor_parallel.tp_size = tp
-    if int(sp) > 1:
+    if o.sp > 1:
         # sp= injects sequence_parallel the same way topology= injects
         # tensor_parallel: the engine builds the (dp, sp, tp) mesh, the
         # serving ctor validates the axis matches
         if isinstance(config, dict):
-            config = {**config, "sequence_parallel": int(sp)}
+            config = {**config, "sequence_parallel": o.sp}
         elif config is None:
-            kwargs["sequence_parallel"] = int(sp)
+            kwargs["sequence_parallel"] = o.sp
         else:
             config = config.model_copy(deep=True)
-            config.sequence_parallel = int(sp)
-    if quantize and "w8a8" in str(quantize):
+            config.sequence_parallel = o.sp
+    if o.quantize and "w8a8" in str(o.quantize):
         # route the engine's weights through the K-grouped int8 records the
         # w8a8 serving kernels consume.  An EXPLICIT quant block in config
         # wins when enabled (the caller may be pinning group_size /
@@ -450,28 +451,27 @@ def init_serving(model=None, config=None, params=None, *, slots=8,
             config = config.model_copy(deep=True)
             config.quant.enabled = True
             config.quant.type = "w8a8"
-    if engine_mode == "replicas":
+    if o.engine_mode == "replicas":
         device_group = device_group or 0
     elif device_group is not None:
         raise ValueError("engine_mode='dp_tp' spans every device — "
                          "device_group does not apply")
     engine = init_inference(model, config, params,
                             device_group=device_group, **kwargs)
-    return ServingEngine(engine, slots=slots, max_seq_len=max_seq_len,
-                         prefill_batch=prefill_batch, block_size=block_size,
-                         num_blocks=num_blocks,
-                         prefill_chunk=prefill_chunk,
-                         prefix_caching=prefix_caching,
-                         decode_steps=decode_steps, engine_mode=engine_mode,
-                         sp=sp,
-                         resident_window_blocks=resident_window_blocks,
-                         spec_tokens=spec_tokens, quantize=quantize,
-                         host_blocks=host_blocks, swap_batch=swap_batch,
-                         draft=draft, role=role, nvme_blocks=nvme_blocks,
-                         nvme_high_watermark=nvme_high_watermark,
-                         nvme_path=nvme_path,
-                         ngram_max=ngram_max, ngram_min=ngram_min,
-                         sampling=sampling, logit_masks=logit_masks,
-                         shard_kv=shard_kv, debug_checks=debug_checks,
-                         trace_capacity=trace_capacity,
-                         slo_targets=slo_targets, peak_flops=peak_flops)
+    return ServingEngine(engine, **vars(o))
+
+
+def _serving_signature():
+    """``init_serving`` as ``inspect.signature`` shows it: its own
+    parameters with every serving option (``inference/options.py``)
+    keyword-only at its default."""
+    import inspect
+
+    from .inference import options
+
+    own = list(inspect.signature(
+        init_serving.__wrapped__).parameters.values())
+    return options.signature(*own[:-1], var_keyword=own[-1].name)
+
+
+init_serving.__signature__ = _serving_signature()

@@ -69,13 +69,13 @@ GL012     per-iteration scalar device sync in a host scheduler loop:
           ``jnp``/``jax``-rooted expression, or a ``jnp``-rooted call as
           an ``if``/``while`` test — each iteration round-trips ONE
           scalar to the host, so the loop runs at device-latency per
-          token instead of dispatching ahead (the motivation for the
-          fused multi-step decode program, ``docs/inference.md``).
+          token instead of dispatching ahead (what the serving engine's
+          one call of lookahead avoids, ``docs/inference.md``).
           Batch the decision onto the device (``lax.while_loop`` with an
           on-device ``active`` mask) and read results back once at a
           sanctioned fence helper — GL007's transfer verbs plus
-          ``fence``/``harvest``/``settle`` (``ServingEngine
-          ._fence_harvest``, ``._settle``).
+          ``harvest``/``settle`` (``ServingEngine._harvest``,
+          ``._settle``).
           (GL009..GL011, the lock-discipline rules, live in
           ``analysis/concurrency.py``.)
 GL013     silent exception swallow in fleet-path code (``serving/``,
@@ -188,12 +188,12 @@ _METRIC_LABEL_KEYS = frozenset(
 _METRIC_PARAM_KWARGS = frozenset({"help", "monitor_name", "buckets"})
 
 #: substrings marking a function as a sanctioned blocking-transfer helper
-#: for GL007/GL012 (the documented sync/swap commit points; "fence"/
-#: "harvest" name the fused-decode fence, e.g. ``_fence_harvest``;
-#: "settle" the plain decode path's, ``ServingEngine._settle``, which
-#: takes the results of the one call the scheduler keeps in flight)
+#: for GL007/GL012 (the documented sync/swap commit points; "harvest" /
+#: "settle" name the decode path's, ``ServingEngine._harvest`` /
+#: ``._settle``, which take the results of the one call the scheduler
+#: keeps in flight)
 _SANCTIONED_XFER = ("demote", "promote", "swap", "sync", "prefetch",
-                    "fence", "harvest", "settle")
+                    "harvest", "settle")
 
 #: ``time`` module entry points whose call inside a traced body is GL006;
 #: the bare spellings (from-imports) are distinctive enough to flag as

@@ -41,7 +41,7 @@ from ..analysis.sentry import RetraceError
 from ..utils.logging import log_dist
 from . import report as report_mod
 from .search import SuccessiveHalving
-from .space import ModelGeom, ServingKnobSpace
+from .space import FLEET_KNOBS, ModelGeom, ServingKnobSpace
 from .trace import ServingTrace
 
 __all__ = ["ParityError", "TrialRunner", "tune_serving"]
@@ -286,7 +286,10 @@ def tune_serving(engine, trace: ServingTrace, *,
         raise RuntimeError(
             "autotuning found no feasible serving configuration; "
             f"see {results_dir}/exps.json")
-    winner_cfg = out["best"]["config"]
+    # ``best_config.json`` is ``init_serving`` keywords, which refuses the
+    # fleet's by name (the trials ran ONE engine)
+    winner_cfg = {k: v for k, v in out["best"]["config"].items()
+                  if k not in FLEET_KNOBS}
     predicted = float(out["best"]["throughput"])
 
     # predicted-vs-measured: fresh full-budget re-runs of the winner and

@@ -24,11 +24,18 @@ the space *with* its constraints:
    ``quantize`` — candidates trade block granularity against pool depth
    under one fixed memory envelope, the way a real chip does.
 
-Every constraint here has a matching *loud* ctor validation in
-``ServingEngine``/``init_serving`` (audited by
-``tests/unit/test_serving_autotune.py``): pruning is an optimization,
-not the safety net — a config that somehow slips through still fails
-with a diagnosis naming the knob, never a mid-trial crash.
+What the constructor refuses — an option out of its range, options that
+do not combine — is not restated here: the defaults are
+``inference/options.py OPTIONS``', and one predicate a rule group reads
+its ``EXCLUDES`` (the sentence a pruned candidate carries is the one the
+constructor's ``ValueError`` would).  A predicate of this module's own
+says what only the space knows: the memory ceiling, the program budget,
+the fleet's prefill:decode ratio, and the two checks that need the
+model's geometry (the pool's floor, KV heads over tp).  Pruning is an
+optimization, not the safety net — a config that somehow slips through
+still fails in the constructor with a diagnosis naming the knob, never a
+mid-trial crash (``tests/unit/test_serving_autotune.py``,
+``tests/unit/test_serving_options.py``).
 """
 
 from __future__ import annotations
@@ -38,44 +45,28 @@ import itertools
 import math
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+from ..inference import options
+
 __all__ = ["ModelGeom", "ServingKnobSpace", "kv_pool_bytes",
            "compile_budget", "prefill_rungs", "workload_space",
            "DEFAULT_DOMAINS", "CONSTRAINTS", "BASE_SERVING_CONFIG",
-           "DECODE_STEPS_MAX"]
+           "FLEET_KNOBS"]
 
-#: the verify kernel's widest speculative window (K+1 <= this);
-#: mirrored from ops/decode_attention.py without importing jax
-VERIFY_T_MAX = 16
-
-#: ``init_serving`` serving-level defaults — the hand-picked config every
-#: search starts from (and the yardstick the winner must beat)
+#: the hand-picked config every search starts from (and the yardstick the
+#: winner must beat): every serving option at its default — the two a plain
+#: model's ``None`` resolves to stated, the block formulae need them — and
+#: the fleet's knobs (``init_serving``'s ``topology``, ``init_router``'s
+#: ``replicas`` / ``prefill_workers``)
 BASE_SERVING_CONFIG: Dict[str, Any] = {
-    "slots": 8,
-    "max_seq_len": None,
+    **{name: opt.default for name, opt in options.OPTIONS.items()},
     "block_size": 32,
-    "num_blocks": None,
-    "prefill_chunk": 128,
-    "prefill_batch": 4,
     "prefix_caching": True,
-    "spec_tokens": 0,
-    "quantize": None,
-    "host_blocks": 0,
-    "swap_batch": 8,
-    "role": "both",
-    "nvme_blocks": 0,
-    "nvme_high_watermark": 0.9,
+    "topology": 1,
     "replicas": 1,
     "prefill_workers": 0,
-    "shard_kv": None,
-    "topology": 1,
-    "decode_steps": 1,
-    "engine_mode": "replicas",
-    "sp": 1,
-    "resident_window_blocks": 0,
-    "sampling": True,
-    "logit_masks": False,
-    "trace_capacity": 131072,
 }
+#: the knobs of a candidate that are ``init_router``'s, not an engine's
+FLEET_KNOBS = ("replicas", "prefill_workers")
 
 #: conservative default domains — callers override per workload (the
 #: bench lane, for one, adds trace-sized ``host_blocks`` choices)
@@ -84,14 +75,7 @@ DEFAULT_DOMAINS: Dict[str, Tuple[Any, ...]] = {
     "prefill_chunk": (64, 128, 256),
     "prefill_batch": (2, 4, 8),
     "spec_tokens": (0, 4),
-    "decode_steps": (1, 4, 8),
 }
-
-#: widest fused-decode window the space searches; a larger K only adds
-#: host-fence latency variance past the point where the Python loop is
-#: already off the critical path (the fused while_loop program is ONE
-#: compile for any K — the budget does not scale with it)
-DECODE_STEPS_MAX = 64
 
 
 @dataclasses.dataclass(frozen=True)
@@ -182,10 +166,7 @@ def compile_budget(config: Dict[str, Any]) -> int:
     model would add 1; the space searches the zero-extra-programs n-gram
     proposer.)
 
-    ``decode_steps > 1`` does NOT add a program: the fused multi-step
-    while_loop REPLACES the per-token decode program (same sentry name,
-    same budget slot), so the count is K-invariant.  ``engine_mode=
-    "dp_tp"`` likewise compiles the same two programs — one dp-sharded
+    ``engine_mode="dp_tp"`` compiles the same programs — one dp-sharded
     decode instead of N per-replica copies.  ``nvme_blocks`` and ``role``
     add NOTHING: the NVMe tier spills/promotes through the host arena's
     existing two swap programs (the file I/O is host-side ``ops/aio``),
@@ -232,32 +213,9 @@ def _c_shard_kv(config, space) -> Optional[str]:
     return None
 
 
-def _c_spec_window(config, space) -> Optional[str]:
-    k = int(config.get("spec_tokens") or 0)
-    if k and k + 1 > VERIFY_T_MAX:
-        return (f"spec_tokens={k} verify window exceeds the kernel max "
-                f"{VERIFY_T_MAX}")
-    return None
-
-
-def _c_tiered_prefix(config, space) -> Optional[str]:
-    if config.get("host_blocks") and not config.get("prefix_caching",
-                                                    True):
-        return "host_blocks > 0 requires prefix_caching=True"
-    return None
-
-
-def _c_swap_batch(config, space) -> Optional[str]:
-    hb = int(config.get("host_blocks") or 0)
-    sb = int(config.get("swap_batch") or 0)
-    if hb and (sb < 1 or sb > hb):
-        return f"swap_batch={sb} outside [1, host_blocks={hb}]"
-    return None
-
-
 def _c_pool_min(config, space) -> Optional[str]:
     if int(config.get("block_size") or 0) < 1:
-        return None                    # positive_knobs owns this failure
+        return None                    # option_ranges owns this failure
     need = 1 + _blocks_per_seq(config)
     win = int(config.get("resident_window_blocks") or 0)
     if win:
@@ -271,38 +229,6 @@ def _c_pool_min(config, space) -> Optional[str]:
         return (f"num_blocks={resolved_num_blocks(config)} cannot hold "
                 f"one {'resident window' if win else 'full sequence'} "
                 f"({need} blocks incl. scratch)")
-    return None
-
-
-def _c_positive(config, space) -> Optional[str]:
-    for k in ("slots", "prefill_batch", "block_size"):
-        if int(config.get(k) or 0) < 1:
-            return f"{k} must be >= 1, got {config.get(k)}"
-    return None
-
-
-def _c_decode_steps(config, space) -> Optional[str]:
-    k = int(config.get("decode_steps") or 1)
-    if k < 1 or k > DECODE_STEPS_MAX:
-        return (f"decode_steps={k} outside [1, {DECODE_STEPS_MAX}]")
-    if k > 1 and int(config.get("spec_tokens") or 0):
-        # not invalid at the ctor (spec dispatch wins; K is inert) but a
-        # duplicate of the K=1 candidate — prune so the trial budget
-        # never pays for the same config twice
-        return (f"decode_steps={k} is inert under spec_tokens="
-                f"{config['spec_tokens']} (speculative dispatch wins) — "
-                "duplicate of the decode_steps=1 candidate")
-    return None
-
-
-def _c_role_tiered(config, space) -> Optional[str]:
-    role = config.get("role") or "both"
-    if role not in ("prefill", "decode", "both"):
-        return (f"role={role!r} — expected 'prefill', 'decode' or 'both'")
-    if role != "both" and not int(config.get("host_blocks") or 0):
-        return (f"role={role!r} needs the tiered KV cache "
-                "(host_blocks > 0): the prefill→decode handoff travels "
-                "as a host-tier chain export/import")
     return None
 
 
@@ -322,141 +248,50 @@ def _c_prefill_ratio(config, space) -> Optional[str]:
     return None
 
 
-def _c_nvme_tier(config, space) -> Optional[str]:
-    nb = int(config.get("nvme_blocks") or 0)
-    if nb < 0:
-        return f"nvme_blocks must be >= 0, got {nb}"
-    if nb and not int(config.get("host_blocks") or 0):
-        return (f"nvme_blocks={nb} needs the host tier above it "
-                "(host_blocks > 0)")
+def _degrees(config) -> Dict[str, Any]:
+    """The mesh a candidate would be built on, as ``options.check`` takes
+    it: ``init_serving`` gives the engine the tp and sp axes the candidate
+    names and the int8 weights a ``w8a8`` candidate needs; what ``dp`` a
+    ``dp_tp`` candidate finds is the host's, and more than one group is
+    what such a candidate is searched for."""
+    return {"tp": int(config.get("topology") or 1),
+            "dp": 2 if config.get("engine_mode") == "dp_tp" else 1,
+            "mesh_sp": int(config.get("sp") or 1),
+            "weights": "w8a8" if "w8a8" in str(config.get("quantize") or "")
+            else None}
+
+
+def _c_ranges(config, space) -> Optional[str]:
+    try:
+        options.check_ranges(config)
+    except ValueError as e:
+        return str(e)
     return None
 
 
-def _c_nvme_watermark(config, space) -> Optional[str]:
-    wm = float(config.get("nvme_high_watermark") or 0.9)
-    if not (0.0 < wm <= 1.0):
-        return f"nvme_high_watermark={wm} outside (0, 1]"
-    hb = int(config.get("host_blocks") or 0)
-    sb = int(config.get("swap_batch") or 0)
-    if int(config.get("nvme_blocks") or 0) and hb and sb > int(wm * hb):
-        return (f"swap_batch={sb} exceeds the host-arena watermark budget "
-                f"int({wm} * {hb}) — one promotion batch would "
-                "immediately re-spill its own head")
-    return None
+def _c_excludes(group: str) -> Callable:
+    """The predicate of one rule group of ``options.EXCLUDES``: the
+    sentence of the first of its rules the candidate breaks."""
+    def broken(config, space) -> Optional[str]:
+        if _c_ranges(config, space):
+            return None                # option_ranges owns this failure
+        return next((says for name, says in options.violations(
+            config, _degrees(config)) if name == group), None)
+    return broken
 
 
-def _c_engine_mode(config, space) -> Optional[str]:
-    mode = config.get("engine_mode") or "replicas"
-    if mode not in ("replicas", "dp_tp"):
-        return (f"engine_mode={mode!r} — expected 'replicas' or 'dp_tp'")
-    if mode != "dp_tp":
-        return None
-    for knob in ("spec_tokens", "host_blocks"):
-        if int(config.get(knob) or 0):
-            return (f"engine_mode='dp_tp' does not compose with "
-                    f"{knob}={config[knob]} (v1: dp groups would need "
-                    "cross-group scheduling)")
-    if config.get("quantize"):
-        return ("engine_mode='dp_tp' does not compose with quantize="
-                f"{config['quantize']!r}")
-    if config.get("prefix_caching", True):
-        return ("engine_mode='dp_tp' requires prefix_caching=False "
-                "(shared trie blocks cannot cross dp pool groups)")
-    return None
-
-
-def _c_sp(config, space) -> Optional[str]:
-    sp = int(config.get("sp") or 1)
-    if sp < 1:
-        return f"sp must be >= 1, got {sp}"
-    if sp == 1:
-        return None
-    chunk = int(config.get("prefill_chunk") or 0)
-    if chunk % sp:
-        return (f"prefill_chunk={chunk} must divide by sp={sp} — every "
-                "rank owns an equal sequence shard of the chunk")
-    if int(config.get("spec_tokens") or 0):
-        return (f"sp={sp} does not compose with spec_tokens="
-                f"{config['spec_tokens']} (v1: the verify window is not "
-                "sequence-sharded)")
-    if (config.get("engine_mode") or "replicas") == "dp_tp":
-        return (f"sp={sp} does not compose with engine_mode='dp_tp' "
-                "(v1: the mesh carries either dp or sp, not both)")
-    return None
-
-
-def _c_resident_window(config, space) -> Optional[str]:
-    win = int(config.get("resident_window_blocks") or 0)
-    if win < 0:
-        return f"resident_window_blocks must be >= 0, got {win}"
-    if not win:
-        return None
-    if not config.get("prefix_caching", True):
-        return ("resident_window_blocks > 0 requires prefix_caching=True "
-                "(slid blocks demote through the chain-keyed host tier)")
-    if not int(config.get("host_blocks") or 0):
-        return ("resident_window_blocks > 0 needs the host tier "
-                "(host_blocks > 0) to hold demoted cold context")
-    for knob in ("spec_tokens",):
-        if int(config.get(knob) or 0):
-            return (f"resident_window_blocks={win} does not compose "
-                    f"with {knob}={config[knob]} (v1: the verify span "
-                    "assumes a contiguous block table)")
-    if int(config.get("decode_steps") or 1) > 1:
-        return (f"resident_window_blocks={win} does not compose with "
-                f"decode_steps={config['decode_steps']} (v1: the fused "
-                "loop cannot slide the window mid-program)")
-    if (config.get("engine_mode") or "replicas") == "dp_tp":
-        return (f"resident_window_blocks={win} does not compose with "
-                "engine_mode='dp_tp'")
-    if int(config.get("sp") or 1) > 1:
-        return (f"resident_window_blocks={win} does not compose with "
-                f"sp={config['sp']} (v1: windowing is decode-side, sp "
-                "is prefill-side — composition is untested)")
-    if int(config.get("block_size") or 0) >= 1:
-        chunk_blocks = int(math.ceil(int(config.get("prefill_chunk")
-                                         or 1)
-                                     / int(config["block_size"])))
-        if win < chunk_blocks + 1:
-            return (f"resident_window_blocks={win} smaller than one "
-                    f"prefill chunk + 1 ({chunk_blocks + 1}): the "
-                    "window would slide past its own in-flight chunk")
-    return None
-
-
-def _c_logit_masks(config, space) -> Optional[str]:
-    if not config.get("logit_masks"):
-        return None
-    if not config.get("sampling", True):
-        return ("logit_masks=True needs the sampling stack — constrained "
-                "decoding applies the mask inside the sampler programs")
-    if (config.get("engine_mode") or "replicas") == "dp_tp":
-        return ("engine_mode='dp_tp' v1 excludes logit_masks — the "
-                "dp-sharded decode program does not carry the "
-                "[slots, vocab] mask operand")
-    return None
-
-
-#: ``(name, predicate)`` — predicate returns a violation message or None.
-#: Each has a loud ctor-validation twin (module docstring).
+#: ``(name, predicate)`` — predicate returns a violation message or None:
+#: the options' ranges, this module's own five, then a predicate a rule
+#: group of ``options.EXCLUDES`` (in the table's order)
 CONSTRAINTS: Tuple[Tuple[str, Callable], ...] = (
-    ("positive_knobs", _c_positive),
+    ("option_ranges", _c_ranges),
     ("kv_pool_memory", _c_memory),
     ("compile_budget", _c_compile),
     ("shard_kv_divisibility", _c_shard_kv),
-    ("spec_window", _c_spec_window),
-    ("tiered_needs_prefix_cache", _c_tiered_prefix),
-    ("swap_batch_bounds", _c_swap_batch),
-    ("role_needs_tiered_kv", _c_role_tiered),
     ("prefill_decode_ratio", _c_prefill_ratio),
-    ("nvme_needs_host_tier", _c_nvme_tier),
-    ("nvme_watermark_window", _c_nvme_watermark),
     ("pool_min_blocks", _c_pool_min),
-    ("decode_steps_window", _c_decode_steps),
-    ("engine_mode_exclusive", _c_engine_mode),
-    ("sp_prefill_exclusive", _c_sp),
-    ("resident_window_span", _c_resident_window),
-    ("logit_masks_excludes_dp_tp", _c_logit_masks),
+    *((group, _c_excludes(group)) for group in dict.fromkeys(
+        rule.group for rule in options.EXCLUDES)),
 )
 
 

@@ -2,7 +2,7 @@
 
 The serving engine's constrained lane (``ServingEngine(logit_masks=True)``)
 threads ONE fixed-shape ``[slots, vocab]`` bool operand through the same
-compiled decode/fused/verify programs everything else uses — a slot
+compiled decode / verify programs everything else uses — a slot
 switching between free and constrained decoding only changes operand
 *values*, never program shapes (zero recompiles).  The mask itself is
 built HERE, on the host, once per scheduler iteration: the engine calls

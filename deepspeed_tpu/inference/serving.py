@@ -221,8 +221,9 @@ trie (``prefix_caching=True``; the default ``None`` turns it off for such
 a model — a shared prefix lacks the window layers' last ``window`` keys),
 the host / NVMe tiers, ``quantize="kv8"``, ``resident_window_blocks``,
 ``spec_tokens`` (a window past a row's budget writes through an unset
-table entry into scratch; a ring entry is never unset), ``decode_steps >
-1``, a draft model, and tp / dp / sp meshes.  ``stats()["kv_kinds"]`` has
+table entry into scratch; a ring entry is never unset), a draft model, and
+tp / dp / sp meshes (``inference/options.py KIND_REFUSES["window"]``: the
+one statement of it, as of every kind's list below).  ``stats()["kv_kinds"]`` has
 both pools, the releases, the reach the scheduler reckons from its rows'
 lengths (``kv_valid`` / ``kv_visible``, :meth:`ServingEngine._kv_reach`)
 and the refusals.
@@ -240,7 +241,7 @@ the block is ``paged_kv.latent_block_tokens``'s, 512 tokens at 768 B (a
 32-token block would be 24 KB, and a block visit costs ~0.4 us whatever it
 moves).  Refused by name at construction, each with its
 reason: ``quantize`` (kv8, w8a8), a tp mesh, ``engine_mode="dp_tp"``, ``sp
-> 1``, a draft model, ``resident_window_blocks``, ``decode_steps > 1``.
+> 1``, a draft model, ``resident_window_blocks``.
 ``stats()["kv_latent"]`` names the kind, the token's width and bytes, the
 block, the read each program was traced with and the refusals; the
 ``decode`` / ``prefill`` / ``spec_verify`` spans carry ``kv_valid`` (valid
@@ -273,10 +274,10 @@ call of lookahead keeps it (it is part of the donated cache), and
 from base 0 and is exact.  The programs take ``block_tables = {"full": ...}``
 (decode: row b is slot b) or ``{"full": ..., "slot": int32 [rows]}``
 (prefill; a pad row's slot is out of range).  Refused by name at
-construction, each with its reason (:meth:`ServingEngine._refuse_for_state`):
+construction, each with its reason (``options.KIND_REFUSES["state"]``):
 the prefix trie, the host / NVMe tiers, ``spec_tokens`` and a draft model,
-``decode_steps > 1``, ``quantize`` (kv8, w8a8), ``resident_window_blocks``
-and tp / dp / sp meshes.  ``stats()["kv_state"]`` has the leaves, their
+``quantize`` (kv8, w8a8), ``resident_window_blocks`` and tp / dp / sp
+meshes.  ``stats()["kv_state"]`` has the leaves, their
 bytes (whatever the rows' lengths), the resets, which body each program's
 recurrence lowered to — under the hook's ``bodies`` (``"kda"``: ``kda_step``
 / ``kda_chunk_state``; ``"ssd"``: ``ssd_step`` / ``ssd_chunk_state``;
@@ -337,7 +338,6 @@ from ..analysis.sentry import RecompileSentry, backend_compiles
 from ..ops import (decode_attention, paged_kv, sp_attention,
                    sparse_index_attention)
 from ..ops import sampling as sampling_ops
-from ..ops.decode_attention import VERIFY_T_MAX
 from ..ops.paged_kv import blocks_for
 from ..parallel.topology import DP_AXIS, SP_AXIS, TP_AXIS
 from ..telemetry import MetricsRegistry, ProfilerWindow, TraceTimeline
@@ -346,6 +346,7 @@ from ..telemetry import trace as trace_mod
 from ..telemetry.slo import SLOTracker
 from ..utils.logging import log_dist, logger
 from ..utils.platform import on_tpu
+from . import options
 from .operands import OperandLayout
 from .paged import (SCRATCH_BLOCK, BlockAllocator, GroupedBlockAllocator,
                     HostBlockStore, NoBlocks, NvmeBlockStore, PrefixCache,
@@ -392,33 +393,14 @@ _STALL_FIELDS = ("wall",) + SEGMENTS + ("offcpu", "gc")
 #: runner or a tier with a fence of its own, a replica that hands its rows
 #: on), what its rows need (a mask made on the host from their tokens), or
 #: what is about to be done to a row that is in flight
-EARLY_SETTLE_CAUSES = ("debug_checks", "speculative", "fused", "kv_tier",
-                       "handoff", "mask_builder", "preempt", "cancel",
-                       "drain", "close")
+EARLY_SETTLE_CAUSES = ("debug_checks", "speculative", "kv_tier", "handoff",
+                       "mask_builder", "preempt", "cancel", "drain", "close")
 #: the cache leaves of the state kind: indexed by SLOT, never by block
 STATE_LEAVES = paged_kv.STATE_LEAVES
 #: a decode row's entry in the call's token operand when its input token is
 #: the one the call before made and the host has not seen: the program
 #: takes the row's entry of the engine's device-resident token vector
 TOKEN_ON_DEVICE = -1
-
-#: legal ``quantize=`` values (order-normalized; ``None`` = full precision)
-_QUANT_MODES = ("kv8", "w8a8", "w8a8+kv8")
-
-
-def _parse_quantize(quantize):
-    """Normalize the ``quantize=`` knob -> ``(normalized str | None,
-    kv_quant bool, want_w8a8 bool)``; raises naming the legal values."""
-    if quantize is None or quantize == "":
-        return None, False, False
-    parts = sorted(str(quantize).split("+"))
-    if not set(parts) <= {"kv8", "w8a8"} or len(set(parts)) != len(parts):
-        raise ValueError(
-            f"quantize={quantize!r} — expected one of {_QUANT_MODES} "
-            "(or None for full precision)")
-    norm = "+".join(p for p in ("w8a8", "kv8") if p in parts)
-    return norm, "kv8" in parts, "w8a8" in parts
-
 
 def _validate_decode_hooks(module, *, speculative: bool = False,
                            kv_quant: bool = False, sampling: bool = False,
@@ -489,7 +471,7 @@ class Request:
     SAME compiled programs as sampled traffic; ``seed`` keys the
     counter-based PRNG (``ops/sampling.py``), so a request's sampled
     stream is a pure function of ``(prompt, params, seed)`` — replayable
-    across crash re-homes, preemptions, and fused/plain decode paths.
+    across crash re-homes and preemptions.
     ``mask_builder`` (a :class:`~deepspeed_tpu.inference.constrain
     .LogitMaskBuilder`) opens the constrained-decoding lane; it needs an
     engine built with ``logit_masks=True``."""
@@ -852,7 +834,9 @@ class ServingEngine:
     :class:`~deepspeed_tpu.inference.engine.InferenceEngine`'s KV-decode
     path, with a block-paged cache (module docstring has the design).
 
-    Parameters
+    Parameters (after ``engine``, keyword options: their names, defaults
+    and ranges, and what does not combine, are ``inference/options.py``'s —
+    ``inspect.signature`` shows them from there)
     ----------
     engine:         an ``init_inference`` engine whose model carries
                     ``decode_hooks`` with ``supports_lengths`` and
@@ -918,22 +902,6 @@ class ServingEngine:
                     fixed shape of the two swap programs; default 8).
                     Larger batches amortize transfer latency, smaller
                     ones waste less padding on short chains.
-    decode_steps:   K decode iterations fused into ONE on-device
-                    ``lax.while_loop`` program (default 1 = the classic
-                    per-token host loop, bit-identical to earlier PRs).
-                    With K > 1 the per-slot eos/budget checks move
-                    on-device behind a fixed-shape ``active`` mask, the
-                    program emits a ``[slots, K]`` token buffer, and the
-                    host catches up once per window at the fence
-                    (``_fence_harvest`` — the ONLY device sync of the
-                    decode path).  Block-table writes stay inside each
-                    slot's pre-reserved span, so the paged invariants
-                    hold across the whole fused window.  Token-exact
-                    with K=1 greedy decode by construction; the fused
-                    program REPLACES the single-token decode program
-                    (compile budget unchanged).  Inert in speculative
-                    mode — the draft/verify round already amortizes the
-                    host loop over K+1 tokens per program.
     engine_mode:    ``"replicas"`` (default) or ``"dp_tp"``.  dp_tp runs
                     ONE engine over a 2-D ``("dp", "tp")`` mesh: the
                     slot/batch axis and the physical-block dim shard
@@ -988,90 +956,25 @@ class ServingEngine:
                     gauge unset unless the report call supplies one.
     """
 
-    def __init__(self, engine, *, slots: int = 8,
-                 max_seq_len: Optional[int] = None,
-                 prefill_batch: int = 4,
-                 block_size: Optional[int] = None,
-                 num_blocks: Optional[int] = None,
-                 prefill_chunk: int = 128,
-                 prefix_caching: Optional[bool] = None,
-                 decode_steps: int = 1,
-                 engine_mode: str = "replicas",
-                 sp: int = 1,
-                 resident_window_blocks: int = 0,
-                 spec_tokens: int = 0,
-                 quantize: Optional[str] = None,
-                 host_blocks: int = 0,
-                 swap_batch: int = 8,
-                 role: str = "both",
-                 nvme_blocks: int = 0,
-                 nvme_high_watermark: float = 0.9,
-                 nvme_path: Optional[str] = None,
-                 draft=None,
-                 ngram_max: int = 3,
-                 ngram_min: int = 1,
-                 shard_kv: Optional[bool] = None,
-                 sampling: bool = True,
-                 logit_masks: bool = False,
-                 debug_checks: bool = False,
-                 trace_capacity: int = trace_mod.DEFAULT_CAPACITY,
-                 slo_targets: Optional[Dict[str, Dict[str, float]]] = None,
-                 peak_flops: Optional[float] = None):
-        self.spec_tokens = int(spec_tokens)
-        if self.spec_tokens < 0:
-            raise ValueError(f"spec_tokens must be >= 0, got {spec_tokens}")
-        if self.spec_tokens and self.spec_tokens + 1 > VERIFY_T_MAX:
-            raise ValueError(
-                f"spec_tokens={spec_tokens} needs a {spec_tokens + 1}-token "
-                f"verify window but the paged verify kernel takes at most "
-                f"{VERIFY_T_MAX} — lower spec_tokens to "
-                f"{VERIFY_T_MAX - 1} or less")
-        if draft is not None and not self.spec_tokens:
-            raise ValueError(
-                "a draft model was given but spec_tokens is 0 — pass "
-                "spec_tokens=K to enable speculative decoding")
+    def __init__(self, engine, **given):
+        o = options.bind(given, "ServingEngine")
+        options.check_ranges(vars(o))
+        num_blocks, draft, shard_kv = o.num_blocks, o.draft, o.shard_kv
+        self.spec_tokens = o.spec_tokens
         # ----- on-device sampling stack (PR 20)
-        self.sampling = bool(sampling)
-        self.logit_masks = bool(logit_masks)
-        if self.logit_masks and not self.sampling:
-            raise ValueError(
-                "logit_masks=True needs the sampling stack — constrained "
-                "decoding applies the mask inside the sampler programs; "
-                "drop sampling=False")
-        self.quantize, self.kv_quant, want_w8a8 = _parse_quantize(quantize)
+        self.sampling, self.logit_masks = o.sampling, o.logit_masks
+        self.quantize, self.kv_quant, _ = options.parse_quantize(o.quantize)
         qcfg = engine._config.quant
         self.weight_quant = qcfg.type if qcfg.enabled else None
-        if want_w8a8 and self.weight_quant != "w8a8":
-            raise ValueError(
-                "quantize includes 'w8a8' but the wrapped engine carries "
-                f"{self.weight_quant or 'full-precision'} weights — build "
-                "it with config={'quant': {'enabled': True, 'type': "
-                "'w8a8'}} (init_serving(quantize=...) does this for you)")
+        hooks = getattr(engine.module, "decode_hooks", None) or {}
         #: a recurrent state a row (decode hook ``state_layers``:
         #: ``{"layers", "heads", "key_dim", "value_dim", "bodies"}`` and
         #: what else the family says of itself): leaves indexed by SLOT,
         #: beside the paged pool where there is one, with no block ids, no
         #: table and no allocator (module docstring "The state kind");
         #: None otherwise
-        self._state = (getattr(engine.module, "decode_hooks", None)
-                       or {}).get("state_layers")
+        self._state = hooks.get("state_layers")
         self._state_totals = {"resets": 0, "state_rows": 0}
-        if self._state:
-            prefix_caching = self._refuse_for_state(
-                engine, prefix_caching=prefix_caching,
-                host_blocks=host_blocks, nvme_blocks=nvme_blocks,
-                draft=draft, decode_steps=decode_steps,
-                engine_mode=engine_mode, sp=sp,
-                resident_window_blocks=resident_window_blocks)
-        hooks = _validate_decode_hooks(engine.module,
-                                       speculative=bool(self.spec_tokens),
-                                       kv_quant=self.kv_quant,
-                                       sampling=self.sampling)
-        self.engine = engine
-        self._fwd = hooks["forward_cached"]
-        #: an expert family's cached forward also returns its per-layer
-        #: routing record (``moe/routed.py RECORD``) when asked
-        self._routing = bool(hooks.get("routing_record"))
         #: learned sparse attention (decode hook ``sparse_attention``:
         #: ``{"topk"}``): the pool has a third leaf (the indexer's keys) and
         #: a row past ``topk`` keys attends ``topk`` of them; None otherwise
@@ -1094,15 +997,10 @@ class ServingEngine:
         #: :meth:`_kv_reach`'s span args, summed
         self._window_totals = {"kv_valid": 0, "kv_visible": 0}
         self._full_peak = 0        # most full-kind blocks in use after a step
-        #: width of a layer's routing record (``moe/routed.py``): a model
-        #: that holds a share of its experts also counts the absent pairs
-        self._rec_width = len(routed.RECORD_HELD) \
-            if hooks.get("experts_held") else len(routed.RECORD)
-        self._rows_absent = 0
-        if prefix_caching is None:
-            prefix_caching = not self._windows
-        self._init_cache = hooks["init_cache"]
+
+        # ----- what the options size: the context, the block, a call
         max_ctx = hooks.get("max_seq_len")
+        max_seq_len = o.max_seq_len
         if max_seq_len is None:
             max_seq_len = max_ctx or 512
         if max_ctx is not None and max_seq_len > max_ctx:
@@ -1110,21 +1008,70 @@ class ServingEngine:
                 f"max_seq_len {max_seq_len} exceeds the model context "
                 f"length {max_ctx}")
         self.max_seq_len = int(max_seq_len)
-        self.slots = int(slots)
-        if self.slots < 1:
-            raise ValueError(f"slots must be >= 1, got {slots}")
+        self.slots = o.slots
+        block_size = o.block_size
         if block_size is None:
             block_size = paged_kv.latent_block_tokens(
                 self._latent["width"],
                 jnp.dtype(engine._config.jnp_dtype).itemsize,
                 self.max_seq_len) if self._latent \
                 else paged_kv.DEFAULT_BLOCK_TOKENS
-        if block_size < 1:
-            raise ValueError(f"block_size must be >= 1, got {block_size}")
         self.block_size = int(block_size)
         # logical per-sequence capacity, rounded up to whole blocks
         self._cache_len = blocks_for(self.max_seq_len, block_size) \
             * block_size
+        # floor of 2: forward_cached dispatches per-row DECODE on T == 1,
+        # so a width-1 prefill window would be misread as a decode step
+        # (1-token prompts prefill fine in a width-2 window — the pad
+        # column writes to scratch)
+        self.prefill_chunk = max(2, min(o.prefill_chunk, self._cache_len))
+        self.prefill_batch = o.prefill_batch
+
+        # ----- the mesh's degrees, and what these options may not be
+        # built as on them for a model with these kinds of cache: ONE
+        # statement, ``inference/options.py``
+        mesh = dict(engine.mesh.shape)
+        #: ``"dp_tp"``: ONE engine over the 2-D mesh, slots and blocks in
+        #: dp groups
+        self.engine_mode = o.engine_mode
+        self.tp_degree = int(mesh.get(TP_AXIS, 1))
+        self.dp_degree = int(mesh.get(DP_AXIS, 1)) \
+            if self.engine_mode == "dp_tp" else 1
+        #: sequence-parallel (Ulysses) prefill over the mesh sp axis
+        self.sp_degree = o.sp
+        kinds = [kind for kind, hook in (
+            ("state", self._state), ("window", self._windows),
+            ("indexer", self._sparse), ("latent", self._latent)) if hook]
+        prefix_caching = o.prefix_caching
+        if prefix_caching is None:
+            # on, unless a kind of this model's cache refuses it
+            prefix_caching = not any(
+                "prefix_caching" in options.KIND_REFUSES[k] for k in kinds)
+        #: ``{kind: what it is not served with}``: ``stats()``'s
+        #: ``["kv_kinds" | "kv_latent" | "kv_state"]["refused"]``
+        self._refusals = options.check(
+            {**vars(o), "block_size": self.block_size,
+             "prefill_chunk": self.prefill_chunk,
+             "prefix_caching": prefix_caching},
+            {"tp": self.tp_degree, "dp": self.dp_degree,
+             "mesh_sp": int(mesh.get(SP_AXIS, 1)),
+             "weights": self.weight_quant},
+            kinds, getattr(engine.module, "name", "<model>"))
+        hooks = _validate_decode_hooks(engine.module,
+                                       speculative=bool(self.spec_tokens),
+                                       kv_quant=self.kv_quant,
+                                       sampling=self.sampling)
+        self.engine = engine
+        self._fwd = hooks["forward_cached"]
+        #: an expert family's cached forward also returns its per-layer
+        #: routing record (``moe/routed.py RECORD``) when asked
+        self._routing = bool(hooks.get("routing_record"))
+        #: width of a layer's routing record (``moe/routed.py``): a model
+        #: that holds a share of its experts also counts the absent pairs
+        self._rec_width = len(routed.RECORD_HELD) \
+            if hooks.get("experts_held") else len(routed.RECORD)
+        self._rows_absent = 0
+        self._init_cache = hooks["init_cache"]
         #: whether the cache tree has a paged leaf at all: a model whose
         #: EVERY layer is of the state kind has no pool, no block and no
         #: table — a request is admitted by a free slot alone, needs nothing
@@ -1136,120 +1083,9 @@ class ServingEngine:
         # block-table width (a model with no paged leaf: no column)
         self._nbper = self._cache_len // block_size if self._paged else 0
 
-        # floor of 2: forward_cached dispatches per-row DECODE on T == 1,
-        # so a width-1 prefill window would be misread as a decode step
-        # (1-token prompts prefill fine in a width-2 window — the pad
-        # column writes to scratch)
-        self.prefill_chunk = max(2, min(int(prefill_chunk),
-                                        self._cache_len))
-        self.prefill_batch = int(prefill_batch)
-        if self.prefill_batch < 1:
-            raise ValueError(
-                f"prefill_batch must be >= 1, got {prefill_batch}")
-
-        # ----- fused multi-step decode window + engine mode
-        self._K = int(decode_steps)
-        if self._K < 1:
-            raise ValueError(
-                f"decode_steps must be >= 1, got {decode_steps}")
-        self.engine_mode = str(engine_mode)
-        if self.engine_mode not in ("replicas", "dp_tp"):
-            raise ValueError(
-                f"engine_mode must be 'replicas' or 'dp_tp', got "
-                f"{engine_mode!r}")
-        dp = int(dict(engine.mesh.shape).get(DP_AXIS, 1)) \
-            if self.engine_mode == "dp_tp" else 1
-        if self.engine_mode == "dp_tp":
-            if spec_tokens or int(host_blocks) or quantize:
-                raise ValueError(
-                    "engine_mode='dp_tp' v1 excludes speculative decoding, "
-                    "the host KV tier and quantization — run those "
-                    "compositions in 'replicas' mode")
-            if prefix_caching:
-                raise ValueError(
-                    "engine_mode='dp_tp' v1 excludes prefix caching (the "
-                    "trie would share blocks across dp groups) — pass "
-                    "prefix_caching=False")
-            if self.logit_masks:
-                raise ValueError(
-                    "engine_mode='dp_tp' v1 excludes logit_masks — the "
-                    "[slots, vocab] mask operand is not dp-sharded yet; "
-                    "run constrained decoding in 'replicas' mode")
-            if self.slots % dp:
-                raise ValueError(
-                    f"engine_mode='dp_tp': slots ({self.slots}) must "
-                    f"divide evenly over the mesh dp axis ({dp})")
-        self.dp_degree = dp
-
-        # ----- sequence-parallel (Ulysses) prefill over the mesh sp axis
-        self.sp_degree = int(sp)
-        if self.sp_degree < 1:
-            raise ValueError(f"sp must be >= 1, got {sp}")
-        if self.sp_degree > 1:
-            mesh_sp = int(dict(engine.mesh.shape).get(SP_AXIS, 1))
-            if mesh_sp != self.sp_degree:
-                raise ValueError(
-                    f"sp={sp} but the engine mesh carries an sp axis of "
-                    f"size {mesh_sp} — build the engine with "
-                    f"config={{'sequence_parallel': {sp}}} "
-                    "(init_serving(sp=...) does this for you)")
-            if self.prefill_chunk % self.sp_degree:
-                raise ValueError(
-                    f"prefill_chunk ({self.prefill_chunk}) must divide "
-                    f"evenly over sp={sp} — each sp rank owns a "
-                    "prefill_chunk/sp sequence shard")
-            if self.dp_degree > 1:
-                raise ValueError(
-                    "sp > 1 composes with tp, not with engine_mode="
-                    "'dp_tp' — run sequence-parallel prefill in "
-                    "'replicas' mode")
-            if self.spec_tokens:
-                raise ValueError(
-                    "sp > 1 v1 excludes speculative decoding — the "
-                    "draft/verify programs are decode-side (T <= "
-                    f"{VERIFY_T_MAX}) where sequence parallelism has "
-                    "nothing to shard; drop spec_tokens")
-
-        # ----- resident-window context paging for 100k+-token prompts
-        self.resident_window_blocks = int(resident_window_blocks)
-        if self.resident_window_blocks < 0:
-            raise ValueError(
-                f"resident_window_blocks must be >= 0, got "
-                f"{resident_window_blocks}")
-        if self.resident_window_blocks:
-            if not int(host_blocks):
-                raise ValueError(
-                    "resident_window_blocks > 0 needs the tiered KV cache "
-                    "(host_blocks > 0): cold context blocks demote to the "
-                    "host arena when the window slides past them")
-            if self.spec_tokens:
-                raise ValueError(
-                    "resident_window_blocks > 0 v1 excludes speculative "
-                    "decoding — the verify window's span math assumes a "
-                    "dense block table; drop spec_tokens")
-            if self._K > 1:
-                raise ValueError(
-                    "resident_window_blocks > 0 v1 excludes decode_steps "
-                    "> 1 — the fused window derives the table span from a "
-                    "dense leading run, which window slides punch holes "
-                    "in; use decode_steps=1")
-            if self.dp_degree > 1:
-                raise ValueError(
-                    "resident_window_blocks > 0 v1 excludes engine_mode="
-                    "'dp_tp' — run resident-window serving in 'replicas' "
-                    "mode")
-            if self.sp_degree > 1:
-                raise ValueError(
-                    "resident_window_blocks > 0 v1 excludes sp > 1 — "
-                    "sequence-parallel prefill assumes every committed "
-                    "block is device-resident; pick one per engine")
-            min_win = blocks_for(self.prefill_chunk, self.block_size) + 1
-            if self.resident_window_blocks < min_win:
-                raise ValueError(
-                    f"resident_window_blocks ({resident_window_blocks}) "
-                    f"must be >= {min_win} (one prefill_chunk span "
-                    f"+ 1 decode block) or the window would slide out "
-                    "from under the chunk currently being prefilled")
+        #: resident-window context paging for 100k+-token prompts: blocks
+        #: of a row's sliding window that stay on the device (0: all)
+        self.resident_window_blocks = o.resident_window_blocks
         #: leading blocks pinned device-resident + attention-visible
         self._landmark_blocks = _LANDMARK_BLOCKS \
             if self.resident_window_blocks else 0
@@ -1266,34 +1102,6 @@ class ServingEngine:
         #: the widest row of any prefill call: what the window kind's ring
         #: and a row's blocks ahead of a call are sized by
         self._prefill_width = self._rungs[-1][1]
-
-        if self._windows:
-            # what a model with window layers is REFUSED, each by name, and
-            # whether this construction asked for it (module docstring)
-            tp = int(dict(engine.mesh.shape).get(TP_AXIS, 1))
-            refused = (
-                ("prefix_caching=True", prefix_caching),
-                (f"host_blocks={host_blocks}", int(host_blocks)),
-                ("quantize='kv8'", self.kv_quant),
-                ("resident_window_blocks", self.resident_window_blocks),
-                (f"spec_tokens={self.spec_tokens}", self.spec_tokens),
-                (f"decode_steps={self._K}", self._K > 1),
-                ("a draft model", draft is not None),
-                (f"a tp mesh (tp={tp})", tp > 1),
-                (f"engine_mode='dp_tp' (dp={self.dp_degree})",
-                 self.dp_degree > 1),
-                (f"sp={self.sp_degree}", self.sp_degree > 1))
-            #: ``stats()["kv_kinds"]["refused"]``
-            self._window_refusals = [what.split("=")[0].split(" (")[0]
-                                     for what, _ in refused]
-            unserved = [what for what, on in refused if on]
-            if unserved:
-                raise ValueError(
-                    f"{engine.module.name} mixes sliding-window and full "
-                    "attention layers (decode hook window_layers): its "
-                    "pool holds a second kind of block under a ring table "
-                    "of its own, which is not served with "
-                    + ", ".join(unserved))
         if num_blocks is None:
             num_blocks = self.dp_degree + self.slots * self._nbper
         if not self._paged:
@@ -1341,116 +1149,23 @@ class ServingEngine:
                     f"+ 1 scratch = {min_need})")
             self._alloc = BlockAllocator(num_blocks)
             self._scratch_blocks = None
+        #: whether the block trie is on
+        self.prefix_caching = bool(prefix_caching)
         self._prefix = PrefixCache(self.block_size) \
             if prefix_caching else None
-        self.host_blocks = int(host_blocks)
-        if self.host_blocks < 0:
-            raise ValueError(f"host_blocks must be >= 0, got {host_blocks}")
-        self.swap_batch = int(swap_batch)
-        if self.host_blocks and self.swap_batch < 1:
-            raise ValueError(f"swap_batch must be >= 1, got {swap_batch}")
-        if self.host_blocks and self.swap_batch > self.host_blocks:
-            raise ValueError(
-                f"swap_batch={swap_batch} exceeds host_blocks="
-                f"{host_blocks} — one demotion batch could never fit the "
-                "host arena; lower swap_batch or grow host_blocks")
-        if self.host_blocks and self._prefix is None:
-            raise ValueError(
-                "the tiered KV cache (host_blocks > 0) needs "
-                "prefix_caching=True — promoted chains re-register in the "
-                "prefix trie (drop prefix_caching=False, or host_blocks)")
-
-        # ----- disaggregated serving role + NVMe third tier
-        self.role = str(role)
-        if self.role not in ("prefill", "decode", "both"):
-            raise ValueError(
-                f"role must be 'prefill', 'decode' or 'both', got {role!r}")
-        if self.role != "both" and not self.host_blocks:
-            raise ValueError(
-                f"role={self.role!r} needs the tiered KV cache "
-                "(host_blocks > 0): the prefill→decode handoff travels as "
-                "a host-tier chain export/import — pass host_blocks, or "
-                "role='both'")
-        self.nvme_blocks = int(nvme_blocks)
-        if self.nvme_blocks < 0:
-            raise ValueError(
-                f"nvme_blocks must be >= 0, got {nvme_blocks}")
-        if self.nvme_blocks and not self.host_blocks:
-            raise ValueError(
-                f"nvme_blocks={nvme_blocks} needs the host tier above it "
-                "(host_blocks > 0) — NVMe entries spill from and promote "
-                "through the host arena, never the device pool directly")
-        self.nvme_high_watermark = float(nvme_high_watermark)
-        if not (0.0 < self.nvme_high_watermark <= 1.0):
-            raise ValueError(
-                f"nvme_high_watermark must be in (0, 1], got "
-                f"{nvme_high_watermark}")
-        if self.nvme_blocks and \
-                self.swap_batch > int(self.nvme_high_watermark
-                                      * self.host_blocks):
-            raise ValueError(
-                f"swap_batch={swap_batch} exceeds the host-arena watermark "
-                f"budget int({nvme_high_watermark} * {host_blocks}) — one "
-                "promotion batch would immediately re-spill its own head; "
-                "lower swap_batch or raise nvme_high_watermark/host_blocks")
-        self._nvme_path_arg = nvme_path
+        # ----- the host-DRAM tier, a replica's role in a disaggregated
+        # fleet, the NVMe tier below the host's
+        self.host_blocks, self.swap_batch = o.host_blocks, o.swap_batch
+        self.role = o.role
+        self.nvme_blocks = o.nvme_blocks
+        self.nvme_high_watermark = o.nvme_high_watermark
+        self._nvme_path_arg = o.nvme_path
 
         # ----- tensor parallelism: one pool, committed on the engine mesh so
         # the very first step sees the same placement as every later one —
         # sharded over the KV-HEAD dim (``P(None, None, "tp")`` on the
         # stacked [L, NB, HKV, bs, hd] buffer) when the mesh carries a tp
         # axis the head count divides, else replicated (module docstring)
-        self.tp_degree = int(dict(engine.mesh.shape).get(TP_AXIS, 1))
-        if self._sparse:
-            unserved = [what for what, on in (
-                (f"a tp mesh (tp={self.tp_degree})", self.tp_degree > 1),
-                (f"engine_mode='dp_tp' (dp={self.dp_degree})",
-                 self.dp_degree > 1),
-                (f"sp={self.sp_degree}", self.sp_degree > 1),
-                ("quantize='kv8'", self.kv_quant),
-                ("resident_window_blocks", self.resident_window_blocks),
-                ("a draft model", draft is not None)) if on]
-            if unserved:
-                raise ValueError(
-                    f"{engine.module.name} selects its keys with a learned "
-                    "indexer (decode hook sparse_attention), which is served "
-                    "on one shard over a float pool; not with "
-                    + ", ".join(unserved))
-        if self._latent:
-            # what a latent model is REFUSED, each by name with its reason,
-            # and whether this construction asked for it
-            refused = (
-                ("quantize='kv8'", self.kv_quant,
-                 "a quantized latent is a different model: the value is a "
-                 "projection of the same vector the key is"),
-                (f"quantized weights ({self.weight_quant})", self.weight_quant,
-                 "the absorbed read takes kv_b_w as the two up-projections "
-                 "it holds, not as an int8 record"),
-                (f"a tp mesh (tp={self.tp_degree})", self.tp_degree > 1,
-                 "the latent has no head axis to shard: it is replicated "
-                 "under tp by design (head-sharded up-projections around a "
-                 "replicated pool), a path that is not built"),
-                (f"engine_mode='dp_tp' (dp={self.dp_degree})",
-                 self.dp_degree > 1, "the latent write and read run on "
-                 "one shard"),
-                (f"sp={self.sp_degree}", self.sp_degree > 1,
-                 "sequence-parallel prefill all-to-alls heads of K and V"),
-                ("a draft model", draft is not None,
-                 "the draft's pool would be a second kind beside it"),
-                ("resident_window_blocks", self.resident_window_blocks,
-                 "the latent kernel carries no resident-window mask"),
-                (f"decode_steps={self._K}", self._K > 1,
-                 "the fused window is not tested over the latent kind"))
-            #: ``stats()["kv_latent"]["refused"]``
-            self._latent_refusals = [what.split("=")[0].split(" (")[0]
-                                     for what, _, _ in refused]
-            unserved = [f"{what} ({why})" for what, on, why in refused if on]
-            if unserved:
-                raise ValueError(
-                    f"{engine.module.name} caches a latent a token (decode "
-                    "hook latent_attention): its pool is one leaf without "
-                    "a head axis, read absorbed, which is not served with "
-                    + "; ".join(unserved))
         if self.kv_quant:
             # int8 pool records {qp, ps} (ops/paged_kv): codes + per-block
             # scale table, built from the float pool's ABSTRACT shapes
@@ -1525,8 +1240,8 @@ class ServingEngine:
                     int(nh) % hkv or int(nh) % sp_tp
                     or (int(nh) // sp_tp) % self.sp_degree):
                 raise ValueError(
-                    f"sp={sp}: the {nh} query heads must shard evenly "
-                    f"over tp={sp_tp} then sp={sp} (and divide the "
+                    f"sp={o.sp}: the {nh} query heads must shard evenly "
+                    f"over tp={sp_tp} then sp={o.sp} (and divide the "
                     f"{hkv} KV heads) for the Ulysses all-to-all — "
                     "lower sp or pick a head-count-compatible mesh")
         rep = NamedSharding(engine.mesh, P())
@@ -1603,7 +1318,7 @@ class ServingEngine:
         # verify replaces decode), 3 with a draft model (fused prefill +
         # rollout + verify).  debug_checks additionally raises at trace
         # time and audits the paged host state every scheduler iteration.
-        self.debug_checks = bool(debug_checks)
+        self.debug_checks = o.debug_checks
         self.compile_budget = 3 if self.spec_tokens and draft is not None \
             else 2
         # one prefill program a rung of the ladder, all built (and run once
@@ -1641,8 +1356,7 @@ class ServingEngine:
         self._dcache = None                # draft paged pool (shares tables)
         self._dcache_sharded = False
         self._proposer = None              # host-side n-gram fallback
-        self.ngram_max = int(ngram_max)    # kept for resolved_config()
-        self.ngram_min = int(ngram_min)
+        self.ngram_max, self.ngram_min = o.ngram_max, o.ngram_min
         if self.spec_tokens:
             if draft is not None:
                 from .engine import InferenceEngine
@@ -1703,8 +1417,8 @@ class ServingEngine:
                     mk_dpool, dsharding, pool="draft", blocks=num_blocks)
             else:
                 self._proposer = NGramProposer(self.spec_tokens,
-                                               max_n=ngram_max,
-                                               min_n=ngram_min)
+                                               max_n=self.ngram_max,
+                                               min_n=self.ngram_min)
 
         # ----- tiered KV: the host-DRAM arena below the device pool
         # (module docstring).  Built from the live swap tree's per-block
@@ -1771,14 +1485,6 @@ class ServingEngine:
             "serving_iterations_total", "scheduler iterations run")
         self._c_decode_steps = m.counter(
             "serving_decode_steps_total", "single-token decode steps")
-        self._c_fused_iterations = m.counter(
-            "serving_fused_iterations_total",
-            "device-side decode iterations executed inside fused "
-            "multi-step windows (0 when decode_steps == 1)")
-        self._c_host_fence_waits = m.counter(
-            "serving_host_fence_waits_total",
-            "host blocks on the device fence — one per fused decode "
-            "window, the ONLY sync of the fused decode path")
         self._c_prefill_calls = m.counter(
             "serving_prefill_calls_total", "prefill program invocations")
         self._c_prefill_shapes = {
@@ -1945,8 +1651,8 @@ class ServingEngine:
         # SLO attainment accounting (telemetry/slo.py): every finished
         # request lands in its class's TTFT/TPOT histograms + attainment
         # counters on THIS registry; slo_report() is the per-class view
-        self._slo = SLOTracker(m, slo_targets)
-        self.peak_flops = peak_flops
+        self._slo = SLOTracker(m, o.slo_targets)
+        self.peak_flops = o.peak_flops
         self._flops_profiler = None        # built lazily by flops_report()
         #: raw (un-sentry-wrapped) program bodies + shape meta, captured
         #: at build time for the FLOPs profiler — lowering a RAW body for
@@ -1962,7 +1668,7 @@ class ServingEngine:
         #: the matching flow-finish so the merged fleet trace draws the
         #: route -> admit arrow (telemetry/trace.py flow events)
         self._flow_ids: Dict[Any, int] = {}
-        self.timeline = TraceTimeline(capacity=trace_capacity)
+        self.timeline = TraceTimeline(capacity=o.trace_capacity)
         # readable after this engine is gone (telemetry/trace.py kept())
         trace_mod.keep("serve", self.timeline)
         #: the argument dict of the ``step`` span being recorded: what
@@ -2051,7 +1757,6 @@ class ServingEngine:
             + (f", speculative K={self.spec_tokens} "
                f"({'draft ' + self._draft.module.name if self._draft else 'n-gram'})"
                if self.spec_tokens else "")
-            + (f", fused decode K={self._K}" if self._K > 1 else "")
             + (f", engine_mode=dp_tp (dp={self.dp_degree} groups)"
                if self.engine_mode == "dp_tp" else "")
             + (f", kv sharded over tp={self.tp_degree} "
@@ -2318,55 +2023,6 @@ class ServingEngine:
         if not isinstance(cache, dict):
             return cache
         return {k: v for k, v in cache.items() if k not in STATE_LEAVES}
-
-    def _refuse_for_state(self, engine, *, prefix_caching, host_blocks,
-                          nvme_blocks, draft, decode_steps, engine_mode, sp,
-                          resident_window_blocks):
-        """What a model with a recurrent state a slot is REFUSED, each by
-        name with its reason, and whether this construction asked for it
-        (``stats()["kv_state"]["refused"]``).  -> ``prefix_caching`` as the
-        engine takes it (``None`` turns it off for such a model)."""
-        mesh = dict(engine.mesh.shape)
-        tp, dp = int(mesh.get(TP_AXIS, 1)), int(mesh.get(DP_AXIS, 1)) \
-            if engine_mode == "dp_tp" else 1
-        qcfg = engine._config.quant
-        refused = (
-            ("prefix_caching=True", prefix_caching,
-             "a state can be shared only where it was snapshotted, not at "
-             "any block boundary"),
-            (f"host_blocks={host_blocks}", int(host_blocks),
-             "the tiers move blocks; a slot's state has none"),
-            (f"nvme_blocks={nvme_blocks}", int(nvme_blocks),
-             "the tiers move blocks; a slot's state has none"),
-            (f"spec_tokens={self.spec_tokens}", self.spec_tokens,
-             "a rejected draft token has already moved the state: "
-             "\"rollback is free\" holds for keys and values only"),
-            ("a draft model", draft is not None,
-             "a rejected draft token has already moved the state"),
-            (f"decode_steps={decode_steps}", int(decode_steps) > 1,
-             "a frozen row of the fused window would advance its state"),
-            ("quantize='kv8'", self.kv_quant,
-             "the state is float32 by construction"),
-            (f"quantized weights ({qcfg.type if qcfg.enabled else None})",
-             qcfg.enabled, "the state kind's leaves (decays, convolution "
-             "taps, gates) have no int8 record"),
-            ("resident_window_blocks", int(resident_window_blocks),
-             "a window slides over blocks; the state has none"),
-            (f"a tp mesh (tp={tp})", tp > 1,
-             "the state's heads are not sharded: one shard"),
-            (f"engine_mode='dp_tp' (dp={dp})", dp > 1,
-             "the state's rows are not sharded: one shard"),
-            (f"sp={sp}", int(sp) > 1,
-             "the chunked recurrence carries its state along the sequence"))
-        self._state_refusals = [what.split("=")[0].split(" (")[0]
-                                for what, _, _ in refused]
-        unserved = [f"{what} ({why})" for what, on, why in refused if on]
-        if unserved:
-            raise ValueError(
-                f"{engine.module.name} keeps a recurrent state a slot "
-                "(decode hook state_layers), which "
-                "is not served with " + "; ".join(unserved))
-        return False
 
     def _donate(self):
         # donating the pool avoids a full cache copy per step; XLA:CPU
@@ -2702,7 +2358,7 @@ class ServingEngine:
     def _get_decode_fn(self):
         if self._decode_fn is None:
             fwd, prepare = self._forward, self.engine._prepare
-            K, constrain = self._K, self._constrain_pool
+            constrain = self._constrain_pool
             next_tokens, with_record = self._next_tokens, self._with_record
 
             def step_core(params, cache, tokens, lengths, block_tables,
@@ -2718,69 +2374,6 @@ class ServingEngine:
                 return with_record(next_tokens(logits, samp), rec), \
                     constrain(cache)
 
-            def fused_core(params, cache, tokens, lengths, block_tables,
-                           active, budgets, eos_ids, samp):
-                """K decode steps in ONE ``lax.while_loop``: per-slot
-                eos/budget checks live on-device behind the fixed-shape
-                ``active`` mask; ``out[slot, i]`` is the i-th token the
-                window committed for the slot, ``-1`` past its end (eos
-                fired or per-slot budget spent).  Frozen rows keep feeding
-                their last token at a frozen length — an idempotent
-                rewrite of already-written KV, never a new position — so
-                the loop stays fixed-shape with no gather/compaction.
-                Sampled rows draw step ``i`` with the counter key
-                ``counts + i`` — the same keys the K=1 path uses, so
-                fused and plain sampled streams are token-identical.
-                An expert family's routing record is summed over the
-                window's iterations (its largest group: the maximum)."""
-                p = prepare(params)
-                out0 = jnp.full((tokens.shape[0], K), -1, jnp.int32)
-                rec0 = jnp.zeros((int(self._pool_shape[0]),
-                                  self._rec_width), jnp.int32) \
-                    if self._routing else None
-                if self._sparse:
-                    # the selections' counts, beside the routing record
-                    rec0 = (rec0, jnp.zeros(len(self._sparse_totals),
-                                            jnp.int32))
-
-                def cond(state):
-                    i, _, _, _, act, _, _ = state
-                    return (i < K) & jnp.any(act)
-
-                def body(state):
-                    i, toks, lens, cache, act, out, rec = state
-                    logits, cache, r = fwd(p, toks[:, None], cache, 0,
-                                           lengths=lens,
-                                           block_tables=block_tables)
-                    self._note_sparse("decode")
-                    self._note_sampler("decode", samp)
-                    if r is not None:
-                        (rec, *seen), (r, *counts) = (
-                            x if isinstance(x, tuple) else (x,)
-                            for x in (rec, r))
-                        rec = jnp.concatenate(
-                            [rec[:, :2] + r[:, :2],
-                             jnp.maximum(rec[:, 2:], r[:, 2:])], axis=1)
-                        if counts:
-                            rec = (rec, seen[0] + counts[0])
-                    cache = constrain(cache)
-                    if samp is None:
-                        nxt = next_tokens(logits, None)
-                    else:
-                        temps, topks, topps, seeds, counts, masks = samp
-                        nxt = next_tokens(logits, (temps, topks, topps,
-                                                   seeds, counts + i, masks))
-                    out = out.at[:, i].set(jnp.where(act, nxt, -1))
-                    lens = lens + act.astype(lens.dtype)
-                    toks = jnp.where(act, nxt, toks)
-                    act = act & (nxt != eos_ids) & (i + 1 < budgets)
-                    return (i + 1, toks, lens, cache, act, out, rec)
-
-                _, _, _, cache, _, out, rec = jax.lax.while_loop(
-                    cond, body, (jnp.int32(0), tokens, lengths, cache,
-                                 active, out0, rec0))
-                return with_record(out, rec), cache
-
             # *samp is the engine's sampling operand tail — () for
             # sampling=False (the exact legacy programs, bit-path
             # identical), (temps, topks, topps, seeds, counts[, masks])
@@ -2793,15 +2386,7 @@ class ServingEngine:
                 return step_core(params, cache, tokens, lengths,
                                  block_tables, pack(samp))
 
-            def decode_fused(params, cache, tokens, lengths, block_tables,
-                             active, budgets, eos_ids, *samp):
-                return fused_core(params, cache, tokens, lengths,
-                                  block_tables, active, budgets, eos_ids,
-                                  pack(samp))
-
-            # the fused program REPLACES the per-token decode program —
-            # same sentry entry, same compile budget
-            body_fn = decode_step if K == 1 else decode_fused
+            body_fn = decode_step
             if self.resident_window_blocks:
                 # the windowed program also REPLACES plain decode (same
                 # sentry entry, +0 budget): window_start rides as a
@@ -2822,39 +2407,31 @@ class ServingEngine:
 
                 body_fn = decode_windowed
             self._program_bodies["decode"] = body_fn
-            tail = ("window_start",) if self.resident_window_blocks else \
-                ("active", "budgets", "eos_ids") if K > 1 else ()
             spec = self._operand_spec(
-                self.slots, {"tokens": None, "lengths": None}, tail)
-            if K > 1:
-                spec["active"] = jax.ShapeDtypeStruct((self.slots,), bool)
-            call, n_dev = body_fn, 2
-            if K == 1:
-                slots, pin = self.slots, self._pin_tokens
+                self.slots, {"tokens": None, "lengths": None},
+                ("window_start",) if self.resident_window_blocks else ())
+            slots, pin = self.slots, self._pin_tokens
 
-                @functools.wraps(body_fn)  # the program keeps its name
-                def decode_ahead(params, cache, devtok, tokens, *rest):
-                    """``body_fn`` on the device-resident token vector: a
-                    row whose entry of ``tokens`` says ``TOKEN_ON_DEVICE``
-                    is fed its entry of ``devtok``, the token the call
-                    before made; the tokens this call makes are the new
-                    vector, beside the flat array the host copies back."""
-                    flat, cache = body_fn(
-                        params, cache,
-                        jnp.where(tokens == TOKEN_ON_DEVICE, devtok, tokens),
-                        *rest)
-                    return flat, cache, pin(flat[:slots])
+            @functools.wraps(body_fn)      # the program keeps its name
+            def decode_ahead(params, cache, devtok, tokens, *rest):
+                """``body_fn`` on the device-resident token vector: a
+                row whose entry of ``tokens`` says ``TOKEN_ON_DEVICE``
+                is fed its entry of ``devtok``, the token the call
+                before made; the tokens this call makes are the new
+                vector, beside the flat array the host copies back."""
+                flat, cache = body_fn(
+                    params, cache,
+                    jnp.where(tokens == TOKEN_ON_DEVICE, devtok, tokens),
+                    *rest)
+                return flat, cache, pin(flat[:slots])
 
-                call, n_dev = decode_ahead, 3
             self._decode_fn = self._first_call(jax.jit(
                 self.sentry.wrap(
-                    self._packed("decode", call, spec,
-                                 device_operands=n_dev), "decode"),
+                    self._packed("decode", decode_ahead, spec,
+                                 device_operands=3), "decode"),
                 donate_argnums=self._donate()),
                 "decode", vars(self), "_decode_fn", slots=self.slots)
-            self.compiled_programs.append(
-                ("decode", self.slots) if K == 1
-                else ("decode", self.slots, K))
+            self.compiled_programs.append(("decode", self.slots))
         return self._decode_fn
 
     def _get_prefill_fn(self, rung=None):
@@ -3858,7 +3435,7 @@ class ServingEngine:
     def _slot_group(self, slot: int) -> int:
         """dp group owning ``slot`` (always 0 outside dp_tp mode): slot
         spans are contiguous, matching the shard_map ``P("dp")`` row
-        chunking of the fused decode program."""
+        chunking of the decode program."""
         return slot // (self.slots // self.dp_degree) \
             if self.dp_degree > 1 else 0
 
@@ -4503,8 +4080,6 @@ class ServingEngine:
                 self._refresh_masks()
             if self.spec_tokens:
                 phase["slots"] = self._run_spec_decode(params)
-            elif self._K > 1:
-                phase["slots"] = self._run_fused_decode(params)
             else:
                 phase["slots"] = self._run_plain_decode(params)
             if self._evicted_blocks:
@@ -4597,10 +4172,10 @@ class ServingEngine:
     @contextlib.contextmanager
     def _in_flight(self, name: str, **args):
         """An in-flight span (:meth:`step`) around a call that is made and
-        harvested in one stay in the runtime — the speculative and fused
-        runners, which keep their own fence; yields the span's argument
-        dict.  The runners make it inside their ``upload`` segment and
-        enter it next, so nothing but two clock reads lies between."""
+        harvested in one stay in the runtime — the speculative runner,
+        which keeps its own fence; yields the span's argument
+        dict.  The runner makes it inside its ``upload`` segment and
+        enters it next, so nothing but two clock reads lies between."""
         self._enter_runtime(name)
         if self.timeline.enabled:
             self._seg_args.append(args)
@@ -4618,8 +4193,6 @@ class ServingEngine:
             return "debug_checks"          # the audit reads committed state
         if self.spec_tokens:
             return "speculative"           # a round plans on its tokens
-        if self._K > 1:
-            return "fused"
         if self._host is not None or self.resident_window_blocks:
             return "kv_tier"               # demotions key blocks by tokens
         if self.role == "prefill":
@@ -5234,126 +4807,6 @@ class ServingEngine:
             else:
                 self._tokens[slot] = tok
 
-    def _fence_harvest(self, *arrays):
-        """The fused decode path's ONE host<->device synchronization point
-        (the fence): dispatch above it is fully asynchronous, the scheduler
-        blocks here exactly once per fused window, and every host-side
-        scalar read below it comes out of the numpy buffers this returns.
-        graft-lint GL012 sanctions per-token host harvesting only inside
-        this helper — anywhere else in a scheduler loop body it flags."""
-        self._c_host_fence_waits.inc()
-        arrays = jax.block_until_ready(arrays)
-        return tuple(np.asarray(a) for a in arrays)
-
-    def _run_fused_decode(self, params):
-        """``decode_steps`` decode iterations in ONE on-device program
-        (the tentpole fused window): per-slot eos/budget checks run on
-        device, and the host bookkeeping — lengths, token emission, SLO
-        stamps, slot finishes — catches up in one batch at the fence by
-        replaying the committed ``out[slot, i]`` tokens through the exact
-        K=1 commit sequence.  Block tables are pre-reserved for the whole
-        window before dispatch (``_ensure_blocks`` up to each slot's
-        remaining-token budget), so every in-window KV write lands inside
-        the slot's held span and the paged invariants hold at the next
-        iteration boundary exactly as in single-step mode."""
-        K = self._K
-        active = self._active
-        seg, phase = self.timeline.segment, self._phase
-        with seg("step.decode.plan", phase):
-            dec = sorted(
-                (s for s, st in active.items() if st.phase == "decode"),
-                key=lambda s: active[s].admit_seq)
-            want: Dict[int, int] = {}
-            for slot in dec:
-                if slot in active and active[slot].phase == "decode":
-                    st = active[slot]
-                    ln = int(self._lengths[slot])
-                    w = max(1, min(K, st.req.max_new_tokens - st.gen_count))
-                    if self._masks is not None \
-                            and st.req.mask_builder is not None:
-                        # constrained slots advance ONE token per dispatch:
-                        # the mask row is a host-built function of every
-                        # token emitted so far, and the host can only
-                        # refresh it between dispatches
-                        w = 1
-                    want[slot] = w
-                    self._kv(self._ensure_blocks, slot,
-                             min(ln + w, self._cache_len))
-            dec = sorted(s for s, st in active.items()
-                         if st.phase == "decode")
-            if not dec:
-                return 0
-            budgets = np.zeros(self.slots, np.int32)
-            eos_ids = np.full(self.slots, -1, np.int32)
-            actv = np.zeros(self.slots, bool)
-            for slot in dec:
-                st = active[slot]
-                ln = int(self._lengths[slot])
-                # the device budget is additionally clamped to the held
-                # span — a window can never write past the blocks it
-                # reserved
-                span = int(np.count_nonzero(self._tables[slot])) \
-                    * self.block_size
-                b = min(want.get(slot, K), max(span - ln, 0))
-                if b < 1:
-                    continue
-                budgets[slot] = b
-                actv[slot] = True
-                if st.eos is not None:
-                    eos_ids[slot] = int(st.eos)
-            dec = [s for s in dec if actv[s]]
-            if not dec:
-                return 0
-            bt = np.zeros_like(self._tables)
-            bt[dec] = self._tables[dec]
-            counts = self._decode_counts()
-            decode_fn = self._get_decode_fn()
-            span_kw = self._sampler_rows(dec)
-        with seg("step.decode.upload", phase):
-            host, puts = self._host_operands(
-                "decode", self._tokens, self._lengths, self._bt(bt), actv,
-                budgets, eos_ids, *self._samp_args(counts))
-            args = (params, self._cache, *host)
-            flight = self._in_flight("decode", slots=len(dec), fused=K,
-                                     **puts, **span_kw)
-        with flight as span_args:
-            with seg("decode.enqueue", span_args), self._decode_ctx():
-                out, self._cache = decode_fn(*args)
-            with seg("decode.wait", span_args):
-                out, = self._fence_harvest(out)
-                out = self._split_record(out, (self.slots, K), span_args)
-        # ----- the fence catch-up: replay each slot's committed window
-        # tokens through the exact K=1 commit sequence (emission order,
-        # finish conditions, TTFT stamps — token- and event-identical)
-        with seg("step.decode.commit", phase):
-            del args, host                 # released on the commit's account
-            trips = 0
-            for slot in dec:
-                st = active[slot]
-                emitted = 0
-                for i in range(K):
-                    tok = int(out[slot, i])
-                    if tok < 0:
-                        break
-                    emitted += 1
-                    self._lengths[slot] += 1
-                    st.out.append(tok)
-                    self._emit_tokens(st, (tok,))
-                    self._mark_first(st)
-                    if (st.eos is not None and tok == st.eos) \
-                            or st.gen_count >= st.req.max_new_tokens:
-                        self._finish_slot(slot)
-                        break
-                    self._tokens[slot] = tok
-                trips = max(trips, emitted)
-            # decode_steps counts executed device ITERATIONS (the
-            # while_loop trip count = the deepest slot's window), keeping
-            # per-iteration FLOPs billing identical to single-step mode
-            self._c_decode_steps.inc(trips)
-            self._c_fused_iterations.inc(trips)
-            del out                        # and the call's results
-        return len(dec)
-
     def _run_spec_decode(self, params):
         """One speculative draft–verify round over every decode-phase slot.
 
@@ -5794,41 +5247,7 @@ class ServingEngine:
         a draft-model speculative engine round-trips to the n-gram
         proposer at the same ``spec_tokens``.
         """
-        return {
-            "slots": self.slots,
-            "max_seq_len": self.max_seq_len,
-            "block_size": self.block_size,
-            "num_blocks": int(self._alloc.num_blocks),
-            "prefill_chunk": int(self.prefill_chunk),
-            "decode_steps": self._K,
-            "engine_mode": self.engine_mode,
-            "sp": self.sp_degree,
-            "resident_window_blocks": self.resident_window_blocks,
-            "prefill_batch": self.prefill_batch,
-            "prefix_caching": self._prefix is not None,
-            "spec_tokens": self.spec_tokens,
-            "ngram_max": self.ngram_max,
-            "ngram_min": self.ngram_min,
-            "sampling": self.sampling,
-            "logit_masks": self.logit_masks,
-            "quantize": self.quantize,
-            "host_blocks": self.host_blocks,
-            "swap_batch": self.swap_batch,
-            "role": self.role,
-            "nvme_blocks": self.nvme_blocks,
-            "nvme_high_watermark": self.nvme_high_watermark,
-            # the user-passed path (None = auto tempfile): a rebuilt
-            # engine mints its OWN spill file rather than contending for
-            # this engine's — behaviorally identical, never shared
-            "nvme_path": self._nvme_path_arg,
-            "shard_kv": bool(self.kv_sharded),
-            "topology": self.tp_degree,
-            "debug_checks": self.debug_checks,
-            "trace_capacity": int(self.timeline.capacity),
-            "slo_targets": {cls: dict(t)
-                            for cls, t in self._slo.targets.items()},
-            "peak_flops": self.peak_flops,
-        }
+        return {**options.resolved(self), "topology": self.tp_degree}
 
     def _kv_footprint(self) -> Dict[str, Any]:
         """KV memory accounting: pool shape, total logical bytes (quant-
@@ -5888,7 +5307,7 @@ class ServingEngine:
                               "slots": self.slots,
                               "bytes": self._state_bytes()},
                     "expert_rows_absent": self._rows_absent,
-                    "refused": list(self._state_refusals)}
+                    "refused": list(self._refusals["state"])}
         layers = self._windows["layers"]
         return {
             "window": self._windows["window"],
@@ -5899,7 +5318,7 @@ class ServingEngine:
                         "released": self._ring.released},
             **self._window_totals,
             "expert_rows_absent": self._rows_absent,
-            "refused": list(self._window_refusals)}
+            "refused": list(self._refusals["window"])}
 
     def _state_bytes(self) -> int:
         """Bytes of the state kind's leaves, all slots."""
@@ -5917,7 +5336,7 @@ class ServingEngine:
                 self._state["bodies"]: dict(
                     self._program_meta.get("state_bodies", {})),
                 **self._state_totals,
-                "refused": list(self._state_refusals)}
+                "refused": list(self._refusals["state"])}
 
     def _kv_latent(self) -> Dict[str, Any]:
         """``stats()["kv_latent"]`` (a model with latent attention)."""
@@ -5941,7 +5360,7 @@ class ServingEngine:
                 "prefill": {self._rung_name(rung): self._latent_walk(
                     rung[1])[1] for rung in self._rungs}},
             **self._latent_totals,
-            "refused": list(self._latent_refusals)}
+            "refused": list(self._refusals["latent"])}
 
     def _latency_stats(self) -> Dict[str, Any]:
         """TTFT/TPOT percentiles over every finished request (cumulative
@@ -5990,8 +5409,6 @@ class ServingEngine:
             "moe_expert_rows": int(self._c_moe_rows.value),
             "moe_experts_touched": int(self._c_moe_touched.value),
             "engine_mode": self.engine_mode,
-            "fused_iterations": int(self._c_fused_iterations.value),
-            "host_fence_waits": int(self._c_host_fence_waits.value),
             "prefill_calls": self.prefill_calls,
             # the ladder (every rung is a built program): calls by their
             # rows x width, and the real prompt tokens of all calls over
@@ -6131,3 +5548,8 @@ class ServingEngine:
         st.update(self._kv_footprint())
         st.update(self._latency_stats())
         return st
+
+
+ServingEngine.__init__.__signature__ = options.signature(
+    inspect.Parameter("self", inspect.Parameter.POSITIONAL_OR_KEYWORD),
+    inspect.Parameter("engine", inspect.Parameter.POSITIONAL_OR_KEYWORD))

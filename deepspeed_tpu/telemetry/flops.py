@@ -225,11 +225,8 @@ class ServingFlopsProfiler:
             return tuple(list(srv._operand_spec(rows, {}).values())[1:])
 
         if family == "decode":
-            args = (params, cache, i32(slots), i32(slots), tables(slots))
-            if getattr(srv, "_K", 1) > 1:    # fused multi-step decode adds
-                args += (jax.ShapeDtypeStruct((slots,), jnp.bool_),
-                         i32(slots), i32(slots))   # active, budgets, eos_ids
-            return args + samp(slots)
+            return (params, cache, i32(slots), i32(slots),
+                    tables(slots)) + samp(slots)
         if family == "prefill":
             j, width = rung or (srv.prefill_batch, srv.prefill_chunk)
             if srv._draft is not None:       # fused target+draft prefill
@@ -284,11 +281,6 @@ class ServingFlopsProfiler:
     def _cost_analysis_flops(self, family: str) -> Optional[float]:
         """``Lowered.cost_analysis()`` of the raw body — lowering only,
         never a compile; ``None`` when the backend reports nothing."""
-        if family == "decode" and getattr(self.srv, "_K", 1) > 1:
-            # fused multi-step decode: the lowered body holds the whole
-            # while_loop but calls are billed per iteration — the backend
-            # cost would be off by up to K.  Use the analytic estimate.
-            return None
         try:
             lowered = self.lower(family)
             if lowered is None:

@@ -203,7 +203,7 @@ def test_an_engine_with_no_paged_leaf_admits_by_slot(tiny, served):
                            max_new_tokens=8))
 
 
-#: ``_refuse_for_state``'s twelve refusals, as they stand for a model with no
+#: ``options.KIND_REFUSES["state"]``'s eleven refusals, as they stand for a model with no
 #: paged pool at all: (refusal, options, a word of its why)
 REFUSED = [
     ("prefix_caching", dict(prefix_caching=True), "snapshotted"),
@@ -212,7 +212,6 @@ REFUSED = [
      "tiers"),
     ("spec_tokens", dict(spec_tokens=2), "rollback is free"),
     ("a draft model", dict(spec_tokens=2, draft="self"), "already moved"),
-    ("decode_steps", dict(decode_steps=4), "frozen row"),
     ("quantize", dict(quantize="kv8"), "float32 by construction"),
     ("quantized weights", dict(quant="int8"), "the state kind's leaves"),
     ("resident_window_blocks", dict(resident_window_blocks=4, host_blocks=8,
@@ -263,7 +262,7 @@ def test_stats_name_the_state_kind_and_no_other(tiny, served):
     assert state["resets"] == len(reqs)          # one a request entering
     assert state["power"] == {"prefill": "power_chunk_plain",
                               "decode": "power_step_plain"}
-    assert len(state["refused"]) == 12
+    assert len(state["refused"]) == 11
     assert st["kv_kinds"] == {
         "state": {"layers": 2, "slots": 3, "bytes": state["bytes"]},
         "expert_rows_absent": 0, "refused": state["refused"]}

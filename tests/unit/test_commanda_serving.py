@@ -229,7 +229,6 @@ def test_held_none_and_softmax_are_the_program_they_were():
     (dict(host_blocks=8, swap_batch=2), "host_blocks=8"),
     (dict(quantize="kv8"), "kv8"),
     (dict(spec_tokens=3), "spec_tokens=3"),
-    (dict(decode_steps=4), "decode_steps=4"),
 ])
 def test_what_window_layers_are_not_served_with_is_refused_by_name(
         model, how, named):

@@ -198,7 +198,7 @@ def test_lookahead_on_and_off_and_a_tight_pool_agree(tiny, served):
         np.testing.assert_array_equal(got[uid], want[uid])
 
 
-#: ``_refuse_for_state``'s twelve refusals, as they stand for a model whose
+#: ``options.KIND_REFUSES["state"]``'s eleven refusals, as they stand for a model whose
 #: state lies beside the ``full`` kind: (refusal, options, a word of its why)
 REFUSED = [
     ("prefix_caching", dict(prefix_caching=True), "snapshotted"),
@@ -207,7 +207,6 @@ REFUSED = [
      "tiers"),
     ("spec_tokens", dict(spec_tokens=2), "rollback is free"),
     ("a draft model", dict(spec_tokens=2, draft="self"), "already moved"),
-    ("decode_steps", dict(decode_steps=4), "frozen row"),
     ("quantize", dict(quantize="kv8"), "float32 by construction"),
     ("quantized weights", dict(quant="int8"), "the state kind's leaves"),
     ("resident_window_blocks", dict(resident_window_blocks=4, host_blocks=8,
@@ -260,7 +259,7 @@ def test_stats_name_the_state_kind_beside_the_full_kind(tiny, served):
     assert state["ssd"] == {"prefill": "ssd_chunk_plain",
                             "decode": "ssd_step_plain"}
     assert "kda" not in state
-    assert len(state["refused"]) == 12
+    assert len(state["refused"]) == 11
     kinds = st["kv_kinds"]
     assert kinds["state"] == {"layers": 9, "slots": 3,
                               "bytes": state["bytes"]}

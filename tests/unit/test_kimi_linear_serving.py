@@ -200,7 +200,6 @@ REFUSED = [
     ("prefix_caching", dict(prefix_caching=True), "snapshotted"),
     ("host_blocks", dict(host_blocks=8, prefix_caching=True), "tiers"),
     ("spec_tokens", dict(spec_tokens=2), "rollback is free"),
-    ("decode_steps", dict(decode_steps=4), "frozen row"),
     ("quantize", dict(quantize="kv8"), "float32 by construction"),
     ("resident_window_blocks", dict(resident_window_blocks=4, host_blocks=8,
                                     prefix_caching=True), "window slides"),
@@ -257,7 +256,7 @@ def test_stats_name_the_state_kind(tiny, served):
                             "decode": "kda_step_plain"}
     assert set(state["refused"]) >= {
         "prefix_caching", "host_blocks", "nvme_blocks", "spec_tokens",
-        "a draft model", "decode_steps", "quantize", "quantized weights",
+        "a draft model", "quantize", "quantized weights",
         "a tp mesh", "engine_mode", "sp", "resident_window_blocks"}
     kinds = st["kv_kinds"]
     assert set(kinds) >= {"latent", "state"}

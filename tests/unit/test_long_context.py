@@ -168,7 +168,7 @@ def test_window_ctor_validations():
     with pytest.raises(ValueError, match="speculative"):
         ServingEngine(engine, resident_window_blocks=4, host_blocks=8,
                       swap_batch=4, spec_tokens=2, **base)
-    with pytest.raises(ValueError, match="decode_steps"):
+    with pytest.raises(TypeError, match="decode_steps"):  # gone at PR 59
         ServingEngine(engine, resident_window_blocks=4, host_blocks=8,
                       swap_batch=4, decode_steps=4, **base)
 

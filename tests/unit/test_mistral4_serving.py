@@ -208,7 +208,7 @@ def test_engine_serves_it_token_exact_and_names_the_pool(model):
     assert lat["latent_attn"] == {"decode": "latent_gather",
                                   "prefill": "latent_gather"}
     assert lat["latent_bytes"] == lat["kv_valid"] * 96
-    assert {"quantize", "a tp mesh", "decode_steps"} <= set(lat["refused"])
+    assert {"quantize", "a tp mesh", "a draft model"} <= set(lat["refused"])
     assert st["kv_kinds"] is None and st["sparse_attn"] is None
     # (the blocks still in use are the prefix trie's: a latent block is
     # kept for the next request like any other)
@@ -389,7 +389,6 @@ def test_the_eight_shares_sum_to_the_uncut_layer(model):
 
 @pytest.mark.parametrize("how,named", [
     (dict(quantize="kv8"), "quantize='kv8'"),
-    (dict(decode_steps=4), "decode_steps=4"),
     (dict(topology=2), "a tp mesh (tp=2)"),
     (dict(host_blocks=8, swap_batch=2, resident_window_blocks=4),
      "resident_window_blocks"),

@@ -3,8 +3,8 @@ host buffer (``inference/operands.py``).
 
  - the layout: every field where it was put, bit for bit (negative zero,
    denormals, NaN payloads, seeds past 2^31, bools, a table per layer kind);
- - every runner — plain decode, a prefill group with pad rows, the fused
-   window, speculative verify with the n-gram and the draft proposer, a
+ - every runner — plain decode, a prefill group with pad rows,
+   speculative verify with the n-gram and the draft proposer, a
    resident window, a model with two layer kinds, a mask matrix beside the
    buffer — emits the tokens it emits when each field is fed separately;
  - a call hands over ONE small host array (two with a mask matrix) and
@@ -170,9 +170,7 @@ def _engine(models, runner):
     engine, cfg = models({"two-kinds": "two-kinds",
                           "window": "long"}.get(runner, "tiny"))
     kw = dict(SERVE_KW)
-    if runner == "fused":
-        kw["decode_steps"] = 4
-    elif runner == "spec-ngram":
+    if runner == "spec-ngram":
         kw["spec_tokens"] = 3
     elif runner == "spec-draft":
         dcfg = gpt2.GPT2Config(vocab_size=cfg.vocab_size, max_seq_len=64,
@@ -231,8 +229,8 @@ def _separately(srv):
     return srv
 
 
-RUNNERS = ["plain", "fused", "spec-ngram", "spec-draft", "window",
-           "two-kinds", "masks"]
+RUNNERS = ["plain", "spec-ngram", "spec-draft", "window", "two-kinds",
+           "masks"]
 
 
 @pytest.mark.parametrize("runner", RUNNERS)
@@ -282,8 +280,8 @@ def _steady(models, runner, steps=4):
     return srv, handles
 
 
-@pytest.mark.parametrize("runner", ["plain", "fused", "spec-ngram",
-                                    "spec-draft", "masks"])
+@pytest.mark.parametrize("runner", ["plain", "spec-ngram", "spec-draft",
+                                    "masks"])
 def test_a_call_hands_over_one_host_array_and_puts_nothing(
         models, runner, monkeypatch):
     """Between the plan and the results a runner makes no ``jnp.asarray``

@@ -337,9 +337,7 @@ CALL_SEGMENTS = ("enqueue_s", "wait_s")
 def _runner_engine(tiny, runner):
     engine, cfg = tiny
     kw = dict(SERVE_KW)
-    if runner == "fused":
-        kw["decode_steps"] = 4
-    elif runner == "spec-ngram":
+    if runner == "spec-ngram":
         kw["spec_tokens"] = 2
     elif runner == "spec-draft":
         dcfg = gpt2.GPT2Config(vocab_size=cfg.vocab_size, max_seq_len=64,
@@ -348,8 +346,7 @@ def _runner_engine(tiny, runner):
     return ServingEngine(engine, **kw)
 
 
-@pytest.mark.parametrize("runner", ["plain", "fused", "spec-ngram",
-                                    "spec-draft"])
+@pytest.mark.parametrize("runner", ["plain", "spec-ngram", "spec-draft"])
 def test_every_runner_times_its_segments(tiny, runner):
     """Table B of ISSUE 36, one vocabulary in every runner: a phase that
     made a call carries ``plan_s`` / ``upload_s`` / ``commit_s`` and their
@@ -363,7 +360,7 @@ def test_every_runner_times_its_segments(tiny, runner):
     flights = [e for e in events if e["ph"] == "X" and e["name"] in (
         "prefill", "decode", "spec_propose", "spec_verify")
         and e["args"].get("mode") != "ngram"]
-    want = {"prefill", "decode"} if runner in ("plain", "fused") else \
+    want = {"prefill", "decode"} if runner == "plain" else \
         {"prefill", "spec_verify"} | (
             {"spec_propose"} if runner == "spec-draft" else set())
     assert {f["name"] for f in flights} == want
@@ -745,9 +742,7 @@ def _module_name(lowered) -> str:
 def _serving_module(tiny, kind):
     engine, cfg = tiny
     kw = dict(SERVE_KW)
-    if kind == "jit_decode_fused":
-        kw["decode_steps"] = 4
-    elif kind == "jit_decode_windowed":
+    if kind == "jit_decode_windowed":
         kw.update(host_blocks=16, swap_batch=4, resident_window_blocks=4)
     elif kind == "jit_prefill_fused":
         dcfg = gpt2.GPT2Config(vocab_size=cfg.vocab_size, max_seq_len=64,
@@ -784,7 +779,6 @@ def _train_module(tiny, kind):
 
 @pytest.mark.parametrize("kind,lower", [
     ("jit_decode_step", _serving_module), ("jit_prefill", _serving_module),
-    ("jit_decode_fused", _serving_module),
     ("jit_decode_windowed", _serving_module),
     ("jit_prefill_fused", _serving_module), ("jit_train_step", _train_module)])
 def test_the_programs_keep_the_names_the_reduction_finds_them_by(

@@ -10,8 +10,6 @@ End-to-end contracts on a tiny gpt2:
  - the compile contract is unchanged: mixed greedy+sampled+constrained
    traces compile the same <= 2 / <= 3 programs (chunked / draft-spec),
    sentry-strict — sampling params ride as fixed-shape operands;
- - fused multi-step decode (``decode_steps=K``) composes: same tokens as
-   the one-step path;
  - speculative decoding composes through the rejection verifier for both
    proposers (n-gram: 2 programs, draft model: 3), temp-0 rows staying
    exactly greedy;
@@ -89,19 +87,6 @@ def test_temp0_rows_bit_identical_to_greedy_engine(tiny_engine):
     res_on, res_off = on.serve(reqs), off.serve(reqs)
     assert_sequential(engine, reqs, res_on, res_off)
     assert on.stats()["sampled_requests"] == 0
-
-
-def test_fused_decode_composes_token_identical(tiny_engine):
-    engine, cfg = tiny_engine
-    reqs = _sampled_trace(cfg, 5, seed=2, greedy_every=3)
-    plain = ServingEngine(engine, **_KW)
-    fused = ServingEngine(engine, decode_steps=4, **_KW)
-    res_p, res_f = plain.serve(reqs), fused.serve(reqs)
-    for r in reqs:
-        np.testing.assert_array_equal(res_p[r.uid], res_f[r.uid],
-                                      err_msg=f"uid {r.uid}")
-    assert fused.stats()["fused_iterations"] > 0
-    assert fused.compile_count == 1 + len(fused._rungs), fused.compiled_programs
 
 
 # ----------------------------------------------------------- speculative

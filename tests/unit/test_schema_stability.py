@@ -44,9 +44,9 @@ ENGINE_STATS_KEYS = frozenset({
     "block_size", "blocks_in_use", "cancelled", "compile_budget",
     "compile_count", "config", "debug_checks", "decode_steps",
     "drafted_tokens", "engine_mode",
-    "evicted", "free_blocks", "fused_iterations", "generated_tokens",
+    "evicted", "free_blocks", "generated_tokens",
     "host_blocks",
-    "host_blocks_in_use", "host_fence_waits", "host_pool_bytes",
+    "host_blocks_in_use", "host_pool_bytes",
     "invariant_checks_run",
     "handoffs",
     "iterations", "kv_dtype", "kv_pool_bytes", "kv_pool_bytes_per_chip",
@@ -102,7 +102,7 @@ ENGINE_STATS_KEYS = frozenset({
 #: dict pinned key-for-key: bench JSONs, ``best_config.json``, and the
 #: autotuner's trial records must stay mutually loadable across PRs
 CONFIG_KEYS = frozenset({
-    "block_size", "debug_checks", "decode_steps",
+    "block_size", "debug_checks",
     "engine_mode", "host_blocks",
     "max_seq_len", "ngram_max", "ngram_min", "num_blocks",
     "nvme_blocks", "nvme_high_watermark", "nvme_path", "peak_flops",

@@ -298,7 +298,9 @@ def test_compile_budget_mirror(tiny_engine):
 def test_every_constraint_has_a_loud_ctor_twin(tiny_engine):
     """A tuner-proposed config that slips past pruning must fail the
     ServingEngine ctor with a message naming the offending knob — one
-    case per space.py predicate with a ctor-reachable violation."""
+    case per space.py predicate with a ctor-reachable violation
+    (``test_serving_options.py`` holds every rule of ``options.EXCLUDES``
+    to the same sentence on both sides)."""
     engine, _ = tiny_engine
     base = dict(slots=2, max_seq_len=64, block_size=8, prefill_chunk=16)
     cases = [
@@ -310,16 +312,16 @@ def test_every_constraint_has_a_loud_ctor_twin(tiny_engine):
         ("swap_batch_bounds",
          {**base, "host_blocks": 4, "swap_batch": 8}, "swap_batch"),
         ("pool_min_blocks", {**base, "num_blocks": 4}, "num_blocks"),
-        ("positive_knobs", {**base, "slots": 0}, "slots"),
-        ("positive_knobs", {**base, "prefill_batch": 0}, "prefill_batch"),
-        ("positive_knobs", {**base, "block_size": 0}, "block_size"),
+        ("option_ranges", {**base, "slots": 0}, "slots"),
+        ("option_ranges", {**base, "prefill_batch": 0}, "prefill_batch"),
+        ("option_ranges", {**base, "block_size": 0}, "block_size"),
         # PR 17: disaggregated role + NVMe third tier
         ("role_needs_tiered_kv", {**base, "role": "prefill"},
          "host_blocks"),
-        ("role_needs_tiered_kv", {**base, "role": "sideways"}, "role"),
+        ("option_ranges", {**base, "role": "sideways"}, "role"),
         ("nvme_needs_host_tier", {**base, "nvme_blocks": 8},
          "host tier"),
-        ("nvme_watermark_window",
+        ("option_ranges",
          {**base, "host_blocks": 8, "swap_batch": 4, "nvme_blocks": 8,
           "nvme_high_watermark": 1.5}, "nvme_high_watermark"),
         ("nvme_watermark_window",
