@@ -4,7 +4,10 @@ algorithmic bandwidth for all_reduce / all_gather / reduce_scatter /
 all_to_all / ppermute over a size sweep on the current mesh.
 
 Usage: python benchmarks/comm_bench.py [--dp N] [--trials T]
-       [--maxsize-mb M] [--op all|all_reduce|...]
+       [--maxsize-mb M | --sizes-mb A,B,...] [--op all|all_reduce|...]
+
+``--sizes-mb`` times the given per-device buffer sizes (MB, decimal) in
+place of the sweep: the sizes a step's own collectives have.
 """
 
 from __future__ import annotations
@@ -36,6 +39,9 @@ def main():
                     help="mesh size (default: all devices)")
     ap.add_argument("--trials", type=int, default=20)
     ap.add_argument("--maxsize-mb", type=float, default=64.0)
+    ap.add_argument("--sizes-mb", default=None,
+                    help="comma-separated per-device sizes in MB (10^6 B) "
+                         "to time in place of the sweep")
     ap.add_argument("--op", default="all",
                     choices=["all", "all_reduce", "all_gather",
                              "reduce_scatter", "all_to_all", "ppermute"])
@@ -44,7 +50,7 @@ def main():
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     from deepspeed_tpu.parallel.topology import MeshTopology
 
@@ -75,6 +81,8 @@ def main():
     while s <= args.maxsize_mb * 2 ** 20:
         sizes.append(int(s))
         s *= 8
+    if args.sizes_mb:
+        sizes = [int(float(mb) * 1e6) for mb in args.sizes_mb.split(",")]
 
     results = []
     for op in selected:
@@ -98,12 +106,13 @@ def main():
                 return jax.shard_map(body, mesh=mesh, in_specs=P("dp"),
                                  out_specs=P("dp"))(x)
 
-            x = jnp.ones((n, elems), jnp.float32)
+            # placed as the program wants it, so that no call moves it
+            x = jax.device_put(jnp.ones((n, elems), jnp.float32),
+                               NamedSharding(mesh, P("dp")))
             with mesh:
                 jax.block_until_ready(bench(x))        # compile
                 t0 = time.perf_counter()
-                out = bench(x)
-                jax.device_get(jnp.sum(out))           # force completion
+                jax.block_until_ready(bench(x))
                 dt = (time.perf_counter() - t0) / args.trials
             results.append({
                 "op": op, "bytes": nbytes,

@@ -109,6 +109,8 @@ class InferenceEngine:
         mc = getattr(model, "model_config", None)
         if mc is not None and hasattr(mc, "scan_group_size"):
             mc.scan_group_size = 1
+        if mc is not None and hasattr(mc, "scan_prefetch"):
+            mc.scan_prefetch = None
 
         tp = config.tensor_parallel.tp_size if config.tensor_parallel.enabled else 1
         sp = int(getattr(config, "sequence_parallel", 1) or 1)

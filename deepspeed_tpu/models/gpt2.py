@@ -104,6 +104,9 @@ class GPT2Config:
     #: ZeRO-3 liveness: gather this many layers per scan step (engine sets
     #: it from stage3_prefetch_bucket_size / stage3_max_live_parameters)
     scan_group_size: int = 1
+    #: the blocks' shardings when the engine pipelines the layer loop
+    #: (``overlap_comm`` at ZeRO-3, ``liveness.scan_layers_prefetched``)
+    scan_prefetch: Any = None
 
     @property
     def head_dim(self) -> int:
@@ -399,11 +402,13 @@ def _trunk(cfg: GPT2Config, params, input_ids, rng=None, train: bool = True):
 
     # ZeRO-3 liveness: scan_group_size > 1 gathers G layers per scan step
     # (engine sets it from stage3_prefetch_bucket_size / max_live_parameters)
-    from ..runtime.zero.liveness import scan_layers_grouped
+    # and scan_prefetch pipelines the loop (overlap_comm)
+    from ..runtime.zero.liveness import scan_layers_prefetched
 
-    (x, _) = scan_layers_grouped(step, (x, jnp.zeros((), jnp.int32)),
-                                 params["blocks"],
-                                 getattr(cfg, "scan_group_size", 1))
+    (x, _) = scan_layers_prefetched(step, (x, jnp.zeros((), jnp.int32)),
+                                    params["blocks"],
+                                    getattr(cfg, "scan_group_size", 1),
+                                    getattr(cfg, "scan_prefetch", None))
     return x
 
 

@@ -55,6 +55,9 @@ class LlamaConfig:
     #: ZeRO-3 liveness: gather this many layers per scan step (engine sets
     #: it from stage3_prefetch_bucket_size / stage3_max_live_parameters)
     scan_group_size: int = 1
+    #: the blocks' shardings when the engine pipelines the layer loop
+    #: (``overlap_comm`` at ZeRO-3, ``liveness.scan_layers_prefetched``)
+    scan_prefetch: Any = None
     #: sequence-parallel attention impl when mesh sp>1: auto|ulysses|ring
     sp_impl: str = "auto"
     #: width of one head; ``None`` is ``hidden_size // num_heads``, read
@@ -682,11 +685,13 @@ def forward(cfg: LlamaConfig, params: PyTree, input_ids, rng=None,
             fn = checkpoint_block(block_apply, static_argnums=(0,))
         return fn(cfg, layer, x, cos, sin)
 
-    # ZeRO-3 liveness: scan_group_size > 1 gathers G layers per scan step
-    from ..runtime.zero.liveness import scan_layers_grouped
+    # ZeRO-3 liveness: scan_group_size > 1 gathers G layers per scan step,
+    # scan_prefetch pipelines the loop (overlap_comm)
+    from ..runtime.zero.liveness import scan_layers_prefetched
 
-    x = scan_layers_grouped(step, x, params["blocks"],
-                            getattr(cfg, "scan_group_size", 1))
+    x = scan_layers_prefetched(step, x, params["blocks"],
+                               getattr(cfg, "scan_group_size", 1),
+                               getattr(cfg, "scan_prefetch", None))
     x = block_norm(cfg, x, params["final_norm"])
     return head_logits(cfg, params, x)
 

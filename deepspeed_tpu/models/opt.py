@@ -55,6 +55,11 @@ class OPTConfig:
     dropout: float = 0.0
     remat: bool = False
     use_flash: Optional[bool] = None
+    #: ZeRO-3 liveness, set by the engine at trace time
+    #: (``runtime/zero/liveness.py``): layers gathered a scan step, and the
+    #: blocks' shardings when the layer loop is pipelined (``overlap_comm``)
+    scan_group_size: int = 1
+    scan_prefetch: Any = None
 
     @property
     def head_dim(self) -> int:
@@ -274,19 +279,27 @@ def _head(cfg: OPTConfig, params, x):
     return x @ params["embed_tokens"].T.astype(x.dtype)
 
 
+def _run_blocks(cfg: OPTConfig, x, blocks: PyTree):
+    """The decoder stack over ``[L, ...]``-stacked blocks (training and
+    uncached forward)."""
+    def step(x, layer):
+        block_fn = checkpoint_block(_block, static_argnums=(0,)) \
+            if cfg.remat else _block
+        return block_fn(cfg, x, layer)
+
+    # ZeRO-3 liveness: the engine sets both (a group of layers a scan step;
+    # the pipelined loop of overlap_comm); unset, this is a plain lax.scan
+    from ..runtime.zero.liveness import scan_layers_prefetched
+
+    return scan_layers_prefetched(step, x, blocks, cfg.scan_group_size,
+                                  cfg.scan_prefetch)
+
+
 def forward(cfg: OPTConfig, params: PyTree, input_ids, rng=None,
             train: bool = True):
     """Token logits. input_ids: [B, S] int32."""
     params = dequant_resident(params)
-    x = _embed(cfg, params, input_ids)
-
-    def body(x, xs):
-        layer, = xs
-        block_fn = checkpoint_block(_block, static_argnums=(0,)) \
-            if cfg.remat else _block
-        return block_fn(cfg, x, layer), None
-
-    x, _ = jax.lax.scan(body, x, (params["blocks"],))
+    x = _run_blocks(cfg, _embed(cfg, params, input_ids), params["blocks"])
     return _head(cfg, params, x)
 
 
@@ -380,15 +393,7 @@ def loss_from_batch(cfg: OPTConfig, params, batch, rng=None,
     if labels is None:
         labels = input_ids[:, 1:]
         input_ids = input_ids[:, :-1]
-    x = _embed(cfg, params, input_ids)
-
-    def body(x, xs):
-        layer, = xs
-        block_fn = checkpoint_block(_block, static_argnums=(0,)) \
-            if cfg.remat else _block
-        return block_fn(cfg, x, layer), None
-
-    x, _ = jax.lax.scan(body, x, (params["blocks"],))
+    x = _run_blocks(cfg, _embed(cfg, params, input_ids), params["blocks"])
     # checkpointed head: backward recomputes logits from [T, D] activations
     head = jax.checkpoint(lambda p, x, t: _head_loss(cfg, p, x, t))
     return head(params, x, labels)

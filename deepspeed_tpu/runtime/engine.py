@@ -176,6 +176,9 @@ class DeepSpeedEngine:
                     int(x.nbytes) for x in jax.tree_util.tree_leaves(
                         self.state[k])) for k in ("params", "opt_state")})
         with setup.span("build_step_fns"):
+            #: what collectives each compiled step has, by program
+            #: (``_read_collectives``, at the program's first call)
+            self.collectives: Dict[str, Dict[str, Dict[str, int]]] = {}
             self._configure_stage3_liveness()
             self._build_step_fns()
 
@@ -617,6 +620,8 @@ class DeepSpeedEngine:
         mc = getattr(self.model_spec, "model_config", None)
         if mc is not None and hasattr(mc, "scan_group_size"):
             mc.scan_group_size = 1  # clear a stale G from a reused config
+        if mc is not None and hasattr(mc, "scan_prefetch"):
+            mc.scan_prefetch = None
         if self.zero_stage != 3 or self.param_stream_enabled:
             return
         hooks = getattr(self.model_spec, "pipeline_hooks", None) or {}
@@ -628,13 +633,22 @@ class DeepSpeedEngine:
 
         node = self._abstract_params
         try:
-            for k in ((key,) if isinstance(key, str) else key):
+            for k in self._pp_blocks_path():
                 node = node[k]
         except (KeyError, TypeError):
             return
         num_layers, per_layer = blocks_param_count(node)
         g = stage3_group_size(self._config.zero_config, per_layer, num_layers)
         mc.scan_group_size = g
+        # overlap_comm (the reference's key; true by default at stage 3):
+        # the layer loop is software-pipelined (liveness.
+        # scan_layers_prefetched) — the model is handed where its blocks
+        # live and what they are gathered to.  A ZeRO world of one device
+        # has nothing to gather: the plain scan.
+        if (self._config.zero_config.overlap_comm
+                and hasattr(mc, "scan_prefetch")
+                and self.topology.data_parallel_size > 1):
+            mc.scan_prefetch = self._blocks_shardings(self._pp_blocks_path())
         if g > 1:
             log_dist(
                 f"ZeRO-3 liveness: gathering {g} layers/scan step "
@@ -644,6 +658,27 @@ class DeepSpeedEngine:
                 f"max_live_parameters="
                 f"{self._config.zero_config.max_live_parameters:.0e})",
                 ranks=[0])
+
+    def _blocks_shardings(self, path):
+        """``liveness.LayerShardings`` of the stacked blocks at ``path``:
+        the ZeRO-3 sharding the state holds each leaf in, and the same
+        without the ZeRO axes (its ``tp`` spec) — what a layer computes
+        on."""
+        from .zero.liveness import LayerShardings
+
+        def at(tree):
+            for k in path:
+                tree = tree[k]
+            return tree
+
+        sharded = at(self.state_shardings["params"])
+        tp = at(self.tp_specs) if self.tp_specs is not None else \
+            jax.tree_util.tree_map(lambda _: None, sharded)
+        gathered = jax.tree_util.tree_map(
+            lambda _, spec: NamedSharding(
+                self.mesh, spec if spec is not None else P()),
+            sharded, tp, is_leaf=lambda x: x is None)
+        return LayerShardings(sharded=sharded, gathered=gathered)
 
     # ------------------------------------------------- ZeRO-Infinity streaming
     def _pp_blocks_path(self) -> tuple:
@@ -1022,8 +1057,35 @@ class DeepSpeedEngine:
             log_dist(trace_mod.setup_line(), ranks=[0])
 
         return trace_mod.FirstCall(
-            fn, program, built, gas=self.gradient_accumulation_steps(),
+            fn, program, built,
+            before=functools.partial(self._read_collectives, program),
+            gas=self.gradient_accumulation_steps(),
             micro_batch=self.train_micro_batch_size_per_gpu())
+
+    def _read_collectives(self, program: str, fn, *args) -> None:
+        """``collectives[program]``: what collectives the compiled step has
+        and how many of them sit plain — synchronous, holding the core —
+        inside a loop (``runtime/zero/collectives.py``, from the scheduled
+        text of the step compiled for these very arguments; the call that
+        follows finds the executable in JAX's caches, so the program is
+        still compiled once).  One log line; the
+        ``train_collectives_in_loop_plain`` gauge on report steps.  A step
+        on one device has none and is not read."""
+        from .zero import collectives
+
+        if self.mesh.size == 1:
+            self.collectives[program] = collectives.count("")
+            return
+        found = collectives.count(fn.lower(*args).compile().as_text())
+        self.collectives[program] = found
+        log_dist(f"{program}: collectives {collectives.line(found)}",
+                 ranks=[0])
+        for kind, row in found.items():
+            self.metrics.gauge(
+                "train_collectives_in_loop_plain",
+                "collectives of a compiled step left synchronous inside a "
+                "loop (phase: the program; mode: the kind)",
+                phase=program, mode=kind).set(row["plain"])
 
     def setup_report(self) -> Optional[Dict[str, Any]]:
         """The process's start-up ring in numbers — seconds by phase of

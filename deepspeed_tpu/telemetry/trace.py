@@ -197,13 +197,17 @@ class FirstCall:
     function once the first call is over — the engine puts it where this
     wrapper was, so no later call passes through here; one that does (a
     reference taken before the first call) is handed straight on.
+    ``before(fn, *args)``, if given, runs inside the span ahead of the call
+    with the call's own arguments: what it builds of the program (a
+    ``fn.lower(*args).compile()`` to read) is built under the program's
+    name, and the call then finds it in JAX's caches.
     Attributes (``lower``, ``_cache_size``) are the function's own."""
 
-    __slots__ = ("_fn", "_program", "_args", "_then", "_called")
+    __slots__ = ("_fn", "_program", "_args", "_then", "_before", "_called")
 
-    def __init__(self, fn, program: str, then=None, **args):
+    def __init__(self, fn, program: str, then=None, before=None, **args):
         self._fn, self._program, self._args = fn, program, args
-        self._then, self._called = then, False
+        self._then, self._before, self._called = then, before, False
 
     def __call__(self, *args, **kwargs):
         if self._called:
@@ -213,6 +217,8 @@ class FirstCall:
         with timeline.span("build", program=self._program, **self._args):
             outer, timeline.building = timeline.building, self._program
             try:
+                if self._before is not None:
+                    self._before(self._fn, *args, **kwargs)
                 out = jax.block_until_ready(self._fn(*args, **kwargs))
             finally:
                 timeline.building = outer

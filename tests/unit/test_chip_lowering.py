@@ -353,16 +353,21 @@ def test_pool_is_donated_and_never_sliced_or_restacked(program, kv8, smoke,
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 #: (shape [B, H, S, hd], KV heads, generation): the four-chip training
@@ -1772,3 +1777,62 @@ def test_longdoc_cells_programs_alias_the_whole_cache(as_on_tpu, one_chip,
     assert max(temps.values()) < 640 << 20, temps
     print("temporaries by program, MB:",
           {k: round(v / 2 ** 20) for k, v in temps.items()})
+
+
+def test_pipelined_zero3_step_has_no_activation_exchange_in_its_loops(
+        v5e_2x2):
+    """ISSUE 60: a small OPT's ZeRO-3 gradient step compiled for the four
+    described chips, its layer loop plain and pipelined
+    (``liveness.scan_layers_prefetched``), read the ``engine.collectives``
+    way.  Pipelined, the two loops gather a layer's four weight matrices
+    each (vectors are gathered whole, outside), most of them in asynchronous
+    chains, and move no activation (no ``all-to-all``); left to the
+    partitioner, more gathers sit plain in the loops.  (How many a compiler
+    leaves plain is its scheduler's to say — at the four-chip cell's size,
+    memory: PERF.md section 6, PR 60 — so the bound here is the plain
+    scan's own count.)"""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.models import opt
+    from deepspeed_tpu.parallel.topology import DATA_AXES, MeshTopology
+    from deepspeed_tpu.runtime.zero import collectives, liveness
+    from deepspeed_tpu.runtime.zero.sharding import ZeroShardingPlan
+
+    mesh = MeshTopology(devices=list(v5e_2x2.devices)).mesh
+    rep = NamedSharding(mesh, P())
+    found = {}
+    for pipelined in (False, True):
+        cfg = opt.OPTConfig(vocab_size=512, max_seq_len=256, num_layers=4,
+                            num_heads=8, hidden_size=512, ffn_size=2048,
+                            remat=True, use_flash=False)
+        spec = opt.build(cfg)
+        shapes = jax.eval_shape(lambda: spec.init_fn(jax.random.PRNGKey(0)))
+        shardings = ZeroShardingPlan(3, mesh).param_shardings(shapes)
+        if pipelined:
+            cfg.scan_prefetch = liveness.LayerShardings(
+                sharded=shardings["blocks"],
+                gathered=jax.tree_util.tree_map(lambda _: rep,
+                                                shardings["blocks"]))
+        params = jax.tree_util.tree_map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            shapes, shardings)
+        batch = {"input_ids": jax.ShapeDtypeStruct(
+            (8, 257), jnp.int32, sharding=NamedSharding(mesh, P(DATA_AXES)))}
+
+        def grads(p, b):
+            return jax.value_and_grad(lambda p: spec.loss_fn(
+                jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16), p),
+                b, None, True))(p)
+
+        with mesh:
+            text = jax.jit(grads, out_shardings=(rep, shardings)).lower(
+                params, batch).compile().as_text()
+        found[pipelined] = collectives.count(text)
+        print("pipelined" if pipelined else "plain scan",
+              collectives.line(found[pipelined]))
+    plain, piped = found[False], found[True]
+    assert piped["all-to-all"]["in_loop"] == 0
+    gathers = piped["all-gather"]
+    assert gathers["in_loop"] == 8            # 4 matrices x 2 loops
+    assert gathers["fused"] >= gathers["plain"]
+    assert gathers["plain"] <= plain["all-gather"]["plain"]

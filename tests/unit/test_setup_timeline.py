@@ -185,7 +185,15 @@ def test_the_train_step_has_a_build_span_with_its_three_children(trained):
     assert build["t0"] >= one(events, "initialize")["t1"]
     assert build["args"]["gas"] == 1 and build["args"]["micro_batch"] == 1
     for phase in BUILT:
-        child = one(events, phase, program="train_step")
+        children = [e for e in events if e["name"] == phase
+                    and e["args"].get("program") == "train_step"]
+        # ISSUE 60: the engine reads the compiled step's text inside the
+        # span, before the call (``engine.collectives``): the call then
+        # finds the program lowered and compiled (no event) and its trace
+        # made (an event of microseconds)
+        child = max(children, key=lambda e: e["dur"])
+        assert len(children) == 1 or phase == "trace" and all(
+            e is child or e["dur"] < 5e3 for e in children), children
         assert child["args"]["fn"] == "train_step" and inside(child, build)
     # the wrapper left with the first call
     assert not isinstance(engine._train_step_fn, trace.FirstCall)
