@@ -544,6 +544,42 @@ def audit_router(router) -> None:
                     "land on the wrong engine")
 
 
+def audit_window_ring(ring, active_rows) -> None:
+    """The window kind's table (``inference/paged.py WindowRing``): a row's
+    live logical blocks ``[lo, hi)`` fit its ring and each sits at entry ``i
+    % width`` under an id of its own (refcount 1, never scratch); every
+    other entry is scratch; the allocator holds exactly those ids; a row
+    that is not active holds none.  Raises :class:`PagedStateError`."""
+    seen = set()
+    for row in range(ring.tables.shape[0]):
+        lo, hi = int(ring.lo[row]), int(ring.hi[row])
+        live = {li % ring.width for li in range(lo, hi)}
+        if hi - lo > ring.width or (row not in active_rows and hi > lo):
+            raise PagedStateError(
+                "window-ring-span",
+                f"row {row} holds window blocks [{lo}, {hi}) in a ring of "
+                f"{ring.width}" + ("" if row in active_rows
+                                   else " but is not active"))
+        for entry, block in enumerate(map(int, ring.tables[row])):
+            if (block != 0) != (entry in live):
+                raise PagedStateError(
+                    "window-ring-entry",
+                    f"row {row} ring entry {entry} holds block {block}; "
+                    f"its live logical blocks are [{lo}, {hi})")
+            if block and (block in seen or ring.alloc.refcount(block) != 1):
+                raise PagedStateError(
+                    "window-ring-ownership",
+                    f"window block {block} (row {row}, entry {entry}) is "
+                    f"shared or has refcount {ring.alloc.refcount(block)}")
+            seen.add(block)
+    seen.discard(0)
+    if len(seen) != ring.alloc.blocks_in_use:
+        raise PagedStateError(
+            "window-ring-leak",
+            f"the window allocator has {ring.alloc.blocks_in_use} blocks in "
+            f"use, the rings hold {len(seen)}")
+
+
 def audit_serving_engine(srv, active) -> None:
     """Engine-facing wrapper: pulls the :class:`ServingEngine` fields and
     derives each active slot's committed-token count (decode: host
@@ -575,6 +611,8 @@ def audit_serving_engine(srv, active) -> None:
                               window_frontiers=frontiers,
                               landmark_blocks=getattr(
                                   srv, "_landmark_blocks", 0))
+        if getattr(srv, "_windows", None):
+            audit_window_ring(srv._ring, set(active))
         if getattr(srv, "_host", None) is not None:
             audit_host_store(
                 srv._host,

@@ -1188,7 +1188,7 @@ class ServingEngine:
                 # a slot (``inference/paged.py WindowRing``)
                 self._ring = WindowRing(
                     self.slots, self._windows["window"], self._prefill_width,
-                    self.block_size)
+                    self._window_block(engine._config.jnp_dtype))
                 kinds["window_blocks"] = self._ring.alloc.num_blocks
             if self._state:
                 kinds["state_rows"] = self.slots
@@ -2004,6 +2004,18 @@ class ServingEngine:
             return f"the prefill kernel's plan takes no {heads // hkv} x " \
                 f"{width} query rows a KV head"
         return None
+
+    def _window_block(self, dtype) -> int:
+        """Tokens a block of the window kind's leaves holds: the full
+        kind's, unless the hook names that kind's leaves (``"leaves"``: a
+        kind whose token is of another width has blocks of its own bytes) —
+        then what the cache tree's own shapes say."""
+        names = self._windows.get("leaves")
+        if not names:
+            return self.block_size
+        shapes = jax.eval_shape(lambda: self._init_cache(
+            2, self.block_size, dtype, window_blocks=2))
+        return int(shapes[names[0]].shape[3])
 
     @classmethod
     def _widest_leaf(cls, mk_pool):
@@ -3599,6 +3611,22 @@ class ServingEngine:
                 - int(np.any((at == 0) & (tiles[:, 0] > 0)))
             for key, v in args.items():
                 self._latent_totals[key] += v
+            if self._windows:
+                # latent layers under a window beside them, on the ring:
+                # ``kv_window``, the keys the rows' last queries keep x the
+                # sliding layers, ``kv_window_blocks``, the ring blocks of
+                # ONE layer those keys lie in
+                window, bs = self._windows["window"], self._ring.block_size
+                reach = {
+                    "kv_window": int(np.minimum(valid, window).sum())
+                    * self._windows["layers"]["sliding"],
+                    "kv_window_blocks": int(
+                        (-(-valid // bs)
+                         - np.maximum(valid - window, 0) // bs).sum())}
+                for key, v in reach.items():
+                    self._window_totals[key] = \
+                        self._window_totals.get(key, 0) + v
+                args.update(reach)
             return args
         if not self._windows:
             return {}
@@ -5309,13 +5337,20 @@ class ServingEngine:
                     "expert_rows_absent": self._rows_absent,
                     "refused": list(self._refusals["state"])}
         layers = self._windows["layers"]
+        own = {}
+        if "token_width" in self._windows:
+            # a window kind whose token is of its own width, in blocks of
+            # its own bytes (``_window_block``)
+            item = jnp.dtype(self._kv_dtype).itemsize
+            own = {"block_size": self._ring.block_size,
+                   "token_bytes": self._windows["token_width"] * item}
         return {
             "window": self._windows["window"],
             "full": kind(self._alloc, layers["full"], self._nbper,
                          self._full_peak),
             "sliding": {**kind(self._ring.alloc, layers["sliding"],
                                self._ring.width, self._ring.peak),
-                        "released": self._ring.released},
+                        "released": self._ring.released, **own},
             **self._window_totals,
             "expert_rows_absent": self._rows_absent,
             "refused": list(self._refusals["window"])}

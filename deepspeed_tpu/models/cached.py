@@ -389,15 +389,21 @@ def decode_over_layers(body, x, blocks, cache_k, cache_v, num_layers,
 #: (``paged_kv.STATE_COMPANIONS``) are indexed by ROW
 #: (``ops/paged_kv.py`` "The state kind") and their "table" is ``slot``,
 #: int32 ``[B]`` — the row of the leaves each row of the call owns (absent
-#: in a decode step beside a paged table, where row ``b`` IS row ``b``)
+#: in a decode step beside a paged table, where row ``b`` IS row ``b``).
+#: ``latent_indexed`` is a latent layer under a learned selection: the latent
+#: leaf and the indexer's key beside it, both under the full kind's table;
+#: ``latent_sliding`` a latent layer under a window: ONE leaf of its own width
+#: (``latw``) under the window kind's ring (``models/dots3.py``)
 KIND_LEAVES = {"full": ("k", "v", "full"), "sliding": ("kw", "vw", "window"),
                "latent": ("latent", None, "full"),
+               "latent_indexed": ("latent", "idx", "full"),
+               "latent_sliding": ("latw", None, "window"),
                **{kind: ("state", beside, "slot")
                   for kind, beside in paged_kv.STATE_COMPANIONS.items()}}
 
 
 def scan_periods_cached(kinds, num_layers: int, step, x, blocks, cache,
-                        block_tables, head: int = 0):
+                        block_tables, head: int = 0, before=None):
     """The layer loop of a patterned model (``kinds``: the period, e.g.
     ``("sliding",) * 3 + ("full",)``) over the block-paged pool: a
     ``lax.scan`` over PERIODS whose body is the period's layers written
@@ -413,7 +419,11 @@ def scan_periods_cached(kinds, num_layers: int, step, x, blocks, cache,
     stacks at its place among its kind's layers.  ``head``: that many
     leading PERIODS are written out before the scan, with a static period
     number (a model whose first layers differ from the rest: a leading
-    dense FFN).  ``step(x, layer, ck, cv, index, table, kind) -> (x, ck,
+    dense FFN).  ``before`` (stacks by kind): ``{kind: layers of it that lie
+    BEFORE this loop's first}`` — a model whose layers are not one whole
+    number of periods (a leading layer outside the period, a partial closing
+    period) runs a loop a stretch, each counting on from the one before.
+    ``step(x, layer, ck, cv, index, table, kind) -> (x, ck,
     cv, aux)`` with ``index`` the layer's place among its KIND's layers
     (``ops/paged_kv.py`` "Layer kinds"; stacks by kind add an eighth
     argument, the layer's number in the model — an ``int`` in a head
@@ -422,6 +432,8 @@ def scan_periods_cached(kinds, num_layers: int, step, x, blocks, cache,
     stacked [L, ...])``."""
     p, n = len(kinds), num_layers
     by_kind = all(isinstance(blocks.get(kind), dict) for kind in kinds)
+    before = dict(before or {})
+    number0 = sum(before.values())
 
     def body(carry, period):
         x, pools = carry
@@ -435,11 +447,12 @@ def scan_periods_cached(kinds, num_layers: int, step, x, blocks, cache,
             # saw copied here was ``q_w``, transposed for a head split XLA
             # had folded into its dot: ``llama._attend_cached``)
             if by_kind:
-                index = period * len(same) + same.index(j)
+                index = before.get(kind, 0) + period * len(same) \
+                    + same.index(j)
                 layer = jax.tree_util.tree_map(
                     lambda a: jax.lax.dynamic_index_in_dim(
                         a, index, keepdims=False), blocks[kind])
-                more = (period * p + j,)
+                more = (number0 + period * p + j,)
             else:
                 layer = jax.tree_util.tree_map(
                     lambda a: jax.lax.dynamic_index_in_dim(

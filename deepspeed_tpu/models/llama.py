@@ -80,8 +80,12 @@ class LlamaConfig:
     #: attends every key and takes NO rotation.  ``num_layers`` is a whole
     #: number of periods.  ``"latent"`` (a latent layer INSIDE a pattern)
     #: and ``"kda"`` (a gated delta-rule layer, whose state is a matrix a
-    #: head a ROW and no cache a token) are ``models/kimi_linear.py``'s:
-    #: its weights are stacked BY KIND.
+    #: head a ROW and no cache a token) are ``models/kimi_linear.py``'s,
+    #: ``"latent_indexed"`` (a latent layer under a learned selection) and
+    #: ``"latent_sliding"`` (one of its own widths under a window)
+    #: ``models/dots3.py``'s: their weights are stacked BY KIND
+    #: (:data:`BY_KIND`), and such a family says itself how its layers
+    #: divide into periods.
     layer_kinds: tuple = ()
     sliding_window: int = 0
     #: the head is the token table transposed (no ``lm_head`` leaf)
@@ -117,6 +121,9 @@ class LlamaConfig:
     #: the weights are stacked BY KIND of layer, not ``[L, ...]`` (set by
     #: the family whose layers differ in shape)
     by_kind: ClassVar[bool] = False
+    #: ``num_layers`` is a whole number of periods of ``layer_kinds`` (a
+    #: family that says itself how its layers divide sets this False)
+    whole_periods: ClassVar[bool] = True
 
     def __post_init__(self):
         if self.qk_norm not in (False, True, "head"):
@@ -125,19 +132,21 @@ class LlamaConfig:
         if self.norm not in ("rms", "layernorm"):
             raise ValueError(f"norm={self.norm!r}: 'rms' or 'layernorm'")
         self.layer_kinds = tuple(self.layer_kinds)
-        if any(k not in ("sliding", "full", "latent", "kda")
+        if any(k not in ("sliding", "full") + BY_KIND
                for k in self.layer_kinds):
             raise ValueError(f"layer_kinds={self.layer_kinds!r}: 'sliding', "
-                             "'full', 'latent' or 'kda' each")
-        if {"latent", "kda"} & set(self.layer_kinds) and (
+                             f"'full' or one of {BY_KIND} each")
+        if set(BY_KIND) & set(self.layer_kinds) and (
                 {"sliding", "full"} & set(self.layer_kinds)
                 or not self.by_kind):
             raise ValueError(
-                f"layer_kinds={self.layer_kinds!r}: 'latent' and 'kda' "
-                "layers have weights of their own shapes, stacked by kind "
-                "(models/kimi_linear.py), and are not mixed with 'sliding' "
-                "or 'full' ones")
-        if self.layer_kinds and self.num_layers % len(self.layer_kinds):
+                f"layer_kinds={self.layer_kinds!r}: {BY_KIND} layers have "
+                "weights of their own shapes, stacked by kind "
+                "(models/kimi_linear.py, models/dots3.py), and are not "
+                "mixed with 'sliding' or 'full' ones, whose K / V weights "
+                "lie in one [L, ...] stack")
+        if self.layer_kinds and self.num_layers % len(self.layer_kinds) \
+                and self.whole_periods:
             raise ValueError(
                 f"num_layers={self.num_layers} is not a whole number of "
                 f"periods of {len(self.layer_kinds)} layers")
@@ -163,10 +172,11 @@ class LlamaConfig:
                     f"qk_rope_dim ({self.qk_nope_dim} + {self.qk_rope_dim}):"
                     " pass head_width")
             if self.qk_norm or (self.layer_kinds
-                                and "latent" not in self.layer_kinds):
+                                and not set(self.layer_kinds) <= set(BY_KIND)):
                 raise ValueError(
                     "latent attention is built without qk_norm, and inside "
-                    "a layer pattern only as the pattern's 'latent' kind")
+                    "a layer pattern only as one of the kinds whose weights "
+                    f"are stacked by kind {BY_KIND}")
 
     @property
     def head_dim(self) -> int:
@@ -223,6 +233,11 @@ class LlamaConfig:
         return v * d + l * (attn + mlp + norms) + d + head
 
 
+#: the layer kinds whose weights are stacked BY KIND (``LlamaConfig.by_kind``):
+#: a latent layer inside a pattern, a gated delta-rule layer
+#: (``models/kimi_linear.py``), a latent layer under a learned selection and
+#: one under a window (``models/dots3.py``)
+BY_KIND = ("latent", "kda", "latent_indexed", "latent_sliding")
 #: what ``LlamaConfig.rope_scaling`` holds
 YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast",
              "beta_slow", "mscale", "mscale_all_dim")

@@ -49,6 +49,12 @@ the full one), 28 query heads on 4 KV heads, 64 softmax-scored ReGLU experts
 (``ffn_act="relu"``) top-6 renormalised, and a router that reads the
 ATTENTION's normed input (``router_input="attn"``), so that routing does not
 wait on attention; it trains dropless.
+What is NOT built here, and refused by name: an indexer (``index_heads``)
+beside a layer pattern or over a latent pool.  That combination is a family
+of its own whose weights are stacked by kind (``models/dots3.py``: the
+``"latent_indexed"`` and ``"latent_sliding"`` kinds, over this file's
+``MixtralConfig``, ``_routed`` and ``_shared``); ``models/kimi_linear.py`` is
+the other such family.
 """
 
 from __future__ import annotations
@@ -143,12 +149,15 @@ class MixtralConfig(L.LlamaConfig):
                 raise ValueError(
                     f"experts_held={self.experts_held} outside the "
                     f"{self.num_experts} experts")
-        if self.layer_kinds and self.index_heads:
-            raise ValueError("a layer pattern (layer_kinds) and a learned "
-                             "indexer (index_heads) are not built together")
-        if self.latent and self.index_heads:
-            raise ValueError("latent attention (kv_lora_rank) and a learned "
-                             "indexer (index_heads) are not built together")
+        if self.index_heads and (self.layer_kinds or self.latent) \
+                and not self.by_kind:
+            raise ValueError(
+                "a learned indexer (index_heads) beside a layer pattern "
+                "(layer_kinds) or latent attention (kv_lora_rank) is built "
+                "as the 'latent_indexed' kind of a model whose weights are "
+                "stacked by kind (models/dots3.py): an indexer over the K / "
+                "V of a pattern's 'full' layers, or over a latent pool with "
+                "no pattern, is not")
 
     @property
     def experts_here(self) -> int:

@@ -1227,6 +1227,9 @@ _INDEX_TILE_BLOCKS = 32
 #: queries one grid step of the scoring kernel scores (its output block is
 #: ``[queries, max_seq_len]`` float32)
 _INDEX_QUERY_TILE = 32
+#: most score columns (key rows) of a landing tile, and most query rows
+#: (heads x queries) of a grid step
+_INDEX_TILE_COLS = 512
 
 
 def _index_scores_kernel(layer_ref, n_ref, bt_ref, q_ref, w_ref, last_ref,
@@ -1314,12 +1317,15 @@ def paged_index_scores_pallas(qi, wi, idx_pool, block_tables, last, *,
     bs = r_in * w_in // di
     g = bs // r
     nbper = block_tables.shape[1]
-    nt = min(_INDEX_TILE_BLOCKS, nbper)
+    # (a block of 32 keys two a lane row, 16 heads: 32 blocks and 32 queries
+    # — 512 x 512 dot products a span; wider blocks and more heads take
+    # fewer of each, so that a step's products stay that size)
+    nt = max(1, min(_INDEX_TILE_BLOCKS, _INDEX_TILE_COLS // r, nbper))
     while nbper % nt:
         nt -= 1
     ntiles, cols = nbper // nt, nt * r
-    tq = t if t <= _INDEX_QUERY_TILE or t % _INDEX_QUERY_TILE \
-        else _INDEX_QUERY_TILE
+    tq = next((c for c in (_INDEX_QUERY_TILE, 16, 8)
+               if t % c == 0 and (c * hi <= _INDEX_TILE_COLS or c == 8)), t)
     tt = t // tq
     # queries once per span, head-major rows within a query tile
     qx = qi.reshape(b, hi, tt, tq, di).transpose(0, 2, 1, 3, 4) \
@@ -1662,10 +1668,15 @@ def paged_sparse_attention_pallas(q, k_pool, v_pool, block_tables, scores,
 #: heads: 512 query rows at 32 heads — a ``[512, 384] x [384, bs]`` score
 #: matmul a block, 0.5 MB of float32 scores at 256 keys a block)
 _LATENT_QUERY_TILE = 16
+#: and most query ROWS a step (both latent families of 32 heads: 16
+#: positions; 128 heads take 4 — a step's query tile, its output and its
+#: float32 accumulator are ``rows x W`` each and share 16 MiB of VMEM)
+_LATENT_QUERY_ROWS = 512
 
 
 def paged_latent_attention_reference(q, pool, block_tables, q_pos, *,
-                                     rank: int, layer=None):
+                                     rank: int, layer=None, window: int = 0,
+                                     valid=None, keep=None):
     """Gather-based absorbed latent attention (pure XLA): the CPU path and
     the tests' oracle.
 
@@ -1676,17 +1687,42 @@ def paged_latent_attention_reference(q, pool, block_tables, q_pos, *,
                   one layer's [NB, 1, block_size, W] with ``layer=None``)
     block_tables: int32 [B, NBPER]
     q_pos:        scalar or int32 [B] — global position of q[:, :, 0]
+    window:       a sliding-window layer's reach (0: none): the table is
+                  the window kind's RING (``ops/paged_kv.py`` "Layer
+                  kinds": entry ``e`` holds the newest logical block ``i <=
+                  last`` with ``i % R == e``) and a query keeps its
+                  ``window`` newest keys, itself included; ``valid`` int32
+                  [B] then says how many of the T queries are real
+                  (default all): the ring holds the blocks up to the last
+                  real one
+    keep:         bool [B, T, S] — the keys each query attends (a learned
+                  selection), under the causal mask; default all
     -> [B, H, T, rank]: ``softmax(q . tile^T) . tile[:, :rank]``, a query
     at position ``p`` keeping keys ``<= p``."""
     pool, layer = paged_kv.whole_pool(pool, layer)
     b, _, t, w = q.shape
-    lat = _paged_gather(pool, jnp.asarray(block_tables, jnp.int32), layer,
-                        w)[:, 0]                                 # [B, S, W]
+    bt = jnp.asarray(block_tables, jnp.int32)
+    lat = _paged_gather(pool, bt, layer, w)[:, 0]                # [B, S, W]
     pos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1), (b,))
-    query = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
-    keep = jnp.arange(lat.shape[1])[None, None, :] <= query[:, :, None]
+    query = (pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :])[:, :,
+                                                                     None]
+    key = jnp.arange(lat.shape[1], dtype=jnp.int32)[None, None, :]
+    if window:
+        ring, bs = bt.shape[1], lat.shape[1] // bt.shape[1]
+        nvalid = jnp.full((b,), t, jnp.int32) if valid is None \
+            else jnp.clip(jnp.asarray(valid, jnp.int32), 0, t)
+        last = (pos + jnp.maximum(nvalid, 1) - 1) // bs
+        entry = jnp.arange(ring, dtype=jnp.int32)
+        li = last[:, None] - (last[:, None] - entry[None, :]) % ring
+        key = (li[:, :, None] * bs
+               + jnp.arange(bs, dtype=jnp.int32)).reshape(b, 1, ring * bs)
+        mask = (key >= 0) & (key <= query) & (key > query - window)
+    else:
+        mask = key <= query
+    if keep is not None:
+        mask = mask & keep
     scores = jnp.einsum("bhtw,bsw->bhts", q, lat).astype(jnp.float32)
-    scores = jnp.where(keep[:, None], scores, NEG_INF)
+    scores = jnp.where(mask[:, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhts,bsc->bhtc", probs, lat[..., :rank])
 
@@ -1735,13 +1771,14 @@ def latent_walk_shape(h: int, t: int, bs: int, w: int, itemsize: int,
     its loop iterations land (:func:`latent_tile_blocks` at ``tq * h``
     query rows).  The launcher's arithmetic, and what the serving engine
     counts a call's walks with."""
-    tq = min(t, _LATENT_QUERY_TILE)
+    tq = min(t, _LATENT_QUERY_TILE, max(1, _LATENT_QUERY_ROWS // h))
     return tq, latent_tile_blocks(tq * h, bs, w, itemsize, nbper)
 
 
 def _paged_latent_kernel(layer_ref, pos_ref, valid_ref, bt_ref, q_ref,
                          pool_ref, o_ref, buf, sem, slot_ref, m_scr, l_scr,
-                         acc_scr, *, heads: int, tq: int, rank: int):
+                         acc_scr, *, heads: int, tq: int, rank: int,
+                         window: int = 0):
     """The latent kind's decode (``T == 1``), verify and prefill kernel.
     Grid ``(B, T / tq)``: one step is ``tq`` query positions of one row,
     all ``heads`` of them — ``rows = tq * heads`` query rows, row ``r`` the
@@ -1778,7 +1815,15 @@ def _paged_latent_kernel(layer_ref, pos_ref, valid_ref, bt_ref, q_ref,
     and only waits, both from :func:`tile` of the step's :func:`reach`.  A step of
     no block passes the duty on; a launch's first step starts its own, the
     last starts nothing.  The slot of a step's tile 0 follows the tiles
-    walked before it (``slot_ref``, SMEM scratch)."""
+    walked before it (``slot_ref``, SMEM scratch).
+
+    ``window`` (static; 0: none, and the text above is the whole kernel): a
+    sliding-window layer over its kind's RING table (``ops/paged_kv.py``
+    "Layer kinds": logical block ``i`` at entry ``i % R``).  A step then
+    walks from the block of its FIRST query's oldest key on, ``lo = max(base
+    + j * tq - window + 1, 0) // bs``, and a query row keeps ``key > its
+    position - window`` too: a [1, 512] chunk under a 513-key window reads
+    ~5 blocks a step whatever the row's length."""
     b, j = pl.program_id(0), pl.program_id(1)
     per_row = pl.num_programs(1)
     steps = pl.num_programs(0) * per_row
@@ -1794,6 +1839,13 @@ def _paged_latent_kernel(layer_ref, pos_ref, valid_ref, bt_ref, q_ref,
         row = jnp.minimum(step // per_row, pl.num_programs(0) - 1)
         at = step % per_row * tq
         last = jnp.minimum(at + tq, valid_ref[row]) - 1
+        if window:
+            # ``(row, blocks, first block)``: the ring holds every block
+            # from the oldest key its first query keeps to its last query's
+            lo = jnp.maximum(pos_ref[row] + at - window + 1, 0) // bs
+            return row, jnp.where(
+                (last >= at) & (step < steps),
+                (pos_ref[row] + last + bs) // bs - lo, 0), lo
         return row, jnp.where(
             (last >= at) & (step < steps),
             jnp.clip((pos_ref[row] + last + bs) // bs, 0, bt_ref.shape[1]),
@@ -1808,11 +1860,12 @@ def _paged_latent_kernel(layer_ref, pos_ref, valid_ref, bt_ref, q_ref,
         of the tile before, so a short tile behind a long one leaves the
         copy queue idle, and a long one behind a short one is waited
         for."""
-        row, n = of
+        row, n, *lo = of
         tiles = (n + nt - 1) // nt
         per = n // jnp.maximum(tiles, 1)
         more = n - per * tiles
-        return (row, i * per + jnp.minimum(i, more),
+        start = i * per + jnp.minimum(i, more)
+        return (row, start + lo[0] if window else start,
                 jnp.where(i < tiles, per + (i < more), 0))
 
     def each_block(of, slot, act):
@@ -1820,8 +1873,9 @@ def _paged_latent_kernel(layer_ref, pos_ref, valid_ref, bt_ref, q_ref,
         row, start, held = of
 
         def one(k, carry):
+            at = (start + k) % bt_ref.shape[1] if window else start + k
             act(pltpu.make_async_copy(
-                pool_ref.at[layer, bt_ref[row, start + k], 0],
+                pool_ref.at[layer, bt_ref[row, at], 0],
                 buf.at[slot, k], sem.at[slot]))
             return carry
 
@@ -1865,7 +1919,9 @@ def _paged_latent_kernel(layer_ref, pos_ref, valid_ref, bt_ref, q_ref,
             row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             key = mine[1] * bs + jax.lax.broadcasted_iota(jnp.int32,
                                                           s.shape, 1)
-            s = jnp.where(key <= base + first + row // heads, s, NEG_INF)
+            query = base + first + row // heads
+            s = jnp.where((key <= query) & (key > query - window)
+                          if window else key <= query, s, NEG_INF)
             m_prev = m_scr[...][:, :1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
@@ -1893,9 +1949,14 @@ def _paged_latent_kernel(layer_ref, pos_ref, valid_ref, bt_ref, q_ref,
         .astype(o_ref.dtype)
 
 
-def latent_kernel_name(t: int) -> str:
+def latent_kernel_name(t: int, window: int = 0) -> str:
     """The name the latent kernel is launched under for a window of ``t``
-    query positions (one reader sums ``paged_latent_*``)."""
+    query positions (one reader sums ``paged_latent_*``); a sliding-window
+    layer's launches (``window``) have names of their own, a decode step's
+    and a chunk's (such a model is served no verify window)."""
+    if window:
+        return "paged_window_latent_attn" if t == 1 \
+            else "paged_window_latent_prefill"
     return "paged_latent_attn" if t == 1 else \
         "paged_latent_verify" if t <= VERIFY_T_MAX else "paged_latent_prefill"
 
@@ -1919,18 +1980,31 @@ def _latent_prefill_call(kernel, call, operands):
                           **call)(*operands)
 
 
+def _window_latent_attn_call(kernel, call, operands):
+    return pl.pallas_call(kernel, name="paged_window_latent_attn",
+                          **call)(*operands)
+
+
+def _window_latent_prefill_call(kernel, call, operands):
+    return pl.pallas_call(kernel, name="paged_window_latent_prefill",
+                          **call)(*operands)
+
+
 _LATENT_CALLS = {"paged_latent_attn": _latent_attn_call,
                  "paged_latent_verify": _latent_verify_call,
-                 "paged_latent_prefill": _latent_prefill_call}
+                 "paged_latent_prefill": _latent_prefill_call,
+                 "paged_window_latent_attn": _window_latent_attn_call,
+                 "paged_window_latent_prefill": _window_latent_prefill_call}
 
 
 def paged_latent_attention_pallas(q, pool, block_tables, q_pos, *, rank: int,
-                                  layer=None, valid=None,
+                                  layer=None, valid=None, window: int = 0,
                                   interpret: Optional[bool] = None):
     """:func:`paged_latent_attention_reference`'s contract through
     :func:`_paged_latent_kernel`, on one shard, for any ``T`` (padded to
     whole query tiles); ``valid`` int32 [B]: how many of the ``T`` queries
-    are real (default all — a pad query's output is unspecified)."""
+    are real (default all — a pad query's output is unspecified);
+    ``window``: a sliding-window layer over its ring table."""
     pool, layer = paged_kv.whole_pool(pool, layer)
     b, h, t, w = q.shape
     nb, bs = pool.shape[1], pool.shape[3]
@@ -1973,32 +2047,250 @@ def paged_latent_attention_pallas(q, pool, block_tables, q_pos, *, rank: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret)
-    out = _LATENT_CALLS[latent_kernel_name(t)](
-        functools.partial(_paged_latent_kernel, heads=h, tq=tq, rank=rank),
+    out = _LATENT_CALLS[latent_kernel_name(t, window)](
+        functools.partial(_paged_latent_kernel, heads=h, tq=tq, rank=rank,
+                          **({"window": window} if window else {})),
         call, (jnp.asarray(layer, jnp.int32).reshape(1), pos, nvalid, bt, qq,
                pool))
     return out.reshape(b, tp, h, rank)[:, :t].transpose(0, 2, 1, 3)
 
 
 def paged_latent_attention(q, pool, block_tables, q_pos, *, rank: int,
-                           layer=None, valid=None):
+                           layer=None, valid=None, window: int = 0):
     """Dispatch: the walking kernel on a TPU (decode, verify window and
     prefill chunk alike, :func:`latent_kernel_name`), gather + XLA
-    otherwise.  One shard over a float pool, outside a resident-window
-    context: the serving engine refuses the rest by name."""
+    otherwise.  ``window`` (static): a sliding-window layer's reach over its
+    kind's ring table; 0 is the program it always was.  One shard over a
+    float pool, outside a resident-window context: the serving engine
+    refuses the rest by name."""
     if is_quantized_pool(pool) or window_state() is not None \
             or paged_kv.tp_mesh() is not None or paged_kv.dp_groups() > 1:
         raise NotImplementedError(
             "the latent kind is read from a float pool on one shard, "
             "outside a resident-window context")
     if on_tpu():
-        _took(latent_kernel_name(q.shape[2]))
+        _took(latent_kernel_name(q.shape[2], window))
         return paged_latent_attention_pallas(q, pool, block_tables, q_pos,
                                              rank=rank, layer=layer,
-                                             valid=valid)
-    _took("latent_gather")
+                                             valid=valid, window=window)
+    _took("window_latent_gather" if window else "latent_gather")
     return paged_latent_attention_reference(q, pool, block_tables, q_pos,
-                                            rank=rank, layer=layer)
+                                            rank=rank, layer=layer,
+                                            window=window, valid=valid)
+
+
+# ---------------------------------------------------------------------------
+# The latent read UNDER A SELECTION (learned sparse attention over a latent
+# pool): ``paged_index_scores`` and ``paged_sparse_select`` above say which
+# keys, this kernel attends them absorbed.
+# ---------------------------------------------------------------------------
+#: keys one softmax update of the selected latent read takes (whole blocks;
+#: twice as many for the one position of a decode step)
+_SPARSE_LATENT_COLS = 512
+#: query POSITIONS one grid step of it takes (a window of one: 1)
+_SPARSE_LATENT_QUERY_TILE = 8
+_SPARSE_LATENT_VMEM = 32 << 20
+
+
+def sparse_latent_walk_shape(t: int, bs: int, nbper: int) -> Tuple[int, int]:
+    """``(tq, nt)`` of the selected latent read for a window of ``t``
+    positions (1, or a multiple of 8): the query positions a grid step takes
+    and the blocks a landing tile holds."""
+    tq = 1 if t == 1 else _SPARSE_LATENT_QUERY_TILE
+    nt = max(1, min(_SPARSE_LATENT_COLS * (2 if tq == 1 else 1) // bs, nbper))
+    while nbper % nt:
+        nt -= 1
+    return tq, nt
+
+
+def _paged_sparse_latent_kernel(layer_ref, n_ref, bt_ref, hit_ref, q_ref,
+                                theta_ref, slast_ref, last_ref, scores, pool,
+                                o_ref, buf, sbuf, sem, m_scr, l_scr, acc_scr,
+                                *, heads: int, tq: int, rank: int):
+    """Absorbed latent attention of ``tq`` query positions of one row, all
+    ``heads`` of them, over the keys each position has CHOSEN.  Grid ``(B,
+    T / tq)``; query row ``o * heads + h`` is head ``h`` at window offset
+    ``j * tq + o`` (:func:`_paged_latent_kernel`'s order).
+
+    ``layer_ref`` int32 [1], ``n_ref`` int32 [B, T / tq] (the blocks up to
+    the step's last real query), ``bt_ref`` int32 [B, NBPER] and ``hit_ref``
+    int32 [B * T / tq, NBPER] (whether any query of the STEP chose a key of
+    the block) arrive via scalar prefetch; the latent pool ``[L, NB, 1, bs,
+    W]`` and the indexer's ``scores`` [B, T, NBPER * bs] float32 stay in
+    HBM.  The step walks its ``n`` blocks ``nt`` a tile and copies, of each
+    tile, the blocks with a hit (one DMA a block: a latent block serves
+    every head, keys and values) and the tile's ``[tq, nt * bs]`` slab of
+    scores; tile ``i + 1`` lands while tile ``i`` is attended.  A block no
+    query of the step chose is neither read nor — where the whole tile has
+    none — attended: the latent bytes read are those of the blocks that hold
+    a chosen key.
+
+    The set is rebuilt from the scores as ``_paged_sparse_kernel`` rebuilds
+    it (``sparse_index_attention.chosen``): position ``o`` keeps key ``s``
+    iff ``s <= last[o]`` and ``score > theta[o]`` or ``score == theta[o]``
+    and ``s <= s_last[o]`` — ONE mask row a position, spread over its
+    ``heads`` query rows: ``q [tq * heads, W] . tile^T`` are the scores of
+    every head of every position (the pad lanes meet zeros), ``p . tile[:,
+    :rank]`` the output in latent space, one product each a tile.  A slot
+    of the landing buffer that was not copied holds an earlier block or the
+    zeros of the start: every key of it is masked.  Online softmax in
+    float32, the probabilities to the MXU in the pool's dtype."""
+    _, nt, bs, w = buf.shape
+    cols = nt * bs
+    b, j = pl.program_id(0), pl.program_id(1)
+    step = b * pl.num_programs(1) + j
+    layer = layer_ref[0]
+    n = jnp.clip(n_ref[b, j], 0, bt_ref.shape[1])
+    ntiles = (n + nt - 1) // nt
+    theta = _lanes(theta_ref[0], cols)
+    slast = _lanes(slast_ref[0], cols)
+    last = _lanes(last_ref[0], cols)
+
+    def each_copy(i, slot, act):
+        """``act`` on tile ``i``'s copies: its blocks with a hit, its
+        scores."""
+        def one(k, hits):
+            hit = hit_ref[step, i * nt + k]
+
+            @pl.when(hit > 0)
+            def _block():
+                act(pltpu.make_async_copy(
+                    pool.at[layer, bt_ref[b, i * nt + k], 0],
+                    buf.at[slot, k], sem.at[slot, 0]))
+            return hits + hit
+
+        hits = jax.lax.fori_loop(0, jnp.clip(n - i * nt, 0, nt), one,
+                                 jnp.int32(0))
+
+        @pl.when(i < ntiles)
+        def _scores():
+            act(pltpu.make_async_copy(
+                scores.at[b, pl.ds(pl.multiple_of(j * tq, tq), tq),
+                          pl.ds(pl.multiple_of(i * cols, cols), cols)],
+                sbuf.at[slot], sem.at[slot, 1]))
+        return hits
+
+    def tile(i, carry):
+        slot = i % 2
+        each_copy(i + 1, 1 - slot, lambda copy: copy.start())
+        hits = each_copy(i, slot, lambda copy: copy.wait())
+
+        @pl.when(hits > 0)
+        def _attend():
+            sc = sbuf[slot]
+            key = i * cols + jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+            keep = (key <= last) & ((sc > theta)
+                                    | ((sc == theta) & (key <= slast)))
+            keys = buf[slot].reshape(cols, w)
+            # ONE product for the step's rows (a product a position, 128
+            # rows each, ran 37.7 ms a [1, 512] chunk at 20k keys where the
+            # dense walk's 512-row products run 19.1: my chip runs, PR 61);
+            # the mask is a row a POSITION, spread over its heads
+            s = jax.lax.dot_general(
+                q_ref[0], keys, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            mine = keep if tq == 1 else jnp.broadcast_to(
+                keep[:, None, :], (tq, heads, cols)).reshape(tq * heads, cols)
+            s = jnp.where(mine, s, NEG_INF)
+            m_prev = m_scr[...][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # a position with no key kept so far has m = NEG_INF, where
+            # exp(s - m) is 1: the mask, not the exponent, zeroes it
+            p = jnp.where(mine, jnp.exp(s - m_new), 0.0)
+            l_new = l_scr[...][:, :1] * alpha \
+                + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+                p.astype(keys.dtype), keys[:, :rank],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+            l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        return carry
+
+    _start_chunks(m_scr, l_scr, acc_scr)
+    buf[...] = jnp.zeros_like(buf)
+    each_copy(0, 0, lambda copy: copy.start())
+    jax.lax.fori_loop(0, ntiles, tile, None)
+    den = l_scr[...][:, :1]
+    o_ref[0] = (acc_scr[...] / jnp.where(den == 0.0, 1.0, den)) \
+        .astype(o_ref.dtype)
+
+
+def paged_sparse_latent_attention_pallas(q, pool, block_tables, scores, theta,
+                                         s_last, last, *, rank: int, layer,
+                                         real=None,
+                                         interpret: Optional[bool] = None):
+    """The read of learned sparse attention over a LATENT pool: ``q [B, H,
+    T, W]`` (absorbed, as :func:`paged_latent_attention_reference` takes
+    it; ``T`` 1 or a multiple of 8) over the keys each position chose, out
+    of the stacked float leaf ``[L, NB, 1, bs, W]`` at ``layer``, read in
+    place.  ``scores [B, T, NBPER * bs]`` float32 (``paged_index_scores``),
+    ``theta`` / ``s_last [B, T]`` (``paged_sparse_select``) and ``last [B,
+    T]`` (the last key a position may see; -1: a pad row, which comes back
+    zeros) say which keys; ``real`` bool ``[B, T]`` which positions are
+    somebody's (default all): a block is read for a grid step iff a real
+    position of the step chose a key of it.  -> ``(out [B, H, T, rank],
+    latent blocks landed)`` (:func:`_paged_sparse_latent_kernel`, as
+    ``paged_sparse_latent_attn``)."""
+    b, h, t, w = q.shape
+    _, nb, one, bs, width = pool.shape
+    assert one == 1 and width == w and w % LANES == 0 \
+        and (t == 1 or t % 8 == 0), (pool.shape, q.shape)
+    if interpret is None:
+        interpret = interpret_kernels()
+    nbper = block_tables.shape[1]
+    tq, nt = sparse_latent_walk_shape(t, bs, nbper)
+    tt, rows = t // tq, tq * h
+    s = jnp.arange(nbper * bs, dtype=jnp.int32)
+    keep = (s <= last[..., None]) & (
+        (scores > theta[..., None])
+        | ((scores == theta[..., None]) & (s <= s_last[..., None])))
+    if real is not None:
+        keep = keep & real[:, :, None]
+    hit = jnp.any(keep.reshape(b, tt, tq, nbper, bs), axis=(2, 4))
+    n = jnp.clip((jnp.max(last.reshape(b, tt, tq), axis=2) + bs) // bs, 0,
+                 nbper)
+    bt = jnp.clip(jnp.asarray(block_tables, jnp.int32), 0, nb - 1)
+    qq = q.transpose(0, 2, 1, 3).reshape(b, t * h, w).astype(pool.dtype)
+
+    def lanes(x, dtype):
+        return jnp.broadcast_to(x.astype(dtype)[..., None], (b, t, LANES))
+
+    def tile(width):
+        return pl.BlockSpec((1, rows, width),
+                            lambda i, j, *prefetched: (i, j, 0))
+
+    per_query = pl.BlockSpec((1, tq, LANES),
+                             lambda i, j, *prefetched: (i, j, 0))
+    out = pl.pallas_call(
+        functools.partial(_paged_sparse_latent_kernel, heads=h, tq=tq,
+                          rank=rank),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,               # layer, blocks, table, hits
+            grid=(b, tt),
+            in_specs=[tile(w), per_query, per_query, per_query]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
+            out_specs=tile(rank),
+            scratch_shapes=[
+                pltpu.VMEM((2, nt, bs, w), pool.dtype),
+                pltpu.VMEM((2, tq, nt * bs), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((rows, LANES), jnp.float32),           # m
+                pltpu.VMEM((rows, LANES), jnp.float32),           # l
+                pltpu.VMEM((rows, rank), jnp.float32)]),          # acc
+        out_shape=jax.ShapeDtypeStruct((b, t * h, rank), q.dtype),
+        # (8 positions of 128 heads: the query tile, the output and the
+        # float32 accumulator are 1,024 rows each, 19.5 MB with a tile's
+        # scores — over the 16 MiB a kernel gets unasked, a sixth of VMEM)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_SPARSE_LATENT_VMEM),
+        interpret=interpret, name="paged_sparse_latent_attn",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), n.astype(jnp.int32), bt,
+      hit.reshape(b * tt, nbper).astype(jnp.int32), qq,
+      lanes(theta, jnp.float32), lanes(s_last, jnp.int32),
+      lanes(last, jnp.int32), scores.astype(jnp.float32), pool)
+    return out.reshape(b, t, h, rank).transpose(0, 2, 1, 3), jnp.sum(hit)
 
 
 #: paths :func:`paged_decode_attention` took while a :func:`dispatch_log`

@@ -41,7 +41,14 @@ Layout contract — the WHOLE stacked pool, addressed in place:
    write) or ``window=W`` (the reads, ``ops/decode_attention.py``) for a
    window layer and are today's programs without; which table and which
    leaves a layer addresses is static in the model's layer loop
-   (``models/cached.py:scan_periods_cached``).
+   (``models/cached.py:scan_periods_cached``).  A kind's leaves need not be
+   K and V (``models/cached.py KIND_LEAVES``): a model whose window layers
+   are LATENT attention keeps ONE leaf of its own lane width under the ring
+   (``latw``, written by :func:`paged_window_update` with ``ring=True``,
+   read by ``paged_latent_attention(window=)``), in blocks of ITS bytes
+   (:func:`latent_block_tokens` of its width: the engine reads the window
+   kind's block off the cache tree), beside a full kind whose leaves are a
+   latent and an indexer's key (``models/dots3.py``).
 
  - **The latent kind.**  A model with latent attention (MLA,
    ``LlamaConfig.kv_lora_rank > 0``) caches no key and no value a head: a
@@ -70,7 +77,13 @@ Layout contract — the WHOLE stacked pool, addressed in place:
    the 128 KB the walk was tuned at, and a block visit costs ~0.4 us
    whatever it moves: a serving engine that is given no ``block_size``
    takes :func:`latent_block_tokens` for this kind, 512 tokens of 768 B
-   (PERF.md section 6, PR 39, has the table).
+   (PERF.md section 6, PR 39, has the table).  A latent leaf may have the
+   indexer's key leaf beside it under the same table (a latent layer under
+   a learned selection: ``ops/sparse_index_attention.
+   paged_sparse_latent_attention`` reads the latents of the blocks that
+   hold a chosen key), and a pool may hold TWO latent leaves of different
+   widths, each in blocks of its own bytes, the second under the window
+   kind's ring ("Layer kinds" above).
 
  - **The state kind.**  A model with recurrent layers keeps, for each such
    layer, no token at all: a row's whole past is a float32 matrix a head,
@@ -618,20 +631,22 @@ def _paged_cache_update(ck, cv, k, v, pos, block_tables, valid, layer,
 
 
 def paged_window_update(leaf, win, pos, block_tables, valid=None,
-                        layer=None):
+                        layer=None, ring: bool = False):
     """One more per-token pool leaf written as :func:`paged_cache_update`
     writes K and V: the ``[B, H, T, width]`` window ``win`` lands at the same
     ``(layer, block, offset)`` of the stacked float leaf ``[L, NB, H,
     block_size, width]`` (either view).  A learned-sparse-attention model
-    keeps its indexer's keys this way (``ops/sparse_index_attention.py``).  One
-    shard only: a leaf whose head dim is 1 has nothing to split over ``tp``."""
+    keeps its indexer's keys this way (``ops/sparse_index_attention.py``), a
+    latent model its latents; ``ring``: the table is a window layer's ring
+    (:func:`paged_cache_update`).  One shard only: a leaf whose head dim is 1
+    has nothing to split over ``tp``."""
     if _DP_GROUPS > 1 or is_quantized_pool(leaf):
         raise NotImplementedError(
             "paged_window_update takes a float leaf outside a dp context")
     b, _, t, width = win.shape
     bs = int(np.prod(leaf.shape[3:])) // width
     where = _window_blocks(bs, b, t, pos, jnp.asarray(block_tables,
-                                                      jnp.int32), valid)
+                                                      jnp.int32), valid, ring)
     return _write_blocks(leaf, win, jnp.asarray(layer, jnp.int32), *where)
 
 

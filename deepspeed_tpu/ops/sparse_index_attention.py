@@ -221,6 +221,23 @@ def _masked_walk(q, k_pool, v_pool, block_tables, keep, last, layer,
 COUNTS = ("index_keys", "kv_selected", "kv_valid", "sparse_rows", "kv_read")
 
 
+def _queries(block_tables, q_pos, t: int, b: int, valid, bs: int):
+    """What both selected reads reckon of a call's queries: ``(the table
+    int32, last_visible, which queries are somebody's [B, T], the keys they
+    may see summed, the blocks a row's real queries reach [B])``."""
+    bt = jnp.asarray(block_tables, jnp.int32)
+    last = last_visible(q_pos, t, b, valid)
+    real = bt[:, :1] != 0
+    if valid is not None:
+        real = real & (jnp.arange(t, dtype=jnp.int32)[None, :]
+                       < jnp.asarray(valid, jnp.int32)[:, None])
+    real = jnp.broadcast_to(real, (b, t)) & (last >= 0)
+    visible = jnp.sum(jnp.where(real, last + 1, 0))
+    # blocks that hold a key some real query of the row may see
+    blocks = (jnp.max(jnp.where(real, last, -1), axis=1) + bs) // bs
+    return bt, last, real, visible, blocks
+
+
 def paged_sparse_attention(q, k_pool, v_pool, idx_pool, qi, wi, block_tables,
                            q_pos, *, topk: int, layer, valid=None,
                            sm_scale: Optional[float] = None,
@@ -242,19 +259,11 @@ def paged_sparse_attention(q, k_pool, v_pool, idx_pool, qi, wi, block_tables,
             "pool or a resident window")
     b, _, t, d = q.shape
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    bt = jnp.asarray(block_tables, jnp.int32)
-    last = last_visible(q_pos, t, b, valid)
-    nbper = bt.shape[1]
     bs = math.prod(k_pool.shape[3:]) // d
+    bt, last, real, visible, blocks = _queries(block_tables, q_pos, t, b,
+                                               valid, bs)
+    nbper = bt.shape[1]
     s_max = nbper * bs
-    real = bt[:, :1] != 0
-    if valid is not None:
-        real = real & (jnp.arange(t, dtype=jnp.int32)[None, :]
-                       < jnp.asarray(valid, jnp.int32)[:, None])
-    real = jnp.broadcast_to(real, (b, t)) & (last >= 0)
-    visible = jnp.sum(jnp.where(real, last + 1, 0))
-    # blocks that hold a key some real query of the row may see
-    blocks = (jnp.max(jnp.where(real, last, -1), axis=1) + bs) // bs
 
     def finish(out, counts, keep):
         counts = jnp.stack(counts).astype(jnp.int32)
@@ -296,6 +305,121 @@ def paged_sparse_attention(q, k_pool, v_pool, idx_pool, qi, wi, block_tables,
                 read = jnp.sum(blocks) * bs
         return finish(
             out, [visible, jnp.sum(mine), visible,
+                  jnp.sum(jnp.any(real & (last >= topk), axis=1)), read],
+            lambda: keep)
+
+    return jax.lax.cond(jnp.max(last) >= topk, sparse, dense)
+
+
+def _masked_latent_walk(q, pool, block_tables, keep, last, layer, rank: int):
+    """:func:`_masked_walk` over a LATENT leaf: ``q [B, H, T, W]`` (absorbed,
+    scaled) against each row's valid blocks, ``_CHUNK_KEYS`` keys a step,
+    under ``keep [B, T, S]``; the value is the tile's first ``rank`` lanes;
+    online softmax in float32.  -> ``[B, H, T, rank]``."""
+    b, h, t, w = q.shape
+    nbper = block_tables.shape[1]
+    bs = keep.shape[-1] // nbper
+    nbc = max(1, min(nbper, _CHUNK_KEYS // bs))
+    while nbper % nbc:
+        nbc -= 1
+    kc = nbc * bs
+    steps = (jnp.max(last) + kc) // kc                  # chunks with a key
+
+    def step(c, carry):
+        m, l, acc = carry
+        bt = jax.lax.dynamic_slice_in_dim(block_tables, c * nbc, nbc, axis=1)
+        lat = paged_kv._paged_gather(pool, bt, layer, w)[:, 0]   # [B, kc, W]
+        kp = jax.lax.dynamic_slice_in_dim(keep, c * kc, kc, axis=2)[:, None]
+        s = jnp.einsum("bhtw,bkw->bhtk", q, lat,
+                       preferred_element_type=jnp.float32)
+        s = jnp.where(kp, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(kp, jnp.exp(s - m_new), 0.0)
+        acc = acc * alpha + jnp.einsum(
+            "bhtk,bkc->bhtc", p.astype(lat.dtype), lat[..., :rank],
+            preferred_element_type=jnp.float32)
+        return m_new, l * alpha + jnp.sum(p, axis=-1, keepdims=True), acc
+
+    shape = (b, h, t)
+    m, l, acc = jax.lax.fori_loop(
+        0, steps, step,
+        (jnp.full(shape + (1,), NEG_INF, jnp.float32),
+         jnp.zeros(shape + (1,), jnp.float32),
+         jnp.zeros(shape + (rank,), jnp.float32)))
+    return (acc / jnp.where(l == 0.0, 1.0, l)).astype(q.dtype)
+
+
+def paged_sparse_latent_attention(q, pool, idx_pool, qi, wi, block_tables,
+                                  q_pos, *, rank: int, topk: int, layer,
+                                  valid=None, return_keep: bool = False):
+    """:func:`paged_sparse_attention` over a LATENT pool (a latent layer
+    under a learned selection): the absorbed queries ``q [B, H, T, W]``
+    (``decode_attention.paged_latent_attention``'s operand) attend, in all
+    their heads, the ``topk`` keys the indexer chose, out of the latent leaf
+    ``pool [L, NB, 1, bs, W]``; ``idx_pool`` is the indexer's key leaf under
+    the same table.  The scores and the threshold are that function's — the
+    same two kernels — and so are the counts (:data:`COUNTS`; ``kv_read``:
+    latent rows landed).  No row's context past ``topk``: the dense latent
+    walk, chosen at run time.  The read under the selection lands the
+    BLOCKS that hold a key some position of a grid step chose and masks (on
+    a TPU the kernel ``paged_sparse_latent_attn``; :func:`_masked_latent_walk`
+    elsewhere and for a window that is neither one position nor a multiple
+    of 8): a latent token is one row of a block, and Mosaic copies rows
+    eight at a time."""
+    global _TOOK
+    if paged_kv.tp_mesh() is not None or paged_kv.dp_groups() > 1 \
+            or decode_attention.window_state() is not None \
+            or paged_kv.is_quantized_pool(pool):
+        raise NotImplementedError(
+            "learned sparse attention (an indexer's selection) runs on one "
+            "shard over a float pool: not under a tp/dp mesh, an int8 KV "
+            "pool or a resident window")
+    b, h, t, w = q.shape
+    bs = pool.shape[3]
+    bt, last, real, visible, blocks = _queries(block_tables, q_pos, t, b,
+                                               valid, bs)
+    nbper = bt.shape[1]
+    s_max = nbper * bs
+
+    def finish(out, counts, keep):
+        counts = jnp.stack(counts).astype(jnp.int32)
+        return (out, counts, keep()) if return_keep else (out, counts)
+
+    def dense():
+        out = decode_attention.paged_latent_attention(
+            q, pool, bt, q_pos, rank=rank, layer=layer, valid=valid)
+        zero = jnp.zeros((), visible.dtype)
+        return finish(
+            out, [zero, visible, visible, zero, jnp.sum(blocks) * bs],
+            lambda: jnp.arange(s_max)[None, None, :] <= last[:, :, None])
+
+    if s_max <= topk:
+        return dense()
+    kernel = on_tpu() and (t == 1 or t % 8 == 0) and h % 8 == 0
+    _TOOK = "paged_index_scores+paged_sparse_select+" + (
+        "paged_sparse_latent_attn" if kernel else "latent_walk") \
+        if on_tpu() else "gather+top_k+latent_walk"
+
+    def sparse():
+        with jax.named_scope("sparse_attn/score"):
+            scores = index_scores(qi, wi, idx_pool, bt, last, layer)
+        with jax.named_scope("sparse_attn/select"):
+            theta, s_last = select_threshold(scores, topk)
+            keep = chosen(scores, theta, s_last, last)
+        with jax.named_scope("sparse_attn/read"):
+            if kernel:
+                out, landed = \
+                    decode_attention.paged_sparse_latent_attention_pallas(
+                        q, pool, bt, scores, theta, s_last, last, rank=rank,
+                        layer=layer, real=real)
+                read = landed * bs
+            else:
+                out = _masked_latent_walk(q, pool, bt, keep, last, layer,
+                                          rank)
+                read = jnp.sum(blocks) * bs
+        return finish(
+            out, [visible, jnp.sum(keep & real[:, :, None]), visible,
                   jnp.sum(jnp.any(real & (last >= topk), axis=1)), read],
             lambda: keep)
 

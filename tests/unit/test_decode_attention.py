@@ -1547,3 +1547,102 @@ def test_dispatcher_sends_the_latent_read_to_its_kernel_on_a_tpu(monkeypatch):
             da.paged_latent_attention(q, pool, bt, pos, rank=rank, layer=0)
     assert paged_kv.latent_pool_width(320) == 384
     assert paged_kv.latent_pool_width(256) == 256
+
+
+# ISSUE 61: a sliding-window LATENT layer reads its kind's ring from the
+# window's first key on (``paged_window_latent_*``: the same body, ``window``
+# static).
+WINDOW = 40
+
+
+def _window_latent_case(t, keys, seed=61):
+    """Rows holding ``keys`` tokens each (0: a pad row) over a ring the
+    scheduler's own ``WindowRing`` laid out for a call of ``t`` positions —
+    a row's ``valid`` queries are its newest ``min(t, keys)`` — the live
+    blocks random, every other block (scratch, released, never written) NaN.
+    -> ``(q, pool, ring tables, pos, valid)``."""
+    from deepspeed_tpu.inference.paged import WindowRing
+
+    bs = LATENT_BS
+    rng = np.random.default_rng(seed + t)
+    keys = np.asarray(keys)
+    valid = np.minimum(t, keys)
+    ring = WindowRing(len(keys), WINDOW, max(t, 2), bs)
+    pool = np.full((2, ring.alloc.num_blocks, 1, bs, LATENT_W), np.nan)
+    for row, n in enumerate(keys):
+        if n:
+            ring.advance(row, int(n - valid[row]), int(n))
+        live = ring.tables[row][ring.tables[row] != 0]
+        pool[:, live] = rng.standard_normal((2, len(live), 1, bs, LATENT_W))
+    q = rng.standard_normal((len(keys), LATENT_HEADS, t, LATENT_W)) * 0.1
+    return (jnp.asarray(q, jnp.float32), jnp.asarray(pool, jnp.float32),
+            jnp.asarray(ring.tables, jnp.int32),
+            jnp.asarray(keys - valid, jnp.int32),
+            jnp.asarray(valid, jnp.int32))
+
+
+def _window_latent_want(q, pool, bt, pos, valid, layer):
+    """Key by key: query ``p`` keeps ``p - WINDOW < j <= p``, key ``j`` at
+    ring entry ``j // bs % R``; zeros for the pad queries."""
+    q, pool = np.asarray(q, np.float64), np.asarray(pool, np.float64)
+    bs, width = LATENT_BS, bt.shape[1]
+    out = np.zeros(q.shape[:3] + (LATENT_RANK,))
+    for r in range(q.shape[0]):
+        for i in range(int(valid[r])):
+            p = int(pos[r]) + i
+            js = np.arange(max(0, p - WINDOW + 1), p + 1)
+            tile = pool[layer, np.asarray(bt)[r, js // bs % width], 0,
+                        js % bs]
+            s = q[r, :, i] @ tile.T
+            w = np.exp(s - s.max(-1, keepdims=True))
+            out[r, :, i] = (w / w.sum(-1, keepdims=True)) \
+                @ tile[:, :LATENT_RANK]
+    return out
+
+
+@pytest.mark.parametrize("t", [1, 24, 128], ids=["decode", "chunk", "wide"])
+def test_window_latent_walk_reads_the_ring_from_the_windows_first_key(t):
+    """``paged_window_latent_*`` against a key-by-key softmax and the XLA
+    reference on a simulated chip (NaN landing buffers, NaN outside every
+    row's live ring blocks, the race detector): rows of 0 / 1 / ``WINDOW``
+    (exactly the window) / ``WINDOW + 1`` (one key out) / many keys, whose
+    rings have wrapped and whose oldest blocks were released."""
+    from deepspeed_tpu.ops import decode_attention as da
+
+    keys = [1, WINDOW, 0, WINDOW + 1, 31, 33, 5 * LATENT_BS + 7,
+            9 * LATENT_BS, 300]
+    q, pool, bt, pos, valid = _window_latent_case(t, keys)
+    want = _window_latent_want(q, pool, bt, pos, valid, 1)
+    ref = da.paged_latent_attention_reference(
+        q, jnp.nan_to_num(pool), bt, pos, rank=LATENT_RANK, layer=1,
+        window=WINDOW, valid=valid)
+    got = np.asarray(da.paged_latent_attention_pallas(
+        q, pool, bt, pos, rank=LATENT_RANK, layer=1, valid=valid,
+        window=WINDOW, interpret=_simulated_chip()))
+    assert not _races_found()
+    np.testing.assert_allclose(_real(ref, valid), want, atol=2e-5)
+    assert np.isfinite(got).all(), "a read outside a row's live ring blocks"
+    np.testing.assert_allclose(_real(got, valid), want, atol=2e-5)
+    assert not got[np.asarray(valid) == 0].any()
+
+
+def test_window_latent_launches_have_names_of_their_own(monkeypatch):
+    """A sliding latent layer's launches are told from the full walk's in a
+    trace, and 32 heads keep the query tile they had while 128 take 4
+    positions a step (``latent_walk_shape``)."""
+    from deepspeed_tpu.ops import decode_attention as da
+
+    assert da.latent_kernel_name(1, 513) == "paged_window_latent_attn"
+    assert da.latent_kernel_name(512, 513) == "paged_window_latent_prefill"
+    assert da.latent_kernel_name(512) == "paged_latent_prefill"
+    assert da.latent_walk_shape(32, 512, 512, 384, 2, 32) == (16, 2)
+    assert da.latent_walk_shape(32, 1, 256, 640, 2, 17) == (1, 4)
+    assert da.latent_walk_shape(128, 512, 256, 640, 2, 128)[0] == 4
+    assert da.latent_walk_shape(64, 512, 128, 1152, 2, 10)[0] == 8
+    q, pool, bt, pos, valid = _window_latent_case(1, [70, 3])
+    monkeypatch.setattr(da, "on_tpu", lambda: True)
+    monkeypatch.setattr(da, "interpret_kernels", lambda: True)
+    with da.dispatch_log() as paths:
+        da.paged_latent_attention(q, jnp.nan_to_num(pool), bt, pos,
+                                  rank=LATENT_RANK, layer=0, window=WINDOW)
+    assert paths == {"paged_window_latent_attn"}

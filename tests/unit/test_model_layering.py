@@ -21,10 +21,14 @@ FAMILIES = sorted(set(MODULES) - {"cached"})
 #: and group-norm layers; Granite 4.0-H takes ``llama``'s RMSNorm by its
 #: public name and nothing else of a sibling's (PR 55); Brumby IS the Qwen3
 #: block with another mixer: ``BrumbyConfig(LlamaConfig)`` and ``llama``'s
-#: norm, q/k-norm, rotary and head as that module's attributes (PR 57)
+#: norm, q/k-norm, rotary and head as that module's attributes (PR 57);
+#: ``Dots3Config(MixtralConfig)``, whose two latent kinds are ``llama``'s
+#: latent attention at sizes of their own and whose routed FFN is
+#: ``mixtral``'s (PR 61)
 ALLOWED = {("mixtral", "llama"), ("megatron_gpt", "gpt2"), ("unet", "vae"),
            ("kimi_linear", "mixtral"), ("kimi_linear", "llama"),
-           ("granite_hybrid", "llama"), ("brumby", "llama")}
+           ("granite_hybrid", "llama"), ("brumby", "llama"),
+           ("dots3", "mixtral"), ("dots3", "llama")}
 
 
 def _sibling_imports(tree):

@@ -227,6 +227,59 @@ def test_latent_kind_stats_and_span_keys_pinned():
     srv.close()
 
 
+def test_two_latent_kinds_stats_and_span_keys_pinned():
+    """A model whose layers are latent attention under a learned selection
+    and under a window (PR 61): ``stats()`` has all three kinds' entries —
+    ``kv_latent`` as any latent model's, ``sparse_attn`` as any indexer's,
+    ``kv_kinds`` as any window model's plus the window kind's own block and
+    token bytes and the ``kv_window`` totals — and its ``decode`` /
+    ``prefill`` spans carry the three kinds' counters side by side."""
+    from deepspeed_tpu.models import dots3
+    from deepspeed_tpu.ops import sparse_index_attention
+
+    deepspeed_tpu.comm.reset_topology()
+    srv = deepspeed_tpu.init_serving(
+        dots3.build(dots3.Dots3Config(
+            vocab_size=64, max_seq_len=64, hidden_size=32,
+            layer_types=("full_attention", "sliding_attention"),
+            num_heads=2, num_kv_heads=2, head_width=16, q_lora_rank=16,
+            kv_lora_rank=16, qk_nope_dim=8, qk_rope_dim=8, v_head_dim=8,
+            index_heads=2, index_head_dim=16, index_topk=8, sliding_window=5,
+            swa_num_heads=2, swa_q_lora_rank=16, swa_kv_lora_rank=24,
+            swa_qk_nope_dim=8, swa_qk_rope_dim=8, swa_v_head_dim=8,
+            ffn_size=16, dense_ffn_size=32, num_experts=4, top_k=2,
+            router_score="sigmoid", router_bias=True, shared_experts=1,
+            capacity_factor=None)),
+        config={"dtype": "fp32"}, slots=2, max_seq_len=64, block_size=8,
+        prefill_chunk=16)
+    srv.serve([Request(uid=0, prompt=np.arange(20) % 64, max_new_tokens=3)])
+    st = srv.stats()
+    walk = {"kv_valid", "kv_blocks", "kv_pairs", "latent_bytes", "kv_tiles",
+            "kv_first_tiles_ahead"}
+    assert set(st["kv_latent"]) == walk | {
+        "kind", "layers", "token_width", "pool_width", "token_bytes",
+        "block_size", "block_bytes", "latent_attn", "tile_blocks", "refused"}
+    assert set(st["sparse_attn"]) == {"decode", "prefill"} | set(
+        sparse_index_attention.COUNTS)
+    kind = {"layers", "num_blocks", "blocks_in_use", "peak_blocks_in_use",
+            "table_width"}
+    assert set(st["kv_kinds"]) == {
+        "window", "full", "sliding", "kv_valid", "kv_visible", "kv_window",
+        "kv_window_blocks", "expert_rows_absent", "refused"}
+    assert set(st["kv_kinds"]["full"]) == kind
+    assert set(st["kv_kinds"]["sliding"]) == kind | {
+        "released", "block_size", "token_bytes"}
+    reach = {"kv_window", "kv_window_blocks"}
+    for name in ("decode", "prefill"):
+        spans = [e["args"] for e in srv.timeline.events()
+                 if e["ph"] == "X" and e["name"] == name]
+        assert spans and all(
+            (walk | reach | set(sparse_index_attention.COUNTS)) <= set(a)
+            for a in spans), name
+    assert st["decode_attn"] is None and st["kv_state"] is None
+    srv.close()
+
+
 def test_engine_stats_keys_pinned_with_draft_pool_extras(served):
     """The only engine stats() extension point: a draft pool adds its
     two byte-accounting keys (PR 5 behavior, unchanged)."""

@@ -258,3 +258,143 @@ def test_sharded_or_quantized_pools_are_refused_by_name():
         sia.paged_sparse_attention(
             q, record, record, pools["idx"], q[:, :2], jnp.ones((2, 1, 2)),
             bt, pos, topk=32, layer=1)
+
+
+# ISSUE 61: the selection over a LATENT pool (``paged_sparse_latent_attention``):
+# the same scores and threshold, the absorbed read under them.
+L_RANK, L_W, L_DI = 128, 256, 128
+
+
+def _latent_read_case(seed, b, t, topk, pos, valid=None, h=8, hi=2, bs=8,
+                      nbper=16, layers=2, layer=1):
+    """``paged_sparse_latent_attention`` against a per-query loop over the
+    gathered latents: -> ``(got, want, counts, the dense latent read)``."""
+    rng = np.random.default_rng(seed)
+    nb = 1 + b * nbper
+    f32 = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
+    pool, idx = f32(layers, nb, 1, bs, L_W), f32(layers, nb, 1, bs, L_DI)
+    bt = jnp.asarray(1 + rng.permutation(b * nbper).reshape(b, nbper),
+                     jnp.int32)
+    q, qi, wi = f32(b, h, t, L_W) * 0.1, f32(b, hi, t, L_DI), f32(b, t, hi)
+    pos = jnp.asarray(pos, jnp.int32)
+    valid = None if valid is None else jnp.asarray(valid, jnp.int32)
+    got, counts = sia.paged_sparse_latent_attention(
+        q, pool, idx, qi, wi, bt, pos, rank=L_RANK, topk=topk, layer=layer,
+        valid=valid)
+    lat = np.asarray(paged_kv.paged_gather(pool, bt, layer=layer),
+                     np.float64)[:, 0]
+    ki = np.asarray(paged_kv.paged_gather(idx, bt, layer=layer),
+                    np.float64)[:, 0]
+    last = np.asarray(sia.last_visible(pos, t, b, valid))
+    q64, qi64, wi64 = (np.asarray(a, np.float64) for a in (q, qi, wi))
+    want = np.zeros((b, h, t, L_RANK))
+    for row in range(b):
+        for i in range(t):
+            n = last[row, i] + 1
+            if n <= 0:
+                continue
+            score = (wi64[row, i][:, None] * np.maximum(
+                qi64[row, :, i] @ ki[row, :n].T, 0)).sum(0)
+            keep = np.argsort(-score, kind="stable")[:topk]
+            tile = lat[row, :n][keep]
+            s = q64[row, :, i] @ tile.T
+            p = np.exp(s - s.max(-1, keepdims=True))
+            want[row, :, i] = (p / p.sum(-1, keepdims=True)) \
+                @ tile[:, :L_RANK]
+    dense = da.paged_latent_attention(q, pool, bt, pos, rank=L_RANK,
+                                      layer=layer, valid=valid)
+    return np.asarray(got), want, dict(zip(
+        sia.COUNTS, np.asarray(counts).tolist())), np.asarray(dense)
+
+
+@pytest.mark.parametrize("name,t,pos,valid", [
+    ("decode", 1, [100, 5, 40], None),            # rows past and under topk
+    ("exactly-topk-and-one-more", 1, [31, 32, 33], None),
+    ("chunk-straddles-topk", 16, [24, 96, 0], [16, 16, 9]),
+    ("chunk-with-a-pad-row", 16, [64, 0, 40], [16, 0, 3]),
+])
+def test_latent_read_agrees_with_a_per_query_loop(name, t, pos, valid):
+    got, want, counts, _ = _latent_read_case(7, 3, t, 32, pos, valid)
+    real = np.ones(got.shape[:1] + got.shape[2:3], bool) if valid is None \
+        else np.arange(t)[None, :] < np.asarray(valid)[:, None]
+    mask = real[:, None, :, None]
+    np.testing.assert_allclose(got * mask, want * mask, atol=2e-5)
+    ctx = np.asarray(pos)[:, None] + np.arange(t)[None, :] + 1
+    n = ctx[real]
+    assert counts["index_keys"] == counts["kv_valid"] == n.sum()
+    assert counts["kv_selected"] == np.minimum(n, 32).sum()
+    assert counts["sparse_rows"] == int(((ctx > 32) & real).any(axis=1).sum())
+
+
+def test_latent_rows_under_topk_take_the_dense_walk():
+    # no row past topk (a context of exactly topk keys too): the dense
+    # latent walk, bit for bit; one key more and the row selects
+    got, want, counts, dense = _latent_read_case(3, 3, 1, 32, [31, 5, 12])
+    np.testing.assert_array_equal(got, dense)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    assert [counts[k] for k in sia.COUNTS] == [0, 51, 51, 0, 32 + 8 + 16]
+    got, want, counts, dense = _latent_read_case(3, 3, 1, 32, [32, 5, 12])
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    assert counts["sparse_rows"] == 1 and counts["kv_selected"] == 32 + 6 + 13
+    assert np.abs(got[0] - dense[0]).max() > 1e-4
+    np.testing.assert_allclose(got[1:], dense[1:], atol=2e-5)
+
+
+@pytest.mark.parametrize("name,t,pos,valid,local", [
+    ("decode", 1, [300, 5, 140, 0], None, False),
+    ("decode-local-choices", 1, [500, 260, 140, 33], None, True),
+    ("chunk-straddles-topk", 16, [24, 296, 0, 100], [16, 16, 9, 0], False),
+    ("chunk-local-choices", 8, [400, 96, 0, 100], [8, 8, 3, 8], True),
+])
+def test_latent_read_kernel_is_the_masked_walk(name, t, pos, valid, local):
+    # ``paged_sparse_latent_attn`` on a simulated chip (NaN landing buffers:
+    # a slot nobody copied must stay masked; the race detector) against the
+    # XLA walk on the same scores and thresholds; ``local``: scores that
+    # fall with distance, so that whole blocks and whole tiles hold no
+    # chosen key and are neither copied nor attended; every block no query
+    # chose is NaN in the pool
+    from jax.experimental.pallas import tpu as pltpu
+
+    rng = np.random.default_rng(len(name))
+    b, h, bs, nbper, layers, layer, topk = 4, 8, 8, 64, 2, 1, 32
+    nb = 1 + b * nbper
+    f32 = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
+    pool = f32(layers, nb, 1, bs, L_W)
+    bt = jnp.asarray(1 + rng.permutation(b * nbper).reshape(b, nbper),
+                     jnp.int32)
+    q = f32(b, h, t, L_W) * 0.1
+    pos = jnp.asarray(pos, jnp.int32)
+    valid = None if valid is None else jnp.asarray(valid, jnp.int32)
+    last = sia.last_visible(pos, t, b, valid)
+    s = jnp.arange(nbper * bs)
+    scores = f32(b, t, nbper * bs)
+    if local:
+        scores = scores - 0.5 * jnp.abs(
+            s[None, None, :] - last[:, :, None] // 2).astype(jnp.float32)
+    scores = jnp.where(s[None, None, :] <= last[:, :, None], scores,
+                       -jnp.inf)
+    theta, s_last = sia.select_threshold_reference(scores, topk)
+    keep = sia.chosen(scores, theta, s_last, last)
+    real = last >= 0 if valid is None else (
+        jnp.arange(t)[None, :] < valid[:, None]) & (last >= 0)
+    hit = np.asarray(jnp.any((keep & real[:, :, None])
+                             .reshape(b, t, nbper, bs), axis=(1, 3)))
+    if local:
+        assert hit.mean() < 0.5
+    want = sia._masked_latent_walk(q, pool, bt, keep, last, layer, L_RANK)
+    holed = np.array(pool)
+    for row in range(b):
+        holed[:, np.asarray(bt)[row][~hit[row]]] = np.nan
+    holed[:, 0] = np.nan
+    got, landed = da.paged_sparse_latent_attention_pallas(
+        q, jnp.asarray(holed), bt, scores, theta, s_last, last, rank=L_RANK,
+        layer=layer, real=real, interpret=pltpu.InterpretParams(
+            dma_execution_mode="on_wait", detect_races=True))
+    mask = np.asarray(real)[:, None, :, None]
+    assert np.isfinite(np.asarray(got)).all(), "a block no query chose"
+    np.testing.assert_allclose(np.asarray(got) * mask,
+                               np.asarray(want) * mask, atol=2e-5)
+    # a decode step lands each hit block once; a chunk's steps each their own
+    assert int(landed) == hit.sum() if t <= 8 else int(landed) >= hit.sum()
+    if valid is not None:
+        assert not np.asarray(got)[np.asarray(valid) == 0].any()
