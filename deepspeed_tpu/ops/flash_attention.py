@@ -45,8 +45,7 @@ def _in_band(run, qi, ki, block_q: int, block_k: int, window: int):
     sees — its last column lies inside the first row's window."""
     if not window:
         return run
-    return jnp.logical_and(
-        run, ki * block_k + block_k - 1 > qi * block_q - window)
+    return run & (ki * block_k + block_k - 1 > qi * block_q - window)
 
 
 def _band_mask(mask, row, col, window: int):
@@ -72,6 +71,219 @@ def _band_q(j, i, block_q: int, block_k: int, window: int, nq: int):
         return i
     hi = jnp.minimum((j * block_k + block_k + window - 2) // block_q, nq - 1)
     return jnp.clip(i, (j * block_k) // block_q, hi)
+
+
+# ---------------------------------------------------------------------------
+# strips: inside a grid step the work follows the visible pairs.
+#
+# A grid step costs ~0.4 us whatever it computes, so the tiles are large
+# (1024 x 1024 in every training cell) — and a tile the causal diagonal or a
+# window's lower edge crosses was computed whole, half of it for the mask to
+# throw away.  With ``strip`` = t > 0 a tile is a grid of t x t sub-tiles,
+# each of them wholly visible, wholly hidden, or cut by ONE edge at its own
+# diagonal (``_tile_strips``); a step then
+#  - is *skipped* where no sub-tile holds a visible pair (as always),
+#  - is *interior* where all of them are visible: one body, no iota, no
+#    compare, no select,
+#  - is an *edge* otherwise: strips of t keys or t queries, each multiplied
+#    against the other side only as far as some pair of it is visible, and
+#    masked only in the sub-tile an edge crosses.
+# Which of these a step is depends on where its first row stands against its
+# first column (``qi * block_q - ki * block_k``): the few values that give
+# an edge are enumerated at trace time, a body each (``_kinds``).  That
+# needs the edges at multiples of t inside the tile; any other call
+# (``_resolve_strip``: W = 1,000, 96-row blocks) has ``strip`` = 0, ONE kind
+# and the whole-tile masked body.  Masked lanes contributed exact zeros, so
+# leaving them out changes no value, only the order of some float32 sums.
+#
+# Which side a strip runs along is each kernel's own.  The compiler's own
+# schedule of these kernels (``benchmarks/flash_bundles.py``: bundles a grid
+# step and the share of them each unit is busy, read without a chip) shows a
+# whole tile bound by the MXU's streamed rows — 13.6 cycles a packed 16-row
+# push on each of four MXUs, 90 % busy — so a strip saves what it does not
+# stream, PROVIDED nothing else takes the MXU's place: cross-lane reductions
+# and lane permutes (one a row a PIECE, 2.6 x a whole tile's), and spills.
+# So the forward and ``dq`` take strips of KEYS (K / V rows latched, the
+# queries that see them streamed); the forward in two passes (``_fwd_tile``:
+# every piece's scores, then one softmax update a row segment, its
+# statistics kept in the scratch's lane-replicated form and folded lane-wise
+# before the one cross-lane reduction); ``dkv`` and the fused backward
+# strips of QUERIES over scores held TRANSPOSED, ``[keys, queries]`` (q / do
+# rows latched, the keys streamed; p^T and ds^T are what dv and dk multiply,
+# lse / delta are read in the lane layout they are stored in, and the fused
+# backward's dp needs nothing of the softmax and runs beside the scores).
+# ---------------------------------------------------------------------------
+#: keys or queries of a strip, chosen from the kernels' schedules and their
+#: times alone on a v5e at the training cells' calls (PERF.md section 6,
+#: PR 62): 512 computes 3/4 of an edge tile where 256 computes 5/8; at 128
+#: ``dq`` and the fused backward schedule worse than at 256
+_STRIP = 256
+#: unrolled strip bodies a kernel may hold before the call keeps whole tiles
+#: (block_q != block_k multiplies the offsets an edge can stand at; a
+#: kernel's text is start-up seconds)
+_MAX_STRIPS = 24
+
+#: what of the mask a piece of scores still needs: the causal diagonal, the
+#: window's lower edge (its width; 0: not), the padded keys
+_Mask = collections.namedtuple("_Mask", "causal window pad")
+#: one body of a kernel: ``spans`` — ``(lo, hi, last)``: the steps whose
+#: first row stands ``lo..hi`` past their first column, on the last key
+#: block only / not (``None``: either) — or ``None``, every step that runs;
+#: ``strips`` — ``(start, size, pieces)`` with ``pieces`` of ``(lo, hi,
+#: mask)`` along the other side of the tile, ``mask`` a ``_Mask`` or None
+_Kind = collections.namedtuple("_Kind", "spans strips")
+
+
+def _tile_strips(d: int, last: bool, by_cols: bool, causal: bool,
+                 block_q: int, block_k: int, window: int, strip: int,
+                 kv_left: int):
+    """The strips of a tile whose first row stands ``d`` past its first
+    column (``last``: its keys end at ``kv_left``), () if it holds no
+    visible pair.  Sub-tile (i, j) lies ``u = i - j + d / strip`` strips
+    under the diagonal: hidden above it (u < 0) and past the window
+    (u > W / strip), cut at u == 0 and at u == W / strip, whole between."""
+    t, w = strip, window // strip
+
+    def kind(i, j):
+        u = i - j + d // t
+        if (causal and u < 0) or (window and u > w) \
+                or (last and j * t >= kv_left):
+            return False
+        mask = _Mask(causal and u == 0, window if window and u == w else 0,
+                     last and (j + 1) * t > kv_left)
+        return mask if any(mask) else None
+
+    n_strips, n_pieces = ((block_k, block_q) if by_cols
+                          else (block_q, block_k))
+    strips = []
+    for a in range(n_strips // t):
+        pieces = []
+        for b in range(n_pieces // t):
+            k = kind(b, a) if by_cols else kind(a, b)
+            if k is False:
+                continue
+            if k is None and pieces and pieces[-1][1:] == (b * t, None):
+                pieces[-1] = (pieces[-1][0], (b + 1) * t, None)
+            else:
+                pieces.append((b * t, (b + 1) * t, k))
+        strips.append((a * t, t, tuple(pieces)))
+    if not any(pieces for _, _, pieces in strips):
+        return ()
+    if all(pieces == ((0, n_pieces, None),) for _, _, pieces in strips):
+        return ((0, n_strips, ((0, n_pieces, None),)),)    # interior
+    return tuple(strips)
+
+
+@functools.lru_cache(maxsize=None)
+def _kinds(causal: bool, block_q: int, block_k: int, nq: int, nk: int,
+           kv_len: int, window: int, strip: int, by_cols: bool = False):
+    """The bodies a kernel over an ``nq x nk`` grid of tiles holds (v2:
+    ``nk`` = 1, ``block_k`` the resident length)."""
+    padded = kv_len != nk * block_k
+    if not strip:
+        mask = _Mask(causal, window, padded)
+        sides = (block_k, block_q) if by_cols else (block_q, block_k)
+        return (_Kind(None, ((0, sides[0],
+                              ((0, sides[1], mask if any(mask) else None),)),
+                             )),)
+    kv_left = kv_len - (nk - 1) * block_k
+    keys = sorted({(qi * block_q - ki * block_k, padded and ki == nk - 1)
+                   for qi in range(nq) for ki in range(nk)})
+    groups = {}
+    for d, last in keys:
+        strips = _tile_strips(d, last, by_cols, causal, block_q, block_k,
+                              window, strip, kv_left)
+        if strips:
+            groups.setdefault(strips, []).append((d, last))
+    kinds = []
+    for strips, members in groups.items():
+        spans = []
+        for last in (False, True):
+            ds = [d for d, l in members if l == last]
+            if not ds:
+                continue
+            # the sub-tiles' kinds are monotone in d: a body's offsets are
+            # an interval of those the grid has
+            assert not any(min(ds) <= d <= max(ds) and l == last
+                           for d, l in keys if (d, l) not in members)
+            spans.append((min(ds), max(ds), last if padded else None))
+        kinds.append(_Kind(tuple(spans), strips))
+    return tuple(kinds)
+
+
+def _all(*conds):
+    """``and`` of conditions of which some are plain ``True``."""
+    conds = [c for c in conds if c is not True]
+    return functools.reduce(jnp.logical_and, conds) if conds else True
+
+
+def _tiles(qi, ki, *, causal: bool, block_q: int, block_k: int, nq: int,
+           nk: int, kv_len: int, window: int, strip: int,
+           by_cols: bool = False):
+    """The bodies of grid step ``(qi, ki)``: ``(when, (row, col, kv), strips)``
+    — run ``strips`` where ``when`` holds; ``row`` / ``col``: where the tile's
+    first pair stands and ``kv`` where the keys end, on one scale (static
+    inside a strip body, so its masks are constants)."""
+    kinds = _kinds(causal, block_q, block_k, nq, nk, kv_len, window, strip,
+                   by_cols)
+    if not strip:
+        run = True
+        if nk > 1:      # one resident block: every query sees key 0 of it
+            run = (ki * block_k <= qi * block_q + block_q - 1) if causal \
+                else True
+            run = _in_band(run, qi, ki, block_q, block_k, window)
+        return [(run, (qi * block_q, ki * block_k, kv_len), kinds[0].strips)]
+    delta = qi * block_q - ki * block_k
+    bodies = []
+    for spans, strips in kinds:
+        when = []
+        for lo, hi, last in spans:
+            when.append(_all(
+                lo <= -(nk - 1) * block_k or delta >= lo,
+                hi >= (nq - 1) * block_q or delta <= hi,
+                last is None or nk == 1
+                or ((ki == nk - 1) if last else (ki < nk - 1))))
+        when = True if any(c is True for c in when) \
+            else functools.reduce(jnp.logical_or, when)
+        bodies.append((when, (spans[0][0], 0, kv_len - (nk - 1) * block_k),
+                       strips))
+    return bodies
+
+
+def _when(cond):
+    """``pl.when``, and no branch at all where ``cond`` is plain True."""
+    return (lambda body: body()) if cond is True else pl.when(cond)
+
+
+def _masked(s2, mask, row, col, kv_len, keys_first: bool = False):
+    """``s2`` with the pairs ``mask`` hides at the mask value; ``s2[0, 0]``
+    is the pair (``row``, ``col``); ``keys_first``: ``s2`` is ``[keys,
+    queries]``."""
+    if mask is None:
+        return s2
+    if isinstance(row, int) and isinstance(col, int):
+        row, col, kv_len = row - col, 0, kv_len - col
+
+    def iota(side, first):
+        at = jax.lax.broadcasted_iota(jnp.int32, s2.shape, side)
+        return at if isinstance(first, int) and first == 0 else first + at
+
+    cols = iota(0 if keys_first else 1, col)
+    keep = [cols < kv_len] if mask.pad else []
+    if mask.causal or mask.window:
+        rows = iota(1 if keys_first else 0, row)
+        if mask.causal:
+            keep.append(rows >= cols)
+        if mask.window:
+            keep.append(rows - cols < mask.window)
+    return jnp.where(functools.reduce(jnp.logical_and, keep), s2,
+                     DEFAULT_MASK_VALUE)
+
+
+def _dot(a, b, contract):
+    """``a`` x ``b`` over dimensions ``contract``, float32 out of the MXU."""
+    return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -545,10 +757,11 @@ KERNELS = {"v1": ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"),
                   "flash_bwd_dkv_chunked")}
 #: one resolution: the operands' lengths and head width, the generation they
 #: dispatch to, the blocks, whether the rule chose them or the caller gave
-#: them, and the sliding window the call was made with (0: none)
+#: them, the sliding window the call was made with (0: none), the rows of a
+#: strip inside a tile (0: whole tiles; ``_resolve_strip``), causal or not
 Choice = collections.namedtuple(
-    "Choice", "q_len kv_len d generation block_q block_k how window",
-    defaults=(0,))
+    "Choice", "q_len kv_len d generation block_q block_k how window strip "
+    "causal", defaults=(0, 0, True))
 _CHOICES: Dict[Choice, int] = {}
 _CHOICES_LOCK = threading.Lock()
 
@@ -643,6 +856,55 @@ def _resolve_blocks(q_len: int, kv_len: int, d: int, itemsize: int,
     return (Choice(q_len, kv_len, d, generation, bq, bk, how), pad_q, pad_k)
 
 
+def _resolve_strip(generation: str, causal: bool, q_pad: int, kv_pad: int,
+                   kv_len: int, block_q: int, block_k: int,
+                   window: int) -> int:
+    """Rows of a strip inside a tile, 0 for whole tiles: ``_STRIP`` where
+    the blocks (v2: the resident keys) and the window are multiples of it —
+    every edge then stands at a multiple of it inside its tile — and the
+    kernels stay under ``_MAX_STRIPS`` unrolled strip bodies.  Read from the
+    shapes at trace time; v1 has no cell and no chip reading and keeps whole
+    tiles."""
+    t = _STRIP
+    if generation == "v2":
+        block_k = kv_pad
+    if generation == "v1" or block_q % t or block_k % t or window % t:
+        return 0
+    kinds = _kinds(causal, block_q, block_k, q_pad // block_q,
+                   kv_pad // block_k, kv_len, window, t)
+    bodies = sum(1 for kind in kinds for _, _, pieces in kind.strips
+                 if pieces)
+    return t if bodies <= _MAX_STRIPS else 0
+
+
+def computed_pairs(choice: Choice):
+    """``(visible, computed)`` (query, key) pairs of one head's call: what
+    the mask lets through, and what the kernels multiply for it — whole
+    tiles, or strips of ``choice.strip`` rows — by the arithmetic the
+    kernels branch on (``_kinds``).  The forward, ``dq`` and ``dkv`` compute
+    the same sub-tiles."""
+    c = choice
+    visible = sum(
+        max(0, (min(p + 1, c.kv_len) if c.causal else c.kv_len)
+            - (max(0, p + 1 - c.window) if c.window else 0))
+        for p in range(c.q_len))
+    q_pad = c.q_len + (-c.q_len) % c.block_q
+    kv_pad = c.kv_len + (-c.kv_len) % c.block_k
+    block_k = kv_pad if c.generation == "v2" else c.block_k
+    nq, nk = q_pad // c.block_q, kv_pad // block_k
+    computed = 0
+    for qi in range(nq):
+        for ki in range(nk):
+            for when, _, strips in _tiles(
+                    qi, ki, causal=c.causal, block_q=c.block_q,
+                    block_k=block_k, nq=nq, nk=nk, kv_len=c.kv_len,
+                    window=c.window, strip=c.strip):
+                if when:
+                    computed += sum(size * (hi - lo) for _, size, pieces
+                                    in strips for lo, hi, _ in pieces)
+    return visible, computed
+
+
 def _v2_compiler_params(dimension_semantics):
     """CompilerParams for the v2 kernels; ``DS_V2_VMEM_MB`` raises the
     per-kernel scoped-vmem budget (the fused v2 backward at kv_pad=2048
@@ -655,44 +917,116 @@ def _v2_compiler_params(dimension_semantics):
         vmem_limit_bytes=(int(float(vmem_mb) * 2**20) if vmem_mb else None))
 
 
+def _rows(x, start: int, size: int):
+    """Rows ``start .. start + size`` of a value (static; the value itself
+    where that is all of it)."""
+    return x if (start, size) == (0, x.shape[0]) else x[start:start + size]
+
+
+def _lanes(x, op):
+    """``x`` folded to its first 128 columns by ``op`` (elementwise, on the
+    vector unit), so that the cross-lane reduction — the scarce unit's — is
+    made once a row and not once a piece."""
+    if x.shape[1] <= LANES or x.shape[1] % LANES:
+        return x
+    return functools.reduce(op, [x[:, c:c + LANES]
+                                 for c in range(0, x.shape[1], LANES)])
+
+
+def _fit(x, n: int):
+    """A row statistic — ``[rows, 1]``, or ``[rows, 128]`` with every lane
+    the same, as the scratch holds it — against ``n`` columns.  (Slicing a
+    column out of the scratch's form and broadcasting it again goes through
+    the lane-permute unit, the busiest in the forward.)"""
+    if x.shape[1] in (1, n):
+        return x
+    if n < x.shape[1]:
+        return x[:, :n]
+    if n % x.shape[1]:
+        return x[:, :1]
+    return pltpu.repeat(x, n // x.shape[1], axis=1)
+
+
+def _fwd_tile(strips, at, qs, k_ref, v_ref, carried=None):
+    """The forward of one tile over ``strips`` of KEYS, in two passes:
+    every piece's scores first — a strip's K rows latched in the MXU once,
+    only the queries that see them streaming past — then ONE softmax update
+    a row segment over all the pieces that cover it, and the pieces'
+    ``p @ v`` likewise by strips of keys.  ``carried(a, b)``: the running
+    ``(m, l, acc)`` of rows ``a..b``, ``m`` and ``l`` in the scratch's
+    ``[rows, 128]`` (None: none yet).  Yields ``(a, b, m, l, acc)`` a
+    segment some piece covers, ``m`` and ``l`` in that form if carried."""
+    row, col, kv = at
+    pieces = [(start, cols, lo, hi, mask) for start, cols, on in strips
+              for lo, hi, mask in on]
+    s2 = [_masked(_dot(_rows(qs, lo, hi - lo),
+                       k_ref[0, pl.ds(start, cols), :], (1, 1)), mask,
+                  row + lo, col + start, kv)
+          for start, cols, lo, hi, mask in pieces]
+    tops = [_lanes(s, jnp.maximum) for s in s2]
+    bounds = sorted({x for _, _, lo, hi, _ in pieces for x in (lo, hi)})
+    segments = []
+    for a, b in zip(bounds, bounds[1:]):
+        over = [i for i, (_, _, lo, hi, _) in enumerate(pieces)
+                if lo <= a and b <= hi]
+        if not over:
+            continue
+        prev = carried(a, b) if carried else None
+        m = jnp.max(functools.reduce(jnp.maximum, [
+            _rows(tops[i], a - pieces[i][2], b - a) for i in over]),
+            axis=1, keepdims=True)
+        segments.append((a, b, over, prev,
+                         jnp.maximum(prev[0], m) if prev else m))
+    sums, outs = [], []
+    for (start, cols, lo, hi, _), s in zip(pieces, s2):
+        m = jnp.concatenate([m for a, b, _, _, m in segments
+                             if lo <= a and b <= hi], axis=0)
+        p = jnp.exp2(s - _fit(m, cols))                 # masked lanes -> 0
+        sums.append(_lanes(p, jnp.add))
+        outs.append(_dot(p.astype(v_ref.dtype),
+                         v_ref[0, pl.ds(start, cols), :], (1, 0)))
+    for a, b, over, prev, m in segments:
+        l = jnp.sum(sum(_rows(sums[i], a - pieces[i][2], b - a)
+                        for i in over), axis=1, keepdims=True)
+        acc = sum(_rows(outs[i], a - pieces[i][2], b - a) for i in over)
+        if prev:
+            alpha = jnp.exp2(prev[0] - m)
+            l = alpha * prev[1] + l
+            acc = prev[2] * _fit(alpha, acc.shape[1]) + acc
+        yield a, b, m, l, acc
+
+
 def _fwd_v2_kernel(q_ref, k_ref, v_ref, o_ref, *, scale2: float, causal: bool,
-                   block_q: int, kv_pad: int, kv_len: int, window: int = 0):
+                   block_q: int, kv_pad: int, kv_len: int, num_q_blocks: int,
+                   window: int = 0, strip: int = 0):
     qi = pl.program_id(1)
     # fold softmax scale AND log2(e) into q (one [bq, d] pass instead of a
     # [bq, S] one); exp2 is the native transcendental
     q = q_ref[0, ...]
     qs = (q.astype(jnp.float32) * scale2).astype(q.dtype)
-    k = k_ref[0, ...]
-    v = v_ref[0, ...]
-    s2 = jax.lax.dot_general(qs, k, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # [bq, S]
-    col = jax.lax.broadcasted_iota(jnp.int32, (block_q, kv_pad), 1)
-    if causal:
-        row = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, kv_pad), 0)
-        mask = _band_mask(col <= row, row, col, window)
-        if kv_len != kv_pad:
-            mask = jnp.logical_and(mask, col < kv_len)
-        s2 = jnp.where(mask, s2, DEFAULT_MASK_VALUE)
-    elif kv_len != kv_pad:
-        s2 = jnp.where(col < kv_len, s2, DEFAULT_MASK_VALUE)
-    m = jnp.max(s2, axis=1, keepdims=True)          # [bq, 1]
-    p = jnp.exp2(s2 - m)                            # masked lanes -> 0
-    l = jnp.sum(p, axis=1, keepdims=True)           # >= 1 for any valid row
-    acc = jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    o_ref[0, ...] = (acc / l).astype(o_ref.dtype)
+    for when, at, strips in _tiles(
+            qi, 0, causal=causal, block_q=block_q, block_k=kv_pad,
+            nq=num_q_blocks, nk=1, kv_len=kv_len, window=window, strip=strip,
+            by_cols=True):
+        @_when(when)
+        def _compute():
+            segments = list(_fwd_tile(strips, at, qs, k_ref, v_ref))
+            if sum(b - a for a, b, *_ in segments) < block_q:
+                # padded queries past a window's reach: unseen by any key
+                o_ref[0, ...] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+            for a, b, _, l, acc in segments:
+                o_ref[0, pl.ds(a, b - a), :] = (acc / l).astype(o_ref.dtype)
 
 
 def _fwd_v2(q, k, v, sm_scale, causal, block_q, interpret, true_kv_len,
-            head_rep, window: int = 0):
+            head_rep, window: int = 0, strip: int = 0):
     bh, q_len, d = q.shape
     kv_pad = k.shape[1]
     nq = pl.cdiv(q_len, block_q)
     kernel = functools.partial(
         _fwd_v2_kernel, scale2=sm_scale * _LOG2E, causal=causal,
-        block_q=block_q, kv_pad=kv_pad, kv_len=true_kv_len,
-        window=window)
+        block_q=block_q, kv_pad=kv_pad, kv_len=true_kv_len, num_q_blocks=nq,
+        window=window, strip=strip)
     rep = head_rep
     return pl.pallas_call(
         kernel,
@@ -713,7 +1047,8 @@ def _fwd_v2(q, k, v, sm_scale, causal, block_q, interpret, true_kv_len,
 def _bwd_v2_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, dq_ref, dk_ref, dv_ref,
                    dk_scr, dv_scr, *, scale2: float, sm_scale: float,
                    causal: bool, block_q: int, kv_pad: int, kv_len: int,
-                   num_q_blocks: int, rep: int, window: int = 0):
+                   num_q_blocks: int, rep: int, window: int = 0,
+                   strip: int = 0):
     inner = pl.program_id(1)
     qi = inner % num_q_blocks
 
@@ -722,51 +1057,52 @@ def _bwd_v2_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, dq_ref, dk_ref, dv_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
+    # scores TRANSPOSED, [keys, queries], in strips of QUERIES: dv, dk and the
+    # scores themselves then latch the strip's q / do rows and stream the
+    # keys it sees, and p^T, ds^T come out as dv and dk multiply them
     q = q_ref[0, ...]
-    qs = (q.astype(jnp.float32) * scale2).astype(q.dtype)
-    k = k_ref[0, ...]
-    v = v_ref[0, ...]
-    o = o_ref[0, ...]
-    do = do_ref[0, ...]
-
-    s2 = jax.lax.dot_general(qs, k, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # [bq, S]
-    col = jax.lax.broadcasted_iota(jnp.int32, (block_q, kv_pad), 1)
-    if causal:
-        row = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, kv_pad), 0)
-        mask = _band_mask(col <= row, row, col, window)
-        if kv_len != kv_pad:
-            mask = jnp.logical_and(mask, col < kv_len)
-        s2 = jnp.where(mask, s2, DEFAULT_MASK_VALUE)
-    elif kv_len != kv_pad:
-        s2 = jnp.where(col < kv_len, s2, DEFAULT_MASK_VALUE)
-    m = jnp.max(s2, axis=1, keepdims=True)
-    p0 = jnp.exp2(s2 - m)                           # l * softmax(s)
-    l = jnp.sum(p0, axis=1, keepdims=True)
-    linv = 1.0 / l                                  # [bq, 1]
-
-    do32 = do.astype(jnp.float32)
-    delta_s = jnp.sum(do32 * o.astype(jnp.float32), axis=1,
-                      keepdims=True) * linv         # delta / l, [bq, 1]
-    do_s = (do32 * linv).astype(do.dtype)           # do / l (folded softmax div)
-    # dp/l = (do/l) @ v^T ; ds = softmax*(dp-delta) = p0*(dp - delta)/l
-    dp_s = jax.lax.dot_general(do_s, v, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-    ds = p0 * (dp_s - delta_s)
-    ds_b = ds.astype(q.dtype)
-    # dv += softmax^T @ do = p0^T @ (do/l)
-    dv_scr[...] += jax.lax.dot_general(
-        p0.astype(do.dtype), do_s, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    # dk_true = ds^T @ (sm_scale*q) = (ds^T @ qs) * ln2   (qs = q*scale*log2e)
-    dk_scr[...] += jax.lax.dot_general(
-        ds_b, qs, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    # dq = sm_scale * (ds @ k)
-    dq = jax.lax.dot_general(ds_b, k, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    dq_ref[0, ...] = (dq * sm_scale).astype(dq_ref.dtype)
+    qs_all = (q.astype(jnp.float32) * scale2).astype(q.dtype)
+    for when, at, strips in _tiles(
+            qi, 0, causal=causal, block_q=block_q, block_k=kv_pad,
+            nq=num_q_blocks, nk=1, kv_len=kv_len, window=window, strip=strip):
+        @_when(when)
+        def _compute():
+            row, col, kv = at
+            for start, rows, pieces in strips:
+                at_rows = pl.ds(start, rows)
+                if not pieces:      # padded queries past a window: unseen
+                    dq_ref[0, at_rows, :] = jnp.zeros(
+                        (rows,) + dq_ref.shape[2:], dq_ref.dtype)
+                    continue
+                qs = _rows(qs_all, start, rows)
+                do = do_ref[0, at_rows, :]
+                s2 = [_masked(_dot(k_ref[0, pl.ds(lo, hi - lo), :], qs,
+                                   (1, 1)), mask, row + start, col + lo, kv,
+                              keys_first=True) for lo, hi, mask in pieces]
+                # dp needs nothing of the softmax: the MXU takes it beside
+                # the scores (1 / l goes into p, not into do)
+                dp = [_dot(v_ref[0, pl.ds(lo, hi - lo), :], do, (1, 1))
+                      for lo, hi, _ in pieces]
+                m = functools.reduce(jnp.maximum, [
+                    jnp.max(s, axis=0, keepdims=True) for s in s2])
+                p0 = [jnp.exp2(s - m) for s in s2]      # l * softmax(s)^T
+                linv = 1.0 / sum(jnp.sum(x, axis=0, keepdims=True)
+                                 for x in p0)           # [1, rows]
+                delta = jnp.sum(
+                    do.astype(jnp.float32)
+                    * o_ref[0, at_rows, :].astype(jnp.float32), axis=1,
+                    keepdims=True).reshape(1, rows)     # sublane -> lane
+                dq = 0.0
+                for x, y, (lo, hi, _) in zip(p0, dp, pieces):
+                    keys = pl.ds(lo, hi - lo)
+                    p = x * linv                        # softmax(s)^T
+                    ds = (p * (y - delta)).astype(q.dtype)
+                    dv_scr[keys, :] += _dot(p.astype(do.dtype), do, (1, 0))
+                    # dk_true = ds^T @ (sm_scale*q) = (ds^T @ qs) * ln2
+                    dk_scr[keys, :] += _dot(ds, qs, (1, 0))
+                    # dq = sm_scale * (ds @ k)
+                    dq = dq + _dot(ds, k_ref[0, keys, :], (0, 0))
+                dq_ref[0, at_rows, :] = (dq * sm_scale).astype(dq_ref.dtype)
 
     @pl.when(inner == rep * num_q_blocks - 1)
     def _finalize():
@@ -775,7 +1111,7 @@ def _bwd_v2_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, dq_ref, dk_ref, dv_ref,
 
 
 def _bwd_v2(q, k, v, o, do, sm_scale, causal, block_q, interpret, true_kv_len,
-            head_rep, window: int = 0):
+            head_rep, window: int = 0, strip: int = 0):
     bh, q_len, d = q.shape
     bh_kv, kv_pad, _ = k.shape
     nq = pl.cdiv(q_len, block_q)
@@ -783,7 +1119,7 @@ def _bwd_v2(q, k, v, o, do, sm_scale, causal, block_q, interpret, true_kv_len,
     kernel = functools.partial(
         _bwd_v2_kernel, scale2=sm_scale * _LOG2E, sm_scale=sm_scale,
         causal=causal, block_q=block_q, kv_pad=kv_pad, kv_len=true_kv_len,
-        num_q_blocks=nq, rep=rep, window=window)
+        num_q_blocks=nq, rep=rep, window=window, strip=strip)
     q_map = lambda b, i: (b * rep + i // nq, i % nq, 0)
     kv_map = lambda b, i: (b, 0, 0)
     dq, dk, dv = pl.pallas_call(
@@ -837,7 +1173,24 @@ def _bwd_v2(q, k, v, o, do, sm_scale, causal, block_q, interpret, true_kv_len,
 #    VMEM cliff at S=8192 (compile failure) and slower at 2048/4096.
 #  - dq-partials-summed-by-XLA fused variant: partial-write traffic costs
 #    more than the two matmuls it saves.
-#  - masked/unmasked chunk-body forking: no measurable win.
+#  - masked/unmasked chunk-body forking ALONE: no measurable win in ``dq``
+#    and ``dkv`` — round 4 at 128 x 128 blocks, where a step was ~0.4 us of
+#    overhead around 0.02 us of work, and again in PR 62 at 1024 x 1024
+#    (fork on / off within 2 % on the chip; 6,062 against 6,009 bundles a
+#    ``dq`` tile: the mask's vector work hides under the matrix products).
+#    The fork stays because the strips need an unmasked body for the
+#    sub-tiles anyway, and the forward, whose vector units are the busier,
+#    does gain from it (6,823 -> 6,313 bundles a tile).  What PR 62 gained
+#    came from not MULTIPLYING the hidden half of the edge tiles.
+#  - PR 62, on the chip at [8,32,2048,64]: row strips of queries in the
+#    forward (a cross-lane reduction and a scratch update a strip and piece:
+#    +5 % at t = 256), key strips with an online-softmax update a strip
+#    (the running max / sum / output of 1,024 rows re-read, lane-sliced and
+#    re-written a strip: +11 %), one piece a strip under the union of its
+#    masks (no better than masking the one cut sub-tile), interior tiles in
+#    strips (no better than whole), the backward's sums carried as values
+#    to one scratch update a block (-1..2 % of the bundles: not worth its
+#    lines).
 # Reference parity: csrc/transformer/ds_transformer_cuda.cpp:78-121 claims
 # fused-kernel supremacy at its benchmark shapes; this path is what makes
 # the S=4096-8192 driver configs run on the measured-best kernels.
@@ -845,8 +1198,8 @@ def _bwd_v2(q, k, v, o, do, sm_scale, causal, block_q, interpret, true_kv_len,
 
 
 def _fwd_v3_kernel(*refs, scale2: float, causal: bool, block_q: int,
-                   block_k: int, kv_pad: int, kv_len: int, num_k_blocks: int,
-                   window: int = 0):
+                   block_k: int, kv_len: int, num_q_blocks: int,
+                   num_k_blocks: int, window: int = 0, strip: int = 0):
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -857,49 +1210,38 @@ def _fwd_v3_kernel(*refs, scale2: float, causal: bool, block_q: int,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    run = (ki * block_k <= qi * block_q + block_q - 1) if causal else True
-    run = _in_band(run, qi, ki, block_q, block_k, window)
+    for when, at, strips in _tiles(
+            qi, ki, causal=causal, block_q=block_q, block_k=block_k,
+            nq=num_q_blocks, nk=num_k_blocks, kv_len=kv_len, window=window,
+            strip=strip, by_cols=True):
+        @_when(when)
+        def _compute():
+            q = q_ref[0, ...]
+            qs = (q.astype(jnp.float32) * scale2).astype(q.dtype)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0, ...]
-        qs = (q.astype(jnp.float32) * scale2).astype(q.dtype)
-        k = k_ref[0, ...]
-        v = v_ref[0, ...]
-        s2 = jax.lax.dot_general(qs, k, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        col = ki * block_k + jax.lax.broadcasted_iota(jnp.int32,
-                                                      (block_q, block_k), 1)
-        mask = col < kv_len
-        if causal:
-            row = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            mask = _band_mask(jnp.logical_and(mask, row >= col), row, col,
-                              window)
-        s2 = jnp.where(mask, s2, DEFAULT_MASK_VALUE)
-        m_prev = m_scr[...][:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s2, axis=1, keepdims=True))
-        alpha = jnp.exp2(m_prev - m_new)
-        p = jnp.exp2(s2 - m_new)
-        l_scr[...] = jnp.broadcast_to(
-            alpha * l_scr[...][:, :1] + jnp.sum(p, axis=1, keepdims=True),
-            l_scr.shape)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+            def carried(a, b):
+                rows = pl.ds(a, b - a)
+                return m_scr[rows, :], l_scr[rows, :], acc_scr[rows, :]
+
+            for a, b, m, l, acc in _fwd_tile(strips, at, qs, k_ref, v_ref,
+                                             carried):
+                rows = pl.ds(a, b - a)
+                m_scr[rows, :] = m
+                l_scr[rows, :] = l
+                acc_scr[rows, :] = acc
 
     @pl.when(ki == num_k_blocks - 1)
     def _finalize():
-        l = l_scr[...][:, :1]
+        l = l_scr[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, ...] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
-        lse2 = m_scr[...][:, :1] + jnp.log2(l_safe)   # exp2-domain lse
-        lse_ref[0, ...] = lse2.reshape(1, block_q)    # sublane -> lane
+        o_ref[0, ...] = (acc_scr[...] / _fit(l_safe, acc_scr.shape[1])
+                         ).astype(o_ref.dtype)
+        lse2 = m_scr[...] + jnp.log2(l_safe)          # exp2-domain lse
+        lse_ref[0, ...] = lse2[:, :1].reshape(1, block_q)   # sublane -> lane
 
 
 def _fwd_v3(q, k, v, sm_scale, causal, block_q, block_k, interpret,
-            true_kv_len, head_rep, window: int = 0):
+            true_kv_len, head_rep, window: int = 0, strip: int = 0):
     bh, q_len, d = q.shape
     kv_pad = k.shape[1]
     nq = pl.cdiv(q_len, block_q)
@@ -907,8 +1249,8 @@ def _fwd_v3(q, k, v, sm_scale, causal, block_q, block_k, interpret,
     rep = head_rep
     kernel = functools.partial(
         _fwd_v3_kernel, scale2=sm_scale * _LOG2E, causal=causal,
-        block_q=block_q, block_k=block_k, kv_pad=kv_pad, kv_len=true_kv_len,
-        num_k_blocks=nk, window=window)
+        block_q=block_q, block_k=block_k, kv_len=true_kv_len,
+        num_q_blocks=nq, num_k_blocks=nk, window=window, strip=strip)
     kv_map = lambda b, i, j: (
         b // rep, _band_k(i, j, block_q, block_k, window), 0)
     o, lse = pl.pallas_call(
@@ -942,7 +1284,8 @@ def _fwd_v3(q, k, v, sm_scale, causal, block_q, block_k, interpret,
 
 def _bwd_v3_dq_kernel(*refs, scale2: float, sm_scale: float, causal: bool,
                       block_q: int, block_k: int, kv_len: int,
-                      num_k_blocks: int, window: int = 0):
+                      num_q_blocks: int, num_k_blocks: int, window: int = 0,
+                      strip: int = 0):
     (q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, dq_scr) = refs
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -951,36 +1294,30 @@ def _bwd_v3_dq_kernel(*refs, scale2: float, sm_scale: float, causal: bool,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    run = (ki * block_k <= qi * block_q + block_q - 1) if causal else True
-    run = _in_band(run, qi, ki, block_q, block_k, window)
-
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0, ...]
-        k = k_ref[0, ...]
-        v = v_ref[0, ...]
-        do = do_ref[0, ...]
-        qs = (q.astype(jnp.float32) * scale2).astype(q.dtype)
-        lse2 = lse_ref[0, ...].reshape(block_q, 1)   # lane -> sublane
-        delta = dl_ref[0, ...].reshape(block_q, 1)
-        s2 = jax.lax.dot_general(qs, k, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        col = ki * block_k + jax.lax.broadcasted_iota(jnp.int32,
-                                                      (block_q, block_k), 1)
-        mask = col < kv_len
-        if causal:
-            row = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            mask = _band_mask(jnp.logical_and(mask, row >= col), row, col,
-                              window)
-        s2 = jnp.where(mask, s2, DEFAULT_MASK_VALUE)
-        p = jnp.exp2(s2 - lse2)                      # true softmax probs
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta)).astype(k.dtype)
-        dq_scr[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    # strips of KEYS, as the forward: k and v latched, the queries stream
+    for when, at, strips in _tiles(
+            qi, ki, causal=causal, block_q=block_q, block_k=block_k,
+            nq=num_q_blocks, nk=num_k_blocks, kv_len=kv_len, window=window,
+            strip=strip, by_cols=True):
+        @_when(when)
+        def _compute():
+            row, col, kv = at
+            q = q_ref[0, ...]
+            do_all = do_ref[0, ...]
+            qs = (q.astype(jnp.float32) * scale2).astype(q.dtype)
+            lse2 = lse_ref[0, ...].reshape(block_q, 1)   # lane -> sublane
+            delta = dl_ref[0, ...].reshape(block_q, 1)
+            for start, cols, pieces in strips:
+                k = k_ref[0, pl.ds(start, cols), :]
+                v = v_ref[0, pl.ds(start, cols), :]
+                for lo, hi, mask in pieces:
+                    s2 = _masked(_dot(_rows(qs, lo, hi - lo), k, (1, 1)),
+                                 mask, row + lo, col + start, kv)
+                    p = jnp.exp2(s2 - _rows(lse2, lo, hi - lo))  # true probs
+                    dp = _dot(_rows(do_all, lo, hi - lo), v, (1, 1))
+                    ds = (p * (dp - _rows(delta, lo, hi - lo))).astype(
+                        q.dtype)
+                    dq_scr[pl.ds(lo, hi - lo), :] += _dot(ds, k, (1, 0))
 
     @pl.when(ki == num_k_blocks - 1)
     def _finalize():
@@ -989,7 +1326,8 @@ def _bwd_v3_dq_kernel(*refs, scale2: float, sm_scale: float, causal: bool,
 
 def _bwd_v3_dkv_kernel(*refs, scale2: float, causal: bool, block_q: int,
                        block_k: int, kv_len: int, num_q_blocks: int,
-                       rep: int, window: int = 0):
+                       num_k_blocks: int, rep: int, window: int = 0,
+                       strip: int = 0):
     (q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref, dv_ref,
      dk_scr, dv_scr) = refs
     ki = pl.program_id(1)
@@ -1001,40 +1339,35 @@ def _bwd_v3_dkv_kernel(*refs, scale2: float, causal: bool, block_q: int,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    run = (ki * block_k <= qi * block_q + block_q - 1) if causal else True
-    run = _in_band(run, qi, ki, block_q, block_k, window)
-
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0, ...]
-        k = k_ref[0, ...]
-        v = v_ref[0, ...]
-        do = do_ref[0, ...]
-        qs = (q.astype(jnp.float32) * scale2).astype(q.dtype)
-        lse2 = lse_ref[0, ...].reshape(block_q, 1)
-        delta = dl_ref[0, ...].reshape(block_q, 1)
-        s2 = jax.lax.dot_general(qs, k, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        col = ki * block_k + jax.lax.broadcasted_iota(jnp.int32,
-                                                      (block_q, block_k), 1)
-        mask = col < kv_len
-        if causal:
-            row = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            mask = _band_mask(jnp.logical_and(mask, row >= col), row, col,
-                              window)
-        s2 = jnp.where(mask, s2, DEFAULT_MASK_VALUE)
-        p = jnp.exp2(s2 - lse2)
-        dv_scr[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta)).astype(q.dtype)
-        # dk_true = sm_scale * ds^T @ q = (ds^T @ qs) * ln2
-        dk_scr[...] += jax.lax.dot_general(
-            ds, qs, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    # scores TRANSPOSED, [keys, queries], in strips of QUERIES: all four
+    # products latch the strip's q / do rows and stream the keys it sees;
+    # p^T and ds^T come out as dv and dk multiply them, and lse / delta are
+    # read in the lane layout they are stored in
+    for when, at, strips in _tiles(
+            qi, ki, causal=causal, block_q=block_q, block_k=block_k,
+            nq=num_q_blocks, nk=num_k_blocks, kv_len=kv_len, window=window,
+            strip=strip):
+        @_when(when)
+        def _compute():
+            row, col, kv = at
+            q = q_ref[0, ...]
+            do_all = do_ref[0, ...]
+            qs_all = (q.astype(jnp.float32) * scale2).astype(q.dtype)
+            for start, rows, pieces in strips:
+                qs = _rows(qs_all, start, rows)
+                do = _rows(do_all, start, rows)
+                lse2 = lse_ref[0, :, pl.ds(start, rows)]        # [1, rows]
+                delta = dl_ref[0, :, pl.ds(start, rows)]
+                for lo, hi, mask in pieces:
+                    keys = pl.ds(lo, hi - lo)
+                    s2 = _masked(_dot(k_ref[0, keys, :], qs, (1, 1)), mask,
+                                 row + start, col + lo, kv, keys_first=True)
+                    p = jnp.exp2(s2 - lse2)                 # [keys, rows]
+                    dv_scr[keys, :] += _dot(p.astype(do.dtype), do, (1, 0))
+                    dp = _dot(v_ref[0, keys, :], do, (1, 1))
+                    ds = (p * (dp - delta)).astype(q.dtype)
+                    # dk_true = sm_scale * ds^T @ q = (ds^T @ qs) * ln2
+                    dk_scr[keys, :] += _dot(ds, qs, (1, 0))
 
     @pl.when(inner == rep * num_q_blocks - 1)
     def _finalize():
@@ -1043,7 +1376,8 @@ def _bwd_v3_dkv_kernel(*refs, scale2: float, causal: bool, block_q: int,
 
 
 def _bwd_v3(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
-            interpret, true_kv_len, head_rep, window: int = 0):
+            interpret, true_kv_len, head_rep, window: int = 0,
+            strip: int = 0):
     bh, q_len, d = q.shape
     bh_kv, kv_pad, _ = k.shape
     nq = pl.cdiv(q_len, block_q)
@@ -1057,7 +1391,7 @@ def _bwd_v3(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
     dq_kernel = functools.partial(
         _bwd_v3_dq_kernel, scale2=scale2, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, kv_len=true_kv_len,
-        num_k_blocks=nk, window=window)
+        num_q_blocks=nq, num_k_blocks=nk, window=window, strip=strip)
     qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     kspec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (
         b // rep, _band_k(i, j, block_q, block_k, window), 0))
@@ -1077,8 +1411,8 @@ def _bwd_v3(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
 
     dkv_kernel = functools.partial(
         _bwd_v3_dkv_kernel, scale2=scale2, causal=causal, block_q=block_q,
-        block_k=block_k, kv_len=true_kv_len, num_q_blocks=nq, rep=rep,
-        window=window)
+        block_k=block_k, kv_len=true_kv_len, num_q_blocks=nq,
+        num_k_blocks=nk, rep=rep, window=window, strip=strip)
     band_q = lambda j, i: _band_q(j, i % nq, block_q, block_k, window, nq)
     q_map = lambda b, j, i: (b * rep + i // nq, band_q(j, i), 0)
     l_map = lambda b, j, i: (b * rep + i // nq, 0, band_q(j, i))
@@ -1106,15 +1440,15 @@ def _bwd_v3(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
 # public op
 # ---------------------------------------------------------------------------
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
 def _flash_attention_bh(q, k, v, sm_scale, causal, block_q, block_k, interpret,
-                        true_kv_len, head_rep, window=0):
+                        true_kv_len, head_rep, window=0, strip=0):
     if _v2_eligible(k.shape[1], q.shape[2]):
         return _fwd_v2(q, k, v, sm_scale, causal, block_q, interpret,
-                       true_kv_len, head_rep, window)
+                       true_kv_len, head_rep, window, strip)
     if _v3_eligible(k.shape[1], q.shape[2]):
         o, _ = _fwd_v3(q, k, v, sm_scale, causal, block_q, block_k, interpret,
-                       true_kv_len, head_rep, window)
+                       true_kv_len, head_rep, window, strip)
         return o
     o, _ = _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
                 true_kv_len, head_rep, window=window)
@@ -1122,18 +1456,18 @@ def _flash_attention_bh(q, k, v, sm_scale, causal, block_q, block_k, interpret,
 
 
 def _flash_fwd_rule(q, k, v, sm_scale, causal, block_q, block_k, interpret,
-                    true_kv_len, head_rep, window=0):
+                    true_kv_len, head_rep, window=0, strip=0):
     from jax.ad_checkpoint import checkpoint_name
 
     if _v2_eligible(k.shape[1], q.shape[2]):
         o = _fwd_v2(q, k, v, sm_scale, causal, block_q, interpret,
-                    true_kv_len, head_rep, window)
+                    true_kv_len, head_rep, window, strip)
         # no lse residual: the fused backward recomputes row stats in-kernel
         o = checkpoint_name(o, "flash_out")
         return o, (q, k, v, o)
     if _v3_eligible(k.shape[1], q.shape[2]):
         o, lse = _fwd_v3(q, k, v, sm_scale, causal, block_q, block_k,
-                         interpret, true_kv_len, head_rep, window)
+                         interpret, true_kv_len, head_rep, window, strip)
         o = checkpoint_name(o, "flash_out")
         lse = checkpoint_name(lse, "flash_lse")
         return o, (q, k, v, o, lse)
@@ -1148,15 +1482,16 @@ def _flash_fwd_rule(q, k, v, sm_scale, causal, block_q, block_k, interpret,
 
 
 def _flash_bwd_rule(sm_scale, causal, block_q, block_k, interpret, true_kv_len,
-                    head_rep, window, res, g):
+                    head_rep, window, strip, res, g):
     if len(res) == 4:  # v2 path (see _flash_fwd_rule)
         q, k, v, o = res
         return _bwd_v2(q, k, v, o, g, sm_scale, causal, block_q, interpret,
-                       true_kv_len, head_rep, window)
+                       true_kv_len, head_rep, window, strip)
     if res[4].ndim == 3:  # v3 path: compact [bh, 1, S] exp2-domain lse
         q, k, v, o, lse = res
         return _bwd_v3(q, k, v, o, lse, g, sm_scale, causal, block_q,
-                       block_k, interpret, true_kv_len, head_rep, window)
+                       block_k, interpret, true_kv_len, head_rep, window,
+                       strip)
     return _bwd(sm_scale, causal, block_q, block_k, interpret, true_kv_len,
                 head_rep, res, g, window)
 
@@ -1206,7 +1541,8 @@ def flash_attention(q, k, v, causal: bool = True,
     ``window`` > 0 (static; causal self-attention): a query sees its
     ``window`` newest keys, itself included — forward, ``dq`` and ``dkv``
     skip the blocks wholly outside that band and mask inside the blocks on
-    its edges; ``window = 0`` is the program without one.
+    its edges (in strips, where the shapes allow: ``_resolve_strip``);
+    ``window = 0`` is the program without one.
 
     Returns [B, H, Sq, D] in q's dtype.  Sequence lengths are padded internally
     to the block size; padded keys are masked, padded query rows sliced off.
@@ -1230,11 +1566,13 @@ def flash_attention(q, k, v, causal: bool = True,
                          f"self-attention's (q {q_len}, kv {kv_len})")
     choice, pad_q, pad_k = _resolve_blocks(q_len, kv_len, d, q.dtype.itemsize,
                                            block_q, block_k)
-    if window:
-        choice = choice._replace(window=window)
+    block_q, block_k = choice.block_q, choice.block_k
+    strip = _resolve_strip(_generation(kv_len + pad_k, d), causal,
+                           q_len + pad_q, kv_len + pad_k, kv_len, block_q,
+                           block_k, window)
+    choice = choice._replace(window=window, strip=strip, causal=bool(causal))
     with _CHOICES_LOCK:
         _CHOICES[choice] = _CHOICES.get(choice, 0) + 1
-    block_q, block_k = choice.block_q, choice.block_k
     qp = jnp.pad(q, ((0, 0), (0, 0), (0, pad_q), (0, 0))) if pad_q else q
     kp = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0))) if pad_k else k
     vp = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0))) if pad_k else v
@@ -1244,7 +1582,7 @@ def flash_attention(q, k, v, causal: bool = True,
     vf = vp.reshape(b * hkv, kv_len + pad_k, d)
     # kv_len for masking must be the real length: padded keys get masked out
     o = _flash_attention_bh(qf, kf, vf, sm_scale, causal, block_q, block_k,
-                            interpret, kv_len, rep, window)
+                            interpret, kv_len, rep, window, strip)
     o = o.reshape(b, h, q_len + pad_q, d)
     if pad_q:
         o = o[:, :, :q_len, :]

@@ -993,7 +993,9 @@ class DeepSpeedEngine:
         got: ``flash_attention`` resolves its blocks at trace time
         (``ops/flash_attention.choices``), so what this trace added to that
         table is what the compiled step runs.  One log line a distinct
-        resolution, its blocks on the ``train_flash_block_q/k`` gauges;
+        resolution — blocks, strip, visible against computed pairs
+        (``fa.computed_pairs``) — and the ``train_flash_block_q/k``,
+        ``train_flash_strip`` and ``train_flash_computed_share`` gauges;
         ``flash_choices[name]`` keeps them whole for a reader without the
         log.  Beside it, what each checkpointed block of the program KEEPS
         for its backward (``runtime/remat.py``: ``remat_kept[name]``, one
@@ -1026,20 +1028,32 @@ class DeepSpeedEngine:
                     "what is kept)",
                     phase=name, mode=k.what).set(k.bytes)
             for c, n in ran.items():
+                visible, computed = fa.computed_pairs(c)
+                share = 100.0 * visible / max(computed, 1)
                 log_dist(
                     f"{name}: flash attention {c.generation} "
                     f"({' + '.join(fa.KERNELS[c.generation])}) at blocks "
                     f"{c.block_q} x {c.block_k} ({c.how}) for q {c.q_len} x "
                     f"kv {c.kv_len}, hd {c.d}"
                     + (f", window {c.window}" if c.window else "")
-                    + f": {n} call(s) traced",
+                    + (f", edge tiles in strips of {c.strip} rows"
+                       if c.strip else ", whole tiles")
+                    + f": {visible:,} visible of {computed:,} computed pairs "
+                    f"a head ({share:.1f} %): {n} call(s) traced",
                     ranks=[0])
-                for side, block in (("q", c.block_q), ("k", c.block_k)):
+                mode = f"{c.generation}_{c.how}"
+                for gauge, value, what in (
+                        ("block_q", c.block_q, "flash attention block"),
+                        ("block_k", c.block_k, "flash attention block"),
+                        ("strip", c.strip, "rows of a strip inside a flash "
+                         "tile an edge crosses (0: whole tiles)"),
+                        ("computed_share", share, "visible pairs over the "
+                         "pairs the flash kernels compute, %")):
                     self.metrics.gauge(
-                        f"train_flash_block_{side}",
-                        "flash attention block of a compiled step (phase: "
-                        "the program; mode: kernel generation, chosen|given)",
-                        phase=name, mode=f"{c.generation}_{c.how}").set(block)
+                        f"train_flash_{gauge}",
+                        f"{what} of a compiled step (phase: the program; "
+                        "mode: kernel generation, chosen|given)",
+                        phase=name, mode=mode).set(value)
             return out
 
         return self.sentry.wrap(traced, name, budget)

@@ -177,6 +177,15 @@ def test_flash_train_step_kernels_lower():
 
     text = _lower_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
     assert text.count("tpu_custom_call") >= 2      # fwd + fused bwd
+    # ISSUE 62: strips inside a tile change no name — the benchmark's
+    # ``flash_share.train`` / ``window_flash_ms`` find the kernels by it
+    assert fa.KERNELS == {
+        "v1": ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"),
+        "v2": ("flash_fwd_resident", "flash_bwd_fused"),
+        "v3": ("flash_fwd_chunked", "flash_bwd_dq_chunked",
+               "flash_bwd_dkv_chunked")}
+    for kernel in fa.KERNELS["v2"]:
+        assert text.count(f'kernel_name = "{kernel}"') == 1, kernel
 
 
 def test_given_flash_blocks_lower_as_if_no_rule_existed(monkeypatch):
@@ -206,7 +215,8 @@ def test_given_flash_blocks_lower_as_if_no_rule_existed(monkeypatch):
         texts.append(_lower_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, q, q))
         noted.append(list(fa.choices(since=before)))
     with_rule, without_rule = texts
-    assert noted[0] == [fa.Choice(1024, 1024, 64, "v2", 1024, 1024, "given")]
+    assert noted[0] == [fa.Choice(1024, 1024, 64, "v2", 1024, 1024, "given",
+                                  window=0, strip=256)]
     for kernel in fa.KERNELS["v2"]:
         assert f'kernel_name = "{kernel}"' in with_rule
     assert with_rule == without_rule
@@ -370,31 +380,38 @@ def one_chip(v5e_2x2):
     return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
-#: (shape [B, H, S, hd], KV heads, generation): the four-chip training
-#: cell's call a chip, Llama-class calls at hd 128 (one GQA), and the
-#: resident path OPT / Llama take at S <= 1024
-FLASH_TRAIN_SHAPES = [((8, 32, 2048, 64), 32, "v3"),
-                      ((2, 32, 4096, 128), 32, "v3"),
-                      ((2, 32, 4096, 128), 8, "v3"),
-                      ((1, 16, 8192, 128), 16, "v3"),
-                      ((16, 32, 1024, 64), 32, "v2"),
-                      ((8, 16, 1024, 128), 16, "v2")]
+#: (shape [B, H, S, hd], KV heads, generation, window): the four-chip
+#: training cell's call a chip (opt13b-zero3-x4), Llama-class calls at hd 128
+#: (one GQA), the resident path OPT / Llama take at S <= 1024; from ISSUE 62
+#: the other two training cells' calls — gpt2m-train-1k's, and
+#: smallthinker-train-8k's full and windowed layers
+FLASH_TRAIN_SHAPES = [((8, 32, 2048, 64), 32, "v3", 0),
+                      ((2, 32, 4096, 128), 32, "v3", 0),
+                      ((2, 32, 4096, 128), 8, "v3", 0),
+                      ((1, 16, 8192, 128), 16, "v3", 0),
+                      ((16, 32, 1024, 64), 32, "v2", 0),
+                      ((8, 16, 1024, 128), 16, "v2", 0),
+                      ((8, 16, 1024, 64), 16, "v2", 0),
+                      ((1, 28, 8192, 128), 4, "v3", 0),
+                      ((1, 28, 8192, 128), 4, "v3", 4096)]
 
 
-@pytest.mark.parametrize("shape,hkv,generation", FLASH_TRAIN_SHAPES)
+@pytest.mark.parametrize("shape,hkv,generation,window", FLASH_TRAIN_SHAPES)
 def test_flash_default_blocks_compile_for_a_v5e(shape, hkv, generation,
-                                                one_chip):
+                                                window, one_chip):
     """ISSUE 35: forward + backward at the blocks ``flash_attention``
     chooses for itself, through Mosaic's own compile for a described v5e —
     the scoped-VMEM cliff (2048-row blocks are refused) caught without a
-    chip."""
+    chip.  ISSUE 62: in the strips the shapes give, every kernel under the
+    name it had."""
     b, h, s_len, d = shape
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((b, hkv, s_len, d), jnp.bfloat16,
                               sharding=one_chip)
 
     def loss(q, k, v):
-        o = fa.flash_attention(q, k, v, causal=True, interpret=False)
+        o = fa.flash_attention(q, k, v, causal=True, window=window,
+                               interpret=False)
         return o.astype(jnp.float32).sum()
 
     before = fa.choices()
@@ -403,6 +420,7 @@ def test_flash_default_blocks_compile_for_a_v5e(shape, hkv, generation,
     (choice,) = fa.choices(since=before)
     assert choice.how == "chosen" and choice.generation == generation
     assert min(choice.block_q, choice.block_k) >= 512, choice
+    assert (choice.window, choice.strip) == (window, fa._STRIP), choice
     for kernel in fa.KERNELS[generation]:
         assert kernel in text, (kernel, choice)
 

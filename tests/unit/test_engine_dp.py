@@ -88,10 +88,19 @@ def test_compiled_step_names_its_flash_kernels_and_blocks(family, how,
     assert f"blocks 32 x 32 ({how})" in lines[0]
     assert "train_step: flash attention v2 (flash_fwd_resident + " \
         "flash_bwd_fused)" in lines[0]
+    # ISSUE 62: the strip and what the kernels compute for what the mask
+    # lets through — S = 32 in one 32 x 32 tile: no strip, 528 of 1,024
+    from deepspeed_tpu.ops import flash_attention as fa
+    assert (choice.strip, choice.window) == (0, 0)
+    assert fa.computed_pairs(choice) == (528, 1024)
+    assert ", whole tiles: 528 visible of 1,024 computed pairs a head " \
+        "(51.6 %)" in lines[0]
     snap = engine.metrics.prometheus_text()
+    labels = f'{{mode="v2_{how}",phase="train_step"}}'
     for side in "qk":
-        assert (f'train_flash_block_{side}{{mode="v2_{how}",'
-                f'phase="train_step"}} 32') in snap, snap
+        assert f'train_flash_block_{side}{labels} 32' in snap, snap
+    assert f'train_flash_strip{labels} 0' in snap, snap
+    assert f'train_flash_computed_share{labels} 51.5625' in snap, snap
 
 
 def test_train_batches_matches_per_step():

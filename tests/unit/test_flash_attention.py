@@ -203,24 +203,82 @@ def test_given_blocks_come_back_untouched_and_choices_records_both(given):
     new = fa.choices(since=before)
     by_how = {c.how: c for c in new}
     assert set(by_how) == {"given", "chosen"} and set(new.values()) == {1}
-    assert by_how["given"] == fa.Choice(2048, 2048, 64, "v3", *given, "given")
+    # ISSUE 62: blocks that are multiples of the strip name it; the others
+    # keep whole tiles
+    strip = 0 if given[0] % fa._STRIP or given[1] % fa._STRIP else fa._STRIP
+    assert by_how["given"] == fa.Choice(2048, 2048, 64, "v3", *given, "given",
+                                        window=0, strip=strip)
     assert by_how["chosen"][:4] == (2048, 2048, 64, "v3")
     assert min(by_how["chosen"].block_q, by_how["chosen"].block_k) >= 512
-    # the GPT-2 cell's call: v2 at the blocks it names, its cap not biting
+    assert by_how["chosen"].strip == fa._STRIP
+    # the GPT-2 cell's call: v2 at the blocks it names, its cap not biting,
+    # its one tile a head in strips
     c, pad_q, pad_k = fa._resolve_blocks(1024, 1024, 64, 2, 1024, 1024)
     assert c == fa.Choice(1024, 1024, 64, "v2", 1024, 1024, "given")
     assert (pad_q, pad_k) == (0, 0)
+    before = fa.choices()
+    shape = jax.ShapeDtypeStruct((1, 2, 1024, 64), jnp.bfloat16)
+    jax.eval_shape(lambda q: flash_attention(
+        q, q, q, block_q=1024, block_k=1024, interpret=True), shape)
+    assert list(fa.choices(since=before)) == [
+        fa.Choice(1024, 1024, 64, "v2", 1024, 1024, "given", window=0,
+                  strip=fa._STRIP)]
 
 
-@pytest.mark.parametrize("causal,hkv", [(True, 2), (False, 2), (True, 1)],
-                         ids=["causal", "full", "causal-gqa"])
-def test_default_blocks_at_the_cells_length_match_reference(causal, hkv):
+#: ISSUE 62, the three training cells' calls: (Choice less its strip) ->
+#: visible / computed pairs of a head, %, in whole tiles and in strips of 256
+CELL_PAIRS = {
+    "gpt2m-train-1k": ((1024, 1024, 64, "v2", 1024, 1024, "given", 0),
+                       50.0, 80.1),
+    "opt13b-zero3-x4": ((2048, 2048, 64, "v3", 1024, 1024, "chosen", 0),
+                        66.7, 88.9),
+    "smallthinker-train-8k-window": (
+        (8192, 8192, 128, "v3", 1024, 1024, "chosen", 4096), 80.0, 94.1),
+    "smallthinker-train-8k-full": (
+        (8192, 8192, 128, "v3", 1024, 1024, "chosen", 0), 88.9, 97.0),
+}
+
+
+@pytest.mark.parametrize("strip", [0, 256])
+@pytest.mark.parametrize("cell", sorted(CELL_PAIRS))
+def test_computed_pairs_is_a_count_of_the_mask(cell, strip):
+    """What the engine logs of a call — visible pairs over the pairs the
+    kernels multiply — against a brute-force count over the mask, tile by
+    tile (whole tiles) and sub-tile by sub-tile (strips)."""
+    fields, whole, strips = CELL_PAIRS[cell]
+    c = fa.Choice(*fields, strip=strip)
+    rows, cols = np.arange(c.q_len)[:, None], np.arange(c.kv_len)[None, :]
+    seen = cols <= rows
+    if c.window:
+        seen &= rows - cols < c.window
+    step_q = strip or c.block_q
+    step_k = strip or (c.kv_len if c.generation == "v2" else c.block_k)
+    tiles = seen.reshape(c.q_len // step_q, step_q, c.kv_len // step_k,
+                         step_k).any(axis=(1, 3))
+    visible, computed = fa.computed_pairs(c)
+    assert (visible, computed) == (seen.sum(), tiles.sum() * step_q * step_k)
+    assert round(100.0 * visible / computed, 1) == pytest.approx(
+        strips if strip else whole, abs=0.06)
+    # the shapes alone give the strip: nothing the caller sets
+    assert fa._resolve_strip(c.generation, True, c.q_len, c.kv_len, c.kv_len,
+                             c.block_q, c.block_k, c.window) == fa._STRIP
+
+
+@pytest.mark.parametrize("causal,hkv,s_len", [
+    (True, 2, 2048), (False, 2, 2048), (True, 1, 2048), (True, 2, 1024)],
+    ids=["causal", "full", "causal-gqa", "causal-resident"])
+def test_default_blocks_at_the_cells_length_match_reference(causal, hkv,
+                                                            s_len):
     """Forward and backward at S = 2048, hd 64, bf16 — the four-chip
-    training cell's call, at the blocks the rule gives it."""
-    q, k, v = rand_qkv(jax.random.PRNGKey(11), b=1, h=2, hkv=hkv, s=2048,
+    training cell's call, at the blocks the rule gives it and (ISSUE 62) in
+    the strips its shapes give it; S = 1024: the resident kernels'."""
+    q, k, v = rand_qkv(jax.random.PRNGKey(11), b=1, h=2, hkv=hkv, s=s_len,
                        dtype=jnp.bfloat16)
-    c, _, _ = fa._resolve_blocks(2048, 2048, 64, 2, None, None)
-    assert c.generation == "v3" and min(c.block_q, c.block_k) >= 512
+    c, _, _ = fa._resolve_blocks(s_len, s_len, 64, 2, None, None)
+    assert c.generation == ("v3" if s_len > 1024 else "v2")
+    assert min(c.block_q, c.block_k) >= 512
+    assert fa._resolve_strip(c.generation, causal, s_len, s_len, s_len,
+                             c.block_q, c.block_k, 0) == fa._STRIP == 256
 
     def loss(attn):
         return lambda q, k, v: jnp.sum(

@@ -2,13 +2,16 @@
 causal flags — every case must match the einsum reference in interpret
 mode.  Each shape runs through BOTH the v2 fused path and (via the
 DS_FLASH_V2=0 kill switch) the v1 two-kernel fallback, so padding/masking
-edges are covered on both code paths."""
+edges are covered on both code paths.  ISSUE 62: every case also with the
+edge tiles in strips of 32 rows (``_STRIP`` set here; the program reads the
+strip from its shapes), where the case's blocks are multiples of that."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.ops import flash_attention as fa
 from deepspeed_tpu.ops.flash_attention import flash_attention, mha_reference
 
 pytestmark = pytest.mark.slow
@@ -26,12 +29,15 @@ for _ in range(10):
 
 # blocks the caller names (128 x 128: several chunks a row at these lengths,
 # as every case ran before flash_attention chose its own) and the chosen ones
+@pytest.mark.parametrize("strip", [None, 32], ids=["strip256", "strip32"])
 @pytest.mark.parametrize("blocks", [{}, {"block_q": 128, "block_k": 128}],
                          ids=["chosen", "given128"])
 @pytest.mark.parametrize("kernel_ver", ["v2", "v1", "v3"])
 @pytest.mark.parametrize("b,h,hkv,s,d,causal", CASES)
 def test_fuzz_matches_reference(b, h, hkv, s, d, causal, kernel_ver, blocks,
-                                monkeypatch):
+                                strip, monkeypatch):
+    if strip:
+        monkeypatch.setattr(fa, "_STRIP", strip)
     # pin ALL branches: an ambient DS_FLASH_V2/V3 from a debugging shell
     # must not silently collapse the matrix onto one path
     monkeypatch.setenv("DS_FLASH_V2", "1" if kernel_ver == "v2" else "0")
