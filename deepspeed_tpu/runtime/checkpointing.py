@@ -20,6 +20,8 @@ Layout (per the reference's tag-directory protocol)::
 
 from __future__ import annotations
 
+import importlib.machinery
+import importlib.util
 import json
 import os
 from abc import ABC, abstractmethod
@@ -28,6 +30,7 @@ from typing import Any, Dict, Optional
 import jax
 import numpy as np
 
+from ..telemetry import trace as trace_mod
 from ..utils.logging import log_dist, logger
 
 
@@ -76,12 +79,51 @@ class OrbaxCheckpointEngine(CheckpointEngine):
         return self._ckptr.restore(path)
 
 
+def _require_orbax() -> None:
+    """Raise what ``import orbax.checkpoint`` would raise where the library
+    is missing, WITHOUT importing it (the import is ~12 s on a v5e host).
+    ``orbax`` is a namespace package, so ``find_spec("orbax.checkpoint")``
+    would import it as the parent and leave it in ``sys.modules``: the
+    parent is found by name and the library under the parent's paths."""
+    parent = importlib.util.find_spec("orbax")
+    if parent is None:
+        raise ModuleNotFoundError("No module named 'orbax'", name="orbax")
+    if importlib.machinery.PathFinder.find_spec(
+            "orbax.checkpoint", parent.submodule_search_locations or ()) is None:
+        raise ModuleNotFoundError("No module named 'orbax.checkpoint'",
+                                  name="orbax.checkpoint")
+
+
 class CheckpointManager:
-    """Engine-facing checkpoint orchestration with the reference's tag protocol."""
+    """Engine-facing checkpoint orchestration with the reference's tag protocol.
+
+    The storage backend is built at its FIRST USE, not here: the first
+    ``save`` or ``load`` of a process carries the checkpoint library's import
+    (~12 s on a v5e host; a ``checkpoint_engine`` span of the start-up ring,
+    ``args.first_use`` = ``"save"`` | ``"load"``), and a run that never saves
+    or loads never pays it.  Without an injected ``checkpoint_engine`` the
+    constructor checks that the library is installed without importing it, so
+    a missing one still fails at ``initialize`` and not at the first save."""
 
     def __init__(self, engine, checkpoint_engine: Optional[CheckpointEngine] = None):
         self.engine = engine
-        self.checkpoint_engine = checkpoint_engine or OrbaxCheckpointEngine()
+        self._checkpoint_engine = checkpoint_engine
+        if checkpoint_engine is None:
+            _require_orbax()
+
+    def _backend(self, first_use: str) -> CheckpointEngine:
+        """The storage backend: the one given, or the default one, built
+        ONCE under a ``checkpoint_engine`` span of the start-up ring by
+        whoever reaches for it first (``first_use``)."""
+        if self._checkpoint_engine is None:
+            with trace_mod.setup_timeline().span("checkpoint_engine",
+                                                 first_use=first_use):
+                self._checkpoint_engine = OrbaxCheckpointEngine()
+        return self._checkpoint_engine
+
+    @property
+    def checkpoint_engine(self) -> CheckpointEngine:
+        return self._backend("attribute")
 
     # -- tag handling (reference engine.py:3050 _checkpoint_tag_validation) ----
     def _validate_tag(self, tag: str) -> None:
@@ -131,9 +173,10 @@ class CheckpointManager:
             tag = f"global_step{engine.global_steps}"
         self._validate_tag(tag)
         ckpt_dir = os.path.join(save_dir, str(tag))
-        self.checkpoint_engine.makedirs(ckpt_dir)
+        backend = self._backend("save")
+        backend.makedirs(ckpt_dir)
 
-        self.checkpoint_engine.save(engine.state, os.path.join(ckpt_dir, "state"))
+        backend.save(engine.state, os.path.join(ckpt_dir, "state"))
         if getattr(engine, "_offload_opt", None) is not None:
             # host-side optimizer partition (ZeRO-Offload/Infinity tier):
             # every process saves ITS ZeRO partition (reference writes
@@ -191,20 +234,21 @@ class CheckpointManager:
             with open(meta_path) as f:
                 meta = json.load(f)
 
+        backend = self._backend("load")
         # abstract target carries *current* shardings -> orbax reshards on read
         abstract = jax.tree_util.tree_map(
             lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
             engine.state, engine.state_shardings)
         if load_module_only or not load_optimizer_states:
-            loaded = self.checkpoint_engine.load(os.path.join(ckpt_dir, "state"),
-                                                 abstract_target=abstract)
+            loaded = backend.load(os.path.join(ckpt_dir, "state"),
+                                  abstract_target=abstract)
             engine.state["params"] = loaded["params"]
             if not load_module_only:
                 engine.state["step"] = loaded["step"]
                 engine.state["scaler"] = loaded["scaler"]
         else:
-            engine.state = self.checkpoint_engine.load(
-                os.path.join(ckpt_dir, "state"), abstract_target=abstract)
+            engine.state = backend.load(os.path.join(ckpt_dir, "state"),
+                                        abstract_target=abstract)
 
         if getattr(engine, "_offload_opt", None) is not None:
             # re-sync this process's host master partition with the restored

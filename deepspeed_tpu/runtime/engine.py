@@ -224,9 +224,9 @@ class DeepSpeedEngine:
         self.monitor = MonitorMaster(self._config.monitor_config)
         self._metrics_server = None
 
-        # (imports the checkpoint library and starts its checkpointer)
-        with setup.span("checkpoint_manager"):
-            self.checkpoint_manager = CheckpointManager(self)
+        # (the storage backend, and the checkpoint library's import with
+        # it, wait for the first save or load)
+        self.checkpoint_manager = CheckpointManager(self)
 
         # micro-step accumulation buffers (forward/backward/step shim path)
         self._accum_grads: Optional[PyTree] = None

@@ -99,8 +99,7 @@ def trained():
 
 # ------------------------------------------------------ the engines' spans
 SERVE_PHASES = ("params_cast", "params_place", "pool")
-TRAIN_PHASES = ("configure", "build_state", "build_step_fns",
-                "checkpoint_manager")
+TRAIN_PHASES = ("configure", "build_state", "build_step_fns")
 
 
 @pytest.mark.parametrize("phase", SERVE_PHASES)
