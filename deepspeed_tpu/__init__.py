@@ -291,7 +291,13 @@ def init_serving(model=None, config=None, params=None, *, topology=None,
     prompt-lookup proposer — and
     verifies the K+1 window in one batched target pass, committing the
     longest target-matching prefix.  Outputs stay token-exact with plain
-    greedy decode at any acceptance rate.
+    greedy decode at any acceptance rate.  ``draft="self"`` makes the
+    served model's OWN multi-token-prediction module the proposer (a model
+    whose decode hooks carry ``self_draft``: GLM-5's): its cache rows are
+    more layers of the target's pool, its draft stays on the device, and a
+    round is one ``spec_round`` span with one harvest
+    (``inference/options.py SELF_DRAFT``; docs/inference.md "The
+    self-drafting proposer").
 
     **Multi-chip serving**: ``topology=N`` (or ``{"tp": N}``) is shorthand
     for ``config={"tensor_parallel": {"tp_size": N}}`` (overriding any

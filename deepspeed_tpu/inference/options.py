@@ -35,7 +35,8 @@ from types import SimpleNamespace
 from typing import Any, Callable, Dict, Mapping, Optional, Sequence, Tuple
 
 __all__ = ["OPTIONS", "FEATURES", "EXCLUDES", "KIND_REFUSES", "KIND_SAYS",
-           "VERIFY_T_MAX", "QUANT_MODES", "Option", "Feature", "Rule",
+           "VERIFY_T_MAX", "QUANT_MODES", "SELF_DRAFT", "Option", "Feature",
+           "Rule",
            "bind", "signature", "resolved", "parse_quantize", "check_ranges",
            "check", "violations"]
 
@@ -43,6 +44,14 @@ __all__ = ["OPTIONS", "FEATURES", "EXCLUDES", "KIND_REFUSES", "KIND_SAYS",
 #: of ``ops/decode_attention.VERIFY_T_MAX``, stated here because this module
 #: imports no jax (``tests/unit/test_serving_options.py`` holds the two equal)
 VERIFY_T_MAX = 16
+#: ``draft=SELF_DRAFT`` (beside ``spec_tokens=K``): the proposer is the
+#: served model's OWN multi-token-prediction module (decode hook
+#: ``self_draft``: ``{"depth", "layers", "cache", "forward"}``) — no second
+#: model, no second pool: the module's rows are more layers of the target's
+#: own leaves under the target's tables, its draft stays on the device beside
+#: the pending token, and a round is one harvest.  Stated here, once: every
+#: other value of ``draft`` is a second MODEL
+SELF_DRAFT = "self"
 #: legal ``quantize=`` values (order-normalized; ``None`` = full precision)
 QUANT_MODES = ("kv8", "w8a8", "w8a8+kv8")
 
@@ -112,7 +121,8 @@ OPTIONS: Dict[str, Option] = {
     # engine mints its OWN spill file rather than contending for this one's
     "nvme_path": Option(None, "_nvme_path_arg"),
     # a model object: not captured (a draft-model engine round-trips to
-    # the n-gram proposer at the same ``spec_tokens``)
+    # the n-gram proposer at the same ``spec_tokens``; ``SELF_DRAFT``, a
+    # word, is what ``resolved_config()`` adds for an engine built with it)
     "draft": Option(None, None),
     "ngram_max": Option(3, "ngram_max"),
     "ngram_min": Option(1, "ngram_min"),
@@ -191,7 +201,9 @@ FEATURES: Dict[str, Feature] = {
                            lambda a: int(a.nvme_blocks)),
     "spec_tokens": Feature("spec_tokens={spec_tokens}",
                            lambda a: int(a.spec_tokens)),
-    "a draft model": Feature("a draft model", lambda a: a.draft is not None),
+    "a draft model": Feature(
+        "a draft model",
+        lambda a: a.draft is not None and a.draft != SELF_DRAFT),
     "quantize": Feature("quantize='kv8'", lambda a: a.kv8),
     "quantized weights": Feature("quantized weights ({weights})",
                                  lambda a: a.weights),
@@ -245,9 +257,12 @@ KIND_REFUSES: Dict[str, Dict[str, Optional[str]]] = {
         "prefix_caching", "host_blocks", "quantize",
         "resident_window_blocks", "spec_tokens", "a draft model",
         "a tp mesh", "engine_mode", "sp")),
-    "indexer": dict.fromkeys((
-        "a tp mesh", "engine_mode", "sp", "quantize",
-        "resident_window_blocks", "a draft model")),
+    "indexer": {
+        **dict.fromkeys(("a tp mesh", "engine_mode", "sp", "quantize",
+                         "resident_window_blocks")),
+        "a draft model": "a second model; the model's own module, "
+                         "draft='self', is served: its index keys are one "
+                         "more layer of this leaf"},
     "latent": {
         "quantize": "a quantized latent is a different model: the value is "
                     "a projection of the same vector the key is",
@@ -258,7 +273,10 @@ KIND_REFUSES: Dict[str, Dict[str, Optional[str]]] = {
                      "a replicated pool), a path that is not built",
         "engine_mode": "the latent write and read run on one shard",
         "sp": "sequence-parallel prefill all-to-alls heads of K and V",
-        "a draft model": "the draft's pool would be a second kind beside it",
+        "a draft model": "a second model's pool would be a second kind "
+                         "beside it (the model's own module, draft='self', "
+                         "is served: its rows are one more layer of this "
+                         "leaf)",
         "resident_window_blocks": "the latent kernel carries no "
                                   "resident-window mask",
     },
@@ -408,6 +426,17 @@ EXCLUDES: Tuple[Rule, ...] = (
          f"watermark budget int({a.nvme_high_watermark} * {a.host_blocks}) "
          "— one promotion batch would immediately re-spill its own head; "
          "lower swap_batch or raise nvme_high_watermark/host_blocks"),
+    Rule("self_draft",
+         lambda a: a.draft == SELF_DRAFT and a.logit_masks,
+         lambda a: "draft='self' excludes logit_masks — a round commits up "
+         "to spec_tokens + 1 tokens on the device and the mask row is built "
+         "on the host a token at a time; drop logit_masks, or draft"),
+    Rule("self_draft",
+         lambda a: a.draft == SELF_DRAFT and int(a.host_blocks),
+         lambda a: "draft='self' excludes the tiered KV cache (host_blocks) "
+         "— the module's entry at a position is made from the NEXT token, "
+         "so a demoted chain's last block is not keyed by its own tokens; "
+         "drop host_blocks, or draft"),
 )
 
 

@@ -76,6 +76,38 @@ draft K-step rollout, and the verify pass.  ``stats()`` adds drafted/
 accepted counters and acceptance rate, plus per-request TTFT/TPOT
 percentiles (recorded for plain serving too).
 
+**A third proposer: the model's own module** (``draft="self"`` beside
+``spec_tokens=K``, ``inference/options.py SELF_DRAFT``; decode hook
+``self_draft``: ``{"depth", "layers", "cache", "forward"}`` — a
+multi-token-prediction module that is one more block of the served model,
+fed by the trunk's hidden state and the next token; the engine asks the hook
+and names no family).
+(a) Its cache rows are more layers of the target's OWN leaves under the
+target's block tables (the hook's ``cache`` keywords to ``init_cache``): no
+second pool, and they roll back for free like every other entry.  The prefill
+programs fill them — entry ``t`` is made from the hidden state at ``t`` AND
+token ``t + 1``, so a chunk's last entry takes the prompt's next token, the
+prompt's last entry the row's own first token — and leave each row's first
+draft.  (b) A round (:meth:`ServingEngine._get_round_fn`) is two device
+programs handed over back to back and ONE harvest: ``verify`` scores the
+window ``[pending, d_1..d_K]`` taken where the device left it
+(``_devtok`` / ``_devdraft``), takes the rejection sampler's verdict and
+WALKS it on the device; ``draft`` runs the module over the 1..K+1 positions
+the walk committed and leaves the next draft beside the next pending token.
+The host gets ``[slots, K + 1]`` emitted ids and a count a row: no round-trip
+between a draft and its verify, one ``spec_round`` span a round.  (c) The
+commit (:meth:`ServingEngine._commit_self_round`) takes 1..K+1 tokens a row,
+cut at the row's ``eos`` or where its budget ends.  (d) The draft is the
+module's argmax, verified by the delta-form rejection sampler: sampled rows
+are distribution-exact, greedy rows token-exact with speculation off.  (e)
+**The trie beside it**: the module's entry at a shared prefix's last position
+belongs to whoever registered it (it was made from THAT request's next
+token), and the first round needs the hidden state of the last cached
+position — so a hit ends one block early (:meth:`ServingEngine._admit`);
+blocks past a prompt's full blocks, where an uncommitted draft's entries may
+lie, are never registered.  Not served with it: ``logit_masks``,
+``host_blocks`` (``options.EXCLUDES``, group ``self_draft``).
+
 **Tensor parallelism** (``shard_kv``, default auto): when the engine's
 mesh carries a ``tp`` axis and the model's KV head count divides it, the
 paged pool (and the draft pool) is committed sharded over the KV-HEAD dim
@@ -820,13 +852,16 @@ class _Flight:
     ``args`` is the argument dict of its in-flight span (``decode`` /
     ``prefill``), ``out`` the flat tokens-and-record array it returns,
     ``shape`` that of the tokens in it, ``commit`` what the harvest hands
-    the tokens to, ``held`` the call's operands, kept until then."""
+    the tokens to, ``held`` the call's operands, kept until then,
+    ``phase`` the host phase its ``upload`` / ``commit`` segments go under
+    (its own name; ``decode`` for a speculative round)."""
 
-    __slots__ = ("name", "args", "out", "shape", "commit", "held")
+    __slots__ = ("name", "args", "out", "shape", "commit", "held", "phase")
 
-    def __init__(self, name, args, shape, commit):
+    def __init__(self, name, args, shape, commit, phase=None):
         self.name, self.args, self.shape = name, args, shape
         self.commit, self.out, self.held = commit, None, None
+        self.phase = phase or name
 
 
 class ServingEngine:
@@ -874,7 +909,8 @@ class ServingEngine:
                     which the ring has dropped; ``True`` then raises.
     spec_tokens:    speculative draft length K (0 = off).  Each decode
                     iteration proposes K tokens per slot and verifies
-                    them in one K+1-token target pass.
+                    them in one K+1-token target pass (with
+                    ``draft="self"`` at most the module's depth).
     shard_kv:       shard the paged pool over the mesh's ``tp`` axis
                     (KV-head dim — module docstring).  Default ``None`` =
                     auto: shard iff tp > 1 and the KV head count divides
@@ -919,7 +955,12 @@ class ServingEngine:
                     a bare ModelSpec (wrapped with the target's inference
                     config) of a small same-family/same-tokenizer model.
                     ``None`` selects the model-free n-gram prompt-lookup
-                    proposer (zero extra compiled programs).
+                    proposer (zero extra compiled programs).  ``"self"``
+                    (``options.SELF_DRAFT``): the served model's OWN
+                    drafting module (decode hook ``self_draft``: a
+                    multi-token-prediction block) — its rows in the
+                    target's pool, its draft on the device, one harvest a
+                    round (module docstring "A third proposer").
     ngram_max/min:  n-gram match lengths for the lookup proposer (longest
                     match first, most recent occurrence wins).
     debug_checks:   turn the documented contracts into enforced ones
@@ -991,6 +1032,28 @@ class ServingEngine:
         #: values a token a layer and no K / V (module docstring "The
         #: latent kind"); None otherwise
         self._latent = hooks.get("latent_attention")
+        #: the model's OWN drafting module (``draft="self"``,
+        #: ``options.SELF_DRAFT``; decode hook ``self_draft``: ``{"depth":
+        #: tokens ahead it guesses, "layers": the layers its rows add to the
+        #: target's leaves, "cache": the keywords ``init_cache`` takes for
+        #: them, "forward": ``(params, hidden, next ids, cache, pos,
+        #: lengths=, block_tables=, all_positions=, at=, routing=) ->
+        #: (logits, cache[, record])``}``): the proposer of a round with ONE
+        #: harvest (:meth:`_get_round_fn`); None otherwise
+        self._self_draft = None
+        if draft == options.SELF_DRAFT:
+            self._self_draft, draft = hooks.get("self_draft"), None
+            name = getattr(engine.module, "name", "<model>")
+            if self._self_draft is None:
+                raise ValueError(
+                    f"draft='self': {name} has no drafting module of its "
+                    "own (decode hook self_draft) — pass a draft MODEL, or "
+                    "no draft (the n-gram proposer)")
+            if self.spec_tokens > int(self._self_draft["depth"]):
+                raise ValueError(
+                    f"draft='self': {name}'s module drafts "
+                    f"{self._self_draft['depth']} token(s) a round, "
+                    f"spec_tokens={self.spec_tokens} asks for more")
         self._latent_totals = dict.fromkeys(
             ("kv_valid", "kv_blocks", "kv_pairs", "latent_bytes", "kv_tiles",
              "kv_first_tiles_ahead"), 0)
@@ -1192,6 +1255,9 @@ class ServingEngine:
                 kinds["window_blocks"] = self._ring.alloc.num_blocks
             if self._state:
                 kinds["state_rows"] = self.slots
+            if self._self_draft:
+                # the module's rows: more layers of the target's own leaves
+                kinds.update(self._self_draft["cache"])
             mk_pool = lambda: self._init_cache(
                 num_blocks, self.block_size, engine._config.jnp_dtype,
                 **kinds)
@@ -1273,6 +1339,16 @@ class ServingEngine:
         #: (``TOKEN_ON_DEVICE``) — ONE ``[slots]`` int32 vector whatever
         #: program made it, committed like the pool
         self._devtok = jax.device_put(np.zeros(self.slots, np.int32), rep)
+        #: beside it, a self-drafting engine's NEXT DRAFT a slot, ``[slots,
+        #: spec_tokens]``: the prefill program and the round leave it there
+        #: and the next round reads it there — a draft never visits the host
+        self._devdraft = jax.device_put(
+            np.zeros((self.slots, self.spec_tokens), np.int32), rep) \
+            if self._self_draft else None
+        #: slots whose pending token the HOST names in the next round (a
+        #: sampled resume backs up one position); every other row's is the
+        #: device's (``TOKEN_ON_DEVICE``)
+        self._host_pending: set = set()
         #: resident-window serving: per-slot first attention-visible token
         #: past the landmark prefix (== landmark span while nothing has
         #: been demoted; rows of idle slots stay 0 and are never read by a
@@ -1307,6 +1383,7 @@ class ServingEngine:
         self._decode_fn = None
         self._verify_fn = None
         self._draft_fn = None
+        self._round_fn = None
         #: compile probe — one entry per traced program: 1 prefill + 1
         #: decode for an entire trace (speculative: 1 prefill + 1 verify
         #: [+ 1 draft rollout] — never more than 3)
@@ -1319,8 +1396,8 @@ class ServingEngine:
         # rollout + verify).  debug_checks additionally raises at trace
         # time and audits the paged host state every scheduler iteration.
         self.debug_checks = o.debug_checks
-        self.compile_budget = 3 if self.spec_tokens and draft is not None \
-            else 2
+        self.compile_budget = 3 if self.spec_tokens and (
+            draft is not None or self._self_draft) else 2
         # one prefill program a rung of the ladder, all built (and run once
         # on pad rows) with the first of them
         self.compile_budget += len(self._rungs) - 1
@@ -1415,7 +1492,7 @@ class ServingEngine:
                 dsharding = pool_sharding if self._dcache_sharded else rep
                 self._dcache = self._commit_pool(
                     mk_dpool, dsharding, pool="draft", blocks=num_blocks)
-            else:
+            elif not self._self_draft:
                 self._proposer = NGramProposer(self.spec_tokens,
                                                max_n=self.ngram_max,
                                                min_n=self.ngram_min)
@@ -1531,6 +1608,12 @@ class ServingEngine:
             "serving_spec_draft_rejected_total",
             "draft tokens the verifier rejected (rejection sampler or "
             "greedy mismatch)")
+        self._c_round_rows = m.counter(
+            "serving_spec_round_rows_total",
+            "decoding rows of self-drafting rounds")
+        self._c_round_tokens = m.counter(
+            "serving_spec_round_tokens_total",
+            "tokens self-drafting rounds committed")
         self._c_sampled = {
             mode: m.counter("serving_sampled_requests_total",
                             "submitted requests by sampling mode",
@@ -1755,7 +1838,7 @@ class ServingEngine:
             + (f" (no wider rows: {self._ladder_stop})"
                if self._ladder_stop else "")
             + (f", speculative K={self.spec_tokens} "
-               f"({'draft ' + self._draft.module.name if self._draft else 'n-gram'})"
+               f"({'draft ' + self._draft.module.name if self._draft else 'its own module' if self._self_draft else 'n-gram'})"
                if self.spec_tokens else "")
             + (f", engine_mode=dp_tp (dp={self.dp_degree} groups)"
                if self.engine_mode == "dp_tp" else "")
@@ -1864,6 +1947,14 @@ class ServingEngine:
 
     @property
     def decode_steps(self) -> int:
+        """Plain single-token decode calls committed: each emits at most
+        ONE token a slot, so "tokens emitted over ``decode_steps x slots``"
+        (a decode batch's occupancy, as the benchmark's ``decode_occupancy``
+        reads it) is a share of 100 %.  A speculative engine makes none:
+        its rounds count in :attr:`spec_rounds`, and a round emits 1 ..
+        ``spec_tokens + 1`` tokens a row — over ``rounds x slots`` that
+        passes 100 % once a draft is accepted, so the per-round measure is
+        ``stats()["tokens_per_round"]`` (tokens, not a share)."""
         return int(self._c_decode_steps.value)
 
     @property
@@ -1931,7 +2022,8 @@ class ServingEngine:
         logs the start-up line."""
         def built(bare):
             holder[key] = bare
-            if program in ("decode", "verify"):
+            if program in ("decode", "draft" if self._self_draft
+                           else "verify"):
                 log_dist(trace_mod.setup_line(), ranks=[0])
 
         return trace_mod.FirstCall(fn, program, built, **sizes)
@@ -2102,6 +2194,42 @@ class ServingEngine:
         if self._routing:
             return self._fwd(*args, routing=True, **kwargs)
         return (*self._fwd(*args, **kwargs), None)
+
+    def _forward_hidden(self, *args, **kwargs):
+        """Traced: :meth:`_forward` that also hands back the final norm's
+        output at every window position (``hidden=True``): ``(logits,
+        cache, record, hidden)``."""
+        if self._routing:
+            return self._fwd(*args, routing=True, hidden=True, **kwargs)
+        logits, cache, hidden = self._fwd(*args, hidden=True, **kwargs)
+        return logits, cache, None, hidden
+
+    def _draft_forward(self, record, *args, **kwargs):
+        """Traced: the model's own drafting module (hook ``self_draft``)
+        as ``(logits, cache, record)`` — ``record`` the trunk's
+        (:meth:`_forward`) with the module's behind it: one more layer's
+        row of the routing record, its selection's counts added."""
+        fwd = self._self_draft["forward"]
+        if record is None:
+            return (*fwd(*args, **kwargs), None)
+        logits, cache, more = fwd(*args, routing=True, **kwargs)
+        if self._sparse:
+            record = (jnp.concatenate([record[0], more[0][None]]),
+                      record[1] + more[1])
+        else:
+            record = jnp.concatenate([record, more[0][None]])
+        return logits, cache, record
+
+    def _keep_device(self, out) -> None:
+        """What a serving program leaves on the device, from its results
+        ``(flat, cache[, draft cache], token vector[, draft vector])``."""
+        self._cache = out[1]
+        if self._self_draft:
+            self._devtok, self._devdraft = out[-2], out[-1]
+            return
+        self._devtok = out[-1]
+        if len(out) == 4:                  # the prefill fused with a draft's
+            self._dcache = out[2]
 
     @staticmethod
     def _with_record(tokens, record):
@@ -2518,14 +2646,55 @@ class ServingEngine:
 
             body, donate = prefill_fused, (2, 3) if donate else ()
             self._program_meta["prefill_fused"] = True
+        elif self._self_draft is not None:
+            def prefill_self(params, cache, ids, block_tables, base, valid,
+                             nxt, draft_at, *samp):
+                """``prefill`` that also fills the model's own drafting
+                module's rows and leaves each row's next draft: entry ``t``
+                of the module is made from the hidden state at ``t`` AND
+                token ``t + 1`` — the window's ids shifted by one, the last
+                real position taking ``nxt`` (the prompt's next token, -1:
+                none) or the row's own first token — and the draft is the
+                module's argmax at window offset ``draft_at`` (the last real
+                one; the one before for a row that backs up,
+                :meth:`_advance_prefill_rows`).  -> ``(first tokens and
+                records, cache, drafts [J])``."""
+                p = prepare(params)
+                with decode_attention.dispatch_log() as paths:
+                    logits, cache, rec, hidden = self._forward_hidden(
+                        p, ids, cache, base, lengths=valid,
+                        block_tables=block_tables)
+                meta["prefill_attn"] = "+".join(sorted(paths))
+                self._note_latent("prefill", paths)
+                self._note_sparse("prefill")
+                samp_t = pack(samp)
+                self._note_sampler("prefill", samp_t)
+                first = next_tokens(logits, samp_t)
+                col = jnp.arange(ids.shape[1], dtype=jnp.int32)[None, :]
+                after = jnp.where(
+                    col == valid[:, None] - 1,
+                    jnp.where(nxt >= 0, nxt, first)[:, None],
+                    jnp.roll(ids, -1, axis=1))
+                with decode_attention.dispatch_log():
+                    guess, cache, rec = self._draft_forward(
+                        rec, p, hidden, after, cache, base, lengths=valid,
+                        block_tables=block_tables, at=draft_at)
+                return with_record(first, rec), constrain(cache), \
+                    jnp.argmax(guess, axis=-1).astype(jnp.int32)
+
+            body = prefill_self
         self._program_bodies["prefill"] = body
         n_dev = 2 if draft is None else 4
+        # the vectors behind the pools: the tokens, a self-drafting
+        # engine's drafts
+        n_vec = 2 if self._self_draft else 1
         pin = self._pin_tokens
         for j, width in self._rungs:
             spec = self._operand_spec(
                 j, {"ids": width},
                 ("base", "valid") + (("window_start",)
                                      if self.resident_window_blocks else ())
+                + (("nxt", "draft_at") if self._self_draft else ())
                 + ("slot",))
             at = list(spec).index("slot")
 
@@ -2535,17 +2704,23 @@ class ServingEngine:
                 device-resident token vector (the operand behind the pools)
                 at ``slot``, each row's slot — a pad row's is out of range
                 and dropped; a row with prompt left writes a token nobody
-                reads.  The vector is returned last."""
-                devtok, rest = args[n_dev], args[n_dev + 1:]
+                reads — and a self-drafting engine's drafts into the vector
+                beside it.  The vectors are returned last."""
+                vecs, rest = args[n_dev:n_dev + n_vec], args[n_dev + n_vec:]
                 out = body(*args[:n_dev], *rest[:at], *rest[at + 1:])
-                return (*out, pin(devtok.at[rest[at]].set(out[0][:j],
-                                                          mode="drop")))
+                made = (out[0][:j],)
+                if n_vec == 2:
+                    *out, drafts = out
+                    made += (jnp.broadcast_to(drafts[:, None],
+                                              (j, vecs[1].shape[1])),)
+                return (*out, *(pin(vec.at[rest[at]].set(new, mode="drop"))
+                                for vec, new in zip(vecs, made)))
 
             self._prefill_fns[j, width] = self._first_call(jax.jit(
                 self.sentry.wrap(
                     self._packed(self._prefill_program((j, width)),
                                  prefill_ahead, spec,
-                                 device_operands=n_dev + 1),
+                                 device_operands=n_dev + n_vec),
                     f"prefill[{self._rung_name((j, width))}]"),
                 donate_argnums=donate),
                 f"prefill[{self._rung_name((j, width))}]",
@@ -2576,21 +2751,28 @@ class ServingEngine:
                         np.zeros(j, np.int32), np.zeros(j, np.int32)]
             if self.resident_window_blocks:
                 operands.append(np.zeros(j, np.int32))
+            if self._self_draft:
+                operands += [np.full(j, -1, np.int32), np.zeros(j, np.int32)]
             host, _ = self._host_operands(
                 self._prefill_program(rung), *operands,
                 np.full(j, self.slots, np.int32),
                 *self._samp_args_rows((), j))
-            if self._draft is not None:
-                args = (params, self._draft.params, self._cache,
-                        self._dcache, self._devtok, *host)
-            else:
-                args = (params, self._cache, self._devtok, *host)
+            args = self._prefill_args(params, host)
             with self._prefill_ctx():
                 out = fn(*args)
             del args
-            self._cache, self._devtok = out[1], out[-1]
-            if len(out) == 4:              # the prefill fused with a draft's
-                self._dcache = out[2]
+            self._keep_device(out)
+
+    def _prefill_args(self, params, host):
+        """A prefill call's operands: the weights and pools (a draft
+        model's beside the target's), the device-resident vectors, then the
+        call's host buffer."""
+        if self._draft is not None:
+            return (params, self._draft.params, self._cache, self._dcache,
+                    self._devtok, *host)
+        if self._self_draft:
+            return (params, self._cache, self._devtok, self._devdraft, *host)
+        return (params, self._cache, self._devtok, *host)
 
     def _get_verify_fn(self):
         """The speculative K+1 verify program: one fixed-shape paged
@@ -2639,52 +2821,8 @@ class ServingEngine:
                 if samp_t is None:
                     return self._with_record(
                         jnp.argmax(logits, -1).astype(jnp.int32), rec), cache
-                temps, topks, topps, seeds, counts, masks = samp_t
-                slots, width = ids.shape          # width == K + 1
-                flat = logits.reshape((-1, logits.shape[-1]))
-                rep = lambda x: jnp.repeat(x, width)  # noqa: E731
-                mrep = None if masks is None else \
-                    jnp.repeat(masks, width, axis=0)
-                greedy, lp = sampling_ops.filtered_logprobs(
-                    flat, rep(temps), rep(topks), rep(topps), mrep)
-                scored = greedy.reshape(slots, width)
-                lp = lp.reshape(slots, width, -1)
-                # accept test: position i decides emission counts + i
-                pos = lp[:, :-1].reshape((-1, lp.shape[-1]))  # [S*K, V]
-                drafts = ids[:, 1:].reshape(-1)
-                p_d = sampling_ops.token_probs(pos, drafts) \
-                    .reshape(slots, k)
-                u = sampling_ops.accept_uniforms(sampling_ops.grid_keys(
-                    seeds, counts, sampling_ops.SALT_ACCEPT, k))
-                accept = u < p_d
-                # tail lanes: the plain draw (accept-cap / bonus stop)
-                # and the residual draw (rejection stop) share the
-                # RESIDUAL-salt key at their emission index — the host
-                # walker consumes exactly ONE of them per round (at the
-                # single stop position), and the accept uniforms live on
-                # their own salt, so the consumed stream stays i.i.d.
-                # They are returned SEPARATELY: only the walker knows the
-                # stop reason, and a cap stop (draft-model K-1 cap,
-                # constrained cap 0) leaves accept[a] unconsumed — a
-                # device-side where(accept, plain, resid) blend there
-                # would emit marginal p(x)(1 + q) / q^2 instead of p.
-                fkeys = sampling_ops.grid_keys(
-                    seeds, counts, sampling_ops.SALT_RESIDUAL, width)
-                fkeys = fkeys.reshape((-1,) + fkeys.shape[2:])
-                plain = sampling_ops.sample_tokens(
-                    lp.reshape((-1, lp.shape[-1])), fkeys) \
-                    .reshape(slots, width)
-                rkeys = sampling_ops.grid_keys(
-                    seeds, counts, sampling_ops.SALT_RESIDUAL, k)
-                resid = sampling_ops.sample_tokens(
-                    sampling_ops.residual_logits(pos, drafts),
-                    rkeys.reshape((-1,) + rkeys.shape[2:])) \
-                    .reshape(slots, k)
-                # temp == 0 rows: bit-exact greedy (already implied by the
-                # one-hot algebra; the select makes it unconditional)
-                plain = jnp.where(temps[:, None] > 0, plain, scored)
-                resid = jnp.where(temps[:, None] > 0, resid,
-                                  scored[:, :k])
+                scored, accept, plain, resid = self._verify_lanes(
+                    logits, ids, samp_t)
                 return self._with_record(scored, rec), accept, plain, \
                     resid, cache
 
@@ -2700,6 +2838,173 @@ class ServingEngine:
             self.compiled_programs.append(
                 ("verify", self.slots, self.spec_tokens + 1))
         return self._verify_fn
+
+    def _verify_lanes(self, logits, ids, samp_t):
+        """Traced: the delta-form rejection sampler over a verify window
+        (``logits [slots, K + 1, V]`` of the window ``ids``): ``(scored,
+        accept, plain, resid)`` as :meth:`_get_verify_fn` documents them —
+        the verify program's and the self-drafting round's alike."""
+        k = ids.shape[1] - 1
+        temps, topks, topps, seeds, counts, masks = samp_t
+        slots, width = ids.shape          # width == K + 1
+        flat = logits.reshape((-1, logits.shape[-1]))
+        rep = lambda x: jnp.repeat(x, width)  # noqa: E731
+        mrep = None if masks is None else \
+            jnp.repeat(masks, width, axis=0)
+        greedy, lp = sampling_ops.filtered_logprobs(
+            flat, rep(temps), rep(topks), rep(topps), mrep)
+        scored = greedy.reshape(slots, width)
+        lp = lp.reshape(slots, width, -1)
+        # accept test: position i decides emission counts + i
+        pos = lp[:, :-1].reshape((-1, lp.shape[-1]))  # [S*K, V]
+        drafts = ids[:, 1:].reshape(-1)
+        p_d = sampling_ops.token_probs(pos, drafts) \
+            .reshape(slots, k)
+        u = sampling_ops.accept_uniforms(sampling_ops.grid_keys(
+            seeds, counts, sampling_ops.SALT_ACCEPT, k))
+        accept = u < p_d
+        # tail lanes: the plain draw (accept-cap / bonus stop)
+        # and the residual draw (rejection stop) share the
+        # RESIDUAL-salt key at their emission index — the host
+        # walker consumes exactly ONE of them per round (at the
+        # single stop position), and the accept uniforms live on
+        # their own salt, so the consumed stream stays i.i.d.
+        # They are returned SEPARATELY: only the walker knows the
+        # stop reason, and a cap stop (draft-model K-1 cap,
+        # constrained cap 0) leaves accept[a] unconsumed — a
+        # device-side where(accept, plain, resid) blend there
+        # would emit marginal p(x)(1 + q) / q^2 instead of p.
+        fkeys = sampling_ops.grid_keys(
+            seeds, counts, sampling_ops.SALT_RESIDUAL, width)
+        fkeys = fkeys.reshape((-1,) + fkeys.shape[2:])
+        plain = sampling_ops.sample_tokens(
+            lp.reshape((-1, lp.shape[-1])), fkeys) \
+            .reshape(slots, width)
+        rkeys = sampling_ops.grid_keys(
+            seeds, counts, sampling_ops.SALT_RESIDUAL, k)
+        resid = sampling_ops.sample_tokens(
+            sampling_ops.residual_logits(pos, drafts),
+            rkeys.reshape((-1,) + rkeys.shape[2:])) \
+            .reshape(slots, k)
+        # temp == 0 rows: bit-exact greedy (already implied by the
+        # one-hot algebra; the select makes it unconditional)
+        plain = jnp.where(temps[:, None] > 0, plain, scored)
+        resid = jnp.where(temps[:, None] > 0, resid,
+                          scored[:, :k])
+        return scored, accept, plain, resid
+
+    def _get_round_fn(self):
+        """The self-drafting round (``draft="self"``): TWO programs handed
+        over back to back, nothing of either visiting the host between them.
+        ``verify`` scores the window ``[pending, d_1..d_K]`` — both taken
+        where the device left them (:attr:`_devtok`, :attr:`_devdraft`) —,
+        takes the rejection sampler's verdict AND walks it on the device (no
+        cap: a row accepts drafts until its first rejection); ``draft`` runs
+        the model's own module over the 1..K+1 positions the walk committed
+        (hidden states of that same forward; each position's NEXT token is
+        what the walk emitted) and leaves the next draft beside the next
+        pending token.  The host gets the emitted ids ``[slots, K + 1]`` and
+        a count a row, the routing record and the selections' counts of both
+        behind them: one harvest a round, of the second program's one array.
+        The pair replaces the decode program as verify + draft rollout do
+        (+1 on the budget).  The module is a program of its own so that a
+        device trace tells its time (it launches the trunk's kernels under
+        the trunk's names); fused into ``verify`` it would save one dispatch
+        a round.  A rejected draft's cache entries (the trunk's at ``base +
+        accepted + 1 ..``, the module's likewise) stay position-masked and
+        are overwritten by the next round."""
+        if self._round_fn is None:
+            prepare = self.engine._prepare
+            k, pack = self.spec_tokens, self._pack_samp
+            constrain, pin = self._constrain_pool, self._pin_tokens
+            leaves = jax.tree_util.tree_leaves
+
+            def verify(params, cache, devtok, devdraft, tokens, block_tables,
+                       base, valid, *samp):
+                """``tokens`` int32 [slots]: a row's pending token, or
+                ``TOKEN_ON_DEVICE``; ``base`` the committed lengths,
+                ``valid`` K + 1 for a decoding row and 0 for any other (all
+                its writes land in scratch).  -> ``(cache, pending tokens,
+                what the module's program takes: the emitted ids and counts
+                flat, the hidden states, the ids, the counts, the tables,
+                the bases, the records)``."""
+                pend = jnp.where(tokens == TOKEN_ON_DEVICE, devtok, tokens)
+                ids = jnp.concatenate([pend[:, None], devdraft], axis=1)
+                with decode_attention.dispatch_log() as paths:
+                    logits, cache, rec, hidden = self._forward_hidden(
+                        prepare(params), ids, cache, base, lengths=valid,
+                        block_tables=block_tables, all_positions=True)
+                self._note_latent("verify", paths)
+                self._note_sparse("verify")
+                samp_t = pack(samp)
+                self._note_sampler("verify", samp_t)
+                with jax.named_scope("verdict"):
+                    if samp_t is None:
+                        plain = jnp.argmax(logits, -1).astype(jnp.int32)
+                        accept, resid = ids[:, 1:] == plain[:, :k], plain
+                    else:
+                        _, accept, plain, resid = self._verify_lanes(
+                            logits, ids, samp_t)
+                    # the walk: drafts accepted up to the first rejection;
+                    # then the residual draw at the rejection, or — all
+                    # accepted — the plain draw at the bonus position
+                    a = jnp.cumprod(accept.astype(jnp.int32),
+                                    axis=1).sum(axis=1)
+                    tail = jnp.where(
+                        a < k, jnp.take_along_axis(
+                            resid, jnp.minimum(a, k - 1)[:, None],
+                            axis=1)[:, 0], plain[:, k])
+                    col = jnp.arange(k + 1, dtype=jnp.int32)[None, :]
+                    emitted = jnp.where(
+                        col < a[:, None], jnp.roll(ids, -1, axis=1),
+                        jnp.where(col == a[:, None], tail[:, None], 0))
+                    count = jnp.where(valid > 0, a + 1, 0)
+                flat = jnp.concatenate([emitted.reshape(-1), count])
+                return constrain(cache), pin(tail), (
+                    flat, hidden, emitted, count, block_tables, base,
+                    tuple(leaves(rec)))
+
+            def draft(params, cache, devdraft, carry):
+                """The module over the positions ``verify`` committed
+                (``carry``: what it handed on) -> ``(emitted ids, counts and
+                both programs' records flat, cache, next drafts)``."""
+                flat, hidden, emitted, count, block_tables, base, rec = carry
+                rec = None if not rec else rec[0] if len(rec) == 1 else rec
+                with decode_attention.dispatch_log():
+                    guess, cache, rec = self._draft_forward(
+                        rec, prepare(params), hidden, emitted, cache, base,
+                        lengths=count, block_tables=block_tables)
+                return self._with_record(flat, rec), constrain(cache), pin(
+                    jnp.broadcast_to(
+                        jnp.argmax(guess, axis=-1).astype(jnp.int32)[:, None],
+                        devdraft.shape))
+
+            self._program_bodies["verify"] = verify
+            self._program_bodies["draft"] = draft
+            spec = self._operand_spec(self.slots, {"tokens": None},
+                                      ("base", "valid"))
+            donate = (1,) if self._donate() else ()
+            self._verify_fn = self._first_call(jax.jit(
+                self.sentry.wrap(
+                    self._packed("verify", verify, spec, device_operands=4),
+                    "verify"), donate_argnums=donate),
+                "verify", vars(self), "_verify_fn",
+                slots=self.slots, window=k + 1)
+            self._draft_fn = self._first_call(jax.jit(
+                self.sentry.wrap(draft, "draft"), donate_argnums=donate),
+                "draft", vars(self), "_draft_fn", slots=self.slots, tokens=k)
+            self.compiled_programs += [("verify", self.slots, k + 1),
+                                       ("draft", self.slots, k)]
+
+            def spec_round(params, cache, devtok, devdraft, *host):
+                cache, tail, carry = self._verify_fn(
+                    params, cache, devtok, devdraft, *host)
+                flat, cache, drafts = self._draft_fn(params, cache, devdraft,
+                                                     carry)
+                return flat, cache, tail, drafts
+
+            self._round_fn = spec_round
+        return self._round_fn
 
     def _get_draft_fn(self):
         """The draft rollout program: K single-token steps of the draft
@@ -3741,6 +4046,8 @@ class ServingEngine:
                     + blocks_for(self._prefill_width, self.block_size))
             n_hit = self._kv(self._prefix.probe, prompt_eff, plen - 1) \
                 if self._prefix is not None else 0
+            if self._self_draft:
+                n_hit = max(n_hit - 1, 0)      # (the lookup below has why)
 
             def _avail():
                 if grp is not None:
@@ -3758,6 +4065,15 @@ class ServingEngine:
                 # cap below the full prompt: >= 1 tail token must prefill
                 hits = self._kv(self._prefix.lookup, prompt_eff, plen - 1,
                                 self._alloc)
+                if self._self_draft and hits:
+                    # the module's entry at a position is made from the
+                    # NEXT token, so the last position of the last matched
+                    # block belongs to whoever registered it — the one
+                    # after it is where the two prompts may part; and the
+                    # first round needs the hidden state of the last cached
+                    # position.  The hit ends a block early (vLLM drops the
+                    # last matched block for its EAGLE / MTP heads likewise)
+                    self._decref(hits.pop())
             # re-check post-claim: hit blocks that were evictable no longer
             # count toward avail, so the probe gate can be optimistic by
             # up to n_hit blocks
@@ -4135,7 +4451,7 @@ class ServingEngine:
                 evicted=self.preempted - preempted0,
                 blocks_in_use=self._alloc.blocks_in_use,
                 active=len(self._active), pending=len(self._pending))
-            if self._windows or self._state:
+            if self._windows or self._state or self._self_draft:
                 self._full_peak = max(self._full_peak,
                                       self._alloc.blocks_in_use)
             if self._windows:
@@ -4267,12 +4583,11 @@ class ServingEngine:
         self._launched = True
         enqueued: Dict[str, float] = {}
         with contextlib.nullcontext() if stay else tl.segment(
-                f"step.{flight.name}.upload", self._phase), \
+                f"step.{flight.phase}.upload", self._phase), \
                 tl.segment(f"{flight.name}.enqueue", enqueued), ctx:
             out = fn(*flight.held)
-        flight.out, self._cache, self._devtok = out[0], out[1], out[-1]
-        if len(out) == 4:                  # the prefill fused with a draft's
-            self._dcache = out[2]
+        flight.out = out[0]
+        self._keep_device(out)
         del out
         if tl.enabled:
             flight.args["enqueue_s"] = enqueued["enqueue_s"]
@@ -4306,11 +4621,19 @@ class ServingEngine:
         with tl.segment(f"{flight.name}.wait", waited):
             out = self._split_record(np.asarray(flight.out), flight.shape,
                                      flight.args)
+            if flight.name == "spec_round":
+                # as the DEVICE walked them: tokens the rows' windows
+                # yielded, drafts accepted among them (a row's eos or
+                # budget may cut its share at the commit)
+                count = out[-self.slots:]
+                flight.args.update(
+                    emitted=int(count.sum()),
+                    accepted=int((count - (count > 0)).sum()))
         if tl.enabled:
             flight.args["wait_s"] = waited["wait_s"]
             self._seg_args.append(waited)
         self._leave_runtime(flight.name, flight.args)
-        with tl.segment(f"step.{flight.name}.commit", self._phase):
+        with tl.segment(f"step.{flight.phase}.commit", self._phase):
             # the call's operands and results are released here, on the
             # commit's account
             flight.held = flight.out = None
@@ -4873,6 +5196,8 @@ class ServingEngine:
             bt[dec] = self._tables[dec]
             counts = self._decode_counts()
             samp = self._samp_args(counts)
+        if self._self_draft:
+            return self._run_self_round(params, dec, bt, samp)
         if self._draft is not None:
             draft_fn = self._get_draft_fn()
             with seg("step.decode.upload", phase):
@@ -4939,6 +5264,92 @@ class ServingEngine:
             return self._commit_spec_round(dec, ids, scored, accept, plain,
                                            resid)
 
+    def _run_self_round(self, params, dec, bt, samp) -> int:
+        """:meth:`_run_spec_decode`'s round where the proposer is the
+        model's own module (:meth:`_get_round_fn`): ONE call, handed over
+        and harvested through :meth:`_launch` like a decode step (it
+        settles at once: the next round plans on this one's counts), under
+        ONE ``spec_round`` span.  ``dec`` / ``bt`` / ``samp``: the decoding
+        slots, their tables and the sampling tail, as planned."""
+        k = self.spec_tokens
+        active = self._active
+        seg, phase = self.timeline.segment, self._phase
+        with seg("step.decode.plan", phase):
+            tokens = np.full(self.slots, TOKEN_ON_DEVICE, np.int32)
+            named = [s for s in dec if s in self._host_pending]
+            tokens[named] = self._tokens[named]
+            self._host_pending.difference_update(dec)
+            valid = np.zeros(self.slots, np.int32)
+            valid[dec] = k + 1
+            round_fn = self._get_round_fn()
+            span_kw = {**self._sampler_rows(dec),
+                       **self._kv_reach(self._lengths[dec] + k + 1,
+                                        np.full(len(dec), k + 1), t=k + 1,
+                                        at=dec)}
+        with seg("step.decode.upload", phase):
+            host, puts = self._host_operands(
+                "verify", tokens, bt, self._lengths, valid, *samp)
+            fed = [(slot, active[slot]) for slot in dec]
+            flight = _Flight(
+                "spec_round", dict(slots=len(dec), window=k + 1,
+                                   drafted=k * len(dec), **puts, **span_kw),
+                (self.slots * (k + 2),),
+                functools.partial(self._commit_self_round, fed),
+                phase="decode")
+            flight.held = (params, self._cache, self._devtok,
+                           self._devdraft, *host)
+            del host
+            ctx = self._decode_ctx()
+        self._launch(flight, round_fn, ctx)
+        return len(dec)
+
+    def _commit_self_round(self, rows, flat) -> None:
+        """The commit of :meth:`_run_self_round`, when the round's emitted
+        ids and counts are on the host: a row takes its 1..K+1 tokens, cut
+        at its ``eos`` or where its budget ends (it is finished then, and
+        what the device left for its next round is never read); a row that
+        goes on has its pending token and its draft on the device.  A row
+        whose request ended while the round was in flight is dropped."""
+        k = self.spec_tokens
+        active = self._active
+        emitted = flat[:self.slots * (k + 1)].reshape(self.slots, k + 1)
+        count = flat[self.slots * (k + 1):]
+        self._c_spec_rounds.inc()
+        accept_lens, total, drafted = [], 0, 0
+        for slot, st in rows:
+            if active.get(slot) is not st:
+                continue
+            n = int(count[slot])
+            budget = st.req.max_new_tokens - st.gen_count
+            # drafts whose verdicts are real: not past the budget
+            # (:meth:`_commit_spec_round` has why); accepted of them
+            eligible = min(k, budget)
+            raw = min(n - 1, eligible)
+            drafted += eligible
+            self._c_drafted.inc(eligible)
+            self._c_accepted.inc(raw)
+            self._c_spec_rejected.inc(eligible - raw)
+            if eligible:
+                self._h_accept_ratio.observe(raw / eligible)
+            toks = [int(t) for t in emitted[slot, :min(n, budget)]]
+            if st.eos is not None and st.eos in toks:
+                toks = toks[:toks.index(st.eos) + 1]
+            accept_lens.append(min(n - 1, len(toks)))
+            total += len(toks)
+            st.out.extend(toks)
+            self._emit_tokens(st, toks)
+            self._mark_first(st)
+            if len(toks) < n or st.gen_count >= st.req.max_new_tokens \
+                    or (st.eos is not None and toks[-1] == st.eos):
+                self._finish_slot(slot)
+            else:
+                self._lengths[slot] += n
+                self._tokens[slot] = toks[-1]
+        self._c_round_rows.inc(len(accept_lens))
+        self._c_round_tokens.inc(total)
+        self.timeline.instant("spec_accept", accept_lens=accept_lens,
+                              drafted=drafted)
+
     def _commit_spec_round(self, dec, ids, scored, accept, plain,
                            resid) -> int:
         """The commit loop of :meth:`_run_spec_decode`: per slot, the
@@ -4950,7 +5361,7 @@ class ServingEngine:
         # KV was never written to the draft pool, so accepting it would
         # desync the draft's next feed position (n-gram has no such state)
         max_accept = k - 1 if self._draft is not None else k
-        accept_lens = []
+        accept_lens, drafted = [], 0
         for slot in dec:
             st = active[slot]
             cap = max_accept
@@ -4992,6 +5403,7 @@ class ServingEngine:
             if eligible:
                 self._h_accept_ratio.observe(raw / eligible)
             accept_lens.append(accepted)
+            drafted += eligible
             st.out.extend(emitted)
             self._emit_tokens(st, emitted)
             self._mark_first(st)
@@ -5002,8 +5414,9 @@ class ServingEngine:
                 # the correction token becomes the new pending feed
                 self._lengths[slot] += accepted + 1
                 self._tokens[slot] = emitted[-1]
+        # (``drafted``: the ELIGIBLE drafts, as ``stats()`` counts them)
         self.timeline.instant("spec_accept", accept_lens=accept_lens,
-                              drafted=k * len(dec))
+                              drafted=drafted)
         return len(dec)
 
     # ---------------------------------------------------------------- prefill
@@ -5146,14 +5559,24 @@ class ServingEngine:
             # a pad row's slot is out of range
             at = np.full(j, self.slots, np.int32)
             at[:len(group)] = group
+            if self._self_draft:
+                # the token after each row's window (-1: the prompt ends in
+                # it) and where in it the row's draft is read: its last
+                # real position — the one before, for a sampled resume,
+                # which backs up one position (:meth:`_advance_prefill_rows`)
+                nxt = np.full(j, -1, np.int32)
+                draft_at = np.maximum(valid - 1, 0)
+                for row, (slot, v) in enumerate(rows):
+                    st = active[slot]
+                    if st.base + v < st.plen_eff:
+                        nxt[row] = st.prompt_eff[st.base + v]
+                    elif self.sampling and st.prior and st.req.sampled:
+                        draft_at[row] = max(v - 2, 0)
+                operands += [nxt, draft_at]
             host, puts = self._host_operands(
                 self._prefill_program(rung), *operands, at,
                 *self._samp_args_rows(group, j))
-            if self._draft is not None:
-                args = (params, self._draft.params, self._cache,
-                        self._dcache, self._devtok, *host)
-            else:
-                args = (params, self._cache, self._devtok, *host)
+            args = self._prefill_args(params, host)
             del host
             flight = _Flight(
                 "prefill", dict(**puts, **span_kw), (j,),
@@ -5197,6 +5620,7 @@ class ServingEngine:
                 # round-identical and token-exact
                 self._tokens[slot] = int(st.prompt_eff[-1])
                 self._lengths[slot] = st.plen_eff - 1
+                self._host_pending.add(slot)
             done.append((row, slot, st, emits))
         return done
 
@@ -5273,9 +5697,12 @@ class ServingEngine:
         Not captured: the wrapped ``init_inference`` engine itself (model,
         params, dtype, quant group sizes) and a ``draft`` model object —
         a draft-model speculative engine round-trips to the n-gram
-        proposer at the same ``spec_tokens``.
+        proposer at the same ``spec_tokens`` (``draft="self"``, the model's
+        own module, is a word and comes back).
         """
-        return {**options.resolved(self), "topology": self.tp_degree}
+        return {**options.resolved(self),
+                **({"draft": options.SELF_DRAFT} if self._self_draft
+                   else {}), "topology": self.tp_degree}
 
     def _kv_footprint(self) -> Dict[str, Any]:
         """KV memory accounting: pool shape, total logical bytes (quant-
@@ -5325,6 +5752,16 @@ class ServingEngine:
                     "blocks_in_use": alloc.blocks_in_use,
                     "peak_blocks_in_use": peak, "table_width": table_width}
 
+        if self._self_draft and not (self._state or self._windows):
+            # ONE kind of block: the model's layers and, behind them in the
+            # same leaves and under the same table, its drafting module's
+            draft = int(self._self_draft["layers"])
+            return {"full": kind(self._alloc,
+                                 int(self._pool_shape[0]) - draft,
+                                 self._nbper, self._full_peak),
+                    "draft": {"layers": draft, "table": "full",
+                              "depth": int(self._self_draft["depth"])},
+                    "expert_rows_absent": self._rows_absent}
         if self._state:
             # the paged kind beside the state kind (which has no blocks)
             paged = "latent" if self._latent else "full"
@@ -5494,7 +5931,7 @@ class ServingEngine:
             # spans' reach counters summed, and what such a model is
             # refused; None for any other model
             "kv_kinds": self._kv_kinds()
-            if self._windows or self._state else None,
+            if self._windows or self._state or self._self_draft else None,
             # a model with a recurrent state a row: its leaves, their bytes
             # (whatever the rows' lengths), the resets, which body each
             # program's recurrence lowered to and what such a model is
@@ -5512,6 +5949,9 @@ class ServingEngine:
             "generated_tokens": int(self._c_gen_tokens.value),
             "prompt_tokens": self.prompt_tokens,
             "prefix_hit_tokens": self.prefix_hit_tokens,
+            # the prompt tokens the trie was asked about: every admission's
+            "prefix_query_tokens": self.prompt_tokens
+            if self._prefix is not None else 0,
             "prefix_cache_hit_rate": (
                 self.prefix_hit_tokens / self.prompt_tokens
                 if self.prompt_tokens else 0.0),
@@ -5526,13 +5966,18 @@ class ServingEngine:
             "speculative": (
                 None if not self.spec_tokens else
                 f"draft:{self._draft.module.name}" if self._draft
-                else "ngram"),
+                else "self" if self._self_draft else "ngram"),
             "spec_tokens": self.spec_tokens,
             "spec_rounds": self.spec_rounds,
             "drafted_tokens": self.drafted_tokens,
             "accepted_tokens": self.accepted_tokens,
             "acceptance_rate": (self.accepted_tokens / self.drafted_tokens
                                 if self.drafted_tokens else 0.0),
+            # a self-drafting engine: tokens a decoding row commits a
+            # round (1 .. spec_tokens + 1); 0.0 before the first
+            "tokens_per_round": (
+                self._c_round_tokens.value / self._c_round_rows.value
+                if self._c_round_rows.value else 0.0),
             # sampling stack (sampling=False: flags off, zeros — schema
             # stays stable)
             "sampling": self.sampling,

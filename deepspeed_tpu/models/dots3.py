@@ -94,6 +94,9 @@ class Dots3Config(M.MixtralConfig):
     head_gate: bool = True
     #: the latents times ``(hidden_size / rank)^0.5`` after their norms
     lora_rescale: bool = True
+    #: the indexer rotates pairs ``(2i, 2i + 1)`` of its first ``qk_rope_dim``
+    #: values (False: rotate-half, pairs ``(i, i + qk_rope_dim / 2)``)
+    index_rope_interleaved: bool = False
     by_kind: ClassVar[bool] = True
     whole_periods: ClassVar[bool] = False
 
@@ -311,7 +314,7 @@ def _indexer(cfg: Dots3Config, layer, y, cq, rope):
     query latent ``cq``, the one key ``[B, 1, T, DI]`` (LayerNorm) and the
     head weights float32 ``[B, T, HI]`` from the block's normed input ``y``.
     ``rope(x)`` rotates the first ``qk_rope_dim`` values of ``[B, *, T, DI]``
-    (rotate-half)."""
+    (:func:`_index_rope`'s pairing)."""
     b, t, _ = y.shape
     hi, di, r = cfg.index_heads, cfg.index_head_dim, cfg.qk_rope_dim
     # (the barrier: the head split moves the product, not ``idx_q_w`` —
@@ -330,13 +333,15 @@ def _indexer(cfg: Dots3Config, layer, y, cq, rope):
 
 
 def _index_rope(cfg: Dots3Config, pos=None, seq_len: int = 0):
-    """The indexer's rotation (rotate-half, the full layers' base): at the
-    traced offset ``pos`` of a cached window, or over ``0 .. seq_len - 1``."""
-    a = dataclasses.replace(cfg.attn(FULL), rope_interleaved=False)
+    """The indexer's rotation (the full layers' base, the pairing
+    ``index_rope_interleaved`` names): at the traced offset ``pos`` of a
+    cached window, or over ``0 .. seq_len - 1``."""
+    a = dataclasses.replace(cfg.attn(FULL),
+                            rope_interleaved=cfg.index_rope_interleaved)
     if pos is not None:
         return lambda x: L._rope_cached(a, x, pos)
     cos, sin = L.rope_angles(a, seq_len, dim=cfg.qk_rope_dim)
-    return lambda x: L.apply_rope(x, cos, sin)
+    return lambda x: L.apply_rope(x, cos, sin, cfg.index_rope_interleaved)
 
 
 def _gated(cfg: Dots3Config, layer, y, out):
@@ -458,51 +463,82 @@ def _ffn(cfg: Dots3Config, blocks, stacks, number, y, live, choices):
 
 
 # --------------------------------------------------------------------- forward
-def forward_cached(cfg: Dots3Config, params, input_ids, cache, pos,
-                   lengths=None, block_tables=None, all_positions=False,
-                   routing: bool = False, choices: bool = False):
-    """The cached forward (module docstring; ``cached.window`` has the
-    contract of ``lengths`` / ``block_tables`` / ``all_positions``,
-    ``mixtral.forward_cached`` that of ``routing`` and ``choices``: the
-    records are ``([L, ..] with zeros for a dense layer, the full layers'
-    selection counts int32 [5] summed)``, ``choices`` gives ``{"experts":
-    int32 [L - first_dense, B, T, top_k], "keys": bool [L_full, B, T,
-    max_seq_len]}``).  ``block_tables`` is ``{"full", "window"}``."""
-    if not isinstance(block_tables, dict):
+def block_cached(cfg: Dots3Config, blocks, stacks, w: cached.Window, live,
+                 choices: bool, s_max: int, x, layer, ck, cv, index, table,
+                 kind, number):
+    """ONE block over the block-paged pool — ``scan_periods_cached``'s
+    ``step`` behind its first seven arguments: the attention of ``kind`` at
+    the kind's place ``index`` of its leaves, then the FFN of layer
+    ``number`` read out of ``blocks`` (``stacks``: the expert leaves whole,
+    for the kernel).  A family whose extra block is this block (a
+    multi-token-prediction module: ``models/glm_dsa.py``) calls it with
+    stacks of its own.  -> ``(x, the kind's leaves.., aux)``."""
+    with jax.named_scope("layer/attn"):
+        y = L.rms_norm(x, layer["attn_norm"], cfg.rms_eps)
+        leaves = (ck,) if kind == SLIDING else (ck, cv)
+        out, leaves, counts, kept = _attend_cached(
+            cfg, kind, layer, y, leaves, index, table, w, choices)
+        x = x + out
+    y = L.rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
+    out, record, chosen = _ffn(cfg, blocks, stacks, number, y, live, choices)
+    aux = {"record": record, "counts": counts}
+    if choices:
+        aux["experts"] = chosen
+        aux["keys"] = kept if kept is not None else jnp.zeros(
+            x.shape[:2] + (s_max,), bool)
+    return (x + out, *leaves, aux)
+
+
+def expert_stacks(blocks):
+    """The expert leaves of ``blocks["moe"]`` kept whole where the kernel
+    reads a layer of them in place (``mixtral.forward_cached``), else
+    None."""
+    if "moe" in blocks and M._expert_kernel(blocks["moe"]):
+        return {k: blocks["moe"][k] for k in M._EXPERT_LEAVES}
+    return None
+
+
+def kind_tables(cfg: Dots3Config, block_tables):
+    """``block_tables`` as a table a kind: a model with no sliding layer
+    has ONE kind of block and may be handed its one table bare."""
+    if isinstance(block_tables, dict):
+        return block_tables
+    if block_tables is None or SLIDING in cfg.kinds:
         raise NotImplementedError(
             "a model whose layers are latent attention under a learned "
             "selection and under a sliding window is served through the "
             "block-paged pool with a block table per layer kind "
             "(init_serving / ServingEngine); the contiguous cache of "
             "InferenceEngine.generate has one kind of state")
+    return {"full": block_tables}
+
+
+def forward_cached(cfg: Dots3Config, params, input_ids, cache, pos,
+                   lengths=None, block_tables=None, all_positions=False,
+                   routing: bool = False, choices: bool = False,
+                   hidden: bool = False):
+    """The cached forward (module docstring; ``cached.window`` has the
+    contract of ``lengths`` / ``block_tables`` / ``all_positions``,
+    ``mixtral.forward_cached`` that of ``routing`` and ``choices``: the
+    records are ``([L, ..] with zeros for a dense layer, the full layers'
+    selection counts int32 [5] summed)``, ``choices`` gives ``{"experts":
+    int32 [L - first_dense, B, T, top_k], "keys": bool [L_full, B, T,
+    max_seq_len]}``).  ``block_tables`` is ``{"full", "window"}`` (the one
+    table bare where no layer slides).  ``hidden``: the final norm's output
+    at EVERY window position, ``[B, T, d]``, as one more result behind the
+    others (what a multi-token-prediction module reads)."""
+    block_tables = kind_tables(cfg, block_tables)
     w = cached.window(input_ids, pos, lengths, block_tables["full"])
     live = live_tokens(input_ids, lengths, block_tables)
     x = params["embed"][input_ids].astype(params["embed"].dtype)
     blocks = params["blocks"]
-    stacks = None
-    if "moe" in blocks and M._expert_kernel(blocks["moe"]):
-        # the expert stacks stay whole: the kernel reads a layer of them in
-        # place (``mixtral.forward_cached``)
-        stacks = {k: blocks["moe"][k] for k in M._EXPERT_LEAVES}
+    stacks = expert_stacks(blocks)
     s_max = block_tables["full"].shape[1] * cache["latent"].shape[3] \
         if "latent" in cache else 0
 
-    def step(x, layer, ck, cv, index, table, kind, number):
-        with jax.named_scope("layer/attn"):
-            y = L.rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-            leaves = (ck,) if kind == SLIDING else (ck, cv)
-            out, leaves, counts, kept = _attend_cached(
-                cfg, kind, layer, y, leaves, index, table, w, choices)
-            x = x + out
-        y = L.rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
-        out, record, chosen = _ffn(cfg, blocks, stacks, number, y, live,
-                                   choices)
-        aux = {"record": record, "counts": counts}
-        if choices:
-            aux["experts"] = chosen
-            aux["keys"] = kept if kept is not None else jnp.zeros(
-                input_ids.shape + (s_max,), bool)
-        return (x + out, *leaves, aux)
+    def step(*args):
+        return block_cached(cfg, blocks, stacks, w, live, choices, s_max,
+                            *args)
 
     head, periods, tail = cfg.stretches
     by_kind = {kind: blocks[kind] for kind in (FULL, SLIDING)
@@ -532,10 +568,9 @@ def forward_cached(cfg: Dots3Config, params, input_ids, cache, pos,
     for kind in tail:
         x, cache = stretch(x, cache, (kind,), 1, 1)
     aux = jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *auxes)
-    if not all_positions:
-        x = cached.gather_last(x, w.gather)
     x = L.rms_norm(x, params["final_norm"], cfg.rms_eps)
-    logits = x @ params["lm_head"].astype(x.dtype)
+    last = x if all_positions else cached.gather_last(x, w.gather)
+    logits = last @ params["lm_head"].astype(x.dtype)
     out = (logits, cache)
     if routing:
         out += ((aux["record"], aux["counts"].sum(axis=0)),)
@@ -544,6 +579,8 @@ def forward_cached(cfg: Dots3Config, params, input_ids, cache, pos,
                            jnp.int32)
         out += ({"experts": aux["experts"][cfg.first_dense:],
                  "keys": aux["keys"][full]},)
+    if hidden:
+        out += (x,)
     return out
 
 
@@ -567,22 +604,24 @@ def forward(cfg: Dots3Config, params, input_ids):
 
 
 def init_cache(cfg: Dots3Config, num_blocks: int, block_size: int,
-               dtype=jnp.bfloat16, window_blocks: Optional[int] = None):
+               dtype=jnp.bfloat16, window_blocks: Optional[int] = None,
+               more_full: int = 0):
     """The cache of a serving engine (block-paged only), leaves BY LAYER KIND
     (``ops/paged_kv.py`` "Layer kinds"): ``latent [L_full, num_blocks, 1,
     block_size, W]`` and the indexer's key ``idx [L_full, num_blocks, 1,
     block_size, DI]`` under the full kind's table; ``latw [L_sliding,
     window_blocks, 1, window block, W']`` — another width, blocks of its own
     bytes (:meth:`Dots3Config.window_block`) — under the window kind's
-    ring."""
-    if window_blocks is None:
+    ring.  ``more_full``: that many more layers of the full kind's two
+    leaves, behind the model's own (a module that is one more such layer)."""
+    full, sliding = cfg.layers_of(FULL) + more_full, cfg.layers_of(SLIDING)
+    if sliding and window_blocks is None:
         raise NotImplementedError(
             "a model with sliding-window layers is served through the "
             "block-paged pool (init_serving / ServingEngine: "
             "init_cache(..., window_blocks=)): the contiguous cache of "
             "InferenceEngine.generate has one kind of state")
     cache = {}
-    full, sliding = cfg.layers_of(FULL), cfg.layers_of(SLIDING)
     if full:
         cache["latent"] = jnp.zeros(
             (full, num_blocks, 1, block_size,

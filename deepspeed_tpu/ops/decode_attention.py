@@ -2094,10 +2094,14 @@ _SPARSE_LATENT_VMEM = 32 << 20
 
 def sparse_latent_walk_shape(t: int, bs: int, nbper: int) -> Tuple[int, int]:
     """``(tq, nt)`` of the selected latent read for a window of ``t``
-    positions (1, or a multiple of 8): the query positions a grid step takes
-    and the blocks a landing tile holds."""
-    tq = 1 if t == 1 else _SPARSE_LATENT_QUERY_TILE
-    nt = max(1, min(_SPARSE_LATENT_COLS * (2 if tq == 1 else 1) // bs, nbper))
+    positions (1, 2 or 4 — a decode step's one, a verify window's K + 1 —
+    or a multiple of 8: ``sparse_index_attention`` pads any other): the query positions a grid step takes and the
+    blocks a landing tile holds.  A short window is ONE grid step a row:
+    its positions' query rows side by side, a block landed once for every
+    position of the row that chose in it."""
+    tq = min(t, _SPARSE_LATENT_QUERY_TILE)
+    short = tq < _SPARSE_LATENT_QUERY_TILE
+    nt = max(1, min(_SPARSE_LATENT_COLS * (2 if short else 1) // bs, nbper))
     while nbper % nt:
         nt -= 1
     return tq, nt
@@ -2222,7 +2226,7 @@ def paged_sparse_latent_attention_pallas(q, pool, block_tables, scores, theta,
                                          interpret: Optional[bool] = None):
     """The read of learned sparse attention over a LATENT pool: ``q [B, H,
     T, W]`` (absorbed, as :func:`paged_latent_attention_reference` takes
-    it; ``T`` 1 or a multiple of 8) over the keys each position chose, out
+    it; ``T`` 1, 2, 4 or a multiple of 8) over the keys each position chose, out
     of the stacked float leaf ``[L, NB, 1, bs, W]`` at ``layer``, read in
     place.  ``scores [B, T, NBPER * bs]`` float32 (``paged_index_scores``),
     ``theta`` / ``s_last [B, T]`` (``paged_sparse_select``) and ``last [B,
@@ -2235,7 +2239,7 @@ def paged_sparse_latent_attention_pallas(q, pool, block_tables, scores, theta,
     b, h, t, w = q.shape
     _, nb, one, bs, width = pool.shape
     assert one == 1 and width == w and w % LANES == 0 \
-        and (t == 1 or t % 8 == 0), (pool.shape, q.shape)
+        and (t in (1, 2, 4) or t % 8 == 0), (pool.shape, q.shape)
     if interpret is None:
         interpret = interpret_kernels()
     nbper = block_tables.shape[1]

@@ -364,9 +364,14 @@ def paged_sparse_latent_attention(q, pool, idx_pool, qi, wi, block_tables,
     walk, chosen at run time.  The read under the selection lands the
     BLOCKS that hold a key some position of a grid step chose and masks (on
     a TPU the kernel ``paged_sparse_latent_attn``; :func:`_masked_latent_walk`
-    elsewhere and for a window that is neither one position nor a multiple
+    elsewhere and for a window of 9 or more positions that is no multiple
     of 8): a latent token is one row of a block, and Mosaic copies rows
-    eight at a time."""
+    eight at a time.  A WINDOW of ``K + 1`` positions (a verify round: ``T``
+    under 8, each row at a base of its own) is one grid step a row — both
+    positions' latents and index keys are written before the call, position
+    ``i`` scores and selects over the keys ``<= q_pos + i``
+    (:func:`last_visible`), and a block lands once for all the positions of
+    the row that chose in it."""
     global _TOOK
     if paged_kv.tp_mesh() is not None or paged_kv.dp_groups() > 1 \
             or decode_attention.window_state() is not None \
@@ -396,28 +401,40 @@ def paged_sparse_latent_attention(q, pool, idx_pool, qi, wi, block_tables,
 
     if s_max <= topk:
         return dense()
-    kernel = on_tpu() and (t == 1 or t % 8 == 0) and h % 8 == 0
+    kernel = on_tpu() and h % 8 == 0
     _TOOK = "paged_index_scores+paged_sparse_select+" + (
         "paged_sparse_latent_attn" if kernel else "latent_walk") \
         if on_tpu() else "gather+top_k+latent_walk"
+    # the window the kernels take: 1, 2 or 4 positions as they are (one grid
+    # step a row), any other padded to whole steps of 8 with positions that
+    # see no key and are nobody's (Mosaic slices a score slab by 4 rows)
+    tk = t if not kernel or t in (1, 2, 4) else -(-t // 8) * 8
+
+    def padded(x, axis, value=0):
+        widths = [(0, 0)] * x.ndim
+        widths[axis] = (0, tk - t)
+        return jnp.pad(x, widths, constant_values=value) if tk != t else x
 
     def sparse():
+        seen, mine = padded(last, 1, -1), padded(real, 1, False)
         with jax.named_scope("sparse_attn/score"):
-            scores = index_scores(qi, wi, idx_pool, bt, last, layer)
+            scores = index_scores(padded(qi, 2), padded(wi, 1), idx_pool, bt,
+                                  seen, layer)
         with jax.named_scope("sparse_attn/select"):
             theta, s_last = select_threshold(scores, topk)
-            keep = chosen(scores, theta, s_last, last)
+            keep = chosen(scores, theta, s_last, seen)
         with jax.named_scope("sparse_attn/read"):
             if kernel:
                 out, landed = \
                     decode_attention.paged_sparse_latent_attention_pallas(
-                        q, pool, bt, scores, theta, s_last, last, rank=rank,
-                        layer=layer, real=real)
-                read = landed * bs
+                        padded(q, 2), pool, bt, scores, theta, s_last, seen,
+                        rank=rank, layer=layer, real=mine)
+                out, read = out[:, :, :t], landed * bs
             else:
                 out = _masked_latent_walk(q, pool, bt, keep, last, layer,
                                           rank)
                 read = jnp.sum(blocks) * bs
+        keep = keep[:, :t]
         return finish(
             out, [visible, jnp.sum(keep & real[:, :, None]), visible,
                   jnp.sum(jnp.any(real & (last >= topk), axis=1)), read],

@@ -57,7 +57,7 @@ __all__ = ["analytic_program_flops", "busy_fractions",
 #: timeline ``X`` span names folded into each busy phase
 _PHASE_SPANS = {
     "prefill": ("prefill",),
-    "decode": ("decode", "spec_propose", "spec_verify"),
+    "decode": ("decode", "spec_propose", "spec_verify", "spec_round"),
     "swap": ("swap",),
 }
 
@@ -234,8 +234,23 @@ class ServingFlopsProfiler:
                         sds(srv._dcache))
             else:
                 head = (params, cache)
+            # (a self-drafting engine: the token after each row's window
+            # and where its draft is read)
+            more = (i32(j), i32(j)) if srv._self_draft else ()
             return head + (i32(j, width), tables(j, prefill=True), i32(j),
-                           i32(j)) + samp(j)
+                           i32(j)) + more + samp(j)
+        if family == "verify" and srv._self_draft:
+            # the round's first program: the window is taken on the device
+            return (params, cache, i32(slots), i32(slots, srv.spec_tokens),
+                    i32(slots), tables(slots), i32(slots), i32(slots)) \
+                + samp(slots)
+        if family == "draft" and srv._self_draft:
+            # its second: the model's own module over what the first handed
+            # on (its abstract results)
+            carry = jax.eval_shape(
+                srv._program_bodies["verify"],
+                *self._abstract_args("verify", sampling=sampling))[2]
+            return (params, cache, i32(slots, srv.spec_tokens), carry)
         if family == "verify":
             w = srv.spec_tokens + 1
             return (params, cache, i32(slots, w), i32(slots, nb),
@@ -252,6 +267,9 @@ class ServingFlopsProfiler:
         if family == "prefill":
             return {"rows": srv.prefill_batch, "width": srv.prefill_chunk}
         if family == "verify":
+            return {"rows": srv.slots, "width": srv.spec_tokens + 1}
+        if family == "draft" and srv._self_draft:
+            # the module over the window's K + 1 positions
             return {"rows": srv.slots, "width": srv.spec_tokens + 1}
         if family == "draft":
             # K single-token scan steps per invocation
@@ -312,6 +330,9 @@ class ServingFlopsProfiler:
                 continue
             meta = self._shape_meta(family)
             fam_dims = ddims if family == "draft" else dims
+            if family == "draft" and srv._self_draft:
+                # the model's own module: ONE block at the model's widths
+                fam_dims = {**dims, "layers": int(srv._self_draft["depth"])}
             comp = analytic_components(
                 family, fam_dims, rows=meta["rows"], width=meta["width"],
                 ctx=srv._cache_len)
@@ -372,8 +393,8 @@ class ServingFlopsProfiler:
         calls = {"prefill": srv.prefill_calls,
                  "decode": srv.decode_steps,
                  "verify": srv.spec_rounds,
-                 "draft": srv.spec_rounds if srv._draft is not None
-                 else 0}
+                 "draft": srv.spec_rounds
+                 if srv._draft is not None or srv._self_draft else 0}
         total = sum(p["flops_per_call"] * calls.get(f, 0)
                     for f, p in programs.items())
         if total > self._last_total:

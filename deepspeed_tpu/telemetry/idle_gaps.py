@@ -59,7 +59,8 @@ OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 #: the program's in-flight spans (``ServingEngine.step``), as annotations
 IN_FLIGHT = tuple("ds.serve." + n for n in (
-    "prefill", "decode", "spec_propose", "spec_verify", "swap"))
+    "prefill", "decode", "spec_propose", "spec_verify", "spec_round",
+    "swap"))
 #: host spans that take part: the program's, and a caller's own
 SPAN_PREFIXES = ("ds.", "cb.")
 OUTSIDE = "outside_any_span"

@@ -24,11 +24,15 @@ FAMILIES = sorted(set(MODULES) - {"cached"})
 #: norm, q/k-norm, rotary and head as that module's attributes (PR 57);
 #: ``Dots3Config(MixtralConfig)``, whose two latent kinds are ``llama``'s
 #: latent attention at sizes of their own and whose routed FFN is
-#: ``mixtral``'s (PR 61)
+#: ``mixtral``'s (PR 61); ``GlmDsaConfig(Dots3Config)``: GLM-5's every layer
+#: is ``dots3``'s full kind (its block, its cache, its forwards by their
+#: public names) and its module one more such block, with ``llama``'s norm
+#: and latent hook (PR 64)
 ALLOWED = {("mixtral", "llama"), ("megatron_gpt", "gpt2"), ("unet", "vae"),
            ("kimi_linear", "mixtral"), ("kimi_linear", "llama"),
            ("granite_hybrid", "llama"), ("brumby", "llama"),
-           ("dots3", "mixtral"), ("dots3", "llama")}
+           ("dots3", "mixtral"), ("dots3", "llama"),
+           ("glm_dsa", "dots3"), ("glm_dsa", "llama")}
 
 
 def _sibling_imports(tree):

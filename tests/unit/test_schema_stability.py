@@ -86,6 +86,9 @@ ENGINE_STATS_KEYS = frozenset({
     "prefix_cache_entries",
     "prefix_cache_evictions", "prefix_cache_hit_rate",
     "prefix_hit_tokens", "prompt_tokens", "quantize", "queue_depth",
+    # PR 64: the prompt tokens the trie was asked about; tokens a decoding
+    # row commits a self-drafting round
+    "prefix_query_tokens", "tokens_per_round",
     "requests_finished", "resume_recompute_tokens", "retraces_observed",
     "role",
     "sampling", "logit_masks", "sampled_requests",

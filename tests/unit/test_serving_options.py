@@ -226,6 +226,10 @@ BREAKS = [
     (dict(role="prefill"), {}, True, True),
     (dict(nvme_blocks=8), {}, True, True),
     (dict(**HOST, nvme_blocks=8, nvme_high_watermark=0.2), {}, True, True),
+    # (the model's own module as the proposer: the tiny engine's model has
+    # none, which its constructor says first)
+    (dict(draft="self", spec_tokens=1, logit_masks=True), {}, False, True),
+    (dict(draft="self", spec_tokens=1, **HOST), {}, False, True),
 ]
 ONE_SHARD = {"tp": 1, "dp": 1, "mesh_sp": 1, "weights": None}
 
