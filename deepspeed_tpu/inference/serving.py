@@ -95,9 +95,16 @@ window ``[pending, d_1..d_K]`` taken where the device left it
 WALKS it on the device; ``draft`` runs the module over the 1..K+1 positions
 the walk committed and leaves the next draft beside the next pending token.
 The host gets ``[slots, K + 1]`` emitted ids and a count a row: no round-trip
-between a draft and its verify, one ``spec_round`` span a round.  (c) The
+between a draft and its verify, one ``spec_round`` span a round.  The count
+stays on the device too (``_devcount``, beside the token and the draft):
+round n + 1 is enqueued BEFORE round n's ids and counts are copied to the
+host — the one call of lookahead every plain program has
+(:meth:`ServingEngine._launch`) — and takes a row's base and sampler count
+as the host's committed values plus what the device holds; the host plans
+that round on bounds (:meth:`ServingEngine._run_self_round`).  (c) The
 commit (:meth:`ServingEngine._commit_self_round`) takes 1..K+1 tokens a row,
-cut at the row's ``eos`` or where its budget ends.  (d) The draft is the
+cut at the row's ``eos`` or where its budget ends; a row that ended there
+has ridden the round in flight, which drops it.  (d) The draft is the
 module's argmax, verified by the delta-form rejection sampler: sampled rows
 are distribution-exact, greedy rows token-exact with speculation off.  (e)
 **The trie beside it**: the module's entry at a shared prefix's last position
@@ -225,7 +232,11 @@ scheduler keeps ONE call of lookahead (:meth:`ServingEngine._launch`): the
 tokens of call n reach their handles during ``step()`` n+1, behind the
 enqueue of call n+1, and ``step()`` says ``False`` only once nothing is in
 flight; ``drain()`` / ``close()`` / a cancel / a preemption take the
-results first, ``salvage()`` leaves them (they were never streamed).  The batch
+results first, ``salvage()`` leaves them (they were never streamed).  A
+self-drafting engine's rounds (``draft="self"``) ride it like a decode step
+— what round n + 1 needs of round n (a token, a draft and a count a row)
+stays on the device —; a draft model's and the n-gram proposer's rounds keep
+their own fence (``EARLY_SETTLE_CAUSES``: ``"speculative"``).  The batch
 ``serve(list)`` entry point survives as a thin wrapper — submit all,
 loop ``step()``, gather results — with byte-identical scheduling, and
 tolerates an empty request list without tracing anything.  Admission
@@ -422,9 +433,12 @@ STALL_CAUSES = ("offcpu", "gc", "device_wait", "host")
 _STALL_FIELDS = ("wall",) + SEGMENTS + ("offcpu", "gc")
 #: why a call's results were taken before the next call was on the device's
 #: queue (:meth:`ServingEngine._settle`): what the engine is (its audits, a
-#: runner or a tier with a fence of its own, a replica that hands its rows
-#: on), what its rows need (a mask made on the host from their tokens), or
-#: what is about to be done to a row that is in flight
+#: runner or a tier with a fence of its own — ``"speculative"``: a draft
+#: model's rollout and the n-gram lookup start from a round's tokens; NOT a
+#: self-drafting engine, whose round leaves its successor's inputs on the
+#: device —, a replica that hands its rows on), what its rows need (a mask
+#: made on the host from their tokens), or what is about to be done to a row
+#: that is in flight
 EARLY_SETTLE_CAUSES = ("debug_checks", "speculative", "kv_tier", "handoff",
                        "mask_builder", "preempt", "cancel", "drain", "close")
 #: the cache leaves of the state kind: indexed by SLOT, never by block
@@ -822,6 +836,13 @@ class _SlotState:
     #: yet, but the cache length, the sampler's count and the budget of the
     #: next call are planned as if they were
     ahead: int = 0
+    #: self-drafting rounds of this row the device has been handed whose
+    #: counts the host has not harvested (0 or 1,
+    #: ``ServingEngine._run_self_round``): each made 1..K+1 tokens, how many
+    #: is on the device only (``_devcount``), so the row's length and its
+    #: sampler's count are the host's plus that, and the host plans on
+    #: bounds
+    rounds: int = 0
     #: prefill calls of this row's group in a row that it was ready for and
     #: did not run in (``ServingEngine._rung_for``): its place in the
     #: group's turn order, at most ``prefill_batch - 1``
@@ -854,7 +875,7 @@ class _Flight:
     ``shape`` that of the tokens in it, ``commit`` what the harvest hands
     the tokens to, ``held`` the call's operands, kept until then,
     ``phase`` the host phase its ``upload`` / ``commit`` segments go under
-    (its own name; ``decode`` for a speculative round)."""
+    (its own name; ``decode`` for a self-drafting round, ``spec_round``)."""
 
     __slots__ = ("name", "args", "out", "shape", "commit", "held", "phase")
 
@@ -1345,6 +1366,15 @@ class ServingEngine:
         self._devdraft = jax.device_put(
             np.zeros((self.slots, self.spec_tokens), np.int32), rep) \
             if self._self_draft else None
+        #: and the third vector, ``[slots]``: how many tokens the round just
+        #: run committed for each row (1..K+1; 0 for a row that was not in
+        #: it).  The NEXT round, enqueued before this one's counts reach the
+        #: host, adds a row's entry to the base and the sampler's count the
+        #: host gave it where the host says the row is a round behind
+        #: (``behind``); the prefill programs neither read nor write it — a
+        #: row that comes from one is not behind
+        self._devcount = jax.device_put(
+            np.zeros(self.slots, np.int32), rep) if self._self_draft else None
         #: slots whose pending token the HOST names in the next round (a
         #: sampled resume backs up one position); every other row's is the
         #: device's (``TOKEN_ON_DEVICE``)
@@ -2222,10 +2252,13 @@ class ServingEngine:
 
     def _keep_device(self, out) -> None:
         """What a serving program leaves on the device, from its results
-        ``(flat, cache[, draft cache], token vector[, draft vector])``."""
+        ``(flat, cache[, draft cache], token vector[, draft vector[, count
+        vector]])`` — the last a self-drafting round's alone."""
         self._cache = out[1]
         if self._self_draft:
-            self._devtok, self._devdraft = out[-2], out[-1]
+            self._devtok, self._devdraft = out[2], out[3]
+            if len(out) == 5:
+                self._devcount = out[4]
             return
         self._devtok = out[-1]
         if len(out) == 4:                  # the prefill fused with a draft's
@@ -2912,24 +2945,51 @@ class ServingEngine:
         the trunk's names); fused into ``verify`` it would save one dispatch
         a round.  A rejected draft's cache entries (the trunk's at ``base +
         accepted + 1 ..``, the module's likewise) stay position-masked and
-        are overwritten by the next round."""
+        are overwritten by the next round.
+
+        **The third vector** (:attr:`_devcount`, int32 ``[slots]``): the
+        counts ``verify`` walked stay on the device beside the token and the
+        draft, because the NEXT round is enqueued before they reach the host
+        (:meth:`_run_self_round`).  ``verify`` reads it: for a row the host
+        marks ``behind`` (its last round is still in flight) the window's
+        base and the sampler's emission count are the host's values PLUS the
+        row's entry — the length and the count the commit of that round
+        will give it if the row goes on; for any other row (its last round
+        is settled, or it comes from a prefill call, which does not touch
+        the vector) they are the host's own.  ``draft`` takes the same base
+        from ``verify``.  A row whose request ended in the round in flight
+        (its ``eos``, or a budget the walk overran) rides the next round
+        all the same, at a base the device advanced by the whole walk: what
+        it writes lands in blocks its slot held or in scratch (a base is
+        held under ``cache_len``, so the window reaches no further past the
+        table than a last round's does), behind nothing a later row reads,
+        and :meth:`_commit_self_round` drops what it made."""
         if self._round_fn is None:
             prepare = self.engine._prepare
             k, pack = self.spec_tokens, self._pack_samp
             constrain, pin = self._constrain_pool, self._pin_tokens
             leaves = jax.tree_util.tree_leaves
+            cache_len = self._cache_len
 
-            def verify(params, cache, devtok, devdraft, tokens, block_tables,
-                       base, valid, *samp):
+            def verify(params, cache, devtok, devdraft, devcount, tokens,
+                       block_tables, base, valid, behind, *samp):
                 """``tokens`` int32 [slots]: a row's pending token, or
-                ``TOKEN_ON_DEVICE``; ``base`` the committed lengths,
-                ``valid`` K + 1 for a decoding row and 0 for any other (all
-                its writes land in scratch).  -> ``(cache, pending tokens,
-                what the module's program takes: the emitted ids and counts
-                flat, the hidden states, the ids, the counts, the tables,
-                the bases, the records)``."""
+                ``TOKEN_ON_DEVICE``; ``base`` the lengths the host has
+                committed, ``valid`` K + 1 for a decoding row and 0 for any
+                other (all its writes land in scratch), ``behind`` 1 for a
+                row whose last round the host has not harvested: its base
+                and its sampler's count are the host's plus its entry of
+                ``devcount``.  -> ``(cache, pending tokens, what the
+                module's program takes: the emitted ids and counts flat, the
+                hidden states, the ids, the counts — the next round's
+                ``devcount`` —, the tables, the bases, the records)``."""
                 pend = jnp.where(tokens == TOKEN_ON_DEVICE, devtok, tokens)
                 ids = jnp.concatenate([pend[:, None], devdraft], axis=1)
+                lag = jnp.where(behind > 0, devcount, 0)
+                # (held inside the cache: a no-op for a row that goes on)
+                base = jnp.minimum(base + lag, cache_len - 1)
+                if samp:
+                    samp = samp[:4] + (samp[4] + lag,) + samp[5:]
                 with decode_attention.dispatch_log() as paths:
                     logits, cache, rec, hidden = self._forward_hidden(
                         prepare(params), ids, cache, base, lengths=valid,
@@ -2958,7 +3018,7 @@ class ServingEngine:
                     emitted = jnp.where(
                         col < a[:, None], jnp.roll(ids, -1, axis=1),
                         jnp.where(col == a[:, None], tail[:, None], 0))
-                    count = jnp.where(valid > 0, a + 1, 0)
+                    count = pin(jnp.where(valid > 0, a + 1, 0))
                 flat = jnp.concatenate([emitted.reshape(-1), count])
                 return constrain(cache), pin(tail), (
                     flat, hidden, emitted, count, block_tables, base,
@@ -2982,11 +3042,11 @@ class ServingEngine:
             self._program_bodies["verify"] = verify
             self._program_bodies["draft"] = draft
             spec = self._operand_spec(self.slots, {"tokens": None},
-                                      ("base", "valid"))
+                                      ("base", "valid", "behind"))
             donate = (1,) if self._donate() else ()
             self._verify_fn = self._first_call(jax.jit(
                 self.sentry.wrap(
-                    self._packed("verify", verify, spec, device_operands=4),
+                    self._packed("verify", verify, spec, device_operands=5),
                     "verify"), donate_argnums=donate),
                 "verify", vars(self), "_verify_fn",
                 slots=self.slots, window=k + 1)
@@ -2996,12 +3056,12 @@ class ServingEngine:
             self.compiled_programs += [("verify", self.slots, k + 1),
                                        ("draft", self.slots, k)]
 
-            def spec_round(params, cache, devtok, devdraft, *host):
+            def spec_round(params, cache, devtok, devdraft, devcount, *host):
                 cache, tail, carry = self._verify_fn(
-                    params, cache, devtok, devdraft, *host)
+                    params, cache, devtok, devdraft, devcount, *host)
                 flat, cache, drafts = self._draft_fn(params, cache, devdraft,
                                                      carry)
-                return flat, cache, tail, drafts
+                return flat, cache, tail, drafts, carry[3]
 
             self._round_fn = spec_round
         return self._round_fn
@@ -4532,11 +4592,18 @@ class ServingEngine:
         """Why the call just enqueued cannot stay in flight while the next
         one is planned (``EARLY_SETTLE_CAUSES``), from what the engine is
         and what its rows carry — or None: the plan of the next call needs
-        nothing of this one that the host does not know."""
+        nothing of this one that the host does not know, or nothing that the
+        device does not hold for it (a decode row's token; a self-drafting
+        round's token, draft and count — its base and sampler count are
+        settled on the device, :meth:`_get_round_fn`)."""
         if self.debug_checks:
             return "debug_checks"          # the audit reads committed state
-        if self.spec_tokens:
-            return "speculative"           # a round plans on its tokens
+        if self.spec_tokens and not self._self_draft:
+            # a draft model's or the n-gram proposer's round plans on its
+            # tokens (the lookup is host work on them).  A self-drafting
+            # round leaves what its successor needs on the device
+            # (``_devtok`` / ``_devdraft`` / ``_devcount``)
+            return "speculative"
         if self._host is not None or self.resident_window_blocks:
             return "kv_tier"               # demotions key blocks by tokens
         if self.role == "prefill":
@@ -4553,9 +4620,11 @@ class ServingEngine:
         the call before it (:meth:`_harvest`): the device goes from one
         program into the next while the host harvests, commits and plans.
         The caller has advanced what is certain of the call's outcome
-        (lengths, bases, phases, ``ahead``); what is not — the tokens —
-        stays on the device (``_devtok``) until this call is settled in
-        its turn, at once if :meth:`_settle_now` names a cause.
+        (lengths, bases, phases, ``ahead``; of a self-drafting round only
+        that it is in flight, ``rounds``); what is not — the tokens, such a
+        round's counts — stays on the device (``_devtok``, ``_devcount``)
+        until this call is settled in its turn, at once if
+        :meth:`_settle_now` names a cause.
 
         On the ring a call's ``decode`` / ``prefill`` span is the stay in
         the runtime that ENDED with its results on the host: from the
@@ -5173,7 +5242,11 @@ class ServingEngine:
         allocated, refcounts untouched).  Block demand is capped at each
         request's remaining completion budget (``pos_cap``) — window
         positions past the cap scatter to scratch instead of allocating.
+        The model's own module as the proposer: :meth:`_run_self_round`,
+        the one of the three whose round rides the call of lookahead.
         """
+        if self._self_draft:
+            return self._run_self_round(params)
         k = self.spec_tokens
         active = self._active
         seg, phase = self.timeline.segment, self._phase
@@ -5196,8 +5269,6 @@ class ServingEngine:
             bt[dec] = self._tables[dec]
             counts = self._decode_counts()
             samp = self._samp_args(counts)
-        if self._self_draft:
-            return self._run_self_round(params, dec, bt, samp)
         if self._draft is not None:
             draft_fn = self._get_draft_fn()
             with seg("step.decode.upload", phase):
@@ -5264,23 +5335,67 @@ class ServingEngine:
             return self._commit_spec_round(dec, ids, scored, accept, plain,
                                            resid)
 
-    def _run_self_round(self, params, dec, bt, samp) -> int:
+    def _run_self_round(self, params) -> int:
         """:meth:`_run_spec_decode`'s round where the proposer is the
         model's own module (:meth:`_get_round_fn`): ONE call, handed over
-        and harvested through :meth:`_launch` like a decode step (it
-        settles at once: the next round plans on this one's counts), under
-        ONE ``spec_round`` span.  ``dec`` / ``bt`` / ``samp``: the decoding
-        slots, their tables and the sampling tail, as planned."""
+        and harvested through :meth:`_launch` like a decode step, under ONE
+        ``spec_round`` span — and, like a decode step, planned while the
+        round before it may still be in flight.
+
+        What the host does not know of such a row (``rounds``) is how many
+        tokens, 1..K+1, the round in flight commits for it.  The DEVICE
+        knows (:attr:`_devcount`): the row is marked ``behind`` and the
+        program adds its count to the base and the sampler's count the host
+        gives — the committed ones.  The host plans on BOUNDS.  Blocks are
+        reserved for the longest the row can be, ``committed + K + 1``,
+        plus the window, under ``pos_cap`` and the cache as ever (a block
+        too many for a round, at most).  A row whose budget the calls in
+        flight spend FOR CERTAIN — each makes at least one token — sits the
+        round out and finishes at their commit; one whose budget they MAY
+        spend (``max_new_tokens - gen_count <= K + 1``) rides, as does one
+        whose ``eos`` may be among them, and :meth:`_commit_self_round`
+        drops what this round made of a row that had ended: the tokens a
+        request receives are those of rounds settled one by one.  The
+        span's reach (:meth:`_kv_reach`: ``kv_valid`` / ``kv_blocks`` /
+        ``kv_pairs`` / ``latent_bytes`` / ``kv_tiles``) is taken at the
+        COMMITTED length — up to K + 1 keys a row short of what a behind
+        row's window reads, of thousands —; what the selection read
+        (``index_keys`` / ``kv_selected`` / ``kv_read``) is counted on the
+        device and exact."""
         k = self.spec_tokens
         active = self._active
         seg, phase = self.timeline.segment, self._phase
+
+        def rows():
+            return [s for s, st in active.items() if st.phase == "decode"
+                    and st.gen_count + st.ahead + st.rounds
+                    < st.req.max_new_tokens]
+
         with seg("step.decode.plan", phase):
+            for slot in sorted(rows(), key=lambda s: active[s].admit_seq):
+                st = active.get(slot)
+                if st is not None:
+                    # (read here: an allocation that finds no block settles
+                    # the round in flight before it picks a victim)
+                    ln = int(self._lengths[slot])
+                    cap = max(st.pos_cap, ln + 1)
+                    self._kv(self._ensure_blocks, slot,
+                             min(ln + (st.rounds + 1) * (k + 1), cap,
+                                 self._cache_len))
+            dec = sorted(rows())
+            if not dec:
+                return 0
+            bt = np.zeros_like(self._tables)
+            bt[dec] = self._tables[dec]
+            samp = self._samp_args(self._decode_counts())
             tokens = np.full(self.slots, TOKEN_ON_DEVICE, np.int32)
             named = [s for s in dec if s in self._host_pending]
             tokens[named] = self._tokens[named]
             self._host_pending.difference_update(dec)
             valid = np.zeros(self.slots, np.int32)
             valid[dec] = k + 1
+            behind = np.zeros(self.slots, np.int32)
+            behind[dec] = [active[s].rounds for s in dec]
             round_fn = self._get_round_fn()
             span_kw = {**self._sampler_rows(dec),
                        **self._kv_reach(self._lengths[dec] + k + 1,
@@ -5288,7 +5403,7 @@ class ServingEngine:
                                         at=dec)}
         with seg("step.decode.upload", phase):
             host, puts = self._host_operands(
-                "verify", tokens, bt, self._lengths, valid, *samp)
+                "verify", tokens, bt, self._lengths, valid, behind, *samp)
             fed = [(slot, active[slot]) for slot in dec]
             flight = _Flight(
                 "spec_round", dict(slots=len(dec), window=k + 1,
@@ -5296,8 +5411,10 @@ class ServingEngine:
                 (self.slots * (k + 2),),
                 functools.partial(self._commit_self_round, fed),
                 phase="decode")
+            for _, st in fed:
+                st.rounds += 1
             flight.held = (params, self._cache, self._devtok,
-                           self._devdraft, *host)
+                           self._devdraft, self._devcount, *host)
             del host
             ctx = self._decode_ctx()
         self._launch(flight, round_fn, ctx)
@@ -5305,11 +5422,18 @@ class ServingEngine:
 
     def _commit_self_round(self, rows, flat) -> None:
         """The commit of :meth:`_run_self_round`, when the round's emitted
-        ids and counts are on the host: a row takes its 1..K+1 tokens, cut
-        at its ``eos`` or where its budget ends (it is finished then, and
-        what the device left for its next round is never read); a row that
-        goes on has its pending token and its draft on the device.  A row
-        whose request ended while the round was in flight is dropped."""
+        ids and counts are on the host — as a rule behind the enqueue of
+        the NEXT round, which these rows ride already: a row takes its
+        1..K+1 tokens, cut at its ``eos`` or where its budget ends (it is
+        finished then: what the device left for its next round is never
+        read, and what the round already in flight makes of it is dropped
+        at that round's commit); a row that goes on advances by the whole
+        count — what the device has added to its base already — and has its
+        pending token, its draft and that count on the device.  A row whose
+        request ended while the round was in flight (the round before cut
+        it, a prefill call's first token was its ``eos``) is dropped:
+        nothing of it is emitted or counted (the span's ``emitted`` /
+        ``accepted`` are the device's walk and include it)."""
         k = self.spec_tokens
         active = self._active
         emitted = flat[:self.slots * (k + 1)].reshape(self.slots, k + 1)
@@ -5317,6 +5441,7 @@ class ServingEngine:
         self._c_spec_rounds.inc()
         accept_lens, total, drafted = [], 0, 0
         for slot, st in rows:
+            st.rounds -= 1
             if active.get(slot) is not st:
                 continue
             n = int(count[slot])

@@ -241,9 +241,11 @@ class ServingFlopsProfiler:
                            i32(j)) + more + samp(j)
         if family == "verify" and srv._self_draft:
             # the round's first program: the window is taken on the device
+            # (the token, draft and count vectors), then the host's tokens,
+            # tables, bases, valid and behind
             return (params, cache, i32(slots), i32(slots, srv.spec_tokens),
-                    i32(slots), tables(slots), i32(slots), i32(slots)) \
-                + samp(slots)
+                    i32(slots), i32(slots), tables(slots), i32(slots),
+                    i32(slots), i32(slots)) + samp(slots)
         if family == "draft" and srv._self_draft:
             # its second: the model's own module over what the first handed
             # on (its abstract results)

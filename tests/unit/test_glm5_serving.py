@@ -365,7 +365,13 @@ def test_a_round_is_one_span_and_one_harvest(small):
     assert st["kv_kinds"]["draft"] == {"layers": 1, "table": "full",
                                        "depth": 1}
     assert st["kv_kinds"]["full"]["layers"] == 3
-    assert st["lookahead"]["early"].keys() == {"speculative"}
+    # the round rides the one call of lookahead: every call but the first
+    # was enqueued behind one in flight, and none was settled for a cause
+    look = st["lookahead"]
+    assert look["early"] == {} and look["ahead"] >= look["calls"] - 2 > 0
+    assert look["ahead"] == sum(e["args"]["ahead"] for e in events
+                                if e["name"] in ("spec_round", "prefill"))
+    assert sum(a["ahead"] for a in rounds) >= len(rounds) - 2
     assert srv.resolved_config()["draft"] == "self"
     assert srv._cache["latent"].shape[0] == 4
     prefills = [e["args"] for e in events if e["name"] == "prefill"]
@@ -379,6 +385,243 @@ def test_a_round_is_one_span_and_one_harvest(small):
     assert report["programs"]["draft"]["flops_per_call"] > 0
     srv.serve(_requests([40, 33], [6, 6], 16, seed=3))
     assert srv.stats()["compile_count"] == st["compile_count"]
+
+
+# ------------------------------------------ a round planned one call ahead
+def _pair(spec, params, **how):
+    """(a self-drafting engine that looks ahead, its twin that settles every
+    call at once — ``debug_checks=True``, there being no option)."""
+    return _engine(spec, params, debug_checks=False, **SELF, **how), \
+        _engine(spec, params, **SELF, **how)
+
+
+@pytest.fixture(scope="module")
+def pair(small):
+    ahead, serial = _pair(*small)
+    yield ahead, serial
+    ahead.close()
+    serial.close()
+
+
+def _mixed(lengths, new, sampled=(), seed=0):
+    reqs = _requests(lengths, new, 16, seed=seed)
+    for i in sampled:
+        reqs[i].temperature, reqs[i].top_p = 0.7, 0.9
+        reqs[i].seed = 2 ** 31 + 97 * i
+    return reqs
+
+
+def _streams(srv, reqs, eos=None, late=()):
+    """uid -> the tokens its handle streamed, ``step()`` driven by hand;
+    ``late``: (step, request) pairs submitted once that many steps ran."""
+    eos = eos or {}
+    submit = lambda r: srv.submit(r, eos_token_id=eos[r.uid]) \
+        if r.uid in eos else srv.submit(r)                 # noqa: E731
+    handles = [submit(r) for r in reqs]
+    late, steps = sorted(late, key=lambda p: p[0]), 0
+    while True:
+        while late and late[0][0] <= steps:
+            handles.append(submit(late.pop(0)[1]))
+        more = srv.step()
+        steps += 1
+        if not more and not late:
+            assert srv._flight is None and not srv._active
+            break
+    assert all(h.done for h in handles)
+    return {h.uid: list(h.tokens()) for h in handles}
+
+
+def _same(ahead, serial, reqs_of, **kw):
+    got, want = _streams(ahead, reqs_of(), **kw), \
+        _streams(serial, reqs_of(), **kw)
+    assert got == want
+    return got
+
+
+LOOK = dict(lengths=[40, 33, 50, 20, 64, 27, 45], new=[12, 9, 17, 15, 5, 20, 8])
+
+
+@pytest.mark.parametrize("sampled", [(), range(7), (1, 4, 5)],
+                         ids=["greedy", "sampled", "mixed"])
+def test_streams_under_lookahead_are_those_of_rounds_settled_at_once(
+        pair, sampled):
+    ahead, serial = pair
+    before = ahead.stats()
+    got = _same(ahead, serial, lambda: _mixed(**LOOK, sampled=sampled))
+    assert [len(got[i]) for i in range(7)] == LOOK["new"]
+    after, twin = ahead.stats(), serial.stats()["lookahead"]
+    calls = after["lookahead"]["calls"] - before["lookahead"]["calls"]
+    ran = after["lookahead"]["ahead"] - before["lookahead"]["ahead"]
+    # all but the first call of the trace was enqueued behind one in flight
+    assert ran >= calls - 2 and calls > 20
+    assert after["lookahead"]["early"] == {}
+    assert twin["ahead"] == 0
+    assert twin["early"] == {"debug_checks": twin["calls"]}
+    # drafts WERE accepted: rows advanced by counts only the device knew
+    assert after["accepted_tokens"] > before["accepted_tokens"]
+    assert after["tokens_per_round"] > 1.0
+
+
+@pytest.mark.parametrize("new", [1, 2, 3])
+def test_a_budget_the_round_in_flight_spends_or_may_spend(pair, new):
+    """K = 1: a budget of one is spent by the prefill call's token, of two
+    by the first round for certain (the row sits the second out), of three
+    perhaps (the row rides, and is dropped if the first round took both)."""
+    ahead, serial = pair
+    got = _same(ahead, serial, lambda: _requests(
+        [30, 31, 32, 33, 34, 35], [new] * 6, 16, seed=new))
+    assert all(len(t) == new for t in got.values())
+    assert ahead._alloc.blocks_in_use == serial._alloc.blocks_in_use
+
+
+def test_a_row_that_ends_on_eos_mid_batch_leaves_nothing_behind(small):
+    """The host cannot know that a round in flight holds a row's ``eos``:
+    the row rides the next round, at a base the device advanced by the whole
+    walk.  What that round makes of it is dropped — not streamed, not
+    counted — and every block comes back."""
+    ahead, serial = _pair(*small)
+    reqs = lambda: _mixed([40, 33, 50, 20, 64, 27], [24] * 6,  # noqa: E731
+                          sampled=range(6), seed=5)
+    free = _streams(serial, reqs())
+    eos = {}
+    for uid, toks in free.items():
+        new = [k for k in range(2, len(toks) - 1) if toks[k] not in toks[:k]]
+        if uid % 3 and new:
+            eos[uid] = toks[new[len(new) // 2]]
+    assert len(eos) >= 3
+    counted = ahead.stats()["generated_tokens"], \
+        serial.stats()["generated_tokens"]
+    got = _same(ahead, serial, reqs, eos=eos)
+    for uid, toks in got.items():
+        assert toks == free[uid][:len(toks)]
+        if uid in eos:
+            assert toks[-1] == eos[uid] and eos[uid] not in toks[:-1]
+            assert len(toks) < len(free[uid])
+    emitted = sum(map(len, got.values()))
+    assert ahead.stats()["generated_tokens"] - counted[0] == emitted
+    assert serial.stats()["generated_tokens"] - counted[1] == emitted
+    # rows rode past their end: the spans' rows outnumber the rows committed
+    rode = sum(e["args"]["slots"] for e in ahead.timeline.events()
+               if e["ph"] == "X" and e["name"] == "spec_round")
+    assert rode > ahead._c_round_rows.value > 0
+    assert ahead._alloc.blocks_in_use == serial._alloc.blocks_in_use
+    # the same prompts again hit what the twin's hit and stream the same
+    hits = ahead.prefix_hit_tokens, serial.prefix_hit_tokens
+    assert _same(ahead, serial, reqs, eos=eos) == got
+    assert ahead.prefix_hit_tokens - hits[0] == \
+        serial.prefix_hit_tokens - hits[1] > 0
+    ahead.close()
+    serial.close()
+
+
+def test_a_prompt_of_several_chunks_admitted_while_a_round_is_in_flight(pair):
+    ahead, serial = pair
+    prompt = np.random.default_rng(21).integers(0, 16, 100).astype(np.int32)
+    long = lambda: [(3, Request("late", prompt, 10))]      # noqa: E731
+    since = len(ahead.timeline.events())
+    first = lambda: _mixed([40, 33], [30, 30], sampled=(1,),  # noqa: E731
+                           seed=8)
+    got = _streams(ahead, first(), late=long())
+    want = _streams(serial, first(), late=long())
+    assert got == want and len(got["late"]) == 10
+    calls = [e for e in ahead.timeline.events()[since:]
+             if e["ph"] == "X" and e["name"] in ("prefill", "spec_round")]
+    chunks = [e for e in calls if e["name"] == "prefill"
+              and e["args"]["step"] > calls[0]["args"]["step"] + 2]
+    # the late prompt's chunks went out behind rounds in flight, and rounds
+    # behind them
+    assert len(chunks) >= 2 and all(e["args"]["ahead"] for e in chunks)
+    assert all(e["args"]["ahead"] for e in calls[1:])
+
+
+def test_a_shared_prefix_on_a_block_boundary_under_lookahead(small):
+    spec, params = small
+    rng = np.random.default_rng(9)
+    shared = rng.integers(0, 16, 2 * BLOCK).astype(np.int32)
+    prompts = [np.concatenate([shared, [t], rng.integers(0, 16, 9)])
+               .astype(np.int32) for t in (2, 11)]
+    ahead, serial = _pair(spec, params, slots=2)
+    outs = []
+    for srv in (ahead, serial):
+        # the second arrives while the first decodes: its hit is one block
+        # (two matched, the last dropped) on both engines
+        outs.append(_streams(srv, [Request(0, prompts[0], 12)],
+                             late=[(4, Request(1, prompts[1], 12))]))
+        assert srv.stats()["prefix_hit_tokens"] == BLOCK
+    assert outs[0] == outs[1]
+    assert ahead._alloc.blocks_in_use == serial._alloc.blocks_in_use
+    ahead.close()
+    serial.close()
+
+
+def test_a_pool_tight_enough_to_preempt_under_lookahead(small):
+    ahead, serial = _pair(*small, num_blocks=1 + 14)
+    reqs = lambda: _mixed([60, 58, 62], [30, 30, 30],  # noqa: E731
+                          sampled=(1,), seed=2)
+    _same(ahead, serial, reqs)
+    assert ahead.preempted > 0 and serial.preempted > 0
+    # a victim is chosen among committed rows: the round in flight settles
+    early = ahead.stats()["lookahead"]["early"]
+    assert set(early) == {"preempt"} and early["preempt"] > 0
+    assert ahead._alloc.blocks_in_use == serial._alloc.blocks_in_use
+    ahead.close()
+    serial.close()
+
+
+def test_cancelling_a_row_whose_round_is_in_flight(pair):
+    ahead, serial = pair
+
+    def run(srv):
+        handles = [srv.submit(r) for r in _requests(
+            [40, 33, 50], [20, 20, 20], 16, seed=11)]
+        for _ in range(6):
+            srv.step()
+        victim = handles[1]
+        assert not victim.done and len(victim.tokens()) > 0
+        pending = srv._flight is not None and \
+            srv._flight.name == "spec_round"
+        victim.cancel()
+        while srv.step():
+            pass
+        return pending, victim.status, \
+            {h.uid: list(h.tokens()) for h in handles}
+
+    early = ahead.stats()["lookahead"]["early"].get("cancel", 0)
+    was_pending, status, got = run(ahead)
+    _, _, want = run(serial)
+    assert was_pending and status == "cancelled"
+    assert ahead.stats()["lookahead"]["early"]["cancel"] == early + 1
+    for uid in got:
+        if uid == 1:
+            # what was streamed stands, and the round settled for the cancel
+            # gave it at most one window more
+            assert got[uid][:len(want[uid])] == want[uid]
+            assert 0 <= len(got[uid]) - len(want[uid]) <= 2
+        else:
+            assert got[uid] == want[uid]
+    assert ahead._alloc.blocks_in_use == serial._alloc.blocks_in_use
+
+
+def test_nothing_compiles_after_the_first_round(small):
+    spec, params = small
+    srv = _engine(spec, params, debug_checks=False, **SELF)
+    handles = [srv.submit(r) for r in _mixed(**LOOK, sampled=(2, 3))]
+    for _ in range(4):
+        srv.step()
+    built, traces = srv.compile_count, srv.sentry.traces
+    assert built == len(srv._rungs) + 2 == srv.compile_budget
+    while srv.step():
+        pass
+    _streams(srv, _mixed([90, 20], [6, 9], seed=29))
+    assert all(h.done for h in handles)
+    assert srv.compile_count == built and srv.sentry.traces == traces
+    assert srv.stats()["retraces_observed"] == 0
+    # the three vectors go from either program into either: one executable
+    for fn in (srv._verify_fn, srv._draft_fn, *srv._prefill_fns.values()):
+        assert fn._cache_size() == 1
+    look = srv.stats()["lookahead"]
+    assert look["ahead"] > 0 and "speculative" not in look["early"]
+    srv.close()
 
 
 @pytest.mark.parametrize("how,match", [

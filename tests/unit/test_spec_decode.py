@@ -162,6 +162,32 @@ def test_spec_draft_model_matches_sequential(tiny_engine):
     assert spec.stats()["speculative"].startswith("draft:")
 
 
+@pytest.mark.parametrize("proposer", ["ngram", "draft"])
+def test_a_draft_model_or_ngram_round_keeps_its_fence(tiny_engine, proposer):
+    """With no audit to settle them (``debug_checks`` off) these runners
+    still take a call's results before the next is planned: the n-gram
+    lookup is host work on the tokens, a draft model's rollout starts from
+    them.  ``stats()["lookahead"]`` says so by name — a self-drafting
+    engine's rounds alone ride the one call of lookahead
+    (``tests/unit/test_glm5_serving.py``)."""
+    engine, cfg = tiny_engine
+    how = {}
+    if proposer == "draft":
+        dcfg = gpt2.GPT2Config(vocab_size=cfg.vocab_size, max_seq_len=128,
+                               num_layers=1, num_heads=2, hidden_size=32)
+        how["draft"] = gpt2.build(dcfg)
+    spec = ServingEngine(engine, slots=3, max_seq_len=128, block_size=8,
+                         prefill_chunk=16, prefill_batch=2, spec_tokens=3,
+                         **how)
+    reqs = _trace(cfg, 5, seed=6)
+    assert_sequential(engine, reqs, spec.serve(reqs))
+    look = spec.stats()["lookahead"]
+    assert set(look["early"]) == {"speculative"}
+    assert look["early"]["speculative"] == look["calls"] > 0
+    assert look["ahead"] == 0
+    spec.close()
+
+
 def test_spec_eos_inside_window_end_to_end(tiny_engine):
     """eos emitted mid-window truncates the accepted run exactly where
     sequential generate stops (back-fill semantics included)."""
