@@ -219,6 +219,9 @@ FEATURES: Dict[str, Feature] = {
 KIND_SAYS: Dict[str, str] = {
     "state": "{model} keeps a recurrent state a slot (decode hook "
              "state_layers), which is not served with ",
+    "tails": "{model} makes its keys by causal convolutions and keeps their "
+             "tails a slot beside the paged pool (decode hook tail_layers), "
+             "which is not served with ",
     "window": "{model} mixes sliding-window and full attention layers "
               "(decode hook window_layers): its pool holds a second kind of "
               "block under a ring table of its own, which is not served "
@@ -252,6 +255,27 @@ KIND_REFUSES: Dict[str, Dict[str, Optional[str]]] = {
         "a tp mesh": "the state's heads are not sharded: one shard",
         "engine_mode": "the state's rows are not sharded: one shard",
         "sp": "the chunked recurrence carries its state along the sequence",
+    },
+    "tails": {
+        "prefix_caching": "the first token after a shared block needs the "
+                          "tails at that block's end, and no block keeps them",
+        "host_blocks": "a demoted row's blocks would come back without its "
+                       "tails",
+        "nvme_blocks": "a demoted row's blocks would come back without its "
+                       "tails",
+        "spec_tokens": "a rejected draft token has already moved the tails",
+        "a draft model": "a rejected draft token has already moved the "
+                         "tails",
+        "quantize": "the tails and the finished keys are float by "
+                    "construction",
+        "quantized weights": "the convolutions' taps and the router's "
+                             "matrices have no int8 record",
+        "resident_window_blocks": "a window slides over blocks; the tails "
+                                  "have none",
+        "a tp mesh": "the grouped convolution's groups are the heads, and "
+                     "they are not sharded: one shard",
+        "engine_mode": "the tails' rows are not sharded: one shard",
+        "sp": "the convolutions carry their tails along the sequence",
     },
     "window": dict.fromkeys((
         "prefix_caching", "host_blocks", "quantize",

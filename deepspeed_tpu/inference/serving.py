@@ -328,6 +328,32 @@ recurrence lowered to — under the hook's ``bodies`` (``"kda"``: ``kda_step``
 the refusals; ``stats()["kv_kinds"]`` names the state kind beside the paged
 one.  The engine names no family: what it knows of one is the hook.
 
+**Tails** (PR 66): row-indexed leaves beside a pool whose EVERY layer is
+paged, with no ``state`` leaf (decode hook ``tail_layers``:
+``models/zaya.py``, whose keys are made by two causal convolutions of two
+taps over the projections and whose values are half the projection of the
+token before).  The finished K and V are the ``full`` kind's, a token a
+block offset like any model's; what the WRITER of a row's next token needs
+of the token before — ``conv [L, slots, 1, taps, channels]``, ``shift [L,
+slots, 1, 1, channels]`` (``ops/paged_kv.py`` "Tails") — follows the slot
+exactly as a recurrent state does, by the same code (``_rowed``: the state
+kind's tree is this tree plus ``state``): the cache is built for ``slots``
+rows, the programs take ``block_tables = {"full": ...}`` (decode) or
+``{"full": ..., "slot": int32 [rows]}`` (prefill), a window at base 0
+starts from zero tails inside the program (the span counts it,
+``tail_resets``), a chunk carries them across its boundary, pads and idle
+rows move none, the one call of lookahead keeps them (part of the donated
+cache), ``_release_slot`` forgets them and ``_preempt``'s recompute from
+base 0 is exact.  Refused by name, each with its reason
+(``options.KIND_REFUSES["tails"]``): the prefix trie (the first token after
+a shared block needs the tails at that block's end, and no block keeps
+them), ``spec_tokens`` and a draft model, the host / NVMe tiers,
+``quantize``, ``resident_window_blocks``, tp / dp / sp meshes.
+``stats()["kv_tails"]`` has the leaves, their bytes, the taps, the resets,
+the bytes the calls moved and the refusals; ``stats()["kv_kinds"]`` names
+the kind beside ``full``; the ``decode`` / ``prefill`` spans carry
+``tail_bytes`` / ``tail_resets``.
+
 **No paged leaf at all** (PR 57): where the hook's cache tree holds the
 state kind's leaves and nothing else (``_paged`` false) there is no pool:
 none is committed (the start-up ring's ``pool`` span carries the state's
@@ -442,7 +468,7 @@ _STALL_FIELDS = ("wall",) + SEGMENTS + ("offcpu", "gc")
 EARLY_SETTLE_CAUSES = ("debug_checks", "speculative", "kv_tier", "handoff",
                        "mask_builder", "preempt", "cancel", "drain", "close")
 #: the cache leaves of the state kind: indexed by SLOT, never by block
-STATE_LEAVES = paged_kv.STATE_LEAVES
+ROW_LEAVES = paged_kv.ROW_LEAVES
 #: a decode row's entry in the call's token operand when its input token is
 #: the one the call before made and the host has not seen: the program
 #: takes the row's entry of the engine's device-resident token vector
@@ -1037,6 +1063,17 @@ class ServingEngine:
         #: None otherwise
         self._state = hooks.get("state_layers")
         self._state_totals = {"resets": 0, "state_rows": 0}
+        #: tails a row (decode hook ``tail_layers``: ``{"layers", "taps":
+        #: {leaf: tokens}}``): what the writer of
+        #: a token's key and value needs of the token BEFORE, leaves
+        #: indexed by SLOT beside a pool whose every layer is paged (module
+        #: docstring "Tails"); None otherwise
+        self._tails = hooks.get("tail_layers")
+        self._tail_totals = {"resets": 0, "tail_bytes": 0}
+        #: row-indexed leaves of either kind (``paged_kv.ROW_LEAVES``): the
+        #: cache is built for ``slots`` rows of them and a prefill call
+        #: names its rows' slots
+        self._rowed = self._state or self._tails
         #: learned sparse attention (decode hook ``sparse_attention``:
         #: ``{"topk"}``): the pool has a third leaf (the indexer's keys) and
         #: a row past ``topk`` keys attends ``topk`` of them; None otherwise
@@ -1124,7 +1161,8 @@ class ServingEngine:
         #: sequence-parallel (Ulysses) prefill over the mesh sp axis
         self.sp_degree = o.sp
         kinds = [kind for kind, hook in (
-            ("state", self._state), ("window", self._windows),
+            ("state", self._state), ("tails", self._tails),
+            ("window", self._windows),
             ("indexer", self._sparse), ("latent", self._latent)) if hook]
         prefix_caching = o.prefix_caching
         if prefix_caching is None:
@@ -1274,7 +1312,7 @@ class ServingEngine:
                     self.slots, self._windows["window"], self._prefill_width,
                     self._window_block(engine._config.jnp_dtype))
                 kinds["window_blocks"] = self._ring.alloc.num_blocks
-            if self._state:
+            if self._rowed:
                 kinds["state_rows"] = self.slots
             if self._self_draft:
                 # the module's rows: more layers of the target's own leaves
@@ -2116,7 +2154,7 @@ class ServingEngine:
             return None
         dtype = engine._config.jnp_dtype
         kinds = {"window_blocks": 2} if self._windows else \
-            {"state_rows": 1} if self._state else {}
+            {"state_rows": 1} if self._rowed else {}
         leaf = self._widest_leaf(lambda: self._init_cache(
             2, self.block_size, dtype, **kinds))
         hkv, bs, hd = map(int, leaf.shape[2:])
@@ -2156,7 +2194,7 @@ class ServingEngine:
         """``cache`` without the state kind's leaves: what has blocks."""
         if not isinstance(cache, dict):
             return cache
-        return {k: v for k, v in cache.items() if k not in STATE_LEAVES}
+        return {k: v for k, v in cache.items() if k not in ROW_LEAVES}
 
     def _donate(self):
         # donating the pool avoids a full cache copy per step; XLA:CPU
@@ -2176,8 +2214,8 @@ class ServingEngine:
             cache = mk_pool()
             if not isinstance(cache, dict):
                 return paged_kv.pack_pool(cache)
-            # (the state kind's leaves have no block to pack)
-            return {k: v if k in STATE_LEAVES else paged_kv.pack_pool(v)
+            # (a row-indexed leaf has no block to pack)
+            return {k: v if k in ROW_LEAVES else paged_kv.pack_pool(v)
                     for k, v in cache.items()}
 
         with trace_mod.setup_timeline().span("pool", **sizes) as made:
@@ -2280,7 +2318,9 @@ class ServingEngine:
         """Host: undo :meth:`_with_record` on the copied-back array; the
         step's routing goes on its in-flight span (``experts_touched`` and
         ``expert_rows`` summed over layers, ``expert_rows_max`` the largest
-        group of any layer) and into the totals, and so do the counts of a
+        group of any layer, ``expert_rows_max_sum`` the layers' largest
+        groups summed: what their grouped matmuls wait for) and into the
+        totals, and so do the counts of a
         learned sparse attention, as the DEVICE made them
         (``ops/sparse_index_attention.COUNTS``: ``index_keys`` scored,
         ``kv_selected`` attended, ``kv_valid`` a dense read attends,
@@ -2302,7 +2342,8 @@ class ServingEngine:
         rec = tail.reshape(-1, self._rec_width)
         touched, rows = int(rec[:, 0].sum()), int(rec[:, 1].sum())
         span_args.update(experts_touched=touched, expert_rows=rows,
-                         expert_rows_max=int(rec[:, 2].max()))
+                         expert_rows_max=int(rec[:, 2].max()),
+                         expert_rows_max_sum=int(rec[:, 2].sum()))
         if self._rec_width > 3:
             span_args["expert_rows_absent"] = int(rec[:, 3].sum())
             self._rows_absent += span_args["expert_rows_absent"]
@@ -2460,8 +2501,9 @@ class ServingEngine:
         spec = {name: sds(width) for name, width in head.items()}
         spec["block_tables"] = sds(self._nbper) if not self._windows else {
             "full": sds(self._nbper), "window": sds(self._ring.width)}
-        if self._state:
-            # the state kind's "table": the slot of each row of a prefill
+        if self._rowed:
+            # the row-indexed leaves' "table": the slot of each row of a
+            # prefill
             # call (a decode step's row b is slot b; where no table tells
             # an idle row, the decode step carries it too)
             spec["block_tables"] = {"full": sds(self._nbper)} \
@@ -4020,6 +4062,18 @@ class ServingEngine:
         return {"state_rows": rows, "state_resets": resets,
                 "state_tokens": tokens}
 
+    def _tail_args(self, rows: int, resets: int) -> Dict[str, int]:
+        """Span args of a dispatch of a model with tails: ``tail_bytes``,
+        the tails its ``rows`` live rows read and write back, all layers,
+        and ``tail_resets``, those of them that enter at base 0 (zero
+        tails inside the program)."""
+        if not self._tails:
+            return {}
+        nbytes = 2 * rows * self._state_bytes() // self.slots
+        self._tail_totals["tail_bytes"] += nbytes
+        self._tail_totals["resets"] += resets
+        return {"tail_bytes": nbytes, "tail_resets": resets}
+
     def _bt(self, tables, rows=None):
         """The block-table operand of a dispatch, on the host: the full
         kind's ``tables`` (already masked to the dispatch's rows) — for a
@@ -4028,7 +4082,7 @@ class ServingEngine:
         pad row; None: row i is slot i, rows whose table is all scratch
         are idle; a model with no paged leaf has no table and names its
         live rows in both programs, :meth:`_live_rows`)."""
-        if self._state:
+        if self._rowed:
             if rows is None:
                 return {"full": tables}
             slot = np.asarray([slot if slot >= 0 else self.slots
@@ -4511,7 +4565,7 @@ class ServingEngine:
                 evicted=self.preempted - preempted0,
                 blocks_in_use=self._alloc.blocks_in_use,
                 active=len(self._active), pending=len(self._pending))
-            if self._windows or self._state or self._self_draft:
+            if self._windows or self._rowed or self._self_draft:
                 self._full_peak = max(self._full_peak,
                                       self._alloc.blocks_in_use)
             if self._windows:
@@ -5186,7 +5240,8 @@ class ServingEngine:
             span_kw = {**self._sampler_rows(dec),
                        **self._kv_walk(dec),
                        **self._kv_reach(self._lengths[dec] + 1, at=dec),
-                       **self._state_args(len(dec), 0, len(dec))}
+                       **self._state_args(len(dec), 0, len(dec)),
+                       **self._tail_args(len(dec), 0)}
         with seg("step.decode.upload", phase):
             host, puts = self._host_operands(
                 "decode", tokens, self._lengths,
@@ -5667,7 +5722,9 @@ class ServingEngine:
                                  valid[:len(group)], t=width),
                 **self._state_args(
                     len(group), int((base[:len(group)] == 0).sum()),
-                    int(valid.sum()))}
+                    int(valid.sum())),
+                **self._tail_args(
+                    len(group), int((base[:len(group)] == 0).sum()))}
         if not self._prefill_warm:
             self._warm_prefill(params)
         with seg("step.prefill.upload", phase):
@@ -5877,7 +5934,7 @@ class ServingEngine:
                     "blocks_in_use": alloc.blocks_in_use,
                     "peak_blocks_in_use": peak, "table_width": table_width}
 
-        if self._self_draft and not (self._state or self._windows):
+        if self._self_draft and not (self._rowed or self._windows):
             # ONE kind of block: the model's layers and, behind them in the
             # same leaves and under the same table, its drafting module's
             draft = int(self._self_draft["layers"])
@@ -5887,17 +5944,18 @@ class ServingEngine:
                     "draft": {"layers": draft, "table": "full",
                               "depth": int(self._self_draft["depth"])},
                     "expert_rows_absent": self._rows_absent}
-        if self._state:
-            # the paged kind beside the state kind (which has no blocks)
+        if self._rowed:
+            # the paged kind beside the row-indexed one (which has no blocks)
             paged = "latent" if self._latent else "full"
+            rowed = "state" if self._state else "tails"
             return {**({paged: kind(self._alloc, int(self._pool_shape[0]),
                                     self._nbper, self._full_peak)}
                        if self._paged else {}),
-                    "state": {"layers": self._state["layers"],
-                              "slots": self.slots,
-                              "bytes": self._state_bytes()},
+                    rowed: {"layers": self._rowed["layers"],
+                            "slots": self.slots,
+                            "bytes": self._state_bytes()},
                     "expert_rows_absent": self._rows_absent,
-                    "refused": list(self._refusals["state"])}
+                    "refused": list(self._refusals[rowed])}
         layers = self._windows["layers"]
         own = {}
         if "token_width" in self._windows:
@@ -5918,9 +5976,10 @@ class ServingEngine:
             "refused": list(self._refusals["window"])}
 
     def _state_bytes(self) -> int:
-        """Bytes of the state kind's leaves, all slots."""
+        """Bytes of the row-indexed leaves (a state and its companions, or
+        tails), all slots."""
         return int(sum(self._cache[k].size * self._cache[k].dtype.itemsize
-                       for k in STATE_LEAVES if k in self._cache))
+                       for k in ROW_LEAVES if k in self._cache))
 
     def _kv_state(self) -> Dict[str, Any]:
         """``stats()["kv_state"]`` (a model with a recurrent state a row)."""
@@ -5929,11 +5988,24 @@ class ServingEngine:
                 "slots": self.slots, "bytes": nbytes,
                 "bytes_per_slot": nbytes // self.slots,
                 "leaves": {k: list(self._cache[k].shape)
-                           for k in STATE_LEAVES if k in self._cache},
+                           for k in ROW_LEAVES if k in self._cache},
                 self._state["bodies"]: dict(
                     self._program_meta.get("state_bodies", {})),
                 **self._state_totals,
                 "refused": list(self._refusals["state"])}
+
+    def _kv_tails(self) -> Dict[str, Any]:
+        """``stats()["kv_tails"]`` (a model whose K/V writer reads the
+        token before: tails a row beside the paged pool)."""
+        nbytes = self._state_bytes()
+        return {"kind": "tails", "layers": self._tails["layers"],
+                "slots": self.slots, "bytes": nbytes,
+                "bytes_per_slot": nbytes // self.slots,
+                "leaves": {k: list(self._cache[k].shape)
+                           for k in self._tails["taps"]},
+                "taps": dict(self._tails["taps"]),
+                **self._tail_totals,
+                "refused": list(self._refusals["tails"])}
 
     def _kv_latent(self) -> Dict[str, Any]:
         """``stats()["kv_latent"]`` (a model with latent attention)."""
@@ -6056,12 +6128,16 @@ class ServingEngine:
             # spans' reach counters summed, and what such a model is
             # refused; None for any other model
             "kv_kinds": self._kv_kinds()
-            if self._windows or self._state or self._self_draft else None,
+            if self._windows or self._rowed or self._self_draft else None,
             # a model with a recurrent state a row: its leaves, their bytes
             # (whatever the rows' lengths), the resets, which body each
             # program's recurrence lowered to and what such a model is
             # refused; None for any other model
             "kv_state": self._kv_state() if self._state else None,
+            # a model with tails a row beside its paged pool: the leaves,
+            # their bytes, the taps, the resets, the bytes the calls moved
+            # and what such a model is refused; None for any other model
+            "kv_tails": self._kv_tails() if self._tails else None,
             # a model with latent attention: the pool's kind, a token's
             # width and bytes, the block, what each program's read was
             # traced with, the spans' counters summed, and what such a

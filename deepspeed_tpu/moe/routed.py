@@ -26,7 +26,9 @@ chosen by the caller from what it can observe (``models/mixtral.py``):
     out = sum_{e in S} p_e * (act(y W1_e) * (y W3_e)) W2_e
 
 (``r`` is ``y`` unless the caller hands the router tokens of its own,
-``router_x``; ``act`` is ``silu`` or ``relu``.)
+``router_x``; ``act`` is ``silu`` or ``relu``.  A model whose router is not
+``r Wr`` at all — an MLP, a score that reads the layer before — hands in the
+router's OUTPUT, ``routed = (p_S, S)``, and the first line is the caller's.)
 
 **Differentiable.**  The gather into expert order, the scatter back and the
 float32 combine are plain XLA; ``moe_gmm`` carries a ``custom_vjp`` (its two
@@ -130,7 +132,7 @@ def routed_ffn(y, gate_w, w1, w3, w2, k: int, renormalize: bool,
                score: str = "softmax", act: str = "silu", router_x=None,
                balance: bool = False,
                choice_major: bool = False, bias=None,
-               scale: float = 1.0) -> Tuple[jnp.ndarray, ...]:
+               scale: float = 1.0, routed=None) -> Tuple[jnp.ndarray, ...]:
     """Gated (``act``: SwiGLU, ReGLU) experts over ``y [..., D]``: ``gate_w [D, E]``, ``w1``/``w3``
     ``[E, D, F]``, ``w2 [E, F, D]`` — or, with ``layer`` (traced index),
     the whole stacks ``[L, E, ..]``.  No capacity, no drop.  ``kernel``
@@ -149,7 +151,10 @@ def routed_ffn(y, gate_w, w1, w3, w2, k: int, renormalize: bool,
     experts.  ``score``: the router's (:func:`route`).  ``router_x``: the
     tokens the router reads, ``y``'s shape (``None``: ``y`` itself).
     ``bias`` / ``scale``: the router's selection bias and the factor on its
-    chosen weights (:func:`route`).
+    chosen weights (:func:`route`).  ``routed``: the router's own OUTPUT,
+    made by the caller — ``(weights float32 [T, k], experts int32 [T, k])``,
+    what :func:`route` returns — in place of every router option above
+    (``gate_w`` is then None and ``E`` the expert stacks'; no ``balance``).
     ``balance`` adds a last result, this layer's balance term (module
     docstring; float32 scalar, 1 under even routing).  ``choice_major``:
     the layout the combine gathers the pairs in — ``[k, T, D]``, what a
@@ -157,16 +162,24 @@ def routed_ffn(y, gate_w, w1, w3, w2, k: int, renormalize: bool,
     ``[T, k, D]``; the same float32 sum either way."""
     shape, d = y.shape, y.shape[-1]
     x = y.reshape(-1, d)
-    t, e = x.shape[0], gate_w.shape[-1]
+    t = x.shape[0]
+    e = gate_w.shape[-1] if routed is None else w1.shape[-3]
     if not 1 <= k <= e:
         raise ValueError(f"top_k={k} outside [1, num_experts={e}]")
     if act not in ACTS:
         raise ValueError(f"expert activation {act!r}: one of {sorted(ACTS)}")
 
     with jax.named_scope("layer/moe/route"):
-        rx = x if router_x is None else router_x.reshape(-1, d)
-        top_p, top_e, *all_p = route(rx, gate_w, k, renormalize, score,
-                                     scores=balance, bias=bias, scale=scale)
+        if routed is None:
+            rx = x if router_x is None else router_x.reshape(-1, d)
+            top_p, top_e, *all_p = route(rx, gate_w, k, renormalize, score,
+                                         scores=balance, bias=bias,
+                                         scale=scale)
+        else:
+            if balance:
+                raise ValueError("routed=: the caller's router has its own "
+                                 "balance term")
+            top_p, top_e = (a.reshape(t, k) for a in routed)
         flat_e = top_e.reshape(-1)                               # [T*k]
         if balance:
             share = jnp.zeros(e, jnp.float32).at[flat_e].add(1.0 / (t * k))

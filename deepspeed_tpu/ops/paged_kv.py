@@ -123,6 +123,23 @@ Layout contract — the WHOLE stacked pool, addressed in place:
    ``ops/power_retention.py``: ``state [L, rows, HKV, hd / 2 + 1, hd, hd]``,
    ``power_step`` / ``power_chunk_state``).
 
+ - **Tails.**  Row-indexed leaves may ride beside paged leaves WITHOUT a
+   ``state`` leaf (:data:`TAIL_LEAVES`).  A model whose keys are made by
+   short causal convolutions over the projections (``models/zaya.py``)
+   caches a finished key and value a token in the ``full`` kind like any
+   K/V — but the WRITER of token ``t`` needs what the convolutions read of
+   token ``t - 1``, a row and a layer: ``conv [L, rows, 1, taps, channels]``
+   (the convolutions' inputs a token back) and ``shift [L, rows, 1, 1,
+   channels]`` (the half of a value that is the projection of the token
+   before).  They are the state kind's contract with nothing recurrent in
+   it: in the cache tree, donated and carried with the pool, indexed by
+   ROW, no block ids, no table, no allocator, not lane-packed; a decode
+   step's row ``b`` is row ``b``, a prefill call names its rows' slots
+   (``block_tables["slot"]``), a window at base 0 starts from zero tails
+   inside the program, a pad or an idle row moves none.  Every layer of
+   such a model is paged AND has tails.  :data:`ROW_LEAVES` is the one
+   table of what is indexed by row, of either kind.
+
 **Layout** (what "in place" takes on a TPU).  A Mosaic kernel reads its
 operand row-major — ``[L][NB][HKV][...]``, a block's tiles contiguous —
 and tiles the last two dims (16 x 128 for bf16).  XLA:TPU's own layout for
@@ -453,6 +470,12 @@ STATE_COMPANIONS = {"kda": "conv", "ssm": "conv", "power": "z"}
 #: the cache leaves of the state kind: indexed by ROW — a serving slot —
 #: never by block
 STATE_LEAVES = ("state",) + tuple(dict.fromkeys(STATE_COMPANIONS.values()))
+#: the tails of a model whose K/V writer reads the token before (module
+#: docstring "Tails"): row-indexed leaves beside paged ones, no ``state``
+TAIL_LEAVES = ("conv", "shift")
+#: every cache leaf that is indexed by ROW and not by block: what
+#: :func:`pack_pool` is not applied to and no allocator knows
+ROW_LEAVES = tuple(dict.fromkeys(STATE_LEAVES + TAIL_LEAVES))
 
 
 def latent_pool_width(width: int) -> int:

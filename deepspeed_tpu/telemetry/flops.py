@@ -70,9 +70,17 @@ def _model_dims(model_config) -> Dict[str, int]:
                       getattr(model_config, "num_key_value_heads", heads)))
     ffn = int(getattr(model_config, "ffn_hidden_size",
                       getattr(model_config, "intermediate_size", 4 * h)))
-    return {"layers": int(model_config.num_layers), "hidden": h,
+    dims = {"layers": int(model_config.num_layers), "hidden": h,
             "heads": heads, "kv_heads": kvh, "ffn": ffn,
             "vocab": int(model_config.vocab_size)}
+    active = getattr(model_config, "active_layer_params", None)
+    if callable(active):
+        # a family that counts what ONE token multiplies with in a layer
+        # (attention at a width of its own, a router that is an MLP, one
+        # expert of many: ``models/zaya.py``) is priced by that count
+        dims.update(layer_macs=int(active()),
+                    head_dim=int(model_config.head_dim))
+    return dims
 
 
 def analytic_components(family: str, dims: Dict[str, int], *,
@@ -92,7 +100,9 @@ def analytic_components(family: str, dims: Dict[str, int], *,
     final-position logits) and at every window position for the
     ``all_positions`` verify head and the draft rollout (one head per
     scan step).  LayerNorms/softmax/residuals are O(h)/O(ctx) per token
-    — noise next to the matmuls — and excluded.
+    — noise next to the matmuls — and excluded.  A family whose layer is
+    not that block hands in its own count (``dims["layer_macs"]``: what one
+    token multiplies with in a layer) and its heads' width.
     """
     L, h = dims["layers"], dims["hidden"]
     hd = h // dims["heads"]
@@ -101,6 +111,11 @@ def analytic_components(family: str, dims: Dict[str, int], *,
                  + 2 * h * h                  # attention out projection
                  + 4 * h * dims["ffn"]        # mlp up + down
                  + 4 * h * ctx)               # scores + weighted sum
+    if "layer_macs" in dims:
+        # the family's own count of a token's multiplies in a layer, and
+        # its attention at its own heads' width (``_model_dims``)
+        per_layer = 2 * dims["layer_macs"] \
+            + 4 * dims["heads"] * dims["head_dim"] * ctx
     tokens = rows * width
     head_positions = tokens if family in ("verify", "draft") else rows
     return {"head": float(head_positions * 2 * h * dims["vocab"]),

@@ -27,12 +27,14 @@ FAMILIES = sorted(set(MODULES) - {"cached"})
 #: ``mixtral``'s (PR 61); ``GlmDsaConfig(Dots3Config)``: GLM-5's every layer
 #: is ``dots3``'s full kind (its block, its cache, its forwards by their
 #: public names) and its module one more such block, with ``llama``'s norm
-#: and latent hook (PR 64)
+#: and latent hook (PR 64); ``zaya`` takes ``llama``'s ``rms_norm`` and
+#: ``apply_rope`` (PR 66)
 ALLOWED = {("mixtral", "llama"), ("megatron_gpt", "gpt2"), ("unet", "vae"),
            ("kimi_linear", "mixtral"), ("kimi_linear", "llama"),
            ("granite_hybrid", "llama"), ("brumby", "llama"),
            ("dots3", "mixtral"), ("dots3", "llama"),
-           ("glm_dsa", "dots3"), ("glm_dsa", "llama")}
+           ("glm_dsa", "dots3"), ("glm_dsa", "llama"),
+           ("zaya", "llama")}
 
 
 def _sibling_imports(tree):

@@ -73,6 +73,9 @@ ENGINE_STATS_KEYS = frozenset({
     # the resets, which body each program's delta rule lowered to, the
     # refusals (None otherwise)
     "kv_state",
+    # PR 66: a model with tails a slot beside its paged pool: the leaves,
+    # their bytes, the taps, the resets, the refusals (None otherwise)
+    "kv_tails",
     # PR 53: the process's start-up ring in numbers
     # (telemetry/trace.py setup_summary)
     "setup",
@@ -279,7 +282,8 @@ def test_two_latent_kinds_stats_and_span_keys_pinned():
         assert spans and all(
             (walk | reach | set(sparse_index_attention.COUNTS)) <= set(a)
             for a in spans), name
-    assert st["decode_attn"] is None and st["kv_state"] is None
+    assert st["decode_attn"] is None and st["kv_state"] is None \
+        and st["kv_tails"] is None
     srv.close()
 
 

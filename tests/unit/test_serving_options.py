@@ -90,6 +90,7 @@ MODELS = {
     "state": (lambda: _family(
         "granite_hybrid", "granite-4.0-h-micro.json"), dict(block_size=16)),
     "state only": (lambda: _family("brumby", "Brumby-14B-Base.json"), {}),
+    "tails": (lambda: _family("zaya", "ZAYA1-8B.json"), dict(block_size=16)),
 }
 BASE = dict(slots=2, max_seq_len=64, prefill_chunk=16)
 
@@ -139,7 +140,8 @@ ASK = {
 }
 #: the kind a model of :data:`MODELS` shows each of ``KIND_REFUSES``' kinds by,
 #: and where ``stats()`` publishes what it refuses
-SHOWN_BY = {"state": ("state", "kv_state"), "window": ("window", "kv_kinds"),
+SHOWN_BY = {"state": ("state", "kv_state"), "tails": ("tails", "kv_tails"),
+            "window": ("window", "kv_kinds"),
             "indexer": ("indexer", None), "latent": ("latent", "kv_latent")}
 ROWS = [(kind, name) for kind, refuses in options.KIND_REFUSES.items()
         for name in refuses]
