@@ -102,6 +102,7 @@ class Sizes:
     gas: int = 2
     sync_dim: int = 4096
     sync_iters: int = 100
+    sampler_vocab: int = 262272    # the widest cell's row (ZAYA1-8B)
     serving_kwargs: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
 
@@ -400,6 +401,29 @@ def phase_kernels(sz: Sizes, report: Dict[str, Any]) -> None:
     for k_dim, n_dim in ((d, 3 * d), (d, d), (d, f), (f, d)):
         checks.append((f"w8a8 {k_dim}x{n_dim}",
                        lambda k=k_dim, n=n_dim: w8a8(k, n)))
+
+    def nucleus():
+        """The sampler's tiled nucleus search (``ops/sampling.py``) at the
+        widest cell's row, 12 rows (no multiple of a tile), against the
+        plain loop: where the two differ the upper set's float64 mass is
+        within 1e-6 of ``top_p`` (the reduction's order decides)."""
+        from deepspeed_tpu.ops import sampling
+
+        rows, vocab = 12, sz.sampler_vocab
+        probs = jax.nn.softmax(jax.random.normal(
+            keys[3], (rows, vocab), jnp.float32) * 0.7, axis=-1)
+        p = jnp.linspace(0.3, 0.95, rows, dtype=jnp.float32)[:, None]
+        tiled = jax.jit(sampling._nucleus_threshold_tiled)
+        _mosaic(tiled.lower(probs, p).as_text(), "nucleus_search", require)
+        plain = jax.jit(sampling._nucleus_threshold)
+        got, want = (np.asarray(f(probs, p))[:, 0] for f in (tiled, plain))
+        pr = np.asarray(probs, np.float64)
+        for r in np.flatnonzero(got != want):
+            upper = pr[r][pr[r] >= max(got[r], want[r])].sum()
+            assert abs(upper - float(p[r, 0])) < 1e-6, (r, upper)
+        return {"nucleus_search_rows_equal": float(np.mean(got == want))}
+
+    checks.append(("nucleus search", nucleus))
 
     # every kernel is tried even after one fails: a Mosaic refusal is the
     # thing this phase exists to find, and each costs a chip call to see

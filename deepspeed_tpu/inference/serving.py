@@ -2384,12 +2384,15 @@ class ServingEngine:
             self._program_meta.setdefault("state_bodies", {})[program] = \
                 "+".join(sorted(p for p in paths if self._state_body(p)))
 
-    def _note_sampler(self, program: str, samp) -> None:
-        """Trace time: how ``program`` picks its tokens
+    def _note_sampler(self, program: str, samp, logits) -> None:
+        """Trace time: how ``program`` picks its tokens from ``logits``
         (``stats()["sampler"]``) — ``"argmax"`` for a greedy-only engine,
-        else how ``ops/sampling.py`` finds the filter's thresholds."""
+        else how ``ops/sampling.py`` finds the filter's thresholds at that
+        vocabulary width (``"bitwise_search"``, or
+        ``"bitwise_search_tiled"`` from ``sampling.TILED_FROM`` entries)."""
         self._program_meta.setdefault("sampler", {})[program] = \
-            "argmax" if samp is None else sampling_ops.THRESHOLDS
+            "argmax" if samp is None \
+            else sampling_ops.thresholds(logits.shape[-1])
 
     def _sampler_rows(self, slots) -> Dict[str, int]:
         """Span counters of a dispatch over ``slots``: ``sampled_rows``
@@ -2585,7 +2588,7 @@ class ServingEngine:
                                              block_tables=block_tables)
                 self._note_latent("decode", paths)
                 self._note_sparse("decode")
-                self._note_sampler("decode", samp)
+                self._note_sampler("decode", samp, logits)
                 return with_record(next_tokens(logits, samp), rec), \
                     constrain(cache)
 
@@ -2687,7 +2690,7 @@ class ServingEngine:
             self._note_latent("prefill", paths)
             self._note_sparse("prefill")
             samp_t = pack(samp)
-            self._note_sampler("prefill", samp_t)
+            self._note_sampler("prefill", samp_t, logits)
             return with_record(next_tokens(logits, samp_t), rec), \
                 constrain(cache)
 
@@ -2743,7 +2746,7 @@ class ServingEngine:
                 self._note_latent("prefill", paths)
                 self._note_sparse("prefill")
                 samp_t = pack(samp)
-                self._note_sampler("prefill", samp_t)
+                self._note_sampler("prefill", samp_t, logits)
                 first = next_tokens(logits, samp_t)
                 col = jnp.arange(ids.shape[1], dtype=jnp.int32)[None, :]
                 after = jnp.where(
@@ -2892,7 +2895,7 @@ class ServingEngine:
                 self._note_latent("verify", paths)
                 self._note_sparse("verify")
                 samp_t = pack(samp)
-                self._note_sampler("verify", samp_t)
+                self._note_sampler("verify", samp_t, logits)
                 if samp_t is None:
                     return self._with_record(
                         jnp.argmax(logits, -1).astype(jnp.int32), rec), cache
@@ -3039,7 +3042,7 @@ class ServingEngine:
                 self._note_latent("verify", paths)
                 self._note_sparse("verify")
                 samp_t = pack(samp)
-                self._note_sampler("verify", samp_t)
+                self._note_sampler("verify", samp_t, logits)
                 with jax.named_scope("verdict"):
                     if samp_t is None:
                         plain = jnp.argmax(logits, -1).astype(jnp.int32)
@@ -3129,13 +3132,13 @@ class ServingEngine:
                         *samp):
                 dp = dprepare(dparams)
                 samp_t = pack(samp)
-                self._note_sampler("draft", samp_t)
 
                 def rollout_step(carry, i):
                     tok, lens, cache = carry
                     logits, cache = dfwd(dp, tok[:, None], cache, 0,
                                          lengths=lens,
                                          block_tables=block_tables)
+                    self._note_sampler("draft", samp_t, logits)
                     if samp_t is None:
                         nxt = jnp.argmax(logits, axis=-1) \
                             .astype(jnp.int32)

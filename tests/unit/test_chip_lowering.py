@@ -269,11 +269,13 @@ def smoke():
 
 
 def _trace_as_on_tpu(patch):
+    from deepspeed_tpu.ops import sampling
     from deepspeed_tpu.utils import platform
 
     for mod in (platform, da):
         patch.setattr(mod, "on_tpu", lambda: True)
         patch.setattr(mod, "interpret_kernels", lambda: False)
+    patch.setattr(sampling, "interpret_kernels", lambda: False)
 
 
 @pytest.fixture
@@ -684,15 +686,19 @@ SAMPLER_SHAPES = [(24, 50272), (64, 50304), (16, 151936), (4, 151936),
 
 @pytest.mark.parametrize("rows,vocab", SAMPLER_SHAPES)
 def test_sampler_compiles_without_a_sort_at_the_cells_shapes(rows, vocab,
-                                                             one_chip):
+                                                             one_chip,
+                                                             monkeypatch):
     """ISSUE 33: ``filtered_logprobs`` compiled for a described v5e at the
     cells' shapes (a vocabulary that is no lane multiple among them) holds
     no ``sort`` — the thresholds come from the two searches' loops — and,
     beside the log-probs it returns, temporaries under ONE ``[rows, vocab]``
     float32 array: the searches form their keys inside each pass and hold
     nothing of that size (the parent's two sorts each held the sorted row
-    set and its permutation)."""
+    set and its permutation).  ISSUE 67: from ``TILED_FROM`` entries a row
+    the two searches are the ``kth_search`` / ``nucleus_search`` kernels."""
     from deepspeed_tpu.ops import sampling
+
+    monkeypatch.setattr(sampling, "interpret_kernels", lambda: False)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -701,11 +707,86 @@ def test_sampler_compiles_without_a_sort_at_the_cells_shapes(rows, vocab,
         sds((rows, vocab), jnp.bfloat16), sds((rows,), jnp.float32),
         sds((rows,), jnp.int32), sds((rows,), jnp.float32))
     text = lowered.as_text()
-    assert "stablehlo.sort" not in text and "stablehlo.while" in text
+    assert "stablehlo.sort" not in text
     assert text.count("stablehlo.case") + text.count("stablehlo.if") == 2
+    tiled = sampling.thresholds(vocab) == "bitwise_search_tiled"
+    assert tiled == (vocab > 100000)
+    for kernel in ("nucleus_search", "kth_search"):
+        assert (kernel in text) == tiled
+    assert ("stablehlo.while" in text) != tiled    # the plain loops
     compiled = lowered.compile()
     assert " sort(" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < rows * vocab * 4
+
+
+@pytest.mark.parametrize("rows,vocab", [(128, 262272), (64, 100352),
+                                        (4, 151936)])
+def test_nucleus_search_lowers_at_the_wide_cells_shapes(rows, vocab):
+    """ISSUE 67: the tiled nucleus search cross-lowered for platform ``tpu``
+    at ZAYA1's and Granite's decode and at Keye's ``[4, vocab]`` prefill
+    emit (4 rows pad to a tile of 16): one Mosaic kernel whose block is
+    the ``[16, vocab]`` float32 row tile."""
+    from deepspeed_tpu.ops import sampling
+
+    text = _lower_tpu(
+        lambda probs, p: sampling._nucleus_threshold_tiled(
+            probs, p, interpret=False),
+        _sds((rows, vocab), jnp.float32), _sds((rows, 1), jnp.float32))
+    assert text.count("tpu_custom_call") == 1 and "nucleus_search" in text
+
+
+def test_tiled_searches_lower_inside_a_program_over_two_chips():
+    """A tensor-parallel engine's program spans its chips and Mosaic
+    partitions no kernel: under the serving engine's ``tp_context`` both
+    searches sit in a ``shard_map`` (every chip searches every row) and
+    ``filtered_logprobs`` lowers for platform ``tpu`` as it does bare on
+    one chip; on the CPU the interpreted kernel there gives the bare
+    kernel's thresholds."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from deepspeed_tpu.ops import paged_kv, sampling
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
+    rep = NamedSharding(mesh, P())
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    args = (sds((16, 100352), jnp.bfloat16), sds((16,), jnp.float32),
+            sds((16,), jnp.int32), sds((16,), jnp.float32))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(sampling, "interpret_kernels", lambda: False)
+        with pytest.raises(NotImplementedError, match="partitioned"):
+            _lower_tpu(lambda *a: sampling.filtered_logprobs(*a), *args)
+        with paged_kv.tp_context(mesh):     # (a trace of its own)
+            text = _lower_tpu(lambda *a: sampling.filtered_logprobs(*a),
+                              *args)
+    assert text.count("tpu_custom_call") == 2
+    rng = np.random.default_rng(2)
+    probs = jax.nn.softmax(jnp.asarray(
+        rng.normal(size=(5, 300)).astype(np.float32)), axis=-1)
+    p = jnp.full((5, 1), 0.8, jnp.float32)
+    with paged_kv.tp_context(mesh):
+        got = jax.jit(sampling._nucleus_threshold_tiled)(
+            jax.device_put(probs, rep), jax.device_put(p, rep))
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(sampling._nucleus_threshold_tiled(
+            probs, p)))
+
+
+def test_nucleus_search_compiles_within_its_vmem_for_a_v5e(one_chip):
+    """Mosaic's own compile of the kernel for a described v5e at ZAYA1's
+    decode shape: two buffers of the 16.8 MB tile inside the limit the call
+    raises (``sampling._TILE_VMEM``)."""
+    from deepspeed_tpu.ops import sampling
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    compiled = jax.jit(lambda probs, p: sampling._nucleus_threshold_tiled(
+        probs, p, interpret=False)).lower(
+            sds((128, 262272)), sds((128, 1))).compile()
+    assert "nucleus_search" in compiled.as_text()
+    assert 2 * sampling._TILE_ROWS * 262272 * 4 < sampling._TILE_VMEM
 
 
 @pytest.mark.parametrize("spec_tokens,programs",

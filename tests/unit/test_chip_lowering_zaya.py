@@ -45,7 +45,8 @@ def test_compiled_programs_fit_and_alias_the_pool_and_the_tails(
     for mod in (platform, da):
         monkeypatch.setattr(mod, "on_tpu", lambda: True)
         monkeypatch.setattr(mod, "interpret_kernels", lambda: False)
-    monkeypatch.setattr(grouped_matmul, "interpret_kernels", lambda: False)
+    for mod in (grouped_matmul, sampling):
+        monkeypatch.setattr(mod, "interpret_kernels", lambda: False)
     with open(os.path.join(ROOT, "chipbench", "configs",
                            "ZAYA1-8B.json")) as f:
         config = json.load(f)
@@ -115,7 +116,7 @@ def test_compiled_programs_fit_and_alias_the_pool_and_the_tails(
     for name, (fn, args, attention) in programs.items():
         compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
         text = compiled.as_text()
-        for kernel in ("moe_gmm", attention):
+        for kernel in ("moe_gmm", attention, "nucleus_search"):
             assert kernel in text, (name, kernel)
         mem = compiled.memory_analysis()
         print(name, "temporaries", mem.temp_size_in_bytes / 1e6, "MB")

@@ -273,6 +273,39 @@ def test_sampler_rows_ride_the_spans_and_stats_name_the_sampler(
             <= e["args"].get("rows", e["args"]["slots"])
 
 
+def test_stats_name_the_tiled_search_for_a_wide_vocabulary(tiny):
+    """ISSUE 67: ``stats()["sampler"]`` says where the nucleus search's
+    passes read from, by the engine's vocabulary width alone
+    (``ops/sampling.py thresholds``): a toy engine of 65,664 entries a row
+    takes the tiled kernel in ``prefill`` as in ``decode``, the tiny one of
+    512 the plain loop, and a greedy-only engine is ``"argmax"`` at any
+    width."""
+    from deepspeed_tpu.ops import sampling
+
+    wide = gpt2.GPT2Config.tiny(vocab_size=sampling.TILED_FROM + 128)
+    engine = deepspeed_tpu.init_inference(gpt2.build(wide),
+                                          config={"dtype": "fp32"})
+    rng = np.random.default_rng(3)
+
+    def serve(engine, cfg, **kw):
+        srv = ServingEngine(engine, **SERVE_KW, **kw)
+        out = srv.serve([
+            Request(uid=i, prompt=rng.integers(0, cfg.vocab_size, 9,
+                                               dtype=np.int32),
+                    max_new_tokens=3, temperature=t, top_p=0.9, seed=5 + i)
+            for i, t in enumerate((0.7, 0.0) if kw == {} else (0.0, 0.0))])
+        assert all(len(v) == 9 + 3 for v in out.values())
+        return srv.stats()["sampler"]
+
+    assert sampling.thresholds(wide.vocab_size) == "bitwise_search_tiled"
+    assert serve(engine, wide) == dict.fromkeys(("prefill", "decode"),
+                                                "bitwise_search_tiled")
+    assert serve(engine, wide, sampling=False) == dict.fromkeys(
+        ("prefill", "decode"), "argmax")
+    assert serve(*tiny) == dict.fromkeys(("prefill", "decode"),
+                                         "bitwise_search")
+
+
 def test_kv_seconds_and_step_numbers(served):
     _, events, _ = served
     for s in _named(events, "step"):

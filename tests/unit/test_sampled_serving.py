@@ -255,6 +255,37 @@ def test_preemption_replays_sampled_streams_token_exact(tiny_engine):
                                       err_msg=f"uid {r.uid}")
 
 
+def test_preemption_replays_token_exact_through_the_tiled_search(
+        tiny_engine, monkeypatch):
+    """ISSUE 67: with the nucleus search's row tile resident (the line
+    ``TILED_FROM`` put under this engine's width, so every program of both
+    engines takes the kernel) a preempted request's replay through
+    ``prefill``'s ``[2, vocab]`` emit draws what ``decode_step`` drew among
+    its 4 or 3 rows: a row's threshold is its own wherever it sits."""
+    from deepspeed_tpu.ops import sampling
+
+    engine, cfg = tiny_engine
+    monkeypatch.setattr(sampling, "TILED_FROM", cfg.vocab_size)
+    rng = np.random.default_rng(67)
+    reqs = [Request(uid=i, prompt=rng.integers(0, cfg.vocab_size, 17),
+                    max_new_tokens=28, temperature=0.7, top_p=0.9,
+                    seed=int(rng.integers(1, 2 ** 31 - 1)))
+            for i in range(5)]
+    roomy = ServingEngine(engine, **_KW)
+    want = roomy.serve(reqs)
+    tight = ServingEngine(engine, slots=3, max_seq_len=64, block_size=8,
+                          prefill_chunk=32, prefill_batch=2, num_blocks=12,
+                          debug_checks=True)
+    got = tight.serve(reqs)
+    assert tight.preempted > 0, tight.stats()
+    for srv in (roomy, tight):
+        assert srv.stats()["sampler"] == dict.fromkeys(
+            ("prefill", "decode"), "bitwise_search_tiled")
+    for r in reqs:
+        np.testing.assert_array_equal(got[r.uid], want[r.uid],
+                                      err_msg=f"uid {r.uid}")
+
+
 def test_preemption_replays_sampled_spec_token_exact(tiny_engine):
     engine, cfg = tiny_engine
     rng = np.random.default_rng(10)

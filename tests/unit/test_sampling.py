@@ -15,6 +15,7 @@ marginal EXACTLY ``p_target`` for ANY proposer, no draft probabilities
 needed.
 """
 
+import hashlib
 import zlib
 
 import jax
@@ -219,13 +220,21 @@ SEARCH_CASES = {
 }
 
 
+#: where ``TILED_FROM`` is put to send every width of a test down one path
+PATHS = {"plain": 1 << 62, "tiled": 1}
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
 @pytest.mark.parametrize("case", sorted(SEARCH_CASES))
-def test_searched_thresholds_keep_the_sorted_references_set(case):
+def test_searched_thresholds_keep_the_sorted_references_set(case, path,
+                                                            monkeypatch):
     """ISSUE 33: the bitwise searches keep exactly the set the sorted
     formulation keeps, and give its log-probs.  A row whose boundary mass
     lies within ``BAND`` of ``top_p`` (named in ``at_boundary``) is held
     to the band instead: its set lies between the reference's sets at
-    ``top_p -/+ 2 BAND``."""
+    ``top_p -/+ 2 BAND``.  ISSUE 67: so with the nucleus search's row tile
+    resident (``tiled``: the kernel interpreted) as with the plain loop."""
+    monkeypatch.setattr(S, "TILED_FROM", PATHS[path])
     logits, temps, top_k, top_p, masks = SEARCH_CASES[case](
         np.random.default_rng(zlib.crc32(case.encode())))
     temps = np.asarray(temps, np.float32)
@@ -277,6 +286,138 @@ def test_searches_give_the_sorted_values_bit_for_bit():
     thr = S._nucleus_threshold(jnp.asarray(pr * 0.5),
                                jnp.full((4, 1), 0.9, jnp.float32))
     assert (_np(thr) == 0.0).all()
+
+
+# ------------------------------------------------- the tile kept resident
+def _case_probs(case):
+    """The probabilities and nucleus masses ``filtered_logprobs`` hands its
+    nucleus search for a ``SEARCH_CASES`` entry (top-k left out)."""
+    logits, temps, _, top_p, masks = SEARCH_CASES[case](
+        np.random.default_rng(zlib.crc32(case.encode())))
+    logits = jnp.asarray(logits, jnp.float32)
+    if masks is not None:
+        ok = jnp.any(masks, axis=-1, keepdims=True)
+        logits = jnp.where(jnp.where(ok, masks, True), logits, -jnp.inf)
+    t = jnp.maximum(jnp.asarray(temps, jnp.float32)[:, None], 1e-6)
+    return jax.nn.softmax(logits / t, axis=-1), \
+        jnp.asarray(top_p, jnp.float32)[:, None]
+
+
+@pytest.mark.parametrize("case", sorted(
+    c for c in SEARCH_CASES if not c.startswith("top_k")))
+def test_tiled_search_finds_the_plain_loops_threshold(case):
+    """ISSUE 67: the kernel whose passes read a resident row tile gives the
+    plain loop's threshold on every regime — rows no multiple of a tile,
+    widths no multiple of a lane, ties at the nucleus, all-equal rows,
+    greedy / sampled / ``top_p == 1`` rows in one batch, masks.  Where the
+    two differ, the larger threshold's upper set has a mass within ``BAND``
+    of ``top_p``: one order of summation reached it and the other did not
+    (one row of ``flat-vocab151936``, 152 k terms of 6e-6)."""
+    probs, p = _case_probs(case)
+    want = _np(S._nucleus_threshold(probs, p))[:, 0]
+    got = _np(S._nucleus_threshold_tiled(probs, p))[:, 0]
+    differ = np.flatnonzero(got != want)
+    assert len(differ) < len(want), differ
+    for r in differ:
+        row = _np(probs)[r].astype(np.float64)
+        upper = row[row >= max(got[r], want[r])].sum()
+        assert abs(upper - float(p[r, 0])) < BAND, (r, upper)
+    assert len(differ) == (1 if case == "flat-vocab151936" else 0)
+
+
+@pytest.mark.parametrize("tile,unroll", [(8, 8), (16, 8), (8, 3)])
+def test_a_rows_threshold_is_its_own_wherever_it_sits(tile, unroll):
+    """A row's threshold is the same alone (padded to a tile), among other
+    rows at any place of any tile, and beside rows of zeros or a one-hot:
+    the replay of a preempted request through ``prefill``'s ``[4, vocab]``
+    emit meets what ``decode_step`` found among 128 rows."""
+    rng = np.random.default_rng(67)
+    vocab = 1000
+    row = rng.dirichlet(np.full(vocab, 0.3)).astype(np.float32)
+    p = np.float32(0.9)
+    search = lambda probs, ps: _np(S._nucleus_threshold_tiled(
+        jnp.asarray(probs), jnp.asarray(ps), tile=tile, unroll=unroll))
+    alone = search(row[None], np.full((1, 1), p))[0, 0]
+    assert alone in row
+    others = rng.dirichlet(np.ones(vocab), size=2 * tile + 3) \
+        .astype(np.float32)
+    others[1] = 0.0
+    others[2] = np.eye(vocab, dtype=np.float32)[5]
+    ps = rng.uniform(0.1, 1.0, size=(len(others), 1)).astype(np.float32)
+    for at in (0, 3, tile - 1, tile, 2 * tile + 2):
+        batch, goal = others.copy(), ps.copy()
+        batch[at], goal[at] = row, p
+        got = search(batch, goal)
+        assert got[at, 0] == alone, at
+        keep = np.arange(len(others)) != at
+        np.testing.assert_array_equal(got[keep], search(others, ps)[keep])
+
+
+@pytest.mark.parametrize("search", ["_nucleus_threshold",
+                                    "_nucleus_threshold_tiled"])
+def test_nucleus_search_gives_an_entry_of_the_row(search):
+    """``test_searches_give_the_sorted_values_bit_for_bit``'s nucleus half
+    for either place the passes read from."""
+    search = getattr(S, search)
+    rng = np.random.default_rng(5)
+    pr = rng.dirichlet(np.ones(50), size=4).astype(np.float32)
+    for p in (0.05, 0.5, 0.97):
+        thr = _np(search(jnp.asarray(pr),
+                         jnp.full((4, 1), p, jnp.float32)))[:, 0]
+        for r in range(4):
+            assert thr[r] in pr[r]
+            assert pr[r][pr[r] >= thr[r]].sum(dtype=np.float64) >= p - 1e-6
+            assert pr[r][pr[r] > thr[r]].sum(dtype=np.float64) < p + 1e-6
+    thr = search(jnp.asarray(pr * 0.5), jnp.full((4, 1), 0.9, jnp.float32))
+    assert (_np(thr) == 0.0).all()
+
+
+def test_tiled_top_k_search_gives_the_sorted_value_bit_for_bit():
+    """``test_searches_give_the_sorted_values_bit_for_bit``'s top-k half
+    through the same tile loop: ``-inf``, ``-0.0``, ties and negative
+    values, every k, a per-row k, a width of 50 padded to a lane."""
+    rng = np.random.default_rng(5)
+    x = rng.normal(size=(4, 50)).astype(np.float32)
+    x[0, :7], x[1, 3], x[2, 4:9], x[3, 11] = -np.inf, -0.0, 0.5, 0.0
+    x[3, 12] = -0.0
+    srt = np.sort(x, axis=-1)[:, ::-1]
+    for k in (1, 2, 7, 43, 44, 50):
+        got = _np(S._kth_largest_tiled(
+            jnp.asarray(x), jnp.full((4, 1), k, jnp.int32)))[:, 0]
+        np.testing.assert_array_equal(got, srt[:, k - 1])
+    ks = np.asarray([[1], [50], [9], [44]], np.int32)
+    np.testing.assert_array_equal(
+        _np(S._kth_largest_tiled(jnp.asarray(x), jnp.asarray(ks))),
+        np.take_along_axis(srt, ks - 1, axis=-1))
+
+
+def test_the_width_alone_says_where_the_passes_read():
+    assert S.thresholds(50304) == "bitwise_search"
+    assert S.thresholds(100352) == S.thresholds(262272) \
+        == "bitwise_search_tiled"
+    assert 50304 < S.TILED_FROM <= 100352
+
+
+#: sha256 of ``filtered_logprobs``' lowered text at the chat cell's and
+#: OLMoE's decode shapes, taken on the parent commit (9d1f9c7): under
+#: ``TILED_FROM`` entries a row the sampler is the parent's program
+PARENT_FILTERED_LOGPROBS = {
+    (24, 50272):
+    "d04211d1cda5cc01911c8592228787e28b443e0516fa261a0b02c4a902e0e2d3",
+    (64, 50304):
+    "356a87ceea9f879edbe1f19d39a176535169c1bd5f997b2d0057b0956fa11d31"}
+
+
+@pytest.mark.parametrize("rows,vocab", sorted(PARENT_FILTERED_LOGPROBS))
+def test_a_narrow_vocabularys_sampler_is_the_parents_program(rows, vocab):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    text = jax.jit(S.filtered_logprobs).lower(
+        sds((rows, vocab), jnp.bfloat16), sds((rows,), jnp.float32),
+        sds((rows,), jnp.int32), sds((rows,), jnp.float32)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == PARENT_FILTERED_LOGPROBS[rows, vocab]
 
 
 # -------------------------------------------------------- key schedule

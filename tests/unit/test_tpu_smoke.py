@@ -48,6 +48,7 @@ def _tiny(smoke):
         opt=o, gpt2=g, moe=moe, dtype="bf16", prompt_lens=(3, 20, 40),
         shared_prefix=16, new_tokens=(4, 6), score_len=40, score_decode=8,
         micro_bs=2, seq=32, gas=2, sync_dim=128, sync_iters=4,
+        sampler_vocab=1000,
         serving_kwargs={"block_size": 8, "prefill_chunk": 16})
 
 
