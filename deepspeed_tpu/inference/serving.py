@@ -412,6 +412,7 @@ from ..parallel.topology import DP_AXIS, SP_AXIS, TP_AXIS
 from ..telemetry import MetricsRegistry, ProfilerWindow, TraceTimeline
 from ..telemetry.metrics import process_registry
 from ..telemetry import trace as trace_mod
+from ..telemetry.programs import Programs
 from ..telemetry.slo import SLOTracker
 from ..utils.logging import log_dist, logger
 from ..utils.platform import on_tpu
@@ -1822,6 +1823,10 @@ class ServingEngine:
         self.timeline = TraceTimeline(capacity=o.trace_capacity)
         # readable after this engine is gone (telemetry/trace.py kept())
         trace_mod.keep("serve", self.timeline)
+        #: what finds this engine's compiled programs again
+        #: (telemetry/programs.py): their scope tables, on demand
+        self.programs = Programs()
+        trace_mod.keep("programs", self.programs)
         #: the argument dict of the ``step`` span being recorded: what
         #: accrues per step (``kv_s``, ``flight_s``, ``flight_cpu_s``)
         #: lands here; outside a step (and with the ring off), on a dict
@@ -2094,7 +2099,21 @@ class ServingEngine:
                            else "verify"):
                 log_dist(trace_mod.setup_line(), ranks=[0])
 
-        return trace_mod.FirstCall(fn, program, built, **sizes)
+        return trace_mod.FirstCall(fn, program, built,
+                                   programs=self.programs, **sizes)
+
+    def program_table(self, name: str) -> Dict[str, Any]:
+        """The scope table of the compiled program ``name`` (``decode``,
+        ``prefill[4x128]``, ``verify``, ``draft``: a key of
+        ``self.programs.records``, there from the program's first call) —
+        per instruction of its schedule and per scope and pass: bytes,
+        matmul flops, kernels, trips (``telemetry/hlo_text.py
+        scope_table``).  Built at the first demand from the executable that
+        is running: no trace, no compile (``backend_compiles`` 0)."""
+        ctx = self._decode_ctx if name == "decode" else \
+            self._prefill_ctx if name.startswith("prefill") else self._tp_ctx
+        with ctx():
+            return self.programs.table(name)
 
     def note_flow(self, uid, flow_id: int) -> None:
         """Register a Chrome flow id for a routed request: admission will
@@ -2415,7 +2434,8 @@ class ServingEngine:
         temp=0 rows stay bit-identical to the legacy greedy path."""
         with jax.named_scope("sample"):
             if samp is None:
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                with jax.named_scope("sample/argmax"):
+                    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
             temps, topks, topps, seeds, counts, masks = samp
             greedy, lp = sampling_ops.filtered_logprobs(
                 logits, temps, topks, topps, masks)

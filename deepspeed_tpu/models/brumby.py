@@ -123,6 +123,7 @@ def init_params(cfg: BrumbyConfig, rng) -> PyTree:
 
 
 # ------------------------------------------------------------------- the mixer
+@jax.named_scope("layer/attn/qkv")
 def _mixer_inputs(cfg: BrumbyConfig, layer, y, rope, live):
     """What the retention takes, from the normed input ``y [B, T, d]``: ``(q
     [B, T, H, hd], k, v [B, T, HKV, hd], lg [B, T, HKV] float32)`` — q and k
@@ -140,13 +141,15 @@ def _mixer_inputs(cfg: BrumbyConfig, layer, y, rope, live):
     heads = lambda a: a.reshape(bsz, t, -1, hd)
     rotated = lambda a: rope(heads(a).transpose(0, 2, 1, 3)) \
         .transpose(0, 2, 1, 3)
-    lg = jax.nn.log_sigmoid(gate.astype(jnp.float32)
-                            + layer["gate_b"].astype(jnp.float32))
+    with jax.named_scope("layer/state/gate"):
+        lg = jax.nn.log_sigmoid(gate.astype(jnp.float32)
+                                + layer["gate_b"].astype(jnp.float32))
     return (rotated(q),
             jnp.where(live[..., None, None], rotated(k), 0), heads(v),
             jnp.where(live[..., None], lg, 0.0))
 
 
+@jax.named_scope("layer/attn/out")
 def _merge(cfg: BrumbyConfig, layer, o, dtype):
     bsz, t = o.shape[:2]
     return qmm(o.reshape(bsz, t, cfg.num_heads * cfg.head_dim).astype(dtype),
@@ -168,28 +171,33 @@ def _mixer_cached(cfg: BrumbyConfig, layer, y, state, z, index, slot, pos,
     # a prefill window: the rows' leaves by ``slot`` (a pad row's is out of
     # range: read clamped, written nowhere); a window at base 0 starts from
     # nothing
-    rows = jnp.clip(slot, 0, state.shape[1] - 1)
-    fresh = (jnp.asarray(pos, jnp.int32) == 0).reshape(-1)
-    s0 = jnp.where(fresh[:, None, None, None, None], 0.0, state[index, rows])
-    z0 = jnp.where(fresh[:, None, None, None], 0.0, z[index, rows])
+    with jax.named_scope("layer/attn/kv_write"):
+        rows = jnp.clip(slot, 0, state.shape[1] - 1)
+        fresh = (jnp.asarray(pos, jnp.int32) == 0).reshape(-1)
+        s0 = jnp.where(fresh[:, None, None, None, None], 0.0,
+                       state[index, rows])
+        z0 = jnp.where(fresh[:, None, None, None], 0.0, z[index, rows])
     o, s1, z1 = pr.chunked(q, k, v, lg, s0, z0)
-    state = state.at[index, slot].set(s1, mode="drop")
-    z = z.at[index, slot].set(z1, mode="drop")
+    with jax.named_scope("layer/attn/kv_write"):
+        state = state.at[index, slot].set(s1, mode="drop")
+        z = z.at[index, slot].set(z1, mode="drop")
     return _merge(cfg, layer, o, y.dtype), state, z
 
 
 def _ffn(cfg: BrumbyConfig, layer, x):
     """``x + SwiGLU(norm(x))``."""
     with jax.named_scope("layer/mlp"):
-        y = L.rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
+        with jax.named_scope("layer/norm"):
+            y = L.rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
         return x + qmm(jax.nn.silu(qmm(y, layer["w1"])) * qmm(y, layer["w3"]),
                        layer["w2"], x.dtype)
 
 
 # --------------------------------------------------------------------- forward
 def _head(cfg: BrumbyConfig, params, x):
-    return L.head_logits(cfg, params,
-                         L.rms_norm(x, params["final_norm"], cfg.rms_eps))
+    with jax.named_scope("layer/norm"):
+        x = L.rms_norm(x, params["final_norm"], cfg.rms_eps)
+    return L.head_logits(cfg, params, x)
 
 
 def forward_cached(cfg: BrumbyConfig, params, input_ids, cache, pos,
@@ -210,13 +218,15 @@ def forward_cached(cfg: BrumbyConfig, params, input_ids, cache, pos,
     live = jnp.broadcast_to((slot < cache["state"].shape[1])[:, None],
                             (bsz, t)) if t == 1 or lengths is None \
         else jnp.arange(t)[None, :] < jnp.asarray(lengths)[:, None]
-    x = params["embed"][input_ids].astype(params["embed"].dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][input_ids].astype(params["embed"].dtype)
 
     def step(x, layer, state, z, index, slot, kind):
         del kind
         index = jnp.asarray(index, jnp.int32)
         with jax.named_scope("layer/attn"):
-            y = L.rms_norm(x, layer["attn_norm"], cfg.rms_eps)
+            with jax.named_scope("layer/norm"):
+                y = L.rms_norm(x, layer["attn_norm"], cfg.rms_eps)
             out, state, z = _mixer_cached(cfg, layer, y, state, z, index,
                                           slot, w.step_pos, live)
             x = x + out

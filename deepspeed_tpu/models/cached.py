@@ -188,14 +188,20 @@ def cached_attention(q, k, v, ck, cv, pos, block_tables=None,
     window kind's leaves and ring (``ops/paged_kv.py`` "Layer kinds").
     ``sm_scale``: the scores' scale where it is not ``head_dim ** -0.5``."""
     if block_tables is None:
-        ck, cv = cache_update(ck, cv, k, v, pos)
-        return da.decode_attention(q, ck, cv, pos, sm_scale=sm_scale), ck, cv
+        with jax.named_scope("layer/attn/kv_write"):
+            ck, cv = cache_update(ck, cv, k, v, pos)
+        with jax.named_scope("layer/attn/core"):
+            out = da.decode_attention(q, ck, cv, pos, sm_scale=sm_scale)
+        return out, ck, cv
+    # (the paged write names its own scope: ops/paged_kv.py)
     ck, cv = paged_kv.paged_cache_update(ck, cv, k, v, pos, block_tables,
                                          valid=chunk_valid, layer=layer,
                                          ring=bool(window))
-    return da.paged_decode_attention(q, ck, cv, block_tables, pos,
-                                     layer=layer, valid=chunk_valid,
-                                     window=window, sm_scale=sm_scale), ck, cv
+    with jax.named_scope("layer/attn/core"):
+        out = da.paged_decode_attention(q, ck, cv, block_tables, pos,
+                                        layer=layer, valid=chunk_valid,
+                                        window=window, sm_scale=sm_scale)
+    return out, ck, cv
 
 
 # ------------------------------------------------------------------ the window
@@ -309,7 +315,8 @@ def scan_layers_cached(step, x, blocks, cache_k, cache_v, paged: bool):
             x, ck, cv, *aux = step(x, layer, ck, cv, None)
             return x, (ck, cv, *aux)
 
-        x, out = jax.lax.scan(sbody, x, (blocks, cache_k, cache_v))
+        with jax.named_scope("layer"):
+            x, out = jax.lax.scan(sbody, x, (blocks, cache_k, cache_v))
         return (x, *out)
 
     def pbody(carry, xs):
@@ -319,9 +326,10 @@ def scan_layers_cached(step, x, blocks, cache_k, cache_v, paged: bool):
         return (x, pk, pv), tuple(aux)
 
     n = jax.tree_util.tree_leaves(blocks)[0].shape[0]
-    carry, aux = jax.lax.scan(
-        pbody, (x, cache_k, cache_v),
-        (blocks, jnp.arange(n, dtype=jnp.int32)))
+    with jax.named_scope("layer"):
+        carry, aux = jax.lax.scan(
+            pbody, (x, cache_k, cache_v),
+            (blocks, jnp.arange(n, dtype=jnp.int32)))
     return (*carry, *aux)
 
 
@@ -369,8 +377,9 @@ def decode_over_layers(body, x, blocks, cache_k, cache_v, num_layers,
                     jax.lax.dynamic_update_index_in_dim(ck_all, ck, l, 0),
                     jax.lax.dynamic_update_index_in_dim(cv_all, cv, l, 0))
 
-        return jax.lax.fori_loop(0, num_layers, ibody,
-                                 (x, cache_k, cache_v))
+        with jax.named_scope("layer"):
+            return jax.lax.fori_loop(0, num_layers, ibody,
+                                     (x, cache_k, cache_v))
 
     return scan_layers_cached(
         lambda x, layer, ck, cv, l: body(x, *layer_accessors(layer),
@@ -469,11 +478,13 @@ def scan_periods_cached(kinds, num_layers: int, step, x, blocks, cache,
 
     heads = []
     for period in range(head):
-        (x, cache), aux = body((x, cache), period)
+        with jax.named_scope("layer"):
+            (x, cache), aux = body((x, cache), period)
         heads.append(aux)
     if head < n // p:
-        (x, cache), aux = jax.lax.scan(
-            body, (x, cache), jnp.arange(head, n // p, dtype=jnp.int32))
+        with jax.named_scope("layer"):
+            (x, cache), aux = jax.lax.scan(
+                body, (x, cache), jnp.arange(head, n // p, dtype=jnp.int32))
         aux = jax.tree_util.tree_map(
             lambda a: a.reshape((n - head * p,) + a.shape[2:]), aux)
         heads.append(aux)

@@ -289,6 +289,7 @@ def _rescale(cfg: Dots3Config, rank: int) -> float:
     return math.sqrt(cfg.hidden_size / rank) if cfg.lora_rescale else 1.0
 
 
+@jax.named_scope("layer/attn/qkv")
 def _project(cfg: Dots3Config, a: L.LlamaConfig, layer, y, rope):
     """A latent layer's projections of its normed input ``y [B, T, d]`` at
     the sizes ``a``: the rescaled query latent ``c_q [B, T, q_rank]``, the
@@ -309,6 +310,7 @@ def _project(cfg: Dots3Config, a: L.LlamaConfig, layer, y, rope):
         rope(q[..., a.qk_nope_dim:]), c.astype(y.dtype), kr
 
 
+@jax.named_scope("layer/attn/qkv")
 def _indexer(cfg: Dots3Config, layer, y, cq, rope):
     """The indexer of a full layer: queries ``[B, HI, T, DI]`` from the
     query latent ``cq``, the one key ``[B, 1, T, DI]`` (LayerNorm) and the
@@ -344,6 +346,7 @@ def _index_rope(cfg: Dots3Config, pos=None, seq_len: int = 0):
     return lambda x: L.apply_rope(x, cos, sin, cfg.index_rope_interleaved)
 
 
+@jax.named_scope("layer/attn/out")
 def _gated(cfg: Dots3Config, layer, y, out):
     """``out [B, T, H, v]`` times the head gate, ``[B, T, H * v]``."""
     if cfg.head_gate:
@@ -371,7 +374,7 @@ def _attend_cached(cfg: Dots3Config, kind: str, layer, y, leaves, index,
                       ((0, 0),) * 3 + ((0, pad),)),
         pos, table, valid=valid, layer=index, ring=ring)
     w_uk, w_uv = L._latent_up(a, layer["kv_b_w"], y.dtype)
-    with jax.named_scope("latent_up"):
+    with jax.named_scope("layer/attn/latent_up"):
         ql = jnp.einsum("bhtn,chn->bhtc", qn, w_uk)
         q = jnp.concatenate([ql, qr], axis=-1).astype(jnp.float32) \
             * L.latent_scale(a)
@@ -379,9 +382,10 @@ def _attend_cached(cfg: Dots3Config, kind: str, layer, y, leaves, index,
     counts = jnp.zeros(len(sparse_attention.COUNTS), jnp.int32)
     kept = None
     if ring:
-        o = decode_attention.paged_latent_attention(
-            q, pool, table, pos, rank=a.kv_lora_rank, layer=index,
-            valid=valid, window=cfg.sliding_window)
+        with jax.named_scope("layer/attn/core"):
+            o = decode_attention.paged_latent_attention(
+                q, pool, table, pos, rank=a.kv_lora_rank, layer=index,
+                valid=valid, window=cfg.sliding_window)
         leaves = (pool,)
     else:
         qi, ki, wi = _indexer(cfg, layer, y, cq, _index_rope(cfg, pos))
@@ -392,10 +396,11 @@ def _attend_cached(cfg: Dots3Config, kind: str, layer, y, leaves, index,
             topk=cfg.index_topk, layer=index, valid=valid, return_keep=keep)
         kept = kept[0] if kept else None
         leaves = (pool, idx)
-    with jax.named_scope("latent_up"):
+    with jax.named_scope("layer/attn/latent_up"):
         out = jnp.einsum("bhtc,chv->bthv", o, w_uv)
-    return qmm(_gated(cfg, layer, y, out), layer["o_w"], y.dtype), leaves, \
-        counts, kept
+    with jax.named_scope("layer/attn/out"):
+        out = qmm(_gated(cfg, layer, y, out), layer["o_w"], y.dtype)
+    return out, leaves, counts, kept
 
 
 def _attend(cfg: Dots3Config, kind: str, layer, y):
@@ -474,12 +479,14 @@ def block_cached(cfg: Dots3Config, blocks, stacks, w: cached.Window, live,
     multi-token-prediction module: ``models/glm_dsa.py``) calls it with
     stacks of its own.  -> ``(x, the kind's leaves.., aux)``."""
     with jax.named_scope("layer/attn"):
-        y = L.rms_norm(x, layer["attn_norm"], cfg.rms_eps)
+        with jax.named_scope("layer/norm"):
+            y = L.rms_norm(x, layer["attn_norm"], cfg.rms_eps)
         leaves = (ck,) if kind == SLIDING else (ck, cv)
         out, leaves, counts, kept = _attend_cached(
             cfg, kind, layer, y, leaves, index, table, w, choices)
         x = x + out
-    y = L.rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
+    with jax.named_scope("layer/norm"):
+        y = L.rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
     out, record, chosen = _ffn(cfg, blocks, stacks, number, y, live, choices)
     aux = {"record": record, "counts": counts}
     if choices:
@@ -530,7 +537,8 @@ def forward_cached(cfg: Dots3Config, params, input_ids, cache, pos,
     block_tables = kind_tables(cfg, block_tables)
     w = cached.window(input_ids, pos, lengths, block_tables["full"])
     live = live_tokens(input_ids, lengths, block_tables)
-    x = params["embed"][input_ids].astype(params["embed"].dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][input_ids].astype(params["embed"].dtype)
     blocks = params["blocks"]
     stacks = expert_stacks(blocks)
     s_max = block_tables["full"].shape[1] * cache["latent"].shape[3] \
@@ -568,9 +576,11 @@ def forward_cached(cfg: Dots3Config, params, input_ids, cache, pos,
     for kind in tail:
         x, cache = stretch(x, cache, (kind,), 1, 1)
     aux = jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *auxes)
-    x = L.rms_norm(x, params["final_norm"], cfg.rms_eps)
+    with jax.named_scope("layer/norm"):
+        x = L.rms_norm(x, params["final_norm"], cfg.rms_eps)
     last = x if all_positions else cached.gather_last(x, w.gather)
-    logits = last @ params["lm_head"].astype(x.dtype)
+    with jax.named_scope("head"):
+        logits = last @ params["lm_head"].astype(x.dtype)
     out = (logits, cache)
     if routing:
         out += ((aux["record"], aux["counts"].sum(axis=0)),)

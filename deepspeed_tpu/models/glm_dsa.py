@@ -155,7 +155,8 @@ def draft_cached(cfg: GlmDsaConfig, params, hidden, next_ids, cache, pos,
     tables = D.kind_tables(cfg, block_tables)
     w = cached.window(next_ids, pos, lengths, tables["full"])
     live = live_tokens(next_ids, lengths, tables)
-    emb = params["embed"][next_ids].astype(params["embed"].dtype)
+    with jax.named_scope("embed"):
+        emb = params["embed"][next_ids].astype(params["embed"].dtype)
     with jax.named_scope("mtp/join"):
         u = qmm(jnp.concatenate(
             [L.rms_norm(emb, m["enorm"], cfg.rms_eps),
@@ -170,12 +171,15 @@ def draft_cached(cfg: GlmDsaConfig, params, hidden, next_ids, cache, pos,
             u, layer, cache["latent"], cache["idx"], cfg.layers_of(FULL),
             tables["full"], FULL, cfg.first_dense)
     cache = {**cache, "latent": latent, "idx": idx}
-    x = L.rms_norm(x, m["final_norm"], cfg.rms_eps)
+    with jax.named_scope("layer/norm"):
+        x = L.rms_norm(x, m["final_norm"], cfg.rms_eps)
     if at is not None:
         x = cached.gather_last(x, jnp.asarray(at, jnp.int32) + 1)
     elif not all_positions:
         x = cached.gather_last(x, w.gather)
-    out = (x @ params["lm_head"].astype(x.dtype), cache)
+    with jax.named_scope("head"):
+        logits = x @ params["lm_head"].astype(x.dtype)
+    out = (logits, cache)
     if routing:
         out += ((aux["record"], aux["counts"]),)
     if choices:

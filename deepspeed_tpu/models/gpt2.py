@@ -203,14 +203,17 @@ def _block(cfg: GPT2Config, x, layer, mask, rng, dropout: float):
                                    getattr(cfg, "act_quant_type",
                                            "symmetric"))
 
+    from ..parallel import sequence as seq_parallel
+
     with jax.named_scope("layer/attn"):
-        y = _aq(_layer_norm(x, layer["ln1_scale"], layer["ln1_bias"]))
-        qkv = qmm(y, layer["qkv_w"]) + layer["qkv_b"].astype(y.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-        k = k.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-        v = v.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-        from ..parallel import sequence as seq_parallel
+        with jax.named_scope("layer/norm"):
+            y = _aq(_layer_norm(x, layer["ln1_scale"], layer["ln1_bias"]))
+        with jax.named_scope("layer/attn/qkv"):
+            qkv = qmm(y, layer["qkv_w"]) + layer["qkv_b"].astype(y.dtype)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
+            k = k.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
+            v = v.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
 
         use_flash = cfg.use_flash
         if use_flash is None:
@@ -225,29 +228,35 @@ def _block(cfg: GPT2Config, x, layer, mask, rng, dropout: float):
                     "mesh sp>1 with attention dropout>0: sequence-parallel "
                     "attention requires dropout=0; falling back to the "
                     "dense path (quadratic in S)")
-        if seq_parallel.sp_size() > 1 and dropout == 0.0 and mask is None:
-            attn = seq_parallel.sequence_parallel_attention(
-                q, k, v, causal=True, impl=getattr(cfg, "sp_impl", "auto"))
-        elif use_flash and dropout == 0.0 and mask is None:
-            attn = seq_parallel.mesh_flash_attention(
-                q, k, v, causal=True,
-                block_q=getattr(cfg, "flash_block_q", 512),
-                block_k=getattr(cfg, "flash_block_k", 1024))
-        else:
-            if mask is None:
-                mask = jnp.tril(jnp.ones((s, s), bool))[None, None, :, :]
-            scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(hd)
-            scores = jnp.where(mask, scores.astype(jnp.float32), -1e9)
-            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-            if dropout > 0.0 and rng is not None:
-                keep = jax.random.bernoulli(rng, 1.0 - dropout, probs.shape)
-                probs = probs * keep / (1.0 - dropout)
-            attn = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
-        attn = _aq(attn.transpose(0, 2, 1, 3).reshape(b, s, d))
-        x = x + qmm(attn, layer["o_w"], x.dtype) + \
-            layer["o_b"].astype(x.dtype)
+        with jax.named_scope("layer/attn/core"):
+            if seq_parallel.sp_size() > 1 and dropout == 0.0 \
+                    and mask is None:
+                attn = seq_parallel.sequence_parallel_attention(
+                    q, k, v, causal=True,
+                    impl=getattr(cfg, "sp_impl", "auto"))
+            elif use_flash and dropout == 0.0 and mask is None:
+                attn = seq_parallel.mesh_flash_attention(
+                    q, k, v, causal=True,
+                    block_q=getattr(cfg, "flash_block_q", 512),
+                    block_k=getattr(cfg, "flash_block_k", 1024))
+            else:
+                if mask is None:
+                    mask = jnp.tril(jnp.ones((s, s), bool))[None, None, :, :]
+                scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(hd)
+                scores = jnp.where(mask, scores.astype(jnp.float32), -1e9)
+                probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+                if dropout > 0.0 and rng is not None:
+                    keep = jax.random.bernoulli(rng, 1.0 - dropout,
+                                                probs.shape)
+                    probs = probs * keep / (1.0 - dropout)
+                attn = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+        with jax.named_scope("layer/attn/out"):
+            attn = _aq(attn.transpose(0, 2, 1, 3).reshape(b, s, d))
+            x = x + qmm(attn, layer["o_w"], x.dtype) + \
+                layer["o_b"].astype(x.dtype)
     with jax.named_scope("layer/mlp"):
-        y = _aq(_layer_norm(x, layer["ln2_scale"], layer["ln2_bias"]))
+        with jax.named_scope("layer/norm"):
+            y = _aq(_layer_norm(x, layer["ln2_scale"], layer["ln2_bias"]))
         hid = _aq(jax.nn.gelu(qmm(y, layer["fc_w"]) +
                               layer["fc_b"].astype(y.dtype)))
         x = x + qmm(hid, layer["proj_w"], x.dtype) + \
@@ -260,8 +269,9 @@ def forward(cfg: GPT2Config, params: PyTree, input_ids, rng=None,
     """Token logits. input_ids: [B, S] int32."""
     params = dequant_resident(params)
     x = _trunk(cfg, params, input_ids, rng=rng, train=train)
-    with jax.named_scope("head"):
+    with jax.named_scope("layer/norm"):
         x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
+    with jax.named_scope("head"):
         logits = x @ params["wte"].T.astype(x.dtype)
     return logits
 
@@ -284,18 +294,22 @@ def _block_cached_body(cfg: GPT2Config, x, get, mm, ck, cv, pos,
     h, hd = cfg.num_heads, cfg.head_dim
 
     with jax.named_scope("layer/attn"):
-        y = _layer_norm(x, get("ln1_scale"), get("ln1_bias"))
-        qkv = mm(y, "qkv_w", None) + get("qkv_b").astype(y.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
-        k = k.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
-        v = v.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
+        with jax.named_scope("layer/norm"):
+            y = _layer_norm(x, get("ln1_scale"), get("ln1_bias"))
+        with jax.named_scope("layer/attn/qkv"):
+            qkv = mm(y, "qkv_w", None) + get("qkv_b").astype(y.dtype)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
+            k = k.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
+            v = v.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
         attn, ck, cv = cached_attention(q, k, v, ck, cv, pos, block_tables,
                                         chunk_valid, layer)
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, t, d)
-        x = x + mm(attn, "o_w", x.dtype) + get("o_b").astype(x.dtype)
+        with jax.named_scope("layer/attn/out"):
+            attn = attn.transpose(0, 2, 1, 3).reshape(b, t, d)
+            x = x + mm(attn, "o_w", x.dtype) + get("o_b").astype(x.dtype)
     with jax.named_scope("layer/mlp"):
-        y = _layer_norm(x, get("ln2_scale"), get("ln2_bias"))
+        with jax.named_scope("layer/norm"):
+            y = _layer_norm(x, get("ln2_scale"), get("ln2_bias"))
         hid = jax.nn.gelu(mm(y, "fc_w", None) + get("fc_b").astype(y.dtype))
         x = x + mm(hid, "proj_w", x.dtype) + get("proj_b").astype(x.dtype)
     return x, ck, cv
@@ -339,8 +353,9 @@ def forward_cached(cfg: GPT2Config, params, input_ids, cache, pos,
         paged=w.paged)
     if not all_positions:
         x = gather_last(x, w.gather)
-    with jax.named_scope("head"):
+    with jax.named_scope("layer/norm"):
         x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
+    with jax.named_scope("head"):
         logits = x @ params["wte"].T.astype(x.dtype)
     return logits, {"k": ks, "v": vs}
 
@@ -462,13 +477,15 @@ def _embed(cfg: GPT2Config, params, input_ids):
     return x.astype(params["wte"].dtype)
 
 
-@jax.named_scope("head")
+@jax.named_scope("loss")
 def _head_loss(cfg: GPT2Config, params, x, targets):
     """Final LN + tied head + CE, as ``lse - label_logit`` so no [T, V]
     log-softmax tensor is ever materialized (XLA fuses the f32 upcast into
     the reductions)."""
-    x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
-    logits = x @ params["wte"].T.astype(x.dtype)
+    with jax.named_scope("layer/norm"):
+        x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
+    with jax.named_scope("head"):
+        logits = x @ params["wte"].T.astype(x.dtype)
     valid = targets >= 0  # -100 = ignore (HF convention, same as loss_from_batch)
     safe = jnp.where(valid, targets, 0)
     lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
@@ -478,10 +495,12 @@ def _head_loss(cfg: GPT2Config, params, x, targets):
     return jnp.where(valid, nll, 0.0).sum() / jnp.maximum(valid.sum(), 1)
 
 
-@jax.named_scope("head")
+@jax.named_scope("loss")
 def _head_loss_fused(cfg: GPT2Config, params, x, targets):
-    """LN + tied-head CE via the chunked fused-backward formulation."""
-    x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
+    """LN + tied-head CE via the chunked fused-backward formulation (the
+    products inside it are ``head``'s: ``ops/chunked_ce.py``)."""
+    with jax.named_scope("layer/norm"):
+        x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
     b, s, d = x.shape
     n = b * s
     return chunked_ce(params["wte"].T.astype(x.dtype), x.reshape(n, d),

@@ -193,13 +193,15 @@ def _ssm_inputs(cfg: GraniteHybridConfig, layer, proj, tail, live):
     inner, ch, n = cfg.ssm_inner, cfg.conv_channels, cfg.ssm_state
     z, xbc, dt = proj[..., :inner], proj[..., inner:inner + ch], \
         proj[..., inner + ch:]
-    ext = jnp.concatenate([tail, xbc.astype(tail.dtype)], axis=1)
-    taps = layer["conv_w"].astype(jnp.float32)
-    conv = jax.nn.silu(sum(ext[:, j:j + t].astype(jnp.float32) * taps[j]
-                           for j in range(cfg.ssm_conv))
-                       + layer["conv_b"].astype(jnp.float32))
-    dt = jax.nn.softplus(dt.astype(jnp.float32)
-                         + layer["dt_bias"].astype(jnp.float32))
+    with jax.named_scope("layer/state/conv"):
+        ext = jnp.concatenate([tail, xbc.astype(tail.dtype)], axis=1)
+        taps = layer["conv_w"].astype(jnp.float32)
+        conv = jax.nn.silu(sum(ext[:, j:j + t].astype(jnp.float32) * taps[j]
+                               for j in range(cfg.ssm_conv))
+                           + layer["conv_b"].astype(jnp.float32))
+    with jax.named_scope("layer/state/gate"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32)
+                             + layer["dt_bias"].astype(jnp.float32))
     return (z, ext,
             conv[..., :inner].reshape(bsz, t, cfg.ssm_heads, cfg.ssm_head_dim),
             jnp.where(live[..., None], dt, 0.0),
@@ -210,14 +212,18 @@ def _ssm_output(cfg: GraniteHybridConfig, layer, z, x, y, dtype):
     """``W_out RMSNorm((y + d_skip x) * SiLU(z); gate_norm)`` from the
     scan's float32 ``y [B, T, H, P]``: one norm over all inner channels."""
     bsz, t = y.shape[:2]
-    y = y + layer["d_skip"].astype(jnp.float32)[:, None] * x
-    g = y.reshape(bsz, t, cfg.ssm_inner) * jax.nn.silu(z.astype(jnp.float32))
-    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
-                          + cfg.rms_eps) * layer["gate_norm"].astype(
-                              jnp.float32)
-    return qmm(g.astype(dtype), layer["out_w"], dtype)
+    with jax.named_scope("layer/state/gate"):
+        y = y + layer["d_skip"].astype(jnp.float32)[:, None] * x
+        g = y.reshape(bsz, t, cfg.ssm_inner) \
+            * jax.nn.silu(z.astype(jnp.float32))
+        g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
+                              + cfg.rms_eps) * layer["gate_norm"].astype(
+                                  jnp.float32)
+    with jax.named_scope("layer/attn/out"):
+        return qmm(g.astype(dtype), layer["out_w"], dtype)
 
 
+@jax.named_scope("layer/attn/qkv")
 def _in_proj(layer, y):
     """``z | xBC | dt`` of ``y`` (the barrier: the later head split moves
     this product, not the weight — ``llama._attend_cached``)."""
@@ -236,32 +242,39 @@ def _ssm_cached(cfg: GraniteHybridConfig, layer, y, state, conv, index, slot,
     proj = _in_proj(layer, y)
     if slot is None:
         # a decode step (one token a row): row b is row b of the leaves
-        tail = jax.lax.dynamic_index_in_dim(conv, index, keepdims=False)[:, 0]
+        with jax.named_scope("layer/attn/kv_write"):
+            tail = jax.lax.dynamic_index_in_dim(conv, index,
+                                                keepdims=False)[:, 0]
         z, ext, x, dt, b, c = _ssm_inputs(cfg, layer, proj, tail, live)
-        conv = jax.lax.dynamic_update_index_in_dim(
-            conv, jnp.where(live[:, :, None], ext[:, 1:], tail)[:, None],
-            index, 0)
+        with jax.named_scope("layer/attn/kv_write"):
+            conv = jax.lax.dynamic_update_index_in_dim(
+                conv, jnp.where(live[:, :, None], ext[:, 1:], tail)[:, None],
+                index, 0)
         o, state = ssd.step(x[:, 0], dt[:, 0], _a(layer), b[:, 0], c[:, 0],
                             state, index)
         return _ssm_output(cfg, layer, z, x, o[:, None], y.dtype), state, conv
     # a prefill window: the rows' leaves by ``slot`` (a pad row's is out of
     # range: read clamped, written nowhere); a window at base 0 starts from
     # nothing
-    rows = jnp.clip(slot, 0, state.shape[1] - 1)
-    fresh = (jnp.asarray(base, jnp.int32) == 0).reshape(-1)
-    tail = jnp.where(fresh[:, None, None], 0, conv[index, rows, 0])
-    s0 = jnp.where(fresh[:, None, None, None], 0.0, state[index, rows])
+    with jax.named_scope("layer/attn/kv_write"):
+        rows = jnp.clip(slot, 0, state.shape[1] - 1)
+        fresh = (jnp.asarray(base, jnp.int32) == 0).reshape(-1)
+        tail = jnp.where(fresh[:, None, None], 0, conv[index, rows, 0])
+        s0 = jnp.where(fresh[:, None, None, None], 0.0, state[index, rows])
     z, ext, x, dt, b, c = _ssm_inputs(cfg, layer, proj, tail, live)
-    valid = live.sum(axis=1, dtype=jnp.int32)
-    tail = jnp.take_along_axis(
-        ext, (valid[:, None] + jnp.arange(taps))[:, :, None], axis=1)
+    with jax.named_scope("layer/attn/kv_write"):
+        valid = live.sum(axis=1, dtype=jnp.int32)
+        tail = jnp.take_along_axis(
+            ext, (valid[:, None] + jnp.arange(taps))[:, :, None], axis=1)
     o, s1 = ssd.chunked(x, dt, _a(layer), b, c, s0)
-    state = state.at[index, slot].set(s1, mode="drop")
-    conv = conv.at[index, slot, 0].set(tail, mode="drop")
+    with jax.named_scope("layer/attn/kv_write"):
+        state = state.at[index, slot].set(s1, mode="drop")
+        conv = conv.at[index, slot, 0].set(tail, mode="drop")
     return _ssm_output(cfg, layer, z, x, o, y.dtype), state, conv
 
 
 # ------------------------------------------------------------------ full layer
+@jax.named_scope("layer/attn/qkv")
 def _qkv(cfg: GraniteHybridConfig, layer, y):
     """``q [B, H, T, hd]``, ``k``, ``v`` ``[B, HKV, T, hd]`` of the normed
     input, unrotated (the barrier: ``llama._attend_cached``)."""
@@ -274,6 +287,7 @@ def _qkv(cfg: GraniteHybridConfig, layer, y):
         split(v, cfg.num_kv_heads)
 
 
+@jax.named_scope("layer/attn/out")
 def _merge(cfg: GraniteHybridConfig, layer, attn, dtype):
     bsz, _, t, _ = attn.shape
     return qmm(attn.transpose(0, 2, 1, 3).reshape(
@@ -283,7 +297,8 @@ def _merge(cfg: GraniteHybridConfig, layer, attn, dtype):
 def _ffn(cfg: GraniteHybridConfig, layer, x):
     """``x + residual_multiplier * SwiGLU(norm(x))``."""
     with jax.named_scope("layer/mlp"):
-        y = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
+        with jax.named_scope("layer/norm"):
+            y = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
         gu = qmm(y, layer["ffn_in_w"])
         out = qmm(jax.nn.silu(gu[..., :cfg.ffn_size])
                   * gu[..., cfg.ffn_size:], layer["ffn_out_w"], x.dtype)
@@ -292,11 +307,15 @@ def _ffn(cfg: GraniteHybridConfig, layer, x):
 
 # --------------------------------------------------------------------- forward
 def _head(cfg: GraniteHybridConfig, params, x):
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    logits = jnp.einsum("...d,vd->...v", x, params["embed"].astype(x.dtype))
-    return logits / cfg.logits_scaling
+    with jax.named_scope("layer/norm"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    with jax.named_scope("head"):
+        logits = jnp.einsum("...d,vd->...v", x,
+                            params["embed"].astype(x.dtype))
+        return logits / cfg.logits_scaling
 
 
+@jax.named_scope("embed")
 def _embed(cfg: GraniteHybridConfig, params, input_ids):
     return params["embed"][input_ids] * jnp.asarray(
         cfg.embedding_multiplier, params["embed"].dtype)
@@ -326,7 +345,8 @@ def forward_cached(cfg: GraniteHybridConfig, params, input_ids, cache, pos,
     @functools.partial(jax.jit, donate_argnums=())
     def ssm_layer(x, layer, state, conv, index, slot, base, live):
         with jax.named_scope("layer/attn"):
-            y = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
+            with jax.named_scope("layer/norm"):
+                y = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
             out, state, conv = _ssm_cached(cfg, layer, y, state, conv, index,
                                            slot, base, live)
             x = x + res * out
@@ -340,7 +360,8 @@ def forward_cached(cfg: GraniteHybridConfig, params, input_ids, cache, pos,
                                   w.step_pos, live)
         else:
             with jax.named_scope("layer/attn"):
-                y = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
+                with jax.named_scope("layer/norm"):
+                    y = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
                 attn, ck, cv = cached.cached_attention(
                     *_qkv(cfg, layer, y), ck, cv, w.step_pos, table,
                     w.chunk_valid, index, sm_scale=cfg.attention_multiplier)

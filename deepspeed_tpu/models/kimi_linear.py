@@ -243,39 +243,48 @@ def _kda_inputs(cfg: KimiLinearConfig, layer, y, ext, live):
     ``beta`` are 0 (the token moves no state)."""
     b, t, _ = y.shape
     h, hd, c = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_width
-    taps = layer["conv_w"].astype(jnp.float32)
-    conv = sum(ext[:, j:j + t].astype(jnp.float32) * taps[j]
-               for j in range(cfg.kda_conv))
-    q, k, v = (jax.nn.silu(conv[..., i * c:(i + 1) * c])
-               .reshape(b, t, h, hd).transpose(0, 2, 1, 3) for i in range(3))
+    with jax.named_scope("layer/state/conv"):
+        taps = layer["conv_w"].astype(jnp.float32)
+        conv = sum(ext[:, j:j + t].astype(jnp.float32) * taps[j]
+                   for j in range(cfg.kda_conv))
+        q, k, v = (jax.nn.silu(conv[..., i * c:(i + 1) * c])
+                   .reshape(b, t, h, hd).transpose(0, 2, 1, 3)
+                   for i in range(3))
 
     def unit(a):
         return a * jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
 
-    decay = qmm(qmm(y, layer["f_a_w"]), layer["f_b_w"]).astype(jnp.float32) \
-        + layer["dt_bias"].astype(jnp.float32)
-    g = -jnp.exp(layer["a_log"].astype(jnp.float32))[None, :, None, None] \
-        * jax.nn.softplus(decay).reshape(b, t, h, hd).transpose(0, 2, 1, 3)
-    beta = jax.nn.sigmoid(qmm(y, layer["b_w"]).astype(jnp.float32)) \
-        .transpose(0, 2, 1)
-    keep = live[:, None, :]
-    return (unit(q) * hd ** -0.5, unit(k), v,
-            jnp.where(keep[..., None], g, 0.0), jnp.where(keep, beta, 0.0))
+    with jax.named_scope("layer/state/gate"):
+        decay = qmm(qmm(y, layer["f_a_w"]), layer["f_b_w"]) \
+            .astype(jnp.float32) + layer["dt_bias"].astype(jnp.float32)
+        g = -jnp.exp(layer["a_log"].astype(jnp.float32))[
+            None, :, None, None] * jax.nn.softplus(decay).reshape(
+                b, t, h, hd).transpose(0, 2, 1, 3)
+        beta = jax.nn.sigmoid(qmm(y, layer["b_w"]).astype(jnp.float32)) \
+            .transpose(0, 2, 1)
+        keep = live[:, None, :]
+        return (unit(q) * hd ** -0.5, unit(k), v,
+                jnp.where(keep[..., None], g, 0.0),
+                jnp.where(keep, beta, 0.0))
 
 
 def _kda_output(cfg: KimiLinearConfig, layer, y, o):
     """``W_o [RMSNorm_head(o) * o_norm * sigmoid(W_g2 W_g1 y)]`` from the
     rule's float32 ``o [B, H, T, dv]``."""
     b, t, _ = y.shape
-    o = o.transpose(0, 2, 1, 3)
-    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
-                          + cfg.rms_eps) * layer["o_norm"].astype(jnp.float32)
-    gate = jax.nn.sigmoid(qmm(qmm(y, layer["g_a_w"]), layer["g_b_w"])
-                          .astype(jnp.float32)).reshape(o.shape)
-    return qmm((o * gate).reshape(b, t, cfg.kda_width).astype(y.dtype),
-               layer["o_w"], y.dtype)
+    with jax.named_scope("layer/state/gate"):
+        o = o.transpose(0, 2, 1, 3)
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                              + cfg.rms_eps) \
+            * layer["o_norm"].astype(jnp.float32)
+        gate = jax.nn.sigmoid(qmm(qmm(y, layer["g_a_w"]), layer["g_b_w"])
+                              .astype(jnp.float32)).reshape(o.shape)
+    with jax.named_scope("layer/attn/out"):
+        return qmm((o * gate).reshape(b, t, cfg.kda_width).astype(y.dtype),
+                   layer["o_w"], y.dtype)
 
 
+@jax.named_scope("layer/attn/qkv")
 def _kda_projections(layer, y):
     """q | k | v of ``y``, ``[B, T, 3 C]`` (the barrier: a later head split
     moves these products, not the weights — ``llama._attend_cached``)."""
@@ -293,11 +302,13 @@ def _kda_cached(cfg: KimiLinearConfig, layer, y, state, conv, index, slot,
     x3 = _kda_projections(layer, y)
     if slot is None:
         # a decode step (one token a row): row b is row b of the leaves
-        tail = jax.lax.dynamic_index_in_dim(conv, index, keepdims=False)[:, 0]
-        ext = jnp.concatenate([tail, x3.astype(tail.dtype)], axis=1)
-        conv = jax.lax.dynamic_update_index_in_dim(
-            conv, jnp.where(live[:, :, None], ext[:, 1:], tail)[:, None],
-            index, 0)
+        with jax.named_scope("layer/attn/kv_write"):
+            tail = jax.lax.dynamic_index_in_dim(conv, index,
+                                                keepdims=False)[:, 0]
+            ext = jnp.concatenate([tail, x3.astype(tail.dtype)], axis=1)
+            conv = jax.lax.dynamic_update_index_in_dim(
+                conv, jnp.where(live[:, :, None], ext[:, 1:], tail)[:, None],
+                index, 0)
         q, k, v, g, beta = _kda_inputs(cfg, layer, y, ext, live)
         o, state = delta_rule.step(q[:, :, 0], k[:, :, 0], v[:, :, 0],
                                    g[:, :, 0], beta[:, :, 0], state, index)
@@ -305,18 +316,20 @@ def _kda_cached(cfg: KimiLinearConfig, layer, y, state, conv, index, slot,
     # a prefill window: the rows' leaves by ``slot`` (a pad row's is out of
     # range: read clamped, written nowhere); a window at base 0 starts from
     # nothing
-    rows = jnp.clip(slot, 0, state.shape[1] - 1)
-    fresh = (jnp.asarray(base, jnp.int32) == 0).reshape(-1)
-    tail = jnp.where(fresh[:, None, None], 0, conv[index, rows, 0])
-    s0 = jnp.where(fresh[:, None, None, None], 0.0, state[index, rows])
-    ext = jnp.concatenate([tail, x3.astype(tail.dtype)], axis=1)
-    valid = live.sum(axis=1, dtype=jnp.int32)
-    tail = jnp.take_along_axis(
-        ext, (valid[:, None] + jnp.arange(taps))[:, :, None], axis=1)
+    with jax.named_scope("layer/attn/kv_write"):
+        rows = jnp.clip(slot, 0, state.shape[1] - 1)
+        fresh = (jnp.asarray(base, jnp.int32) == 0).reshape(-1)
+        tail = jnp.where(fresh[:, None, None], 0, conv[index, rows, 0])
+        s0 = jnp.where(fresh[:, None, None, None], 0.0, state[index, rows])
+        ext = jnp.concatenate([tail, x3.astype(tail.dtype)], axis=1)
+        valid = live.sum(axis=1, dtype=jnp.int32)
+        tail = jnp.take_along_axis(
+            ext, (valid[:, None] + jnp.arange(taps))[:, :, None], axis=1)
     q, k, v, g, beta = _kda_inputs(cfg, layer, y, ext, live)
     o, s1 = delta_rule.chunked(q, k, v, g, beta, s0)
-    state = state.at[index, slot].set(s1, mode="drop")
-    conv = conv.at[index, slot, 0].set(tail, mode="drop")
+    with jax.named_scope("layer/attn/kv_write"):
+        state = state.at[index, slot].set(s1, mode="drop")
+        conv = conv.at[index, slot, 0].set(tail, mode="drop")
     return _kda_output(cfg, layer, y, o), state, conv
 
 
@@ -377,7 +390,8 @@ def forward_cached(cfg: KimiLinearConfig, params, input_ids, cache, pos,
             "InferenceEngine.generate has one kind of state")
     w = cached.window(input_ids, pos, lengths, block_tables["full"])
     live = live_tokens(input_ids, lengths, block_tables)
-    x = params["embed"][input_ids].astype(params["embed"].dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][input_ids].astype(params["embed"].dtype)
     blocks = params["blocks"]
     stacks = None
     if M._expert_kernel(blocks["moe"]):
@@ -401,7 +415,8 @@ def forward_cached(cfg: KimiLinearConfig, params, input_ids, cache, pos,
     def step(x, layer, ck, cv, index, table, kind, number):
         get, mm = layer_accessors(layer)
         with jax.named_scope("layer/attn"):
-            y = L.rms_norm(x, layer["attn_norm"], cfg.rms_eps)
+            with jax.named_scope("layer/norm"):
+                y = L.rms_norm(x, layer["attn_norm"], cfg.rms_eps)
             if kind == "kda":
                 out, ck, cv = kda(layer, y, ck, cv,
                                   jnp.asarray(index, jnp.int32), table,
@@ -409,9 +424,11 @@ def forward_cached(cfg: KimiLinearConfig, params, input_ids, cache, pos,
             else:
                 attn, ck = L._latent_cached(cfg, y, get, mm, ck, w.step_pos,
                                             table, w.chunk_valid, index)
-                out = mm(attn, "o_w", x.dtype)
+                with jax.named_scope("layer/attn/out"):
+                    out = mm(attn, "o_w", x.dtype)
             x = x + out
-        y = L.rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
+        with jax.named_scope("layer/norm"):
+            y = L.rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
         out, record, chosen = _ffn(cfg, blocks, stacks, number, y, live,
                                    choices, routed)
         leaves = (ck, cv) if kind == "kda" else (ck,)
@@ -423,8 +440,10 @@ def forward_cached(cfg: KimiLinearConfig, params, input_ids, cache, pos,
         block_tables, head=1 if cfg.first_dense else 0)
     if not all_positions:
         x = cached.gather_last(x, w.gather)
-    x = L.rms_norm(x, params["final_norm"], cfg.rms_eps)
-    logits = x @ params["lm_head"].astype(x.dtype)
+    with jax.named_scope("layer/norm"):
+        x = L.rms_norm(x, params["final_norm"], cfg.rms_eps)
+    with jax.named_scope("head"):
+        logits = x @ params["lm_head"].astype(x.dtype)
     records, chosen = aux if choices else (aux, None)
     out = (logits, cache, records) if routing else (logits, cache)
     if choices:

@@ -349,6 +349,7 @@ def layer_norm(x, scale, eps: float = 1e-5):
     return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
+@jax.named_scope("layer/norm")
 def block_norm(cfg: LlamaConfig, x, scale):
     """The norm ``cfg.norm`` names, at ``cfg.rms_eps``."""
     if cfg.norm == "layernorm":
@@ -356,6 +357,7 @@ def block_norm(cfg: LlamaConfig, x, scale):
     return rms_norm(x, scale, cfg.rms_eps)
 
 
+@jax.named_scope("head")
 def head_logits(cfg: LlamaConfig, params, x):
     """The final norm's output times the head — the token table itself for
     ``tie_embeddings``."""
@@ -569,21 +571,24 @@ def _latent_attention(cfg: LlamaConfig, layer, y, cos, sin):
     b, s, _ = y.shape
     h = cfg.num_heads
     get, mm = layer_accessors(layer)
-    qn, qr, c, kr = _latent_project(
-        cfg, y, get, mm, (lambda a: apply_rope(a, cos, sin,
-                                               cfg.rope_interleaved))
-        if cfg.latent_rope else (lambda a: a))
-    w_uk, w_uv = _latent_up(cfg, get("kv_b_w"), y.dtype)
-    kn = jnp.einsum("bsc,chn->bhsn", c, w_uk)
-    v = jnp.einsum("bsc,chv->bhsv", c, w_uv)
-    scores = (jnp.einsum("bhqn,bhkn->bhqk", qn, kn)
-              + jnp.einsum("bhqr,bkr->bhqk", qr, kr[:, 0])) \
-        .astype(jnp.float32) * _latent_temperature(cfg, jnp.arange(s))
-    scores = jnp.where(jnp.tril(jnp.ones((s, s), bool))[None, None],
-                       scores, -1e9)
-    probs = jax.nn.softmax(scores, axis=-1).astype(y.dtype)
-    return jnp.einsum("bhqk,bhkv->bqhv", probs, v).reshape(
-        b, s, h * cfg.value_dim)
+    with jax.named_scope("layer/attn/qkv"):
+        qn, qr, c, kr = _latent_project(
+            cfg, y, get, mm, (lambda a: apply_rope(a, cos, sin,
+                                                   cfg.rope_interleaved))
+            if cfg.latent_rope else (lambda a: a))
+    with jax.named_scope("layer/attn/latent_up"):
+        w_uk, w_uv = _latent_up(cfg, get("kv_b_w"), y.dtype)
+        kn = jnp.einsum("bsc,chn->bhsn", c, w_uk)
+        v = jnp.einsum("bsc,chv->bhsv", c, w_uv)
+    with jax.named_scope("layer/attn/core"):
+        scores = (jnp.einsum("bhqn,bhkn->bhqk", qn, kn)
+                  + jnp.einsum("bhqr,bkr->bhqk", qr, kr[:, 0])) \
+            .astype(jnp.float32) * _latent_temperature(cfg, jnp.arange(s))
+        scores = jnp.where(jnp.tril(jnp.ones((s, s), bool))[None, None],
+                           scores, -1e9)
+        probs = jax.nn.softmax(scores, axis=-1).astype(y.dtype)
+        return jnp.einsum("bhqk,bhkv->bqhv", probs, v).reshape(
+            b, s, h * cfg.value_dim)
 
 
 def _latent_cached(cfg: LlamaConfig, y, get, mm, pool, pos, block_tables,
@@ -601,9 +606,10 @@ def _latent_cached(cfg: LlamaConfig, y, get, mm, pool, pos, block_tables,
 
     b, t, _ = y.shape
     rank = cfg.kv_lora_rank
-    qn, qr, c, kr = _latent_project(
-        cfg, y, get, mm, (lambda a: _rope_cached(cfg, a, pos))
-        if cfg.latent_rope else (lambda a: a))
+    with jax.named_scope("layer/attn/qkv"):
+        qn, qr, c, kr = _latent_project(
+            cfg, y, get, mm, (lambda a: _rope_cached(cfg, a, pos))
+            if cfg.latent_rope else (lambda a: a))
     pad = pool.shape[-1] - cfg.latent_width
     pool = paged_window_update(
         pool, jnp.pad(jnp.concatenate([c[:, None], kr], axis=-1),
@@ -613,14 +619,15 @@ def _latent_cached(cfg: LlamaConfig, y, get, mm, pool, pos, block_tables,
     p = jnp.asarray(pos, jnp.int32)
     positions = (p + jnp.arange(t)) if p.ndim == 0 \
         else p[:, None] + jnp.arange(t)[None, :]
-    with jax.named_scope("latent_up"):
+    with jax.named_scope("layer/attn/latent_up"):
         ql = jnp.einsum("bhtn,chn->bhtc", qn, w_uk)
         q = jnp.concatenate([ql, qr], axis=-1).astype(jnp.float32) \
             * _latent_temperature(cfg, positions)
         q = jnp.pad(q.astype(y.dtype), ((0, 0),) * 3 + ((0, pad),))
-    o = paged_latent_attention(q, pool, block_tables, pos, rank=rank,
-                               layer=layer, valid=chunk_valid)
-    with jax.named_scope("latent_up"):
+    with jax.named_scope("layer/attn/core"):
+        o = paged_latent_attention(q, pool, block_tables, pos, rank=rank,
+                                   layer=layer, valid=chunk_valid)
+    with jax.named_scope("layer/attn/latent_up"):
         out = jnp.einsum("bhtc,chv->bthv", o, w_uv)
     return out.reshape(b, t, cfg.num_heads * cfg.value_dim), pool
 
@@ -645,27 +652,31 @@ def attn_apply(cfg: LlamaConfig, layer: PyTree, x, cos, sin, attention=None,
     if cfg.latent:
         with jax.named_scope("layer/attn"):
             y = block_norm(cfg, x, layer["attn_norm"])
-            out = qmm(_latent_attention(cfg, layer, y, cos, sin),
-                      layer["o_w"], x.dtype)
+            attn = _latent_attention(cfg, layer, y, cos, sin)
+            with jax.named_scope("layer/attn/out"):
+                out = qmm(attn, layer["o_w"], x.dtype)
             return (y, out) if delta else x + out
     with jax.named_scope("layer/attn"):
         y = block_norm(cfg, x, layer["attn_norm"])
-        q, k = qk_normed(cfg, qmm(y, layer["q_w"]), qmm(y, layer["k_w"]),
-                         layer.__getitem__)
-        q = q.reshape(b, s, h, hd)
-        k = k.reshape(b, s, hkv, hd)
-        v = qmm(y, layer["v_w"]).reshape(b, s, hkv, hd)
-        q = q.transpose(0, 2, 1, 3)
-        if rotated:
-            q = apply_rope(q, cos, sin, cfg.rope_interleaved)
-        k = k.transpose(0, 2, 1, 3)
-        if rotated:
-            k = apply_rope(k, cos, sin, cfg.rope_interleaved)
-        v = v.transpose(0, 2, 1, 3)
-        attn = _attention(cfg, q, k, v, window) if attention is None \
-            else attention(y, q, k, v)
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
-        out = qmm(attn, layer["o_w"], x.dtype)
+        with jax.named_scope("layer/attn/qkv"):
+            q, k = qk_normed(cfg, qmm(y, layer["q_w"]), qmm(y, layer["k_w"]),
+                             layer.__getitem__)
+            q = q.reshape(b, s, h, hd)
+            k = k.reshape(b, s, hkv, hd)
+            v = qmm(y, layer["v_w"]).reshape(b, s, hkv, hd)
+            q = q.transpose(0, 2, 1, 3)
+            if rotated:
+                q = apply_rope(q, cos, sin, cfg.rope_interleaved)
+            k = k.transpose(0, 2, 1, 3)
+            if rotated:
+                k = apply_rope(k, cos, sin, cfg.rope_interleaved)
+            v = v.transpose(0, 2, 1, 3)
+        with jax.named_scope("layer/attn/core"):
+            attn = _attention(cfg, q, k, v, window) if attention is None \
+                else attention(y, q, k, v)
+        with jax.named_scope("layer/attn/out"):
+            attn = attn.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
+            out = qmm(attn, layer["o_w"], x.dtype)
         return (y, out) if delta else x + out
 
 
@@ -691,7 +702,8 @@ def forward(cfg: LlamaConfig, params: PyTree, input_ids, rng=None,
     del rng, train  # no dropout in llama pretraining config
     params = dequant_resident(params)
     b, s = input_ids.shape
-    x = params["embed"][input_ids].astype(params["embed"].dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][input_ids].astype(params["embed"].dtype)
     cos, sin = rope_angles(cfg, s)
 
     def step(x, layer):
@@ -759,27 +771,29 @@ def _attend_cached(cfg: LlamaConfig, x, get, mm, ck, cv, pos, block_tables,
         # folds it into the dots and transposes their WEIGHTS instead, every
         # call (Command A+: 134 MB of q_w a layer; test_chip_lowering.py
         # ..._write_no_weight_sized_value)
-        q, k, v = jax.lax.optimization_barrier(
-            (mm(y, "q_w", None), mm(y, "k_w", None), mm(y, "v_w", None)))
-        q, k = qk_normed(cfg, q, k, get)
-        q = q.reshape(b, t, h, hd)
-        k = k.reshape(b, t, hkv, hd)
-        v = v.reshape(b, t, hkv, hd)
-        q = q.transpose(0, 2, 1, 3)
-        if rotated:
-            q = _rope_cached(cfg, q, pos)
-        k = k.transpose(0, 2, 1, 3)
-        if rotated:
-            k = _rope_cached(cfg, k, pos)
-        v = v.transpose(0, 2, 1, 3)
+        with jax.named_scope("layer/attn/qkv"):
+            q, k, v = jax.lax.optimization_barrier(
+                (mm(y, "q_w", None), mm(y, "k_w", None), mm(y, "v_w", None)))
+            q, k = qk_normed(cfg, q, k, get)
+            q = q.reshape(b, t, h, hd)
+            k = k.reshape(b, t, hkv, hd)
+            v = v.reshape(b, t, hkv, hd)
+            q = q.transpose(0, 2, 1, 3)
+            if rotated:
+                q = _rope_cached(cfg, q, pos)
+            k = k.transpose(0, 2, 1, 3)
+            if rotated:
+                k = _rope_cached(cfg, k, pos)
+            v = v.transpose(0, 2, 1, 3)
         if attend is not None:
             attn, ck, cv, extra = attend(y, q, k, v, ck, cv, extra)
         else:
             attn, ck, cv = cached_attention(
                 q, k, v, ck, cv, pos, block_tables, chunk_valid, layer,
                 window=window)
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, t, h * hd)
-        return x + mm(attn, "o_w", x.dtype), y, ck, cv, extra
+        with jax.named_scope("layer/attn/out"):
+            attn = attn.transpose(0, 2, 1, 3).reshape(b, t, h * hd)
+            return x + mm(attn, "o_w", x.dtype), y, ck, cv, extra
 
 
 def _block_cached_body(cfg: LlamaConfig, x, get, mm, ck, cv, pos,
@@ -807,7 +821,8 @@ def _block_cached_body(cfg: LlamaConfig, x, get, mm, ck, cv, pos,
             y = block_norm(cfg, x, get("attn_norm"))
             attn, ck = _latent_cached(cfg, y, get, mm, ck, pos, block_tables,
                                       chunk_valid, layer)
-            x = x + mm(attn, "o_w", x.dtype)
+            with jax.named_scope("layer/attn/out"):
+                x = x + mm(attn, "o_w", x.dtype)
     else:
         x, y, ck, cv, extra = _attend_cached(
             cfg, x, get, mm, ck, cv, pos, block_tables, chunk_valid, layer,
@@ -871,7 +886,9 @@ def forward_cached(cfg: LlamaConfig, params, input_ids, cache, pos,
     step_pos, chunk_valid, gather, paged = cached.window(
         input_ids, pos, lengths, block_tables)
     # sequence-parallel prefill hook (no-op outside an sp context)
-    x = shard_seq(params["embed"][input_ids].astype(params["embed"].dtype))
+    with jax.named_scope("embed"):
+        x = shard_seq(params["embed"][input_ids].astype(
+            params["embed"].dtype))
     # a latent model's pool is ONE leaf; it rides where K does and nothing
     # rides where V does
     first = "latent" if cfg.latent else "k"
@@ -942,12 +959,14 @@ def loss_from_batch(cfg: LlamaConfig, params, batch, rng=None,
         labels = input_ids[:, 1:]
         input_ids = input_ids[:, :-1]
     logits = forward(cfg, params, input_ids, rng=rng, train=train)
-    logits = logits.astype(jnp.float32)
-    valid = labels >= 0
-    safe = jnp.where(valid, labels, 0)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
-    return jnp.where(valid, nll, 0.0).sum() / jnp.maximum(valid.sum(), 1)
+    with jax.named_scope("loss"):
+        logits = logits.astype(jnp.float32)
+        valid = labels >= 0
+        safe = jnp.where(valid, labels, 0)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
+        return jnp.where(valid, nll, 0.0).sum() \
+            / jnp.maximum(valid.sum(), 1)
 
 
 def tp_rules(cfg: LlamaConfig, abstract_params: PyTree) -> PyTree:

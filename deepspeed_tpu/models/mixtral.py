@@ -358,14 +358,15 @@ def _indexer(cfg: MixtralConfig, get, mm, y, rope):
     weights float32 ``[B, T, HI]``."""
     b, t, _ = y.shape
     hi, di = cfg.index_heads, cfg.index_head_dim
-    # (the barrier: the head split moves the product, not ``idx_q_w`` —
-    # ``llama._attend_cached``)
-    qi = jax.lax.optimization_barrier(mm(y, "idx_q_w", None))
-    qi = qi.reshape(b, t, hi, di).transpose(0, 2, 1, 3)
-    ki = sparse_attention.layer_norm(mm(y, "idx_k_w", None),
-                                     get("idx_k_norm"))[:, None]
-    wi = mm(y, "idx_w_w", None).astype(jnp.float32)
-    return rope(qi), rope(ki), wi
+    with jax.named_scope("layer/attn/qkv"):
+        # (the barrier: the head split moves the product, not ``idx_q_w`` —
+        # ``llama._attend_cached``)
+        qi = jax.lax.optimization_barrier(mm(y, "idx_q_w", None))
+        qi = qi.reshape(b, t, hi, di).transpose(0, 2, 1, 3)
+        ki = sparse_attention.layer_norm(mm(y, "idx_k_w", None),
+                                         get("idx_k_norm"))[:, None]
+        wi = mm(y, "idx_w_w", None).astype(jnp.float32)
+        return rope(qi), rope(ki), wi
 
 
 def _moe_block(cfg: MixtralConfig, layer: PyTree, x, cos, sin,
@@ -420,7 +421,8 @@ def _trunk(cfg: MixtralConfig, params: PyTree, input_ids, train: bool,
     training, with ``choices`` (inference) every layer's chosen experts
     ``[L, B, S, top_k]``, else ``None``."""
     b, s = input_ids.shape
-    x = params["embed"][input_ids].astype(params["embed"].dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][input_ids].astype(params["embed"].dtype)
     cos, sin = L.rope_angles(cfg, s)
 
     counted = train and cfg.dropless
@@ -476,7 +478,8 @@ def _trunk(cfg: MixtralConfig, params: PyTree, input_ids, train: bool,
     init = (x, jnp.zeros((), jnp.float32))
     if counted:
         init += (jnp.zeros(len(record_columns(cfg)), jnp.int32),)
-    (x, aux_sum, *rec_sum), chosen = jax.lax.scan(body, init, blocks)
+    with jax.named_scope("layer"):
+        (x, aux_sum, *rec_sum), chosen = jax.lax.scan(body, init, blocks)
     x = L.block_norm(cfg, x, params["final_norm"])
     if choices:
         # [periods, layers a period, B, S, k] -> [L, B, S, k]
@@ -527,7 +530,8 @@ def loss_from_batch(cfg: MixtralConfig, params, batch, rng=None,
         x, aux, rec = _trunk(cfg, params, input_ids, train)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         n = labels.size
-        with jax.named_scope("head"):
+        with jax.named_scope("loss"):
+            # (the products inside it are ``head``'s: ops/chunked_ce.py)
             lm_loss = chunked_ce(head.astype(x.dtype),
                                  x.reshape(n, x.shape[-1]),
                                  labels.reshape(n),
@@ -536,13 +540,15 @@ def loss_from_batch(cfg: MixtralConfig, params, batch, rng=None,
         record.update(lm_loss=lm_loss, router_aux=aux)
         return lm_loss + cfg.router_aux_loss_coef * aux, record
     logits, aux = forward_with_aux(cfg, params, input_ids, train=train)
-    logits = logits.astype(jnp.float32)
-    valid = labels >= 0
-    safe = jnp.where(valid, labels, 0)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
-    lm_loss = jnp.where(valid, nll, 0.0).sum() / jnp.maximum(valid.sum(), 1)
-    return lm_loss + cfg.router_aux_loss_coef * aux
+    with jax.named_scope("loss"):
+        logits = logits.astype(jnp.float32)
+        valid = labels >= 0
+        safe = jnp.where(valid, labels, 0)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
+        lm_loss = jnp.where(valid, nll, 0.0).sum() \
+            / jnp.maximum(valid.sum(), 1)
+        return lm_loss + cfg.router_aux_loss_coef * aux
 
 
 def _moe_ffn(cfg: MixtralConfig, layer, y):

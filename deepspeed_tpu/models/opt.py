@@ -216,23 +216,29 @@ def _block(cfg: OPTConfig, x, layer):
 
     with jax.named_scope("layer/attn"):
         res = x
-        y = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"]) \
-            if cfg.do_layer_norm_before else x
-        qkv = qmm(y, layer["qkv_w"]) + layer["qkv_b"].astype(y.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-        k = k.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-        v = v.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-        attn = _attention(cfg, q, k, v)
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, s, d)
-        x = res + qmm(attn, layer["o_w"], x.dtype) + \
-            layer["o_b"].astype(x.dtype)
+        with jax.named_scope("layer/norm"):
+            y = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"]) \
+                if cfg.do_layer_norm_before else x
+        with jax.named_scope("layer/attn/qkv"):
+            qkv = qmm(y, layer["qkv_w"]) + layer["qkv_b"].astype(y.dtype)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
+            k = k.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
+            v = v.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
+        with jax.named_scope("layer/attn/core"):
+            attn = _attention(cfg, q, k, v)
+        with jax.named_scope("layer/attn/out"):
+            attn = attn.transpose(0, 2, 1, 3).reshape(b, s, d)
+            x = res + qmm(attn, layer["o_w"], x.dtype) + \
+                layer["o_b"].astype(x.dtype)
         if not cfg.do_layer_norm_before:
-            x = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"])
+            with jax.named_scope("layer/norm"):
+                x = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"])
     with jax.named_scope("layer/mlp"):
         res = x
-        y = _layer_norm(x, layer["ln2_scale"], layer["ln2_bias"]) \
-            if cfg.do_layer_norm_before else x
+        with jax.named_scope("layer/norm"):
+            y = _layer_norm(x, layer["ln2_scale"], layer["ln2_bias"]) \
+                if cfg.do_layer_norm_before else x
         hid = jax.nn.relu(qmm(y, layer["fc_w"]) +
                           layer["fc_b"].astype(y.dtype))
         x = res + qmm(hid, layer["proj_w"], x.dtype) + \
@@ -273,7 +279,8 @@ def _embed(cfg: OPTConfig, params, input_ids, pos0: int = 0):
 def _head(cfg: OPTConfig, params, x):
     """Final LN (pre-LN models) + tied lm head; x: [..., D] -> logits."""
     if cfg.do_layer_norm_before:
-        x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
+        with jax.named_scope("layer/norm"):
+            x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
     if cfg.has_proj:
         x = x @ params["project_out"].astype(x.dtype)
     return x @ params["embed_tokens"].T.astype(x.dtype)
@@ -322,23 +329,28 @@ def _block_cached_body(cfg: OPTConfig, x, get, mm, ck, cv, pos,
 
     with jax.named_scope("layer/attn"):
         res = x
-        y = _layer_norm(x, get("ln1_scale"), get("ln1_bias")) \
-            if cfg.do_layer_norm_before else x
-        qkv = mm(y, "qkv_w", None) + get("qkv_b").astype(y.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
-        k = k.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
-        v = v.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
+        with jax.named_scope("layer/norm"):
+            y = _layer_norm(x, get("ln1_scale"), get("ln1_bias")) \
+                if cfg.do_layer_norm_before else x
+        with jax.named_scope("layer/attn/qkv"):
+            qkv = mm(y, "qkv_w", None) + get("qkv_b").astype(y.dtype)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
+            k = k.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
+            v = v.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
         attn, ck, cv = cached_attention(q, k, v, ck, cv, pos, block_tables,
                                         chunk_valid, layer)
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, t, d)
-        x = res + mm(attn, "o_w", x.dtype) + get("o_b").astype(x.dtype)
+        with jax.named_scope("layer/attn/out"):
+            attn = attn.transpose(0, 2, 1, 3).reshape(b, t, d)
+            x = res + mm(attn, "o_w", x.dtype) + get("o_b").astype(x.dtype)
         if not cfg.do_layer_norm_before:
-            x = _layer_norm(x, get("ln1_scale"), get("ln1_bias"))
+            with jax.named_scope("layer/norm"):
+                x = _layer_norm(x, get("ln1_scale"), get("ln1_bias"))
     with jax.named_scope("layer/mlp"):
         res = x
-        y = _layer_norm(x, get("ln2_scale"), get("ln2_bias")) \
-            if cfg.do_layer_norm_before else x
+        with jax.named_scope("layer/norm"):
+            y = _layer_norm(x, get("ln2_scale"), get("ln2_bias")) \
+                if cfg.do_layer_norm_before else x
         hid = jax.nn.relu(mm(y, "fc_w", None) + get("fc_b").astype(y.dtype))
         x = res + mm(hid, "proj_w", x.dtype) + get("proj_b").astype(x.dtype)
         if not cfg.do_layer_norm_before:
@@ -371,6 +383,7 @@ def forward_cached(cfg: OPTConfig, params, input_ids, cache, pos,
     return _head(cfg, params, x), {"k": ks, "v": vs}
 
 
+@jax.named_scope("loss")
 def _ce_from_logits(logits, targets):
     """``lse - picked_logit`` cross entropy: never materializes a [T, V] f32
     log-softmax tensor (same memory reasoning as gpt2._head_loss)."""

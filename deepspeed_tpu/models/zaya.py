@@ -240,6 +240,7 @@ def _rotate(cfg: ZayaConfig, x, positions):
     return jnp.concatenate([turned, x[..., rd:]], axis=-1)
 
 
+@jax.named_scope("layer/attn/qkv")
 def _cca(cfg: ZayaConfig, layer, h, tails, positions):
     """Compressed convolutional attention's operands from the normed input
     ``h [B, T, d]`` and the row's ``tails = (c[-1], c1[-1] [B, channels],
@@ -260,15 +261,16 @@ def _cca(cfg: ZayaConfig, layer, h, tails, positions):
          qmm(h, layer["vb_w"])))
     c = jnp.concatenate([qt, kt], axis=-1)
     c_prev, c1_prev, vb_prev = tails
-    w0 = layer["conv0_w"].astype(f32)
-    c1 = (w0[0] * c.astype(f32)
-          + w0[1] * _behind(c, c_prev).astype(f32)).astype(h.dtype)
-    w1 = layer["conv1_w"].astype(h.dtype)
-    split = lambda a: a.reshape(b, t, heads, hd)
-    c2 = jnp.einsum("bthi,hio->btho", split(c1), w1[0],
-                    preferred_element_type=f32) \
-        + jnp.einsum("bthi,hio->btho", split(_behind(c1, c1_prev)), w1[1],
-                     preferred_element_type=f32)
+    with jax.named_scope("layer/state/conv"):
+        w0 = layer["conv0_w"].astype(f32)
+        c1 = (w0[0] * c.astype(f32)
+              + w0[1] * _behind(c, c_prev).astype(f32)).astype(h.dtype)
+        w1 = layer["conv1_w"].astype(h.dtype)
+        split = lambda a: a.reshape(b, t, heads, hd)
+        c2 = jnp.einsum("bthi,hio->btho", split(c1), w1[0],
+                        preferred_element_type=f32) \
+            + jnp.einsum("bthi,hio->btho", split(_behind(c1, c1_prev)),
+                         w1[1], preferred_element_type=f32)
     q4 = qt.astype(f32).reshape(b, t, g, rep, hd)
     k3 = kt.astype(f32).reshape(b, t, g, hd)
     mean_q = (0.5 * (q4 + k3[:, :, :, None])).reshape(b, t, hq, hd)
@@ -307,8 +309,9 @@ def _experts(cfg: ZayaConfig, layer, x, r, live, stacks=None,
     in place at ``layer["layer_index"]``; without them ``layer`` holds its
     own ``[E, ..]`` slices."""
     with jax.named_scope("layer/mlp"):
-        y = rms_norm(x, layer["moe_norm"], cfg.rms_eps)
-        with jax.named_scope("layer/moe/router"):
+        with jax.named_scope("layer/norm"):
+            y = rms_norm(x, layer["moe_norm"], cfg.rms_eps)
+        with jax.named_scope("layer/moe/route"):
             r, scores = _route(cfg, layer, y, r)
             chosen = jnp.argmax(scores, axis=-1).astype(jnp.int32)[..., None]
             weight = jnp.take_along_axis(scores, chosen, axis=-1)
@@ -323,10 +326,14 @@ def _experts(cfg: ZayaConfig, layer, x, r, live, stacks=None,
 
 
 def _head(cfg: ZayaConfig, params, x):
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    return jnp.einsum("...d,vd->...v", x, params["embed"].astype(x.dtype))
+    with jax.named_scope("layer/norm"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    with jax.named_scope("head"):
+        return jnp.einsum("...d,vd->...v", x,
+                          params["embed"].astype(x.dtype))
 
 
+@jax.named_scope("layer/attn/out")
 def _merge_heads(cfg: ZayaConfig, layer, attn, dtype):
     b, _, t, _ = attn.shape
     return qmm(attn.transpose(0, 2, 1, 3).reshape(
@@ -373,32 +380,37 @@ def forward_cached(cfg: ZayaConfig, params, input_ids, cache, pos,
         x, r = xr
         (ck, conv), (cv, shift) = ck, cv
         with jax.named_scope("layer/attn"):
-            if slot is None:
-                held = conv[index, :, 0], shift[index, :, 0, 0]
-            else:
-                # (a pad row's slot is out of range: read clamped, written
-                # nowhere)
-                rows = jnp.clip(slot, 0, conv.shape[1] - 1)
-                held = conv[index, rows, 0], shift[index, rows, 0, 0]
-            # a window at base 0 starts from nothing
-            tails = tuple(jnp.where(fresh, 0, a) for a in (
-                held[0][:, 0], held[0][:, 1], held[1]))
-            h = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
+            with jax.named_scope("layer/attn/kv_write"):
+                if slot is None:
+                    held = conv[index, :, 0], shift[index, :, 0, 0]
+                else:
+                    # (a pad row's slot is out of range: read clamped,
+                    # written nowhere)
+                    rows = jnp.clip(slot, 0, conv.shape[1] - 1)
+                    held = conv[index, rows, 0], shift[index, rows, 0, 0]
+                # a window at base 0 starts from nothing
+                tails = tuple(jnp.where(fresh, 0, a) for a in (
+                    held[0][:, 0], held[0][:, 1], held[1]))
+            with jax.named_scope("layer/norm"):
+                h = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
             q, k, v, seqs = _cca(cfg, layer, h, tails, positions)
-            c, c1, vb = (jnp.take_along_axis(s, last, axis=1)[:, 0]
-                         for s in seqs)
-            new_conv = jnp.where(moved[:, :, None], jnp.stack([c, c1], 1),
-                                 held[0]).astype(conv.dtype)[:, None]
-            new_shift = jnp.where(moved, vb, held[1]).astype(
-                shift.dtype)[:, None, None]
-            if slot is None:
-                conv = jax.lax.dynamic_update_index_in_dim(
-                    conv, new_conv, index, 0)
-                shift = jax.lax.dynamic_update_index_in_dim(
-                    shift, new_shift, index, 0)
-            else:
-                conv = conv.at[index, slot].set(new_conv, mode="drop")
-                shift = shift.at[index, slot].set(new_shift, mode="drop")
+            with jax.named_scope("layer/attn/kv_write"):
+                c, c1, vb = (jnp.take_along_axis(s, last, axis=1)[:, 0]
+                             for s in seqs)
+                new_conv = jnp.where(
+                    moved[:, :, None], jnp.stack([c, c1], 1),
+                    held[0]).astype(conv.dtype)[:, None]
+                new_shift = jnp.where(moved, vb, held[1]).astype(
+                    shift.dtype)[:, None, None]
+                if slot is None:
+                    conv = jax.lax.dynamic_update_index_in_dim(
+                        conv, new_conv, index, 0)
+                    shift = jax.lax.dynamic_update_index_in_dim(
+                        shift, new_shift, index, 0)
+                else:
+                    conv = conv.at[index, slot].set(new_conv, mode="drop")
+                    shift = shift.at[index, slot].set(new_shift,
+                                                      mode="drop")
             attn, ck, cv = cached.cached_attention(
                 q, k, v, ck, cv, w.step_pos, table, w.chunk_valid, index)
             x = _merge(layer, "attn", x,
@@ -406,7 +418,8 @@ def forward_cached(cfg: ZayaConfig, params, input_ids, cache, pos,
         x, r, *aux = _experts(cfg, layer, x, r, live, stacks, choices)
         return (x, r), (ck, conv), (cv, shift), tuple(aux)
 
-    x = params["embed"][input_ids]
+    with jax.named_scope("embed"):
+        x = params["embed"][input_ids]
     r = jnp.zeros((b, t, cfg.router_size), jnp.float32)
     (x, _), (ck, conv), (cv, shift), aux = cached.scan_layers_cached(
         step, (x, r), blocks, (cache["k"], cache["conv"]),

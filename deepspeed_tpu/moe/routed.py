@@ -195,6 +195,8 @@ def routed_ffn(y, gate_w, w1, w3, w2, k: int, renormalize: bool,
         order = jnp.argsort(flat_e, stable=True)
         hits = flat_e[:, None] == jnp.arange(e, dtype=jnp.int32)[None, :]
         group_sizes = hits.sum(0, dtype=jnp.int32)               # [E]
+
+    with jax.named_scope("layer/moe/gather"):
         xs = x[order // k]                                       # [T*k, D]
 
     with jax.named_scope("layer/moe/experts"):

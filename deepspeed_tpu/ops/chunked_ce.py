@@ -47,7 +47,8 @@ def _chunked_ce_fwd(w, x2d, targets, n_chunks):
     denom = jnp.maximum(valid_all.sum(), 1).astype(jnp.float32)
 
     def chunk(xc, tc):
-        logits = (xc @ w).astype(jnp.float32)            # [c, V]
+        with jax.named_scope("head"):
+            logits = (xc @ w).astype(jnp.float32)        # [c, V]
         valid = tc >= 0
         safe = jnp.where(valid, tc, 0)
         lse = jax.nn.logsumexp(logits, axis=-1)           # [c]
@@ -63,10 +64,11 @@ def _chunked_ce_fwd(w, x2d, targets, n_chunks):
         gc = g.astype(w.dtype)
         # MXU inputs stay in param dtype; outputs come out f32 so unscaled
         # fp16 grads don't flush to subnormals before the bwd ct multiply
-        dxi = jax.lax.dot_general(gc, w, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        dwi = jax.lax.dot_general(xc, gc, (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
+        with jax.named_scope("head"):
+            dxi = jax.lax.dot_general(gc, w, (((1,), (1,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+            dwi = jax.lax.dot_general(xc, gc, (((0,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
         return loss, dxi, dwi                             # loss, [c,D], [D,V]
 
     # unrolled chunk loop (a scan's dw carry would copy [D, V] f32 per
@@ -75,12 +77,13 @@ def _chunked_ce_fwd(w, x2d, targets, n_chunks):
     loss = jnp.zeros((), jnp.float32)
     dw = jnp.zeros((d, v), jnp.float32)
     dxs = []
-    for i in range(n_chunks):
-        li, dxi, dwi = chunk(xs[i], ts[i])
-        loss += li
-        dw += dwi
-        dxs.append(dxi)
-    dx = jnp.concatenate(dxs, axis=0) if n_chunks > 1 else dxs[0]
+    with jax.named_scope("loss"):
+        for i in range(n_chunks):
+            li, dxi, dwi = chunk(xs[i], ts[i])
+            loss += li
+            dw += dwi
+            dxs.append(dxi)
+        dx = jnp.concatenate(dxs, axis=0) if n_chunks > 1 else dxs[0]
     # Residuals stay f32: under fp16 loss scaling the upstream cotangent
     # (the scale) is applied in _chunked_ce_bwd, and casting the UNSCALED
     # grads to fp16 here would underflow exactly the small values the
@@ -94,9 +97,12 @@ def _chunked_ce_fwd(w, x2d, targets, n_chunks):
 
 def _chunked_ce_bwd(n_chunks, res, ct):
     w_proto, x_proto, dw, dx = res
-    ct = ct.astype(jnp.float32)
-    return ((ct * dw).astype(w_proto.dtype), (ct * dx).astype(x_proto.dtype),
-            None)
+    # (a backward rule is a function of its own: the forward's scope does
+    # not reach it)
+    with jax.named_scope("loss"):
+        ct = ct.astype(jnp.float32)
+        return ((ct * dw).astype(w_proto.dtype),
+                (ct * dx).astype(x_proto.dtype), None)
 
 
 chunked_ce.defvjp(_chunked_ce_fwd, _chunked_ce_bwd)

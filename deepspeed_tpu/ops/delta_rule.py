@@ -25,7 +25,7 @@ correction is a contraction.  Three forms of the one recurrence:
   (``B`` is ``A`` with ``q_i`` for ``k_i`` and the diagonal kept).  What does
   not need the state — ``G``, ``A``, ``B``, the solve, ``W``, ``U~`` — is
   plain XLA over all chunks at once (:func:`_intra`, under the scope
-  ``kda_chunk_intra``); what carries it from
+  ``layer/state/chunk_intra``); what carries it from
   chunk to chunk — four matmuls against ``S`` a chunk, most of the FLOPs —
   is the Pallas kernel ``kda_chunk_state`` on a TPU and :func:`_state_plain`
   elsewhere.  ``exp(G_i - G_j)`` is never split into ``exp(G_i) exp(-G_j)``
@@ -230,6 +230,7 @@ def _state_pallas(w, ut, qd, kd, bm, decay, state, interpret=None):
     return o, state
 
 
+@jax.named_scope("layer/state/chunk")
 def chunked(q, k, v, g, beta, state, *, kernel: Optional[bool] = None,
             interpret: Optional[bool] = None):
     """:func:`recurrent`'s contract through the chunked form; ``T`` a whole
@@ -242,7 +243,7 @@ def chunked(q, k, v, g, beta, state, *, kernel: Optional[bool] = None,
     if t % chunk or (chunk > SUB and chunk % SUB):
         raise ValueError(f"{t} tokens are not whole chunks of {chunk} in "
                          f"sub-blocks of {SUB}")
-    with jax.named_scope("kda_chunk_intra"):
+    with jax.named_scope("layer/state/chunk_intra"):
         parts = _intra(q, k, v, g, beta, chunk)
     if on_tpu() if kernel is None else kernel:
         da._took("kda_chunk_state")
@@ -306,6 +307,7 @@ def _step_pallas(cols, bv, leaf, layer, interpret=None):
     return o, leaf
 
 
+@jax.named_scope("layer/state/step")
 def step(q, k, v, g, beta, leaf, layer, *, kernel: Optional[bool] = None,
          interpret: Optional[bool] = None):
     """One token a row against the WHOLE state leaf ``[L, rows, H, dk, dv]``

@@ -653,6 +653,7 @@ def _paged_cache_update(ck, cv, k, v, pos, block_tables, valid, layer,
     return ck, cv
 
 
+@jax.named_scope("layer/attn/kv_write")
 def paged_window_update(leaf, win, pos, block_tables, valid=None,
                         layer=None, ring: bool = False):
     """One more per-token pool leaf written as :func:`paged_cache_update`
@@ -673,6 +674,7 @@ def paged_window_update(leaf, win, pos, block_tables, valid=None,
     return _write_blocks(leaf, win, jnp.asarray(layer, jnp.int32), *where)
 
 
+@jax.named_scope("layer/attn/kv_write")
 def paged_cache_update(ck, cv, k, v, pos, block_tables, valid=None,
                        layer=None, ring: bool = False):
     """Scatter a window of new keys/values into the paged pool, in place.

@@ -364,6 +364,7 @@ def _chunked_pallas(q, k, v, lg, state, z, chunk: int, interpret=None):
     return jnp.moveaxis(y, 3, 1).reshape(bsz, t, hq, n), state, z
 
 
+@jax.named_scope("layer/state/chunk")
 def chunked(q, k, v, lg, state, z, *, kernel: Optional[bool] = None,
             interpret: Optional[bool] = None):
     """:func:`recurrent`'s contract through the chunked form, on the STORED
@@ -459,6 +460,7 @@ def _step_pallas(x, v, leaf, zleaf, layer, group: int, interpret=None):
     return num, den[:, :, 1:group + 1].sum(-1), leaf, zleaf
 
 
+@jax.named_scope("layer/state/step")
 def step(q, k, v, lg, leaf, zleaf, layer, *, kernel: Optional[bool] = None,
          interpret: Optional[bool] = None):
     """One token a row against the WHOLE stored leaves ``[L, rows, H, n / 2

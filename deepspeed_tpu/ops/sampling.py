@@ -81,6 +81,7 @@ SALT_RESIDUAL = 3
 SALT_DRAFT = 4
 
 
+@jax.named_scope("sample/draw")
 def slot_keys(seeds, counts, salt):
     """``[rows]`` PRNG keys: ``fold_in(fold_in(PRNGKey(seed), salt),
     count)`` per row — the whole counter-based scheme in one place."""
@@ -312,37 +313,47 @@ def filtered_logprobs(logits, temps, top_k, top_p, masks=None):
     of ``top_p`` may fall on the other side.  Ties, the
     boundary-crossing token, ``top_p == 1`` and the one-hot greedy rows
     are exact."""
-    logits = logits.astype(jnp.float32)
-    rows, vocab = logits.shape
-    if masks is not None:
-        ok = jnp.any(masks, axis=-1, keepdims=True)
-        logits = jnp.where(jnp.where(ok, masks, True), logits, -jnp.inf)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    temps = jnp.asarray(temps, jnp.float32)[:, None]
-    scaled = logits / jnp.maximum(temps, 1e-6)
-    k = jnp.asarray(top_k, jnp.int32)[:, None]
-    p = jnp.asarray(top_p, jnp.float32)[:, None]
-    k_on = (temps > 0) & (k > 0) & (k < vocab)
-    p_on = (temps > 0) & (p < 1)
-    kth_largest, nucleus_threshold = _SEARCHES[thresholds(vocab)]
-    # a row that does not ask searches for its minimum: everything stays
-    kth = jax.lax.cond(
-        jnp.any(k_on),
-        lambda: kth_largest(scaled, jnp.where(k_on, k, vocab)),
-        lambda: jnp.full((rows, 1), -jnp.inf))
-    keep = scaled >= kth
-    probs = jax.nn.softmax(jnp.where(keep, scaled, -jnp.inf), axis=-1)
-    thr = jax.lax.cond(jnp.any(p_on),
-                       lambda: nucleus_threshold(probs, p),
-                       lambda: jnp.zeros((rows, 1)))
-    keep = keep & (probs >= jnp.where(p_on, thr, 0.0))
-    logprobs = jax.nn.log_softmax(jnp.where(keep, scaled, -jnp.inf),
-                                  axis=-1)
-    onehot = jnp.where(
-        jnp.arange(vocab)[None, :] == greedy[:, None], 0.0, -jnp.inf)
-    return greedy, jnp.where(temps > 0, logprobs, onehot)
+    # every pass over the ``[rows, vocab]`` logits under its own scope
+    # (telemetry/scopes.py): every caller's program gets them
+    with jax.named_scope("sample/filter"):
+        logits = logits.astype(jnp.float32)
+        rows, vocab = logits.shape
+        if masks is not None:
+            ok = jnp.any(masks, axis=-1, keepdims=True)
+            logits = jnp.where(jnp.where(ok, masks, True), logits, -jnp.inf)
+    with jax.named_scope("sample/argmax"):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("sample/filter"):
+        temps = jnp.asarray(temps, jnp.float32)[:, None]
+        scaled = logits / jnp.maximum(temps, 1e-6)
+        k = jnp.asarray(top_k, jnp.int32)[:, None]
+        p = jnp.asarray(top_p, jnp.float32)[:, None]
+        k_on = (temps > 0) & (k > 0) & (k < vocab)
+        p_on = (temps > 0) & (p < 1)
+        kth_largest, nucleus_threshold = _SEARCHES[thresholds(vocab)]
+        # a row that does not ask searches for its minimum: everything stays
+        kth = jax.lax.cond(
+            jnp.any(k_on),
+            lambda: kth_largest(scaled, jnp.where(k_on, k, vocab)),
+            lambda: jnp.full((rows, 1), -jnp.inf))
+        keep = scaled >= kth
+    with jax.named_scope("sample/softmax"):
+        probs = jax.nn.softmax(jnp.where(keep, scaled, -jnp.inf), axis=-1)
+    with jax.named_scope("sample/filter"):
+        thr = jax.lax.cond(jnp.any(p_on),
+                           lambda: nucleus_threshold(probs, p),
+                           lambda: jnp.zeros((rows, 1)))
+        keep = keep & (probs >= jnp.where(p_on, thr, 0.0))
+    with jax.named_scope("sample/softmax"):
+        logprobs = jax.nn.log_softmax(jnp.where(keep, scaled, -jnp.inf),
+                                      axis=-1)
+    with jax.named_scope("sample/filter"):
+        onehot = jnp.where(
+            jnp.arange(vocab)[None, :] == greedy[:, None], 0.0, -jnp.inf)
+        return greedy, jnp.where(temps > 0, logprobs, onehot)
 
 
+@jax.named_scope("sample/draw")
 def sample_tokens(logprobs, keys):
     """One categorical draw per row (``[rows, vocab]`` log-probs +
     ``[rows]`` keys -> ``[rows]`` i32).  One-hot rows (greedy /
@@ -352,6 +363,7 @@ def sample_tokens(logprobs, keys):
         .astype(jnp.int32)
 
 
+@jax.named_scope("sample/draw")
 def accept_uniforms(keys):
     """One ``U[0, 1)`` per key (any leading shape).  ``u < p(token)``
     against a one-hot row is exact: ``p`` is exactly 1.0 or 0.0, so the
@@ -369,6 +381,7 @@ def token_probs(logprobs, tokens):
     return jnp.exp(lp)
 
 
+@jax.named_scope("sample/filter")
 def residual_logits(logprobs, tokens):
     """Rejection-sampler residual per row: the filtered distribution
     with the rejected ``token`` removed (categorical renormalizes, so

@@ -149,6 +149,7 @@ def chosen(scores, theta, s_last, last):
         (scores > theta) | ((scores == theta) & (s <= s_last)))
 
 
+@jax.named_scope("layer/attn/core")
 def sparse_attention_uncached(q, k, v, qi, wi, ki, topk: int):
     """The whole sequence at once, no cache: ``q [B, H, S, D]``, ``k`` /
     ``v [B, HKV, S, D]``, the indexer's ``qi [B, HI, S, DI]``, ``wi [B, S,
@@ -238,6 +239,7 @@ def _queries(block_tables, q_pos, t: int, b: int, valid, bs: int):
     return bt, last, real, visible, blocks
 
 
+@jax.named_scope("layer/attn/core")
 def paged_sparse_attention(q, k_pool, v_pool, idx_pool, qi, wi, block_tables,
                            q_pos, *, topk: int, layer, valid=None,
                            sm_scale: Optional[float] = None,
@@ -286,14 +288,14 @@ def paged_sparse_attention(q, k_pool, v_pool, idx_pool, qi, wi, block_tables,
         else "gather+top_k+walk"
 
     def sparse():
-        with jax.named_scope("sparse_attn/score"):
+        with jax.named_scope("layer/attn/select/score"):
             scores = index_scores(qi, wi, idx_pool, bt, last, layer)
-        with jax.named_scope("sparse_attn/select"):
+        with jax.named_scope("layer/attn/select/select"):
             theta, s_last = select_threshold(scores, topk)
             keep = chosen(scores, theta, s_last, last)
             mine = keep & real[:, :, None]
             hit = jnp.any(mine.reshape(b, t, nbper, bs), axis=(1, 3))
-        with jax.named_scope("sparse_attn/read"):
+        with jax.named_scope("layer/attn/select/read"):
             if kernel:
                 out = decode_attention.paged_sparse_attention_pallas(
                     q, k_pool, v_pool, bt, scores, theta, s_last, last, hit,
@@ -350,6 +352,7 @@ def _masked_latent_walk(q, pool, block_tables, keep, last, layer, rank: int):
     return (acc / jnp.where(l == 0.0, 1.0, l)).astype(q.dtype)
 
 
+@jax.named_scope("layer/attn/core")
 def paged_sparse_latent_attention(q, pool, idx_pool, qi, wi, block_tables,
                                   q_pos, *, rank: int, topk: int, layer,
                                   valid=None, return_keep: bool = False):
@@ -417,13 +420,13 @@ def paged_sparse_latent_attention(q, pool, idx_pool, qi, wi, block_tables,
 
     def sparse():
         seen, mine = padded(last, 1, -1), padded(real, 1, False)
-        with jax.named_scope("sparse_attn/score"):
+        with jax.named_scope("layer/attn/select/score"):
             scores = index_scores(padded(qi, 2), padded(wi, 1), idx_pool, bt,
                                   seen, layer)
-        with jax.named_scope("sparse_attn/select"):
+        with jax.named_scope("layer/attn/select/select"):
             theta, s_last = select_threshold(scores, topk)
             keep = chosen(scores, theta, s_last, seen)
-        with jax.named_scope("sparse_attn/read"):
+        with jax.named_scope("layer/attn/select/read"):
             if kernel:
                 out, landed = \
                     decode_attention.paged_sparse_latent_attention_pallas(

@@ -261,6 +261,7 @@ def _chunked_pallas(x, dt, a, b, c, state, chunk: int, interpret=None):
     return y.reshape(bsz, t, h, p), state
 
 
+@jax.named_scope("layer/state/chunk")
 def chunked(x, dt, a, b, c, state, *, kernel: Optional[bool] = None,
             interpret: Optional[bool] = None):
     """:func:`recurrent`'s contract through the chunked form, on the PACKED
@@ -329,6 +330,7 @@ def _step_pallas(dr, bc, leaf, layer, interpret=None):
     return y[:, 0], leaf
 
 
+@jax.named_scope("layer/state/step")
 def step(x, dt, a, b, c, leaf, layer, *, kernel: Optional[bool] = None,
          interpret: Optional[bool] = None):
     """One token a row against the WHOLE packed state leaf ``[L, rows, H /

@@ -46,6 +46,7 @@ from ..parallel.topology import (DATA_AXES, SP_AXIS, MeshTopology,
                                  topology_from_config)
 from ..telemetry import MetricsRegistry
 from ..telemetry import trace as trace_mod
+from ..telemetry.programs import Programs
 from ..telemetry.metrics import process_registry
 from ..telemetry.trace import annotation
 from ..utils.logging import log_dist, logger
@@ -179,6 +180,10 @@ class DeepSpeedEngine:
             #: what collectives each compiled step has, by program
             #: (``_read_collectives``, at the program's first call)
             self.collectives: Dict[str, Dict[str, Dict[str, int]]] = {}
+            #: what finds the compiled steps again (telemetry/programs.py):
+            #: their scope tables, on demand
+            self.programs = Programs()
+            trace_mod.keep("programs", self.programs)
             self._configure_stage3_liveness()
             self._build_step_fns()
 
@@ -907,10 +912,12 @@ class DeepSpeedEngine:
         def micro_loss(params, micro, rng, scale):
             """-> (scaled loss, (loss, record)); ``record`` is what the
             model returned beside its loss, ``None`` for a scalar loss."""
-            p = _cast_floating(params, compute_dtype) if cast else params
+            with jax.named_scope("optim/cast"):
+                p = _cast_floating(params, compute_dtype) if cast else params
             out = loss_fn(p, micro, rng, True)
             loss, record = out if isinstance(out, tuple) else (out, None)
-            return (loss.astype(jnp.float32) * scale), (loss, record)
+            with jax.named_scope("loss"):
+                return (loss.astype(jnp.float32) * scale), (loss, record)
 
         return micro_loss
 
@@ -949,11 +956,13 @@ class DeepSpeedEngine:
         tx = self.tx
         next_scaler, make_metrics = self._scaler_bookkeeping()
 
+        @jax.named_scope("optim/update")
         def apply_update(state, grads, mean_loss):
             """grads: fp32, already averaged over the global batch & unscaled."""
             params, opt_state, scaler = (state["params"], state["opt_state"],
                                          state["scaler"])
-            grad_norm = optax.global_norm(grads)
+            with jax.named_scope("grad/merge"):
+                grad_norm = optax.global_norm(grads)
             overflow = has_overflow(grads) if fp16 else jnp.asarray(False)
 
             def do_update(_):
@@ -1073,8 +1082,17 @@ class DeepSpeedEngine:
         return trace_mod.FirstCall(
             fn, program, built,
             before=functools.partial(self._read_collectives, program),
-            gas=self.gradient_accumulation_steps(),
+            programs=self.programs, gas=self.gradient_accumulation_steps(),
             micro_batch=self.train_micro_batch_size_per_gpu())
+
+    def program_table(self, name: str) -> Dict[str, Any]:
+        """The scope table of the compiled step ``name`` (``train_step``,
+        ``train_multi``: a key of ``self.programs.records``, there from the
+        step's first call) — per instruction of its schedule and per scope
+        and pass: bytes, matmul flops, kernels, trips
+        (``telemetry/hlo_text.py scope_table``).  Built at the first demand
+        from the executable that is running: no trace, no compile."""
+        return self.programs.table(name)
 
     def _read_collectives(self, program: str, fn, *args) -> None:
         """``collectives[program]``: what collectives the compiled step has
@@ -1129,9 +1147,12 @@ class DeepSpeedEngine:
             del scaled_loss
             return loss, grads, record
 
+        @jax.named_scope("grad/merge")
         def accumulate(state, batch, base_rng):
             """Scan the GAS microbatches; returns (unscaled fp32 grads,
-            loss, the model's record summed over them or None)."""
+            loss, the model's record summed over them or None).  What the
+            model's own scopes do not name inside it — the accumulation,
+            the scan's stacked-leaf merge — is ``grad/merge``'s."""
             params, scaler = state["params"], state["scaler"]
             scale = scaler.cur_scale if fp16 else jnp.asarray(1.0, jnp.float32)
             step_rng = jax.random.fold_in(base_rng, state["step"])

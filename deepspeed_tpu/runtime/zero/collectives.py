@@ -19,54 +19,33 @@ The text is what ``jax.jit(f).lower(...).compile().as_text()`` returns,
 CPU compiler emitted (no chains: everything in a loop is plain).  The
 engine keeps the count of each compiled step as ``engine.collectives[name]``
 (``runtime/engine.py``); ``docs/zero.md`` shows how to read a step's text
-for a described chip from a machine without one.
+for a described chip from a machine without one.  The text is cut into
+computations and the loops' bodies are walked by the package's one reader of
+compiled text, ``telemetry/hlo_text.py``.
 """
 
 from __future__ import annotations
 
 import re
-from typing import Dict, List
+from typing import Dict
+
+from ...telemetry import hlo_text
 
 KINDS = ("all-gather", "reduce-scatter", "all-to-all", "all-reduce")
 FIELDS = ("total", "in_loop", "fused", "started", "plain")
 
-_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{\s*$")
 _INSTRUCTION = re.compile(
     r"^\s*(?:ROOT )?%?[\w.\-]+ = .*? (" + "|".join(KINDS) +
     r")(-start)?\(")
-_CALLED = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)")
-_BODY = re.compile(r"\bbody=%?([\w.\-]+)")
 _CHAIN = re.compile(r'chain_id="?(\d+)')
-
-
-def _computations(text: str) -> Dict[str, List[str]]:
-    found: Dict[str, List[str]] = {}
-    lines = None
-    for line in text.splitlines():
-        start = _COMPUTATION.match(line)
-        if start:
-            lines = found.setdefault(start.group(1), [])
-        elif line.startswith("}"):
-            lines = None
-        elif lines is not None:
-            lines.append(line)
-    return found
 
 
 def count(text: str) -> Dict[str, Dict[str, int]]:
     """-> ``{kind: {total, in_loop, fused, started, plain}}`` for the four
     ``KINDS``; ``in_loop = fused + started + plain``."""
-    comps = _computations(text)
-    called = {name: {c for line in lines for c in _CALLED.findall(line)}
-              for name, lines in comps.items()}
+    comps = hlo_text.computations(text)
     # every computation a while body reaches: its fusions, its nested calls
-    in_loop, stack = set(), [b for lines in comps.values()
-                             for line in lines for b in _BODY.findall(line)]
-    while stack:
-        name = stack.pop()
-        if name not in in_loop:
-            in_loop.add(name)
-            stack.extend(called.get(name, ()))
+    in_loop = hlo_text.reached_from_loops(comps)
     out = {kind: dict.fromkeys(FIELDS, 0) for kind in KINDS}
     chains = set()
     for name, lines in comps.items():

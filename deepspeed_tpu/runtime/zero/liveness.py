@@ -74,7 +74,8 @@ def scan_layers_grouped(step, carry, blocks, group_size: int = 1):
     if g <= 1 or num_layers % g:
         def body(c, layer):
             return step(c, layer), None
-        carry, _ = jax.lax.scan(body, carry, blocks)
+        with jax.named_scope("layer"):
+            carry, _ = jax.lax.scan(body, carry, blocks)
         return carry
 
     grouped = jax.tree_util.tree_map(
@@ -85,7 +86,8 @@ def scan_layers_grouped(step, carry, blocks, group_size: int = 1):
             c = step(c, jax.tree_util.tree_map(lambda p: p[i], grp))
         return c, None
 
-    carry, _ = jax.lax.scan(gbody, carry, grouped)
+    with jax.named_scope("layer"):
+        carry, _ = jax.lax.scan(gbody, carry, grouped)
     return carry
 
 
@@ -180,11 +182,13 @@ def scan_layers_prefetched(step, carry, blocks, group_size: int = 1,
     whole = jax.tree_util.tree_map(
         lambda p: p.size // num_layers <= _GATHER_WHOLE_BELOW, blocks)
 
+    @jax.named_scope("zero/gather")
     def gather_small(stack):
         return jax.tree_util.tree_map(
             lambda p, w, s: jax.lax.with_sharding_constraint(p, s) if w
             else p, stack, whole, shardings.gathered)
 
+    @jax.named_scope("zero/gather")
     def gather(stack, i):
         # the slice is pinned to the stack's own sharding first: left to
         # propagation, the gathered constraint reaches back through the
@@ -231,8 +235,9 @@ def scan_layers_prefetched(step, carry, blocks, group_size: int = 1,
             return (d_out, after, ahead), (d, rest, kept)
 
         stack = gather_small(stack)
-        (d, rest, _), res = jax.lax.scan(
-            body, (d, rest, gather(stack, 0)), jnp.arange(units))
+        with jax.named_scope("layer"):
+            (d, rest, _), res = jax.lax.scan(
+                body, (d, rest, gather(stack, 0)), jnp.arange(units))
         return (d, rest), res
 
     @jax.custom_vjp
@@ -256,18 +261,25 @@ def scan_layers_prefetched(step, carry, blocks, group_size: int = 1,
             _, pullback, _ = jax.vjp(
                 lambda d, w: diff_step(d, w, rest), d, layers, has_aux=True)
             d_cot, cots = _with_residuals(pullback, (d, layers), kept)(d_cot)
-            unit_cot = _constrain(jax.tree_util.tree_map(
-                lambda *q: jnp.stack(q), *cots), sharded)
-            grads = jax.tree_util.tree_map(
-                lambda buf, q: jax.lax.dynamic_update_slice_in_dim(
-                    buf, q.astype(buf.dtype), i * g, 0), grads, unit_cot)
+            with jax.named_scope("zero/reduce"):
+                unit_cot = _constrain(jax.tree_util.tree_map(
+                    lambda *q: jnp.stack(q), *cots), sharded)
+            with jax.named_scope("grad/merge"):
+                grads = jax.tree_util.tree_map(
+                    lambda buf, q: jax.lax.dynamic_update_slice_in_dim(
+                        buf, q.astype(buf.dtype), i * g, 0), grads, unit_cot)
             return (d_cot, ahead, grads), None
 
-        zeros = constrain(jax.tree_util.tree_map(jnp.zeros_like, saved[1]),
-                          shardings.sharded)
-        (d_cot, _, grads), _ = jax.lax.scan(
-            body, (list(d_cot), gather(stack, units - 1), zeros),
-            (jnp.arange(units), d_in, rest_in, kept), reverse=True)
+        # (a backward rule is a function of its own: the forward's scopes do
+        # not reach it — telemetry/scopes.py)
+        with jax.named_scope("grad/merge"):
+            zeros = constrain(jax.tree_util.tree_map(jnp.zeros_like,
+                                                     saved[1]),
+                              shardings.sharded)
+        with jax.named_scope("layer"):
+            (d_cot, _, grads), _ = jax.lax.scan(
+                body, (list(d_cot), gather(stack, units - 1), zeros),
+                (jnp.arange(units), d_in, rest_in, kept), reverse=True)
         return d_cot, None, grads
 
     run.defvjp(run_fwd, run_bwd)

@@ -16,6 +16,18 @@ Coordinated pieces (design notes in each module):
  - :mod:`~deepspeed_tpu.telemetry.idle_gaps` — from one profile, the
    device's idle time partitioned over the program's ``ds.*`` spans
    (``python -m deepspeed_tpu.telemetry.idle_gaps <profile_dir>``).
+ - :mod:`~deepspeed_tpu.telemetry.scopes` /
+   :mod:`~deepspeed_tpu.telemetry.programs` /
+   :mod:`~deepspeed_tpu.telemetry.hlo_text` /
+   :mod:`~deepspeed_tpu.telemetry.device_scopes` — the device's BUSY
+   time by layer: the one vocabulary of ``jax.named_scope`` names, what
+   each engine keeps at a program's first call to find its executable
+   again, the package's one reader of compiled HLO text (a scope table a
+   program, built on demand), and the reader that sums a profile's device
+   seconds by scope (``python -m deepspeed_tpu.telemetry.device_scopes
+   <profile_dir> --tables tables.json``; it shares
+   :mod:`~deepspeed_tpu.telemetry.profile`, the one profile loader, with
+   ``idle_gaps``).
  - :mod:`~deepspeed_tpu.telemetry.aggregate` — fleet federation: merge
    the router + replica registries into one ``replica=``-labeled
    registry (bucket-wise-summed histograms) and the per-replica trace

@@ -313,11 +313,35 @@ class ServingFlopsProfiler:
         with ctx():
             return jax.jit(body).lower(*args)
 
-    def _cost_analysis_flops(self, family: str) -> Optional[float]:
-        """``Lowered.cost_analysis()`` of the raw body — lowering only,
-        never a compile; ``None`` when the backend reports nothing."""
+    def built(self, family: str) -> Optional[str]:
+        """The name under which the engine RECORDED the program of
+        ``family`` at its first call (``telemetry/programs.py``: ``decode``,
+        ``verify``, ``draft``; ``prefill[<rows>x<width>]`` of the default
+        rung), or None for a program not yet called."""
+        srv = self.srv
+        name = family if family != "prefill" else \
+            f"prefill[{srv._rung_name((srv.prefill_batch, srv.prefill_chunk))}]"
+        records = getattr(getattr(srv, "programs", None), "records", {})
+        return name if name in records else None
+
+    def _cost_analysis_flops(self, family: str, built: bool = True
+                             ) -> Optional[float]:
+        """``Lowered.cost_analysis()`` of the program that was BUILT — the
+        engine's own jitted function at the abstract signature of its first
+        call, sampling operands and all (a trace-cache hit: the sentry does
+        not tick) — or, for a program not yet called (or with ``built``
+        false), of the raw body at :meth:`_abstract_args`' hand-derived
+        greedy shapes.  Lowering only, never a compile; ``None`` when the
+        backend reports nothing."""
         try:
-            lowered = self.lower(family)
+            name = self.built(family) if built else None
+            if name is not None:
+                ctx = getattr(self.srv, "_decode_ctx", self.srv._tp_ctx) \
+                    if family == "decode" else self.srv._tp_ctx
+                with ctx():
+                    lowered = self.srv.programs.lowered(name)
+            else:
+                lowered = self.lower(family)
             if lowered is None:
                 return None
             ca = lowered.cost_analysis()
@@ -354,9 +378,17 @@ class ServingFlopsProfiler:
                 family, fam_dims, rows=meta["rows"], width=meta["width"],
                 ctx=srv._cache_len)
             analytic = comp["head"] + comp["layers"]
-            reported = self._cost_analysis_flops(family)
+            # the layer loop's correction is read off the greedy body (the
+            # analytic components know matmuls, not a sampler's passes);
+            # what the BUILT program reports beyond it — the sampler over its
+            # operands, outside the loop — is counted once on top
+            body = self._cost_analysis_flops(family, built=False)
+            reported = self._cost_analysis_flops(family) \
+                if self.built(family) else body
             flops, source = self._reconcile(
-                family, reported, comp, fam_dims["layers"])
+                family, body, comp, fam_dims["layers"])
+            if reported is not None and body is not None:
+                flops += max(reported - body, 0.0)
             self._programs[family] = {
                 "rows": meta["rows"],
                 "width": meta["width"],
@@ -365,6 +397,9 @@ class ServingFlopsProfiler:
                 "flops_per_call": flops,
                 "tokens_per_call": meta["rows"] * max(meta["width"], 1),
                 "source": source,
+                # which signature was priced: the program's own, or the
+                # greedy body's derived one (never called yet)
+                "priced": "built" if self.built(family) else "derived",
             }
         return self._programs
 

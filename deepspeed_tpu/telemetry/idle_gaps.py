@@ -43,100 +43,20 @@ sum stands.
 from __future__ import annotations
 
 import bisect
-import glob
-import os
-import re
 import sys
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-Interval = Tuple[float, float]                 # (start_ns, end_ns)
-Span = Tuple[str, float, float]                # (name, start_ns, end_ns)
+from .profile import (DEVICE_PLANE, MODULES_LINE, OPS_LINE,  # noqa: F401
+                      SPAN_PREFIXES, Interval, Span, find_xplane, gaps, load,
+                      union, window_of)
 
-DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
-OPS_LINE = "XLA Ops"
-MODULES_LINE = "XLA Modules"
 #: the program's in-flight spans (``ServingEngine.step``), as annotations
 IN_FLIGHT = tuple("ds.serve." + n for n in (
     "prefill", "decode", "spec_propose", "spec_verify", "spec_round",
     "swap"))
-#: host spans that take part: the program's, and a caller's own
-SPAN_PREFIXES = ("ds.", "cb.")
 OUTSIDE = "outside_any_span"
-
-
-def find_xplane(path: str) -> str:
-    if os.path.isfile(path):
-        return path
-    found = sorted(glob.glob(os.path.join(path, "**", "*.xplane.pb"),
-                             recursive=True))
-    if not found:
-        raise FileNotFoundError(f"no .xplane.pb under {path}")
-    return found[-1]
-
-
-def load(path: str, prefixes: Iterable[str] = SPAN_PREFIXES
-         ) -> Tuple[List[Interval], List[Span], List[Span]]:
-    """(operation intervals of the first device plane, host spans whose
-    name starts with one of ``prefixes``, module executions of the same
-    device plane), all in nanoseconds on the profile's clock."""
-    from jax.profiler import ProfileData
-
-    data = ProfileData.from_file(find_xplane(path))
-    prefixes = tuple(prefixes)
-    ops: Optional[List[Interval]] = None
-    spans: List[Span] = []
-    modules: List[Span] = []
-    for plane in data.planes:
-        if DEVICE_PLANE.match(plane.name):
-            if ops is not None:
-                continue                        # the first device only
-            for line in plane.lines:
-                if line.name == OPS_LINE:
-                    ops = [(float(ev.start_ns),
-                            float(ev.start_ns + ev.duration_ns))
-                           for ev in line.events]
-                elif line.name == MODULES_LINE:
-                    modules = [(ev.name, float(ev.start_ns),
-                                float(ev.start_ns + ev.duration_ns))
-                               for ev in line.events]
-            continue
-        for line in plane.lines:
-            for ev in line.events:
-                if ev.name.startswith(prefixes):
-                    spans.append((ev.name, float(ev.start_ns),
-                                  float(ev.start_ns + ev.duration_ns)))
-    if not ops:
-        raise ValueError("the profile has no device plane with an "
-                         f"{OPS_LINE!r} line: nothing ran on the device, "
-                         "or the profiler saw none")
-    return ops, spans, modules
-
-
-def union(intervals: Iterable[Interval]) -> List[Interval]:
-    """Sorted, disjoint union of intervals."""
-    out: List[List[float]] = []
-    for s, e in sorted(i for i in intervals if i[1] > i[0]):
-        if out and s <= out[-1][1]:
-            out[-1][1] = max(out[-1][1], e)
-        else:
-            out.append([s, e])
-    return [(s, e) for s, e in out]
-
-
-def gaps(busy: List[Interval], lo: float, hi: float) -> List[Interval]:
-    """``[lo, hi]`` minus ``busy`` (sorted, disjoint)."""
-    out, cur = [], lo
-    for s, e in busy:
-        if e <= lo or s >= hi:
-            continue
-        if s > cur:
-            out.append((cur, s))
-        cur = max(cur, e)
-    if cur < hi:
-        out.append((cur, hi))
-    return out
 
 
 def innermost(spans: List[Span]) -> List[Span]:
@@ -179,13 +99,6 @@ def partition(gap_list: List[Interval], spans: List[Span]
         if hi - lo > covered:
             out[OUTSIDE] = out.get(OUTSIDE, 0.0) + (hi - lo - covered)
     return out
-
-
-def window_of(ops: List[Interval], spans: List[Span]) -> Interval:
-    wins = [(s, e) for n, s, e in spans if n.endswith(".window")]
-    if wins:
-        return max(wins, key=lambda w: w[1] - w[0])
-    return min(s for s, _ in ops), max(e for _, e in ops)
 
 
 def idle_by_span(ops: List[Interval], spans: List[Span]) -> Dict[str, Any]:
@@ -258,7 +171,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         description="the device's idle time by host span, from a profile")
     ap.add_argument("path", help="profile directory or .xplane.pb")
     args = ap.parse_args(argv)
-    ops, spans, modules = load(args.path)
+    ops, spans, modules = load(args.path)[:3]
     res = idle_by_span(ops, spans)
     print(f"window {res['window_s']:.3f} s, device idle "
           f"{res['idle_s']:.4f} s "

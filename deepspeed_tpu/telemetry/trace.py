@@ -95,8 +95,9 @@ SETUP_CAPACITY = 8192
 #: the JAX events the start-up ring holds, by phase of a function's build
 BUILD_PHASES = ("trace", "lower", "compile")
 
-#: role -> the newest timeline built for it (see :func:`keep`)
-_KEPT: Dict[str, "TraceTimeline"] = {}
+#: role -> the newest timeline built for it (see :func:`keep`); the role
+#: ``programs`` holds the newest engine's ``telemetry/programs.py Programs``
+_KEPT: Dict[str, Any] = {}
 
 
 def annotation(name: str):
@@ -150,15 +151,18 @@ class _Segment:
         return False
 
 
-def keep(role: str, timeline: "TraceTimeline") -> None:
+def keep(role: str, timeline: Any) -> None:
     """Hold ``timeline`` as the newest of its ``role`` (``serve``): whoever
     drove an engine reads its spans through :func:`kept` after the engine is
-    closed or collected.  One slot per role, replaced by the next engine."""
+    closed or collected.  One slot per role, replaced by the next engine.
+    The role ``programs`` holds an engine's ``Programs`` the same way: the
+    scope tables of its compiled programs, built when first asked for."""
     _KEPT[role] = timeline
 
 
-def kept(role: str) -> Optional["TraceTimeline"]:
-    """The newest timeline :func:`keep` was given for ``role``, or None."""
+def kept(role: str) -> Optional[Any]:
+    """The newest timeline (``programs``: the newest ``Programs``)
+    :func:`keep` was given for ``role``, or None."""
     return _KEPT.get(role)
 
 
@@ -201,18 +205,27 @@ class FirstCall:
     with the call's own arguments: what it builds of the program (a
     ``fn.lower(*args).compile()`` to read) is built under the program's
     name, and the call then finds it in JAX's caches.
+    ``programs``, if given (``telemetry/programs.py Programs``), is handed
+    the function and the call's arguments BEFORE the call and keeps their
+    abstract signature: what finds the executable again for a scope table —
+    microseconds, nothing lowered or compiled.
     Attributes (``lower``, ``_cache_size``) are the function's own."""
 
-    __slots__ = ("_fn", "_program", "_args", "_then", "_before", "_called")
+    __slots__ = ("_fn", "_program", "_args", "_then", "_before", "_programs",
+                 "_called")
 
-    def __init__(self, fn, program: str, then=None, before=None, **args):
+    def __init__(self, fn, program: str, then=None, before=None,
+                 programs=None, **args):
         self._fn, self._program, self._args = fn, program, args
         self._then, self._before, self._called = then, before, False
+        self._programs = programs
 
     def __call__(self, *args, **kwargs):
         if self._called:
             return self._fn(*args, **kwargs)
         self._called = True
+        if self._programs is not None:
+            self._programs.record(self._program, self._fn, args, kwargs)
         timeline = setup_timeline()
         with timeline.span("build", program=self._program, **self._args):
             outer, timeline.building = timeline.building, self._program

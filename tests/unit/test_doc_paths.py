@@ -30,7 +30,9 @@ SKIP_DIRS = {".git", "_parent", "_chip", "chiprun_out", ".jax_cache",
 #: files the program WRITES (the autotuner's results, an incident bundle's
 #: manifest, the example name of a dumped trace): never committed
 RUNTIME_OUTPUTS = {"best_config.json", "exps.json", "report.md",
-                   "manifest.json", "serving_trace.json"}
+                   "manifest.json", "serving_trace.json",
+                   # what benchmarks/scope_trace.py writes
+                   "tables.json", "by_scope.json", "by_scope.txt"}
 
 
 def _checkout_files():

@@ -400,4 +400,4 @@ def test_flops_report_schema_pinned(served):
     for prog in rep["programs"].values():
         assert set(prog.keys()) == {
             "rows", "width", "flops_analytic", "flops_cost_analysis",
-            "flops_per_call", "tokens_per_call", "source"}
+            "flops_per_call", "tokens_per_call", "source", "priced"}
